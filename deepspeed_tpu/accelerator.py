@@ -16,10 +16,29 @@ the reference should feature-check via ``hasattr``.
 """
 from __future__ import annotations
 
+import os
 from typing import Optional
 
 import jax
 import numpy as np
+
+
+def require_accelerator(cpu_requested: bool = False) -> bool:
+    """What the entry points (``bench.py``, ``examples/``) call before
+    any work: True on an accelerator.  On the CPU it is False only when
+    the caller asked for the CPU in so many words — its own switch
+    (``cpu_requested``, e.g. ``--smoke``) or ``JAX_PLATFORMS=cpu`` in the
+    environment — and an error otherwise: a missing chip is never
+    something discovered and papered over."""
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        return True
+    if cpu_requested or os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return False
+    raise SystemExit(
+        "no accelerator: JAX found only the CPU.  Run on the chip, or ask "
+        "for the CPU explicitly (JAX_PLATFORMS=cpu, or this program's own "
+        "smoke switch)")
 
 
 class TPU_Accelerator:

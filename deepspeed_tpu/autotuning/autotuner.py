@@ -47,10 +47,15 @@ class ModelInfo:
         import jax
         import numpy as np
 
-        rng = rng if rng is not None else jax.random.PRNGKey(0)
-        shapes = jax.eval_shape(
-            lambda: model.init({"params": rng, "dropout": rng},
-                               example_batch))
+        def init():
+            # the default key is made INSIDE the trace: nothing here may
+            # touch a backend, or a tuner whose experiments run in child
+            # processes (make_subprocess_runner) would hold their chip
+            key = rng if rng is not None else jax.random.PRNGKey(0)
+            return model.init({"params": key, "dropout": key},
+                              example_batch)
+
+        shapes = jax.eval_shape(init)
         n = sum(int(np.prod(l.shape))
                 for l in jax.tree_util.tree_leaves(shapes))
         return ModelInfo(num_params=n)
@@ -78,9 +83,14 @@ class Autotuner:
                                     1))
         self.stages = at.get("zero_stages", [0, 1, 2, 3])
         self.runner = runner or self._default_runner
-        import jax
+        # asked of the backend only when not given: with a subprocess
+        # runner pass both, so that this (parent) process stays off the
+        # chip its experiments need
+        if num_chips is None:
+            import jax
 
-        self.num_chips = num_chips or len(jax.devices())
+            num_chips = len(jax.devices())
+        self.num_chips = num_chips
         self.hbm_bytes = hbm_bytes or self._detect_hbm()
         self.records: List[Dict[str, Any]] = []
         self.best: Optional[Tuple[Dict, float]] = None

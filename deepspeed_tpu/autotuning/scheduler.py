@@ -10,7 +10,9 @@ launches with result scraping, ``tuner/base_tuner.py BaseTuner``,
   python subprocess (``exp_runner`` below): a config that OOMs, fails to
   compile, or wedges the TPU runtime kills its own interpreter, not the
   tuner — the reference's reason for subprocess isolation, plus the TPU
-  twist that a poisoned client/tunnel often cannot recover in-process.
+  twist that a poisoned runtime client often cannot recover in-process.
+  The chip belongs to one process at a time, so the process that runs
+  the scheduler must stay off the jax backend while experiments run.
   Failures are quarantined as records with the error string.
 - Tuners search a ``tuning_space`` dict-of-lists (e.g. zero stage,
   micro-batch, remat, offload).  ``ModelBasedTuner`` fits a ridge
@@ -194,6 +196,11 @@ def make_subprocess_runner(factory: str, steps: int = 3,
     ``fn()`` must return ``(model, batch_fn)`` where ``batch_fn(global
     _batch_size)`` yields a training batch.  OOM / compile failure /
     hang (timeout) surface as exceptions here and quarantine upstream.
+
+    A chip belongs to one process at a time: the process that calls this
+    runner must not have touched a jax backend (give ``Autotuner`` its
+    ``num_chips`` and ``hbm_bytes``), or every experiment fails at
+    start-up.
     """
 
     def run(ds_config: Dict[str, Any]) -> float:

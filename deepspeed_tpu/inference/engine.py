@@ -93,10 +93,7 @@ class PendingGeneration:
         harvested, or the device reports the buffer ready)."""
         if self._result is not None:
             return True
-        try:
-            return bool(self._arr.is_ready())
-        except AttributeError:      # pragma: no cover - old jax arrays
-            return True
+        return bool(self._arr.is_ready())
 
     def result(self) -> np.ndarray:
         if self._result is None:

@@ -29,10 +29,16 @@ from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 import numpy as np
 
+from deepspeed_tpu.ops import routes
+from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
+
 TRASH_PAGE = 0
+
+# block sizes handed to the upstream ragged paged kernel (see ``attend``)
+_RPA_KV_BLOCK_TOKENS = 512
+_RPA_QUERY_BLOCK = 32
 
 # same mask-value family as ops/flash_attention.py and the Pallas
 # quantized kernel: vanishes under softmax, (mask - mask) stays exact 0
@@ -599,20 +605,32 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         # models should use 128-dim heads.  An incoming chunk-scan
         # carry always routes to the reference: the upstream kernel has
         # no carry operand (the quantized pool's own kernel does).
-        if carry is None and jax.default_backend() == "tpu" and D == 128:
+        if paged_attention_path(D, carry=carry is not None) == \
+                "pallas-ragged":
             from jax.experimental.pallas.ops.tpu.ragged_paged_attention \
                 import kernel as rpa
 
+            # explicit blocks: for a shape outside its tuned table the
+            # upstream lookup answers (128 pages, 32 queries), and from
+            # max_seq_len ~2k up that KV block no longer fits the chip's
+            # scoped VMEM (the v5e compiler: "Ran out of memory in memory
+            # space vmem").  512 KV tokens x 32 queries compiles under the
+            # default limit at every page size the engine offers.
             return rpa.ragged_paged_attention(
                 qt, pages, kv_lens, jnp.maximum(page_indices, 0),
                 cu_q_lens, num_seqs, sm_scale=sm_scale,
-                sliding_window=window)
-        if carry is None and jax.default_backend() == "tpu":
+                sliding_window=window,
+                num_kv_pages_per_block=max(
+                    1, min(page_indices.shape[1],
+                           _RPA_KV_BLOCK_TOKENS // page)),
+                num_queries_per_block=min(T, _RPA_QUERY_BLOCK))
+        if carry is None and routes.on_tpu():
             from deepspeed_tpu.utils.logging import logger
 
             logger.warning(
                 f"paged attention: head_dim={D} != 128 — the Pallas "
-                "ragged kernel needs 128; using the dense XLA fallback")
+                "ragged kernel needs 128; using the dense XLA route "
+                "(serving_stages()['kernels'] reports it)")
         return ref_paged_attention(
             qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
             sm_scale=sm_scale, sliding_window=window, carry=carry)
@@ -688,9 +706,19 @@ def kv_dequant_path(head_dim: int) -> str:
     (:func:`ref_paged_attention_quant`).  Neither materializes a
     full-width pool operand.  The engine reports this in its
     ``serving_stages()['kv_quant']`` block."""
-    if jax.default_backend() == "tpu" and head_dim == 128:
-        return "pallas-quant"
-    return "xla-gather"
+    return routes.take("paged_attention_quant", eligible=head_dim == 128,
+                       pallas="pallas-quant", xla="xla-gather")
+
+
+def paged_attention_path(head_dim: int, carry: bool = False) -> str:
+    """Which read path a full-width pool takes on this backend: the
+    upstream Pallas ragged kernel (TPU, head_dim 128, no incoming
+    chunk-scan carry — it has no carry operand) or the dense XLA
+    reference (:func:`ref_paged_attention`).  The engine reports this in
+    ``serving_stages()['kernels']``."""
+    return routes.take("paged_attention",
+                       eligible=head_dim == 128 and not carry,
+                       pallas="pallas-ragged", xla="xla-dense")
 
 
 def _serving_tp(cfg) -> int:

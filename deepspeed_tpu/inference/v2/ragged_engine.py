@@ -340,14 +340,23 @@ class RaggedInferenceEngineV2:
 
         import deepspeed_tpu.comm as dist
 
-        if topology is not None:
+        # a ONE-device topology is a replica slice: this engine's params,
+        # KV pool and per-tick metadata are committed to that device, and
+        # the process-global topology is left alone — N such engines share
+        # the process, each ticking on its own thread
+        # (serving/replica_set.py), so a global one would be whichever
+        # replica was built last
+        if topology is not None and topology.mesh.size > 1:
             dist.set_topology(topology)
-        else:
+        elif topology is None:
             topology = dist.peek_topology()
         self.topology = topology
         self.mesh = topology.mesh if topology is not None else None
         self.tp = (topology.tensor_parallel_size
                    if topology is not None else 1)
+        self._device = (self.mesh.devices.flat[0]
+                        if self.mesh is not None and self.mesh.size == 1
+                        else None)
 
         # config-sourced knobs resolve BEFORE pool sizing: the resolved
         # kv_cache_dtype decides the per-page byte cost a kv_pool_bytes
@@ -599,7 +608,7 @@ class RaggedInferenceEngineV2:
                     dmcfg, decode=False)))
             if tp_lib.has_partitioning(dparams):
                 dparams = tp_lib.unbox_params(dparams)
-            self._draft_params = jax.device_put(dparams)
+            self._draft_params = jax.device_put(dparams, self._device)
             self._draft_cache = self._init_cache(self._draft)
         self._uid = itertools.count()
         self.waiting: Deque[Request] = deque()
@@ -768,6 +777,8 @@ class RaggedInferenceEngineV2:
         if self.tp <= 1:
             if tp_lib.has_partitioning(params):
                 params = tp_lib.unbox_params(params)
+            if self._device is not None:
+                params = jax.device_put(params, self._device)
             return params
         if tp_lib.has_partitioning(params):
             specs = tp_lib.extract_partition_specs(
@@ -797,11 +808,14 @@ class RaggedInferenceEngineV2:
         """KV page pools shard their combined-head dim over `tensor`
         (reference v2 KV sharding: heads split over the TP group); the
         quantized pools' [P, page, 2Hkv] scale buffers shard the same
-        head dim."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        head dim.  A one-device replica slice pins them to its device;
+        ``None`` leaves the default placement."""
+        from jax.sharding import (NamedSharding, PartitionSpec as P,
+                                  SingleDeviceSharding)
 
         if self.tp <= 1:
-            return None
+            return (SingleDeviceSharding(self._device)
+                    if self._device is not None else None)
         if len(leaf_shape) == 4:
             return NamedSharding(self.mesh, P(None, None, "tensor", None))
         if len(leaf_shape) == 3:
@@ -1374,14 +1388,21 @@ class RaggedInferenceEngineV2:
                       hit_tokens=st.prefix_hit_tokens,
                       cow_copies=st.prefix_cow_copies)
             out["prefix_cache"] = pc
+        from deepspeed_tpu.inference.paged import (kv_dequant_path,
+                                                   paged_attention_path)
+
+        head_dim = int(getattr(self.cfg, "head_dim", 0))
         if self.kv_cache_dtype != "none":
             from deepspeed_tpu.inference.common import kv_quant_block
-            from deepspeed_tpu.inference.paged import kv_dequant_path
 
+            route = kv_dequant_path(head_dim)
             out["kv_quant"] = kv_quant_block(
-                self.cache, self.kv_cache_dtype,
-                kv_dequant_path(int(getattr(self.cfg, "head_dim", 0))),
-                self.num_pages)
+                self.cache, self.kv_cache_dtype, route, self.num_pages)
+        else:
+            route = paged_attention_path(head_dim)
+        # the attention read route this backend gives this pool: a Pallas
+        # kernel, or the XLA twin it stands in for (ops/routes.py)
+        out["kernels"] = {"paged_attention": route}
         # pool pressure: the scale-out router's least-pressure policy
         # reads this (waiting queue + page occupancy, both plain host
         # ints — no device sync)
@@ -1439,7 +1460,7 @@ class RaggedInferenceEngineV2:
         ``host_stats.meta_uploads`` asserts it in tests)."""
         with self.host_stats.stage("upload"):
             self.host_stats.meta_uploads += 1
-            return jnp.asarray(x)
+            return jax.device_put(x, self._device)
 
     def _fetch(self, tree):
         """Blocking device -> host fetch — the serving loop's only sync
@@ -1480,9 +1501,9 @@ class RaggedInferenceEngineV2:
         assert "cache" in shapes
 
         def make(s):
-            z = jnp.zeros(s.shape, s.dtype)
-            sh = self._cache_sharding(s.shape)
-            return jax.device_put(z, sh) if sh is not None else z
+            # allocated where it lives: a pool is most of a chip's memory
+            return jnp.zeros(s.shape, s.dtype,
+                             device=self._cache_sharding(s.shape))
 
         return jax.tree_util.tree_map(make, shapes["cache"])
 

@@ -65,10 +65,14 @@ def _ensure_built() -> ctypes.CDLL:
         stale = (not os.path.exists(_LIB) or
                  os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
         if stale:
+            # built from aio.cpp alone, into a name of this process's own:
+            # several processes (xdist workers) may find it stale at once,
+            # and each must publish a whole file
+            tmp = f"{_LIB}.{os.getpid()}.tmp"
             cmd = ["g++", "-O2", "-shared", "-fPIC", "-pthread",
-                   "-std=c++17", _SRC, "-o", _LIB + ".tmp"]
+                   "-std=c++17", _SRC, "-o", tmp]
             subprocess.run(cmd, check=True, capture_output=True)
-            os.replace(_LIB + ".tmp", _LIB)
+            os.replace(tmp, _LIB)
         lib = ctypes.CDLL(_LIB)
         lib.aio_create.restype = ctypes.c_void_p
         lib.aio_create.argtypes = [ctypes.c_int, ctypes.c_int64,

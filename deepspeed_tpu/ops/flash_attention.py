@@ -30,9 +30,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x spells it TPUCompilerParams; modern jax CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.ops import routes
+from deepspeed_tpu.utils.compat import get_abstract_mesh, shard_map
 
 DEFAULT_MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 _LANE = 128  # TPU lane width; scratch row-stat buffers are (bq, _LANE)
@@ -342,7 +343,7 @@ def _flash_fwd_pallas(q, k, v, *, sm_scale: float, causal: bool,
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -522,7 +523,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale: float, causal: bool,
         out_specs=[q_spec],
         out_shape=[jax.ShapeDtypeStruct((B, H, nq * block_q, D), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -549,7 +550,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale: float, causal: bool,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -571,10 +572,6 @@ def _expand_kv(q, k, v):
     return k, v
 
 
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     out, _ = _flash_fwd_dispatch(q, k, v, sm_scale, causal, block_q, block_k,
@@ -584,7 +581,7 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 
 def _flash_fwd_dispatch(q, k, v, sm_scale, causal, block_q, block_k,
                         interpret):
-    if _use_pallas() or interpret:
+    if routes.take("flash_attention_fwd", interpret=interpret) != routes.XLA:
         return _flash_fwd_pallas(q, k, v, sm_scale=sm_scale, causal=causal,
                                  block_q=block_q, block_k=block_k,
                                  interpret=interpret)
@@ -604,7 +601,7 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, out, lse = res
     n_kv = k.shape[1]
     groups = q.shape[1] // n_kv
-    if _use_pallas() or interpret:
+    if routes.take("flash_attention_bwd", interpret=interpret) != routes.XLA:
         dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, do,
                                        sm_scale=sm_scale, causal=causal,
                                        block_q=block_q, block_k=block_k,
@@ -648,5 +645,40 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # mask away) up to block_q/S times the work on short sequences
     block_q = min(int(block_q), q.shape[2])
     block_k = min(int(block_k), k.shape[2])
-    return _flash(q, k, v, float(sm_scale), bool(causal), block_q,
-                  block_k, bool(interpret))
+    attend = functools.partial(
+        _flash, sm_scale=float(sm_scale), causal=bool(causal),
+        block_q=block_q, block_k=block_k, interpret=bool(interpret))
+    if interpret or routes.on_tpu():
+        sharded = _mesh_partition(q, k)
+        if sharded is not None:
+            mesh, spec = sharded
+            attend = shard_map(attend, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec, check_vma=False)
+    return attend(q, k, v)
+
+
+def _mesh_partition(q, k):
+    """``(mesh, spec)`` to run the Pallas kernel manually partitioned
+    under the installed multi-device mesh, ``None`` on one device or
+    inside a region that is already manual (Ulysses, ring).
+
+    GSPMD cannot split a Mosaic kernel (the TPU compiler: "Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map"), and attention is independent per sample and per head:
+    batch goes over the data axes, heads over ``tensor``, each only where
+    it divides.  A dim that does not divide stays whole, every shard then
+    computing it."""
+    import deepspeed_tpu.comm as dist
+
+    topo = dist.peek_topology()
+    if topo is None or topo.mesh.size == 1 or \
+            get_abstract_mesh().manual_axes:
+        return None
+    sizes = topo.mesh.shape
+    batch = tuple(a for a in ("data", "data_sub", "expert") if sizes[a] > 1)
+    if q.shape[0] % int(np.prod([sizes[a] for a in batch])):
+        batch = ()
+    tp = sizes["tensor"]
+    heads = ("tensor" if tp > 1 and q.shape[1] % tp == 0
+             and k.shape[1] % tp == 0 else None)
+    return topo.mesh, P(batch or None, heads, None, None)

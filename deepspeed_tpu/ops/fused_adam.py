@@ -30,6 +30,8 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import routes
+
 _LANE = 128
 _BLOCK_ROWS = 512  # rows of 128 lanes per grid step
 
@@ -128,10 +130,6 @@ def _run_elementwise(kernel, scalars, tiles, n_outs: int, interpret: bool):
     )(scalars, *tiles)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 # ---------------------------------------------------------------------------
 # Per-leaf updates (pallas on TPU / jnp elsewhere)
 # ---------------------------------------------------------------------------
@@ -139,7 +137,7 @@ def _on_tpu() -> bool:
 def adam_update_leaf(g, p, m, v, step, *, b1, b2, eps, wd, adam_w,
                      interpret: bool = False):
     """Returns (u, m_new, v_new) for one leaf."""
-    if _on_tpu() or interpret:
+    if routes.take("fused_adam", interpret=interpret) != routes.XLA:
         t = step.astype(jnp.float32)
         scalars = jnp.stack([1.0 - jnp.power(jnp.float32(b1), t),
                              1.0 - jnp.power(jnp.float32(b2), t)])
@@ -166,7 +164,7 @@ def adam_update_leaf(g, p, m, v, step, *, b1, b2, eps, wd, adam_w,
 
 
 def lion_update_leaf(g, p, m, step, *, b1, b2, wd, interpret: bool = False):
-    if _on_tpu() or interpret:
+    if routes.take("fused_lion", interpret=interpret) != routes.XLA:
         kern = functools.partial(_lion_kernel, b1=b1, b2=b2, wd=wd)
         u, m_new = _run_elementwise(
             kern, jnp.zeros((2,), jnp.float32),

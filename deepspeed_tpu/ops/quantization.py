@@ -26,6 +26,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import routes
+
 _LANE = 128
 
 
@@ -61,10 +63,6 @@ def _dequant_kernel(v_ref, s_ref, o_ref, x_ref):
                 ).astype(x_ref.dtype)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _grouped(x: jax.Array, group_size: int) -> Tuple[jax.Array, int]:
     n = x.size
     num_groups = pl.cdiv(n, group_size)
@@ -84,7 +82,7 @@ def quantize(x: jax.Array, num_bits: int = 8, group_size: int = 2048,
     q_max = float(2 ** (num_bits - 1) - 1)
     xg, num_groups = _grouped(x, group_size)
 
-    if _on_tpu() or interpret:
+    if routes.take("quantize", interpret=interpret) != routes.XLA:
         rows_blk = min(256, num_groups)
         grid = (pl.cdiv(num_groups, rows_blk),)
         pad_rows = grid[0] * rows_blk - num_groups
@@ -122,7 +120,7 @@ def quantize(x: jax.Array, num_bits: int = 8, group_size: int = 2048,
 
 
 def dequantize(qt: QuantizedTensor, interpret: bool = False) -> jax.Array:
-    if _on_tpu() or interpret:
+    if routes.take("dequantize", interpret=interpret) != routes.XLA:
         num_groups, group_size = qt.values.shape
         rows_blk = min(256, num_groups)
         grid = (pl.cdiv(num_groups, rows_blk),)

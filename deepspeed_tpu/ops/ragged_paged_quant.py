@@ -18,19 +18,31 @@ matching ``[num_pages, page_size, 2*Hkv]`` fp32 buffer; ``page_indices``
 pads unused entries with -1; ``kv_lens`` includes the current tick's
 tokens.
 
-Grid: ``(num_seq_slots, pages_per_seq)`` with the page dim minor, so the
-streaming-softmax accumulators (m, l, acc) carry across one sequence's
-pages in VMEM scratch.  The ragged metadata rides scalar prefetch
-(:class:`~jax.experimental.pallas.tpu.PrefetchScalarGridSpec`): page ids
-feed the page/scale BlockSpec index maps, so the DMA engine fetches only
-attended pages.  The output block is constant-indexed and revisited —
-each sequence's programs write only their own query rows at their last
-page step.
+Grid: ``(query_row_blocks, num_seq_slots, pages_per_seq)`` with the page
+dim minor, so the streaming-softmax accumulators (m, l, acc) carry across
+one sequence's pages in VMEM scratch; a query-row block holds at most
+``_Q_BLOCK`` tokens, which bounds VMEM at any tick width and skips the
+sequences that own no row in it.  The ragged metadata rides scalar
+prefetch (:class:`~jax.experimental.pallas.tpu.PrefetchScalarGridSpec`):
+page ids feed the page/scale BlockSpec index maps, so the DMA engine
+fetches only attended pages.  The output block changes only with the
+row block and is revisited — each sequence's programs write only their
+own query rows at their last page step.
+
+Written for Mosaic's vector layouts: everything per-query is
+``[Hkv, rows, lanes]`` (KV head leading, untiled), each KV head's work is
+2-D ``[rows, D] x [page, D]`` tiles, K/V of one head are sublane-strided
+loads of the widened page tile, a scale column becomes a ``[page, 1]``
+vector by a masked lane reduction, and masks are built at the shape they
+are used — no reshape splits the lane dim or moves an ``i1`` vector.
 
 Head dim must be 128 (the MXU lane width, same constraint as the
-full-width kernel).  ``interpret=True`` runs the identical kernel through
-the Pallas interpreter — that is how tier-1 covers this file on the CPU
-container (``tests/unit/inference/test_paged_quant.py`` parity suite).
+full-width kernel), and ``2 * Hkv`` a multiple of 8 to compile for the
+chip.  Two suites cover this file on the CPU: ``interpret=True`` runs the
+identical kernel through the Pallas interpreter
+(``tests/unit/inference/test_paged_quant.py``, parity), and
+``tests/unit/ops/test_tpu_compile.py`` hands it to the TPU's compiler at
+Mistral-7B widths.
 """
 from __future__ import annotations
 
@@ -46,6 +58,16 @@ from jax.experimental.pallas import tpu as pltpu
 # same mask value family as ops/flash_attention.py: large enough to
 # vanish under softmax, small enough that (mask - mask) stays exact 0
 _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
+# the blocks below are f32 and lane-padded (a [rows, 1] stat costs a full
+# 128-lane tile): ~14 MiB at 128 query rows x 32 heads, ~40 MiB with the
+# carry in/out operands — over the 16 MiB default scope, well inside a
+# v5e core's 128 MiB
+_VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+
+# query rows (tokens) one grid step holds in VMEM: bounds the q / output /
+# accumulator blocks at any fused-tick width T
+_Q_BLOCK = 128
 
 
 def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
@@ -54,7 +76,12 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
                   out_stats: bool):
     # positional refs vary with the carry/stats variants: inputs are
     # (q, pages, scales[, m_in, l_in, acc_in]), outputs are
-    # (o[, m_out, l_out, acc_out]), then the three VMEM scratch buffers
+    # (o[, m_out, l_out, acc_out]), then the four VMEM scratch buffers.
+    # Everything per-query is laid out [Hkv, rows, lanes] with
+    # rows = q_block * groups (row r is token r // groups, q head
+    # h * groups + r % groups), so each KV head's work is plain 2-D
+    # [rows, D] x [page, D] tiles — the only layouts Mosaic is asked for
+    # keep D (or the page) on the 128-lane dim and never reshape a mask.
     q_ref, pages_ref, scales_ref = refs[0], refs[1], refs[2]
     n = 3
     if has_carry:
@@ -65,11 +92,15 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
     if out_stats:
         mo_ref, lo_ref, acco_ref = refs[n], refs[n + 1], refs[n + 2]
         n += 3
-    acc_sc, m_sc, l_sc = refs[n], refs[n + 1], refs[n + 2]
+    acc_sc, m_sc, l_sc, kv_sc = refs[n:n + 4]
 
-    i = pl.program_id(0)                   # sequence slot
-    j = pl.program_id(1)                   # page ordinal within the slot
-    pp = pl.num_programs(1)
+    b = pl.program_id(0)                   # query-row block
+    i = pl.program_id(1)                   # sequence slot
+    j = pl.program_id(2)                   # page ordinal within the slot
+    pp = pl.num_programs(2)
+    Hkv, N, D = q_ref.shape
+    combined = 2 * Hkv
+    t0 = b * (N // groups)                 # first token of this block
 
     @pl.when(jnp.logical_and(i == 0, j == 0))
     def _zero_out():
@@ -95,7 +126,10 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
     q0 = cu_ref[i]
     q1 = cu_ref[i + 1]
     kvl = kv_lens_ref[i]
-    live = jnp.logical_and(i < ns_ref[0], q1 > q0)
+    # the slot is in use and owns query rows inside this block
+    live = jnp.logical_and(
+        jnp.logical_and(i < ns_ref[0], q1 > q0),
+        jnp.logical_and(q0 < t0 + N // groups, q1 > t0))
     # page j holds attended rows AND is resident: a -1 entry is padding
     # or a parked partial-residency hole — its tile (the index map
     # clamped it to the trash page) is skipped, and because kv
@@ -105,64 +139,69 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
 
     @pl.when(jnp.logical_and(live, in_range))
     def _tile():
-        T, H, D = q_ref.shape
-        Hkv = pages_ref.shape[2] // 2
-        # dequantize THIS page tile only, in registers: 1-byte rows and
-        # one fp32 scale per (row, combined head)
-        tile = pages_ref[0].astype(jnp.float32)          # [page, 2Hkv, D]
-        kvf = tile * scales_ref[0][..., None]
-        kvf = kvf.reshape(page, Hkv, 2, D)
-        k = kvf[:, :, 0, :]                              # [page, Hkv, D]
-        v = kvf[:, :, 1, :]
+        # dequantize THIS page tile only: 1-byte rows widen to f32 into a
+        # [page * 2Hkv, D] scratch whose row p * 2Hkv + c is token p of
+        # combined head c, so K / V of one head are sublane-strided loads
+        kv_sc[...] = pages_ref[0].astype(jnp.float32).reshape(
+            page * combined, D)
+        sc = scales_ref[0]                               # [page, 2Hkv]
+        head_of = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
 
-        qf = q_ref[...].astype(jnp.float32)              # pre-scaled
-        qg = qf.reshape(T, Hkv, groups, D)
-        att = jnp.einsum("thgd,phd->thgp", qg, k,
-                         preferred_element_type=jnp.float32)
-
-        t_idx = jax.lax.broadcasted_iota(jnp.int32, (T, page), 0)
-        kv_idx = jax.lax.broadcasted_iota(jnp.int32, (T, page), 1) + \
+        t_idx = t0 + jax.lax.broadcasted_iota(
+            jnp.int32, (N, page), 0) // groups
+        kv_idx = jax.lax.broadcasted_iota(jnp.int32, (N, page), 1) + \
             j * page
         q_pos = kvl - (q1 - q0) + (t_idx - q0)           # abs position
         mask = ((t_idx >= q0) & (t_idx < q1) &
                 (kv_idx <= q_pos) & (kv_idx < kvl))
         if sliding_window is not None:
             mask = mask & (kv_idx > q_pos - sliding_window)
-        att = jnp.where(mask[:, None, None, :], att, _MASK_VALUE)
 
-        att2 = att.reshape(T, Hkv * groups, page)        # [T, H, page]
-        curr_m = jnp.max(att2, axis=-1)                  # [T, H]
-        m_new = jnp.maximum(m_sc[...], curr_m)
-        alpha = jnp.exp(m_sc[...] - m_new)
-        p = jnp.exp(att2 - m_new[..., None])             # [T, H, page]
-        pv = jnp.einsum("thgp,phd->thgd",
-                        p.reshape(T, Hkv, groups, page), v,
-                        preferred_element_type=jnp.float32)
-        acc_sc[...] = (acc_sc[...] * alpha[..., None] +
-                       pv.reshape(T, Hkv * groups, D))
-        l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=-1)
-        m_sc[...] = m_new
+        def row_scale(c):
+            # one fp32 scale per (row, combined head): pick head c's
+            # column as a [page, 1] sublane vector (a masked lane
+            # reduction — exact, the other terms are 0)
+            return jnp.sum(jnp.where(head_of == c, sc, 0.0), axis=1,
+                           keepdims=True)
+
+        for h in range(Hkv):
+            k = kv_sc[pl.ds(2 * h, page, stride=combined), :] * \
+                row_scale(2 * h)                         # [page, D]
+            v = kv_sc[pl.ds(2 * h + 1, page, stride=combined), :] * \
+                row_scale(2 * h + 1)
+            att = jax.lax.dot_general(                   # [N, page]
+                q_ref[h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            att = jnp.where(mask, att, _MASK_VALUE)
+            m_prev = m_sc[h]                             # [N, 1]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(att, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(att - m_new)
+            acc_sc[h] = acc_sc[h] * alpha + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            l_sc[h] = l_sc[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            m_sc[h] = m_new
 
     @pl.when(jnp.logical_and(live, j == pp - 1))
     def _finalize():
-        T = q_ref.shape[0]
-        l = jnp.maximum(l_sc[...], 1e-30)
-        # rows that never matched a key keep 0 (the engine's padding
-        # rows and other sequences' rows are written by their own
-        # programs or stay at the j==0 zero fill)
-        valid = m_sc[...] > _MASK_VALUE * 0.5            # [T, H]
-        val = jnp.where(valid[..., None], acc_sc[...] / l[..., None], 0.0)
-        rows = jax.lax.broadcasted_iota(jnp.int32, (T,), 0)
-        mine = jnp.logical_and(rows >= q0, rows < q1)    # [T]
-        o_ref[...] = jnp.where(mine[:, None, None],
-                               val.astype(o_ref.dtype), o_ref[...])
-        if out_stats:
-            # the explicit carry OUTPUT: raw (un-normalized) stats, so a
-            # later dispatch can keep folding
-            mo_ref[...] = jnp.where(mine[:, None], m_sc[...], mo_ref[...])
-            lo_ref[...] = jnp.where(mine[:, None], l_sc[...], lo_ref[...])
-            acco_ref[...] = jnp.where(mine[:, None, None], acc_sc[...],
-                                      acco_ref[...])
+        rows = t0 + jax.lax.broadcasted_iota(
+            jnp.int32, (N, 1), 0) // groups
+        mine = jnp.logical_and(rows >= q0, rows < q1)    # [N, 1]
+        for h in range(Hkv):
+            l = jnp.maximum(l_sc[h], 1e-30)
+            # rows that never matched a key keep 0 (the engine's padding
+            # rows and other sequences' rows are written by their own
+            # programs or stay at the j==0 zero fill)
+            valid = m_sc[h] > _MASK_VALUE * 0.5          # [N, 1]
+            val = jnp.where(valid, acc_sc[h] / l, 0.0)
+            o_ref[h] = jnp.where(mine, val, o_ref[h])
+            if out_stats:
+                # the explicit carry OUTPUT: raw (un-normalized) stats,
+                # so a later dispatch can keep folding
+                mo_ref[h] = jnp.where(mine, m_sc[h], mo_ref[h])
+                lo_ref[h] = jnp.where(mine, l_sc[h], lo_ref[h])
+                acco_ref[h] = jnp.where(mine, acc_sc[h], acco_ref[h])
 
 
 def ragged_paged_attention_quant(
@@ -200,68 +239,81 @@ def ragged_paged_attention_quant(
     groups = H // Hkv
     assert pages.dtype in (jnp.int8, jnp.float8_e4m3fn), pages.dtype
 
-    # fold sm_scale into q host-side (one mul per q element, exactly as
-    # ops/flash_attention.py) and pad q rows to the f32 sublane multiple
-    qf = q.astype(jnp.float32) * jnp.float32(sm_scale)
+    # pad query rows to the f32 sublane multiple, then to whole blocks
     Tp = (T + 7) // 8 * 8
-    if Tp != T:
-        qf = jnp.pad(qf, ((0, Tp - T), (0, 0), (0, 0)))
+    tq = min(Tp, _Q_BLOCK)
+    Tp = (Tp + tq - 1) // tq * tq
+    N = tq * groups
+
+    def to_rows(x, fill=0.0):
+        # [T, H, ...] -> [Hkv, Tp * groups, lanes]: the KV head leads so
+        # the kernel indexes it on an untiled dim
+        x = x.astype(jnp.float32)
+        x = x.reshape(T, H, -1)
+        if Tp != T:
+            # padded rows belong to no sequence; neutral-pad them so the
+            # seeded accumulators stay finite
+            x = jnp.pad(x, ((0, Tp - T), (0, 0), (0, 0)),
+                        constant_values=fill)
+        lanes = x.shape[-1]
+        return x.reshape(Tp, Hkv, groups, lanes).transpose(
+            1, 0, 2, 3).reshape(Hkv, Tp * groups, lanes)
+
+    def from_rows(x):
+        lanes = x.shape[-1]
+        return x.reshape(Hkv, Tp, groups, lanes).transpose(
+            1, 0, 2, 3).reshape(Tp, H, lanes)[:T]
+
+    # fold sm_scale into q host-side (one mul per q element, exactly as
+    # ops/flash_attention.py)
+    qf = to_rows(q.astype(jnp.float32) * jnp.float32(sm_scale))
 
     # raw page ids ride the scalar prefetch so the kernel can SKIP -1
     # tiles; the BlockSpec index maps clamp to the trash page only to
     # keep the DMA address legal for skipped tiles
     pi = page_indices.astype(jnp.int32)
 
+    def wide():
+        return pl.BlockSpec((Hkv, N, D), lambda b, i, j, *refs: (0, b, 0))
+
+    def stat():
+        return pl.BlockSpec((Hkv, N, 1), lambda b, i, j, *refs: (0, b, 0))
+
     in_specs = [
-        pl.BlockSpec((Tp, H, D), lambda i, j, *refs: (0, 0, 0)),
+        wide(),
         pl.BlockSpec((1, page, combined, D),
-                     lambda i, j, kvl, pi, cu, ns: (
+                     lambda b, i, j, kvl, pi, cu, ns: (
                          jnp.maximum(pi[i, j], 0), 0, 0, 0)),
         pl.BlockSpec((1, page, combined),
-                     lambda i, j, kvl, pi, cu, ns: (
+                     lambda b, i, j, kvl, pi, cu, ns: (
                          jnp.maximum(pi[i, j], 0), 0, 0)),
     ]
     operands = [qf, pages, scales]
     if carry is not None:
         m0, l0, acc0 = carry
-        if Tp != T:
-            # padded rows belong to no sequence; neutral-pad them so the
-            # seeded accumulators stay finite
-            m0 = jnp.pad(m0.astype(jnp.float32), ((0, Tp - T), (0, 0)),
-                         constant_values=_MASK_VALUE)
-            l0 = jnp.pad(l0.astype(jnp.float32), ((0, Tp - T), (0, 0)))
-            acc0 = jnp.pad(acc0.astype(jnp.float32),
-                           ((0, Tp - T), (0, 0), (0, 0)))
-        in_specs += [
-            pl.BlockSpec((Tp, H), lambda i, j, *refs: (0, 0)),
-            pl.BlockSpec((Tp, H), lambda i, j, *refs: (0, 0)),
-            pl.BlockSpec((Tp, H, D), lambda i, j, *refs: (0, 0, 0)),
-        ]
-        operands += [m0.astype(jnp.float32), l0.astype(jnp.float32),
-                     acc0.astype(jnp.float32)]
+        in_specs += [stat(), stat(), wide()]
+        operands += [to_rows(m0, _MASK_VALUE), to_rows(l0), to_rows(acc0)]
 
-    out_shape = jax.ShapeDtypeStruct((Tp, H, D), q.dtype)
-    out_spec = pl.BlockSpec((Tp, H, D), lambda i, j, *refs: (0, 0, 0))
+    rows = Tp * groups
+    out_shape = jax.ShapeDtypeStruct((Hkv, rows, D), jnp.float32)
+    out_spec = wide()
     if return_stats:
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((Tp, H), jnp.float32),
-                     jax.ShapeDtypeStruct((Tp, H), jnp.float32),
-                     jax.ShapeDtypeStruct((Tp, H, D), jnp.float32))
-        out_spec = (out_spec,
-                    pl.BlockSpec((Tp, H), lambda i, j, *refs: (0, 0)),
-                    pl.BlockSpec((Tp, H), lambda i, j, *refs: (0, 0)),
-                    pl.BlockSpec((Tp, H, D),
-                                 lambda i, j, *refs: (0, 0, 0)))
+                     jax.ShapeDtypeStruct((Hkv, rows, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((Hkv, rows, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((Hkv, rows, D), jnp.float32))
+        out_spec = (out_spec, stat(), stat(), wide())
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(S, pp),
+        grid=(Tp // tq, S, pp),
         in_specs=in_specs,
         out_specs=out_spec,
         scratch_shapes=[
-            pltpu.VMEM((Tp, H, D), jnp.float32),
-            pltpu.VMEM((Tp, H), jnp.float32),
-            pltpu.VMEM((Tp, H), jnp.float32),
+            pltpu.VMEM((Hkv, N, D), jnp.float32),
+            pltpu.VMEM((Hkv, N, 1), jnp.float32),
+            pltpu.VMEM((Hkv, N, 1), jnp.float32),
+            pltpu.VMEM((page * combined, D), jnp.float32),
         ],
     )
     res = pl.pallas_call(
@@ -271,10 +323,14 @@ def ragged_paged_attention_quant(
                           out_stats=return_stats),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="ragged_paged_attention_quant",
     )(kv_lens.astype(jnp.int32), pi, cu_q_lens.astype(jnp.int32),
       num_seqs.astype(jnp.int32), *operands)
     if return_stats:
         out, m, l, acc = res
-        return out[:T], (m[:T], l[:T], acc[:T])
-    return res[:T]
+        return from_rows(out).astype(q.dtype), (
+            from_rows(m)[..., 0], from_rows(l)[..., 0], from_rows(acc))
+    return from_rows(res).astype(q.dtype)

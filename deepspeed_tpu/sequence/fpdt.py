@@ -177,7 +177,7 @@ def fpdt_chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _host_handles(mesh: Optional[Mesh]):
     """(park, fetch) pair moving chunk stacks to pinned host memory and
     chunks back, in-graph and sharding-preserving
-    (``TransferToMemoryKind`` — the engine's ZeRO-Offload mechanism);
+    (``jax.memory.Space`` — the engine's ZeRO-Offload mechanism);
     identity when the backend has no host placement (CPU)."""
     devices = (mesh.devices.flat if mesh is not None else jax.devices())
     if list(devices)[0].platform == "cpu":

@@ -335,14 +335,16 @@ class EngineReplicaHandle:
 class ReplicaSet:
     """N data-parallel replicas built from ``factory(i) -> engine``.
 
-    On the CPU tier-1 path every engine shares the host platform
-    (thread-per-replica; start the process with
-    ``--xla_force_host_platform_device_count=N`` in ``XLA_FLAGS`` when
-    real device overlap is wanted); on TPU the factory places each
-    replica's params/cache on its own mesh slice and the engine's
-    existing GSPMD annotations shard WITHIN the slice — replica data
-    parallelism composes with in-replica tensor parallelism without
-    the router knowing either exists.
+    The factory decides where replica ``i`` lives, through the engine's
+    ``topology`` argument: a one-device ``MeshTopology(devices=[dev])``
+    commits that replica's params, KV pool and ticks to ``dev`` (each
+    replica ticks on its own thread, which a ``jax.default_device``
+    context around the factory would not reach); a multi-device slice
+    shards WITHIN it by the engine's GSPMD annotations — replica data
+    parallelism composes with in-replica tensor parallelism without the
+    router knowing either exists.  A factory that passes no topology
+    puts every replica on the default device (the CPU tier-1 path:
+    thread-per-replica on one host platform).
     """
 
     def __init__(self, factory: Callable[[int], Any], n: int,

@@ -1,61 +1,22 @@
-"""Version-compat shims for the jax API surface this framework uses.
+"""The two jax entry points the framework reaches through one name.
 
-The framework targets current jax (``jax.shard_map`` with ``check_vma``
-/ ``axis_names``), but must degrade gracefully on the 0.4.x line where
-shard_map still lives in ``jax.experimental`` and spells those knobs
-``check_rep`` / ``auto``.  Keeping the translation in ONE place means
-call sites write the modern spelling only.
+Call sites import ``shard_map`` and ``get_abstract_mesh`` from here; both
+are thin passes to the installed jax.
 """
 from __future__ import annotations
 
-try:                                        # jax >= 0.5
-    from jax import shard_map as _shard_map
-
-    _MODERN = True
-except ImportError:                         # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _MODERN = False
+import jax
 
 
 def get_abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()`` (the ambient mesh context)
-    on any supported jax; ``None`` when no ambient mesh is set or the
-    jax line predates the concept."""
-    import jax
-
-    try:
-        return jax.sharding.get_abstract_mesh()
-    except AttributeError:
-        try:
-            from jax._src.mesh import get_abstract_mesh as _gam
-
-            m = _gam()
-            return m if getattr(m, "axis_names", None) else None
-        except Exception:
-            return None
+    """``jax.sharding.get_abstract_mesh()`` — the ambient mesh context."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True,
               axis_names=None):
-    """``jax.shard_map`` with the modern keyword surface on any
-    supported jax.  ``axis_names`` (partial-manual) maps onto the old
-    API's complementary ``auto`` set."""
-    if _MODERN:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-    kw = {"check_rep": check_vma}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    mapped = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **kw)
-    if kw.get("auto"):
-        # the 0.4.x EAGER impl raises NotImplementedError for partial
-        # manual; the jit lowering supports it — stage the call
-        import jax
-
-        return jax.jit(mapped)
-    return mapped
+    """``jax.shard_map``; ``axis_names`` (partial-manual) is passed only
+    when given."""
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)

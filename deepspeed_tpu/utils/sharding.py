@@ -26,9 +26,6 @@ def maybe_constrain(x: jax.Array, spec) -> jax.Array:
 
 def memory_space(kind: str):
     """``jax.device_put`` target for crossing memory KINDS (host↔device
-    streaming), across jax versions: ``TransferToMemoryKind`` pre-0.9,
-    the ``jax.memory.Space`` enum from 0.9."""
-    if hasattr(jax.memory, "TransferToMemoryKind"):
-        return jax.memory.TransferToMemoryKind(kind)
+    streaming): the ``jax.memory.Space`` enum."""
     return (jax.memory.Space.Device if kind == "device"
             else jax.memory.Space.Host)

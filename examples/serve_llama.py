@@ -9,10 +9,13 @@ engine and prints per-request outputs as slots free up.
 Scale-out serving (``--replicas N``) puts N data-parallel engine
 replicas behind the SLO-aware router (``--router-policy`` picks the
 load-balancing policy) and prints the router stats after the drain.
-On a CPU host, set ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-before launching when you want the replica threads to overlap on
-separate host devices; without it they interleave on one device
-(bit-identical results, no wall-clock overlap).
+Replica ``i`` lives on device ``i`` (modulo the device count): its
+weights, KV pool and ticks are committed there through a one-device
+``topology``.  On a CPU host, set
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before launching
+when you want the replica threads to overlap on separate host devices;
+without it they interleave on one device (bit-identical results, no
+wall-clock overlap).
 
     python examples/serve_llama.py --replicas 2 --router-policy pressure
 
@@ -38,8 +41,11 @@ import argparse
 import jax
 import numpy as np
 
+from deepspeed_tpu.accelerator import require_accelerator
 from deepspeed_tpu.inference.v2 import RaggedInferenceEngineV2
 from deepspeed_tpu.models.llama import LlamaForCausalLM, get_config
+from deepspeed_tpu.parallel.topology import MeshTopology
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -112,7 +118,9 @@ def main() -> None:
                         "with the controller seeded from it")
     args = p.parse_args()
 
-    on_tpu = jax.devices()[0].platform != "cpu"
+    # a missing chip is an error; JAX_PLATFORMS=cpu asks for the CPU
+    require_accelerator()
+    enable_compile_cache()
     cfg = get_config(args.preset, scan_layers=True, remat=False,
                      use_flash_attention=False,
                      max_position_embeddings=max(
@@ -183,8 +191,14 @@ def main() -> None:
                    if args.control_profile else True)
 
     def build_engine(replica_idx: int = 0) -> RaggedInferenceEngineV2:
+        topology = None
+        if args.replicas > 1:
+            devices = jax.devices()
+            topology = MeshTopology(
+                devices=[devices[replica_idx % len(devices)]])
         return RaggedInferenceEngineV2(
-            model, params=params, max_seqs=args.max_seqs,
+            model, params=params, topology=topology,
+            max_seqs=args.max_seqs,
             max_seq_len=args.max_seq_len, prefill_chunk=64,
             pipeline=not args.no_pipeline,
             harvest_interval=args.harvest_interval,

@@ -16,7 +16,9 @@ import numpy as np
 
 import deepspeed_tpu
 import deepspeed_tpu.comm as dist
+from deepspeed_tpu.accelerator import require_accelerator
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMLoss, get_config
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -29,9 +31,11 @@ def main() -> None:
     p.add_argument("--save", default=None, help="checkpoint dir")
     args = p.parse_args()
 
+    # a missing chip is an error; JAX_PLATFORMS=cpu asks for the CPU
+    on_tpu = require_accelerator()
+    enable_compile_cache()
     topo = dist.initialize_mesh()            # all chips on the data axis
     dp = topo.zero_partition_count()
-    on_tpu = jax.devices()[0].platform != "cpu"
 
     if args.preset == "tiny":
         cfg = GPT2Config(vocab_size=256, n_positions=args.seq, n_embd=64,

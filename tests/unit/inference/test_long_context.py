@@ -291,18 +291,14 @@ class TestComposition:
         """The chunked scan is a bounded program set (embed / chunk /
         finish+-carry / head x two query shapes): a second LC request
         recompiles nothing."""
-        try:
-            from jax._src import test_util as jtu
-            counter = jtu.count_jit_compilation_cache_miss
-        except (ImportError, AttributeError):
-            pytest.skip("jax compilation-cache miss counter unavailable")
+        from deepspeed_tpu.telemetry.compiles import count_compiles
         eng = make(params, tiering=dict(LC_TIER), num_pages=8)
         p = _prompt(200)
         _serve(eng, [p], audit=False)
         assert eng.serving_stages()["kv_tiering"]["pageins"] > 0
-        with counter() as misses:
+        with count_compiles() as compiles:
             _serve(eng, [p], audit=False)
-        assert misses[0] == 0, (
-            f"{misses[0]} recompilations in LC steady state — the "
+        assert compiles.count == 0, (
+            f"{compiles.count} recompilations in LC steady state — the "
             "chunked-scan programs must be fixed-shape")
         eng.close()

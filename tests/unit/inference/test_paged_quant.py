@@ -232,3 +232,59 @@ def test_tiny_magnitude_rows_survive_roundtrip(fmt, tol):
     rel = np.abs(deq - want).max() / np.abs(want).max()
     assert rel < tol, f"{fmt}: tiny rows lost to quantization ({rel})"
     assert np.abs(deq).max() > 0, "rows collapsed to zero (old floor)"
+
+
+@pytest.mark.parametrize("fmt", ["int8", "fp8"])
+def test_pallas_kernel_carry_in_stats_out_matches_reference(fmt):
+    """The chunk-scan variant: an incoming (m, l, acc) carry folds exactly
+    as in the XLA reference, the returned raw stats normalize to the
+    output, and a neutral carry changes nothing."""
+    from deepspeed_tpu.inference.paged import neutral_carry
+
+    pages, scales = _pool(fmt)
+    q, kv_lens, pi, cu, ns = _meta()
+    T, H, D = q.shape
+    r = np.random.default_rng(1)
+    carry = (jnp.asarray(r.standard_normal((T, H)), jnp.float32),
+             jnp.asarray(r.random((T, H)) + 0.5, jnp.float32),
+             jnp.asarray(r.standard_normal((T, H, D)), jnp.float32))
+    ref = ref_paged_attention_quant(q, pages, scales, kv_lens, pi, cu, ns,
+                                    sm_scale=SM, sliding_window=7,
+                                    carry=carry)
+    ker, (m, l, acc) = ragged_paged_attention_quant(
+        q, pages, scales, kv_lens, pi, cu, ns, sm_scale=SM,
+        sliding_window=7, carry=carry, return_stats=True, interpret=True)
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=5e-6)
+    assert m.shape == l.shape == (T, H) and acc.shape == (T, H, D)
+    np.testing.assert_allclose(
+        np.asarray(acc / jnp.maximum(l, 1e-30)[..., None]),
+        np.asarray(ker), atol=5e-6)
+
+    plain = ragged_paged_attention_quant(q, pages, scales, kv_lens, pi, cu,
+                                         ns, sm_scale=SM, interpret=True)
+    seeded = ragged_paged_attention_quant(
+        q, pages, scales, kv_lens, pi, cu, ns, sm_scale=SM,
+        carry=neutral_carry(T, H, D), interpret=True)
+    np.testing.assert_array_equal(np.asarray(seeded), np.asarray(plain))
+
+
+def test_pallas_kernel_blocks_over_query_rows():
+    """More query rows than one VMEM block (128): sequences that span, end
+    in and start in different blocks, and padded tail rows."""
+    r = np.random.default_rng(2)
+    pages, scales = _pool("int8", P=40, page=8)
+    T = 300
+    q = jnp.asarray(r.standard_normal((T, 4, 128)), jnp.float32)
+    kv_lens = jnp.asarray([150, 160, 20], jnp.int32)
+    pi = np.full((3, 20), -1, np.int32)
+    pi[0, :19] = np.arange(1, 20)
+    pi[1, :20] = np.arange(20, 40)
+    pi[2, :3] = [5, 6, 7]
+    cu = jnp.asarray([0, 140, 290, 300], jnp.int32)
+    ns = jnp.asarray([3], jnp.int32)
+    ref = ref_paged_attention_quant(q, pages, scales, kv_lens,
+                                    jnp.asarray(pi), cu, ns, sm_scale=SM)
+    ker = ragged_paged_attention_quant(q, pages, scales, kv_lens,
+                                       jnp.asarray(pi), cu, ns, sm_scale=SM,
+                                       interpret=True)
+    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=5e-6)

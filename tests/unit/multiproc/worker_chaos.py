@@ -25,11 +25,7 @@ import time
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 1)
-except AttributeError:  # pre-0.5 jax: 1 CPU device is already the default;
-    # the CPU backend needs gloo for cross-process collectives
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+jax.config.update("jax_num_cpu_devices", 1)
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))))

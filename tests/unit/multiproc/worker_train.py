@@ -13,13 +13,7 @@ import sys
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:  # pre-0.5 jax (same fallback as tests/conftest.py)
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                               " --xla_force_host_platform_device_count=4")
-    # pre-0.5 CPU backend needs gloo for cross-process collectives
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+jax.config.update("jax_num_cpu_devices", 4)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))))))
 

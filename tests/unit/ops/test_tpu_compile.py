@@ -1,0 +1,160 @@
+"""Ask the chip's compiler, without the chip.
+
+Every Pallas kernel the main path reaches is compiled here for a
+*described* v5e device at published widths, so a kernel that only ever ran
+under the interpreter cannot reach a chip run before Mosaic has seen it.
+Nothing executes: a pass says the compiler accepts the kernel, not that
+its results are right (the interpret-mode parity tests say that).
+
+The topology is described inside a fixture of THIS file only — never at
+import, in a ``skipif`` or in a ``parametrize`` argument: one process at a
+time may load the TPU library, and under xdist every worker imports every
+test file.  The code under test asks ``jax.default_backend()`` and sees
+the CPU here, so each case compiles the kernel function itself.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.inference import paged
+from deepspeed_tpu.ops import fused_adam
+from deepspeed_tpu.ops.flash_attention import (_flash_bwd_pallas,
+                                               _flash_fwd_pallas)
+from deepspeed_tpu.ops.ragged_paged_quant import ragged_paged_attention_quant
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def chip_compile(one_chip):
+    """``chip_compile(fn, *specs)`` -> compiled text; each spec is a
+    ``(shape, dtype)`` placed on the described chip.  The persistent
+    compile cache is off around it: an entry written for a chip that is
+    not attached cannot be read back and only warns."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in specs]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+        return text
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+# (B, H, Hkv, S, D): GPT-2 medium's attention as chip_smoke trains it, and
+# a Llama-3-8B / Mistral-7B GQA layer at 2k
+FLASH_SHAPES = [
+    pytest.param((2, 16, 16, 1024, 64), id="d64-s1024"),
+    pytest.param((1, 32, 8, 2048, 128), id="d128-s2048-gqa"),
+]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES)
+def test_flash_forward(chip_compile, shape):
+    B, H, Hkv, S, D = shape
+    chip_compile(
+        functools.partial(_flash_fwd_pallas, sm_scale=D ** -0.5,
+                          causal=True, block_q=1024, block_k=1024),
+        ((B, H, S, D), jnp.bfloat16), ((B, Hkv, S, D), jnp.bfloat16),
+        ((B, Hkv, S, D), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES)
+def test_flash_backward(chip_compile, shape):
+    B, H, Hkv, S, D = shape
+    q, kv = ((B, H, S, D), jnp.bfloat16), ((B, Hkv, S, D), jnp.bfloat16)
+    chip_compile(
+        functools.partial(_flash_bwd_pallas, sm_scale=D ** -0.5,
+                          causal=True, block_q=1024, block_k=1024),
+        q, kv, kv, q, ((B, H, S), jnp.float32), q)
+
+
+def test_fused_adam_largest_leaf(chip_compile):
+    """GPT-2's tied embedding, the largest leaf the optimizer walks."""
+    def update(g, p, m, v):
+        kern = functools.partial(fused_adam._adam_kernel, b1=0.9, b2=0.999,
+                                 eps=1e-8, wd=0.01, adam_w=True)
+        return fused_adam._run_elementwise(
+            kern, jnp.ones((2,), jnp.float32),
+            [fused_adam._tile(x) for x in (g, p, m, v)], 3, False)
+
+    leaf = ((50257, 1024), jnp.float32)
+    chip_compile(update, leaf, leaf, leaf, leaf)
+
+
+# one fused serving tick at Mistral-7B attention widths, as chip_smoke
+# serves it: T = 8 decode rows + a 256-token prefill chunk, 64-token
+# pages, 33 pages a sequence, the published 4096 window
+T, H, HKV, D = 264, 32, 8, 128
+PAGE, SEQS, PAGES_PER_SEQ, WINDOW = 64, 8, 33, 4096
+POOL = 1 + SEQS * PAGES_PER_SEQ
+META = [((SEQS,), jnp.int32), ((SEQS, PAGES_PER_SEQ), jnp.int32),
+        ((SEQS + 1,), jnp.int32), ((1,), jnp.int32)]
+
+
+def test_upstream_ragged_paged_kernel_bf16_pages(chip_compile):
+    """With the block sizes ``inference/paged.py`` hands it: its own
+    tuned-table lookup asks the ATTACHED device for its generation, and
+    what it answers for this shape does not fit VMEM."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
+        kernel as rpa
+
+    chip_compile(
+        functools.partial(
+            rpa.ragged_paged_attention, sm_scale=D ** -0.5,
+            sliding_window=WINDOW,
+            num_kv_pages_per_block=paged._RPA_KV_BLOCK_TOKENS // PAGE,
+            num_queries_per_block=paged._RPA_QUERY_BLOCK),
+        ((T, H, D), jnp.bfloat16),
+        ((POOL, PAGE, 2 * HKV, D), jnp.bfloat16), *META)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.int8, jnp.float8_e4m3fn],
+                         ids=["int8", "fp8"])
+def test_quantized_pages_kernel(chip_compile, pool_dtype):
+    chip_compile(
+        functools.partial(ragged_paged_attention_quant, sm_scale=D ** -0.5,
+                          sliding_window=WINDOW),
+        ((T, H, D), jnp.bfloat16), ((POOL, PAGE, 2 * HKV, D), pool_dtype),
+        ((POOL, PAGE, 2 * HKV), jnp.float32), *META)
+
+
+def test_quantized_pages_kernel_chunk_scan_carry(chip_compile):
+    """The long-context scan's variant: carry in, stats out."""
+    def attend(q, pages, scales, kv_lens, pi, cu, ns, m, l, acc):
+        return ragged_paged_attention_quant(
+            q, pages, scales, kv_lens, pi, cu, ns, sm_scale=D ** -0.5,
+            sliding_window=WINDOW, carry=(m, l, acc), return_stats=True)
+
+    chip_compile(
+        attend, ((T, H, D), jnp.bfloat16),
+        ((POOL, PAGE, 2 * HKV, D), jnp.int8),
+        ((POOL, PAGE, 2 * HKV), jnp.float32), *META,
+        ((T, H), jnp.float32), ((T, H), jnp.float32),
+        ((T, H, D), jnp.float32))

@@ -11,16 +11,6 @@ import deepspeed_tpu.comm as dist
 from deepspeed_tpu.ops.flash_attention import mha_reference
 from deepspeed_tpu.sequence import (ring_attention, ulysses_attention,
                                     vocab_sequence_parallel_cross_entropy)
-from deepspeed_tpu.utils import compat
-
-# jaxlib 0.4.x's SPMD partitioner CHECK-fails (aborting the whole test
-# process, not just the test) on partial-manual shard_map over a mixed
-# dp x sp mesh — the exact shape every test here uses.  Modern jax
-# handles it; skip rather than take down the suite on the old line.
-pytestmark = pytest.mark.skipif(
-    not compat._MODERN,
-    reason="jaxlib 0.4.x SPMD partitioner aborts on partial-manual "
-           "shard_map over dp x sp meshes")
 
 
 def _qkv(rng, B=2, H=4, Hkv=None, S=64, D=16):

@@ -824,11 +824,7 @@ class TestRoutedBitParity:
 
 class TestNoRecompileAcrossReplicas:
     def test_two_live_replicas_compile_nothing_new(self, params):
-        try:
-            from jax._src import test_util as jtu
-            counter = jtu.count_jit_compilation_cache_miss
-        except (ImportError, AttributeError):
-            pytest.skip("jax compilation-cache miss counter unavailable")
+        from deepspeed_tpu.telemetry.compiles import count_compiles
         prompts = _prompts((5, 9, 13, 7, 11, 6))
         rs = ReplicaSet(lambda i: _engine(params), 2)
         try:
@@ -836,14 +832,63 @@ class TestNoRecompileAcrossReplicas:
             for p in prompts:                    # warm both replicas
                 router.submit(p, max_new_tokens=8)
             router.drain()
-            with counter() as misses:
+            with count_compiles() as compiles:
                 for p in prompts:
                     router.submit(p, max_new_tokens=8)
                 outs = router.drain()
             assert len(outs) == len(prompts)
-            assert misses[0] == 0, (
-                f"{misses[0]} recompilations while serving through 2 "
+            assert compiles.count == 0, (
+                f"{compiles.count} recompilations while serving through 2 "
                 "live replicas — routed steady state must reuse both "
                 "replicas' warm executables")
         finally:
             rs.close()
+
+
+def _devices_of(tree):
+    return {d for leaf in jax.tree_util.tree_leaves(tree)
+            for d in leaf.devices()}
+
+
+class TestReplicaPlacement:
+    """A one-device ``topology`` is a replica slice: params, KV pool and
+    what the ticks produce live on THAT device — on the worker thread
+    too, where a ``jax.default_device`` context would not reach."""
+
+    def test_each_replica_lives_and_ticks_on_its_own_device(
+            self, params, devices):
+        from deepspeed_tpu.parallel.topology import MeshTopology
+
+        def factory(i):
+            return RaggedInferenceEngineV2(
+                LlamaForCausalLM(CFG), params=params, pipeline=True,
+                rng=jax.random.PRNGKey(11), max_seqs=3, max_seq_len=128,
+                prefill_chunk=8, decode_block_size=4, harvest_interval=3,
+                topology=MeshTopology(devices=[devices[i + 1]]))
+
+        prompts = _prompts((5, 9, 13, 7, 11, 6, 8, 10))
+        ref = _single_engine_reference(params, prompts, max_new=12)
+        rs = ReplicaSet(factory, 3)
+        try:
+            router = Router(rs, policy="rr")
+            rids = {router.submit(p, max_new_tokens=12): i
+                    for i, p in enumerate(prompts)}
+            outs = router.drain()
+            for rid, toks in outs.items():
+                np.testing.assert_array_equal(toks, ref[rids[rid]])
+            for i, h in enumerate(rs):
+                want = {devices[i + 1]}
+                assert _devices_of(h.engine.params) == want
+                # the pool AFTER serving: every tick's donated update
+                # stayed on the replica's device
+                assert _devices_of(h.engine.cache) == want
+                assert router.stats()[f"routed_{h.name}"] > 0
+        finally:
+            rs.close()
+
+    def test_no_topology_keeps_default_placement(self, params, devices):
+        eng = _engine(params)
+        try:
+            assert _devices_of(eng.cache) == {devices[0]}
+        finally:
+            eng.close()

@@ -18,18 +18,10 @@ from deepspeed_tpu.runtime.onebit import (scale_by_onebit_adam,
                                           scale_by_onebit_lamb,
                                           scale_by_zero_one_adam)
 
-try:
-    from jax import shard_map as _sm
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-except ImportError:  # older jax spelling
-    from jax.experimental.shard_map import shard_map as _sme
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sme(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @pytest.fixture(scope="module")
