@@ -1,0 +1,165 @@
+"""A lint of ``BENCHMARK.json`` against the rules a reader can check
+without the chip, and the proof that a configuration, a mix and a
+per-layer metric are added as files of their own."""
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+import textwrap
+
+from benchmark import harness as H
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"}
+WIDTH = re.compile(r"(_dim|_rank)$|hidden_size|intermediate_size|head_dim|"
+                   r"num_experts_per_tok")
+
+
+def test_manifest_shape_names_and_units():
+    m = H.manifest()
+    assert set(m) == KEYS
+    assert 1 <= m["run_seconds"] <= 51 and isinstance(m["run_seconds"], int)
+    assert os.path.getsize(os.path.join(H.ROOT, "BENCHMARK.json")) < 65536
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        names = [x["name"] for x in m[group]]
+        assert len(names) == len(set(names)), group
+        assert all(NAME.match(n) for n in names), group
+    for met in m["end_to_end"] + m["per_layer"]:
+        assert UNIT.match(met["unit"]), met
+        assert met["better"] in ("lower", "higher")
+        assert met["source"] in ("device_trace", "program_span",
+                                 "program_counter", "host_clock")
+    for met in m["end_to_end"]:
+        assert set(met) <= {"name", "unit", "better", "bound", "source",
+                            "workloads"}
+        assert met["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= met["bound"] <= 0.1
+    assert any(e["name"] == "setup_s" and "workloads" not in e
+               for e in m["end_to_end"])
+    for met in m["per_layer"]:
+        assert set(met) <= {"name", "unit", "better", "source", "layer",
+                            "moves", "workloads"}
+        assert 1 <= len(met["layer"]) <= 200 and "\n" not in met["layer"]
+        if "roofline" in met["name"] or "mfu" in met["name"]:
+            assert met["unit"] == "%"
+
+
+def test_cells_files_and_chips():
+    m = H.manifest()
+    used = set()
+    for w in m["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["chips"] in (1, 4) and 1 <= len(w["why"]) <= 200
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"])
+        cell = H.Cell(w["name"])            # both files exist and parse
+        assert cell.config["chips"] == w["chips"]
+        assert cell.config["kind"] in ("serve", "train")
+        used.add(w["config"])
+    assert used == {c["name"] for c in m["configs"]}
+    pairs = [(w["config"], w["traffic"]) for w in m["workloads"]]
+    assert len(pairs) == len(set(pairs))
+    four = sum(w["chips"] == 4 for w in m["workloads"])
+    assert four <= max(1, len(m["workloads"]) // 4)
+    files = [c["file"] for c in m["configs"]]
+    assert len(files) == len(set(files))
+    for c in m["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert c["file"].startswith(tuple(p + "/" for p in m["paths"]))
+        body = H.load_json(os.path.join(H.ROOT, c["file"]))
+        assert body["source"] == c["source"]
+        assert body["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
+        assert not any(WIDTH.search(k) for k in c["reduced"])
+        for key in ("source", "reduced", "assumed", "deployment", "chips"):
+            assert key in body, (c["name"], key)
+
+
+def test_every_metric_is_reported_where_its_end_to_end_metric_is():
+    m = H.manifest()
+    e2e = {e["name"]: e for e in m["end_to_end"]}
+    for w in m["workloads"]:
+        cell = H.Cell(w["name"])
+        mine = {e["name"] for e in cell.metrics("end_to_end")}
+        assert "setup_s" in mine and len(mine) >= 2
+        layer = cell.metrics("per_layer")
+        assert layer
+        for met in layer:
+            assert met["moves"] in e2e and met["moves"] in mine, met
+            family = met["name"].split(".", 1)[0]
+            assert os.path.exists(os.path.join(
+                H.HERE, "layer_metrics", family + ".py")), family
+        gen = cell.traffic["generator"]
+        assert os.path.exists(os.path.join(H.HERE, "generators",
+                                           gen + ".py"))
+
+
+def test_nothing_in_the_benchmark_imports_bench_or_private_engine_names():
+    bad = re.compile(r"^\s*(import|from)\s+(bench|chip_smoke)\b|"
+                     r"engine\._[a-z]|eng\._[a-z]|deepspeed_tpu\S*\s+import\s+_[a-z]", re.M)
+    for base, _, files in os.walk(H.HERE):
+        if os.sep + "out" in base or "__pycache__" in base:
+            continue
+        for f in files:
+            if f.endswith(".py") and f != "test_manifest.py":
+                text = open(os.path.join(base, f)).read()
+                assert not bad.search(text), os.path.join(base, f)
+
+
+def test_a_configuration_a_mix_and_a_metric_are_added_as_files(tmp_path):
+    """A throw-away copy of the benchmark gains a configuration, a mix and
+    a per-layer metric -- three new files and three manifest entries, no
+    existing file edited -- and the tiny CPU driver runs the new cell."""
+    root = tmp_path / "copy"
+    shutil.copytree(H.HERE, root / "benchmark",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    m = H.manifest()
+    cfg = H.load_json(os.path.join(H.HERE, "configs",
+                                   "mistral-7b-serve.json"))
+    cfg["deployment"] = "throw-away"
+    (root / "benchmark/configs/extra-serve.json").write_text(json.dumps(cfg))
+    mix = H.load_json(os.path.join(H.HERE, "traffic", "longdoc-closed.json"))
+    mix["clients"] = 2
+    (root / "benchmark/traffic/extra-closed.json").write_text(json.dumps(mix))
+    (root / "benchmark/layer_metrics/requests_done.py").write_text(
+        "def read(ctx):\n    return float(len(ctx['sample']))\n")
+    m["configs"].append({"name": "extra-serve", "source": cfg["source"],
+                         "file": "benchmark/configs/extra-serve.json",
+                         "reduced": cfg["reduced"], "why": "lint"})
+    m["workloads"].append({"name": "extra", "config": "extra-serve",
+                           "traffic": "extra-closed", "chips": 1,
+                           "why": "lint"})
+    for e in m["end_to_end"]:
+        if e["name"] == "total_tokens_per_s":
+            e["workloads"].append("extra")
+    m["per_layer"].append({"name": "requests_done.extra", "unit": "requests",
+                           "better": "higher", "source": "program_counter",
+                           "layer": "load generator", "workloads": ["extra"],
+                           "moves": "total_tokens_per_s"})
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+    script = textwrap.dedent("""
+        import json, sys, time
+        T = time.monotonic()
+        from benchmark import harness as H
+        from benchmark.drivers import serve
+        from benchmark.tests import tiny
+        assert H.ROOT == sys.argv[1], (H.ROOT, sys.argv[1])
+        cell = tiny.serve_cell("extra")
+        ctx = {"cell": cell, "sample": [1, 2, 3]}
+        got = H.layer_metrics(cell, ctx)
+        assert got == {"requests_done.extra":
+                       {"value": 3.0, "unit": "requests"}}, got
+        line = json.loads(serve.run(cell, 5, 2.0, False, T, allow_cpu=True))
+        assert set(line["metrics"]) == {"total_tokens_per_s", "setup_s"}
+        assert line["correct"] and line["attempted"] > 0, line
+        print("ok")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(root), H.ROOT]))
+    out = subprocess.run([sys.executable, "-c", script, str(root)], env=env,
+                         cwd=str(root), capture_output=True, text=True,
+                         timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().endswith("ok")
