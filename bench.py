@@ -1384,7 +1384,7 @@ def bench_ragged(args) -> None:
 
     # 2x-overload Poisson leg: arrivals at twice the measured capacity,
     # tight queue caps + burn-rate shedding, tracer on so the wall not
-    # covered by engine stages lands in the router_pump span
+    # covered by engine stages lands in the router.pump span
     so_arrivals = np.cumsum(so_rng.exponential(
         1.0 / (2.0 * cap_rps), size=so_n))
     slo_thr = max(3.0 * (_pctl(so_e2e, 99) or 0.0), 50.0)
@@ -1398,7 +1398,7 @@ def bench_ragged(args) -> None:
     router_span_s = sum(
         ev.get("dur", 0.0) for ev in _telemetry.trace.snapshot()
         if ev.get("ph") == "X" and ev.get("name") in
-        ("router_pump", "router_dispatch")) / 1e6
+        ("router.pump", "router.dispatch")) / 1e6
     _telemetry.trace.configure(enabled=False)
     _telemetry.trace.clear()
     goodput_rps = len(ov_outs) / ov_wall

@@ -1,0 +1,9 @@
+"""Device idle time, as a percentage of the traced extent, while the replica's
+worker thread was in no ``replica.op`` at all: waiting for the router's next
+op, or for a request.  One of five shares that partition the idle time
+(``host_spans.idle_by_class``): together they are ``device_idle_pct``."""
+from benchmark.layer_metrics import host_spans
+
+
+def read(ctx):
+    return host_spans.idle_pct(ctx, "between_ops")

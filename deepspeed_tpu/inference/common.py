@@ -1,15 +1,36 @@
 """Conventions shared by the v1 and v2 inference engines."""
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
 from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
 
 from deepspeed_tpu.telemetry import trace
+from deepspeed_tpu.telemetry.tracer import Span
 from deepspeed_tpu.telemetry.metrics import metrics as _metrics
+
+
+class _StageSpan(Span):
+    """A serving-loop stage bracket: the tracer's span (profiler + ring)
+    whose duration also lands in ``HostStageStats.seconds`` and the
+    stage histogram."""
+
+    __slots__ = ("_stats", "_stage")
+
+    def __init__(self, stats: "HostStageStats", stage: str,
+                 attrs: Optional[Dict[str, Any]]):
+        super().__init__(trace, stats.SPAN_NAMES[stage], "serving", attrs)
+        self._stats = stats
+        self._stage = stage
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        st = self._stats
+        st.seconds[self._stage] += self.dur
+        if _metrics.enabled:
+            st._stage_hist(self._stage).observe(self.dur)
+        return False
 
 
 class HostStageStats:
@@ -21,19 +42,47 @@ class HostStageStats:
 
     - ``plan``:     host-side numpy scheduling (admission, SplitFuse
                     packing, page growth, run-ahead projection)
-    - ``upload``:   host->device metadata transfers (``jnp.asarray``)
+    - ``upload``:   host->device metadata transfers
     - ``dispatch``: handing the jitted program to the async runtime
     - ``device``:   host BLOCKED waiting on device results (the only
                     sync points: harvests and run-ahead depth waits)
     - ``harvest``:  folding fetched tokens back into request state
 
+    One bracket, three sinks: ``stage(name, **counts)`` opens ONE span
+    that (a) is a ``jax.profiler.TraceAnnotation`` named
+    ``engine.<name>`` (``device`` is ``engine.fetch``) with the counts
+    as its stats — serving-loop spans appear in any ``jax.profiler``
+    trace, on the profiler's own clock, with no knob; (b) lands in the
+    tracer's ring under ``DSTPU_TRACE=1``; (c) adds its duration to
+    ``seconds[name]`` and the stage histogram — read on the tracer's
+    clock, ``time.perf_counter`` unless ``telemetry.configure(clock=)``
+    replaced it, so ring and stage seconds agree.  Besides the stages
+    above the v2 engine brackets ``rng_split`` (the eager key split
+    before a decode block), ``sample`` (the fused tick's eager gather +
+    ``sample_logits``) and ``reap`` (``_reap`` and the post-sample
+    handoff sweep), and opens ``engine.step`` around a whole ``step()``;
+    the replica worker opens ``replica.op`` around each op and the
+    router ``router.pump`` / ``router.fold`` / ``router.dispatch`` and,
+    on the intake path, ``router.submit`` with ``router.prefix_hash``
+    inside it.  ``benchmark/idle_report.py`` reads all of them.
+
     ``serving_stages()`` reports per-dispatch milliseconds plus
-    ``host_bound_fraction`` = host-stage time / (host + device-wait)
-    — ~1.0 means the loop never waits on the device (host-bound),
-    ~0.0 means the host keeps the device saturated (device-bound).
+    ``host_bound_fraction`` = host-stage time / (host + device-wait).
+    That is the share of the HOST's bracketed time not spent blocked on
+    the device — NOT the chip's idle share: with a two-deep decode
+    pipeline the host can be blocked most of the time while the chip
+    still idles between programs.  The chip's idle share comes from a
+    profiler trace (``benchmark/layer_metrics/device_idle_pct.py``),
+    and the spans above say which host stage each idle gap fell into.
     Counters make the pipelining contract testable: ``meta_uploads``
     and ``blocking_gets`` must stay flat across steady-state decode
-    blocks when the pipeline is on.
+    blocks when the pipeline is on.  ``prefill_tokens`` /
+    ``prefill_key_tokens`` (prompt tokens dispatched in fused ticks,
+    and the keys they attend to) and ``decode_key_token_steps`` (context
+    tokens read, summed over decode token steps) are the same counts
+    the ``engine.dispatch`` spans carry; ``sample_s`` / ``rng_split_s``
+    / ``reap_s`` are the seconds of the three brackets outside
+    ``STAGES``.
 
     Speculative decoding adds two host stages — ``draft`` (draft-model
     KV catch-up prefill + host-side draft planning) and ``verify`` (the
@@ -55,6 +104,12 @@ class HostStageStats:
 
     STAGES = ("plan", "upload", "dispatch", "device", "harvest", "draft",
               "verify", "spill", "restore", "prefix")
+    # bracketed and reported in seconds, but no key of the per-dispatch
+    # ``<stage>_ms`` table nor part of ``host_s`` (``sample`` and
+    # ``reap`` overlap ``harvest`` where they nest in it)
+    EXTRA = ("rng_split", "sample", "reap")
+    SPAN_NAMES = {s: "engine." + ("fetch" if s == "device" else s)
+                  for s in STAGES + EXTRA}
 
     def __init__(self, replica: str = ""):
         # scale-out serving runs several engines in one process; the
@@ -75,7 +130,8 @@ class HostStageStats:
         self._hist_fam = None
 
     def reset(self) -> None:
-        self.seconds: Dict[str, float] = {s: 0.0 for s in self.STAGES}
+        self.seconds: Dict[str, float] = {
+            s: 0.0 for s in self.STAGES + self.EXTRA}
         self.ticks = 0            # model ticks (a K-block counts K)
         self.dispatches = 0       # compiled-program launches
         self.meta_uploads = 0     # host->device metadata arrays sent
@@ -90,19 +146,14 @@ class HostStageStats:
         self.prefix_hit_pages = 0   # cached pages attached
         self.prefix_hit_tokens = 0  # prefill tokens skipped via the cache
         self.prefix_cow_copies = 0  # copy-on-write page copies
+        self.prefill_tokens = 0     # prompt tokens in fused ticks
+        self.prefill_key_tokens = 0   # keys those tokens attend to
+        self.decode_key_token_steps = 0   # context tokens x token steps
 
-    @contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self.seconds[name] += dt
-            if trace.enabled:
-                trace.add_complete(name, t0, dt, cat="serving")
-            if _metrics.enabled:
-                self._stage_hist(name).observe(dt)
+    def stage(self, name: str, **counts) -> _StageSpan:
+        """Bracket one stage of the hot loop; ``counts`` become the
+        span's stats."""
+        return _StageSpan(self, name, counts or None)
 
     def _stage_hist(self, name: str):
         """Cached registry child for this stage (lookup once, then a
@@ -134,7 +185,12 @@ class HostStageStats:
         out.update(ticks=self.ticks, dispatches=self.dispatches,
                    meta_uploads=self.meta_uploads,
                    blocking_gets=self.blocking_gets,
-                   harvests=self.harvests)
+                   harvests=self.harvests,
+                   prefill_tokens=self.prefill_tokens,
+                   prefill_key_tokens=self.prefill_key_tokens,
+                   decode_key_token_steps=self.decode_key_token_steps,
+                   **{f"{s}_s": round(self.seconds[s], 6)
+                      for s in self.EXTRA})
         if self.spec_dispatches:
             sd = self.spec_dispatches
             out["speculation"] = {

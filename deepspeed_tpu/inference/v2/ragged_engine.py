@@ -913,7 +913,7 @@ class RaggedInferenceEngineV2:
             # windowed partial-residency policy (LongContextDriver)
             req.lc = True
         self.waiting.append(req)
-        self.request_latency.on_submit(req.uid)
+        self.request_latency.on_submit(req.uid, int(prompt.size))
         if trace.enabled:
             trace.event("request_submit", cat="request", uid=req.uid,
                         prompt_len=int(prompt.size), max_new=max_new)
@@ -1215,7 +1215,7 @@ class RaggedInferenceEngineV2:
                          np.asarray(req.generated, np.int32)])
                     req.prefill_done = 0
             self.waiting.append(req)
-            self.request_latency.on_submit(req.uid)
+            self.request_latency.on_submit(req.uid, int(req.prompt.size))
             if trace.enabled:
                 trace.event("request_import", cat="request", uid=req.uid,
                             donor_uid=int(s.get("uid", -1)),
@@ -1458,9 +1458,21 @@ class RaggedInferenceEngineV2:
         """Host -> device metadata transfer.  The pipelined decode loop
         must NOT call this in steady state (metadata is device-resident;
         ``host_stats.meta_uploads`` asserts it in tests)."""
+        return self._upload_all((x,))[0]
+
+    def _upload_all(self, arrays) -> List[Any]:
+        """One ``upload`` bracket around the transfers of one dispatch's
+        metadata (each array still its own ``device_put``)."""
         with self.host_stats.stage("upload"):
-            self.host_stats.meta_uploads += 1
-            return jax.device_put(x, self._device)
+            self.host_stats.meta_uploads += len(arrays)
+            return [jax.device_put(x, self._device) for x in arrays]
+
+    def _split_rng(self):
+        """The eager per-block key split — a tiny program of its own,
+        so it gets its own bracket."""
+        with self.host_stats.stage("rng_split"):
+            self.rng, sub = jax.random.split(self.rng)
+        return sub
 
     def _fetch(self, tree):
         """Blocking device -> host fetch — the serving loop's only sync
@@ -1999,10 +2011,10 @@ class RaggedInferenceEngineV2:
             sampled = bool(do_sample.any())
             hist = self._hist_array(reqs)
         self._draft_catchup(reqs)
-        self.rng, sub = jax.random.split(self.rng)
-        args = [self._upload(a) for a in
-                (hist, last_tok, pos, active, remaining, self.page_table,
-                 eos_ids, do_sample, temperature, top_k, top_p)]
+        sub = self._split_rng()
+        args = self._upload_all(
+            (hist, last_tok, pos, active, remaining, self.page_table,
+             eos_ids, do_sample, temperature, top_k, top_p))
         if trace.enabled:
             trace.event("decode_block", cat="request",
                         uids=[r.uid for r in reqs],
@@ -2041,16 +2053,15 @@ class RaggedInferenceEngineV2:
             (last_tok, pos, active, remaining, eos_ids, do_sample,
              temperature, top_k, top_p, seeds) = self._block_arrays(reqs)
             sampled = bool(do_sample.any())
-        self.rng, sub = jax.random.split(self.rng)
-        args = [self._upload(a) for a in
-                (last_tok, pos, active, remaining, self.page_table,
-                 eos_ids, do_sample, temperature, top_k, top_p, seeds)]
+        sub = self._split_rng()
+        args = self._upload_all(
+            (last_tok, pos, active, remaining, self.page_table,
+             eos_ids, do_sample, temperature, top_k, top_p, seeds))
         if trace.enabled:
             trace.event("decode_block", cat="request",
                         uids=[r.uid for r in reqs],
                         ticks=self.decode_block_size)
-        with st.stage("dispatch"):
-            st.dispatches += 1
+        with self._decode_dispatch(reqs, [r.length for r in reqs]):
             (cache, new_last, _pos, _active, _remaining, toks,
              mask) = self._decode_block_fn(sampled)(
                 self.params, self.cache, *args, sub)
@@ -2069,6 +2080,20 @@ class RaggedInferenceEngineV2:
                 self._maybe_finish(r)
             self._reap()
         return produced
+
+    def _decode_dispatch(self, reqs: List[Request], contexts):
+        """The ``dispatch`` bracket of one plain decode block, with the
+        counts its roofline needs: ``rows`` live sequences run ``ticks``
+        token steps, the first reading ``key_tokens`` context tokens in
+        all (``contexts``: each row's length as the host projects it at
+        dispatch) and every later one a token more per row."""
+        st = self.host_stats
+        K = self.decode_block_size
+        rows, keys = len(reqs), int(sum(contexts))
+        st.dispatches += 1
+        st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
+        return st.stage("dispatch", program="decode_block", rows=rows,
+                        ticks=K, key_tokens=keys)
 
     # -- the pipelined decode loop (serving host-path tentpole) ----------
 
@@ -2173,19 +2198,14 @@ class RaggedInferenceEngineV2:
                 plen[r.slot] = r.length
                 rem[r.slot] = remaining[r.slot]
                 has_eos[r.slot] = r.eos_token_id is not None
+        names = ("last_tok", "pos", "active", "remaining", "page_table",
+                 "eos_ids", "do_sample", "temperature", "top_k", "top_p")
         self._dev = {
             "reqs": list(reqs),
             "sampled": bool(do_sample.any()),
-            "last_tok": self._upload(last_tok),
-            "pos": self._upload(pos),
-            "active": self._upload(active),
-            "remaining": self._upload(remaining),
-            "page_table": self._upload(self.page_table),
-            "eos_ids": self._upload(eos_ids),
-            "do_sample": self._upload(do_sample),
-            "temperature": self._upload(temperature),
-            "top_k": self._upload(top_k),
-            "top_p": self._upload(top_p),
+            **dict(zip(names, self._upload_all(
+                (last_tok, pos, active, remaining, self.page_table,
+                 eos_ids, do_sample, temperature, top_k, top_p)))),
             "plen": plen, "rem": rem, "has_eos": has_eos,
             "spec": spec,
             # un-harvested decode blocks ride the shared bounded-window
@@ -2227,9 +2247,9 @@ class RaggedInferenceEngineV2:
             # reach this point un-harvested, see _pipeline_start); for a
             # speculative block the projection is the FAST bound, so
             # growth covers the worst-case k+1-wide write span
-            slots_active = [r.slot for r in dv["reqs"]
-                            if dv["rem"][r.slot] > 0 and
-                            dv["plen"][r.slot] < self.max_seq_len]
+            live = [r for r in dv["reqs"] if dv["rem"][r.slot] > 0 and
+                    dv["plen"][r.slot] < self.max_seq_len]
+            slots_active = [r.slot for r in live]
             grow_ok = bool(slots_active)
             table_dirty = False
             for s in slots_active:
@@ -2251,7 +2271,7 @@ class RaggedInferenceEngineV2:
             return self._pipeline_harvest(teardown=True)
         if table_dirty:
             dv["page_table"] = self._upload(self.page_table)
-        self.rng, sub = jax.random.split(self.rng)
+        sub = self._split_rng()
         if trace.enabled:
             trace.event("decode_block", cat="request",
                         uids=[r.uid for r in dv["reqs"]],
@@ -2272,8 +2292,8 @@ class RaggedInferenceEngineV2:
                     dv["temperature"], dv["top_k"], dv["top_p"], sub)
             block = (toks, mask, prop, accd)
         else:
-            with st.stage("dispatch"):
-                st.dispatches += 1
+            with self._decode_dispatch(
+                    live, [dv["plen"][r.slot] for r in live]):
                 (self.cache, dv["last_tok"], dv["pos"], dv["active"],
                  dv["remaining"], toks, mask) = self._decode_block_fn(
                     dv["sampled"])(
@@ -2394,6 +2414,10 @@ class RaggedInferenceEngineV2:
         dispatches when ``pipeline=True``; any prefilling sequence
         falls back to the fused SplitFuse tick."""
         self._sched_seq += 1
+        with trace.bracket("engine.step", cat="serving"):
+            return self._step()
+
+    def _step(self) -> int:
         if self._controller is not None:
             self._control_steps += 1
             if self._control_steps >= self._control_cfg.interval:
@@ -2491,21 +2515,23 @@ class RaggedInferenceEngineV2:
                     self._evict(max(stalled, key=lambda r: r.uid))
             return lc_produced
         (token_ids, positions, kv_lens, page_indices, cu_q_lens, num_seqs,
-         new_kv_dest, sample_rows, samplers) = plan
-        args = [self._upload(a) for a in
-                (token_ids[None], positions[None], kv_lens, page_indices,
-                 cu_q_lens, num_seqs, new_kv_dest, sample_rows)]
-        with st.stage("dispatch"):
+         new_kv_dest, sample_rows, samplers, counts) = plan
+        args = self._upload_all(
+            (token_ids[None], positions[None], kv_lens, page_indices,
+             cu_q_lens, num_seqs, new_kv_dest, sample_rows))
+        st.prefill_tokens += counts["prefill_tokens"]
+        st.prefill_key_tokens += counts["prefill_key_tokens"]
+        st.decode_key_token_steps += counts["decode_key_tokens"]
+        with st.stage("dispatch", program="fused", **counts):
             st.dispatches += 1
             sel_logits, self.cache = self._fused_step_fn()(
                 self.params, self.cache, *args)
         st.ticks += 1
         produced = self._sample(sel_logits, samplers)
-        self._reap()
         # a handoff prefill that just sampled its first token parks NOW
         # — same step — so the router's next export pulls it without an
         # extra tick of decode-side latency
-        self._handoff_sweep()
+        self._reap(handoff_sweep=True)
         return produced + lc_produced
 
     def _admit(self) -> None:
@@ -3059,6 +3085,10 @@ class RaggedInferenceEngineV2:
         cu_q_lens = np.zeros((self.max_seqs + 1,), np.int32)
         sample_rows = np.zeros((self.max_seqs,), np.int32)
         samplers: List[Tuple[Request, int, bool]] = []  # (req, seq_j, sample?)
+        # what the tick asks of the device, for the dispatch span and
+        # the counters: a token at position p attends to p + 1 keys
+        counts = dict(prefill_tokens=0, prefill_key_tokens=0,
+                      decode_key_tokens=0)
 
         budget = self.T - len(decode_rs)
         takes: Dict[int, int] = {}
@@ -3099,6 +3129,7 @@ class RaggedInferenceEngineV2:
                 new_kv_dest[t] = self._flat_dest(r.slot, p)
                 page_indices[j] = self.page_table[r.slot]
                 kv_lens[j] = p + 1
+                counts["decode_key_tokens"] += p + 1
                 cu_q_lens[j + 1] = cu_q_lens[j] + 1
                 sample_rows[j] = t
                 samplers.append((r, j, True))
@@ -3117,6 +3148,9 @@ class RaggedInferenceEngineV2:
                 new_kv_dest[t:t + take] = (pg * self.page_size +
                                            pos % self.page_size)
                 r.prefill_done += take
+                counts["prefill_tokens"] += int(take)
+                counts["prefill_key_tokens"] += int(
+                    take * lo + take * (take + 1) // 2)
                 if self._pfx is not None:
                     # publish every freshly completed full page to the
                     # prefix index (chain-hash it onto the request's
@@ -3151,7 +3185,7 @@ class RaggedInferenceEngineV2:
             return None
         return (token_ids, positions, kv_lens, page_indices, cu_q_lens,
                 np.asarray([j], np.int32), new_kv_dest, sample_rows,
-                samplers)
+                samplers, counts)
 
     def _sample(self, sel_logits, samplers) -> int:
         """One host sync per tick; one sampling call per distinct config."""
@@ -3162,25 +3196,29 @@ class RaggedInferenceEngineV2:
                 continue
             key = (r.do_sample, r.temperature, r.top_k, r.top_p)
             groups.setdefault(key, []).append((r, seq_j))
+        st = self.host_stats
         for (do_sample, temp, top_k, top_p), pairs in groups.items():
-            rows = np.asarray([j for _, j in pairs])
-            sub = None
-            if do_sample:
-                # (uid, position)-keyed streams: the draw for token n of
-                # request u is the same whatever else is co-batched, so
-                # seeded sampling is reproducible under prefix-cache
-                # admission reordering (same convention as the decode
-                # block's per-tick keys)
-                sub = position_keys(
-                    self._sample_base,
-                    jnp.asarray([r.uid for r, _ in pairs], jnp.int32),
-                    jnp.asarray([r.length - 1 for r, _ in pairs],
-                                jnp.int32))
-            dev_toks = sample_logits(
-                sel_logits[rows], sub, do_sample=do_sample,
-                temperature=temp, top_k=top_k, top_p=top_p)
+            # eager device work (a gather and a handful of tiny sampling
+            # programs per distinct row count): its own bracket
+            with st.stage("sample"):
+                rows = np.asarray([j for _, j in pairs])
+                sub = None
+                if do_sample:
+                    # (uid, position)-keyed streams: the draw for token n
+                    # of request u is the same whatever else is
+                    # co-batched, so seeded sampling is reproducible
+                    # under prefix-cache admission reordering (same
+                    # convention as the decode block's per-tick keys)
+                    sub = position_keys(
+                        self._sample_base,
+                        jnp.asarray([r.uid for r, _ in pairs], jnp.int32),
+                        jnp.asarray([r.length - 1 for r, _ in pairs],
+                                    jnp.int32))
+                dev_toks = sample_logits(
+                    sel_logits[rows], sub, do_sample=do_sample,
+                    temperature=temp, top_k=top_k, top_p=top_p)
             toks = np.asarray(self._fetch(dev_toks))
-            with self.host_stats.stage("harvest"):
+            with st.stage("harvest"):
                 for (r, _), tok in zip(pairs, toks):
                     r.generated.append(int(tok))
                     self._last_tokens[r.slot] = int(tok)
@@ -3197,7 +3235,15 @@ class RaggedInferenceEngineV2:
                 req.length >= self.max_seq_len):
             req.done = True
 
-    def _reap(self) -> None:
+    def _reap(self, handoff_sweep: bool = False) -> None:
+        """Free the slots of finished sequences (one ``reap`` bracket,
+        with the fused tick's post-sample handoff sweep inside it)."""
+        with self.host_stats.stage("reap"):
+            self._reap_finished()
+            if handoff_sweep:
+                self._handoff_sweep()
+
+    def _reap_finished(self) -> None:
         for i, r in enumerate(self.slots):
             if r is not None and r.done:
                 if r.lc and self.tiering is not None:

@@ -179,7 +179,7 @@ class EngineReplicaHandle:
             eng.request_latency.note_router_accept(uid, accept_t)
             return uid
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "put")
 
     def step_async(self, on_done: Callable[[Any], Any]) -> None:
         """One engine iteration; the payload handed to ``on_done`` is
@@ -210,7 +210,7 @@ class EngineReplicaHandle:
             outs = eng.get_outputs()       # collected uid drops its cursor
             return outs, self._pool_snapshot(eng), deltas
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "step")
 
     def cancel_async(self, uid: int,
                      on_done: Optional[Callable[[Any], Any]] = None
@@ -219,7 +219,7 @@ class EngineReplicaHandle:
         stage it is in (queued / spilled / mid-decode / LC-parked);
         ``on_done(stage_or_None)`` at join time."""
         eng = self.engine
-        self._submit(lambda: eng.cancel(uid), on_done)
+        self._submit(lambda: eng.cancel(uid), on_done, "cancel")
 
     def drain_async(self, on_done: Callable[[Any], Any]) -> None:
         """Run the replica to completion (shutdown half)."""
@@ -229,7 +229,7 @@ class EngineReplicaHandle:
             outs = list(eng.drain().items())
             return outs, self._pool_snapshot(eng)
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "drain")
 
     def export_parked_async(self, on_done: Callable[[Any], Any]) -> None:
         """Pull the engine's parked sessions (spill-format blobs) off
@@ -240,7 +240,7 @@ class EngineReplicaHandle:
         def op() -> List[Dict[str, Any]]:
             return eng.export_parked()
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "export_parked")
 
     def import_parked_async(self, sessions: List[Dict[str, Any]],
                             on_done: Callable[[Any], Any]) -> None:
@@ -252,7 +252,7 @@ class EngineReplicaHandle:
         def op() -> List[int]:
             return eng.import_parked(sessions)
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "import_parked")
 
     def export_handoff_async(self, on_done: Callable[[Any], Any]) -> None:
         """Pull the engine's handoff-ready sessions (prefill + first
@@ -264,7 +264,7 @@ class EngineReplicaHandle:
         def op() -> List[Dict[str, Any]]:
             return eng.export_handoff()
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "export_handoff")
 
     def import_handoff_async(self, sessions: List[Dict[str, Any]],
                              export_t: float,
@@ -278,7 +278,7 @@ class EngineReplicaHandle:
         def op() -> List[int]:
             return eng.import_handoff(sessions, export_t)
 
-        self._submit(op, on_done)
+        self._submit(op, on_done, "import_handoff")
 
     def join_all(self) -> None:
         """Fold every pending op (its ``on_done`` runs here, on the
@@ -319,12 +319,20 @@ class EngineReplicaHandle:
                 "pressure": round(in_use / usable + len(eng.waiting), 4)}
 
     def _submit(self, fn: Callable[[], Any],
-                on_done: Optional[Callable[[Any], Any]]) -> None:
+                on_done: Optional[Callable[[Any], Any]],
+                kind: str) -> None:
         if not self.alive or self._pool is None:
             raise RuntimeError(f"replica {self.name} is not alive")
         key = self._seq
         self._seq += 1
-        self._window.submit(key, self._pool.submit(fn), on_done=on_done)
+
+        def op() -> Any:
+            # on the worker: the time outside any ``replica.op`` is the
+            # engine waiting for the router or for a request
+            with trace.bracket("replica.op", cat="serving", kind=kind):
+                return fn()
+
+        self._window.submit(key, self._pool.submit(op), on_done=on_done)
 
     def feed_stats(self) -> Dict[str, Any]:
         """Window counters/timers (``submitted``/``completed`` +

@@ -551,12 +551,14 @@ class Router:
         """Chain hash over the page-aligned prompt prefix — the SAME
         chunking the prefix cache indexes with, so two prompts that
         would share cached pages land on the same replica."""
-        n = (prompt.size // self._chunk) * self._chunk
-        h = ROOT_HASH
-        for i in range(0, n, self._chunk):
-            h = _chunk_hash(h, tuple(int(t) for t in
-                                     prompt[i:i + self._chunk]))
-        return h
+        # on the intake path, token by token in Python: its own span
+        with trace.bracket("router.prefix_hash", cat="serving"):
+            n = (prompt.size // self._chunk) * self._chunk
+            h = ROOT_HASH
+            for i in range(0, n, self._chunk):
+                h = _chunk_hash(h, tuple(int(t) for t in
+                                         prompt[i:i + self._chunk]))
+            return h
 
     def submit(self, prompt: Any, priority: int = 0,
                deadline_ms: Optional[float] = None, **kw) -> int:
@@ -566,6 +568,13 @@ class Router:
         ``deadline_ms`` is an ADMISSION input: already burned at
         submit raises :class:`DeadlineRejection`; burning while queued
         expires the request in the heap before it ever costs a slot."""
+        # the caller's thread (the front door's pump thread) steps no
+        # replica while it is in here
+        with trace.bracket("router.submit", cat="serving"):
+            return self._accept(prompt, priority, deadline_ms, kw)
+
+    def _accept(self, prompt: Any, priority: int,
+                deadline_ms: Optional[float], kw: Dict[str, Any]) -> int:
         if not self.accepting:
             self.stats_counters["rejected_draining"] += 1
             raise DrainingRejection(
@@ -661,8 +670,8 @@ class Router:
         self._routed[name] += 1
         req.replica = name
         req.attempts += 1
-        with trace.span("router_dispatch", cat="serving", rid=req.rid,
-                        replica=name):
+        with trace.bracket("router.dispatch", cat="serving", rid=req.rid,
+                           replica=name):
             try:
                 d = faults.hook("router.dispatch", replica=name,
                                 rid=req.rid)
@@ -801,7 +810,7 @@ class Router:
         what admission allows, then submit one step op per busy
         replica.  Results fold back on THIS thread at window joins
         (back-pressure, ``join_all`` or ``drain``)."""
-        with trace.span("router_pump", cat="serving"):
+        with trace.bracket("router.pump", cat="serving"):
             self._check_health()
             self._maybe_revive()
             self._dispatch_queued()
@@ -967,6 +976,10 @@ class Router:
                                  and payload.get("digests")), **attrs)
 
     def _on_step_done(self, h: Any, payload: Any) -> None:
+        with trace.bracket("router.fold", cat="serving"):
+            self._fold_step(h, payload)
+
+    def _fold_step(self, h: Any, payload: Any) -> None:
         # payload is (outs, pool, deltas); legacy fakes post (outs, pool)
         outs, pool = payload[0], payload[1]
         deltas = payload[2] if len(payload) > 2 else ()

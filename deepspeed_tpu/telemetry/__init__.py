@@ -29,11 +29,13 @@ in the tree:
 Enable knobs: ``DSTPU_TRACE=1`` (env) or
 ``telemetry.configure(enabled=True)``; ``DSTPU_TRACE_BUFFER`` sizes
 the per-thread rings; ``DSTPU_TRACE_SAMPLE=N`` arms tail sampling;
-``DSTPU_TRACE_ANNOTATE=1`` bridges spans into ``jax.profiler`` device
-profiles; ``DSTPU_FLIGHT_DIR`` picks the flight-dump directory;
+serving-loop spans (``trace.bracket``: ``replica.op``, ``engine.*``,
+``router.*``) appear in any ``jax.profiler`` trace with no knob;
+``DSTPU_FLIGHT_DIR`` picks the flight-dump directory;
 ``DSTPU_METRICS=0`` disables the metrics registry.
 
-Stdlib-only on import (jax is lazy) — safe to import from every layer.
+Imports only the stdlib and ``jax.profiler`` — safe to import from
+every layer.
 """
 from deepspeed_tpu.telemetry.tracer import (Tracer, configure, get_tracer,
                                             trace)

@@ -74,11 +74,12 @@ class _Rec:
                  "last_token_t", "tokens", "spill_stall_s", "spills",
                  "finish_t", "prefill_end_t", "prefill_computed",
                  "prefill_cached", "errors", "router_accept_t",
-                 "handoff_stall_s", "handoffs")
+                 "handoff_stall_s", "handoffs", "prompt_tokens")
 
-    def __init__(self, uid: Any, submit_t: float):
+    def __init__(self, uid: Any, submit_t: float, prompt_tokens: int = 0):
         self.uid = uid
         self.submit_t = submit_t
+        self.prompt_tokens = int(prompt_tokens)
         self.router_accept_t: Optional[float] = None
         self.admit_t: Optional[float] = None
         self.first_token_t: Optional[float] = None
@@ -161,8 +162,10 @@ class RequestLatencyTracker:
 
     # -- lifecycle hooks (called by the engine) --------------------------
 
-    def on_submit(self, uid: Any) -> None:
-        self._live[uid] = _Rec(uid, self.clock())
+    def on_submit(self, uid: Any, prompt_tokens: int = 0) -> None:
+        """``prompt_tokens`` rides on the completed record, so a reader
+        never has to pair engine records with a client's by order."""
+        self._live[uid] = _Rec(uid, self.clock(), prompt_tokens)
         self.submitted += 1
 
     def note_router_accept(self, uid: Any, accept_t: float) -> None:
@@ -311,6 +314,7 @@ class RequestLatencyTracker:
             "handoff_stall_ms": (r.handoff_stall_s * 1e3
                                  if r.handoffs > 0 else None),
             "tokens": r.tokens,
+            "prompt_tokens": r.prompt_tokens,
             "spills": r.spills,
             "handoffs": r.handoffs,
             "errors": r.errors,

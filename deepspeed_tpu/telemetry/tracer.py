@@ -6,9 +6,16 @@ Design constraints (the reason this is NOT just another timer class):
 - **Near-zero cost when disabled.**  ``trace.span(...)`` on a disabled
   tracer returns a process-wide singleton no-op context manager: no
   allocation, no string formatting, no clock read.  Adapters in the
-  legacy telemetry (``HostStageStats``, ``StageTimers``,
-  ``utils/timer.py``) guard their re-emit with ``if trace.enabled``,
-  so tracing off means the hot paths behave byte-for-byte as before.
+  legacy telemetry (``StageTimers``, ``utils/timer.py``) guard their
+  re-emit with ``if trace.enabled``, so tracing off means the hot
+  paths behave byte-for-byte as before.
+- **One bracket, three sinks.**  ``trace.bracket(...)`` is the serving
+  loop's stage bracket: ALWAYS a ``jax.profiler.TraceAnnotation`` (so
+  it shows in a profiler trace exactly when a profiler session is open
+  — no environment variable, no ``configure`` call; 1.5 us with no
+  session, 0.4 of it the annotation), in the ring when the tracer is
+  enabled, and timed (``Span.dur``) for whoever opened it
+  (``HostStageStats`` folds it into its stage seconds).
 - **Thread-aware.**  Every span/event lands in a bounded per-thread
   ring (``collections.deque(maxlen=...)``); threads never contend on a
   lock in the record path (the lock only guards ring *registration*).
@@ -26,8 +33,8 @@ Design constraints (the reason this is NOT just another timer class):
   postmortem buffer: ``snapshot()`` hands the recent timeline to
   ``telemetry.flight.dump_on_fault`` on hard-failure paths.
 
-The module is stdlib-only (``jax`` imported lazily for the optional
-``TraceAnnotation`` bridge) so every layer of the codebase — comm
+The module imports nothing of this package (only the stdlib and
+``jax.profiler.TraceAnnotation``) so every layer of the codebase — comm
 watchdog, resilience guards, swap path, serving engines — can import
 it without cycles.
 """
@@ -40,7 +47,9 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
-__all__ = ["Tracer", "get_tracer", "configure", "trace"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "Tracer", "get_tracer", "configure", "trace"]
 
 DEFAULT_BUFFER = 8192          # spans+events retained per thread
 _SCHEMA_VERSION = 1
@@ -65,10 +74,14 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """A live span: records one complete ("X") event on exit."""
+class Span:
+    """A live span.  It is a ``jax.profiler.TraceAnnotation`` (host
+    plane of any open profiler session, ``attrs`` as the event's
+    stats), records one complete ("X") event into the ring on exit when
+    the tracer is enabled, and keeps its duration in ``dur`` (seconds
+    on the tracer's clock) for the code that opened it."""
 
-    __slots__ = ("_tracer", "name", "cat", "attrs", "t0", "_ann")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "t0", "dur", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: Optional[Dict[str, Any]]):
@@ -76,33 +89,28 @@ class _Span:
         self.name = name
         self.cat = cat
         self.attrs = attrs
-        self.t0 = 0.0
-        self._ann = None
+        self._ann = (TraceAnnotation(name, **attrs) if attrs
+                     else TraceAnnotation(name))
 
     def __enter__(self):
-        tr = self._tracer
-        self.t0 = tr.clock()
-        if tr.annotate:
-            ann = tr._annotation_cls()
-            if ann is not None:
-                self._ann = ann(self.name)
-                self._ann.__enter__()
+        self._ann.__enter__()
+        self.t0 = self._tracer.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         tr = self._tracer
-        t1 = tr.clock()
-        if self._ann is not None:
-            self._ann.__exit__(exc_type, exc, tb)
-        attrs = self.attrs
-        if exc_type is not None:
-            attrs = dict(attrs) if attrs else {}
-            attrs["error"] = exc_type.__name__
-        tr._append({
-            "ph": "X", "name": self.name, "cat": self.cat,
-            "ts": tr._us(self.t0), "dur": max(0.0, (t1 - self.t0) * 1e6),
-            "args": attrs or {},
-        })
+        self.dur = tr.clock() - self.t0
+        self._ann.__exit__(exc_type, exc, tb)
+        if tr.enabled:
+            attrs = self.attrs
+            if exc_type is not None:
+                attrs = dict(attrs) if attrs else {}
+                attrs["error"] = exc_type.__name__
+            tr._append({
+                "ph": "X", "name": self.name, "cat": self.cat,
+                "ts": tr._us(self.t0), "dur": max(0.0, self.dur * 1e6),
+                "args": attrs or {},
+            })
         return False
 
 
@@ -119,14 +127,12 @@ class Tracer:
     def __init__(self, enabled: bool = False,
                  buffer_size: int = DEFAULT_BUFFER,
                  clock: Callable[[], float] = time.perf_counter,
-                 annotate: bool = False,
                  sampling: bool = False,
                  sample_n: int = 0,
                  retained_size: int = 4 * DEFAULT_BUFFER):
         self.enabled = bool(enabled)
         self.buffer_size = int(buffer_size)
         self.clock = clock
-        self.annotate = bool(annotate)
         # Tail sampling: record always-on into the per-thread staging
         # rings, but treat them as scratch — only spans *promoted* (the
         # request breached an SLO, errored, or fell in the 1-in-N
@@ -141,14 +147,12 @@ class Tracer:
         self._rings: Dict[int, deque] = {}
         self._thread_names: Dict[int, str] = {}
         self._local = threading.local()
-        self._annotation = None      # resolved lazily, cached
 
     # -- configuration ---------------------------------------------------
 
     def configure(self, enabled: Optional[bool] = None,
                   buffer_size: Optional[int] = None,
                   clock: Optional[Callable[[], float]] = None,
-                  annotate: Optional[bool] = None,
                   sampling: Optional[bool] = None,
                   sample_n: Optional[int] = None,
                   retained_size: Optional[int] = None) -> "Tracer":
@@ -162,8 +166,6 @@ class Tracer:
                 for tid, ring in list(self._rings.items()):
                     self._rings[tid] = deque(ring, maxlen=self.buffer_size)
                 self._local = threading.local()
-            if annotate is not None:
-                self.annotate = bool(annotate)
             if sampling is not None:
                 self.sampling = bool(sampling)
             if sample_n is not None:
@@ -185,7 +187,13 @@ class Tracer:
         Disabled: returns the shared no-op singleton (no allocation)."""
         if not self.enabled:
             return _NULL_SPAN
-        return _Span(self, name, cat, attrs or None)
+        return Span(self, name, cat, attrs or None)
+
+    def bracket(self, name: str, cat: str = "host", **attrs) -> Span:
+        """A span that reaches the profiler whether or not the tracer
+        is enabled (the serving loop's stage brackets): always a live
+        :class:`Span`, never the no-op singleton."""
+        return Span(self, name, cat, attrs or None)
 
     def event(self, name: str, cat: str = "host", **attrs) -> None:
         """Instant event (Chrome ``ph: "i"``) — request lifecycle marks."""
@@ -221,17 +229,6 @@ class Tracer:
 
     def _us(self, t: float) -> float:
         return (t - self._epoch) * 1e6
-
-    def _annotation_cls(self):
-        """``jax.profiler.TraceAnnotation`` when available, else None —
-        bridges host spans into the device profile timeline."""
-        if self._annotation is None:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._annotation = TraceAnnotation
-            except Exception:
-                self._annotation = False
-        return self._annotation or None
 
     # -- read path -------------------------------------------------------
 
@@ -360,7 +357,6 @@ _SAMPLE_N = _env_sample_n()
 trace = Tracer(
     enabled=_env_truthy("DSTPU_TRACE") or _SAMPLE_N is not None,
     buffer_size=int(os.environ.get("DSTPU_TRACE_BUFFER", DEFAULT_BUFFER)),
-    annotate=_env_truthy("DSTPU_TRACE_ANNOTATE"),
     sampling=_SAMPLE_N is not None,
     sample_n=_SAMPLE_N or 0,
 )
@@ -371,6 +367,6 @@ def get_tracer() -> Tracer:
 
 
 def configure(**kw) -> Tracer:
-    """``telemetry.configure(enabled=True, buffer_size=..., clock=...,
-    annotate=...)`` — mutates the process singleton in place."""
+    """``telemetry.configure(enabled=True, buffer_size=..., clock=...)``
+    — mutates the process singleton in place."""
     return trace.configure(**kw)

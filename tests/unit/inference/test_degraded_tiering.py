@@ -210,7 +210,7 @@ class TestEngineDegradedMode:
                                                      tmp_path):
         off, _eoff = _serve(params, None, SIZES)
         tr = tracer_mod.trace
-        prev = (tr.enabled, tr.buffer_size, tr.clock, tr.annotate)
+        prev = (tr.enabled, tr.buffer_size, tr.clock)
         tr.clear()
         tr.configure(enabled=True)
         try:
@@ -247,5 +247,5 @@ class TestEngineDegradedMode:
             eon.close()
         finally:
             tr.configure(enabled=prev[0], buffer_size=prev[1],
-                         clock=prev[2], annotate=prev[3])
+                         clock=prev[2])
             tr.clear()

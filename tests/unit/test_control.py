@@ -71,11 +71,10 @@ def registry():
 @pytest.fixture
 def global_trace():
     tr = tracer_mod.trace
-    prev = (tr.enabled, tr.buffer_size, tr.clock, tr.annotate)
+    prev = (tr.enabled, tr.buffer_size, tr.clock)
     tr.clear()
     yield tr
-    tr.configure(enabled=prev[0], buffer_size=prev[1], clock=prev[2],
-                 annotate=prev[3])
+    tr.configure(enabled=prev[0], buffer_size=prev[1], clock=prev[2])
     tr.clear()
 
 

@@ -33,11 +33,10 @@ class ManualClock:
 def global_trace():
     """The process singleton, restored to its prior configuration."""
     tr = tracer_mod.trace
-    prev = (tr.enabled, tr.buffer_size, tr.clock, tr.annotate)
+    prev = (tr.enabled, tr.buffer_size, tr.clock)
     tr.clear()
     yield tr
-    tr.configure(enabled=prev[0], buffer_size=prev[1], clock=prev[2],
-                 annotate=prev[3])
+    tr.configure(enabled=prev[0], buffer_size=prev[1], clock=prev[2])
     tr.clear()
 
 
