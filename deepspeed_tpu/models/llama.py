@@ -298,6 +298,8 @@ class LlamaMLP(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        from deepspeed_tpu.runtime.zero import pin_activation
+
         cfg = self.config
         dense = dict(use_bias=False, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, **_wq_kwargs(cfg))
@@ -305,8 +307,12 @@ class LlamaMLP(nn.Module):
                         **_tp_kwargs(cfg, "col"))(x)
         up = nn.Dense(cfg.intermediate_size, name="up_proj", **dense,
                       **_tp_kwargs(cfg, "col"))(x)
+        # the widest activation stays on its batch shard: under ZeRO-3 its
+        # trailing dim is the one the weights are split over, and left alone
+        # the partitioner ran the backward tensor-parallel over ``data``
         return nn.Dense(cfg.hidden_size, name="down_proj", **dense,
-                        **_tp_kwargs(cfg, "row"))(nn.silu(gate) * up)
+                        **_tp_kwargs(cfg, "row"))(
+                            pin_activation(nn.silu(gate) * up))
 
 
 class LlamaBlock(nn.Module):
@@ -315,7 +321,11 @@ class LlamaBlock(nn.Module):
     @nn.compact
     def __call__(self, x, positions, deterministic: bool = True,
                  ragged_meta=None):
+        from deepspeed_tpu.runtime.zero import pin_activation
+
         cfg = self.config
+        # the residual stream enters every block on its batch shard
+        x = pin_activation(x)
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="input_layernorm")(x)
         x = x + LlamaAttention(cfg, name="self_attn")(h, positions,
                                                       deterministic,

@@ -836,6 +836,7 @@ class DeepSpeedEngine:
 
     def _build_train_step(self):
         plan = self.plan
+        base_specs = self.base_specs
         mesh = self.mesh
         loss_fn = self.loss_fn
         tx_update = self._tx_update
@@ -859,7 +860,7 @@ class DeepSpeedEngine:
         check_overflow = (self.config.fp16.enabled
                           or self.config.resilience.check_grad_finite > 0)
 
-        def train_step(state: TrainState, batch, lr):
+        def step_body(state: TrainState, batch, lr):
             rng, new_rng = jax.random.split(state.rng)
             scale = state.scale.loss_scale
             # ZeRO-Offload: explicit H2D fetch of host-resident state
@@ -958,6 +959,14 @@ class DeepSpeedEngine:
                 "loss_scale": new_scale.loss_scale,
             }
             return new_state, metrics
+
+        def train_step(state: TrainState, batch, lr):
+            # runs while the step is traced: the plan counts the activation
+            # pins that engage, and its line is logged again with the count
+            with plan.counting_pins():
+                out = step_body(state, batch, lr)
+            log_dist(plan.describe(state.params, base_specs), ranks=[0])
+            return out
 
         metric_shardings = {k: self._repl()
                             for k in ("loss", "grad_norm", "overflow",

@@ -23,18 +23,45 @@ the gathers; scan-over-layers in the model bounds live parameters the way
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Optional, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+import deepspeed_tpu.comm as dist
 from deepspeed_tpu.parallel.topology import MeshTopology
+from deepspeed_tpu.telemetry import trace
+from deepspeed_tpu.utils.compat import get_abstract_mesh
 from deepspeed_tpu.utils.logging import log_dist
+
+# the plan whose step is being traced (``ZeroShardingPlan.counting_pins``)
+_counting_plan: contextvars.ContextVar = contextvars.ContextVar(
+    "zero_counting_plan", default=None)
+
+
+def batch_axes(topology: MeshTopology) -> Tuple[str, ...]:
+    """The mesh axes a batch dim is split over: those larger than 1."""
+    return tuple(a for a in ("data", "data_sub", "expert")
+                 if topology.axis_size(a) > 1)
 
 
 class ZeroShardingPlan:
-    """Computes per-leaf PartitionSpecs for a given stage and topology."""
+    """Computes per-leaf PartitionSpecs for a given stage and topology.
+
+    The plan fixes four layouts: parameters (``param_specs``), gradients
+    (``grad_specs``), the batch (``batch_spec``) and activations
+    (:func:`pin_activation`, which models call on the values whose place
+    matters).  The fourth is needed because the first three leave the
+    partitioner free to trade a weight gather for activation traffic: with
+    a stage-3 weight sharded over ``data`` it may keep the weight where it
+    is and move the activations instead, running a layer tensor-parallel
+    over the data axis behind all-to-alls and gathers of the global batch.
+    ZeRO means the opposite: activations stay on the batch shard they were
+    born on, weights are gathered to them and gradients reduce-scattered
+    back."""
 
     def __init__(self, topology: MeshTopology, stage: int,
                  persistence_threshold: int = 100_000,
@@ -64,6 +91,9 @@ class ZeroShardingPlan:
                     "wide — build the mesh with initialize_mesh(..., "
                     "hpz=<size>) (the engine does this automatically)")
             self.param_axes = tuple(a for a in self.axes if a == HPZ_AXIS)
+        # pin_activation sites that engaged in the newest traced step
+        # (counting_pins); None until a step has been traced
+        self.activation_pins: Optional[int] = None
 
     # -- per-leaf spec ----------------------------------------------------
 
@@ -197,8 +227,7 @@ class ZeroShardingPlan:
         and guessing one would mis-shard it.  Pass ``dtype`` to engage
         the check; ``dtype=None`` keeps the token-array assumption for
         backward compatibility."""
-        axes = tuple(a for a in ("data", "data_sub", "expert")
-                     if self.topology.axis_size(a) > 1)
+        axes = batch_axes(self.topology)
         specs = []
         if has_gas_dim:
             specs.append(None)  # scan (GAS) dim never sharded
@@ -233,9 +262,52 @@ class ZeroShardingPlan:
             if any(s is not None for s in spec):
                 n_sharded += 1
                 bytes_sharded += sz
+        pins = ("" if self.activation_pins is None else
+                f", {self.activation_pins} activation pins in the traced step")
         return (f"ZeRO stage {self.stage}: {n_sharded}/{n_total} param tensors "
                 f"sharded over {self.axes} ({self.partitions} partitions), "
-                f"{bytes_sharded / max(bytes_total, 1):.0%} of param bytes")
+                f"{bytes_sharded / max(bytes_total, 1):.0%} of param bytes"
+                + pins)
+
+    @contextlib.contextmanager
+    def counting_pins(self):
+        """Count into ``activation_pins`` the :func:`pin_activation` sites
+        that engage while a step of this plan is traced.  A model that
+        trains under ZeRO on more than one device and reads 0 here has its
+        activations placed by the partitioner's cost model alone."""
+        self.activation_pins = 0
+        token = _counting_plan.set(self)
+        try:
+            yield
+        finally:
+            _counting_plan.reset(token)
+            trace.event("zero.activation_pins", cat="zero",
+                        stage=self.stage, pins=self.activation_pins)
+
+
+def pin_activation(x):
+    """Pin an activation to the plan's batch layout: its leading (batch)
+    dim over the axes ``ZeroShardingPlan.batch_spec`` splits a batch over,
+    every other dim left to the partitioner (a ``tensor`` or ``seq`` split
+    of those stays the model's affair).  The constraint transposes to the
+    same constraint on the cotangent, so pinning forward values places the
+    backward too.
+
+    Returns ``x`` itself, untouched, where there is nothing to pin to: no
+    installed topology, one device, no batch axis larger than 1, a batch
+    dim the axes do not divide, or a region that is already manual
+    (pipeline, Ulysses, ring)."""
+    topo = dist.peek_topology()
+    axes = batch_axes(topo) if topo is not None else ()
+    if not axes or \
+            x.shape[0] % int(np.prod([topo.axis_size(a) for a in axes])) or \
+            get_abstract_mesh().manual_axes:
+        return x
+    plan = _counting_plan.get()
+    if plan is not None:
+        plan.activation_pins += 1
+    spec = P(axes, *[P.UNCONSTRAINED] * (x.ndim - 1))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(topo.mesh, spec))
 
 
 def constrain_tree(tree, spec_tree, mesh: Mesh):
