@@ -295,7 +295,8 @@ def _logits_parity(plan: Plan, eng, model, prompt: List[int]) -> float:
         args = [eng._upload(a) for a in (
             ids[None], pos[None], kv_lens, pages, cu,
             np.asarray([1], np.int32), dest, rows)]
-        sel, eng.cache = eng._fused_step_fn()(eng.params, eng.cache, *args)
+        sel, eng.cache, _ = eng._fused_step_fn()(eng.params, eng.cache,
+                                                 *args)
         return np.asarray(sel[0], np.float32)
 
     at_last = tick(prompt, 0)

@@ -149,6 +149,10 @@ class HostStageStats:
         self.prefill_tokens = 0     # prompt tokens in fused ticks
         self.prefill_key_tokens = 0   # keys those tokens attend to
         self.decode_key_token_steps = 0   # context tokens x token steps
+        # dropless expert layers, live rows only, summed over layers:
+        self.moe_rows = 0           # token copies routed
+        self.moe_experts_hit = 0    # experts with at least one row
+        self.moe_load_max = 0       # rows of the fullest expert, ever
 
     def stage(self, name: str, **counts) -> _StageSpan:
         """Bracket one stage of the hot loop; ``counts`` become the

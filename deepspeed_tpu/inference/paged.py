@@ -36,9 +36,23 @@ from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 
 TRASH_PAGE = 0
 
-# block sizes handed to the upstream ragged paged kernel (see ``attend``)
+# block sizes handed to the upstream ragged paged kernel (see ``attend``):
+# a KV block of at most 512 tokens, and of at most 4096 rows of K or V
+# (tokens x KV heads) — what 8 KV heads x 512 tokens hold
 _RPA_KV_BLOCK_TOKENS = 512
+_RPA_KV_BLOCK_HEAD_TOKENS = 4096
 _RPA_QUERY_BLOCK = 32
+
+
+def rpa_kv_pages_per_block(kv_heads: int, page_size: int,
+                           pages_per_seq: int) -> int:
+    """KV pages one block of the upstream kernel holds.  The block lives
+    in scoped VMEM, double-buffered, and its bytes grow with the KV heads
+    (pages are ``[P, page, 2 * Hkv, D]``): 512 tokens at up to 8 KV heads,
+    256 at 16, never under one page."""
+    tokens = min(_RPA_KV_BLOCK_TOKENS,
+                 _RPA_KV_BLOCK_HEAD_TOKENS // max(kv_heads, 1))
+    return max(1, min(pages_per_seq, tokens // page_size))
 
 # same mask-value family as ops/flash_attention.py and the Pallas
 # quantized kernel: vanishes under softmax, (mask - mask) stays exact 0
@@ -614,15 +628,15 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
             # upstream lookup answers (128 pages, 32 queries), and from
             # max_seq_len ~2k up that KV block no longer fits the chip's
             # scoped VMEM (the v5e compiler: "Ran out of memory in memory
-            # space vmem").  512 KV tokens x 32 queries compiles under the
+            # space vmem").  512 KV tokens x 32 queries at up to 8 KV
+            # heads (fewer tokens at more heads) compiles under the
             # default limit at every page size the engine offers.
             return rpa.ragged_paged_attention(
                 qt, pages, kv_lens, jnp.maximum(page_indices, 0),
                 cu_q_lens, num_seqs, sm_scale=sm_scale,
                 sliding_window=window,
-                num_kv_pages_per_block=max(
-                    1, min(page_indices.shape[1],
-                           _RPA_KV_BLOCK_TOKENS // page)),
+                num_kv_pages_per_block=rpa_kv_pages_per_block(
+                    pages.shape[2] // 2, page, page_indices.shape[1]),
                 num_queries_per_block=min(T, _RPA_QUERY_BLOCK))
         if carry is None and routes.on_tpu():
             from deepspeed_tpu.utils.logging import logger

@@ -134,6 +134,22 @@ from deepspeed_tpu.inference.sampling import (filter_logits_batched,
 from deepspeed_tpu.utils.logging import log_dist
 
 
+def _fold_moe_counts(counts: jax.Array) -> jax.Array:
+    """``[n, 3]`` per-layer (or per-tick) expert-layer counts -> ``[3]``:
+    token copies routed and experts hit add up, the fullest expert's rows
+    take the largest."""
+    return jnp.concatenate([jnp.sum(counts[:, :2], axis=0),
+                            jnp.max(counts[:, 2:], axis=0)])
+
+
+def _moe_counts(variables) -> Optional[jax.Array]:
+    """One apply's ``moe_stats`` collection (each dropless expert layer
+    sows ``[rows, experts_hit, load_max]``) folded over its layers; None
+    for a model without expert layers, so its programs gain no output."""
+    leaves = jax.tree_util.tree_leaves(variables.get("moe_stats", {}))
+    return _fold_moe_counts(jnp.stack(leaves)) if leaves else None
+
+
 def _paged_kv_page_bytes(model, mcfg, page_size: int,
                          kv_cache_dtype: str) -> int:
     """Exact device bytes ONE KV page costs across every cache leaf —
@@ -617,6 +633,9 @@ class RaggedInferenceEngineV2:
         self._unclaimed: Dict[int, np.ndarray] = {}
         self._step_fn = None
         self._decode_block_cache: Dict[bool, Any] = {}
+        # (device counts, span attributes) of dispatched expert-layer
+        # programs whose counts no fetch has brought to the host yet
+        self._moe_pending: List[Tuple[Any, Dict[str, Any]]] = []
         self._last_tokens = np.zeros((max_seqs,), np.int32)
         # streaming cursor: generated-token count already reported per
         # uid (stream_deltas); cancels counts cancellations at any stage
@@ -1403,6 +1422,15 @@ class RaggedInferenceEngineV2:
         # the attention read route this backend gives this pool: a Pallas
         # kernel, or the XLA twin it stands in for (ops/routes.py)
         out["kernels"] = {"paged_attention": route}
+        st = self.host_stats
+        if st.moe_rows:
+            from deepspeed_tpu.moe.sharded_moe import (GROUPED_MATMUL,
+                                                       grouped_matmul_route)
+
+            out["kernels"][GROUPED_MATMUL] = grouped_matmul_route()
+            out["moe"] = {"rows": st.moe_rows,
+                          "experts_hit": st.moe_experts_hit,
+                          "load_max": st.moe_load_max}
         # pool pressure: the scale-out router's least-pressure policy
         # reads this (waiting queue + page occupancy, both plain host
         # ints — no device sync)
@@ -1479,7 +1507,27 @@ class RaggedInferenceEngineV2:
         point (``host_stats.blocking_gets`` counts them)."""
         with self.host_stats.stage("device"):
             self.host_stats.blocking_gets += 1
-            return jax.device_get(tree)
+            if not self._moe_pending:
+                return jax.device_get(tree)
+            # the expert layers' counts of the dispatches since the last
+            # fetch ride on this one: no round trip of their own
+            pending, self._moe_pending = self._moe_pending, []
+            tree, counts = jax.device_get((tree, [c for c, _ in pending]))
+        for c, (_, attrs) in zip(counts, pending):
+            self._record_moe(c, attrs)
+        return tree
+
+    def _record_moe(self, counts, attrs: Dict[str, Any]) -> None:
+        """One dispatch's expert-layer counts, as a span of their own in
+        the profiler's host plane and in ``serving_stages()['moe']``."""
+        rows, hit, load = (int(v) for v in counts)
+        st = self.host_stats
+        st.moe_rows += rows
+        st.moe_experts_hit += hit
+        st.moe_load_max = max(st.moe_load_max, load)
+        with trace.bracket("engine.moe", cat="serving", moe_rows=rows,
+                           moe_experts_hit=hit, moe_load_max=load, **attrs):
+            pass
 
     def _block_ready(self, block):
         """The pipeline window's waiter: joining a decode block means
@@ -1552,13 +1600,17 @@ class RaggedInferenceEngineV2:
                 params = unroll_scan_params(params)
             meta = {"kv_lens": kv_lens, "page_indices": page_indices,
                     "cu_q_lens": cu_q_lens, "num_seqs": num_seqs,
-                    "new_kv_dest": new_kv_dest}
+                    "new_kv_dest": new_kv_dest,
+                    # rows past the last sequence's end are padding
+                    "live": (jnp.arange(token_ids.shape[1]) <
+                             cu_q_lens[num_seqs[0]])}
             out, vars_ = model.apply(
                 {"params": params, "cache": cache}, token_ids,
-                positions=positions, mutable=["cache"], ragged_meta=meta)
+                positions=positions, mutable=["cache", "moe_stats"],
+                ragged_meta=meta)
             logits = logits_of(out)[0]                      # [T, V]
             sel = jnp.take(logits, sample_rows, axis=0)     # [max_seqs, V]
-            return sel, vars_["cache"]
+            return sel, vars_["cache"], _moe_counts(vars_)
 
         # distinguishable XLA program name ("jit_ragged_fused_step") so
         # the profiler bridge can attribute device time per program
@@ -1612,12 +1664,13 @@ class RaggedInferenceEngineV2:
                         "page_indices": page_table,
                         "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
                         "num_seqs": jnp.asarray([S], jnp.int32),
-                        "new_kv_dest": dest}
+                        "new_kv_dest": dest, "live": active}
                 out, vars_ = model.apply(
                     {"params": params, "cache": cache}, last_tok[None],
                     positions=jnp.where(active, pos, 0)[None],
-                    mutable=["cache"], ragged_meta=meta)
+                    mutable=["cache", "moe_stats"], ragged_meta=meta)
                 logits = logits_of(out)[0]              # [S, V]
+                moe = _moe_counts(vars_)
                 rng, _ = jax.random.split(rng)
                 # position-keyed per-row keys: the draw at cache position
                 # `pos` is the same bits the fused tick's host sampler
@@ -1635,15 +1688,16 @@ class RaggedInferenceEngineV2:
                 active = (active & ~hit_eos & (remaining > 0) &
                           (pos + 1 < max_len))
                 return (vars_["cache"], nxt, pos, active, remaining,
-                        rng), (nxt, produced)
+                        rng), (nxt, produced, moe)
 
-            carry, (toks, mask) = jax.lax.scan(
+            carry, (toks, mask, moe) = jax.lax.scan(
                 tick, (cache, last_tok, pos, active, remaining, rng),
                 length=K)
             cache, last_tok, pos, active, remaining, rng = carry
             # the full carry returns so the pipelined host path can keep
             # it device-resident across dispatches (no re-upload)
-            return cache, last_tok, pos, active, remaining, toks, mask
+            return (cache, last_tok, pos, active, remaining, toks, mask,
+                    None if moe is None else _fold_moe_counts(moe))
 
         run.__name__ = run.__qualname__ = "ragged_decode_block"
         fn = jax.jit(run, donate_argnums=(1,))
@@ -2062,10 +2116,11 @@ class RaggedInferenceEngineV2:
                         uids=[r.uid for r in reqs],
                         ticks=self.decode_block_size)
         with self._decode_dispatch(reqs, [r.length for r in reqs]):
-            (cache, new_last, _pos, _active, _remaining, toks,
-             mask) = self._decode_block_fn(sampled)(
+            (cache, new_last, _pos, _active, _remaining, toks, mask,
+             moe) = self._decode_block_fn(sampled)(
                 self.params, self.cache, *args, sub)
         self.cache = cache
+        self._moe_dispatched(moe, "decode_block", self.decode_block_size)
         st.ticks += self.decode_block_size
         toks, mask, new_last = self._fetch((toks, mask, new_last))
         st.harvests += 1
@@ -2080,6 +2135,13 @@ class RaggedInferenceEngineV2:
                 self._maybe_finish(r)
             self._reap()
         return produced
+
+    def _moe_dispatched(self, counts, program: str, ticks: int) -> None:
+        """Keep a dispatch's expert-layer counts (a device array, or None
+        for a model without expert layers) until a fetch brings them."""
+        if counts is not None:
+            self._moe_pending.append(
+                (counts, {"program": program, "ticks": ticks}))
 
     def _decode_dispatch(self, reqs: List[Request], contexts):
         """The ``dispatch`` bracket of one plain decode block, with the
@@ -2295,12 +2357,13 @@ class RaggedInferenceEngineV2:
             with self._decode_dispatch(
                     live, [dv["plen"][r.slot] for r in live]):
                 (self.cache, dv["last_tok"], dv["pos"], dv["active"],
-                 dv["remaining"], toks, mask) = self._decode_block_fn(
-                    dv["sampled"])(
+                 dv["remaining"], toks, mask,
+                 moe) = self._decode_block_fn(dv["sampled"])(
                     self.params, self.cache, dv["last_tok"], dv["pos"],
                     dv["active"], dv["remaining"], dv["page_table"],
                     dv["eos_ids"], dv["do_sample"], dv["temperature"],
                     dv["top_k"], dv["top_p"], dv["seeds"], sub)
+            self._moe_dispatched(moe, "decode_block", K)
             block = (toks, mask)
         # track the block in the bounded window: past async_depth the
         # submit first joins the oldest un-joined block (waiting for
@@ -2524,8 +2587,9 @@ class RaggedInferenceEngineV2:
         st.decode_key_token_steps += counts["decode_key_tokens"]
         with st.stage("dispatch", program="fused", **counts):
             st.dispatches += 1
-            sel_logits, self.cache = self._fused_step_fn()(
+            sel_logits, self.cache, moe = self._fused_step_fn()(
                 self.params, self.cache, *args)
+        self._moe_dispatched(moe, "fused", 1)
         st.ticks += 1
         produced = self._sample(sel_logits, samplers)
         # a handoff prefill that just sampled its first token parks NOW

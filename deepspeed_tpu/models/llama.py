@@ -77,6 +77,9 @@ class LlamaConfig:
     # only the first partial_rotary_factor * head_dim dims (phi-2: 0.4)
     attention_out_bias: bool = False
     partial_rotary_factor: float = 1.0
+    # OLMoE: a learned RMSNorm over the WHOLE q and the whole k projection
+    # (not per head), before the head split and RoPE
+    qk_norm: bool = False
     # serving: "w8a8" makes every Dense consume per-channel int8 kernels
     # natively (dynamic activation quant + int8 MXU dot) — set by the
     # inference engines when quantize_weights engages, never for training
@@ -191,6 +194,9 @@ class LlamaAttention(nn.Module):
                      **_tp_kwargs(cfg, "col"))(x)
         v = nn.Dense(Hkv * Dh, name="v_proj", **qkv,
                      **_tp_kwargs(cfg, "col"))(x)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
 
         q = q.reshape(B, S, H, Dh).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)

@@ -57,20 +57,31 @@ def get_config(preset: str, **overrides) -> MixtralConfig:
     return MixtralConfig(**kw)
 
 
-def _moe(cfg: MixtralConfig, name: str) -> MoE:
-    return MoE(hidden_size=cfg.hidden_size,
-               num_experts=cfg.num_local_experts,
-               intermediate_size=cfg.intermediate_size,
-               k=cfg.num_experts_per_tok,
-               capacity_factor=cfg.capacity_factor,
-               min_capacity=cfg.min_capacity,
-               drop_tokens=cfg.drop_tokens,
-               activation="swiglu",
-               dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-               expert_parallel=cfg.expert_parallel,
-               tensor_parallel=cfg.tensor_parallel,
-               dispatch_impl=cfg.dispatch_impl,
-               name=name)
+def live_rows(ragged_meta) -> Optional[jax.Array]:
+    """Which rows of a serving engine's token batch are tokens (padding of
+    a fused tick and inactive decode slots are not); None outside one."""
+    return None if ragged_meta is None else ragged_meta.get("live")
+
+
+def moe_layer(cfg: MixtralConfig, name: str, **overrides) -> MoE:
+    """The routed FFN of an expert family's block.  A serving config
+    (``decode`` or ``paged_decode``) is DROPLESS: a served token's logits
+    may not depend on who shares its tick, so no capacity applies there."""
+    kw = dict(hidden_size=cfg.hidden_size,
+              num_experts=cfg.num_local_experts,
+              intermediate_size=cfg.intermediate_size,
+              k=cfg.num_experts_per_tok,
+              capacity_factor=cfg.capacity_factor,
+              min_capacity=cfg.min_capacity,
+              drop_tokens=cfg.drop_tokens,
+              activation="swiglu",
+              dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+              expert_parallel=cfg.expert_parallel,
+              tensor_parallel=cfg.tensor_parallel,
+              dispatch_impl=("grouped" if cfg.decode or cfg.paged_decode
+                             else cfg.dispatch_impl))
+    kw.update(overrides)
+    return MoE(name=name, **kw)
 
 
 class MixtralBlock(nn.Module):
@@ -86,11 +97,8 @@ class MixtralBlock(nn.Module):
                                                       ragged_meta)
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype,
                     name="post_attention_layernorm")(x)
-        # is_training stays the MoE default (train capacity factor):
-        # `deterministic` is a traced value under nn.remat, so the static
-        # capacity selection cannot branch on it — serving engines that
-        # want the eval capacity set capacity_factor on the decode config
-        y, l_aux = _moe(cfg, "block_sparse_moe")(h)
+        y, l_aux = moe_layer(cfg, "block_sparse_moe")(
+            h, live=live_rows(ragged_meta))
         return x + y, l_aux
 
 

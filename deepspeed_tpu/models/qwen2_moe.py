@@ -27,8 +27,8 @@ import numpy as np
 
 from deepspeed_tpu.models.llama import (LlamaAttention, LlamaMLP, RMSNorm,
                                         _tp_kwargs)
-from deepspeed_tpu.models.mixtral import MixtralConfig
-from deepspeed_tpu.moe.layer import MoE
+from deepspeed_tpu.models.mixtral import (MixtralConfig, live_rows,
+                                          moe_layer)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,20 +91,10 @@ class Qwen2MoeBlock(nn.Module):
                                                       ragged_meta)
         h = RMSNorm(cfg.rms_norm_eps, cfg.dtype,
                     name="post_attention_layernorm")(x)
-        y, l_aux = MoE(hidden_size=cfg.hidden_size,
-                       num_experts=cfg.num_local_experts,
-                       intermediate_size=cfg.expert_intermediate,
-                       k=cfg.num_experts_per_tok,
-                       capacity_factor=cfg.capacity_factor,
-                       min_capacity=cfg.min_capacity,
-                       drop_tokens=cfg.drop_tokens,
-                       activation="swiglu",
-                       dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                       expert_parallel=cfg.expert_parallel,
-                       tensor_parallel=cfg.tensor_parallel,
-                       dispatch_impl=cfg.dispatch_impl,
-                       normalize_weights=cfg.norm_topk_prob,
-                       name="mlp")(h)
+        y, l_aux = moe_layer(
+            cfg, "mlp", intermediate_size=cfg.expert_intermediate,
+            normalize_weights=cfg.norm_topk_prob)(
+                h, live=live_rows(ragged_meta))
         if cfg.shared_expert_intermediate_size:
             shared_cfg = dataclasses.replace(
                 cfg, intermediate_size=cfg.shared_expert_intermediate_size)

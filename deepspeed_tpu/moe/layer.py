@@ -38,10 +38,12 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 
-from deepspeed_tpu.moe.sharded_moe import (moe_combine, moe_combine_gather,
+from deepspeed_tpu.moe.sharded_moe import (grouped_matmul_route, grouped_moe,
+                                           moe_combine, moe_combine_gather,
                                            moe_dispatch, moe_dispatch_gather,
                                            routing_plan, sorted_combine,
-                                           sorted_dispatch, topkgating)
+                                           sorted_dispatch, topk_routing,
+                                           topkgating)
 from deepspeed_tpu.utils.sharding import maybe_constrain as _maybe_constrain
 
 EXPERT_AXIS = "expert"
@@ -100,6 +102,11 @@ class MoE(nn.Module):
     # einsums, which GSPMD shards over any mesh — the parity oracle.
     # "gather" is the row-scatter path: measured ~20x slower on v5e (TPU
     # scatter lowering), CPU/debug only.
+    # "grouped" is the DROPLESS path every serving config takes (the
+    # model blocks select it from ``decode``/``paged_decode``): expert-
+    # sorted rows with no padding into ``jax.lax.ragged_dot`` over the
+    # stacked banks, combine by gather — no capacity, so a row's output
+    # depends on no other row.  Forward only.
     # "auto" (default) resolves to "sorted" on single-device topologies
     # and "alltoall" on multi-device meshes (falling back to "einsum"
     # when the expert count does not divide over the expert axis).
@@ -179,6 +186,24 @@ class MoE(nn.Module):
         h = jax.nn.gelu(h + w["b1"].astype(dt)[:, None])
         out = jnp.einsum("eci,eim->ecm", h, w["w2"].astype(dt))
         return out + w["b2"].astype(dt)[:, None]
+
+    def _grouped_ffn(self, rows: jax.Array, group_sizes: jax.Array, w
+                     ) -> jax.Array:
+        """[R, M] expert-sorted rows -> [R, M]: the same FFN as
+        :meth:`_expert_ffn`, each expert's run of rows against its own
+        bank."""
+        dt = self.dtype
+        grouped_matmul_route()
+        dot = lambda a, b: jax.lax.ragged_dot(a, b.astype(dt), group_sizes)
+        if self.activation == "swiglu":
+            return dot(nn.silu(dot(rows, w["w1"])) * dot(rows, w["w3"]),
+                       w["w2"])
+        # a row's bias is its expert's: expand by the same group sizes
+        total = rows.shape[0]
+        bias = lambda b: jnp.repeat(b.astype(dt), group_sizes, axis=0,
+                                    total_repeat_length=total)
+        h = jax.nn.gelu(dot(rows, w["w1"]) + bias(w["b1"]))
+        return dot(h, w["w2"]) + bias(w["b2"])
 
     # -- gating (shared) -------------------------------------------------
 
@@ -311,8 +336,12 @@ class MoE(nn.Module):
     # -- forward ---------------------------------------------------------
 
     @nn.compact
-    def __call__(self, x: jax.Array, is_training: bool = True
+    def __call__(self, x: jax.Array, is_training: bool = True,
+                 live: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, jax.Array]:
+        """``live`` (flat bool, one per row of ``x``; None: all) is read
+        by the dropless path only: rows that are no token take no expert
+        row and count in no counter."""
         cfg = self
         orig_shape = x.shape
         M, E = cfg.hidden_size, cfg.num_experts
@@ -330,6 +359,23 @@ class MoE(nn.Module):
             noise_rng = self.make_rng("gating")
 
         impl = cfg._resolve_dispatch(x.shape[0])
+        if impl == "grouped":
+            # float32 for real: a TPU's default f32 matmul is one bf16
+            # pass, and a served token's experts are not to turn on that
+            experts, weights, l_aux = topk_routing(
+                jnp.dot(x.astype(jnp.float32), wg,
+                        precision=jax.lax.Precision.HIGHEST),
+                cfg.k, cfg.normalize_weights)
+            y, counts = grouped_moe(
+                x.astype(cfg.dtype), experts, weights, E,
+                lambda rows, gs: self._grouped_ffn(rows, gs, w),
+                None if live is None else live.reshape(-1))
+            # what the tick routed, for whoever asked (the serving
+            # engine's spans); a no-op unless the caller made the
+            # collection mutable
+            if not self.is_initializing():
+                self.sow("moe_stats", "counts", counts)
+            return y.reshape(orig_shape), l_aux
         if impl == "alltoall":
             y, l_aux = self._alltoall_moe(x, wg, w, noise_rng, is_training)
             return y.reshape(orig_shape), l_aux.astype(jnp.float32)

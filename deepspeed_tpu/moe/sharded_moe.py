@@ -340,3 +340,82 @@ def _sc_bwd(res, dy):
 
 
 sorted_combine.defvjp(_sc_fwd, _sc_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Dropless grouped dispatch: the serving path
+# ---------------------------------------------------------------------------
+# Every path above is capacity-based: a token is dropped when its expert's
+# buffer is full, and WHICH token depends on the other rows of the batch.  A
+# served token's logits may depend on no other row of its tick, so serving
+# takes none of them.  Here the routed copies are sorted by expert into ONE
+# ``[k*G, M]`` buffer with no padding between experts, and each expert's
+# contiguous run of rows meets its weight in a grouped matmul
+# (``jax.lax.ragged_dot``, the reference's ``moe_gemm``): the v5e compiler
+# lowers it to a Mosaic kernel of its own (``ragged-dot`` custom calls, no
+# dense ``[E, G, M]`` expansion), the CPU backend to plain XLA.  Work and
+# memory are linear in the routed copies; no capacity, no ``[G, E, C]``.
+
+GROUPED_MATMUL = "moe_grouped_matmul"
+
+
+def grouped_matmul_route() -> str:
+    """Which implementation ``jax.lax.ragged_dot`` gets on this backend,
+    recorded like every kernel switch (``ops/routes.py``)."""
+    from deepspeed_tpu.ops import routes
+
+    return routes.take(GROUPED_MATMUL, pallas="ragged-dot-mosaic",
+                       xla="ragged-dot-xla")
+
+
+def topk_routing(logits: jax.Array, k: int, normalize_weights: bool
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(experts [G, k] int32, weights [G, k] f32, l_aux)`` of a float32
+    softmax router: the k largest, ties to the lower expert as the
+    iterative argmax of :func:`topkgating` breaks them; their softmax
+    values as they are, or renormalized over the k."""
+    logits = logits.astype(jnp.float32)
+    E = logits.shape[-1]
+    gates = jax.nn.softmax(logits, axis=-1)
+    _, experts = jax.lax.top_k(logits, k)
+    weights = jnp.take_along_axis(gates, experts, axis=-1)
+    if normalize_weights:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True),
+            jnp.finfo(jnp.float32).eps)
+    ce = jnp.mean(jax.nn.one_hot(experts[:, 0], E, dtype=jnp.float32), 0)
+    l_aux = jnp.sum(jnp.mean(gates, axis=0) * ce) * E
+    return experts.astype(jnp.int32), weights, l_aux
+
+
+def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
+                num_experts: int, expert_ffn, live: Optional[jax.Array]
+                ) -> Tuple[jax.Array, jax.Array]:
+    """Dropless routed FFN.  ``x [G, M]``; ``experts``/``weights [G, k]``;
+    ``expert_ffn(rows [k*G, M], group_sizes [E]) -> [k*G, M]`` runs the
+    grouped matmuls over expert-sorted rows; ``live [G]`` (or None: all)
+    marks the rows that are tokens — padding of a tick and inactive decode
+    slots take no expert row and come back as zeros.
+
+    Returns ``(y [G, M], counts int32[3])``: token copies routed, experts
+    with at least one row, rows of the fullest expert.
+    """
+    G, k = experts.shape
+    E = num_experts
+    ec = experts.reshape(-1)                          # copy = token * k + j
+    if live is not None:
+        # a dead row's copies sort past every expert and join no group
+        ec = jnp.where(jnp.repeat(live, k), ec, E)
+    order = jnp.argsort(ec, stable=True)              # sorted pos -> copy
+    group_sizes = jnp.sum(jax.nn.one_hot(ec, E, dtype=jnp.int32), axis=0)
+    out = expert_ffn(jnp.take(x, order // k, axis=0), group_sizes)
+    # combine by gather: copy -> its row of the sorted buffer
+    rows = jnp.take(out, jnp.argsort(order), axis=0).reshape(G, k, -1)
+    y = jnp.sum(weights.astype(out.dtype)[..., None] * rows, axis=1)
+    if live is not None:
+        # rows past the last group are whatever the kernel left there
+        y = jnp.where(live[:, None], y, 0)
+    counts = jnp.stack([jnp.sum(group_sizes),
+                        jnp.sum((group_sizes > 0).astype(jnp.int32)),
+                        jnp.max(group_sizes)])
+    return y, counts

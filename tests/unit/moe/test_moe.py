@@ -169,3 +169,72 @@ def test_capacity_formula():
                     min_capacity=4, k=2) == 16
     assert capacity(num_tokens=8, num_experts=8, capacity_factor=1.0,
                     min_capacity=4) == 4
+
+
+# -- the dropless grouped path (serving) -----------------------------------
+
+def _pair(activation, normalize, impl_kw):
+    return MoE(hidden_size=32, num_experts=8, intermediate_size=16, k=2,
+               activation=activation, dtype=jnp.float32,
+               expert_parallel=False, normalize_weights=normalize,
+               **impl_kw)
+
+
+@pytest.mark.parametrize("normalize", [False, True],
+                         ids=["raw-gates", "renormalized"])
+@pytest.mark.parametrize("activation", ["swiglu", "gelu"])
+def test_grouped_matches_the_einsum_path_that_drops_nothing(activation,
+                                                            normalize):
+    """Forward of the grouped (``ragged_dot``) path against the dense
+    one-hot oracle with ``drop_tokens=False``, with one expert that no
+    token takes (an empty group between full ones)."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 32))
+    oracle = _pair(activation, normalize,
+                   dict(dispatch_impl="einsum", drop_tokens=False))
+    params = oracle.init(jax.random.PRNGKey(1), x)
+    # expert 3 is never among the two largest
+    gate = params["params"]["gate"].at[:, 3].set(0.0)
+    x = x.at[..., 0].set(4.0)
+    gate = gate.at[0].set(jnp.where(jnp.arange(8) == 3, -50.0, gate[0]))
+    params = {"params": {**params["params"], "gate": gate}}
+    want, aux_want = oracle.apply(params, x)
+    grouped = _pair(activation, normalize, dict(dispatch_impl="grouped"))
+    (got, aux), stats = grouped.apply(params, x, mutable=["moe_stats"])
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    np.testing.assert_allclose(aux, aux_want, rtol=1e-6)
+    rows, hit, load = (int(v) for v in stats["moe_stats"]["counts"][0])
+    assert rows == 2 * 18 and hit <= 7 and load >= 36 / 7
+
+
+def test_grouped_dead_rows_take_no_expert_row():
+    """Rows that are no token (padding of a tick, inactive decode slots)
+    come back as zeros, count in no counter and change no live row."""
+    layer = _pair("swiglu", False, dict(dispatch_impl="grouped"))
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 12, 32))
+    params = layer.init(jax.random.PRNGKey(3), x)
+    live = jnp.arange(12) < 5
+    (y, _), stats = layer.apply(params, x, live=live[None],
+                                mutable=["moe_stats"])
+    (y5, _), _ = layer.apply(params, x[:, :5], mutable=["moe_stats"])
+    np.testing.assert_allclose(y[0, :5], y5[0], atol=1e-6)
+    assert not np.asarray(y[0, 5:]).any()
+    assert int(stats["moe_stats"]["counts"][0][0]) == 2 * 5
+    # nobody asked for the counts: nothing is returned, nothing at init
+    assert "moe_stats" not in params
+    assert isinstance(layer.apply(params, x), tuple)
+
+
+def test_serving_configs_are_dropless():
+    """``decode`` or ``paged_decode`` on the config is what selects the
+    grouped path, for every expert family; training keeps its own."""
+    import dataclasses
+
+    from deepspeed_tpu.models import mixtral, olmoe, qwen2_moe
+
+    for mod, preset in ((mixtral, "tinymixtral"),
+                        (qwen2_moe, "tinyqwen2moe"), (olmoe, "tinyolmoe")):
+        cfg = mod.get_config(preset)
+        assert mixtral.moe_layer(cfg, "m").dispatch_impl == "auto"
+        for flag in ("decode", "paged_decode"):
+            serving = dataclasses.replace(cfg, **{flag: True})
+            assert mixtral.moe_layer(serving, "m").dispatch_impl == "grouped"

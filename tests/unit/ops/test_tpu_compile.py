@@ -118,21 +118,55 @@ META = [((SEQS,), jnp.int32), ((SEQS, PAGES_PER_SEQ), jnp.int32),
         ((SEQS + 1,), jnp.int32), ((1,), jnp.int32)]
 
 
-def test_upstream_ragged_paged_kernel_bf16_pages(chip_compile):
+# (query heads, KV heads): Mistral-7B's GQA and OLMoE-1B-7B's MHA, whose
+# pages are twice as wide
+RPA_HEADS = [pytest.param((32, 8), id="mistral-8kv"),
+             pytest.param((16, 16), id="olmoe-16kv")]
+
+
+@pytest.mark.parametrize("heads", RPA_HEADS)
+def test_upstream_ragged_paged_kernel_bf16_pages(chip_compile, heads):
     """With the block sizes ``inference/paged.py`` hands it: its own
     tuned-table lookup asks the ATTACHED device for its generation, and
-    what it answers for this shape does not fit VMEM."""
+    what it answers for this shape does not fit VMEM.  The KV block
+    follows from the KV heads and the page size."""
     from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
         kernel as rpa
 
+    h, hkv = heads
+    kv_pages = paged.rpa_kv_pages_per_block(hkv, PAGE, PAGES_PER_SEQ)
+    assert kv_pages * PAGE * hkv == 512 * 8       # Mistral's: 512 x 32
     chip_compile(
         functools.partial(
             rpa.ragged_paged_attention, sm_scale=D ** -0.5,
-            sliding_window=WINDOW,
-            num_kv_pages_per_block=paged._RPA_KV_BLOCK_TOKENS // PAGE,
+            sliding_window=WINDOW, num_kv_pages_per_block=kv_pages,
             num_queries_per_block=paged._RPA_QUERY_BLOCK),
-        ((T, H, D), jnp.bfloat16),
-        ((POOL, PAGE, 2 * HKV, D), jnp.bfloat16), *META)
+        ((T, h, D), jnp.bfloat16),
+        ((POOL, PAGE, 2 * hkv, D), jnp.bfloat16), *META)
+
+
+def test_kv_block_never_under_a_page_nor_over_512_tokens():
+    assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
+    assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4
+    assert paged.rpa_kv_pages_per_block(2, 64, 64) == 8
+    assert paged.rpa_kv_pages_per_block(128, 64, 64) == 1
+    assert paged.rpa_kv_pages_per_block(8, 16, 4) == 4
+
+
+@pytest.mark.parametrize("rows", [32 * 8, (32 + 512) * 8],
+                         ids=["decode-tick", "fused-tick"])
+def test_grouped_expert_matmul_is_a_kernel_not_a_dense_expansion(
+        chip_compile, rows):
+    """OLMoE's expert bank (64 x 2048 x 1024) against the routed copies
+    of a decode tick and of a fused tick: the v5e compiler lowers
+    ``jax.lax.ragged_dot`` to a Mosaic kernel, with no operand of
+    experts x rows x width size."""
+    E, M, I = 64, 2048, 1024
+    text = chip_compile(
+        jax.lax.ragged_dot, ((rows, M), jnp.bfloat16),
+        ((E, M, I), jnp.bfloat16), ((E,), jnp.int32))
+    assert "ragged-dot" in text
+    assert f"[{E},{rows}," not in text and f"[{rows},{E}," not in text
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.int8, jnp.float8_e4m3fn],
