@@ -1424,10 +1424,10 @@ class RaggedInferenceEngineV2:
         out["kernels"] = {"paged_attention": route}
         st = self.host_stats
         if st.moe_rows:
-            from deepspeed_tpu.moe.sharded_moe import (GROUPED_MATMUL,
-                                                       grouped_matmul_route)
-
-            out["kernels"][GROUPED_MATMUL] = grouped_matmul_route()
+            from deepspeed_tpu.moe.sharded_moe import GROUPED_MATMUL
+            from deepspeed_tpu.ops import routes
+            # its two keys, as the trace of this process's programs left them
+            out["kernels"].update(routes.taken(prefix=GROUPED_MATMUL))
             out["moe"] = {"rows": st.moe_rows,
                           "experts_hit": st.moe_experts_hit,
                           "load_max": st.moe_load_max}

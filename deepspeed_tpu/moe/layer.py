@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 
+from deepspeed_tpu.ops.grouped_matmul import grouped_matmul
 from deepspeed_tpu.moe.sharded_moe import (grouped_matmul_route, grouped_moe,
                                            moe_combine, moe_combine_gather,
                                            moe_dispatch, moe_dispatch_gather,
@@ -104,9 +105,10 @@ class MoE(nn.Module):
     # scatter lowering), CPU/debug only.
     # "grouped" is the DROPLESS path every serving config takes (the
     # model blocks select it from ``decode``/``paged_decode``): expert-
-    # sorted rows with no padding into ``jax.lax.ragged_dot`` over the
-    # stacked banks, combine by gather — no capacity, so a row's output
-    # depends on no other row.  Forward only.
+    # sorted rows with no padding into a grouped matmul over the stacked
+    # banks (``ops/grouped_matmul.py``: a tiled Pallas kernel on one TPU
+    # device, ``jax.lax.ragged_dot`` elsewhere), combine by gather — no
+    # capacity, so a row's output depends on no other row.  Forward only.
     # "auto" (default) resolves to "sorted" on single-device topologies
     # and "alltoall" on multi-device meshes (falling back to "einsum"
     # when the expert count does not divide over the expert axis).
@@ -194,7 +196,7 @@ class MoE(nn.Module):
         bank."""
         dt = self.dtype
         grouped_matmul_route()
-        dot = lambda a, b: jax.lax.ragged_dot(a, b.astype(dt), group_sizes)
+        dot = lambda a, b: grouped_matmul(a, b.astype(dt), group_sizes)
         if self.activation == "swiglu":
             return dot(nn.silu(dot(rows, w["w1"])) * dot(rows, w["w3"]),
                        w["w2"])

@@ -350,18 +350,26 @@ sorted_combine.defvjp(_sc_fwd, _sc_bwd)
 # served token's logits may depend on no other row of its tick, so serving
 # takes none of them.  Here the routed copies are sorted by expert into ONE
 # ``[k*G, M]`` buffer with no padding between experts, and each expert's
-# contiguous run of rows meets its weight in a grouped matmul
-# (``jax.lax.ragged_dot``, the reference's ``moe_gemm``): the v5e compiler
-# lowers it to a Mosaic kernel of its own (``ragged-dot`` custom calls, no
-# dense ``[E, G, M]`` expansion), the CPU backend to plain XLA.  Work and
-# memory are linear in the routed copies; no capacity, no ``[G, E, C]``.
+# contiguous run of rows meets its weight in a grouped matmul (the
+# reference's ``moe_gemm``): ``ops/grouped_matmul.py``, a tiled Pallas
+# kernel (megablox ``gmm``'s forward) on one TPU device and its twin
+# ``jax.lax.ragged_dot`` elsewhere, which the v5e compiler lowers to a
+# Mosaic kernel of its own (``ragged-dot`` custom calls; the Pallas kernel
+# takes about three quarters of their time, PERF.md §6 PR 29) and the CPU
+# backend to plain XLA; neither builds a dense ``[E, G, M]``
+# expansion.  Work and memory are linear in the routed copies; no
+# capacity, no ``[G, E, C]``.
 
 GROUPED_MATMUL = "moe_grouped_matmul"
 
 
 def grouped_matmul_route() -> str:
-    """Which implementation ``jax.lax.ragged_dot`` gets on this backend,
-    recorded like every kernel switch (``ops/routes.py``)."""
+    """Whether the grouped matmul runs as a Mosaic kernel or as XLA's
+    expansion: the backend's answer alone, recorded like every kernel
+    switch (``ops/routes.py``).  WHICH Mosaic kernel (the Pallas one and
+    its tiles, or the compiler's lowering of ``ragged_dot`` where a call
+    fell back) is the second key's to say
+    (``ops/grouped_matmul.py:KERNEL``)."""
     from deepspeed_tpu.ops import routes
 
     return routes.take(GROUPED_MATMUL, pallas="ragged-dot-mosaic",

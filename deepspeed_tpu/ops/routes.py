@@ -17,7 +17,8 @@ import jax
 XLA = "xla"
 
 # kernel name -> route of its most recent trace (a route is chosen while
-# jit traces, once per compiled program)
+# jit traces, once per compiled program), or every distinct route of a
+# switch that is asked once a call (``take(every=True)``)
 _taken: Dict[str, str] = {}
 
 
@@ -27,20 +28,26 @@ def on_tpu() -> bool:
 
 
 def take(kernel: str, *, eligible: bool = True, interpret: bool = False,
-         pallas: str = "pallas", xla: str = XLA) -> str:
+         pallas: str = "pallas", xla: str = XLA, every: bool = False
+         ) -> str:
     """Choose and record ``kernel``'s route: ``pallas`` on a TPU backend
     (or under the Pallas interpreter, marked ``-interpret``) when the
-    call is ``eligible`` for the kernel, else ``xla``."""
+    call is ``eligible`` for the kernel, else ``xla``.  A switch whose
+    answer turns on the shapes of the call, and is asked several times a
+    program, records ``every`` distinct answer (joined by ``+``, in the
+    order first taken) and not the last alone."""
     if eligible and on_tpu():
         route = pallas
     elif eligible and interpret:
         route = pallas + "-interpret"
     else:
         route = xla
-    _taken[kernel] = route
+    seen = _taken[kernel].split("+") if every and kernel in _taken else []
+    _taken[kernel] = "+".join(seen if route in seen else seen + [route])
     return route
 
 
-def taken() -> Dict[str, str]:
-    """Routes recorded so far in this process, by kernel name."""
-    return dict(_taken)
+def taken(prefix: str = "") -> Dict[str, str]:
+    """Routes recorded so far in this process, by kernel name: all, or
+    those of the kernels whose name starts with ``prefix``."""
+    return {k: v for k, v in _taken.items() if k.startswith(prefix)}

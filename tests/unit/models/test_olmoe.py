@@ -113,8 +113,9 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(served):
     moe = eng.serving_stages()["moe"]
     # 2 layers x 2 experts a token: only live rows were routed
     assert moe["rows"] == 2 * 2 * (21 + 9)
-    assert eng.serving_stages()["kernels"]["moe_grouped_matmul"] == \
-        "ragged-dot-xla"
+    kernels = eng.serving_stages()["kernels"]
+    assert kernels["moe_grouped_matmul"] == "ragged-dot-xla"
+    assert kernels["moe_grouped_matmul_kernel"] == "ragged-dot"
 
 
 def _first_tick_logits(eng, prompts, which):
