@@ -292,11 +292,11 @@ def _logits_parity(plan: Plan, eng, model, prompt: List[int]) -> float:
         cu[0] = 0
         rows = np.zeros((eng.max_seqs,), np.int32)
         rows[0] = k - 1
-        args = [eng._upload(a) for a in (
+        args = eng.upload_all((
             ids[None], pos[None], kv_lens, pages, cu,
-            np.asarray([1], np.int32), dest, rows)]
-        sel, eng.cache, _ = eng._fused_step_fn()(eng.params, eng.cache,
-                                                 *args)
+            np.asarray([1], np.int32), dest, rows))
+        sel, eng.kv.cache, _ = eng.fused_step()(eng.params, eng.kv.cache,
+                                                *args)
         return np.asarray(sel[0], np.float32)
 
     at_last = tick(prompt, 0)

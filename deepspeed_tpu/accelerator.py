@@ -24,7 +24,7 @@ import numpy as np
 
 
 def require_accelerator(cpu_requested: bool = False) -> bool:
-    """What the entry points (``bench.py``, ``examples/``) call before
+    """What the entry points (``examples/``) call before
     any work: True on an accelerator.  On the CPU it is False only when
     the caller asked for the CPU in so many words — its own switch
     (``cpu_requested``, e.g. ``--smoke``) or ``JAX_PLATFORMS=cpu`` in the

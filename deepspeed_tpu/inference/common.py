@@ -36,9 +36,8 @@ class _StageSpan(Span):
 class HostStageStats:
     """Per-dispatch host-path breakdown for the serving engines.
 
-    The serving wall/device throughput gap lives entirely on the host
-    (BENCH_MATRIX ragged: 23.3k device vs 295 wall tok/s), so both
-    engines bracket every hot-loop stage:
+    What a serving step costs beyond its device time is spent on the
+    host, so both engines bracket every hot-loop stage:
 
     - ``plan``:     host-side numpy scheduling (admission, SplitFuse
                     packing, page growth, run-ahead projection)

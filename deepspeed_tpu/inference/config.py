@@ -9,7 +9,7 @@ so there is nothing to inject.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, ClassVar, Dict, List, Optional, Union
 
 from pydantic import Field, model_validator
 
@@ -34,7 +34,33 @@ class QuantConfig(ConfigModel):
     group_size: int = 128
 
 
-class SpeculationConfig(ConfigModel):
+class V2Subtree(ConfigModel):
+    """Base of the ``v2`` option subtrees that
+    :class:`~deepspeed_tpu.inference.v2.RaggedInferenceEngineV2` also
+    takes as a keyword in a short form."""
+
+    #: the field a bare value of that type sets (``True`` -> enabled)
+    _short: ClassVar[Dict[type, str]] = {}
+
+    @classmethod
+    def coerce(cls, value: Any) -> "V2Subtree":
+        """``None`` (the defaults) | an instance | a bare value of a type
+        in ``_short`` | a dict of fields, which implies ``enabled=True``
+        where the subtree has the switch and the dict does not say."""
+        if value is None:
+            return cls()
+        if isinstance(value, cls):
+            return value
+        if type(value) in cls._short:
+            return cls(**{cls._short[type(value)]: value})
+        if isinstance(value, dict):
+            return cls(**({"enabled": True, **value}
+                          if "enabled" in cls.model_fields else value))
+        raise TypeError(
+            f"{cls.__name__}: cannot build from {type(value).__name__}")
+
+
+class SpeculationConfig(V2Subtree):
     """``v2.speculation`` subtree: speculative decoding on the ragged
     engine's decode-block path.
 
@@ -47,6 +73,8 @@ class SpeculationConfig(ConfigModel):
     ``k+1`` positions in ONE ragged dispatch, so one weight pass
     amortizes over up to ``k+1`` emitted tokens.
     ``ngram``: the lookup n-gram length for ``mode=ngram``."""
+
+    _short: ClassVar[Dict[type, str]] = {str: "mode"}
 
     mode: str = "off"
     k: int = 4
@@ -66,7 +94,7 @@ class SpeculationConfig(ConfigModel):
         return self
 
 
-class KVTieringConfig(ConfigModel):
+class KVTieringConfig(V2Subtree):
     """``v2.kv_tiering`` subtree: host-RAM + NVMe spill tiers for the
     paged-KV pool.
 
@@ -165,7 +193,7 @@ class KVTieringConfig(ConfigModel):
         return self
 
 
-class PrefixCacheConfig(ConfigModel):
+class PrefixCacheConfig(V2Subtree):
     """``v2.prefix_cache`` subtree: cross-request KV sharing over the
     paged pool.
 
@@ -185,6 +213,8 @@ class PrefixCacheConfig(ConfigModel):
     not guaranteed identical to the fused prefill program's, so
     bit-parity vs cache-off is only contracted while this is off."""
 
+    _short: ClassVar[Dict[type, str]] = {bool: "enabled"}
+
     enabled: bool = False
     max_index_entries: int = 1024
     min_match_pages: int = 1
@@ -199,7 +229,7 @@ class PrefixCacheConfig(ConfigModel):
         return self
 
 
-class ControlConfig(ConfigModel):
+class ControlConfig(V2Subtree):
     """``v2.control`` subtree: the closed-loop autotuner.
 
     ``enabled`` arms the online controller on the engine's host loop
@@ -215,6 +245,8 @@ class ControlConfig(ConfigModel):
     at a per-host profile file or directory that seeds knob values at
     construction (fingerprint-checked; a foreign host's profile is
     ignored)."""
+
+    _short: ClassVar[Dict[type, str]] = {bool: "enabled"}
 
     enabled: bool = False
     interval: int = 8

@@ -36,11 +36,11 @@ Exactly two query-shape families exist (prefill ticks take one page of
 prompt tokens, decode ticks take one token), so the compiled-program
 count is bounded and the steady state compiles nothing.
 
-The driver owns no device state: pages live in the engine's pool, the
-parked middle lives in the engine's :class:`TieredKVStore` under
-``mid-<uid>-<g>`` keys, and sampling reuses the engine's position-keyed
-sampler (seeded sampling is reproducible against a fully-resident
-control).
+The driver owns no device state: pages and the parked middle (under
+``mid-<uid>-<g>`` keys) belong to the engine's
+:class:`~deepspeed_tpu.inference.v2.kv_state.KVState`, which moves them, and
+sampling reuses the engine's position-keyed sampler (seeded sampling is
+reproducible against a fully-resident control).
 """
 from __future__ import annotations
 
@@ -86,8 +86,9 @@ class LongContextDriver:
         from deepspeed_tpu.models.llama import LlamaForCausalLM
 
         eng = engine
-        t = eng._tier_cfg
-        assert eng.tiering is not None and t.long_context, (
+        self.kv = eng.kv
+        t = self.kv.tier_cfg
+        assert self.kv.tiering is not None and t.long_context, (
             "LongContextDriver needs kv_tiering.long_context=True")
         if eng.tp > 1:
             raise NotImplementedError(
@@ -95,12 +96,12 @@ class LongContextDriver:
                 "tensor-parallel serving yet (the chunked scan threads "
                 "an explicit attention carry the TP shard_map path "
                 "does not)")
-        if eng._wq:
+        if eng.param_form["wq"]:
             raise NotImplementedError(
                 "long-context partial residency does not compose with "
                 "quantize_weights yet — the per-layer dispatch applies "
                 "raw param subtrees")
-        assert not eng._unroll_params, (
+        assert not eng.param_form["unroll"], (
             "long-context needs unrolled layers_<i> params (the engine "
             "unrolls scan params itself in-jit; pass unrolled params)")
         assert isinstance(eng.model, LlamaForCausalLM), (
@@ -125,7 +126,7 @@ class LongContextDriver:
         # in the cache's tree_leaves order (spill payloads travel as
         # flat leaf lists; dict keys sort "layers_10" before "layers_2")
         self._leaf_idx: Dict[int, List[Optional[int]]] = {}
-        flat, _ = jax.tree_util.tree_flatten_with_path(eng.cache)
+        flat, _ = jax.tree_util.tree_flatten_with_path(self.kv.cache)
         for i, (path, _leaf) in enumerate(flat):
             keys = [str(getattr(k, "key", k)) for k in path]
             layer = next((k for k in keys if k.startswith("layers_")),
@@ -144,21 +145,10 @@ class LongContextDriver:
 
     def _window(self) -> int:
         # read fresh each tick: kv.window_pages is an online knob
-        return max(int(self.eng._tier_cfg.window_pages), 1)
+        return max(int(self.kv.tier_cfg.window_pages), 1)
 
     def _key(self, r, g: int) -> str:
         return f"mid-{r.uid}-{g}"
-
-    def _ensure_col(self, r, col: int) -> bool:
-        eng = self.eng
-        if eng.page_table[r.slot, col] >= 0:
-            return True
-        eng._reclaim_for(1)
-        if eng.allocator.free_pages < 1:
-            return False
-        page = eng.allocator.grow(r.slot, 1)[0]
-        eng.page_table[r.slot, col] = page
-        return True
 
     def _grow(self, r, lo: int, hi: int) -> bool:
         """Pages for write positions ``[lo, hi)`` — always at/past the
@@ -166,7 +156,7 @@ class LongContextDriver:
         eng = self.eng
         for col in range(lo // eng.page_size,
                          (hi - 1) // eng.page_size + 1):
-            if not self._ensure_col(r, col):
+            if not self.kv.grow_col(r.slot, col):
                 others = any(s is not None and s is not r and not s.done
                              for s in eng.slots)
                 if others or eng.waiting:
@@ -196,20 +186,8 @@ class LongContextDriver:
                 return                      # group not fully written yet
             if end > frontier_col - window:
                 return                      # inside the resident window
-            if not eng.tiering.can_spill(self.chunk):
+            if not self.kv.park(r.slot, col0, end, self._key(r, g)):
                 return                      # tiers full: stay resident
-            gather, _ = eng._tier_jits()
-            idx = np.zeros((eng.pages_per_seq,), np.int32)  # pad: trash
-            idx[:self.chunk] = eng.page_table[r.slot, col0:end]
-            rows = jax.device_get(gather(eng.cache, jnp.asarray(idx)))
-            eng.tiering.spill(
-                self._key(r, g),
-                [np.asarray(leaf[:self.chunk]) for leaf in
-                 jax.tree_util.tree_leaves(rows)],
-                self.chunk)
-            pages = [int(p) for p in eng.page_table[r.slot, col0:end]]
-            eng.allocator.release_pages(r.slot, pages)
-            eng.page_table[r.slot, col0:end] = -1
             r.lc_parked += 1
             if trace.enabled:
                 trace.event("lc_park", cat="kv", uid=r.uid, group=g,
@@ -219,7 +197,7 @@ class LongContextDriver:
     def residency(self, r) -> Dict[str, int]:
         """Resident vs parked page split for ``r`` (bench/monitor
         surface)."""
-        resident = int((self.eng.page_table[r.slot] >= 0).sum())
+        resident = int((self.kv.page_table[r.slot] >= 0).sum())
         return {"resident_pages": resident,
                 "parked_pages": r.lc_parked * self.chunk}
 
@@ -320,17 +298,17 @@ class LongContextDriver:
         return self._fns[key]
 
     def _layer_cache(self, l: int):
-        return self.eng.cache["model"][f"layers_{l}"]
+        return self.kv.cache["model"][f"layers_{l}"]
 
     def _set_layer_cache(self, l: int, sub) -> None:
-        c = self.eng.cache
+        c = self.kv.cache
         name = f"layers_{l}"
         if isinstance(c, dict):
             m = dict(c["model"])
             m[name] = sub
-            self.eng.cache = {**c, "model": m}
+            self.kv.cache = {**c, "model": m}
         else:                                  # flax FrozenDict
-            self.eng.cache = c.copy(
+            self.kv.cache = c.copy(
                 {"model": c["model"].copy({name: sub})})
 
     # -- the tick ---------------------------------------------------------
@@ -355,7 +333,7 @@ class LongContextDriver:
             take = 1
             Tq = 1
             written = lo
-            tokens = np.asarray([eng._last_tokens[r.slot]], np.int32)
+            tokens = np.asarray([eng.last_tokens[r.slot]], np.int32)
         hi = lo + take                        # tokens written after tick
         kv_len = hi
         if not self._grow(r, lo, hi):
@@ -380,25 +358,25 @@ class LongContextDriver:
         if n_parked:
             # read-ahead for THIS tick's peeks, bounded by the staging
             # ring; the tail re-issue below overlaps the NEXT tick
-            eng.tiering.prefetch([self._key(r, g) for g in groups])
+            self.kv.tiering.prefetch([self._key(r, g) for g in groups])
         staged: Dict[int, List[np.ndarray]] = {
-            g: eng.tiering.peek(self._key(r, g)) for g in groups}
+            g: self.kv.tiering.peek(self._key(r, g)) for g in groups}
 
         params = eng.params
         x = self._embed_fn(Tq)(params["model"]["embed_tokens"],
-                               eng._upload(tokens)[None])
-        qpos_dev = eng._upload(qpos)
-        pos_dev = eng._upload(positions)
-        kv_lens = eng._upload(np.asarray([kv_len], np.int32))
-        page_indices = eng._upload(eng.page_table[r.slot][None])
-        cu_q_lens = eng._upload(np.asarray([0, take], np.int32))
-        num_seqs = eng._upload(np.asarray([1], np.int32))
+                               eng.upload(tokens)[None])
+        qpos_dev = eng.upload(qpos)
+        pos_dev = eng.upload(positions)
+        kv_lens = eng.upload(np.asarray([kv_len], np.int32))
+        page_indices = eng.upload(self.kv.page_table[r.slot][None])
+        cu_q_lens = eng.upload(np.asarray([0, take], np.int32))
+        num_seqs = eng.upload(np.asarray([1], np.int32))
         dest = np.zeros((Tq,), np.int32)      # pad rows -> trash page 0
         pos_r = np.arange(lo, hi)
-        pg = eng.page_table[r.slot, pos_r // page]
+        pg = self.kv.page_table[r.slot, pos_r // page]
         assert (pg > 0).all(), "write into unallocated page"
         dest[:take] = pg * page + pos_r % page
-        dest_dev = eng._upload(dest)
+        dest_dev = eng.upload(dest)
 
         chunk_fn = self._chunk_fn(Tq)
         for l in range(self.L):
@@ -407,9 +385,9 @@ class LongContextDriver:
             for g in groups:
                 arrs = staged[g]
                 kv_i, sc_i = self._leaf_idx[l]
-                staged_kv = eng._upload(
+                staged_kv = eng.upload(
                     arrs[kv_i].reshape(self.R, -1, self.D))
-                scales = (eng._upload(
+                scales = (eng.upload(
                     arrs[sc_i].reshape(self.R, -1))
                     if self._quant else None)
                 c = carry if carry is not None else self._neutral(Tq)
@@ -445,11 +423,11 @@ class LongContextDriver:
             sel = self._head_fn(Tq)(params["model"]["norm"],
                                     params["lm_head"], x,
                                     jnp.int32(take - 1))
-            produced = eng._sample(sel, [(r, 0, True)])
+            produced = eng.sample(sel, [(r, 0, True)])
         # read-ahead for the NEXT tick: decode revisits the same groups,
         # so the NVMe->host copies overlap host-side sampling/planning
         if n_parked:
-            eng.tiering.prefetch(
+            self.kv.tiering.prefetch(
                 [self._key(r, g) for g in groups]
                 [:max(eng.prefetch_lookahead, 1)])
         return produced

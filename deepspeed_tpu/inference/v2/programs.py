@@ -1,0 +1,605 @@
+"""The ragged engine's compiled programs, as functions of what they close
+over.
+
+Every program the serving loop dispatches is built here from a model module
+and a few static sizes, so anything can lower or call one without an engine:
+the engine caches what these return, ``chip_smoke.py`` checks logits through
+the fused tick, a rehearsal can compile one for a described chip.
+
+- :func:`build_fused_step` — ONE ``[1, T]`` SplitFuse tick (a decode token
+  for every ready sequence plus prompt chunks; raggedness lives in int32
+  metadata).  XLA program ``jit_ragged_fused_step``.
+- :func:`build_decode_block` — ``block`` decode ticks in a ``lax.scan`` with
+  on-device, position-keyed sampling.  ``jit_ragged_decode_block``.
+- :func:`build_spec_block` — the speculative draft + verify + accept block
+  (``jit_spec_verify_block``); :func:`build_draft_prefill` catches the draft
+  model's pool up (``jit_draft_prefill``).
+
+The names are how the profiler's trace and the benchmark's readers find
+device time per program: they do not change.  Beside the programs live what
+they are built from: the serving form of a model and its weights
+(:func:`serving_config`, :func:`prepare_params`, :func:`place_params`,
+:func:`prepare_draft`), the zeroed page pools (:func:`init_cache`,
+:func:`cache_sharding`, :func:`paged_kv_page_bytes`) and the folding of the
+expert layers' counts.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.inference.common import (logits_of, normalize_params,
+                                            unroll_scan_params)
+from deepspeed_tpu.inference.paged import pages_for
+from deepspeed_tpu.inference.quantization import (dequantize_param_tree,
+                                                  quantize_param_tree)
+from deepspeed_tpu.inference.sampling import (filter_logits_batched,
+                                              position_keys,
+                                              sample_logits_batched,
+                                              speculative_verify)
+from deepspeed_tpu.utils.logging import log_dist
+
+KV_CACHE_DTYPES = ("none", "int8", "fp8", "fp8_e4m3")
+
+
+def fold_moe_counts(counts: jax.Array) -> jax.Array:
+    """``[n, 3]`` per-layer (or per-tick) expert-layer counts -> ``[3]``:
+    token copies routed and experts hit add up, the fullest expert's rows
+    take the largest."""
+    return jnp.concatenate([jnp.sum(counts[:, :2], axis=0),
+                            jnp.max(counts[:, 2:], axis=0)])
+
+
+def moe_counts(variables) -> Optional[jax.Array]:
+    """One apply's ``moe_stats`` collection (each dropless expert layer
+    sows ``[rows, experts_hit, load_max]``) folded over its layers; None
+    for a model without expert layers, so its programs gain no output."""
+    leaves = jax.tree_util.tree_leaves(variables.get("moe_stats", {}))
+    return fold_moe_counts(jnp.stack(leaves)) if leaves else None
+
+
+def serving_config(mcfg, *, max_seq_len: int, page_size: int,
+                   num_pages: int, tensor_parallel: bool,
+                   kv_cache_dtype: str):
+    """A zoo model's config in its serving form: paged ragged decode over
+    a pool of ``num_pages`` pages, layers unrolled (a scan over layers
+    restacks the mutable cache every step)."""
+    assert kv_cache_dtype in KV_CACHE_DTYPES, (
+        f"kv_cache_dtype must be {'|'.join(KV_CACHE_DTYPES)}, got "
+        f"{kv_cache_dtype!r}")
+    return dataclasses.replace(
+        mcfg, decode=True, ragged_decode=False, paged_decode=True,
+        max_cache_len=max_seq_len, scan_layers=False,
+        kv_page_size=page_size, kv_num_pages=num_pages,
+        tensor_parallel=tensor_parallel, kv_cache_dtype=kv_cache_dtype)
+
+
+def paged_kv_page_bytes(model, mcfg, page_size: int,
+                        kv_cache_dtype: str) -> int:
+    """Exact device bytes ONE KV page costs across every cache leaf —
+    for a quantized pool that is the 1-byte payload page plus its fp32
+    scale rows.  Measured by an eval_shape probe of a 2-page pool
+    rather than guessed from the layer count, so any model-zoo cache
+    layout (extra leaves, fused layers) is accounted automatically."""
+    probe = type(model)(serving_config(
+        mcfg, max_seq_len=2 * page_size, page_size=page_size, num_pages=2,
+        tensor_parallel=False, kv_cache_dtype=kv_cache_dtype))
+    meta = {"kv_lens": jnp.zeros((1,), jnp.int32),
+            "page_indices": jnp.full((1, 2), -1, jnp.int32),
+            "cu_q_lens": jnp.zeros((2,), jnp.int32),
+            "num_seqs": jnp.zeros((1,), jnp.int32),
+            "new_kv_dest": jnp.zeros((4,), jnp.int32)}
+    ids = jnp.zeros((1, 4), jnp.int32)
+    pos = jnp.zeros((1, 4), jnp.int32)
+    shapes = jax.eval_shape(lambda: probe.init(
+        jax.random.PRNGKey(0), ids, positions=pos, ragged_meta=meta))
+    total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+                for leaf in jax.tree_util.tree_leaves(shapes["cache"]))
+    assert total % 2 == 0, total
+    return total // 2
+
+
+def prepare_params(model, params, cfg, quantize_weights: Optional[str], *,
+                   tp: int = 1):
+    """The weights as the programs read them.  Returns ``(cfg, params,
+    form)``: ``cfg`` is ``cfg`` or its w8a8 variant, ``form`` the
+    ``unroll``/``wq``/``native`` keywords every ``build_*`` takes.
+
+    ``quantize_weights``: None | "int8" | "fp8" | "fp6" | "w8a8" —
+    weights persist quantized in HBM and dequantize in-jit at use
+    (reference FP6-LLM cuda_linear / int8 quantized inference); "w8a8"
+    (explicit opt-in: it quantizes ACTIVATIONS per row too, so numerics
+    differ from weight-only "int8") runs the NATIVE path on models whose
+    Dense layers consume quantized kernels: int8 stays on the per-tick
+    read path (decode is weight-bandwidth-bound — tree-level dequant
+    reads 2x the bytes), dotted on the MXU's int8 path."""
+    mcfg = model.config
+    unroll = bool(getattr(mcfg, "scan_layers", False))
+    params = normalize_params(
+        model, params,
+        plain_model=type(model)(dataclasses.replace(mcfg, decode=False)))
+    native = False
+    if quantize_weights is not None:
+        assert tp <= 1, (
+            "quantize_weights does not compose with tensor-parallel "
+            "serving yet — quantized leaves carry their own "
+            "group-scale layout")
+        from deepspeed_tpu.parallel import tensor_parallel as tp_lib
+
+        if quantize_weights == "w8a8":
+            assert getattr(type(model), "w8a8_native", False), (
+                f"quantize_weights='w8a8' needs a model whose Dense "
+                f"layers consume w8a8 kernels natively (the Llama "
+                f"family: llama/mistral/qwen2); "
+                f"{type(model).__name__} does not — use weight-only "
+                f"'int8' instead")
+            native = True
+            cfg = dataclasses.replace(cfg, weight_quant="w8a8")
+            if unroll:
+                # unroll scan-stacked [L, ...] kernels NOW: the
+                # per-channel w8a8 format is 2-D-kernel only, so a
+                # stacked tree would silently fall back to the
+                # dequant path for every block kernel
+                params = (
+                    {"params": unroll_scan_params(params["params"])}
+                    if isinstance(params, dict) and "params" in params
+                    else unroll_scan_params(params))
+                unroll = False
+        # unbox flax Partitioned metadata FIRST: the quantizer's
+        # leaf-name check reads path tails, which inside a metadata
+        # box are the box's own keys — boxed trees would silently
+        # pass through unquantized
+        if tp_lib.has_partitioning(params):
+            params = tp_lib.unbox_params(params)
+        params, b0, b1 = quantize_param_tree(params, quantize_weights)
+        params = jax.device_put(params)
+        log_dist(f"ragged engine weights -> {quantize_weights}"
+                 f"{' (native int8 dots)' if native else ''}: "
+                 f"{b0 / 2**20:.1f} MiB -> {b1 / 2**20:.1f} MiB "
+                 f"({b0 / max(b1, 1):.2f}x)", ranks=[0])
+    return cfg, params, dict(unroll=unroll, wq=quantize_weights,
+                             native=native)
+
+
+def prepare_draft(draft_model, draft_params, target_cfg, *,
+                  kv_cache_dtype: str, tp: int = 1, device=None):
+    """The draft model of ``speculation.mode='draft'`` in serving form
+    over the TARGET's page geometry (its pool is its own, its page
+    cursors are shared).  Returns ``(draft module, params, dunroll)``."""
+    if draft_model is None:
+        raise ValueError(
+            "speculation.mode='draft' needs a draft model: pass "
+            "draft_model=<small same-vocab llama-family module> "
+            "and draft_params=... (the config's "
+            "speculation.draft_model preset name is for CLIs to "
+            "construct one)")
+    assert tp <= 1, (
+        "draft-model speculation does not compose with "
+        "tensor-parallel serving yet")
+    dmcfg = getattr(draft_model, "config", None)
+    assert (dataclasses.is_dataclass(dmcfg) and
+            hasattr(dmcfg, "rope_theta") and
+            hasattr(dmcfg, "paged_decode")), (
+        "draft model must be a llama-family model-zoo module "
+        "(the ragged paged decode path's requirement)")
+    assert dmcfg.vocab_size == target_cfg.vocab_size, (
+        f"draft vocab {dmcfg.vocab_size} != target vocab "
+        f"{target_cfg.vocab_size} — speculative verify compares token "
+        "ids, the models must share a tokenizer")
+    assert kv_cache_dtype in KV_CACHE_DTYPES, (
+        f"draft_kv_cache_dtype must be {'|'.join(KV_CACHE_DTYPES)}, "
+        f"got {kv_cache_dtype!r}")
+    from deepspeed_tpu.parallel import tensor_parallel as tp_lib
+
+    draft = type(draft_model)(serving_config(
+        dmcfg, max_seq_len=target_cfg.max_cache_len,
+        page_size=target_cfg.kv_page_size,
+        num_pages=target_cfg.kv_num_pages, tensor_parallel=False,
+        kv_cache_dtype=kv_cache_dtype))
+    dparams = normalize_params(
+        draft_model, draft_params,
+        plain_model=type(draft_model)(dataclasses.replace(
+            dmcfg, decode=False)))
+    if tp_lib.has_partitioning(dparams):
+        dparams = tp_lib.unbox_params(dparams)
+    return (draft, jax.device_put(dparams, device),
+            bool(getattr(dmcfg, "scan_layers", False)))
+
+
+def place_params(params, *, mesh=None, tp: int = 1, device=None):
+    """TP-shard (AutoTP name rules / flax metadata) over the `tensor`
+    mesh axis, mirroring the v1 engine; otherwise replicate, or pin to
+    a one-device replica slice's ``device``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.parallel import tensor_parallel as tp_lib
+
+    if tp <= 1:
+        if tp_lib.has_partitioning(params):
+            params = tp_lib.unbox_params(params)
+        if device is not None:
+            params = jax.device_put(params, device)
+        return params
+    if tp_lib.has_partitioning(params):
+        specs = tp_lib.extract_partition_specs(
+            {"params": params}, mesh.axis_names)["params"]
+        params = tp_lib.unbox_params(params)
+        # training-oriented metadata (e.g. a MoE bank's `expert` axis
+        # with no `tensor` entries) doesn't shard a TP serving mesh —
+        # fall back to AutoTP name rules
+        if not any("tensor" in tuple(s)
+                   for s in jax.tree_util.tree_leaves(
+                       specs, is_leaf=lambda x: isinstance(x, P))):
+            specs = None
+    else:
+        specs = None
+    if specs is None:
+        specs = tp_lib.auto_tp_specs(params, tp)
+        log_dist("ragged engine AutoTP: inferred tensor-parallel "
+                 "sharding from parameter names", ranks=[0])
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda x: isinstance(x, P))
+    return jax.tree_util.tree_map(
+        lambda x, sh: jax.device_put(jnp.asarray(x), sh), params,
+        shardings)
+
+
+def cache_sharding(leaf_shape, *, mesh=None, tp: int = 1, device=None):
+    """KV page pools shard their combined-head dim over `tensor`
+    (reference v2 KV sharding: heads split over the TP group); the
+    quantized pools' [P, page, 2Hkv] scale buffers shard the same
+    head dim.  A one-device replica slice pins them to its device;
+    ``None`` leaves the default placement."""
+    from jax.sharding import (NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    if tp <= 1:
+        return SingleDeviceSharding(device) if device is not None else None
+    if len(leaf_shape) == 4:
+        return NamedSharding(mesh, P(None, None, "tensor", None))
+    if len(leaf_shape) == 3:
+        return NamedSharding(mesh, P(None, None, "tensor"))
+    return None
+
+
+def init_cache(model, *, max_seqs: int, width: int, **placement):
+    """Zeroed page buffers for every layer of ``model`` (a serving-form
+    module; eval_shape, no params), each allocated where it lives
+    (``placement``: :func:`cache_sharding`'s keywords) — a pool is most
+    of a chip's memory.  ``width``: the fused batch's ``T``."""
+    pages_per_seq = pages_for(model.config.max_cache_len,
+                              model.config.kv_page_size)
+    # host arrays put on the device: no program compiles to make them
+    dummy_meta = jax.tree_util.tree_map(jnp.asarray, {
+        "kv_lens": np.zeros((max_seqs,), np.int32),
+        "page_indices": np.full((max_seqs, pages_per_seq), -1, np.int32),
+        "cu_q_lens": np.zeros((max_seqs + 1,), np.int32),
+        "num_seqs": np.zeros((1,), np.int32),
+        "new_kv_dest": np.zeros((width,), np.int32)})
+    ids = jnp.zeros((1, width), jnp.int32)
+    pos = jnp.zeros((1, width), jnp.int32)
+
+    def _init():
+        return model.init(jax.random.PRNGKey(0), ids,
+                          positions=pos, ragged_meta=dummy_meta)
+
+    shapes = jax.eval_shape(_init)
+    assert "cache" in shapes
+    return jax.tree_util.tree_map(
+        lambda s: jnp.zeros(s.shape, s.dtype,
+                            device=cache_sharding(s.shape, **placement)),
+        shapes["cache"])
+
+
+def build_fused_step(model, *, unroll: bool = False,
+                     wq: Optional[str] = None, native: bool = False):
+    """ONE jitted program for every tick: fused decode + prefill
+    chunk(s) forward, paged-KV update, and logits row selection.
+    ``unroll``: the stored params are scan-stacked and unroll in-jit;
+    ``wq``/``native``: they are stored quantized (``native``: int8 dots,
+    no dequantized copy)."""
+    def run(params, cache, token_ids, positions, kv_lens, page_indices,
+            cu_q_lens, num_seqs, new_kv_dest, sample_rows):
+        if wq:
+            params = dequantize_param_tree(params, native_w8a8=native)
+        if unroll:
+            params = unroll_scan_params(params)
+        meta = {"kv_lens": kv_lens, "page_indices": page_indices,
+                "cu_q_lens": cu_q_lens, "num_seqs": num_seqs,
+                "new_kv_dest": new_kv_dest,
+                # rows past the last sequence's end are padding
+                "live": (jnp.arange(token_ids.shape[1]) <
+                         cu_q_lens[num_seqs[0]])}
+        out, vars_ = model.apply(
+            {"params": params, "cache": cache}, token_ids,
+            positions=positions, mutable=["cache", "moe_stats"],
+            ragged_meta=meta)
+        logits = logits_of(out)[0]                      # [T, V]
+        sel = jnp.take(logits, sample_rows, axis=0)     # [max_seqs, V]
+        return sel, vars_["cache"], moe_counts(vars_)
+
+    # distinguishable XLA program name ("jit_ragged_fused_step") so
+    # the profiler bridge can attribute device time per program
+    run.__name__ = run.__qualname__ = "ragged_fused_step"
+    return jax.jit(run, donate_argnums=(1,))
+
+
+def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
+                       page_size: int, max_seq_len: int, sample_base,
+                       unroll: bool = False, wq: Optional[str] = None,
+                       native: bool = False):
+    """``block`` decode ticks per dispatch: ``lax.scan`` over fused
+    [1, max_seqs] decode forwards with on-device sampling.  The host
+    round trip the reference pays per generated token
+    (``engine_v2.py:107`` put -> schedule -> logits) amortizes to
+    1/``block``.  Two variants compile: pure-greedy (no sort) and
+    per-seq ``sampled``."""
+    S, page, max_len = max_seqs, page_size, max_seq_len
+
+    def run(params, cache, last_tok, pos, active, remaining,
+            page_table, eos_ids, do_sample, temperature, top_k, top_p,
+            seeds, rng):
+        if wq:
+            params = dequantize_param_tree(params, native_w8a8=native)
+        if unroll:
+            params = unroll_scan_params(params)
+
+        def tick(carry, _):
+            cache, last_tok, pos, active, remaining, rng = carry
+            dest_page = jnp.take_along_axis(
+                jnp.maximum(page_table, 0),
+                (pos // page)[:, None], axis=1)[:, 0]
+            dest = jnp.where(active, dest_page * page + pos % page, 0)
+            kv_lens = jnp.where(active, pos + 1, 1)
+            meta = {"kv_lens": kv_lens,
+                    "page_indices": page_table,
+                    "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
+                    "num_seqs": jnp.asarray([S], jnp.int32),
+                    "new_kv_dest": dest, "live": active}
+            out, vars_ = model.apply(
+                {"params": params, "cache": cache}, last_tok[None],
+                positions=jnp.where(active, pos, 0)[None],
+                mutable=["cache", "moe_stats"], ragged_meta=meta)
+            logits = logits_of(out)[0]              # [S, V]
+            moe = moe_counts(vars_)
+            rng, _ = jax.random.split(rng)
+            # position-keyed per-row keys: the draw at cache position
+            # `pos` is the same bits the fused tick's host sampler
+            # would use, no matter how this block was scheduled
+            sub = (position_keys(sample_base, seeds, pos)
+                   if sampled else None)
+            nxt = sample_logits_batched(
+                logits, sub, do_sample,
+                temperature, top_k, top_p)
+            produced = active
+            nxt = jnp.where(active, nxt, last_tok)
+            hit_eos = active & (nxt == eos_ids)
+            remaining = remaining - produced.astype(jnp.int32)
+            pos = jnp.where(active, pos + 1, pos)
+            active = (active & ~hit_eos & (remaining > 0) &
+                      (pos + 1 < max_len))
+            return (vars_["cache"], nxt, pos, active, remaining,
+                    rng), (nxt, produced, moe)
+
+        carry, (toks, mask, moe) = jax.lax.scan(
+            tick, (cache, last_tok, pos, active, remaining, rng),
+            length=block)
+        cache, last_tok, pos, active, remaining, rng = carry
+        # the full carry returns so the pipelined host path can keep
+        # it device-resident across dispatches (no re-upload)
+        return (cache, last_tok, pos, active, remaining, toks, mask,
+                None if moe is None else fold_moe_counts(moe))
+
+    run.__name__ = run.__qualname__ = "ragged_decode_block"
+    return jax.jit(run, donate_argnums=(1,))
+
+
+def build_draft_prefill(draft, *, dunroll: bool = False):
+    """ONE compiled chunked prefill program for the draft model's
+    paged KV catch-up (fixed [1, prefill_chunk] shape, one sequence
+    per call — entry-time cost, not steady-state)."""
+    def run(dparams, dcache, ids, positions, kv_lens, page_row, cu,
+            dest):
+        if dunroll:
+            dparams = unroll_scan_params(dparams)
+        meta = {"kv_lens": kv_lens, "page_indices": page_row,
+                "cu_q_lens": cu,
+                "num_seqs": jnp.asarray([1], jnp.int32),
+                "new_kv_dest": dest}
+        _, vars_ = draft.apply(
+            {"params": dparams, "cache": dcache}, ids,
+            positions=positions, mutable=["cache"], ragged_meta=meta)
+        return vars_["cache"]
+
+    run.__name__ = run.__qualname__ = "draft_prefill"
+    return jax.jit(run, donate_argnums=(1,))
+
+
+def build_spec_block(model, draft, *, sampled: bool, mode: str, k: int,
+                     ngram: int, max_seqs: int, block: int, page_size: int,
+                     pages_per_seq: int, max_seq_len: int,
+                     unroll: bool = False, wq: Optional[str] = None,
+                     native: bool = False, dunroll: bool = False):
+    """The fused draft+verify+accept block: ``block`` speculative ticks
+    per dispatch in a ``lax.scan``.  Each tick drafts ``k`` tokens
+    (``mode='ngram'``: lookup over the history buffer; ``'draft'``: a
+    k-step ``draft``-model sub-scan sharing the target's page table),
+    scores all k+1 positions with the TARGET in one ragged chunk per
+    slot, and accepts/rolls back device-side
+    (:func:`~deepspeed_tpu.inference.sampling.speculative_verify`).
+    Rollback is position rollback: rows written for rejected positions
+    sit between the new cursor and the old write frontier, a span the
+    NEXT tick's scatter fully overwrites before attention can reach
+    it."""
+    S, Tt, K1, n = max_seqs, block, k + 1, ngram
+    page, pp, max_len = page_size, pages_per_seq, max_seq_len
+
+    def ngram_propose(hist, pos, last_tok):
+        """Prompt-lookup drafting: most recent earlier occurrence of
+        the trailing n-gram proposes the k tokens that followed it;
+        no match proposes the last token repeated (any proposal is
+        distribution-safe — bad drafts are simply rejected)."""
+        L = max_len
+        ar = jnp.arange(L, dtype=jnp.int32)
+        histp = jnp.pad(hist, ((0, 0), (0, n + k)))
+        tpos = jnp.clip(pos[:, None] - (n - 1) +
+                        jnp.arange(n, dtype=jnp.int32)[None, :],
+                        0, L - 1)
+        tail = jnp.take_along_axis(hist, tpos, axis=1)      # [S, n]
+        match = jnp.ones((S, L), bool)
+        for t in range(n):
+            match = match & (histp[:, t:t + L] == tail[:, t:t + 1])
+        valid = ar[None, :] <= (pos[:, None] - n)
+        score = jnp.where(match & valid, ar[None, :] + 1, 0)
+        best = jnp.argmax(score, axis=1)       # most recent match
+        found = jnp.any(match & valid, axis=1)
+        cidx = best[:, None] + n + jnp.arange(k,
+                                              dtype=jnp.int32)[None, :]
+        cand = jnp.take_along_axis(histp, cidx, axis=1)     # [S, k]
+        fb = jnp.broadcast_to(last_tok[:, None], (S, k))
+        return jnp.where(found[:, None], cand, fb).astype(jnp.int32)
+
+    def run(params, dparams, cache, dcache, hist, last_tok, pos,
+            active, remaining, page_table, eos_ids, do_sample,
+            temperature, top_k, top_p, rng):
+        if wq:
+            params = dequantize_param_tree(params, native_w8a8=native)
+        if unroll:
+            params = unroll_scan_params(params)
+        if mode == "draft" and dunroll:
+            dparams = unroll_scan_params(dparams)
+        rows = jnp.arange(S)
+
+        def draft_propose(dcache, last_tok, pos, active, key):
+            def dstep(carry, key_j):
+                dcache, cur, dpos = carry
+                dvalid = active & (dpos < max_len)
+                dp = jnp.take_along_axis(
+                    jnp.maximum(page_table, 0),
+                    jnp.minimum(dpos // page, pp - 1)[:, None],
+                    axis=1)[:, 0]
+                ddest = jnp.where(dvalid, dp * page + dpos % page, 0)
+                dmeta = {"kv_lens": jnp.where(active, dpos + 1, 1),
+                         "page_indices": page_table,
+                         "cu_q_lens": jnp.arange(S + 1,
+                                                 dtype=jnp.int32),
+                         "num_seqs": jnp.asarray([S], jnp.int32),
+                         "new_kv_dest": ddest}
+                dout, dvars = draft.apply(
+                    {"params": dparams, "cache": dcache}, cur[None],
+                    positions=jnp.where(dvalid, dpos, 0)[None],
+                    mutable=["cache"], ragged_meta=dmeta)
+                dlg = logits_of(dout)[0].astype(jnp.float32)
+                dgreedy = jnp.argmax(dlg, axis=-1).astype(jnp.int32)
+                if sampled:
+                    flg = filter_logits_batched(dlg, temperature,
+                                                top_k, top_p)
+                    qj = jax.nn.softmax(flg, axis=-1)
+                    samp = jax.random.categorical(
+                        key_j, flg, axis=-1).astype(jnp.int32)
+                    nxt = jnp.where(do_sample, samp, dgreedy)
+                    out = (nxt, qj)
+                else:
+                    nxt = dgreedy
+                    out = (nxt,)
+                return (dvars["cache"], nxt, dpos + 1), out
+
+            keys = jax.random.split(key, k)
+            (dcache, _, _), outs = jax.lax.scan(
+                dstep, (dcache, last_tok, pos), keys)
+            d_toks = outs[0].T                              # [S, k]
+            q_probs = (outs[1].transpose(1, 0, 2) if sampled
+                       else None)
+            return dcache, d_toks, q_probs
+
+        def tick(carry, _):
+            (cache, dcache, hist, last_tok, pos, active, remaining,
+             rng, prop, accd) = carry
+            rng, key_d, key_v = jax.random.split(rng, 3)
+            # ---- draft k proposals per slot ----
+            if mode == "ngram":
+                # the cursor token joins the history before matching
+                hist = hist.at[rows, jnp.where(active, pos,
+                                               max_len)].set(
+                    last_tok, mode="drop")
+                d_toks = ngram_propose(hist, pos, last_tok)
+                q_probs = None
+            else:
+                dcache, d_toks, q_probs = draft_propose(
+                    dcache, last_tok, pos, active, key_d)
+            # ---- verify: one ragged chunk of k+1 rows per slot ----
+            chunk = jnp.concatenate([last_tok[:, None], d_toks],
+                                    axis=1)                 # [S, K1]
+            cpos = (pos[:, None] +
+                    jnp.arange(K1, dtype=jnp.int32)[None, :])
+            valid = active[:, None] & (cpos < max_len)
+            dest_page = jnp.take_along_axis(
+                jnp.maximum(page_table, 0),
+                jnp.minimum(cpos // page, pp - 1), axis=1)
+            dest = jnp.where(valid,
+                             dest_page * page + cpos % page, 0)
+            meta = {"kv_lens": jnp.where(active, pos + K1, 1),
+                    "page_indices": page_table,
+                    "cu_q_lens": jnp.arange(
+                        S + 1, dtype=jnp.int32) * K1,
+                    "num_seqs": jnp.asarray([S], jnp.int32),
+                    "new_kv_dest": dest.reshape(-1)}
+            out, vars_ = model.apply(
+                {"params": params, "cache": cache},
+                chunk.reshape(1, -1),
+                positions=jnp.where(valid, cpos, 0).reshape(1, -1),
+                mutable=["cache"], ragged_meta=meta)
+            cache = vars_["cache"]
+            logits = logits_of(out)[0].reshape(S, K1, -1)
+            out_toks, acc = speculative_verify(
+                logits, d_toks, q_probs, key_v if sampled else None,
+                do_sample, temperature, top_k, top_p)
+            # ---- emission clamp: budget, max_len, first eos ----
+            emit = jnp.minimum(acc + 1, remaining)
+            emit = jnp.minimum(emit, max_len - 1 - pos)
+            eos_hit = out_toks == eos_ids[:, None]
+            first_eos = jnp.argmax(eos_hit, axis=1)
+            emit = jnp.where(jnp.any(eos_hit, axis=1),
+                             jnp.minimum(emit, first_eos + 1), emit)
+            emit = jnp.where(active, jnp.clip(emit, 1, K1), 0)
+            j = jnp.arange(K1, dtype=jnp.int32)[None, :]
+            mask_out = active[:, None] & (j < emit[:, None])
+            # ---- advance / roll back the carry ----
+            new_last = jnp.take_along_axis(
+                out_toks, jnp.maximum(emit - 1, 0)[:, None],
+                axis=1)[:, 0]
+            ended_eos = jnp.any(eos_hit & (j < emit[:, None]),
+                                axis=1)
+            if mode == "ngram":
+                hidx = jnp.where(mask_out, pos[:, None] + 1 + j,
+                                 max_len)
+                hist = hist.at[rows[:, None], hidx].set(
+                    out_toks, mode="drop")
+            last_tok = jnp.where(active, new_last, last_tok)
+            pos = jnp.where(active, pos + emit, pos)
+            remaining = remaining - emit
+            prop = prop + jnp.sum(jnp.where(active, k, 0))
+            accd = accd + jnp.sum(jnp.where(active, emit - 1, 0))
+            active = (active & ~ended_eos & (remaining > 0) &
+                      (pos + 1 < max_len))
+            return (cache, dcache, hist, last_tok, pos, active,
+                    remaining, rng, prop, accd), (out_toks, mask_out)
+
+        carry0 = (cache, dcache, hist, last_tok, pos, active,
+                  remaining, rng, jnp.int32(0), jnp.int32(0))
+        carry, (toks, mask) = jax.lax.scan(tick, carry0, length=Tt)
+        (cache, dcache, hist, last_tok, pos, active, remaining, _,
+         prop, accd) = carry
+        # tick-major emission order, [Tt*K1, S] — _fold_block's shape
+        toks = toks.transpose(0, 2, 1).reshape(Tt * K1, S)
+        mask = mask.transpose(0, 2, 1).reshape(Tt * K1, S)
+        return (cache, dcache, hist, last_tok, pos, active,
+                remaining, toks, mask, prop, accd)
+
+    run.__name__ = run.__qualname__ = "spec_verify_block"
+    return jax.jit(run, donate_argnums=(2, 3, 4))

@@ -6,53 +6,38 @@ Dynamic SplitFuse scheduling from the FastGen blog): requests of different
 lengths share one running decode batch — sequences join the moment a slot
 frees, never waiting for the batch to drain.
 
-Round-3 architecture (replacing the slot-row cache + split prefill/decode
-dispatches of round 2):
+This module is the LOOP: admission, chunk budgeting, the choice of program
+per step, sampling, finish detection, the request API — plain Python on the
+host, as the reference's scheduler tier is.  Two neighbours hold the rest:
 
-- **Blocked KV** (reference ``ragged/blocked_allocator.py:1``,
-  ``ragged/kv_cache.py``): KV lives in fixed-size pages addressed by a
-  per-sequence page table; device memory scales with pages, not
-  ``max_seqs x max_seq_len``.  Allocation is host-side
-  (:class:`deepspeed_tpu.inference.paged.PageAllocator`), worst-case
-  reserved at admission.
-- **One fused compiled program per tick** (Dynamic SplitFuse,
-  ``engine_v2.py:107``): a single static ``[1, T]`` token batch carries one
-  decode token for EVERY ready sequence AND this tick's prefill chunk(s) —
-  multiple prefilling requests share the chunk budget.  Shapes never vary,
-  so exactly one XLA program is compiled; raggedness lives in int32
-  metadata (``cu_q_lens`` et al.).
-- **Attention** is the vLLM-TPU ragged paged Pallas kernel on TPU and an
-  XLA-compilable reference on CPU (``inference/paged.py``).
+- :mod:`~deepspeed_tpu.inference.v2.kv_state` owns every form of a
+  sequence's KV state (reference ``ragged/blocked_allocator.py:1``,
+  ``ragged/kv_cache.py``): fixed-size pages addressed by a per-sequence
+  page table, so device memory scales with pages, not
+  ``max_seqs x max_seq_len``; the spill tiers; the prefix index.
+- :mod:`~deepspeed_tpu.inference.v2.programs` builds what the device runs:
+  ONE fused SplitFuse tick (``engine_v2.py:107`` — a static ``[1, T]``
+  batch carries a decode token for EVERY ready sequence AND this tick's
+  prefill chunks; raggedness lives in int32 metadata, so exactly one XLA
+  program compiles), the on-device multi-tick decode block (everyone past
+  prefill: ``decode_block_size`` ticks in a ``lax.scan`` with on-device
+  sampling, the host round trip amortized to 1/K), and the speculative
+  draft + verify + accept block.  Attention is the vLLM-TPU ragged paged
+  Pallas kernel on TPU and an XLA-compilable reference on CPU
+  (``inference/paged.py``).
 
-Round-4 additions:
+**Tensor-parallel serving**: pass a ``topology`` with a >1 ``tensor``
+axis — weights shard by AutoTP name rules, the paged KV pool shards over
+its head dim, and the fused tick runs under GSPMD with the paged attention
+shard_map-manual over ``tensor``.
 
-- **Tensor-parallel serving** (reference v2 TP sharding,
-  ``inference/v2/model_implementations/sharding/attn.py`` + engine TP
-  groups ``inference/engine.py:247``): pass a ``topology`` with a >1
-  ``tensor`` axis — weights shard by AutoTP name rules, the paged KV pool
-  shards over its head dim, and the fused tick runs under GSPMD with the
-  paged attention shard_map-manual over ``tensor``.
-- **On-device multi-tick decode**: when every active sequence is past
-  prefill, ``step()`` dispatches ONE compiled program that runs
-  ``decode_block_size`` decode ticks in a ``lax.scan`` with on-device
-  per-sequence sampling (``sampling.sample_logits_batched``) — amortizing
-  the host round trip the reference's FastGen scheduler pays per tick to
-  1/K.  Finished sequences park on the trash page mid-block; the host
-  reconstructs outputs from the per-tick produced mask.
-
-Round-5/6 addition — the **pipelined serving host path** (this repo's
-software-pipeline treatment, same shape as the NVMe moment stream): the
-round-5 verdict measured the ragged engine at 23.3k device tok/s but 295
-WALL tok/s — ~99% of serving wall time was host planning, per-tick
-``jnp.asarray`` metadata uploads, and a blocking ``device_get`` per
-dispatch.  With ``pipeline=True`` (default) the decode steady state runs
-as a software pipeline:
+**The pipelined host path** (``pipeline=True``, the default): the decode
+steady state runs as a software pipeline.
 
 - the decode-block carry (``last_tok``/``pos``/``active``/``remaining``)
-  and the per-tick metadata (``page_indices``/``kv_lens`` derivation
-  inputs, sampler configs, eos ids) stay RESIDENT on device across
-  dispatches — uploaded once at loop entry, re-uploaded only when the
-  page table actually grows;
+  and the per-tick metadata (page table, sampler configs, eos ids) stay
+  RESIDENT on device across dispatches — uploaded once at loop entry,
+  re-uploaded only when the page table actually grows;
 - while block *k* executes on device, the host plans block *k+1* from an
   exact projection of each sequence's length/remaining budget (JAX
   dispatch is async — the host never synchronizes per block, bounded by
@@ -69,42 +54,25 @@ outputs are bit-identical, greedy or seeded-sampling.  ``host_stats``
 (:class:`~deepspeed_tpu.inference.common.HostStageStats`) breaks the
 host path into plan/upload/dispatch/device/harvest per dispatch.
 
-Round-6 addition — **speculative decoding on the pipelined decode
-path**: decode is memory-bound (every dispatch re-reads the weights from
-HBM for ONE token per sequence), so with ``speculation.mode != off`` each
-decode-block tick drafts ``k`` tokens per slot and the target model
-scores all ``k+1`` positions in ONE ragged dispatch (the drafted tokens
-enter as a short prefill-like chunk against the paged KV — the same
-SplitFuse machinery that mixes prefill chunks into decode ticks).  A
-device-resident accept/rollback step then compares draft vs target:
+**Speculative decoding** (``speculation.mode != off``) rides the same
+path: each decode-block tick drafts ``k`` tokens per slot (``ngram``:
+prompt lookup over a device-resident token history; ``draft``: a small
+same-vocab family member against the SAME page table, its pool its own)
+and the target scores all ``k+1`` positions in ONE ragged dispatch.
+Greedy slots emit the target's argmax everywhere, so speculative greedy
+output is bit-identical to non-speculative decode; sampled slots use
+rejection sampling with residual resampling
+(:func:`~deepspeed_tpu.inference.sampling.speculative_verify`), so the
+output distribution equals the non-speculative one.  Rollback is pure
+position rollback: KV rows written for rejected positions are overwritten
+by the next block before any query can attend to them, and the pages stay
+owned.  The host projects per-slot advance as ``[1, k+1]``-per-tick BOUNDS
+instead of exact counts, grows pages to the worst case, and forces a
+harvest whenever a finish is possible under the fast bound.
 
-- **greedy** slots accept the longest exact-match prefix and emit the
-  target's argmax everywhere, so speculative greedy output is
-  bit-identical to non-speculative decode regardless of draft quality;
-- **sampled** slots use standard rejection sampling with
-  residual-distribution resampling
-  (:func:`~deepspeed_tpu.inference.sampling.speculative_verify`), so
-  the output distribution provably equals the non-speculative one.
-
-Two draft modes share the interface: ``ngram`` (prompt-lookup over a
-device-resident token-history buffer — no second model) and ``draft``
-(a small same-vocab family member runs its own decode carry against the
-SAME page table; its KV pool is separate, its page cursors are shared).
-Rollback is pure position rollback: KV rows written for rejected draft
-positions are provably overwritten by the next block before any query
-can attend to them, and the pages stay owned (the next block writes the
-same span).  Accepted length, rolled-back cursors, and the corrected
-bonus token all live in the decode carry, so speculation composes with
-the pipelined host path — the host projects per-slot advance as
-``[1, k+1]``-per-tick BOUNDS instead of exact counts, grows pages to
-the worst case, and forces a harvest whenever a finish is possible
-under the fast bound.
-
-Host-side scheduling (admission, chunk budgeting, finish detection) is
-plain Python — the reference's scheduler tier is host-side too.  Models:
-anything llama-shaped in the zoo (Llama, Mistral, Qwen2, Mixtral, ... —
-per-token positions thread through attention, which the ragged path
-requires).
+Models: anything llama-shaped in the zoo (Llama, Mistral, Qwen2, Mixtral,
+OLMoE, ... — per-token positions thread through attention, which the
+ragged path requires).
 """
 from __future__ import annotations
 
@@ -122,60 +90,12 @@ from deepspeed_tpu.inference.common import HostStageStats
 from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.telemetry import RequestLatencyTracker, trace
 from deepspeed_tpu.utils.async_stage import BoundedAsyncStage, StageTimers
-from deepspeed_tpu.inference.paged import (PageAllocator,
-                                           pages_for)
-from deepspeed_tpu.inference.prefix_cache import (ROOT_HASH,
-                                                  PrefixCacheIndex)
-from deepspeed_tpu.inference.sampling import (filter_logits_batched,
-                                              position_keys,
-                                              sample_logits,
-                                              sample_logits_batched,
-                                              speculative_verify)
+from deepspeed_tpu.inference.paged import pages_for
+from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
+from deepspeed_tpu.inference.sampling import position_keys, sample_logits
+from deepspeed_tpu.inference.v2 import programs
+from deepspeed_tpu.inference.v2.kv_state import KVState
 from deepspeed_tpu.utils.logging import log_dist
-
-
-def _fold_moe_counts(counts: jax.Array) -> jax.Array:
-    """``[n, 3]`` per-layer (or per-tick) expert-layer counts -> ``[3]``:
-    token copies routed and experts hit add up, the fullest expert's rows
-    take the largest."""
-    return jnp.concatenate([jnp.sum(counts[:, :2], axis=0),
-                            jnp.max(counts[:, 2:], axis=0)])
-
-
-def _moe_counts(variables) -> Optional[jax.Array]:
-    """One apply's ``moe_stats`` collection (each dropless expert layer
-    sows ``[rows, experts_hit, load_max]``) folded over its layers; None
-    for a model without expert layers, so its programs gain no output."""
-    leaves = jax.tree_util.tree_leaves(variables.get("moe_stats", {}))
-    return _fold_moe_counts(jnp.stack(leaves)) if leaves else None
-
-
-def _paged_kv_page_bytes(model, mcfg, page_size: int,
-                         kv_cache_dtype: str) -> int:
-    """Exact device bytes ONE KV page costs across every cache leaf —
-    for a quantized pool that is the 1-byte payload page plus its fp32
-    scale rows.  Measured by an eval_shape probe of a 2-page pool
-    rather than guessed from the layer count, so any model-zoo cache
-    layout (extra leaves, fused layers) is accounted automatically."""
-    probe_cfg = dataclasses.replace(
-        mcfg, decode=True, ragged_decode=False, paged_decode=True,
-        max_cache_len=2 * page_size, scan_layers=False,
-        kv_page_size=page_size, kv_num_pages=2,
-        tensor_parallel=False, kv_cache_dtype=kv_cache_dtype)
-    probe = type(model)(probe_cfg)
-    meta = {"kv_lens": jnp.zeros((1,), jnp.int32),
-            "page_indices": jnp.full((1, 2), -1, jnp.int32),
-            "cu_q_lens": jnp.zeros((2,), jnp.int32),
-            "num_seqs": jnp.zeros((1,), jnp.int32),
-            "new_kv_dest": jnp.zeros((4,), jnp.int32)}
-    ids = jnp.zeros((1, 4), jnp.int32)
-    pos = jnp.zeros((1, 4), jnp.int32)
-    shapes = jax.eval_shape(lambda: probe.init(
-        jax.random.PRNGKey(0), ids, positions=pos, ragged_meta=meta))
-    total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-                for leaf in jax.tree_util.tree_leaves(shapes["cache"]))
-    assert total % 2 == 0, total
-    return total // 2
 
 
 @dataclasses.dataclass
@@ -235,6 +155,17 @@ class Request:
         return self.prefill_done + len(self.generated) - \
             (self.ctx_len - self.prompt.size)
 
+    def fold(self) -> None:
+        """Become a re-prefill CONTINUATION: whatever rows the sequence
+        had are gone, so its context is its prompt plus its own output,
+        prefilled from zero and registered with the prefix index anew
+        (greedy continuations are exact; sampled ones resume from the
+        same sampled prefix)."""
+        self.ctx = np.concatenate(
+            [self.prompt, np.asarray(self.generated, np.int32)])
+        self.prefill_done = 0
+        self.pc_parent, self.pc_pages, self.pc_cached = ROOT_HASH, 0, 0
+
 
 class RaggedInferenceEngineV2:
     """``put_request`` -> repeated ``step()`` -> ``get_outputs``.
@@ -269,82 +200,30 @@ class RaggedInferenceEngineV2:
                  replica: Optional[str] = None,
                  control: Any = None,
                  config: Any = None):
-        """``kv_cache_dtype``: ``None`` (config subtree
-        ``v2.kv_cache_dtype`` decides; "none" by default) | "none" |
-        "fp8" | "int8" — paged KV pool storage format (reference
-        fp_quantizer KV quantization).  Quantized pools are read
-        dequant-free: the Pallas quantized-pages kernel on TPU at
-        head_dim 128, the gathered-pages XLA reference elsewhere
-        (:func:`~deepspeed_tpu.inference.paged.kv_dequant_path`).
-        ``kv_pool_bytes``: size the pool by a device byte budget instead
-        of page count — ``num_pages`` becomes the exact number of pages
-        (payload + scale rows) that fit, so the same HBM budget holds
-        ~2x the pages when quantized.  Ignored when ``num_pages`` is
-        given explicitly.
-        ``draft_kv_cache_dtype``: storage format for the draft model's
-        pool under ``speculation.mode='draft'``; default ``None``
-        follows the target pool's resolved ``kv_cache_dtype``.
-        ``quantize_weights``: None | "int8" | "fp8" | "fp6" | "w8a8" —
-        weights persist quantized in HBM and dequantize in-jit at use
-        (reference FP6-LLM cuda_linear / int8 quantized inference);
-        "w8a8" additionally quantizes activations per row and dots
-        int8 x int8 on the MXU (reference W8A8 GEMMs,
-        ``csrc/quantization``) — Llama-family models only, and the
-        faster choice whenever decode is weight-bandwidth-bound.
+        """The ten options that ``config`` (a ``DeepSpeedInferenceConfig``
+        or dict with a ``v2`` subtree) also carries — ``pipeline``,
+        ``async_depth``, ``harvest_interval``, ``kv_cache_dtype``,
+        ``speculation``, ``kv_tiering``, ``prefix_cache``, ``control``,
+        ``slo``, ``trace_sample`` — default to None: the config decides
+        (:class:`~deepspeed_tpu.inference.config.InferenceV2Config`
+        documents each) and an explicit keyword wins.  The four subtrees
+        take what their class's ``coerce`` takes.
+
+        ``kv_pool_bytes``: size the pool by a device byte budget —
+        ``num_pages`` becomes the exact number of pages (payload + scale
+        rows) that fit; ignored when ``num_pages`` is given.
+        ``quantize_weights``: see
+        :func:`~deepspeed_tpu.inference.v2.programs.prepare_params`.
         ``kv_reserve``: "on_demand" (reference blocked-allocator model —
         admit on prompt-size pages, grow per decode block, evict +
         requeue as a continuation when the pool runs dry) or
-        "worst_case" (reserve prompt + max_new_tokens at admission; no
-        mid-flight out-of-pages state, lower concurrency per byte).
-        ``pipeline``/``async_depth``/``harvest_interval``: the serving
-        host-path pipeline knobs (module docstring).  Defaults come from
-        ``config`` (a ``DeepSpeedInferenceConfig``/dict with a ``v2``
-        subtree: ``inference.v2.pipeline`` default-on, ``async_depth``
-        2, ``harvest_interval`` 4); explicit kwargs win.
-        ``pipeline=False`` preserves the unpipelined host loop exactly
-        — one blocking harvest and a fresh metadata upload per
-        dispatch.
-        ``speculation``: ``None`` (config subtree decides; off by
-        default), a mode string (``"ngram"``/``"draft"``/``"off"``), a
-        dict, or a :class:`~deepspeed_tpu.inference.config.SpeculationConfig`
-        — speculative decoding on the decode-block path (module
-        docstring).  ``mode="draft"`` additionally needs ``draft_model``
-        (a small same-vocab llama-family zoo module) and its
-        ``draft_params``.
-        ``kv_tiering``: ``None`` (config subtree decides; off by
-        default), a dict (implies ``enabled=True`` unless it says
-        otherwise), or a
-        :class:`~deepspeed_tpu.inference.config.KVTieringConfig` —
-        host-RAM + NVMe spill tiers for the paged-KV pool
-        (:mod:`deepspeed_tpu.inference.kv_tiering`).  With tiering
-        disabled the engine is byte-for-byte the untiered engine.
-        ``prefix_cache``: ``None`` (config subtree decides; off by
-        default), a bool, a dict (implies ``enabled=True``), or a
-        :class:`~deepspeed_tpu.inference.config.PrefixCacheConfig` —
-        cross-request KV sharing over the paged pool
-        (:mod:`deepspeed_tpu.inference.prefix_cache`): admission
-        attaches fully-matched resident prefix pages read-only and
-        prefills only the non-cached suffix; the first divergent write
-        copy-on-writes.  Greedy outputs are bit-identical to
-        cache-off, and seeded sampling too, because sampling keys are
-        position-keyed (:func:`~deepspeed_tpu.inference.sampling.position_keys`)
-        rather than drawn from a dispatch-ordered stream.
-        ``slo``: ``None`` (config subtree ``v2.slo`` decides; off by
-        default), a list of objective strings like
-        ``"ttft_ms_p99 <= 150"``, or a prebuilt
-        :class:`~deepspeed_tpu.telemetry.slo.SLOSet` — every reaped
-        request feeds its summary record; ``serving_stages()["slo"]``
-        carries the rolling error-budget burn per objective.
-        ``trace_sample``: tail-based trace sampling N (kwarg > config
-        ``v2.trace_sample`` > env ``DSTPU_TRACE_SAMPLE``).  When the
-        tracer's sampling mode is armed, a reaped request's spans are
-        promoted to the retained ring only on SLO breach, error, or a
-        deterministic 1-in-N draw.
-        ``replica``: metric-label identity for scale-out serving — each
-        replica engine's registry children (``dstpu_request_*``,
-        ``dstpu_serving_stage_seconds``) carry ``replica="<value>"`` so
-        ``export_text()`` distinguishes replicas; solo engines keep the
-        empty label value."""
+        "worst_case" (reserve prompt + max_new_tokens at admission).
+        ``speculation`` with ``mode="draft"`` needs ``draft_model`` (a
+        small same-vocab llama-family zoo module) and ``draft_params``;
+        ``draft_kv_cache_dtype`` defaults to the target pool's format.
+        ``slo``: objective strings (``"ttft_ms_p99 <= 150"``) or an
+        :class:`~deepspeed_tpu.telemetry.slo.SLOSet`, fed at reap time.
+        ``replica``: metric-label identity for scale-out serving."""
         mcfg = getattr(model, "config", None)
         assert dataclasses.is_dataclass(mcfg) and hasattr(mcfg, "decode"), \
             "ragged engine needs a model-zoo module with a decode config"
@@ -355,6 +234,11 @@ class RaggedInferenceEngineV2:
             "model config predates paged ragged decode support")
 
         import deepspeed_tpu.comm as dist
+        from deepspeed_tpu.inference.config import (ControlConfig,
+                                                    KVTieringConfig,
+                                                    PrefixCacheConfig,
+                                                    SpeculationConfig,
+                                                    load_inference_config)
 
         # a ONE-device topology is a replica slice: this engine's params,
         # KV pool and per-tick metadata are committed to that device, and
@@ -373,41 +257,23 @@ class RaggedInferenceEngineV2:
         self._device = (self.mesh.devices.flat[0]
                         if self.mesh is not None and self.mesh.size == 1
                         else None)
+        placement = dict(mesh=self.mesh, tp=self.tp, device=self._device)
 
         # config-sourced knobs resolve BEFORE pool sizing: the resolved
         # kv_cache_dtype decides the per-page byte cost a kv_pool_bytes
-        # budget divides by (kwarg > config > default, as for every
-        # other v2 knob)
+        # budget divides by (kwarg > config > default)
+        o = dict(pipeline=pipeline, async_depth=async_depth,
+                 harvest_interval=harvest_interval, speculation=speculation,
+                 kv_tiering=kv_tiering, prefix_cache=prefix_cache,
+                 kv_cache_dtype=kv_cache_dtype, slo=slo,
+                 trace_sample=trace_sample, control=control)
         if config is not None:
-            from deepspeed_tpu.inference.config import \
-                load_inference_config
-
             v2cfg = load_inference_config(config).v2
-            pipeline = v2cfg.pipeline if pipeline is None else pipeline
-            async_depth = (v2cfg.async_depth if async_depth is None
-                           else async_depth)
-            harvest_interval = (v2cfg.harvest_interval
-                                if harvest_interval is None
-                                else harvest_interval)
-            speculation = (v2cfg.speculation if speculation is None
-                           else speculation)
-            kv_tiering = (v2cfg.kv_tiering if kv_tiering is None
-                          else kv_tiering)
-            prefix_cache = (v2cfg.prefix_cache if prefix_cache is None
-                            else prefix_cache)
-            kv_cache_dtype = (v2cfg.kv_cache_dtype
-                              if kv_cache_dtype is None
-                              else kv_cache_dtype)
-            slo = (v2cfg.slo if slo is None else slo)
-            trace_sample = (v2cfg.trace_sample if trace_sample is None
-                            else trace_sample)
-            control = v2cfg.control if control is None else control
-        kv_cache_dtype = ("none" if kv_cache_dtype is None
-                          else str(kv_cache_dtype))
-        assert kv_cache_dtype in ("none", "int8", "fp8", "fp8_e4m3"), (
-            f"kv_cache_dtype must be none|int8|fp8|fp8_e4m3, got "
-            f"{kv_cache_dtype!r}")
-        self.kv_cache_dtype = kv_cache_dtype
+            o = {k: getattr(v2cfg, k) if v is None else v
+                 for k, v in o.items()}
+        self.kv_cache_dtype = kv_cache_dtype = (
+            "none" if o["kv_cache_dtype"] is None
+            else str(o["kv_cache_dtype"]))
 
         self.page_size = int(page_size)
         self.pages_per_seq = pages_for(max_seq_len, self.page_size)
@@ -416,9 +282,9 @@ class RaggedInferenceEngineV2:
             # cost (quantized pools count the 1-byte payload AND the
             # fp32 scale rows) and fit as many pages as the budget holds
             # — page 0 is the trash page, so >= 2 keeps one usable
-            page_bytes = _paged_kv_page_bytes(
-                model, mcfg, self.page_size, kv_cache_dtype)
-            num_pages = max(2, int(kv_pool_bytes) // page_bytes)
+            num_pages = max(2, int(kv_pool_bytes) //
+                            programs.paged_kv_page_bytes(
+                                model, mcfg, self.page_size, kv_cache_dtype))
         if num_pages is None:
             # full provisioning: every slot can reach max_seq_len. Callers
             # serving long-max_len traffic shrink this — memory then
@@ -426,20 +292,21 @@ class RaggedInferenceEngineV2:
             num_pages = 1 + max_seqs * self.pages_per_seq
         self.num_pages = int(num_pages)
 
-        self._unroll_params = bool(getattr(mcfg, "scan_layers", False))
-        self.cfg = dataclasses.replace(
-            mcfg, decode=True, ragged_decode=False, paged_decode=True,
-            max_cache_len=max_seq_len, scan_layers=False,
-            kv_page_size=self.page_size, kv_num_pages=self.num_pages,
-            tensor_parallel=self.tp > 1, kv_cache_dtype=kv_cache_dtype)
+        # the model and its weights in serving form; ``param_form`` is
+        # what every program builder needs to know of the stored params
+        self.cfg, params, self.param_form = programs.prepare_params(
+            model, params, programs.serving_config(
+                mcfg, max_seq_len=max_seq_len, page_size=self.page_size,
+                num_pages=self.num_pages, tensor_parallel=self.tp > 1,
+                kv_cache_dtype=kv_cache_dtype),
+            quantize_weights, tp=self.tp)
         self.model = type(model)(self.cfg)
+        self.params = programs.place_params(params, **placement)
         self.max_seqs = max_seqs
         self.max_seq_len = max_seq_len
         self.prefill_chunk = prefill_chunk
         self.T = max_seqs + prefill_chunk          # fused batch width
         self.decode_block_size = max(int(decode_block_size), 1)
-        assert kv_reserve in ("on_demand", "worst_case"), kv_reserve
-        self.kv_reserve = kv_reserve
         self.evictions = 0
         self.rng = rng if rng is not None else jax.random.PRNGKey(0)
         # base key for position-keyed sampling (sampling.position_keys):
@@ -448,36 +315,25 @@ class RaggedInferenceEngineV2:
         # — never on how dispatches happened to be scheduled
         self._sample_base = jax.random.fold_in(self.rng, 0x5EED)
 
-        self.pipeline = True if pipeline is None else bool(pipeline)
-        self.async_depth = max(
-            int(async_depth) if async_depth is not None else 2, 1)
-        self.harvest_interval = max(
-            int(harvest_interval) if harvest_interval is not None else 4,
-            1)
+        self.pipeline = True if o["pipeline"] is None else bool(o["pipeline"])
+        self.async_depth, self.harvest_interval = (
+            max(int(default if o[k] is None else o[k]), 1)
+            for k, default in (("async_depth", 2), ("harvest_interval", 4)))
         self.replica = "" if replica is None else str(replica)
         self.host_stats = HostStageStats(replica=self.replica)
-        # substrate timers for the pipelined decode window (submitted/
-        # completed counters + submit_wait brackets; serving_stages()
-        # exposes the snapshot as ``pipeline_window``)
+        # serving_stages()["pipeline_window"]: the decode window's timers
         self._pipe_timers = StageTimers(cat="serving")
-        # per-request lifecycle latency (TTFT/TPOT/queue-wait/spill-
-        # stall percentiles) — always on; independent of the tracer
+        # per-request lifecycle latency — always on, tracer or not
         self.request_latency = RequestLatencyTracker(replica=self.replica)
 
-        # -- SLO objectives + tail-based trace sampling --
-        # All evaluation happens at reap time on the host — the traced
-        # dispatch path never sees the registry or the sampler, so the
-        # zero-new-compilations guarantee is structural, not incidental.
+        # -- SLO objectives + tail-based trace sampling: evaluated at
+        # reap time on the host, never on the traced dispatch path --
         from deepspeed_tpu.telemetry.slo import SLOSet, TailSampler
 
-        if slo is None or slo is False or (isinstance(slo, (list, tuple))
-                                           and not slo):
-            self.slo = None
-        elif isinstance(slo, SLOSet):
-            self.slo = slo
-        else:
-            self.slo = SLOSet(list(slo))
-        n = (int(trace_sample) if trace_sample is not None
+        slo = o["slo"]
+        self.slo = (slo if isinstance(slo, SLOSet)
+                    else SLOSet(list(slo)) if slo else None)
+        n = (int(o["trace_sample"]) if o["trace_sample"] is not None
              else trace.sample_n)
         self._tail_sampler = (TailSampler(n=n)
                               if (trace.sampling or n > 0) else None)
@@ -489,156 +345,61 @@ class RaggedInferenceEngineV2:
         # ahead of the host (None <=> host state is authoritative)
         self._dev: Optional[Dict[str, Any]] = None
 
-        # -- speculative decoding config (module docstring) --
-        from deepspeed_tpu.inference.config import SpeculationConfig
-
-        if speculation is None:
-            speculation = SpeculationConfig()
-        elif isinstance(speculation, str):
-            speculation = SpeculationConfig(mode=speculation)
-        elif isinstance(speculation, dict):
-            speculation = SpeculationConfig(**speculation)
+        # -- speculative decoding (programs.build_spec_block) --
+        speculation = SpeculationConfig.coerce(o["speculation"])
         self.spec_mode = speculation.mode
         self.spec_k = int(speculation.k)
         self.spec_ngram = int(speculation.ngram)
-        self._spec_block_cache: Dict[bool, Any] = {}
         self._draft = None
         self._draft_params: Any = {}
-        self._draft_cache: Any = {}
         self._draft_unroll = False
-        self._draft_prefill = None
-        # host tracker: draft KV coverage per slot (positions < value
-        # hold correct draft K/V; reset on admit/evict/reap)
+        # draft KV coverage per slot (positions < value hold draft K/V)
         self._draft_len = np.zeros((max_seqs,), np.int64)
-
-        from deepspeed_tpu.inference.common import normalize_params
-
-        params = normalize_params(
-            model, params,
-            plain_model=type(model)(dataclasses.replace(mcfg,
-                                                        decode=False)))
-        self._wq = quantize_weights
-        self._wq_native = False
-        if quantize_weights is not None:
-            assert self.tp <= 1, (
-                "quantize_weights does not compose with tensor-parallel "
-                "serving yet — quantized leaves carry their own "
-                "group-scale layout")
-            from deepspeed_tpu.inference.quantization import \
-                quantize_param_tree
-            from deepspeed_tpu.parallel import tensor_parallel as tp_lib
-
-            # "w8a8" (explicit opt-in — it quantizes ACTIVATIONS too, so
-            # numerics differ from weight-only "int8") runs the NATIVE
-            # path on models whose Dense layers consume quantized
-            # kernels: int8 stays on the per-tick read path (decode is
-            # weight-bandwidth-bound — tree-level dequant reads 2x the
-            # bytes), dotted on the MXU's int8 path with dynamic
-            # per-row activation scales
-            if quantize_weights == "w8a8":
-                assert getattr(type(model), "w8a8_native", False), (
-                    f"quantize_weights='w8a8' needs a model whose Dense "
-                    f"layers consume w8a8 kernels natively (the Llama "
-                    f"family: llama/mistral/qwen2); "
-                    f"{type(model).__name__} does not — use weight-only "
-                    f"'int8' instead")
-                self._wq_native = True
-                self.cfg = dataclasses.replace(self.cfg,
-                                               weight_quant="w8a8")
-                self.model = type(model)(self.cfg)
-                if self._unroll_params:
-                    # unroll scan-stacked [L, ...] kernels NOW: the
-                    # per-channel w8a8 format is 2-D-kernel only, so a
-                    # stacked tree would silently fall back to the
-                    # dequant path for every block kernel
-                    from deepspeed_tpu.inference.common import \
-                        unroll_scan_params
-
-                    params = (
-                        {"params": unroll_scan_params(params["params"])}
-                        if isinstance(params, dict) and "params" in params
-                        else unroll_scan_params(params))
-                    self._unroll_params = False
-            # unbox flax Partitioned metadata FIRST: the quantizer's
-            # leaf-name check reads path tails, which inside a metadata
-            # box are the box's own keys — boxed trees would silently
-            # pass through unquantized
-            if tp_lib.has_partitioning(params):
-                params = tp_lib.unbox_params(params)
-            params, b0, b1 = quantize_param_tree(params, quantize_weights)
-            params = jax.device_put(params)
-            log_dist(f"ragged engine weights -> {quantize_weights}"
-                     f"{' (native int8 dots)' if self._wq_native else ''}: "
-                     f"{b0 / 2**20:.1f} MiB -> {b1 / 2**20:.1f} MiB "
-                     f"({b0 / max(b1, 1):.2f}x)", ranks=[0])
-        self.params = self._place_params(params)
-
-        self.allocator = PageAllocator(self.num_pages, self.page_size)
-        self.page_table = np.full((max_seqs, self.pages_per_seq), -1,
-                                  np.int32)
-        self.cache = self._init_cache()
+        draft_cache = None
         if self.spec_mode == "draft":
-            if draft_model is None:
-                raise ValueError(
-                    "speculation.mode='draft' needs a draft model: pass "
-                    "draft_model=<small same-vocab llama-family module> "
-                    "and draft_params=... (the config's "
-                    "speculation.draft_model preset name is for CLIs to "
-                    "construct one)")
-            assert self.tp <= 1, (
-                "draft-model speculation does not compose with "
-                "tensor-parallel serving yet")
-            dmcfg = getattr(draft_model, "config", None)
-            assert (dataclasses.is_dataclass(dmcfg) and
-                    hasattr(dmcfg, "rope_theta") and
-                    hasattr(dmcfg, "paged_decode")), (
-                "draft model must be a llama-family model-zoo module "
-                "(the ragged paged decode path's requirement)")
-            assert dmcfg.vocab_size == mcfg.vocab_size, (
-                f"draft vocab {dmcfg.vocab_size} != target vocab "
-                f"{mcfg.vocab_size} — speculative verify compares token "
-                "ids, the models must share a tokenizer")
-            self._draft_unroll = bool(getattr(dmcfg, "scan_layers",
-                                              False))
             # the draft pool defaults to the target pool's storage
             # format — self-draft speculation gets the same capacity
             # win unless the caller overrides draft_kv_cache_dtype
-            draft_fmt = (self.kv_cache_dtype
-                         if draft_kv_cache_dtype is None
-                         else str(draft_kv_cache_dtype))
-            assert draft_fmt in ("none", "int8", "fp8", "fp8_e4m3"), (
-                f"draft_kv_cache_dtype must be none|int8|fp8|fp8_e4m3, "
-                f"got {draft_fmt!r}")
-            self.draft_kv_cache_dtype = draft_fmt
-            self._draft_cfg = dataclasses.replace(
-                dmcfg, decode=True, ragged_decode=False,
-                paged_decode=True, max_cache_len=max_seq_len,
-                scan_layers=False, kv_page_size=self.page_size,
-                kv_num_pages=self.num_pages, tensor_parallel=False,
-                kv_cache_dtype=draft_fmt)
-            self._draft = type(draft_model)(self._draft_cfg)
-            from deepspeed_tpu.parallel import tensor_parallel as tp_lib
-            dparams = normalize_params(
-                draft_model, draft_params,
-                plain_model=type(draft_model)(dataclasses.replace(
-                    dmcfg, decode=False)))
-            if tp_lib.has_partitioning(dparams):
-                dparams = tp_lib.unbox_params(dparams)
-            self._draft_params = jax.device_put(dparams, self._device)
-            self._draft_cache = self._init_cache(self._draft)
+            self.draft_kv_cache_dtype = (
+                kv_cache_dtype if draft_kv_cache_dtype is None
+                else str(draft_kv_cache_dtype))
+            self._draft, self._draft_params, self._draft_unroll = \
+                programs.prepare_draft(
+                    draft_model, draft_params, self.cfg,
+                    kv_cache_dtype=self.draft_kv_cache_dtype, tp=self.tp,
+                    device=self._device)
+            draft_cache = programs.init_cache(
+                self._draft, max_seqs=max_seqs, width=self.T, **placement)
+        # the built programs, cached: one fused tick, one draft prefill,
+        # a greedy and a sampled variant of each block
+        self._step_fn = None
+        self._draft_prefill = None
+        self._decode_block_cache: Dict[bool, Any] = {}
+        self._spec_block_cache: Dict[bool, Any] = {}
+
+        # -- every form of a sequence's KV state: pool, tiers, index --
+        tier_cfg = KVTieringConfig.coerce(o["kv_tiering"])
+        self.kv = KVState(
+            programs.init_cache(self.model, max_seqs=max_seqs, width=self.T,
+                                **placement),
+            num_pages=self.num_pages, page_size=self.page_size,
+            max_seqs=max_seqs, max_seq_len=max_seq_len,
+            kv_reserve=kv_reserve, tier_cfg=tier_cfg,
+            pfx_cfg=PrefixCacheConfig.coerce(o["prefix_cache"]),
+            host_stats=self.host_stats,
+            request_latency=self.request_latency, draft_cache=draft_cache)
+
         self._uid = itertools.count()
         self.waiting: Deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * max_seqs
         self.finished: List[Request] = []
         self._unclaimed: Dict[int, np.ndarray] = {}
-        self._step_fn = None
-        self._decode_block_cache: Dict[bool, Any] = {}
         # (device counts, span attributes) of dispatched expert-layer
         # programs whose counts no fetch has brought to the host yet
         self._moe_pending: List[Tuple[Any, Dict[str, Any]]] = []
-        self._last_tokens = np.zeros((max_seqs,), np.int32)
-        # streaming cursor: generated-token count already reported per
-        # uid (stream_deltas); cancels counts cancellations at any stage
+        # each slot's pending token: sampled, not yet written to the pool
+        self.last_tokens = np.zeros((max_seqs,), np.int32)
+        # generated-token count already reported per uid (stream_deltas)
         self._stream_cursor: Dict[int, int] = {}
         self.cancels = 0
         # disaggregated serving: sessions whose prefill (+ first token)
@@ -647,111 +408,41 @@ class RaggedInferenceEngineV2:
         self._handoff_ready: List[Request] = []
         self.handoffs = 0              # sessions exported to a decoder
         self.handoff_folds = 0         # handoffs degraded to re-prefill
-
-        # -- tiered KV spill store (HBM -> host RAM -> NVMe) --
-        from deepspeed_tpu.inference.config import KVTieringConfig
-
-        if kv_tiering is None:
-            kv_tiering = KVTieringConfig()
-        elif isinstance(kv_tiering, dict):
-            kv_tiering = KVTieringConfig(**{"enabled": True, **kv_tiering})
-        self._tier_cfg = kv_tiering
-        self.tiering = None
-        self._tier_gather = None       # jitted fixed-shape page gather
-        self._tier_scatter = None      # jitted fixed-shape page scatter
         self._lc = None                # lazy LongContextDriver
         # queued spilled sequences prefetched ahead of a possible reap
-        # (config field, online-tunable via the kv.prefetch_lookahead
-        # knob — was a hardcoded islice(waiting, 8))
-        self.prefetch_lookahead = max(
-            int(getattr(kv_tiering, "prefetch_lookahead", 8)), 1)
-        self._sched_seq = 0            # step counter for victim coldness
+        # (online-tunable via the kv.prefetch_lookahead knob)
+        self.prefetch_lookahead = max(int(tier_cfg.prefetch_lookahead), 1)
+        # victim coldness: step counter, each slot's last scheduled step
+        self._sched_seq = 0
         self._last_sched = np.zeros((max_seqs,), np.int64)
-        self.spills = 0                # sessions spilled to the tiers
-        self.restores = 0              # sessions restored bit-identically
-        if kv_tiering.enabled:
-            assert self.kv_reserve == "on_demand", (
-                "kv_tiering requires kv_reserve='on_demand' — the spill "
-                "tiers ARE the on-demand model's overflow story; a "
-                "worst-case reservation could never admit what tiering "
-                "holds")
-            from deepspeed_tpu.inference.kv_tiering import TieredKVStore
-
-            leaves, self._cache_treedef = jax.tree_util.tree_flatten(
-                self.cache)
-            assert all(leaf.shape[0] == self.num_pages
-                       for leaf in leaves), (
-                "every paged-KV cache leaf must lead with the page dim")
-            self.tiering = TieredKVStore(
-                page_shapes=[leaf.shape[1:] for leaf in leaves],
-                page_dtypes=[np.dtype(leaf.dtype) for leaf in leaves],
-                pages_per_seq=self.pages_per_seq,
-                host_pages=kv_tiering.host_pages,
-                nvme_pages=kv_tiering.nvme_pages,
-                nvme_dir=kv_tiering.nvme_dir,
-                use_odirect=kv_tiering.use_odirect,
-                prefetch=kv_tiering.prefetch,
-                verify=kv_tiering.verify,
-                checksum=kv_tiering.checksum,
-                max_reread=kv_tiering.max_reread,
-                nvme_fail_threshold=kv_tiering.nvme_fail_threshold,
-                probe_every=kv_tiering.probe_every)
-        # -- cross-request prefix cache over the paged pool --
-        from deepspeed_tpu.inference.config import PrefixCacheConfig
-
-        if prefix_cache is None:
-            prefix_cache = PrefixCacheConfig()
-        elif isinstance(prefix_cache, bool):
-            prefix_cache = PrefixCacheConfig(enabled=prefix_cache)
-        elif isinstance(prefix_cache, dict):
-            prefix_cache = PrefixCacheConfig(
-                **{"enabled": True, **prefix_cache})
-        self._pfx_cfg = prefix_cache
-        self._pfx: Optional[PrefixCacheIndex] = None
-        self._cow_jit = None           # jitted fixed-shape page copy
-        if prefix_cache.enabled:
-            self._pfx = PrefixCacheIndex(
-                self.allocator, self.page_size,
-                max_entries=prefix_cache.max_index_entries,
-                min_match_pages=prefix_cache.min_match_pages)
-            if self.tiering is not None:
-                # under pool pressure, cold single-ref prefix pages
-                # demote into the tier store keyed by prefix hash (one
-                # restore serves every waiter) instead of being dropped
-                self._pfx.demote = self._pfx_demote
-                self._pfx.drop_spilled = self.tiering.drop
-        tier_note = ""
-        if self.tiering is not None:
-            tier_note = (f" kv_tiering=host:{kv_tiering.host_pages}"
-                         f"+nvme:{kv_tiering.nvme_pages}p")
-        if self._pfx is not None:
-            tier_note += (f" prefix_cache=max:"
-                          f"{prefix_cache.max_index_entries}"
-                          f"/min:{prefix_cache.min_match_pages}p")
         log_dist(
             f"RaggedInferenceEngineV2: max_seqs={max_seqs} "
             f"max_seq_len={max_seq_len} prefill_chunk={prefill_chunk} "
             f"pages={self.num_pages}x{self.page_size} tp={self.tp} "
             f"decode_block={self.decode_block_size} "
             f"pipeline={self.pipeline} depth={self.async_depth} "
-            f"harvest={self.harvest_interval} "
-            f"spec={self.spec_mode}"
+            f"harvest={self.harvest_interval} spec={self.spec_mode}"
             f"{f'/k={self.spec_k}' if self.spec_mode != 'off' else ''}"
-            f"{tier_note} "
+            f"{self.kv.describe()} "
             f"(paged KV, fused SplitFuse step)", ranks=[0])
 
-        # -- closed-loop control plane (deepspeed_tpu.control) --
-        # Ticks on this host loop (step() counts engine steps); no
-        # thread of its own.  DSTPU_CONTROL=0 disarms regardless of
-        # config, leaving the structurally pre-control engine.
-        from deepspeed_tpu.inference.config import ControlConfig
+        self._arm_control(ControlConfig.coerce(o["control"]))
 
-        if control is None:
-            control = ControlConfig()
-        elif isinstance(control, bool):
-            control = ControlConfig(enabled=control)
-        elif isinstance(control, dict):
-            control = ControlConfig(**{"enabled": True, **control})
+    # what serving/, control/, the benchmark and tests read of the KV
+    # state; everything else goes through ``self.kv``
+    allocator = property(lambda self: self.kv.allocator)
+    page_table = property(lambda self: self.kv.page_table)
+    cache = property(lambda self: self.kv.cache)
+    tiering = property(lambda self: self.kv.tiering)
+    prefix_index = property(lambda self: self.kv.pfx)
+    spills = property(lambda self: self.kv.spills)
+    restores = property(lambda self: self.kv.restores)
+
+    def _arm_control(self, control) -> None:
+        """The closed-loop control plane (:mod:`deepspeed_tpu.control`):
+        it ticks on this host loop (``step()`` counts engine steps), with
+        no thread of its own.  ``DSTPU_CONTROL=0`` disarms regardless of
+        config, leaving the structurally pre-control engine."""
         self._control_cfg = control
         self._controller = None
         self._control_steps = 0
@@ -770,76 +461,14 @@ class RaggedInferenceEngineV2:
                         log_dist(
                             f"control plane seeded from host profile "
                             f"{prof.key}: {applied}", ranks=[0])
-                rules = []
-                if self.tiering is not None:
-                    rules.append(prefetch_rule())
                 self._controller = Controller(
                     knobs, engine_signal_feed(self),
-                    objective=control.objective,
-                    settle=control.settle,
-                    hysteresis=control.hysteresis,
-                    cooldown=control.cooldown,
-                    guard_window=control.guard_window,
-                    guard_reverts=control.guard_reverts,
-                    freeze=control.freeze, smooth=control.smooth,
-                    rules=rules)
-
-    # -- parameter / cache placement (TP) --------------------------------
-
-    def _place_params(self, params):
-        """TP-shard (AutoTP name rules / flax metadata) over the `tensor`
-        mesh axis, mirroring the v1 engine; replicate otherwise."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        from deepspeed_tpu.parallel import tensor_parallel as tp_lib
-
-        if self.tp <= 1:
-            if tp_lib.has_partitioning(params):
-                params = tp_lib.unbox_params(params)
-            if self._device is not None:
-                params = jax.device_put(params, self._device)
-            return params
-        if tp_lib.has_partitioning(params):
-            specs = tp_lib.extract_partition_specs(
-                {"params": params}, self.mesh.axis_names)["params"]
-            params = tp_lib.unbox_params(params)
-            # training-oriented metadata (e.g. a MoE bank's `expert` axis
-            # with no `tensor` entries) doesn't shard a TP serving mesh —
-            # fall back to AutoTP name rules
-            if not any("tensor" in tuple(s)
-                       for s in jax.tree_util.tree_leaves(
-                           specs, is_leaf=lambda x: isinstance(x, P))):
-                specs = None
-        else:
-            specs = None
-        if specs is None:
-            specs = tp_lib.auto_tp_specs(params, self.tp)
-            log_dist("ragged engine AutoTP: inferred tensor-parallel "
-                     "sharding from parameter names", ranks=[0])
-        self._param_shardings = jax.tree_util.tree_map(
-            lambda s: NamedSharding(self.mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, P))
-        return jax.tree_util.tree_map(
-            lambda x, sh: jax.device_put(jnp.asarray(x), sh), params,
-            self._param_shardings)
-
-    def _cache_sharding(self, leaf_shape):
-        """KV page pools shard their combined-head dim over `tensor`
-        (reference v2 KV sharding: heads split over the TP group); the
-        quantized pools' [P, page, 2Hkv] scale buffers shard the same
-        head dim.  A one-device replica slice pins them to its device;
-        ``None`` leaves the default placement."""
-        from jax.sharding import (NamedSharding, PartitionSpec as P,
-                                  SingleDeviceSharding)
-
-        if self.tp <= 1:
-            return (SingleDeviceSharding(self._device)
-                    if self._device is not None else None)
-        if len(leaf_shape) == 4:
-            return NamedSharding(self.mesh, P(None, None, "tensor", None))
-        if len(leaf_shape) == 3:
-            return NamedSharding(self.mesh, P(None, None, "tensor"))
-        return None
+                    rules=([prefetch_rule()] if self.tiering is not None
+                           else []),
+                    **{k: getattr(control, k) for k in (
+                        "objective", "settle", "hysteresis", "cooldown",
+                        "guard_window", "guard_reverts", "freeze",
+                        "smooth")})
 
     # -- request API ----------------------------------------------------
 
@@ -873,61 +502,19 @@ class RaggedInferenceEngineV2:
                 f"{total} exceeds the engine token budget "
                 f"max_seq_len={self.max_seq_len} — the request can never "
                 "be scheduled; shorten the prompt or raise max_seq_len")
-        if self.tiering is None:
-            if self.allocator.pages_for(total) > self.num_pages - 1:
-                raise ValueError(
-                    f"request needs {self.allocator.pages_for(total)} KV "
-                    f"pages but the engine owns {self.num_pages - 1} "
-                    "usable pages — even after evicting every other "
-                    "sequence it could never be scheduled; raise "
-                    "num_pages")
-        else:
-            # two separate bounds, named separately in the rejection:
-            # (1) with long_context, the RESIDENT-WINDOW need (sinks +
-            # recent window + staging slack) must fit HBM — without it
-            # the working-set bound stays an admission-time check, so
-            # tiering keeps accepting requests beyond HBM whose others
-            # spill (max_new_tokens is a budget, not a promise); (2)
-            # the COMBINED-TIER total must fit HBM + host + NVMe.
-            total_pages = self.allocator.pages_for(total)
-            usable = self.num_pages - 1
-            lc = bool(self._tier_cfg.long_context)
-            if lc:
-                resident_need = min(total_pages,
-                                    self._lc_resident_pages())
-                if resident_need > usable:
-                    raise ValueError(
-                        f"request needs {resident_need} HBM-resident KV "
-                        "pages (the partial-residency window: "
-                        "sink_pages + window_pages + chunk_pages + 1) "
-                        f"but the HBM tier owns {usable} usable pages — "
-                        "raise num_pages or shrink the kv_tiering "
-                        "sink_pages/window_pages/chunk_pages knobs")
-            cap = usable + self.tiering.budget_pages
-            if total_pages > cap:
-                raise ValueError(
-                    f"request needs {total_pages} KV pages in total but "
-                    f"the combined tiers — HBM ({usable} usable) + host "
-                    f"({self.tiering.host_budget}) + NVMe "
-                    f"({self.tiering.nvme_budget}) — hold only {cap} "
-                    "— it could never be scheduled; raise num_pages or "
-                    "the kv_tiering host_pages/nvme_pages budgets")
+        self.kv.check_capacity(total)
 
     def put_request(self, prompt, **kw) -> int:
-        """Queue a request; raises ``ValueError`` AT SUBMIT TIME for a
-        request that could never be scheduled (a prompt + budget beyond
-        ``max_seq_len``, or needing more KV pages than the whole pool
-        holds even after evicting every other sequence) — admitting one
-        would deadlock the FIFO queue behind an unschedulable head.
-        (``ValueError``, not ``assert``: these guard USER input and must
-        stay loud under ``python -O``.)"""
+        """Queue a request; raises ``ValueError`` AT SUBMIT TIME
+        (:meth:`validate_request`) for one that could never be
+        scheduled — admitting it would deadlock the FIFO queue behind an
+        unschedulable head.  (``ValueError``, not ``assert``: these
+        guard USER input and must stay loud under ``python -O``.)"""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         max_new = int(kw.get("max_new_tokens", 64))
         self.validate_request(prompt, max_new)
         req = Request(uid=next(self._uid), prompt=prompt, **kw)
-        if (self.tiering is not None and self._tier_cfg.long_context
-                and self.allocator.pages_for(prompt.size + max_new)
-                > self.num_pages - 1):
+        if self.kv.partially_resident(prompt.size + max_new):
             # the full KV cannot be device-resident: decode under the
             # windowed partial-residency policy (LongContextDriver)
             req.lc = True
@@ -1001,53 +588,27 @@ class RaggedInferenceEngineV2:
         dv = self._dev
         if dv is not None and any(r.uid == uid for r in dv["reqs"]):
             self._pipeline_harvest(teardown=True)
-        # parked in the waiting queue: never admitted, an evicted
-        # continuation, or spilled out to the tiers
-        for r in list(self.waiting):
-            if r.uid != uid:
-                continue
-            self.waiting.remove(r)
-            if r.spilled is not None:
-                # release the spill-holds pinning shared prefix pages
-                # resident, then the tiered payload itself (the same
-                # cleanup export_parked runs when folding a session)
-                for p in r.spilled.get("shared_pages", ()):
-                    self.allocator.decref(p)
-                if self.tiering is not None:
-                    self.tiering.drop(r.uid)
-                stage = "spilled"
-            else:
-                stage = "queued"
-            self._drop_lc_parked(r)
-            break
-        if stage is None:
-            # parked for a prefill->decode handoff the router never
-            # collected: release like a waiting spilled session
-            for r in list(self._handoff_ready):
-                if r.uid != uid:
-                    continue
-                self._handoff_ready.remove(r)
-                if r.spilled is not None:
-                    for p in r.spilled.get("shared_pages", ()):
-                        self.allocator.decref(p)
-                    if self.tiering is not None:
-                        self.tiering.drop(r.uid)
-                stage = "handoff"
-                break
+        # parked: in the waiting queue (never admitted, an evicted
+        # continuation, or spilled out to the tiers), or for a
+        # prefill->decode handoff the router never collected
+        for parked in (self.waiting, self._handoff_ready):
+            for r in [r for r in parked if r.uid == uid]:
+                parked.remove(r)
+                stage = ("handoff" if parked is self._handoff_ready
+                         else "spilled" if r.spilled is not None
+                         else "queued")
+                self.kv.forget(r)
         if stage is None:
             # resident in a slot (prefill or decode phase; LC sequences
             # tick outside the fused batch but park in slots the same)
-            for i, r in enumerate(self.slots):
+            for r in self.slots:
                 if r is None or r.uid != uid:
                     continue
                 stage = ("lc" if r.lc
                          else "prefill" if r.prefill_done < r.ctx_len
                          else "decode")
-                self._drop_lc_parked(r)
-                self.allocator.free(i)
-                self.page_table[i, :] = -1
-                self.slots[i] = None
-                self._draft_len[i] = 0
+                self.kv.forget(r)
+                self._unseat(r)
                 break
         if stage is None:
             # reaped but not yet collected: drop the pending output
@@ -1068,14 +629,6 @@ class RaggedInferenceEngineV2:
             trace.event("request_cancel", cat="request", uid=int(uid),
                         stage=stage)
         return stage
-
-    def _drop_lc_parked(self, r: Request) -> None:
-        """Release a long-context request's parked middle page groups
-        (the tier keys ``_reap`` would drop at finish)."""
-        if self.tiering is not None:
-            for g in range(r.lc_parked):
-                self.tiering.drop(f"mid-{r.uid}-{g}")
-        r.lc_parked = 0
 
     def has_work(self) -> bool:
         return bool(self.waiting) or any(s is not None for s in self.slots)
@@ -1116,78 +669,23 @@ class RaggedInferenceEngineV2:
     def export_parked(self) -> List[Dict[str, Any]]:
         """Pop every PARKED session (the waiting queue: not yet
         admitted, spilled out of the pool, or queued as a re-prefill
-        continuation) and return portable session blobs for
-        :meth:`import_parked` on another replica.  A spilled session's
-        private pages travel in SPILL FORMAT (packed bytes + the
-        spill-time digests via ``TieredKVStore.export_spilled``), so
-        the receiver's restore verifies them end-to-end.  Shared-prefix
-        pages are rows in THIS engine's HBM and cannot travel — a
-        session holding any folds to a re-prefill continuation
-        (``ctx = prompt + generated``), which is output-identical under
-        greedy decode, just re-paying its prefill."""
+        continuation) and return portable session blobs
+        (:meth:`KVState.export`) for :meth:`import_parked` on another
+        replica.  A session that folds to a re-prefill continuation on
+        the way is output-identical under greedy decode, just re-paying
+        its prefill."""
         sessions: List[Dict[str, Any]] = []
-        while self.waiting:
-            r = self.waiting.popleft()
-            blob = self._session_blob(r)
-            if trace.enabled:
-                trace.event("request_export", cat="request", uid=r.uid,
-                            spilled=blob["spill"] is not None)
-            sessions.append(blob)
         # pending prefill->decode handoffs the router never pulled ride
         # the same retirement: they are parked sessions like any other
-        for r in self._handoff_ready:
-            blob = self._session_blob(r)
+        for r in itertools.chain(self.waiting, self._handoff_ready):
+            blob = self.kv.export(r)
             if trace.enabled:
                 trace.event("request_export", cat="request", uid=r.uid,
                             spilled=blob["spill"] is not None)
             sessions.append(blob)
+        self.waiting.clear()
         self._handoff_ready = []
         return sessions
-
-    def _session_blob(self, r: Request) -> Dict[str, Any]:
-        """Portable session blob for ``import_parked`` on another
-        replica (shared by :meth:`export_parked` and
-        :meth:`export_handoff`).  A spilled session's private pages
-        travel in spill format via ``TieredKVStore.export_spilled``;
-        one pinning shared-prefix pages (rows in THIS engine's HBM —
-        they cannot travel) folds to a re-prefill continuation."""
-        blob: Dict[str, Any] = {
-            "uid": int(r.uid),
-            "prompt": np.asarray(r.prompt, np.int32),
-            "max_new_tokens": int(r.max_new_tokens),
-            "eos_token_id": r.eos_token_id,
-            "do_sample": bool(r.do_sample),
-            "temperature": float(r.temperature),
-            "top_k": int(r.top_k),
-            "top_p": float(r.top_p),
-            "generated": [int(t) for t in r.generated],
-            "ctx": (None if r.ctx is None
-                    else np.asarray(r.ctx, np.int32)),
-            "prefill_done": int(r.prefill_done),
-            "spill": None}
-        if r.spilled is not None:
-            shared = [int(p) for p in r.spilled.get("shared_pages",
-                                                    ())]
-            n_priv = int(r.spilled.get("n_pages", 0))
-            holds = (self.tiering is not None
-                     and self.tiering.holds(r.uid))
-            if shared or (n_priv > 0 and not holds):
-                # fold to a re-prefill continuation; release the
-                # spill-holds and the orphaned payload
-                for p in shared:
-                    self.allocator.decref(p)
-                if self.tiering is not None:
-                    self.tiering.drop(r.uid)
-                blob["ctx"] = np.concatenate(
-                    [r.prompt, np.asarray(r.generated, np.int32)])
-                blob["prefill_done"] = 0
-            else:
-                blob["spill"] = {
-                    "last_tok": int(r.spilled["last_tok"]),
-                    "live_tokens": int(r.spilled["live_tokens"]),
-                    "payload": (self.tiering.export_spilled(r.uid)
-                                if n_priv > 0 else None)}
-        return blob
 
     def import_parked(self, sessions: List[Dict[str, Any]]) -> List[int]:
         """Receiving half of the handoff: install each exported session
@@ -1212,27 +710,8 @@ class RaggedInferenceEngineV2:
             req.ctx = None if ctx is None else np.asarray(ctx, np.int32)
             req.prefill_done = int(s.get("prefill_done", 0))
             sp = s.get("spill")
-            if sp is not None:
-                payload = sp.get("payload")
-                installed = payload is None
-                if payload is not None and self.tiering is not None:
-                    try:
-                        self.tiering.import_spilled(req.uid, payload)
-                        installed = True
-                    except (ValueError, RuntimeError):
-                        installed = False
-                if installed:
-                    req.spilled = {
-                        "last_tok": int(sp["last_tok"]),
-                        "n_pages": (int(payload["n_pages"])
-                                    if payload is not None else 0),
-                        "live_tokens": int(sp["live_tokens"]),
-                        "shared_pages": []}
-                else:
-                    req.ctx = np.concatenate(
-                        [req.prompt,
-                         np.asarray(req.generated, np.int32)])
-                    req.prefill_done = 0
+            if sp is not None and not self.kv.adopt(sp, req):
+                req.fold()
             self.waiting.append(req)
             self.request_latency.on_submit(req.uid, int(req.prompt.size))
             if trace.enabled:
@@ -1254,7 +733,7 @@ class RaggedInferenceEngineV2:
         path, so greedy outputs stay bit-identical to a fused tick."""
         sessions: List[Dict[str, Any]] = []
         for r in self._handoff_ready:
-            blob = self._session_blob(r)
+            blob = self.kv.export(r)
             self.handoffs += 1
             self._stream_cursor.pop(r.uid, None)
             self.request_latency.on_handoff_out(r.uid)
@@ -1375,7 +854,7 @@ class RaggedInferenceEngineV2:
                 self.prefetch_lookahead = max(int(v), 1)
 
             def _set_window(v):
-                self._tier_cfg.window_pages = max(int(v), 1)
+                self.kv.tier_cfg.window_pages = max(int(v), 1)
 
             reg.register(Knob(
                 "kv.prefetch_lookahead",
@@ -1384,7 +863,7 @@ class RaggedInferenceEngineV2:
                 doc="queued spilled sequences prefetched ahead of reap"))
             reg.register(Knob(
                 "kv.window_pages",
-                lambda: int(self._tier_cfg.window_pages), _set_window,
+                lambda: int(self.kv.tier_cfg.window_pages), _set_window,
                 lo=1, hi=64, step=1, kind="int",
                 doc="recent HBM-resident pages per partially-resident "
                     "sequence (long-context residency window)"))
@@ -1399,9 +878,9 @@ class RaggedInferenceEngineV2:
         out = self.host_stats.serving_stages()
         if self.tiering is not None:
             out["kv_tiering"] = self.tiering.stats()
-        if self._pfx is not None:
+        if self.kv.pfx is not None:
             st = self.host_stats
-            pc = self._pfx.stats()
+            pc = self.kv.pfx.stats()
             pc.update(hit_requests=st.prefix_hits,
                       miss_requests=st.prefix_misses,
                       hit_tokens=st.prefix_hit_tokens,
@@ -1416,7 +895,7 @@ class RaggedInferenceEngineV2:
 
             route = kv_dequant_path(head_dim)
             out["kv_quant"] = kv_quant_block(
-                self.cache, self.kv_cache_dtype, route, self.num_pages)
+                self.kv.cache, self.kv_cache_dtype, route, self.num_pages)
         else:
             route = paged_attention_path(head_dim)
         # the attention read route this backend gives this pool: a Pallas
@@ -1468,27 +947,17 @@ class RaggedInferenceEngineV2:
         """Release tier-store resources (AIO handle, staging buffers,
         digest pool, spill files) and prefix-cache holds.  Idempotent;
         a no-op with tiering and the prefix cache off."""
-        for r in itertools.chain(self.waiting, self._handoff_ready):
-            if r.spilled is not None:
-                for p in r.spilled.get("shared_pages", ()):
-                    self.allocator.decref(int(p))
-                r.spilled["shared_pages"] = []
-        if self._pfx is not None:
-            self._pfx.clear()
-            self._pfx = None
-        if self.tiering is not None:
-            self.tiering.close()
-            self.tiering = None
+        self.kv.close(itertools.chain(self.waiting, self._handoff_ready))
 
     # -- host<->device funnels (every transfer is counted/timed) ---------
 
-    def _upload(self, x):
+    def upload(self, x):
         """Host -> device metadata transfer.  The pipelined decode loop
         must NOT call this in steady state (metadata is device-resident;
         ``host_stats.meta_uploads`` asserts it in tests)."""
-        return self._upload_all((x,))[0]
+        return self.upload_all((x,))[0]
 
-    def _upload_all(self, arrays) -> List[Any]:
+    def upload_all(self, arrays) -> List[Any]:
         """One ``upload`` bracket around the transfers of one dispatch's
         metadata (each array still its own ``device_put``)."""
         with self.host_stats.stage("upload"):
@@ -1502,7 +971,7 @@ class RaggedInferenceEngineV2:
             self.rng, sub = jax.random.split(self.rng)
         return sub
 
-    def _fetch(self, tree):
+    def fetch(self, tree):
         """Blocking device -> host fetch — the serving loop's only sync
         point (``host_stats.blocking_gets`` counts them)."""
         with self.host_stats.stage("device"):
@@ -1537,179 +1006,33 @@ class RaggedInferenceEngineV2:
             jax.block_until_ready(block[0])
         return block
 
-    # -- compiled fused step ---------------------------------------------
+    # -- the compiled programs (built by programs.py, cached here) --------
 
-    def _init_cache(self, model=None):
-        """Zeroed page buffers for every layer (eval_shape, no params);
-        ``model`` defaults to the target (the draft model gets its own,
-        smaller, pool tree)."""
-        model = model if model is not None else self.model
-        dummy_meta = self._device_meta(
-            np.zeros((self.max_seqs,), np.int32),
-            np.full((self.max_seqs, self.pages_per_seq), -1, np.int32),
-            np.zeros((self.max_seqs + 1,), np.int32),
-            np.zeros((1,), np.int32),
-            np.zeros((self.T,), np.int32))
-        ids = jnp.zeros((1, self.T), jnp.int32)
-        pos = jnp.zeros((1, self.T), jnp.int32)
-
-        def _init():
-            return model.init(jax.random.PRNGKey(0), ids,
-                              positions=pos, ragged_meta=dummy_meta)
-
-        shapes = jax.eval_shape(_init)
-        assert "cache" in shapes
-
-        def make(s):
-            # allocated where it lives: a pool is most of a chip's memory
-            return jnp.zeros(s.shape, s.dtype,
-                             device=self._cache_sharding(s.shape))
-
-        return jax.tree_util.tree_map(make, shapes["cache"])
-
-    @staticmethod
-    def _device_meta(kv_lens, page_indices, cu_q_lens, num_seqs,
-                     new_kv_dest):
-        return {"kv_lens": jnp.asarray(kv_lens),
-                "page_indices": jnp.asarray(page_indices),
-                "cu_q_lens": jnp.asarray(cu_q_lens),
-                "num_seqs": jnp.asarray(num_seqs),
-                "new_kv_dest": jnp.asarray(new_kv_dest)}
-
-    def _fused_step_fn(self):
-        """ONE jitted program for every tick: fused decode + prefill
-        chunk(s) forward, paged-KV update, and logits row selection."""
-        if self._step_fn is not None:
-            return self._step_fn
-        from deepspeed_tpu.inference.common import (logits_of,
-                                                    unroll_scan_params)
-
-        model = self.model
-        unroll = self._unroll_params
-        wq = self._wq
-        native = self._wq_native
-
-        def run(params, cache, token_ids, positions, kv_lens, page_indices,
-                cu_q_lens, num_seqs, new_kv_dest, sample_rows):
-            if wq:
-                from deepspeed_tpu.inference.quantization import \
-                    dequantize_param_tree
-
-                params = dequantize_param_tree(params, native_w8a8=native)
-            if unroll:
-                params = unroll_scan_params(params)
-            meta = {"kv_lens": kv_lens, "page_indices": page_indices,
-                    "cu_q_lens": cu_q_lens, "num_seqs": num_seqs,
-                    "new_kv_dest": new_kv_dest,
-                    # rows past the last sequence's end are padding
-                    "live": (jnp.arange(token_ids.shape[1]) <
-                             cu_q_lens[num_seqs[0]])}
-            out, vars_ = model.apply(
-                {"params": params, "cache": cache}, token_ids,
-                positions=positions, mutable=["cache", "moe_stats"],
-                ragged_meta=meta)
-            logits = logits_of(out)[0]                      # [T, V]
-            sel = jnp.take(logits, sample_rows, axis=0)     # [max_seqs, V]
-            return sel, vars_["cache"], _moe_counts(vars_)
-
-        # distinguishable XLA program name ("jit_ragged_fused_step") so
-        # the profiler bridge can attribute device time per program
-        run.__name__ = run.__qualname__ = "ragged_fused_step"
-        self._step_fn = jax.jit(run, donate_argnums=(1,))
+    def fused_step(self):
+        """The ONE program of every SplitFuse tick
+        (:func:`~deepspeed_tpu.inference.v2.programs.build_fused_step`)."""
+        if self._step_fn is None:
+            self._step_fn = programs.build_fused_step(
+                self.model, **self.param_form)
         return self._step_fn
 
-    # -- the on-device decode block --------------------------------------
-
-    def _decode_block_fn(self, sampled: bool):
-        """K decode ticks per dispatch: ``lax.scan`` over fused
-        [1, max_seqs] decode forwards with on-device sampling.  The host
-        round trip the reference pays per generated token
-        (``engine_v2.py:107`` put -> schedule -> logits) amortizes to 1/K.
-        Two variants compile: pure-greedy (no sort) and per-seq sampled."""
-        if sampled in self._decode_block_cache:
-            return self._decode_block_cache[sampled]
-        from deepspeed_tpu.inference.common import (logits_of,
-                                                    unroll_scan_params)
-
-        model = self.model
-        unroll = self._unroll_params
-        S = self.max_seqs
-        K = self.decode_block_size
-        page = self.page_size
-        max_len = self.max_seq_len
-
-        wq = self._wq
-        native = self._wq_native
-        sample_base = self._sample_base
-
-        def run(params, cache, last_tok, pos, active, remaining,
-                page_table, eos_ids, do_sample, temperature, top_k, top_p,
-                seeds, rng):
-            if wq:
-                from deepspeed_tpu.inference.quantization import \
-                    dequantize_param_tree
-
-                params = dequantize_param_tree(params, native_w8a8=native)
-            if unroll:
-                params = unroll_scan_params(params)
-
-            def tick(carry, _):
-                cache, last_tok, pos, active, remaining, rng = carry
-                dest_page = jnp.take_along_axis(
-                    jnp.maximum(page_table, 0),
-                    (pos // page)[:, None], axis=1)[:, 0]
-                dest = jnp.where(active, dest_page * page + pos % page, 0)
-                kv_lens = jnp.where(active, pos + 1, 1)
-                meta = {"kv_lens": kv_lens,
-                        "page_indices": page_table,
-                        "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
-                        "num_seqs": jnp.asarray([S], jnp.int32),
-                        "new_kv_dest": dest, "live": active}
-                out, vars_ = model.apply(
-                    {"params": params, "cache": cache}, last_tok[None],
-                    positions=jnp.where(active, pos, 0)[None],
-                    mutable=["cache", "moe_stats"], ragged_meta=meta)
-                logits = logits_of(out)[0]              # [S, V]
-                moe = _moe_counts(vars_)
-                rng, _ = jax.random.split(rng)
-                # position-keyed per-row keys: the draw at cache position
-                # `pos` is the same bits the fused tick's host sampler
-                # would use, no matter how this block was scheduled
-                sub = (position_keys(sample_base, seeds, pos)
-                       if sampled else None)
-                nxt = sample_logits_batched(
-                    logits, sub, do_sample,
-                    temperature, top_k, top_p)
-                produced = active
-                nxt = jnp.where(active, nxt, last_tok)
-                hit_eos = active & (nxt == eos_ids)
-                remaining = remaining - produced.astype(jnp.int32)
-                pos = jnp.where(active, pos + 1, pos)
-                active = (active & ~hit_eos & (remaining > 0) &
-                          (pos + 1 < max_len))
-                return (vars_["cache"], nxt, pos, active, remaining,
-                        rng), (nxt, produced, moe)
-
-            carry, (toks, mask, moe) = jax.lax.scan(
-                tick, (cache, last_tok, pos, active, remaining, rng),
-                length=K)
-            cache, last_tok, pos, active, remaining, rng = carry
-            # the full carry returns so the pipelined host path can keep
-            # it device-resident across dispatches (no re-upload)
-            return (cache, last_tok, pos, active, remaining, toks, mask,
-                    None if moe is None else _fold_moe_counts(moe))
-
-        run.__name__ = run.__qualname__ = "ragged_decode_block"
-        fn = jax.jit(run, donate_argnums=(1,))
-        self._decode_block_cache[sampled] = fn
-        return fn
+    def decode_block(self, sampled: bool):
+        """The on-device decode block, greedy or ``sampled``
+        (:func:`~deepspeed_tpu.inference.v2.programs.build_decode_block`)."""
+        if sampled not in self._decode_block_cache:
+            self._decode_block_cache[sampled] = programs.build_decode_block(
+                self.model, sampled=sampled, max_seqs=self.max_seqs,
+                block=self.decode_block_size, page_size=self.page_size,
+                max_seq_len=self.max_seq_len,
+                sample_base=self._sample_base, **self.param_form)
+        return self._decode_block_cache[sampled]
 
     def _block_arrays(self, reqs: List[Request]):
         """Host numpy decode-block state for ``reqs`` (shared by the
         unpipelined per-block rebuild and the pipelined loop's one-time
         entry upload)."""
         S = self.max_seqs
-        last_tok = np.asarray(self._last_tokens, np.int32)
+        last_tok = np.asarray(self.last_tokens, np.int32)
         pos = np.zeros((S,), np.int32)
         active = np.zeros((S,), bool)
         remaining = np.zeros((S,), np.int32)
@@ -1777,34 +1100,6 @@ class RaggedInferenceEngineV2:
                 hist[r.slot, :L] = seq[:L]
         return hist
 
-    def _draft_prefill_fn(self):
-        """ONE compiled chunked prefill program for the draft model's
-        paged KV catch-up (fixed [1, prefill_chunk] shape, one sequence
-        per call — entry-time cost, not steady-state)."""
-        if self._draft_prefill is not None:
-            return self._draft_prefill
-        from deepspeed_tpu.inference.common import unroll_scan_params
-
-        draft = self._draft
-        dunroll = self._draft_unroll
-
-        def run(dparams, dcache, ids, positions, kv_lens, page_row, cu,
-                dest):
-            if dunroll:
-                dparams = unroll_scan_params(dparams)
-            meta = {"kv_lens": kv_lens, "page_indices": page_row,
-                    "cu_q_lens": cu,
-                    "num_seqs": jnp.asarray([1], jnp.int32),
-                    "new_kv_dest": dest}
-            _, vars_ = draft.apply(
-                {"params": dparams, "cache": dcache}, ids,
-                positions=positions, mutable=["cache"], ragged_meta=meta)
-            return vars_["cache"]
-
-        run.__name__ = run.__qualname__ = "draft_prefill"
-        self._draft_prefill = jax.jit(run, donate_argnums=(1,))
-        return self._draft_prefill
-
     def _draft_catchup(self, reqs: List[Request]) -> None:
         """Bring the draft model's paged KV up to each slot's cursor
         (positions ``< length - 1``; the drafter itself processes the
@@ -1817,7 +1112,10 @@ class RaggedInferenceEngineV2:
         C = self.prefill_chunk
         page = self.page_size
         with st.stage("draft"):
-            fn = self._draft_prefill_fn()
+            if self._draft_prefill is None:
+                self._draft_prefill = programs.build_draft_prefill(
+                    self._draft, dunroll=self._draft_unroll)
+            fn = self._draft_prefill
             for r in reqs:
                 target = r.length - 1
                 lo = int(self._draft_len[r.slot])
@@ -1839,8 +1137,8 @@ class RaggedInferenceEngineV2:
                         np.maximum(pg, 0) * page + pos % page,
                         0).astype(np.int32)
                     st.meta_uploads += 6
-                    self._draft_cache = fn(
-                        self._draft_params, self._draft_cache,
+                    self.kv.draft_cache = fn(
+                        self._draft_params, self.kv.draft_cache,
                         jnp.asarray(ids[None]), jnp.asarray(posc[None]),
                         jnp.asarray([lo + take], jnp.int32),
                         jnp.asarray(self.page_table[r.slot][None]),
@@ -1849,208 +1147,18 @@ class RaggedInferenceEngineV2:
                     lo += take
                 self._draft_len[r.slot] = target
 
-    def _spec_block_fn(self, sampled: bool):
-        """The fused draft+verify+accept block: ``decode_block_size``
-        speculative ticks per dispatch in a ``lax.scan``.  Each tick
-        drafts k tokens (n-gram lookup over the history buffer, or a
-        k-step draft-model sub-scan sharing the target's page table),
-        scores all k+1 positions with the TARGET in one ragged chunk
-        per slot, and accepts/rolls back device-side
-        (:func:`~deepspeed_tpu.inference.sampling.speculative_verify`).
-        Rollback is position rollback: rows written for rejected
-        positions sit between the new cursor and the old write frontier,
-        a span the NEXT tick's scatter fully overwrites before attention
-        can reach it."""
-        if sampled in self._spec_block_cache:
-            return self._spec_block_cache[sampled]
-        from deepspeed_tpu.inference.common import (logits_of,
-                                                    unroll_scan_params)
-
-        model = self.model
-        unroll = self._unroll_params
-        wq = self._wq
-        native = self._wq_native
-        mode = self.spec_mode
-        draft = self._draft
-        dunroll = self._draft_unroll
-        S = self.max_seqs
-        Tt = self.decode_block_size
-        k = self.spec_k
-        K1 = k + 1
-        n = self.spec_ngram
-        page = self.page_size
-        pp = self.pages_per_seq
-        max_len = self.max_seq_len
-
-        def ngram_propose(hist, pos, last_tok):
-            """Prompt-lookup drafting: most recent earlier occurrence of
-            the trailing n-gram proposes the k tokens that followed it;
-            no match proposes the last token repeated (any proposal is
-            distribution-safe — bad drafts are simply rejected)."""
-            L = max_len
-            ar = jnp.arange(L, dtype=jnp.int32)
-            histp = jnp.pad(hist, ((0, 0), (0, n + k)))
-            tpos = jnp.clip(pos[:, None] - (n - 1) +
-                            jnp.arange(n, dtype=jnp.int32)[None, :],
-                            0, L - 1)
-            tail = jnp.take_along_axis(hist, tpos, axis=1)      # [S, n]
-            match = jnp.ones((S, L), bool)
-            for t in range(n):
-                match = match & (histp[:, t:t + L] == tail[:, t:t + 1])
-            valid = ar[None, :] <= (pos[:, None] - n)
-            score = jnp.where(match & valid, ar[None, :] + 1, 0)
-            best = jnp.argmax(score, axis=1)       # most recent match
-            found = jnp.any(match & valid, axis=1)
-            cidx = best[:, None] + n + jnp.arange(k,
-                                                  dtype=jnp.int32)[None, :]
-            cand = jnp.take_along_axis(histp, cidx, axis=1)     # [S, k]
-            fb = jnp.broadcast_to(last_tok[:, None], (S, k))
-            return jnp.where(found[:, None], cand, fb).astype(jnp.int32)
-
-        def run(params, dparams, cache, dcache, hist, last_tok, pos,
-                active, remaining, page_table, eos_ids, do_sample,
-                temperature, top_k, top_p, rng):
-            if wq:
-                from deepspeed_tpu.inference.quantization import \
-                    dequantize_param_tree
-
-                params = dequantize_param_tree(params, native_w8a8=native)
-            if unroll:
-                params = unroll_scan_params(params)
-            if mode == "draft" and dunroll:
-                dparams = unroll_scan_params(dparams)
-            rows = jnp.arange(S)
-
-            def draft_propose(dcache, last_tok, pos, active, key):
-                def dstep(carry, key_j):
-                    dcache, cur, dpos = carry
-                    dvalid = active & (dpos < max_len)
-                    dp = jnp.take_along_axis(
-                        jnp.maximum(page_table, 0),
-                        jnp.minimum(dpos // page, pp - 1)[:, None],
-                        axis=1)[:, 0]
-                    ddest = jnp.where(dvalid, dp * page + dpos % page, 0)
-                    dmeta = {"kv_lens": jnp.where(active, dpos + 1, 1),
-                             "page_indices": page_table,
-                             "cu_q_lens": jnp.arange(S + 1,
-                                                     dtype=jnp.int32),
-                             "num_seqs": jnp.asarray([S], jnp.int32),
-                             "new_kv_dest": ddest}
-                    dout, dvars = draft.apply(
-                        {"params": dparams, "cache": dcache}, cur[None],
-                        positions=jnp.where(dvalid, dpos, 0)[None],
-                        mutable=["cache"], ragged_meta=dmeta)
-                    dlg = logits_of(dout)[0].astype(jnp.float32)
-                    dgreedy = jnp.argmax(dlg, axis=-1).astype(jnp.int32)
-                    if sampled:
-                        flg = filter_logits_batched(dlg, temperature,
-                                                    top_k, top_p)
-                        qj = jax.nn.softmax(flg, axis=-1)
-                        samp = jax.random.categorical(
-                            key_j, flg, axis=-1).astype(jnp.int32)
-                        nxt = jnp.where(do_sample, samp, dgreedy)
-                        out = (nxt, qj)
-                    else:
-                        nxt = dgreedy
-                        out = (nxt,)
-                    return (dvars["cache"], nxt, dpos + 1), out
-
-                keys = jax.random.split(key, k)
-                (dcache, _, _), outs = jax.lax.scan(
-                    dstep, (dcache, last_tok, pos), keys)
-                d_toks = outs[0].T                              # [S, k]
-                q_probs = (outs[1].transpose(1, 0, 2) if sampled
-                           else None)
-                return dcache, d_toks, q_probs
-
-            def tick(carry, _):
-                (cache, dcache, hist, last_tok, pos, active, remaining,
-                 rng, prop, accd) = carry
-                rng, key_d, key_v = jax.random.split(rng, 3)
-                # ---- draft k proposals per slot ----
-                if mode == "ngram":
-                    # the cursor token joins the history before matching
-                    hist = hist.at[rows, jnp.where(active, pos,
-                                                   max_len)].set(
-                        last_tok, mode="drop")
-                    d_toks = ngram_propose(hist, pos, last_tok)
-                    q_probs = None
-                else:
-                    dcache, d_toks, q_probs = draft_propose(
-                        dcache, last_tok, pos, active, key_d)
-                # ---- verify: one ragged chunk of k+1 rows per slot ----
-                chunk = jnp.concatenate([last_tok[:, None], d_toks],
-                                        axis=1)                 # [S, K1]
-                cpos = (pos[:, None] +
-                        jnp.arange(K1, dtype=jnp.int32)[None, :])
-                valid = active[:, None] & (cpos < max_len)
-                dest_page = jnp.take_along_axis(
-                    jnp.maximum(page_table, 0),
-                    jnp.minimum(cpos // page, pp - 1), axis=1)
-                dest = jnp.where(valid,
-                                 dest_page * page + cpos % page, 0)
-                meta = {"kv_lens": jnp.where(active, pos + K1, 1),
-                        "page_indices": page_table,
-                        "cu_q_lens": jnp.arange(
-                            S + 1, dtype=jnp.int32) * K1,
-                        "num_seqs": jnp.asarray([S], jnp.int32),
-                        "new_kv_dest": dest.reshape(-1)}
-                out, vars_ = model.apply(
-                    {"params": params, "cache": cache},
-                    chunk.reshape(1, -1),
-                    positions=jnp.where(valid, cpos, 0).reshape(1, -1),
-                    mutable=["cache"], ragged_meta=meta)
-                cache = vars_["cache"]
-                logits = logits_of(out)[0].reshape(S, K1, -1)
-                out_toks, acc = speculative_verify(
-                    logits, d_toks, q_probs, key_v if sampled else None,
-                    do_sample, temperature, top_k, top_p)
-                # ---- emission clamp: budget, max_len, first eos ----
-                emit = jnp.minimum(acc + 1, remaining)
-                emit = jnp.minimum(emit, max_len - 1 - pos)
-                eos_hit = out_toks == eos_ids[:, None]
-                first_eos = jnp.argmax(eos_hit, axis=1)
-                emit = jnp.where(jnp.any(eos_hit, axis=1),
-                                 jnp.minimum(emit, first_eos + 1), emit)
-                emit = jnp.where(active, jnp.clip(emit, 1, K1), 0)
-                j = jnp.arange(K1, dtype=jnp.int32)[None, :]
-                mask_out = active[:, None] & (j < emit[:, None])
-                # ---- advance / roll back the carry ----
-                new_last = jnp.take_along_axis(
-                    out_toks, jnp.maximum(emit - 1, 0)[:, None],
-                    axis=1)[:, 0]
-                ended_eos = jnp.any(eos_hit & (j < emit[:, None]),
-                                    axis=1)
-                if mode == "ngram":
-                    hidx = jnp.where(mask_out, pos[:, None] + 1 + j,
-                                     max_len)
-                    hist = hist.at[rows[:, None], hidx].set(
-                        out_toks, mode="drop")
-                last_tok = jnp.where(active, new_last, last_tok)
-                pos = jnp.where(active, pos + emit, pos)
-                remaining = remaining - emit
-                prop = prop + jnp.sum(jnp.where(active, k, 0))
-                accd = accd + jnp.sum(jnp.where(active, emit - 1, 0))
-                active = (active & ~ended_eos & (remaining > 0) &
-                          (pos + 1 < max_len))
-                return (cache, dcache, hist, last_tok, pos, active,
-                        remaining, rng, prop, accd), (out_toks, mask_out)
-
-            carry0 = (cache, dcache, hist, last_tok, pos, active,
-                      remaining, rng, jnp.int32(0), jnp.int32(0))
-            carry, (toks, mask) = jax.lax.scan(tick, carry0, length=Tt)
-            (cache, dcache, hist, last_tok, pos, active, remaining, _,
-             prop, accd) = carry
-            # tick-major emission order, [Tt*K1, S] — _fold_block's shape
-            toks = toks.transpose(0, 2, 1).reshape(Tt * K1, S)
-            mask = mask.transpose(0, 2, 1).reshape(Tt * K1, S)
-            return (cache, dcache, hist, last_tok, pos, active,
-                    remaining, toks, mask, prop, accd)
-
-        run.__name__ = run.__qualname__ = "spec_verify_block"
-        fn = jax.jit(run, donate_argnums=(2, 3, 4))
-        self._spec_block_cache[sampled] = fn
-        return fn
+    def spec_block(self, sampled: bool):
+        """The fused draft + verify + accept block
+        (:func:`~deepspeed_tpu.inference.v2.programs.build_spec_block`)."""
+        if sampled not in self._spec_block_cache:
+            self._spec_block_cache[sampled] = programs.build_spec_block(
+                self.model, self._draft, sampled=sampled,
+                mode=self.spec_mode, k=self.spec_k, ngram=self.spec_ngram,
+                max_seqs=self.max_seqs, block=self.decode_block_size,
+                page_size=self.page_size, pages_per_seq=self.pages_per_seq,
+                max_seq_len=self.max_seq_len, dunroll=self._draft_unroll,
+                **self.param_form)
+        return self._spec_block_cache[sampled]
 
     def _step_spec_block(self, reqs: List[Request]) -> int:
         """One speculative block, unpipelined (fresh uploads + one
@@ -2066,7 +1174,7 @@ class RaggedInferenceEngineV2:
             hist = self._hist_array(reqs)
         self._draft_catchup(reqs)
         sub = self._split_rng()
-        args = self._upload_all(
+        args = self.upload_all(
             (hist, last_tok, pos, active, remaining, self.page_table,
              eos_ids, do_sample, temperature, top_k, top_p))
         if trace.enabled:
@@ -2076,16 +1184,16 @@ class RaggedInferenceEngineV2:
         with st.stage("verify"):
             st.dispatches += 1
             st.spec_dispatches += 1
-            (self.cache, self._draft_cache, _h, new_last, _p, _a, _r,
-             toks, mask, prop, accd) = self._spec_block_fn(sampled)(
-                self.params, self._draft_params, self.cache,
-                self._draft_cache, *args, sub)
+            (self.kv.cache, self.kv.draft_cache, _h, new_last, _p, _a, _r,
+             toks, mask, prop, accd) = self.spec_block(sampled)(
+                self.params, self._draft_params, self.kv.cache,
+                self.kv.draft_cache, *args, sub)
         st.ticks += self.decode_block_size
-        toks, mask, new_last, prop, accd = self._fetch(
+        toks, mask, new_last, prop, accd = self.fetch(
             (toks, mask, new_last, prop, accd))
         st.harvests += 1
         with st.stage("harvest"):
-            self._last_tokens = np.array(new_last)
+            self.last_tokens = np.array(new_last)
             produced = self._fold_block(reqs, np.asarray(toks),
                                         np.asarray(mask))
             st.spec_proposed += int(prop)
@@ -2108,7 +1216,7 @@ class RaggedInferenceEngineV2:
              temperature, top_k, top_p, seeds) = self._block_arrays(reqs)
             sampled = bool(do_sample.any())
         sub = self._split_rng()
-        args = self._upload_all(
+        args = self.upload_all(
             (last_tok, pos, active, remaining, self.page_table,
              eos_ids, do_sample, temperature, top_k, top_p, seeds))
         if trace.enabled:
@@ -2117,19 +1225,19 @@ class RaggedInferenceEngineV2:
                         ticks=self.decode_block_size)
         with self._decode_dispatch(reqs, [r.length for r in reqs]):
             (cache, new_last, _pos, _active, _remaining, toks, mask,
-             moe) = self._decode_block_fn(sampled)(
-                self.params, self.cache, *args, sub)
-        self.cache = cache
+             moe) = self.decode_block(sampled)(
+                self.params, self.kv.cache, *args, sub)
+        self.kv.cache = cache
         self._moe_dispatched(moe, "decode_block", self.decode_block_size)
         st.ticks += self.decode_block_size
-        toks, mask, new_last = self._fetch((toks, mask, new_last))
+        toks, mask, new_last = self.fetch((toks, mask, new_last))
         st.harvests += 1
         with st.stage("harvest"):
             toks = np.asarray(toks)                     # [K, S]
             mask = np.asarray(mask)                     # [K, S]
             # np.array: device_get returns a READ-ONLY view; the
-            # SplitFuse tick assigns into _last_tokens per sampled token
-            self._last_tokens = np.array(new_last)
+            # SplitFuse tick assigns into last_tokens per sampled token
+            self.last_tokens = np.array(new_last)
             produced = self._fold_block(reqs, toks, mask)
             for r in reqs:
                 self._maybe_finish(r)
@@ -2161,74 +1269,14 @@ class RaggedInferenceEngineV2:
 
     def _admittable(self) -> bool:
         """Would the unpipelined engine admit the queue head right now?
-        Evaluated from EXACT global state (allocator + slots), so the
+        Evaluated from EXACT global state (pool + slots), so the
         pipelined loop reconciles at precisely the steps where
         ``pipeline=False`` would have admitted."""
         if not self.waiting or not any(s is None for s in self.slots):
             return False
         req = self.waiting[0]
-        need = self._admit_need(req)
-        fresh, entries = self._fresh_pages_needed(req, need, touch=False)
-        avail = self.allocator.free_pages
-        if self._pfx is not None:
-            avail += self._pfx.reclaimable(
-                exclude={e.key for e in entries})
-        return fresh <= avail
-
-    def _lc_resident_pages(self) -> int:
-        """HBM pages a partially-resident sequence needs at steady
-        state: sinks + the recent window + one not-yet-parked group in
-        flight + the growth frontier."""
-        t = self._tier_cfg
-        return (int(t.sink_pages) + int(t.window_pages)
-                + int(t.chunk_pages) + 1)
-
-    def _admit_need(self, req: Request) -> int:
-        """Token coverage ``_admit`` reserves for ``req`` — ONE formula
-        shared with ``_admittable`` so the pipelined loop reconciles at
-        precisely the steps where ``pipeline=False`` would admit."""
-        ctx_len = req.ctx_len
-        rem = max(req.max_new_tokens - len(req.generated), 1)
-        if req.lc:
-            # partial residency: reserve the resident WINDOW, not the
-            # context — the parked middle lives in the spill tiers
-            return min(ctx_len + rem,
-                       self._lc_resident_pages() * self.page_size)
-        if self.kv_reserve == "worst_case":
-            # worst case INCLUDING re-prefilled output for evicted
-            # continuations (their ctx carries earlier tokens)
-            return ctx_len + req.max_new_tokens - len(req.generated)
-        if req.spilled is not None and req.prefill_done >= ctx_len:
-            # spilled decode-phase continuation: _admit allocates for
-            # its full restored length, not just the prompt
-            return req.length + min(self.decode_block_size, rem)
-        # on-demand (reference can_schedule): context + the first
-        # decode block; growth happens per block
-        return ctx_len + min(self.decode_block_size, rem)
-
-    def _fresh_pages_needed(self, req: Request, need: int,
-                            touch: bool = False):
-        """Free pages an admission of ``req`` would consume, after
-        prefix-cache attaches (resident matched pages cost nothing; a
-        FULL match costs one extra page for the COW re-prefill) and
-        spill-hold re-attaches.  Returns ``(fresh, matched_entries)``.
-        ``touch=False`` for probes — LRU order must not move until the
-        admission actually happens."""
-        total = self.allocator.pages_for(need)
-        if req.lc:
-            # long-context admissions skip the prefix cache (parked
-            # columns would punch holes in a shared prefix run)
-            return total, []
-        if req.spilled is not None:
-            return total - len(req.spilled.get("shared_pages", ())), []
-        if self._pfx is None:
-            return total, []
-        ctx = req.ctx if req.ctx is not None else req.prompt
-        entries = self._pfx.match(ctx, touch=touch)
-        resident = sum(1 for e in entries if e.state == "resident")
-        full = bool(entries) and (
-            len(entries) * self.page_size == ctx.size)
-        return total - resident + (1 if full else 0), entries
+        return self.kv.can_admit(
+            req, self.kv.need(req, self.decode_block_size))
 
     def _pipeline_start(self, reqs: List[Request],
                         spec: bool = False) -> None:
@@ -2265,7 +1313,7 @@ class RaggedInferenceEngineV2:
         self._dev = {
             "reqs": list(reqs),
             "sampled": bool(do_sample.any()),
-            **dict(zip(names, self._upload_all(
+            **dict(zip(names, self.upload_all(
                 (last_tok, pos, active, remaining, self.page_table,
                  eos_ids, do_sample, temperature, top_k, top_p)))),
             "plen": plen, "rem": rem, "has_eos": has_eos,
@@ -2284,11 +1332,11 @@ class RaggedInferenceEngineV2:
             "block_seq": 0,
         }
         if spec:
-            self._dev["hist"] = self._upload(self._hist_array(reqs))
+            self._dev["hist"] = self.upload(self._hist_array(reqs))
             self._dev["plen_hi"] = plen.copy()
             self._dev["rem_lo"] = rem.copy()
         else:
-            self._dev["seeds"] = self._upload(seeds)
+            self._dev["seeds"] = self.upload(seeds)
 
     def _pipeline_step(self) -> int:
         """One pipelined iteration: plan + dispatch block k+1 while the
@@ -2323,7 +1371,7 @@ class RaggedInferenceEngineV2:
                     want = int(min(dv["plen"][s] + min(K, dv["rem"][s]),
                                    self.max_seq_len))
                 before = self.allocator.owned(s)
-                if not self._ensure_pages(s, want):
+                if not self.kv.ensure(s, want):
                     grow_ok = False
                     break
                 table_dirty |= self.allocator.owned(s) != before
@@ -2332,7 +1380,7 @@ class RaggedInferenceEngineV2:
             # control back to the normal path (stall/evict semantics)
             return self._pipeline_harvest(teardown=True)
         if table_dirty:
-            dv["page_table"] = self._upload(self.page_table)
+            dv["page_table"] = self.upload(self.page_table)
         sub = self._split_rng()
         if trace.enabled:
             trace.event("decode_block", cat="request",
@@ -2343,12 +1391,12 @@ class RaggedInferenceEngineV2:
             with st.stage("verify"):
                 st.dispatches += 1
                 st.spec_dispatches += 1
-                (self.cache, self._draft_cache, dv["hist"],
+                (self.kv.cache, self.kv.draft_cache, dv["hist"],
                  dv["last_tok"], dv["pos"], dv["active"],
                  dv["remaining"], toks, mask, prop,
-                 accd) = self._spec_block_fn(dv["sampled"])(
-                    self.params, self._draft_params, self.cache,
-                    self._draft_cache, dv["hist"], dv["last_tok"],
+                 accd) = self.spec_block(dv["sampled"])(
+                    self.params, self._draft_params, self.kv.cache,
+                    self.kv.draft_cache, dv["hist"], dv["last_tok"],
                     dv["pos"], dv["active"], dv["remaining"],
                     dv["page_table"], dv["eos_ids"], dv["do_sample"],
                     dv["temperature"], dv["top_k"], dv["top_p"], sub)
@@ -2356,10 +1404,10 @@ class RaggedInferenceEngineV2:
         else:
             with self._decode_dispatch(
                     live, [dv["plen"][r.slot] for r in live]):
-                (self.cache, dv["last_tok"], dv["pos"], dv["active"],
+                (self.kv.cache, dv["last_tok"], dv["pos"], dv["active"],
                  dv["remaining"], toks, mask,
-                 moe) = self._decode_block_fn(dv["sampled"])(
-                    self.params, self.cache, dv["last_tok"], dv["pos"],
+                 moe) = self.decode_block(dv["sampled"])(
+                    self.params, self.kv.cache, dv["last_tok"], dv["pos"],
                     dv["active"], dv["remaining"], dv["page_table"],
                     dv["eos_ids"], dv["do_sample"], dv["temperature"],
                     dv["top_k"], dv["top_p"], dv["seeds"], sub)
@@ -2428,13 +1476,13 @@ class RaggedInferenceEngineV2:
         # whole run with ONE blocking fetch
         dv["window"].drain()
         blocks = dv["ready"]
-        toks_l, mask_l, last_tok, extra = self._fetch((
+        toks_l, mask_l, last_tok, extra = self.fetch((
             [p[0] for p in blocks],
             [p[1] for p in blocks], dv["last_tok"],
             [p[2:] for p in blocks] if spec else []))
         with st.stage("harvest"):
             # np.array: device_get returns READ-ONLY views
-            self._last_tokens = np.array(last_tok)
+            self.last_tokens = np.array(last_tok)
             produced = 0
             for toks, mask in zip(toks_l, mask_l):
                 produced += self._fold_block(
@@ -2510,7 +1558,7 @@ class RaggedInferenceEngineV2:
             # decision is taken from EXACT state, so pipelined and
             # unpipelined runs degrade at the same steps)
             spec_block = (decoding_ready and self.spec_mode != "off" and
-                          all(self._ensure_pages(
+                          all(self.kv.ensure(
                               r.slot,
                               self._spec_grow_want(
                                   r.length, r.max_new_tokens -
@@ -2519,7 +1567,7 @@ class RaggedInferenceEngineV2:
             all_decoding = (
                 not spec_block and decoding_ready and
                 self.decode_block_size > 1 and
-                all(self._ensure_pages(
+                all(self.kv.ensure(
                     r.slot,
                     r.length + min(self.decode_block_size,
                                    r.max_new_tokens - len(r.generated)))
@@ -2571,7 +1619,7 @@ class RaggedInferenceEngineV2:
                     # park the coldest stalled sequence in the spill
                     # tiers (restore = page upload); destructive evict
                     # only when the tiers are full
-                    victim = self._pick_victim(stalled)
+                    victim = self.kv.pick_victim(stalled, self._last_sched)
                     if not self._spill(victim):
                         self._evict(victim)
                 else:
@@ -2579,7 +1627,7 @@ class RaggedInferenceEngineV2:
             return lc_produced
         (token_ids, positions, kv_lens, page_indices, cu_q_lens, num_seqs,
          new_kv_dest, sample_rows, samplers, counts) = plan
-        args = self._upload_all(
+        args = self.upload_all(
             (token_ids[None], positions[None], kv_lens, page_indices,
              cu_q_lens, num_seqs, new_kv_dest, sample_rows))
         st.prefill_tokens += counts["prefill_tokens"]
@@ -2587,11 +1635,11 @@ class RaggedInferenceEngineV2:
         st.decode_key_token_steps += counts["decode_key_tokens"]
         with st.stage("dispatch", program="fused", **counts):
             st.dispatches += 1
-            sel_logits, self.cache, moe = self._fused_step_fn()(
-                self.params, self.cache, *args)
+            sel_logits, self.kv.cache, moe = self.fused_step()(
+                self.params, self.kv.cache, *args)
         self._moe_dispatched(moe, "fused", 1)
         st.ticks += 1
-        produced = self._sample(sel_logits, samplers)
+        produced = self.sample(sel_logits, samplers)
         # a handoff prefill that just sampled its first token parks NOW
         # — same step — so the router's next export pulls it without an
         # extra tick of decode-side latency
@@ -2607,7 +1655,7 @@ class RaggedInferenceEngineV2:
             req = self.waiting[0]
             if req.ctx is None:
                 req.ctx = req.prompt
-            need = self._admit_need(req)
+            need = self.kv.need(req, self.decode_block_size)
             if self.allocator.pages_for(need) > self.num_pages - 1:
                 # defense in depth behind put_request's submit-time
                 # check: an unschedulable head would deadlock the FIFO
@@ -2632,13 +1680,7 @@ class RaggedInferenceEngineV2:
                     f"({need} tokens) but the engine owns "
                     f"{self.num_pages - 1} usable pages — it can never "
                     "be scheduled, even after full eviction")
-            fresh, probe = self._fresh_pages_needed(req, need,
-                                                    touch=False)
-            avail = self.allocator.free_pages
-            if self._pfx is not None:
-                avail += self._pfx.reclaimable(
-                    exclude={e.key for e in probe})
-            if fresh > avail:
+            if not self.kv.can_admit(req, need):
                 break                      # FIFO: wait for pages to free
             self.waiting.popleft()
             req.slot = i
@@ -2647,120 +1689,30 @@ class RaggedInferenceEngineV2:
                 req.pc_parent, req.pc_pages, req.pc_cached = ROOT_HASH, \
                     0, 0
             self.slots[i] = req
-            self._draft_len[i] = 0
-            self.page_table[i, :] = -1
-            if not self._attach_and_allocate(req, need):
+            if not self.kv.attach(req, need):
                 # a tombstone revival failed mid-attach and the shrunken
                 # match needs more fresh pages than the pool holds —
                 # undo and retry this head next step (rare: the probe
                 # assumed the revivals would land)
-                self.allocator.free(i)
-                self.page_table[i, :] = -1
-                self.slots[i] = None
-                req.slot = -1
+                self._unseat(req)
                 self.waiting.appendleft(req)
                 break
-            self.request_latency.on_admit(req.uid)
-            if trace.enabled:
-                trace.event("request_admit", cat="request", uid=req.uid,
-                            slot=i, pages=self.allocator.owned(i),
-                            cached_pages=req.pc_pages
-                            if req.spilled is None else 0,
-                            spilled=req.spilled is not None)
             if req.spilled is not None:
                 self._restore(req)
 
-    def _attach_and_allocate(self, req: Request, need: int) -> bool:
-        """Build slot ``req.slot``'s page run for an admission covering
-        ``need`` tokens: prefix-cache attaches (and tombstone revivals)
-        first, spill-hold re-attaches for a restoring request, COW of
-        the last page on a FULL prefix match, then fresh pages for the
-        remainder.  False when revival failures shrank the match below
-        what the pool can cover (caller undoes the admission)."""
-        i = req.slot
-        st = self.host_stats
-        attached = 0
-        full = False
-        if req.spilled is not None:
-            shared = [int(p) for p in req.spilled.get("shared_pages", ())]
-            if shared:
-                self.allocator.attach(i, shared)
-                self.page_table[i, :len(shared)] = shared
-                attached = len(shared)
-        elif self._pfx is not None and not req.lc:
-            with st.stage("prefix"):
-                entries = self._pfx.match(req.ctx, touch=True)
-                pages_att: List[int] = []
-                parent = ROOT_HASH
-                for e in entries:
-                    if e.state == "spilled" and not self._pfx_revive(e):
-                        break
-                    pages_att.append(int(e.page))
-                    parent = e.key
-                attached = len(pages_att)
-                if attached:
-                    self.allocator.attach(i, pages_att)
-                    self.page_table[i, :attached] = pages_att
-                    full = attached * self.page_size == req.ctx_len
-                    # the matched pages are already prefilled: schedule
-                    # only the suffix.  A FULL match still re-prefills
-                    # its LAST token through the fused program (after a
-                    # COW below) so the first sampled token's logits
-                    # come from the same compiled program as a cache-off
-                    # run — the bit-parity contract
-                    req.prefill_done = attached * self.page_size - (
-                        1 if full else 0)
-                    req.pc_parent = parent
-                    req.pc_pages = attached
-                    req.pc_cached = req.prefill_done
-                    st.prefix_hits += 1
-                    st.prefix_hit_pages += attached
-                    st.prefix_hit_tokens += req.prefill_done
-                else:
-                    st.prefix_misses += 1
-        grow_n = self.allocator.pages_for(need) - attached
-        want_free = grow_n + (1 if full else 0)
-        self._reclaim_for(want_free)
-        if want_free > self.allocator.free_pages:
-            return False
-        if full:
-            # the write frontier (position ctx_len - 1) lands in the
-            # last matched page: make it private before the re-prefill
-            old, new = self.allocator.cow(i, attached - 1)
-            if new != old:
-                self._cow_copy(old, new)
-                self.page_table[i, attached - 1] = new
-        if grow_n > 0:
-            pages = self.allocator.grow(i, grow_n)
-            self.page_table[i, attached:attached + grow_n] = pages
-        return True
-
-    def _ensure_pages(self, slot: int, upto_tokens: int) -> bool:
-        """Grow ``slot``'s page run to cover ``upto_tokens`` cache
-        positions; False when the pool can't (scheduler backpressure —
-        the sequence sits this tick out, or gets evicted)."""
-        upto_tokens = min(upto_tokens, self.max_seq_len)
-        need = pages_for(upto_tokens, self.page_size)
-        have = self.allocator.owned(slot)
-        if need <= have:
-            return True
-        self._reclaim_for(need - have)
-        if need - have > self.allocator.free_pages:
-            return False
-        pages = self.allocator.grow(slot, need - have)
-        self.page_table[slot, have:have + len(pages)] = pages
-        return True
-
-    def _reclaim_for(self, n_pages: int) -> None:
-        """Ask the prefix index to give back cold single-reference pages
-        when the free list can't cover ``n_pages``.  Safe against any
-        page a live slot uses: those hold a slot reference on top of the
-        index's, so the index never reclaims them."""
-        if self._pfx is None:
-            return
-        short = n_pages - self.allocator.free_pages
-        if short > 0:
-            self._pfx.reclaim(short)
+    def _unseat(self, r: Request, fold: bool = False) -> None:
+        """Give ``r``'s slot back — the ONE place on the scheduler's
+        side: its pages go through ``KVState.release``, the draft
+        model's coverage resets, the slot empties and the request
+        forgets it.  ``fold``: its rows are lost, so it becomes a
+        re-prefill continuation (a spilled, cancelled or finished
+        request keeps its cursors)."""
+        self.kv.release(r.slot)
+        self._draft_len[r.slot] = 0
+        self.slots[r.slot] = None
+        r.slot = -1
+        if fold:
+            r.fold()
 
     def _handoff_sweep(self) -> None:
         """Park every handoff-marked sequence whose prefill AND first
@@ -2779,15 +1731,7 @@ class RaggedInferenceEngineV2:
             else:
                 # planned handoff, not pool exhaustion — fold inline
                 # instead of via _evict (no eviction counter/log)
-                self.allocator.free(r.slot)
-                self.page_table[r.slot, :] = -1
-                self.slots[r.slot] = None
-                self._draft_len[r.slot] = 0
-                r.ctx = np.concatenate(
-                    [r.prompt, np.asarray(r.generated, np.int32)])
-                r.prefill_done = 0
-                r.pc_parent, r.pc_pages, r.pc_cached = ROOT_HASH, 0, 0
-                r.slot = -1
+                self._unseat(r, fold=True)
                 self.handoff_folds += 1
             self._handoff_ready.append(r)
             if trace.enabled:
@@ -2801,15 +1745,7 @@ class RaggedInferenceEngineV2:
         resume from the same sampled prefix)."""
         from deepspeed_tpu.utils.logging import logger
 
-        self.allocator.free(r.slot)
-        self.page_table[r.slot, :] = -1
-        self.slots[r.slot] = None
-        self._draft_len[r.slot] = 0
-        r.ctx = np.concatenate(
-            [r.prompt, np.asarray(r.generated, np.int32)])
-        r.prefill_done = 0
-        r.pc_parent, r.pc_pages, r.pc_cached = ROOT_HASH, 0, 0
-        r.slot = -1
+        self._unseat(r, fold=True)
         self.waiting.append(r)             # back of the queue: the freed
         self.evictions += 1                # pages go to older work first
         if trace.enabled:
@@ -2819,297 +1755,36 @@ class RaggedInferenceEngineV2:
                     f"({r.ctx.size} ctx tokens) — KV pool exhausted; "
                     "requeued as continuation")
 
-    # -- tiered KV spill/restore (HBM <-> host RAM <-> NVMe) -------------
-
-    def _tier_jits(self):
-        """The two fixed-shape page-movement programs (compiled once,
-        first spill/restore — the zero-new-compilation guard covers the
-        steady state after that):
-
-        - gather: ``[pages_per_seq]`` page rows out of every cache leaf
-          (indices padded with the trash page 0 — always allocated).
-        - scatter: the same rows back in, donating the cache buffers;
-          pad indices point one past the pool and ``mode='drop'``
-          discards them, so a partial restore writes exactly its live
-          rows."""
-        if self._tier_gather is None:
-            def gather(cache, idx):
-                return jax.tree_util.tree_map(
-                    lambda l: jnp.take(l, idx, axis=0), cache)
-
-            def scatter(cache, idx, rows):
-                return jax.tree_util.tree_map(
-                    lambda l, r: l.at[idx].set(r, mode="drop"),
-                    cache, rows)
-
-            self._tier_gather = jax.jit(gather)
-            self._tier_scatter = jax.jit(scatter, donate_argnums=(0,))
-        return self._tier_gather, self._tier_scatter
-
-    def _live_tokens(self, r) -> int:
-        """Cache rows that hold real KV for ``r`` RIGHT NOW.  Decode
-        phase: the last sampled token's row is written by the NEXT tick
-        (at position length-1), so ``length - 1`` rows are live.
-        Prefill phase: exactly the prefilled prefix."""
-        if r.prefill_done >= r.ctx_len:
-            return r.length - 1
-        return r.prefill_done
+    # -- tiered KV spill/restore: the scheduler's half -------------------
 
     def _spill(self, r) -> bool:
-        """Park ``r`` in the spill tiers instead of destroying its KV:
-        gather its live page rows, hand them (device_get) to the tier
-        store, and requeue it as a RESTORABLE continuation.  Returns
-        False when the tiers can't take it (caller falls back to
-        ``_evict``'s re-prefill).  Restore is bit-identical to never
-        having spilled: the exact cache rows come back, ``prefill_done``
-        and the pending last token are preserved."""
-        live = self._live_tokens(r)
-        n_live = pages_for(live, self.page_size) if live > 0 else 0
-        if n_live == 0 or self.tiering is None:
+        """Park ``r`` in the spill tiers (``KVState.spill``) and requeue
+        it as a RESTORABLE continuation; False when the tiers can't take
+        it (caller falls back to ``_evict``'s re-prefill)."""
+        if not self.kv.spill(r, self.last_tokens[r.slot]):
             return False
-        # shared-prefix pages (refcount > 1: the prefix index or another
-        # sequence also holds them) are maximally hot — they never leave
-        # HBM.  Take a spill-hold (+1 ref) on the maximal shared PREFIX
-        # of the live run and spill only the private suffix; no owner
-        # ever writes a shared page (COW precedes any write), so the
-        # rows stay valid for re-attach at restore
-        j = 0
-        while j < n_live and self.allocator.refcount(
-                int(self.page_table[r.slot, j])) > 1:
-            j += 1
-        n_priv = n_live - j
-        if n_priv > 0 and not self.tiering.can_spill(n_priv):
-            return False
-        shared = [int(p) for p in self.page_table[r.slot, :j]]
-        st = self.host_stats
-        with st.stage("spill"):
-            if n_priv > 0:
-                gather, _ = self._tier_jits()
-                idx = np.zeros((self.pages_per_seq,),
-                               np.int32)               # pad: trash
-                idx[:n_priv] = self.page_table[r.slot, j:n_live]
-                rows = jax.device_get(gather(self.cache,
-                                             jnp.asarray(idx)))
-                try:
-                    self.tiering.spill(
-                        r.uid,
-                        [np.asarray(leaf[:n_priv]) for leaf in
-                         jax.tree_util.tree_leaves(rows)],
-                        n_priv)
-                except RuntimeError:
-                    return False           # tiers full: caller evicts
-            r.spilled = {"last_tok": int(self._last_tokens[r.slot]),
-                         "n_pages": n_priv, "live_tokens": live,
-                         "shared_pages": shared}
-            for p in shared:
-                self.allocator.incref(p)   # spill-hold survives free()
-        from deepspeed_tpu.utils.logging import logger
-
-        self.allocator.free(r.slot)
-        self.page_table[r.slot, :] = -1
-        self.slots[r.slot] = None
-        self._draft_len[r.slot] = 0
-        r.slot = -1
+        self._unseat(r)
         self.waiting.append(r)             # back of the queue, like evict
-        self.spills += 1
-        self.request_latency.on_spill(r.uid)
-        if trace.enabled:
-            trace.event("request_spill", cat="request", uid=r.uid,
-                        pages=int(n_live), live_tokens=int(live))
-        logger.info(f"ragged engine: spilled uid={r.uid} ({n_live} pages,"
-                    f" {live} live tokens) to the KV tiers — restore is "
-                    "a page upload, not a re-prefill")
         return True
 
     def _restore(self, req) -> None:
-        """Upload ``req``'s spilled page rows into its freshly allocated
-        pages (slot already assigned by ``_admit``).  On unrecoverable
-        corruption (:class:`KVRestoreError` — the store already
-        quarantined the payload) the request falls back to a plain
-        re-prefill continuation at the FRONT of the queue, loudly."""
-        from deepspeed_tpu.inference.kv_tiering import KVRestoreError
-        from deepspeed_tpu.utils.logging import logger
-
-        st = self.host_stats
-        info = req.spilled
-        n = info["n_pages"]                 # private pages in the tiers
-        shared = info.get("shared_pages", [])
-        jn = len(shared)                    # shared prefix re-attached by
-        t_restore0 = time.perf_counter()    # _attach_and_allocate
-        try:
-            if n > 0:
-                with st.stage("restore"):
-                    arrs = self.tiering.restore(req.uid)
-                    _, scatter = self._tier_jits()
-                    # pad indices past the pool: mode='drop' drops them
-                    idx = np.full((self.pages_per_seq,), self.num_pages,
-                                  np.int32)
-                    idx[:n] = self.page_table[req.slot, jn:jn + n]
-                    leaves = []
-                    for a in arrs:
-                        full = np.zeros(
-                            (self.pages_per_seq,) + a.shape[1:], a.dtype)
-                        full[:n] = a
-                        leaves.append(jnp.asarray(full))
-                    rows = jax.tree_util.tree_unflatten(
-                        self._cache_treedef, leaves)
-                    self.cache = scatter(self.cache, jnp.asarray(idx),
-                                         rows)
-            self._last_tokens[req.slot] = info["last_tok"]
-            req.spilled = None
-            # release the spill-holds: the slot attach owns its refs now
-            for p in shared:
-                self.allocator.decref(p)
-            self.restores += 1
-            self.request_latency.on_restore_stall(
-                req.uid, time.perf_counter() - t_restore0)
-            if trace.enabled:
-                trace.event("request_restore", cat="request",
-                            uid=req.uid, pages=int(n),
-                            shared_pages=int(jn))
-        except KVRestoreError as e:
-            self.allocator.free(req.slot)
-            self.page_table[req.slot, :] = -1
-            self.slots[req.slot] = None
-            self._draft_len[req.slot] = 0
-            for p in shared:
-                self.allocator.decref(p)
-            req.ctx = np.concatenate(
-                [req.prompt, np.asarray(req.generated, np.int32)])
-            req.prefill_done = 0
-            req.pc_parent, req.pc_pages, req.pc_cached = ROOT_HASH, 0, 0
-            req.spilled = None
-            req.slot = -1
+        """Bring ``req``'s spilled rows back into its freshly attached
+        pages (slot already assigned by ``_admit``); a restore that
+        fails verification falls back to a plain re-prefill continuation
+        at the FRONT of the queue."""
+        last_tok = self.kv.restore(req)
+        if last_tok is None:
+            self._unseat(req, fold=True)
             self.waiting.appendleft(req)   # front: it already waited
-            self.request_latency.on_restore_stall(
-                req.uid, time.perf_counter() - t_restore0)
-            self.request_latency.on_error(req.uid)
-            if trace.enabled:
-                trace.event("request_restore_failed", cat="request",
-                            uid=req.uid, page=int(e.page))
-            logger.error(
-                f"ragged engine: restore of uid={req.uid} failed "
-                f"verification (page {e.page}; payload quarantined) — "
-                "re-prefilling the session from its own tokens")
-
-    def _pfx_demote(self, e) -> bool:
-        """Index-LRU hook: move a single-reference prefix page's KV into
-        the tiered store under the entry's PREFIX-HASH key (not a uid —
-        one restore serves every future waiter).  Returns False when the
-        tiers can't take it, in which case the index drops the entry."""
-        if self.tiering is None or not self.tiering.can_spill(1):
-            return False
-        st = self.host_stats
-        with st.stage("spill"):
-            gather, _ = self._tier_jits()
-            idx = np.zeros((self.pages_per_seq,), np.int32)  # pad: trash
-            idx[0] = e.page
-            rows = jax.device_get(gather(self.cache, jnp.asarray(idx)))
-            try:
-                self.tiering.spill(
-                    PrefixCacheIndex.tier_key(e.key),
-                    [np.asarray(leaf[:1]) for leaf in
-                     jax.tree_util.tree_leaves(rows)],
-                    1)
-            except RuntimeError:
-                return False
-        if trace.enabled:
-            trace.event("prefix_demote", cat="request",
-                        key=PrefixCacheIndex.tier_key(e.key))
-        return True
-
-    def _pfx_revive(self, e) -> bool:
-        """Bring a demoted prefix page back into a fresh pool page so an
-        admission can attach it.  On failure the tombstone is dropped —
-        the requester falls back to computing that page itself."""
-        from deepspeed_tpu.inference.kv_tiering import KVRestoreError
-
-        if self.tiering is None:
-            self._pfx._drop(e)
-            return False
-        if self.allocator.free_pages < 1:
-            return False
-        st = self.host_stats
-        page = self.allocator.take_page()
-        try:
-            with st.stage("restore"):
-                arrs = self.tiering.restore(
-                    PrefixCacheIndex.tier_key(e.key))
-                _, scatter = self._tier_jits()
-                idx = np.full((self.pages_per_seq,), self.num_pages,
-                              np.int32)
-                idx[0] = page
-                leaves = []
-                for a in arrs:
-                    full = np.zeros((self.pages_per_seq,) + a.shape[1:],
-                                    a.dtype)
-                    full[:1] = a
-                    leaves.append(jnp.asarray(full))
-                rows = jax.tree_util.tree_unflatten(self._cache_treedef,
-                                                    leaves)
-                self.cache = scatter(self.cache, jnp.asarray(idx), rows)
-        except KVRestoreError:
-            self.allocator.decref(page)
-            self._pfx._drop(e)             # payload quarantined: forget
-            return False
-        self._pfx.revive(e, page)
-        if trace.enabled:
-            trace.event("prefix_revive", cat="request",
-                        key=PrefixCacheIndex.tier_key(e.key),
-                        page=int(page))
-        return True
-
-    def _cow_copy(self, src: int, dst: int) -> None:
-        """Fixed-shape device copy of one page row across every cache
-        leaf — the copy half of copy-on-write.  ``src``/``dst`` are
-        traced int32 operands, so every COW reuses one compiled
-        program."""
-        st = self.host_stats
-        if self._cow_jit is None:
-            self._cow_jit = jax.jit(
-                lambda cache, s, d: jax.tree_util.tree_map(
-                    lambda l: l.at[d].set(l[s]), cache),
-                donate_argnums=(0,))
-        with st.stage("prefix"):
-            self.cache = self._cow_jit(self.cache, jnp.int32(src),
-                                       jnp.int32(dst))
-        st.prefix_cow_copies += 1
-        if trace.enabled:
-            trace.event("prefix_cow", cat="request", src=int(src),
-                        dst=int(dst))
+        else:
+            self.last_tokens[req.slot] = last_tok
 
     def audit_kv_sharing(self) -> Dict[str, int]:
-        """Refcount-conservation audit: every physical page's refcount
-        must equal the number of holders that can reach it — slot
-        page-table rows, resident prefix-index entries, and spill-holds
-        on parked requests' shared prefixes.  Spilled payloads (tiers)
-        hold no pool pages by construction.  Delegates the per-page
-        equality to :meth:`PageAllocator.audit`."""
-        external: Dict[int, int] = {}
-        if self._pfx is not None:
-            for e in self._pfx._entries.values():
-                if e.state == "resident":
-                    external[e.page] = external.get(e.page, 0) + 1
-        for r in itertools.chain(self.waiting, self._handoff_ready):
-            if r.spilled is not None:
-                for p in r.spilled.get("shared_pages", ()):
-                    external[p] = external.get(p, 0) + 1
-        for s, r in enumerate(self.slots):
-            if r is None:
-                continue
-            row = [int(p) for p in self.page_table[s] if p >= 0]
-            owned = self.allocator.owned_pages(s)
-            assert row == owned, (
-                f"slot {s}: page-table row {row} != allocator "
-                f"ownership {owned}")
-        return self.allocator.audit(external=external)
-
-    def _pick_victim(self, stalled):
-        """Coldest page-stalled sequence: least-recently scheduled
-        (tie: youngest) — it has waited longest for pages and will wait
-        longest for them, so parking it frees the most useful HBM."""
-        return min(stalled,
-                   key=lambda r: (self._last_sched[r.slot], -r.uid))
+        """Refcount-conservation audit over every holder of a pool page
+        (:meth:`KVState.audit`): slot rows, resident prefix-index
+        entries, spill-holds of parked requests."""
+        return self.kv.audit(
+            self.slots, itertools.chain(self.waiting, self._handoff_ready))
 
     def _flat_dest(self, slot: int, pos: int) -> int:
         page = self.page_table[slot, pos // self.page_size]
@@ -3126,7 +1801,7 @@ class RaggedInferenceEngineV2:
                 continue
             # the tick writes the last generated token at position
             # length-1, so pages must cover `length` tokens
-            if self._ensure_pages(r.slot, r.length):
+            if self.kv.ensure(r.slot, r.length):
                 decode_rs.append(r)
             else:
                 self._stalled.append(r)    # out of pages: sit this tick out
@@ -3160,20 +1835,16 @@ class RaggedInferenceEngineV2:
             take = min(budget, r.ctx_len - r.prefill_done)
             if take <= 0:
                 continue                   # batch-budget-limited, not stalled
-            if not self._ensure_pages(r.slot, r.prefill_done + take):
+            if not self.kv.ensure(r.slot, r.prefill_done + take):
                 # partial growth: cover what the pool allows this tick
-                # (cold prefix-index pages count — _ensure_pages
+                # (cold prefix-index pages count — KVState.ensure
                 # reclaims them on demand)
-                coverable = (self.allocator.owned(r.slot) +
-                             self.allocator.free_pages +
-                             (self._pfx.reclaimable()
-                              if self._pfx is not None else 0)
-                             ) * self.page_size
+                coverable = self.kv.coverable_tokens(r.slot)
                 take = min(take, coverable - r.prefill_done)
                 if take <= 0:
                     self._stalled.append(r)     # page-limited
                     continue
-                self._ensure_pages(r.slot, r.prefill_done + take)
+                self.kv.ensure(r.slot, r.prefill_done + take)
             takes[r.uid] = take
             budget -= take
 
@@ -3188,7 +1859,7 @@ class RaggedInferenceEngineV2:
             self._last_sched[r.slot] = self._sched_seq
             if r.prefill_done >= r.ctx_len:                 # decode: 1 tok
                 p = min(r.length - 1, self.max_seq_len - 1)
-                token_ids[t] = self._last_tokens[r.slot]
+                token_ids[t] = self.last_tokens[r.slot]
                 positions[t] = p
                 new_kv_dest[t] = self._flat_dest(r.slot, p)
                 page_indices[j] = self.page_table[r.slot]
@@ -3215,19 +1886,9 @@ class RaggedInferenceEngineV2:
                 counts["prefill_tokens"] += int(take)
                 counts["prefill_key_tokens"] += int(
                     take * lo + take * (take + 1) // 2)
-                if self._pfx is not None:
-                    # publish every freshly completed full page to the
-                    # prefix index (chain-hash it onto the request's
-                    # registered prefix); identical in both pipeline
-                    # modes — registration keys off prefill progress,
-                    # not dispatch timing
-                    page = self.page_size
-                    while (r.pc_pages + 1) * page <= r.prefill_done:
-                        k = r.pc_pages
-                        r.pc_parent = self._pfx.register(
-                            r.pc_parent, r.ctx[k * page:(k + 1) * page],
-                            int(self.page_table[r.slot, k]))
-                        r.pc_pages += 1
+                # every freshly completed full page goes to the prefix
+                # index
+                self.kv.publish(r, r.ctx, r.prefill_done)
                 if trace.enabled:
                     trace.event("prefill_chunk", cat="request",
                                 uid=r.uid, take=int(take),
@@ -3251,7 +1912,7 @@ class RaggedInferenceEngineV2:
                 np.asarray([j], np.int32), new_kv_dest, sample_rows,
                 samplers, counts)
 
-    def _sample(self, sel_logits, samplers) -> int:
+    def sample(self, sel_logits, samplers) -> int:
         """One host sync per tick; one sampling call per distinct config."""
         produced = 0
         groups: Dict[Tuple, List[Tuple[Request, int]]] = {}
@@ -3281,11 +1942,11 @@ class RaggedInferenceEngineV2:
                 dev_toks = sample_logits(
                     sel_logits[rows], sub, do_sample=do_sample,
                     temperature=temp, top_k=top_k, top_p=top_p)
-            toks = np.asarray(self._fetch(dev_toks))
+            toks = np.asarray(self.fetch(dev_toks))
             with st.stage("harvest"):
                 for (r, _), tok in zip(pairs, toks):
                     r.generated.append(int(tok))
-                    self._last_tokens[r.slot] = int(tok)
+                    self.last_tokens[r.slot] = int(tok)
                     produced += 1
                     self.request_latency.on_tokens(r.uid,
                                                    len(r.generated))
@@ -3308,35 +1969,25 @@ class RaggedInferenceEngineV2:
                 self._handoff_sweep()
 
     def _reap_finished(self) -> None:
-        for i, r in enumerate(self.slots):
+        for r in self.slots:
             if r is not None and r.done:
-                if r.lc and self.tiering is not None:
-                    # drop the parked middle groups with the session
-                    for g in range(r.lc_parked):
-                        self.tiering.drop(f"mid-{r.uid}-{g}")
-                if (self._pfx is not None and not r.lc
-                        and self._pfx_cfg.include_generated):
+                if self.kv.pfx_cfg.include_generated:
                     # opt-in: publish full pages of generated tokens
                     # before the refs drop.  Decode pages come from a
                     # different compiled program than fused prefill, so
                     # the bit-parity contract is waived for hits on them
-                    # (documented on the config knob).
-                    seq = np.concatenate(
-                        [r.ctx, np.asarray(r.generated, np.int32)[
-                            r.ctx_len - r.prompt.size:]])
-                    written = r.length - 1   # last token never written
-                    page = self.page_size
-                    while (r.pc_pages + 1) * page <= written:
-                        k = r.pc_pages
-                        r.pc_parent = self._pfx.register(
-                            r.pc_parent, seq[k * page:(k + 1) * page],
-                            int(self.page_table[i, k]))
-                        r.pc_pages += 1
+                    # (documented on the config knob).  The last token's
+                    # row is never written.
+                    self.kv.publish(
+                        r, np.concatenate(
+                            [r.ctx, np.asarray(r.generated, np.int32)[
+                                r.ctx_len - r.prompt.size:]]),
+                        r.length - 1)
                 self.finished.append(r)
-                self.slots[i] = None
-                self.allocator.free(i)
-                self.page_table[i, :] = -1
-                self._draft_len[i] = 0
+                # the parked middle groups of a long-context session go
+                # with it
+                self.kv.forget(r)
+                self._unseat(r)
                 rec = self.request_latency.on_finish(r.uid)
                 if trace.enabled:
                     trace.event("request_reap", cat="request", uid=r.uid,
@@ -3358,11 +2009,8 @@ class RaggedInferenceEngineV2:
     # -- introspection ----------------------------------------------------
 
     def cache_bytes(self) -> int:
-        """Device bytes held by the paged KV cache (scales with
-        ``num_pages``, the blocked-KV contract the reference's allocator
-        provides — NOT with ``max_seqs * max_seq_len``)."""
-        return sum(leaf.size * leaf.dtype.itemsize
-                   for leaf in jax.tree_util.tree_leaves(self.cache))
+        """Device bytes held by the paged KV cache."""
+        return self.kv.cache_bytes()
 
     # -- convenience ------------------------------------------------------
 

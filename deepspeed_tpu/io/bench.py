@@ -104,54 +104,6 @@ def bench_point(directory: str, size_bytes: int, block_size: int,
             pass
 
 
-def raw_control(directory: str, size_bytes: int,
-                block: int = 8 << 20) -> Tuple[float, float]:
-    """Device-roofline CONTROL: single-stream O_DIRECT sequential
-    pwritev/preadv with a page-aligned buffer and NO ring engine, no
-    threads — what the raw device gives the dumbest possible writer.
-    Engine numbers near this are device-bound, not engine-bound;
-    an engine well below it has submission overhead to claim back.
-    Returns (read_gbps, write_gbps); (0, 0) when O_DIRECT is
-    unsupported on the target filesystem (e.g. tmpfs)."""
-    import mmap
-
-    path = os.path.join(directory, f"dstpu_io_ctrl_{os.getpid()}.bin")
-    buf = mmap.mmap(-1, block)                      # page-aligned
-    buf.write(os.urandom(min(block, 1 << 16)))
-    n_blocks = max(1, size_bytes // block)
-    try:
-        try:
-            fd = os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC |
-                         os.O_DIRECT)
-        except OSError:
-            return 0.0, 0.0
-        try:
-            t0 = time.perf_counter()
-            for i in range(n_blocks):
-                os.pwritev(fd, [buf], i * block)
-            os.fsync(fd)
-            wt = time.perf_counter() - t0
-        finally:
-            os.close(fd)
-        _sync_and_evict(path)
-        fd = os.open(path, os.O_RDONLY | os.O_DIRECT)
-        try:
-            t0 = time.perf_counter()
-            for i in range(n_blocks):
-                os.preadv(fd, [buf], i * block)
-            rt = time.perf_counter() - t0
-        finally:
-            os.close(fd)
-        gb = n_blocks * block / 1e9
-        return gb / rt, gb / wt
-    finally:
-        buf.close()
-        try:
-            os.remove(path)
-        except OSError:
-            pass
-
-
 def sweep(directory: str, size_bytes: int,
           block_sizes: Optional[List[int]] = None,
           thread_counts: Optional[List[int]] = None,

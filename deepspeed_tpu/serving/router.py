@@ -1254,7 +1254,7 @@ class Router:
         prefix cache ends up holding the same chains the donor's sticky
         pins reference.  Best-effort: a donor without a prefix cache
         (or with none populated) warms nothing."""
-        pfx = getattr(getattr(donor, "engine", None), "_pfx", None)
+        pfx = getattr(getattr(donor, "engine", None), "prefix_index", None)
         entries = getattr(pfx, "_entries", None)
         if not entries:
             return 0

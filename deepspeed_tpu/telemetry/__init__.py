@@ -23,8 +23,6 @@ in the tree:
 - :class:`~.slo.SLOSet` / :class:`~.slo.TailSampler` — objectives like
   ``"ttft_ms_p99 <= 150"`` with rolling-window error-budget burn rate,
   and the tail-sampling promotion policy.
-- :mod:`~.profiler` — per-program device seconds from XPlane traces
-  (the host-vs-device split for bench rows).
 
 Enable knobs: ``DSTPU_TRACE=1`` (env) or
 ``telemetry.configure(enabled=True)``; ``DSTPU_TRACE_BUFFER`` sizes
@@ -51,12 +49,10 @@ from deepspeed_tpu.telemetry.flight import (dump_on_fault, last_dump_path,
                                             read_flight_record)
 from deepspeed_tpu.telemetry.requests import (RequestLatencyTracker,
                                               percentile)
-from deepspeed_tpu.telemetry import profiler
 
 __all__ = ["Tracer", "trace", "get_tracer", "configure", "flight",
            "dump_on_fault", "last_dump_path", "read_flight_record",
            "RequestLatencyTracker", "percentile",
            "metrics", "MetricsRegistry", "exponential_buckets",
            "get_registry", "validate_metrics_doc",
-           "Objective", "SLOSet", "TailSampler", "parse_objective",
-           "profiler"]
+           "Objective", "SLOSet", "TailSampler", "parse_objective"]

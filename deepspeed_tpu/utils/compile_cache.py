@@ -1,6 +1,6 @@
 """Where the persistent XLA compile cache lives — decided in one place.
 
-``chip_smoke.py``, ``bench.py`` and the examples call
+``chip_smoke.py``, ``benchmark/run.py`` and the examples call
 :func:`enable_compile_cache` before their first compile.  When
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this sets
 nothing; otherwise the cache goes to one fixed directory inside the

@@ -20,8 +20,8 @@ if not os.environ.get("DSTPU_TEST_ON_TPU"):
     # building unique tiny-model programs whose execution takes
     # milliseconds) — skip the backend optimization passes on the CPU
     # test path: measured 46% off the heaviest file, same results.
-    # Anything timing-sensitive runs on real hardware via bench.py, not
-    # here.  An explicit user setting of the flag wins.
+    # Anything timing-sensitive runs on the chip through benchmark/run.py,
+    # not here.  An explicit user setting of the flag wins.
     if "--xla_backend_optimization_level" not in os.environ.get(
             "XLA_FLAGS", ""):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +

@@ -1,8 +1,7 @@
 """AIO engine throughput floor (VERDICT r2 weak #6 / item 9).
 
-Absolute GB/s depends on the host's storage (the committed evidence is
-BENCH_MATRIX.json's ``io`` record, measured on the bench host next to the
-reference's 7/4 GB/s DeepNVMe numbers), so CI asserts a RELATIVE floor:
+Absolute GB/s depends on the host's storage (the reference's DeepNVMe
+numbers are 7/4 GB/s), so CI asserts a RELATIVE floor:
 the thread-pooled chunk-parallel engine must reach a healthy fraction of
 raw single-stream file IO on the same mount.  A serializing regression in
 the native pool (the failure mode that would justify an io_uring backend)
@@ -64,8 +63,7 @@ def test_aio_reaches_fraction_of_raw_io(tmp_path):
 def test_aio_combined_floor_vs_reference(tmp_path):
     """Sanity floor: the engine moves data at >= 0.2 GB/s combined even
     on modest CI disks (the reference's no-GDS 11 GB/s combined needs
-    4x NVMe hardware; BENCH_MATRIX.json carries the bench host's real
-    number)."""
+    4x NVMe hardware)."""
     r, w = bench_point(str(tmp_path), SIZE, block_size=8 << 20,
                        thread_count=8, loops=1)
     assert r + w >= 0.2, (r, w)

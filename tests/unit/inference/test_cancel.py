@@ -274,7 +274,7 @@ class TestCancelUnderPrefixSharing:
         # pages still out are exactly the prefix cache's resident
         # entries (published chains outlive their requests by design);
         # nothing else may hold a page
-        pfx_held = sum(1 for e in eng._pfx._entries.values()
+        pfx_held = sum(1 for e in eng.kv.pfx._entries.values()
                        if e.state == "resident")
         assert eng.allocator.free_pages == free0 - pfx_held, (
             f"leak: {free0 - pfx_held - eng.allocator.free_pages} pages "

@@ -146,14 +146,13 @@ class TestPlumbing:
         draft = LlamaForCausalLM(CFG)
         eng = make(params, fmt="int8", speculation="draft",
                    draft_model=draft, draft_params=params)
-        assert eng._draft_cfg.kv_cache_dtype == "int8"
         assert eng.draft_kv_cache_dtype == "int8"
-        assert _scale_leaves(eng._draft_cache)
+        assert _scale_leaves(eng.kv.draft_cache)
         over = make(params, fmt="int8", speculation="draft",
                     draft_model=draft, draft_params=params,
                     draft_kv_cache_dtype="none")
-        assert over._draft_cfg.kv_cache_dtype == "none"
-        assert not _scale_leaves(over._draft_cache)
+        assert over.draft_kv_cache_dtype == "none"
+        assert not _scale_leaves(over.kv.draft_cache)
 
     def test_serving_stages_kv_quant_block(self, params):
         eng = make(params, fmt="int8")
@@ -242,7 +241,7 @@ class TestComposition:
         # after the drain only the index's resident entries hold refs,
         # and close() releases those too
         fin = eng.audit_kv_sharing()
-        assert fin["referenced"] == eng._pfx.stats()["resident_entries"]
+        assert fin["referenced"] == eng.kv.pfx.stats()["resident_entries"]
         eng.close()
         assert eng.allocator.audit(external={})["referenced"] == 0
 

@@ -214,7 +214,7 @@ class TestAdmission:
         reg.set("kv.prefetch_lookahead", 2)
         assert eng.prefetch_lookahead == 2
         reg.set("kv.window_pages", 3)
-        assert eng._tier_cfg.window_pages == 3
+        assert eng.kv.tier_cfg.window_pages == 3
         eng.close()
 
 
@@ -281,7 +281,7 @@ class TestComposition:
             steps += 1
             assert steps < 8000
         fin = eng.audit_kv_sharing()
-        assert fin["referenced"] == eng._pfx.stats()["resident_entries"]
+        assert fin["referenced"] == eng.kv.pfx.stats()["resident_entries"]
         assert eng.tiering.audit()["sessions"] == 0, (
             "drained run leaves no parked payload")
         eng.close()

@@ -131,6 +131,6 @@ class TestFusedStep:
         prompts = [r.integers(1, 64, size=(s,), dtype=np.int32)
                    for s in (3, 17, 29, 9, 23)]
         eng.generate_all(prompts, max_new_tokens=4)
-        fn = eng._fused_step_fn()
+        fn = eng.fused_step()
         assert fn._cache_size() == 1, (
             f"expected 1 compiled variant, got {fn._cache_size()}")

@@ -147,14 +147,15 @@ def test_w8a8_native_int8_dots(params):
                                   max_seqs=2, max_seq_len=64,
                                   prefill_chunk=8, decode_block_size=4,
                                   quantize_weights="w8a8")
-    assert eng._wq_native and eng._wq == "w8a8"
+    assert eng.param_form["native"] and eng.param_form["wq"] == "w8a8"
     # weight-only int8 keeps the documented dequant semantics (no
     # silent activation quantization)
     eng_i8 = RaggedInferenceEngineV2(LlamaForCausalLM(CFG), params=params,
                                      max_seqs=2, max_seq_len=64,
                                      prefill_chunk=8, decode_block_size=4,
                                      quantize_weights="int8")
-    assert not eng_i8._wq_native and eng_i8._wq == "int8"
+    assert (not eng_i8.param_form["native"]
+            and eng_i8.param_form["wq"] == "int8")
     # and w8a8 on a model without native Dense consumption fails loudly
     from deepspeed_tpu.models.gptneox import (GPTNeoXForCausalLM,
                                               get_config as neox_config)
@@ -229,7 +230,7 @@ def test_w8a8_scan_stacked_params_unroll_eagerly():
                                   max_seq_len=64, prefill_chunk=8,
                                   decode_block_size=4,
                                   quantize_weights="w8a8")
-    assert not eng._unroll_params      # consumed at init
+    assert not eng.param_form["unroll"]      # consumed at init
     qleaves = [l for l in jax.tree_util.tree_leaves(
         eng.params, is_leaf=lambda x: isinstance(x, QuantizedWeight))
         if isinstance(l, QuantizedWeight)]

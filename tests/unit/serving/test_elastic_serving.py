@@ -268,7 +268,7 @@ class TestRouterElastic:
         k1 = _chunk_hash(ROOT_HASH, (1, 2, 3, 4))
         k2 = _chunk_hash(k1, (5, 6, 7, 8))
         k3 = _chunk_hash(ROOT_HASH, (9, 9, 9, 9))
-        donor.engine = types.SimpleNamespace(_pfx=types.SimpleNamespace(
+        donor.engine = types.SimpleNamespace(prefix_index=types.SimpleNamespace(
             _entries=OrderedDict([
                 (k1, types.SimpleNamespace(parent=ROOT_HASH,
                                            tokens=(1, 2, 3, 4))),
@@ -507,5 +507,36 @@ class TestElasticServingParity:
             assert summary["handed_off"] >= 1
             assert s["sessions_handed_off"] == summary["handed_off"]
             assert [h.name for h in rs] == ["r1"]
+        finally:
+            rs.close()
+
+    def test_grow_warms_from_a_real_prefix_cache_donor(self, params):
+        """The donor is a real engine with the prefix cache on: the
+        router finds its index under the engine's public name, replays
+        the cached chains, and the new replica's own index holds them
+        afterwards (the fake above cannot see a renamed member)."""
+        rs = ReplicaSet(lambda i: RaggedInferenceEngineV2(
+            LlamaForCausalLM(CFG), params=params, pipeline=True,
+            rng=jax.random.PRNGKey(11), max_seqs=3, max_seq_len=128,
+            prefill_chunk=16, page_size=16, decode_block_size=4,
+            prefix_cache=True), 1)
+        try:
+            router = Router(rs, policy="least_tokens")
+            shared = _prompts((48,), seed=5)[0]
+            for tail in _prompts((5, 9), seed=6):
+                router.submit(np.concatenate([shared, tail]),
+                              max_new_tokens=4)
+            router.drain()
+            donor = rs.handles[0]
+            assert len(donor.engine.prefix_index) > 0
+            (h2,) = rs.grow(1)
+            assert len(h2.engine.prefix_index) == 0
+            router.add_replica(h2, warm_from=donor)
+            assert len(h2.engine.prefix_index) > 0
+            # the shared 48-token chain now hits on the new replica
+            hit = h2.engine.prefix_index.match(
+                np.concatenate([shared, _prompts((7,), seed=8)[0]]),
+                touch=False)
+            assert len(hit) == 3
         finally:
             rs.close()

@@ -313,7 +313,7 @@ class TestPrefixServingParity:
         assert len(outs) == 10
         fin = eng.audit_kv_sharing()
         # only the index's resident entries survive the drain
-        assert fin["referenced"] == eng._pfx.stats()["resident_entries"]
+        assert fin["referenced"] == eng.kv.pfx.stats()["resident_entries"]
         eng.close()
         assert eng.allocator.audit(external={})["referenced"] == 0
 
@@ -341,7 +341,7 @@ class TestPrefixComposition:
         sys_pages = 2
         first = _shared_prompts(3, sys_pages=sys_pages, seed=5)
         _serve(eng, first)
-        ix = eng._pfx
+        ix = eng.kv.pfx
         assert ix.stats()["resident_entries"] >= sys_pages
         # pressure stand-in: demote every reclaimable index page to the
         # tier store (keyed by prefix hash, not uid)
