@@ -337,9 +337,21 @@ def serve_phase(plan: Plan) -> Dict[str, Any]:
         "requests": len(plan.prompt_lens),
         "prompt_tokens": list(plan.prompt_lens),
         "new_tokens": plan.new_tokens, "transport": "loopback socket"}
-    want = ({"bf16": "pallas-ragged", "int8": "pallas-quant"}
+    from deepspeed_tpu.ops import paged_decode
+
+    cfg = plan.mistral
+    tiles = paged_decode.tiling(
+        cfg.num_key_value_heads, plan.serve["page_size"],
+        -(-plan.serve["max_seq_len"] // plan.serve["page_size"]),
+        cfg.head_dim, cfg.dtype)
+    # the decode blocks take the decode kernel wherever its layout holds
+    want = ({"bf16": "pallas-ragged", "int8": "pallas-quant",
+             "bf16_decode": (paged_decode.route_name(
+                 tiles, cfg.num_key_value_heads)
+                 if tiles else "pallas-ragged")}
             if plan.on_chip else
-            {"bf16": "xla-dense", "int8": "xla-gather"})
+            {"bf16": "xla-dense", "int8": "xla-gather",
+             "bf16_decode": "xla-dense"})
     routes: Dict[str, str] = {}
 
     # bf16 pool, the path of `examples/serve_llama.py --listen`
@@ -379,7 +391,9 @@ def serve_phase(plan: Plan) -> Dict[str, Any]:
         out["in_flight_passes_identical"] = together == again
         out["in_flight_identical_to_one_at_a_time"] = together == alone
         eng = rs.handles[0].engine
-        routes["bf16"] = eng.serving_stages()["kernels"]["paged_attention"]
+        kernels = eng.serving_stages()["kernels"]
+        routes["bf16"] = kernels["paged_attention"]
+        routes["bf16_decode"] = kernels.get(paged_decode.KERNEL)
         out["logits_max_abs_diff_vs_plain_apply"] = round(_logits_parity(
             plan, eng, model,
             _prompts(plan, (plan.parity_prompt_len,))[0]), 5)

@@ -13,6 +13,9 @@ Device side, attention over the paged cache is JAX's built-in vLLM-TPU
 Pallas kernel (``jax.experimental.pallas.ops.tpu.ragged_paged_attention``)
 on TPU, and :func:`ref_paged_attention` — an XLA-compilable, mask-based
 equivalent of the kernel's reference math — everywhere else (CPU tests).
+A decode program, which has one query row a sequence slot and says so in
+its ``ragged_meta``, takes ``ops/paged_decode.py`` instead wherever that
+kernel's layout holds.
 The page allocator is host-side Python, like the reference's scheduler
 tier.
 
@@ -32,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.ops import routes
+from deepspeed_tpu.ops.paged_decode import paged_decode_attention
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 
 TRASH_PAGE = 0
@@ -612,7 +616,25 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     page_indices = ragged_meta["page_indices"]
     window = getattr(cfg, "sliding_window", None)
 
-    def attend(qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs):
+    # a decode program says so itself (build_decode_block: one query a
+    # slot, ``cu_q_lens`` an arange): its attention is the decode kernel's
+    # wherever that kernel's layout holds for this shard of the pool, and
+    # otherwise, as for every other program, the call in attend_ragged
+    one_query_a_slot = (ragged_meta.get("one_query_a_slot") is True
+                        and carry is None and qmax is None)
+
+    def attend(qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
+               live=None):
+        ragged = (qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs)
+        if not one_query_a_slot:
+            return attend_ragged(*ragged)
+        return paged_decode_attention(
+            qt, pages, kv_lens, page_indices, live, sm_scale=sm_scale,
+            sliding_window=window, fallback=lambda: attend_ragged(*ragged),
+            fallback_route=paged_attention_path(D))
+
+    def attend_ragged(qt, pages, kv_lens, page_indices, cu_q_lens,
+                      num_seqs):
         # the vLLM-TPU kernel is built for head_dim 128 (its lane-width
         # row stats assert on smaller D); other dims take the XLA
         # reference — correct but O(T * total_page_rows), serving-shape
@@ -672,6 +694,9 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     # required for the Pallas kernel, which composes with shard_map, not
     # with GSPMD auto-sharding
     tp = _serving_tp(cfg)
+    # which slots of a decode program are in use, where it says
+    live = ([ragged_meta["live"]]
+            if one_query_a_slot and "live" in ragged_meta else [])
     if tp > 1:
         from jax.sharding import PartitionSpec as P
 
@@ -689,10 +714,11 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                 attend, mesh=mesh,
                 in_specs=(P(None, "tensor", None),
                           P(None, None, "tensor", None), P(), P(), P(),
-                          P()),
+                          P()) + (P(),) * len(live),
                 out_specs=P(None, "tensor", None),
                 axis_names={"tensor"}, check_vma=False)(
-                    qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs)
+                    qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
+                    *live)
         else:
             # the scale buffer shards its combined-head dim with the pool
             y = _shard_map_compat(
@@ -705,7 +731,8 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                     qt, pages, kv_scales, kv_lens, page_indices,
                     cu_q_lens, num_seqs)
     elif qmax is None:
-        y = attend(qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs)
+        y = attend(qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
+                   *live)
     else:
         y = attend_quant(qt, pages, kv_scales, kv_lens, page_indices,
                          cu_q_lens, num_seqs)

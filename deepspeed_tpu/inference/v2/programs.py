@@ -360,7 +360,10 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
                     "page_indices": page_table,
                     "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
                     "num_seqs": jnp.asarray([S], jnp.int32),
-                    "new_kv_dest": dest, "live": active}
+                    "new_kv_dest": dest, "live": active,
+                    # static: row s is slot s's one query, which is what
+                    # inference/paged.py picks the decode kernel by
+                    "one_query_a_slot": True}
             out, vars_ = model.apply(
                 {"params": params, "cache": cache}, last_tok[None],
                 positions=jnp.where(active, pos, 0)[None],

@@ -900,11 +900,14 @@ class RaggedInferenceEngineV2:
             route = paged_attention_path(head_dim)
         # the attention read route this backend gives this pool: a Pallas
         # kernel, or the XLA twin it stands in for (ops/routes.py)
-        out["kernels"] = {"paged_attention": route}
+        from deepspeed_tpu.ops import paged_decode, routes
+
+        # and what each decode program traced so far took for its own
+        out["kernels"] = {"paged_attention": route,
+                          **routes.taken(prefix=paged_decode.KERNEL)}
         st = self.host_stats
         if st.moe_rows:
             from deepspeed_tpu.moe.sharded_moe import GROUPED_MATMUL
-            from deepspeed_tpu.ops import routes
             # its two keys, as the trace of this process's programs left them
             out["kernels"].update(routes.taken(prefix=GROUPED_MATMUL))
             out["moe"] = {"rows": st.moe_rows,

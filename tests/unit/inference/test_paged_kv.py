@@ -134,3 +134,124 @@ class TestFusedStep:
         fn = eng.fused_step()
         assert fn._cache_size() == 1, (
             f"expected 1 compiled variant, got {fn._cache_size()}")
+
+
+# -- which attention a program takes (inference/paged.py:attend) -------------
+
+# the narrowest model the decode kernel's layout holds for: head_dim 128,
+# 8 KV heads, a bf16 pool
+WIDE = get_config("tinyllama", vocab_size=64, hidden_size=1024,
+                  intermediate_size=64, num_hidden_layers=1,
+                  num_attention_heads=8, num_key_value_heads=8,
+                  max_position_embeddings=128, dtype=jnp.bfloat16,
+                  param_dtype=jnp.bfloat16, scan_layers=True, remat=False,
+                  use_flash_attention=False)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    model = LlamaForCausalLM(WIDE)
+    return jax.jit(model.init)(jax.random.PRNGKey(3),
+                               np.zeros((1, 8), np.int32))
+
+
+def _wide_engine(params, **kw):
+    return RaggedInferenceEngineV2(
+        LlamaForCausalLM(WIDE), params=params, max_seqs=4, max_seq_len=128,
+        prefill_chunk=16, page_size=16, **kw)
+
+
+class TestDecodeKernelSelection:
+    """A decode block says it has one query a slot and takes the decode
+    kernel where its layout holds; every other program keeps the call it
+    had, whatever its shapes."""
+
+    @pytest.fixture
+    def interpreter(self, monkeypatch):
+        """The decode kernel through the Pallas interpreter, and a clean
+        record of routes."""
+        import functools
+
+        from deepspeed_tpu.inference import paged
+        from deepspeed_tpu.ops import paged_decode, routes
+
+        monkeypatch.setattr(paged, "paged_decode_attention", functools.partial(
+            paged_decode.paged_decode_attention, interpret=True))
+        monkeypatch.setattr(routes, "_taken", {})
+        return lambda: routes.taken().get(paged_decode.KERNEL)
+
+    def _prompts(self):
+        r = np.random.default_rng(5)
+        return [r.integers(1, 64, size=(n,), dtype=np.int32)
+                for n in (5, 21, 9)]
+
+    def test_decode_block_takes_the_kernel_and_gives_the_twins_tokens(
+            self, wide_params, interpreter, monkeypatch):
+        eng = _wide_engine(wide_params, decode_block_size=4)
+        got = eng.generate_all(self._prompts(), max_new_tokens=9)
+        assert interpreter() == "pallas-decode-16x8-interpret"
+        kernels = eng.serving_stages()["kernels"]
+        assert kernels == {"paged_attention": "xla-dense",
+                           "paged_attention_decode":
+                               "pallas-decode-16x8-interpret"}
+        from deepspeed_tpu.inference import paged
+        from deepspeed_tpu.ops import paged_decode
+
+        # the same engine on the route a CPU gives it
+        monkeypatch.setattr(paged, "paged_decode_attention",
+                            paged_decode.paged_decode_attention)
+        twin = _wide_engine(wide_params, decode_block_size=4)
+        want = twin.generate_all(self._prompts(), max_new_tokens=9)
+        assert interpreter() == "pallas-decode-16x8-interpret+xla-dense"
+        for a, b in zip(sorted(got), sorted(want)):
+            np.testing.assert_array_equal(got[a], want[b])
+
+    def test_fused_ticks_alone_never_ask(self, wide_params, interpreter):
+        eng = _wide_engine(wide_params, decode_block_size=1)
+        eng.generate_all(self._prompts(), max_new_tokens=3)
+        assert interpreter() is None
+        assert "paged_attention_decode" not in eng.serving_stages()["kernels"]
+
+    def test_a_speculative_verify_tick_never_asks(self, wide_params,
+                                                  interpreter):
+        eng = _wide_engine(wide_params, decode_block_size=4,
+                           speculation="ngram")
+        eng.generate_all(self._prompts(), max_new_tokens=9)
+        assert eng.host_stats.spec_dispatches > 0
+        assert interpreter() is None
+
+    def test_a_tick_with_a_carry_keeps_the_reference(self, interpreter):
+        """The chunk scan's finish dispatch folds earlier chunks' stats:
+        no kernel but the quantized pool's takes a carry."""
+        import flax.linen as nn
+
+        from deepspeed_tpu.inference import paged
+
+        S, Hq, Dh, P, page = 2, 8, 128, 4, 16
+        cfg = dataclasses.replace(WIDE, kv_num_pages=P, kv_page_size=page)
+
+        class Attend(nn.Module):
+            @nn.compact
+            def __call__(self, q, k, v, meta):
+                return paged.paged_update_and_attend(self, q, k, v, meta, cfg)
+
+        r = np.random.default_rng(0)
+        q, k, v = (jnp.asarray(r.standard_normal((1, Hq, S, Dh)),
+                               jnp.bfloat16) for _ in range(3))
+        meta = {"kv_lens": jnp.asarray([3, 1], jnp.int32),
+                "page_indices": jnp.asarray([[1, -1], [2, -1]], jnp.int32),
+                "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
+                "num_seqs": jnp.asarray([S], jnp.int32),
+                "new_kv_dest": jnp.asarray([page + 2, 2 * page], jnp.int32),
+                "live": jnp.asarray([True, True]),
+                "one_query_a_slot": True}
+        plain, _ = Attend().apply({}, q, k, v, meta, mutable=["cache"])
+        assert interpreter() == "pallas-decode-16x8-interpret"
+        m, l, acc = paged.neutral_carry(S, Hq, Dh)
+        carried, _ = Attend().apply(
+            {}, q, k, v, dict(meta, carry_m=m, carry_l=l, carry_acc=acc),
+            mutable=["cache"])
+        assert interpreter() == "pallas-decode-16x8-interpret"   # not asked
+        np.testing.assert_allclose(np.asarray(plain, np.float32),
+                                   np.asarray(carried, np.float32),
+                                   atol=1.6e-2)

@@ -145,6 +145,102 @@ def test_upstream_ragged_paged_kernel_bf16_pages(chip_compile, heads):
         ((POOL, PAGE, 2 * hkv, D), jnp.bfloat16), *META)
 
 
+# the engine sizes of both serving configurations: 32 slots, 64 pages of 64
+# tokens a sequence, a pool of 1,280 pages
+ENGINE_SLOTS, ENGINE_PAGES_PER_SEQ, ENGINE_POOL = 32, 64, 1280
+
+
+@pytest.mark.parametrize("heads,window,tiles", [
+    pytest.param((32, 8), WINDOW, (4, 4), id="mistral-8kv"),
+    pytest.param((16, 16), None, (2, 2), id="olmoe-16kv")])
+def test_decode_kernel_at_the_serving_configurations(chip_compile, heads,
+                                                     window, tiles):
+    """``ops/paged_decode.py`` at the tiling its rule gives, under the
+    default scoped VMEM, and the name a device trace will show for it."""
+    from deepspeed_tpu.ops import paged_decode as pd
+
+    h, hkv = heads
+    assert pd.tiling(hkv, PAGE, ENGINE_PAGES_PER_SEQ, D,
+                     jnp.bfloat16) == tiles
+    text = chip_compile(
+        functools.partial(pd._decode, sm_scale=D ** -0.5,
+                          sliding_window=window, tiles=tiles,
+                          interpret=False),
+        ((ENGINE_SLOTS, h, D), jnp.bfloat16),
+        ((ENGINE_POOL, PAGE, 2 * hkv, D), jnp.bfloat16),
+        ((ENGINE_SLOTS,), jnp.int32),
+        ((ENGINE_SLOTS, ENGINE_PAGES_PER_SEQ), jnp.int32),
+        ((ENGINE_SLOTS,), jnp.bool_))
+    assert pd.NAME == "paged_decode_attention" and f"%{pd.NAME}." in text
+
+
+def test_each_program_holds_its_own_attention_kernel(one_chip, chip_compile,
+                                                     monkeypatch):
+    """The engine's two programs at ``mistral-7b-serve``'s widths and engine
+    sizes, two layers deep, compiled for the described chip: the decode
+    block holds one ``paged_decode_attention`` call a layer and no
+    ``ragged_paged_attention_kernel``, the fused tick the reverse.  The
+    switches ask for the backend and see the CPU here, so the test answers
+    for them."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import programs
+    from deepspeed_tpu.models import mistral
+    from deepspeed_tpu.ops import routes
+
+    monkeypatch.setattr(routes, "on_tpu", lambda: True)
+    monkeypatch.setattr(routes, "_taken", {})
+    layers, chunk, max_len = 2, 512, PAGE * ENGINE_PAGES_PER_SEQ
+    slots, width = ENGINE_SLOTS, ENGINE_SLOTS + 512
+    model = mistral.MistralForCausalLM(programs.serving_config(
+        mistral.get_config(
+            "mistral-7b", num_hidden_layers=layers, dtype=jnp.bfloat16,
+            param_dtype=jnp.bfloat16, scan_layers=False, remat=False,
+            use_flash_attention=False),
+        max_seq_len=max_len, page_size=PAGE, num_pages=ENGINE_POOL,
+        tensor_parallel=False, kv_cache_dtype="none"))
+    i32 = jnp.int32
+    meta = {"kv_lens": jnp.zeros((slots,), i32),
+            "page_indices": jnp.zeros((slots, ENGINE_PAGES_PER_SEQ), i32),
+            "cu_q_lens": jnp.zeros((slots + 1,), i32),
+            "num_seqs": jnp.zeros((1,), i32),
+            "new_kv_dest": jnp.zeros((width,), i32)}
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, width), i32),
+        positions=jnp.zeros((1, width), i32), ragged_meta=meta))
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, cache = (jax.tree_util.tree_map(
+        lambda s: spec(s.shape, s.dtype), shapes[k])
+        for k in ("params", "cache"))
+    per_slot = [spec((slots,), d) for d in (
+        i32, i32, jnp.bool_, i32)]                # last_tok .. remaining
+    table = spec((slots, ENGINE_PAGES_PER_SEQ), i32)
+    sampling = [spec((slots,), d) for d in (
+        i32, jnp.bool_, jnp.float32, i32, jnp.float32, jnp.uint32)]
+    decode = programs.build_decode_block(
+        model, sampled=False, max_seqs=slots, block=8, page_size=PAGE,
+        max_seq_len=max_len, sample_base=jax.random.PRNGKey(0))
+    fused = programs.build_fused_step(model)
+    texts = {
+        "decode": decode.lower(params, cache, *per_slot, table, *sampling,
+                               spec((2,), jnp.uint32)).compile().as_text(),
+        "fused": fused.lower(
+            params, cache, spec((1, width), i32), spec((1, width), i32),
+            spec((slots,), i32), table, spec((slots + 1,), i32),
+            spec((1,), i32), spec((width,), i32),
+            spec((slots,), i32)).compile().as_text()}
+    calls = {name: (len(re.findall(r"%paged_decode_attention[.\d]* = ", t)),
+                    len(re.findall(
+                        r"%ragged_paged_attention_kernel[.\d]* = ", t)))
+             for name, t in texts.items()}
+    assert calls == {"decode": (layers, 0), "fused": (0, layers)}
+    assert routes.taken() == {"paged_attention": "pallas-ragged",
+                              "paged_attention_decode": "pallas-decode-4x8"}
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4

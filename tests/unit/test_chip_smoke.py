@@ -78,7 +78,8 @@ def test_one_chip_phases_and_last_line(chip_smoke, capsys, monkeypatch,
     assert train["phase"] == "train" and train["losses"][-1] < \
         train["losses"][0]
     assert serve["phase"] == "serve" and serve["compiles_after_warmup"] == 0
-    assert serve["routes"] == {"bf16": "xla-dense", "int8": "xla-gather"}
+    assert serve["routes"] == {"bf16": "xla-dense", "int8": "xla-gather",
+                               "bf16_decode": "xla-dense"}
     assert serve["streamed"] == 2
     assert cache["phase"] == "compile_cache" and cache["dir"] == \
         str(tmp_path)
@@ -125,6 +126,28 @@ def test_a_failing_phase_raises_before_any_result(chip_smoke, capsys,
     with pytest.raises(chip_smoke.SmokeFailure, match="fell to a reference"):
         chip_smoke.run(plan, chips=1)
     assert '"ok"' not in capsys.readouterr().out
+
+
+def test_a_decode_block_on_another_route_fails_the_serve_phase(
+        chip_smoke, monkeypatch):
+    """The route check holds the decode blocks' own key too: a decode
+    block that took another path than the plan expects (on the chip: the
+    fused tick's kernel or a twin where the decode kernel should run) is
+    a failure, whatever ``paged_attention`` says."""
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.ops import paged_decode, routes
+
+    def elsewhere(*args, fallback, **kw):
+        routes.take(paged_decode.KERNEL, eligible=False, every=True,
+                    xla="pallas-ragged")
+        return fallback()
+
+    monkeypatch.setattr(routes, "_taken", {})
+    monkeypatch.setattr(paged, "paged_decode_attention", elsewhere)
+    monkeypatch.setattr(jax, "devices", lambda *a: jax.local_devices()[:1])
+    with pytest.raises(chip_smoke.SmokeFailure,
+                       match="'bf16_decode': 'pallas-ragged'"):
+        chip_smoke.serve_phase(_tiny_plan(chip_smoke))
 
 
 def test_compile_cache_dir(monkeypatch, tmp_path, cache_config_restored):
