@@ -295,8 +295,8 @@ def _logits_parity(plan: Plan, eng, model, prompt: List[int]) -> float:
         args = eng.upload_all((
             ids[None], pos[None], kv_lens, pages, cu,
             np.asarray([1], np.int32), dest, rows))
-        sel, eng.kv.cache, _ = eng.fused_step()(eng.params, eng.kv.cache,
-                                                *args)
+        sel, eng.kv.cache, *_ = eng.fused_step()(eng.params, eng.kv.cache,
+                                                 *args)
         return np.asarray(sel[0], np.float32)
 
     at_last = tick(prompt, 0)

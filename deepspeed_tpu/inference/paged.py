@@ -25,6 +25,14 @@ indices and V at odd; a tick's new K/V rows are scattered into the flat
 page buffer BEFORE attention, and ``kv_lens`` includes this tick's
 tokens.  Page 0 is reserved as the trash page: padding tokens write
 there, no sequence is ever allocated it.
+
+A model whose layers run ``U`` times a token (``models/ouro.py``) needs
+``U`` K/V slots a layer.  Its layer's pool is ``[num_pages, U, page_size,
+2 * Hkv, Dh]``: a page holds all ``U`` slots of its tokens, so whatever
+copies, spills or shares ``leaf[page]`` moves them together and the
+allocator counts pages as before.  Pass ``u`` (``ragged_meta["kv_slot"]``)
+reads and writes the same buffer as ``[num_pages * U, ...]`` at page
+``p * U + u``; the kernels see an ordinary pool.
 """
 from __future__ import annotations
 
@@ -66,6 +74,12 @@ _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 # ---------------------------------------------------------------------------
 # Host-side page allocator (reference blocked_allocator.py)
 # ---------------------------------------------------------------------------
+
+def kv_slots_per_layer(cfg) -> int:
+    """K/V slots a layer holds for one token: the passes of a looped model
+    (its config's ``total_ut_steps``), 1 for every other."""
+    return int(getattr(cfg, "total_ut_steps", 1))
+
 
 def pages_for(n_tokens: int, page_size: int) -> int:
     """Pages needed to hold ``n_tokens`` (ceil-div, min 1) — the single
@@ -537,9 +551,15 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     ``carry_acc`` keys make the normal (finish) dispatch fold the
     accumulated chunk stats into its attention via the explicit-carry
     paths of the reference functions / quantized kernel.
+
+    A looped model (``cfg.total_ut_steps = U > 1``) says which pass this
+    is in ``ragged_meta["kv_slot"]``: the layer's pool has ``U`` slots a
+    page and this call reads and writes slot ``u`` alone, in place.
     """
     _, H, T, D = q.shape
+    slots = kv_slots_per_layer(cfg)
     if "staged_kv" in ragged_meta:
+        assert slots == 1, "partial residency has one K/V slot a layer"
         return _staged_attend_stats(mdl, q, ragged_meta, cfg)
     carry = None
     if "carry_m" in ragged_meta:
@@ -548,6 +568,24 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     Hkv = k.shape[1]
     P, page = cfg.kv_num_pages, cfg.kv_page_size
     assert P > 1, "paged_decode requires kv_num_pages (engine sets it)"
+    new_kv_dest = ragged_meta["new_kv_dest"]
+    page_indices = ragged_meta["page_indices"]
+    pool_shape = (P, page, 2 * Hkv, D)
+    if slots > 1:
+        assert carry is None and (
+            getattr(cfg, "kv_cache_dtype", "none") or "none") == "none", (
+            "a looped model's pool is full-width and wholly resident: no "
+            "quantized pages, no chunk-scan carry")
+        # slot u of page p is page p * U + u of the same buffer; a row
+        # bound for no page (past the pool) stays past it, and the -1 of
+        # an unused page-table entry stays -1
+        slot = ragged_meta["kv_slot"]
+        pool_shape = (P, slots, page, 2 * Hkv, D)
+        new_kv_dest = ((new_kv_dest // page * slots + slot) * page
+                       + new_kv_dest % page)
+        page_indices = jnp.where(page_indices >= 0,
+                                 page_indices * slots + slot, page_indices)
+        P = P * slots
 
     # KV-cache quantization (reference csrc/fp_quantizer selective_dequant
     # + inference v2 KV configs): pages persist in fp8 e4m3 or int8 with a
@@ -565,7 +603,7 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         store_dtype, qmax = k.dtype, None
 
     pages_var = mdl.variable(
-        "cache", "kv_pages", jnp.zeros, (P, page, 2 * Hkv, D), store_dtype)
+        "cache", "kv_pages", jnp.zeros, pool_shape, store_dtype)
 
     # interleave K/V onto combined heads: [T, 2Hkv, D], K even, V odd
     k_rows = k[0].transpose(1, 0, 2)                   # [T, Hkv, D]
@@ -574,10 +612,10 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
 
     flat = pages_var.value.reshape(P * page, 2 * Hkv, D)
     if qmax is None:
-        flat = flat.at[ragged_meta["new_kv_dest"]].set(
+        flat = flat.at[new_kv_dest].set(
             combined.astype(flat.dtype), mode="drop")
         pages = flat.reshape(P, page, 2 * Hkv, D)
-        pages_var.value = pages
+        pages_var.value = pages.reshape(pool_shape)
     else:
         scales_var = mdl.variable(
             "cache", "kv_scales", jnp.zeros, (P, page, 2 * Hkv),
@@ -594,11 +632,10 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         qv = cf / scale[..., None]
         if store_dtype == jnp.int8:
             qv = jnp.clip(jnp.round(qv), -qmax, qmax)
-        flat = flat.at[ragged_meta["new_kv_dest"]].set(
+        flat = flat.at[new_kv_dest].set(
             qv.astype(store_dtype), mode="drop")
         flat_s = scales_var.value.reshape(P * page, 2 * Hkv)
-        flat_s = flat_s.at[ragged_meta["new_kv_dest"]].set(scale,
-                                                           mode="drop")
+        flat_s = flat_s.at[new_kv_dest].set(scale, mode="drop")
         scales_var.value = flat_s.reshape(P, page, 2 * Hkv)
         pages = flat.reshape(P, page, 2 * Hkv, D)
         pages_var.value = pages
@@ -613,7 +650,6 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     kv_lens = ragged_meta["kv_lens"]
     cu_q_lens = ragged_meta["cu_q_lens"]
     num_seqs = ragged_meta["num_seqs"]
-    page_indices = ragged_meta["page_indices"]
     window = getattr(cfg, "sliding_window", None)
 
     # a decode program says so itself (build_decode_block: one query a

@@ -104,6 +104,11 @@ class LongContextDriver:
         assert not eng.param_form["unroll"], (
             "long-context needs unrolled layers_<i> params (the engine "
             "unrolls scan params itself in-jit; pass unrolled params)")
+        if getattr(eng, "kv_slots", 1) > 1:
+            raise NotImplementedError(
+                "long-context partial residency reads num_hidden_layers as "
+                "the number of K/V slots; a looped model "
+                f"({type(eng.model).__name__}) has {eng.kv_slots} a layer")
         assert isinstance(eng.model, LlamaForCausalLM), (
             "long-context partial residency supports llama-family "
             "models (the per-layer chunked scan mirrors LlamaBlock)")

@@ -21,7 +21,7 @@ they are built from: the serving form of a model and its weights
 (:func:`serving_config`, :func:`prepare_params`, :func:`place_params`,
 :func:`prepare_draft`), the zeroed page pools (:func:`init_cache`,
 :func:`cache_sharding`, :func:`paged_kv_page_bytes`) and the folding of the
-expert layers' counts.
+expert layers' and the pass loop's counts.
 """
 from __future__ import annotations
 
@@ -34,7 +34,7 @@ import numpy as np
 
 from deepspeed_tpu.inference.common import (logits_of, normalize_params,
                                             unroll_scan_params)
-from deepspeed_tpu.inference.paged import pages_for
+from deepspeed_tpu.inference.paged import kv_slots_per_layer, pages_for
 from deepspeed_tpu.inference.quantization import (dequantize_param_tree,
                                                   quantize_param_tree)
 from deepspeed_tpu.inference.sampling import (filter_logits_batched,
@@ -60,6 +60,20 @@ def moe_counts(variables) -> Optional[jax.Array]:
     for a model without expert layers, so its programs gain no output."""
     leaves = jax.tree_util.tree_leaves(variables.get("moe_stats", {}))
     return fold_moe_counts(jnp.stack(leaves)) if leaves else None
+
+
+# what a model may sow beside its cache: the dropless expert layers' counts
+# and a looped model's exit gate
+MUTABLE = ["cache", "moe_stats", "loop_stats"]
+
+
+def loop_counts(variables) -> Optional[jax.Array]:
+    """One apply's ``loop_stats`` collection: a looped model
+    (``models/ouro.py``) sows, over the rows that are tokens, the summed
+    probability of having stopped by the end of passes ``1..U-1``; None
+    for every other model, so its programs gain no output."""
+    leaves = jax.tree_util.tree_leaves(variables.get("loop_stats", {}))
+    return leaves[0] if leaves else None
 
 
 def serving_config(mcfg, *, max_seq_len: int, page_size: int,
@@ -317,11 +331,10 @@ def build_fused_step(model, *, unroll: bool = False,
                          cu_q_lens[num_seqs[0]])}
         out, vars_ = model.apply(
             {"params": params, "cache": cache}, token_ids,
-            positions=positions, mutable=["cache", "moe_stats"],
-            ragged_meta=meta)
+            positions=positions, mutable=MUTABLE, ragged_meta=meta)
         logits = logits_of(out)[0]                      # [T, V]
         sel = jnp.take(logits, sample_rows, axis=0)     # [max_seqs, V]
-        return sel, vars_["cache"], moe_counts(vars_)
+        return sel, vars_["cache"], moe_counts(vars_), loop_counts(vars_)
 
     # distinguishable XLA program name ("jit_ragged_fused_step") so
     # the profiler bridge can attribute device time per program
@@ -367,9 +380,9 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
             out, vars_ = model.apply(
                 {"params": params, "cache": cache}, last_tok[None],
                 positions=jnp.where(active, pos, 0)[None],
-                mutable=["cache", "moe_stats"], ragged_meta=meta)
+                mutable=MUTABLE, ragged_meta=meta)
             logits = logits_of(out)[0]              # [S, V]
-            moe = moe_counts(vars_)
+            moe, loop = moe_counts(vars_), loop_counts(vars_)
             rng, _ = jax.random.split(rng)
             # position-keyed per-row keys: the draw at cache position
             # `pos` is the same bits the fused tick's host sampler
@@ -387,16 +400,17 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
             active = (active & ~hit_eos & (remaining > 0) &
                       (pos + 1 < max_len))
             return (vars_["cache"], nxt, pos, active, remaining,
-                    rng), (nxt, produced, moe)
+                    rng), (nxt, produced, moe, loop)
 
-        carry, (toks, mask, moe) = jax.lax.scan(
+        carry, (toks, mask, moe, loop) = jax.lax.scan(
             tick, (cache, last_tok, pos, active, remaining, rng),
             length=block)
         cache, last_tok, pos, active, remaining, rng = carry
         # the full carry returns so the pipelined host path can keep
         # it device-resident across dispatches (no re-upload)
         return (cache, last_tok, pos, active, remaining, toks, mask,
-                None if moe is None else fold_moe_counts(moe))
+                None if moe is None else fold_moe_counts(moe),
+                None if loop is None else jnp.sum(loop, axis=0))
 
     run.__name__ = run.__qualname__ = "ragged_decode_block"
     return jax.jit(run, donate_argnums=(1,))

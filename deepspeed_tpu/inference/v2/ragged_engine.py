@@ -275,6 +275,40 @@ class RaggedInferenceEngineV2:
             "none" if o["kv_cache_dtype"] is None
             else str(o["kv_cache_dtype"]))
 
+        # K/V slots a layer keeps for a token: the passes of a looped model
+        # (models/ouro.py), whose page holds them all.  What copies whole
+        # pages (prefix copy-on-write, spill, restore) moves them together;
+        # what reads a layer's pool as ONE slot is refused by name
+        self.kv_slots = programs.kv_slots_per_layer(mcfg)
+        # passes a token and K/V rows a cached token owns, as the dispatch
+        # spans and serving_stages() say them; nothing for other models
+        self._loop_attrs = ({} if self.kv_slots == 1 else {
+            "ut_steps": self.kv_slots,
+            "kv_slots": self.kv_slots * int(mcfg.num_hidden_layers)})
+        if self.kv_slots > 1:
+            looped = (f"{type(model).__name__} runs its layers "
+                      f"{self.kv_slots} times a token")
+            spec = SpeculationConfig.coerce(o["speculation"])
+            if spec.mode != "off":
+                raise NotImplementedError(
+                    f"speculation.mode={spec.mode!r}: {looped}, and the "
+                    "speculative block's rollback and draft pool know one "
+                    "K/V slot a layer")
+            if KVTieringConfig.coerce(o["kv_tiering"]).long_context:
+                raise NotImplementedError(
+                    f"kv_tiering.long_context: {looped}, and the partial-"
+                    "residency scan (inference/v2/long_context.py) reads "
+                    "num_hidden_layers as the number of K/V slots")
+            if kv_cache_dtype != "none":
+                raise NotImplementedError(
+                    f"kv_cache_dtype={kv_cache_dtype!r}: {looped}; its "
+                    "pool is full-width (the quantized pool's scale rows "
+                    "have one slot a layer)")
+            if self.tp > 1:
+                raise NotImplementedError(
+                    f"tensor-parallel serving: {looped}, and the pool's "
+                    "sharding rule knows pages of one slot")
+
         self.page_size = int(page_size)
         self.pages_per_seq = pages_for(max_seq_len, self.page_size)
         if num_pages is None and kv_pool_bytes is not None:
@@ -394,9 +428,10 @@ class RaggedInferenceEngineV2:
         self.slots: List[Optional[Request]] = [None] * max_seqs
         self.finished: List[Request] = []
         self._unclaimed: Dict[int, np.ndarray] = {}
-        # (device counts, span attributes) of dispatched expert-layer
-        # programs whose counts no fetch has brought to the host yet
-        self._moe_pending: List[Tuple[Any, Dict[str, Any]]] = []
+        # (device counts, recorder, span attributes) of dispatched
+        # programs whose expert-layer or pass-loop counts no fetch has
+        # brought to the host yet
+        self._counts_pending: List[Tuple[Any, Any, Dict[str, Any]]] = []
         # each slot's pending token: sampled, not yet written to the pool
         self.last_tokens = np.zeros((max_seqs,), np.int32)
         # generated-token count already reported per uid (stream_deltas)
@@ -913,6 +948,11 @@ class RaggedInferenceEngineV2:
             out["moe"] = {"rows": st.moe_rows,
                           "experts_hit": st.moe_experts_hit,
                           "load_max": st.moe_load_max}
+        if self.kv_slots > 1:
+            # a looped model: passes a token, K/V slots a cached token,
+            # and the exit gate's sums as far as fetches have brought them
+            out["loop"] = {**self._loop_attrs, "rows": st.loop_rows,
+                           "exit_cdf": list(st.loop_exit_cdf)}
         # pool pressure: the scale-out router's least-pressure policy
         # reads this (waiting queue + page occupancy, both plain host
         # ints — no device sync)
@@ -979,14 +1019,15 @@ class RaggedInferenceEngineV2:
         point (``host_stats.blocking_gets`` counts them)."""
         with self.host_stats.stage("device"):
             self.host_stats.blocking_gets += 1
-            if not self._moe_pending:
+            if not self._counts_pending:
                 return jax.device_get(tree)
-            # the expert layers' counts of the dispatches since the last
-            # fetch ride on this one: no round trip of their own
-            pending, self._moe_pending = self._moe_pending, []
-            tree, counts = jax.device_get((tree, [c for c, _ in pending]))
-        for c, (_, attrs) in zip(counts, pending):
-            self._record_moe(c, attrs)
+            # the expert layers' and the pass loop's counts of the
+            # dispatches since the last fetch ride on this one: no round
+            # trip of their own
+            pending, self._counts_pending = self._counts_pending, []
+            tree, counts = jax.device_get((tree, [p[0] for p in pending]))
+        for c, (_, record, attrs) in zip(counts, pending):
+            record(c, attrs)
         return tree
 
     def _record_moe(self, counts, attrs: Dict[str, Any]) -> None:
@@ -999,6 +1040,22 @@ class RaggedInferenceEngineV2:
         st.moe_load_max = max(st.moe_load_max, load)
         with trace.bracket("engine.moe", cat="serving", moe_rows=rows,
                            moe_experts_hit=hit, moe_load_max=load, **attrs):
+            pass
+
+    def _record_loop(self, exit_cdf, attrs: Dict[str, Any]) -> None:
+        """One dispatch's exit-gate sums of a looped model, as a span of
+        their own (``engine.loop``) and in ``serving_stages()['loop']``:
+        ``exit_cdf_<u>`` is the probability of having stopped by the end
+        of pass ``u`` (``u < U``), summed over the dispatch's live rows
+        and ticks."""
+        cdf = [float(v) for v in exit_cdf]
+        st = self.host_stats
+        st.loop_rows += int(attrs["rows"])
+        st.loop_exit_cdf = [a + b for a, b in zip(
+            st.loop_exit_cdf or [0.0] * len(cdf), cdf)]
+        with trace.bracket("engine.loop", cat="serving", **attrs,
+                           **{f"exit_cdf_{u + 1}": v
+                              for u, v in enumerate(cdf)}):
             pass
 
     def _block_ready(self, block):
@@ -1228,10 +1285,11 @@ class RaggedInferenceEngineV2:
                         ticks=self.decode_block_size)
         with self._decode_dispatch(reqs, [r.length for r in reqs]):
             (cache, new_last, _pos, _active, _remaining, toks, mask,
-             moe) = self.decode_block(sampled)(
+             moe, loop) = self.decode_block(sampled)(
                 self.params, self.kv.cache, *args, sub)
         self.kv.cache = cache
-        self._moe_dispatched(moe, "decode_block", self.decode_block_size)
+        self._counts_dispatched(moe, loop, "decode_block",
+                                self.decode_block_size, len(reqs))
         st.ticks += self.decode_block_size
         toks, mask, new_last = self.fetch((toks, mask, new_last))
         st.harvests += 1
@@ -1247,12 +1305,20 @@ class RaggedInferenceEngineV2:
             self._reap()
         return produced
 
-    def _moe_dispatched(self, counts, program: str, ticks: int) -> None:
-        """Keep a dispatch's expert-layer counts (a device array, or None
-        for a model without expert layers) until a fetch brings them."""
-        if counts is not None:
-            self._moe_pending.append(
-                (counts, {"program": program, "ticks": ticks}))
+    def _counts_dispatched(self, moe, loop, program: str, ticks: int,
+                           rows: int) -> None:
+        """Keep a dispatch's expert-layer and pass-loop counts (device
+        arrays; None for a model without expert layers, or whose layers
+        run once) until a fetch brings them.  ``rows``: its token rows
+        (a fused tick) or live slots (a decode block) at dispatch."""
+        if moe is not None:
+            self._counts_pending.append(
+                (moe, self._record_moe, {"program": program,
+                                         "ticks": ticks}))
+        if loop is not None:
+            self._counts_pending.append(
+                (loop, self._record_loop, {"program": program,
+                                           "ticks": ticks, "rows": rows}))
 
     def _decode_dispatch(self, reqs: List[Request], contexts):
         """The ``dispatch`` bracket of one plain decode block, with the
@@ -1266,7 +1332,16 @@ class RaggedInferenceEngineV2:
         st.dispatches += 1
         st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
         return st.stage("dispatch", program="decode_block", rows=rows,
-                        ticks=K, key_tokens=keys)
+                        ticks=K, key_tokens=keys, **self._pool_counts())
+
+    def _pool_counts(self) -> Dict[str, int]:
+        """What every ``engine.dispatch`` span says of the pool as the
+        allocator has it at dispatch (``pages_in_use`` of ``pages_total``
+        usable pages), and of a looped model's passes: ``ut_steps`` a
+        token and ``kv_slots`` (passes x layers) a cached token."""
+        total = self.num_pages - 1
+        return {"pages_in_use": total - self.allocator.free_pages,
+                "pages_total": total, **self._loop_attrs}
 
     # -- the pipelined decode loop (serving host-path tentpole) ----------
 
@@ -1408,13 +1483,13 @@ class RaggedInferenceEngineV2:
             with self._decode_dispatch(
                     live, [dv["plen"][r.slot] for r in live]):
                 (self.kv.cache, dv["last_tok"], dv["pos"], dv["active"],
-                 dv["remaining"], toks, mask,
-                 moe) = self.decode_block(dv["sampled"])(
+                 dv["remaining"], toks, mask, moe,
+                 loop) = self.decode_block(dv["sampled"])(
                     self.params, self.kv.cache, dv["last_tok"], dv["pos"],
                     dv["active"], dv["remaining"], dv["page_table"],
                     dv["eos_ids"], dv["do_sample"], dv["temperature"],
                     dv["top_k"], dv["top_p"], dv["seeds"], sub)
-            self._moe_dispatched(moe, "decode_block", K)
+            self._counts_dispatched(moe, loop, "decode_block", K, len(live))
             block = (toks, mask)
         # track the block in the bounded window: past async_depth the
         # submit first joins the oldest un-joined block (waiting for
@@ -1636,11 +1711,13 @@ class RaggedInferenceEngineV2:
         st.prefill_tokens += counts["prefill_tokens"]
         st.prefill_key_tokens += counts["prefill_key_tokens"]
         st.decode_key_token_steps += counts["decode_key_tokens"]
-        with st.stage("dispatch", program="fused", **counts):
+        with st.stage("dispatch", program="fused", **counts,
+                      **self._pool_counts()):
             st.dispatches += 1
-            sel_logits, self.kv.cache, moe = self.fused_step()(
+            sel_logits, self.kv.cache, moe, loop = self.fused_step()(
                 self.params, self.kv.cache, *args)
-        self._moe_dispatched(moe, "fused", 1)
+        self._counts_dispatched(moe, loop, "fused", 1,
+                                int(cu_q_lens[int(num_seqs[0])]))
         st.ticks += 1
         produced = self.sample(sel_logits, samplers)
         # a handoff prefill that just sampled its first token parks NOW

@@ -17,7 +17,7 @@ classification the reference's AutoTP applies by name).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, ClassVar, Optional
+from typing import Any, Callable, ClassVar, Optional
 
 import flax.linen as nn
 import jax
@@ -84,6 +84,9 @@ class LlamaConfig:
     # natively (dynamic activation quant + int8 MXU dot) — set by the
     # inference engines when quantize_weights engages, never for training
     weight_quant: str = "none"
+    # how attention's and the MLP's matrices are drawn; None is flax's own
+    # (lecun normal, drawn IN ``param_dtype``).  models/ouro.py sets it
+    kernel_init: Optional[Callable] = None
 
     def __post_init__(self):
         assert self.sequence_parallel in ("none", "ulysses", "ring"), (
@@ -136,6 +139,10 @@ def _tp_kwargs(cfg: LlamaConfig, kind: str):
     return tp_dense_kwargs(cfg.tensor_parallel, kind)
 
 
+def _init_kwargs(cfg: LlamaConfig):
+    return {} if cfg.kernel_init is None else {"kernel_init": cfg.kernel_init}
+
+
 def _wq_kwargs(cfg: LlamaConfig):
     from deepspeed_tpu.inference.quantization import \
         weight_quant_dense_kwargs
@@ -146,10 +153,11 @@ def _wq_kwargs(cfg: LlamaConfig):
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    scale_init: float = 1.0          # the first value of every scale
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones_init(),
+        scale = self.param("scale", nn.initializers.constant(self.scale_init),
                            (x.shape[-1],), jnp.float32)
         xf = x.astype(jnp.float32)
         var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -185,7 +193,8 @@ class LlamaAttention(nn.Module):
         H, Hkv, Dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                       cfg.head_dim)
         dense = dict(use_bias=False, dtype=cfg.dtype,
-                     param_dtype=cfg.param_dtype, **_wq_kwargs(cfg))
+                     param_dtype=cfg.param_dtype, **_wq_kwargs(cfg),
+                     **_init_kwargs(cfg))
         # Qwen2: biases on q/k/v only, never on o_proj
         qkv = dict(dense, use_bias=cfg.attention_bias)
         q = nn.Dense(H * Dh, name="q_proj", **qkv,
@@ -308,7 +317,8 @@ class LlamaMLP(nn.Module):
 
         cfg = self.config
         dense = dict(use_bias=False, dtype=cfg.dtype,
-                     param_dtype=cfg.param_dtype, **_wq_kwargs(cfg))
+                     param_dtype=cfg.param_dtype, **_wq_kwargs(cfg),
+                     **_init_kwargs(cfg))
         gate = nn.Dense(cfg.intermediate_size, name="gate_proj", **dense,
                         **_tp_kwargs(cfg, "col"))(x)
         up = nn.Dense(cfg.intermediate_size, name="up_proj", **dense,

@@ -126,7 +126,7 @@ def _first_tick_logits(eng, prompts, which):
     eng._admit()
     (token_ids, positions, kv_lens, page_indices, cu_q_lens, num_seqs,
      new_kv_dest, sample_rows, samplers, _) = eng._plan_tick()
-    sel, eng.kv.cache, counts = eng.fused_step()(
+    sel, eng.kv.cache, counts, _ = eng.fused_step()(
         eng.params, eng.kv.cache, token_ids[None], positions[None], kv_lens,
         page_indices, cu_q_lens, num_seqs, new_kv_dest, sample_rows)
     j = [j for r, j, _ in samplers if r.uid == which][0]

@@ -241,6 +241,113 @@ def test_each_program_holds_its_own_attention_kernel(one_chip, chip_compile,
                               "paged_attention_decode": "pallas-decode-4x8"}
 
 
+# ouro-2.6b-serve: 16 slots, 16 pages of 64 tokens a sequence, 85 pages of
+# 4 K/V slots each, which both kernels read as a pool of 340
+LOOP_SLOTS, LOOP_PAGES_PER_SEQ, LOOP_STEPS, LOOP_POOL = 16, 16, 4, 85
+
+
+def test_both_kernels_on_a_looped_models_pool(chip_compile):
+    """16 KV heads of 128 on ``P * U`` = 340 pages: the decode kernel at
+    the tiling its rule gives for 16 pages a sequence, and the upstream
+    kernel for a fused tick of 16 + 512 rows, no window."""
+    from jax.experimental.pallas.ops.tpu.ragged_paged_attention import \
+        kernel as rpa
+
+    from deepspeed_tpu.ops import paged_decode as pd
+
+    h = hkv = 16
+    pool = ((LOOP_POOL * LOOP_STEPS, PAGE, 2 * hkv, D), jnp.bfloat16)
+    tiles = pd.tiling(hkv, PAGE, LOOP_PAGES_PER_SEQ, D, jnp.bfloat16)
+    assert tiles == (2, 2) and pd.route_name(tiles, hkv) == \
+        "pallas-decode-2x16"
+    text = chip_compile(
+        functools.partial(pd._decode, sm_scale=D ** -0.5,
+                          sliding_window=None, tiles=tiles, interpret=False),
+        ((LOOP_SLOTS, h, D), jnp.bfloat16), pool, ((LOOP_SLOTS,), jnp.int32),
+        ((LOOP_SLOTS, LOOP_PAGES_PER_SEQ), jnp.int32),
+        ((LOOP_SLOTS,), jnp.bool_))
+    assert f"%{pd.NAME}." in text
+    chip_compile(
+        functools.partial(
+            rpa.ragged_paged_attention, sm_scale=D ** -0.5,
+            sliding_window=None,
+            num_kv_pages_per_block=paged.rpa_kv_pages_per_block(
+                hkv, PAGE, LOOP_PAGES_PER_SEQ),
+            num_queries_per_block=paged._RPA_QUERY_BLOCK),
+        ((LOOP_SLOTS + 512, h, D), jnp.bfloat16), pool,
+        ((LOOP_SLOTS,), jnp.int32),
+        ((LOOP_SLOTS, LOOP_PAGES_PER_SEQ), jnp.int32),
+        ((LOOP_SLOTS + 1,), jnp.int32), ((1,), jnp.int32))
+
+
+def test_a_looped_models_programs_hold_each_layer_once(one_chip,
+                                                       chip_compile,
+                                                       monkeypatch):
+    """The engine's two programs at ``ouro-2.6b-serve``'s widths and engine
+    sizes, two layers deep and four passes a token, compiled for the
+    described chip: one attention call a LAYER (the pass loop is a
+    ``while`` of the program), each on its own route, and the pool one
+    buffer of ``[P, U, page, 2 * Hkv, D]`` a layer."""
+    import re
+
+    from deepspeed_tpu.inference.v2 import programs
+    from deepspeed_tpu.models import ouro
+    from deepspeed_tpu.ops import routes
+
+    monkeypatch.setattr(routes, "on_tpu", lambda: True)
+    monkeypatch.setattr(routes, "_taken", {})
+    layers, slots, width = 2, LOOP_SLOTS, LOOP_SLOTS + 512
+    model = ouro.OuroForCausalLM(programs.serving_config(
+        ouro.get_config(
+            "ouro-2.6b", num_hidden_layers=layers, dtype=jnp.bfloat16,
+            param_dtype=jnp.bfloat16, scan_layers=False, remat=False,
+            use_flash_attention=False),
+        max_seq_len=PAGE * LOOP_PAGES_PER_SEQ, page_size=PAGE,
+        num_pages=LOOP_POOL, tensor_parallel=False, kv_cache_dtype="none"))
+    i32 = jnp.int32
+    meta = {"kv_lens": jnp.zeros((slots,), i32),
+            "page_indices": jnp.zeros((slots, LOOP_PAGES_PER_SEQ), i32),
+            "cu_q_lens": jnp.zeros((slots + 1,), i32),
+            "num_seqs": jnp.zeros((1,), i32),
+            "new_kv_dest": jnp.zeros((width,), i32)}
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, width), i32),
+        positions=jnp.zeros((1, width), i32), ragged_meta=meta))
+    assert [leaf.shape for leaf in jax.tree_util.tree_leaves(
+        shapes["cache"])] == [(LOOP_POOL, LOOP_STEPS, PAGE, 32, D)] * layers
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, cache = (jax.tree_util.tree_map(
+        lambda s: spec(s.shape, s.dtype), shapes[k])
+        for k in ("params", "cache"))
+    per_slot = [spec((slots,), d) for d in (i32, i32, jnp.bool_, i32)]
+    table = spec((slots, LOOP_PAGES_PER_SEQ), i32)
+    sampling = [spec((slots,), d) for d in (
+        i32, jnp.bool_, jnp.float32, i32, jnp.float32, jnp.uint32)]
+    decode = programs.build_decode_block(
+        model, sampled=False, max_seqs=slots, block=8, page_size=PAGE,
+        max_seq_len=PAGE * LOOP_PAGES_PER_SEQ,
+        sample_base=jax.random.PRNGKey(0))
+    fused = programs.build_fused_step(model)
+    texts = {
+        "decode": decode.lower(params, cache, *per_slot, table, *sampling,
+                               spec((2,), jnp.uint32)).compile().as_text(),
+        "fused": fused.lower(
+            params, cache, spec((1, width), i32), spec((1, width), i32),
+            spec((slots,), i32), table, spec((slots + 1,), i32),
+            spec((1,), i32), spec((width,), i32),
+            spec((slots,), i32)).compile().as_text()}
+    calls = {name: (len(re.findall(r"%paged_decode_attention[.\d]* = ", t)),
+                    len(re.findall(
+                        r"%ragged_paged_attention_kernel[.\d]* = ", t)))
+             for name, t in texts.items()}
+    assert calls == {"decode": (layers, 0), "fused": (0, layers)}
+    assert routes.taken() == {"paged_attention": "pallas-ragged",
+                              "paged_attention_decode": "pallas-decode-2x16"}
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4
