@@ -57,9 +57,10 @@ class HostStageStats:
     clock, ``time.perf_counter`` unless ``telemetry.configure(clock=)``
     replaced it, so ring and stage seconds agree.  Besides the stages
     above the v2 engine brackets ``rng_split`` (the eager key split
-    before a decode block), ``sample`` (the fused tick's eager gather +
-    ``sample_logits``) and ``reap`` (``_reap`` and the post-sample
-    handoff sweep), and opens ``engine.step`` around a whole ``step()``;
+    before a decode block), ``sample`` (the long-context driver's eager
+    ``sample_logits``; a fused tick samples inside its program) and
+    ``reap`` (``_reap`` and the handoff sweep after a tick's harvest),
+    and opens ``engine.step`` around a whole ``step()``;
     the replica worker opens ``replica.op`` around each op and the
     router ``router.pump`` / ``router.fold`` / ``router.dispatch`` and,
     on the intake path, ``router.submit`` with ``router.prefix_hash``
@@ -81,7 +82,9 @@ class HostStageStats:
     tokens read, summed over decode token steps) are the same counts
     the ``engine.dispatch`` spans carry; ``sample_s`` / ``rng_split_s``
     / ``reap_s`` are the seconds of the three brackets outside
-    ``STAGES``.
+    ``STAGES``.  ``fused_ticks`` counts SplitFuse ticks and
+    ``fused_ticks_ahead`` those dispatched while the tick before was
+    un-fetched (their ``engine.dispatch`` spans say ``ahead=1``).
 
     Speculative decoding adds two host stages — ``draft`` (draft-model
     KV catch-up prefill + host-side draft planning) and ``verify`` (the
@@ -148,6 +151,9 @@ class HostStageStats:
         self.prefill_tokens = 0     # prompt tokens in fused ticks
         self.prefill_key_tokens = 0   # keys those tokens attend to
         self.decode_key_token_steps = 0   # context tokens x token steps
+        self.fused_ticks = 0        # SplitFuse ticks dispatched
+        # ... of them while the tick before was un-fetched (one ahead)
+        self.fused_ticks_ahead = 0
         # dropless expert layers, live rows only, summed over layers:
         self.moe_rows = 0           # token copies routed
         self.moe_experts_hit = 0    # experts with at least one row
@@ -195,6 +201,8 @@ class HostStageStats:
                    prefill_tokens=self.prefill_tokens,
                    prefill_key_tokens=self.prefill_key_tokens,
                    decode_key_token_steps=self.decode_key_token_steps,
+                   fused_ticks=self.fused_ticks,
+                   fused_ticks_ahead=self.fused_ticks_ahead,
                    **{f"{s}_s": round(self.seconds[s], 6)
                       for s in self.EXTRA})
         if self.spec_dispatches:

@@ -6,9 +6,12 @@ and a few static sizes, so anything can lower or call one without an engine:
 the engine caches what these return, ``chip_smoke.py`` checks logits through
 the fused tick, a rehearsal can compile one for a described chip.
 
-- :func:`build_fused_step` — ONE ``[1, T]`` SplitFuse tick (a decode token
-  for every ready sequence plus prompt chunks; raggedness lives in int32
-  metadata).  XLA program ``jit_ragged_fused_step``.
+- :func:`build_fused_sample_step` — ONE ``[1, T]`` SplitFuse tick (a decode
+  token for every ready sequence plus prompt chunks; raggedness lives in
+  int32 metadata) that samples on the device and keeps every slot's newest
+  token there.  XLA program ``jit_ragged_fused_step``;
+  :func:`build_fused_step` is the same forward with its logits as the
+  result, for whoever checks them.
 - :func:`build_decode_block` — ``block`` decode ticks in a ``lax.scan`` with
   on-device, position-keyed sampling.  ``jit_ragged_decode_block``.
 - :func:`build_spec_block` — the speculative draft + verify + accept block
@@ -310,15 +313,13 @@ def init_cache(model, *, max_seqs: int, width: int, **placement):
         shapes["cache"])
 
 
-def build_fused_step(model, *, unroll: bool = False,
-                     wq: Optional[str] = None, native: bool = False):
-    """ONE jitted program for every tick: fused decode + prefill
-    chunk(s) forward, paged-KV update, and logits row selection.
-    ``unroll``: the stored params are scan-stacked and unroll in-jit;
-    ``wq``/``native``: they are stored quantized (``native``: int8 dots,
-    no dequantized copy)."""
-    def run(params, cache, token_ids, positions, kv_lens, page_indices,
-            cu_q_lens, num_seqs, new_kv_dest, sample_rows):
+def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
+    """The body both fused ticks share: the ``[1, T]`` forward with its
+    paged-KV update, and the logits of the one row a sequence may sample
+    from (``sample_rows``, by packed sequence number).  Returns
+    ``(sel [max_seqs, V], variables)``."""
+    def forward(params, cache, token_ids, positions, kv_lens, page_indices,
+                cu_q_lens, num_seqs, new_kv_dest, sample_rows):
         if wq:
             params = dequantize_param_tree(params, native_w8a8=native)
         if unroll:
@@ -333,11 +334,82 @@ def build_fused_step(model, *, unroll: bool = False,
             {"params": params, "cache": cache}, token_ids,
             positions=positions, mutable=MUTABLE, ragged_meta=meta)
         logits = logits_of(out)[0]                      # [T, V]
-        sel = jnp.take(logits, sample_rows, axis=0)     # [max_seqs, V]
+        return jnp.take(logits, sample_rows, axis=0), vars_
+
+    return forward
+
+
+def build_fused_step(model, *, unroll: bool = False,
+                     wq: Optional[str] = None, native: bool = False):
+    """The fused tick with its LOGITS as the result: fused decode +
+    prefill chunk(s) forward, paged-KV update, and logits row selection.
+    What checks read (``chip_smoke.py``); the serving loop dispatches
+    :func:`build_fused_sample_step`.
+    ``unroll``: the stored params are scan-stacked and unroll in-jit;
+    ``wq``/``native``: they are stored quantized (``native``: int8 dots,
+    no dequantized copy)."""
+    forward = _fused_forward(model, unroll=unroll, wq=wq, native=native)
+
+    def run(params, cache, token_ids, positions, kv_lens, page_indices,
+            cu_q_lens, num_seqs, new_kv_dest, sample_rows):
+        sel, vars_ = forward(params, cache, token_ids, positions, kv_lens,
+                             page_indices, cu_q_lens, num_seqs, new_kv_dest,
+                             sample_rows)
         return sel, vars_["cache"], moe_counts(vars_), loop_counts(vars_)
 
     # distinguishable XLA program name ("jit_ragged_fused_step") so
     # the profiler bridge can attribute device time per program
+    run.__name__ = run.__qualname__ = "ragged_fused_step"
+    return jax.jit(run, donate_argnums=(1,))
+
+
+def build_fused_sample_step(model, *, sampled: bool, sample_base,
+                            unroll: bool = False, wq: Optional[str] = None,
+                            native: bool = False):
+    """ONE jitted program for every tick of the serving loop: the fused
+    tick of :func:`build_fused_step` (the same forward, the same XLA
+    program name) that also SAMPLES, so its tokens need not reach the host
+    before the next tick is planned.
+
+    Beside the forward's arguments it takes ``last_tok [max_seqs]``, every
+    slot's newest token as the device has it, and ``tok_src [1, T]``: a
+    row whose entry is a slot takes its token id from ``last_tok`` there (a
+    decode row whose token the tick before sampled and no fetch has
+    brought yet), a row at -1 from ``token_ids``.  ``sample_slot
+    [max_seqs]``, by packed sequence number, is the slot a sequence's
+    sampled token is kept under, -1 where it samples nothing (a prompt
+    chunk that is not the last).  Returns the tokens by packed sequence
+    number, the updated ``last_tok``, the cache and the counts.
+
+    Two variants compile, as for the decode block: pure-greedy (no sort)
+    and per-sequence ``sampled``, which takes the five sampler arrays.  The
+    keys are position-keyed as the decode block's are, so a draw is the
+    same bits whichever program makes it."""
+    forward = _fused_forward(model, unroll=unroll, wq=wq, native=native)
+
+    def run(params, cache, last_tok, token_ids, tok_src, positions,
+            kv_lens, page_indices, cu_q_lens, num_seqs, new_kv_dest,
+            sample_rows, sample_slot, *sampler):
+        token_ids = jnp.where(
+            tok_src >= 0, jnp.take(last_tok, jnp.maximum(tok_src, 0)),
+            token_ids)
+        sel, vars_ = forward(params, cache, token_ids, positions, kv_lens,
+                             page_indices, cu_q_lens, num_seqs, new_kv_dest,
+                             sample_rows)
+        if sampled:
+            *configs, seeds = sampler   # do_sample, temperature, top_k, top_p
+            sub = position_keys(sample_base, seeds,
+                                jnp.take(positions[0], sample_rows))
+        else:
+            configs, sub = [None] * 4, None
+        toks = sample_logits_batched(sel, sub, *configs)
+        # a sequence that samples nothing is scattered nowhere
+        last_tok = last_tok.at[
+            jnp.where(sample_slot >= 0, sample_slot, last_tok.shape[0])
+        ].set(toks, mode="drop")
+        return (toks, last_tok, vars_["cache"], moe_counts(vars_),
+                loop_counts(vars_))
+
     run.__name__ = run.__qualname__ = "ragged_fused_step"
     return jax.jit(run, donate_argnums=(1,))
 
@@ -385,8 +457,8 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
             moe, loop = moe_counts(vars_), loop_counts(vars_)
             rng, _ = jax.random.split(rng)
             # position-keyed per-row keys: the draw at cache position
-            # `pos` is the same bits the fused tick's host sampler
-            # would use, no matter how this block was scheduled
+            # `pos` is the same bits the fused tick would draw there,
+            # no matter how this block was scheduled
             sub = (position_keys(sample_base, seeds, pos)
                    if sampled else None)
             nxt = sample_logits_batched(

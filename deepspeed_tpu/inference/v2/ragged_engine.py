@@ -31,8 +31,10 @@ axis — weights shard by AutoTP name rules, the paged KV pool shards over
 its head dim, and the fused tick runs under GSPMD with the paged attention
 shard_map-manual over ``tensor``.
 
-**The pipelined host path** (``pipeline=True``, the default): the decode
-steady state runs as a software pipeline.
+**The pipelined host path** (``pipeline=True``, the default): both kinds
+of step run as a software pipeline.
+
+*Decode blocks.*
 
 - the decode-block carry (``last_tok``/``pos``/``active``/``remaining``)
   and the per-tick metadata (page table, sampler configs, eos ids) stay
@@ -46,13 +48,38 @@ steady state runs as a software pipeline.
   ``device_get``) every ``harvest_interval`` blocks.  EOS/finish
   detection stays device-side (the decode block's ``active`` carry).
 
+*Fused ticks*, one ahead.  The tick samples inside its program and hands
+every slot's newest token to the next tick on the device
+(``programs.build_fused_sample_step``), so the only thing the next plan
+lacked is no longer the host's to wait for: ``_step`` plans, uploads and
+dispatches tick *k+1* and THEN fetches tick *k*'s tokens, folds them and
+reaps.  Between the two a request counts the token it has not seen
+(``Request.pending``): lengths, budgets and every finish by count are
+exact, and a decode row of *k+1* whose token *k* sampled reads it from
+the device.  The fetch still comes once a tick, so a stream sees each
+token when its tick ends.  A tick is left in flight only where the plan
+after it is exact without its tokens, which the loop reads off its own
+state: no sampling row carries an EOS id (its finish is the token's to
+say) or is a handoff prefill (parked the step its token lands), no
+sequence was short of pages in its plan, none ticks outside the batch
+(``lc``), and ``pipeline`` is on.  Whoever needs the host's tokens,
+budgets or slots flushes it first (``_flush_tick``): a finish in it while
+a request waits (the reap decides the admission), the entry into decode
+blocks and the long-context driver (the step ends with the flush, so a
+stream has a prompt's first token before the first block is built), a
+plan that falls short of pages or finds nothing to run (made again after
+the flush), ``cancel``, ``sync``, ``drain``, ``export_handoff`` /
+``export_parked``, ``_evict`` and ``_spill``; ``has_work()`` is true
+while a tick is un-harvested.
+
 Harvests are FORCED at every point where the unpipelined engine could
 have reaped, admitted, or evicted (a possible finish, a newly admittable
 request, page-growth failure), so the dispatch sequence — programs,
 metadata values, and rng splits — is identical to ``pipeline=False`` and
 outputs are bit-identical, greedy or seeded-sampling.  ``host_stats``
 (:class:`~deepspeed_tpu.inference.common.HostStageStats`) breaks the
-host path into plan/upload/dispatch/device/harvest per dispatch.
+host path into plan/upload/dispatch/device/harvest per dispatch, and
+counts ``fused_ticks`` and, of them, ``fused_ticks_ahead``.
 
 **Speculative decoding** (``speculation.mode != off``) rides the same
 path: each decode-block tick drafts ``k`` tokens per slot (``ngram``:
@@ -112,6 +139,9 @@ class Request:
     slot: int = -1
     prefill_done: int = 0                 # context tokens already cached
     generated: List[int] = dataclasses.field(default_factory=list)
+    # tokens fused ticks in flight have sampled on the device and no fetch
+    # has folded into ``generated`` yet (0 wherever host state is exact)
+    pending: int = 0
     done: bool = False
     # prefill SOURCE: the prompt, or prompt + already-generated tokens
     # after an eviction (the continuation re-prefills its own output)
@@ -151,8 +181,9 @@ class Request:
     def length(self) -> int:
         # tokens in the KV cache: prefilled context + tokens generated
         # AFTER that context (an evicted continuation's ctx already
-        # contains its earlier output)
-        return self.prefill_done + len(self.generated) - \
+        # contains its earlier output); a token still on the device is
+        # as much in the sequence as one that was fetched
+        return self.prefill_done + len(self.generated) + self.pending - \
             (self.ctx_len - self.prompt.size)
 
     def fold(self) -> None:
@@ -165,6 +196,18 @@ class Request:
             [self.prompt, np.asarray(self.generated, np.int32)])
         self.prefill_done = 0
         self.pc_parent, self.pc_pages, self.pc_cached = ROOT_HASH, 0, 0
+
+
+@dataclasses.dataclass
+class _FusedTick:
+    """A dispatched fused tick whose tokens no fetch has brought yet."""
+    toks: Any                             # device [max_seqs], by packed row
+    # (request, packed sequence number) of every sequence that samples
+    sampling: List[Tuple[Request, int]]
+    # its expert-layer and pass-loop counts, for the fetch that harvests it
+    counts: List[Tuple[Any, Any, Dict[str, Any]]]
+    # a sequence ends with it (by its count: nothing else is known ahead)
+    finishes: bool
 
 
 class RaggedInferenceEngineV2:
@@ -407,6 +450,7 @@ class RaggedInferenceEngineV2:
         # the built programs, cached: one fused tick, one draft prefill,
         # a greedy and a sampled variant of each block
         self._step_fn = None
+        self._sample_step_cache: Dict[bool, Any] = {}
         self._draft_prefill = None
         self._decode_block_cache: Dict[bool, Any] = {}
         self._spec_block_cache: Dict[bool, Any] = {}
@@ -434,6 +478,18 @@ class RaggedInferenceEngineV2:
         self._counts_pending: List[Tuple[Any, Any, Dict[str, Any]]] = []
         # each slot's pending token: sampled, not yet written to the pool
         self.last_tokens = np.zeros((max_seqs,), np.int32)
+        # the same as the fused ticks keep it on the device, each handing
+        # it to the next: right in the slots whose token the tick in
+        # flight sampled, which is all a tick reads of it (a put, not a
+        # program: nothing compiles to make it)
+        self._dev_last_tok = jax.device_put(
+            np.zeros((max_seqs,), np.int32), self._device)
+        # the fused tick in flight: dispatched, its tokens not fetched
+        self._tick: Optional[_FusedTick] = None
+        # what the last plan of a tick found of the pool: sequences that
+        # sit the tick out, and whether any growth fell short
+        self._stalled: List[Request] = []
+        self._page_limited = False
         # generated-token count already reported per uid (stream_deltas)
         self._stream_cursor: Dict[int, int] = {}
         self.cancels = 0
@@ -616,6 +672,7 @@ class RaggedInferenceEngineV2:
         ``"finished"``) or ``None`` for an unknown uid (never
         submitted, or already collected)."""
         stage: Optional[str] = None
+        self._flush_tick()
         # fold an active pipelined carry first: the target may be
         # mid-decode inside it, and teardown re-anchors host state so
         # the slot release below is authoritative (the target may
@@ -666,18 +723,22 @@ class RaggedInferenceEngineV2:
         return stage
 
     def has_work(self) -> bool:
-        return bool(self.waiting) or any(s is not None for s in self.slots)
+        # a fused tick in flight holds its sequences in their slots until
+        # a step harvests it
+        return (bool(self.waiting) or self._tick is not None or
+                any(s is not None for s in self.slots))
 
     def sync(self) -> int:
-        """Fold any pipelined in-flight device work back into host
-        request state (deferred-harvest flush); returns the tokens
-        folded.  No-op when the pipeline is off or idle.  Callers that
-        read ``slots[*].generated`` / ``finished`` between ``step()``
-        calls (benchmark boundaries, draining shutdown) call this
-        first."""
-        if self._dev is None:
-            return 0
-        return self._pipeline_harvest()
+        """Fold any in-flight device work (a fused tick dispatched
+        ahead, pipelined decode blocks) back into host request state;
+        returns the tokens folded.  No-op when the pipeline is off or
+        idle.  Callers that read ``slots[*].generated`` / ``finished``
+        between ``step()`` calls (benchmark boundaries, draining
+        shutdown) call this first."""
+        produced = self._flush_tick()
+        if self._dev is not None:
+            produced += self._pipeline_harvest()
+        return produced
 
     def drain(self) -> Dict[int, np.ndarray]:
         """Run until every queued/resident request finishes; returns
@@ -685,6 +746,7 @@ class RaggedInferenceEngineV2:
         replica-shutdown half of the engine handle — ``close()``
         releases resources after)."""
         outs: Dict[int, np.ndarray] = {}
+        self._flush_tick()
         # nobody is coming to collect a pending handoff during a drain:
         # finish those sessions locally through the normal spilled /
         # continuation re-admission path
@@ -710,6 +772,7 @@ class RaggedInferenceEngineV2:
         the way is output-identical under greedy decode, just re-paying
         its prefill."""
         sessions: List[Dict[str, Any]] = []
+        self._flush_tick()
         # pending prefill->decode handoffs the router never pulled ride
         # the same retirement: they are parked sessions like any other
         for r in itertools.chain(self.waiting, self._handoff_ready):
@@ -767,6 +830,7 @@ class RaggedInferenceEngineV2:
         the session through the normal spilled-request re-admission
         path, so greedy outputs stay bit-identical to a fused tick."""
         sessions: List[Dict[str, Any]] = []
+        self._flush_tick()
         for r in self._handoff_ready:
             blob = self.kv.export(r)
             self.handoffs += 1
@@ -1069,12 +1133,26 @@ class RaggedInferenceEngineV2:
     # -- the compiled programs (built by programs.py, cached here) --------
 
     def fused_step(self):
-        """The ONE program of every SplitFuse tick
-        (:func:`~deepspeed_tpu.inference.v2.programs.build_fused_step`)."""
+        """The SplitFuse tick with its logits as the result
+        (:func:`~deepspeed_tpu.inference.v2.programs.build_fused_step`),
+        for whoever checks them; the loop's own ticks are
+        :meth:`fused_sample_step`'s."""
         if self._step_fn is None:
             self._step_fn = programs.build_fused_step(
                 self.model, **self.param_form)
         return self._step_fn
+
+    def fused_sample_step(self, sampled: bool):
+        """The program of every SplitFuse tick of the loop, greedy or
+        ``sampled``: the tick samples on the device and hands every slot's
+        newest token to the next (:func:`~deepspeed_tpu.inference.v2.
+        programs.build_fused_sample_step`)."""
+        if sampled not in self._sample_step_cache:
+            self._sample_step_cache[sampled] = \
+                programs.build_fused_sample_step(
+                    self.model, sampled=sampled,
+                    sample_base=self._sample_base, **self.param_form)
+        return self._sample_step_cache[sampled]
 
     def decode_block(self, sampled: bool):
         """The on-device decode block, greedy or ``sampled``
@@ -1288,8 +1366,8 @@ class RaggedInferenceEngineV2:
              moe, loop) = self.decode_block(sampled)(
                 self.params, self.kv.cache, *args, sub)
         self.kv.cache = cache
-        self._counts_dispatched(moe, loop, "decode_block",
-                                self.decode_block_size, len(reqs))
+        self._counts_pending += self._count_entries(
+            moe, loop, "decode_block", self.decode_block_size, len(reqs))
         st.ticks += self.decode_block_size
         toks, mask, new_last = self.fetch((toks, mask, new_last))
         st.harvests += 1
@@ -1305,20 +1383,22 @@ class RaggedInferenceEngineV2:
             self._reap()
         return produced
 
-    def _counts_dispatched(self, moe, loop, program: str, ticks: int,
-                           rows: int) -> None:
-        """Keep a dispatch's expert-layer and pass-loop counts (device
-        arrays; None for a model without expert layers, or whose layers
-        run once) until a fetch brings them.  ``rows``: its token rows
-        (a fused tick) or live slots (a decode block) at dispatch."""
+    def _count_entries(self, moe, loop, program: str, ticks: int, rows: int
+                       ) -> List[Tuple[Any, Any, Dict[str, Any]]]:
+        """A dispatch's expert-layer and pass-loop counts (device arrays;
+        None for a model without expert layers, or whose layers run once)
+        as entries of ``_counts_pending``, where they wait for a fetch.
+        ``rows``: its token rows (a fused tick) or live slots (a decode
+        block) at dispatch."""
+        out = []
         if moe is not None:
-            self._counts_pending.append(
-                (moe, self._record_moe, {"program": program,
-                                         "ticks": ticks}))
+            out.append((moe, self._record_moe, {"program": program,
+                                                "ticks": ticks}))
         if loop is not None:
-            self._counts_pending.append(
-                (loop, self._record_loop, {"program": program,
-                                           "ticks": ticks, "rows": rows}))
+            out.append((loop, self._record_loop, {"program": program,
+                                                  "ticks": ticks,
+                                                  "rows": rows}))
+        return out
 
     def _decode_dispatch(self, reqs: List[Request], contexts):
         """The ``dispatch`` bracket of one plain decode block, with the
@@ -1489,7 +1569,8 @@ class RaggedInferenceEngineV2:
                     dv["active"], dv["remaining"], dv["page_table"],
                     dv["eos_ids"], dv["do_sample"], dv["temperature"],
                     dv["top_k"], dv["top_p"], dv["seeds"], sub)
-            self._counts_dispatched(moe, loop, "decode_block", K, len(live))
+            self._counts_pending += self._count_entries(
+                moe, loop, "decode_block", K, len(live))
             block = (toks, mask)
         # track the block in the bounded window: past async_depth the
         # submit first joins the oldest un-joined block (waiting for
@@ -1616,6 +1697,11 @@ class RaggedInferenceEngineV2:
         if self._dev is not None:
             return self._pipeline_step()
         st = self.host_stats
+        produced = 0
+        if self._tick is not None and self._tick.finishes and self.waiting:
+            # a sequence ends with the tick in flight and a request
+            # waits: what the reap frees decides the admission
+            produced += self._flush_tick()
         with st.stage("plan"):
             # park finished handoff prefills BEFORE admission: a
             # handoff request never reaches the decode block / spec /
@@ -1629,6 +1715,14 @@ class RaggedInferenceEngineV2:
                     if r is not None and not r.done and not r.lc]
             decoding_ready = (not lc_live and bool(live) and all(
                 r.prefill_done >= r.ctx_len for r in live))
+        if self._tick is not None and (decoding_ready or lc_live):
+            # the blocks and the long-context driver start from the
+            # host's tokens and budgets: fold the tick, and end the step
+            # there, so that a stream has a prompt's first token before
+            # the host turns to the first block (whose own harvest can
+            # take a block's time)
+            return produced + self._flush_tick()
+        with st.stage("plan"):
             # speculation first: its block writes a k+1-wide span per
             # tick, so it needs more page coverage than a plain block —
             # when the pool can't back it, degrade to the plain decode
@@ -1653,33 +1747,38 @@ class RaggedInferenceEngineV2:
         if spec_block:
             if self.pipeline:
                 self._pipeline_start(live, spec=True)
-                return self._pipeline_step()
-            return self._step_spec_block(live)
+                return produced + self._pipeline_step()
+            return produced + self._step_spec_block(live)
         if all_decoding:
             if self.pipeline:
                 self._pipeline_start(live)
-                return self._pipeline_step()
-            return self._step_decode_block(live)
+                return produced + self._pipeline_step()
+            return produced + self._step_decode_block(live)
         # partially-resident sequences tick through the chunked-scan
         # driver — never the fused batch, the decode block, or the
         # pipeline (decoding_ready is gated off while any is live, so
         # the pipeline cannot start and orphan them)
-        lc_produced = 0
         if lc_live:
             if self._lc is None:
                 from deepspeed_tpu.inference.v2.long_context import \
                     LongContextDriver
                 self._lc = LongContextDriver(self)
             for r in lc_live:
-                lc_produced += self._lc.tick(r)
+                produced += self._lc.tick(r)
         with st.stage("plan"):
-            plan = self._plan_tick()
-        if plan is None:
+            takes = self._plan_rows()
+        if self._tick is not None and (takes is None or self._page_limited):
+            # the pool fell short, or nothing is left to run: what the
+            # tick in flight gives back comes first, then the plan anew
+            produced += self._flush_tick()
+            with st.stage("plan"):
+                takes = self._plan_rows()
+        if takes is None:
             self._reap()
             # every live sequence is page-stalled: evict the youngest as
             # a continuation so the rest (and the queue) can progress
             # (reference scheduler backpressure, engine_v2.py:184)
-            stalled = getattr(self, "_stalled", [])
+            stalled = self._stalled
             if stalled and live:
                 if len(live) == 1 and not self.waiting:
                     raise RuntimeError(
@@ -1702,29 +1801,72 @@ class RaggedInferenceEngineV2:
                         self._evict(victim)
                 else:
                     self._evict(max(stalled, key=lambda r: r.uid))
-            return lc_produced
-        (token_ids, positions, kv_lens, page_indices, cu_q_lens, num_seqs,
-         new_kv_dest, sample_rows, samplers, counts) = plan
-        args = self.upload_all(
-            (token_ids[None], positions[None], kv_lens, page_indices,
-             cu_q_lens, num_seqs, new_kv_dest, sample_rows))
+            return produced
+        with st.stage("plan"):
+            arrays, sampled, sampling, rows, counts = self._pack_tick(takes)
+        args = self.upload_all(arrays)
         st.prefill_tokens += counts["prefill_tokens"]
         st.prefill_key_tokens += counts["prefill_key_tokens"]
         st.decode_key_token_steps += counts["decode_key_tokens"]
-        with st.stage("dispatch", program="fused", **counts,
+        ahead = int(self._tick is not None)
+        with st.stage("dispatch", program="fused", ahead=ahead, **counts,
                       **self._pool_counts()):
             st.dispatches += 1
-            sel_logits, self.kv.cache, moe, loop = self.fused_step()(
-                self.params, self.kv.cache, *args)
-        self._counts_dispatched(moe, loop, "fused", 1,
-                                int(cu_q_lens[int(num_seqs[0])]))
+            toks, self._dev_last_tok, self.kv.cache, moe, loop = \
+                self.fused_sample_step(sampled)(
+                    self.params, self.kv.cache, self._dev_last_tok, *args)
         st.ticks += 1
-        produced = self.sample(sel_logits, samplers)
+        st.fused_ticks += 1
+        st.fused_ticks_ahead += ahead
+        for r, _ in sampling:
+            # the token exists from here on, wherever it is: lengths and
+            # budgets count it, and who ends by its count is known now
+            r.pending += 1
+            self._maybe_finish(r)
+        prev, self._tick = self._tick, _FusedTick(
+            toks=toks, sampling=sampling,
+            counts=self._count_entries(moe, loop, "fused", 1, rows),
+            finishes=any(r.done for r, _ in sampling))
+        if prev is not None:
+            # tick k+1 is queued behind it: NOW wait for tick k's tokens
+            produced += self._harvest_tick(prev)
+        # the next plan is exact without this tick's tokens where nothing
+        # they could say changes it: no end by an EOS id, no handoff
+        # parked the step its token lands, no sequence short of pages or
+        # ticking outside the batch; else it is fetched now, as ever
+        if not (self.pipeline and sampling and not self._page_limited
+                and not lc_live and
+                not any(r.eos_token_id is not None or r.handoff
+                        for r, _ in sampling)):
+            produced += self._flush_tick()
+        return produced
+
+    def _flush_tick(self) -> int:
+        """Harvest the fused tick in flight, if any: host state is exact
+        after it.  Every path that reads tokens, budgets or slots from the
+        host calls this first."""
+        tick, self._tick = self._tick, None
+        return 0 if tick is None else self._harvest_tick(tick)
+
+    def _harvest_tick(self, tick: _FusedTick) -> int:
+        """Fetch one fused tick's tokens, fold them into their requests
+        and reap — the tick's one sync point, whether it comes right
+        after its dispatch or after the next tick's."""
+        self._counts_pending += tick.counts
+        if tick.sampling:
+            toks = np.asarray(self.fetch(tick.toks))
+            with self.host_stats.stage("harvest"):
+                for r, seq_j in tick.sampling:
+                    r.generated.append(int(toks[seq_j]))
+                    r.pending -= 1
+                    self.last_tokens[r.slot] = r.generated[-1]
+                    self.request_latency.on_tokens(r.uid, len(r.generated))
+                    self._maybe_finish(r)
         # a handoff prefill that just sampled its first token parks NOW
         # — same step — so the router's next export pulls it without an
         # extra tick of decode-side latency
         self._reap(handoff_sweep=True)
-        return produced + lc_produced
+        return len(tick.sampling)
 
     def _admit(self) -> None:
         for i in range(self.max_seqs):
@@ -1825,6 +1967,7 @@ class RaggedInferenceEngineV2:
         resume from the same sampled prefix)."""
         from deepspeed_tpu.utils.logging import logger
 
+        self._flush_tick()
         self._unseat(r, fold=True)
         self.waiting.append(r)             # back of the queue: the freed
         self.evictions += 1                # pages go to older work first
@@ -1841,6 +1984,7 @@ class RaggedInferenceEngineV2:
         """Park ``r`` in the spill tiers (``KVState.spill``) and requeue
         it as a RESTORABLE continuation; False when the tiers can't take
         it (caller falls back to ``_evict``'s re-prefill)."""
+        self._flush_tick()
         if not self.kv.spill(r, self.last_tokens[r.slot]):
             return False
         self._unseat(r)
@@ -1871,10 +2015,16 @@ class RaggedInferenceEngineV2:
         assert page > 0, "write into unallocated page"
         return int(page) * self.page_size + pos % self.page_size
 
-    def _plan_tick(self):
-        """Host-side SplitFuse plan: one decode token per ready sequence
-        plus prompt chunks for prefilling sequences, all in ONE batch."""
+    def _plan_rows(self):
+        """Who runs in the next SplitFuse tick: one decode token per
+        ready sequence plus prompt chunks for prefilling sequences, as
+        far as the pool backs them.  Grows pages and changes nothing
+        else, so a plan can be thrown away and made again
+        (``_page_limited``: some growth fell short; ``_stalled``: who
+        sits the tick out for it).  Returns the prompt tokens each
+        prefilling sequence takes, by uid; None when nothing can run."""
         self._stalled = []
+        self._page_limited = False
         decode_rs = []
         for r in self.slots:
             if r is None or r.done or r.lc or r.prefill_done < r.ctx_len:
@@ -1890,25 +2040,6 @@ class RaggedInferenceEngineV2:
              if r is not None and not r.lc
              and r.prefill_done < r.ctx_len),
             key=lambda r: r.uid)
-        if not decode_rs and not prefill_rs:
-            return None
-
-        token_ids = np.zeros((self.T,), np.int32)
-        positions = np.zeros((self.T,), np.int32)
-        new_kv_dest = np.full((self.T,), 0, np.int32)   # trash page row 0
-        kv_lens = np.zeros((self.max_seqs,), np.int32)
-        # metadata rows are indexed by PACKED sequence number j, not slot:
-        # pack each active slot's page-table row as it is assigned a j
-        page_indices = np.full((self.max_seqs, self.pages_per_seq), -1,
-                               np.int32)
-        cu_q_lens = np.zeros((self.max_seqs + 1,), np.int32)
-        sample_rows = np.zeros((self.max_seqs,), np.int32)
-        samplers: List[Tuple[Request, int, bool]] = []  # (req, seq_j, sample?)
-        # what the tick asks of the device, for the dispatch span and
-        # the counters: a token at position p attends to p + 1 keys
-        counts = dict(prefill_tokens=0, prefill_key_tokens=0,
-                      decode_key_tokens=0)
-
         budget = self.T - len(decode_rs)
         takes: Dict[int, int] = {}
         for r in prefill_rs:
@@ -1919,6 +2050,7 @@ class RaggedInferenceEngineV2:
                 # partial growth: cover what the pool allows this tick
                 # (cold prefix-index pages count — KVState.ensure
                 # reclaims them on demand)
+                self._page_limited = True
                 coverable = self.kv.coverable_tokens(r.slot)
                 take = min(take, coverable - r.prefill_done)
                 if take <= 0:
@@ -1927,6 +2059,33 @@ class RaggedInferenceEngineV2:
                 self.kv.ensure(r.slot, r.prefill_done + take)
             takes[r.uid] = take
             budget -= take
+        self._page_limited |= bool(self._stalled)
+        return takes if decode_rs or takes else None
+
+    def _pack_tick(self, takes: Dict[int, int]):
+        """``_plan_rows``'s tick as the arrays the fused program takes, all
+        in ONE batch: ``(arrays, sampled, sampling, rows, counts)``.
+        A decode row whose token a tick in flight sampled reads it on the
+        device (``tok_src``); ``sampling``: (request, packed sequence
+        number) of who samples; ``sampled``: any of them by a draw, so the
+        five sampler rows ride along and the sampled program runs."""
+        S = self.max_seqs
+        token_ids = np.zeros((self.T,), np.int32)
+        tok_src = np.full((self.T,), -1, np.int32)
+        positions = np.zeros((self.T,), np.int32)
+        new_kv_dest = np.full((self.T,), 0, np.int32)   # trash page row 0
+        kv_lens = np.zeros((S,), np.int32)
+        # metadata rows are indexed by PACKED sequence number j, not slot:
+        # pack each active slot's page-table row as it is assigned a j
+        page_indices = np.full((S, self.pages_per_seq), -1, np.int32)
+        cu_q_lens = np.zeros((S + 1,), np.int32)
+        sample_rows = np.zeros((S,), np.int32)
+        sample_slot = np.full((S,), -1, np.int32)
+        sampling: List[Tuple[Request, int]] = []
+        # what the tick asks of the device, for the dispatch span and
+        # the counters: a token at position p attends to p + 1 keys
+        counts = dict(prefill_tokens=0, prefill_key_tokens=0,
+                      decode_key_tokens=0)
 
         # pack sequences in slot order (any fixed order works; the kernel
         # sees sequences via cu_q_lens row j)
@@ -1939,7 +2098,10 @@ class RaggedInferenceEngineV2:
             self._last_sched[r.slot] = self._sched_seq
             if r.prefill_done >= r.ctx_len:                 # decode: 1 tok
                 p = min(r.length - 1, self.max_seq_len - 1)
-                token_ids[t] = self.last_tokens[r.slot]
+                if r.pending:
+                    tok_src[t] = r.slot
+                else:
+                    token_ids[t] = self.last_tokens[r.slot]
                 positions[t] = p
                 new_kv_dest[t] = self._flat_dest(r.slot, p)
                 page_indices[j] = self.page_table[r.slot]
@@ -1947,7 +2109,8 @@ class RaggedInferenceEngineV2:
                 counts["decode_key_tokens"] += p + 1
                 cu_q_lens[j + 1] = cu_q_lens[j] + 1
                 sample_rows[j] = t
-                samplers.append((r, j, True))
+                sample_slot[j] = r.slot
+                sampling.append((r, j))
                 t += 1
                 j += 1
             else:                                           # prefill chunk
@@ -1977,23 +2140,38 @@ class RaggedInferenceEngineV2:
                 page_indices[j] = self.page_table[r.slot]
                 kv_lens[j] = r.prefill_done
                 cu_q_lens[j + 1] = cu_q_lens[j] + take
-                finishes = r.prefill_done >= r.ctx_len
-                if finishes:
+                sample_rows[j] = t + take - 1
+                if r.prefill_done >= r.ctx_len:
                     self.request_latency.on_prefill_done(
                         r.uid, r.ctx_len - r.pc_cached, r.pc_cached)
-                sample_rows[j] = t + take - 1
-                samplers.append((r, j, finishes))
+                    sample_slot[j] = r.slot
+                    sampling.append((r, j))
                 t += take
                 j += 1
         cu_q_lens[j + 1:] = cu_q_lens[j]
-        if j == 0:
-            return None
-        return (token_ids, positions, kv_lens, page_indices, cu_q_lens,
-                np.asarray([j], np.int32), new_kv_dest, sample_rows,
-                samplers, counts)
+        arrays = (token_ids[None], tok_src[None], positions[None], kv_lens,
+                  page_indices, cu_q_lens, np.asarray([j], np.int32),
+                  new_kv_dest, sample_rows, sample_slot)
+        sampled = any(r.do_sample for r, _ in sampling)
+        if sampled:
+            do_sample = np.zeros((S,), bool)
+            temperature = np.ones((S,), np.float32)
+            top_k = np.zeros((S,), np.int32)
+            top_p = np.ones((S,), np.float32)
+            seeds = np.zeros((S,), np.int32)    # per-row sampling seed (uid)
+            for r, seq_j in sampling:
+                do_sample[seq_j] = r.do_sample
+                temperature[seq_j] = r.temperature
+                top_k[seq_j] = r.top_k
+                top_p[seq_j] = r.top_p
+                seeds[seq_j] = r.uid
+            arrays += (do_sample, temperature, top_k, top_p, seeds)
+        return arrays, sampled, sampling, t, counts
 
     def sample(self, sel_logits, samplers) -> int:
-        """One host sync per tick; one sampling call per distinct config."""
+        """Host-side sampling of selected logits, for a tick that runs
+        outside the fused batch (the long-context driver): one sync, one
+        sampling call per distinct config."""
         produced = 0
         groups: Dict[Tuple, List[Tuple[Request, int]]] = {}
         for r, seq_j, wants in samplers:
@@ -2034,7 +2212,10 @@ class RaggedInferenceEngineV2:
         return produced
 
     def _maybe_finish(self, req: Request) -> None:
-        if (len(req.generated) >= req.max_new_tokens or
+        """``done`` by the count (a token still on the device counts: a
+        finish by count or by length is known at dispatch), or by the
+        newest token the host has."""
+        if (len(req.generated) + req.pending >= req.max_new_tokens or
                 (req.eos_token_id is not None and req.generated and
                  req.generated[-1] == req.eos_token_id) or
                 req.length >= self.max_seq_len):
@@ -2050,7 +2231,9 @@ class RaggedInferenceEngineV2:
 
     def _reap_finished(self) -> None:
         for r in self.slots:
-            if r is not None and r.done:
+            # (a sequence whose last token is still on the device keeps
+            # its slot until the harvest that brings it)
+            if r is not None and r.done and not r.pending:
                 if self.kv.pfx_cfg.include_generated:
                     # opt-in: publish full pages of generated tokens
                     # before the refs drop.  Decode pages come from a

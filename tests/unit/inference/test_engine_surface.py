@@ -48,7 +48,7 @@ PROGRAMS = {"draft_prefill", "ragged_decode_block", "ragged_fused_step",
 # "trace_sampling" come and go with the process-wide registry
 STAGES = {"blocking_gets", "decode_key_token_steps", "device_ms",
           "device_wait_s", "dispatch_ms", "dispatches", "draft_ms",
-          "harvest_ms", "harvests", "host_bound_fraction", "host_s",
+          "fused_ticks", "fused_ticks_ahead", "harvest_ms", "harvests", "host_bound_fraction", "host_s",
           "kernels", "meta_uploads", "pipeline_window", "plan_ms", "pool",
           "prefill_key_tokens", "prefill_tokens", "prefix_ms", "reap_s",
           "requests", "restore_ms", "rng_split_s", "sample_s", "spill_ms",
@@ -94,6 +94,7 @@ def test_what_a_served_engine_reports(served):
                                            "trace_sampling"} == STAGES
     assert set(served.request_latency.completed()[0]) == COMPLETED
     assert served.fused_step().__name__ == "ragged_fused_step"
+    assert served.fused_sample_step(False).__name__ == "ragged_fused_step"
     assert served.decode_block(False).__name__ == "ragged_decode_block"
 
 

@@ -131,7 +131,7 @@ class TestFusedStep:
         prompts = [r.integers(1, 64, size=(s,), dtype=np.int32)
                    for s in (3, 17, 29, 9, 23)]
         eng.generate_all(prompts, max_new_tokens=4)
-        fn = eng.fused_step()
+        fn = eng.fused_sample_step(False)
         assert fn._cache_size() == 1, (
             f"expected 1 compiled variant, got {fn._cache_size()}")
 

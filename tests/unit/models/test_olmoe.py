@@ -97,11 +97,19 @@ def _engine(served, **kw):
                                         decode_block_size=4), **kw})
 
 
-def test_chunked_prefill_then_paged_decode_matches_the_reference(served):
+def test_chunked_prefill_then_paged_decode_matches_the_reference(
+        served, monkeypatch):
     """(b) a 21-token prompt prefilled in chunks of 8, then 10 tokens
     decoded through the paged cache (fused ticks and decode blocks):
     every greedy token is the reference's own argmax of its full forward
     pass, or within the tolerance of it."""
+    from deepspeed_tpu.ops import routes
+
+    # the routes are one record a process, and an xdist worker runs other
+    # files in this one (the kernel's interpret-mode tests leave
+    # ``gmm-...-interpret`` under the same key): read what THIS engine's
+    # programs take while they trace, and nothing older
+    monkeypatch.setattr(routes, "_taken", {})
     eng = _engine(served)
     prompt = _tokens(21, seed=3)
     out = eng.generate_all([np.asarray(prompt, np.int32)],
@@ -124,12 +132,13 @@ def _first_tick_logits(eng, prompts, which):
     for p in prompts:
         eng.put_request(np.asarray(p, np.int32), max_new_tokens=4)
     eng._admit()
-    (token_ids, positions, kv_lens, page_indices, cu_q_lens, num_seqs,
-     new_kv_dest, sample_rows, samplers, _) = eng._plan_tick()
+    arrays, _, sampling, _, _ = eng._pack_tick(eng._plan_rows())
+    # the logits tick takes the sampling tick's arrays less the two that
+    # say where tokens come from and go to
+    token_ids, _tok_src, *meta, _sample_slot = arrays
     sel, eng.kv.cache, counts, _ = eng.fused_step()(
-        eng.params, eng.kv.cache, token_ids[None], positions[None], kv_lens,
-        page_indices, cu_q_lens, num_seqs, new_kv_dest, sample_rows)
-    j = [j for r, j, _ in samplers if r.uid == which][0]
+        eng.params, eng.kv.cache, token_ids, *meta)
+    j = [j for r, j in sampling if r.uid == which][0]
     return np.asarray(sel[j]), np.asarray(counts)
 
 

@@ -202,12 +202,13 @@ def _first_tick_logits(eng, prompts, which):
     for p in prompts:
         eng.put_request(np.asarray(p, np.int32), max_new_tokens=4)
     eng._admit()
-    (token_ids, positions, kv_lens, page_indices, cu_q_lens, num_seqs,
-     new_kv_dest, sample_rows, samplers, _) = eng._plan_tick()
+    arrays, _, sampling, _, _ = eng._pack_tick(eng._plan_rows())
+    # the logits tick takes the sampling tick's arrays less the two that
+    # say where tokens come from and go to
+    token_ids, _tok_src, *meta, _sample_slot = arrays
     sel, eng.kv.cache, _, cdf = eng.fused_step()(
-        eng.params, eng.kv.cache, token_ids[None], positions[None], kv_lens,
-        page_indices, cu_q_lens, num_seqs, new_kv_dest, sample_rows)
-    j = [j for r, j, _ in samplers if r.uid == which][0]
+        eng.params, eng.kv.cache, token_ids, *meta)
+    j = [j for r, j in sampling if r.uid == which][0]
     return np.asarray(sel[j]), np.asarray(cdf)
 
 

@@ -165,6 +165,7 @@ class TestSteadyStateSyncFree:
                 s is not None and s.prefill_done < s.ctx_len
                 for s in eng.slots):
             eng.step()
+        eng.sync()      # the last prefill tick may still be in flight
         eng.host_stats.reset()
         while eng.has_work():
             eng.step()
@@ -202,6 +203,7 @@ class TestSteadyStateSyncFree:
                 s is not None and s.prefill_done < s.ctx_len
                 for s in eng.slots):
             eng.step()
+        eng.sync()      # the prefill tick's token may still be in flight
         generated_before = len(eng.slots[0].generated)
         eng.step()                       # one pipelined block, deferred
         assert len(eng.slots[0].generated) == generated_before
@@ -263,6 +265,299 @@ class TestSubmitTimeValidation:
         with pytest.raises(ValueError, match="never be scheduled"):
             eng.step()
         assert not eng.waiting           # poison head was dropped
+
+
+# prompts of several 8-token chunks beside decoding rows, with arrivals in
+# mid-run: the fused path runs for many ticks on end
+LONG_MIX = dict(sizes=[30, 5, 44],
+                mid={3: _prompts([21], seed=9), 7: _prompts([37], seed=10),
+                     12: _prompts([9], seed=11)})
+SEEDED = dict(do_sample=True, temperature=0.8, top_k=8, top_p=0.9)
+
+
+def _tiny(family):
+    """A tiny OLMoE (dropless experts) or Ouro (a looped model) and its
+    weights, in float32."""
+    from deepspeed_tpu.models import olmoe, ouro
+
+    plain = dict(dtype=jnp.float32, param_dtype=jnp.float32,
+                 scan_layers=False, remat=False, use_flash_attention=False)
+    model = (olmoe.OlmoeForCausalLM(olmoe.get_config(
+        "tinyolmoe", drop_tokens=False, **plain)) if family == "olmoe"
+        else ouro.OuroForCausalLM(ouro.get_config("tinyouro", **plain)))
+    return model, jax.jit(model.init)(
+        jax.random.PRNGKey(5), np.zeros((1, 8), np.int32))["params"]
+
+
+def _log_calls(eng):
+    """Record the order of the fused ticks' dispatches ("D") and of the
+    blocking fetches ("F") of ``eng`` from here on."""
+    log = []
+    build, fetch = eng.fused_sample_step, eng.fetch
+
+    def fused_sample_step(sampled):
+        fn = build(sampled)
+
+        def dispatch(*a):
+            log.append("D")
+            return fn(*a)
+        return dispatch
+
+    def logged_fetch(tree):
+        log.append("F")
+        return fetch(tree)
+
+    eng.fused_sample_step, eng.fetch = fused_sample_step, logged_fetch
+    return log
+
+
+def _until_in_flight(eng, limit=200):
+    """Step until a fused tick is left in flight."""
+    for _ in range(limit):
+        eng.step()
+        if eng._tick is not None:
+            return
+    raise AssertionError("no fused tick was ever left in flight")
+
+
+class TestFusedTicksAhead:
+    """The SplitFuse loop dispatches tick k+1 before it waits for tick k's
+    tokens wherever the plan of k+1 is exact without them, and nowhere
+    else; the tokens are ``pipeline=False``'s bit for bit."""
+
+    @pytest.mark.parametrize("kw", [{}, SEEDED], ids=["greedy", "seeded"])
+    def test_parity_on_a_mix_of_chunked_prompts_and_decoding_rows(
+            self, params, kw):
+        on, eng_on = _serve(params, True, LONG_MIX["sizes"],
+                            mid=LONG_MIX["mid"], max_new_tokens=14, **kw)
+        off, eng_off = _serve(params, False, LONG_MIX["sizes"],
+                              mid=LONG_MIX["mid"], max_new_tokens=14, **kw)
+        assert len(on) == 6
+        _assert_same_outputs(on, off)
+        st_on, st_off = eng_on.host_stats, eng_off.host_stats
+        assert st_on.fused_ticks == st_off.fused_ticks > 10
+        assert st_on.fused_ticks_ahead > st_on.fused_ticks // 2
+        assert st_off.fused_ticks_ahead == 0
+        stages = eng_on.serving_stages()
+        assert stages["fused_ticks_ahead"] == st_on.fused_ticks_ahead
+        assert stages["fused_ticks"] == st_on.fused_ticks
+
+    def test_tick_k_plus_1_is_dispatched_before_tick_k_is_fetched(
+            self, params):
+        eng = make(params, True)
+        log = _log_calls(eng)
+        eng.put_request(_prompts([5], seed=1)[0], max_new_tokens=12)
+        eng.put_request(_prompts([40], seed=2)[0], max_new_tokens=4)
+        while eng.slots[1] is None or (eng.slots[1].prefill_done <
+                                       eng.slots[1].ctx_len):
+            eng.step()
+        # five chunk ticks, a decode row of the short request in each
+        # after the first: every dispatch but the first goes out with the
+        # tick before un-fetched, and every fetch but the last comes
+        # after the NEXT tick's dispatch
+        assert "".join(log) == "D" + "DF" * 4
+        assert eng._tick is not None and eng.has_work()
+        assert eng.host_stats.fused_ticks_ahead == 4
+        # the token of the tick in flight is not the host's yet
+        before = len(eng.slots[0].generated)
+        assert eng.slots[0].pending == 1
+        assert eng.sync() == 2       # a decode row and the prompt's end
+        assert eng._tick is None and eng.slots[0].pending == 0
+        assert len(eng.slots[0].generated) == before + 1
+
+    def test_a_prompts_first_token_streams_before_the_first_block_is_built(
+            self, params):
+        """The flush at the entry into decode blocks ends its step: the
+        first token reaches ``stream_deltas`` before the host builds the
+        pipeline's carry, as under ``pipeline=False``."""
+        eng = make(params, True)
+        uid = eng.put_request(_prompts([5], seed=1)[0], max_new_tokens=12)
+        assert eng.step() == 0 and eng._tick is not None
+        assert eng.stream_deltas() == []
+        assert eng.step() == 1 and eng._tick is None and eng._dev is None
+        ((got, toks, total, done),) = eng.stream_deltas()
+        assert (got, len(toks), total, done) == (uid, 1, 1, False)
+        eng.step()
+        assert eng._dev is not None          # the blocks start now
+
+    def test_dispatch_spans_say_whether_they_went_ahead(self, params):
+        from deepspeed_tpu.telemetry import trace
+        prev = (trace.enabled, trace.buffer_size, trace.clock)
+        trace.clear()
+        trace.configure(enabled=True)
+        try:
+            _, eng = _serve(params, True, [5, 40], max_new_tokens=6)
+            fused = [e["args"] for e in trace.snapshot()
+                     if e["name"] == "engine.dispatch"
+                     and e["args"].get("program") == "fused"]
+        finally:
+            trace.configure(enabled=prev[0], buffer_size=prev[1],
+                            clock=prev[2])
+            trace.clear()
+        st = eng.host_stats
+        assert len(fused) == st.fused_ticks
+        assert sum(a["ahead"] for a in fused) == st.fused_ticks_ahead > 0
+        assert {a["ahead"] for a in fused} == {0, 1}
+
+    @pytest.mark.parametrize("case", ["eos", "pipeline_off"])
+    def test_no_tick_goes_ahead_where_its_tokens_decide_the_plan(
+            self, params, case):
+        """A row with an EOS id may end on any token, so its tick is
+        fetched before the next plan, as ``has_eos`` forces a harvest of
+        the decode pipeline; ``pipeline=False`` is the switch."""
+        kw = dict(max_new_tokens=14)
+        if case == "eos":
+            kw["eos_token_id"] = 1000     # no token of the vocabulary
+        outs, eng = _serve(params, case != "pipeline_off",
+                           LONG_MIX["sizes"], mid=LONG_MIX["mid"], **kw)
+        ref, _ = _serve(params, False, LONG_MIX["sizes"],
+                        mid=LONG_MIX["mid"], max_new_tokens=14)
+        _assert_same_outputs(outs, ref)
+        assert eng.host_stats.fused_ticks > 10
+        assert eng.host_stats.fused_ticks_ahead == 0
+
+    def test_a_handoff_prefill_is_fetched_and_parked_in_its_own_step(
+            self, params):
+        """The first token of a handoff prefill parks the session the
+        step it lands (``_reap(handoff_sweep=True)``): its tick is never
+        left in flight, while the ticks around it are."""
+        eng = make(params, True)
+        eng.put_request(_prompts([5], seed=1)[0], max_new_tokens=30)
+        uid = eng.put_request(_prompts([29], seed=2)[0], max_new_tokens=8,
+                              handoff=True)
+        parked_at = None
+        for i in range(40):
+            eng.step()
+            assert eng._tick is None or not any(
+                r.handoff for r, _ in eng._tick.sampling)
+            if eng._handoff_ready and parked_at is None:
+                parked_at = i
+                assert eng.host_stats.fused_ticks_ahead > 0
+                # parked with its first token, in the step of its tick
+                (r,) = eng._handoff_ready
+                assert r.uid == uid and len(r.generated) == 1
+                assert r.pending == 0 and r.slot == -1
+                break
+        assert parked_at is not None
+        # pulled with a tick of the other request in flight or not:
+        # export flushes first
+        (blob,) = eng.export_handoff()
+        assert eng._tick is None and len(blob["generated"]) == 1
+        alone = make(params, False).generate_all(
+            _prompts([29], seed=2), max_new_tokens=1)
+        assert list(alone[0][29:]) == blob["generated"]
+        eng.drain()
+        eng.audit_kv_sharing()          # raises on a leaked page
+
+    @pytest.mark.parametrize("op", ["cancel", "sync", "drain"])
+    def test_an_op_that_needs_exact_state_flushes_the_tick_in_flight(
+            self, params, op):
+        """The same op at the same step on both engines: the pipelined
+        one has a tick in flight then, and ends with the same tokens."""
+        def run(pipeline):
+            eng = make(params, pipeline)
+            uids = [eng.put_request(p, max_new_tokens=12)
+                    for p in _prompts([5, 40, 33], seed=4)]
+            for _ in range(3):
+                eng.step()
+            in_flight = eng._tick is not None
+            outs = {}
+            if op == "cancel":
+                assert eng.cancel(uids[0]) == "decode"
+                eng.audit_kv_sharing()          # raises on a leaked page
+            elif op == "sync":
+                # the short request's decode row of the tick in flight
+                assert eng.sync() == (1 if in_flight else 0)
+                assert all(r.pending == 0 for r in eng.slots
+                           if r is not None)
+            if op == "drain":
+                outs.update(eng.drain())
+            else:
+                assert eng._tick is None
+                while eng.has_work():
+                    eng.step()
+                    outs.update(eng.get_outputs())
+            assert eng._tick is None and not eng.has_work()
+            return outs, in_flight
+
+        (on, flew), (off, never) = run(True), run(False)
+        assert flew and not never
+        assert len(on) == (2 if op == "cancel" else 3)
+        _assert_same_outputs(on, off)
+
+    def test_a_page_stall_flushes_the_tick_in_flight_and_evicts_alike(
+            self, params):
+        """A pool too small for its sequences: the plan that falls short
+        of pages is made again after the tick in flight is folded, so
+        stalls and evictions are ``pipeline=False``'s."""
+        eng_kw = dict(max_seqs=3, prefill_chunk=8, page_size=8, num_pages=9,
+                      decode_block_size=4, kv_reserve="on_demand")
+        on, eng_on = _serve(params, True, [12, 20, 9], eng_kw=eng_kw,
+                            max_new_tokens=24)
+        off, eng_off = _serve(params, False, [12, 20, 9], eng_kw=eng_kw,
+                              max_new_tokens=24)
+        assert eng_on.evictions == eng_off.evictions > 0
+        assert eng_on.host_stats.fused_ticks_ahead > 0
+        _assert_same_outputs(on, off)
+        eng_on.audit_kv_sharing()       # raises on a leaked page
+
+    def test_no_count_of_sampling_rows_compiles_after_the_first_tick(
+            self, params):
+        """Everything the host hands the tick is a fixed-shape array: ticks
+        with 1..max_seqs sampling rows run the program the first compiled."""
+        from deepspeed_tpu.telemetry.compiles import count_compiles
+        eng = make(params, True, max_seqs=4)
+        # one request alone: the fused tick and the decode block compile
+        eng.generate_all(_prompts([11], seed=3), max_new_tokens=8)
+        seen = set()
+        pack = eng._pack_tick
+
+        def counting_pack(takes):
+            out = pack(takes)
+            seen.add(len(out[2]))
+            return out
+
+        eng._pack_tick = counting_pack
+        with count_compiles() as compiles:
+            # long prompts arriving one behind the other: each prefills
+            # beside the decode rows of those before it
+            for p in _prompts([30, 30, 30, 30], seed=6):
+                eng.put_request(p, max_new_tokens=40)
+                for _ in range(5):
+                    eng.step()
+            eng.drain()
+        assert {1, 2, 3, 4} <= seen, seen
+        assert compiles.count == 0, compiles.count
+
+    @pytest.mark.parametrize("family", ["olmoe", "ouro"])
+    def test_parity_and_counts_on_an_expert_and_a_looped_model(self, family):
+        """The tick's inputs gain ``last_tok`` and a row-source vector, its
+        model call is the same: the counts of the expert layers and of the
+        pass loop ride the fetches as they did, to the same sums."""
+        model, weights = _tiny(family)
+        prompts = [np.asarray(p, np.int32)
+                   for p in _prompts([21, 5, 13], seed=3)]
+        runs = {}
+        for pipeline in (True, False):
+            eng = RaggedInferenceEngineV2(
+                model, params=weights, pipeline=pipeline, max_seqs=3,
+                max_seq_len=64, prefill_chunk=8, page_size=8,
+                decode_block_size=4)
+            runs[pipeline] = (eng.generate_all(prompts, max_new_tokens=9),
+                              eng.serving_stages(), eng)
+        (on, st_on, eng_on), (off, st_off, _) = runs[True], runs[False]
+        _assert_same_outputs(on, off)
+        assert eng_on.host_stats.fused_ticks_ahead > 0
+        assert not eng_on._counts_pending
+        if family == "olmoe":
+            assert st_on["moe"] == st_off["moe"]
+            assert st_on["moe"]["rows"] == 2 * 2 * sum(
+                p.size + 8 for p in prompts)
+        else:
+            assert st_on["loop"]["rows"] == st_off["loop"]["rows"] > 0
+            np.testing.assert_allclose(st_on["loop"]["exit_cdf"],
+                                       st_off["loop"]["exit_cdf"],
+                                       rtol=1e-5)
 
 
 class TestV1DeferredHarvest:

@@ -363,6 +363,7 @@ class TestSteadyState:
                 s is not None and s.prefill_done < s.ctx_len
                 for s in eng.slots):
             eng.step()
+        eng.sync()      # the last prefill tick may still be in flight
         eng.host_stats.reset()
         while eng.has_work():
             eng.step()
