@@ -158,6 +158,9 @@ class HostStageStats:
         self.moe_rows = 0           # token copies routed
         self.moe_experts_hit = 0    # experts with at least one row
         self.moe_load_max = 0       # rows of the fullest expert, ever
+        # (a sigmoid router with a selection bias) tokens whose chosen
+        # set the bias changed
+        self.moe_bias_swaps = 0
         # a looped model's exit gate, live rows only:
         self.loop_rows = 0          # rows at dispatch, summed
         self.loop_exit_cdf = []     # P(stopped by pass u), summed, u < U

@@ -33,6 +33,20 @@ copies, spills or shares ``leaf[page]`` moves them together and the
 allocator counts pages as before.  Pass ``u`` (``ragged_meta["kv_slot"]``)
 reads and writes the same buffer as ``[num_pages * U, ...]`` at page
 ``p * U + u``; the kernels see an ordinary pool.
+
+Heads of 64 (``models/lfm2_moe.py``) are stored **zero-padded to 128**
+(:func:`kv_lane_dim`): both Pallas kernels are built for 128 lanes, a
+padded key adds ``0 * 0`` to a score and a padded value column is sliced
+off the output, so the result is exact and the softmax scale stays the
+true head's.  A token then costs the pool ``2 * Hkv * 128`` values a K/V
+layer, twice its unpadded size.
+
+Beside its pages a sequence may own **per-slot state** that is no page:
+the last ``conv_L_cache - 1`` inputs of each short-convolution layer
+(:func:`ragged_short_conv`), a ``[max_seqs, rows, width]`` leaf named
+:data:`SLOT_STATE` in the same ``cache`` collection, indexed by slot.
+``inference/v2/kv_state.py`` tells the two kinds of leaf apart by that
+name.
 """
 from __future__ import annotations
 
@@ -47,6 +61,9 @@ from deepspeed_tpu.ops.paged_decode import paged_decode_attention
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 
 TRASH_PAGE = 0
+_LANES = 128
+# the name of a per-slot state leaf in the ``cache`` collection
+SLOT_STATE = "slot_state"
 
 # block sizes handed to the upstream ragged paged kernel (see ``attend``):
 # a KV block of at most 512 tokens, and of at most 4096 rows of K or V
@@ -79,6 +96,120 @@ def kv_slots_per_layer(cfg) -> int:
     """K/V slots a layer holds for one token: the passes of a looped model
     (its config's ``total_ut_steps``), 1 for every other."""
     return int(getattr(cfg, "total_ut_steps", 1))
+
+
+def kv_lane_dim(head_dim: int, kv_cache_dtype: str = "none") -> int:
+    """Width of a K/V head AS THE POOL STORES IT: heads of 64 in a
+    full-width pool are zero-padded to the kernels' 128 lanes, every other
+    head is stored as it is."""
+    full_width = (kv_cache_dtype or "none") == "none"
+    return _LANES if head_dim == 64 and full_width else head_dim
+
+
+def state_layers(cfg) -> int:
+    """Layers of a model that keep per-slot state beside the pool: the
+    ``"conv"`` entries of its config's ``layer_types``; 0 for a model
+    whose config names no layer types (every layer holds K/V)."""
+    return sum(t == "conv" for t in getattr(cfg, "layer_types", ()))
+
+
+def kv_layers(cfg) -> int:
+    """Layers of a model that keep keys and values in the pool."""
+    return int(cfg.num_hidden_layers) - state_layers(cfg)
+
+
+def is_slot_state(path) -> bool:
+    """Is the cache leaf at ``path`` (a ``tree_flatten_with_path`` key
+    path) per-slot state and not a page pool?"""
+    return any(getattr(k, "key", None) == SLOT_STATE for k in path)
+
+
+def split_cache(cache):
+    """``(pools, states)``: the leaves of a cache tree that are page pools
+    (leading dim: pages) and those that are per-slot state (leading dim:
+    slots), each in the tree's own leaf order."""
+    flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+    return ([l for p, l in flat if not is_slot_state(p)],
+            [l for p, l in flat if is_slot_state(p)])
+
+
+def map_cache(cache, pool_fn=None, state_fn=None):
+    """``cache`` with ``pool_fn`` applied to every pool leaf and
+    ``state_fn`` to every per-slot state leaf (None: left as it is)."""
+    keep = lambda l: l
+    pool_fn, state_fn = pool_fn or keep, state_fn or keep
+    return jax.tree_util.tree_map_with_path(
+        lambda p, l: state_fn(l) if is_slot_state(p) else pool_fn(l), cache)
+
+
+def ragged_short_conv(mdl, u: jax.Array, taps: jax.Array, pos: jax.Array,
+                      ragged_meta: Dict[str, jax.Array]) -> jax.Array:
+    """Causal depthwise convolution of ``K = taps.shape[1]`` taps over a
+    packed ragged token batch, inside a flax module ``mdl``.
+
+    ``u [T, C]``: the convolution's input, row ``t`` a token of the
+    sequence ``cu_q_lens`` puts it in, at position ``pos [T]`` of it;
+    ``taps [C, K]``, tap ``K - 1`` on the token itself.  Returns ``c [T,
+    C]`` with ``c_t = sum_k taps[:, k] * u_{t - (K - 1 - k)}`` where ``u``
+    before a sequence's first token (position 0) is zero, before a CHUNK's first token is the sequence's
+    carried state, and no row of another sequence is ever read.
+
+    The state is ``cache/slot_state [max_seqs, K - 1, C]``: the last
+    ``K - 1`` inputs of the sequence in slot ``s``, oldest first.  A
+    sequence's slot is ``ragged_meta["seq_slot"][j]`` by packed sequence
+    number (a decode program, one query a slot, leaves it out: row ``s``
+    IS slot ``s``).  Every sequence with a live row in this call leaves
+    its last ``K - 1`` inputs there; no other slot's state changes.  A
+    chunk at position 0 reads zeros whatever its slot held, so a slot's
+    previous tenant cannot leak.
+    """
+    T, C = u.shape
+    K = taps.shape[1]
+    cu = ragged_meta["cu_q_lens"]
+    S = cu.shape[0] - 1
+    state = mdl.variable("cache", SLOT_STATE, jnp.zeros, (S, K - 1, C),
+                         u.dtype)
+    seq_slot = ragged_meta.get("seq_slot")
+    if seq_slot is None:
+        seq_slot = jnp.arange(S, dtype=jnp.int32)
+    t_idx = jnp.arange(T, dtype=jnp.int32)
+    seq_of_t = jnp.minimum(jnp.sum(
+        (t_idx[:, None] >= cu[None, 1:]).astype(jnp.int32), axis=1), S - 1)
+    off = t_idx - jnp.take(cu[:-1], seq_of_t)         # row within its chunk
+    old = jnp.take(state.value, jnp.take(seq_slot, seq_of_t), axis=0)
+    # the chunk's rows behind its carried state: [T + K - 1, C] is never
+    # built; tap d steps back is row t - d of u where the chunk reaches
+    # that far, else row K - 1 - (d - off) of the state
+    w = taps.astype(jnp.float32)
+    c = u.astype(jnp.float32) * w[:, K - 1]
+    for d in range(1, K):
+        shifted = jnp.pad(u, ((d, 0), (0, 0)))[:T]
+        carried = jnp.take_along_axis(
+            old, jnp.clip(K - 1 - d + off, 0, K - 2)[:, None, None],
+            axis=1)[:, 0]
+        back = jnp.where((off >= d)[:, None], shifted, carried)
+        back = jnp.where((pos >= d)[:, None], back, 0)
+        c = c + back.astype(jnp.float32) * w[:, K - 1 - d]
+    # the new state of sequence j: the last K - 1 rows of (state, chunk)
+    q_len = cu[1:] - cu[:-1]
+    last = cu[1:] - 1                                 # its last row
+    live = ragged_meta.get("live")
+    seq_live = (q_len > 0) & (jnp.arange(S) < ragged_meta["num_seqs"][0])
+    if live is not None:
+        seq_live = seq_live & jnp.take(live, jnp.clip(last, 0, T - 1))
+    old_j = jnp.take(state.value, seq_slot, axis=0)   # [S, K - 1, C]
+    rows = []
+    for i in range(K - 1):                            # new row i, oldest 0
+        d = K - 2 - i                                 # steps back from last
+        from_chunk = jnp.take(u, jnp.clip(last - d, 0, T - 1), axis=0)
+        from_state = jnp.take_along_axis(
+            old_j, jnp.clip(K - 1 - d + q_len - 1, 0, K - 2)[:, None, None],
+            axis=1)[:, 0]
+        rows.append(jnp.where((q_len > d)[:, None], from_chunk, from_state))
+    new = jnp.stack(rows, axis=1)
+    state.value = state.value.at[
+        jnp.where(seq_live, seq_slot, S)].set(new, mode="drop")
+    return c.astype(u.dtype)
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:
@@ -557,6 +688,16 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     page and this call reads and writes slot ``u`` alone, in place.
     """
     _, H, T, D = q.shape
+    sm_scale = float(1.0 / np.sqrt(D))
+    head_dim = D
+    D = kv_lane_dim(head_dim, getattr(cfg, "kv_cache_dtype", "none"))
+    if D != head_dim:
+        # heads of 64 live in the pool zero-padded to the kernels' lanes
+        assert "staged_kv" not in ragged_meta and \
+            "carry_m" not in ragged_meta, (
+                "padded K/V heads have no partial-residency scan")
+        pad = lambda a: jnp.pad(a, ((0, 0),) * 3 + ((0, D - head_dim),))
+        q, k, v = pad(q), pad(k), pad(v)
     slots = kv_slots_per_layer(cfg)
     if "staged_kv" in ragged_meta:
         assert slots == 1, "partial residency has one K/V slot a layer"
@@ -646,7 +787,6 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         kv_scales = scales_var.value
 
     qt = q[0].transpose(1, 0, 2)                       # [T, H, D]
-    sm_scale = float(1.0 / np.sqrt(D))
     kv_lens = ragged_meta["kv_lens"]
     cu_q_lens = ragged_meta["cu_q_lens"]
     num_seqs = ragged_meta["num_seqs"]
@@ -667,18 +807,20 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         return paged_decode_attention(
             qt, pages, kv_lens, page_indices, live, sm_scale=sm_scale,
             sliding_window=window, fallback=lambda: attend_ragged(*ragged),
-            fallback_route=paged_attention_path(D))
+            fallback_route=paged_attention_path(head_dim))
 
     def attend_ragged(qt, pages, kv_lens, page_indices, cu_q_lens,
                       num_seqs):
-        # the vLLM-TPU kernel is built for head_dim 128 (its lane-width
-        # row stats assert on smaller D); other dims take the XLA
-        # reference — correct but O(T * total_page_rows), serving-shape
-        # models should use 128-dim heads.  An incoming chunk-scan
-        # carry always routes to the reference: the upstream kernel has
-        # no carry operand (the quantized pool's own kernel does).
-        if paged_attention_path(D, carry=carry is not None) == \
-                "pallas-ragged":
+        # the vLLM-TPU kernel is built for 128 lanes (its lane-width row
+        # stats assert on a smaller D): heads of 128 take it as they are,
+        # heads of 64 zero-padded to 128 (``kv_lane_dim``), every other
+        # width the XLA reference — correct but O(T * total_page_rows).
+        # An incoming chunk-scan carry always routes to the reference:
+        # the upstream kernel has no carry operand (the quantized pool's
+        # own kernel does).
+        if paged_attention_path(
+                head_dim, carry=carry is not None).startswith(
+                    "pallas-ragged"):
             from jax.experimental.pallas.ops.tpu.ragged_paged_attention \
                 import kernel as rpa
 
@@ -700,8 +842,9 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
             from deepspeed_tpu.utils.logging import logger
 
             logger.warning(
-                f"paged attention: head_dim={D} != 128 — the Pallas "
-                "ragged kernel needs 128; using the dense XLA route "
+                f"paged attention: head_dim={head_dim} — the Pallas "
+                "kernels serve heads of 128, and heads of 64 padded to "
+                "128; this width takes the dense XLA route "
                 "(serving_stages()['kernels'] reports it)")
         return ref_paged_attention(
             qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
@@ -772,7 +915,8 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     else:
         y = attend_quant(qt, pages, kv_scales, kv_lens, page_indices,
                          cu_q_lens, num_seqs)
-    return y.transpose(1, 0, 2)[None]                  # [1, H, T, D]
+    y = y.transpose(1, 0, 2)[None]                     # [1, H, T, D]
+    return y if D == head_dim else y[..., :head_dim]
 
 
 def kv_dequant_path(head_dim: int) -> str:
@@ -789,13 +933,17 @@ def kv_dequant_path(head_dim: int) -> str:
 
 def paged_attention_path(head_dim: int, carry: bool = False) -> str:
     """Which read path a full-width pool takes on this backend: the
-    upstream Pallas ragged kernel (TPU, head_dim 128, no incoming
-    chunk-scan carry — it has no carry operand) or the dense XLA
-    reference (:func:`ref_paged_attention`).  The engine reports this in
-    ``serving_stages()['kernels']``."""
+    upstream Pallas ragged kernel (TPU, no incoming chunk-scan carry — it
+    has no carry operand) for heads of 128 (``pallas-ragged``) and for
+    heads of 64 stored zero-padded to 128 (``pallas-ragged-pad128``), or
+    the dense XLA reference (:func:`ref_paged_attention`: ``xla-dense``,
+    over the padded pool ``xla-dense-pad128``).  The engine reports this
+    in ``serving_stages()['kernels']``."""
+    pad = "-pad128" if kv_lane_dim(head_dim) != head_dim else ""
     return routes.take("paged_attention",
-                       eligible=head_dim == 128 and not carry,
-                       pallas="pallas-ragged", xla="xla-dense")
+                       eligible=kv_lane_dim(head_dim) == _LANES
+                       and not carry,
+                       pallas="pallas-ragged" + pad, xla="xla-dense" + pad)
 
 
 def _serving_tp(cfg) -> int:

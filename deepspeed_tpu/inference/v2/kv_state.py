@@ -28,6 +28,15 @@ write a page-table cell themselves:
 ``audit``           refcount conservation over every holder
 ==================  =====================================================
 
+A model with layers that keep **per-slot state** and no K/V (the short
+convolutions of ``models/lfm2_moe.py``: a few rows a layer a sequence, in the
+same device tree under the leaf name ``inference/paged.py:SLOT_STATE``) has
+that state owned here too, with the pages' life: zeroed at ``attach``, carried
+by ``spill``/``restore`` and ``export``/``adopt`` (in the request's ``spilled``
+record: it is kilobytes, not pages), dead at ``release`` with the slot.  The
+page verbs move pool leaves only.  What cannot carry it (a shared prefix
+needs the state AT the prefix's end) is refused by the engine by name.
+
 A request is duck-typed (``uid``, ``slot``, ``ctx``, ``prompt``,
 ``generated``, ``prefill_done``, ``spilled``, ``pc_*``, ``lc``,
 ``lc_parked``); nothing here imports the engine.
@@ -41,7 +50,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference.paged import PageAllocator, pages_for
+from deepspeed_tpu.inference.paged import (PageAllocator, map_cache,
+                                           pages_for, split_cache)
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH, PrefixCacheIndex
 from deepspeed_tpu.telemetry import trace
 from deepspeed_tpu.utils.logging import logger
@@ -52,8 +62,9 @@ __all__ = ["KVState"]
 class KVState:
     """The paged pool, its tiers and its prefix index, for ``max_seqs``
     slots.  ``cache`` is the device tree of page pools (every leaf leads
-    with the page dim); ``draft_cache`` the draft model's, whose page
-    cursors are the target's.  ``host_stats`` / ``request_latency`` are
+    with the page dim) and of per-slot state, if the model has any (those
+    leaves lead with the slot dim); ``draft_cache`` the draft model's, whose
+    page cursors are the target's.  ``host_stats`` / ``request_latency`` are
     the engine's: the ``prefix`` / ``spill`` / ``restore`` spans and the
     per-request spill and restore stalls are recorded where the pages
     move."""
@@ -80,6 +91,11 @@ class KVState:
         self._tier_gather = None       # jitted fixed-shape page gather
         self._tier_scatter = None      # jitted fixed-shape page scatter
         self._cow_jit = None           # jitted fixed-shape page copy
+        self._state_jits = None        # jitted per-slot state get / put / zero
+        # bytes of per-slot state one sequence owns (0: the model has none)
+        self.state_bytes_per_seq = sum(
+            leaf[0].size * leaf.dtype.itemsize
+            for leaf in split_cache(cache)[1])
 
         # -- tiered KV spill store (HBM -> host RAM -> NVMe) --
         self.tier_cfg = tier_cfg
@@ -92,7 +108,7 @@ class KVState:
                 "holds")
             from deepspeed_tpu.inference.kv_tiering import TieredKVStore
 
-            leaves, self._cache_treedef = jax.tree_util.tree_flatten(cache)
+            leaves = split_cache(cache)[0]
             assert all(leaf.shape[0] == self.num_pages
                        for leaf in leaves), (
                 "every paged-KV cache leaf must lead with the page dim")
@@ -331,6 +347,9 @@ class KVState:
         if grow_n > 0:
             pages = self.allocator.grow(i, grow_n)
             self.page_table[i, attached:attached + grow_n] = pages
+        if self.state_bytes_per_seq:
+            # the slot's previous tenant must not leak into this one
+            self._put_state(i, None)
         self.request_latency.on_admit(req.uid)
         if trace.enabled:
             trace.event("request_admit", cat="request", uid=req.uid,
@@ -398,7 +417,8 @@ class KVState:
     def release(self, slot: int) -> None:
         """Give ``slot``'s pages back: every page loses the slot's
         reference (shared ones stay with their other holders) and the
-        page-table row empties."""
+        page-table row empties.  The slot's per-slot state dies with it:
+        nothing reads it before the next ``attach`` zeroes it."""
         self.allocator.free(slot)
         self.page_table[slot, :] = -1
 
@@ -425,13 +445,13 @@ class KVState:
           rows."""
         if self._tier_gather is None:
             def gather(cache, idx):
-                return jax.tree_util.tree_map(
-                    lambda l: jnp.take(l, idx, axis=0), cache)
+                return [jnp.take(l, idx, axis=0)
+                        for l in split_cache(cache)[0]]
 
             def scatter(cache, idx, rows):
-                return jax.tree_util.tree_map(
-                    lambda l, r: l.at[idx].set(r, mode="drop"),
-                    cache, rows)
+                rows = iter(rows)      # one a pool leaf, in leaf order
+                return map_cache(
+                    cache, lambda l: l.at[idx].set(next(rows), mode="drop"))
 
             self._tier_gather = jax.jit(gather)
             self._tier_scatter = jax.jit(scatter, donate_argnums=(0,))
@@ -444,8 +464,7 @@ class KVState:
         idx = np.zeros((self.pages_per_seq,), np.int32)     # pad: trash
         idx[:n] = pages
         rows = jax.device_get(gather(self.cache, jnp.asarray(idx)))
-        return [np.asarray(leaf[:n])
-                for leaf in jax.tree_util.tree_leaves(rows)]
+        return [np.asarray(leaf[:n]) for leaf in rows]
 
     def _scatter_rows(self, pages, arrs) -> None:
         """Write ``arrs`` (one ``[n, ...]`` array per cache leaf) into
@@ -460,8 +479,7 @@ class KVState:
             full = np.zeros((self.pages_per_seq,) + a.shape[1:], a.dtype)
             full[:n] = a
             leaves.append(jnp.asarray(full))
-        rows = jax.tree_util.tree_unflatten(self._cache_treedef, leaves)
-        self.cache = scatter(self.cache, jnp.asarray(idx), rows)
+        self.cache = scatter(self.cache, jnp.asarray(idx), leaves)
 
     def _cow_copy(self, src: int, dst: int) -> None:
         """Fixed-shape device copy of one page row across every cache
@@ -471,8 +489,8 @@ class KVState:
         st = self.host_stats
         if self._cow_jit is None:
             self._cow_jit = jax.jit(
-                lambda cache, s, d: jax.tree_util.tree_map(
-                    lambda l: l.at[d].set(l[s]), cache),
+                lambda cache, s, d: map_cache(
+                    cache, lambda l: l.at[d].set(l[s])),
                 donate_argnums=(0,))
         with st.stage("prefix"):
             self.cache = self._cow_jit(self.cache, jnp.int32(src),
@@ -482,7 +500,55 @@ class KVState:
             trace.event("prefix_cow", cat="request", src=int(src),
                         dst=int(dst))
 
+    # -- per-slot state beside the pages ------------------------------------
+
+    def _state_programs(self):
+        """The three fixed-shape programs that move one slot's state: get
+        (every state leaf's row of the slot), put (the same rows back) and
+        zero (the slot's rows cleared, nothing uploaded); the last two
+        donate the cache."""
+        if self._state_jits is None:
+            def get(cache, slot):
+                return [l[slot] for l in split_cache(cache)[1]]
+
+            def put(cache, slot, rows):
+                rows = iter(rows)      # one a state leaf, in leaf order
+                return map_cache(
+                    cache, state_fn=lambda l: l.at[slot].set(next(rows)))
+
+            def zero(cache, slot):
+                return map_cache(
+                    cache, state_fn=lambda l: l.at[slot].set(0))
+
+            self._state_jits = (jax.jit(get),
+                                jax.jit(put, donate_argnums=(0,)),
+                                jax.jit(zero, donate_argnums=(0,)))
+        return self._state_jits
+
+    def _get_state(self, slot: int) -> List[np.ndarray]:
+        """``slot``'s state on the host, one array a state leaf."""
+        get = self._state_programs()[0]
+        return [np.asarray(a) for a in jax.device_get(
+            get(self.cache, jnp.int32(slot)))]
+
+    def _put_state(self, slot: int, rows: Optional[List[np.ndarray]]
+                   ) -> None:
+        """Write ``rows`` (as :meth:`_get_state` gave them; None: zeros)
+        into ``slot``'s state."""
+        _, put, zero = self._state_programs()
+        if rows is None:
+            self.cache = zero(self.cache, jnp.int32(slot))
+        else:
+            self.cache = put(self.cache, jnp.int32(slot),
+                             [jnp.asarray(a) for a in rows])
+
     # -- tiered spill / restore (HBM <-> host RAM <-> NVMe) ----------------
+
+    def _state_attrs(self) -> Dict[str, int]:
+        """What the ``engine.spill`` / ``engine.restore`` spans say of the
+        per-slot state they carry; nothing for a model without any."""
+        n = self.state_bytes_per_seq
+        return {"state_bytes": n} if n else {}
 
     def live_tokens(self, r) -> int:
         """Cache rows that hold real KV for ``r`` RIGHT NOW.  Decode
@@ -520,7 +586,8 @@ class KVState:
         if n_priv > 0 and not self.tiering.can_spill(n_priv):
             return False
         shared = [int(p) for p in self.page_table[r.slot, :j]]
-        with self.host_stats.stage("spill"):
+        state = self._state_attrs()
+        with self.host_stats.stage("spill", **state):
             if n_priv > 0:
                 rows = self._gather_rows(self.page_table[r.slot, j:n_live])
                 try:
@@ -530,6 +597,9 @@ class KVState:
             r.spilled = {"last_tok": int(last_tok),
                          "n_pages": n_priv, "live_tokens": live,
                          "shared_pages": shared}
+            if state:
+                # the rows are the state after exactly ``live`` tokens
+                r.spilled["state"] = self._get_state(r.slot)
             for p in shared:
                 self.allocator.incref(p)   # spill-hold survives release
         self.spills += 1
@@ -559,11 +629,15 @@ class KVState:
         t_restore0 = time.perf_counter()
         failed = None
         try:
-            if n > 0:
-                with self.host_stats.stage("restore"):
-                    self._scatter_rows(
-                        self.page_table[req.slot, jn:jn + n],
-                        self.tiering.restore(req.uid))
+            if n > 0 or "state" in info:
+                with self.host_stats.stage("restore",
+                                           **self._state_attrs()):
+                    if n > 0:
+                        self._scatter_rows(
+                            self.page_table[req.slot, jn:jn + n],
+                            self.tiering.restore(req.uid))
+                    if "state" in info:
+                        self._put_state(req.slot, info["state"])
         except KVRestoreError as e:
             failed = e
         req.spilled = None
@@ -696,6 +770,8 @@ class KVState:
                     "live_tokens": int(r.spilled["live_tokens"]),
                     "payload": (self.tiering.export_spilled(r.uid)
                                 if n_priv > 0 else None)}
+                if "state" in r.spilled:
+                    blob["spill"]["state"] = r.spilled["state"]
         return blob
 
     def adopt(self, spill: Dict[str, Any], req) -> bool:
@@ -719,6 +795,8 @@ class KVState:
                         if payload is not None else 0),
             "live_tokens": int(spill["live_tokens"]),
             "shared_pages": []}
+        if "state" in spill:
+            req.spilled["state"] = [np.asarray(a) for a in spill["state"]]
         return True
 
     # -- long context: a resident window with a parked middle ---------------

@@ -37,7 +37,9 @@ import numpy as np
 
 from deepspeed_tpu.inference.common import (logits_of, normalize_params,
                                             unroll_scan_params)
-from deepspeed_tpu.inference.paged import kv_slots_per_layer, pages_for
+from deepspeed_tpu.inference.paged import (kv_layers, kv_slots_per_layer,
+                                           pages_for, split_cache,
+                                           state_layers)
 from deepspeed_tpu.inference.quantization import (dequantize_param_tree,
                                                   quantize_param_tree)
 from deepspeed_tpu.inference.sampling import (filter_logits_batched,
@@ -60,9 +62,31 @@ def fold_moe_counts(counts: jax.Array) -> jax.Array:
 def moe_counts(variables) -> Optional[jax.Array]:
     """One apply's ``moe_stats`` collection (each dropless expert layer
     sows ``[rows, experts_hit, load_max]``) folded over its layers; None
-    for a model without expert layers, so its programs gain no output."""
-    leaves = jax.tree_util.tree_leaves(variables.get("moe_stats", {}))
-    return fold_moe_counts(jnp.stack(leaves)) if leaves else None
+    for a model without expert layers, so its programs gain no output.
+    Where the layers also sow ``bias_swaps`` (the sigmoid router with a
+    selection bias: tokens whose chosen set the bias changed) their sum is
+    a fourth entry."""
+    flat = jax.tree_util.tree_flatten_with_path(
+        variables.get("moe_stats", {}))[0]
+    is_swaps = lambda path: any(                    # noqa: E731
+        getattr(k, "key", None) == "bias_swaps" for k in path)
+    swaps = [leaf for path, leaf in flat if is_swaps(path)]
+    leaves = [leaf for path, leaf in flat if not is_swaps(path)]
+    if not leaves:
+        return None
+    counts = fold_moe_counts(jnp.stack(leaves))
+    if swaps:
+        counts = jnp.concatenate([counts, jnp.sum(jnp.stack(swaps))[None]])
+    return counts
+
+
+def fold_moe_ticks(counts: jax.Array) -> jax.Array:
+    """:func:`moe_counts` of the ticks of a block, ``[ticks, 3 or 4]``,
+    as one: the fourth entry, where there is one, adds up."""
+    folded = fold_moe_counts(counts[:, :3])
+    if counts.shape[1] == 3:
+        return folded
+    return jnp.concatenate([folded, jnp.sum(counts[:, 3:], axis=0)])
 
 
 # what a model may sow beside its cache: the dropless expert layers' counts
@@ -114,8 +138,9 @@ def paged_kv_page_bytes(model, mcfg, page_size: int,
     pos = jnp.zeros((1, 4), jnp.int32)
     shapes = jax.eval_shape(lambda: probe.init(
         jax.random.PRNGKey(0), ids, positions=pos, ragged_meta=meta))
+    # per-slot state beside the pool is no page's cost
     total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-                for leaf in jax.tree_util.tree_leaves(shapes["cache"]))
+                for leaf in split_cache(shapes["cache"])[0])
     assert total % 2 == 0, total
     return total // 2
 
@@ -319,7 +344,7 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
     from (``sample_rows``, by packed sequence number).  Returns
     ``(sel [max_seqs, V], variables)``."""
     def forward(params, cache, token_ids, positions, kv_lens, page_indices,
-                cu_q_lens, num_seqs, new_kv_dest, sample_rows):
+                cu_q_lens, num_seqs, new_kv_dest, sample_rows, *seq_slot):
         if wq:
             params = dequantize_param_tree(params, native_w8a8=native)
         if unroll:
@@ -330,6 +355,9 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
                 # rows past the last sequence's end are padding
                 "live": (jnp.arange(token_ids.shape[1]) <
                          cu_q_lens[num_seqs[0]])}
+        if seq_slot:
+            # a model with per-slot state: each packed sequence's slot
+            meta["seq_slot"], = seq_slot
         out, vars_ = model.apply(
             {"params": params, "cache": cache}, token_ids,
             positions=positions, mutable=MUTABLE, ragged_meta=meta)
@@ -347,14 +375,17 @@ def build_fused_step(model, *, unroll: bool = False,
     :func:`build_fused_sample_step`.
     ``unroll``: the stored params are scan-stacked and unroll in-jit;
     ``wq``/``native``: they are stored quantized (``native``: int8 dots,
-    no dequantized copy)."""
+    no dequantized copy).  A model with per-slot state beside the pool
+    (:func:`~deepspeed_tpu.inference.paged.state_layers`) takes one
+    argument more, last: ``seq_slot [max_seqs]``, each packed sequence's
+    slot."""
     forward = _fused_forward(model, unroll=unroll, wq=wq, native=native)
 
     def run(params, cache, token_ids, positions, kv_lens, page_indices,
-            cu_q_lens, num_seqs, new_kv_dest, sample_rows):
+            cu_q_lens, num_seqs, new_kv_dest, sample_rows, *seq_slot):
         sel, vars_ = forward(params, cache, token_ids, positions, kv_lens,
                              page_indices, cu_q_lens, num_seqs, new_kv_dest,
-                             sample_rows)
+                             sample_rows, *seq_slot)
         return sel, vars_["cache"], moe_counts(vars_), loop_counts(vars_)
 
     # distinguishable XLA program name ("jit_ragged_fused_step") so
@@ -378,24 +409,31 @@ def build_fused_sample_step(model, *, sampled: bool, sample_base,
     brought yet), a row at -1 from ``token_ids``.  ``sample_slot
     [max_seqs]``, by packed sequence number, is the slot a sequence's
     sampled token is kept under, -1 where it samples nothing (a prompt
-    chunk that is not the last).  Returns the tokens by packed sequence
-    number, the updated ``last_tok``, the cache and the counts.
+    chunk that is not the last).  A model with per-slot state beside the
+    pool takes ``seq_slot [max_seqs]`` next, each packed sequence's slot.
+    Returns the tokens by packed sequence number, the updated
+    ``last_tok``, the cache and the counts.
 
     Two variants compile, as for the decode block: pure-greedy (no sort)
     and per-sequence ``sampled``, which takes the five sampler arrays.  The
     keys are position-keyed as the decode block's are, so a draw is the
     same bits whichever program makes it."""
     forward = _fused_forward(model, unroll=unroll, wq=wq, native=native)
+    stateful = state_layers(model.config) > 0
 
     def run(params, cache, last_tok, token_ids, tok_src, positions,
             kv_lens, page_indices, cu_q_lens, num_seqs, new_kv_dest,
             sample_rows, sample_slot, *sampler):
+        if stateful:                # the slot of each packed sequence
+            seq_slot, sampler = sampler[:1], sampler[1:]
+        else:
+            seq_slot = ()
         token_ids = jnp.where(
             tok_src >= 0, jnp.take(last_tok, jnp.maximum(tok_src, 0)),
             token_ids)
         sel, vars_ = forward(params, cache, token_ids, positions, kv_lens,
                              page_indices, cu_q_lens, num_seqs, new_kv_dest,
-                             sample_rows)
+                             sample_rows, *seq_slot)
         if sampled:
             *configs, seeds = sampler   # do_sample, temperature, top_k, top_p
             sub = position_keys(sample_base, seeds,
@@ -481,7 +519,7 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
         # the full carry returns so the pipelined host path can keep
         # it device-resident across dispatches (no re-upload)
         return (cache, last_tok, pos, active, remaining, toks, mask,
-                None if moe is None else fold_moe_counts(moe),
+                None if moe is None else fold_moe_ticks(moe),
                 None if loop is None else jnp.sum(loop, axis=0))
 
     run.__name__ = run.__qualname__ = "ragged_decode_block"

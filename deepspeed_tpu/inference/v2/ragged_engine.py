@@ -352,6 +352,46 @@ class RaggedInferenceEngineV2:
                     f"tensor-parallel serving: {looped}, and the pool's "
                     "sharding rule knows pages of one slot")
 
+        # layers that keep per-slot state beside the pool and no K/V (the
+        # short convolutions of models/lfm2_moe.py, read off the config's
+        # ``layer_types``).  KVState owns that state as it owns pages;
+        # what would have to snapshot, share or roll it back and cannot
+        # yet is refused by name, not served wrongly
+        self.state_layers = programs.state_layers(mcfg)
+        self._state_attrs = ({"state_layers": self.state_layers}
+                             if self.state_layers else {})
+        if self.state_layers:
+            stateful = (f"{type(model).__name__} keeps per-slot state in "
+                        f"{self.state_layers} of its "
+                        f"{int(mcfg.num_hidden_layers)} layers")
+            if PrefixCacheConfig.coerce(o["prefix_cache"]).enabled:
+                raise NotImplementedError(
+                    f"prefix_cache: {stateful}, and a shared prefix (or its "
+                    "copy-on-write page) brings keys and values only — a "
+                    "sequence that starts behind one needs the state AT "
+                    "the prefix's end, which the index does not keep")
+            spec = SpeculationConfig.coerce(o["speculation"])
+            if spec.mode != "off":
+                raise NotImplementedError(
+                    f"speculation.mode={spec.mode!r}: {stateful}, and the "
+                    "speculative block's rollback rewinds page cursors, "
+                    "not state")
+            if KVTieringConfig.coerce(o["kv_tiering"]).long_context:
+                raise NotImplementedError(
+                    f"kv_tiering.long_context: {stateful}, and the partial-"
+                    "residency scan (inference/v2/long_context.py) runs "
+                    "programs of its own that know keys and values only")
+            if kv_cache_dtype != "none":
+                raise NotImplementedError(
+                    f"kv_cache_dtype={kv_cache_dtype!r}: {stateful}; its "
+                    "pool is full-width (heads of 64 are stored padded to "
+                    "the kernels' 128 lanes, which the quantized pool's "
+                    "kernel does not know)")
+            if self.tp > 1:
+                raise NotImplementedError(
+                    f"tensor-parallel serving: {stateful}, and the cache's "
+                    "sharding rule knows page pools only")
+
         self.page_size = int(page_size)
         self.pages_per_seq = pages_for(max_seq_len, self.page_size)
         if num_pages is None and kv_pool_bytes is not None:
@@ -1017,6 +1057,20 @@ class RaggedInferenceEngineV2:
             # and the exit gate's sums as far as fetches have brought them
             out["loop"] = {**self._loop_attrs, "rows": st.loop_rows,
                            "exit_cdf": list(st.loop_exit_cdf)}
+        if self.state_layers:
+            # a model with layers of two kinds: which keep K/V, which keep
+            # per-slot state, what a sequence's state costs, how its
+            # router scores and which routes its attention takes
+            from deepspeed_tpu.inference.paged import kv_lane_dim
+            out["hybrid"] = {
+                "kv_layers": programs.kv_layers(self.cfg),
+                "state_layers": self.state_layers,
+                "state_bytes_per_seq": self.kv.state_bytes_per_seq,
+                "kv_head_dim_stored": kv_lane_dim(head_dim),
+                "routing": ("sigmoid+bias" if getattr(
+                    self.cfg, "use_expert_bias", False) else "sigmoid"),
+                "bias_swaps": st.moe_bias_swaps,
+                "attention_routes": dict(out["kernels"])}
         # pool pressure: the scale-out router's least-pressure policy
         # reads this (waiting queue + page occupancy, both plain host
         # ints — no device sync)
@@ -1097,11 +1151,16 @@ class RaggedInferenceEngineV2:
     def _record_moe(self, counts, attrs: Dict[str, Any]) -> None:
         """One dispatch's expert-layer counts, as a span of their own in
         the profiler's host plane and in ``serving_stages()['moe']``."""
-        rows, hit, load = (int(v) for v in counts)
+        rows, hit, load, *swaps = (int(v) for v in counts)
         st = self.host_stats
         st.moe_rows += rows
         st.moe_experts_hit += hit
         st.moe_load_max = max(st.moe_load_max, load)
+        if swaps:
+            # a router with a selection bias: tokens whose chosen set is
+            # not the top k of the scores alone
+            st.moe_bias_swaps += swaps[0]
+            attrs = {**attrs, "bias_swaps": swaps[0]}
         with trace.bracket("engine.moe", cat="serving", moe_rows=rows,
                            moe_experts_hit=hit, moe_load_max=load, **attrs):
             pass
@@ -1411,8 +1470,10 @@ class RaggedInferenceEngineV2:
         rows, keys = len(reqs), int(sum(contexts))
         st.dispatches += 1
         st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
+        state = {"state_rows": rows} if self.state_layers else {}
         return st.stage("dispatch", program="decode_block", rows=rows,
-                        ticks=K, key_tokens=keys, **self._pool_counts())
+                        ticks=K, key_tokens=keys, **state,
+                        **self._pool_counts())
 
     def _pool_counts(self) -> Dict[str, int]:
         """What every ``engine.dispatch`` span says of the pool as the
@@ -1421,7 +1482,8 @@ class RaggedInferenceEngineV2:
         token and ``kv_slots`` (passes x layers) a cached token."""
         total = self.num_pages - 1
         return {"pages_in_use": total - self.allocator.free_pages,
-                "pages_total": total, **self._loop_attrs}
+                "pages_total": total, **self._loop_attrs,
+                **self._state_attrs}
 
     # -- the pipelined decode loop (serving host-path tentpole) ----------
 
@@ -2081,6 +2143,9 @@ class RaggedInferenceEngineV2:
         cu_q_lens = np.zeros((S + 1,), np.int32)
         sample_rows = np.zeros((S,), np.int32)
         sample_slot = np.full((S,), -1, np.int32)
+        # each packed sequence's slot: where a model with per-slot state
+        # finds and leaves it (no other model's program takes it)
+        seq_slot = np.zeros((S,), np.int32)
         sampling: List[Tuple[Request, int]] = []
         # what the tick asks of the device, for the dispatch span and
         # the counters: a token at position p attends to p + 1 keys
@@ -2096,6 +2161,7 @@ class RaggedInferenceEngineV2:
             if r.done or r.lc or r.uid in stalled_uids:
                 continue
             self._last_sched[r.slot] = self._sched_seq
+            seq_slot[j] = r.slot
             if r.prefill_done >= r.ctx_len:                 # decode: 1 tok
                 p = min(r.length - 1, self.max_seq_len - 1)
                 if r.pending:
@@ -2152,6 +2218,9 @@ class RaggedInferenceEngineV2:
         arrays = (token_ids[None], tok_src[None], positions[None], kv_lens,
                   page_indices, cu_q_lens, np.asarray([j], np.int32),
                   new_kv_dest, sample_rows, sample_slot)
+        if self.state_layers:
+            arrays += (seq_slot,)
+            counts["state_rows"] = j
         sampled = any(r.do_sample for r, _ in sampling)
         if sampled:
             do_sample = np.zeros((S,), bool)

@@ -80,6 +80,9 @@ class LlamaConfig:
     # OLMoE: a learned RMSNorm over the WHOLE q and the whole k projection
     # (not per head), before the head split and RoPE
     qk_norm: bool = False
+    # LFM2: with ``qk_norm``, the RMSNorm is over EACH HEAD's values (one
+    # scale of ``head_dim`` shared by the heads), after the head split
+    qk_norm_per_head: bool = False
     # serving: "w8a8" makes every Dense consume per-channel int8 kernels
     # natively (dynamic activation quant + int8 MXU dot) — set by the
     # inference engines when quantize_weights engages, never for training
@@ -203,12 +206,19 @@ class LlamaAttention(nn.Module):
                      **_tp_kwargs(cfg, "col"))(x)
         v = nn.Dense(Hkv * Dh, name="v_proj", **qkv,
                      **_tp_kwargs(cfg, "col"))(x)
-        if cfg.qk_norm:
+        per_head = cfg.qk_norm and cfg.qk_norm_per_head
+        if cfg.qk_norm and not per_head:
             q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
 
-        q = q.reshape(B, S, H, Dh).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
+        q = q.reshape(B, S, H, Dh)
+        if per_head:
+            q = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="q_norm")(q)
+        q = q.transpose(0, 2, 1, 3)
+        k = k.reshape(B, S, Hkv, Dh)
+        if per_head:
+            k = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="k_norm")(k)
+        k = k.transpose(0, 2, 1, 3)
         v = v.reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
         rot = int(Dh * cfg.partial_rotary_factor)
         if rot >= Dh:

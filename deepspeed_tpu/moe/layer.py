@@ -43,6 +43,7 @@ from deepspeed_tpu.moe.sharded_moe import (grouped_matmul_route, grouped_moe,
                                            moe_combine, moe_combine_gather,
                                            moe_dispatch, moe_dispatch_gather,
                                            routing_plan, sorted_combine,
+                                           sigmoid_bias_routing,
                                            sorted_dispatch, topk_routing,
                                            topkgating)
 from deepspeed_tpu.utils.sharding import maybe_constrain as _maybe_constrain
@@ -113,6 +114,20 @@ class MoE(nn.Module):
     # and "alltoall" on multi-device meshes (falling back to "einsum"
     # when the expert count does not divide over the expert axis).
     dispatch_impl: str = "auto"
+    # how the router scores: "softmax" (top-k of the logits, softmax
+    # values as weights) or "sigmoid" (DeepSeek-V3 / LFM2-MoE: top-k of
+    # ``sigmoid + expert_bias``, the sigmoids themselves as weights, times
+    # ``routed_scaling_factor``).  ``expert_bias`` is a float32 leaf of
+    # ``params`` that no gradient trains (load balancing moves it); its
+    # first values are drawn at ``expert_bias_init_std``, because under a
+    # zero bias every bias mistake is invisible.  Served only ("grouped")
+    scoring: str = "softmax"
+    expert_bias: bool = False
+    expert_bias_init_std: float = 0.0
+    routed_scaling_factor: float = 1.0
+    # first values of the experts' down-projection ``w2``, as a share of
+    # lecun-normal's (a family's own: ``models/lfm2_moe.py`` says why)
+    down_init_scale: float = 1.0
 
     def _can_alltoall(self, topo, n_tokens: int) -> bool:
         ep = int(topo.mesh.shape.get(EXPERT_AXIS, 1))
@@ -159,6 +174,10 @@ class MoE(nn.Module):
             init = (nn.initializers.zeros_init() if bias else
                     nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                                  batch_axis=(0,)))
+            if name == "w2" and cfg.down_init_scale != 1.0:
+                lecun, scale = init, cfg.down_init_scale
+                init = lambda key, shape, dtype: (    # noqa: E731
+                    scale * lecun(key, shape, jnp.float32)).astype(dtype)
             if any(s is not None for s in spec):
                 init = nn.with_partitioning(init, spec)
             return self.param(name, init, shape, cfg.param_dtype)
@@ -354,6 +373,9 @@ class MoE(nn.Module):
         wg = self.param("gate", nn.initializers.lecun_normal(), (M, E),
                         jnp.float32)
         w = self._expert_params()
+        bias = (self.param(
+            "expert_bias", nn.initializers.normal(cfg.expert_bias_init_std),
+            (E,), jnp.float32) if cfg.expert_bias else None)
 
         noise_rng = None
         if (cfg.noisy_gate_policy == "Jitter" and is_training
@@ -361,13 +383,33 @@ class MoE(nn.Module):
             noise_rng = self.make_rng("gating")
 
         impl = cfg._resolve_dispatch(x.shape[0])
+        sigmoid = cfg.scoring == "sigmoid"
+        if sigmoid and impl != "grouped":
+            raise NotImplementedError(
+                f"scoring='sigmoid' with dispatch_impl={impl!r}: the "
+                "sigmoid router with a selection bias has its dropless "
+                "serving form only (its training form needs the bias's "
+                "balancing update and an auxiliary loss of its own)")
+        assert cfg.scoring in ("softmax", "sigmoid"), cfg.scoring
         if impl == "grouped":
             # float32 for real: a TPU's default f32 matmul is one bf16
             # pass, and a served token's experts are not to turn on that
-            experts, weights, l_aux = topk_routing(
-                jnp.dot(x.astype(jnp.float32), wg,
-                        precision=jax.lax.Precision.HIGHEST),
-                cfg.k, cfg.normalize_weights)
+            logits = jnp.dot(x.astype(jnp.float32), wg,
+                             precision=jax.lax.Precision.HIGHEST)
+            if sigmoid:
+                experts, weights, swaps = sigmoid_bias_routing(
+                    logits, bias, cfg.k, cfg.normalize_weights,
+                    cfg.routed_scaling_factor)
+                l_aux = jnp.zeros((), jnp.float32)
+                if live is not None:
+                    swaps = swaps & live.reshape(-1)
+                if not self.is_initializing():
+                    # tokens whose chosen set the bias changed
+                    self.sow("moe_stats", "bias_swaps",
+                             jnp.sum(swaps.astype(jnp.int32)))
+            else:
+                experts, weights, l_aux = topk_routing(
+                    logits, cfg.k, cfg.normalize_weights)
             y, counts = grouped_moe(
                 x.astype(cfg.dtype), experts, weights, E,
                 lambda rows, gs: self._grouped_ffn(rows, gs, w),

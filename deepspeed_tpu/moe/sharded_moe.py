@@ -396,6 +396,33 @@ def topk_routing(logits: jax.Array, k: int, normalize_weights: bool
     return experts.astype(jnp.int32), weights, l_aux
 
 
+def sigmoid_bias_routing(logits: jax.Array, bias: Optional[jax.Array],
+                         k: int, normalize_weights: bool,
+                         scaling_factor: float = 1.0
+                         ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(experts [G, k] int32, weights [G, k] f32, bias_swaps)`` of a
+    float32 sigmoid router with a per-expert SELECTION bias (DeepSeek-V3's
+    auxiliary-loss-free balancing; LFM2-MoE's ``use_expert_bias``): the
+    scores are ``sigmoid(logits)``, the chosen set the k largest of
+    ``score + bias``, and the weights the chosen SCORES, without the bias,
+    renormalised over the k (``+ 1e-6`` in the denominator) or as they
+    are, times ``scaling_factor``.  ``bias_swaps [G]`` marks the tokens
+    whose chosen set is not the k largest scores: what the bias changed."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, plain = jax.lax.top_k(scores, k)
+    if bias is None:
+        experts = plain
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if normalize_weights:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+    E = logits.shape[-1]
+    chosen = lambda idx: jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.int32), 1)
+    swaps = jnp.any(chosen(experts) != chosen(plain), axis=-1)
+    return experts.astype(jnp.int32), weights * scaling_factor, swaps
+
+
 def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
                 num_experts: int, expert_ffn, live: Optional[jax.Array]
                 ) -> Tuple[jax.Array, jax.Array]:

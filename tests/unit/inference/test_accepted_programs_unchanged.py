@@ -1,0 +1,101 @@
+"""The two engine programs of the three serving configurations the
+benchmark had before LFM2 (``mistral-7b-serve``, ``olmoe-1b-7b-serve``,
+``ouro-2.6b-serve``) lower to the text they lowered to at the parent of
+PR 35, at a tiny size: what state beside pages, sigmoid routing, per-head
+QK-norm and padded heads of 64 added is reached by no model that does not
+ask for it.
+
+The hashes were taken on the parent commit by this file's own
+:func:`lowered_hashes` (``python tests/unit/inference/
+test_accepted_programs_unchanged.py`` prints them).  A change that MEANS to
+move one of these programs re-takes them and says so; JAX's version is part
+of the text, so an upgrade re-takes them too.
+"""
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ENGINE = dict(max_seqs=4, max_seq_len=64, page_size=16, prefill_chunk=16,
+              decode_block_size=4, num_pages=12)
+
+# at the parent of PR 35 (commit 92a6aee), JAX 0.9.0
+EXPECTED = {
+    "mistral.fused": "2fe90b2191f5888e",
+    "mistral.decode_block": "1eb55c041ba3f8f4",
+    "olmoe.fused": "a6b96b00615d0f10",
+    "olmoe.decode_block": "03ce8a62bd7290ce",
+    "ouro.fused": "ef7ae60c7448a035",
+    "ouro.decode_block": "f87646743932c66c",
+}
+
+
+def _models():
+    from deepspeed_tpu.models import mistral, olmoe, ouro
+
+    bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                scan_layers=False, remat=False, use_flash_attention=False)
+    return {
+        # heads of 128, as the configuration's own
+        "mistral": mistral.MistralForCausalLM(mistral.get_config(
+            "tinymistral", hidden_size=256, num_attention_heads=2,
+            num_key_value_heads=1, **bf16)),
+        "olmoe": olmoe.OlmoeForCausalLM(olmoe.get_config(
+            "tinyolmoe", expert_parallel=False, **bf16)),
+        "ouro": ouro.OuroForCausalLM(ouro.get_config("tinyouro", **bf16)),
+    }
+
+
+def _strip(text: str) -> str:
+    text = re.sub(r"loc\(.*?\)\)?", "", text)
+    return "\n".join(l.rstrip() for l in text.splitlines()
+                     if not l.lstrip().startswith("#loc"))
+
+
+def lowered_hashes():
+    from deepspeed_tpu.inference.v2.ragged_engine import \
+        RaggedInferenceEngineV2
+
+    out = {}
+    for name, model in _models().items():
+        params = jax.jit(model.init)(
+            jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))["params"]
+        eng = RaggedInferenceEngineV2(model, params=params, **ENGINE)
+        S, T, pp = eng.max_seqs, eng.T, eng.pages_per_seq
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+        boo = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)
+        fused = eng.fused_sample_step(False).lower(
+            eng.params, eng.kv.cache, i32(S), i32(1, T), i32(1, T),
+            i32(1, T), i32(S), i32(S, pp), i32(S + 1), i32(1), i32(T),
+            i32(S), i32(S))
+        block = eng.decode_block(False).lower(
+            eng.params, eng.kv.cache, i32(S), i32(S), boo(S), i32(S),
+            i32(S, pp), i32(S), boo(S), f32(S), i32(S), f32(S), i32(S),
+            jax.random.PRNGKey(0))
+        for prog, low in (("fused", fused), ("decode_block", block)):
+            out[f"{name}.{prog}"] = hashlib.sha256(
+                _strip(low.as_text()).encode()).hexdigest()[:16]
+        eng.close()
+    return out
+
+
+@pytest.fixture(scope="module")
+def hashes():
+    return lowered_hashes()
+
+
+@pytest.mark.parametrize("program", sorted(EXPECTED))
+def test_program_lowers_to_the_parents_text(hashes, program):
+    assert hashes[program] == EXPECTED[program], (
+        f"{program} no longer lowers to the text it had at the parent of "
+        "PR 35: a model that asked for none of LFM2's mechanisms got one")
+
+
+if __name__ == "__main__":
+    import json
+
+    print(json.dumps(lowered_hashes(), indent=4))

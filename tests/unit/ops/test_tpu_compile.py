@@ -241,6 +241,80 @@ def test_each_program_holds_its_own_attention_kernel(one_chip, chip_compile,
                               "paged_attention_decode": "pallas-decode-4x8"}
 
 
+def test_a_hybrid_models_programs_serve_heads_of_64_from_both_kernels(
+        one_chip, monkeypatch):
+    """The engine's two programs of ``lfm2-24b-a2b-serve`` at its widths
+    and engine sizes, three layers deep (a convolution, attention and a
+    convolution; a dense MLP and two expert layers), compiled for the
+    described chip: heads of 64 lie in the pool zero-padded to 128, so the
+    fused tick holds the upstream ragged kernel and the decode block the
+    decode kernel, one call a K/V LAYER, and neither falls to the dense
+    XLA route; the convolution state is a leaf of its own beside the pool."""
+    import re
+
+    from deepspeed_tpu.inference import paged
+    from deepspeed_tpu.inference.v2 import programs
+    from deepspeed_tpu.models import lfm2_moe
+    from deepspeed_tpu.ops import routes
+
+    monkeypatch.setattr(routes, "on_tpu", lambda: True)
+    monkeypatch.setattr(routes, "_taken", {})
+    max_len = PAGE * ENGINE_PAGES_PER_SEQ
+    slots, width = ENGINE_SLOTS, ENGINE_SLOTS + 512
+    model = lfm2_moe.Lfm2MoeForCausalLM(programs.serving_config(
+        lfm2_moe.get_config(
+            "lfm2-24b-a2b", num_hidden_layers=3, num_dense_layers=1,
+            layer_types=("conv", "full_attention", "conv"),
+            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=False,
+            use_flash_attention=False),
+        max_seq_len=max_len, page_size=PAGE, num_pages=ENGINE_POOL,
+        tensor_parallel=False, kv_cache_dtype="none"))
+    i32 = jnp.int32
+    meta = {"kv_lens": jnp.zeros((slots,), i32),
+            "page_indices": jnp.zeros((slots, ENGINE_PAGES_PER_SEQ), i32),
+            "cu_q_lens": jnp.zeros((slots + 1,), i32),
+            "num_seqs": jnp.zeros((1,), i32),
+            "new_kv_dest": jnp.zeros((width,), i32)}
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, width), i32),
+        positions=jnp.zeros((1, width), i32), ragged_meta=meta))
+    pools, states = paged.split_cache(shapes["cache"])
+    assert [l.shape for l in pools] == [(ENGINE_POOL, PAGE, 16, 128)]
+    assert [l.shape for l in states] == [(slots, 2, 2048)] * 2
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, cache = (jax.tree_util.tree_map(
+        lambda s: spec(s.shape, s.dtype), shapes[k])
+        for k in ("params", "cache"))
+    per_slot = [spec((slots,), d) for d in (i32, i32, jnp.bool_, i32)]
+    table = spec((slots, ENGINE_PAGES_PER_SEQ), i32)
+    sampling = [spec((slots,), d) for d in (
+        i32, jnp.bool_, jnp.float32, i32, jnp.float32, jnp.uint32)]
+    decode = programs.build_decode_block(
+        model, sampled=False, max_seqs=slots, block=8, page_size=PAGE,
+        max_seq_len=max_len, sample_base=jax.random.PRNGKey(0))
+    fused = programs.build_fused_step(model)
+    texts = {
+        "decode": decode.lower(params, cache, *per_slot, table, *sampling,
+                               spec((2,), jnp.uint32)).compile().as_text(),
+        "fused": fused.lower(
+            params, cache, spec((1, width), i32), spec((1, width), i32),
+            spec((slots,), i32), table, spec((slots + 1,), i32),
+            spec((1,), i32), spec((width,), i32), spec((slots,), i32),
+            spec((slots,), i32)).compile().as_text()}
+    calls = {name: (len(re.findall(r"%paged_decode_attention[.\d]* = ", t)),
+                    len(re.findall(
+                        r"%ragged_paged_attention_kernel[.\d]* = ", t)),
+                    len(re.findall(r"%ragged-dot-gmm[.\d]* = ", t)))
+             for name, t in texts.items()}
+    assert calls == {"decode": (1, 0, 6), "fused": (0, 1, 6)}
+    taken = routes.taken()
+    assert taken["paged_attention"] == "pallas-ragged-pad128"
+    assert taken["paged_attention_decode"] == "pallas-decode-4x8"
+
+
 # ouro-2.6b-serve: 16 slots, 16 pages of 64 tokens a sequence, 85 pages of
 # 4 K/V slots each, which both kernels read as a pool of 340
 LOOP_SLOTS, LOOP_PAGES_PER_SEQ, LOOP_STEPS, LOOP_POOL = 16, 16, 4, 85
@@ -382,6 +456,8 @@ GMM_BANKS = [
     pytest.param((8, 14336, 4096), (128, 7168, 256), id="mixtral-down"),
     pytest.param((60, 2048, 1408), (128, 2048, 1408), id="qwen-gate-up"),
     pytest.param((60, 1408, 2048), (128, 1408, 2048), id="qwen-down"),
+    pytest.param((64, 2048, 1536), (128, 2048, 768), id="lfm2-gate-up"),
+    pytest.param((64, 1536, 2048), (128, 1536, 1024), id="lfm2-down"),
 ]
 
 

@@ -38,7 +38,9 @@ def test_paged_attention_paths_name_the_route():
                                                paged_attention_path)
 
     assert paged_attention_path(128) == "xla-dense"        # CPU backend
-    assert paged_attention_path(64) == "xla-dense"
+    # heads of 64 lie in the pool padded to the kernels' 128 lanes
+    assert paged_attention_path(64) == "xla-dense-pad128"
+    assert paged_attention_path(32) == "xla-dense"
     assert kv_dequant_path(128) == "xla-gather"
     assert routes.taken()["paged_attention"] == "xla-dense"
 
