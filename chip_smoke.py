@@ -337,21 +337,26 @@ def serve_phase(plan: Plan) -> Dict[str, Any]:
         "requests": len(plan.prompt_lens),
         "prompt_tokens": list(plan.prompt_lens),
         "new_tokens": plan.new_tokens, "transport": "loopback socket"}
-    from deepspeed_tpu.ops import paged_decode
+    from deepspeed_tpu.ops import paged_decode, paged_prefill
 
-    cfg = plan.mistral
+    cfg, page = plan.mistral, plan.serve["page_size"]
+    heads, kv_heads = cfg.num_attention_heads, cfg.num_key_value_heads
     tiles = paged_decode.tiling(
-        cfg.num_key_value_heads, plan.serve["page_size"],
-        -(-plan.serve["max_seq_len"] // plan.serve["page_size"]),
+        kv_heads, page, -(-plan.serve["max_seq_len"] // page),
         cfg.head_dim, cfg.dtype)
-    # the decode blocks take the decode kernel wherever its layout holds
+    chunk_tiles = tiles and paged_prefill.tiling(
+        heads, kv_heads, page, cfg.head_dim, cfg.dtype)
+    # the decode blocks take the decode kernel and the fused ticks the
+    # chunk kernel wherever their layouts hold
     want = ({"bf16": "pallas-ragged", "int8": "pallas-quant",
-             "bf16_decode": (paged_decode.route_name(
-                 tiles, cfg.num_key_value_heads)
-                 if tiles else "pallas-ragged")}
+             "bf16_decode": (paged_decode.route_name(tiles, kv_heads)
+                             if tiles else "pallas-ragged"),
+             "bf16_prefill": (paged_prefill.route_name(
+                 chunk_tiles, heads, kv_heads, page)
+                 if chunk_tiles else "pallas-ragged")}
             if plan.on_chip else
             {"bf16": "xla-dense", "int8": "xla-gather",
-             "bf16_decode": "xla-dense"})
+             "bf16_decode": "xla-dense", "bf16_prefill": "xla-dense"})
     routes: Dict[str, str] = {}
 
     # bf16 pool, the path of `examples/serve_llama.py --listen`
@@ -394,6 +399,7 @@ def serve_phase(plan: Plan) -> Dict[str, Any]:
         kernels = eng.serving_stages()["kernels"]
         routes["bf16"] = kernels["paged_attention"]
         routes["bf16_decode"] = kernels.get(paged_decode.KERNEL)
+        routes["bf16_prefill"] = kernels.get(paged_prefill.KERNEL)
         out["logits_max_abs_diff_vs_plain_apply"] = round(_logits_parity(
             plan, eng, model,
             _prompts(plan, (plan.parity_prompt_len,))[0]), 5)

@@ -15,7 +15,9 @@ on TPU, and :func:`ref_paged_attention` — an XLA-compilable, mask-based
 equivalent of the kernel's reference math — everywhere else (CPU tests).
 A decode program, which has one query row a sequence slot and says so in
 its ``ragged_meta``, takes ``ops/paged_decode.py`` instead wherever that
-kernel's layout holds.
+kernel's layout holds; a fused tick, which says so too, takes
+``ops/paged_prefill.py`` for its prompt chunks and the decode kernel for
+its one-query rows.
 The page allocator is host-side Python, like the reference's scheduler
 tier.
 
@@ -58,6 +60,7 @@ import numpy as np
 
 from deepspeed_tpu.ops import routes
 from deepspeed_tpu.ops.paged_decode import paged_decode_attention
+from deepspeed_tpu.ops.paged_prefill import fused_tick_attention
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
 
 TRASH_PAGE = 0
@@ -798,10 +801,22 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     # otherwise, as for every other program, the call in attend_ragged
     one_query_a_slot = (ragged_meta.get("one_query_a_slot") is True
                         and carry is None and qmax is None)
+    # so does a fused tick (programs._fused_forward: decode rows and
+    # prompt chunks packed by ``cu_q_lens``): its chunks are the chunk
+    # kernel's and its one-query rows the decode kernel's, under the same
+    # rule.  Every other caller (the speculative verify, the chunk scan)
+    # keeps the call in attend_ragged
+    fused_tick = (ragged_meta.get("fused_tick") is True
+                  and carry is None and qmax is None)
 
     def attend(qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
                live=None):
         ragged = (qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs)
+        if fused_tick:
+            return fused_tick_attention(
+                *ragged, sm_scale=sm_scale, sliding_window=window,
+                fallback=lambda: attend_ragged(*ragged),
+                fallback_route=paged_attention_path(head_dim))
         if not one_query_a_slot:
             return attend_ragged(*ragged)
         return paged_decode_attention(

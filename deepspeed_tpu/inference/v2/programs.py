@@ -352,6 +352,11 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
         meta = {"kv_lens": kv_lens, "page_indices": page_indices,
                 "cu_q_lens": cu_q_lens, "num_seqs": num_seqs,
                 "new_kv_dest": new_kv_dest,
+                # static: decode rows and prompt chunks packed by
+                # ``cu_q_lens``, which is what inference/paged.py picks
+                # the chunk kernel (and the decode kernel for the
+                # one-query rows) by
+                "fused_tick": True,
                 # rows past the last sequence's end are padding
                 "live": (jnp.arange(token_ids.shape[1]) <
                          cu_q_lens[num_seqs[0]])}

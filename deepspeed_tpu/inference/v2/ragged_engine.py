@@ -1039,10 +1039,12 @@ class RaggedInferenceEngineV2:
             route = paged_attention_path(head_dim)
         # the attention read route this backend gives this pool: a Pallas
         # kernel, or the XLA twin it stands in for (ops/routes.py)
-        from deepspeed_tpu.ops import paged_decode, routes
+        from deepspeed_tpu.ops import paged_decode, paged_prefill, routes
 
-        # and what each decode program traced so far took for its own
+        # and what each fused tick and each decode program traced so far
+        # took for its own
         out["kernels"] = {"paged_attention": route,
+                          **routes.taken(prefix=paged_prefill.KERNEL),
                           **routes.taken(prefix=paged_decode.KERNEL)}
         st = self.host_stats
         if st.moe_rows:

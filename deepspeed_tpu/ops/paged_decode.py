@@ -125,18 +125,27 @@ def work_list(kv_lens: jax.Array, live: jax.Array, *, page_size: int,
     first_page = first_key // page_size
     end_page = jnp.minimum(-(-kv_lens // page_size), pages_per_seq)
     pages = jnp.where(live, jnp.maximum(end_page - first_page, 0), 0)
-    groups = -(-pages // group)
-    ends = jnp.cumsum(groups)
-    starts = ends - groups
-    w = jnp.arange(S * -(-pages_per_seq // group), dtype=jnp.int32)
-    slot = jnp.minimum(
-        jnp.sum((ends[None, :] <= w[:, None]).astype(jnp.int32), axis=1),
-        S - 1)
-    g = w - starts[slot]
-    flags = (g == 0).astype(jnp.int32) + 2 * (g == groups[slot] - 1)
+    slot, g, flags, n_work = flat_list(-(-pages // group),
+                                       S * -(-pages_per_seq // group))
     return ((slot, first_page[slot] + g * group,
-             jnp.clip(pages[slot] - g * group, 0, group), flags),
-            ends[-1:])
+             jnp.clip(pages[slot] - g * group, 0, group), flags), n_work)
+
+
+def flat_list(counts: jax.Array, width: int):
+    """``(owner, index, flags) [width], n [1]``: one entry for each of
+    ``counts[s]`` items of every owner ``s``, in owner order: whose it is,
+    which of its owner's it is, and ``flags`` bit 0 on an owner's first
+    item, bit 1 on its last.  ``n`` is how many there are; entries past it
+    are never to be read."""
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    w = jnp.arange(width, dtype=jnp.int32)
+    owner = jnp.minimum(
+        jnp.sum((ends[None, :] <= w[:, None]).astype(jnp.int32), axis=1),
+        counts.shape[0] - 1)
+    index = w - starts[owner]
+    flags = (index == 0).astype(jnp.int32) + 2 * (index == counts[owner] - 1)
+    return owner, index, flags, ends[-1:]
 
 
 def _kernel(slot_ref, page0_ref, count_ref, flags_ref, nwork_ref,

@@ -192,6 +192,7 @@ class TestDecodeKernelSelection:
         assert interpreter() == "pallas-decode-16x8-interpret"
         kernels = eng.serving_stages()["kernels"]
         assert kernels == {"paged_attention": "xla-dense",
+                           "paged_attention_prefill": "xla-dense",
                            "paged_attention_decode":
                                "pallas-decode-16x8-interpret"}
         from deepspeed_tpu.inference import paged

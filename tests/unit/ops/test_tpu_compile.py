@@ -148,6 +148,10 @@ def test_upstream_ragged_paged_kernel_bf16_pages(chip_compile, heads):
 # the engine sizes of both serving configurations: 32 slots, 64 pages of 64
 # tokens a sequence, a pool of 1,280 pages
 ENGINE_SLOTS, ENGINE_PAGES_PER_SEQ, ENGINE_POOL = 32, 64, 1280
+# the attention calls a compiled program may hold, by their names in its
+# text: the decode kernel, the chunk kernel, upstream's ragged kernel
+ATTENTION_KERNELS = ("paged_decode_attention", "paged_chunk_attention",
+                     "ragged_paged_attention_kernel")
 
 
 @pytest.mark.parametrize("heads,window,tiles", [
@@ -174,14 +178,46 @@ def test_decode_kernel_at_the_serving_configurations(chip_compile, heads,
     assert pd.NAME == "paged_decode_attention" and f"%{pd.NAME}." in text
 
 
+@pytest.mark.parametrize("heads,window,slots,pages_per_seq,pool,tiles", [
+    pytest.param((32, 8), WINDOW, ENGINE_SLOTS, ENGINE_PAGES_PER_SEQ,
+                 ENGINE_POOL, (128, 8), id="mistral-8kv"),
+    pytest.param((16, 16), None, ENGINE_SLOTS, ENGINE_PAGES_PER_SEQ,
+                 ENGINE_POOL, (256, 8), id="olmoe-16kv"),
+    pytest.param((16, 16), None, 16, 16, 85 * 4, (256, 8),
+                 id="ouro-16kv-looped-pool"),
+    pytest.param((32, 8), None, ENGINE_SLOTS, ENGINE_PAGES_PER_SEQ,
+                 ENGINE_POOL, (128, 8), id="lfm2-8kv-heads-padded-to-128")])
+def test_chunk_kernel_at_the_serving_configurations(
+        chip_compile, heads, window, slots, pages_per_seq, pool, tiles):
+    """``ops/paged_prefill.py`` at the tiling its rule gives for a fused
+    tick of ``slots`` + 512 rows, under the VMEM it reckons for itself,
+    and the name a device trace will show for it."""
+    from deepspeed_tpu.ops import paged_prefill as pp
+
+    h, hkv = heads
+    width = slots + 512
+    assert pp.tiling(h, hkv, PAGE, D, jnp.bfloat16) == tiles
+    assert pp.vmem_bytes(width, h, hkv, PAGE, D, tiles) < pp._VMEM_CEILING
+    text = chip_compile(
+        functools.partial(pp._chunk, sm_scale=D ** -0.5,
+                          sliding_window=window, tiles=tiles,
+                          interpret=False),
+        ((h, width, D), jnp.bfloat16),
+        ((pool, PAGE, 2 * hkv, D), jnp.bfloat16), ((slots,), jnp.int32),
+        ((slots, pages_per_seq), jnp.int32), ((slots + 1,), jnp.int32),
+        ((1,), jnp.int32))
+    assert pp.NAME == "paged_chunk_attention" and f"%{pp.NAME}." in text
+
+
 def test_each_program_holds_its_own_attention_kernel(one_chip, chip_compile,
                                                      monkeypatch):
     """The engine's two programs at ``mistral-7b-serve``'s widths and engine
     sizes, two layers deep, compiled for the described chip: the decode
-    block holds one ``paged_decode_attention`` call a layer and no
-    ``ragged_paged_attention_kernel``, the fused tick the reverse.  The
-    switches ask for the backend and see the CPU here, so the test answers
-    for them."""
+    block holds one ``paged_decode_attention`` call a layer, the fused tick
+    one ``paged_chunk_attention`` call a layer for its chunks and one
+    ``paged_decode_attention`` for its one-query rows, and neither holds
+    upstream's ``ragged_paged_attention_kernel``.  The switches ask for
+    the backend and see the CPU here, so the test answers for them."""
     import re
 
     from deepspeed_tpu.inference.v2 import programs
@@ -232,12 +268,13 @@ def test_each_program_holds_its_own_attention_kernel(one_chip, chip_compile,
             spec((slots,), i32), table, spec((slots + 1,), i32),
             spec((1,), i32), spec((width,), i32),
             spec((slots,), i32)).compile().as_text()}
-    calls = {name: (len(re.findall(r"%paged_decode_attention[.\d]* = ", t)),
-                    len(re.findall(
-                        r"%ragged_paged_attention_kernel[.\d]* = ", t)))
+    calls = {name: tuple(len(re.findall(rf"%{kernel}[.\d]* = ", t))
+                         for kernel in ATTENTION_KERNELS)
              for name, t in texts.items()}
-    assert calls == {"decode": (layers, 0), "fused": (0, layers)}
+    assert calls == {"decode": (layers, 0, 0), "fused": (layers, layers, 0)}
     assert routes.taken() == {"paged_attention": "pallas-ragged",
+                              "paged_attention_prefill":
+                                  "pallas-chunk-512x512",
                               "paged_attention_decode": "pallas-decode-4x8"}
 
 
@@ -247,9 +284,10 @@ def test_a_hybrid_models_programs_serve_heads_of_64_from_both_kernels(
     and engine sizes, three layers deep (a convolution, attention and a
     convolution; a dense MLP and two expert layers), compiled for the
     described chip: heads of 64 lie in the pool zero-padded to 128, so the
-    fused tick holds the upstream ragged kernel and the decode block the
-    decode kernel, one call a K/V LAYER, and neither falls to the dense
-    XLA route; the convolution state is a leaf of its own beside the pool."""
+    fused tick holds the chunk kernel (and the decode kernel for its
+    one-query rows) and the decode block the decode kernel, one call a K/V
+    LAYER, and neither falls to upstream's kernel or the dense XLA route;
+    the convolution state is a leaf of its own beside the pool."""
     import re
 
     from deepspeed_tpu.inference import paged
@@ -304,14 +342,13 @@ def test_a_hybrid_models_programs_serve_heads_of_64_from_both_kernels(
             spec((slots,), i32), table, spec((slots + 1,), i32),
             spec((1,), i32), spec((width,), i32), spec((slots,), i32),
             spec((slots,), i32)).compile().as_text()}
-    calls = {name: (len(re.findall(r"%paged_decode_attention[.\d]* = ", t)),
-                    len(re.findall(
-                        r"%ragged_paged_attention_kernel[.\d]* = ", t)),
-                    len(re.findall(r"%ragged-dot-gmm[.\d]* = ", t)))
+    calls = {name: tuple(len(re.findall(rf"%{kernel}[.\d]* = ", t))
+                         for kernel in ATTENTION_KERNELS + ("ragged-dot-gmm",))
              for name, t in texts.items()}
-    assert calls == {"decode": (1, 0, 6), "fused": (0, 1, 6)}
+    assert calls == {"decode": (1, 0, 0, 6), "fused": (1, 1, 0, 6)}
     taken = routes.taken()
     assert taken["paged_attention"] == "pallas-ragged-pad128"
+    assert taken["paged_attention_prefill"] == "pallas-chunk-512x512"
     assert taken["paged_attention_decode"] == "pallas-decode-4x8"
 
 
@@ -359,8 +396,8 @@ def test_a_looped_models_programs_hold_each_layer_once(one_chip,
                                                        monkeypatch):
     """The engine's two programs at ``ouro-2.6b-serve``'s widths and engine
     sizes, two layers deep and four passes a token, compiled for the
-    described chip: one attention call a LAYER (the pass loop is a
-    ``while`` of the program), each on its own route, and the pool one
+    described chip: one attention call of a kind a LAYER (the pass loop is
+    a ``while`` of the program), each on its own route, and the pool one
     buffer of ``[P, U, page, 2 * Hkv, D]`` a layer."""
     import re
 
@@ -413,12 +450,13 @@ def test_a_looped_models_programs_hold_each_layer_once(one_chip,
             spec((slots,), i32), table, spec((slots + 1,), i32),
             spec((1,), i32), spec((width,), i32),
             spec((slots,), i32)).compile().as_text()}
-    calls = {name: (len(re.findall(r"%paged_decode_attention[.\d]* = ", t)),
-                    len(re.findall(
-                        r"%ragged_paged_attention_kernel[.\d]* = ", t)))
+    calls = {name: tuple(len(re.findall(rf"%{kernel}[.\d]* = ", t))
+                         for kernel in ATTENTION_KERNELS)
              for name, t in texts.items()}
-    assert calls == {"decode": (layers, 0), "fused": (0, layers)}
+    assert calls == {"decode": (layers, 0, 0), "fused": (layers, layers, 0)}
     assert routes.taken() == {"paged_attention": "pallas-ragged",
+                              "paged_attention_prefill":
+                                  "pallas-chunk-256x512",
                               "paged_attention_decode": "pallas-decode-2x16"}
 
 

@@ -69,6 +69,11 @@ def test_one_chip_phases_and_last_line(chip_smoke, capsys, monkeypatch,
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     before = jax.config.jax_compilation_cache_dir
     plan = _tiny_plan(chip_smoke)
+    # the record of routes is the process's: whatever this worker's earlier
+    # files took (heads of 64, say) is not this run's
+    from deepspeed_tpu.ops import routes
+
+    monkeypatch.setattr(routes, "_taken", {})
     # one device, as the driver's one-chip machine has
     monkeypatch.setattr(jax, "devices", lambda *a: jax.local_devices()[:1])
     assert chip_smoke.run(plan, chips=1) == 0
@@ -79,7 +84,8 @@ def test_one_chip_phases_and_last_line(chip_smoke, capsys, monkeypatch,
         train["losses"][0]
     assert serve["phase"] == "serve" and serve["compiles_after_warmup"] == 0
     assert serve["routes"] == {"bf16": "xla-dense", "int8": "xla-gather",
-                               "bf16_decode": "xla-dense"}
+                               "bf16_decode": "xla-dense",
+                               "bf16_prefill": "xla-dense"}
     assert serve["streamed"] == 2
     assert cache["phase"] == "compile_cache" and cache["dir"] == \
         str(tmp_path)
