@@ -74,9 +74,11 @@ def moe_counts(variables) -> Optional[jax.Array]:
     leaves = [leaf for path, leaf in flat if not is_swaps(path)]
     if not leaves:
         return None
-    counts = fold_moe_counts(jnp.stack(leaves))
-    if swaps:
-        counts = jnp.concatenate([counts, jnp.sum(jnp.stack(swaps))[None]])
+    with jax.named_scope("counts"):
+        counts = fold_moe_counts(jnp.stack(leaves))
+        if swaps:
+            counts = jnp.concatenate(
+                [counts, jnp.sum(jnp.stack(swaps))[None]])
     return counts
 
 
@@ -439,17 +441,19 @@ def build_fused_sample_step(model, *, sampled: bool, sample_base,
         sel, vars_ = forward(params, cache, token_ids, positions, kv_lens,
                              page_indices, cu_q_lens, num_seqs, new_kv_dest,
                              sample_rows, *seq_slot)
-        if sampled:
-            *configs, seeds = sampler   # do_sample, temperature, top_k, top_p
-            sub = position_keys(sample_base, seeds,
-                                jnp.take(positions[0], sample_rows))
-        else:
-            configs, sub = [None] * 4, None
-        toks = sample_logits_batched(sel, sub, *configs)
-        # a sequence that samples nothing is scattered nowhere
-        last_tok = last_tok.at[
-            jnp.where(sample_slot >= 0, sample_slot, last_tok.shape[0])
-        ].set(toks, mode="drop")
+        with jax.named_scope("sample"):
+            if sampled:
+                # do_sample, temperature, top_k, top_p
+                *configs, seeds = sampler
+                sub = position_keys(sample_base, seeds,
+                                    jnp.take(positions[0], sample_rows))
+            else:
+                configs, sub = [None] * 4, None
+            toks = sample_logits_batched(sel, sub, *configs)
+            # a sequence that samples nothing is scattered nowhere
+            last_tok = last_tok.at[
+                jnp.where(sample_slot >= 0, sample_slot, last_tok.shape[0])
+            ].set(toks, mode="drop")
         return (toks, last_tok, vars_["cache"], moe_counts(vars_),
                 loop_counts(vars_))
 
@@ -498,15 +502,16 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
                 mutable=MUTABLE, ragged_meta=meta)
             logits = logits_of(out)[0]              # [S, V]
             moe, loop = moe_counts(vars_), loop_counts(vars_)
-            rng, _ = jax.random.split(rng)
-            # position-keyed per-row keys: the draw at cache position
-            # `pos` is the same bits the fused tick would draw there,
-            # no matter how this block was scheduled
-            sub = (position_keys(sample_base, seeds, pos)
-                   if sampled else None)
-            nxt = sample_logits_batched(
-                logits, sub, do_sample,
-                temperature, top_k, top_p)
+            with jax.named_scope("sample"):
+                rng, _ = jax.random.split(rng)
+                # position-keyed per-row keys: the draw at cache position
+                # `pos` is the same bits the fused tick would draw there,
+                # no matter how this block was scheduled
+                sub = (position_keys(sample_base, seeds, pos)
+                       if sampled else None)
+                nxt = sample_logits_batched(
+                    logits, sub, do_sample,
+                    temperature, top_k, top_p)
             produced = active
             nxt = jnp.where(active, nxt, last_tok)
             hit_eos = active & (nxt == eos_ids)
@@ -688,9 +693,10 @@ def build_spec_block(model, draft, *, sampled: bool, mode: str, k: int,
                 mutable=["cache"], ragged_meta=meta)
             cache = vars_["cache"]
             logits = logits_of(out)[0].reshape(S, K1, -1)
-            out_toks, acc = speculative_verify(
-                logits, d_toks, q_probs, key_v if sampled else None,
-                do_sample, temperature, top_k, top_p)
+            with jax.named_scope("sample"):
+                out_toks, acc = speculative_verify(
+                    logits, d_toks, q_probs, key_v if sampled else None,
+                    do_sample, temperature, top_k, top_p)
             # ---- emission clamp: budget, max_len, first eos ----
             emit = jnp.minimum(acc + 1, remaining)
             emit = jnp.minimum(emit, max_len - 1 - pos)

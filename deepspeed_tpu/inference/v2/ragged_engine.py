@@ -115,7 +115,8 @@ import numpy as np
 
 from deepspeed_tpu.inference.common import HostStageStats
 from deepspeed_tpu.resilience import faults
-from deepspeed_tpu.telemetry import RequestLatencyTracker, trace
+from deepspeed_tpu.telemetry import (RequestLatencyTracker, device_scopes,
+                                     trace)
 from deepspeed_tpu.utils.async_stage import BoundedAsyncStage, StageTimers
 from deepspeed_tpu.inference.paged import pages_for
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
@@ -494,6 +495,7 @@ class RaggedInferenceEngineV2:
         self._draft_prefill = None
         self._decode_block_cache: Dict[bool, Any] = {}
         self._spec_block_cache: Dict[bool, Any] = {}
+        self._scoped: set = set()       # programs device_scopes knows
 
         # -- every form of a sequence's KV state: pool, tiers, index --
         tier_cfg = KVTieringConfig.coerce(o["kv_tiering"])
@@ -938,6 +940,8 @@ class RaggedInferenceEngineV2:
 
         def _set_block(v):
             self.decode_block_size = max(int(v), 1)
+            self._scoped.difference_update(
+                self._decode_block_cache.values())
             self._decode_block_cache.clear()
 
         reg.register(Knob(
@@ -958,6 +962,8 @@ class RaggedInferenceEngineV2:
         if self.spec_mode != "off":
             def _set_spec_k(v):
                 self.spec_k = max(int(v), 1)
+                self._scoped.difference_update(
+                    self._spec_block_cache.values())
                 self._spec_block_cache.clear()
 
             reg.register(Knob(
@@ -1226,6 +1232,17 @@ class RaggedInferenceEngineV2:
                 sample_base=self._sample_base, **self.param_form)
         return self._decode_block_cache[sampled]
 
+    def _run(self, fn, *args):
+        """Dispatch a compiled program.  The first dispatch of each notes
+        it with ``telemetry.device_scopes`` (its name in a trace and the
+        avals it is about to compile for), so device time can be joined to
+        layer kinds afterwards; every later one pays a membership test."""
+        if fn not in self._scoped:
+            self._scoped.add(fn)
+            device_scopes.register(getattr(fn, "__name__", "program"), fn,
+                                   args)
+        return fn(*args)
+
     def _block_arrays(self, reqs: List[Request]):
         """Host numpy decode-block state for ``reqs`` (shared by the
         unpipelined per-block rebuild and the pipelined loop's one-time
@@ -1336,8 +1353,8 @@ class RaggedInferenceEngineV2:
                         np.maximum(pg, 0) * page + pos % page,
                         0).astype(np.int32)
                     st.meta_uploads += 6
-                    self.kv.draft_cache = fn(
-                        self._draft_params, self.kv.draft_cache,
+                    self.kv.draft_cache = self._run(
+                        fn, self._draft_params, self.kv.draft_cache,
                         jnp.asarray(ids[None]), jnp.asarray(posc[None]),
                         jnp.asarray([lo + take], jnp.int32),
                         jnp.asarray(self.page_table[r.slot][None]),
@@ -1384,7 +1401,8 @@ class RaggedInferenceEngineV2:
             st.dispatches += 1
             st.spec_dispatches += 1
             (self.kv.cache, self.kv.draft_cache, _h, new_last, _p, _a, _r,
-             toks, mask, prop, accd) = self.spec_block(sampled)(
+             toks, mask, prop, accd) = self._run(
+                self.spec_block(sampled),
                 self.params, self._draft_params, self.kv.cache,
                 self.kv.draft_cache, *args, sub)
         st.ticks += self.decode_block_size
@@ -1424,7 +1442,8 @@ class RaggedInferenceEngineV2:
                         ticks=self.decode_block_size)
         with self._decode_dispatch(reqs, [r.length for r in reqs]):
             (cache, new_last, _pos, _active, _remaining, toks, mask,
-             moe, loop) = self.decode_block(sampled)(
+             moe, loop) = self._run(
+                self.decode_block(sampled),
                 self.params, self.kv.cache, *args, sub)
         self.kv.cache = cache
         self._counts_pending += self._count_entries(
@@ -1616,7 +1635,8 @@ class RaggedInferenceEngineV2:
                 (self.kv.cache, self.kv.draft_cache, dv["hist"],
                  dv["last_tok"], dv["pos"], dv["active"],
                  dv["remaining"], toks, mask, prop,
-                 accd) = self.spec_block(dv["sampled"])(
+                 accd) = self._run(
+                    self.spec_block(dv["sampled"]),
                     self.params, self._draft_params, self.kv.cache,
                     self.kv.draft_cache, dv["hist"], dv["last_tok"],
                     dv["pos"], dv["active"], dv["remaining"],
@@ -1628,7 +1648,8 @@ class RaggedInferenceEngineV2:
                     live, [dv["plen"][r.slot] for r in live]):
                 (self.kv.cache, dv["last_tok"], dv["pos"], dv["active"],
                  dv["remaining"], toks, mask, moe,
-                 loop) = self.decode_block(dv["sampled"])(
+                 loop) = self._run(
+                    self.decode_block(dv["sampled"]),
                     self.params, self.kv.cache, dv["last_tok"], dv["pos"],
                     dv["active"], dv["remaining"], dv["page_table"],
                     dv["eos_ids"], dv["do_sample"], dv["temperature"],
@@ -1877,7 +1898,8 @@ class RaggedInferenceEngineV2:
                       **self._pool_counts()):
             st.dispatches += 1
             toks, self._dev_last_tok, self.kv.cache, moe, loop = \
-                self.fused_sample_step(sampled)(
+                self._run(
+                    self.fused_sample_step(sampled),
                     self.params, self.kv.cache, self._dev_last_tok, *args)
         st.ticks += 1
         st.fused_ticks += 1

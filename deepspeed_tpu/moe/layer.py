@@ -394,22 +394,23 @@ class MoE(nn.Module):
         if impl == "grouped":
             # float32 for real: a TPU's default f32 matmul is one bf16
             # pass, and a served token's experts are not to turn on that
-            logits = jnp.dot(x.astype(jnp.float32), wg,
-                             precision=jax.lax.Precision.HIGHEST)
-            if sigmoid:
-                experts, weights, swaps = sigmoid_bias_routing(
-                    logits, bias, cfg.k, cfg.normalize_weights,
-                    cfg.routed_scaling_factor)
-                l_aux = jnp.zeros((), jnp.float32)
-                if live is not None:
-                    swaps = swaps & live.reshape(-1)
-                if not self.is_initializing():
-                    # tokens whose chosen set the bias changed
-                    self.sow("moe_stats", "bias_swaps",
-                             jnp.sum(swaps.astype(jnp.int32)))
-            else:
-                experts, weights, l_aux = topk_routing(
-                    logits, cfg.k, cfg.normalize_weights)
+            with jax.named_scope("moe.route"):
+                logits = jnp.dot(x.astype(jnp.float32), wg,
+                                 precision=jax.lax.Precision.HIGHEST)
+                if sigmoid:
+                    experts, weights, swaps = sigmoid_bias_routing(
+                        logits, bias, cfg.k, cfg.normalize_weights,
+                        cfg.routed_scaling_factor)
+                    l_aux = jnp.zeros((), jnp.float32)
+                    if live is not None:
+                        swaps = swaps & live.reshape(-1)
+                    if not self.is_initializing():
+                        # tokens whose chosen set the bias changed
+                        self.sow("moe_stats", "bias_swaps",
+                                 jnp.sum(swaps.astype(jnp.int32)))
+                else:
+                    experts, weights, l_aux = topk_routing(
+                        logits, cfg.k, cfg.normalize_weights)
             y, counts = grouped_moe(
                 x.astype(cfg.dtype), experts, weights, E,
                 lambda rows, gs: self._grouped_ffn(rows, gs, w),
@@ -421,10 +422,13 @@ class MoE(nn.Module):
                 self.sow("moe_stats", "counts", counts)
             return y.reshape(orig_shape), l_aux
         if impl == "alltoall":
-            y, l_aux = self._alltoall_moe(x, wg, w, noise_rng, is_training)
+            with jax.named_scope("moe.alltoall"):
+                y, l_aux = self._alltoall_moe(x, wg, w, noise_rng,
+                                              is_training)
             return y.reshape(orig_shape), l_aux.astype(jnp.float32)
 
-        gr, _ = self._gate(x, wg, noise_rng, is_training)
+        with jax.named_scope("moe.route"):
+            gr, _ = self._gate(x, wg, noise_rng, is_training)
 
         ep = EXPERT_AXIS if cfg.expert_parallel else None
 
@@ -432,26 +436,30 @@ class MoE(nn.Module):
         # expert axis is the reference's first all-to-all (_AllToAll fwd)
         x_d = x.astype(cfg.dtype)      # one cast shared by all impls
         plan = None
-        if impl == "gather":
-            disp = moe_dispatch_gather(x_d, gr, cfg.num_experts)
-        elif impl == "einsum":
-            disp = moe_dispatch(x_d, gr.dispatch.astype(cfg.dtype))
-        elif impl == "sorted":
-            plan = routing_plan(gr, cfg.num_experts)
-            disp = sorted_dispatch(x_d, plan.slot_token, plan.slot_of_copy)
-        else:
-            raise ValueError(f"unknown dispatch_impl {impl!r}")
-        disp = _maybe_constrain(disp, (ep, None, None))
+        with jax.named_scope("moe.dispatch"):
+            if impl == "gather":
+                disp = moe_dispatch_gather(x_d, gr, cfg.num_experts)
+            elif impl == "einsum":
+                disp = moe_dispatch(x_d, gr.dispatch.astype(cfg.dtype))
+            elif impl == "sorted":
+                plan = routing_plan(gr, cfg.num_experts)
+                disp = sorted_dispatch(x_d, plan.slot_token,
+                                       plan.slot_of_copy)
+            else:
+                raise ValueError(f"unknown dispatch_impl {impl!r}")
+            disp = _maybe_constrain(disp, (ep, None, None))
 
-        out = self._expert_ffn(disp, w)
+        with jax.named_scope("moe.experts"):
+            out = self._expert_ffn(disp, w)
 
-        out = _maybe_constrain(out, (ep, None, None))
-        # combine: [E, C, M] -> [G, M] (the second all-to-all)
-        if impl == "gather":
-            y = moe_combine_gather(out, gr)
-        elif impl == "sorted":
-            y = sorted_combine(out, gr.weights, plan.slot_token,
-                               plan.slot_of_copy)
-        else:
-            y = moe_combine(out, gr.combine.astype(cfg.dtype))
+        with jax.named_scope("moe.combine"):
+            out = _maybe_constrain(out, (ep, None, None))
+            # combine: [E, C, M] -> [G, M] (the second all-to-all)
+            if impl == "gather":
+                y = moe_combine_gather(out, gr)
+            elif impl == "sorted":
+                y = sorted_combine(out, gr.weights, plan.slot_token,
+                                   plan.slot_of_copy)
+            else:
+                y = moe_combine(out, gr.combine.astype(cfg.dtype))
         return y.reshape(orig_shape), gr.l_aux.astype(jnp.float32)

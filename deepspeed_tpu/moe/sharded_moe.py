@@ -437,20 +437,27 @@ def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
     """
     G, k = experts.shape
     E = num_experts
-    ec = experts.reshape(-1)                          # copy = token * k + j
-    if live is not None:
-        # a dead row's copies sort past every expert and join no group
-        ec = jnp.where(jnp.repeat(live, k), ec, E)
-    order = jnp.argsort(ec, stable=True)              # sorted pos -> copy
-    group_sizes = jnp.sum(jax.nn.one_hot(ec, E, dtype=jnp.int32), axis=0)
-    out = expert_ffn(jnp.take(x, order // k, axis=0), group_sizes)
-    # combine by gather: copy -> its row of the sorted buffer
-    rows = jnp.take(out, jnp.argsort(order), axis=0).reshape(G, k, -1)
-    y = jnp.sum(weights.astype(out.dtype)[..., None] * rows, axis=1)
-    if live is not None:
-        # rows past the last group are whatever the kernel left there
-        y = jnp.where(live[:, None], y, 0)
-    counts = jnp.stack([jnp.sum(group_sizes),
-                        jnp.sum((group_sizes > 0).astype(jnp.int32)),
-                        jnp.max(group_sizes)])
+    # the scopes name the four stages for ``telemetry/device_scopes.py``:
+    # this is a plain function, so no module path tells them apart
+    with jax.named_scope("moe.dispatch"):
+        ec = experts.reshape(-1)                      # copy = token * k + j
+        if live is not None:
+            # a dead row's copies sort past every expert and join no group
+            ec = jnp.where(jnp.repeat(live, k), ec, E)
+        order = jnp.argsort(ec, stable=True)          # sorted pos -> copy
+        group_sizes = jnp.sum(jax.nn.one_hot(ec, E, dtype=jnp.int32),
+                              axis=0)
+        sorted_rows = jnp.take(x, order // k, axis=0)
+    with jax.named_scope("moe.experts"):
+        out = expert_ffn(sorted_rows, group_sizes)
+    with jax.named_scope("moe.combine"):
+        # combine by gather: copy -> its row of the sorted buffer
+        rows = jnp.take(out, jnp.argsort(order), axis=0).reshape(G, k, -1)
+        y = jnp.sum(weights.astype(out.dtype)[..., None] * rows, axis=1)
+        if live is not None:
+            # rows past the last group are whatever the kernel left there
+            y = jnp.where(live[:, None], y, 0)
+        counts = jnp.stack([jnp.sum(group_sizes),
+                            jnp.sum((group_sizes > 0).astype(jnp.int32)),
+                            jnp.max(group_sizes)])
     return y, counts

@@ -346,7 +346,7 @@ def _flash_fwd_pallas(q, k, v, *, sm_scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="flash_fwd",
     )(q, k, v)
     return out[:, :, :S], lse[:, :, 0, :S]
 
@@ -526,7 +526,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dq",
     )(q, k, v, do, lse4, delta4)[0]
 
     # dkv sweep: k block outer, q block inner (accumulate over q)
@@ -553,7 +553,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale: float, causal: bool,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dkv",
     )(q, k, v, do, lse4, delta4)
     # dq was computed against the pre-scaled q; undo the fold
     dq = dq * sm_scale

@@ -115,48 +115,86 @@ def _accumulate(root: _Node, path: List[str], flops: float,
         node.macs += macs
 
 
-def _name_path(eqn, prefix: List[str]) -> List[str]:
-    stack = str(eqn.source_info.name_stack)
-    parts = [p for p in stack.split("/") if p] if stack else []
-    return prefix + parts
+def name_parts(stack: str) -> List[str]:
+    """A name stack (an equation's, or the ``op_name`` a compiled
+    instruction carries) as its elements: split at ``/`` outside
+    parentheses, so ``transpose(jvp(Model))`` stays one element."""
+    parts, depth, start = [], 0, 0
+    for i, c in enumerate(stack):
+        if c == "(":
+            depth += 1
+        elif c == ")":
+            depth -= 1
+        elif c == "/" and depth == 0:
+            parts.append(stack[start:i])
+            start = i + 1
+    parts.append(stack[start:])
+    return [p for p in parts if p]
+
+
+def _sub_jaxpr(eqn, repeat: float):
+    """``(jaxpr an equation calls or None, how often it runs)``."""
+    name, params = eqn.primitive.name, eqn.params
+    if name == "scan":
+        return params["jaxpr"].jaxpr, repeat * int(params.get("length", 1))
+    if name == "while":
+        # trip count is dynamic; bill one iteration (documented)
+        return params["body_jaxpr"].jaxpr, repeat
+    if "jaxpr" in params:
+        # closed (pjit, custom_jvp) or open (``checkpoint``: the forward
+        # run again inside a backward pass is work the device does)
+        sub = params["jaxpr"]
+        sub = getattr(sub, "jaxpr", sub)
+        if hasattr(sub, "eqns"):
+            return sub, repeat
+    if "call_jaxpr" in params:
+        cj = params["call_jaxpr"]
+        return (cj.jaxpr if hasattr(cj, "jaxpr") else cj), repeat
+    if "fun_jaxpr" in params:
+        return params["fun_jaxpr"].jaxpr, repeat
+    return None, repeat
+
+
+def walk_equations(jaxpr, prefix: Tuple[str, ...] = (), repeat: float = 1.0,
+                   pick_branches=None):
+    """Every equation of ``jaxpr`` that calls no other, as ``(equation,
+    module path, how often it runs)``, through scans (times their
+    length), whiles (once), calls and ``cond`` branches (all of them, or
+    those ``pick_branches(branches, path, repeat)`` returns).  The path is
+    the name stack: a sub-jaxpr's stacks are relative to the call site,
+    hence the prefix threading."""
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        path = tuple(prefix) + tuple(name_parts(stack) if stack else ())
+        if eqn.primitive.name == "cond":
+            branches = eqn.params["branches"]
+            if pick_branches is not None:
+                branches = pick_branches(branches, path, repeat)
+            for br in branches:
+                yield from walk_equations(br.jaxpr, path, repeat,
+                                          pick_branches)
+            continue
+        sub, factor = _sub_jaxpr(eqn, repeat)
+        if sub is not None:
+            yield from walk_equations(sub, path, factor, pick_branches)
+        else:
+            yield eqn, list(path), factor
+
+
+def _dearest_branch(branches, path, repeat):
+    """Bill the most expensive branch of a ``cond``."""
+    costs = []
+    for br in branches:
+        tmp = _Node()
+        _walk(br.jaxpr, tmp, list(path), repeat)
+        costs.append(tmp.flops)
+    return [branches[costs.index(max(costs))]] if costs else []
 
 
 def _walk(jaxpr, root: _Node, prefix: List[str], repeat: float) -> None:
-    for eqn in jaxpr.eqns:
-        path = _name_path(eqn, prefix)
-        name = eqn.primitive.name
-        sub = None
-        factor = repeat
-        if name == "scan":
-            sub = eqn.params["jaxpr"].jaxpr
-            factor = repeat * int(eqn.params.get("length", 1))
-        elif name == "while":
-            # trip count is dynamic; bill one iteration (documented)
-            sub = eqn.params["body_jaxpr"].jaxpr
-        elif name == "cond":
-            # bill the most expensive branch
-            branches = eqn.params["branches"]
-            costs = []
-            for br in branches:
-                tmp = _Node()
-                _walk(br.jaxpr, tmp, path, repeat)
-                costs.append(tmp)
-            if costs:
-                best = max(costs, key=lambda n: n.flops)
-                _merge(root, best)
-            continue
-        elif "jaxpr" in eqn.params and hasattr(eqn.params["jaxpr"], "jaxpr"):
-            sub = eqn.params["jaxpr"].jaxpr
-        elif "call_jaxpr" in eqn.params:
-            cj = eqn.params["call_jaxpr"]
-            sub = cj.jaxpr if hasattr(cj, "jaxpr") else cj
-        elif "fun_jaxpr" in eqn.params:
-            sub = eqn.params["fun_jaxpr"].jaxpr
-        if sub is not None:
-            # sub-jaxpr name stacks are relative to the call site, hence
-            # the prefix threading; costs stay rooted at `root`
-            _walk(sub, root, path, factor)
-            continue
+    # costs stay rooted at `root`
+    for eqn, path, factor in walk_equations(jaxpr, tuple(prefix), repeat,
+                                            _dearest_branch):
         flops, macs = _eqn_cost(eqn)
         _accumulate(root, path, flops * factor, macs * factor)
 

@@ -39,6 +39,7 @@ from deepspeed_tpu.runtime.optimizers import (build_optimizer,
                                               is_fused_optimizer)
 from deepspeed_tpu.runtime.train_state import TrainState
 from deepspeed_tpu.runtime.zero import ZeroShardingPlan, constrain_tree
+from deepspeed_tpu.telemetry import device_scopes
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (BACKWARD_GLOBAL_TIMER,
                                        FORWARD_GLOBAL_TIMER,
@@ -864,8 +865,9 @@ class DeepSpeedEngine:
             rng, new_rng = jax.random.split(state.rng)
             scale = state.scale.loss_scale
             # ZeRO-Offload: explicit H2D fetch of host-resident state
-            live_params = fetch_params(state.params)
-            live_opt = fetch_opt(state.opt_state)
+            with jax.named_scope("zero.fetch"):
+                live_params = fetch_params(state.params)
+                live_opt = fetch_opt(state.opt_state)
 
             def micro_grads(mb, idx):
                 mrng = jax.random.fold_in(rng, idx)
@@ -909,32 +911,35 @@ class DeepSpeedEngine:
                 inv = 1.0 / (scale * gas)
                 grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
 
-            grad_norm = prec.global_norm(grads)
-            if clip and clip > 0:
-                grads, _ = prec.clip_by_global_norm(grads, clip, grad_norm)
+            with jax.named_scope("optim"):
+                grad_norm = prec.global_norm(grads)
+                if clip and clip > 0:
+                    grads, _ = prec.clip_by_global_norm(grads, clip,
+                                                        grad_norm)
 
-            if check_overflow:
-                overflow = prec.has_inf_or_nan(grads)
-                safe_grads = jax.tree_util.tree_map(
-                    lambda g: jnp.where(overflow, jnp.zeros_like(g), g), grads)
-                updates, new_opt = tx_update(safe_grads, live_opt,
-                                             live_params)
-                new_params = jax.tree_util.tree_map(
-                    lambda p, u: jnp.where(overflow, p,
-                                           (p - lr * u.astype(jnp.float32)
-                                            ).astype(p.dtype)),
-                    live_params, updates)
-                new_opt = jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(overflow, o, n), new_opt,
-                    live_opt)
-            else:
-                overflow = jnp.asarray(False)
-                updates, new_opt = tx_update(grads, live_opt,
-                                             live_params)
-                new_params = jax.tree_util.tree_map(
-                    lambda p, u: (p - lr * u.astype(jnp.float32)
-                                  ).astype(p.dtype),
-                    live_params, updates)
+                if check_overflow:
+                    overflow = prec.has_inf_or_nan(grads)
+                    safe_grads = jax.tree_util.tree_map(
+                        lambda g: jnp.where(overflow, jnp.zeros_like(g), g),
+                        grads)
+                    updates, new_opt = tx_update(safe_grads, live_opt,
+                                                 live_params)
+                    new_params = jax.tree_util.tree_map(
+                        lambda p, u: jnp.where(overflow, p,
+                                               (p - lr * u.astype(jnp.float32)
+                                                ).astype(p.dtype)),
+                        live_params, updates)
+                    new_opt = jax.tree_util.tree_map(
+                        lambda n, o: jnp.where(overflow, o, n), new_opt,
+                        live_opt)
+                else:
+                    overflow = jnp.asarray(False)
+                    updates, new_opt = tx_update(grads, live_opt,
+                                                 live_params)
+                    new_params = jax.tree_util.tree_map(
+                        lambda p, u: (p - lr * u.astype(jnp.float32)
+                                      ).astype(p.dtype),
+                        live_params, updates)
 
             new_scale = prec.update_loss_scale(
                 state.scale, overflow, dynamic,
@@ -1365,12 +1370,16 @@ class DeepSpeedEngine:
             raise
         if breakdown:
             self.timers("batch_prep").stop()
+        lr = self._lr_device()
         if self._train_step_fn is None and self.nvme_swapper is None:
             self._train_step_fn = (
                 self._build_onebit_train_step(gbatch)
                 if self._onebit_axes is not None
                 else self._build_train_step())
-        lr = self._lr_device()
+            # for whoever joins a profile's device time to layer kinds
+            device_scopes.register(self._train_step_fn.__name__,
+                                   self._train_step_fn,
+                                   (self.state, gbatch, lr))
 
         self.tput_timer.start()
         if breakdown:
@@ -1515,10 +1524,13 @@ class DeepSpeedEngine:
                                          np.asarray(x).ndim,
                                          dtype=np.asarray(x).dtype)),
             batch)
-        if self._eval_step_fn is None:
-            self._eval_step_fn = self._build_eval_step()
         rng = jax.random.fold_in(jax.random.PRNGKey(self.config.seed ^ 0x5EED),
                                  self.global_steps)
+        if self._eval_step_fn is None:
+            self._eval_step_fn = self._build_eval_step()
+            device_scopes.register(self._eval_step_fn.__name__,
+                                   self._eval_step_fn,
+                                   (self.state, batch, rng))
         return self._eval_step_fn(self.state, batch, rng)
 
     # -- imperative compat ----------------------------------------------

@@ -24,6 +24,12 @@ in the tree:
   ``"ttft_ms_p99 <= 150"`` with rolling-window error-budget burn rate,
   and the tail-sampling promotion policy.
 
+- ``telemetry.device_scopes`` (a submodule, imported by name; it needs
+  ``jax``): the scope paths the compiled programs carry, folded into
+  layer kinds and joined to instruction names: ``tables()``,
+  ``dump(path)`` beside any profile.  No knob: the engines note each
+  program they dispatch, the tables are built only when asked.
+
 Enable knobs: ``DSTPU_TRACE=1`` (env) or
 ``telemetry.configure(enabled=True)``; ``DSTPU_TRACE_BUFFER`` sizes
 the per-thread rings; ``DSTPU_TRACE_SAMPLE=N`` arms tail sampling;
