@@ -83,8 +83,15 @@ class HostStageStats:
     the ``engine.dispatch`` spans carry; ``sample_s`` / ``rng_split_s``
     / ``reap_s`` are the seconds of the three brackets outside
     ``STAGES``.  ``fused_ticks`` counts SplitFuse ticks and
-    ``fused_ticks_ahead`` those dispatched while the tick before was
-    un-fetched (their ``engine.dispatch`` spans say ``ahead=1``).
+    ``fused_ticks_ahead`` those dispatched with earlier work of the
+    v2 engine's queue still un-folded (their ``engine.dispatch`` spans say
+    ``ahead=1``); ``decode_blocks`` / ``decode_blocks_ahead`` say the
+    same of plain decode blocks and ``admissions`` /
+    ``admissions_ahead`` of requests seated while work was in flight.
+    ``drains`` counts the times the loop waited for the queue's newest
+    entry with nothing dispatched behind it, because its next plan
+    needed a token's value; ``drain_reasons`` says why, by name
+    (``eos``, ``pages``, ``spec``, ``handoff``, ``lc``, ``api``).
 
     Speculative decoding adds two host stages — ``draft`` (draft-model
     KV catch-up prefill + host-side draft planning) and ``verify`` (the
@@ -152,8 +159,16 @@ class HostStageStats:
         self.prefill_key_tokens = 0   # keys those tokens attend to
         self.decode_key_token_steps = 0   # context tokens x token steps
         self.fused_ticks = 0        # SplitFuse ticks dispatched
-        # ... of them while the tick before was un-fetched (one ahead)
+        # ... of them with earlier work un-folded (dispatched ahead)
         self.fused_ticks_ahead = 0
+        self.decode_blocks = 0      # plain decode blocks dispatched
+        self.decode_blocks_ahead = 0    # ... with work still in flight
+        self.admissions = 0         # requests seated in a slot
+        self.admissions_ahead = 0   # ... behind work still in flight
+        # waits for the newest dispatched work with nothing queued
+        # behind it (the chip idles for the host's turn), and why
+        self.drains = 0
+        self.drain_reasons: Dict[str, int] = {}
         # dropless expert layers, live rows only, summed over layers:
         self.moe_rows = 0           # token copies routed
         self.moe_experts_hit = 0    # experts with at least one row
@@ -206,8 +221,15 @@ class HostStageStats:
                    decode_key_token_steps=self.decode_key_token_steps,
                    fused_ticks=self.fused_ticks,
                    fused_ticks_ahead=self.fused_ticks_ahead,
+                   decode_blocks=self.decode_blocks,
+                   decode_blocks_ahead=self.decode_blocks_ahead,
+                   admissions=self.admissions,
+                   admissions_ahead=self.admissions_ahead,
+                   drains=self.drains,
                    **{f"{s}_s": round(self.seconds[s], 6)
                       for s in self.EXTRA})
+        if self.drain_reasons:
+            out["drain_reasons"] = dict(self.drain_reasons)
         if self.spec_dispatches:
             sd = self.spec_dispatches
             out["speculation"] = {

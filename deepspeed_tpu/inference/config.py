@@ -280,13 +280,15 @@ class ControlConfig(V2Subtree):
 class InferenceV2Config(ConfigModel):
     """``v2`` subtree: the serving host-path pipeline knobs.
 
-    ``pipeline`` (default ON) runs the ragged engine's decode steady
-    state as a software pipeline — metadata pinned on device, host
-    planning overlapped with device work, tokens harvested every
-    ``harvest_interval`` decode blocks with at most ``async_depth``
-    blocks in flight.  ``pipeline=False`` preserves the unpipelined
-    host loop exactly (one blocking harvest + fresh metadata upload per
-    dispatch) and is the bit-identical parity reference.  The v1 engine
+    ``pipeline`` (default ON) runs the ragged engine's host loop as a
+    software pipeline: metadata pinned on device, every dispatch planned
+    from counts, fused ticks and decode blocks in one in-order queue of
+    dispatched, un-folded work with at most ``async_depth`` entries in
+    flight; at ``harvest_interval`` un-folded entries the completed ones
+    are folded (nothing in flight is waited for).  ``pipeline=False``
+    preserves the unpipelined host loop exactly (one blocking harvest +
+    fresh metadata upload per dispatch) and is the parity reference:
+    every request's tokens are bit-identical.  The v1 engine
     consumes the same subtree for its deferred-harvest
     ``generate_async`` path."""
 

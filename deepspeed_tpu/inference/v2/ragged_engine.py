@@ -31,55 +31,67 @@ axis — weights shard by AutoTP name rules, the paged KV pool shards over
 its head dim, and the fused tick runs under GSPMD with the paged attention
 shard_map-manual over ``tensor``.
 
-**The pipelined host path** (``pipeline=True``, the default): both kinds
-of step run as a software pipeline.
+**The pipelined host path** (``pipeline=True``, the default): the host
+plans from COUNTS and the device runs behind it, through ONE in-order
+queue of dispatched, un-folded work.
 
-*Decode blocks.*
+- *What is queued.*  Every fused tick and every decode block the loop
+  dispatches (``_Work``), in dispatch order.  Both sample on the device
+  and hand every slot's newest token to the next program there (a tick's
+  ``last_tok`` result, a block's ``last_tok`` carry: the same ``[max_seqs]``
+  vector, ``_dev_last_tok`` between them), so a row of the next program
+  reads its token where it was sampled (``tok_src`` in a tick, the carry in
+  a block).  ``Request.pending`` counts a request's tokens that are
+  dispatched and not folded (a tick adds one, a block up to
+  ``decode_block_size``): lengths, budgets, page growth and every finish
+  by count are exact at dispatch.
+- *The decode blocks' carry* (``last_tok``/``pos``/``active``/
+  ``remaining``) and per-tick metadata (page table, sampler configs, eos
+  ids) stay RESIDENT on the device while block follows block (``_dev``):
+  uploaded at entry, built from counts, re-uploaded only when the page
+  table grows.  A row that ends by its count is retired by the device's
+  own ``active`` carry (it writes page 0 from then on); the carry lives on,
+  less that request.  An admission releases the carry and leaves the
+  blocks in the queue; the request's ticks are dispatched behind them and
+  the blocks that follow start from the device's tokens again.
+- *Folding* takes entries from the queue's HEAD, in order, one blocking
+  ``device_get`` for all it takes (``_fold``), and reaps who finished: the
+  freed slot's pages go back to the allocator there.  After each dispatch
+  (``_settle``) the loop folds every tick with tokens and every entry a
+  sequence ends with ONCE SOMETHING IS DISPATCHED BEHIND IT (a stream sees
+  a tick's token, a prompt's first token and a finish when their program
+  ends, while the chip already has the next to run), and, when
+  ``harvest_interval`` entries are un-folded, those the run-ahead window
+  (``async_depth`` entries in flight; a submit past it joins the oldest)
+  has joined already: a copy, no wait.  None of this waits for the
+  queue's tail.
+- *The loop waits for the tail* (``_drain``: the chip idles for the host's
+  turn) only where the next plan depends on a token's VALUE or on what a
+  reap gives back, which it reads off its own state, and says why in
+  ``host_stats.drain_reasons``: a live row with an EOS id (``eos``: its
+  finish is the token's to say); a handoff prefill (``handoff``: parked
+  the step its token lands); the speculative block, whose advance is
+  data-dependent (``spec``: it drains at every harvest and at its entry);
+  a plan short of pages, an eviction, a spill, or a finish in flight while
+  a request waits that what is free before it cannot seat (``pages``); a
+  sequence that ticks outside the batch (``lc``); ``cancel``, ``sync``,
+  ``drain``, ``export_handoff`` / ``export_parked`` (``api``).  Where
+  nothing is left to run, folding the tail is the end of the work and
+  counts as no drain.  ``has_work()`` is true while anything is queued.
 
-- the decode-block carry (``last_tok``/``pos``/``active``/``remaining``)
-  and the per-tick metadata (page table, sampler configs, eos ids) stay
-  RESIDENT on device across dispatches — uploaded once at loop entry,
-  re-uploaded only when the page table actually grows;
-- while block *k* executes on device, the host plans block *k+1* from an
-  exact projection of each sequence's length/remaining budget (JAX
-  dispatch is async — the host never synchronizes per block, bounded by
-  ``async_depth`` blocks in flight);
-- sampled tokens accumulate on device and are harvested (one
-  ``device_get``) every ``harvest_interval`` blocks.  EOS/finish
-  detection stays device-side (the decode block's ``active`` carry).
-
-*Fused ticks*, one ahead.  The tick samples inside its program and hands
-every slot's newest token to the next tick on the device
-(``programs.build_fused_sample_step``), so the only thing the next plan
-lacked is no longer the host's to wait for: ``_step`` plans, uploads and
-dispatches tick *k+1* and THEN fetches tick *k*'s tokens, folds them and
-reaps.  Between the two a request counts the token it has not seen
-(``Request.pending``): lengths, budgets and every finish by count are
-exact, and a decode row of *k+1* whose token *k* sampled reads it from
-the device.  The fetch still comes once a tick, so a stream sees each
-token when its tick ends.  A tick is left in flight only where the plan
-after it is exact without its tokens, which the loop reads off its own
-state: no sampling row carries an EOS id (its finish is the token's to
-say) or is a handoff prefill (parked the step its token lands), no
-sequence was short of pages in its plan, none ticks outside the batch
-(``lc``), and ``pipeline`` is on.  Whoever needs the host's tokens,
-budgets or slots flushes it first (``_flush_tick``): a finish in it while
-a request waits (the reap decides the admission), the entry into decode
-blocks and the long-context driver (the step ends with the flush, so a
-stream has a prompt's first token before the first block is built), a
-plan that falls short of pages or finds nothing to run (made again after
-the flush), ``cancel``, ``sync``, ``drain``, ``export_handoff`` /
-``export_parked``, ``_evict`` and ``_spill``; ``has_work()`` is true
-while a tick is un-harvested.
-
-Harvests are FORCED at every point where the unpipelined engine could
-have reaped, admitted, or evicted (a possible finish, a newly admittable
-request, page-growth failure), so the dispatch sequence — programs,
-metadata values, and rng splits — is identical to ``pipeline=False`` and
-outputs are bit-identical, greedy or seeded-sampling.  ``host_stats``
+Every request's tokens are ``pipeline=False``'s, greedy and seeded (a
+greedy row is the argmax of its own logits; a drawn token's key is
+``position_keys(sample_base, uid, position)``, never the schedule's), under
+mid-run arrivals, finishes, eviction and EOS.  What is NOT kept is the
+schedule: the pipelined loop admits at the host step where
+``pipeline=False`` would, but the request's first tick runs behind the
+blocks in flight (``async_depth`` and the one just dispatched, at most)
+instead of after a drain, so the batch a row shares a tick with can differ
+from the unpipelined run's.  ``host_stats``
 (:class:`~deepspeed_tpu.inference.common.HostStageStats`) breaks the
 host path into plan/upload/dispatch/device/harvest per dispatch, and
-counts ``fused_ticks`` and, of them, ``fused_ticks_ahead``.
+counts ``drains``, ``fused_ticks`` / ``decode_blocks`` / ``admissions``
+and, of each, those that went out with work still in flight (``*_ahead``).
 
 **Speculative decoding** (``speculation.mode != off``) rides the same
 path: each decode-block tick drafts ``k`` tokens per slot (``ngram``:
@@ -94,8 +106,8 @@ output distribution equals the non-speculative one.  Rollback is pure
 position rollback: KV rows written for rejected positions are overwritten
 by the next block before any query can attend to them, and the pages stay
 owned.  The host projects per-slot advance as ``[1, k+1]``-per-tick BOUNDS
-instead of exact counts, grows pages to the worst case, and forces a
-harvest whenever a finish is possible under the fast bound.
+instead of exact counts, grows pages to the worst case, and drains
+whenever a finish is possible under the fast bound.
 
 Models: anything llama-shaped in the zoo (Llama, Mistral, Qwen2, Mixtral,
 OLMoE, ... — per-token positions thread through attention, which the
@@ -140,8 +152,10 @@ class Request:
     slot: int = -1
     prefill_done: int = 0                 # context tokens already cached
     generated: List[int] = dataclasses.field(default_factory=list)
-    # tokens fused ticks in flight have sampled on the device and no fetch
-    # has folded into ``generated`` yet (0 wherever host state is exact)
+    # tokens that dispatched work (fused ticks, decode blocks) samples on
+    # the device and no fetch has folded into ``generated`` yet: a tick
+    # adds one, a block up to ``decode_block_size`` (0 wherever host state
+    # is exact)
     pending: int = 0
     done: bool = False
     # prefill SOURCE: the prompt, or prompt + already-generated tokens
@@ -200,15 +214,23 @@ class Request:
 
 
 @dataclasses.dataclass
-class _FusedTick:
-    """A dispatched fused tick whose tokens no fetch has brought yet."""
-    toks: Any                             # device [max_seqs], by packed row
-    # (request, packed sequence number) of every sequence that samples
-    sampling: List[Tuple[Request, int]]
-    # its expert-layer and pass-loop counts, for the fetch that harvests it
+class _Work:
+    """A dispatched program (a fused tick or a decode block) whose tokens
+    no fetch has folded into their requests yet: one entry of the engine's
+    in-order queue."""
+    seq: int                              # its key in the run-ahead window
+    block: bool                           # a decode block; else a fused tick
+    # a tick: device [max_seqs] tokens by packed sequence; a block: ([K, S]
+    # tokens, [K, S] produced-mask), and (proposed, accepted) behind them
+    # if it speculates
+    toks: Any
+    # who gets tokens of it: (request, packed sequence number) of a tick's
+    # sampling rows, (request, tokens it added to ``pending``) of a block's
+    rows: List[Tuple[Request, int]]
+    # its expert-layer and pass-loop counts, for the fetch that folds it
     counts: List[Tuple[Any, Any, Dict[str, Any]]]
     # a sequence ends with it (by its count: nothing else is known ahead)
-    finishes: bool
+    finishes: bool = False
 
 
 class RaggedInferenceEngineV2:
@@ -439,8 +461,20 @@ class RaggedInferenceEngineV2:
             for k, default in (("async_depth", 2), ("harvest_interval", 4)))
         self.replica = "" if replica is None else str(replica)
         self.host_stats = HostStageStats(replica=self.replica)
-        # serving_stages()["pipeline_window"]: the decode window's timers
+        # dispatched, un-folded work (fused ticks and decode blocks alike),
+        # in dispatch order; folding takes it from the head
+        self._queue: Deque[_Work] = deque()
+        self._work_seq = itertools.count()
+        # the shared bounded-window substrate bounds the device's run-ahead
+        # at async_depth entries: a submit past it first joins the oldest
+        # (block_until_ready on its tokens, bracketed as device wait).  Same
+        # substrate instance shape as the NVMe moment stream and the
+        # router's per-replica feed loop; its timers are
+        # serving_stages()["pipeline_window"]
         self._pipe_timers = StageTimers(cat="serving")
+        self._window = BoundedAsyncStage(
+            waiter=self._work_ready, depth=self.async_depth,
+            timers=self._pipe_timers, name="serving_pipeline")
         # per-request lifecycle latency — always on, tracer or not
         self.request_latency = RequestLatencyTracker(replica=self.replica)
 
@@ -459,8 +493,8 @@ class RaggedInferenceEngineV2:
             # an explicit engine/config N arms the tracer's sampling
             # mode the same way DSTPU_TRACE_SAMPLE does
             trace.configure(enabled=True, sampling=True, sample_n=n)
-        # device-resident decode-loop state while the pipeline runs
-        # ahead of the host (None <=> host state is authoritative)
+        # the decode blocks' device-resident carry and metadata while
+        # block follows block (None: the next block builds them anew)
         self._dev: Optional[Dict[str, Any]] = None
 
         # -- speculative decoding (programs.build_spec_block) --
@@ -520,14 +554,14 @@ class RaggedInferenceEngineV2:
         self._counts_pending: List[Tuple[Any, Any, Dict[str, Any]]] = []
         # each slot's pending token: sampled, not yet written to the pool
         self.last_tokens = np.zeros((max_seqs,), np.int32)
-        # the same as the fused ticks keep it on the device, each handing
-        # it to the next: right in the slots whose token the tick in
-        # flight sampled, which is all a tick reads of it (a put, not a
-        # program: nothing compiles to make it)
+        # the same as dispatched work keeps it on the device, each program
+        # handing it to the next (tick to tick, a block's carry to the tick
+        # behind it, a tick to the blocks' new carry): right in the slots
+        # whose newest token queued work sampled (``pending``), which is
+        # all that is read of it (a put, not a program: nothing compiles to
+        # make it)
         self._dev_last_tok = jax.device_put(
             np.zeros((max_seqs,), np.int32), self._device)
-        # the fused tick in flight: dispatched, its tokens not fetched
-        self._tick: Optional[_FusedTick] = None
         # what the last plan of a tick found of the pool: sequences that
         # sit the tick out, and whether any growth fell short
         self._stalled: List[Request] = []
@@ -714,14 +748,15 @@ class RaggedInferenceEngineV2:
         ``"finished"``) or ``None`` for an unknown uid (never
         submitted, or already collected)."""
         stage: Optional[str] = None
-        self._flush_tick()
-        # fold an active pipelined carry first: the target may be
-        # mid-decode inside it, and teardown re-anchors host state so
-        # the slot release below is authoritative (the target may
-        # FINISH during this harvest — then it lands in ``finished``)
+        # fold whatever is in flight first: the target may be mid-decode
+        # inside it, and host state must be exact for the slot release
+        # below to be authoritative (the target may FINISH during this
+        # fold — then it lands in ``finished``); a device carry that
+        # still counts the target as live is built anew
+        self._drain("api")
         dv = self._dev
         if dv is not None and any(r.uid == uid for r in dv["reqs"]):
-            self._pipeline_harvest(teardown=True)
+            self._release_carry()
         # parked: in the waiting queue (never admitted, an evicted
         # continuation, or spilled out to the tiers), or for a
         # prefill->decode handoff the router never collected
@@ -765,22 +800,19 @@ class RaggedInferenceEngineV2:
         return stage
 
     def has_work(self) -> bool:
-        # a fused tick in flight holds its sequences in their slots until
-        # a step harvests it
-        return (bool(self.waiting) or self._tick is not None or
+        # queued work holds its sequences in their slots until a step
+        # folds it
+        return (bool(self.waiting) or bool(self._queue) or
                 any(s is not None for s in self.slots))
 
     def sync(self) -> int:
-        """Fold any in-flight device work (a fused tick dispatched
-        ahead, pipelined decode blocks) back into host request state;
-        returns the tokens folded.  No-op when the pipeline is off or
-        idle.  Callers that read ``slots[*].generated`` / ``finished``
+        """Fold every dispatched, un-folded tick and decode block back
+        into host request state (the decode blocks' device carry lives
+        on); returns the tokens folded.  No-op when the pipeline is off
+        or idle.  Callers that read ``slots[*].generated`` / ``finished``
         between ``step()`` calls (benchmark boundaries, draining
         shutdown) call this first."""
-        produced = self._flush_tick()
-        if self._dev is not None:
-            produced += self._pipeline_harvest()
-        return produced
+        return self._drain("api")
 
     def drain(self) -> Dict[int, np.ndarray]:
         """Run until every queued/resident request finishes; returns
@@ -788,7 +820,7 @@ class RaggedInferenceEngineV2:
         replica-shutdown half of the engine handle — ``close()``
         releases resources after)."""
         outs: Dict[int, np.ndarray] = {}
-        self._flush_tick()
+        self._drain("api")
         # nobody is coming to collect a pending handoff during a drain:
         # finish those sessions locally through the normal spilled /
         # continuation re-admission path
@@ -814,7 +846,7 @@ class RaggedInferenceEngineV2:
         the way is output-identical under greedy decode, just re-paying
         its prefill."""
         sessions: List[Dict[str, Any]] = []
-        self._flush_tick()
+        self._drain("api")
         # pending prefill->decode handoffs the router never pulled ride
         # the same retirement: they are parked sessions like any other
         for r in itertools.chain(self.waiting, self._handoff_ready):
@@ -872,7 +904,7 @@ class RaggedInferenceEngineV2:
         the session through the normal spilled-request re-admission
         path, so greedy outputs stay bit-identical to a fused tick."""
         sessions: List[Dict[str, Any]] = []
-        self._flush_tick()
+        self._drain("api")
         for r in self._handoff_ready:
             blob = self.kv.export(r)
             self.handoffs += 1
@@ -917,9 +949,9 @@ class RaggedInferenceEngineV2:
         """The engine's typed knob surface for the control plane
         (:class:`~deepspeed_tpu.control.knobs.KnobRegistry`).
 
-        Online-safe knobs: ``harvest_interval`` is read fresh each
-        pipelined step; ``async_depth`` re-sizes the live decode window
-        in place (the substrate back-pressures on the next submit).
+        Online-safe knobs: ``harvest_interval`` is read fresh after each
+        dispatch; ``async_depth`` re-sizes the run-ahead window in place
+        (the substrate back-pressures on the next submit).
         ``decode_block_size`` and ``spec_k`` are baked into compiled
         block shapes, so they carry ``recompiles=True`` — reachable only
         by the offline sweep / profile seeding, never the online policy
@@ -935,8 +967,7 @@ class RaggedInferenceEngineV2:
 
         def _set_depth(v):
             self.async_depth = max(int(v), 1)
-            if self._dev is not None:
-                self._dev["window"].depth = self.async_depth
+            self._window.depth = self.async_depth
 
         def _set_block(v):
             self.decode_block_size = max(int(v), 1)
@@ -948,12 +979,15 @@ class RaggedInferenceEngineV2:
             "engine.harvest_interval",
             lambda: self.harvest_interval, _set_harvest,
             lo=1, hi=16, step=1, kind="int",
-            doc="pipelined decode blocks between token harvests"))
+            doc="dispatched, un-folded ticks and decode blocks at which "
+                "the oldest completed ones are folded (no wait for any "
+                "still in flight)"))
         reg.register(Knob(
             "engine.async_depth",
             lambda: self.async_depth, _set_depth,
             lo=1, hi=8, step=1, kind="int",
-            doc="in-flight decode blocks in the pipeline window"))
+            doc="dispatched ticks and decode blocks the device may run "
+                "ahead of the host"))
         reg.register(Knob(
             "engine.decode_block_size",
             lambda: self.decode_block_size, _set_block,
@@ -1095,8 +1129,8 @@ class RaggedInferenceEngineV2:
                               "folds": int(self.handoff_folds),
                               "pending": len(self._handoff_ready)}
         if self._pipe_timers.seconds or self._pipe_timers.counters:
-            # the pipelined decode window's substrate counters
-            # (submitted/completed blocks, submit_wait back-pressure)
+            # the run-ahead window's substrate counters (submitted /
+            # joined ticks and blocks, submit_wait back-pressure)
             out["pipeline_window"] = self._pipe_timers.snapshot()
         if self._controller is not None:
             out["control"] = self._controller.stats()
@@ -1189,13 +1223,14 @@ class RaggedInferenceEngineV2:
                               for u, v in enumerate(cdf)}):
             pass
 
-    def _block_ready(self, block):
-        """The pipeline window's waiter: joining a decode block means
-        waiting for its device tokens (run-ahead bound, NOT a fetch —
-        harvest later folds all ready blocks in one blocking get)."""
+    def _work_ready(self, work: _Work) -> _Work:
+        """The run-ahead window's waiter: joining a tick or a decode
+        block means waiting for its device tokens (run-ahead bound, NOT a
+        fetch: a fold later brings the tokens of all it takes in one
+        blocking get)."""
         with self.host_stats.stage("device"):
-            jax.block_until_ready(block[0])
-        return block
+            jax.block_until_ready(work.toks)
+        return work
 
     # -- the compiled programs (built by programs.py, cached here) --------
 
@@ -1245,10 +1280,12 @@ class RaggedInferenceEngineV2:
 
     def _block_arrays(self, reqs: List[Request]):
         """Host numpy decode-block state for ``reqs`` (shared by the
-        unpipelined per-block rebuild and the pipelined loop's one-time
-        entry upload)."""
+        unpipelined per-block rebuild and the pipelined loop's entry
+        upload), from counts alone: a token still on the device is in
+        ``length`` and off the budget."""
         S = self.max_seqs
-        last_tok = np.asarray(self.last_tokens, np.int32)
+        # a copy: folds write ``last_tokens`` in place under the upload
+        last_tok = np.array(self.last_tokens, np.int32)
         pos = np.zeros((S,), np.int32)
         active = np.zeros((S,), bool)
         remaining = np.zeros((S,), np.int32)
@@ -1263,7 +1300,7 @@ class RaggedInferenceEngineV2:
             self._last_sched[s] = self._sched_seq
             pos[s] = min(r.length - 1, self.max_seq_len - 1)
             active[s] = True
-            remaining[s] = r.max_new_tokens - len(r.generated)
+            remaining[s] = self._budget(r)
             if r.eos_token_id is not None:
                 eos_ids[s] = r.eos_token_id
             do_sample[s] = r.do_sample
@@ -1274,6 +1311,11 @@ class RaggedInferenceEngineV2:
         return (last_tok, pos, active, remaining, eos_ids, do_sample,
                 temperature, top_k, top_p, seeds)
 
+    def _budget(self, r: Request) -> int:
+        """Tokens ``r`` may still be given, those on the device counted
+        as given."""
+        return r.max_new_tokens - len(r.generated) - r.pending
+
     def _fold_block(self, reqs: List[Request], toks: np.ndarray,
                     mask: np.ndarray) -> int:
         """Fold one harvested [K, S] block into request state."""
@@ -1283,6 +1325,7 @@ class RaggedInferenceEngineV2:
             r.generated.extend(int(t) for t in new)
             produced += int(new.size)
             if new.size:
+                self.last_tokens[r.slot] = r.generated[-1]
                 # harvest-time token visibility: the honest host-side
                 # TTFT/TPOT timestamp under the deferred-harvest pipeline
                 self.request_latency.on_tokens(r.uid, len(r.generated))
@@ -1440,7 +1483,7 @@ class RaggedInferenceEngineV2:
             trace.event("decode_block", cat="request",
                         uids=[r.uid for r in reqs],
                         ticks=self.decode_block_size)
-        with self._decode_dispatch(reqs, [r.length for r in reqs]):
+        with self._decode_dispatch(reqs):
             (cache, new_last, _pos, _active, _remaining, toks, mask,
              moe, loop) = self._run(
                 self.decode_block(sampled),
@@ -1480,20 +1523,24 @@ class RaggedInferenceEngineV2:
                                                   "rows": rows}))
         return out
 
-    def _decode_dispatch(self, reqs: List[Request], contexts):
+    def _decode_dispatch(self, reqs: List[Request]):
         """The ``dispatch`` bracket of one plain decode block, with the
         counts its roofline needs: ``rows`` live sequences run ``ticks``
         token steps, the first reading ``key_tokens`` context tokens in
-        all (``contexts``: each row's length as the host projects it at
-        dispatch) and every later one a token more per row."""
+        all (each row's length as the host counts it at dispatch) and
+        every later one a token more per row; ``ahead``: earlier work
+        was still un-folded when it went out."""
         st = self.host_stats
         K = self.decode_block_size
-        rows, keys = len(reqs), int(sum(contexts))
+        rows, keys = len(reqs), int(sum(r.length for r in reqs))
+        ahead = int(bool(self._queue))
         st.dispatches += 1
+        st.decode_blocks += 1
+        st.decode_blocks_ahead += ahead
         st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
         state = {"state_rows": rows} if self.state_layers else {}
-        return st.stage("dispatch", program="decode_block", rows=rows,
-                        ticks=K, key_tokens=keys, **state,
+        return st.stage("dispatch", program="decode_block", ahead=ahead,
+                        rows=rows, ticks=K, key_tokens=keys, **state,
                         **self._pool_counts())
 
     def _pool_counts(self) -> Dict[str, int]:
@@ -1506,42 +1553,184 @@ class RaggedInferenceEngineV2:
                 "pages_total": total, **self._loop_attrs,
                 **self._state_attrs}
 
-    # -- the pipelined decode loop (serving host-path tentpole) ----------
+    # -- the queue of dispatched work and the decode blocks' carry --------
 
     def _admittable(self) -> bool:
-        """Would the unpipelined engine admit the queue head right now?
-        Evaluated from EXACT global state (pool + slots), so the
-        pipelined loop reconciles at precisely the steps where
-        ``pipeline=False`` would have admitted."""
+        """Could the queue head be seated right now?  Evaluated from
+        EXACT global state (pool + slots; a finish gives its slot and
+        pages back at the fold that reaps it).  The pipelined loop admits
+        at the host step where it turns true, as ``pipeline=False`` does,
+        but plans the request's first tick BEHIND the work in flight
+        instead of after a drain: the batch a row shares a tick with can
+        differ from the unpipelined run's, its own tokens cannot."""
         if not self.waiting or not any(s is None for s in self.slots):
             return False
         req = self.waiting[0]
         return self.kv.can_admit(
             req, self.kv.need(req, self.decode_block_size))
 
+    def _enqueue(self, work: _Work) -> None:
+        """Put dispatched work at the queue's tail.  Past ``async_depth``
+        entries in flight the window first joins the oldest: a wait with
+        work queued behind it, so the chip stays busy."""
+        self._queue.append(work)
+        self._window.submit(work.seq, work)
+
+    def _settle(self) -> int:
+        """After a dispatch, fold what is due WITHOUT waiting for the
+        queue's tail: every tick with tokens and every entry a sequence
+        ends with, once something is dispatched behind it (a stream sees
+        a tick's token and a finish when their program ends, and the chip
+        has the next to run meanwhile); and, at ``harvest_interval``
+        entries, those the run-ahead window has joined already (complete:
+        a copy, no wait)."""
+        q = self._queue
+        n = len(q)
+        due = 0
+        for i in range(n - 1):
+            w = q[i]
+            if w.rows and (w.finishes or not w.block):
+                due = i + 1
+        if n >= self.harvest_interval:
+            due = max(due, min(n - 1, max(n - self.async_depth,
+                                          n - self.harvest_interval + 1)))
+        return self._fold(due) if due else 0
+
+    def _drain(self, reason: Optional[str]) -> int:
+        """Fold EVERYTHING dispatched, the queue's tail included: host
+        state is exact after it, and the chip idles while the host takes
+        its turn.  For whoever's next plan depends on a token's value or
+        on what a reap gives back, each by its ``reason`` in
+        ``host_stats.drain_reasons`` (None: the unpipelined loop, or
+        nothing is left to run, which counts as no drain)."""
+        if not self._queue:
+            return 0
+        if reason is not None:
+            st = self.host_stats
+            st.drains += 1
+            st.drain_reasons[reason] = st.drain_reasons.get(reason, 0) + 1
+        produced = self._fold(len(self._queue))
+        if self._dev is not None and self._dev["spec"]:
+            self._spec_settle()
+        return produced
+
+    def _fold(self, n: int) -> int:
+        """Take the ``n`` oldest entries off the queue, bring their tokens
+        in ONE blocking fetch, fold them into their requests in dispatch
+        order, and reap who finished."""
+        st = self.host_stats
+        batch = [self._queue.popleft() for _ in range(n)]
+        for w in batch:
+            self._window.discard(w.seq)
+            self._counts_pending += w.counts
+        ticks = any(not w.block for w in batch)
+        batch = [w for w in batch if w.rows]
+        produced = 0
+        if batch:
+            st.harvests += 1
+            fetched = self.fetch([w.toks for w in batch])
+            with st.stage("harvest"):
+                for w, toks in zip(batch, fetched):
+                    produced += (self._fold_block_work(w, toks) if w.block
+                                 else self._fold_tick(w, toks))
+        # a handoff prefill that just sampled its first token parks NOW
+        # (same step) so the router's next export pulls it without an
+        # extra tick of decode-side latency
+        self._reap(handoff_sweep=ticks)
+        return produced
+
+    def _fold_tick(self, w: _Work, toks) -> int:
+        toks = np.asarray(toks)
+        for r, seq_j in w.rows:
+            r.generated.append(int(toks[seq_j]))
+            r.pending -= 1
+            self.last_tokens[r.slot] = r.generated[-1]
+            self.request_latency.on_tokens(r.uid, len(r.generated))
+            self._maybe_finish(r)
+        return len(w.rows)
+
+    def _fold_block_work(self, w: _Work, fetched) -> int:
+        toks, mask, *spec = fetched
+        produced = self._fold_block([r for r, _ in w.rows],
+                                    np.asarray(toks), np.asarray(mask))
+        for r, n in w.rows:
+            r.pending -= n
+            self._maybe_finish(r)
+        if spec:
+            st = self.host_stats
+            st.spec_proposed += int(spec[0])
+            st.spec_accepted += int(spec[1])
+            st.spec_tokens += produced
+        return produced
+
+    def _spec_settle(self) -> None:
+        """After a drain under a speculative carry: a finish tears the
+        carry down (the fused path re-plans); else the device carry stays
+        authoritative and the host's fast/slow bounds collapse to the
+        now-exact lengths."""
+        dv = self._dev
+        if any(r.done for r in dv["reqs"]):
+            self._dev = None
+            return
+        for r in dv["reqs"]:
+            self._draft_len[r.slot] = max(r.length - 1, 0)
+            dv["plen"][r.slot] = r.length
+            dv["rem"][r.slot] = self._budget(r)
+        np.copyto(dv["plen_hi"], dv["plen"])
+        np.copyto(dv["rem_lo"], dv["rem"])
+
+    def _table_now(self) -> np.ndarray:
+        """The page table as the blocks about to be dispatched must see
+        it: a COPY.  The host's table changes in place (growth, a reap, an
+        admission) under blocks still in flight, and a ``device_put`` may
+        alias host memory (the CPU backend does when it is aligned) or
+        read it after the call returns."""
+        return self.page_table.copy()
+
+    def _release_carry(self) -> None:
+        """Let the decode blocks' device carry go: the next blocks build
+        theirs anew, from counts.  Every slot's newest token stays on the
+        device, for whatever is dispatched next."""
+        dv, self._dev = self._dev, None
+        self._dev_last_tok = dv["last_tok"]
+
     def _pipeline_start(self, reqs: List[Request],
                         spec: bool = False) -> None:
         """Enter the pipelined decode loop: upload the decode-block
-        carry and sampler metadata ONCE; subsequent blocks chain
-        device-resident state (zero steady-state uploads).  With
-        ``spec`` the speculative block runs instead of the plain one and
-        the projection becomes per-slot BOUNDS (advance is 1..k+1 per
-        tick, data-dependent): ``plen``/``rem`` hold the slow bound
-        (1 token per tick — the guaranteed floor), ``plen_hi``/
-        ``rem_lo`` the fast bound (k+1 per tick); growth covers the
-        fast bound's write span and a harvest is forced as soon as the
-        fast bound says a finish is POSSIBLE."""
+        carry and sampler metadata ONCE, built from counts (a token
+        still on the device is in ``length`` and off the budget);
+        subsequent blocks chain device-resident state (zero steady-state
+        uploads).  With work in flight every row's newest token is on the
+        device (the caller saw to it) and ``last_tok`` is what that work
+        hands on, not an upload.  With ``spec`` the speculative block
+        runs instead of the plain one, from exact host state, and the
+        host projects per-slot BOUNDS (advance is 1..k+1 per tick,
+        data-dependent): ``plen``/``rem`` hold the slow bound (1 token
+        per tick, the guaranteed floor), ``plen_hi``/``rem_lo`` the fast
+        bound (k+1 per tick); growth covers the fast bound's write span
+        and a drain is forced as soon as the fast bound says a finish is
+        POSSIBLE."""
         if spec:
             self._draft_catchup(reqs)
         with self.host_stats.stage("plan"):
             (last_tok, pos, active, remaining, eos_ids, do_sample,
              temperature, top_k, top_p, seeds) = self._block_arrays(reqs)
+        host = dict(pos=pos, active=active, remaining=remaining,
+                    page_table=self._table_now(), eos_ids=eos_ids,
+                    do_sample=do_sample, temperature=temperature,
+                    top_k=top_k, top_p=top_p)
+        if not self._queue:
+            # nothing in flight: the host's tokens are the newest
+            host["last_tok"] = last_tok
+        self._dev = {
+            "reqs": list(reqs),
+            "sampled": bool(do_sample.any()),
+            "spec": spec,
+            "last_tok": self._dev_last_tok,
+            **dict(zip(host, self.upload_all(list(host.values())))),
+        }
+        if spec:
             S = self.max_seqs
-            # exact host projection of per-slot cache length and token
-            # budget — for eos-free sequences the device's active/
-            # remaining carry is a deterministic function of these, so
-            # the host can plan ahead without syncing; eos-bearing
-            # sequences force a harvest every block (finish_possible)
             plen = np.zeros((S,), np.int64)
             rem = np.zeros((S,), np.int64)
             has_eos = np.zeros((S,), bool)
@@ -1549,83 +1738,76 @@ class RaggedInferenceEngineV2:
                 plen[r.slot] = r.length
                 rem[r.slot] = remaining[r.slot]
                 has_eos[r.slot] = r.eos_token_id is not None
-        names = ("last_tok", "pos", "active", "remaining", "page_table",
-                 "eos_ids", "do_sample", "temperature", "top_k", "top_p")
-        self._dev = {
-            "reqs": list(reqs),
-            "sampled": bool(do_sample.any()),
-            **dict(zip(names, self.upload_all(
-                (last_tok, pos, active, remaining, self.page_table,
-                 eos_ids, do_sample, temperature, top_k, top_p)))),
-            "plen": plen, "rem": rem, "has_eos": has_eos,
-            "spec": spec,
-            # un-harvested decode blocks ride the shared bounded-window
-            # substrate: the window bounds device run-ahead at
-            # async_depth (joining = block_until_ready on the block's
-            # tokens, bracketed as device wait), joined blocks park in
-            # "ready" until the next harvest folds them.  Same substrate
-            # instance shape as the NVMe moment stream and the router's
-            # per-replica feed loop.
-            "window": BoundedAsyncStage(
-                waiter=self._block_ready, depth=self.async_depth,
-                timers=self._pipe_timers, name="serving_pipeline"),
-            "ready": [],                  # joined, un-harvested blocks
-            "block_seq": 0,
-        }
-        if spec:
-            self._dev["hist"] = self.upload(self._hist_array(reqs))
-            self._dev["plen_hi"] = plen.copy()
-            self._dev["rem_lo"] = rem.copy()
+            self._dev.update(
+                hist=self.upload(self._hist_array(reqs)), plen=plen,
+                rem=rem, has_eos=has_eos, plen_hi=plen.copy(),
+                rem_lo=rem.copy())
         else:
             self._dev["seeds"] = self.upload(seeds)
 
-    def _pipeline_step(self) -> int:
-        """One pipelined iteration: plan + dispatch block k+1 while the
-        device still runs block k; harvest only when forced."""
+    def _pipeline_step(self, admit: bool = True) -> Optional[int]:
+        """One more decode block, planned from counts and dispatched
+        behind whatever is in flight; then fold what is due
+        (:meth:`_settle`), or everything where the next plan needs it
+        (:meth:`_drain`: a live row with an EOS id, a pool short of
+        pages, the speculative block).  Returns None where the step is
+        not a block's to take (a request became admittable, or no row
+        goes on): the carry is released with the blocks left in the
+        queue, and ``_step`` goes on along the fused path."""
         dv = self._dev
         st = self.host_stats
         K = self.decode_block_size
-        spec = dv.get("spec", False)
+        spec = dv["spec"]
         K1 = self.spec_k + 1
-        # a queued request became admittable (put_request arrived, or a
-        # reap freed capacity): reconcile so the normal path admits it
-        # exactly when the unpipelined engine would
-        if self._admittable():
-            return self._pipeline_harvest(teardown=True)
-        with st.stage("plan"):
-            # grow pages to cover the next block — exact for the plain
-            # block (the projection is exact for every sequence that can
-            # reach this point un-harvested, see _pipeline_start); for a
-            # speculative block the projection is the FAST bound, so
-            # growth covers the worst-case k+1-wide write span
+        if spec:
             live = [r for r in dv["reqs"] if dv["rem"][r.slot] > 0 and
                     dv["plen"][r.slot] < self.max_seq_len]
-            slots_active = [r.slot for r in live]
-            grow_ok = bool(slots_active)
-            table_dirty = False
-            for s in slots_active:
+        else:
+            # (the device's ``active`` carry retired a finished row by
+            # itself; it writes page 0 in the blocks that follow)
+            live = [r for r in dv["reqs"] if not r.done]
+        if not live or (admit and self._admittable()):
+            if spec:
+                # its host state is bounds: exact only once all is folded
+                produced = self._drain("spec")
+                self._dev = None
+                return produced
+            self._release_carry()
+            return None
+        with st.stage("plan"):
+            # grow pages to cover the next block: exact for the plain
+            # block (every sequence that reaches this point un-folded
+            # runs to a count); for a speculative block the projection is
+            # the FAST bound, so growth covers the worst-case k+1-wide
+            # write span
+            grow_ok, table_dirty = True, False
+            for r in live:
+                s = r.slot
                 self._last_sched[s] = self._sched_seq
                 if spec:
                     want = self._spec_grow_want(int(dv["plen_hi"][s]),
                                                 int(dv["rem"][s]))
                 else:
-                    want = int(min(dv["plen"][s] + min(K, dv["rem"][s]),
-                                   self.max_seq_len))
+                    want = min(r.length + min(K, self._budget(r)),
+                               self.max_seq_len)
                 before = self.allocator.owned(s)
                 if not self.kv.ensure(s, want):
                     grow_ok = False
                     break
                 table_dirty |= self.allocator.owned(s) != before
         if not grow_ok:
-            # out of pages (or nothing left to run): reconcile and hand
-            # control back to the normal path (stall/evict semantics)
-            return self._pipeline_harvest(teardown=True)
+            # out of pages: fold everything and hand control back to the
+            # fused path (stall/evict semantics)
+            produced = self._drain("pages")
+            if self._dev is not None:
+                self._release_carry()
+            return produced
         if table_dirty:
-            dv["page_table"] = self.upload(self.page_table)
+            dv["page_table"] = self.upload(self._table_now())
         sub = self._split_rng()
         if trace.enabled:
             trace.event("decode_block", cat="request",
-                        uids=[r.uid for r in dv["reqs"]],
+                        uids=[r.uid for r in live],
                         ticks=self.decode_block_size, pipelined=True,
                         spec=bool(spec))
         if spec:
@@ -1642,10 +1824,11 @@ class RaggedInferenceEngineV2:
                     dv["pos"], dv["active"], dv["remaining"],
                     dv["page_table"], dv["eos_ids"], dv["do_sample"],
                     dv["temperature"], dv["top_k"], dv["top_p"], sub)
-            block = (toks, mask, prop, accd)
+            work = _Work(next(self._work_seq), True,
+                         (toks, mask, prop, accd),
+                         [(r, 0) for r in live], [])
         else:
-            with self._decode_dispatch(
-                    live, [dv["plen"][r.slot] for r in live]):
+            with self._decode_dispatch(live):
                 (self.kv.cache, dv["last_tok"], dv["pos"], dv["active"],
                  dv["remaining"], toks, mask, moe,
                  loop) = self._run(
@@ -1654,22 +1837,15 @@ class RaggedInferenceEngineV2:
                     dv["active"], dv["remaining"], dv["page_table"],
                     dv["eos_ids"], dv["do_sample"], dv["temperature"],
                     dv["top_k"], dv["top_p"], dv["seeds"], sub)
-            self._counts_pending += self._count_entries(
-                moe, loop, "decode_block", K, len(live))
-            block = (toks, mask)
-        # track the block in the bounded window: past async_depth the
-        # submit first joins the oldest un-joined block (waiting for
-        # its tokens under the "device" bracket), bounding device
-        # run-ahead exactly as the hand-rolled carry did
-        dv["window"].submit(dv["block_seq"], block,
-                            on_done=dv["ready"].append)
-        dv["block_seq"] += 1
+            work = _Work(next(self._work_seq), True, (toks, mask), [],
+                         self._count_entries(moe, loop, "decode_block", K,
+                                             len(live)))
         st.ticks += K
         with st.stage("plan"):
-            # advance the projection past this block and decide whether
-            # the unpipelined engine could have reaped after it
-            finish_possible = False
-            for s in slots_active:
+            # count this block's tokens in, and see who ends with it
+            wait = False
+            for r in live:
+                s = r.slot
                 if spec:
                     # bounds: per tick a slot advances 1..k+1 tokens
                     slow = int(max(0, min(K, dv["rem_lo"][s])))
@@ -1680,82 +1856,37 @@ class RaggedInferenceEngineV2:
                                            self.max_seq_len)
                     dv["rem"][s] -= slow
                     dv["rem_lo"][s] -= K * K1
-                    if (dv["has_eos"][s] or dv["rem_lo"][s] <= 0 or
-                            dv["plen_hi"][s] >= self.max_seq_len):
-                        finish_possible = True
+                    wait |= bool(dv["has_eos"][s] or dv["rem_lo"][s] <= 0
+                                 or dv["plen_hi"][s] >= self.max_seq_len)
                 else:
-                    prod = int(min(K, dv["rem"][s],
-                                   self.max_seq_len - dv["plen"][s]))
-                    dv["rem"][s] -= prod
-                    dv["plen"][s] += prod
-                    if (dv["has_eos"][s] or dv["rem"][s] <= 0 or
-                            dv["plen"][s] >= self.max_seq_len):
-                        finish_possible = True
-            if self.tiering is not None and finish_possible:
-                # the projection says a slot may free at the next
-                # harvest: start NVMe->host reads for the spilled
-                # sequences the FIFO queue would re-admit first, under
-                # the decode block the device is still running
+                    # exact but for an EOS id, which may end the row on
+                    # any token: the fold takes back what it gave
+                    n = int(min(K, self._budget(r),
+                                self.max_seq_len - r.length))
+                    r.pending += n
+                    self._maybe_finish(r)
+                    work.rows.append((r, n))
+                    work.finishes |= r.done
+                    wait |= r.eos_token_id is not None
+            if self.tiering is not None and (wait or work.finishes):
+                # a slot may free at the fold of this block: start
+                # NVMe->host reads for the spilled sequences the FIFO
+                # queue would re-admit first, under the decode block the
+                # device is still running
                 self.tiering.prefetch(
                     [q.uid for q in
                      itertools.islice(self.waiting,
                                       self.prefetch_lookahead)
                      if q.spilled is not None])
-        pending = dv["window"].in_flight + len(dv["ready"])
-        if finish_possible or pending >= self.harvest_interval:
-            return self._pipeline_harvest()
-        return 0
-
-    def _pipeline_harvest(self, teardown: bool = False) -> int:
-        """Fold every pending block back into host request state (ONE
-        blocking fetch), reap finishes, and either keep the
-        device-resident carry (nothing changed) or tear down so the
-        normal path re-plans."""
-        dv = self._dev
-        st = self.host_stats
-        st.harvests += 1
-        spec = dv.get("spec", False)
-        # join every block still tracked by the bounded window (on_done
-        # appends them to dv["ready"] in submit order), then fold the
-        # whole run with ONE blocking fetch
-        dv["window"].drain()
-        blocks = dv["ready"]
-        toks_l, mask_l, last_tok, extra = self.fetch((
-            [p[0] for p in blocks],
-            [p[1] for p in blocks], dv["last_tok"],
-            [p[2:] for p in blocks] if spec else []))
-        with st.stage("harvest"):
-            # np.array: device_get returns READ-ONLY views
-            self.last_tokens = np.array(last_tok)
-            produced = 0
-            for toks, mask in zip(toks_l, mask_l):
-                produced += self._fold_block(
-                    dv["reqs"], np.asarray(toks), np.asarray(mask))
-            if spec:
-                st.spec_proposed += sum(int(p) for p, _ in extra)
-                st.spec_accepted += sum(int(a) for _, a in extra)
-                st.spec_tokens += produced
-            for r in dv["reqs"]:
-                self._maybe_finish(r)
-                if spec and not r.done:
-                    self._draft_len[r.slot] = max(r.length - 1, 0)
-            changed = any(r.done for r in dv["reqs"])
-            self._reap()
-            dv["ready"] = []
-            if teardown or changed:
-                self._dev = None
-            else:
-                # device carry stays authoritative; re-anchor the host
-                # projection on the now-exact lengths (and the
-                # speculative fast/slow bounds collapse to exact)
-                for r in dv["reqs"]:
-                    dv["plen"][r.slot] = r.length
-                    dv["rem"][r.slot] = (r.max_new_tokens -
-                                         len(r.generated))
-                if spec:
-                    np.copyto(dv["plen_hi"], dv["plen"])
-                    np.copyto(dv["rem_lo"], dv["rem"])
-        return produced
+        self._enqueue(work)
+        if spec:
+            # the bounds are re-anchored on exact lengths only
+            if wait or len(self._queue) >= self.harvest_interval:
+                return self._drain("spec")
+            return 0
+        if wait:
+            return self._drain("eos")
+        return self._settle()
 
     # -- the scheduler tick ----------------------------------------------
 
@@ -1779,14 +1910,19 @@ class RaggedInferenceEngineV2:
                 self._control_steps = 0
                 with self.host_stats.stage("plan"):
                     self._controller.tick()
-        if self._dev is not None:
-            return self._pipeline_step()
         st = self.host_stats
         produced = 0
-        if self._tick is not None and self._tick.finishes and self.waiting:
-            # a sequence ends with the tick in flight and a request
-            # waits: what the reap frees decides the admission
-            produced += self._flush_tick()
+        if (self.waiting and any(w.finishes for w in self._queue)
+                and not self._admittable()):
+            # a sequence ends with work in flight, a request waits and
+            # what is free BEFORE that finish does not seat it: what the
+            # reap frees decides the admission
+            produced += self._drain("pages")
+        if self._dev is not None:
+            out = self._pipeline_step()
+            if out is not None:
+                return produced + out
+            # an admission behind the blocks in flight, or no row goes on
         with st.stage("plan"):
             # park finished handoff prefills BEFORE admission: a
             # handoff request never reaches the decode block / spec /
@@ -1800,26 +1936,27 @@ class RaggedInferenceEngineV2:
                     if r is not None and not r.done and not r.lc]
             decoding_ready = (not lc_live and bool(live) and all(
                 r.prefill_done >= r.ctx_len for r in live))
-        if self._tick is not None and (decoding_ready or lc_live):
-            # the blocks and the long-context driver start from the
-            # host's tokens and budgets: fold the tick, and end the step
-            # there, so that a stream has a prompt's first token before
-            # the host turns to the first block (whose own harvest can
-            # take a block's time)
-            return produced + self._flush_tick()
+        if self._queue and (lc_live or (decoding_ready and
+                                        self.spec_mode != "off")):
+            # the long-context driver and the speculative block (its
+            # token history, the draft's catch-up) start from the host's
+            # tokens: fold everything, and end the step there
+            return produced + self._drain("lc" if lc_live else "spec")
+        if self._queue and decoding_ready:
+            # the blocks' carry takes every row's newest token from the
+            # device: a row whose token only the host has (restored from
+            # a spill) goes through one more tick first
+            decoding_ready = all(r.pending for r in live)
         with st.stage("plan"):
             # speculation first: its block writes a k+1-wide span per
             # tick, so it needs more page coverage than a plain block —
             # when the pool can't back it, degrade to the plain decode
-            # block (greedy outputs are unchanged either way; the
-            # decision is taken from EXACT state, so pipelined and
-            # unpipelined runs degrade at the same steps)
+            # block (greedy outputs are unchanged either way)
             spec_block = (decoding_ready and self.spec_mode != "off" and
                           all(self.kv.ensure(
                               r.slot,
-                              self._spec_grow_want(
-                                  r.length, r.max_new_tokens -
-                                  len(r.generated)))
+                              self._spec_grow_want(r.length,
+                                                   self._budget(r)))
                               for r in live))
             all_decoding = (
                 not spec_block and decoding_ready and
@@ -1827,17 +1964,17 @@ class RaggedInferenceEngineV2:
                 all(self.kv.ensure(
                     r.slot,
                     r.length + min(self.decode_block_size,
-                                   r.max_new_tokens - len(r.generated)))
+                                   self._budget(r)))
                     for r in live))
         if spec_block:
             if self.pipeline:
                 self._pipeline_start(live, spec=True)
-                return produced + self._pipeline_step()
+                return produced + self._pipeline_step(admit=False)
             return produced + self._step_spec_block(live)
         if all_decoding:
             if self.pipeline:
                 self._pipeline_start(live)
-                return produced + self._pipeline_step()
+                return produced + self._pipeline_step(admit=False)
             return produced + self._step_decode_block(live)
         # partially-resident sequences tick through the chunked-scan
         # driver — never the fused batch, the decode block, or the
@@ -1852,10 +1989,11 @@ class RaggedInferenceEngineV2:
                 produced += self._lc.tick(r)
         with st.stage("plan"):
             takes = self._plan_rows()
-        if self._tick is not None and (takes is None or self._page_limited):
-            # the pool fell short, or nothing is left to run: what the
-            # tick in flight gives back comes first, then the plan anew
-            produced += self._flush_tick()
+        if self._queue and (takes is None or self._page_limited):
+            # the pool fell short: what the work in flight gives back
+            # comes first, then the plan anew.  Or nothing is left to run
+            # (the end of the work, not a drain)
+            produced += self._drain("pages" if self._page_limited else None)
             with st.stage("plan"):
                 takes = self._plan_rows()
         if takes is None:
@@ -1893,7 +2031,7 @@ class RaggedInferenceEngineV2:
         st.prefill_tokens += counts["prefill_tokens"]
         st.prefill_key_tokens += counts["prefill_key_tokens"]
         st.decode_key_token_steps += counts["decode_key_tokens"]
-        ahead = int(self._tick is not None)
+        ahead = int(bool(self._queue))
         with st.stage("dispatch", program="fused", ahead=ahead, **counts,
                       **self._pool_counts()):
             st.dispatches += 1
@@ -1909,50 +2047,23 @@ class RaggedInferenceEngineV2:
             # budgets count it, and who ends by its count is known now
             r.pending += 1
             self._maybe_finish(r)
-        prev, self._tick = self._tick, _FusedTick(
-            toks=toks, sampling=sampling,
-            counts=self._count_entries(moe, loop, "fused", 1, rows),
-            finishes=any(r.done for r, _ in sampling))
-        if prev is not None:
-            # tick k+1 is queued behind it: NOW wait for tick k's tokens
-            produced += self._harvest_tick(prev)
+        self._enqueue(_Work(
+            next(self._work_seq), False, toks, sampling,
+            self._count_entries(moe, loop, "fused", 1, rows),
+            any(r.done for r, _ in sampling)))
+        if not self.pipeline:
+            return produced + self._drain(None)
         # the next plan is exact without this tick's tokens where nothing
-        # they could say changes it: no end by an EOS id, no handoff
-        # parked the step its token lands, no sequence short of pages or
-        # ticking outside the batch; else it is fetched now, as ever
-        if not (self.pipeline and sampling and not self._page_limited
-                and not lc_live and
-                not any(r.eos_token_id is not None or r.handoff
-                        for r, _ in sampling)):
-            produced += self._flush_tick()
-        return produced
-
-    def _flush_tick(self) -> int:
-        """Harvest the fused tick in flight, if any: host state is exact
-        after it.  Every path that reads tokens, budgets or slots from the
-        host calls this first."""
-        tick, self._tick = self._tick, None
-        return 0 if tick is None else self._harvest_tick(tick)
-
-    def _harvest_tick(self, tick: _FusedTick) -> int:
-        """Fetch one fused tick's tokens, fold them into their requests
-        and reap — the tick's one sync point, whether it comes right
-        after its dispatch or after the next tick's."""
-        self._counts_pending += tick.counts
-        if tick.sampling:
-            toks = np.asarray(self.fetch(tick.toks))
-            with self.host_stats.stage("harvest"):
-                for r, seq_j in tick.sampling:
-                    r.generated.append(int(toks[seq_j]))
-                    r.pending -= 1
-                    self.last_tokens[r.slot] = r.generated[-1]
-                    self.request_latency.on_tokens(r.uid, len(r.generated))
-                    self._maybe_finish(r)
-        # a handoff prefill that just sampled its first token parks NOW
-        # — same step — so the router's next export pulls it without an
-        # extra tick of decode-side latency
-        self._reap(handoff_sweep=True)
-        return len(tick.sampling)
+        # they could say changes it: no sequence short of pages or ticking
+        # outside the batch, no handoff parked the step its token lands,
+        # no end by an EOS id; else they are waited for now, as ever
+        why = ("pages" if self._page_limited else "lc" if lc_live else
+               "handoff" if any(r.handoff for r, _ in sampling) else
+               "eos" if any(r.eos_token_id is not None
+                            for r, _ in sampling) else None)
+        if why is not None:
+            return produced + self._drain(why)
+        return produced + self._settle()
 
     def _admit(self) -> None:
         for i in range(self.max_seqs):
@@ -2005,6 +2116,10 @@ class RaggedInferenceEngineV2:
                 self._unseat(req)
                 self.waiting.appendleft(req)
                 break
+            st = self.host_stats
+            st.admissions += 1
+            # (its first tick is planned behind the work in flight)
+            st.admissions_ahead += int(bool(self._queue))
             if req.spilled is not None:
                 self._restore(req)
 
@@ -2034,7 +2149,7 @@ class RaggedInferenceEngineV2:
             if (r is None or not r.handoff or r.done or r.lc
                     or r.prefill_done < r.ctx_len or not r.generated):
                 continue
-            if self._spill(r):
+            if self._spill(r, reason="handoff"):
                 self.waiting.remove(r)     # _spill parked it there
             else:
                 # planned handoff, not pool exhaustion — fold inline
@@ -2053,7 +2168,7 @@ class RaggedInferenceEngineV2:
         resume from the same sampled prefix)."""
         from deepspeed_tpu.utils.logging import logger
 
-        self._flush_tick()
+        self._drain("pages")
         self._unseat(r, fold=True)
         self.waiting.append(r)             # back of the queue: the freed
         self.evictions += 1                # pages go to older work first
@@ -2066,11 +2181,12 @@ class RaggedInferenceEngineV2:
 
     # -- tiered KV spill/restore: the scheduler's half -------------------
 
-    def _spill(self, r) -> bool:
+    def _spill(self, r, reason: str = "pages") -> bool:
         """Park ``r`` in the spill tiers (``KVState.spill``) and requeue
         it as a RESTORABLE continuation; False when the tiers can't take
-        it (caller falls back to ``_evict``'s re-prefill)."""
-        self._flush_tick()
+        it (caller falls back to ``_evict``'s re-prefill).  ``reason``:
+        why the work in flight is drained for it."""
+        self._drain(reason)
         if not self.kv.spill(r, self.last_tokens[r.slot]):
             return False
         self._unseat(r)

@@ -46,9 +46,11 @@ PROGRAMS = {"draft_prefill", "ragged_decode_block", "ragged_fused_step",
             "spec_verify_block"}
 # keys of a plain engine that served one request; "metrics" and
 # "trace_sampling" come and go with the process-wide registry
-STAGES = {"blocking_gets", "decode_key_token_steps", "device_ms",
-          "device_wait_s", "dispatch_ms", "dispatches", "draft_ms",
-          "fused_ticks", "fused_ticks_ahead", "harvest_ms", "harvests", "host_bound_fraction", "host_s",
+STAGES = {"admissions", "admissions_ahead", "blocking_gets",
+          "decode_blocks", "decode_blocks_ahead", "decode_key_token_steps",
+          "device_ms", "device_wait_s", "dispatch_ms", "dispatches",
+          "draft_ms", "drains", "fused_ticks", "fused_ticks_ahead",
+          "harvest_ms", "harvests", "host_bound_fraction", "host_s",
           "kernels", "meta_uploads", "pipeline_window", "plan_ms", "pool",
           "prefill_key_tokens", "prefill_tokens", "prefix_ms", "reap_s",
           "requests", "restore_ms", "rng_split_s", "sample_s", "spill_ms",

@@ -2,14 +2,17 @@
 
 The load-bearing contracts:
 
-- **Bit-identical parity**: pipelined serving (``pipeline=True``,
-  deferred harvest, device-resident metadata) and the unpipelined host
-  loop (``pipeline=False``) produce the same ``(uid, tokens)`` outputs
-  on mixed prompt-length workloads — greedy AND seeded sampling,
-  including mid-run admissions and eviction backpressure.  The pipeline
-  forces a harvest at every point where the unpipelined engine could
-  have reaped/admitted/evicted, so the dispatch sequence (programs,
-  metadata, rng splits) is identical by construction.
+- **Bit-identical parity, request by request**: pipelined serving
+  (``pipeline=True``: one queue of dispatched, un-folded ticks and decode
+  blocks, planned from counts) and the unpipelined host loop
+  (``pipeline=False``) produce the same ``(uid, tokens)`` outputs on
+  mixed prompt-length workloads, greedy AND seeded sampling, under
+  mid-run admissions, finishes, EOS and eviction backpressure.  The
+  SCHEDULES differ (an admission's tick runs behind the blocks in flight,
+  a finish does not restart the blocks): a row's tokens are its own.
+- **The loop waits only where it must**: on EOS-free traffic nothing
+  drains, every block and every admission goes out with work in flight;
+  what needs a token's value drains and says why.
 - **Steady state is sync-free**: across a decode window the engine
   performs no per-block metadata uploads and no per-block blocking
   ``device_get`` — the ``host_stats`` counters assert it.
@@ -311,19 +314,19 @@ def _log_calls(eng):
     return log
 
 
-def _until_in_flight(eng, limit=200):
-    """Step until a fused tick is left in flight."""
-    for _ in range(limit):
-        eng.step()
-        if eng._tick is not None:
-            return
-    raise AssertionError("no fused tick was ever left in flight")
+def _queued(eng):
+    """The queued (dispatched, un-folded) work of ``eng``, as "T" for a
+    fused tick and "B" for a decode block, oldest first."""
+    return "".join("B" if w.block else "T" for w in eng._queue)
 
 
 class TestFusedTicksAhead:
     """The SplitFuse loop dispatches tick k+1 before it waits for tick k's
     tokens wherever the plan of k+1 is exact without them, and nowhere
     else; the tokens are ``pipeline=False``'s bit for bit."""
+
+    # (that the two engines run the same NUMBER of ticks is no contract:
+    # an admission's tick runs behind the blocks in flight)
 
     @pytest.mark.parametrize("kw", [{}, SEEDED], ids=["greedy", "seeded"])
     def test_parity_on_a_mix_of_chunked_prompts_and_decoding_rows(
@@ -335,8 +338,11 @@ class TestFusedTicksAhead:
         assert len(on) == 6
         _assert_same_outputs(on, off)
         st_on, st_off = eng_on.host_stats, eng_off.host_stats
-        assert st_on.fused_ticks == st_off.fused_ticks > 10
+        assert st_on.fused_ticks > 10 and st_off.fused_ticks > 10
         assert st_on.fused_ticks_ahead > st_on.fused_ticks // 2
+        # three slots, six requests: a finish in flight is waited for
+        # only while a request waits that nothing free can seat
+        assert set(st_on.drain_reasons) <= {"pages"}
         assert st_off.fused_ticks_ahead == 0
         stages = eng_on.serving_stages()
         assert stages["fused_ticks_ahead"] == st_on.fused_ticks_ahead
@@ -356,29 +362,39 @@ class TestFusedTicksAhead:
         # tick before un-fetched, and every fetch but the last comes
         # after the NEXT tick's dispatch
         assert "".join(log) == "D" + "DF" * 4
-        assert eng._tick is not None and eng.has_work()
+        assert _queued(eng) == "T" and eng.has_work()
         assert eng.host_stats.fused_ticks_ahead == 4
         # the token of the tick in flight is not the host's yet
         before = len(eng.slots[0].generated)
         assert eng.slots[0].pending == 1
         assert eng.sync() == 2       # a decode row and the prompt's end
-        assert eng._tick is None and eng.slots[0].pending == 0
+        assert not eng._queue and eng.slots[0].pending == 0
         assert len(eng.slots[0].generated) == before + 1
+        assert eng.host_stats.drain_reasons == {"api": 1}
 
-    def test_a_prompts_first_token_streams_before_the_first_block_is_built(
-            self, params):
-        """The flush at the entry into decode blocks ends its step: the
-        first token reaches ``stream_deltas`` before the host builds the
-        pipeline's carry, as under ``pipeline=False``."""
+    def test_a_prompts_first_token_streams_when_its_tick_ends(self, params):
+        """The first decode block is built from counts and dispatched
+        BEHIND the prompt's tick, its carry taking the first token from
+        the device; then the tick is folded, in the same step: the token
+        reaches ``stream_deltas`` when its tick ends, no block's time
+        later, and the chip has the block to run meanwhile."""
         eng = make(params, True)
         uid = eng.put_request(_prompts([5], seed=1)[0], max_new_tokens=12)
-        assert eng.step() == 0 and eng._tick is not None
+        assert eng.step() == 0 and _queued(eng) == "T"
         assert eng.stream_deltas() == []
-        assert eng.step() == 1 and eng._tick is None and eng._dev is None
+        uploads = eng.host_stats.meta_uploads
+        assert eng.step() == 1 and _queued(eng) == "B"
+        assert eng._dev is not None and eng.slots[0].pending == 4
+        # the carry's last_tok was not uploaded: nine arrays and the seeds
+        assert eng.host_stats.meta_uploads == uploads + 10
         ((got, toks, total, done),) = eng.stream_deltas()
         assert (got, len(toks), total, done) == (uid, 1, 1, False)
-        eng.step()
-        assert eng._dev is not None          # the blocks start now
+        st = eng.host_stats
+        assert (st.decode_blocks, st.decode_blocks_ahead, st.drains) == \
+            (1, 1, 0)
+        ref = make(params, False).generate_all(_prompts([5], seed=1),
+                                               max_new_tokens=12)
+        np.testing.assert_array_equal(eng.drain()[uid], ref[0])
 
     def test_dispatch_spans_say_whether_they_went_ahead(self, params):
         from deepspeed_tpu.telemetry import trace
@@ -408,13 +424,29 @@ class TestFusedTicksAhead:
         kw = dict(max_new_tokens=14)
         if case == "eos":
             kw["eos_token_id"] = 1000     # no token of the vocabulary
-        outs, eng = _serve(params, case != "pipeline_off",
-                           LONG_MIX["sizes"], mid=LONG_MIX["mid"], **kw)
+        eng = make(params, case != "pipeline_off")
+        for p in _prompts(LONG_MIX["sizes"], seed=3):
+            eng.put_request(p, **kw)
+        mid, outs, step_i = dict(LONG_MIX["mid"]), {}, 0
+        while eng.has_work() or mid:
+            for p in mid.pop(step_i, []):
+                eng.put_request(p, **kw)
+            if eng.has_work():
+                eng.step()
+                # no token is left on the device past its step (a tick
+                # that samples nothing has none, and may stay queued)
+                assert not any(w.rows for w in eng._queue)
+                outs.update(eng.get_outputs())
+            step_i += 1
         ref, _ = _serve(params, False, LONG_MIX["sizes"],
                         mid=LONG_MIX["mid"], max_new_tokens=14)
         _assert_same_outputs(outs, ref)
-        assert eng.host_stats.fused_ticks > 10
-        assert eng.host_stats.fused_ticks_ahead == 0
+        st = eng.host_stats
+        assert st.fused_ticks > 10
+        if case == "eos":
+            assert st.drain_reasons == {"eos": st.drains} and st.drains > 10
+        else:
+            assert st.fused_ticks_ahead == st.drains == 0
 
     def test_a_handoff_prefill_is_fetched_and_parked_in_its_own_step(
             self, params):
@@ -428,8 +460,8 @@ class TestFusedTicksAhead:
         parked_at = None
         for i in range(40):
             eng.step()
-            assert eng._tick is None or not any(
-                r.handoff for r, _ in eng._tick.sampling)
+            assert not any(r.handoff for w in eng._queue
+                           for r, _ in w.rows)
             if eng._handoff_ready and parked_at is None:
                 parked_at = i
                 assert eng.host_stats.fused_ticks_ahead > 0
@@ -441,8 +473,9 @@ class TestFusedTicksAhead:
         assert parked_at is not None
         # pulled with a tick of the other request in flight or not:
         # export flushes first
+        assert eng.host_stats.drain_reasons == {"handoff": 1}
         (blob,) = eng.export_handoff()
-        assert eng._tick is None and len(blob["generated"]) == 1
+        assert not eng._queue and len(blob["generated"]) == 1
         alone = make(params, False).generate_all(
             _prompts([29], seed=2), max_new_tokens=1)
         assert list(alone[0][29:]) == blob["generated"]
@@ -460,7 +493,7 @@ class TestFusedTicksAhead:
                     for p in _prompts([5, 40, 33], seed=4)]
             for _ in range(3):
                 eng.step()
-            in_flight = eng._tick is not None
+            in_flight = bool(eng._queue)
             outs = {}
             if op == "cancel":
                 assert eng.cancel(uids[0]) == "decode"
@@ -473,11 +506,11 @@ class TestFusedTicksAhead:
             if op == "drain":
                 outs.update(eng.drain())
             else:
-                assert eng._tick is None
+                assert not eng._queue
                 while eng.has_work():
                     eng.step()
                     outs.update(eng.get_outputs())
-            assert eng._tick is None and not eng.has_work()
+            assert not eng._queue and not eng.has_work()
             return outs, in_flight
 
         (on, flew), (off, never) = run(True), run(False)
@@ -488,16 +521,18 @@ class TestFusedTicksAhead:
     def test_a_page_stall_flushes_the_tick_in_flight_and_evicts_alike(
             self, params):
         """A pool too small for its sequences: the plan that falls short
-        of pages is made again after the tick in flight is folded, so
-        stalls and evictions are ``pipeline=False``'s."""
+        of pages is made again after the work in flight is folded, and
+        whoever is evicted re-prefills its own output: the tokens are
+        ``pipeline=False``'s."""
         eng_kw = dict(max_seqs=3, prefill_chunk=8, page_size=8, num_pages=9,
                       decode_block_size=4, kv_reserve="on_demand")
         on, eng_on = _serve(params, True, [12, 20, 9], eng_kw=eng_kw,
                             max_new_tokens=24)
         off, eng_off = _serve(params, False, [12, 20, 9], eng_kw=eng_kw,
                               max_new_tokens=24)
-        assert eng_on.evictions == eng_off.evictions > 0
+        assert eng_on.evictions > 0 and eng_off.evictions > 0
         assert eng_on.host_stats.fused_ticks_ahead > 0
+        assert eng_on.host_stats.drain_reasons["pages"] > 0
         _assert_same_outputs(on, off)
         eng_on.audit_kv_sharing()       # raises on a leaked page
 
@@ -558,6 +593,203 @@ class TestFusedTicksAhead:
             np.testing.assert_allclose(st_on["loop"]["exit_cdf"],
                                        st_off["loop"]["exit_cdf"],
                                        rtol=1e-5)
+
+
+def _family(family):
+    """``(model, weights)`` of a tiny member of ``family`` in float32: the
+    dense llama of this file, OLMoE's dropless experts, Ouro's looped
+    layers, LFM2's convolution state beside the pages."""
+    if family in ("olmoe", "ouro"):
+        return _tiny(family)
+    if family == "llama":
+        model = LlamaForCausalLM(CFG)
+    else:
+        from deepspeed_tpu.models import lfm2_moe
+        model = lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config(
+            "tinylfm2", dtype=jnp.float32, param_dtype=jnp.float32,
+            remat=False, use_flash_attention=False))
+    return model, jax.jit(model.init)(
+        jax.random.PRNGKey(7), np.zeros((1, 8), np.int32))["params"]
+
+
+CALLERS = 4
+
+
+def _closed_loop(model, weights, pipeline, total=12, **req_kw):
+    """``CALLERS`` callers that each send their next request when the last
+    one's answer is collected, ``total`` requests in all, prompts of 3-19
+    tokens and answers of 9-33: finishes and arrivals fall while decode
+    blocks are in flight.  Returns ``({uid: tokens}, engine, stream)``,
+    ``stream`` the ``(step, uid, new tokens, done)`` of every delta."""
+    eng = RaggedInferenceEngineV2(
+        model, params=weights, pipeline=pipeline, max_seqs=6,
+        max_seq_len=64, prefill_chunk=8, page_size=8, decode_block_size=4,
+        rng=jax.random.PRNGKey(11))
+    rng = np.random.default_rng(5)
+    work = [(rng.integers(1, 64, size=(int(rng.integers(3, 20)),),
+                          dtype=np.int32), int(rng.integers(9, 34)))
+            for _ in range(total)]
+    queue = iter(work)
+
+    def submit():
+        prompt, new = next(queue)
+        eng.put_request(prompt, max_new_tokens=new, **req_kw)
+
+    for _ in range(CALLERS):
+        submit()
+    outs, stream, step_i, sent = {}, [], 0, CALLERS
+    while eng.has_work():
+        eng.step()
+        step_i += 1
+        stream += [(step_i, uid, len(toks), done)
+                   for uid, toks, _, done in eng.stream_deltas()]
+        for uid, toks in eng.get_outputs():
+            outs[uid] = toks
+            if sent < total:
+                submit()
+                sent += 1
+    return outs, eng, stream
+
+
+class TestOneQueueOfDispatchedWork:
+    """Ticks and decode blocks in ONE in-order queue, planned from counts:
+    a periodic harvest, a finish by count and an admission wait for
+    nothing in flight; what needs a token's value drains, and says why."""
+
+    @pytest.mark.parametrize("kw", [{}, SEEDED], ids=["greedy", "seeded"])
+    @pytest.mark.parametrize("family", ["llama", "olmoe", "ouro", "lfm2"])
+    def test_each_requests_tokens_are_the_unpipelined_runs_in_a_closed_loop(
+            self, family, kw):
+        model, weights = _family(family)
+        on, eng, _ = _closed_loop(model, weights, True, **kw)
+        off, _, _ = _closed_loop(model, weights, False, **kw)
+        assert len(on) == 12
+        _assert_same_outputs(on, off)
+        st = eng.host_stats
+        # the schedule really was the queue's: blocks and admissions went
+        # out behind work in flight, and nothing was waited for
+        assert st.decode_blocks_ahead > 10 and st.admissions_ahead >= 6
+        assert st.drains == 0 and not eng._counts_pending
+        eng.audit_kv_sharing()          # raises on a leaked page
+
+    def test_eos_free_traffic_never_drains_and_everything_goes_ahead(
+            self, params):
+        from deepspeed_tpu.telemetry import trace
+        prev = (trace.enabled, trace.buffer_size, trace.clock)
+        trace.clear()
+        trace.configure(enabled=True)
+        try:
+            _, eng, _ = _closed_loop(*_family("llama"), True)
+            spans = [e["args"] for e in trace.snapshot()
+                     if e["name"] == "engine.dispatch"]
+        finally:
+            trace.configure(enabled=prev[0], buffer_size=prev[1],
+                            clock=prev[2])
+            trace.clear()
+        st = eng.host_stats
+        stages = eng.serving_stages()
+        assert st.drains == 0 and "drain_reasons" not in stages
+        # every block goes out with work in flight (the first behind the
+        # last prompt's tick), every admission after the callers' first
+        assert st.decode_blocks_ahead == st.decode_blocks > 10
+        assert st.admissions == 12
+        assert st.admissions_ahead == st.admissions - CALLERS
+        for key in ("drains", "decode_blocks", "decode_blocks_ahead",
+                    "admissions", "admissions_ahead"):
+            assert stages[key] == getattr(st, key)
+        # the spans say the same, and a tick that follows a block is ahead
+        blocks = [a for a in spans if a["program"] == "decode_block"]
+        assert len(blocks) == st.decode_blocks
+        assert sum(a["ahead"] for a in blocks) == st.decode_blocks_ahead
+        after_a_block = [b for a, b in zip(spans, spans[1:])
+                         if a["program"] == "decode_block"
+                         and b["program"] == "fused"]
+        assert len(after_a_block) >= 3
+        assert all(b["ahead"] == 1 for b in after_a_block)
+
+    @pytest.mark.parametrize("case", ["eos", "pages", "handoff", "spec"])
+    def test_what_needs_a_tokens_value_drains_and_says_why(self, params,
+                                                           case):
+        """The same traffic that never drains, with the one thing added
+        that the loop cannot plan past from counts."""
+        eng_kw, kw, extra = {}, dict(max_new_tokens=14), {}
+        if case == "eos":
+            kw["eos_token_id"] = 1000     # no token of the vocabulary
+        elif case == "pages":
+            eng_kw = dict(max_seqs=3, page_size=8, num_pages=9,
+                          kv_reserve="on_demand")
+            kw["max_new_tokens"] = 24
+        elif case == "handoff":
+            extra = dict(handoff=True)
+        else:
+            eng_kw = dict(speculation={"mode": "ngram", "k": 2})
+        eng = make(params, True, **eng_kw)
+        for i, p in enumerate(_prompts([12, 20, 9], seed=3)):
+            eng.put_request(p, **kw, **(extra if i == 1 else {}))
+        while eng.has_work():
+            eng.step()
+            eng.export_handoff()
+        reasons = dict(eng.host_stats.drain_reasons)
+        reasons.pop("api", None)          # (export_handoff is one)
+        assert set(reasons) == {case}, reasons
+        assert eng.serving_stages()["drain_reasons"][case] == reasons[case]
+        eng.audit_kv_sharing()
+
+    def test_a_finish_streams_at_its_blocks_end_with_the_next_block_queued(
+            self, params):
+        """Two rows decode side by side and the short one ends, by its
+        count, in block 3: block 4 goes out for the row that goes on, THEN
+        block 3 is joined and folded, so the finish reaches
+        ``stream_deltas`` as soon as block 3 ends (in the step that
+        dispatches block 4, where a drain of block 3 used to deliver it),
+        the long row is not restarted, and nothing is uploaded for it."""
+        eng = make(params, True, max_seqs=2, harvest_interval=8)
+        long_, short = (eng.put_request(p, max_new_tokens=n) for p, n in
+                        zip(_prompts([5, 6], seed=5), (40, 10)))
+        seen = {}
+        while short not in seen:
+            eng.step()
+            for uid, _, total, done in eng.stream_deltas():
+                if done:
+                    seen[uid] = (eng.host_stats.decode_blocks, total)
+        # 10 tokens: one of the prompt's tick, 4 + 4 + 1 of three blocks
+        assert seen == {short: (4, 10)}
+        assert _queued(eng) == "B" and eng._dev is not None
+        assert [r.uid for r in eng._dev["reqs"] if not r.done] == [long_]
+        # the long row has what two ticks and blocks 1-3 gave it; block 4 flies
+        (row,) = [r for r in eng.slots if r is not None]
+        assert (len(row.generated), row.pending) == (14, 4)
+        uploads = eng.host_stats.meta_uploads
+        outs = eng.drain()
+        assert eng.host_stats.meta_uploads == uploads
+        assert eng.host_stats.drain_reasons == {"api": 1}
+        ref = make(params, False, max_seqs=2).generate_all(
+            _prompts([5, 6], seed=5)[:1], max_new_tokens=40)
+        np.testing.assert_array_equal(outs[long_], ref[0])
+
+    def test_a_periodic_harvest_folds_what_is_joined_and_waits_for_nothing(
+            self, params):
+        """``harvest_interval`` 4, ``async_depth`` 2: at four un-folded
+        blocks the two the window has joined are folded and the two in
+        flight are left alone, so a stream sees 8 tokens every second
+        block."""
+        eng = make(params, True, max_seqs=2, harvest_interval=4,
+                   kv_reserve="worst_case")
+        uid = eng.put_request(_prompts([5], seed=1)[0], max_new_tokens=60)
+        eng.step()
+        eng.step()                       # the tick folded, block 1 queued
+        eng.stream_deltas()
+        got = []
+        for _ in range(8):
+            eng.step()
+            got.append((sum(len(t) for _, t, _, _ in eng.stream_deltas()),
+                        _queued(eng)))
+        assert got == [(0, "BB"), (0, "BBB"), (8, "BB"), (0, "BBB"),
+                       (8, "BB"), (0, "BBB"), (8, "BB"), (0, "BBB")]
+        assert eng.host_stats.drains == 0
+        ref = make(params, False, max_seqs=2).generate_all(
+            _prompts([5], seed=1), max_new_tokens=60)
+        np.testing.assert_array_equal(eng.drain()[uid], ref[0])
 
 
 class TestV1DeferredHarvest:
