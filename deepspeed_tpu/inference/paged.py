@@ -43,6 +43,22 @@ off the output, so the result is exact and the softmax scale stays the
 true head's.  A token then costs the pool ``2 * Hkv * 128`` values a K/V
 layer, twice its unpadded size.
 
+A **latent-attention** layer (``models/glm4_moe_lite.py``) keeps ONE row a
+token and no heads: the normalised compressed vector beside the rotated key
+its heads share, ``W = kv_lora_rank + qk_rope_head_dim`` wide
+(:func:`latent_width`).  Its pool is ``[num_pages, page_size, W]`` under the
+leaf name :data:`LATENT_PAGES`; the row is every head's key and its first
+``kv_lora_rank`` columns the value (:func:`latent_update_and_attend`,
+``ops/latent_attention.py``).  It leads with the page dim like every pool,
+so whatever copies, spills or shares ``leaf[page]`` moves it unchanged, and
+a page's bytes are its leaf's own.  ``W`` is the row **zero-padded to whole
+lane tiles** (576 -> 640 at the published widths: 1,280 bytes a token a
+layer in bf16, a ninth over the unpadded 1,152): the chip lays a minor dim
+out in tiles of 128 lanes whatever the program declares, so an unpadded
+pool would take the same memory, and Mosaic moves whole tiles only (it
+refuses a page copy 576 wide).  A padded key column adds ``0 * 0`` to a
+score and no padded column is a value, so the result is exact.
+
 Beside its pages a sequence may own **per-slot state** that is no page:
 the last ``conv_L_cache - 1`` inputs of each short-convolution layer
 (:func:`ragged_short_conv`), a ``[max_seqs, rows, width]`` leaf named
@@ -58,7 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.ops import routes
+from deepspeed_tpu.ops import latent_attention, routes
 from deepspeed_tpu.ops.paged_decode import paged_decode_attention
 from deepspeed_tpu.ops.paged_prefill import fused_tick_attention
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
@@ -67,6 +83,8 @@ TRASH_PAGE = 0
 _LANES = 128
 # the name of a per-slot state leaf in the ``cache`` collection
 SLOT_STATE = "slot_state"
+# and of a latent-attention layer's pool of one row a token
+LATENT_PAGES = "latent_pages"
 
 # block sizes handed to the upstream ragged paged kernel (see ``attend``):
 # a KV block of at most 512 tokens, and of at most 4096 rows of K or V
@@ -107,6 +125,17 @@ def kv_lane_dim(head_dim: int, kv_cache_dtype: str = "none") -> int:
     head is stored as it is."""
     full_width = (kv_cache_dtype or "none") == "none"
     return _LANES if head_dim == 64 and full_width else head_dim
+
+
+def latent_width(cfg) -> int:
+    """Width of the one row a latent-attention layer caches for a token AS
+    THE POOL STORES IT: its config's ``kv_lora_rank + qk_rope_head_dim``
+    zero-padded to whole 128-lane tiles (576 -> 640); 0 for a model whose
+    layers keep per-head keys and values."""
+    rank = getattr(cfg, "kv_lora_rank", None)
+    if not rank:
+        return 0
+    return -(-(int(rank) + int(cfg.qk_rope_head_dim)) // _LANES) * _LANES
 
 
 def state_layers(cfg) -> int:
@@ -932,6 +961,69 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                          cu_q_lens, num_seqs)
     y = y.transpose(1, 0, 2)[None]                     # [1, H, T, D]
     return y if D == head_dim else y[..., :head_dim]
+
+
+def ref_latent_paged_attention(q: jax.Array, pages: jax.Array,
+                               kv_lens: jax.Array, page_indices: jax.Array,
+                               cu_q_lens: jax.Array, num_seqs: jax.Array, *,
+                               rank: int, sm_scale: float) -> jax.Array:
+    """The XLA twin of ``ops/latent_attention.py``: ``q [T, H, W]`` over
+    the latent pool ``pages [P, page, W]``, ``[T, H, rank]``.  Latent
+    attention is attention with ONE key/value head whose key is the row and
+    whose value is its first ``rank`` columns, so this is
+    :func:`ref_paged_attention` over the pool seen that way (a copy of the
+    pool: test scale, as that function is)."""
+    W = pages.shape[-1]
+    value = jnp.where(jnp.arange(W) < rank, pages, 0)
+    kv = jnp.stack([pages, value], axis=2)              # K even, V odd
+    return ref_paged_attention(q, kv, kv_lens, page_indices, cu_q_lens,
+                               num_seqs, sm_scale=sm_scale)[..., :rank]
+
+
+def latent_update_and_attend(mdl, q: jax.Array, rows: jax.Array,
+                             ragged_meta: Dict[str, jax.Array], cfg, *,
+                             value_width: int, sm_scale: float
+                             ) -> jax.Array:
+    """Inside a latent-attention module: write this tick's rows into the
+    layer's latent page buffer, then attend for all T tokens.
+
+    ``q [T, H, w]``: every head's ABSORBED query (the up-projection of the
+    keys folded in, the rotated part behind it); ``rows [T, w]``: ``[c |
+    k_r]`` of this tick's tokens, the compressed vector after its norm and
+    the shared key after its rotation; both are zero-padded here to the
+    pool's ``W`` (:func:`latent_width`).  Returns ``[T, H, value_width]``:
+    the weighted sums of the rows' first ``value_width`` columns, which the
+    caller up-projects.  As :func:`paged_update_and_attend`: ``kv_lens``
+    includes this tick's tokens, page 0 is the trash page, ``mutable=
+    ["cache"]`` on apply.  A decode program and a fused tick say which they
+    are in ``ragged_meta`` and take ``ops/latent_attention.py``'s kernels;
+    every other caller takes the XLA twin."""
+    assert "staged_kv" not in ragged_meta and "carry_m" not in ragged_meta, (
+        "latent pages have no partial-residency scan")
+    W = latent_width(cfg)
+    pad = lambda a: jnp.pad(                            # noqa: E731
+        a, ((0, 0),) * (a.ndim - 1) + ((0, W - a.shape[-1]),))
+    q, rows = pad(q), pad(rows)
+    P, page = cfg.kv_num_pages, cfg.kv_page_size
+    assert P > 1, "paged_decode requires kv_num_pages (engine sets it)"
+    pages_var = mdl.variable("cache", LATENT_PAGES, jnp.zeros, (P, page, W),
+                             rows.dtype)
+    flat = pages_var.value.reshape(P * page, W)
+    pages = flat.at[ragged_meta["new_kv_dest"]].set(
+        rows.astype(flat.dtype), mode="drop").reshape(P, page, W)
+    pages_var.value = pages
+    ragged = (q, pages, ragged_meta["kv_lens"], ragged_meta["page_indices"],
+              ragged_meta["cu_q_lens"], ragged_meta["num_seqs"])
+    twin = lambda: ref_latent_paged_attention(          # noqa: E731
+        *ragged, rank=value_width, sm_scale=sm_scale)
+    if ragged_meta.get("fused_tick") is True:
+        return latent_attention.fused_tick_attention(
+            *ragged, rank=value_width, sm_scale=sm_scale, fallback=twin)
+    if ragged_meta.get("one_query_a_slot") is True:
+        return latent_attention.decode_attention(
+            *ragged[:4], ragged_meta.get("live"), rank=value_width,
+            sm_scale=sm_scale, fallback=twin)
+    return twin()
 
 
 def kv_dequant_path(head_dim: int) -> str:

@@ -116,6 +116,7 @@ ragged path requires).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import deque
@@ -130,7 +131,7 @@ from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.telemetry import (RequestLatencyTracker, device_scopes,
                                      trace)
 from deepspeed_tpu.utils.async_stage import BoundedAsyncStage, StageTimers
-from deepspeed_tpu.inference.paged import pages_for
+from deepspeed_tpu.inference.paged import latent_width, pages_for
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
 from deepspeed_tpu.inference.sampling import position_keys, sample_logits
 from deepspeed_tpu.inference.v2 import programs
@@ -351,29 +352,20 @@ class RaggedInferenceEngineV2:
         self._loop_attrs = ({} if self.kv_slots == 1 else {
             "ut_steps": self.kv_slots,
             "kv_slots": self.kv_slots * int(mcfg.num_hidden_layers)})
+        refuse = functools.partial(self._refuse_features, o, kv_cache_dtype)
         if self.kv_slots > 1:
-            looped = (f"{type(model).__name__} runs its layers "
-                      f"{self.kv_slots} times a token")
-            spec = SpeculationConfig.coerce(o["speculation"])
-            if spec.mode != "off":
-                raise NotImplementedError(
-                    f"speculation.mode={spec.mode!r}: {looped}, and the "
-                    "speculative block's rollback and draft pool know one "
-                    "K/V slot a layer")
-            if KVTieringConfig.coerce(o["kv_tiering"]).long_context:
-                raise NotImplementedError(
-                    f"kv_tiering.long_context: {looped}, and the partial-"
-                    "residency scan (inference/v2/long_context.py) reads "
-                    "num_hidden_layers as the number of K/V slots")
-            if kv_cache_dtype != "none":
-                raise NotImplementedError(
-                    f"kv_cache_dtype={kv_cache_dtype!r}: {looped}; its "
-                    "pool is full-width (the quantized pool's scale rows "
-                    "have one slot a layer)")
-            if self.tp > 1:
-                raise NotImplementedError(
-                    f"tensor-parallel serving: {looped}, and the pool's "
-                    "sharding rule knows pages of one slot")
+            refuse(
+                f"{type(model).__name__} runs its layers {self.kv_slots} "
+                "times a token",
+                speculation=", and the speculative block's rollback and "
+                "draft pool know one K/V slot a layer",
+                long_context=", and the partial-residency scan (inference/"
+                "v2/long_context.py) reads num_hidden_layers as the number "
+                "of K/V slots",
+                quantized="; its pool is full-width (the quantized pool's "
+                "scale rows have one slot a layer)",
+                tensor_parallel=", and the pool's sharding rule knows pages "
+                "of one slot")
 
         # layers that keep per-slot state beside the pool and no K/V (the
         # short convolutions of models/lfm2_moe.py, read off the config's
@@ -384,36 +376,45 @@ class RaggedInferenceEngineV2:
         self._state_attrs = ({"state_layers": self.state_layers}
                              if self.state_layers else {})
         if self.state_layers:
-            stateful = (f"{type(model).__name__} keeps per-slot state in "
-                        f"{self.state_layers} of its "
-                        f"{int(mcfg.num_hidden_layers)} layers")
-            if PrefixCacheConfig.coerce(o["prefix_cache"]).enabled:
-                raise NotImplementedError(
-                    f"prefix_cache: {stateful}, and a shared prefix (or its "
-                    "copy-on-write page) brings keys and values only — a "
-                    "sequence that starts behind one needs the state AT "
-                    "the prefix's end, which the index does not keep")
-            spec = SpeculationConfig.coerce(o["speculation"])
-            if spec.mode != "off":
-                raise NotImplementedError(
-                    f"speculation.mode={spec.mode!r}: {stateful}, and the "
-                    "speculative block's rollback rewinds page cursors, "
-                    "not state")
-            if KVTieringConfig.coerce(o["kv_tiering"]).long_context:
-                raise NotImplementedError(
-                    f"kv_tiering.long_context: {stateful}, and the partial-"
-                    "residency scan (inference/v2/long_context.py) runs "
-                    "programs of its own that know keys and values only")
-            if kv_cache_dtype != "none":
-                raise NotImplementedError(
-                    f"kv_cache_dtype={kv_cache_dtype!r}: {stateful}; its "
-                    "pool is full-width (heads of 64 are stored padded to "
-                    "the kernels' 128 lanes, which the quantized pool's "
-                    "kernel does not know)")
-            if self.tp > 1:
-                raise NotImplementedError(
-                    f"tensor-parallel serving: {stateful}, and the cache's "
-                    "sharding rule knows page pools only")
+            refuse(
+                f"{type(model).__name__} keeps per-slot state in "
+                f"{self.state_layers} of its {int(mcfg.num_hidden_layers)} "
+                "layers",
+                prefix_cache=", and a shared prefix (or its copy-on-write "
+                "page) brings keys and values only — a sequence that starts "
+                "behind one needs the state AT the prefix's end, which the "
+                "index does not keep",
+                speculation=", and the speculative block's rollback rewinds "
+                "page cursors, not state",
+                long_context=", and the partial-residency scan (inference/"
+                "v2/long_context.py) runs programs of its own that know "
+                "keys and values only",
+                quantized="; its pool is full-width (heads of 64 are stored "
+                "padded to the kernels' 128 lanes, which the quantized "
+                "pool's kernel does not know)",
+                tensor_parallel=", and the cache's sharding rule knows page "
+                "pools only")
+
+        # latent attention (models/glm4_moe_lite.py): a layer's pool holds
+        # ONE row a token, ``[c | k_r]``, and no heads.  It is a pool leaf
+        # like any other to KVState's verbs (attach, ensure, release, copy-
+        # on-write, spill, restore, export, adopt move ``leaf[page]``), so
+        # prefix sharing and tiering serve it; what reads a pool as heads
+        # of keys and values is refused by name
+        self.latent_width = latent_width(mcfg)
+        if self.latent_width:
+            refuse(
+                f"{type(model).__name__} caches one latent row of "
+                f"{self.latent_width} values a token a layer",
+                speculation=", and the speculative verify has no latent "
+                "attention kernel (nor a draft that shares the latent pool)",
+                long_context=", and the partial-residency scan (inference/"
+                "v2/long_context.py) stages and folds per-head keys and "
+                "values",
+                quantized="; the quantized pool's scale rows and kernel are "
+                "per head",
+                tensor_parallel=", which has no head dim for the pool's "
+                "sharding rule to split")
 
         self.page_size = int(page_size)
         self.pages_per_seq = pages_for(max_seq_len, self.page_size)
@@ -542,6 +543,14 @@ class RaggedInferenceEngineV2:
             pfx_cfg=PrefixCacheConfig.coerce(o["prefix_cache"]),
             host_stats=self.host_stats,
             request_latency=self.request_latency, draft_cache=draft_cache)
+        # the most pages any dispatch found in use, and (latent attention)
+        # what a cached token costs the pool as its leaves are allocated:
+        # on every ``engine.dispatch`` span and in ``serving_stages()``
+        self._pages_peak = 0
+        self._latent_attrs = ({} if not self.latent_width else {
+            "latent_bytes_per_token": sum(
+                int(np.prod(leaf.shape[2:])) * leaf.dtype.itemsize
+                for leaf in jax.tree_util.tree_leaves(self.kv.cache))})
 
         self._uid = itertools.count()
         self.waiting: Deque[Request] = deque()
@@ -1048,6 +1057,36 @@ class RaggedInferenceEngineV2:
                     "sequence (long-context residency window)"))
         return reg
 
+    def _refuse_features(self, o: Dict[str, Any], kv_cache_dtype: str,
+                         model_is: str, *, prefix_cache: str = "",
+                         speculation: str = "", long_context: str = "",
+                         quantized: str = "", tensor_parallel: str = ""
+                         ) -> None:
+        """Refuse by name each asked-for feature that cannot serve a model
+        whose cache is not plain per-head K/V pages: ``model_is`` says what
+        the model keeps, each keyword why that feature cannot carry it
+        (empty: it can).  ``o`` holds the resolved options."""
+        from deepspeed_tpu.inference.config import (KVTieringConfig,
+                                                    PrefixCacheConfig,
+                                                    SpeculationConfig)
+
+        spec = SpeculationConfig.coerce(o["speculation"])
+        asked = (
+            (prefix_cache and PrefixCacheConfig.coerce(
+                o["prefix_cache"]).enabled, "prefix_cache", prefix_cache),
+            (speculation and spec.mode != "off",
+             f"speculation.mode={spec.mode!r}", speculation),
+            (long_context and KVTieringConfig.coerce(
+                o["kv_tiering"]).long_context, "kv_tiering.long_context",
+             long_context),
+            (quantized and kv_cache_dtype != "none",
+             f"kv_cache_dtype={kv_cache_dtype!r}", quantized),
+            (tensor_parallel and self.tp > 1, "tensor-parallel serving",
+             tensor_parallel))
+        for wanted, feature, why in asked:
+            if wanted:
+                raise NotImplementedError(f"{feature}: {model_is}{why}")
+
     def serving_stages(self) -> Dict[str, Any]:
         """Per-dispatch host-path breakdown + ``host_bound_fraction``
         (see :class:`~deepspeed_tpu.inference.common.HostStageStats`);
@@ -1087,6 +1126,19 @@ class RaggedInferenceEngineV2:
                           **routes.taken(prefix=paged_prefill.KERNEL),
                           **routes.taken(prefix=paged_decode.KERNEL)}
         st = self.host_stats
+        if self.latent_width:
+            # latent attention: its own two routes and no other, and what
+            # the pool costs a token as its leaves are allocated
+            from deepspeed_tpu.ops import latent_attention
+
+            out["kernels"] = routes.taken(prefix=latent_attention.KERNEL)
+            row_bytes = self._latent_attrs["latent_bytes_per_token"]
+            layers = int(self.cfg.num_hidden_layers)
+            out["latent"] = {
+                "width": self.latent_width, "layers": layers,
+                "bytes_per_token": row_bytes,
+                "bytes_per_token_layer": row_bytes // layers,
+                "pages_peak": self._pages_peak}
         if st.moe_rows:
             from deepspeed_tpu.moe.sharded_moe import GROUPED_MATMUL
             # its two keys, as the trace of this process's programs left them
@@ -1549,9 +1601,11 @@ class RaggedInferenceEngineV2:
         usable pages), and of a looped model's passes: ``ut_steps`` a
         token and ``kv_slots`` (passes x layers) a cached token."""
         total = self.num_pages - 1
-        return {"pages_in_use": total - self.allocator.free_pages,
+        in_use = total - self.allocator.free_pages
+        self._pages_peak = max(self._pages_peak, in_use)
+        return {"pages_in_use": in_use,
                 "pages_total": total, **self._loop_attrs,
-                **self._state_attrs}
+                **self._state_attrs, **self._latent_attrs}
 
     # -- the queue of dispatched work and the decode blocks' carry --------
 

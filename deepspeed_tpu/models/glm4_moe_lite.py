@@ -1,0 +1,318 @@
+"""GLM-4-MoE-Lite model family (zai-org GLM-4.7-Flash, ``model_type:
+glm4_moe_lite``): DeepSeek-V2/V3's multi-head LATENT attention with a
+low-rank query, one leading dense layer, then expert layers of a sigmoid
+router with a selection bias beside one ungated shared expert.
+
+With ``N`` RMSNorm and ``h`` the residual stream, layer ``l`` is::
+
+    h <- h + Attn_l(N_in(h));   h <- h + FF_l(N_post(h))
+
+- **Attention** (every layer; no bias anywhere).  ``c_q = N_q(W_qa x)``
+  (``q_lora_rank``); per head ``[q_nope | q_rope] = W_qb c_q``
+  (``qk_nope_head_dim | qk_rope_head_dim``).  ``[c_raw | k_r_raw] = W_kva
+  x`` (``kv_lora_rank | qk_rope_head_dim``); ``c = N_kv(c_raw)``; ``k_r =
+  RoPE(k_r_raw)``, ONE rotated key shared by the heads; ``q_rope <-
+  RoPE(q_rope)``.  Per head ``[k_nope | v] = W_kvb c`` (``qk_nope_head_dim |
+  v_head_dim``).  Scores ``(q_nope . k_nope + q_rope . k_r) / sqrt(
+  qk_nope_head_dim + qk_rope_head_dim)``, causal softmax in float32, ``o =
+  W_o concat_h(sum_u p v)``.
+- **The same numbers absorbed**, which is what a served token runs: with
+  ``W_kvb = [W_uk | W_uv]`` a head, ``q_nope . k_nope(u) = (W_uk^T q_nope) .
+  c(u)`` and ``sum_u p v(u) = W_uv (sum_u p c(u))``: the heads' queries,
+  ``kv_lora_rank + qk_rope_head_dim`` wide, meet the cached row ``[c |
+  k_r]``, whose first ``kv_lora_rank`` columns are also the value.  **The
+  cache holds ``c`` AFTER its norm and ``k_r`` AFTER its rotation**: one row
+  a token a layer, nothing per head (``inference/paged.py:
+  latent_update_and_attend``; the pool is ``[num_pages, page_size, W]``).
+- **FF, the first** ``first_k_dense_replace`` **layers**: ``models/llama.py``'s
+  SwiGLU MLP, ``intermediate_size`` wide.
+- **FF, every later layer**: ``moe/layer.py``'s dropless form with the
+  sigmoid router (``topk_method: noaux_tc``): ``s = sigmoid(W_g x)`` in
+  float32, the chosen set the top k of ``s + b`` (``b`` the
+  ``e_score_correction_bias``; ``n_group = topk_group = 1``, so no group
+  step), the weights ``routed_scaling_factor * s_i / (sum_chosen s +
+  1e-20)`` without the bias (``norm_topk_prob``); beside it ``n_shared_experts``
+  shared experts as ONE SwiGLU of ``n_shared_experts *
+  moe_intermediate_size``, ungated and unscaled, on every live row.
+- ``logits = W_head N_final(h)``, untied.
+
+RoPE is the half-split ("rotate_half") form over all ``qk_rope_head_dim``
+dims (``models/llama.py:rotary_embedding``); a checkpoint whose rope columns
+are interleaved pairs differs by a fixed permutation of ``q_b_proj``'s and
+``kv_a_proj_with_mqa``'s rope columns.
+
+Only the serving form and the plain forward pass are here: the expert layer
+is dropless whatever the call, and the dense decode cache is refused (it
+keeps per-head keys and values; the paged engine keeps latent rows).  The
+multi-token-prediction block (``num_nextn_predict_layers``) is not built: it
+drafts the token after next and changes no logit of the model.
+
+**First values** (``init``; a checkpoint brings its own): every matrix
+lecun-normal drawn in float32 and rounded to ``param_dtype``, the untied
+head among them, so logits have standard deviation about 1; every norm
+scale 1.  Two follow ``models/lfm2_moe.py``, for its reasons (a model
+served AS DRAWN has to be one whose check can tell a sound program from a
+wrong one): the routed experts' down-projections start at
+:data:`EXPERT_DOWN_INIT_SCALE` of lecun-normal's size, which with
+``routed_scaling_factor`` 1.8 gives a chosen expert the share of a layer's
+update it has there (1.8 x 1/6 = 0.3), and the selection bias is drawn at
+standard deviation :data:`EXPERT_BIAS_INIT_STD`.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.models.llama import (LlamaMLP, RMSNorm, _init_kwargs,
+                                        count_params, rotary_embedding)
+from deepspeed_tpu.models.mixtral import (MixtralConfig, live_rows,
+                                          moe_layer)
+from deepspeed_tpu.models.ouro import in_float32
+
+__all__ = ["Glm4MoeLiteConfig", "Glm4MoeLiteModel", "Glm4MoeLiteForCausalLM",
+           "get_config", "count_params"]
+
+# first values (see the module docstring)
+EXPERT_BIAS_INIT_STD = 0.03
+EXPERT_DOWN_INIT_SCALE = 1.0 / 6.0
+# the DeepSeek-V3 family's code adds this to the sum of the chosen scores
+ROUTED_WEIGHT_EPS = 1e-20
+
+
+@dataclasses.dataclass(frozen=True)
+class Glm4MoeLiteConfig(MixtralConfig):
+    # the source's keys (``intermediate_size`` is the DENSE layers' width)
+    hidden_act: str = "silu"
+    moe_intermediate_size: int = 1536
+    topk_method: str = "noaux_tc"
+    norm_topk_prob: bool = True
+    n_group: int = 1
+    topk_group: int = 1
+    n_routed_experts: int = 64
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 1.8
+    num_experts_per_tok: int = 4
+    first_k_dense_replace: int = 1
+    num_nextn_predict_layers: int = 0
+    rope_scaling: Any = None
+    tie_word_embeddings: bool = False
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    # layers of two feed-forwards do not scan; one chip holds the stage
+    scan_layers: bool = False
+    expert_parallel: bool = False
+    kernel_init: Callable = in_float32(nn.initializers.lecun_normal())
+
+    def __post_init__(self):
+        super().__post_init__()
+        put = lambda k, v: object.__setattr__(self, k, v)
+        for key, want in (("hidden_act", "silu"), ("topk_method", "noaux_tc"),
+                          ("n_group", 1), ("topk_group", 1),
+                          ("rope_scaling", None),
+                          ("tie_word_embeddings", False),
+                          ("attention_bias", False),
+                          ("partial_rotary_factor", 1)):
+            if getattr(self, key) != want:
+                raise NotImplementedError(
+                    f"{key}={getattr(self, key)!r}: only {want!r} is "
+                    "implemented (the published GLM-4.7-Flash's value)")
+        if self.num_nextn_predict_layers:
+            raise NotImplementedError(
+                f"num_nextn_predict_layers={self.num_nextn_predict_layers}: "
+                "the multi-token-prediction block is not built (it drafts "
+                "the token after next and changes no logit; self-drafting "
+                "through the engine's speculative path waits for weights "
+                "under which a draft is likely)")
+        assert not self.scan_layers, "layers of two feed-forwards do not scan"
+        assert self.qk_rope_head_dim % 2 == 0, self.qk_rope_head_dim
+        # what the shared blocks read, under the names they read it by
+        put("num_local_experts", int(self.n_routed_experts))
+        put("sliding_window", None)
+        if self.decode and not self.paged_decode:
+            raise NotImplementedError(
+                "the dense decode cache keeps per-head keys and values; "
+                "latent attention caches one [c | k_r] row a token — serve "
+                "this model through RaggedInferenceEngineV2")
+
+    @property
+    def head_dim(self) -> int:
+        """A query's (and an expanded key's) width; no key of the source."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+PRESETS = {
+    "glm-4.7-flash": dict(
+        vocab_size=154880, hidden_size=2048, intermediate_size=10240,
+        moe_intermediate_size=1536, num_hidden_layers=47,
+        num_attention_heads=20, num_key_value_heads=20, n_routed_experts=64,
+        n_shared_experts=1, num_experts_per_tok=4, routed_scaling_factor=1.8,
+        first_k_dense_replace=1, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        rope_theta=1000000.0, max_position_embeddings=202752),
+    # v and q/k widths differ, heads no power of two, a dense layer first
+    "tinyglm": dict(
+        vocab_size=96, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_hidden_layers=3,
+        num_attention_heads=3, num_key_value_heads=3, n_routed_experts=8,
+        n_shared_experts=1, num_experts_per_tok=2, first_k_dense_replace=1,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=12, max_position_embeddings=256),
+}
+
+
+def get_config(preset: str, **overrides) -> Glm4MoeLiteConfig:
+    kw = dict(PRESETS[preset])
+    kw.update(overrides)
+    return Glm4MoeLiteConfig(**kw)
+
+
+class Glm4MoeLiteAttention(nn.Module):
+    """Multi-head latent attention (the module docstring's equations):
+    expanded where whole sequences are run, absorbed over the paged latent
+    rows where the engine serves."""
+
+    config: Glm4MoeLiteConfig
+
+    @nn.compact
+    def __call__(self, x, positions, deterministic: bool = True,
+                 ragged_meta=None):
+        cfg = self.config
+        B, S, E = x.shape
+        H = cfg.num_attention_heads
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        rank = cfg.kv_lora_rank
+        dense = dict(use_bias=False, dtype=cfg.dtype,
+                     param_dtype=cfg.param_dtype, **_init_kwargs(cfg))
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+        c_q = norm("q_a_layernorm")(
+            nn.Dense(cfg.q_lora_rank, name="q_a_proj", **dense)(x))
+        q = nn.Dense(H * (dn + dr), name="q_b_proj", **dense)(c_q)
+        q = q.reshape(B, S, H, dn + dr).transpose(0, 2, 1, 3)
+        q_nope = q[..., :dn]                                # [B, H, S, dn]
+        q_rope = rotary_embedding(q[..., dn:], positions, cfg.rope_theta)
+        kv_a = nn.Dense(rank + dr, name="kv_a_proj_with_mqa", **dense)(x)
+        c = norm("kv_a_layernorm")(kv_a[..., :rank])        # [B, S, rank]
+        k_r = rotary_embedding(kv_a[..., rank:][:, None], positions,
+                               cfg.rope_theta)[:, 0]        # [B, S, dr]
+        w_kvb = self.param("kv_b_proj", cfg.kernel_init,
+                           (rank, H * (dn + dv)), cfg.param_dtype)
+        w_kvb = w_kvb.astype(cfg.dtype).reshape(rank, H, dn + dv)
+        sm_scale = float(1.0 / np.sqrt(dn + dr))
+        out = lambda y: nn.Dense(E, name="o_proj", **dense)(
+            y.reshape(B, S, H * dv))
+
+        if cfg.paged_decode:
+            from deepspeed_tpu.inference.paged import latent_update_and_attend
+
+            assert ragged_meta is not None and B == 1, (
+                "paged token batches are [1, T] with the engine's "
+                "ragged_meta")
+            with jax.named_scope("absorb"):
+                q_c = jnp.einsum("hsn,chn->shc", q_nope[0], w_kvb[..., :dn])
+            q_abs = jnp.concatenate(
+                [q_c, q_rope[0].transpose(1, 0, 2)], axis=-1)  # [S, H, W]
+            rows = jnp.concatenate([c[0], k_r[0]], axis=-1)    # [S, W]
+            o_c = latent_update_and_attend(
+                self, q_abs, rows, ragged_meta, cfg, value_width=rank,
+                sm_scale=sm_scale)                             # [S, H, rank]
+            with jax.named_scope("absorb"):
+                y = jnp.einsum("shc,chv->shv", o_c, w_kvb[..., dn:])
+            return out(y[None])
+
+        # whole sequences from their first token: expanded, nothing cached
+        with jax.named_scope("kv_b"):
+            kv = jnp.einsum("bsc,chd->bhsd", c, w_kvb)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+        scores = (jnp.einsum("bhqn,bhkn->bhqk", q_nope, k_nope,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bhqr,bkr->bhqk", q_rope, k_r,
+                               preferred_element_type=jnp.float32))
+        causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+        p = jax.nn.softmax(
+            jnp.where(causal, scores * sm_scale, -jnp.inf), axis=-1)
+        y = jnp.einsum("bhqk,bhkv->bqhv", p.astype(cfg.dtype), v)
+        return out(y)
+
+
+class Glm4MoeLiteBlock(nn.Module):
+    config: Glm4MoeLiteConfig
+    layer_idx: int
+
+    @nn.compact
+    def __call__(self, x, positions, deterministic: bool = True,
+                 ragged_meta=None):
+        cfg = self.config
+        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+        x = x + Glm4MoeLiteAttention(cfg, name="self_attn")(
+            norm("input_layernorm")(x), positions, deterministic,
+            ragged_meta)
+        h = norm("post_attention_layernorm")(x)
+        if self.layer_idx < cfg.first_k_dense_replace:
+            return x + LlamaMLP(cfg, name="mlp")(h)
+        live = live_rows(ragged_meta)
+        y, _ = moe_layer(
+            cfg, "mlp", intermediate_size=cfg.moe_intermediate_size,
+            normalize_weights=cfg.norm_topk_prob, scoring="sigmoid",
+            expert_bias=True, expert_bias_init_std=EXPERT_BIAS_INIT_STD,
+            down_init_scale=EXPERT_DOWN_INIT_SCALE,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            routed_weight_eps=ROUTED_WEIGHT_EPS,
+            dispatch_impl="grouped")(h, live=live)
+        if cfg.n_shared_experts:
+            # ungated, unscaled, on every live row; padding rows and
+            # inactive slots stay zeros as the routed path leaves them
+            with jax.named_scope("moe.shared"):
+                shared = LlamaMLP(dataclasses.replace(
+                    cfg, intermediate_size=(cfg.n_shared_experts
+                                            * cfg.moe_intermediate_size)),
+                    name="shared_experts")(h)
+                if live is not None:
+                    shared = jnp.where(live.reshape(h.shape[:-1])[..., None],
+                                       shared, 0)
+                y = y + shared
+        return x + y
+
+
+class Glm4MoeLiteModel(nn.Module):
+    """Hidden states after the final norm."""
+
+    config: Glm4MoeLiteConfig
+
+    @nn.compact
+    def __call__(self, input_ids, positions=None, deterministic: bool = True,
+                 ragged_meta=None):
+        cfg = self.config
+        if positions is None:
+            positions = jnp.arange(input_ids.shape[1])
+        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
+                     param_dtype=cfg.param_dtype, name="embed_tokens",
+                     embedding_init=in_float32(
+                         nn.initializers.variance_scaling(
+                             1.0, "fan_in", "normal", out_axis=0)))(input_ids)
+        for i in range(cfg.num_hidden_layers):
+            x = Glm4MoeLiteBlock(cfg, i, name=f"layers_{i}")(
+                x, positions, deterministic, ragged_meta)
+        return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
+
+
+class Glm4MoeLiteForCausalLM(nn.Module):
+    config: Glm4MoeLiteConfig
+
+    @nn.compact
+    def __call__(self, input_ids, positions=None, deterministic: bool = True,
+                 ragged_meta=None):
+        cfg = self.config
+        x = Glm4MoeLiteModel(cfg, name="model")(
+            input_ids, positions, deterministic, ragged_meta)
+        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                        param_dtype=cfg.param_dtype, name="lm_head",
+                        **_init_kwargs(cfg))(x)

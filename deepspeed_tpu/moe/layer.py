@@ -125,6 +125,9 @@ class MoE(nn.Module):
     expert_bias: bool = False
     expert_bias_init_std: float = 0.0
     routed_scaling_factor: float = 1.0
+    # what the sigmoid router adds to the renormalising sum of the chosen
+    # scores (LFM2-MoE's code 1e-6, the DeepSeek-V3 family's 1e-20)
+    routed_weight_eps: float = 1e-6
     # first values of the experts' down-projection ``w2``, as a share of
     # lecun-normal's (a family's own: ``models/lfm2_moe.py`` says why)
     down_init_scale: float = 1.0
@@ -400,7 +403,7 @@ class MoE(nn.Module):
                 if sigmoid:
                     experts, weights, swaps = sigmoid_bias_routing(
                         logits, bias, cfg.k, cfg.normalize_weights,
-                        cfg.routed_scaling_factor)
+                        cfg.routed_scaling_factor, cfg.routed_weight_eps)
                     l_aux = jnp.zeros((), jnp.float32)
                     if live is not None:
                         swaps = swaps & live.reshape(-1)

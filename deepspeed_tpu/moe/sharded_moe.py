@@ -398,15 +398,16 @@ def topk_routing(logits: jax.Array, k: int, normalize_weights: bool
 
 def sigmoid_bias_routing(logits: jax.Array, bias: Optional[jax.Array],
                          k: int, normalize_weights: bool,
-                         scaling_factor: float = 1.0
+                         scaling_factor: float = 1.0, eps: float = 1e-6
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``(experts [G, k] int32, weights [G, k] f32, bias_swaps)`` of a
     float32 sigmoid router with a per-expert SELECTION bias (DeepSeek-V3's
     auxiliary-loss-free balancing; LFM2-MoE's ``use_expert_bias``): the
     scores are ``sigmoid(logits)``, the chosen set the k largest of
     ``score + bias``, and the weights the chosen SCORES, without the bias,
-    renormalised over the k (``+ 1e-6`` in the denominator) or as they
-    are, times ``scaling_factor``.  ``bias_swaps [G]`` marks the tokens
+    renormalised over the k (``+ eps`` in the denominator: 1e-6 in
+    LFM2-MoE's code, 1e-20 in the DeepSeek-V3 family's) or as they are,
+    times ``scaling_factor``.  ``bias_swaps [G]`` marks the tokens
     whose chosen set is not the k largest scores: what the bias changed."""
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, plain = jax.lax.top_k(scores, k)
@@ -416,7 +417,7 @@ def sigmoid_bias_routing(logits: jax.Array, bias: Optional[jax.Array],
         _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if normalize_weights:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
     E = logits.shape[-1]
     chosen = lambda idx: jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.int32), 1)
     swaps = jnp.any(chosen(experts) != chosen(plain), axis=-1)

@@ -460,6 +460,66 @@ def test_a_looped_models_programs_hold_each_layer_once(one_chip,
                               "paged_attention_decode": "pallas-decode-2x16"}
 
 
+# glm-4.7-flash-serve: 20 heads over rows of 512 + 64 stored as 640, pages
+# of 64, 8 slots of 258 pages, a pool of 2,457 pages
+LATENT = dict(S=8, pp=258, pool=2457, page=64, H=20, W=640, rank=512)
+
+
+def test_latent_decode_kernel_at_the_serving_configuration(chip_compile):
+    """``ops/latent_attention.py``'s decode kernel at the group its rule
+    gives for ``glm-4.7-flash-serve``."""
+    from deepspeed_tpu.ops import latent_attention as la
+
+    c, i32, bf = LATENT, jnp.int32, jnp.bfloat16
+    group, _, _ = la.tiling(c["page"], c["pp"], c["W"], c["rank"], bf)
+    assert group == 16
+    text = chip_compile(
+        functools.partial(la._decode, rank=c["rank"], sm_scale=1 / 16.0,
+                          group=group, interpret=False),
+        ((c["S"], c["H"], c["W"]), bf),
+        ((c["pool"], c["page"], c["W"]), bf), ((c["S"],), i32),
+        ((c["S"], c["pp"]), i32), ((c["S"],), jnp.bool_))
+    assert f"%{la.NAME_DECODE}" in text
+
+
+@pytest.mark.parametrize("chunk", [512, 1024])
+def test_latent_chunk_kernel_at_the_serving_configuration(chip_compile,
+                                                          chunk):
+    """The chunk kernel at the tiling its rule gives, for the fused tick of
+    ``glm-4.7-flash-serve`` (a chunk of 512 beside 8 slots, padded to whole
+    sublane tiles) and for a chunk of 1,024, which still fits VMEM."""
+    from deepspeed_tpu.ops import latent_attention as la
+
+    c, i32, bf = LATENT, jnp.int32, jnp.bfloat16
+    _, rt, kb = la.tiling(c["page"], c["pp"], c["W"], c["rank"], bf)
+    T = -(-(c["S"] + chunk) // 16) * 16
+    assert la.chunk_vmem_bytes(T, c["H"], c["W"], c["rank"], rt,
+                               kb * c["page"]) < la._VMEM_CEILING
+    text = chip_compile(
+        functools.partial(la._chunk, rank=c["rank"], sm_scale=1 / 16.0,
+                          rt=rt, kb=kb, interpret=False),
+        ((c["H"], T, c["W"]), bf), ((c["pool"], c["page"], c["W"]), bf),
+        ((c["S"],), i32), ((c["S"], c["pp"]), i32), ((c["S"] + 1,), i32),
+        ((1,), i32))
+    assert f"%{la.NAME_PREFILL}" in text
+
+
+def test_a_latent_pool_of_576_columns_is_laid_out_in_640(chip_compile):
+    """Why the pool stores the row padded: the chip's compiler refuses a
+    page copy that is no whole number of lane tiles, the memref of an
+    unpadded pool being 640 wide whatever the program declares."""
+    from deepspeed_tpu.ops import latent_attention as la
+
+    c, i32, bf = LATENT, jnp.int32, jnp.bfloat16
+    with pytest.raises(Exception, match="aligned to tiling"):
+        chip_compile(
+            functools.partial(la._decode, rank=c["rank"], sm_scale=1 / 16.0,
+                              group=16, interpret=False),
+            ((c["S"], c["H"], 576), bf), ((c["pool"], c["page"], 576), bf),
+            ((c["S"],), i32), ((c["S"], c["pp"]), i32),
+            ((c["S"],), jnp.bool_))
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4

@@ -32,11 +32,13 @@ FAMILIES = {
     "olmoe": {"attn", "moe"},
     "ouro": {"attn", "mlp"},
     "lfm2": {"attn", "conv", "mlp", "moe"},
+    "glm": {"attn", "mlp", "moe"},
 }
 
 
 def _model(family):
-    from deepspeed_tpu.models import lfm2_moe, mistral, olmoe, ouro
+    from deepspeed_tpu.models import (glm4_moe_lite, lfm2_moe, mistral, olmoe,
+                                      ouro)
 
     if family == "mistral":
         return mistral.MistralForCausalLM(mistral.get_config(
@@ -48,6 +50,9 @@ def _model(family):
     if family == "ouro":
         return ouro.OuroForCausalLM(ouro.get_config("tinyouro", **BF16,
                                                     **PLAIN))
+    if family == "glm":
+        return glm4_moe_lite.Glm4MoeLiteForCausalLM(
+            glm4_moe_lite.get_config("tinyglm", **BF16))
     return lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config("tinylfm2",
                                                            **BF16))
 
@@ -126,6 +131,13 @@ def test_every_equation_of_a_serving_program_has_a_kind(served, program):
     if family == "ouro":
         assert ("rest", "early_exit_gate") in {(k, s)
                                                for _, (k, s, _) in rows}
+    if family == "glm":
+        # latent attention's projections and its absorption each under a
+        # sub-scope of their own, the shared expert beside the routed ones
+        assert {"q_a_proj", "q_a_layernorm", "q_b_proj",
+                "kv_a_proj_with_mqa", "kv_a_layernorm", "absorb",
+                "o_proj"} <= {s for _, (k, s, _) in rows if k == "attn"}
+        assert "moe.shared" in subs
 
 
 def test_train_step_shows_the_three_phases_and_the_added_scopes(devices):
@@ -206,6 +218,17 @@ def _train_engine(loss_module, dp=2):
      ("moe", "moe.experts", "-")),
     ("jit(f)/M/model/layers_2/conv/in_proj/dot_general",
      ("conv", "in_proj", "-")),
+    ("jit(f)/M/model/layers_1/self_attn/kv_a_proj_with_mqa/dot_general",
+     ("attn", "kv_a_proj_with_mqa", "-")),
+    ("jit(f)/M/model/layers_1/self_attn/absorb/dot_general",
+     ("attn", "absorb", "-")),
+    ("jit(f)/M/model/layers_1/self_attn/jit(_chunk)/latent_chunk_attention/"
+     "pallas_call", ("attn", "latent_chunk_attention", "-")),
+    ("jit(f)/M/model/layers_1/self_attn/jit(_decode)/"
+     "latent_decode_attention/pallas_call",
+     ("attn", "latent_decode_attention", "-")),
+    ("jit(f)/M/model/layers_1/moe.shared/shared_experts/gate_proj/"
+     "dot_general", ("moe", "moe.shared", "-")),
     ("jit(f)/while/body/closed_call/sample/sample_logits_batched/reduce",
      ("sample", "sample_logits_batched", "-")),
     ("jit(train_step)/optim/mul", ("optim", "optim", "-")),
