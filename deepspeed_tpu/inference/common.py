@@ -4,6 +4,7 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.telemetry import trace
@@ -80,7 +81,9 @@ class HostStageStats:
     ``prefill_key_tokens`` (prompt tokens dispatched in fused ticks,
     and the keys they attend to) and ``decode_key_token_steps`` (context
     tokens read, summed over decode token steps) are the same counts
-    the ``engine.dispatch`` spans carry; ``sample_s`` / ``rng_split_s``
+    the ``engine.dispatch`` spans carry (a fused tick's span also says
+    ``head_rows``: the rows its head multiplied, the ``max_seqs`` it
+    samples and not the tick's ``T``); ``sample_s`` / ``rng_split_s``
     / ``reap_s`` are the seconds of the three brackets outside
     ``STAGES``.  ``fused_ticks`` counts SplitFuse ticks and
     ``fused_ticks_ahead`` those dispatched with earlier work of the
@@ -287,6 +290,25 @@ def logits_of(out):
     """Models may return (logits, aux) tuples (e.g. Mixtral's router
     loss); serving wants the logits."""
     return out[0] if isinstance(out, tuple) else out
+
+
+def sampled_rows(x, ragged_meta):
+    """The rows of the body's output ``x [1, T, E]`` that the head, and a
+    row-wise final norm before it, are to see.  A fused tick samples one
+    row a packed sequence and says which in ``ragged_meta["sample_rows"]``
+    ``[max_seqs]``: those rows are gathered here, ``[1, max_seqs, E]``, so
+    no logit is computed that nothing reads.  Every other caller's metadata
+    lacks the key (a decode block samples every row, training passes no
+    metadata) and ``x`` returns as it came.  Every serving-form model
+    calls this once on its body's output: before the final norm where that
+    is row-wise and the head follows it, after the pass loop where the
+    norm runs inside it (``models/ouro.py``).  The fused tick refuses a
+    model that does not (``inference/v2/programs.py:_fused_forward``)."""
+    rows = None if ragged_meta is None else ragged_meta.get("sample_rows")
+    if rows is None:
+        return x
+    with jax.named_scope("head.rows"):
+        return jnp.take(x, rows, axis=1)
 
 
 def normalize_params(model, params: Any,

@@ -343,8 +343,11 @@ def init_cache(model, *, max_seqs: int, width: int, **placement):
 def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
     """The body both fused ticks share: the ``[1, T]`` forward with its
     paged-KV update, and the logits of the one row a sequence may sample
-    from (``sample_rows``, by packed sequence number).  Returns
-    ``(sel [max_seqs, V], variables)``."""
+    from (``sample_rows``, by packed sequence number).  The rows ride in
+    the metadata, and the model's final norm and head see them alone
+    (``inference/common.py:sampled_rows``): no ``[T, V]`` array exists.  A
+    model whose head ignores them is refused when the tick is traced.
+    Returns ``(sel [max_seqs, V], variables)``."""
     def forward(params, cache, token_ids, positions, kv_lens, page_indices,
                 cu_q_lens, num_seqs, new_kv_dest, sample_rows, *seq_slot):
         if wq:
@@ -359,6 +362,9 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
                 # the chunk kernel (and the decode kernel for the
                 # one-query rows) by
                 "fused_tick": True,
+                # the one row a packed sequence samples from: the head
+                # runs on these rows of the body's output and no other
+                "sample_rows": sample_rows,
                 # rows past the last sequence's end are padding
                 "live": (jnp.arange(token_ids.shape[1]) <
                          cu_q_lens[num_seqs[0]])}
@@ -368,8 +374,14 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
         out, vars_ = model.apply(
             {"params": params, "cache": cache}, token_ids,
             positions=positions, mutable=MUTABLE, ragged_meta=meta)
-        logits = logits_of(out)[0]                      # [T, V]
-        return jnp.take(logits, sample_rows, axis=0), vars_
+        sel = logits_of(out)[0]                         # [max_seqs, V]
+        if sel.shape[0] != sample_rows.shape[0]:
+            raise NotImplementedError(
+                f"{type(model).__name__} ran its head on {sel.shape[0]} "
+                f"rows of a fused tick that samples {sample_rows.shape[0]}"
+                ": a serving-form model gathers ragged_meta['sample_rows'] "
+                "before its head (inference/common.py:sampled_rows)")
+        return sel, vars_
 
     return forward
 

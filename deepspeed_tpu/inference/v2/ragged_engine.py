@@ -1165,6 +1165,10 @@ class RaggedInferenceEngineV2:
                     self.cfg, "use_expert_bias", False) else "sigmoid"),
                 "bias_swaps": st.moe_bias_swaps,
                 "attention_routes": dict(out["kernels"])}
+        # the head of a fused tick multiplies the rows it samples, gathered
+        # from the hidden states: ``max_seqs`` of the tick's ``T``
+        out["fused_head"] = {"form": "sampled-rows", "rows": self.max_seqs,
+                             "tick_rows": self.T}
         # pool pressure: the scale-out router's least-pressure policy
         # reads this (waiting queue + page occupancy, both plain host
         # ints — no device sync)
@@ -2087,7 +2091,7 @@ class RaggedInferenceEngineV2:
         st.decode_key_token_steps += counts["decode_key_tokens"]
         ahead = int(bool(self._queue))
         with st.stage("dispatch", program="fused", ahead=ahead, **counts,
-                      **self._pool_counts()):
+                      head_rows=self.max_seqs, **self._pool_counts()):
             st.dispatches += 1
             toks, self._dev_last_tok, self.kv.cache, moe, loop = \
                 self._run(

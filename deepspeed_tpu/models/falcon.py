@@ -121,6 +121,7 @@ class FalconModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None):
+        from deepspeed_tpu.inference.common import sampled_rows
         from deepspeed_tpu.models.gpt2 import _maybe_remat
         from deepspeed_tpu.parallel.tensor_parallel import tp_embed_kwargs
 
@@ -149,6 +150,8 @@ class FalconModel(nn.Module):
                 x = block_cls(cfg, name=f"h_{i}")(x, positions,
                                                   deterministic,
                                                   ragged_meta)
+        # a fused tick's head rows, before the row-wise final norm
+        x = sampled_rows(x, ragged_meta)
         return nn.LayerNorm(name="ln_f", epsilon=cfg.layer_norm_epsilon,
                             dtype=cfg.dtype, param_dtype=jnp.float32)(x)
 

@@ -290,6 +290,8 @@ class Glm4MoeLiteModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None):
+        from deepspeed_tpu.inference.common import sampled_rows
+
         cfg = self.config
         if positions is None:
             positions = jnp.arange(input_ids.shape[1])
@@ -301,6 +303,8 @@ class Glm4MoeLiteModel(nn.Module):
         for i in range(cfg.num_hidden_layers):
             x = Glm4MoeLiteBlock(cfg, i, name=f"layers_{i}")(
                 x, positions, deterministic, ragged_meta)
+        # a fused tick's head rows, before the row-wise final norm
+        x = sampled_rows(x, ragged_meta)
         return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
 
 

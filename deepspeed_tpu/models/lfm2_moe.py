@@ -259,7 +259,8 @@ class Lfm2MoeBlock(nn.Module):
 
 
 class Lfm2MoeModel(nn.Module):
-    """Hidden states after the final norm; with ``head``, the logits of
+    """Hidden states after the final norm (of the rows a fused tick
+    samples, where its metadata names them); with ``head``, the logits of
     the tied head (the embedding matrix is this module's)."""
 
     config: Lfm2MoeConfig
@@ -267,6 +268,8 @@ class Lfm2MoeModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None, head: bool = False):
+        from deepspeed_tpu.inference.common import sampled_rows
+
         cfg = self.config
         if positions is None:
             positions = jnp.arange(input_ids.shape[1])
@@ -279,6 +282,8 @@ class Lfm2MoeModel(nn.Module):
         for i in range(cfg.num_hidden_layers):
             x = Lfm2MoeBlock(cfg, i, name=f"layers_{i}")(
                 x, positions, deterministic, ragged_meta)
+        # a fused tick's head rows, before the row-wise final norm
+        x = sampled_rows(x, ragged_meta)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
         return embed.attend(x) if head else x
 

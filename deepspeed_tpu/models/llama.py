@@ -404,6 +404,7 @@ class LlamaModel(nn.Module):
             assert not cfg.scan_layers and cfg.pipeline_stages == 1, (
                 "paged_decode requires unrolled layers (the fused step "
                 "threads dynamic ragged metadata the scan carry cannot)")
+        from deepspeed_tpu.inference.common import sampled_rows
         from deepspeed_tpu.parallel.tensor_parallel import tp_embed_kwargs
 
         embed_kwargs = tp_embed_kwargs(cfg.tensor_parallel)
@@ -441,6 +442,8 @@ class LlamaModel(nn.Module):
                 x = block_cls(cfg, name=f"layers_{i}")(x, positions,
                                                        deterministic,
                                                        ragged_meta)
+        # a fused tick's head rows, before the row-wise final norm
+        x = sampled_rows(x, ragged_meta)
         return RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
 
 

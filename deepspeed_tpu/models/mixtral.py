@@ -122,6 +122,7 @@ class MixtralModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None):
+        from deepspeed_tpu.inference.common import sampled_rows
         from deepspeed_tpu.models.gpt2 import _maybe_remat
         from deepspeed_tpu.parallel.tensor_parallel import tp_embed_kwargs
 
@@ -153,6 +154,8 @@ class MixtralModel(nn.Module):
                     cfg, name=f"layers_{i}")(x, positions, deterministic,
                                              ragged_meta)
                 aux = aux + l_aux
+        # a fused tick's head rows, before the row-wise final norm
+        x = sampled_rows(x, ragged_meta)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
         return x, aux / cfg.num_hidden_layers
 

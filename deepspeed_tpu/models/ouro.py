@@ -248,11 +248,15 @@ class OuroForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None):
+        from deepspeed_tpu.inference.common import sampled_rows
         from deepspeed_tpu.models.llama import _wq_kwargs
 
         cfg = self.config
         x = OuroModel(cfg, name="model")(input_ids, positions, deterministic,
                                          ragged_meta)
+        # after the pass loop: the final norm and the exit gate run inside
+        # it over every row, and ``exit_cdf`` sums over the live ones
+        x = sampled_rows(x, ragged_meta)
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="lm_head",
                         kernel_init=cfg.kernel_init, **_wq_kwargs(cfg))(x)

@@ -127,6 +127,7 @@ class Qwen2MoeModel(nn.Module):
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None):
+        from deepspeed_tpu.inference.common import sampled_rows
         from deepspeed_tpu.models.gpt2 import _maybe_remat
         from deepspeed_tpu.parallel.tensor_parallel import tp_embed_kwargs
 
@@ -158,6 +159,8 @@ class Qwen2MoeModel(nn.Module):
                     cfg, name=f"layers_{i}")(x, positions, deterministic,
                                              ragged_meta)
                 aux = aux + l_aux
+        # a fused tick's head rows, before the row-wise final norm
+        x = sampled_rows(x, ragged_meta)
         x = RMSNorm(cfg.rms_norm_eps, cfg.dtype, name="norm")(x)
         return x, aux / cfg.num_hidden_layers
 

@@ -8,10 +8,10 @@ module method in ``jax.named_scope``), the phase markers are JAX's own
 (``jvp(`` forward, ``transpose(jvp(`` backward, ``checkpoint/
 rematted_computation`` recompute), and a handful of ``jax.named_scope``
 sites stand where no module does (``zero.fetch``, ``optim``, ``moe.*``,
-``sample``, ``counts``).  A profiler's trace names a device operation by its
-HLO line only (``%fusion.285 = bf16[32,14336] fusion(...)``), whose result
-name is the instruction's name in the compiled module.  Three pieces join
-the two:
+``sample``, ``counts``, ``head.rows``).  A profiler's trace names a device
+operation by its HLO line only (``%fusion.285 = bf16[32,14336]
+fusion(...)``), whose result name is the instruction's name in the compiled
+module.  Three pieces join the two:
 
 - :func:`classify` folds one ``op_name`` into ``(kind, sub, phase)``;
 - :func:`scope_table` parses a compiled module's text into ``{instruction
@@ -53,7 +53,7 @@ MAX_HOPS = 3
 # ``jax.named_scope`` site of this package): wherever one is in a path, it
 # names the kind
 _SCOPE_KIND = {"zero.fetch": "zero", "optim": "optim", "sample": "sample",
-               "counts": "rest"}
+               "counts": "rest", "head.rows": "head"}
 _MOE_SCOPE = "moe."
 # the module names of the model files -> kinds
 _MODULE_KIND = {

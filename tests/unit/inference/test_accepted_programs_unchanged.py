@@ -22,13 +22,17 @@ import pytest
 ENGINE = dict(max_seqs=4, max_seq_len=64, page_size=16, prefill_chunk=16,
               decode_block_size=4, num_pages=12)
 
-# at the parent of PR 35 (commit 92a6aee), JAX 0.9.0
+# at the parent of PR 35 (commit 92a6aee), JAX 0.9.0.  The three ``fused``
+# hashes were re-taken by PR 41, which MEANT to move them: the tick's final
+# norm and head see the ``max_seqs`` rows it samples (gathered from the
+# hidden states) and no longer all ``T``; the decode blocks, where every
+# row samples, are the parent's to the letter
 EXPECTED = {
-    "mistral.fused": "2fe90b2191f5888e",
+    "mistral.fused": "1ddf10a92080f32a",
     "mistral.decode_block": "1eb55c041ba3f8f4",
-    "olmoe.fused": "a6b96b00615d0f10",
+    "olmoe.fused": "92bf3f0e8437d581",
     "olmoe.decode_block": "03ce8a62bd7290ce",
-    "ouro.fused": "ef7ae60c7448a035",
+    "ouro.fused": "8443cc00e342ba49",
     "ouro.decode_block": "f87646743932c66c",
 }
 
@@ -91,8 +95,8 @@ def hashes():
 @pytest.mark.parametrize("program", sorted(EXPECTED))
 def test_program_lowers_to_the_parents_text(hashes, program):
     assert hashes[program] == EXPECTED[program], (
-        f"{program} no longer lowers to the text it had at the parent of "
-        "PR 35: a model that asked for none of LFM2's mechanisms got one")
+        f"{program} no longer lowers to the text it had when its hash was "
+        "taken: a model that asked for no new mechanism got one")
 
 
 if __name__ == "__main__":

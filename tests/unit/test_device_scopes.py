@@ -123,6 +123,10 @@ def test_every_equation_of_a_serving_program_has_a_kind(served, program):
     assert {k for _, k in in_block} <= FAMILIES[family], sorted(
         {(p, k) for p, k in in_block if k not in FAMILIES[family]})[:5]
     assert FAMILIES[family] | {"head", "embed", "sample"} <= kinds
+    # the gather of a fused tick's sampled rows counts to the head; a decode
+    # block, where every row samples, has none
+    assert (("head", "head.rows") in {(k, s) for _, (k, s, _) in rows}) == (
+        program == "fused")
     subs = {s for _, (k, s, _) in rows if k == "moe"}
     if "moe" in FAMILIES[family]:
         assert {"moe.route", "moe.dispatch", "moe.experts",
@@ -231,6 +235,8 @@ def _train_engine(loss_module, dp=2):
      "dot_general", ("moe", "moe.shared", "-")),
     ("jit(f)/while/body/closed_call/sample/sample_logits_batched/reduce",
      ("sample", "sample_logits_batched", "-")),
+    ("jit(f)/M/head.rows/gather", ("head", "head.rows", "-")),
+    ("jit(f)/M/model/head.rows/gather", ("head", "head.rows", "-")),
     ("jit(train_step)/optim/mul", ("optim", "optim", "-")),
     ("jit(train_step)/zero.fetch/device_put", ("zero", "zero.fetch", "-")),
     ("jit(f)/M/model/layers_0/add;jit(f)/M/model/layers_0/mlp/mul",
