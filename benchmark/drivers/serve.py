@@ -86,12 +86,16 @@ def _warm_sampling_shapes(mcfg, max_seqs: int) -> None:
 
 
 def _pool_filled(cell: H.Cell, records, engine_records, clock_offset: float,
-                 t0: float, seconds: float) -> Optional[Dict[str, float]]:
+                 t0: float, seconds: float, num_pages: int
+                 ) -> Optional[Dict[str, float]]:
     """How much of the reserved KV pool the window's traffic really held:
     pages (a request's tokens rounded up to whole pages) over the engine's
-    admit -> finish spans, sampled every 50 ms.  ``memory_peak_bytes``
-    counts the whole reserved pool whatever the traffic holds of it."""
-    from benchmark import flops
+    admit -> finish spans, sampled every 50 ms, against the usable pages of
+    the engine's own pool (``num_pages`` less the null page, as the
+    dispatch spans' ``pages_total`` and ``kv_pool_peak_pct`` have it: what
+    a token costs is the pool's to know, not ``flops.py``'s).
+    ``memory_peak_bytes`` counts the whole reserved pool whatever the
+    traffic holds of it."""
     from benchmark.layer_metrics import windows as W
 
     spans = W.request_spans(records, engine_records, clock_offset)
@@ -99,14 +103,14 @@ def _pool_filled(cell: H.Cell, records, engine_records, clock_offset: float,
     if spans is None or "kv_pool_bytes" not in eng:
         return None
     page = int(eng["page_size"])
-    page_bytes = page * flops.kv_bytes_per_token(cell.config)
-    pages_total = int(eng["kv_pool_bytes"]) // page_bytes
+    pages_total = num_pages - 1
     held = [sum(-(-W.kv_tokens_held(s, t0 + k * 0.05) // page)
                 for s in spans) for k in range(max(int(seconds / 0.05), 1))]
     return {"pages_total": pages_total,
             "mean_share": float(np.mean(held)) / pages_total,
             "peak_share": float(max(held)) / pages_total,
-            "peak_bytes": float(max(held)) * page_bytes}
+            "peak_bytes": float(max(held)) * int(eng["kv_pool_bytes"])
+            / num_pages}
 
 
 def _check_alone(cell: H.Cell, srv, params, seed: int) -> Dict[str, Any]:
@@ -169,8 +173,7 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
 
     timeline = {"imports_and_devices_s": H.now() - t_process}
     with count_compiles() as setup_compiles:
-        params = jax.jit(model.init)(
-            H.seed_key(seed), np.zeros((1, 8), np.int32))["params"]
+        params = H.init_params(model, cell.config, seed)
         rs = ReplicaSet(lambda i: RaggedInferenceEngineV2(
             model, params=params, **cell.config["engine"]), 1)
         jax.block_until_ready(params)
@@ -223,6 +226,7 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
         engine_records = engine.request_latency.completed()
         routes = dict(stages["kernels"])
         evictions = int(engine.evictions)
+        num_pages = int(engine.num_pages)
         device = H.device_block(devices)
     finally:
         rs.close()
@@ -278,13 +282,23 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
     clock_offset = time.perf_counter() - H.now()
     correct = (check["ok"] and not failed and bool(sample) and routes_ok
                and window_compiles.count == 0)
+    compared = {"requests_in_sample_at_least": H.compared(len(sample), 1),
+                "failed_requests": H.compared(len(failed), 0),
+                "window_compiles": H.compared(window_compiles.count, 0),
+                "routes_as_expected": H.compared(int(routes_ok), 1)}
+    if "max_gap" in check:
+        compared["logit_gap_max"] = H.compared(max(check["max_gap"]),
+                                               check["margin"])
+    else:                       # a correctness request failed outright
+        compared["check_requests_ok"] = H.compared(0, 1)
     H.log({"phase": "window", "cell": cell.name, "seed": seed,
            "seconds": seconds, "loop": loop,
            "rate_per_s": traffic.get("rate_per_s"),
            "requests_in_sample": len(sample), "failed": len(failed),
            "by_fifth": by_fifth, "evictions": evictions,
            "kv_pool_filled": _pool_filled(cell, records, engine_records,
-                                          clock_offset, t0, seconds),
+                                          clock_offset, t0, seconds,
+                                          num_pages),
            "errors": sorted({str(r["error"]) for r in failed}),
            "out_tokens_per_s": sum(r["max_new_tokens"] for r in done)
            / seconds,
@@ -303,7 +317,7 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
     if not trace:
         metrics = H.end_to_end_metrics(cell, values)
         return H.result_line(correct, len(sample), len(failed), metrics,
-                             device)
+                             device, check=compared)
     reduced = tr.reduce()
     ctx = {"cell": cell, "t0": t0, "seconds": seconds,
            "engine_clock_offset": clock_offset,
@@ -315,4 +329,4 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
     metrics = H.layer_metrics(cell, ctx)
     device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
     return H.result_line(correct, len(sample), len(failed), metrics, device,
-                         reduced.breakdown())
+                         reduced.breakdown(), check=compared)

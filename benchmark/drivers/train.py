@@ -186,6 +186,17 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
     routes_ok = allow_cpu or all(taken.get(r) == v for r, v in want.items())
     correct = bool(check["ok"] and np.all(np.isfinite(losses)) and fell
                    and routes_ok and window_compiles.count == 0)
+    compared = {
+        "position_loss_abs_diff_max": H.compared(
+            check["position_loss_max_abs_diff"],
+            check["position_loss_abs_tol"]),
+        "loss_rel_diff_max": H.compared(check["loss_max_rel_diff"],
+                                        check["loss_rel_tol"]),
+        "loss_last_less_first": H.compared(
+            float(np.mean(losses[-k:])) - float(np.mean(losses[:k]))
+            if len(losses) >= 2 else 0.0, 0.0),
+        "window_compiles": H.compared(window_compiles.count, 0),
+        "routes_as_expected": H.compared(int(routes_ok), 1)}
     rate = n * tokens_per_step / window / cell.chips
     values = {"setup_s": setup_s, "train_tokens_per_s": rate}
     per_token = flops.train_flops_per_token(cell.config, seq)
@@ -211,7 +222,8 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
 
     if not trace:
         return H.result_line(correct, n, 0,
-                             H.end_to_end_metrics(cell, values), device)
+                             H.end_to_end_metrics(cell, values), device,
+                             check=compared)
     reduced = tr.reduce()
     ctx = {"cell": cell, "trace": reduced,
            "trace_steps": int(sl["steps"]), "seq_len": seq,
@@ -219,4 +231,4 @@ def run(cell: H.Cell, seed: int, seconds: float, trace: bool,
            "peaks": None if allow_cpu else H.peaks_for(device["kind"])}
     device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
     return H.result_line(correct, n, 0, H.layer_metrics(cell, ctx), device,
-                         reduced.breakdown())
+                         reduced.breakdown(), check=compared)

@@ -6,8 +6,9 @@ cell is found in ``BENCHMARK.json``; its configuration in
 ``benchmark/configs/<config>.json``; its mix in
 ``benchmark/traffic/<traffic>.json``; its driver in
 ``benchmark/drivers/<kind>.py``; a generator in
-``benchmark/generators/<name>.py``; a per-layer metric ``family.cell`` in
-``benchmark/layer_metrics/<family>.py``; the plain reference of a model
+``benchmark/generators/<name>.py``; a per-layer metric ``reader`` (several
+cells listed) or ``reader.suffix`` (one cell) in
+``benchmark/layer_metrics/<reader>.py``; the plain reference of a model
 family in ``benchmark/reference/<family>.py``.  A later PR adds files and
 manifest entries and edits none.
 """
@@ -15,7 +16,9 @@ from __future__ import annotations
 
 import importlib
 import json
+import math
 import os
+import sys
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -137,6 +140,35 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(int(seed) % (2 ** 31 - 1))
 
 
+def init_params(model: Any, config: Dict[str, Any], seed: int) -> Any:
+    """The configuration's weights from ``--seed``: the program's own
+    ``model.init``, in the type they are served in, made on the device in
+    ONE jitted call.  Where the configuration's ``weights`` asks for
+    ``centre_matrices``, every leaf of two or more dimensions has its mean
+    taken out in float32 and is rounded back: jax draws a bf16 normal 1.2 %
+    of a standard deviation below zero, a rank-one all-ones part in every
+    matrix, under which one token wins at every position on some seeds and
+    a dozen keys are ill-conditioned on others (PERF.md section 6, PR 42)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    centre = bool(config.get("weights", {}).get("centre_matrices"))
+
+    def centred(w):
+        if w.ndim < 2:
+            return w
+        w32 = w.astype(jnp.float32)
+        return (w32 - jnp.mean(w32)).astype(w.dtype)
+
+    def make(key, tokens):
+        params = model.init(key, tokens)["params"]
+        return (jax.tree_util.tree_map(centred, params) if centre
+                else params)
+
+    return jax.jit(make)(seed_key(seed), np.zeros((1, 8), np.int32))
+
+
 def enable_compile_cache() -> str:
     """JAX's persistent cache at the program's fixed place
     (``JAX_COMPILATION_CACHE_DIR`` where set, else ``<repo>/.jax_cache``),
@@ -191,13 +223,17 @@ class TraceSlice:
 
 # -- the last line -----------------------------------------------------------
 
+def reader_of(metric_name: str) -> str:
+    """``reader`` or ``reader.suffix`` -> the reader's file name."""
+    return metric_name.split(".", 1)[0]
+
+
 def layer_metrics(cell: Cell, ctx: Dict[str, Any]) -> Dict[str, Any]:
     """Every per-layer metric this cell lists, each from its own reader;
     a reader that finds nothing to read returns None and is left out."""
     out: Dict[str, Any] = {}
     for m in cell.metrics("per_layer"):
-        family = m["name"].split(".", 1)[0]
-        value = plugin("layer_metrics", family).read(ctx)
+        value = plugin("layer_metrics", reader_of(m["name"])).read(ctx)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
@@ -228,15 +264,31 @@ def write_out(name: str, obj: Any) -> str:
     return path
 
 
+def compared(value: float, limit: float) -> Dict[str, float]:
+    """One number the run's ``correct`` was decided on, beside its limit
+    (a number that is not finite is written as the largest float: the line
+    stays JSON)."""
+    value = float(value)
+    return {"value": value if math.isfinite(value) else sys.float_info.max,
+            "limit": float(limit)}
+
+
 def result_line(correct: bool, attempted: int, failed: int,
                 metrics: Dict[str, Any], device: Dict[str, Any],
-                breakdown: Optional[Dict[str, Any]] = None) -> str:
+                breakdown: Optional[Dict[str, Any]] = None,
+                check: Optional[Dict[str, Any]] = None) -> str:
+    """The last line.  ``check`` holds every number compared for
+    ``correct``, each with its limit; it comes last in the line and is
+    printed as the last line of standard error too."""
     line: Dict[str, Any] = {"correct": bool(correct),
                             "attempted": int(attempted),
                             "failed": int(failed), "metrics": metrics,
                             "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if check is not None:
+        line["check"] = check
+        print("check " + json.dumps(check), file=sys.stderr, flush=True)
     return json.dumps(line)
 
 

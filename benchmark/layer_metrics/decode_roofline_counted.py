@@ -8,8 +8,9 @@ must move = ticks x (weights + KV bytes x (key_tokens + rows x (ticks - 1)
 
 Both sides per block, as in ``prefill_roofline_counted``: mean needed
 bytes of the blocks DISPATCHED in the slice over mean device time of the
-blocks RUN in it.  Beside ``decode_roofline``, which estimates the live
-contexts from the requests' records."""
+blocks RUN in it.  (The estimate of the live contexts from the requests'
+records that stood beside it, ``decode_roofline``, went with PR 42: the
+two read within 0.1 point in the ledger.)"""
 from benchmark import flops
 from benchmark.layer_metrics import host_spans
 from benchmark.layer_metrics import windows as W

@@ -1,9 +1,7 @@
 """The grouped expert matmul's share of the device's busy time in a hybrid
 model: self time of the ``ragged-dot...`` custom calls over the busy time
-of the traced slice.  The kernel alone, told by its NAME: ``moe_expert_
-share_pct`` tells routing, dispatch and combine by their shapes, and at 32
-rows x 4 experts a token the routed copies are 128, the lane width that
-every attention shape of this model carries too."""
+of the traced slice.  The kernel alone, told by its NAME; routing,
+dispatch and combine with it are ``scope_moe_pct``."""
 from benchmark.layer_metrics import moe_trace
 
 

@@ -2,10 +2,9 @@
 pages: the largest ``pages_in_use`` / ``pages_total`` over the
 ``engine.dispatch`` spans (the allocator's own numbers at each dispatch:
 whole pages granted, whatever a page holds).  The window line's
-``kv_pool_filled`` estimates the same from request records and
-``flops.kv_bytes_per_token``, which counts one K/V slot a layer; this is
-the number to trust for a model with several.  A program whose spans carry
-no page counts gives nothing to read."""
+``kv_pool_filled`` estimates the same over the whole window from request
+records, against the same count of usable pages.  A program whose spans
+carry no page counts gives nothing to read."""
 from benchmark.layer_metrics import host_spans
 
 

@@ -8,8 +8,9 @@ A tick dispatched in the slice may run after it and the slice's first run
 was dispatched before it, so both sides are taken per tick: mean needed
 work of the ticks DISPATCHED in the slice over mean device time of the
 ticks RUN in it.  The decode rows that ride in the same ticks, and the
-head, are left out of the needed work, as in ``prefill_roofline``, the
-estimate this one stands beside."""
+head, are left out of the needed work.  (The estimate from request
+records that stood beside it, ``prefill_roofline``, went with PR 42: it
+read within 2 points of this in every line of the ledger.)"""
 from benchmark import flops
 from benchmark.layer_metrics import host_spans
 from benchmark.layer_metrics import windows as W

@@ -4,8 +4,8 @@ not by shape: self time of every operation under a module that holds
 ``moe.dispatch``: sort and gather; ``moe.experts``: the grouped matmuls;
 ``moe.combine``: gather back and weighted sum) with the norm that feeds it,
 over the summed self time of the traced slice (``scope_trace``).  The
-architecture-agnostic reading of ``moe_expert_share_pct`` (a shape rule)
-and ``hybrid_expert_share_pct`` (the kernel alone)."""
+whole layer in every architecture; ``hybrid_expert_share_pct`` beside it
+reads the kernel alone."""
 from benchmark.layer_metrics import scope_trace
 
 

@@ -3,11 +3,7 @@ to the traced slice.  A reader gets one ``ctx`` dict from its driver and
 returns a number, or None where there is nothing to read."""
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
-
-
-def overlap(a0: float, a1: float, b0: float, b1: float) -> float:
-    return max(0.0, min(a1, b1) - max(a0, b0))
+from typing import Any, Dict, List, Optional
 
 
 def engine_records_in_window(ctx: Dict[str, Any]) -> List[Dict[str, Any]]:
@@ -20,11 +16,6 @@ def engine_records_in_window(ctx: Dict[str, Any]) -> List[Dict[str, Any]]:
     t1 = t0 + ctx["seconds"]
     return [r for r in ctx.get("engine_records", [])
             if t0 <= r["submit_t"] < t1]
-
-
-def trace_slice(ctx: Dict[str, Any]) -> Optional[Tuple[float, float]]:
-    t = ctx.get("trace_t")
-    return None if not t or t[0] is None or t[1] is None else (t[0], t[1])
 
 
 def program_seconds(ctx: Dict[str, Any], role: str) -> Optional[float]:
@@ -74,11 +65,6 @@ def request_spans(records: List[Dict[str, Any]],
                     "prompt_tokens": float(r["prompt_tokens"]),
                     "new_tokens": float(e["tokens"])})
     return out
-
-
-def spans(ctx: Dict[str, Any]) -> Optional[List[Dict[str, float]]]:
-    return request_spans(ctx["records"], ctx.get("engine_records", []),
-                         ctx.get("engine_clock_offset", 0.0))
 
 
 def kv_tokens_held(span: Dict[str, float], t: float) -> float:

@@ -36,7 +36,6 @@ def main(argv=None) -> int:
     p.add_argument("--precision", default="float8")
     args = p.parse_args(argv)
 
-    import jax
     import numpy as np
 
     from benchmark import harness as H
@@ -49,8 +48,7 @@ def main(argv=None) -> int:
         H.build_model_config(config))
     spec = config["correct"]
     for seed in (int(s) for s in args.seeds.split(",")):
-        params = jax.jit(model.init)(
-            H.seed_key(seed), np.zeros((1, 8), np.int32))["params"]
+        params = H.init_params(model, config, seed)
         rng = np.random.default_rng([seed, 0xC0])     # the driver's prompts
         worst, over = [], []
         for n in spec["prompt_tokens"]:

@@ -16,6 +16,11 @@ parameter tree's leaves, by the names flax gives them.
 
 Departures from the description: none in the mathematics.  The weights are
 whatever the caller hands over (the program's random weights, cast up).
+
+``mask_shift`` and ``precision`` are set only where the tolerance of the
+serving check is set or tested: ``precision="float8"`` rounds every matmul
+operand of the layers to e4m3, the nearest precision below the
+configuration's bf16 (the control of ``benchmark/margin.py``).
 """
 from __future__ import annotations
 
@@ -57,14 +62,22 @@ def _rope(x, positions, theta):
 
 
 def _layer(x, layer, *, heads: int, kv_heads: int, eps: float, theta: float,
-           window: Optional[int], mask_shift: int):
-    f32 = lambda a: a.astype(jnp.float32)
+           window: Optional[int], mask_shift: int,
+           precision: str = "float32"):
+    if precision == "float32":
+        q8 = lambda a: a
+    else:
+        low = {"float8": jnp.float8_e4m3fn, "bfloat16": jnp.bfloat16}
+        q8 = lambda a: a.astype(low[precision]).astype(jnp.float32)
+    scale = lambda a: a.astype(jnp.float32)
+    f32 = lambda a: q8(a.astype(jnp.float32))
+    mm = lambda a, b: q8(a) @ b
     s = x.shape[0]
     attn, mlp = layer["self_attn"], layer["mlp"]
-    h = _rms_norm(x, f32(layer["input_layernorm"]["scale"]), eps)
-    q = h @ f32(attn["q_proj"]["kernel"])
-    k = h @ f32(attn["k_proj"]["kernel"])
-    v = h @ f32(attn["v_proj"]["kernel"])
+    h = _rms_norm(x, scale(layer["input_layernorm"]["scale"]), eps)
+    q = mm(h, f32(attn["q_proj"]["kernel"]))
+    k = mm(h, f32(attn["k_proj"]["kernel"]))
+    v = mm(h, f32(attn["v_proj"]["kernel"]))
     d = q.shape[-1] // heads
     pos = jnp.arange(s)
     q = _rope(q.reshape(s, heads, d), pos, theta)
@@ -73,7 +86,7 @@ def _layer(x, layer, *, heads: int, kv_heads: int, eps: float, theta: float,
     group = heads // kv_heads
     k = jnp.repeat(k, group, axis=1)
     v = jnp.repeat(v, group, axis=1)
-    scores = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(d)
+    scores = jnp.einsum("qhd,khd->hqk", q8(q), q8(k)) / np.sqrt(d)
     # key j is seen by query i iff j <= i and j > i - window.  mask_shift
     # moves the causal edge; only the test of the tolerance sets it
     i, j = pos[:, None], pos[None, :]
@@ -81,16 +94,18 @@ def _layer(x, layer, *, heads: int, kv_heads: int, eps: float, theta: float,
     if window is not None:
         keep &= j > i - window
     scores = jnp.where(keep[None], scores, -jnp.inf)
-    y = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(scores, axis=-1), v)
-    x = x + y.reshape(s, heads * d) @ f32(attn["o_proj"]["kernel"])
-    h = _rms_norm(x, f32(layer["post_attention_layernorm"]["scale"]), eps)
-    gate = h @ f32(mlp["gate_proj"]["kernel"])
-    up = h @ f32(mlp["up_proj"]["kernel"])
-    return x + (jax.nn.silu(gate) * up) @ f32(mlp["down_proj"]["kernel"])
+    y = jnp.einsum("hqk,khd->qhd", q8(jax.nn.softmax(scores, axis=-1)),
+                   q8(v))
+    x = x + mm(y.reshape(s, heads * d), f32(attn["o_proj"]["kernel"]))
+    h = _rms_norm(x, scale(layer["post_attention_layernorm"]["scale"]), eps)
+    gate = mm(h, f32(mlp["gate_proj"]["kernel"]))
+    up = mm(h, f32(mlp["up_proj"]["kernel"]))
+    return x + mm(jax.nn.silu(gate) * up, f32(mlp["down_proj"]["kernel"]))
 
 
 def logits(params: Dict[str, Any], config: Dict[str, Any], tokens,
-           mask_shift: int = 0, last: Optional[int] = None) -> jax.Array:
+           mask_shift: int = 0, last: Optional[int] = None,
+           precision: str = "float32") -> jax.Array:
     """Float32 logits ``[S, V]`` (the last ``last`` positions if given) of
     one sequence ``tokens`` under ``config`` (the configuration file's
     keys, the source's names)."""
@@ -102,7 +117,8 @@ def logits(params: Dict[str, Any], config: Dict[str, Any], tokens,
               kv_heads=int(config["num_key_value_heads"]),
               eps=float(config["rms_norm_eps"]),
               theta=float(config["rope_theta"]),
-              window=config.get("sliding_window"), mask_shift=mask_shift)
+              window=config.get("sliding_window"), mask_shift=mask_shift,
+              precision=precision)
     tokens = jnp.asarray(tokens, jnp.int32)
     with jax.default_matmul_precision("highest"):
         step = jax.jit(lambda x, layer: _layer(x, layer, **kw))

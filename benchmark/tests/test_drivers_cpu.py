@@ -16,7 +16,10 @@ LAST_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 def _check_line(line, names):
     obj = json.loads(line)
-    assert set(obj) == LAST_KEYS
+    # every number compared for ``correct`` beside its limit, last in the line
+    assert set(obj) == LAST_KEYS | {"check"} and list(obj)[-1] == "check"
+    assert obj["check"] and all(set(v) == {"value", "limit"}
+                                for v in obj["check"].values())
     assert set(obj["metrics"]) == set(names)
     for v in obj["metrics"].values():
         assert set(v) == {"value", "unit"} and v["value"] > 0
@@ -36,6 +39,34 @@ def test_serve_driver(name, names):
     line = serve.run(tiny.serve_cell(name), 2 ** 31 + 11, 3.0, False,
                      time.monotonic(), allow_cpu=True)
     _check_line(line, names)
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(monkeypatch,
+                                                            capsys):
+    """The rest of a run with the timed path broken underneath: one served
+    token of a correctness request is another token.  ``correct`` comes out
+    false, and the number over its limit is in the line and on stderr."""
+    from benchmark import loadgen
+    from benchmark.drivers import serve
+
+    run_plan = loadgen.run_plan
+
+    def altered(plan):
+        out = run_plan(plan)
+        for rec in out["records"]:
+            if rec.get("generated"):
+                rec["generated"][3] = 1 + (rec["generated"][3] + 7) % 200
+        return out
+
+    monkeypatch.setattr(loadgen, "run_plan", altered)
+    line = serve.run(tiny.serve_cell("longdoc-closed"), 2 ** 31 + 12, 2.0,
+                     False, time.monotonic(), allow_cpu=True)
+    obj = json.loads(line)
+    gap = obj["check"]["logit_gap_max"]
+    assert obj["correct"] is False and obj["failed"] == 0
+    assert gap["value"] > 100 * gap["limit"]
+    last = capsys.readouterr().err.strip().splitlines()[-1]
+    assert last.startswith("check ") and json.loads(last[6:]) == obj["check"]
 
 
 def test_train_driver_on_four_virtual_devices():

@@ -1,6 +1,5 @@
 """The host-span helper and the readers built on it, on a hand-built
 neutral trace: device idle intervals x the worker's spans."""
-import os
 import threading
 import types
 
@@ -15,10 +14,13 @@ from benchmark.layer_metrics import (decode_roofline_counted,
                                      idle_plan_pct, idle_sample_pct,
                                      idle_unnamed_pct,
                                      prefill_roofline_counted)
-from benchmark.tests.test_layer_metrics import CONFIG
 from benchmark.tests.test_trace_reduce import _xspace
 
 MS = 1e6
+CONFIG = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
+              num_attention_heads=4, num_key_value_heads=2, vocab_size=256,
+              programs={"decode": "jit_decode", "prefill": "jit_prefill"},
+              engine={"decode_block_size": 4})
 NEW = {"idle_plan_pct", "idle_feed_pct", "idle_sample_pct",
        "idle_between_ops_pct", "idle_unnamed_pct", "engine_step_host_ms",
        "prefill_roofline_counted", "decode_roofline_counted"}
@@ -267,25 +269,22 @@ def test_the_loader_reads_spans_and_their_counts_from_a_real_profile(
         host_spans.from_planes(dev, host)
 
 
-def test_the_fourteen_entries_are_files_and_entries_only():
-    """test_manifest.py's rule for what this PR added: each new metric is
-    a ``per_layer`` entry at the end of the list with a reader file of its
-    family, on a layer the manifest already named, in a cell that reports
-    the end-to-end metric it moves."""
+def test_which_cells_report_each_host_span_reader():
+    """The five idle shares and the host's step time are every serving
+    cell's (together the shares are its ``device_idle_pct``); a counted
+    roofline is a dense serving cell's alone.  Read through
+    ``Cell.metrics``, so a bare entry with a list and a suffixed entry of
+    one cell count alike."""
     m = H.manifest()
-    new = [x for x in m["per_layer"] if x["name"].split(".")[0] in NEW]
-    assert len(new) == 14 and m["per_layer"][-14:] == new
-    old_layers = {x["layer"] for x in m["per_layer"][:-14]}
-    e2e = {e["name"]: e for e in m["end_to_end"]}
-    for met in new:
-        family, cell = met["name"].split(".")
-        assert os.path.exists(os.path.join(H.HERE, "layer_metrics",
-                                           family + ".py"))
-        assert met["layer"] in old_layers
-        assert met["source"] == ("program_counter" if "roofline" in family
-                                 else "program_span")
-        (w,) = met["workloads"]
-        assert {"chat": "chat-open", "longdoc": "longdoc-closed"}[cell] == w
-        assert w in e2e[met["moves"]]["workloads"]
-        assert set(met) == {"name", "unit", "better", "source", "layer",
-                            "moves", "workloads"}
+    by_reader = {}
+    for w in m["workloads"]:
+        for met in H.Cell(w["name"]).metrics("per_layer"):
+            reader = H.reader_of(met["name"])
+            if reader in NEW:
+                assert met["source"] == ("program_counter" if "roofline"
+                                         in reader else "program_span")
+                by_reader.setdefault(reader, set()).add(w["name"])
+    serving = {w["name"] for w in m["workloads"]} - {"pretrain-zero3"}
+    counted = {"prefill_roofline_counted": {"longdoc-closed"},
+               "decode_roofline_counted": {"chat-open", "reasoning-closed"}}
+    assert by_reader == {r: counted.get(r, serving) for r in NEW}

@@ -1,14 +1,7 @@
-"""The readers that place work in the traced slice by the engine's own
-records: the pairing of client and engine records, and what it refuses."""
-import types
-
-from benchmark.layer_metrics import decode_roofline, prefill_roofline
+"""The pairing of client and engine records that places a request's pages
+in the window (``drivers/serve.py:_pool_filled``), and what it refuses."""
 from benchmark.layer_metrics import windows as W
 
-CONFIG = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
-              num_attention_heads=4, num_key_value_heads=2, vocab_size=256,
-              programs={"decode": "jit_decode", "prefill": "jit_prefill"},
-              engine={"decode_block_size": 4})
 OFFSET = 100.0          # the engine's clock runs this far ahead
 
 
@@ -51,33 +44,3 @@ def test_a_pairing_that_does_not_check_out_is_refused():
     engine[1]["tokens"] += 1
     assert W.request_spans(client, engine, OFFSET) is None
     assert W.request_spans([], engine, OFFSET) is None
-
-
-def _ctx(slice_t):
-    client, engine = _records()
-    cell = types.SimpleNamespace(config=CONFIG)
-    trace = types.SimpleNamespace(
-        program_s={"jit_decode": 0.5, "jit_prefill": 0.5},
-        program_runs={"jit_decode": 10, "jit_prefill": 10})
-    return {"cell": cell, "records": client, "engine_records": engine,
-            "engine_clock_offset": OFFSET, "trace": trace,
-            "trace_t": slice_t,
-            "peaks": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}
-
-
-def test_prefill_roofline_counts_only_what_was_prefilled_in_the_slice():
-    from benchmark import flops
-
-    whole = prefill_roofline.read(_ctx((0.0, 20.0)))
-    want = (flops.prefill_flops(CONFIG, 1000, 500) +
-            flops.prefill_flops(CONFIG, 200, 100)) / 1e12 / 0.5 * 100
-    assert abs(whole - want) < 1e-9 * want
-    # a slice over the first request's second half; the other comes later
-    half = prefill_roofline.read(_ctx((10.61, 10.61 + 0.5 - 1e-9)))
-    want = flops.prefill_flops(CONFIG, 500, 750) / 1e12 / 0.5 * 100
-    assert abs(half - want) < 1e-6 * want
-    # nothing to read where the pairing fails
-    ctx = _ctx((0.0, 20.0))
-    ctx["engine_records"] = ctx["engine_records"][:-1]
-    assert prefill_roofline.read(ctx) is None
-    assert decode_roofline.read(ctx) is None

@@ -8,6 +8,7 @@ import shutil
 import subprocess
 import sys
 import textwrap
+import types
 
 from benchmark import harness as H
 
@@ -88,12 +89,83 @@ def test_every_metric_is_reported_where_its_end_to_end_metric_is():
         assert layer
         for met in layer:
             assert met["moves"] in e2e and met["moves"] in mine, met
-            family = met["name"].split(".", 1)[0]
+            reader = H.reader_of(met["name"])
             assert os.path.exists(os.path.join(
-                H.HERE, "layer_metrics", family + ".py")), family
+                H.HERE, "layer_metrics", reader + ".py")), met
         gen = cell.traffic["generator"]
         assert os.path.exists(os.path.join(H.HERE, "generators",
                                            gen + ".py"))
+
+
+def test_per_layer_is_one_entry_a_reader_and_moves_with_its_cells():
+    """``per_layer`` may hold 128 entries, so an entry is a (reader,
+    ``moves``) pair with the LIST of its cells: bare where it has several,
+    ``<reader>.<suffix>`` where it has one.  No cell is named twice for one
+    pair (it would report one reading under two names), every cell named
+    exists and reports the end-to-end metric the entry moves, and the
+    cells stand in the order of ``workloads``."""
+    m = H.manifest()
+    assert len(m["per_layer"]) <= 128
+    order = [w["name"] for w in m["workloads"]]
+    e2e = {e["name"]: e.get("workloads", order) for e in m["end_to_end"]}
+    seen = set()
+    for met in m["per_layer"]:
+        cells = met["workloads"]
+        assert cells and len(cells) == len(set(cells)), met
+        assert cells == sorted(cells, key=order.index), met
+        assert ("." in met["name"]) == (len(cells) == 1), met
+        for c in cells:
+            assert c in e2e[met["moves"]], met
+            key = (H.reader_of(met["name"]), met["moves"], c)
+            assert key not in seen, met
+            seen.add(key)
+
+
+def test_no_reader_file_is_left_that_nothing_uses():
+    """A file of ``layer_metrics/`` is a reader some entry names, or a
+    helper some other file there (or a driver) imports."""
+    m = H.manifest()
+    named = {H.reader_of(e["name"]) for e in m["per_layer"]}
+    folder = os.path.join(H.HERE, "layer_metrics")
+    files = {f[:-3] for f in os.listdir(folder) if f.endswith(".py")}
+    text = ""
+    for base in (folder, os.path.join(H.HERE, "drivers"), H.HERE):
+        for f in os.listdir(base):
+            if f.endswith(".py"):
+                text += open(os.path.join(base, f)).read()
+    for f in sorted(files - named):
+        assert re.search(r"import .*\b%s\b" % f, text), f
+
+
+def test_a_ninth_cell_adds_suffixed_entries_beside_the_folded_ones(
+        monkeypatch):
+    """How the next ``model_config`` PR lists its cell without editing a
+    list: one ``<reader>.<suffix>`` entry a reader, beside the folded
+    entry.  The new cell reports its own and none of the others'; no other
+    cell reports the new one; every reading keeps its reader."""
+    m = H.manifest()
+    folded = next(e for e in m["per_layer"] if e["name"] == "device_idle_pct")
+    before = {w["name"]: [e["name"] for e in H.Cell(w["name"]).metrics(
+        "per_layer")] for w in m["workloads"]}
+    m["workloads"].append(dict(m["workloads"][2], name="ninth",
+                               traffic="reasoning-closed"))
+    for e in m["end_to_end"]:
+        if e["name"] == "total_tokens_per_s":
+            e["workloads"].append("ninth")
+    mine = [dict(folded, name=f"{r}.ninth", workloads=["ninth"])
+            for r in ("device_idle_pct", "scope_attn_pct")]
+    m["per_layer"] += mine
+    monkeypatch.setattr(H, "manifest", lambda: m)
+    assert [e["name"] for e in H.Cell("ninth").metrics("per_layer")] == \
+        ["device_idle_pct.ninth", "scope_attn_pct.ninth"]
+    for name, had in before.items():
+        assert [e["name"] for e in H.Cell(name).metrics("per_layer")] == had
+    # the harness finds the reader by the part before the first "."
+    monkeypatch.setattr(H, "plugin", lambda group, name:
+                        types.SimpleNamespace(read=lambda ctx: len(name)))
+    assert H.layer_metrics(H.Cell("ninth"), {}) == {
+        "device_idle_pct.ninth": {"value": 15.0, "unit": "%"},
+        "scope_attn_pct.ninth": {"value": 14.0, "unit": "%"}}
 
 
 def test_nothing_in_the_benchmark_imports_bench_or_private_engine_names():

@@ -188,3 +188,63 @@ def test_serve_check_short_prompt_fails_a_shifted_mask_at_the_shipped_margin():
         wrong = dict(cfg, **{k: v for k, v in fault.items() if k not in kw})
         gaps = ref.generated_gaps(params, wrong, prompt, generated, **kw)
         assert (gaps > margin).sum() >= 4, fault
+
+
+# -- the control of the serving check: the nearest precision below bf16 -----
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_serve_check_fails_the_e4m3_reference_at_the_shipped_margin(seed):
+    """The control of ``logit_margin``, at a size a test run can hold
+    (hidden 512, four layers, the whole vocabulary, the configuration's own
+    weights recipe): the reference with every matmul operand rounded to
+    e4m3, put in the program's place at every position of one seeded
+    document, puts a token first that lies OVER the shipped margin under the
+    float32 maximum; the reference rounded to bf16, what the configuration
+    states, stays under it.  At the published size on the chip the two read
+    0.92-2.03 and at most 0.035 (PERF.md section 6, PR 42)."""
+    from benchmark import harness as H
+
+    cfg = H.load_json(H.HERE + "/configs/mistral-7b-serve.json")
+    cfg.update(hidden_size=512, intermediate_size=1024, num_hidden_layers=4,
+               num_attention_heads=8, num_key_value_heads=2)
+    margin = float(cfg["correct"]["logit_margin"])
+    model = H.resolve(cfg["family"]["module"])(H.build_model_config(cfg))
+    params = H.init_params(model, cfg, seed)
+    doc = np.random.default_rng([seed, 0xD0]).integers(
+        1, cfg["vocab_size"], size=96).tolist()
+    exact = np.asarray(ref.logits(params, cfg, doc))
+    at = np.arange(len(doc))
+
+    def widest_gap(precision):
+        low = np.asarray(ref.logits(params, cfg, doc, precision=precision))
+        return float((exact.max(axis=-1) - exact[at, low.argmax(axis=-1)])
+                     .max())
+
+    assert widest_gap("bfloat16") <= margin / 2
+    assert widest_gap("float8") > 2 * margin
+
+
+def test_init_params_centres_matrices_only_where_the_configuration_asks():
+    from benchmark import harness as H
+
+    cfg = H.load_json(H.HERE + "/configs/mistral-7b-serve.json")
+    cfg.update(hidden_size=256, intermediate_size=512, num_hidden_layers=2,
+               num_attention_heads=4, num_key_value_heads=2)
+    assert cfg["weights"] == {"centre_matrices": True}
+    model = H.resolve(cfg["family"]["module"])(H.build_model_config(cfg))
+    centred = H.init_params(model, cfg, 2 ** 31 + 5)
+    again = H.init_params(model, cfg, 2 ** 31 + 5)
+    plain = H.init_params(model, dict(cfg, weights={}), 2 ** 31 + 5)
+    leaves = jax.tree_util.tree_leaves_with_path
+    for (path, c), (_, a), (_, p) in zip(leaves(centred), leaves(again),
+                                         leaves(plain)):
+        assert c.dtype == p.dtype and c.shape == p.shape
+        assert np.array_equal(np.asarray(c, np.float32),
+                              np.asarray(a, np.float32)), path
+        c32, p32 = np.asarray(c, np.float32), np.asarray(p, np.float32)
+        if c.ndim < 2:                      # norm scales: untouched
+            assert np.array_equal(c32, p32), path
+            continue
+        # the bf16 draw lies about 1.2 % of a standard deviation under zero
+        assert p32.mean() < -0.005 * p32.std(), path
+        assert abs(c32.mean()) < 0.002 * c32.std(), path

@@ -1,9 +1,8 @@
 """The ``scope_*_pct`` readers and ``device_unscoped_pct`` on a hand-built
 trace and hand-written compiled texts: the join by program and result name,
 the partition into families and the log line's rest, a program the registry
-does not know, an empty registry, and the manifest's 33 entries."""
+does not know, an empty registry, and which cells list each reader."""
 import json
-import os
 import types
 
 import pytest
@@ -215,28 +214,25 @@ def test_the_real_registry_is_empty_without_an_engine():
     assert scope_trace._tables()[0] == {}
 
 
-def test_the_33_entries_are_files_and_entries_only():
+def test_which_cells_report_each_scope_reader():
+    """Read through ``Cell.metrics``, so a bare entry with a list and a
+    suffixed entry of one cell count alike."""
     m = H.manifest()
-    mine = [e for e in m["per_layer"]
-            if e["name"].split(".", 1)[0] in FAMILIES]
-    assert len(mine) == 33 and m["per_layer"][-33:] == mine
-    cells = {w["name"] for w in m["workloads"]}
-    for e in mine:
-        family = e["name"].split(".", 1)[0]
-        assert os.path.exists(os.path.join(H.HERE, "layer_metrics",
-                                           family + ".py"))
-        assert e["unit"] == "%" and e["source"] == "device_trace"
-        assert len(e["workloads"]) == 1 and e["workloads"][0] in cells
-        e2e = next(x for x in m["end_to_end"] if x["name"] == e["moves"])
-        assert e["workloads"][0] in e2e["workloads"]
-    by_family = {}
-    for e in mine:
-        by_family.setdefault(e["name"].split(".", 1)[0], set()).add(
-            e["name"].split(".", 1)[1])
-    every = {"chat", "longdoc", "reason", "moe", "ouro", "lfm2", "train"}
-    assert by_family == {
-        "scope_attn_pct": every, "scope_mlp_pct": every - {"moe"},
-        "scope_moe_pct": {"moe", "lfm2"}, "scope_conv_pct": {"lfm2"},
+    by_reader = {}
+    for w in m["workloads"]:
+        for e in H.Cell(w["name"]).metrics("per_layer"):
+            reader = H.reader_of(e["name"])
+            if reader in FAMILIES:
+                assert e["unit"] == "%" and e["source"] == "device_trace"
+                by_reader.setdefault(reader, set()).add(w["name"])
+    every = {w["name"] for w in m["workloads"]}
+    expert = {"olmoe-reasoning-closed", "lfm2-reasoning-closed",
+              "glm-longdoc16k-closed"}
+    train = {"pretrain-zero3"}
+    assert by_reader == {
+        "scope_attn_pct": every,
+        "scope_mlp_pct": every - {"olmoe-reasoning-closed"},
+        "scope_moe_pct": expert, "scope_conv_pct": {"lfm2-reasoning-closed"},
         "scope_head_pct": every, "device_unscoped_pct": every,
-        "scope_remat_pct": {"train"}, "scope_bwd_pct": {"train"},
-        "scope_optim_pct": {"train"}}
+        "scope_remat_pct": train, "scope_bwd_pct": train,
+        "scope_optim_pct": train}

@@ -1,7 +1,6 @@
-"""From a profiler trace to numbers.  A strict copy of the per-program
-reduction in ``deepspeed_tpu/telemetry/profiler.py`` (device-plane
-durations per ``jit_<name>``), which swallows every fault and has no busy
-union, no gaps and no collectives; this one raises and has them.
+"""From a profiler trace to numbers: device-plane durations per
+``jit_<name>``, the busy union, self times, gaps and collectives.  A fault
+in the trace raises; nothing is swallowed.
 
 The JAX profiler writes ``<dir>/plugins/profile/<time>/*.xplane.pb``.
 ``jax.profiler.ProfileData`` reads it: planes, their lines, events with a
