@@ -1,0 +1,16 @@
+"""What a sequence's per-slot state costs beside its pages, as the cache's
+state leaves are allocated: ``state_bytes`` of the ``engine.dispatch`` spans
+(the program's own count from its cache's shapes: nine layers of 32 heads
+of a 128 x 128 float32 matrix, 18,874,368).  Spans without the count (a
+model without per-slot state, or the parent of the PR that added it) give
+nothing to read."""
+from benchmark.layer_metrics import host_spans
+
+
+def read(ctx):
+    ht = host_spans.load(ctx)
+    if ht is None:
+        return None
+    got = [int(e[3]["state_bytes"]) for e in ht.spans("engine.dispatch")
+           if "state_bytes" in e[3]]
+    return sum(got) / len(got) if got else None

@@ -179,6 +179,14 @@ class HostStageStats:
         # (a sigmoid router with a selection bias) tokens whose chosen
         # set the bias changed
         self.moe_bias_swaps = 0
+        # a model with per-slot state: sequences a dispatch carried state
+        # for, summed; and (block-selecting attention) the queries past
+        # ``dense_len``, the keys their chosen blocks hold and the keys a
+        # dense pass would read, a KV head
+        self.state_rows = 0
+        self.sparse_rows = 0
+        self.sparse_key_tokens = 0
+        self.sparse_visible_tokens = 0
         # a looped model's exit gate, live rows only:
         self.loop_rows = 0          # rows at dispatch, summed
         self.loop_exit_cdf = []     # P(stopped by pass u), summed, u < U

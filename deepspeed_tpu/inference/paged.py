@@ -64,7 +64,21 @@ the last ``conv_L_cache - 1`` inputs of each short-convolution layer
 (:func:`ragged_short_conv`), a ``[max_seqs, rows, width]`` leaf named
 :data:`SLOT_STATE` in the same ``cache`` collection, indexed by slot.
 ``inference/v2/kv_state.py`` tells the two kinds of leaf apart by that
-name.
+name.  A **linear-attention** layer (``models/minicpm_sala.py``) keeps a
+decaying ``d x d`` float32 matrix a head there instead of keys and values:
+``[max_seqs, H, d, d]`` under the same name and the same contract
+(:func:`ragged_linear_attention`, ``ops/linear_attention.py``): 2 MiB a
+layer a sequence at 32 heads of 128.
+
+A **block-selecting** layer of the same model keeps two pool leaves:
+:data:`SPARSE_KV_PAGES` ``[num_pages, 2 * Hkv, page_size, D]`` (heads lead
+the tokens: with two KV heads a slab a head is whole tiles, where ``[page,
+4, D]`` would be laid out in sixteen sublanes) and :data:`CKEY_PAGES`
+``[num_pages, Hkv * page_size / stride, D]`` float32, the mean key of every
+window of ``kernel`` tokens, kept in the page the window starts in.  From
+``dense_len`` on a query attends the ``topk`` pages its compressed scores
+choose (:func:`sparse_update_and_attend`,
+``ops/block_select_attention.py``).  Both lead with the page dim.
 """
 from __future__ import annotations
 
@@ -74,7 +88,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.ops import latent_attention, routes
+from deepspeed_tpu.ops import (block_select_attention, latent_attention,
+                               linear_attention, routes)
 from deepspeed_tpu.ops.paged_decode import paged_decode_attention
 from deepspeed_tpu.ops.paged_prefill import fused_tick_attention
 from deepspeed_tpu.utils.compat import shard_map as _shard_map_compat
@@ -85,6 +100,12 @@ _LANES = 128
 SLOT_STATE = "slot_state"
 # and of a latent-attention layer's pool of one row a token
 LATENT_PAGES = "latent_pages"
+# and of a block-selecting layer's two pools: keys and values, heads first,
+# and the compressed keys its selection scores
+SPARSE_KV_PAGES = "sparse_kv_pages"
+CKEY_PAGES = "ckey_pages"
+# the mixers of a model whose config names them (models/minicpm_sala.py)
+LINEAR_MIXER = "lightning-attn"
 
 # block sizes handed to the upstream ragged paged kernel (see ``attend``):
 # a KV block of at most 512 tokens, and of at most 4096 rows of K or V
@@ -140,10 +161,46 @@ def latent_width(cfg) -> int:
 
 def state_layers(cfg) -> int:
     """Layers of a model that keep per-slot state beside the pool: the
-    ``"conv"`` entries of its config's ``layer_types``; 0 for a model
-    whose config names no layer types (every layer holds K/V)."""
-    return sum(t == "conv" for t in getattr(cfg, "layer_types", ()))
+    ``"conv"`` entries of its config's ``layer_types`` and the
+    ``"lightning-attn"`` entries of its ``mixer_types``; 0 for a model
+    whose config names neither (every layer holds K/V)."""
+    return (sum(t == "conv" for t in getattr(cfg, "layer_types", ()))
+            + sum(t == LINEAR_MIXER
+                  for t in getattr(cfg, "mixer_types", ())))
 
+
+def sparse_selection(cfg) -> Optional[block_select_attention.Selection]:
+    """The block selection of a model whose attention layers choose their
+    keys (its config's ``selection``), None for every other."""
+    return getattr(cfg, "selection", None)
+
+
+def sparse_key_counts(positions: np.ndarray, sel,
+                      one_row: Optional[np.ndarray] = None
+                      ) -> Dict[str, int]:
+    """What the queries at ``positions`` ask of a block-selecting layer, a
+    KV head.  Of those at or past ``dense_len``: how many they are
+    (``sparse_rows``), the keys their chosen blocks hold at or before them
+    (``sparse_key_tokens``) and the keys a dense pass would read
+    (``sparse_visible_tokens``).  Of all of them: the keys attended, chosen
+    or every earlier one, by the queries of prompt chunks
+    (``attend_prefill_tokens``) and by those that are their sequence's one
+    row (``one_row`` true; ``attend_decode_tokens``).  Host-side, from
+    positions alone: the chosen set's SIZE is the architecture's, which
+    blocks are in it the device's to say."""
+    p = np.asarray(positions, np.int64).reshape(-1)
+    one = (np.ones(p.shape, bool) if one_row is None
+           else np.asarray(one_row, bool).reshape(-1))
+    far = p >= sel.dense_len
+    blocks = np.minimum(sel.topk, p // sel.block_size + 1)
+    chosen = blocks * sel.block_size - (sel.block_size - 1
+                                        - p % sel.block_size)
+    attended = np.where(far, chosen, p + 1)
+    return {"sparse_rows": int(far.sum()),
+            "sparse_key_tokens": int(chosen[far].sum()),
+            "sparse_visible_tokens": int((p[far] + 1).sum()),
+            "attend_prefill_tokens": int(attended[~one].sum()),
+            "attend_decode_tokens": int(attended[one].sum())}
 
 def kv_layers(cfg) -> int:
     """Layers of a model that keep keys and values in the pool."""
@@ -242,6 +299,242 @@ def ragged_short_conv(mdl, u: jax.Array, taps: jax.Array, pos: jax.Array,
     state.value = state.value.at[
         jnp.where(seq_live, seq_slot, S)].set(new, mode="drop")
     return c.astype(u.dtype)
+
+
+def _seq_of_rows(cu: jax.Array, T: int) -> jax.Array:
+    """The packed sequence each of ``T`` rows lies in, by ``cu_q_lens``."""
+    S = cu.shape[0] - 1
+    t_idx = jnp.arange(T, dtype=jnp.int32)
+    return jnp.minimum(jnp.sum(
+        (t_idx[:, None] >= cu[None, 1:]).astype(jnp.int32), axis=1), S - 1)
+
+
+def _one_row_seqs(ragged_meta, T: int):
+    """``(one [S], first [S])``: which packed sequences of a ragged batch
+    have exactly one query row, and every sequence's first row."""
+    cu = ragged_meta["cu_q_lens"]
+    S = cu.shape[0] - 1
+    first = jnp.minimum(cu[:-1], T - 1)
+    one = jnp.logical_and(jnp.arange(S) < ragged_meta["num_seqs"][0],
+                          cu[1:] - cu[:-1] == 1)
+    return one, first
+
+
+def ragged_linear_attention(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
+                            log_decay: jax.Array, pos: jax.Array,
+                            ragged_meta: Dict[str, jax.Array]) -> jax.Array:
+    """Decaying linear attention over a packed ragged token batch, inside a
+    flax module ``mdl``: ``q, k, v [T, H, d]`` (normed, rotated, scaled),
+    ``log_decay [H]``, ``pos [T]`` -> ``o [T, H, d]`` with ``S_t = l S_(t-1)
+    + k_t^T v_t``, ``o_t = q_t S_t`` along each sequence.
+
+    The state is ``cache/slot_state [max_seqs, H, d, d]`` float32 with
+    :func:`ragged_short_conv`'s contract: a sequence's slot is
+    ``ragged_meta["seq_slot"][j]`` by packed sequence number (a decode
+    program leaves it out: row ``s`` IS slot ``s``); every sequence with a
+    live row in this call leaves its state there and no other slot's
+    changes; a sequence whose first row here is at position 0 reads zeros
+    whatever its slot held.
+
+    A decode program (one query a slot) takes the one-token step
+    (``ops/linear_attention.py:decode_step``: live rows first, each on its
+    slot's state where it lies; an idle slot's state does not move).  Every
+    other batch (a fused tick) is separated by ``cu_q_lens``: sequences of
+    two rows or more take the chunked scan over tiles of their own
+    (:func:`~deepspeed_tpu.ops.linear_attention.tile_layout`), sequences of
+    one row the same one-token step; the two write disjoint rows and
+    slots."""
+    T, H, d = q.shape
+    cu = ragged_meta["cu_q_lens"]
+    S = cu.shape[0] - 1
+    state = mdl.variable("cache", SLOT_STATE, jnp.zeros, (S, H, d, d),
+                         jnp.float32)
+    la = linear_attention
+    take = lambda a, rows: jnp.take(a, rows, axis=0)
+
+    def one_token(rows, slots, on, state_now):
+        """The one-token step for the sequences where ``on`` holds: their
+        rows of q, k, v, their slots' states in place."""
+        order, n = la.live_first(on)
+        rows, slots = take(rows, order), take(slots, order)
+        o, new = la.decode_step(
+            take(q, rows), take(k, rows), take(v, rows), log_decay,
+            state_now, slots, take(pos, rows) == 0, n)
+        return rows, jnp.arange(S) < n[0], o, new
+
+    if ragged_meta.get("one_query_a_slot") is True:
+        live = ragged_meta.get("live")
+        live = jnp.ones((S,), bool) if live is None else live
+        slots = jnp.arange(S, dtype=jnp.int32)
+        rows, on, o, state.value = one_token(slots, slots, live, state.value)
+        return jnp.zeros_like(q).at[jnp.where(on, rows, T)].set(
+            o, mode="drop")
+    seq_slot = ragged_meta.get("seq_slot")
+    if seq_slot is None:
+        seq_slot = jnp.arange(S, dtype=jnp.int32)
+    one, first = _one_row_seqs(ragged_meta, T)
+    first_pos = jnp.take(pos, first)
+    lay = la.tile_layout(cu, ragged_meta["num_seqs"], first_pos, T,
+                         la.CHUNK)
+    tiles = lambda a: la.to_tiles(a, lay["rows"]).transpose(1, 0, 2)
+    o_t, st = la.chunk_scan(
+        tiles(q), tiles(k), tiles(v), log_decay, state.value,
+        jnp.take(seq_slot, lay["seq"]), lay["init"], lay["len"])
+    o = la.from_tiles(o_t.transpose(1, 0, 2), lay["rows"], T)
+    # the one-row sequences, each on its slot's state where it lies
+    live = ragged_meta.get("live")
+    if live is not None:
+        one = jnp.logical_and(one, jnp.take(live, first))
+    rows, on, o_one, state.value = one_token(first, seq_slot, one, st)
+    return o.at[jnp.where(on, rows, T)].set(o_one, mode="drop")
+
+
+def _write_compressed_keys(flat_kv, ckeys, pos, seq_of_t, live,
+                           page_indices, sel, Hkv: int, page: int):
+    """The compressed key of every window a live row of this call
+    completes: row at position ``p`` with ``(p + 1) % stride == 0`` ends
+    window ``(p + 1 - kernel) / stride``, whose ``kernel`` keys are read
+    back from the pool (this call's rows are in it) and whose mean goes to
+    the page the window starts in."""
+    T = pos.shape[0]
+    S = page_indices.shape[0]
+    D = flat_kv.shape[-1]
+    kernel, stride, wpb = (sel.kernel_size, sel.kernel_stride,
+                           sel.windows_per_block)
+    done = jnp.logical_and((pos + 1) % stride == 0, pos >= kernel - 1)
+    if live is not None:
+        done = jnp.logical_and(done, live)
+    W = T // stride + S + 1
+    rows = jnp.nonzero(done, size=W, fill_value=T)[0]
+    ok = rows < T
+    p_w = jnp.take(pos, rows, mode="fill", fill_value=kernel - 1)
+    s_w = jnp.take(seq_of_t, rows, mode="fill", fill_value=0)
+    key_pos = p_w[:, None] - (kernel - 1) + jnp.arange(kernel)[None]
+    pg = jnp.maximum(page_indices[s_w[:, None], key_pos // page], 0)
+    g = jnp.arange(Hkv)
+    src = ((pg[:, :, None] * 2 * Hkv + 2 * g[None, None]) * page
+           + (key_pos % page)[:, :, None])                 # [W, kernel, Hkv]
+    mean = jnp.mean(jnp.take(flat_kv, src, axis=0).astype(jnp.float32),
+                    axis=1)                                 # [W, Hkv, D]
+    j = (p_w - (kernel - 1)) // stride
+    home = jnp.maximum(page_indices[s_w, j // wpb], 0)
+    dest = (home[:, None] * Hkv + g[None]) * wpb + (j % wpb)[:, None]
+    dest = jnp.where(ok[:, None], dest, ckeys.shape[0] * ckeys.shape[1])
+    P = ckeys.shape[0]
+    return ckeys.reshape(P * Hkv * wpb, D).at[dest.reshape(-1)].set(
+        mean.reshape(-1, D), mode="drop").reshape(ckeys.shape)
+
+
+def sparse_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
+                             pos: jax.Array,
+                             ragged_meta: Dict[str, jax.Array], cfg, *,
+                             sm_scale: float) -> jax.Array:
+    """Inside a block-selecting attention module: write this tick's keys
+    and values into the layer's pool and the windows they complete into
+    its compressed keys, choose each query's blocks and attend them.
+
+    ``q [T, H, D]``, ``k, v [T, Hkv, D]`` (normed; no rotation), ``pos
+    [T]`` -> ``[T, H, D]``.  As :func:`paged_update_and_attend`:
+    ``kv_lens`` includes this tick's tokens, page 0 is the trash page,
+    ``mutable=["cache"]`` on apply.  A query before ``dense_len`` attends
+    every earlier key, one from there on the keys of its chosen blocks
+    (``ops/block_select_attention.py``; scopes ``compress`` and
+    ``select``).  A decode program's rows take the chosen-page kernel;
+    every other batch is separated by ``cu_q_lens`` as
+    :func:`ragged_linear_attention` separates it: sequences of two rows or
+    more take the masked chunk kernel in tiles, sequences of one row the
+    chosen-page kernel."""
+    bsa = block_select_attention
+    sel = sparse_selection(cfg).check(cfg.kv_page_size)
+    T, H, D = q.shape
+    Hkv = k.shape[1]
+    G = H // Hkv
+    P, page = cfg.kv_num_pages, cfg.kv_page_size
+    assert P > 1, "paged_decode requires kv_num_pages (engine sets it)"
+    wpb = sel.windows_per_block
+    pages_var = mdl.variable("cache", SPARSE_KV_PAGES, jnp.zeros,
+                             (P, 2 * Hkv, page, D), k.dtype)
+    ckey_var = mdl.variable("cache", CKEY_PAGES, jnp.zeros,
+                            (P, Hkv * wpb, D), jnp.float32)
+    page_indices = ragged_meta["page_indices"]
+    S, pp = page_indices.shape
+    cu = ragged_meta["cu_q_lens"]
+    live = ragged_meta.get("live")
+    decode = ragged_meta.get("one_query_a_slot") is True
+
+    dest = ragged_meta["new_kv_dest"]
+    combined = jnp.stack([k, v], axis=2).reshape(T * 2 * Hkv, D)
+    flat_idx = (((dest // page)[:, None] * 2 * Hkv
+                 + jnp.arange(2 * Hkv)[None]) * page
+                + (dest % page)[:, None]).reshape(-1)
+    flat = pages_var.value.reshape(P * 2 * Hkv * page, D).at[flat_idx].set(
+        combined.astype(k.dtype), mode="drop")
+    pages = flat.reshape(P, 2 * Hkv, page, D)
+    pages_var.value = pages
+    seq_of_t = (jnp.arange(S, dtype=jnp.int32) if decode
+                else _seq_of_rows(cu, T))
+    with jax.named_scope("compress"):
+        ckeys = _write_compressed_keys(flat, ckey_var.value, pos, seq_of_t,
+                                       live, page_indices, sel, Hkv, page)
+        ckey_var.value = ckeys
+        # every sequence's windows, in order: [S, Hkv, pp * wpb, D]
+        seq_keys = jnp.take(ckeys, jnp.maximum(page_indices, 0), axis=0
+                            ).reshape(S, pp, Hkv, wpb, D).transpose(
+                                0, 2, 1, 3, 4).reshape(S, Hkv, pp * wpb, D)
+
+    def one_row(q_rows, pos_rows, on):
+        """One query a sequence (``[S, H, D]``) over its chosen pages."""
+        with jax.named_scope("select"):
+            blocks, count = bsa.block_list(
+                q_rows[:, None], seq_keys, pos_rows[:, None], sel, sm_scale)
+            page_list = jnp.take_along_axis(
+                jnp.broadcast_to(page_indices[:, None], (S, Hkv, pp)),
+                jnp.minimum(blocks[:, 0], pp - 1), axis=-1)
+            count = jnp.where(on, count[:, 0], 0)
+        return bsa.decode_attention(
+            q_rows.reshape(S, Hkv, G, D), pages, page_list, count,
+            pos_rows % page + 1, sm_scale=sm_scale).reshape(S, H, D)
+
+    if decode:
+        return one_row(q, pos, jnp.ones((S,), bool) if live is None
+                       else live)
+    one, first = _one_row_seqs(ragged_meta, T)
+    first_pos = jnp.take(pos, first)
+    lay = linear_attention.tile_layout(cu, ragged_meta["num_seqs"],
+                                       first_pos, T, bsa.TILE)
+    NT = lay["seq"].shape[0]
+    rows = lay["rows"]
+    q_t = linear_attention.to_tiles(q, rows)               # [NT*TILE, H, D]
+    pos_t = linear_attention.to_tiles(pos, rows).reshape(NT, bsa.TILE)
+    row_ok = (rows < T).reshape(NT, bsa.TILE)
+    K = bsa.mask_width(pp, page)
+    with jax.named_scope("select"):
+        visible = (jnp.arange(pp)[None, None, None]
+                   <= (pos_t // page)[:, :, None, None])
+        chosen = jax.lax.cond(
+            jnp.any(jnp.logical_and(row_ok, pos_t >= sel.dense_len)),
+            lambda: bsa.block_mask(
+                q_t.reshape(NT, bsa.TILE, H, D),
+                jnp.take(seq_keys, lay["seq"], axis=0), pos_t, sel,
+                sm_scale),
+            lambda: jnp.broadcast_to(visible, (NT, bsa.TILE, Hkv, pp)))
+        chosen = jnp.logical_and(chosen, row_ok[:, :, None, None])
+        chosen = jnp.pad(chosen, ((0, 0),) * 3 + ((0, K // page - pp),))
+        allowed = jnp.logical_and(
+            jnp.repeat(chosen, page, axis=-1),
+            (jnp.arange(K)[None, None] <= pos_t[:, :, None])[:, :, None])
+        allowed = allowed.transpose(2, 0, 1, 3).reshape(
+            Hkv, NT * bsa.TILE, K).astype(q.dtype)
+    tile_last = jnp.where(lay["len"] > 0, lay["pos0"] + lay["len"] - 1, -1)
+    y_t = bsa.chunk_attention(
+        q_t.reshape(NT * bsa.TILE, Hkv, G, D).transpose(1, 2, 0, 3), pages,
+        allowed, lay["seq"], tile_last, page_indices, sm_scale=sm_scale)
+    y = linear_attention.from_tiles(
+        y_t.transpose(2, 0, 1, 3).reshape(NT * bsa.TILE, H, D), rows, T)
+    if live is not None:
+        one = jnp.logical_and(one, jnp.take(live, first))
+    y_one = one_row(jnp.take(q, first, axis=0), first_pos, one)
+    return y.at[jnp.where(one, first, T)].set(y_one, mode="drop")
 
 
 def pages_for(n_tokens: int, page_size: int) -> int:

@@ -131,7 +131,9 @@ from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.telemetry import (RequestLatencyTracker, device_scopes,
                                      trace)
 from deepspeed_tpu.utils.async_stage import BoundedAsyncStage, StageTimers
-from deepspeed_tpu.inference.paged import latent_width, pages_for
+from deepspeed_tpu.inference.paged import (latent_width, pages_for,
+                                           sparse_key_counts,
+                                           sparse_selection)
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
 from deepspeed_tpu.inference.sampling import position_keys, sample_logits
 from deepspeed_tpu.inference.v2 import programs
@@ -395,6 +397,23 @@ class RaggedInferenceEngineV2:
                 tensor_parallel=", and the cache's sharding rule knows page "
                 "pools only")
 
+        # a model whose attention layers CHOOSE their keys by block and
+        # whose other layers keep a matrix state a head (models/
+        # minicpm_sala.py): the state is per-slot state like a short
+        # convolution's (the refusals above hold), the compressed keys a
+        # second pool leaf.  A matrix state is megabytes a sequence: no
+        # entry of a request's record in the spill tiers
+        self._sparse_sel = sparse_selection(mcfg)
+        if self._sparse_sel is not None:
+            self._sparse_sel.check(int(page_size))
+            refuse(
+                f"{type(model).__name__} keeps a matrix state a head in "
+                f"{self.state_layers} of its {int(mcfg.num_hidden_layers)} "
+                "layers and chooses the pages its other layers attend",
+                tiering=", and a spilled request's record would carry "
+                "megabytes of state a sequence beside its pages (ROADMAP "
+                "R5(d): a tier for matrix state)")
+
         # latent attention (models/glm4_moe_lite.py): a layer's pool holds
         # ONE row a token, ``[c | k_r]``, and no heads.  It is a pool leaf
         # like any other to KVState's verbs (attach, ensure, release, copy-
@@ -547,6 +566,9 @@ class RaggedInferenceEngineV2:
         # what a cached token costs the pool as its leaves are allocated:
         # on every ``engine.dispatch`` span and in ``serving_stages()``
         self._pages_peak = 0
+        if self.state_layers:
+            self._state_attrs["state_bytes"] = int(
+                self.kv.state_bytes_per_seq)
         self._latent_attrs = ({} if not self.latent_width else {
             "latent_bytes_per_token": sum(
                 int(np.prod(leaf.shape[2:])) * leaf.dtype.itemsize
@@ -1060,8 +1082,8 @@ class RaggedInferenceEngineV2:
     def _refuse_features(self, o: Dict[str, Any], kv_cache_dtype: str,
                          model_is: str, *, prefix_cache: str = "",
                          speculation: str = "", long_context: str = "",
-                         quantized: str = "", tensor_parallel: str = ""
-                         ) -> None:
+                         quantized: str = "", tensor_parallel: str = "",
+                         tiering: str = "") -> None:
         """Refuse by name each asked-for feature that cannot serve a model
         whose cache is not plain per-head K/V pages: ``model_is`` says what
         the model keeps, each keyword why that feature cannot carry it
@@ -1079,6 +1101,8 @@ class RaggedInferenceEngineV2:
             (long_context and KVTieringConfig.coerce(
                 o["kv_tiering"]).long_context, "kv_tiering.long_context",
              long_context),
+            (tiering and KVTieringConfig.coerce(o["kv_tiering"]).enabled,
+             "kv_tiering", tiering),
             (quantized and kv_cache_dtype != "none",
              f"kv_cache_dtype={kv_cache_dtype!r}", quantized),
             (tensor_parallel and self.tp > 1, "tensor-parallel serving",
@@ -1151,7 +1175,32 @@ class RaggedInferenceEngineV2:
             # and the exit gate's sums as far as fetches have brought them
             out["loop"] = {**self._loop_attrs, "rows": st.loop_rows,
                            "exit_cdf": list(st.loop_exit_cdf)}
-        if self.state_layers:
+        if self._sparse_sel is not None:
+            # linear attention beside block selection: their own four
+            # routes and no other; what the state and the compressed keys
+            # cost, and what the selection spared
+            from deepspeed_tpu.inference.paged import CKEY_PAGES
+            from deepspeed_tpu.ops import (block_select_attention,
+                                           linear_attention)
+
+            out["kernels"] = {
+                **routes.taken(prefix=linear_attention.KERNEL),
+                **routes.taken(prefix=block_select_attention.KERNEL)}
+            state = {"state_layers": self.state_layers,
+                     "state_bytes": int(self.kv.state_bytes_per_seq)}
+            out["linear_state"] = {**state, "state_rows": st.state_rows}
+            flat = jax.tree_util.tree_flatten_with_path(self.kv.cache)[0]
+            out["sparse"] = {
+                "kv_layers": programs.kv_layers(self.cfg),
+                "compressed_bytes": sum(
+                    int(leaf.nbytes) for path, leaf in flat
+                    if any(getattr(k, "key", None) == CKEY_PAGES
+                           for k in path)),
+                "sparse_rows": st.sparse_rows,
+                "sparse_key_tokens": st.sparse_key_tokens,
+                "sparse_visible_tokens": st.sparse_visible_tokens,
+                **self._sparse_sel._asdict()}
+        elif self.state_layers:
             # a model with layers of two kinds: which keep K/V, which keep
             # per-slot state, what a sequence's state costs, how its
             # router scores and which routes its attention takes
@@ -1595,9 +1644,26 @@ class RaggedInferenceEngineV2:
         st.decode_blocks_ahead += ahead
         st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
         state = {"state_rows": rows} if self.state_layers else {}
+        st.state_rows += rows if self.state_layers else 0
+        if self._sparse_sel is not None:
+            # tick k of the block asks at position length - 1 + k
+            state.update(self._count_sparse(np.add.outer(
+                [r.length - 1 for r in reqs], np.arange(K))))
         return st.stage("dispatch", program="decode_block", ahead=ahead,
                         rows=rows, ticks=K, key_tokens=keys, **state,
                         **self._pool_counts())
+
+    def _count_sparse(self, positions, one_row=None) -> Dict[str, int]:
+        """What a dispatch's queries at ``positions`` ask of a block-
+        selecting layer (``paged.sparse_key_counts``: for its span), the
+        first three added to the counters.  ``one_row``: which of them are
+        their sequence's only row (all, in a decode block)."""
+        counts = sparse_key_counts(positions, self._sparse_sel, one_row)
+        st = self.host_stats
+        st.sparse_rows += counts["sparse_rows"]
+        st.sparse_key_tokens += counts["sparse_key_tokens"]
+        st.sparse_visible_tokens += counts["sparse_visible_tokens"]
+        return counts
 
     def _pool_counts(self) -> Dict[str, int]:
         """What every ``engine.dispatch`` span says of the pool as the
@@ -2419,6 +2485,13 @@ class RaggedInferenceEngineV2:
         if self.state_layers:
             arrays += (seq_slot,)
             counts["state_rows"] = j
+            self.host_stats.state_rows += j
+        if self._sparse_sel is not None:
+            q_lens = np.diff(cu_q_lens[:j + 1])
+            counts.update(self._count_sparse(
+                positions[:t], np.repeat(q_lens == 1, q_lens)))
+            # the sequences that take the two one-token kernels
+            counts["one_row_seqs"] = int((q_lens == 1).sum())
         sampled = any(r.do_sample for r, _ in sampling)
         if sampled:
             do_sample = np.zeros((S,), bool)

@@ -44,8 +44,8 @@ import numpy as np
 
 from deepspeed_tpu.profiling.flops_profiler import name_parts
 
-KINDS = ("attn", "mlp", "moe", "conv", "head", "embed", "sample", "zero",
-         "optim", "rest")
+KINDS = ("attn", "mlp", "moe", "conv", "linear", "head", "embed", "sample",
+         "zero", "optim", "rest")
 VIAS = ("own", "user", "operand", "none")
 MAX_HOPS = 3
 
@@ -62,6 +62,7 @@ _MODULE_KIND = {
     "shared_expert_gate": "mlp",
     "moe": "moe", "block_sparse_moe": "moe", "experts": "moe",
     "conv": "conv",
+    "lightning_attn": "linear",
     "lm_head": "head", "embed_tokens.attend": "head",
     "embed_tokens": "embed", "wte": "embed", "wpe": "embed",
 }
@@ -73,7 +74,7 @@ _NORM_ROLE = {"input_layernorm": "mixer", "operator_norm": "mixer",
               "post_attention_layernorm": "ffn", "ffn_norm": "ffn",
               "post_attention_layernorm_2": "ffn"}
 _ROLE_DEFAULT = {"mixer": "attn", "ffn": "mlp"}
-_ROLE_KINDS = {"mixer": ("attn", "conv"), "ffn": ("mlp", "moe")}
+_ROLE_KINDS = {"mixer": ("attn", "conv", "linear"), "ffn": ("mlp", "moe")}
 _LAYER = re.compile(r"^(layers(_\d+)?|block|h(_\d+)?)$")
 _WRAPPED = re.compile(r"^\w+\((.*)\)$")
 

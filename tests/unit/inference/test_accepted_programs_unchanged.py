@@ -3,7 +3,10 @@ benchmark had before LFM2 (``mistral-7b-serve``, ``olmoe-1b-7b-serve``,
 ``ouro-2.6b-serve``) lower to the text they lowered to at the parent of
 PR 35, at a tiny size: what state beside pages, sigmoid routing, per-head
 QK-norm and padded heads of 64 added is reached by no model that does not
-ask for it.
+ask for it.  PR 43 added the two programs of ``minicpm-sala-serve``
+(tile layout, chunked scan and one-token step over a matrix state; block
+selection over a two-KV-head pool), taken on its own tree: what a later PR
+adds must leave them as they are too.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -34,11 +37,14 @@ EXPECTED = {
     "olmoe.decode_block": "03ce8a62bd7290ce",
     "ouro.fused": "8443cc00e342ba49",
     "ouro.decode_block": "f87646743932c66c",
+    # taken by PR 43, which added the model
+    "sala.fused": "9e3d99fee4138173",
+    "sala.decode_block": "86bf79c9930ecd7c",
 }
 
 
 def _models():
-    from deepspeed_tpu.models import mistral, olmoe, ouro
+    from deepspeed_tpu.models import minicpm_sala, mistral, olmoe, ouro
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -50,6 +56,8 @@ def _models():
         "olmoe": olmoe.OlmoeForCausalLM(olmoe.get_config(
             "tinyolmoe", expert_parallel=False, **bf16)),
         "ouro": ouro.OuroForCausalLM(ouro.get_config("tinyouro", **bf16)),
+        "sala": minicpm_sala.MiniCPMSALAForCausalLM(minicpm_sala.get_config(
+            "tinysala", **bf16)),
     }
 
 
@@ -75,7 +83,9 @@ def lowered_hashes():
         fused = eng.fused_sample_step(False).lower(
             eng.params, eng.kv.cache, i32(S), i32(1, T), i32(1, T),
             i32(1, T), i32(S), i32(S, pp), i32(S + 1), i32(1), i32(T),
-            i32(S), i32(S))
+            i32(S), i32(S),
+            # a model with per-slot state: each packed sequence's slot
+            *([i32(S)] if eng.state_layers else []))
         block = eng.decode_block(False).lower(
             eng.params, eng.kv.cache, i32(S), i32(S), boo(S), i32(S),
             i32(S, pp), i32(S), boo(S), f32(S), i32(S), f32(S), i32(S),

@@ -22,16 +22,27 @@ PAGE, PAGES, SEQS, MAX_LEN = 4, 12, 3, 32
 TIERS = {"host_pages": 32}
 
 
-def make_kv(tiering=None, prefix=False, num_pages=PAGES):
+# per-slot state beside the pool, by shape: a few rows a layer (a short
+# convolution's), a matrix a head (linear attention's)
+STATE_SHAPES = {"rows": (2, 8), "matrix": (2, 4, 4)}
+
+
+def make_kv(tiering=None, prefix=False, num_pages=PAGES, state=None):
     """A pool whose row ``[p, t]`` holds ``100 * p + t`` in every cell of
     one leaf and its negative in the other, so a moved page is told by
-    its content."""
+    its content; with ``state`` (a key of ``STATE_SHAPES``) a per-slot
+    state leaf beside it whose slot ``s`` holds ``7 + s`` and counts up."""
     rows = (100 * np.arange(num_pages)[:, None]
             + np.arange(PAGE)[None, :]).astype(np.float32)
     cache = {"model": {"layers_0": {
         "kv_pages": jnp.asarray(np.broadcast_to(
             rows[:, :, None, None], (num_pages, PAGE, 2, 4)).copy()),
         "kv_scales": jnp.asarray(-rows)}}}
+    if state is not None:
+        shape = STATE_SHAPES[state]
+        cache["model"]["layers_1"] = {"slot_state": jnp.asarray(
+            (7 + np.arange(SEQS)).reshape((SEQS,) + (1,) * len(shape))
+            + np.arange(np.prod(shape), dtype=np.float32).reshape(shape))}
     return KVState(cache, num_pages=num_pages, page_size=PAGE,
                    max_seqs=SEQS, max_seq_len=MAX_LEN,
                    kv_reserve="on_demand",
@@ -58,6 +69,10 @@ def page_rows(kv, pages):
     """``kv_pages[p, :, 0, 0]`` of each page: its 4 recognisable rows."""
     return np.asarray(kv.cache["model"]["layers_0"]["kv_pages"])[
         np.asarray(pages), :, 0, 0]
+
+
+def state_of(kv, slot):
+    return np.asarray(kv.cache["model"]["layers_1"]["slot_state"])[slot]
 
 
 def slots_of(*reqs):
@@ -154,11 +169,20 @@ def test_a_full_prefix_match_copies_its_last_page_before_the_write():
     kv.audit(slots_of(first, second))
 
 
-def test_export_then_adopt_round_trips_on_a_second_pool():
-    kv, kv2 = make_kv(tiering=TIERS), make_kv(tiering=TIERS)
+@pytest.mark.parametrize("state", [None, "rows", "matrix"])
+def test_export_then_adopt_round_trips_on_a_second_pool(state):
+    """The pages, and the per-slot state whatever its shape, of a spilled
+    sequence reach a second pool's slot through ``export`` and ``adopt``."""
+    kv, kv2 = (make_kv(tiering=TIERS, state=state) for _ in range(2))
     r = decoding(make_req(1, 10, slot=0), new_tokens=2)
     pages = seat(kv, r)
     want = page_rows(kv, pages[:3])             # 11 live tokens: 3 pages
+    if state:
+        # ``attach`` zeroed the slot; what the sequence then left there
+        kv.cache["model"]["layers_1"]["slot_state"] = \
+            kv.cache["model"]["layers_1"]["slot_state"].at[0].add(5.0)
+        held = state_of(kv, 0).copy()
+        assert held.shape == STATE_SHAPES[state] and (held == 5.0).all()
     assert kv.spill(r, last_tok=4)
     kv.release(0)
     blob = kv.export(r)
@@ -172,10 +196,38 @@ def test_export_then_adopt_round_trips_on_a_second_pool():
     now = seat(kv2, twin)
     assert kv2.restore(twin) == 4
     np.testing.assert_array_equal(page_rows(kv2, now[:3]), want)
+    if state:
+        np.testing.assert_array_equal(state_of(kv2, 2), held)
+        assert (state_of(kv2, 0) == 0).all()
     # a pool with no tiers cannot take the payload: the caller folds
     assert not make_kv().adopt(blob["spill"], make_req(8, 10, slot=0))
     kv.close()
     kv2.close()
+
+
+@pytest.mark.parametrize("state", ["rows", "matrix"])
+def test_attach_zeroes_spill_carries_and_release_ends_the_state(state):
+    """The state's life is the pages': a slot's next tenant reads zeros,
+    ``spill`` -> ``restore`` brings back what the sequence left, into
+    another slot, and no other slot's state moves."""
+    kv = make_kv(tiering=TIERS, state=state)
+    before = state_of(kv, 1).copy()
+    r = decoding(make_req(1, 10, slot=0))
+    seat(kv, r)
+    assert (state_of(kv, 0) == 0).all()
+    assert kv.state_bytes_per_seq == 4 * int(np.prod(STATE_SHAPES[state]))
+    kv.cache["model"]["layers_1"]["slot_state"] = \
+        kv.cache["model"]["layers_1"]["slot_state"].at[0].set(3.5)
+    assert kv.spill(r, last_tok=9)
+    kv.release(0)
+    r.slot = 2
+    seat(kv, r)
+    assert (state_of(kv, 2) == 0).all()
+    assert kv.restore(r) == 9
+    assert (state_of(kv, 2) == 3.5).all()
+    np.testing.assert_array_equal(state_of(kv, 1), before)
+    kv.release(2)
+    kv.close()
 
 
 def test_ensure_past_the_pool_says_no_and_leaks_nothing():

@@ -1,5 +1,7 @@
 """Per-slot state beside the paged pool (the short convolutions of
-``models/lfm2_moe.py``), owned by ``KVState`` with the pages' life: counted
+``models/lfm2_moe.py``: a few ROWS a layer; the linear attention of
+``models/minicpm_sala.py``: a MATRIX a head), owned by ``KVState`` with the
+pages' life: counted
 apart from a page's bytes, zeroed when a request attaches, carried by spill
 and restore and by a handoff's export and adopt, dead at release and
 cancel; one-ahead dispatch reads the state its predecessor wrote; what
@@ -19,14 +21,19 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.reference import lfm2_moe as ref  # noqa: E402
+from benchmark.reference import minicpm_sala as sala_ref  # noqa: E402
 from deepspeed_tpu.inference import paged  # noqa: E402
 from deepspeed_tpu.inference.v2 import programs  # noqa: E402
 from deepspeed_tpu.inference.v2.ragged_engine import \
     RaggedInferenceEngineV2  # noqa: E402
-from deepspeed_tpu.models import lfm2_moe  # noqa: E402
+from deepspeed_tpu.models import lfm2_moe, minicpm_sala  # noqa: E402
 from deepspeed_tpu.telemetry import trace  # noqa: E402
 from tests.unit.models.test_lfm2_moe import CFG as REF_CFG  # noqa: E402
 from tests.unit.models.test_lfm2_moe import TOL  # noqa: E402
+from tests.unit.models.test_minicpm_sala import CFG as SALA_CFG  # noqa: E402
+from tests.unit.models.test_minicpm_sala import MODEL as SALA  # noqa: E402
+from tests.unit.models.test_minicpm_sala import \
+    REF_CFG as SALA_REF_CFG  # noqa: E402
 
 PAGE = 8
 CFG = lfm2_moe.get_config("tinylfm2", dtype=jnp.float32,
@@ -41,6 +48,45 @@ PROMPT = np.arange(1, 20, dtype=np.int32)           # 19 tokens: 3 pages
 def params():
     return jax.jit(MODEL.init)(jax.random.PRNGKey(7),
                                np.zeros((1, 8), np.int32))["params"]
+
+
+@pytest.fixture(scope="module")
+def sala_params():
+    return jax.jit(SALA.init)(jax.random.PRNGKey(7),
+                              np.zeros((1, 8), np.int32))["params"]
+
+
+class Family:
+    """One shape of per-slot state, with the engine that serves it."""
+
+    def __init__(self, model, params, reference, ref_cfg, tol, state_layers,
+                 state_bytes, **engine):
+        self.model, self.params, self.reference = model, params, reference
+        self.ref_cfg, self.tol, self.engine = ref_cfg, tol, engine
+        self.state_layers, self.state_bytes = state_layers, state_bytes
+
+    def make(self, **kw):
+        return RaggedInferenceEngineV2(
+            self.model, params=self.params, **{**dict(
+                pipeline=False, max_seqs=2, max_seq_len=64, prefill_chunk=8,
+                decode_block_size=2), **self.engine, **kw})
+
+    def is_the_references(self, prompt, out):
+        gaps = self.reference.generated_gaps(
+            self.params, self.ref_cfg, list(prompt), out[len(prompt):])
+        return gaps.max() < self.tol
+
+
+@pytest.fixture(params=["rows", "matrix"])
+def fam(request, params, sala_params):
+    """The state life cycle holds for both shapes: a few rows a layer
+    (``[max_seqs, 2, 128]``), a matrix a head (``[max_seqs, 2, 128,
+    128]``)."""
+    if request.param == "rows":
+        return Family(MODEL, params, ref, REF_CFG, TOL, STATE_LAYERS,
+                      STATE_BYTES, page_size=PAGE, num_pages=14)
+    return Family(SALA, sala_params, sala_ref, SALA_REF_CFG, 1e-3, 2,
+                  2 * 2 * 128 * 128 * 4, page_size=16, num_pages=10)
 
 
 def make_engine(params, **kw):
@@ -90,14 +136,14 @@ def test_the_state_is_counted_apart_from_a_pages_bytes(params):
         lfm2_moe.Lfm2MoeForCausalLM(big), big, 64, "none") == 64 * 8192
 
 
-def test_a_reused_slot_starts_from_zeros(params):
+def test_a_reused_slot_starts_from_zeros(fam):
     """Two requests through the ONE slot of an engine: the second finds
     the slot's state zeroed at its attach and gives the reference's
     tokens, whatever the first left there."""
-    eng = make_engine(params, max_seqs=1)
+    eng = fam.make(max_seqs=1)
     first = eng.put_request(PROMPT, max_new_tokens=6)
     out = _run(eng)
-    assert _is_the_references(params, PROMPT, out[first])
+    assert fam.is_the_references(PROMPT, out[first])
     assert all(np.abs(s).max() > 0 for s in _states(eng, 0))
     other = np.arange(40, 53, dtype=np.int32)
     second = eng.put_request(other, max_new_tokens=6)
@@ -105,7 +151,7 @@ def test_a_reused_slot_starts_from_zeros(params):
     assert eng.slots[0].uid == second
     assert all(np.abs(s).max() == 0 for s in _states(eng, 0))
     out = _run(eng)
-    assert _is_the_references(params, other, out[second])
+    assert fam.is_the_references(other, out[second])
 
 
 def test_a_spilled_and_restored_sequence_carries_its_state(params):
@@ -160,8 +206,8 @@ def test_a_handoff_carries_the_state_to_another_engine(params):
     assert _run(b)[uid] == want
 
 
-def test_cancel_and_release_give_back_the_slot_with_its_pages(params):
-    eng = make_engine(params, max_seqs=1)
+def test_cancel_and_release_give_back_the_slot_with_its_pages(fam):
+    eng = fam.make(max_seqs=1)
     free = eng.allocator.free_pages
     uid = eng.put_request(PROMPT, max_new_tokens=20)
     for _ in range(3):
@@ -172,11 +218,11 @@ def test_cancel_and_release_give_back_the_slot_with_its_pages(params):
     eng.audit_kv_sharing()
     # the next tenant of the slot is served as if nothing had been there
     nxt = eng.put_request(PROMPT[:11], max_new_tokens=5)
-    assert _is_the_references(params, PROMPT[:11], _run(eng)[nxt])
+    assert fam.is_the_references(PROMPT[:11], _run(eng)[nxt])
     assert eng.allocator.free_pages == free
 
 
-def test_one_ahead_dispatch_reads_the_state_its_predecessor_wrote(params):
+def test_one_ahead_dispatch_reads_the_state_its_predecessor_wrote(fam):
     """Four requests of different lengths through two slots, with fused
     ticks dispatched one ahead (``pipeline=True``) and not: the same
     tokens, the reference's."""
@@ -184,8 +230,7 @@ def test_one_ahead_dispatch_reads_the_state_its_predecessor_wrote(params):
                for i, n in enumerate((19, 5, 26, 11))]
 
     def serve(pipeline):
-        eng = make_engine(params, pipeline=pipeline, decode_block_size=4,
-                          num_pages=20)
+        eng = fam.make(pipeline=pipeline, decode_block_size=4, num_pages=20)
         uids = [eng.put_request(p, max_new_tokens=9) for p in prompts]
         out = _run(eng)
         return [out[u] for u in uids], eng.serving_stages()
@@ -194,8 +239,7 @@ def test_one_ahead_dispatch_reads_the_state_its_predecessor_wrote(params):
     plain, _ = serve(False)
     assert ahead == plain
     assert stages["fused_ticks_ahead"] > 0
-    assert all(_is_the_references(params, p, o)
-               for p, o in zip(prompts, ahead))
+    assert all(fam.is_the_references(p, o) for p, o in zip(prompts, ahead))
 
 
 @pytest.mark.parametrize("kw,named", [
@@ -205,9 +249,20 @@ def test_one_ahead_dispatch_reads_the_state_its_predecessor_wrote(params):
      "long_context"),
     (dict(kv_cache_dtype="int8"), "kv_cache_dtype")],
     ids=["prefix-cache", "speculation", "long-context", "quantized-pool"])
-def test_what_cannot_carry_the_state_is_refused_by_name(params, kw, named):
+def test_what_cannot_carry_the_state_is_refused_by_name(fam, kw, named):
     with pytest.raises(NotImplementedError, match=named):
-        make_engine(params, **kw)
+        fam.make(**kw)
+
+
+def test_a_matrix_state_is_no_entry_of_the_spill_tiers(sala_params):
+    """18 MiB a sequence at the published widths: the engine refuses the
+    tiers by name for such a model (``KVState``'s own verbs carry any
+    state leaf: ``test_kv_state.py``)."""
+    with pytest.raises(NotImplementedError, match="kv_tiering"):
+        RaggedInferenceEngineV2(
+            SALA, params=sala_params, max_seqs=2, max_seq_len=64,
+            prefill_chunk=8, page_size=16, num_pages=10,
+            kv_tiering={"host_pages": 32})
 
 
 def test_tensor_parallel_serving_is_refused_by_name(params):
@@ -252,3 +307,47 @@ def test_the_spans_and_the_stages_say_what_ran(params):
         "attention_routes": st["kernels"]}
     assert st["hybrid"]["bias_swaps"] == sum(m["bias_swaps"] for m in moe)
     assert st["kernels"]["paged_attention"] == "xla-dense-pad128"
+
+
+def test_a_matrix_states_spans_and_stages_say_what_ran(sala_params):
+    """``engine.dispatch`` carries ``state_rows``, ``state_layers`` and
+    ``state_bytes`` and the selection's counts; ``serving_stages()`` holds
+    the same under ``linear_state`` and ``sparse``, and the four routes."""
+    trace.configure(enabled=True)
+    try:
+        trace.clear()
+        eng = RaggedInferenceEngineV2(
+            SALA, params=sala_params, pipeline=False, max_seqs=2,
+            max_seq_len=128, prefill_chunk=32, page_size=16, num_pages=20,
+            decode_block_size=2)
+        eng.put_request(np.arange(1, 91, dtype=np.int32), max_new_tokens=6)
+        _run(eng)
+        events = trace.snapshot()
+    finally:
+        trace.configure(enabled=False)
+        trace.clear()
+    dispatches = [e["args"] for e in events if e["name"] == "engine.dispatch"]
+    assert {d["program"] for d in dispatches} == {"fused", "decode_block"}
+    assert all(d["state_layers"] == 2 and d["state_rows"] == 1
+               and d["state_bytes"] == 2 * 2 * 128 * 128 * 4
+               for d in dispatches)
+    # positions 64..89 of the prompt and every decode step choose blocks
+    # (five of the six to seven visible ones); a block counts all its
+    # ticks, the one past the last token too
+    far = sum(d["sparse_rows"] for d in dispatches)
+    assert (90 - 64) + 5 <= far <= (90 - 64) + 6
+    keys = sum(d["sparse_key_tokens"] for d in dispatches)
+    seen = sum(d["sparse_visible_tokens"] for d in dispatches)
+    assert 0.5 < keys / seen < 1.0
+    fused = [d for d in dispatches if d["program"] == "fused"]
+    assert sum(d["attend_prefill_tokens"] for d in fused) < 90 * 91 // 2
+    st = eng.serving_stages()
+    assert st["linear_state"] == {
+        "state_layers": 2, "state_bytes": 2 * 2 * 128 * 128 * 4,
+        "state_rows": len(dispatches)}
+    assert st["sparse"]["sparse_key_tokens"] == keys
+    assert st["sparse"]["sparse_visible_tokens"] == seen
+    assert st["sparse"]["compressed_bytes"] == 2 * 20 * 4 * 128 * 4
+    assert set(st["kernels"]) == {
+        "linear_attention_scan", "linear_attention_decode",
+        "sparse_attention_prefill", "sparse_attention_decode"}

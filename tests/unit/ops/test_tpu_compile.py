@@ -520,6 +520,68 @@ def test_a_latent_pool_of_576_columns_is_laid_out_in_640(chip_compile):
             ((c["S"],), jnp.bool_))
 
 
+# minicpm-sala-serve: 32 linear heads of 128 over a 128 x 128 float32 state;
+# 32 query heads over 2 KV heads, a pool of 2,457 pages laid out heads
+# first, 8 slots of 258 pages, a fused tick of 8 + 1,024 rows
+SALA = dict(S=8, pp=258, pool=2457, page=64, H=32, Hkv=2, D=128, T=1032)
+
+
+def test_linear_attention_kernels_at_the_serving_configuration(chip_compile):
+    """``ops/linear_attention.py``: the chunked scan over the tiles a
+    fused tick of ``minicpm-sala-serve`` can hold, and the one-token step
+    over its eight slots."""
+    from deepspeed_tpu.ops import linear_attention as la
+
+    c, i32, bf, f32 = SALA, jnp.int32, jnp.bfloat16, jnp.float32
+    NT = -(-c["T"] // la.CHUNK) + c["S"]
+    rows = ((c["H"], NT * la.CHUNK, c["D"]), bf)
+    state = ((c["S"], c["H"], c["D"], c["D"]), f32)
+    lam = ((c["H"], 1, 128), f32)
+    text = chip_compile(
+        functools.partial(la._scan.__wrapped__, interpret=False),
+        rows, rows, rows, lam, state, ((NT,), i32), ((NT,), i32),
+        ((NT,), i32))
+    assert f"%{la.SCAN_NAME}" in text
+    row = ((c["S"], c["H"], c["D"]), bf)
+    text = chip_compile(
+        functools.partial(la._step.__wrapped__, interpret=False),
+        row, row, row, lam, state, ((c["S"],), i32), ((c["S"],), jnp.bool_),
+        ((1,), i32))
+    assert f"%{la.STEP_NAME}" in text
+
+
+def test_block_select_kernels_are_tiled_for_two_kv_heads(chip_compile):
+    """``ops/block_select_attention.py`` over a pool of two KV heads laid
+    out heads first (``[P, 4, page, D]``: a slab a head is whole tiles):
+    the masked chunk kernel over a tick's tiles and the chosen-page kernel
+    over lists of 128 pages."""
+    from deepspeed_tpu.ops import block_select_attention as bsa
+    from deepspeed_tpu.ops import linear_attention as la
+
+    c, i32, bf = SALA, jnp.int32, jnp.bfloat16
+    G = c["H"] // c["Hkv"]
+    NT = -(-c["T"] // bsa.TILE) + c["S"]
+    assert bsa.TILE == la.CHUNK         # one tile layout for both mixers
+    pool = ((c["pool"], 2 * c["Hkv"], c["page"], c["D"]), bf)
+    K = bsa.mask_width(c["pp"], c["page"])
+    assert K == 33 * 512
+    text = chip_compile(
+        functools.partial(bsa._chunk.__wrapped__, sm_scale=0.0884,
+                          interpret=False),
+        ((c["Hkv"], G, NT * bsa.TILE, c["D"]), bf), pool,
+        ((c["Hkv"], NT * bsa.TILE, K), bf), ((NT,), i32), ((NT,), i32),
+        ((c["S"], c["pp"]), i32))
+    assert f"%{bsa.CHUNK_NAME}" in text
+    L = bsa.Selection().list_width()
+    assert L == 128
+    text = chip_compile(
+        functools.partial(bsa._decode.__wrapped__, sm_scale=0.0884,
+                          interpret=False),
+        ((c["S"], c["Hkv"], G, c["D"]), bf), pool,
+        ((c["S"], c["Hkv"], L), i32), ((c["S"],), i32), ((c["S"],), i32))
+    assert f"%{bsa.DECODE_NAME}" in text
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4

@@ -33,12 +33,13 @@ FAMILIES = {
     "ouro": {"attn", "mlp"},
     "lfm2": {"attn", "conv", "mlp", "moe"},
     "glm": {"attn", "mlp", "moe"},
+    "sala": {"attn", "linear", "mlp"},
 }
 
 
 def _model(family):
-    from deepspeed_tpu.models import (glm4_moe_lite, lfm2_moe, mistral, olmoe,
-                                      ouro)
+    from deepspeed_tpu.models import (glm4_moe_lite, lfm2_moe, minicpm_sala,
+                                      mistral, olmoe, ouro)
 
     if family == "mistral":
         return mistral.MistralForCausalLM(mistral.get_config(
@@ -53,6 +54,9 @@ def _model(family):
     if family == "glm":
         return glm4_moe_lite.Glm4MoeLiteForCausalLM(
             glm4_moe_lite.get_config("tinyglm", **BF16))
+    if family == "sala":
+        return minicpm_sala.MiniCPMSALAForCausalLM(
+            minicpm_sala.get_config("tinysala", **BF16))
     return lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config("tinylfm2",
                                                            **BF16))
 
@@ -233,6 +237,23 @@ def _train_engine(loss_module, dp=2):
      ("attn", "latent_decode_attention", "-")),
     ("jit(f)/M/model/layers_1/moe.shared/shared_experts/gate_proj/"
      "dot_general", ("moe", "moe.shared", "-")),
+    ("jit(f)/M/model/layers_1/lightning_attn/q_proj/dot_general",
+     ("linear", "q_proj", "-")),
+    ("jit(f)/M/model/layers_1/lightning_attn/gate/logistic",
+     ("linear", "gate", "-")),
+    ("jit(f)/M/model/layers_1/lightning_attn/o_norm/rsqrt",
+     ("linear", "o_norm", "-")),
+    ("jit(f)/M/model/layers_1/lightning_attn/jit(_scan)/linear_chunk_scan/"
+     "pallas_call", ("linear", "linear_chunk_scan", "-")),
+    ("jit(f)/M/model/layers_1/lightning_attn/jit(_step)/linear_decode_step/"
+     "pallas_call", ("linear", "linear_decode_step", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/compress/gather",
+     ("attn", "compress", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/select/top_k",
+     ("attn", "select", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/jit(_decode)/"
+     "sparse_decode_attention/pallas_call",
+     ("attn", "sparse_decode_attention", "-")),
     ("jit(f)/while/body/closed_call/sample/sample_logits_batched/reduce",
      ("sample", "sample_logits_batched", "-")),
     ("jit(f)/M/head.rows/gather", ("head", "head.rows", "-")),
