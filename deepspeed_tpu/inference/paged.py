@@ -442,8 +442,8 @@ def sparse_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     ``select``).  A decode program's rows take the chosen-page kernel;
     every other batch is separated by ``cu_q_lens`` as
     :func:`ragged_linear_attention` separates it: sequences of two rows or
-    more take the masked chunk kernel in tiles, sequences of one row the
-    chosen-page kernel."""
+    more take the chunk kernel in tiles (the choice handed over a block a
+    bit), sequences of one row the chosen-page kernel."""
     bsa = block_select_attention
     sel = sparse_selection(cfg).check(cfg.kv_page_size)
     T, H, D = q.shape
@@ -506,29 +506,24 @@ def sparse_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     rows = lay["rows"]
     q_t = linear_attention.to_tiles(q, rows)               # [NT*TILE, H, D]
     pos_t = linear_attention.to_tiles(pos, rows).reshape(NT, bsa.TILE)
-    row_ok = (rows < T).reshape(NT, bsa.TILE)
-    K = bsa.mask_width(pp, page)
+    blocks = bsa.chunk_blocks(pp)
     with jax.named_scope("select"):
-        visible = (jnp.arange(pp)[None, None, None]
-                   <= (pos_t // page)[:, :, None, None])
+        # a block a bit, blocks down and a tile's rows across; the kernel
+        # reads it in the tiles with a row at or past ``dense_len`` alone
         chosen = jax.lax.cond(
-            jnp.any(jnp.logical_and(row_ok, pos_t >= sel.dense_len)),
-            lambda: bsa.block_mask(
-                q_t.reshape(NT, bsa.TILE, H, D),
-                jnp.take(seq_keys, lay["seq"], axis=0), pos_t, sel,
-                sm_scale),
-            lambda: jnp.broadcast_to(visible, (NT, bsa.TILE, Hkv, pp)))
-        chosen = jnp.logical_and(chosen, row_ok[:, :, None, None])
-        chosen = jnp.pad(chosen, ((0, 0),) * 3 + ((0, K // page - pp),))
-        allowed = jnp.logical_and(
-            jnp.repeat(chosen, page, axis=-1),
-            (jnp.arange(K)[None, None] <= pos_t[:, :, None])[:, :, None])
-        allowed = allowed.transpose(2, 0, 1, 3).reshape(
-            Hkv, NT * bsa.TILE, K).astype(q.dtype)
-    tile_last = jnp.where(lay["len"] > 0, lay["pos0"] + lay["len"] - 1, -1)
+            jnp.any(jnp.logical_and(
+                lay["len"] > 0, lay["pos0"] + lay["len"] > sel.dense_len)),
+            lambda: jnp.pad(
+                bsa.block_mask(
+                    q_t.reshape(NT, bsa.TILE, H, D),
+                    jnp.take(seq_keys, lay["seq"], axis=0), pos_t, sel,
+                    sm_scale).transpose(2, 0, 3, 1).astype(jnp.float32),
+                ((0, 0), (0, 0), (0, blocks - pp), (0, 0))),
+            lambda: jnp.zeros((Hkv, NT, blocks, bsa.TILE), jnp.float32))
     y_t = bsa.chunk_attention(
         q_t.reshape(NT * bsa.TILE, Hkv, G, D).transpose(1, 2, 0, 3), pages,
-        allowed, lay["seq"], tile_last, page_indices, sm_scale=sm_scale)
+        chosen, lay["seq"], lay["pos0"], lay["len"], page_indices,
+        dense_len=sel.dense_len, sm_scale=sm_scale)
     y = linear_attention.from_tiles(
         y_t.transpose(2, 0, 1, 3).reshape(NT * bsa.TILE, H, D), rows, T)
     if live is not None:

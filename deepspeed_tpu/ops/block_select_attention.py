@@ -27,9 +27,26 @@ as they move every pool.
   its own.
 - :func:`chunk_attention` (kernel ``sparse_chunk_attention``): the prompt
   chunks of a fused tick, rows in ``ops/linear_attention.py:tile_layout``'s
-  tiles, a dense pass over the sequence's pages **under the per-query
-  mask** of chosen blocks (key blocks past a tile's last row are skipped;
-  at 8k-16k the pass is a few percent of a tick and exactly (vi)).
+  tiles, laid out as ``ops/paged_prefill.py`` is.  A grid step is a tile;
+  inside it a loop over the key steps (eight pages) up to the tile's last
+  row, the pages fetched whole and double-buffered, so no step runs that
+  no row of the tile can see.  **Every query head of a KV head rides one
+  product** (``G x TILE`` MXU rows against a step's keys) and **scores are
+  held transposed** (keys down the sublanes: maxima and sums are
+  reductions down them, the statistics rows of lanes).  **The choice comes
+  a block at a time** (``chosen [Hkv, NT, blocks, TILE]`` float32, blocks
+  down and the tile's rows across: a key step's eight blocks are one vreg)
+  and the kernel expands it: a block's row broadcast down its page's
+  sublanes, folded with the causal edge into one additive ``[keys, TILE]``
+  bias a KV head that the sixteen heads share.  **Only a step a mask edge
+  crosses pays for one**: a tile wholly before ``dense_len`` reads no
+  choice and takes the copy of the code without a bias on every step under
+  its first row; a tile with a row at or past ``dense_len`` takes the bias
+  on every step.  The pass stays DENSE over the keys a tile can see: its
+  128 rows share a key block, and the union of what 128 rows choose is
+  every visible block (PERF.md, PR 44), so there is nothing to skip.  It
+  was 14.5 % of ``sala-longdoc16k-closed``'s busy time as first built (a
+  product a head, a mask a key) and is about 5 % so (PERF.md, PR 44).
 - :func:`decode_attention` (kernel ``sparse_decode_attention``): one query a
   row over a **page list a (row, KV head)**: it fetches the listed pages
   and no other.
@@ -62,7 +79,6 @@ _LANES = 128
 # mixers) and pages a key step
 TILE = 128
 _STEP_PAGES = 8
-_VMEM_LIMIT = 48 * 1024 * 1024
 
 
 class Selection(NamedTuple):
@@ -177,16 +193,30 @@ def _gather_kv(pages, table):
     return got[..., 0::2, :, :], got[..., 1::2, :, :]
 
 
-def chunk_twin(q, pages, allowed, tile_table, sm_scale: float):
-    """``q [Hkv, G, NT * TILE, D]``, ``allowed [Hkv, NT * TILE, K]`` (0 / 1
-    a key), ``tile_table [NT, pp]`` (each tile's sequence's pages) ->
-    ``[Hkv, G, NT * TILE, D]``."""
+def chunk_twin(q, pages, chosen, tile_table, tile_pos0, tile_len,
+               dense_len: int, sm_scale: float):
+    """``q [Hkv, G, NT * TILE, D]``, ``chosen [Hkv, NT, blocks, TILE]`` (0 /
+    1 a block, blocks down and a tile's rows across), ``tile_table [NT,
+    pp]`` (each tile's sequence's pages), a tile's first position and live
+    rows -> ``[Hkv, G, NT * TILE, D]``.  Row ``i`` of tile ``n`` attends
+    key ``c`` where ``c <= tile_pos0[n] + i``, ``i < tile_len[n]`` and,
+    **in a tile with a row at or past** ``dense_len``, block ``c // page``
+    is chosen; a tile wholly before it reads no choice."""
     Hkv, G, rows, D = q.shape
     NT = tile_table.shape[0]
+    page = pages.shape[2]
     k, v = _gather_kv(pages, tile_table)                   # [NT, Hkv, K, D]
     K = k.shape[2]
+    i = jnp.arange(rows // NT)
+    ok = jnp.logical_and(
+        jnp.arange(K)[None, None] <= (tile_pos0[:, None] + i[None])[..., None],
+        (i[None] < tile_len[:, None])[..., None])           # [NT, rows, K]
+    picked = jnp.repeat(chosen.transpose(0, 1, 3, 2), page, axis=-1
+                        )[..., :K] > 0.5                    # [Hkv,NT,rows,K]
+    sparse = tile_pos0 + tile_len - 1 >= dense_len
+    ok = jnp.logical_and(ok[None], jnp.logical_or(
+        picked, jnp.logical_not(sparse)[None, :, None, None]))
     qt = q.reshape(Hkv, G, NT, rows // NT, D)
-    ok = allowed[:, :, :K].reshape(Hkv, NT, rows // NT, K) > 0.5
     s = jnp.einsum("hgnrd,nhkd->hgnrk", qt.astype(jnp.float32),
                    k.astype(jnp.float32)) * sm_scale
     s = jnp.where(ok[:, None], s, _MASK_VALUE)
@@ -235,92 +265,191 @@ def _fold(s, ok, v, m_prev, l_prev, acc_prev, dtype):
     return m_new, l_new, acc
 
 
-def _chunk_kernel(seq_ref, nblk_ref, table_ref,             # scalar prefetch
-                  q_ref, mask_ref, *rest, sm_scale: float, steps: int):
-    # q, o: [1, G, TILE, D]; mask: [1, TILE, keys]; then _STEP_PAGES K
-    # slabs and as many V slabs, each [1, 1, page, D]
-    kv = rest[:2 * _STEP_PAGES]
-    o_ref, kbuf, vbuf, m_sc, l_sc, acc_sc = rest[2 * _STEP_PAGES:]
-    n, b = pl.program_id(1), pl.program_id(2)
-    G = q_ref.shape[1]
-    page = kv[0].shape[2]
+def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
+                  q_ref, chosen_ref, pages_hbm, o_ref,
+                  buf, sems, vt_sc, delta_sc, m_sc, l_sc, acc_sc, *,
+                  sm_scale: float, dense_len: int, pages_per_seq: int):
+    # q_ref, o_ref: [Hkv, G, TILE, D] (one tile); chosen_ref: [Hkv, 1,
+    # blocks, TILE] float32; pages_hbm: [P, 2 Hkv, page, D]; buf: [2,
+    # _STEP_PAGES, 2 Hkv, page, D]; vt_sc: [Hkv, D, keys]; delta_sc: [keys,
+    # TILE] int32; m_sc, l_sc: [Hkv, 1, R]; acc_sc: [Hkv, D, R] with R = G *
+    # TILE (query head g's i-th row at lane ``g * TILE + i``).  Scores,
+    # weights and the accumulator are held TRANSPOSED (keys down, the
+    # tile's rows x heads across), as ``ops/paged_prefill.py`` holds them
+    Hkv, G, _, D = q_ref.shape
+    page = pages_hbm.shape[2]
+    keys = _STEP_PAGES * page
+    n = pl.program_id(0)
+    seq, pos0, length = seq_ref[n], pos0_ref[n], len_ref[n]
+    R = G * TILE
+    last = pos0 + length - 1
+    sparse = last >= dense_len
 
-    @pl.when(b == 0)
+    def copies(b, slot):
+        for i in range(_STEP_PAGES):
+            # a page past the sequence's last holds nothing a row attends
+            # (it lies past the diagonal); the clamps keep the address legal
+            page_id = jnp.maximum(table_ref[seq * pages_per_seq + jnp.minimum(
+                b * _STEP_PAGES + i, pages_per_seq - 1)], 0)
+            yield pltpu.make_async_copy(pages_hbm.at[page_id],
+                                        buf.at[slot, i], sems.at[slot])
+
+    @pl.when(n == 0)
+    def _():
+        # the key's column minus the row's number in the tile: the causal
+        # mask's only vector operand
+        delta_sc[...] = (
+            jax.lax.broadcasted_iota(jnp.int32, delta_sc.shape, 0)
+            - jax.lax.broadcasted_iota(jnp.int32, delta_sc.shape, 1))
+
+    def one_head(h, slot, bias):
+        """Every query head of KV head ``h`` against the step's keys: one
+        product for the scores, one for ``p V``."""
+        q = q_ref[h].reshape(R, D)
+        k = buf[slot, :, 2 * h].reshape(keys, D)
+        s = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale    # [keys, R]
+        if bias is not None:
+            s = s + jnp.concatenate([bias] * G, axis=1)
+        m_prev = m_sc[h]                                      # [1, R]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_sc[h] = alpha * l_sc[h] + jnp.sum(p, axis=0, keepdims=True)
+        acc_sc[h] = acc_sc[h] * alpha + jnp.dot(
+            vt_sc[h], p.astype(vt_sc.dtype),
+            preferred_element_type=jnp.float32)               # [D, R]
+        m_sc[h] = m_new
+
+    def every_head(slot, biases):
+        for h in range(Hkv):
+            one_head(h, slot, None if biases is None else biases[h])
+
+    def one_step(b, _):
+        slot = b % 2
+
+        @pl.when((b + 1) * keys <= last)
+        def _():
+            for copy in copies(b + 1, 1 - slot):
+                copy.start()
+
+        for copy in copies(b, slot):
+            copy.wait()
+        for h in range(Hkv):
+            vt_sc[h] = buf[slot, :, 2 * h + 1].reshape(keys, D).astype(
+                jnp.float32).T.astype(vt_sc.dtype)
+        # key ``b * keys + c`` lies at or before the tile's i-th row where
+        # ``c - i <= edge``; a step that ends at or before the tile's first
+        # row has no key past any row
+        edge = pos0 - b * keys
+        diagonal = keys - 1 > edge
+
+        def masked():
+            # masked entries take _MASK_VALUE (a score added to it IS it)
+            # and weigh exp(_MASK_VALUE - m) = 0 once a row has met a key
+            # it attends; what a row gathered before that is scaled by the
+            # same 0 then, and a row that never meets one is zeroed at the
+            # end by its maximum
+            causal = jnp.where(
+                delta_sc[...] <= jnp.where(diagonal, edge, keys),
+                0.0, _MASK_VALUE)                              # [keys, TILE]
+            biases = []
+            for h in range(Hkv):
+                c = chosen_ref[h, 0, pl.ds(
+                    pl.multiple_of(b * _STEP_PAGES, _STEP_PAGES),
+                    _STEP_PAGES), :]                           # [8, TILE]
+                c = jnp.where(sparse, (1.0 - c) * _MASK_VALUE, 0.0)
+                biases.append(jnp.minimum(causal, jnp.concatenate([
+                    jnp.broadcast_to(c[i:i + 1], (page, TILE))
+                    for i in range(_STEP_PAGES)], axis=0)))
+            every_head(slot, biases)
+
+        # only a step a mask edge crosses pays for a mask: the choice in a
+        # tile with a row at or past ``dense_len``, the diagonal in all
+        is_masked = jnp.logical_or(sparse, diagonal)
+        pl.when(is_masked)(masked)
+        pl.when(jnp.logical_not(is_masked))(
+            functools.partial(every_head, slot, None))
+
+    @pl.when(length == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(length > 0)
     def _():
         m_sc[...] = jnp.full_like(m_sc, _MASK_VALUE)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
-
-    @pl.when(b < nblk_ref[n])
-    def _():
-        for i in range(_STEP_PAGES):
-            kbuf[pl.ds(i * page, page), :] = kv[i][0, 0]
-            vbuf[pl.ds(i * page, page), :] = kv[_STEP_PAGES + i][0, 0]
-        ok = mask_ref[0].astype(jnp.float32) > 0.5
-        k, v = kbuf[...], vbuf[...]
-
-        def head(h, _):
-            s = jax.lax.dot_general(
-                q_ref[0, h], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale
-            m_sc[h], l_sc[h], acc_sc[h] = _fold(
-                s, ok, v, m_sc[h], l_sc[h], acc_sc[h], k.dtype)
-            return None
-
-        jax.lax.fori_loop(0, G, head, None)
-
-    @pl.when(b == steps - 1)
-    def _():
-        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)
-                    ).astype(o_ref.dtype)
+        for copy in copies(0, 0):
+            copy.start()
+        jax.lax.fori_loop(0, last // keys + 1, one_step, None)
+        live = jax.lax.broadcasted_iota(jnp.int32, (D, TILE), 1) < length
+        for h in range(Hkv):
+            for g in range(G):
+                lanes = pl.ds(g * TILE, TILE)
+                # a row that met no key it attends holds the first maximum
+                ok = jnp.logical_and(live, m_sc[h, :, lanes] > _MASK_VALUE)
+                o_ref[h, g] = jnp.where(
+                    ok, acc_sc[h, :, lanes] / l_sc[h, :, lanes], 0.0
+                ).T.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
-def _chunk(q, pages, allowed, tile_seq, tile_nblk, page_indices, *,
-           sm_scale: float, interpret: bool):
+def chunk_vmem_bytes(Hkv: int, G: int, D: int, page: int,
+                     blocks: int) -> int:
+    """What a call of the chunk kernel keeps in VMEM, for
+    ``vmem_limit_bytes``: a tile of q and of the output and the tile's
+    choice, two buffers each (the pipeline's); two key steps as fetched
+    and V of one transposed; the accumulator of every head; the causal
+    table and a bias a KV head; and seven score tiles (a key step against
+    every query head of a KV head) of working room."""
+    keys, R = _STEP_PAGES * page, G * TILE
+    tile = Hkv * R * D * 2
+    return (2 * 2 * tile + 2 * Hkv * blocks * TILE * 4
+            + 2 * keys * 2 * Hkv * D * 2 + Hkv * D * keys * 2
+            + Hkv * D * R * 4 + (1 + Hkv) * keys * TILE * 4
+            + 7 * keys * R * 4)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "dense_len", "sm_scale", "interpret"))
+def _chunk(q, pages, chosen, tile_seq, tile_pos0, tile_len, page_indices, *,
+           dense_len: int, sm_scale: float, interpret: bool):
     Hkv, G, rows, D = q.shape
     _, combined, page, _ = pages.shape
     NT = rows // TILE
     pp = page_indices.shape[1]
-    keys = _STEP_PAGES * page
-    steps = allowed.shape[-1] // keys
-    assert allowed.shape[-1] == steps * keys, (allowed.shape, keys)
-
-    def step_of(n, b, nblk):
-        return jnp.minimum(b, jnp.maximum(nblk[n] - 1, 0))
-
-    def slab(i, c):
-        def index(g, n, b, seq, nblk, table):
-            p = jnp.minimum(step_of(n, b, nblk) * _STEP_PAGES + i, pp - 1)
-            return (jnp.maximum(table[seq[n] * pp + p], 0), 2 * g + c, 0, 0)
-        return pl.BlockSpec((1, 1, page, D), index)
-
-    tile = pl.BlockSpec((1, G, TILE, D), lambda g, n, b, *_: (g, 0, n, 0))
+    keys, R = _STEP_PAGES * page, G * TILE
+    blocks = chosen.shape[2]
+    assert chosen.shape == (Hkv, NT, blocks, TILE) and \
+        blocks == chunk_blocks(pp), (chosen.shape, pp)
+    tile = pl.BlockSpec((Hkv, G, TILE, D), lambda n, *_: (0, 0, n, 0))
     return pl.pallas_call(
-        functools.partial(_chunk_kernel, sm_scale=sm_scale, steps=steps),
+        functools.partial(_chunk_kernel, sm_scale=sm_scale,
+                          dense_len=dense_len, pages_per_seq=pp),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             in_specs=[tile,
-                      pl.BlockSpec((1, TILE, keys),
-                                   lambda g, n, b, seq, nblk, table:
-                                   (g, n, step_of(n, b, nblk)))]
-            + [slab(i, 0) for i in range(_STEP_PAGES)]
-            + [slab(i, 1) for i in range(_STEP_PAGES)],
+                      pl.BlockSpec((Hkv, 1, blocks, TILE),
+                                   lambda n, *_: (0, n, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=tile,
-            grid=(Hkv, NT, steps),
+            grid=(NT,),
             scratch_shapes=[
-                pltpu.VMEM((keys, D), pages.dtype),
-                pltpu.VMEM((keys, D), pages.dtype),
-                pltpu.VMEM((G, TILE, 1), jnp.float32),
-                pltpu.VMEM((G, TILE, 1), jnp.float32),
-                pltpu.VMEM((G, TILE, D), jnp.float32)]),
+                pltpu.VMEM((2, _STEP_PAGES, combined, page, D), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((Hkv, D, keys), pages.dtype),
+                pltpu.VMEM((keys, TILE), jnp.int32),
+                pltpu.VMEM((Hkv, 1, R), jnp.float32),
+                pltpu.VMEM((Hkv, 1, R), jnp.float32),
+                pltpu.VMEM((Hkv, D, R), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=chunk_vmem_bytes(Hkv, G, D, page, blocks)),
         interpret=interpret, name=CHUNK_NAME,
-    )(tile_seq, tile_nblk, page_indices.reshape(-1), q, allowed,
-      *([pages] * (2 * _STEP_PAGES)))
+    )(tile_seq, tile_pos0, tile_len, page_indices.reshape(-1), q, chosen,
+      pages)
 
 
 def eligible(D: int, page: int, dtype) -> bool:
@@ -329,20 +458,23 @@ def eligible(D: int, page: int, dtype) -> bool:
             and page % 16 == 0)
 
 
-def mask_width(pages_per_seq: int, page: int) -> int:
-    """Keys the chunk kernel's mask spans: whole key steps."""
-    return -(-pages_per_seq // _STEP_PAGES) * _STEP_PAGES * page
+def chunk_blocks(pages_per_seq: int) -> int:
+    """Blocks the chunk kernel's choice spans: whole key steps."""
+    return -(-pages_per_seq // _STEP_PAGES) * _STEP_PAGES
 
 
-def chunk_attention(q, pages, allowed, tile_seq, tile_last, page_indices, *,
-                    sm_scale: float, interpret: bool = False) -> jax.Array:
+def chunk_attention(q, pages, chosen, tile_seq, tile_pos0, tile_len,
+                    page_indices, *, dense_len: int, sm_scale: float,
+                    interpret: bool = False) -> jax.Array:
     """Attention of the tiled prompt rows of a tick: ``q [Hkv, G, NT *
-    TILE, D]`` over ``pages [P, 2 Hkv, page, D]``; ``allowed [Hkv, NT *
-    TILE, mask_width]`` says which keys (by position in the tile's
-    sequence, ``tile_seq [NT]``, through ``page_indices [S, pp]``) a row
-    of a KV head attends: chosen block, causal and live all in one;
-    ``tile_last [NT]`` the position of a tile's last live row (-1: no row).
-    The Pallas kernel on a TPU, else the twin; recorded under
+    TILE, D]`` over ``pages [P, 2 Hkv, page, D]`` through ``page_indices
+    [S, pp]``, tile ``n`` rows ``tile_len[n]`` (0: no row, and zeros come
+    back) of sequence ``tile_seq[n]`` from position ``tile_pos0[n]`` on.  A
+    row attends the keys at or before it; in a tile with a row at or past
+    ``dense_len``, of those only the keys of its chosen blocks: ``chosen
+    [Hkv, NT, chunk_blocks(pp), TILE]`` float32, 1 where the row across
+    chose the block down (a tile wholly before ``dense_len`` reads none of
+    it).  The Pallas kernel on a TPU, else the twin; recorded under
     :data:`KERNEL_PREFILL`."""
     page, D = pages.shape[2], pages.shape[3]
     route = routes.take(
@@ -350,12 +482,14 @@ def chunk_attention(q, pages, allowed, tile_seq, tile_last, page_indices, *,
         and q.dtype == pages.dtype, interpret=interpret,
         pallas=f"pallas-sparse-chunk-{TILE}x{_STEP_PAGES * page}")
     if route == routes.XLA:
-        return chunk_twin(q, pages, allowed,
-                          jnp.take(page_indices, tile_seq, axis=0), sm_scale)
-    nblk = (tile_last + _STEP_PAGES * page) // (_STEP_PAGES * page)
-    return _chunk(q, pages, allowed.astype(pages.dtype), tile_seq,
-                  nblk.astype(jnp.int32), page_indices.astype(jnp.int32),
-                  sm_scale=sm_scale, interpret=interpret)
+        return chunk_twin(q, pages, chosen,
+                          jnp.take(page_indices, tile_seq, axis=0),
+                          tile_pos0, tile_len, dense_len, sm_scale)
+    return _chunk(q, pages, chosen.astype(jnp.float32),
+                  tile_seq.astype(jnp.int32), tile_pos0.astype(jnp.int32),
+                  tile_len.astype(jnp.int32), page_indices.astype(jnp.int32),
+                  dense_len=dense_len, sm_scale=sm_scale,
+                  interpret=interpret)
 
 
 def _decode_kernel(list_ref, count_ref, last_ref,           # scalar prefetch

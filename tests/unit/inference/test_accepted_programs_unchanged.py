@@ -6,7 +6,8 @@ QK-norm and padded heads of 64 added is reached by no model that does not
 ask for it.  PR 43 added the two programs of ``minicpm-sala-serve``
 (tile layout, chunked scan and one-token step over a matrix state; block
 selection over a two-KV-head pool), taken on its own tree: what a later PR
-adds must leave them as they are too.
+adds must leave them as they are too (PR 44 rebuilt the fused tick's
+block-selecting chunk kernel and re-took ``sala.fused`` alone).
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -37,8 +38,12 @@ EXPECTED = {
     "olmoe.decode_block": "03ce8a62bd7290ce",
     "ouro.fused": "8443cc00e342ba49",
     "ouro.decode_block": "f87646743932c66c",
-    # taken by PR 43, which added the model
-    "sala.fused": "9e3d99fee4138173",
+    # taken by PR 43, which added the model.  ``sala.fused`` was re-taken by
+    # PR 44, which MEANT to move it: the tick's prompt-chunk attention takes
+    # the chosen blocks a block at a time (``[Hkv, NT, blocks, 128]``) where
+    # it took a mask a key (``[Hkv, rows, keys]``); the decode block, whose
+    # rows take the chosen-page kernel, is PR 43's to the letter
+    "sala.fused": "2e90302b3dc12e5f",
     "sala.decode_block": "86bf79c9930ecd7c",
 }
 

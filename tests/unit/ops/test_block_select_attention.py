@@ -78,38 +78,69 @@ def _pool(r, P, page):
                        ).astype(jnp.bfloat16)
 
 
-def test_the_chunk_kernel_is_its_twin():
+# (packed rows' cu_q_lens, each sequence's first position, dense_len, the
+# choice).  A page is 16 keys, a key step 128, a tile 128 rows; "random" is 60
+# % of the blocks for a row at or past dense_len (and every block before it,
+# as ``block_mask`` answers), "poisoned" is all zero
+CHUNK_CASES = {
+    # tiles wholly before dense_len read no choice: plain causal attention
+    "before-dense-len-choice-poisoned": ([0, 256], [0], 10_000, "poisoned"),
+    "tile-straddles-dense-len": ([0, 128], [64], 128, "random"),
+    "tile-past-dense-len": ([0, 128], [200], 64, "random"),
+    "last-tile-partial": ([0, 141], [50], 100, "random"),
+    # one sequence of two rows among four slots: five of six tiles are empty
+    "empty-tiles": ([0, 1, 3, 4, 4], [30, 140, 7, 0], 64, "random"),
+    "two-sequences-two-page-tables": ([0, 1, 141, 291], [30, 50, 0], 128,
+                                      "random"),
+    "context-ends-inside-a-key-step": ([0, 100], [100], 150, "random"),
+    "a-prompt-in-three-ticks-third": ([0, 200], [230], 128, "random"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CHUNK_CASES))
+def test_the_chunk_kernel_is_its_twin(case):
+    cu, first_pos, dense_len, choice = CHUNK_CASES[case]
     r = np.random.default_rng(1)
-    P, page, pp, seqs, T = 40, 16, 12, 3, 300
+    page, pp, seqs, T = 16, 28, len(first_pos), 300
+    P = 1 + seqs * pp
     pages = _pool(r, P, page)
     table = jnp.asarray(r.permutation(P - 1)[:seqs * pp].reshape(seqs, pp)
                         + 1, jnp.int32)
-    cu = jnp.asarray([0, 1, 141, 191])
-    first_pos = jnp.asarray([30, 50, 0])
-    lay = la.tile_layout(cu, jnp.asarray([3]), first_pos, T, bsa.TILE)
+    lay = la.tile_layout(jnp.asarray(cu), jnp.asarray([seqs]),
+                         jnp.asarray(first_pos), T, bsa.TILE)
     NT = lay["seq"].shape[0]
-    K = bsa.mask_width(pp, page)
+    blocks = bsa.chunk_blocks(pp)
     q = jnp.asarray(r.normal(size=(HKV, G, NT * bsa.TILE, D)), jnp.float32
                     ).astype(jnp.bfloat16)
     pos = lay["pos0"][:, None] + jnp.arange(bsa.TILE)[None]
-    live = (jnp.arange(bsa.TILE)[None] < lay["len"][:, None])
-    block_ok = jnp.asarray(r.random((HKV, NT, bsa.TILE, K // page)) < 0.6)
-    block_ok = block_ok.at[..., 0].set(True)
-    allowed = (jnp.repeat(block_ok, page, axis=-1)
-               & (jnp.arange(K)[None, None, None] <= pos[None, :, :, None])
-               & live[None, :, :, None]).reshape(HKV, NT * bsa.TILE, K)
-    allowed = allowed.astype(jnp.bfloat16)
-    tile_last = jnp.where(lay["len"] > 0, lay["pos0"] + lay["len"] - 1, -1)
-    want = bsa.chunk_twin(q, pages, allowed,
-                          jnp.take(table, lay["seq"], axis=0), SCALE)
-    nblk = (tile_last + 8 * page) // (8 * page)
-    got = bsa._chunk(q, pages, allowed, lay["seq"], nblk.astype(jnp.int32),
-                     table, sm_scale=SCALE, interpret=True)
-    rows = np.asarray(live).reshape(-1)
-    diff = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
-    assert diff[:, :, rows].max() < 2.0 ** -6
-    assert np.abs(np.asarray(want, np.float32))[:, :, rows].max() > 0.3
-    assert (np.asarray(got, np.float32)[:, :, ~rows] == 0).all()
+    live = np.asarray(jnp.arange(bsa.TILE)[None] < lay["len"][:, None])
+    if choice == "poisoned":
+        chosen = jnp.zeros((HKV, NT, blocks, bsa.TILE), jnp.float32)
+        plain = jnp.ones_like(chosen)
+    else:
+        chosen = jnp.asarray(r.random((HKV, NT, blocks, bsa.TILE)) < 0.6)
+        chosen = jnp.logical_or(chosen, (pos < dense_len)[None, :, None])
+        # the first live row past dense_len chose nothing: it comes back zero
+        far = np.argwhere(live & (np.asarray(pos) >= dense_len))[0]
+        chosen = chosen.at[:, far[0], :, far[1]].set(False)
+        chosen = chosen.astype(jnp.float32)
+    args = (jnp.take(table, lay["seq"], axis=0), lay["pos0"], lay["len"])
+    want = bsa.chunk_twin(q, pages, chosen, *args, dense_len, SCALE)
+    if choice == "poisoned":
+        # every block chosen everywhere: the causal mask and nothing else
+        want = bsa.chunk_twin(q, pages, plain, *args, 0, SCALE)
+    got = bsa._chunk(q, pages, chosen, lay["seq"], lay["pos0"], lay["len"],
+                     table, dense_len=dense_len, sm_scale=SCALE,
+                     interpret=True)
+    rows = live.reshape(-1)
+    assert rows.sum() == sum(b - a for a, b in zip(cu, cu[1:]) if b - a > 1)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.abs(got - want)[:, :, rows].max() < 2.0 ** -6
+    assert np.abs(want)[:, :, rows].max() > 0.3
+    assert (got[:, :, ~rows] == 0).all()
+    if choice == "random":
+        row = far[0] * bsa.TILE + far[1]
+        assert (got[:, :, row] == 0).all() and (want[:, :, row] == 0).all()
 
 
 def test_the_decode_kernel_reads_the_listed_pages_and_is_its_twin():

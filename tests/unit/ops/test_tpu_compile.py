@@ -553,8 +553,9 @@ def test_linear_attention_kernels_at_the_serving_configuration(chip_compile):
 def test_block_select_kernels_are_tiled_for_two_kv_heads(chip_compile):
     """``ops/block_select_attention.py`` over a pool of two KV heads laid
     out heads first (``[P, 4, page, D]``: a slab a head is whole tiles):
-    the masked chunk kernel over a tick's tiles and the chosen-page kernel
-    over lists of 128 pages."""
+    the chunk kernel over a tick's tiles, its choice a block a bit and its
+    VMEM reckoned from the shapes, and the chosen-page kernel over lists of
+    128 pages."""
     from deepspeed_tpu.ops import block_select_attention as bsa
     from deepspeed_tpu.ops import linear_attention as la
 
@@ -562,16 +563,22 @@ def test_block_select_kernels_are_tiled_for_two_kv_heads(chip_compile):
     G = c["H"] // c["Hkv"]
     NT = -(-c["T"] // bsa.TILE) + c["S"]
     assert bsa.TILE == la.CHUNK         # one tile layout for both mixers
+    assert NT == 17
     pool = ((c["pool"], 2 * c["Hkv"], c["page"], c["D"]), bf)
-    K = bsa.mask_width(c["pp"], c["page"])
-    assert K == 33 * 512
+    blocks = bsa.chunk_blocks(c["pp"])
+    assert blocks == 33 * 8
+    # the limit the call asks for is its own reckoning, and the compiler
+    # (which refuses a kernel that needs more than its limit) takes it
+    asked = bsa.chunk_vmem_bytes(c["Hkv"], G, c["D"], c["page"], blocks)
+    assert asked < 64 * 1024 * 1024     # half of the v5e's VMEM
     text = chip_compile(
-        functools.partial(bsa._chunk.__wrapped__, sm_scale=0.0884,
-                          interpret=False),
+        functools.partial(bsa._chunk.__wrapped__, dense_len=8192,
+                          sm_scale=0.0884, interpret=False),
         ((c["Hkv"], G, NT * bsa.TILE, c["D"]), bf), pool,
-        ((c["Hkv"], NT * bsa.TILE, K), bf), ((NT,), i32), ((NT,), i32),
-        ((c["S"], c["pp"]), i32))
+        ((c["Hkv"], NT, blocks, bsa.TILE), jnp.float32), ((NT,), i32),
+        ((NT,), i32), ((NT,), i32), ((c["S"], c["pp"]), i32))
     assert f"%{bsa.CHUNK_NAME}" in text
+    assert f'"size":"{asked}"' in text      # the call's scoped memory
     L = bsa.Selection().list_width()
     assert L == 128
     text = chip_compile(
