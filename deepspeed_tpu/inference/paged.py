@@ -1277,21 +1277,21 @@ def latent_update_and_attend(mdl, q: jax.Array, rows: jax.Array,
 
     ``q [T, H, w]``: every head's ABSORBED query (the up-projection of the
     keys folded in, the rotated part behind it); ``rows [T, w]``: ``[c |
-    k_r]`` of this tick's tokens, the compressed vector after its norm and
-    the shared key after its rotation; both are zero-padded here to the
-    pool's ``W`` (:func:`latent_width`).  Returns ``[T, H, value_width]``:
-    the weighted sums of the rows' first ``value_width`` columns, which the
-    caller up-projects.  As :func:`paged_update_and_attend`: ``kv_lens``
-    includes this tick's tokens, page 0 is the trash page, ``mutable=
-    ["cache"]`` on apply.  A decode program and a fused tick say which they
-    are in ``ragged_meta`` and take ``ops/latent_attention.py``'s kernels;
-    every other caller takes the XLA twin."""
+    k_r]`` of this tick's tokens; narrower than the pool's ``W``
+    (:func:`latent_width`) they are zero-padded here.  Returns ``[T, H,
+    value_width]``: the weighted sums of the rows' first ``value_width``
+    columns, which the caller up-projects.  A FUSED TICK (``ragged_meta``
+    says which program it is) hands ``q`` as the pair ``ops/
+    latent_attention.py:fused_tick_attention`` takes (heads first as the
+    module holds it, and the packed sequences' first rows), and gets that
+    function's answer in the layout the up-projection reads; a decode
+    program takes the decode kernel, every other caller the XLA twin."""
     assert "staged_kv" not in ragged_meta and "carry_m" not in ragged_meta, (
         "latent pages have no partial-residency scan")
     W = latent_width(cfg)
-    pad = lambda a: jnp.pad(                            # noqa: E731
+    pad = lambda a: a if a.shape[-1] == W else jnp.pad(  # noqa: E731
         a, ((0, 0),) * (a.ndim - 1) + ((0, W - a.shape[-1]),))
-    q, rows = pad(q), pad(rows)
+    q, rows = jax.tree_util.tree_map(pad, (q, rows))
     P, page = cfg.kv_num_pages, cfg.kv_page_size
     assert P > 1, "paged_decode requires kv_num_pages (engine sets it)"
     pages_var = mdl.variable("cache", LATENT_PAGES, jnp.zeros, (P, page, W),
@@ -1300,16 +1300,16 @@ def latent_update_and_attend(mdl, q: jax.Array, rows: jax.Array,
     pages = flat.at[ragged_meta["new_kv_dest"]].set(
         rows.astype(flat.dtype), mode="drop").reshape(P, page, W)
     pages_var.value = pages
-    ragged = (q, pages, ragged_meta["kv_lens"], ragged_meta["page_indices"],
+    ragged = (pages, ragged_meta["kv_lens"], ragged_meta["page_indices"],
               ragged_meta["cu_q_lens"], ragged_meta["num_seqs"])
-    twin = lambda: ref_latent_paged_attention(          # noqa: E731
-        *ragged, rank=value_width, sm_scale=sm_scale)
+    twin = lambda q=q: ref_latent_paged_attention(      # noqa: E731
+        q, *ragged, rank=value_width, sm_scale=sm_scale)
     if ragged_meta.get("fused_tick") is True:
         return latent_attention.fused_tick_attention(
-            *ragged, rank=value_width, sm_scale=sm_scale, fallback=twin)
+            *q, *ragged, rank=value_width, sm_scale=sm_scale, fallback=twin)
     if ragged_meta.get("one_query_a_slot") is True:
         return latent_attention.decode_attention(
-            *ragged[:4], ragged_meta.get("live"), rank=value_width,
+            q, *ragged[:3], ragged_meta.get("live"), rank=value_width,
             sm_scale=sm_scale, fallback=twin)
     return twin()
 

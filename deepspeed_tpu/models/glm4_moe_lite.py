@@ -61,6 +61,7 @@ standard deviation :data:`EXPERT_BIAS_INIT_STD`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -195,10 +196,16 @@ class Glm4MoeLiteAttention(nn.Module):
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
         c_q = norm("q_a_layernorm")(
             nn.Dense(cfg.q_lora_rank, name="q_a_proj", **dense)(x))
-        q = nn.Dense(H * (dn + dr), name="q_b_proj", **dense)(c_q)
-        q = q.reshape(B, S, H, dn + dr).transpose(0, 2, 1, 3)
-        q_nope = q[..., :dn]                                # [B, H, S, dn]
-        q_rope = rotary_embedding(q[..., dn:], positions, cfg.rope_theta)
+        q_b_proj = nn.Dense(H * (dn + dr), name="q_b_proj", **dense)
+
+        def queries(c_q, positions):
+            """``[B, n, q_lora_rank]`` -> every head's ``(q_nope [B, H, n,
+            dn], q_rope [B, H, n, dr])``, heads first."""
+            q = q_b_proj(c_q).reshape(B, -1, H, dn + dr).transpose(0, 2, 1, 3)
+            return q[..., :dn], rotary_embedding(q[..., dn:], positions,
+                                                 cfg.rope_theta)
+
+        q_nope, q_rope = queries(c_q, positions)
         kv_a = nn.Dense(rank + dr, name="kv_a_proj_with_mqa", **dense)(x)
         c = norm("kv_a_layernorm")(kv_a[..., :rank])        # [B, S, rank]
         k_r = rotary_embedding(kv_a[..., rank:][:, None], positions,
@@ -207,25 +214,65 @@ class Glm4MoeLiteAttention(nn.Module):
                            (rank, H * (dn + dv)), cfg.param_dtype)
         w_kvb = w_kvb.astype(cfg.dtype).reshape(rank, H, dn + dv)
         sm_scale = float(1.0 / np.sqrt(dn + dr))
-        out = lambda y: nn.Dense(E, name="o_proj", **dense)(
-            y.reshape(B, S, H * dv))
+        o_proj = nn.Dense(E, name="o_proj", **dense)
+        out = lambda y: o_proj(y.reshape(y.shape[:-2] + (H * dv,)))
 
         if cfg.paged_decode:
-            from deepspeed_tpu.inference.paged import latent_update_and_attend
+            from deepspeed_tpu.inference.paged import (
+                latent_update_and_attend, latent_width)
+            from deepspeed_tpu.ops.latent_attention import tick_rows
 
             assert ragged_meta is not None and B == 1, (
                 "paged token batches are [1, T] with the engine's "
                 "ragged_meta")
             with jax.named_scope("absorb"):
-                q_c = jnp.einsum("hsn,chn->shc", q_nope[0], w_kvb[..., :dn])
-            q_abs = jnp.concatenate(
-                [q_c, q_rope[0].transpose(1, 0, 2)], axis=-1)  # [S, H, W]
+                w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
             rows = jnp.concatenate([c[0], k_r[0]], axis=-1)    # [S, W]
-            o_c = latent_update_and_attend(
-                self, q_abs, rows, ragged_meta, cfg, value_width=rank,
-                sm_scale=sm_scale)                             # [S, H, rank]
+            attend = functools.partial(
+                latent_update_and_attend, self, rows=rows,
+                ragged_meta=ragged_meta, cfg=cfg, value_width=rank,
+                sm_scale=sm_scale)
+
+            def tokens_first(q_nope, q_rope):
+                """Every head's absorbed query, ``[n, H, rank + dr]``."""
+                with jax.named_scope("absorb"):
+                    q_c = jnp.einsum("hsn,chn->shc", q_nope[0], w_k)
+                return jnp.concatenate(
+                    [q_c, q_rope[0].transpose(1, 0, 2)], axis=-1)
+
+            if ragged_meta.get("fused_tick") is True:
+                # a fused tick's kernel takes q HEADS FIRST as it is held
+                # here and answers as the up-projection reads: the tick's
+                # rows grow to whole sublane tiles while they are ``dn`` and
+                # ``dr`` wide, the absorbed query is born beside its
+                # rotated part and the row's padding, and nothing as large
+                # as ``T x H x W`` is turned or padded on either side
+                Tp = tick_rows(S)
+                grow = lambda a: jnp.pad(                   # noqa: E731
+                    a, ((0, 0), (0, Tp - S), (0, 0)))
+                with jax.named_scope("absorb"):
+                    q_c = jnp.einsum("hsn,chn->hsc", grow(q_nope[0]), w_k)
+                q_abs = jnp.concatenate([q_c, grow(q_rope[0]), jnp.zeros(
+                    (H, Tp, latent_width(cfg) - rank - dr), q_c.dtype)],
+                    axis=-1)                                   # [H, Tp, W]
+                # the first row of every packed sequence again, tokens
+                # first as the decode kernel takes the sequences of ONE
+                # query row: from the rows of ``c_q`` (a gather along the
+                # tokens of anything held heads first has XLA turn it)
+                first = jnp.minimum(ragged_meta["cu_q_lens"][:-1], S - 1)
+                q_one = tokens_first(*queries(
+                    jnp.take(c_q, first, axis=1),
+                    jnp.take(positions, first, axis=-1)))
+                o_c, o_one, at = attend(q=(q_abs, q_one))
+                with jax.named_scope("absorb"):
+                    y = jnp.einsum("htc,chv->thv", o_c, w_v)
+                    y_one = jnp.einsum("shc,chv->shv", o_one, w_v)
+                # the sequences of one query row join where the rows are
+                # narrowest, behind the output projection
+                return out(y)[:S].at[at].set(out(y_one), mode="drop")[None]
+            o_c = attend(q=tokens_first(q_nope, q_rope))       # [S, H, rank]
             with jax.named_scope("absorb"):
-                y = jnp.einsum("shc,chv->shv", o_c, w_kvb[..., dn:])
+                y = jnp.einsum("shc,chv->shv", o_c, w_v)
             return out(y[None])
 
         # whole sequences from their first token: expanded, nothing cached

@@ -244,3 +244,65 @@ def test_the_reference_blocks_its_queries_and_slices_its_head(served,
 def test_reference_is_independent_of_the_program():
     src = open(ref.__file__).read()
     assert "deepspeed_tpu" not in src.split('"""', 2)[2]
+
+
+def test_a_fused_tick_turns_and_pads_nothing_as_large_as_its_queries(
+        monkeypatch):
+    """The attention module's fused branch, traced where the kernels run
+    (bf16, a latent row of whole lane tiles, the backend answering as the
+    chip): the absorbed queries are born heads first and as wide as the
+    pool's row, and the kernel's answer is multiplied as it lies.  Between
+    the absorbing products and the kernel call the jaxpr holds NO
+    ``transpose`` and NO ``pad`` of an array of ``T x H x rank`` elements
+    or more (the parent turned q and the answer once each and padded q
+    twice: ``T x H x W``); both kernels are in it."""
+    from deepspeed_tpu.ops import latent_attention as la
+    from deepspeed_tpu.ops import routes
+
+    monkeypatch.setattr(routes, "on_tpu", lambda: True)
+    monkeypatch.setattr(routes, "_taken", {})
+    T, slots, pp, page = 200, 4, 20, 16
+    cfg = glm.get_config(
+        "tinyglm", dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        kv_lora_rank=128, paged_decode=True, kv_page_size=page,
+        kv_num_pages=1 + slots * pp, remat=False, use_flash_attention=False)
+    H, rank, W = cfg.num_attention_heads, 128, paged.latent_width(cfg)
+    assert W == 256
+    module = glm.Glm4MoeLiteAttention(cfg)
+    i32 = jnp.int32
+    meta = {"kv_lens": jnp.asarray([150, 31, 120, 0], i32),
+            "page_indices": jnp.arange(1, 1 + slots * pp, dtype=i32
+                                       ).reshape(slots, pp),
+            "cu_q_lens": jnp.asarray([0, 150, 151, 200, 200], i32),
+            "num_seqs": jnp.asarray([3], i32),
+            "new_kv_dest": jnp.arange(T, dtype=i32), "fused_tick": True}
+    x = jnp.zeros((1, T, cfg.hidden_size), jnp.bfloat16)
+    pos = jnp.arange(T)[None]
+    variables = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0), x, pos, True, meta))
+    jaxpr = jax.make_jaxpr(lambda v, x: module.apply(
+        v, x, pos, True, meta, mutable=["cache"]))(variables, x)
+
+    def equations(jp):
+        """Every equation outside the kernels' own bodies."""
+        for eqn in jp.eqns:
+            yield eqn
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations(sub)
+
+    eqns = list(equations(jaxpr.jaxpr))
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels == [la.NAME_PREFILL, la.NAME_DECODE]
+    assert routes.taken()[la.KERNEL_PREFILL].startswith("pallas-latent-chunk")
+    turned = [(e.primitive.name, e.outvars[0].aval.shape) for e in eqns
+              if e.primitive.name in ("transpose", "pad")
+              and e.outvars[0].aval.size >= T * H * rank]
+    assert not turned, turned
+    # and the queries the kernel takes are the einsum's own layout
+    chunk = next(e for e in eqns if e.primitive.name == "pallas_call")
+    q_in = [v.aval.shape for v in chunk.invars if len(v.aval.shape) == 3]
+    assert (H, la.tick_rows(T), W) in q_in
+    assert chunk.outvars[0].aval.shape == (H, la.tick_rows(T), rank)

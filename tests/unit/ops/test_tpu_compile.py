@@ -482,19 +482,29 @@ def test_latent_decode_kernel_at_the_serving_configuration(chip_compile):
     assert f"%{la.NAME_DECODE}" in text
 
 
-@pytest.mark.parametrize("chunk", [512, 1024])
+@pytest.mark.parametrize("chunk", [512, 1024, 2048])
 def test_latent_chunk_kernel_at_the_serving_configuration(chip_compile,
                                                           chunk):
-    """The chunk kernel at the tiling its rule gives, for the fused tick of
-    ``glm-4.7-flash-serve`` (a chunk of 512 beside 8 slots, padded to whole
-    sublane tiles) and for a chunk of 1,024, which still fits VMEM."""
+    """The chunk kernel at the tiling its rule gives, at the published
+    widths (20 heads, rows of 640, rank 512, pages of 64), heads first as
+    the module hands it q: the fused tick of ``glm-4.7-flash-serve`` (a
+    chunk of 1,024 beside 8 slots: 1,032 rows, grown to whole sublane
+    tiles), the chunk of 512 it was first served at, and a chunk of 2,048,
+    which fits VMEM since q no longer lies whole in it (ROADMAP R3(d)).
+    ``chunk_vmem_bytes`` is what a call keeps there: the reckoning stands
+    over what the chip's compiler asks (45.75 and 65.9 MiB at 1,024 and
+    2,048: compile rehearsal, PR 45) and under the ceiling the call hands
+    it."""
     from deepspeed_tpu.ops import latent_attention as la
 
     c, i32, bf = LATENT, jnp.int32, jnp.bfloat16
     _, rt, kb = la.tiling(c["page"], c["pp"], c["W"], c["rank"], bf)
-    T = -(-(c["S"] + chunk) // 16) * 16
-    assert la.chunk_vmem_bytes(T, c["H"], c["W"], c["rank"], rt,
-                               kb * c["page"]) < la._VMEM_CEILING
+    T = la.tick_rows(c["S"] + chunk)
+    assert T == -(-(c["S"] + chunk) // 16) * 16
+    need = la.chunk_vmem_bytes(T, c["H"], c["W"], c["rank"], rt,
+                               kb * c["page"])
+    assert need < la._VMEM_CEILING
+    assert need >> 20 == {512: 40, 1024: 50, 2048: 70}[chunk]
     text = chip_compile(
         functools.partial(la._chunk, rank=c["rank"], sm_scale=1 / 16.0,
                           rt=rt, kb=kb, interpret=False),
