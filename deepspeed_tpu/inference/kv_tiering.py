@@ -1,7 +1,7 @@
 """Tiered paged-KV store: HBM -> host RAM -> NVMe spill tiers.
 
 Concurrent serving sessions are capped by HBM because KV pages live
-only in the device pool (``inference/paged.py``): an idle or page-
+only in the device pool (``inference/paged/``): an idle or page-
 stalled session holds hot memory until the engine evicts it and repays
 its whole prefill from scratch.  This store extends the pool past HBM
 the same way ZeRO-Infinity extends optimizer state past device memory:

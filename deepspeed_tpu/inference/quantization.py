@@ -11,7 +11,7 @@ the elementwise expansion into the consuming matmul's operand read — the
 TPU equivalent of the reference's dequant-in-GEMM-prologue kernels.
 
 KV-cache quantization (fp8/int8 paged pools with per-row-per-head
-scales) lives in ``inference/paged.py`` — it is a storage-layout concern
+scales) lives in ``inference/paged/kv_pages.py`` — it is a storage-layout concern
 of the blocked KV pool.
 """
 from __future__ import annotations

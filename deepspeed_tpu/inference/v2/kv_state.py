@@ -30,7 +30,7 @@ write a page-table cell themselves:
 
 A model with layers that keep **per-slot state** and no K/V (the short
 convolutions of ``models/lfm2_moe.py``: a few rows a layer a sequence, in the
-same device tree under the leaf name ``inference/paged.py:SLOT_STATE``) has
+same device tree under the leaf name ``paged/tree.py:SLOT_STATE``) has
 that state owned here too, with the pages' life: zeroed at ``attach``, carried
 by ``spill``/``restore`` and ``export``/``adopt`` (in the request's ``spilled``
 record: it is kilobytes, not pages), dead at ``release`` with the slot.  The
@@ -51,7 +51,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.paged import (PageAllocator, map_cache,
-                                           pages_for, split_cache)
+                                           pages_for, split_cache,
+                                           state_bytes_per_seq)
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH, PrefixCacheIndex
 from deepspeed_tpu.telemetry import trace
 from deepspeed_tpu.utils.logging import logger
@@ -93,9 +94,7 @@ class KVState:
         self._cow_jit = None           # jitted fixed-shape page copy
         self._state_jits = None        # jitted per-slot state get / put / zero
         # bytes of per-slot state one sequence owns (0: the model has none)
-        self.state_bytes_per_seq = sum(
-            leaf[0].size * leaf.dtype.itemsize
-            for leaf in split_cache(cache)[1])
+        self.state_bytes_per_seq = state_bytes_per_seq(cache)
 
         # -- tiered KV spill store (HBM -> host RAM -> NVMe) --
         self.tier_cfg = tier_cfg

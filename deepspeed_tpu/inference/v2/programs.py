@@ -37,9 +37,9 @@ import numpy as np
 
 from deepspeed_tpu.inference.common import (logits_of, normalize_params,
                                             unroll_scan_params)
-from deepspeed_tpu.inference.paged import (kv_layers, kv_slots_per_layer,
-                                           pages_for, split_cache,
-                                           state_layers)
+from deepspeed_tpu.inference.paged import (describe, kv_layers,
+                                           kv_slots_per_layer, pages_for,
+                                           split_cache, state_layers)
 from deepspeed_tpu.inference.quantization import (dequantize_param_tree,
                                                   quantize_param_tree)
 from deepspeed_tpu.inference.sampling import (filter_logits_batched,
@@ -358,7 +358,7 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
                 "cu_q_lens": cu_q_lens, "num_seqs": num_seqs,
                 "new_kv_dest": new_kv_dest,
                 # static: decode rows and prompt chunks packed by
-                # ``cu_q_lens``, which is what inference/paged.py picks
+                # ``cu_q_lens``, which is what inference/paged/kv_pages.py picks
                 # the chunk kernel (and the decode kernel for the
                 # one-query rows) by
                 "fused_tick": True,
@@ -395,7 +395,7 @@ def build_fused_step(model, *, unroll: bool = False,
     ``unroll``: the stored params are scan-stacked and unroll in-jit;
     ``wq``/``native``: they are stored quantized (``native``: int8 dots,
     no dequantized copy).  A model with per-slot state beside the pool
-    (:func:`~deepspeed_tpu.inference.paged.state_layers`) takes one
+    (``paged.describe(cfg).state_layers``) takes one
     argument more, last: ``seq_slot [max_seqs]``, each packed sequence's
     slot."""
     forward = _fused_forward(model, unroll=unroll, wq=wq, native=native)
@@ -438,7 +438,7 @@ def build_fused_sample_step(model, *, sampled: bool, sample_base,
     keys are position-keyed as the decode block's are, so a draw is the
     same bits whichever program makes it."""
     forward = _fused_forward(model, unroll=unroll, wq=wq, native=native)
-    stateful = state_layers(model.config) > 0
+    stateful = describe(model.config).state_layers > 0
 
     def run(params, cache, last_tok, token_ids, tok_src, positions,
             kv_lens, page_indices, cu_q_lens, num_seqs, new_kv_dest,
@@ -506,7 +506,7 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
                     "num_seqs": jnp.asarray([S], jnp.int32),
                     "new_kv_dest": dest, "live": active,
                     # static: row s is slot s's one query, which is what
-                    # inference/paged.py picks the decode kernel by
+                    # inference/paged/kv_pages.py picks the decode kernel by
                     "one_query_a_slot": True}
             out, vars_ = model.apply(
                 {"params": params, "cache": cache}, last_tok[None],

@@ -24,7 +24,7 @@ host, as the reference's scheduler tier is.  Two neighbours hold the rest:
   sampling, the host round trip amortized to 1/K), and the speculative
   draft + verify + accept block.  Attention is the vLLM-TPU ragged paged
   Pallas kernel on TPU and an XLA-compilable reference on CPU
-  (``inference/paged.py``).
+  (``inference/paged/``).
 
 **Tensor-parallel serving**: pass a ``topology`` with a >1 ``tensor``
 axis — weights shard by AutoTP name rules, the paged KV pool shards over
@@ -116,7 +116,6 @@ ragged path requires).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 import time
 from collections import deque
@@ -131,9 +130,8 @@ from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.telemetry import (RequestLatencyTracker, device_scopes,
                                      trace)
 from deepspeed_tpu.utils.async_stage import BoundedAsyncStage, StageTimers
-from deepspeed_tpu.inference.paged import (latent_width, pages_for,
-                                           sparse_key_counts,
-                                           sparse_selection)
+from deepspeed_tpu.inference.paged import (describe, kv_dequant_path,
+                                           paged_attention_path, pages_for)
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
 from deepspeed_tpu.inference.sampling import position_keys, sample_logits
 from deepspeed_tpu.inference.v2 import programs
@@ -344,96 +342,16 @@ class RaggedInferenceEngineV2:
             "none" if o["kv_cache_dtype"] is None
             else str(o["kv_cache_dtype"]))
 
-        # K/V slots a layer keeps for a token: the passes of a looped model
-        # (models/ouro.py), whose page holds them all.  What copies whole
-        # pages (prefix copy-on-write, spill, restore) moves them together;
-        # what reads a layer's pool as ONE slot is refused by name
-        self.kv_slots = programs.kv_slots_per_layer(mcfg)
-        # passes a token and K/V rows a cached token owns, as the dispatch
-        # spans and serving_stages() say them; nothing for other models
-        self._loop_attrs = ({} if self.kv_slots == 1 else {
-            "ut_steps": self.kv_slots,
-            "kv_slots": self.kv_slots * int(mcfg.num_hidden_layers)})
-        refuse = functools.partial(self._refuse_features, o, kv_cache_dtype)
-        if self.kv_slots > 1:
-            refuse(
-                f"{type(model).__name__} runs its layers {self.kv_slots} "
-                "times a token",
-                speculation=", and the speculative block's rollback and "
-                "draft pool know one K/V slot a layer",
-                long_context=", and the partial-residency scan (inference/"
-                "v2/long_context.py) reads num_hidden_layers as the number "
-                "of K/V slots",
-                quantized="; its pool is full-width (the quantized pool's "
-                "scale rows have one slot a layer)",
-                tensor_parallel=", and the pool's sharding rule knows pages "
-                "of one slot")
-
-        # layers that keep per-slot state beside the pool and no K/V (the
-        # short convolutions of models/lfm2_moe.py, read off the config's
-        # ``layer_types``).  KVState owns that state as it owns pages;
-        # what would have to snapshot, share or roll it back and cannot
-        # yet is refused by name, not served wrongly
-        self.state_layers = programs.state_layers(mcfg)
-        self._state_attrs = ({"state_layers": self.state_layers}
-                             if self.state_layers else {})
-        if self.state_layers:
-            refuse(
-                f"{type(model).__name__} keeps per-slot state in "
-                f"{self.state_layers} of its {int(mcfg.num_hidden_layers)} "
-                "layers",
-                prefix_cache=", and a shared prefix (or its copy-on-write "
-                "page) brings keys and values only — a sequence that starts "
-                "behind one needs the state AT the prefix's end, which the "
-                "index does not keep",
-                speculation=", and the speculative block's rollback rewinds "
-                "page cursors, not state",
-                long_context=", and the partial-residency scan (inference/"
-                "v2/long_context.py) runs programs of its own that know "
-                "keys and values only",
-                quantized="; its pool is full-width (heads of 64 are stored "
-                "padded to the kernels' 128 lanes, which the quantized "
-                "pool's kernel does not know)",
-                tensor_parallel=", and the cache's sharding rule knows page "
-                "pools only")
-
-        # a model whose attention layers CHOOSE their keys by block and
-        # whose other layers keep a matrix state a head (models/
-        # minicpm_sala.py): the state is per-slot state like a short
-        # convolution's (the refusals above hold), the compressed keys a
-        # second pool leaf.  A matrix state is megabytes a sequence: no
-        # entry of a request's record in the spill tiers
-        self._sparse_sel = sparse_selection(mcfg)
-        if self._sparse_sel is not None:
-            self._sparse_sel.check(int(page_size))
-            refuse(
-                f"{type(model).__name__} keeps a matrix state a head in "
-                f"{self.state_layers} of its {int(mcfg.num_hidden_layers)} "
-                "layers and chooses the pages its other layers attend",
-                tiering=", and a spilled request's record would carry "
-                "megabytes of state a sequence beside its pages (ROADMAP "
-                "R5(d): a tier for matrix state)")
-
-        # latent attention (models/glm4_moe_lite.py): a layer's pool holds
-        # ONE row a token, ``[c | k_r]``, and no heads.  It is a pool leaf
-        # like any other to KVState's verbs (attach, ensure, release, copy-
-        # on-write, spill, restore, export, adopt move ``leaf[page]``), so
-        # prefix sharing and tiering serve it; what reads a pool as heads
-        # of keys and values is refused by name
-        self.latent_width = latent_width(mcfg)
-        if self.latent_width:
-            refuse(
-                f"{type(model).__name__} caches one latent row of "
-                f"{self.latent_width} values a token a layer",
-                speculation=", and the speculative verify has no latent "
-                "attention kernel (nor a draft that shares the latent pool)",
-                long_context=", and the partial-residency scan (inference/"
-                "v2/long_context.py) stages and folds per-head keys and "
-                "values",
-                quantized="; the quantized pool's scale rows and kernel are "
-                "per head",
-                tensor_parallel=", which has no head dim for the pool's "
-                "sharding rule to split")
+        # what the model's layers keep for a token (K/V pages, slots a pass,
+        # per-slot state, a row of another shape): ONE description, built by
+        # the files that own each kind (inference/paged/).  KVState moves
+        # ``leaf[page]`` and ``leaf[slot]`` whatever a leaf is; each feature
+        # that reads a leaf as something it is not is refused by name
+        self.cache_kind = describe(mcfg)
+        self.kv_slots = self.cache_kind.kv_slots
+        self.state_layers = self.cache_kind.state_layers
+        self._refuse_features(
+            o, kv_cache_dtype, self.cache_kind.refusals(type(model).__name__))
 
         self.page_size = int(page_size)
         self.pages_per_seq = pages_for(max_seq_len, self.page_size)
@@ -562,17 +480,11 @@ class RaggedInferenceEngineV2:
             pfx_cfg=PrefixCacheConfig.coerce(o["prefix_cache"]),
             host_stats=self.host_stats,
             request_latency=self.request_latency, draft_cache=draft_cache)
-        # the most pages any dispatch found in use, and (latent attention)
-        # what a cached token costs the pool as its leaves are allocated:
-        # on every ``engine.dispatch`` span and in ``serving_stages()``
+        # the most pages any dispatch found in use, and what the cache kind
+        # says of its leaves as they are allocated: on every
+        # ``engine.dispatch`` span and in ``serving_stages()``
         self._pages_peak = 0
-        if self.state_layers:
-            self._state_attrs["state_bytes"] = int(
-                self.kv.state_bytes_per_seq)
-        self._latent_attrs = ({} if not self.latent_width else {
-            "latent_bytes_per_token": sum(
-                int(np.prod(leaf.shape[2:])) * leaf.dtype.itemsize
-                for leaf in jax.tree_util.tree_leaves(self.kv.cache))})
+        self._kind_attrs = self.cache_kind.span_attrs(self.kv.cache)
 
         self._uid = itertools.count()
         self.waiting: Deque[Request] = deque()
@@ -1080,36 +992,33 @@ class RaggedInferenceEngineV2:
         return reg
 
     def _refuse_features(self, o: Dict[str, Any], kv_cache_dtype: str,
-                         model_is: str, *, prefix_cache: str = "",
-                         speculation: str = "", long_context: str = "",
-                         quantized: str = "", tensor_parallel: str = "",
-                         tiering: str = "") -> None:
+                         refusals) -> None:
         """Refuse by name each asked-for feature that cannot serve a model
-        whose cache is not plain per-head K/V pages: ``model_is`` says what
-        the model keeps, each keyword why that feature cannot carry it
-        (empty: it can).  ``o`` holds the resolved options."""
+        whose cache is not plain per-head K/V pages.  ``refusals``: the
+        description's ``(model_is, {feature: why})`` pairs (what the model
+        keeps; why that feature cannot carry it); ``o``: the resolved
+        options."""
         from deepspeed_tpu.inference.config import (KVTieringConfig,
                                                     PrefixCacheConfig,
                                                     SpeculationConfig)
 
         spec = SpeculationConfig.coerce(o["speculation"])
-        asked = (
-            (prefix_cache and PrefixCacheConfig.coerce(
-                o["prefix_cache"]).enabled, "prefix_cache", prefix_cache),
-            (speculation and spec.mode != "off",
-             f"speculation.mode={spec.mode!r}", speculation),
-            (long_context and KVTieringConfig.coerce(
-                o["kv_tiering"]).long_context, "kv_tiering.long_context",
-             long_context),
-            (tiering and KVTieringConfig.coerce(o["kv_tiering"]).enabled,
-             "kv_tiering", tiering),
-            (quantized and kv_cache_dtype != "none",
-             f"kv_cache_dtype={kv_cache_dtype!r}", quantized),
-            (tensor_parallel and self.tp > 1, "tensor-parallel serving",
-             tensor_parallel))
-        for wanted, feature, why in asked:
-            if wanted:
-                raise NotImplementedError(f"{feature}: {model_is}{why}")
+        tier = KVTieringConfig.coerce(o["kv_tiering"])
+        asked = {       # feature: (asked for, as the message names it)
+            "prefix_cache": (PrefixCacheConfig.coerce(
+                o["prefix_cache"]).enabled, "prefix_cache"),
+            "speculation": (spec.mode != "off",
+                            f"speculation.mode={spec.mode!r}"),
+            "long_context": (tier.long_context, "kv_tiering.long_context"),
+            "tiering": (tier.enabled, "kv_tiering"),
+            "quantized": (kv_cache_dtype != "none",
+                          f"kv_cache_dtype={kv_cache_dtype!r}"),
+            "tensor_parallel": (self.tp > 1, "tensor-parallel serving")}
+        for model_is, refuses in refusals:
+            for feature, (wanted, named) in asked.items():
+                if wanted and feature in refuses:
+                    raise NotImplementedError(
+                        f"{named}: {model_is}{refuses[feature]}")
 
     def serving_stages(self) -> Dict[str, Any]:
         """Per-dispatch host-path breakdown + ``host_bound_fraction``
@@ -1128,9 +1037,6 @@ class RaggedInferenceEngineV2:
                       hit_tokens=st.prefix_hit_tokens,
                       cow_copies=st.prefix_cow_copies)
             out["prefix_cache"] = pc
-        from deepspeed_tpu.inference.paged import (kv_dequant_path,
-                                                   paged_attention_path)
-
         head_dim = int(getattr(self.cfg, "head_dim", 0))
         if self.kv_cache_dtype != "none":
             from deepspeed_tpu.inference.common import kv_quant_block
@@ -1140,80 +1046,21 @@ class RaggedInferenceEngineV2:
                 self.kv.cache, self.kv_cache_dtype, route, self.num_pages)
         else:
             route = paged_attention_path(head_dim)
-        # the attention read route this backend gives this pool: a Pallas
-        # kernel, or the XLA twin it stands in for (ops/routes.py)
-        from deepspeed_tpu.ops import paged_decode, paged_prefill, routes
-
-        # and what each fused tick and each decode program traced so far
-        # took for its own
-        out["kernels"] = {"paged_attention": route,
-                          **routes.taken(prefix=paged_prefill.KERNEL),
-                          **routes.taken(prefix=paged_decode.KERNEL)}
+        # the attention read route this backend gives this pool (a Pallas
+        # kernel, or the XLA twin it stands in for: ops/routes.py) and what
+        # the programs traced so far took, as the cache kind names them
+        out["kernels"] = self.cache_kind.kernels(route)
         st = self.host_stats
-        if self.latent_width:
-            # latent attention: its own two routes and no other, and what
-            # the pool costs a token as its leaves are allocated
-            from deepspeed_tpu.ops import latent_attention
-
-            out["kernels"] = routes.taken(prefix=latent_attention.KERNEL)
-            row_bytes = self._latent_attrs["latent_bytes_per_token"]
-            layers = int(self.cfg.num_hidden_layers)
-            out["latent"] = {
-                "width": self.latent_width, "layers": layers,
-                "bytes_per_token": row_bytes,
-                "bytes_per_token_layer": row_bytes // layers,
-                "pages_peak": self._pages_peak}
         if st.moe_rows:
             from deepspeed_tpu.moe.sharded_moe import GROUPED_MATMUL
+            from deepspeed_tpu.ops import routes
             # its two keys, as the trace of this process's programs left them
             out["kernels"].update(routes.taken(prefix=GROUPED_MATMUL))
             out["moe"] = {"rows": st.moe_rows,
                           "experts_hit": st.moe_experts_hit,
                           "load_max": st.moe_load_max}
-        if self.kv_slots > 1:
-            # a looped model: passes a token, K/V slots a cached token,
-            # and the exit gate's sums as far as fetches have brought them
-            out["loop"] = {**self._loop_attrs, "rows": st.loop_rows,
-                           "exit_cdf": list(st.loop_exit_cdf)}
-        if self._sparse_sel is not None:
-            # linear attention beside block selection: their own four
-            # routes and no other; what the state and the compressed keys
-            # cost, and what the selection spared
-            from deepspeed_tpu.inference.paged import CKEY_PAGES
-            from deepspeed_tpu.ops import (block_select_attention,
-                                           linear_attention)
-
-            out["kernels"] = {
-                **routes.taken(prefix=linear_attention.KERNEL),
-                **routes.taken(prefix=block_select_attention.KERNEL)}
-            state = {"state_layers": self.state_layers,
-                     "state_bytes": int(self.kv.state_bytes_per_seq)}
-            out["linear_state"] = {**state, "state_rows": st.state_rows}
-            flat = jax.tree_util.tree_flatten_with_path(self.kv.cache)[0]
-            out["sparse"] = {
-                "kv_layers": programs.kv_layers(self.cfg),
-                "compressed_bytes": sum(
-                    int(leaf.nbytes) for path, leaf in flat
-                    if any(getattr(k, "key", None) == CKEY_PAGES
-                           for k in path)),
-                "sparse_rows": st.sparse_rows,
-                "sparse_key_tokens": st.sparse_key_tokens,
-                "sparse_visible_tokens": st.sparse_visible_tokens,
-                **self._sparse_sel._asdict()}
-        elif self.state_layers:
-            # a model with layers of two kinds: which keep K/V, which keep
-            # per-slot state, what a sequence's state costs, how its
-            # router scores and which routes its attention takes
-            from deepspeed_tpu.inference.paged import kv_lane_dim
-            out["hybrid"] = {
-                "kv_layers": programs.kv_layers(self.cfg),
-                "state_layers": self.state_layers,
-                "state_bytes_per_seq": self.kv.state_bytes_per_seq,
-                "kv_head_dim_stored": kv_lane_dim(head_dim),
-                "routing": ("sigmoid+bias" if getattr(
-                    self.cfg, "use_expert_bias", False) else "sigmoid"),
-                "bias_swaps": st.moe_bias_swaps,
-                "attention_routes": dict(out["kernels"])}
+        out.update(self.cache_kind.stages(
+            st, self.kv.cache, self.cfg, out["kernels"], self._pages_peak))
         # the head of a fused tick multiplies the rows it samples, gathered
         # from the hidden states: ``max_seqs`` of the tick's ``T``
         out["fused_head"] = {"form": "sampled-rows", "rows": self.max_seqs,
@@ -1643,39 +1490,22 @@ class RaggedInferenceEngineV2:
         st.decode_blocks += 1
         st.decode_blocks_ahead += ahead
         st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
-        state = {"state_rows": rows} if self.state_layers else {}
-        st.state_rows += rows if self.state_layers else 0
-        if self._sparse_sel is not None:
-            # tick k of the block asks at position length - 1 + k
-            state.update(self._count_sparse(np.add.outer(
-                [r.length - 1 for r in reqs], np.arange(K))))
+        # tick k of the block asks at position length - 1 + k
+        asks = self.cache_kind.counts(st, np.add.outer(
+            [r.length - 1 for r in reqs], np.arange(K)))
         return st.stage("dispatch", program="decode_block", ahead=ahead,
-                        rows=rows, ticks=K, key_tokens=keys, **state,
+                        rows=rows, ticks=K, key_tokens=keys, **asks,
                         **self._pool_counts())
-
-    def _count_sparse(self, positions, one_row=None) -> Dict[str, int]:
-        """What a dispatch's queries at ``positions`` ask of a block-
-        selecting layer (``paged.sparse_key_counts``: for its span), the
-        first three added to the counters.  ``one_row``: which of them are
-        their sequence's only row (all, in a decode block)."""
-        counts = sparse_key_counts(positions, self._sparse_sel, one_row)
-        st = self.host_stats
-        st.sparse_rows += counts["sparse_rows"]
-        st.sparse_key_tokens += counts["sparse_key_tokens"]
-        st.sparse_visible_tokens += counts["sparse_visible_tokens"]
-        return counts
 
     def _pool_counts(self) -> Dict[str, int]:
         """What every ``engine.dispatch`` span says of the pool as the
         allocator has it at dispatch (``pages_in_use`` of ``pages_total``
-        usable pages), and of a looped model's passes: ``ut_steps`` a
-        token and ``kv_slots`` (passes x layers) a cached token."""
+        usable pages), and what the cache kind says of its leaves."""
         total = self.num_pages - 1
         in_use = total - self.allocator.free_pages
         self._pages_peak = max(self._pages_peak, in_use)
-        return {"pages_in_use": in_use,
-                "pages_total": total, **self._loop_attrs,
-                **self._state_attrs, **self._latent_attrs}
+        return {"pages_in_use": in_use, "pages_total": total,
+                **self._kind_attrs}
 
     # -- the queue of dispatched work and the decode blocks' carry --------
 
@@ -2484,14 +2314,8 @@ class RaggedInferenceEngineV2:
                   new_kv_dest, sample_rows, sample_slot)
         if self.state_layers:
             arrays += (seq_slot,)
-            counts["state_rows"] = j
-            self.host_stats.state_rows += j
-        if self._sparse_sel is not None:
-            q_lens = np.diff(cu_q_lens[:j + 1])
-            counts.update(self._count_sparse(
-                positions[:t], np.repeat(q_lens == 1, q_lens)))
-            # the sequences that take the two one-token kernels
-            counts["one_row_seqs"] = int((q_lens == 1).sum())
+        counts.update(self.cache_kind.counts(
+            self.host_stats, positions[:t], np.diff(cu_q_lens[:j + 1])))
         sampled = any(r.do_sample for r, _ in sampling)
         if sampled:
             do_sample = np.zeros((S,), bool)

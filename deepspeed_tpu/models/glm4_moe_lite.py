@@ -22,7 +22,7 @@ With ``N`` RMSNorm and ``h`` the residual stream, layer ``l`` is::
   ``kv_lora_rank + qk_rope_head_dim`` wide, meet the cached row ``[c |
   k_r]``, whose first ``kv_lora_rank`` columns are also the value.  **The
   cache holds ``c`` AFTER its norm and ``k_r`` AFTER its rotation**: one row
-  a token a layer, nothing per head (``inference/paged.py:
+  a token a layer, nothing per head (``inference/paged/latent.py:
   latent_update_and_attend``; the pool is ``[num_pages, page_size, W]``).
 - **FF, the first** ``first_k_dense_replace`` **layers**: ``models/llama.py``'s
   SwiGLU MLP, ``intermediate_size`` wide.

@@ -15,11 +15,11 @@ carry a dense SwiGLU MLP, every later one a routed expert layer.  With
   zero before the sequence starts); ``y_t = W_out (C_t * c_t)``.  No
   activation.  What a served sequence carries from tick to tick is
   ``(u_{t-2}, u_{t-1})``: per-slot state beside the paged K/V pool
-  (``inference/paged.py:ragged_short_conv``), owned by
+  (``inference/paged/conv_state.py:ragged_short_conv``), owned by
   ``inference/v2/kv_state.py`` as the pages are.
 - **Attention**: ``models/llama.py``'s, with an RMSNorm over EACH HEAD of
   q and k before RoPE (``qk_norm_per_head``); heads of 64 here, which the
-  paged pool stores zero-padded to 128 (``inference/paged.py``).
+  paged pool stores zero-padded to 128 (``inference/paged/kv_pages.py``).
 - **Expert layer**: ``moe/layer.py``'s dropless form with the sigmoid
   router: ``s = sigmoid(W_g x)`` in float32, the chosen set the top k of
   ``s + b`` with ``b`` a per-expert selection bias (``use_expert_bias``),

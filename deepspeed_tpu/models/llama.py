@@ -237,7 +237,7 @@ class LlamaAttention(nn.Module):
         if cfg.paged_decode:
             # blocked-KV continuous batching: one fused token batch over
             # the paged cache (reference ragged_ops kernels + blocked
-            # allocator) — see inference/paged.py
+            # allocator) — see inference/paged/kv_pages.py
             from deepspeed_tpu.inference.paged import paged_update_and_attend
 
             assert ragged_meta is not None, (

@@ -20,7 +20,7 @@ residual stream and ``a = scale_depth / sqrt(depth_scale_layers)``::
   ``l_h = exp(-2^(-8 (h + 1) / heads))``; ``y = W_o(N_E(o) * sigmoid(W_g
   u))`` (output norm over the concatenated heads, output gate).  What a
   served sequence carries is ``S``: per-slot state beside the pages
-  (``inference/paged.py:ragged_linear_attention``).
+  (``inference/paged/linear_state.py:ragged_linear_attention``).
 - **minicpm4**, ``num_attention_heads`` query heads over
   ``num_key_value_heads`` KV heads, no RoPE, RMSNorm over each head of
   ``q`` and ``k``, scale ``1 / sqrt(d)``, ``y = W_o(att * sigmoid(W_g
@@ -35,7 +35,7 @@ residual stream and ``a = scale_depth / sqrt(depth_scale_layers)``::
   reproduce; by position the logits are the same however a prompt is
   chunked).  Served, its keys and values are a pool of two KV heads and
   its compressed keys a second pool leaf
-  (``inference/paged.py:sparse_update_and_attend``).
+  (``inference/paged/block_select.py:sparse_update_and_attend``).
 
 Only the serving form and whole sequences from their first token are here;
 the dense decode cache is refused (it has nowhere to keep either state).

@@ -14,7 +14,7 @@ the loop changes, from the published ``config.json`` and HF
   to what the same pass of the same layer wrote for the earlier positions,
   so a cached token owns ``total_ut_steps x num_hidden_layers`` K/V rows
   (HF ``UniversalTransformerCache``).  The paged pool gives every layer a
-  page of ``[total_ut_steps, page, 2 * Hkv, D]`` (``inference/paged.py``:
+  page of ``[total_ut_steps, page, 2 * Hkv, D]`` (``inference/paged/kv_pages.py``:
   the ``kv_slot`` entry of ``ragged_meta``);
 - **an exit gate**: one ``Linear(hidden, 1)`` on every pass's output; its
   sigmoids give a distribution over the passes a token could stop at.  At
@@ -188,7 +188,7 @@ def _one_pass(mdl: "OuroModel", x, u, positions, deterministic,
     cfg = mdl.config
     if ragged_meta is not None and cfg.total_ut_steps > 1:
         # which of a page's K/V slots this pass reads and writes
-        # (``inference/paged.py``): its own
+        # (``inference/paged/kv_pages.py``): its own
         ragged_meta = {**ragged_meta, "kv_slot": u}
     block_cls = _maybe_remat(OuroBlock, cfg)
     for i in range(cfg.num_hidden_layers):

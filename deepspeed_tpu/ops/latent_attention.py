@@ -4,7 +4,7 @@ multi-head latent attention over a pool of one row a token.
 A latent-attention layer (``models/glm4_moe_lite.py``) caches ``[c | k_r]``
 for a token: the normalised compressed vector (``rank`` wide) beside the
 one rotated key its heads share, zero-padded to whole lane tiles (``W``:
-640 for the published 512 + 64; ``inference/paged.py:latent_width``).  With
+640 for the published 512 + 64; ``paged/latent.py:latent_width``).  With
 the up-projections absorbed into the queries, every head's query is as
 wide as the row (its padding columns zero too), the row is the key of
 EVERY head, and its first ``rank`` columns are the value.  So the pool is
@@ -66,7 +66,7 @@ NaN would survive a zero weight, and as a key would survive the bias).
 
 Mosaic needs a bf16 pool, ``rank`` and ``W`` whole lane tiles and pages of
 whole sublane tiles (:func:`tiling` answers None otherwise and the caller takes
-its XLA twin, ``inference/paged.py:ref_latent_paged_attention``).  Under
+its XLA twin, ``inference/paged/latent.py:ref_latent_paged_attention``).  Under
 ``interpret=True`` the kernels run through the Pallas interpreter
 (``tests/unit/ops/test_latent_attention.py``); ``tests/unit/ops/
 test_tpu_compile.py`` hands them to the chip's compiler at the published

@@ -5,7 +5,7 @@ head, a ``d x d`` float32 state in place of keys and values::
 
     S_t = l S_(t-1) + k_t^T v_t        o_t = q_t S_t        l = exp(log_decay)
 
-Two forms serve it (``inference/paged.py:ragged_linear_attention``):
+Two forms serve it (``paged/linear_state.py:ragged_linear_attention``):
 
 - :func:`chunk_scan` (kernel ``linear_chunk_scan``): the prompt chunks of a
   fused tick, ``C`` = :data:`CHUNK` tokens at a time.  With ``D_ij =

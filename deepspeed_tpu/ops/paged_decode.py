@@ -20,7 +20,7 @@ a decode tick needs:
   while this one is multiplied, across sequences as within one;
 - **pages multiplied as they lie in the pool**.  A page is rows of
   (token, KV head), each a packed ``uint32`` of that head's K (low half:
-  even combined head) and V (high half; ``inference/paged.py``).  Picking
+  even combined head) and V (high half; ``inference/paged/kv_pages.py``).  Picking
   one head's rows out is a sublane-strided load, which the v5e issues one
   a cycle and in pieces (45 loads a 128 keys of a head at 8 KV heads, 78
   at 16: the bound of a body written a head at a time, PERF.md PR 31).

@@ -1,7 +1,7 @@
 """Quantized-pages ragged paged attention (Pallas TPU kernel).
 
 The serving KV pool persists int8 / fp8-e4m3 pages with per-(row, head)
-fp32 scales (``inference/paged.py``).  The full-width Pallas kernel the
+fp32 scales (``inference/paged/kv_pages.py``).  The full-width Pallas kernel the
 paged path was written against (``jax.experimental.pallas.ops.tpu.
 ragged_paged_attention``) reads float pages, so a quantized pool used to
 be dequantized into a transient ``[P, page, 2*Hkv, D]`` float operand

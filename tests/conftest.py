@@ -6,6 +6,7 @@ exercised single-node with fake devices — here via
 processes, since one JAX controller drives all 8 virtual devices.
 """
 import os
+import tempfile
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -27,6 +28,19 @@ if not os.environ.get("DSTPU_TEST_ON_TPU"):
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    " --xla_backend_optimization_level=0")
     jax.config.update("jax_num_cpu_devices", 8)
+    # One persistent compile cache for the run, shared by its workers: the
+    # same tiny engine's programs are built by dozens of tests, each behind
+    # a jit of its own, and XLA compiled every one of them anew (a third of
+    # the CPU seconds of tests/unit/test_serving_pipeline.py).  The key is
+    # the program's text and the compiler's options, so a hit is what the
+    # compile would have given; ``count_compiles`` counts it as a compile.
+    # Under the run's TMPDIR, never in the checkout; a user's own setting of
+    # JAX_COMPILATION_CACHE_DIR wins.
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            tempfile.gettempdir(), "dstpu-tests-jax-cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 @pytest.fixture(scope="session")

@@ -19,6 +19,7 @@ tick, at a tiny size in bfloat16:
 And the engine says what ran: ``head_rows`` on a fused tick's
 ``engine.dispatch`` span, ``fused_head`` in ``serving_stages()``.
 """
+import functools
 import importlib
 
 import jax
@@ -106,6 +107,17 @@ class Tick:
     def cache(self):
         return programs.init_cache(self.model, max_seqs=S, width=T)
 
+    @functools.cached_property
+    def fused(self):
+        """The tick, lowered once for the tests that read or run it."""
+        return programs.build_fused_step(self.model).lower(
+            self.params, self.cache(), *self.args)
+
+    @functools.cached_property
+    def every_row(self):
+        """:meth:`all_rows`, lowered once as well."""
+        return self.all_rows().lower(self.params, self.cache(), *self.args)
+
     def all_rows(self):
         """The program the tick was before: the model called WITHOUT
         ``sample_rows``, every row's logits ``[T, V]``, the take after."""
@@ -139,9 +151,10 @@ def _holds(lowered, rows):
 
 
 def test_the_sampled_rows_logits_are_the_all_row_programs(tick):
-    sel, _, _, cdf = programs.build_fused_step(tick.model)(
+    sel, _, _, cdf = tick.fused.compile()(
         tick.params, tick.cache(), *tick.args)
-    want, want_cdf = tick.all_rows()(tick.params, tick.cache(), *tick.args)
+    want, want_cdf = tick.every_row.compile()(
+        tick.params, tick.cache(), *tick.args)
     assert sel.shape == (S, V) and sel.dtype == jnp.bfloat16
     got, want = (np.asarray(a, np.float32)[:tick.n] for a in (sel, want))
     assert np.abs(want).max() > 0.05        # logits, not zeros
@@ -156,13 +169,10 @@ def test_the_sampled_rows_logits_are_the_all_row_programs(tick):
 
 
 def test_the_lowered_tick_holds_no_array_of_all_rows_logits(tick):
-    fused = programs.build_fused_step(tick.model).lower(
-        tick.params, tick.cache(), *tick.args)
-    assert not _holds(fused, T)
-    assert _holds(fused, S)
+    assert not _holds(tick.fused, T)
+    assert _holds(tick.fused, S)
     # the search finds the array where it exists
-    assert _holds(tick.all_rows().lower(
-        tick.params, tick.cache(), *tick.args), T)
+    assert _holds(tick.every_row, T)
 
 
 def test_a_model_that_ignores_the_rows_is_refused_by_name(tick, monkeypatch):

@@ -136,7 +136,7 @@ class TestFusedStep:
             f"expected 1 compiled variant, got {fn._cache_size()}")
 
 
-# -- which attention a program takes (inference/paged.py:attend) -------------
+# -- which attention a program takes (inference/paged/kv_pages.py:attend) ----
 
 # the narrowest model the decode kernel's layout holds for: head_dim 128,
 # 8 KV heads, a bf16 pool
@@ -175,8 +175,9 @@ class TestDecodeKernelSelection:
         from deepspeed_tpu.inference import paged
         from deepspeed_tpu.ops import paged_decode, routes
 
-        monkeypatch.setattr(paged, "paged_decode_attention", functools.partial(
-            paged_decode.paged_decode_attention, interpret=True))
+        monkeypatch.setattr(
+            paged.kv_pages, "paged_decode_attention", functools.partial(
+                paged_decode.paged_decode_attention, interpret=True))
         monkeypatch.setattr(routes, "_taken", {})
         return lambda: routes.taken().get(paged_decode.KERNEL)
 
@@ -199,7 +200,7 @@ class TestDecodeKernelSelection:
         from deepspeed_tpu.ops import paged_decode
 
         # the same engine on the route a CPU gives it
-        monkeypatch.setattr(paged, "paged_decode_attention",
+        monkeypatch.setattr(paged.kv_pages, "paged_decode_attention",
                             paged_decode.paged_decode_attention)
         twin = _wide_engine(wide_params, decode_block_size=4)
         want = twin.generate_all(self._prompts(), max_new_tokens=9)

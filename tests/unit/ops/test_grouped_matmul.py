@@ -108,10 +108,14 @@ def test_group_metadata_is_upstreams(skew):
         live = rows if rng.random() < 0.3 else int(rng.integers(0, rows + 1))
         sizes = jnp.asarray(rng.multinomial(
             live, rng.dirichlet(np.full(E, skew))).astype(np.int32))
-        (off, gid, mid), visits = gm.group_metadata(sizes, rows, tm)
-        (off_, gid_, mid_), visits_ = make_group_metadata(
-            group_sizes=sizes, m=rows, tm=tm, start_group=jnp.int32(0),
-            num_nonzero_groups=E, visit_empty_groups=False)
+        # each under one jit: a draw's shapes are its own, and op by op
+        # every one of them would compile some hundred small programs
+        (off, gid, mid), visits = jax.jit(
+            gm.group_metadata, static_argnums=(1, 2))(sizes, rows, tm)
+        (off_, gid_, mid_), visits_ = jax.jit(functools.partial(
+            make_group_metadata, m=rows, tm=tm, num_nonzero_groups=E,
+            visit_empty_groups=False))(
+                group_sizes=sizes, start_group=jnp.int32(0))
         v = int(visits)
         assert v == int(visits_) and gid.shape == gid_.shape
         np.testing.assert_array_equal(off, off_)

@@ -1,5 +1,5 @@
 """``ops/latent_attention.py``'s two kernels under the Pallas interpreter
-against their XLA twin (``inference/paged.py:ref_latent_paged_attention``)
+against their XLA twin (``inference/paged/latent.py:ref_latent_paged_attention``)
 and against the EXPANDED form computed densely: per-head keys and values
 made from the cached rows by the up-projection, which is what the absorbed
 form must equal.
@@ -135,6 +135,10 @@ LONG_TICKS = {
     "tick-of-300-rows-in-three-tiles": [(170, 20), (1, 9), (129, 700)],
     "few-rows-at-a-tiles-end": [(120, 0), (100, 520)],
 }
+# rows a tick is padded to, where its edge needs no width of its own: the
+# eight rows at a tile's end lie in the first of three tiles as they lie in
+# the first of two, and every distinct width is a program to compile
+PADDED_TO = {"few-rows-at-a-tiles-end": 300}
 CASES = ([(tick, rt, kb) for tick in TICKS for rt, kb in ((16, 1), (32, 4))]
          + [(tick, RULE_RT, RULE_KB) for tick in LONG_TICKS])
 ALL_TICKS = {**TICKS, **LONG_TICKS}
@@ -183,7 +187,7 @@ def test_chunk_kernel_matches_the_dense_sum(tick, rt, kb, stale):
     slots, pp = (LONG_S, LONG_PP) if long else (S, PP)
     pages, table = _pool(jnp.float32, seed=2, slots=slots, pp=pp)
     cu, kv_lens, n_seqs = _packed(ALL_TICKS[tick], slots)
-    T = int(cu[-1]) + 3                       # three rows of padding
+    T = max(int(cu[-1]), PADDED_TO.get(tick, 0)) + 3    # rows of padding
     Tp = max(-(-T // 16) * 16, rt)
     q = jnp.asarray(np.random.default_rng(5).normal(size=(H, Tp, W)),
                     jnp.float32)
@@ -212,7 +216,7 @@ def test_a_fused_tick_in_bf16_matches_the_twin(tick):
     bf16's own step."""
     pages, table = _pool(jnp.bfloat16, seed=7, slots=LONG_S, pp=LONG_PP)
     cu, kv_lens, n_seqs = _packed(LONG_TICKS[tick], LONG_S)
-    T = int(cu[-1]) + 5
+    T = max(int(cu[-1]), PADDED_TO.get(tick, 0)) + 5
     Tp = la.tick_rows(T)
     q = jnp.asarray(np.random.default_rng(8).normal(size=(H, Tp, W)) * 0.3,
                     jnp.bfloat16)
@@ -245,17 +249,16 @@ def test_a_fused_tick_in_bf16_takes_both_kernels_at_the_rules_tiling():
     chunk rows from the chunk kernel, one-query rows from the decode kernel,
     both recorded; bf16 against the float32 twin."""
     routes._taken.pop(la.KERNEL_PREFILL, None)
-    pages, table = _pool(jnp.bfloat16, seed=4)
+    # the long pool's shapes: the program the 144-row ticks above compiled
+    pages, table = _pool(jnp.bfloat16, seed=4, slots=LONG_S, pp=LONG_PP)
     T = 144
     Tp = la.tick_rows(T)
-    rows = [(1, 90), (130, 20), (1, 5), (6, 0)]
-    cu = np.concatenate([[0], np.cumsum([n for n, _ in rows])]
-                        ).astype(np.int32)
-    kv_lens = np.asarray([p0 + n for n, p0 in rows], np.int32)
+    cu, kv_lens, n_seqs = _packed([(1, 90), (130, 20), (1, 5), (6, 0)],
+                                  LONG_S)
     q = jnp.asarray(np.random.default_rng(6).normal(size=(H, Tp, W)) * 0.3,
                     jnp.bfloat16)
     ragged = (jnp.asarray(kv_lens), jnp.asarray(table), jnp.asarray(cu),
-              jnp.asarray([S], jnp.int32))
+              jnp.asarray([n_seqs], jnp.int32))
     twin = lambda q, dt: ref_latent_paged_attention(   # noqa: E731
         q.astype(dt), pages.astype(dt), *ragged, rank=RANK, sm_scale=SCALE)
     out, ones, at = la.fused_tick_attention(
@@ -264,7 +267,7 @@ def test_a_fused_tick_in_bf16_takes_both_kernels_at_the_rules_tiling():
     assert routes.taken()[la.KERNEL_PREFILL] == (
         f"pallas-latent-chunk-{H * 128}x512-interpret")
     assert Tp == 144 and out.shape == (H, Tp, RANK)
-    assert list(np.asarray(at)) == [0, Tp, 131, Tp]
+    assert list(np.asarray(at)) == [0, Tp, 131, Tp] + [Tp] * (LONG_S - 4)
     want = np.asarray(twin(q.transpose(1, 0, 2), jnp.float32))
     got = np.array(out.astype(jnp.float32)).transpose(1, 0, 2)
     got[[0, 131]] = np.asarray(ones.astype(jnp.float32))[[0, 2]]

@@ -126,7 +126,7 @@ RPA_HEADS = [pytest.param((32, 8), id="mistral-8kv"),
 
 @pytest.mark.parametrize("heads", RPA_HEADS)
 def test_upstream_ragged_paged_kernel_bf16_pages(chip_compile, heads):
-    """With the block sizes ``inference/paged.py`` hands it: its own
+    """With the block sizes ``inference/paged/kv_pages.py`` hands it: its own
     tuned-table lookup asks the ATTACHED device for its generation, and
     what it answers for this shape does not fit VMEM.  The KV block
     follows from the KV heads and the page size."""

@@ -149,7 +149,7 @@ def test_a_decode_block_on_another_route_fails_the_serve_phase(
         return fallback()
 
     monkeypatch.setattr(routes, "_taken", {})
-    monkeypatch.setattr(paged, "paged_decode_attention", elsewhere)
+    monkeypatch.setattr(paged.kv_pages, "paged_decode_attention", elsewhere)
     monkeypatch.setattr(jax, "devices", lambda *a: jax.local_devices()[:1])
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="'bf16_decode': 'pallas-ragged'"):

@@ -11,6 +11,9 @@ from deepspeed_tpu.module_inject import (convert_hf_state_dict,
 
 transformers = pytest.importorskip("transformers")
 torch = pytest.importorskip("torch")
+# models of 32 columns: one thread does them, and a pool a core wide beside
+# five other workers' does not (227 s in a run of six workers, 12 s alone)
+torch.set_num_threads(1)
 
 
 def _gpt2_pair():
