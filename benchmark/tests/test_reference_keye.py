@@ -1,0 +1,95 @@
+"""The Keye-VL-2.0 reference against the program at a tiny size (through
+``apply``: the program's own tier-1 tests drive the serving form), every
+mutation and a lower precision outside the tolerance, the rule by query (a
+prefix's logits do not depend on what follows it), the sizes of what it
+takes, and that it imports nothing of the program."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import keye_vl2 as ref
+
+TOL = 2e-4
+SA = dict(indexer_num_heads=2, indexer_head_dim=16, indexer_num_kv_heads=1,
+          topk=24, q_chunk_size=512, kv_chunk_size=512)
+CFG = dict(vocab_size=96, hidden_size=64, num_hidden_layers=2,
+           num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+           rms_norm_eps=1e-6, rope_theta=1e7,
+           rope_scaling=dict(mrope_section=[4, 6, 6]), sa_config=SA,
+           num_experts_per_tok=2, norm_topk_prob=True)
+
+
+@pytest.fixture(scope="module")
+def served():
+    from deepspeed_tpu.models import keye_vl2 as keye
+
+    model = keye.KeyeVL2ForCausalLM(keye.get_config(
+        "tinykeye", dtype=jnp.float32, param_dtype=jnp.float32, remat=False,
+        use_flash_attention=False, drop_tokens=False))
+    params = jax.jit(model.init)(jax.random.PRNGKey(5),
+                                 np.zeros((1, 8), np.int32))["params"]
+    return model, params
+
+
+TOKENS = np.random.default_rng(0).integers(1, 96, size=130).tolist()
+
+
+def test_reference_matches_the_program(served):
+    model, params = served
+    got = np.asarray(jax.jit(model.apply)(
+        {"params": params}, np.asarray([TOKENS], np.int32))[0][0])
+    want = np.asarray(ref.logits(params, CFG, TOKENS))
+    assert np.abs(got - want).max() < TOL
+    assert np.abs(np.asarray(ref.logits(params, CFG, TOKENS, last=3))
+                  - want[-3:]).max() < 1e-5
+    losses = ref.token_losses(params, CFG, TOKENS)
+    assert losses.shape == (129,) and ref.loss(params, CFG, TOKENS) == \
+        pytest.approx(float(losses.mean()))
+    greedy = [int(np.argmax(want[-1]))]
+    assert ref.generated_gaps(params, CFG, TOKENS, greedy)[0] == 0.0
+
+
+def test_what_a_query_and_a_token_take(served):
+    """A query takes ``min(t + 1, topk)`` keys, itself never excluded by
+    the mask; a token takes ``num_experts_per_tok`` distinct experts whose
+    weights sum to 1."""
+    _, params = served
+    _, taken, routed = ref.forward(params, CFG, TOKENS, keep=True)
+    assert len(taken) == len(routed) == 2
+    for layer in taken:
+        layer = np.asarray(layer)
+        assert (layer.sum(-1) == np.minimum(np.arange(130) + 1, 24)).all()
+        assert not np.triu(layer, 1).any()
+    ids, weights = (np.asarray(a) for a in routed[0])
+    assert ids.shape == (130, 2) and (ids[:, 0] != ids[:, 1]).all()
+    np.testing.assert_allclose(weights.sum(-1), 1.0, atol=1e-6)
+
+
+def test_the_rule_is_by_query_not_by_the_length_of_the_call(served):
+    """A prefix's logits are the same whatever follows it."""
+    _, params = served
+    whole = np.asarray(ref.logits(params, CFG, TOKENS))
+    for n in (20, 24, 25, 100):
+        part = np.asarray(ref.logits(params, CFG, TOKENS[:n]))
+        assert np.abs(part - whole[:n]).max() < 1e-5
+
+
+@pytest.mark.parametrize("mutate", ref.MUTATIONS)
+def test_every_mutation_is_outside_the_tolerance(served, mutate):
+    _, params = served
+    want = np.asarray(ref.logits(params, CFG, TOKENS))
+    got = np.asarray(ref.logits(params, CFG, TOKENS, mutate=mutate))
+    assert np.abs(got - want).max() > 10 * TOL
+
+
+def test_e4m3_operands_are_outside_the_tolerance(served):
+    _, params = served
+    want = np.asarray(ref.logits(params, CFG, TOKENS))
+    low = np.asarray(ref.logits(params, CFG, TOKENS, precision="float8"))
+    assert np.abs(low - want).max() > 100 * TOL
+
+
+def test_reference_is_independent_of_the_program():
+    src = open(ref.__file__).read()
+    assert "deepspeed_tpu" not in src.split('"""', 2)[2]

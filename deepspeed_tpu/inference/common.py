@@ -187,6 +187,14 @@ class HostStageStats:
         self.sparse_rows = 0
         self.sparse_key_tokens = 0
         self.sparse_visible_tokens = 0
+        # (attention that chooses its keys by token) the queries, the keys
+        # the indexer scores for them; of those with more than ``topk`` to
+        # choose from: how many, the keys they attend, the keys they see
+        self.index_rows = 0
+        self.index_visible_tokens = 0
+        self.sel_rows = 0
+        self.sel_key_tokens = 0
+        self.sel_visible_tokens = 0
         # a looped model's exit gate, live rows only:
         self.loop_rows = 0          # rows at dispatch, summed
         self.loop_exit_cdf = []     # P(stopped by pass u), summed, u < U

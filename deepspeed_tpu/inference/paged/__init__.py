@@ -24,6 +24,7 @@ what the engine reads of it (a :class:`~.tree.Part`): ``kv_pages.py`` (per-head
 K/V pages; a looped model's slots a layer), ``conv_state.py`` (a short
 convolution's last inputs), ``linear_state.py`` (a matrix a head),
 ``block_select.py`` (pages of keys and values chosen by compressed keys),
+``index_select.py`` (tokens chosen by an indexer whose keys are a pool leaf),
 ``latent.py`` (one latent row a token).  :func:`describe` composes a model's
 parts into the ONE description the engine reads.
 
@@ -44,6 +45,9 @@ from deepspeed_tpu.inference.paged.block_select import (  # noqa: F401
     sparse_selection, sparse_update_and_attend)
 from deepspeed_tpu.inference.paged.conv_state import (  # noqa: F401
     ConvState, conv_layers, ragged_short_conv)
+from deepspeed_tpu.inference.paged.index_select import (  # noqa: F401
+    INDEX_KEY_PAGES, TOKENSEL_KV_PAGES, IndexSelect, token_select_counts,
+    token_selection, tokensel_update_and_attend)
 from deepspeed_tpu.inference.paged.kv_pages import (  # noqa: F401
     _RPA_QUERY_BLOCK, KVPages, kv_dequant_path, kv_lane_dim,
     kv_slots_per_layer, paged_attention_path, paged_update_and_attend,
@@ -101,14 +105,15 @@ class CacheKind:
 def describe(cfg) -> CacheKind:
     """The cache of the model whose config is ``cfg``, read off the config's
     own keys (``total_ut_steps``, ``layer_types``, ``mixer_types``,
-    ``selection``, ``kv_lora_rank``) by each kind's predicate."""
+    ``selection``, ``sa_config``, ``kv_lora_rank``) by each kind's
+    predicate."""
     of = int(cfg.num_hidden_layers)
     conv, linear = conv_layers(cfg), linear_layers(cfg)
     rows = of - conv - linear
     slots, width = kv_slots_per_layer(cfg), latent_width(cfg)
-    selection = sparse_selection(cfg)
+    selection, indexer = sparse_selection(cfg), token_selection(cfg)
     parts: List[Part] = []
-    if selection is None and not width:
+    if selection is None and indexer is None and not width:
         parts.append(KVPages(rows, of, slots))
     if conv:
         parts.append(ConvState(conv, of))
@@ -116,6 +121,8 @@ def describe(cfg) -> CacheKind:
         parts.append(LinearState(linear, of))
     if selection is not None:
         parts.append(BlockSelect(rows, of, selection))
+    elif indexer is not None:
+        parts.append(IndexSelect(rows, of, indexer))
     elif width:
         parts.append(Latent(rows, of, width))
     return CacheKind(tuple(parts), slots, conv + linear, rows, width,

@@ -265,6 +265,59 @@ def _fold(s, ok, v, m_prev, l_prev, acc_prev, dtype):
     return m_new, l_new, acc
 
 
+def step_copies(table_ref, pages_hbm, buf, sems, seq, pages_per_seq: int,
+                b, slot):
+    """The copies that bring key step ``b`` of sequence ``seq`` (its
+    ``_STEP_PAGES`` pages through the table) into buffer ``slot``.  A page
+    past the sequence's last holds nothing a row attends (the mask covers
+    it); the clamps keep the address legal."""
+    for i in range(_STEP_PAGES):
+        page_id = jnp.maximum(table_ref[seq * pages_per_seq + jnp.minimum(
+            b * _STEP_PAGES + i, pages_per_seq - 1)], 0)
+        yield pltpu.make_async_copy(pages_hbm.at[page_id],
+                                    buf.at[slot, i], sems.at[slot])
+
+
+def fold_head(h, q, k, bias, repeat: int, vt_sc, m_sc, l_sc, acc_sc,
+              sm_scale: float):
+    """Every query head of KV head ``h`` (``q [R, D]``) against a step's
+    keys ``k [keys, D]``, scores held TRANSPOSED (keys down, the tile's
+    rows x heads across): one product for the scores, one for ``p V``
+    (``vt_sc[h]`` holds the step's values transposed), folded into the
+    running maximum, sum and accumulator of the head.  ``bias [keys, R /
+    repeat]`` is additive and repeated across the lanes, or None."""
+    s = jax.lax.dot_general(
+        k, q, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * sm_scale        # [keys, R]
+    if bias is not None:
+        s = s + (bias if repeat == 1
+                 else jnp.concatenate([bias] * repeat, axis=1))
+    m_prev = m_sc[h]                                          # [1, R]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_sc[h] = alpha * l_sc[h] + jnp.sum(p, axis=0, keepdims=True)
+    acc_sc[h] = acc_sc[h] * alpha + jnp.dot(
+        vt_sc[h], p.astype(vt_sc.dtype),
+        preferred_element_type=jnp.float32)                   # [D, R]
+    m_sc[h] = m_new
+
+
+def finish_tile(o_ref, m_sc, l_sc, acc_sc, length, mask_value: float):
+    """The tile's output from the folded heads: ``o_ref [Hkv, G, TILE, D]``
+    from ``acc_sc [Hkv, D, G * TILE]``; zeros for a row past ``length`` and
+    for one that met no key it attends (it holds the first maximum)."""
+    Hkv, G, _, D = o_ref.shape
+    live = jax.lax.broadcasted_iota(jnp.int32, (D, TILE), 1) < length
+    for h in range(Hkv):
+        for g in range(G):
+            lanes = pl.ds(g * TILE, TILE)
+            ok = jnp.logical_and(live, m_sc[h, :, lanes] > mask_value)
+            o_ref[h, g] = jnp.where(
+                ok, acc_sc[h, :, lanes] / l_sc[h, :, lanes], 0.0
+            ).T.astype(o_ref.dtype)
+
+
 def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
                   q_ref, chosen_ref, pages_hbm, o_ref,
                   buf, sems, vt_sc, delta_sc, m_sc, l_sc, acc_sc, *,
@@ -285,14 +338,8 @@ def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
     last = pos0 + length - 1
     sparse = last >= dense_len
 
-    def copies(b, slot):
-        for i in range(_STEP_PAGES):
-            # a page past the sequence's last holds nothing a row attends
-            # (it lies past the diagonal); the clamps keep the address legal
-            page_id = jnp.maximum(table_ref[seq * pages_per_seq + jnp.minimum(
-                b * _STEP_PAGES + i, pages_per_seq - 1)], 0)
-            yield pltpu.make_async_copy(pages_hbm.at[page_id],
-                                        buf.at[slot, i], sems.at[slot])
+    copies = functools.partial(step_copies, table_ref, pages_hbm, buf, sems,
+                               seq, pages_per_seq)
 
     @pl.when(n == 0)
     def _():
@@ -302,29 +349,12 @@ def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
             jax.lax.broadcasted_iota(jnp.int32, delta_sc.shape, 0)
             - jax.lax.broadcasted_iota(jnp.int32, delta_sc.shape, 1))
 
-    def one_head(h, slot, bias):
-        """Every query head of KV head ``h`` against the step's keys: one
-        product for the scores, one for ``p V``."""
-        q = q_ref[h].reshape(R, D)
-        k = buf[slot, :, 2 * h].reshape(keys, D)
-        s = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale    # [keys, R]
-        if bias is not None:
-            s = s + jnp.concatenate([bias] * G, axis=1)
-        m_prev = m_sc[h]                                      # [1, R]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[h] = alpha * l_sc[h] + jnp.sum(p, axis=0, keepdims=True)
-        acc_sc[h] = acc_sc[h] * alpha + jnp.dot(
-            vt_sc[h], p.astype(vt_sc.dtype),
-            preferred_element_type=jnp.float32)               # [D, R]
-        m_sc[h] = m_new
-
     def every_head(slot, biases):
         for h in range(Hkv):
-            one_head(h, slot, None if biases is None else biases[h])
+            q = q_ref[h].reshape(R, D)
+            k = buf[slot, :, 2 * h].reshape(keys, D)
+            fold_head(h, q, k, None if biases is None else biases[h], G,
+                      vt_sc, m_sc, l_sc, acc_sc, sm_scale)
 
     def one_step(b, _):
         slot = b % 2
@@ -384,15 +414,7 @@ def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
         for copy in copies(0, 0):
             copy.start()
         jax.lax.fori_loop(0, last // keys + 1, one_step, None)
-        live = jax.lax.broadcasted_iota(jnp.int32, (D, TILE), 1) < length
-        for h in range(Hkv):
-            for g in range(G):
-                lanes = pl.ds(g * TILE, TILE)
-                # a row that met no key it attends holds the first maximum
-                ok = jnp.logical_and(live, m_sc[h, :, lanes] > _MASK_VALUE)
-                o_ref[h, g] = jnp.where(
-                    ok, acc_sc[h, :, lanes] / l_sc[h, :, lanes], 0.0
-                ).T.astype(o_ref.dtype)
+        finish_tile(o_ref, m_sc, l_sc, acc_sc, length, _MASK_VALUE)
 
 
 def chunk_vmem_bytes(Hkv: int, G: int, D: int, page: int,

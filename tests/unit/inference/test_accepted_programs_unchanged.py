@@ -7,7 +7,10 @@ ask for it.  PR 43 added the two programs of ``minicpm-sala-serve``
 (tile layout, chunked scan and one-token step over a matrix state; block
 selection over a two-KV-head pool), taken on its own tree: what a later PR
 adds must leave them as they are too (PR 44 rebuilt the fused tick's
-block-selecting chunk kernel and re-took ``sala.fused`` alone).
+block-selecting chunk kernel and re-took ``sala.fused`` alone).  PR 47 added
+the two programs of ``keye-vl2-30b-a3b-serve`` (an indexer's keys a pool
+leaf, the choice by token, 4-KV-head pages a token a row), taken on its own
+tree; the eight older hashes are as they were.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -45,11 +48,15 @@ EXPECTED = {
     # rows take the chosen-page kernel, is PR 43's to the letter
     "sala.fused": "2e90302b3dc12e5f",
     "sala.decode_block": "86bf79c9930ecd7c",
+    # taken by PR 47, which added the model
+    "keye.fused": "d5f08eb36d7e8449",
+    "keye.decode_block": "d822c05479e78df3",
 }
 
 
 def _models():
-    from deepspeed_tpu.models import minicpm_sala, mistral, olmoe, ouro
+    from deepspeed_tpu.models import (keye_vl2, minicpm_sala, mistral, olmoe,
+                                      ouro)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -63,6 +70,8 @@ def _models():
         "ouro": ouro.OuroForCausalLM(ouro.get_config("tinyouro", **bf16)),
         "sala": minicpm_sala.MiniCPMSALAForCausalLM(minicpm_sala.get_config(
             "tinysala", **bf16)),
+        "keye": keye_vl2.KeyeVL2ForCausalLM(keye_vl2.get_config(
+            "tinykeye", expert_parallel=False, **bf16)),
     }
 
 

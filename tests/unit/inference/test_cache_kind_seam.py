@@ -46,6 +46,8 @@ MODELS = {
     "tinylfm2": ("lfm2_moe", "Lfm2MoeForCausalLM", {}),
     "tinyglm": ("glm4_moe_lite", "Glm4MoeLiteForCausalLM", {}),
     "tinysala": ("minicpm_sala", "MiniCPMSALAForCausalLM", {}),
+    "tinykeye": ("keye_vl2", "KeyeVL2ForCausalLM",
+                 dict(expert_parallel=False)),
 }
 ENGINE = dict(pipeline=False, max_seqs=2, max_seq_len=128, prefill_chunk=32,
               page_size=16, num_pages=20, decode_block_size=2)
@@ -54,7 +56,7 @@ COMMON = {"program", "ahead", "rows", "ticks", "key_tokens", "pages_in_use",
           "pages_total", "prefill_tokens", "prefill_key_tokens",
           "decode_key_tokens", "head_rows"}
 KIND_BLOCKS = ("kernels", "latent", "loop", "linear_state", "sparse",
-               "hybrid")
+               "hybrid", "token_select")
 PLAIN = {"paged_attention": "xla-dense",
          "paged_attention_decode": "xla-dense",
          "paged_attention_prefill": "xla-dense"}
@@ -137,6 +139,46 @@ EXPECTED = {
                            "kernel_stride": 4, "block_size": 16,
                            "init_blocks": 1, "window_size": 32, "topk": 5,
                            "dense_len": 64}}),
+    # topk 24: the first tick's last ten rows choose, every later row does
+    "tinykeye": dict(
+        numbers=(1, 0, 2, 0, None),
+        attrs={"index_bytes_per_token": 128,
+               "tokensel_kv_bytes_per_token": 1024},
+        asks=[dict(index_rows=34, index_visible_tokens=595,
+                   index_prefill_tokens=595, index_decode_tokens=0,
+                   sel_rows=10, sel_key_tokens=240, sel_visible_tokens=295,
+                   attend_prefill_tokens=540, attend_decode_tokens=0,
+                   one_row_seqs=0),
+              dict(index_rows=34, index_visible_tokens=1751,
+                   index_prefill_tokens=1751, index_decode_tokens=0,
+                   sel_rows=34, sel_key_tokens=816, sel_visible_tokens=1751,
+                   attend_prefill_tokens=816, attend_decode_tokens=0,
+                   one_row_seqs=0),
+              dict(index_rows=22, index_visible_tokens=1749,
+                   index_prefill_tokens=1749, index_decode_tokens=0,
+                   sel_rows=22, sel_key_tokens=528, sel_visible_tokens=1749,
+                   attend_prefill_tokens=528, attend_decode_tokens=0,
+                   one_row_seqs=0)]
+        + [dict(index_rows=2, index_visible_tokens=seen,
+                index_prefill_tokens=0, index_decode_tokens=seen, sel_rows=2,
+                sel_key_tokens=48, sel_visible_tokens=seen,
+                attend_prefill_tokens=0, attend_decode_tokens=48)
+           for seen in (183, 187, 191)],
+        stages={"kernels": {"token_select_decode": "xla-token-gather-24",
+                            "token_select_index": "xla",
+                            "token_select_threshold": "xla",
+                            "token_select_prefill": "xla",
+                            "token_select_row_index": "xla", **GROUPED},
+                "token_select": {
+                    "kv_layers": 2, "index_pool_bytes": 40960,
+                    "index_bytes_per_token": 128,
+                    "tokensel_kv_bytes_per_token": 1024, "index_rows": 96,
+                    "index_visible_tokens": 4656, "sel_rows": 72,
+                    "sel_key_tokens": 1728, "sel_visible_tokens": 4356,
+                    "pages_peak": 6, "indexer_num_heads": 2,
+                    "indexer_head_dim": 16, "indexer_num_kv_heads": 1,
+                    "topk": 24, "q_chunk_size": 512,
+                    "kv_chunk_size": 512}}),
 }
 
 
@@ -200,6 +242,8 @@ _LFM2 = "Lfm2MoeForCausalLM keeps per-slot state in 5 of its 6 layers"
 _SALA = "MiniCPMSALAForCausalLM keeps per-slot state in 2 of its 4 layers"
 _GLM = ("Glm4MoeLiteForCausalLM caches one latent row of 128 values a token "
         "a layer")
+_KEYE = ("KeyeVL2ForCausalLM attends the 24 keys an indexer of 2 heads "
+         "chooses, token by token, in 2 of its 2 layers")
 _STATE = {
     "prefix_cache": "prefix_cache: {}, and a shared prefix (or its copy-on-"
     "write page) brings keys and values only — a sequence that starts behind "
@@ -241,11 +285,24 @@ MESSAGES = {
     "pool's scale rows and kernel are per head",
     ("tinyglm", "tensor_parallel"): f"tensor-parallel serving: {_GLM}, which "
     "has no head dim for the pool's sharding rule to split",
+    ("tinykeye", "speculation"): f"speculation.mode='ngram': {_KEYE}, and the "
+    "speculative verify has no kernel that scores and chooses for its rows "
+    "(nor a draft that shares the index keys)",
+    ("tinykeye", "long_context"): f"kv_tiering.long_context: {_KEYE}, and "
+    "the partial-residency scan (inference/v2/long_context.py) stages and "
+    "folds keys and values a page at a time, where a choice by token needs "
+    "every index key of the sequence at once",
+    ("tinykeye", "quantized"): f"kv_cache_dtype='int8': {_KEYE}; the "
+    "quantized pool's scale rows and kernel are per head of a pool laid out "
+    "heads first, and an index key has no scale",
+    ("tinykeye", "tensor_parallel"): f"tensor-parallel serving: {_KEYE}, and "
+    "the cache's sharding rule splits a pool's head dim, which a token's row "
+    "of every head and the one index key a token do not have",
 }
 
 
 def test_the_descriptions_refusals_are_the_ones_listed():
-    """Fourteen refusals in four kinds' files (per-slot state's five serve
+    """Eighteen refusals in five kinds' files (per-slot state's five serve
     two models), and none for a model of plain K/V pages."""
     listed = set()
     for preset in MODELS:
@@ -254,7 +311,7 @@ def test_the_descriptions_refusals_are_the_ones_listed():
                 type(model).__name__):
             assert model_is.startswith(type(model).__name__)
             listed |= {(preset, feature) for feature in refuses}
-    assert listed == set(MESSAGES) and len(MESSAGES) == 14 + 5
+    assert listed == set(MESSAGES) and len(MESSAGES) == 18 + 5
 
 
 @pytest.mark.parametrize("preset,feature", sorted(MESSAGES))
@@ -320,11 +377,12 @@ def test_the_paged_cache_imports_nothing_that_stands_on_it(path):
 
 def test_the_engine_names_no_cache_kind():
     words = re.compile(r"latent|sparse|conv(?!ert|enien)|lightning|"
-                       r"linear_state|hybrid|ut_steps|CKEY", re.IGNORECASE)
+                       r"linear_state|hybrid|ut_steps|CKEY|tokensel|"
+                       r"token_select|index_key|indexer", re.IGNORECASE)
     code = _code(ENGINE_FILE)
     assert [(n, t) for n, t in code if words.search(t)] == []
     kernels = ("latent_attention", "block_select_attention",
-               "linear_attention")
+               "linear_attention", "token_select_attention")
     assert not any(k in m for m in _imports(ENGINE_FILE) for k in kernels)
     calls = [n for (n, t), (_, nxt) in zip(code, code[1:])
              if t == "_refuse_features" and nxt == "("]

@@ -27,17 +27,34 @@ TIERS = {"host_pages": 32}
 STATE_SHAPES = {"rows": (2, 8), "matrix": (2, 4, 4)}
 
 
-def make_kv(tiering=None, prefix=False, num_pages=PAGES, state=None):
-    """A pool whose row ``[p, t]`` holds ``100 * p + t`` in every cell of
-    one leaf and its negative in the other, so a moved page is told by
-    its content; with ``state`` (a key of ``STATE_SHAPES``) a per-slot
-    state leaf beside it whose slot ``s`` holds ``7 + s`` and counts up."""
-    rows = (100 * np.arange(num_pages)[:, None]
-            + np.arange(PAGE)[None, :]).astype(np.float32)
-    cache = {"model": {"layers_0": {
-        "kv_pages": jnp.asarray(np.broadcast_to(
-            rows[:, :, None, None], (num_pages, PAGE, 2, 4)).copy()),
-        "kv_scales": jnp.asarray(-rows)}}}
+# a layer's pool leaves, by what a cache kind keeps for a page (each leads
+# with the page dim, which is all ``KVState`` knows of it): per-head K/V with
+# its scale rows; K/V heads first beside compressed keys (block selection);
+# one latent row a token; K/V a token a row beside one index key a token
+LEAF_SETS = {
+    "kv": {"kv_pages": (PAGE, 2, 4), "kv_scales": (PAGE,)},
+    "kv+compressed": {"sparse_kv_pages": (4, PAGE, 4), "ckey_pages": (2, 4)},
+    "latent": {"latent_pages": (PAGE, 8)},
+    "kv+index": {"tokensel_kv_pages": (PAGE, 16),
+                 "index_key_pages": (PAGE // 2, 8)},
+}
+
+
+def make_kv(tiering=None, prefix=False, num_pages=PAGES, state=None,
+            leaves="kv"):
+    """A pool whose page ``p`` holds ``100 * p`` plus a small count in
+    every leaf of ``LEAF_SETS[leaves]`` (negated in every second leaf), so
+    a moved page is told by its content; with ``state`` (a key of
+    ``STATE_SHAPES``) a per-slot state leaf beside it whose slot ``s`` holds
+    ``7 + s`` and counts up."""
+    layer = {}
+    for i, (name, shape) in enumerate(LEAF_SETS[leaves].items()):
+        within = np.arange(int(np.prod(shape)), dtype=np.float32).reshape(
+            shape) % PAGE
+        layer[name] = jnp.asarray((-1) ** i * (
+            100 * np.arange(num_pages, dtype=np.float32).reshape(
+                (num_pages,) + (1,) * len(shape)) + within))
+    cache = {"model": {"layers_0": layer}}
     if state is not None:
         shape = STATE_SHAPES[state]
         cache["model"]["layers_1"] = {"slot_state": jnp.asarray(
@@ -66,9 +83,11 @@ def seat(kv, r, block=4):
 
 
 def page_rows(kv, pages):
-    """``kv_pages[p, :, 0, 0]`` of each page: its 4 recognisable rows."""
-    return np.asarray(kv.cache["model"]["layers_0"]["kv_pages"])[
-        np.asarray(pages), :, 0, 0]
+    """What each of ``pages`` holds in every pool leaf, side by side."""
+    return np.concatenate(
+        [np.asarray(leaf)[np.asarray(pages)].reshape(len(pages), -1)
+         for _, leaf in sorted(kv.cache["model"]["layers_0"].items())],
+        axis=1)
 
 
 def state_of(kv, slot):
@@ -121,8 +140,10 @@ def test_release_leaves_the_pool_whole(after):
     kv.close(parked)
 
 
-def test_spill_then_restore_brings_back_the_rows_and_keeps_the_shared_prefix():
-    kv = make_kv(tiering=TIERS, prefix=True)
+@pytest.mark.parametrize("leaves", sorted(LEAF_SETS))
+def test_spill_then_restore_brings_back_the_rows_and_keeps_the_shared_prefix(
+        leaves):
+    kv = make_kv(tiering=TIERS, prefix=True, leaves=leaves)
     r = make_req(1, 13, slot=0)
     pages = seat(kv, r)
     decoding(r)
@@ -151,8 +172,9 @@ def test_spill_then_restore_brings_back_the_rows_and_keeps_the_shared_prefix():
     kv.close()
 
 
-def test_a_full_prefix_match_copies_its_last_page_before_the_write():
-    kv = make_kv(prefix=True)
+@pytest.mark.parametrize("leaves", sorted(LEAF_SETS))
+def test_a_full_prefix_match_copies_its_last_page_before_the_write(leaves):
+    kv = make_kv(prefix=True, leaves=leaves)
     first = make_req(1, 8, slot=0)
     pages = seat(kv, first)
     first.prefill_done = 8
@@ -169,11 +191,15 @@ def test_a_full_prefix_match_copies_its_last_page_before_the_write():
     kv.audit(slots_of(first, second))
 
 
-@pytest.mark.parametrize("state", [None, "rows", "matrix"])
-def test_export_then_adopt_round_trips_on_a_second_pool(state):
-    """The pages, and the per-slot state whatever its shape, of a spilled
-    sequence reach a second pool's slot through ``export`` and ``adopt``."""
-    kv, kv2 = (make_kv(tiering=TIERS, state=state) for _ in range(2))
+@pytest.mark.parametrize("state,leaves", [
+    (None, "kv"), ("rows", "kv"), ("matrix", "kv"), ("matrix", "kv+compressed"),
+    (None, "latent"), (None, "kv+index")])
+def test_export_then_adopt_round_trips_on_a_second_pool(state, leaves):
+    """The pages, whatever their leaves hold, and the per-slot state,
+    whatever its shape, of a spilled sequence reach a second pool's slot
+    through ``export`` and ``adopt``."""
+    kv, kv2 = (make_kv(tiering=TIERS, state=state, leaves=leaves)
+               for _ in range(2))
     r = decoding(make_req(1, 10, slot=0), new_tokens=2)
     pages = seat(kv, r)
     want = page_rows(kv, pages[:3])             # 11 live tokens: 3 pages

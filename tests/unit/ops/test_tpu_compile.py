@@ -599,6 +599,64 @@ def test_block_select_kernels_are_tiled_for_two_kv_heads(chip_compile):
     assert f"%{bsa.DECODE_NAME}" in text
 
 
+# keye-vl2-30b-a3b-serve: 32 query heads over 4 KV heads of 128, an indexer
+# of 16 heads of 64 over one index key a token, a pool of 4,160 pages (K/V a
+# token a row, index keys beside them), 8 slots of 513 pages, a fused tick
+# of 8 + 1,024 rows
+KEYE = dict(S=8, pp=513, pool=4160, page=64, H=32, Hkv=4, D=128, HI=16,
+            dI=64, T=1032, topk=2048)
+
+
+def test_token_select_kernels_at_the_serving_configuration(chip_compile):
+    """``ops/token_select_attention.py`` at the published widths: the index
+    scores through the page table (eight pages a key step), the exact
+    threshold with a tile's 33,280 scores resident in VMEM, the dense pass
+    under the per-(row, key) bias over a pool of four KV heads a token a
+    row, and a decode row's scores over the pages it sees; the limits the
+    calls ask for are their own reckoning."""
+    from deepspeed_tpu.ops import linear_attention as la
+    from deepspeed_tpu.ops import token_select_attention as tsa
+
+    c, i32, bf = KEYE, jnp.int32, jnp.bfloat16
+    G = c["H"] // c["Hkv"]
+    NT = -(-c["T"] // tsa.TILE) + c["S"]
+    assert tsa.TILE == la.CHUNK and NT == 17
+    K = tsa.padded_keys(c["pp"], c["page"])
+    assert K == 65 * 512
+    tiles = (((NT,), i32),) * 3
+    table = ((c["S"], c["pp"]), i32)
+    index_pool = tsa.index_leaf_shape(c["pool"], c["page"], c["dI"])
+    assert index_pool == (c["pool"], 32, 128)     # two keys a row of lanes
+    text = chip_compile(
+        functools.partial(tsa._index.__wrapped__, interpret=False),
+        ((NT, c["HI"] * tsa.TILE, c["dI"]), bf),
+        ((NT, 1, c["HI"] * tsa.TILE), jnp.float32),
+        (index_pool, bf), table, *tiles)
+    assert f"%{tsa.INDEX_NAME}" in text
+    asked = tsa.select_vmem_bytes(K)
+    assert asked < 64 * 1024 * 1024         # half of the v5e's VMEM
+    text = chip_compile(
+        functools.partial(tsa._select.__wrapped__, topk=c["topk"], keys=512,
+                          interpret=False),
+        ((NT, K, tsa.TILE), i32), *tiles[:2])
+    assert f"%{tsa.SELECT_NAME}" in text and f'"size":"{asked}"' in text
+    asked = tsa.chunk_vmem_bytes(c["Hkv"], G, c["D"], c["page"], K)
+    assert asked < 64 * 1024 * 1024
+    text = chip_compile(
+        functools.partial(tsa._chunk.__wrapped__, sm_scale=0.0884,
+                          interpret=False),
+        ((c["Hkv"], G, NT * tsa.TILE, c["D"]), bf),
+        ((c["pool"], c["page"], 2 * c["Hkv"] * c["D"]), bf),
+        ((NT, K, tsa.TILE), bf), *tiles, table)
+    assert f"%{tsa.CHUNK_NAME}" in text and f'"size":"{asked}"' in text
+    text = chip_compile(
+        functools.partial(tsa._row_index.__wrapped__, interpret=False),
+        ((c["S"], c["HI"], c["dI"]), bf), ((c["S"], c["HI"]), jnp.float32),
+        (index_pool, bf), table, ((c["S"],), i32),
+        ((c["S"],), i32))
+    assert f"%{tsa.ROW_INDEX_NAME}" in text
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4
@@ -635,6 +693,9 @@ GMM_BANKS = [
     pytest.param((60, 1408, 2048), (128, 1408, 2048), id="qwen-down"),
     pytest.param((64, 2048, 1536), (128, 2048, 768), id="lfm2-gate-up"),
     pytest.param((64, 1536, 2048), (128, 1536, 1024), id="lfm2-down"),
+    # a third tiling regime: 768 divides by 128 six ways, not eight
+    pytest.param((128, 2048, 768), (128, 2048, 768), id="keye-gate-up"),
+    pytest.param((128, 768, 2048), (128, 768, 2048), id="keye-down"),
 ]
 
 

@@ -34,12 +34,13 @@ FAMILIES = {
     "lfm2": {"attn", "conv", "mlp", "moe"},
     "glm": {"attn", "mlp", "moe"},
     "sala": {"attn", "linear", "mlp"},
+    "keye": {"attn", "moe"},
 }
 
 
 def _model(family):
-    from deepspeed_tpu.models import (glm4_moe_lite, lfm2_moe, minicpm_sala,
-                                      mistral, olmoe, ouro)
+    from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
+                                      minicpm_sala, mistral, olmoe, ouro)
 
     if family == "mistral":
         return mistral.MistralForCausalLM(mistral.get_config(
@@ -57,6 +58,9 @@ def _model(family):
     if family == "sala":
         return minicpm_sala.MiniCPMSALAForCausalLM(
             minicpm_sala.get_config("tinysala", **BF16))
+    if family == "keye":
+        return keye_vl2.KeyeVL2ForCausalLM(keye_vl2.get_config(
+            "tinykeye", expert_parallel=False, **BF16))
     return lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config("tinylfm2",
                                                            **BF16))
 
@@ -254,6 +258,30 @@ def _train_engine(loss_module, dp=2):
     ("jit(f)/M/model/layers_0/self_attn/jit(_decode)/"
      "sparse_decode_attention/pallas_call",
      ("attn", "sparse_decode_attention", "-")),
+    # a token-selecting layer: the indexer's module, the chunks' scopes and
+    # kernels, the one-row path's scopes (inside the step's ``cond``)
+    ("jit(f)/M/model/layers_0/self_attn/indexer/k_norm/rsqrt",
+     ("attn", "indexer", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/index/gather",
+     ("attn", "index", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/index/jit(_index)/index_scores/"
+     "pallas_call", ("attn", "index_scores", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/select/jit(_select)/index_select/"
+     "pallas_call", ("attn", "index_select", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/select/cond/branch_1_fun/cumsum",
+     ("attn", "select", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/jit(_chunk)/"
+     "tokensel_chunk_attention/pallas_call",
+     ("attn", "tokensel_chunk_attention", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/index_row/cond/branch_1_fun/"
+     "select_n", ("attn", "index_row", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/index_row/cond/branch_1_fun/"
+     "jit(_row_index)/row_index_scores/pallas_call",
+     ("attn", "row_index_scores", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/select_row/top_k",
+     ("attn", "select_row", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/tokensel_row_attention/gather",
+     ("attn", "tokensel_row_attention", "-")),
     ("jit(f)/while/body/closed_call/sample/sample_logits_batched/reduce",
      ("sample", "sample_logits_batched", "-")),
     ("jit(f)/M/head.rows/gather", ("head", "head.rows", "-")),
