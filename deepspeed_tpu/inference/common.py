@@ -176,6 +176,8 @@ class HostStageStats:
         self.moe_rows = 0           # token copies routed
         self.moe_experts_hit = 0    # experts with at least one row
         self.moe_load_max = 0       # rows of the fullest expert, ever
+        # row-tile visits of the grouped matmul (ops/grouped_matmul.py)
+        self.moe_visits = 0
         # (a sigmoid router with a selection bias) tokens whose chosen
         # set the bias changed
         self.moe_bias_swaps = 0

@@ -52,20 +52,21 @@ KV_CACHE_DTYPES = ("none", "int8", "fp8", "fp8_e4m3")
 
 
 def fold_moe_counts(counts: jax.Array) -> jax.Array:
-    """``[n, 3]`` per-layer (or per-tick) expert-layer counts -> ``[3]``:
-    token copies routed and experts hit add up, the fullest expert's rows
-    take the largest."""
-    return jnp.concatenate([jnp.sum(counts[:, :2], axis=0),
-                            jnp.max(counts[:, 2:], axis=0)])
+    """``[n, 4]`` per-layer expert-layer counts -> ``[4]``, or the ``[ticks,
+    4 or 5]`` of a block's :func:`moe_counts` as one: token copies routed,
+    experts hit, the grouped matmul's row-tile visits (and the fifth entry,
+    where there is one) add up, the fullest expert's rows take the
+    largest."""
+    return jnp.sum(counts, axis=0).at[2].set(jnp.max(counts[:, 2]))
 
 
 def moe_counts(variables) -> Optional[jax.Array]:
     """One apply's ``moe_stats`` collection (each dropless expert layer
-    sows ``[rows, experts_hit, load_max]``) folded over its layers; None
-    for a model without expert layers, so its programs gain no output.
-    Where the layers also sow ``bias_swaps`` (the sigmoid router with a
-    selection bias: tokens whose chosen set the bias changed) their sum is
-    a fourth entry."""
+    sows ``[rows, experts_hit, load_max, visits]``) folded over its
+    layers; None for a model without expert layers, so its programs gain
+    no output.  Where the layers also sow ``bias_swaps`` (the sigmoid
+    router with a selection bias: tokens whose chosen set the bias
+    changed) their sum is a fifth entry."""
     flat = jax.tree_util.tree_flatten_with_path(
         variables.get("moe_stats", {}))[0]
     is_swaps = lambda path: any(                    # noqa: E731
@@ -80,15 +81,6 @@ def moe_counts(variables) -> Optional[jax.Array]:
             counts = jnp.concatenate(
                 [counts, jnp.sum(jnp.stack(swaps))[None]])
     return counts
-
-
-def fold_moe_ticks(counts: jax.Array) -> jax.Array:
-    """:func:`moe_counts` of the ticks of a block, ``[ticks, 3 or 4]``,
-    as one: the fourth entry, where there is one, adds up."""
-    folded = fold_moe_counts(counts[:, :3])
-    if counts.shape[1] == 3:
-        return folded
-    return jnp.concatenate([folded, jnp.sum(counts[:, 3:], axis=0)])
 
 
 # what a model may sow beside its cache: the dropless expert layers' counts
@@ -541,7 +533,7 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
         # the full carry returns so the pipelined host path can keep
         # it device-resident across dispatches (no re-upload)
         return (cache, last_tok, pos, active, remaining, toks, mask,
-                None if moe is None else fold_moe_ticks(moe),
+                None if moe is None else fold_moe_counts(moe),
                 None if loop is None else jnp.sum(loop, axis=0))
 
     run.__name__ = run.__qualname__ = "ragged_decode_block"

@@ -1058,7 +1058,10 @@ class RaggedInferenceEngineV2:
             out["kernels"].update(routes.taken(prefix=GROUPED_MATMUL))
             out["moe"] = {"rows": st.moe_rows,
                           "experts_hit": st.moe_experts_hit,
-                          "load_max": st.moe_load_max}
+                          "load_max": st.moe_load_max,
+                          # less ``experts_hit``: the visits of the grouped
+                          # matmul that needed no new bank
+                          "visits": st.moe_visits}
         out.update(self.cache_kind.stages(
             st, self.kv.cache, self.cfg, out["kernels"], self._pages_peak))
         # the head of a fused tick multiplies the rows it samples, gathered
@@ -1145,18 +1148,20 @@ class RaggedInferenceEngineV2:
     def _record_moe(self, counts, attrs: Dict[str, Any]) -> None:
         """One dispatch's expert-layer counts, as a span of their own in
         the profiler's host plane and in ``serving_stages()['moe']``."""
-        rows, hit, load, *swaps = (int(v) for v in counts)
+        rows, hit, load, visits, *swaps = (int(v) for v in counts)
         st = self.host_stats
         st.moe_rows += rows
         st.moe_experts_hit += hit
         st.moe_load_max = max(st.moe_load_max, load)
+        st.moe_visits += visits
         if swaps:
             # a router with a selection bias: tokens whose chosen set is
             # not the top k of the scores alone
             st.moe_bias_swaps += swaps[0]
             attrs = {**attrs, "bias_swaps": swaps[0]}
         with trace.bracket("engine.moe", cat="serving", moe_rows=rows,
-                           moe_experts_hit=hit, moe_load_max=load, **attrs):
+                           moe_experts_hit=hit, moe_load_max=load,
+                           moe_visits=visits, **attrs):
             pass
 
     def _record_loop(self, exit_cdf, attrs: Dict[str, Any]) -> None:

@@ -433,9 +433,12 @@ def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
     marks the rows that are tokens — padding of a tick and inactive decode
     slots take no expert row and come back as zeros.
 
-    Returns ``(y [G, M], counts int32[3])``: token copies routed, experts
-    with at least one row, rows of the fullest expert.
+    Returns ``(y [G, M], counts int32[4])``: token copies routed, experts
+    with at least one row, rows of the fullest expert, row-tile visits of
+    the grouped matmul (``ops/grouped_matmul.py:visits``).
     """
+    from deepspeed_tpu.ops import grouped_matmul
+
     G, k = experts.shape
     E = num_experts
     # the scopes name the four stages for ``telemetry/device_scopes.py``:
@@ -460,5 +463,6 @@ def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
             y = jnp.where(live[:, None], y, 0)
         counts = jnp.stack([jnp.sum(group_sizes),
                             jnp.sum((group_sizes > 0).astype(jnp.int32)),
-                            jnp.max(group_sizes)])
+                            jnp.max(group_sizes),
+                            grouped_matmul.visits(group_sizes)])
     return y, counts

@@ -10,7 +10,10 @@ adds must leave them as they are too (PR 44 rebuilt the fused tick's
 block-selecting chunk kernel and re-took ``sala.fused`` alone).  PR 47 added
 the two programs of ``keye-vl2-30b-a3b-serve`` (an indexer's keys a pool
 leaf, the choice by token, 4-KV-head pages a token a row), taken on its own
-tree; the eight older hashes are as they were.
+tree; the eight older hashes are as they were.  PR 48 re-took ``olmoe.*``
+and ``keye.*`` and no other: a dropless expert layer's counts gained a
+fourth entry (``moe_visits``, the grouped matmul's row-tile visits), which
+is an output of both programs of every model with expert layers.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -37,8 +40,11 @@ ENGINE = dict(max_seqs=4, max_seq_len=64, page_size=16, prefill_chunk=16,
 EXPECTED = {
     "mistral.fused": "1ddf10a92080f32a",
     "mistral.decode_block": "1eb55c041ba3f8f4",
-    "olmoe.fused": "92bf3f0e8437d581",
-    "olmoe.decode_block": "03ce8a62bd7290ce",
+    # re-taken by PR 48, which MEANT to move them (and ``keye.*`` below):
+    # the expert layers' counts are four, the grouped matmul's visits the
+    # fourth
+    "olmoe.fused": "92c6b2106939dc21",
+    "olmoe.decode_block": "d39fb8c7bb91ce0e",
     "ouro.fused": "8443cc00e342ba49",
     "ouro.decode_block": "f87646743932c66c",
     # taken by PR 43, which added the model.  ``sala.fused`` was re-taken by
@@ -48,9 +54,9 @@ EXPECTED = {
     # rows take the chosen-page kernel, is PR 43's to the letter
     "sala.fused": "2e90302b3dc12e5f",
     "sala.decode_block": "86bf79c9930ecd7c",
-    # taken by PR 47, which added the model
-    "keye.fused": "d5f08eb36d7e8449",
-    "keye.decode_block": "d822c05479e78df3",
+    # taken by PR 47, which added the model; re-taken by PR 48 (above)
+    "keye.fused": "354cbe5ddd30061e",
+    "keye.decode_block": "1c188bb320a89427",
 }
 
 

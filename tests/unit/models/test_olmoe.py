@@ -126,6 +126,58 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(
     assert kernels["moe_grouped_matmul_kernel"] == "ragged-dot"
 
 
+def test_the_grouped_matmuls_visits_are_counted(served, monkeypatch):
+    """``moe_visits`` on the ``engine.moe`` spans and in
+    ``serving_stages()['moe']``: the row-tile visits of the grouped matmul
+    over every layer of every tick, against a plain loop over the group
+    sizes each call was given.  A chunk of 128 tokens routes 256 copies to
+    8 experts, so groups straddle the kernel's row tiles of 128; less the
+    experts hit it is the visits that needed no new bank."""
+    from deepspeed_tpu.telemetry import trace
+    from deepspeed_tpu.moe import layer as moe_layer
+    from deepspeed_tpu.ops import grouped_matmul as gm
+
+    seen = []
+
+    def counted(rows, banks, group_sizes):
+        jax.debug.callback(lambda g: seen.append(np.asarray(g)), group_sizes)
+        return gm.grouped_matmul(rows, banks, group_sizes)
+
+    monkeypatch.setattr(moe_layer, "grouped_matmul", counted)
+    trace.configure(enabled=True)
+    try:
+        trace.clear()
+        eng = _engine(served, max_seq_len=256, prefill_chunk=128)
+        out = eng.generate_all([np.asarray(_tokens(150, seed=4), np.int32)],
+                               max_new_tokens=6)
+        jax.effects_barrier()
+        events = trace.snapshot()
+    finally:
+        trace.configure(enabled=False)
+        trace.clear()
+    assert len(out[min(out)]) == 156
+
+    def visits(sizes):
+        count, start = 0, 0
+        for size in map(int, sizes):
+            if size:
+                count += (start + size - 1) // gm.TM - start // gm.TM + 1
+            start += size
+        return count
+
+    # a swiglu layer calls the kernel three times with the same groups
+    assert len(seen) % 3 == 0
+    want = sum(map(visits, seen)) // 3
+    hit = sum(int((g > 0).sum()) for g in seen) // 3
+    moe = [e["args"] for e in events if e["name"] == "engine.moe"]
+    stages = eng.serving_stages()["moe"]
+    assert stages["visits"] == sum(m["moe_visits"] for m in moe) == want
+    assert stages["experts_hit"] == hit
+    assert all(m["moe_visits"] >= m["moe_experts_hit"] for m in moe)
+    # the first chunk alone: 256 copies in two row tiles, a layer
+    assert want > hit
+
+
 def _first_tick_logits(eng, prompts, which):
     """The fused tick's selected logits of request ``which`` when
     ``prompts`` are admitted together."""

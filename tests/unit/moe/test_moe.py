@@ -202,8 +202,11 @@ def test_grouped_matches_the_einsum_path_that_drops_nothing(activation,
     (got, aux), stats = grouped.apply(params, x, mutable=["moe_stats"])
     np.testing.assert_allclose(got, want, atol=2e-6)
     np.testing.assert_allclose(aux, aux_want, rtol=1e-6)
-    rows, hit, load = (int(v) for v in stats["moe_stats"]["counts"][0])
+    rows, hit, load, visits = (
+        int(v) for v in stats["moe_stats"]["counts"][0])
     assert rows == 2 * 18 and hit <= 7 and load >= 36 / 7
+    # 36 rows lie in one row tile of the grouped matmul: a visit a group
+    assert visits == hit
 
 
 def test_grouped_dead_rows_take_no_expert_row():

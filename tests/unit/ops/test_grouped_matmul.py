@@ -53,6 +53,24 @@ CASES = {
     # K split: partial sums meet in the accumulator in another order
     "tk-under-K": (300, 512, 256, _spread(290, 8, 2), (128, 128, 128)),
     "tk-under-K-tn-under-N": (256, 384, 384, [0, 250, 6, 0], (128, 128, 128)),
+    # every group starts 64 rows into a row tile: the second visit of each
+    # multiplies a bank that is resident while the next one comes
+    "all-groups-straddle": (640, 128, 256, [64, 128, 128, 128, 128, 64],
+                            None),
+    # more visits of one bank than there are slots, between groups that
+    # share their tile with it
+    "five-tiles-between-threes": (640, 128, 128, [3, 3, 600, 3, 3], None),
+    # more empty groups in a run than slots: the sequence of bank tiles
+    # skips them
+    "empty-before-between-after": (384, 128, 256,
+                                   [0, 0, 0, 0, 130, 0, 0, 0, 0, 0, 120, 1,
+                                    0, 0, 0, 0], None),
+    "last-group-ends-short-of-R": (512, 128, 128, [100, 0, 150, 30], None),
+    # keye's prefill tick cut in K and N: 128 groups of 64.5 rows at the
+    # mean, two passes over n
+    "keye-cut": (8256, 128, 256, _spread(8256, 128, 3), (128, 128, 128)),
+    # one group hit: its tile of the second pass is the next of the first's
+    "one-group-two-passes": (256, 128, 256, [0, 200, 0, 0], (128, 128, 128)),
 }
 
 
@@ -81,7 +99,7 @@ def test_kernel_matches_ragged_dot(case, data):
         got = gm.grouped_matmul(rows, banks, sizes, interpret=True)
         assert routes.taken()[gm.KERNEL].endswith("-interpret")
     else:
-        assert tiling[1] < rows.shape[1]
+        assert tiling[1:] != rows.shape[1:] + banks.shape[2:]
         got = gm._gmm(rows, banks, sizes, tiling=tiling, interpret=True)
     assert got.shape == want.shape and got.dtype == want.dtype
     live = int(sizes.sum())             # rows past it are nobody's
@@ -110,7 +128,7 @@ def test_group_metadata_is_upstreams(skew):
             live, rng.dirichlet(np.full(E, skew))).astype(np.int32))
         # each under one jit: a draw's shapes are its own, and op by op
         # every one of them would compile some hundred small programs
-        (off, gid, mid), visits = jax.jit(
+        (off, gid, mid, *_), visits = jax.jit(
             gm.group_metadata, static_argnums=(1, 2))(sizes, rows, tm)
         (off_, gid_, mid_), visits_ = jax.jit(functools.partial(
             make_group_metadata, m=rows, tm=tm, num_nonzero_groups=E,
@@ -121,6 +139,33 @@ def test_group_metadata_is_upstreams(skew):
         np.testing.assert_array_equal(off, off_)
         np.testing.assert_array_equal(gid[:v], gid_[:v])
         np.testing.assert_array_equal(mid[:v], mid_[:v])
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_bank_sequence_is_the_non_empty_groups_in_order(case):
+    """What ``group_metadata`` gives beside upstream's three, and
+    ``visits``, against a plain loop over the groups."""
+    rows, _, _, sizes, tiling = CASES[case]
+    tm = gm.TM if tiling is None else tiling[0]
+    padded = -(-rows // tm) * tm
+    (_, gid, _, hit_groups, hit), visits = gm.group_metadata(
+        jnp.asarray(sizes, jnp.int32), padded, tm)
+    want_hit, want_visits, start = [], 0, 0
+    for g, size in enumerate(sizes):
+        if size:
+            want_hit.append(g)
+            want_visits += (start + size - 1) // tm - start // tm + 1
+        start += size
+    assert hit.shape == (1,) and int(hit[0]) == len(want_hit)
+    assert hit_groups.shape == (len(sizes),)
+    assert hit_groups[:len(want_hit)].tolist() == want_hit
+    assert int(visits) == want_visits
+    if tm == gm.TM:
+        assert int(gm.visits(jnp.asarray(sizes, jnp.int32))) == want_visits
+    # a visit's group is new exactly where the kernel takes its next tile
+    gid = gid[:want_visits].tolist()
+    firsts = [v for v in range(want_visits) if v == 0 or gid[v] != gid[v - 1]]
+    assert [gid[v] for v in firsts] == want_hit
 
 
 def test_float32_operands():
@@ -216,4 +261,4 @@ def test_tile_rule():
                            (4096, 14336, 4)):
         tm, tk, tn = gm.tiles(k, n, itemsize)
         assert k % tk == 0 and n % tn == 0
-        assert gm._vmem_bytes(tm, tk, tn, itemsize) <= gm._VMEM_BUDGET
+        assert gm._vmem_bytes(tm, tk, tn, itemsize, slots=2) <= gm._TILE_BUDGET

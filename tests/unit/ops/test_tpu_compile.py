@@ -699,14 +699,7 @@ GMM_BANKS = [
 ]
 
 
-@pytest.mark.parametrize("rows", [32 * 8, (32 + 512) * 8],
-                         ids=["decode-tick", "fused-tick"])
-@pytest.mark.parametrize("bank,tiling", GMM_BANKS)
-def test_grouped_matmul_kernel_at_the_tiles_the_rule_gives(
-        chip_compile, bank, tiling, rows):
-    """``ops/grouped_matmul.py`` under the default VMEM limit, and the
-    name a device trace will show for it (the benchmark's expert-layer
-    readers find the grouped matmul by ``ragged-dot`` in that name)."""
+def _gmm_compiles(chip_compile, bank, tiling, rows):
     from deepspeed_tpu.ops import grouped_matmul as gm
 
     E, K, N = bank
@@ -716,6 +709,39 @@ def test_grouped_matmul_kernel_at_the_tiles_the_rule_gives(
         ((rows, K), jnp.bfloat16), (bank, jnp.bfloat16), ((E,), jnp.int32))
     assert gm.NAME == "ragged-dot-gmm" and f"%{gm.NAME}." in text
     assert f"[{E},{rows}," not in text and f"[{rows},{E}," not in text
+    # three bank tiles where the tile rule counted two: asked for, and
+    # under half of the v5e's VMEM at the widest tile the rule gives
+    asked = gm.vmem_limit_bytes(*tiling, 2)
+    assert asked == gm._vmem_bytes(*tiling, 2, slots=3) + 2 * 1024 * 1024
+    assert asked < 64 * 1024 * 1024 and f'"size":"{asked}"' in text
+
+
+@pytest.mark.parametrize("rows", [32 * 8, (32 + 512) * 8],
+                         ids=["decode-tick", "fused-tick"])
+@pytest.mark.parametrize("bank,tiling", GMM_BANKS)
+def test_grouped_matmul_kernel_at_the_tiles_the_rule_gives(
+        chip_compile, bank, tiling, rows):
+    """``ops/grouped_matmul.py`` with the VMEM it reckons for itself, and
+    the name a device trace will show for it (the benchmark's expert-layer
+    readers find the grouped matmul by ``ragged-dot`` in that name)."""
+    _gmm_compiles(chip_compile, bank, tiling, rows)
+
+
+# a prefill tick of 1,024 + 8 rows, its routed copies padded to whole row
+# tiles: GLM-4.7-Flash's (top 4 of 64) and Keye-VL-2.0's (top 8 of 128)
+GMM_PREFILL = [
+    pytest.param((64, 2048, 1536), (128, 2048, 768), 4224, id="glm-gate-up"),
+    pytest.param((64, 1536, 2048), (128, 1536, 1024), 4224, id="glm-down"),
+    pytest.param((128, 2048, 768), (128, 2048, 768), 8320,
+                 id="keye-gate-up"),
+    pytest.param((128, 768, 2048), (128, 768, 2048), 8320, id="keye-down"),
+]
+
+
+@pytest.mark.parametrize("bank,tiling,rows", GMM_PREFILL)
+def test_grouped_matmul_kernel_at_the_prefill_ticks_of_the_cells(
+        chip_compile, bank, tiling, rows):
+    _gmm_compiles(chip_compile, bank, tiling, rows)
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.int8, jnp.float8_e4m3fn],
