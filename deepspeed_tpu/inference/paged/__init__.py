@@ -46,8 +46,9 @@ from deepspeed_tpu.inference.paged.block_select import (  # noqa: F401
 from deepspeed_tpu.inference.paged.conv_state import (  # noqa: F401
     ConvState, conv_layers, ragged_short_conv)
 from deepspeed_tpu.inference.paged.index_select import (  # noqa: F401
-    INDEX_KEY_PAGES, TOKENSEL_KV_PAGES, IndexSelect, token_select_counts,
-    token_selection, tokensel_update_and_attend)
+    INDEX_KEY_PAGES, TOKENSEL_KV_PAGES, IndexSelect, TickShape,
+    index_key_steps, token_select_counts, token_selection,
+    tokensel_update_and_attend)
 from deepspeed_tpu.inference.paged.kv_pages import (  # noqa: F401
     _RPA_QUERY_BLOCK, KVPages, kv_dequant_path, kv_lane_dim,
     kv_slots_per_layer, paged_attention_path, paged_update_and_attend,
@@ -102,11 +103,12 @@ class CacheKind:
                        for p in self.parts)
 
 
-def describe(cfg) -> CacheKind:
+def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
     """The cache of the model whose config is ``cfg``, read off the config's
     own keys (``total_ut_steps``, ``layer_types``, ``mixer_types``,
     ``selection``, ``sa_config``, ``kv_lora_rank``) by each kind's
-    predicate."""
+    predicate; ``tick``: the fused program's shape, for a kind that counts
+    a tick's work in the program's tiles."""
     of = int(cfg.num_hidden_layers)
     conv, linear = conv_layers(cfg), linear_layers(cfg)
     rows = of - conv - linear
@@ -122,7 +124,7 @@ def describe(cfg) -> CacheKind:
     if selection is not None:
         parts.append(BlockSelect(rows, of, selection))
     elif indexer is not None:
-        parts.append(IndexSelect(rows, of, indexer))
+        parts.append(IndexSelect(rows, of, indexer, tick))
     elif width:
         parts.append(Latent(rows, of, width))
     return CacheKind(tuple(parts), slots, conv + linear, rows, width,

@@ -17,7 +17,7 @@ Both leaves lead with the page dim."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +33,7 @@ TOKENSEL_KV_PAGES = "tokensel_kv_pages"
 INDEX_KEY_PAGES = "index_key_pages"
 # the scopes of the one-row path in a device trace: its scores, its choice,
 # its gather by token and attention (a chunk's are ``index``, ``select`` and
-# the three kernels' own names)
+# its two kernels' own names: ``index_select``, ``tokensel_chunk_attention``)
 INDEX_ROW_SCOPE, SELECT_ROW_SCOPE = "index_row", "select_row"
 ROW_SCOPE = "tokensel_row_attention"
 
@@ -42,6 +42,34 @@ def token_selection(cfg) -> Optional[tsa.Indexer]:
     """The indexer of a model whose attention layers choose their keys by
     token (its config's ``sa_config``), None for every other."""
     return getattr(cfg, "indexer", None)
+
+
+class TickShape(NamedTuple):
+    """The fused program as an engine builds it: its packed rows and
+    sequences, and a sequence's pages."""
+    rows: int
+    max_seqs: int
+    page_size: int
+    pages_per_seq: int
+
+
+def index_key_steps(first_pos: np.ndarray, q_lens: np.ndarray,
+                    tick: TickShape) -> Dict[str, int]:
+    """The key steps ``ops/token_select_attention.py:index_select`` spans
+    in a fused tick of sequences of ``q_lens`` rows from ``first_pos`` on
+    (``index_key_steps``: every tile the program holds by every step a
+    sequence's pages fill) and those it works on (``index_live_key_steps``:
+    a tile of a sequence of two rows or more, up to its last row's own
+    key)."""
+    keys = tsa.key_step(tick.page_size)
+    spanned = tsa.padded_keys(tick.pages_per_seq, tick.page_size) // keys
+    live = 0
+    for p0, n in zip(first_pos, q_lens):
+        if n >= 2:
+            ends = np.minimum(np.arange(tsa.TILE, n + tsa.TILE, tsa.TILE), n)
+            live += int(((int(p0) + ends - 1) // keys + 1).sum())
+    tiles = -(-tick.rows // tsa.TILE) + tick.max_seqs
+    return {"index_key_steps": tiles * spanned, "index_live_key_steps": live}
 
 
 def token_select_counts(positions: np.ndarray, topk: int,
@@ -94,9 +122,11 @@ def tokensel_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     program's rows take the one-row path (scores, ``lax.top_k``, the gather
     by token); every other batch is separated by ``cu_q_lens`` as
     :func:`ragged_linear_attention` separates it: sequences of two rows or
-    more take the three chunk kernels in tiles (scopes ``index`` and
-    ``select``), sequences of one row the one-row path (scopes
-    ``index_row``, ``select_row``, ``tokensel_row_attention``)."""
+    more take the two chunk kernels in tiles (``index_select``, which
+    scores and chooses, under the scopes ``index``, the queries' layout,
+    and ``select``; then ``tokensel_chunk_attention``), sequences of one
+    row the one-row path (scopes ``index_row``, ``select_row``,
+    ``tokensel_row_attention``)."""
     sa = token_selection(cfg)
     T, H, D = q.shape
     Hkv = k.shape[1]
@@ -163,14 +193,14 @@ def tokensel_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         # index head h of a tile's row i at h * TILE + i
         heads_first = lambda a: a.reshape(                    # noqa: E731
             (NT, tsa.TILE, HI) + a.shape[2:]).swapaxes(1, 2)
-        scores = tsa.index_scores(
-            heads_first(tiled(qI)).reshape(NT, HI * tsa.TILE, dI),
-            heads_first(tiled(w.astype(jnp.float32))).reshape(
-                NT, 1, HI * tsa.TILE),
-            index_pages, page_indices, *where)
+        qI_t = heads_first(tiled(qI)).reshape(NT, HI * tsa.TILE, dI)
+        w_t = heads_first(tiled(w.astype(jnp.float32))).reshape(
+            NT, 1, HI * tsa.TILE)
     with jax.named_scope("select"):
-        _, bias = tsa.select(scores, lay["pos0"], lay["len"],
-                             topk=sa.topk, page=page)
+        # scores, threshold and bias in ONE kernel (``index_select``); the
+        # scores never reach HBM
+        _, bias = tsa.index_select(qI_t, w_t, index_pages, page_indices,
+                                   *where, topk=sa.topk)
     y_t = tsa.chunk_attention(
         tiled(q).reshape(NT * tsa.TILE, Hkv, G, D).transpose(1, 2, 0, 3),
         pages, bias, *where, page_indices, sm_scale=sm_scale)
@@ -202,8 +232,10 @@ INDEX_REFUSES = {
 
 @dataclasses.dataclass(frozen=True)
 class IndexSelect(Part):
-    """Layers that attend the tokens ``indexer`` chooses."""
+    """Layers that attend the tokens ``indexer`` chooses; ``tick``: the
+    fused program's shape, where an engine has said it."""
     indexer: tsa.Indexer
+    tick: Optional[TickShape] = None
 
     def refusals(self, model):
         return INDEX_IS.format(model=model, p=self), INDEX_REFUSES
@@ -238,6 +270,11 @@ class IndexSelect(Part):
         if q_lens is not None:
             # the sequences that take the one-row path
             counts["one_row_seqs"] = int((q_lens == 1).sum())
+            if self.tick is not None:
+                first = np.cumsum(q_lens) - q_lens
+                counts.update(index_key_steps(
+                    np.asarray(positions).reshape(-1)[first], q_lens,
+                    self.tick))
         return counts
 
     def kernels(self, route):

@@ -130,7 +130,8 @@ from deepspeed_tpu.resilience import faults
 from deepspeed_tpu.telemetry import (RequestLatencyTracker, device_scopes,
                                      trace)
 from deepspeed_tpu.utils.async_stage import BoundedAsyncStage, StageTimers
-from deepspeed_tpu.inference.paged import (describe, kv_dequant_path,
+from deepspeed_tpu.inference.paged import (TickShape, describe,
+                                           kv_dequant_path,
                                            paged_attention_path, pages_for)
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
 from deepspeed_tpu.inference.sampling import position_keys, sample_logits
@@ -342,19 +343,22 @@ class RaggedInferenceEngineV2:
             "none" if o["kv_cache_dtype"] is None
             else str(o["kv_cache_dtype"]))
 
+        self.page_size = int(page_size)
+        self.pages_per_seq = pages_for(max_seq_len, self.page_size)
+
         # what the model's layers keep for a token (K/V pages, slots a pass,
         # per-slot state, a row of another shape): ONE description, built by
         # the files that own each kind (inference/paged/).  KVState moves
         # ``leaf[page]`` and ``leaf[slot]`` whatever a leaf is; each feature
         # that reads a leaf as something it is not is refused by name
-        self.cache_kind = describe(mcfg)
+        self.cache_kind = describe(mcfg, TickShape(
+            max_seqs + prefill_chunk, max_seqs, self.page_size,
+            self.pages_per_seq))
         self.kv_slots = self.cache_kind.kv_slots
         self.state_layers = self.cache_kind.state_layers
         self._refuse_features(
             o, kv_cache_dtype, self.cache_kind.refusals(type(model).__name__))
 
-        self.page_size = int(page_size)
-        self.pages_per_seq = pages_for(max_seq_len, self.page_size)
         if num_pages is None and kv_pool_bytes is not None:
             # byte-accounted sizing: probe the exact per-page device
             # cost (quantized pools count the 1-byte payload AND the

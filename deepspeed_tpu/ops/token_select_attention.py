@@ -34,16 +34,25 @@ The prompt chunks of a fused tick, rows in ``ops/linear_attention.py:
 tile_layout``'s tiles of :data:`TILE`, scores held transposed (keys down
 the sublanes, a tile's rows across the lanes):
 
-- :func:`index_scores` (kernel ``index_scores``): a tile's ``[HI * TILE,
-  dI]`` index queries against a key step of its sequence's index keys, one
-  product; relu, the head weights, the sum over heads down lane slices.
-- :func:`select` (kernel ``index_select``): the exact ``topk``-th largest a
-  row by bisection over the 32 bits, the tile's scores resident in VMEM
-  (32 counts of ``score >= candidate``), and the additive bias ``[keys,
-  TILE]`` bfloat16 the chosen keys leave at 0.  Where a row's threshold is
-  shared by more keys than it has room for, the lower positions win, as
-  ``lax.top_k`` has it: a second bisection, over the position's bits, in
-  the tiles that hold such a row (:func:`resolve_ties` is its twin).
+- :func:`index_select` (kernel ``index_select``): a grid step a tile, and in
+  it three loops over the tile's LIVE key steps (those up to its last row's
+  own key; a tile of no row makes none, fetches nothing and writes
+  nothing but its row of statistics).  First the scores: a key step's eight
+  pages of index keys, fetched through the page table a step ahead, against
+  the tile's ``[HI * TILE, dI]`` index queries, one product a lane block;
+  relu, the head weights, the sum over heads down lane slices, into a
+  ``[K, TILE]`` int32 scratch that never leaves VMEM.  Then the exact
+  ``topk``-th largest a row by bisection over the 32 bits (32 counts of
+  ``score >= candidate``); where a row's threshold is shared by more keys
+  than it has room for, the lower positions win, as ``lax.top_k`` has it:
+  a second bisection, over the position's bits, in the tiles that hold
+  such a row (:func:`resolve_ties` is its twin).  Last the additive bias
+  ``[keys, TILE]`` bfloat16 the chosen keys leave at 0, a block a step
+  copied out to HBM behind the next block's making.  (As two kernels the
+  scores crossed HBM twice as ``[NT, K, TILE]`` int32 and every tile and
+  key step was paid for, live or not: 1.40 ms a call where this takes 0.62
+  at eight live tiles of 24 key steps of seventeen and 65, PERF.md section
+  6, PR 49.)
 - :func:`chunk_attention` (kernel ``tokensel_chunk_attention``): the dense
   pass under that bias, with ``ops/block_select_attention.py``'s own key
   step, fold and output (``step_copies``, ``fold_head``, ``finish_tile``: a
@@ -51,7 +60,9 @@ the sublanes, a tile's rows across the lanes):
   last row, every query head of a KV head one product, scores transposed);
   what is this kernel's own is the pool's slices (a token a row) and the
   bias, a ``[keys, TILE]`` array a (row, key) where that one has a bit a
-  block.
+  block, fetched a key step at a time with the step's pages: a step past
+  a tile's last row's, which :func:`index_select` does not write, is
+  never fetched.
   With weights from a seed each row's choice is a random subset, so the
   union over a tile's rows is every key and nothing can be skipped: a
   masked dense pass is the honest first form.
@@ -66,10 +77,13 @@ page list would save nothing).  A Pallas form would issue one 2 KB copy a
 chosen token, two thousand descriptors a row a layer; XLA's gather brought
 nine rows' tokens in 0.39 ms on the chip and is the better tool.
 
-Each kernel has a ``jax.numpy`` twin taken on the CPU; which ran is
-recorded under :data:`KERNEL_INDEX` / :data:`KERNEL_SELECT` /
-:data:`KERNEL_PREFILL` / :data:`KERNEL_ROW_INDEX`, and the one-row path's
-gather under :data:`KERNEL_DECODE`.
+Each kernel has ``jax.numpy`` twins taken on the CPU (``index_select``
+three: :func:`index_scores_twin`, :func:`select_twin`,
+:func:`resolve_ties`, which are the definition it is tested against); which
+ran is recorded under :data:`KERNEL_INDEX` and :data:`KERNEL_SELECT` (the
+one kernel's two halves) / :data:`KERNEL_PREFILL` /
+:data:`KERNEL_ROW_INDEX`, and the one-row path's gather under
+:data:`KERNEL_DECODE`.
 """
 from __future__ import annotations
 
@@ -95,7 +109,6 @@ KERNEL_SELECT = KERNEL + "_threshold"
 KERNEL_PREFILL = KERNEL + "_prefill"
 KERNEL_DECODE = KERNEL + "_decode"
 KERNEL_ROW_INDEX = KERNEL + "_row_index"
-INDEX_NAME = "index_scores"
 ROW_INDEX_NAME = "row_index_scores"
 SELECT_NAME = "index_select"
 CHUNK_NAME = "tokensel_chunk_attention"
@@ -130,9 +143,14 @@ def sortable(x: jax.Array) -> jax.Array:
     return b ^ ((b >> 31) & 0x7FFFFFFF)
 
 
+def key_step(page: int) -> int:
+    """Keys a step of the chunk kernels holds: eight pages."""
+    return _STEP_PAGES * page
+
+
 def padded_keys(pages_per_seq: int, page: int) -> int:
     """Keys a tile's scores span: its sequence's pages in whole key steps."""
-    step = _STEP_PAGES * page
+    step = key_step(page)
     return -(-pages_per_seq * page // step) * step
 
 
@@ -368,12 +386,6 @@ def chunk_twin(q, pages, bias, tile_table, sm_scale: float):
 
 # -- the kernels --------------------------------------------------------------
 
-def _last_step(pos0, length, keys: int):
-    """The key step that holds a tile's last row's own key (0 for a tile
-    with no row: its blocks then do not move)."""
-    return jnp.maximum(pos0 + length - 1, 0) // keys
-
-
 def _packed_queries(q, pack: int):
     """``q [..., dI]`` -> ``[..., pack, <rows>, pack * dI]`` on a new axis
     before the last two: copy ``p`` holds ``q`` at lanes ``p * dI`` on and
@@ -385,118 +397,11 @@ def _packed_queries(q, pack: int):
                       for p in range(pack)], axis=-3)
 
 
-def _index_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
-                  q_ref, w_ref, *rest, heads: int, pack: int):
-    # q_ref: [1, pack, HI * TILE, W] (:func:`_packed_queries`); w_ref: [1, 1,
-    # HI * TILE] float32; then _STEP_PAGES pages of index keys [1, rows, W];
-    # o_ref: [1, keys, TILE] int32; kbuf: [_STEP_PAGES * rows, W]
-    pages = rest[:_STEP_PAGES]
-    o_ref, kbuf = rest[_STEP_PAGES:]
-    n, b = pl.program_id(0), pl.program_id(1)
-    pos0, length = pos0_ref[n], len_ref[n]
-    rows = pages[0].shape[1]
-    page = rows * pack
-    keys = _STEP_PAGES * page
-    dead = jnp.logical_or(length == 0, b * keys > pos0 + length - 1)
-
-    @pl.when(dead)
-    def _():
-        o_ref[...] = jnp.full_like(o_ref, _INT_MIN)
-
-    @pl.when(jnp.logical_not(dead))
-    def _():
-        for i in range(_STEP_PAGES):
-            kbuf[pl.ds(i * rows, rows), :] = pages[i][0]
-        for p in range(pack):
-            # the keys of lane block p: tokens p * rows on of each page
-            s = jax.lax.dot_general(
-                kbuf[...], q_ref[0, p], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [8 rows, HI * TILE]
-            s = jnp.maximum(s, 0.0) * w_ref[0]
-            acc = s[:, :TILE]
-            for h in range(1, heads):
-                acc = acc + s[:, h * TILE:(h + 1) * TILE]
-            acc = jnp.where(acc == 0, 0.0, acc)
-            bits = jax.lax.bitcast_convert_type(acc, jnp.int32)
-            r = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
-            c = b * keys + (r // rows) * page + p * rows + r % rows
-            i = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
-            seen = jnp.logical_and(c <= pos0 + i, i < length)
-            val = jnp.where(seen, bits ^ ((bits >> 31) & 0x7FFFFFFF),
-                            _INT_MIN)
-            for g in range(_STEP_PAGES):
-                o_ref[0, pl.ds(g * page + p * rows, rows), :] = val[
-                    g * rows:(g + 1) * rows]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _index(qI, w, index_pages, page_indices, tile_seq, tile_pos0, tile_len,
-           *, interpret: bool):
-    NT, qrows, dI = qI.shape
-    _, rows, width = index_pages.shape
-    pack = width // dI
-    page = rows * pack
-    pp = page_indices.shape[1]
-    heads = qrows // TILE
-    keys = _STEP_PAGES * page
-    K = padded_keys(pp, page)
-
-    def page_block(i):
-        def index(n, b, seq, pos0, length, table):
-            # a dead step fetches the tile's last live step's pages again
-            # (a block that does not move is not fetched); a page past the
-            # sequence's last holds no key a row sees
-            step = jnp.minimum(b, _last_step(pos0[n], length[n], keys))
-            at = jnp.minimum(step * _STEP_PAGES + i, pp - 1)
-            return jnp.maximum(table[seq[n] * pp + at], 0), 0, 0
-        return pl.BlockSpec((1, rows, width), index)
-
-    return pl.pallas_call(
-        functools.partial(_index_kernel, heads=heads, pack=pack),
-        out_shape=jax.ShapeDtypeStruct((NT, K, TILE), jnp.int32),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            in_specs=[pl.BlockSpec((1, pack, qrows, width),
-                                   lambda n, b, *_: (n, 0, 0, 0)),
-                      pl.BlockSpec((1, 1, qrows), lambda n, b, *_: (n, 0, 0))]
-            + [page_block(i) for i in range(_STEP_PAGES)],
-            out_specs=pl.BlockSpec((1, keys, TILE),
-                                   lambda n, b, *_: (n, b, 0)),
-            grid=(NT, K // keys),
-            scratch_shapes=[pltpu.VMEM((_STEP_PAGES * rows, width),
-                                       index_pages.dtype)]),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=32 * 2 ** 20),
-        interpret=interpret, name=INDEX_NAME,
-    )(tile_seq, tile_pos0, tile_len, page_indices.reshape(-1),
-      _packed_queries(qI, pack), w, *([index_pages] * _STEP_PAGES))
-
-
 def _index_eligible(qI, index_pages) -> bool:
     """Mosaic's layout: bf16 rows of 128 lanes, a page whole tiles of them."""
     _, rows, width = index_pages.shape
     return (qI.dtype == jnp.bfloat16 and index_pages.dtype == jnp.bfloat16
             and width == _LANES and rows % 16 == 0)
-
-
-def index_scores(qI, w, index_pages, page_indices, tile_seq, tile_pos0,
-                 tile_len, *, interpret: bool = False) -> jax.Array:
-    """A tick's tiled prompt rows' sortable index scores ``[NT, K, TILE]``
-    (:func:`index_scores_twin` says of what).  The Pallas kernel on a TPU,
-    else the twin; recorded under :data:`KERNEL_INDEX`."""
-    page = index_pages.shape[1] * index_pages.shape[2] // qI.shape[-1]
-    route = routes.take(
-        KERNEL_INDEX, eligible=_index_eligible(qI, index_pages),
-        interpret=interpret,
-        pallas=f"pallas-index-scores-{TILE}x{_STEP_PAGES * page}")
-    if route == routes.XLA:
-        return index_scores_twin(qI, w, index_pages, page_indices, tile_seq,
-                                 tile_pos0, tile_len)
-    return _index(qI, w.astype(jnp.float32), index_pages,
-                  page_indices.astype(jnp.int32),
-                  tile_seq.astype(jnp.int32), tile_pos0.astype(jnp.int32),
-                  tile_len.astype(jnp.int32), interpret=interpret)
 
 
 def _row_index_kernel(pos_ref, on_ref, table_ref,           # scalar prefetch
@@ -600,22 +505,76 @@ def row_index_scores(qI, w, index_pages, page_indices, pos, on, *,
                       on.astype(jnp.int32), interpret=interpret)
 
 
-def _select_kernel(pos0_ref, len_ref,                        # scalar prefetch
-                   s_ref, stats_ref, bias_ref, *, topk: int, keys: int):
-    # s_ref: [1, K, TILE] int32 (the tile's scores, whole); stats_ref: [1,
-    # 8, TILE] int32; bias_ref: [1, K, TILE] bfloat16
+def _live_tile(tile_len):
+    """``[NT]``: each tile's own number, a tile of no row the number of the
+    live tile before it (live tiles come first in ``tile_layout``): a block
+    indexed by it does not move, so a tile of no row fetches none."""
+    n = jnp.arange(tile_len.shape[0], dtype=jnp.int32)
+    return jax.lax.cummax(jnp.where(tile_len > 0, n, 0))
+
+
+def _select_kernel(src_ref, seq_ref, pos0_ref, len_ref, table_ref,  # prefetch
+                   q_ref, w_ref, pages_hbm, stats_ref, bias_hbm,
+                   s_sc, kbuf, ksems, bbuf, bsems, *,
+                   topk: int, heads: int, pack: int, pages_per_seq: int):
+    # q_ref: [1, pack, HI * TILE, W] (:func:`_packed_queries`); w_ref: [1, 1,
+    # HI * TILE] float32; pages_hbm: [P, rows, W] (the index pool, in HBM);
+    # stats_ref: [1, 8, TILE] int32; bias_hbm: [NT, K, TILE] bfloat16 (in
+    # HBM); s_sc: [K, TILE] int32 (the tile's scores, never out of VMEM);
+    # kbuf: [2, _STEP_PAGES, rows, W]; bbuf: [2, keys, TILE] bfloat16
     n = pl.program_id(0)
-    pos0, length = pos0_ref[n], len_ref[n]
-    steps_all = s_ref.shape[1] // keys
-    # a step past the tile's last row holds the least integer alone
-    steps = jnp.where(length == 0, 0,
-                      _last_step(pos0, length, keys) + 1)
+    seq, pos0, length = seq_ref[n], pos0_ref[n], len_ref[n]
+    rows, width = pages_hbm.shape[1], pages_hbm.shape[2]
+    page = rows * pack
+    keys = key_step(page)
+    # the steps up to the tile's last row's own key: one past them is never
+    # scored, searched or written
+    steps = (pos0 + length - 1) // keys + 1
+    row = jax.lax.broadcasted_iota(jnp.int32, (8, TILE), 0)
+
+    copies = functools.partial(step_copies, table_ref, pages_hbm, kbuf,
+                               ksems, seq, pages_per_seq)
+
+    def score(b, _):
+        slot = b % 2
+
+        @pl.when(b + 1 < steps)
+        def _():
+            for copy in copies(b + 1, 1 - slot):
+                copy.start()
+
+        for copy in copies(b, slot):
+            copy.wait()
+        k = kbuf[slot].reshape(_STEP_PAGES * rows, width)
+        at = pl.multiple_of(b * keys, keys)
+        for p in range(pack):
+            # the keys of lane block p: tokens p * rows on of each page
+            s = jax.lax.dot_general(
+                k, q_ref[0, p], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # [8 rows, HI * TILE]
+            s = jnp.maximum(s, 0.0) * w_ref[0]
+            acc = s[:, :TILE]
+            for h in range(1, heads):
+                acc = acc + s[:, h * TILE:(h + 1) * TILE]
+            acc = jnp.where(acc == 0, 0.0, acc)
+            bits = jax.lax.bitcast_convert_type(acc, jnp.int32)
+            r = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+            c = b * keys + (r // rows) * page + p * rows + r % rows
+            i = jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+            seen = jnp.logical_and(c <= pos0 + i, i < length)
+            val = jnp.where(seen, bits ^ ((bits >> 31) & 0x7FFFFFFF),
+                            _INT_MIN)
+            for g in range(_STEP_PAGES):
+                s_sc[pl.ds(at + g * page + p * rows, rows), :] = val[
+                    g * rows:(g + 1) * rows]
+
+    def block(j):
+        return s_sc[pl.ds(pl.multiple_of(j * keys, keys), keys), :]
 
     def count_ge(cand):
         """How many of a row's scores are at or above ``cand [1, TILE]``."""
         def step(j, acc):
-            blk = s_ref[0, pl.ds(pl.multiple_of(j * keys, keys), keys), :]
-            hit = jnp.where(blk >= cand, 1, 0).astype(jnp.int32)
+            hit = jnp.where(block(j) >= cand, 1, 0).astype(jnp.int32)
             return acc + jnp.sum(hit.reshape(keys // 8, 8, TILE), axis=0)
         acc = jax.lax.fori_loop(0, steps, step,
                                 jnp.zeros((8, TILE), jnp.int32))
@@ -628,108 +587,175 @@ def _select_kernel(pos0_ref, len_ref,                        # scalar prefetch
                          tau + jnp.left_shift(jnp.int32(1), 31 - i))
         return jnp.where(count_ge(cand) >= topk, cand, tau)
 
-    tau = jax.lax.fori_loop(0, 32, bit,
-                            jnp.full((1, TILE), _INT_MIN, jnp.int32))
-    tau = jnp.maximum(tau, _INT_MIN + 1)
-    held = count_ge(tau)
-    row = jax.lax.broadcasted_iota(jnp.int32, (8, TILE), 0)
-    stats_ref[0] = jnp.where(row == 0, tau, jnp.where(row == 1, held, 0))
+    def bias_copy(j, slot):
+        return pltpu.make_async_copy(
+            bbuf.at[slot],
+            bias_hbm.at[n, pl.ds(pl.multiple_of(j * keys, keys), keys)],
+            bsems.at[slot])
 
-    # a row whose threshold more keys hold than it has room for shares it
-    # out to the lower positions: the largest ``cut`` with no more than
-    # ``room`` of them before it, by bisection over the position's bits; a
-    # tile with no such row (nearly every one) makes no pass
-    room = topk - count_ge(tau + 1)
-    bits = max(int(s_ref.shape[1]).bit_length(), 1)
+    @pl.when(length == 0)
+    def _():
+        stats_ref[0] = jnp.where(row == 0, _INT_MIN + 1, 0)
 
-    def tied_before(cut):
-        def step(j, acc):
-            blk = s_ref[0, pl.ds(pl.multiple_of(j * keys, keys), keys), :]
+    @pl.when(length > 0)
+    def _():
+        for copy in copies(0, 0):
+            copy.start()
+        jax.lax.fori_loop(0, steps, score, None)
+
+        tau = jax.lax.fori_loop(0, 32, bit,
+                                jnp.full((1, TILE), _INT_MIN, jnp.int32))
+        tau = jnp.maximum(tau, _INT_MIN + 1)
+        held = count_ge(tau)
+        stats_ref[0] = jnp.where(row == 0, tau, jnp.where(row == 1, held, 0))
+
+        # a row whose threshold more keys hold than it has room for shares
+        # it out to the lower positions: the largest ``cut`` with no more
+        # than ``room`` of them before it, by bisection over the position's
+        # bits; a tile with no such row (nearly every one) makes no pass
+        room = topk - count_ge(tau + 1)
+        bits = max(int(s_sc.shape[0]).bit_length(), 1)
+
+        def tied_before(cut):
+            def step(j, acc):
+                blk = block(j)
+                c = j * keys + jax.lax.broadcasted_iota(jnp.int32, blk.shape,
+                                                        0)
+                hit = jnp.where(jnp.logical_and(blk == tau, c < cut), 1, 0)
+                return acc + jnp.sum(hit.astype(jnp.int32).reshape(
+                    keys // 8, 8, TILE), axis=0)
+            acc = jax.lax.fori_loop(0, steps, step,
+                                    jnp.zeros((8, TILE), jnp.int32))
+            return jnp.sum(acc, axis=0, keepdims=True)
+
+        def position_bit(i, cut):
+            cand = cut + jnp.left_shift(jnp.int32(1), bits - 1 - i)
+            return jnp.where(tied_before(cand) <= room, cand, cut)
+
+        shared = jnp.max(jnp.where(held > topk, 1, 0)) > 0
+        cut = jax.lax.fori_loop(
+            0, jnp.where(shared, bits, 0), position_bit,
+            jnp.where(shared, jnp.zeros_like(tau), jnp.int32(2 ** bits - 1)))
+
+        def write(j, _):
+            slot = j % 2
+            blk = block(j)
             c = j * keys + jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
-            hit = jnp.where(jnp.logical_and(blk == tau, c < cut), 1, 0)
-            return acc + jnp.sum(hit.astype(jnp.int32).reshape(
-                keys // 8, 8, TILE), axis=0)
-        acc = jax.lax.fori_loop(0, steps, step,
-                                jnp.zeros((8, TILE), jnp.int32))
-        return jnp.sum(acc, axis=0, keepdims=True)
+            hit = jnp.logical_or(blk > tau,
+                                 jnp.logical_and(blk == tau, c < cut))
 
-    def position_bit(i, cut):
-        cand = cut + jnp.left_shift(jnp.int32(1), bits - 1 - i)
-        return jnp.where(tied_before(cand) <= room, cand, cut)
+            @pl.when(j >= 2)
+            def _():
+                bias_copy(j - 2, slot).wait()
 
-    shared = jnp.max(jnp.where(held > topk, 1, 0)) > 0
-    cut = jax.lax.fori_loop(
-        0, jnp.where(shared, bits, 0), position_bit,
-        jnp.where(shared, jnp.zeros_like(tau), jnp.int32(2 ** bits - 1)))
+            bbuf[slot] = jnp.where(hit, 0.0, _MASK_VALUE).astype(bbuf.dtype)
+            bias_copy(j, slot).start()
 
-    def write(j, _):
-        at = pl.ds(pl.multiple_of(j * keys, keys), keys)
-        blk = s_ref[0, at, :]
-        c = j * keys + jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
-        hit = jnp.logical_or(blk > tau, jnp.logical_and(blk == tau, c < cut))
-        bias_ref[0, at, :] = jnp.where(
-            jnp.logical_and(hit, j < steps), 0.0, _MASK_VALUE).astype(
-                bias_ref.dtype)
+        jax.lax.fori_loop(0, steps, write, None)
 
-    jax.lax.fori_loop(0, steps_all, write, None)
+        @pl.when(steps >= 2)
+        def _():
+            bias_copy(steps - 2, steps % 2).wait()
+
+        bias_copy(steps - 1, (steps - 1) % 2).wait()
 
 
-def select_vmem_bytes(K: int) -> int:
-    """What a call of the selection kernel keeps in VMEM: a tile's scores
-    and its bias, two buffers each, and working room."""
-    return 2 * K * TILE * (4 + 2) + 8 * 2 ** 20
+def select_vmem_bytes(K: int, qrows: int, keys: int, pack: int) -> int:
+    """What a call of the selection kernel keeps in VMEM: a tile's scores;
+    its packed queries, two buffers; four results of a product (a key
+    step's rows against every index head of the tile) of working room; the
+    key steps and bias blocks in flight, the weights and the statistics
+    inside two MiB more."""
+    return (K * TILE * 4 + 2 * pack * qrows * _LANES * 2
+            + 4 * (keys // pack) * qrows * 4 + 2 * 2 ** 20)
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "keys", "interpret"))
-def _select(scores, tile_pos0, tile_len, *, topk: int, keys: int,
-            interpret: bool):
-    NT, K, _ = scores.shape
-    whole = lambda rows: pl.BlockSpec((1, rows, TILE),      # noqa: E731
-                                      lambda n, *_: (n, 0, 0))
+@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
+def _select(qI, w, index_pages, page_indices, tile_seq, tile_pos0, tile_len,
+            *, topk: int, interpret: bool):
+    NT, qrows, dI = qI.shape
+    _, rows, width = index_pages.shape
+    pack = width // dI
+    page = rows * pack
+    pp = page_indices.shape[1]
+    keys, K = key_step(page), padded_keys(pp, page)
+    # a tile of no row fetches the queries the tile before it fetched: none
+    mine = lambda n, src, *_: (src[n],) + (0,) * 3          # noqa: E731
     return pl.pallas_call(
-        functools.partial(_select_kernel, topk=topk, keys=keys),
+        functools.partial(_select_kernel, topk=topk, heads=qrows // TILE,
+                          pack=pack, pages_per_seq=pp),
         out_shape=(jax.ShapeDtypeStruct((NT, 8, TILE), jnp.int32),
                    jax.ShapeDtypeStruct((NT, K, TILE), jnp.bfloat16)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, in_specs=[whole(K)],
-            out_specs=(whole(8), whole(K)), grid=(NT,)),
+            num_scalar_prefetch=5,
+            in_specs=[pl.BlockSpec((1, pack, qrows, width), mine),
+                      pl.BlockSpec((1, 1, qrows),
+                                   lambda n, src, *_: (src[n], 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=(pl.BlockSpec((1, 8, TILE), lambda n, *_: (n, 0, 0)),
+                       pl.BlockSpec(memory_space=pl.ANY)),
+            grid=(NT,),
+            scratch_shapes=[
+                pltpu.VMEM((K, TILE), jnp.int32),
+                pltpu.VMEM((2, _STEP_PAGES, rows, width), index_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, keys, TILE), jnp.bfloat16),
+                pltpu.SemaphoreType.DMA((2,))]),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=select_vmem_bytes(K)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=select_vmem_bytes(K, qrows, keys, pack)),
         interpret=interpret, name=SELECT_NAME,
-    )(tile_pos0, tile_len, scores)
+    )(_live_tile(tile_len), tile_seq, tile_pos0, tile_len,
+      page_indices.reshape(-1), _packed_queries(qI, pack), w, index_pages)
 
 
-def select(scores, tile_pos0, tile_len, *, topk: int, page: int,
-           interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """Every tiled row's threshold (:func:`select_twin`'s ``stats``) and the
-    bias its choice leaves, a shared threshold given to the lower positions
-    (:func:`resolve_ties`); ``page``: of the pool the scores span (a key
-    step is eight).  The Pallas kernel on a TPU, else the two twins;
-    recorded under :data:`KERNEL_SELECT`."""
-    keys = _STEP_PAGES * page
+def index_select(qI, w, index_pages, page_indices, tile_seq, tile_pos0,
+                 tile_len, *, topk: int, interpret: bool = False
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """A tick's tiled prompt rows' choice: every row's threshold
+    (:func:`select_twin`'s ``stats [NT, 8, TILE]``) and the bias ``[NT, K,
+    TILE]`` bfloat16 its choice leaves over its sequence's keys
+    (:func:`index_scores_twin` says of what scores, ``qI``, ``w`` and the
+    tiles as there), a shared threshold given to the lower positions
+    (:func:`resolve_ties`).  ONE Pallas kernel on a TPU, the scores never
+    out of VMEM, and it writes the bias of a tile's LIVE key steps alone:
+    what lies past a tile's last row, and the whole of a tile of no row, is
+    whatever the buffer held (nothing reads it: :func:`chunk_attention`
+    stops at the same step).  Else the three twins.  Recorded under
+    :data:`KERNEL_INDEX` and :data:`KERNEL_SELECT`: what the one kernel
+    does of each."""
+    page = index_pages.shape[1] * index_pages.shape[2] // qI.shape[-1]
+    ok = dict(eligible=_index_eligible(qI, index_pages), interpret=interpret)
     route = routes.take(
-        KERNEL_SELECT, eligible=scores.shape[1] % keys == 0
-        and page % 16 == 0, interpret=interpret,
-        pallas=f"pallas-index-select-{TILE}-bisect32")
+        KERNEL_INDEX, pallas=f"pallas-index-scores-{TILE}x{key_step(page)}",
+        **ok)
+    routes.take(KERNEL_SELECT, pallas=f"pallas-index-select-{TILE}-bisect32",
+                **ok)
     if route == routes.XLA:
+        scores = index_scores_twin(qI, w, index_pages, page_indices,
+                                   tile_seq, tile_pos0, tile_len)
         stats, bias = select_twin(scores, topk)
         return stats, resolve_ties(scores, stats, bias, topk)
-    return _select(scores, tile_pos0.astype(jnp.int32),
-                   tile_len.astype(jnp.int32), topk=int(topk), keys=keys,
+    return _select(qI, w.astype(jnp.float32), index_pages,
+                   page_indices.astype(jnp.int32),
+                   tile_seq.astype(jnp.int32), tile_pos0.astype(jnp.int32),
+                   tile_len.astype(jnp.int32), topk=int(topk),
                    interpret=interpret)
 
 
 def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
-                  q_ref, bias_ref, pages_hbm, o_ref,
-                  buf, sems, vt_sc, m_sc, l_sc, acc_sc, *,
+                  q_ref, bias_hbm, pages_hbm, o_ref,
+                  buf, sems, bbuf, bsems, vt_sc, m_sc, l_sc, acc_sc, *,
                   sm_scale: float, pages_per_seq: int):
-    # q_ref, o_ref: [Hkv, G, TILE, D] (one tile); bias_ref: [1, K, TILE]
-    # bfloat16; pages_hbm: [P, page, 2 Hkv D]; buf: [2, _STEP_PAGES, page,
-    # 2 Hkv D]; vt_sc: [Hkv, D, keys]; m_sc, l_sc: [Hkv, 1, R]; acc_sc:
-    # [Hkv, D, R] with R = G * TILE (query head g's i-th row at lane ``g *
-    # TILE + i``).  Scores, weights and the accumulator are held TRANSPOSED
-    # (keys down, the tile's rows x heads across)
+    # q_ref, o_ref: [Hkv, G, TILE, D] (one tile); bias_hbm: [NT, K, TILE]
+    # bfloat16 (in HBM: a key step's block comes with the step's pages, so
+    # what lies past a tile's last step, and a tile of no row, is never
+    # fetched); pages_hbm: [P, page, 2 Hkv D]; buf: [2, _STEP_PAGES, page,
+    # 2 Hkv D]; bbuf: [2, keys, TILE] bfloat16; vt_sc: [Hkv, D, keys];
+    # m_sc, l_sc: [Hkv, 1, R]; acc_sc: [Hkv, D, R] with R = G * TILE (query
+    # head g's i-th row at lane ``g * TILE + i``).  Scores, weights and
+    # the accumulator are held TRANSPOSED (keys down, the tile's rows x
+    # heads across)
     Hkv, G, _, D = q_ref.shape
     page = pages_hbm.shape[1]
     keys = _STEP_PAGES * page
@@ -738,8 +764,12 @@ def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
     R = G * TILE
     last = pos0 + length - 1
 
-    copies = functools.partial(step_copies, table_ref, pages_hbm, buf, sems,
-                               seq, pages_per_seq)
+    def copies(b, slot):
+        yield from step_copies(table_ref, pages_hbm, buf, sems, seq,
+                               pages_per_seq, b, slot)
+        yield pltpu.make_async_copy(
+            bias_hbm.at[n, pl.ds(pl.multiple_of(b * keys, keys), keys)],
+            bbuf.at[slot], bsems.at[slot])
 
     def one_step(b, _):
         slot = b % 2
@@ -755,8 +785,7 @@ def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
         # weigh exp(_MASK_VALUE - m) = 0 once a row has met a key it
         # attends; what a row gathered before that is scaled by the same 0
         # then, and every live row attends itself
-        bias = bias_ref[0, pl.ds(pl.multiple_of(b * keys, keys), keys), :
-                        ].astype(jnp.float32)                  # [keys, TILE]
+        bias = bbuf[slot].astype(jnp.float32)                  # [keys, TILE]
         bias = jnp.concatenate([bias] * G, axis=1)
         for h in range(Hkv):
             vt = buf[slot, :, :, pl.ds((Hkv + h) * D, D)].reshape(
@@ -781,15 +810,15 @@ def _chunk_kernel(seq_ref, pos0_ref, len_ref, table_ref,    # scalar prefetch
         finish_tile(o_ref, m_sc, l_sc, acc_sc, length, _MASK_VALUE)
 
 
-def chunk_vmem_bytes(Hkv: int, G: int, D: int, page: int, K: int) -> int:
-    """What a call of the chunk kernel keeps in VMEM: a tile of q, of the
-    output and of the bias, two buffers each; two key steps as fetched and
-    V of one transposed; the accumulator of every head; and seven score
-    tiles (a key step against every query head of a KV head) of working
-    room."""
+def chunk_vmem_bytes(Hkv: int, G: int, D: int, page: int) -> int:
+    """What a call of the chunk kernel keeps in VMEM: a tile of q and of
+    the output, two buffers each; two key steps as fetched, with their
+    blocks of the bias, and V of one transposed; the accumulator of every
+    head; and seven score tiles (a key step against every query head of a
+    KV head) of working room."""
     keys, R = _STEP_PAGES * page, G * TILE
     tile = Hkv * R * D * 2
-    return (2 * 2 * tile + 2 * K * TILE * 2
+    return (2 * 2 * tile + 2 * keys * TILE * 2
             + 2 * keys * 2 * Hkv * D * 2 + Hkv * D * keys * 2
             + Hkv * D * R * 4 + 8 * keys * R * 4)
 
@@ -813,13 +842,14 @@ def _chunk(q, pages, bias, tile_seq, tile_pos0, tile_len, page_indices, *,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            in_specs=[tile,
-                      pl.BlockSpec((1, K, TILE), lambda n, *_: (n, 0, 0)),
+            in_specs=[tile, pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=tile,
             grid=(NT,),
             scratch_shapes=[
                 pltpu.VMEM((2, _STEP_PAGES, page, width), pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((2, keys, TILE), bias.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((Hkv, D, keys), pages.dtype),
                 pltpu.VMEM((Hkv, 1, R), jnp.float32),
@@ -827,7 +857,7 @@ def _chunk(q, pages, bias, tile_seq, tile_pos0, tile_len, page_indices, *,
                 pltpu.VMEM((Hkv, D, R), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=chunk_vmem_bytes(Hkv, G, D, page, K)),
+            vmem_limit_bytes=chunk_vmem_bytes(Hkv, G, D, page)),
         interpret=interpret, name=CHUNK_NAME,
     )(tile_seq, tile_pos0, tile_len, page_indices.reshape(-1), q, bias,
       pages)
@@ -848,8 +878,11 @@ def chunk_attention(q, pages, bias, tile_seq, tile_pos0, tile_len,
     and zeros come back) of sequence ``tile_seq[n]`` from position
     ``tile_pos0[n]`` on; ``bias [NT, padded_keys(pp, page), TILE]``
     bfloat16, 0 where the row across attends the key down (which is never
-    one after it) and :data:`_MASK_VALUE` elsewhere.  The Pallas kernel on
-    a TPU, else the twin; recorded under :data:`KERNEL_PREFILL`."""
+    one after it) and :data:`_MASK_VALUE` elsewhere; of a tile's bias only
+    the key steps up to its last row's are read, and of a tile of no row
+    nothing (:func:`index_select` writes no more).  The Pallas kernel on a
+    TPU, else the twin (which reads every step: give it a whole bias);
+    recorded under :data:`KERNEL_PREFILL`."""
     page, D = pages.shape[1], q.shape[-1]
     route = routes.take(
         KERNEL_PREFILL, eligible=eligible(D, page, pages.dtype)

@@ -64,6 +64,7 @@ PAD128 = {k: "xla-dense-pad128" for k in PLAIN}
 GROUPED = {"moe_grouped_matmul": "ragged-dot-xla",
            "moe_grouped_matmul_kernel": "ragged-dot"}
 ANY = type("Any", (), {"__eq__": lambda self, other: True})()
+KEY_STEPS = dict(index_key_steps=3, index_live_key_steps=1)
 
 # ``numbers``: (kv_slots, state_layers, kv_layers, latent_width, selection);
 # ``attrs``: on every dispatch span; ``asks``: of each of the six dispatches
@@ -140,6 +141,9 @@ EXPECTED = {
                            "init_blocks": 1, "window_size": 32, "topk": 5,
                            "dense_len": 64}}),
     # topk 24: the first tick's last ten rows choose, every later row does
+    # (PR 49: a fused tick also says how many key steps of its program's
+    # tiles the index kernel works on: 34 rows are 1 + 2 tiles of one step
+    # of 128 keys each, and the one chunk's tile is live in its one step)
     "tinykeye": dict(
         numbers=(1, 0, 2, 0, None),
         attrs={"index_bytes_per_token": 128,
@@ -148,17 +152,17 @@ EXPECTED = {
                    index_prefill_tokens=595, index_decode_tokens=0,
                    sel_rows=10, sel_key_tokens=240, sel_visible_tokens=295,
                    attend_prefill_tokens=540, attend_decode_tokens=0,
-                   one_row_seqs=0),
+                   one_row_seqs=0, **KEY_STEPS),
               dict(index_rows=34, index_visible_tokens=1751,
                    index_prefill_tokens=1751, index_decode_tokens=0,
                    sel_rows=34, sel_key_tokens=816, sel_visible_tokens=1751,
                    attend_prefill_tokens=816, attend_decode_tokens=0,
-                   one_row_seqs=0),
+                   one_row_seqs=0, **KEY_STEPS),
               dict(index_rows=22, index_visible_tokens=1749,
                    index_prefill_tokens=1749, index_decode_tokens=0,
                    sel_rows=22, sel_key_tokens=528, sel_visible_tokens=1749,
                    attend_prefill_tokens=528, attend_decode_tokens=0,
-                   one_row_seqs=0)]
+                   one_row_seqs=0, **KEY_STEPS)]
         + [dict(index_rows=2, index_visible_tokens=seen,
                 index_prefill_tokens=0, index_decode_tokens=seen, sel_rows=2,
                 sel_key_tokens=48, sel_visible_tokens=seen,
@@ -373,6 +377,28 @@ def test_the_paged_cache_imports_nothing_that_stands_on_it(path):
     if path.name == "allocator.py":
         assert not any(m.split(".")[0] == "jax" for m in _imports(path))
     assert len(path.read_text().splitlines()) <= 500
+
+
+@pytest.mark.parametrize("first_pos,q_lens,live", [
+    # one chunk of 1,024 rows that ends at 12,287: four tiles end in key
+    # step 22, four in 23
+    ([11264], [1024], 4 * 23 + 4 * 24),
+    # a chunk that straddles two sequences among three decoding ones: five
+    # tiles near 30k (59 + 4 x 60 steps), four in key step 0
+    ([5000, 70, 30000, 0, 9], [1, 1, 600, 424, 1], 299 + 4),
+    # decoding sequences alone: no tile holds a row
+    ([5000, 70, 9], [1, 1, 1], 0)],
+    ids=["one-chunk-at-12k", "straddles-two-sequences", "no-chunk"])
+def test_index_key_steps_are_the_live_tiles_up_to_their_last_row(
+        first_pos, q_lens, live):
+    """``keye-longdoc32k-closed``'s fused program: 1,032 rows are 9 + 8
+    tiles of 65 key steps of 512 keys; a tile is live up to the step of its
+    last row's own key."""
+    tick = paged.TickShape(rows=1032, max_seqs=8, page_size=64,
+                           pages_per_seq=513)
+    got = paged.index_key_steps(np.asarray(first_pos), np.asarray(q_lens),
+                                tick)
+    assert got == {"index_key_steps": 17 * 65, "index_live_key_steps": live}
 
 
 def test_the_engine_names_no_cache_kind():

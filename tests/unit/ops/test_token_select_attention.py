@@ -28,11 +28,12 @@ def pack_index_keys(keys):
     return jnp.asarray(out, keys.dtype)
 
 
-def _pool(seed=0):
-    """Three sequences of 300 / 130 / 7 cached tokens on shuffled pages."""
+def _pool(seed=0, lens=(300, 130, 7), PP=PP, P=P):
+    """Three sequences of 300 / 130 / 7 cached tokens on shuffled pages (or
+    those of ``lens``, of ``PP`` pages at most, in a pool of ``P``)."""
     rng = np.random.default_rng(seed)
-    lens = np.asarray([300, 130, 7])
-    table = np.full((S, PP), -1, np.int32)
+    lens = np.asarray(lens)
+    table = np.full((len(lens), PP), -1, np.int32)
     free = rng.permutation(np.arange(1, P))
     at = 0
     for s, n in enumerate(lens):
@@ -127,54 +128,98 @@ def test_the_index_pool_packs_whole_rows_and_is_written_where_it_is_read(
                                              ).reshape(-1, dI))
 
 
+def _twins(qI, w, ik, table, seq, pos0, length, topk=TOPK):
+    """The definition the fused kernel is held to: the three twins in
+    their order -> ``(scores, stats, bias)``."""
+    scores = tsa.index_scores_twin(qI, w, ik, table, seq, pos0, length)
+    stats, bias = tsa.select_twin(scores, topk)
+    return scores, stats, tsa.resolve_ties(scores, stats, bias, topk)
+
+
+def _live_keys(pos0, length, page=PAGE):
+    """Keys of each tile the fused kernel writes a bias for: its key steps
+    up to its last row's own key; none in a tile of no row."""
+    step = tsa.key_step(page)
+    last = np.asarray(pos0) + np.asarray(length) - 1
+    return np.where(np.asarray(length) > 0, (last // step + 1) * step, 0)
+
+
+def _poisoned(bias, pos0, length):
+    """``bias`` with NaN wherever the fused kernel writes nothing."""
+    c = np.arange(bias.shape[1])[None, :, None]
+    return jnp.where(c < _live_keys(pos0, length)[:, None, None], bias,
+                     jnp.nan)
+
+
+def _same_where_written(bias, bias_t, pos0, length):
+    for n, live in enumerate(_live_keys(pos0, length)):
+        np.testing.assert_array_equal(
+            np.asarray(bias[n, :live], np.float32),
+            np.asarray(bias_t[n, :live], np.float32))
+
+
+_back = lambda a: np.asarray(jax.lax.bitcast_convert_type(    # noqa: E731
+    jnp.where(a < 0, a ^ 0x7FFFFFFF, a), jnp.float32))
+
+
 def test_index_scores_kernel_is_its_twin_and_the_definition():
+    """The one kernel's scores, read back through what it leaves: a row's
+    threshold is its ``TOPK``-th largest score and its bias opens the keys
+    that score at or above it."""
     lens, table, _, ik = _pool()
     seq, pos0, length = _tiles(lens)
     qI, w, _ = _queries(0, 5)
-    twin = tsa.index_scores_twin(qI, w, ik, table, seq, pos0, length)
-    got = tsa.index_scores(qI, w, ik, table, seq, pos0, length,
-                           interpret=True)
-    assert got.shape == (5, K, TILE) and got.dtype == jnp.int32
+    twin, stats_t, bias_t = _twins(qI, w, ik, table, seq, pos0, length)
+    stats, bias = tsa.index_select(qI, w, ik, table, seq, pos0, length,
+                                   topk=TOPK, interpret=True)
+    assert bias.shape == (5, K, TILE) and bias.dtype == jnp.bfloat16
+    assert stats.shape == (5, 8, TILE) and stats.dtype == jnp.int32
     # the MXU's accumulation order is not the einsum's: compare as floats
-    back = lambda a: np.asarray(jax.lax.bitcast_convert_type(  # noqa: E731
-        jnp.where(a < 0, a ^ 0x7FFFFFFF, a), jnp.float32))
-    seen = np.asarray(twin) != np.iinfo(np.int32).min
-    np.testing.assert_array_equal(np.asarray(got) != np.iinfo(np.int32).min,
-                                  seen)
-    np.testing.assert_allclose(back(got)[seen], back(twin)[seen], rtol=1e-5,
+    rows = np.arange(TILE)[None] < np.asarray(length)[:, None]
+    np.testing.assert_allclose(_back(stats[:, 0])[rows],
+                               _back(stats_t[:, 0])[rows], rtol=1e-5,
                                atol=1e-5)
+    _same_where_written(bias, bias_t, pos0, length)
     # the definition, for one row: tile 1, row 5 is position 233 of seq 0
     keys = np.asarray(tsa.sequence_keys(ik, table, DI), np.float32)[0]
     qr = np.asarray(qI, np.float32)[1].reshape(HI, TILE, DI)[:, 5]
     wr = np.asarray(w)[1, 0].reshape(HI, TILE)[:, 5]
     want = (np.maximum(qr @ keys.T, 0) * wr[:, None]).sum(0)
-    np.testing.assert_allclose(back(twin)[1, :234, 5], want[:234],
+    np.testing.assert_allclose(_back(twin)[1, :234, 5], want[:234],
                                rtol=1e-4, atol=1e-4)
-    assert not seen[1, 234:, 5].any() and not seen[1, :, 72:].any()
-    assert not seen[4].any()
+    best = np.sort(want[:234])[::-1]
+    np.testing.assert_allclose(_back(stats[1, 0])[5], best[TOPK - 1],
+                               rtol=1e-4, atol=1e-4)
+    assert best[TOPK - 1] - best[TOPK] > 1e-3     # no near tie to blur it
+    np.testing.assert_array_equal(
+        (np.asarray(bias, np.float32)[1, :, 5] == 0).nonzero()[0],
+        np.sort(np.argsort(-want[:234], kind="stable")[:TOPK]))
+    # a key after the row's own, a row past the tile's length, a tile of no
+    # row: never chosen (the last writes no bias at all)
+    chosen = np.asarray(_poisoned(bias, pos0, length), np.float32) == 0
+    assert not chosen[1, 234:, 5].any() and not chosen[1, :, 72:].any()
+    assert not chosen[4].any()
+    np.testing.assert_array_equal(np.asarray(stats[4, :2]),
+                                  np.asarray(stats_t[4, :2]))
 
 
 def test_select_kernel_finds_the_exact_threshold():
     lens, table, _, ik = _pool(1)
     seq, pos0, length = _tiles(lens)
     qI, w, _ = _queries(1, 5)
-    scores = tsa.index_scores_twin(qI, w, ik, table, seq, pos0, length)
-    stats_t, bias_t = tsa.select_twin(scores, TOPK)
-    stats, bias = tsa.select(scores, pos0, length, topk=TOPK, page=PAGE,
-                             interpret=True)
+    scores, stats_t, bias_t = _twins(qI, w, ik, table, seq, pos0, length)
+    stats, bias = tsa.index_select(qI, w, ik, table, seq, pos0, length,
+                                   topk=TOPK, interpret=True)
     np.testing.assert_array_equal(np.asarray(stats[:, :2]),
                                   np.asarray(stats_t[:, :2]))
     # with four index heads a sixteenth of the scores are exactly 0 (every
     # relu shut), so some thresholds are shared: the lower positions win,
     # in the kernel as in the twin's second half
     assert (np.asarray(stats)[:, 1] > TOPK).any()
-    assert (np.asarray(bias_t, np.float32) == 0).sum() > (
-        np.asarray(bias, np.float32) == 0).sum()
-    np.testing.assert_array_equal(
-        np.asarray(bias, np.float32),
-        np.asarray(tsa.resolve_ties(scores, stats_t, bias_t, TOPK),
-                   np.float32))
-    chosen = np.asarray(bias, np.float32) == 0
+    assert (np.asarray(tsa.select_twin(scores, TOPK)[1], np.float32)
+            == 0).sum() > (np.asarray(bias_t, np.float32) == 0).sum()
+    _same_where_written(bias, bias_t, pos0, length)
+    chosen = np.asarray(_poisoned(bias, pos0, length), np.float32) == 0
     # a row past topk chooses exactly topk keys, an earlier one all it sees
     count = chosen.sum(axis=1)
     t = np.asarray(pos0)[:, None] + np.arange(TILE)[None]
@@ -183,26 +228,131 @@ def test_select_kernel_finds_the_exact_threshold():
         count, np.where(live, np.minimum(t + 1, TOPK), 0))
 
 
+# name: (cached tokens a sequence, pages a sequence, tiles as (seq, pos0,
+# len)); ``zero_q``: index queries of zeros, so every visible score is 0
+FUSED_CASES = {
+    # the last row's own key lies in key step 0 (a step is 128 keys here)
+    "last_row_in_key_step_0": dict(
+        lens=(300, 130, 7), PP=PP, tiles=[(1, 0, 100), (2, 0, 7), (2, 0, 0)]),
+    # a chunk of 1,020 rows: the last 520 of one sequence, the first 500 of
+    # the next, in nine live tiles of twelve
+    "chunk_straddles_two_sequences": dict(
+        lens=(640, 500), PP=40,
+        tiles=[(0, 120 + 128 * i, 128) for i in range(4)] + [(0, 632, 8)]
+        + [(1, 128 * i, 128) for i in range(3)] + [(1, 384, 116)]
+        + [(1, 0, 0)] * 3),
+    "no_dead_tile": dict(
+        lens=(300, 130, 7), PP=PP, tiles=[(0, 100, 128), (0, 228, 72),
+                                          (1, 2, 128)]),
+    "every_tile_dead_but_one": dict(
+        lens=(300, 130, 7), PP=PP, tiles=[(0, 172, 128)] + [(0, 0, 0)] * 4),
+    # every visible score equal: the first topk positions win
+    "threshold_shared_by_every_key": dict(
+        lens=(300, 130, 7), PP=PP, zero_q=True,
+        tiles=[(0, 40, 3), (1, 2, 128), (1, 0, 0)]),
+    # t + 1 <= topk: every visible key is chosen
+    "every_visible_key_chosen": dict(
+        lens=(300, 130, 7), PP=PP, tiles=[(1, 0, TOPK), (2, 0, 7)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FUSED_CASES))
+def test_fused_kernel_is_the_three_twins(case):
+    """``index_select`` under the interpreter against ``index_scores_twin``,
+    ``select_twin`` and ``resolve_ties`` in that order: the same statistics
+    and, wherever the kernel writes, the same bias bit for bit."""
+    c = FUSED_CASES[case]
+    pool = 1 + len(c["lens"]) * c["PP"]
+    lens, table, _, ik = _pool(6, c["lens"], c["PP"], pool)
+    seq, pos0, length = (jnp.asarray(a, jnp.int32) for a in zip(*c["tiles"]))
+    NT = len(c["tiles"])
+    qI, w, _ = _queries(6, NT)
+    if c.get("zero_q"):
+        qI = jnp.zeros_like(qI)
+    scores, stats_t, bias_t = _twins(qI, w, ik, table, seq, pos0, length)
+    stats, bias = tsa.index_select(qI, w, ik, table, seq, pos0, length,
+                                   topk=TOPK, interpret=True)
+    assert bias.shape == (NT, tsa.padded_keys(c["PP"], PAGE), TILE)
+    np.testing.assert_array_equal(np.asarray(stats[:, :2]),
+                                  np.asarray(stats_t[:, :2]))
+    _same_where_written(bias, bias_t, pos0, length)
+    # and what it does not write, no row of the twin's bias opens
+    c_ = np.arange(bias.shape[1])[None, :, None]
+    assert not np.logical_and(
+        c_ >= _live_keys(pos0, length)[:, None, None],
+        np.asarray(bias_t, np.float32) == 0).any()
+    chosen = np.asarray(_poisoned(bias, pos0, length), np.float32) == 0
+    t = np.asarray(pos0)[:, None] + np.arange(TILE)[None]
+    live = np.arange(TILE)[None] < np.asarray(length)[:, None]
+    np.testing.assert_array_equal(
+        chosen.sum(axis=1), np.where(live, np.minimum(t + 1, TOPK), 0))
+    if case == "last_row_in_key_step_0":
+        assert (_live_keys(pos0, length) == [128, 128, 0]).all()
+    if case == "chunk_straddles_two_sequences":
+        assert (np.asarray(length) > 0).sum() == 9
+        assert int(np.asarray(length).sum()) == 1020
+    if case == "threshold_shared_by_every_key":
+        assert np.asarray(stats)[0, 1, :3].tolist() == [41, 42, 43]
+        for n, i in ((0, 0), (0, 2), (1, 127)):
+            assert chosen[n, :, i].nonzero()[0].tolist() == list(range(TOPK))
+    if case == "every_visible_key_chosen":
+        for i in (0, 5, TOPK - 1):
+            assert chosen[0, :, i].nonzero()[0].tolist() == list(range(i + 1))
+
+
 def test_ties_go_to_the_lower_position():
     # every visible score equal: the first topk positions win
+    lens, table, _, ik = _pool()
+    seq = jnp.asarray([0], jnp.int32)
     pos0 = jnp.asarray([40], jnp.int32)
     length = jnp.asarray([3], jnp.int32)
     seen = tsa._visible(K, pos0, length)
     scores = jnp.where(seen, tsa.sortable(jnp.float32(1.5)), tsa._INT_MIN)
+    qI, w, _ = _queries(0, 1)
+    # index queries of zeros score every key 0: as equal as 1.5 is
     for fn in (lambda: tsa.select_twin(scores, TOPK),
-               lambda: tsa.select(scores, pos0, length, topk=TOPK,
-                                  page=PAGE, interpret=True)):
+               lambda: tsa.index_select(jnp.zeros_like(qI), w, ik, table, seq,
+                                        pos0, length, topk=TOPK,
+                                        interpret=True)):
         stats, bias = fn()
         assert np.asarray(stats)[0, 1, :3].tolist() == [41, 42, 43]
         if (np.asarray(bias, np.float32) == 0).sum() > 3 * TOPK:
             bias = tsa.resolve_ties(scores, stats, bias, TOPK)  # the twin's
-        fixed = np.asarray(bias, np.float32) == 0
+        fixed = np.asarray(_poisoned(bias, pos0, length), np.float32) == 0
         for i in range(3):
             assert fixed[0, :, i].nonzero()[0].tolist() == list(range(TOPK))
         assert not fixed[0, :, 3:].any()
     # and token_mask agrees, on plain floats
     m = tsa.token_mask(jnp.ones((1, 50)), jnp.arange(50)[None] <= 42, TOPK)
     assert np.asarray(m)[0].nonzero()[0].tolist() == list(range(TOPK))
+
+
+def test_chunk_kernel_reads_no_bias_the_fused_kernel_did_not_write():
+    """The poison test: NaN in every key step past a tile's last row's and
+    in the whole of a tile of no row, which is where ``index_select``
+    writes nothing; the chunk kernel answers as on the clean bias."""
+    lens, table, kv, ik = _pool(7)
+    seq, pos0, length = _tiles(lens)
+    qI, w, q = _queries(7, 5)
+    _, _, clean = _twins(qI, w, ik, table, seq, pos0, length)
+    _, bias = tsa.index_select(qI, w, ik, table, seq, pos0, length,
+                               topk=TOPK, interpret=True)
+    poisoned = _poisoned(bias, pos0, length)
+    written = np.isfinite(np.asarray(poisoned, np.float32))
+    assert not written[4].any() and not written[2, 128:].any()
+    assert written[1, :384].all()
+    sm = float(1 / np.sqrt(D))
+    got = tsa.chunk_attention(q, kv, poisoned, seq, pos0, length, table,
+                              sm_scale=sm, interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(tsa.chunk_attention(q, kv, clean, seq, pos0, length, table,
+                                       sm_scale=sm, interpret=True),
+                   np.float32))
+    twin = tsa.chunk_twin(q, kv, clean, jnp.take(table, seq, axis=0), sm)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(twin, np.float32), atol=2e-2)
 
 
 @pytest.mark.parametrize("seed", [2, 3])

@@ -608,12 +608,14 @@ KEYE = dict(S=8, pp=513, pool=4160, page=64, H=32, Hkv=4, D=128, HI=16,
 
 
 def test_token_select_kernels_at_the_serving_configuration(chip_compile):
-    """``ops/token_select_attention.py`` at the published widths: the index
-    scores through the page table (eight pages a key step), the exact
-    threshold with a tile's 33,280 scores resident in VMEM, the dense pass
-    under the per-(row, key) bias over a pool of four KV heads a token a
-    row, and a decode row's scores over the pages it sees; the limits the
-    calls ask for are their own reckoning."""
+    """``ops/token_select_attention.py`` at the published widths: the one
+    kernel that scores a tile's index queries through the page table (eight
+    pages a key step), finds the exact threshold with the tile's 33,280
+    scores resident in VMEM and never elsewhere, and copies the bias out a
+    key step at a time; the dense pass under that per-(row, key) bias over
+    a pool of four KV heads a token a row; and a decode row's scores over
+    the pages it sees; the limits the calls ask for are their own
+    reckoning."""
     from deepspeed_tpu.ops import linear_attention as la
     from deepspeed_tpu.ops import token_select_attention as tsa
 
@@ -622,26 +624,27 @@ def test_token_select_kernels_at_the_serving_configuration(chip_compile):
     NT = -(-c["T"] // tsa.TILE) + c["S"]
     assert tsa.TILE == la.CHUNK and NT == 17
     K = tsa.padded_keys(c["pp"], c["page"])
-    assert K == 65 * 512
+    assert K == 65 * 512 and tsa.key_step(c["page"]) == 512
     tiles = (((NT,), i32),) * 3
     table = ((c["S"], c["pp"]), i32)
     index_pool = tsa.index_leaf_shape(c["pool"], c["page"], c["dI"])
     assert index_pool == (c["pool"], 32, 128)     # two keys a row of lanes
+    asked = tsa.select_vmem_bytes(K, c["HI"] * tsa.TILE, 512, 2)
+    assert asked < 32 * 1024 * 1024         # a quarter of the v5e's VMEM
     text = chip_compile(
-        functools.partial(tsa._index.__wrapped__, interpret=False),
+        functools.partial(tsa._select.__wrapped__, topk=c["topk"],
+                          interpret=False),
         ((NT, c["HI"] * tsa.TILE, c["dI"]), bf),
         ((NT, 1, c["HI"] * tsa.TILE), jnp.float32),
         (index_pool, bf), table, *tiles)
-    assert f"%{tsa.INDEX_NAME}" in text
-    asked = tsa.select_vmem_bytes(K)
-    assert asked < 64 * 1024 * 1024         # half of the v5e's VMEM
-    text = chip_compile(
-        functools.partial(tsa._select.__wrapped__, topk=c["topk"], keys=512,
-                          interpret=False),
-        ((NT, K, tsa.TILE), i32), *tiles[:2])
     assert f"%{tsa.SELECT_NAME}" in text and f'"size":"{asked}"' in text
-    asked = tsa.chunk_vmem_bytes(c["Hkv"], G, c["D"], c["page"], K)
-    assert asked < 64 * 1024 * 1024
+    # the scores and the choice are one kernel: no second call, and no
+    # [tiles, keys, 128] int32 array between two
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"s32[{NT},{K},{tsa.TILE}]" not in text
+    # its bias comes a key step at a time: the limit no longer grows with K
+    asked = tsa.chunk_vmem_bytes(c["Hkv"], G, c["D"], c["page"])
+    assert asked < 32 * 1024 * 1024
     text = chip_compile(
         functools.partial(tsa._chunk.__wrapped__, sm_scale=0.0884,
                           interpret=False),

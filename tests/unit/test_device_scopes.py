@@ -264,8 +264,10 @@ def _train_engine(loss_module, dp=2):
      ("attn", "indexer", "-")),
     ("jit(f)/M/model/layers_0/self_attn/index/gather",
      ("attn", "index", "-")),
-    ("jit(f)/M/model/layers_0/self_attn/index/jit(_index)/index_scores/"
-     "pallas_call", ("attn", "index_scores", "-")),
+    # (the scores and their choice are one kernel since PR 49; the packed
+    # queries are its wrapper's, under the same scope)
+    ("jit(f)/M/model/layers_0/self_attn/select/jit(_select)/pad",
+     ("attn", "select", "-")),
     ("jit(f)/M/model/layers_0/self_attn/select/jit(_select)/index_select/"
      "pallas_call", ("attn", "index_select", "-")),
     ("jit(f)/M/model/layers_0/self_attn/select/cond/branch_1_fun/cumsum",
