@@ -21,8 +21,10 @@ the contract ``KVState`` stands on (pools and per-slot state); ``reference.py``:
 the XLA twins.  One file a CACHE KIND, which owns its leaves' names and
 shapes, its predicate on a model config, its update-and-attend function and
 what the engine reads of it (a :class:`~.tree.Part`): ``kv_pages.py`` (per-head
-K/V pages; a looped model's slots a layer), ``conv_state.py`` (a short
-convolution's last inputs), ``linear_state.py`` (a matrix a head),
+K/V pages; a looped model's slots a layer; thirty heads stored as thirty-two),
+``conv_state.py`` (a short convolution's last inputs), ``linear_state.py`` (a
+decaying matrix a head), ``delta_state.py`` (a matrix a head corrected by the
+delta rule, and the rows of the convolution before it: two leaves a layer),
 ``block_select.py`` (pages of keys and values chosen by compressed keys),
 ``index_select.py`` (tokens chosen by an indexer whose keys are a pool leaf),
 ``latent.py`` (one latent row a token).  :func:`describe` composes a model's
@@ -45,12 +47,14 @@ from deepspeed_tpu.inference.paged.block_select import (  # noqa: F401
     sparse_selection, sparse_update_and_attend)
 from deepspeed_tpu.inference.paged.conv_state import (  # noqa: F401
     ConvState, conv_layers, ragged_short_conv)
+from deepspeed_tpu.inference.paged.delta_state import (  # noqa: F401
+    DELTA_LAYER, DeltaState, delta_layers, ragged_gated_delta)
 from deepspeed_tpu.inference.paged.index_select import (  # noqa: F401
     INDEX_KEY_PAGES, TOKENSEL_KV_PAGES, IndexSelect, TickShape,
     index_key_steps, token_select_counts, token_selection,
     tokensel_update_and_attend)
 from deepspeed_tpu.inference.paged.kv_pages import (  # noqa: F401
-    _RPA_QUERY_BLOCK, KVPages, kv_dequant_path, kv_lane_dim,
+    _RPA_QUERY_BLOCK, KVPages, kv_dequant_path, kv_heads_stored, kv_lane_dim,
     kv_slots_per_layer, paged_attention_path, paged_update_and_attend,
     rpa_kv_pages_per_block)
 from deepspeed_tpu.inference.paged.latent import (  # noqa: F401
@@ -111,7 +115,8 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
     a tick's work in the program's tiles."""
     of = int(cfg.num_hidden_layers)
     conv, linear = conv_layers(cfg), linear_layers(cfg)
-    rows = of - conv - linear
+    delta = delta_layers(cfg)
+    rows = of - conv - linear - delta
     slots, width = kv_slots_per_layer(cfg), latent_width(cfg)
     selection, indexer = sparse_selection(cfg), token_selection(cfg)
     parts: List[Part] = []
@@ -121,21 +126,24 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
         parts.append(ConvState(conv, of))
     if linear:
         parts.append(LinearState(linear, of))
+    if delta:
+        parts.append(DeltaState(delta, of))
     if selection is not None:
         parts.append(BlockSelect(rows, of, selection))
     elif indexer is not None:
         parts.append(IndexSelect(rows, of, indexer, tick))
     elif width:
         parts.append(Latent(rows, of, width))
-    return CacheKind(tuple(parts), slots, conv + linear, rows, width,
+    return CacheKind(tuple(parts), slots, conv + linear + delta, rows, width,
                      selection)
 
 
 def state_layers(cfg) -> int:
     """Layers of a model that keep per-slot state beside the pool: the
-    ``"conv"`` entries of its config's ``layer_types`` and the
-    ``"lightning-attn"`` entries of its ``mixer_types``; 0 for a model
-    whose config names neither (every layer holds K/V)."""
+    ``"conv"`` and the ``"linear_attention"`` entries of its config's
+    ``layer_types`` and the ``"lightning-attn"`` entries of its
+    ``mixer_types``; 0 for a model whose config names none of the three
+    (every layer holds K/V)."""
     return describe(cfg).state_layers
 
 

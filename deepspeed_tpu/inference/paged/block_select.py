@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference.paged.tree import (Part, _one_row_seqs,
+from deepspeed_tpu.inference.paged.tree import (MATRIX_STATE_REFUSES, Part,
+                                                _one_row_seqs,
                                                 _seq_of_rows)
 from deepspeed_tpu.ops import block_select_attention, linear_attention, routes
 
@@ -204,14 +205,10 @@ def sparse_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     return y.at[jnp.where(one, first, T)].set(y_one, mode="drop")
 
 
-# the model's other layers keep a matrix state a head, megabytes a sequence:
-# no entry of a request's record in the spill tiers
+# the model's other layers keep a matrix state a head (tree.py:
+# MATRIX_STATE_REFUSES)
 SELECT_IS = ("{model} keeps a matrix state a head in {state} of its {p.of} "
              "layers and chooses the pages its other layers attend")
-SELECT_REFUSES = {
-    "tiering": ", and a spilled request's record would carry megabytes of "
-    "state a sequence beside its pages (ROADMAP R5(d): a tier for matrix "
-    "state)"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,7 +218,7 @@ class BlockSelect(Part):
 
     def refusals(self, model):
         return SELECT_IS.format(model=model, p=self,
-                                state=self.of - self.layers), SELECT_REFUSES
+                                state=self.of - self.layers), MATRIX_STATE_REFUSES
 
     def counts(self, st, positions, q_lens=None):
         one_row = None if q_lens is None else np.repeat(q_lens == 1, q_lens)

@@ -26,6 +26,15 @@ padded key adds ``0 * 0`` to a score and a padded value column is sliced
 off the output, so the result is exact and the softmax scale stays the
 true head's.  A token then costs the pool ``2 * Hkv * 128`` values a K/V
 layer, twice its unpadded size.
+
+Thirty KV heads (``models/olmo_hybrid.py``) are stored as **thirty-two**
+(:func:`kv_heads_stored`): a token's ``2 * Hkv`` combined heads are the
+sublanes of a page's tiles, both Pallas kernels need whole tiles of them
+(``2 * Hkv`` a multiple of 16), and 60 is none.  The model pads q, k and v
+with zero heads before the call and drops the padded heads' (zero-query)
+outputs after it, so the result is exact; a token costs the pool 32 heads'
+bytes, a fifteenth more.  Only where the padding is that cheap: two or four
+KV heads are stored as they are.
 """
 from __future__ import annotations
 
@@ -76,6 +85,14 @@ def kv_lane_dim(head_dim: int, kv_cache_dtype: str = "none") -> int:
     head is stored as it is."""
     full_width = (kv_cache_dtype or "none") == "none"
     return _LANES if head_dim == 64 and full_width else head_dim
+
+
+def kv_heads_stored(kv_heads: int) -> int:
+    """KV heads AS THE POOL STORES THEM: the next multiple of 8 (whole
+    sublane tiles of combined heads, which the kernels need) where that
+    costs at most an eighth more, else the heads as they are."""
+    up = -(-kv_heads // 8) * 8
+    return up if (up - kv_heads) * 8 <= kv_heads else kv_heads
 
 
 def _staged_attend_stats(mdl, q: jax.Array, ragged_meta, cfg) -> jax.Array:

@@ -4,7 +4,8 @@ two cache kinds share without importing each other.
 Every leaf of a model's ``cache`` collection is a page pool (leading dim:
 pages) but for **per-slot state** that is no page: a ``[max_seqs, ...]`` leaf
 named :data:`SLOT_STATE`, indexed by slot (``conv_state.py``,
-``linear_state.py``).  ``KVState`` tells the two apart by that name and knows
+``linear_state.py``, ``delta_state.py``; a layer may keep several, each in a
+module of its own).  ``KVState`` tells the two apart by that name and knows
 nothing else of a leaf.  :class:`Part` is what the layers of one kind add to
 the description the engine reads (``paged.describe``)."""
 from __future__ import annotations
@@ -119,6 +120,14 @@ SLOT_STATE_REFUSES = {
     "the kernels' 128 lanes, which the quantized pool's kernel does not "
     "know)",
     "tensor_parallel": ", and the cache's sharding rule knows page pools only"}
+
+
+# a matrix a head is megabytes a sequence: no entry of a request's record in
+# the spill tiers
+MATRIX_STATE_REFUSES = {
+    "tiering": ", and a spilled request's record would carry megabytes of "
+    "state a sequence beside its pages (ROADMAP R5(d): a tier for matrix "
+    "state)"}
 
 
 class SlotState(Part):

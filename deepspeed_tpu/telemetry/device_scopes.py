@@ -63,6 +63,8 @@ _MODULE_KIND = {
     "moe": "moe", "block_sparse_moe": "moe", "experts": "moe",
     "conv": "conv",
     "lightning_attn": "linear",
+    # a gated-delta-rule mixer, the short convolution inside it with it
+    "linear_attn": "linear",
     "lm_head": "head", "embed_tokens.attend": "head",
     "embed_tokens": "embed", "wte": "embed", "wpe": "embed",
 }
@@ -70,6 +72,9 @@ _MODULE_KIND = {
 # what they follow); ``mixer`` and ``ffn`` are resolved per layer by
 # :func:`scope_table`, which sees which mixer and which MLP the layer has
 _NORM_ROLE = {"input_layernorm": "mixer", "operator_norm": "mixer",
+              "attention_layer_norm": "mixer",
+              "feedforward_layer_norm": "ffn",
+              "post_attention_norm": "mixer", "post_feedforward_norm": "ffn",
               "input_layernorm_2": "mixer",
               "post_attention_layernorm": "ffn", "ffn_norm": "ffn",
               "post_attention_layernorm_2": "ffn"}

@@ -13,7 +13,9 @@ leaf, the choice by token, 4-KV-head pages a token a row), taken on its own
 tree; the eight older hashes are as they were.  PR 48 re-took ``olmoe.*``
 and ``keye.*`` and no other: a dropless expert layer's counts gained a
 fourth entry (``moe_visits``, the grouped matmul's row-tile visits), which
-is an output of both programs of every model with expert layers.
+is an output of both programs of every model with expert layers.  PR 50
+added the two programs of ``olmo-hybrid-7b-serve``, taken on its own tree,
+and moved none of the ten.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -63,12 +65,18 @@ EXPECTED = {
     # the chip in ``tests/unit/ops/test_tpu_compile.py``)
     "keye.fused": "354cbe5ddd30061e",
     "keye.decode_block": "1c188bb320a89427",
+    # taken by PR 50, which added the model (the tile layout at the delta
+    # scan's chunk, the scan and the one-token step over a state held heads
+    # along the lanes, a convolution's rows beside it, fifteen attention
+    # heads stored as sixteen); the ten older hashes are as they were
+    "olmo_hybrid.fused": "930b42b334a6e816",
+    "olmo_hybrid.decode_block": "45abd5d3a9dd0bed",
 }
 
 
 def _models():
-    from deepspeed_tpu.models import (keye_vl2, minicpm_sala, mistral, olmoe,
-                                      ouro)
+    from deepspeed_tpu.models import (keye_vl2, minicpm_sala, mistral,
+                                      olmo_hybrid, olmoe, ouro)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -84,6 +92,8 @@ def _models():
             "tinysala", **bf16)),
         "keye": keye_vl2.KeyeVL2ForCausalLM(keye_vl2.get_config(
             "tinykeye", expert_parallel=False, **bf16)),
+        "olmo_hybrid": olmo_hybrid.OlmoHybridForCausalLM(
+            olmo_hybrid.get_config("tinyolmohybrid", **bf16)),
     }
 
 

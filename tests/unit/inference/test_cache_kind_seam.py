@@ -48,6 +48,7 @@ MODELS = {
     "tinysala": ("minicpm_sala", "MiniCPMSALAForCausalLM", {}),
     "tinykeye": ("keye_vl2", "KeyeVL2ForCausalLM",
                  dict(expert_parallel=False)),
+    "tinyolmohybrid": ("olmo_hybrid", "OlmoHybridForCausalLM", {}),
 }
 ENGINE = dict(pipeline=False, max_seqs=2, max_seq_len=128, prefill_chunk=32,
               page_size=16, num_pages=20, decode_block_size=2)
@@ -56,13 +57,14 @@ COMMON = {"program", "ahead", "rows", "ticks", "key_tokens", "pages_in_use",
           "pages_total", "prefill_tokens", "prefill_key_tokens",
           "decode_key_tokens", "head_rows"}
 KIND_BLOCKS = ("kernels", "latent", "loop", "linear_state", "sparse",
-               "hybrid", "token_select")
+               "hybrid", "token_select", "delta_state")
 PLAIN = {"paged_attention": "xla-dense",
          "paged_attention_decode": "xla-dense",
          "paged_attention_prefill": "xla-dense"}
 PAD128 = {k: "xla-dense-pad128" for k in PLAIN}
 GROUPED = {"moe_grouped_matmul": "ragged-dot-xla",
            "moe_grouped_matmul_kernel": "ragged-dot"}
+DELTA = {"gated_delta_scan": "xla", "gated_delta_decode": "xla"}
 ANY = type("Any", (), {"__eq__": lambda self, other: True})()
 KEY_STEPS = dict(index_key_steps=3, index_live_key_steps=1)
 
@@ -183,6 +185,22 @@ EXPECTED = {
                     "indexer_head_dim": 16, "indexer_num_kv_heads": 1,
                     "topk": 24, "q_chunk_size": 512,
                     "kv_chunk_size": 512}}),
+    # nine of twelve as three of four: three state layers of TWO leaves each
+    # (a 8 x 2*16 float32 matrix and 3 rows of 64 convolution inputs: 5,376
+    # bytes a sequence, stored as counted), one K/V layer of fifteen heads
+    # stored as sixteen
+    "tinyolmohybrid": dict(
+        numbers=(1, 3, 1, 0, None),
+        attrs={"state_layers": 3, "state_bytes": 5376, "delta_layers": 3},
+        asks=[dict(state_rows=1, one_row_seqs=0)] * 3
+        + [dict(state_rows=1)] * 3,
+        stages={"kernels": {**PLAIN, **DELTA},
+                "delta_state": {"state_layers": 3, "kv_layers": 1,
+                                "state_bytes_per_seq": 5376,
+                                "state_bytes_per_seq_counted": 5376,
+                                "state_rows": 6, "kv_heads_stored": 16,
+                                "kv_bytes_per_token": 1024,
+                                "routes": {**PLAIN, **DELTA}}}),
 }
 
 
@@ -246,6 +264,7 @@ _LFM2 = "Lfm2MoeForCausalLM keeps per-slot state in 5 of its 6 layers"
 _SALA = "MiniCPMSALAForCausalLM keeps per-slot state in 2 of its 4 layers"
 _GLM = ("Glm4MoeLiteForCausalLM caches one latent row of 128 values a token "
         "a layer")
+_OLMO = "OlmoHybridForCausalLM keeps per-slot state in 3 of its 4 layers"
 _KEYE = ("KeyeVL2ForCausalLM attends the 24 keys an indexer of 2 heads "
          "chooses, token by token, in 2 of its 2 layers")
 _STATE = {
@@ -279,6 +298,10 @@ MESSAGES = {
     "layers attend, and a spilled request's record would carry megabytes of "
     "state a sequence beside its pages (ROADMAP R5(d): a tier for matrix "
     "state)",
+    **{("tinyolmohybrid", f): m.format(_OLMO) for f, m in _STATE.items()},
+    ("tinyolmohybrid", "tiering"): f"kv_tiering: {_OLMO}, and a spilled "
+    "request's record would carry megabytes of state a sequence beside its "
+    "pages (ROADMAP R5(d): a tier for matrix state)",
     ("tinyglm", "speculation"): f"speculation.mode='ngram': {_GLM}, and the "
     "speculative verify has no latent attention kernel (nor a draft that "
     "shares the latent pool)",
@@ -307,7 +330,8 @@ MESSAGES = {
 
 def test_the_descriptions_refusals_are_the_ones_listed():
     """Eighteen refusals in five kinds' files (per-slot state's five serve
-    two models), and none for a model of plain K/V pages."""
+    three models, and the matrix state's tiering two), and none for a model
+    of plain K/V pages."""
     listed = set()
     for preset in MODELS:
         model = _model(preset)
@@ -315,7 +339,7 @@ def test_the_descriptions_refusals_are_the_ones_listed():
                 type(model).__name__):
             assert model_is.startswith(type(model).__name__)
             listed |= {(preset, feature) for feature in refuses}
-    assert listed == set(MESSAGES) and len(MESSAGES) == 18 + 5
+    assert listed == set(MESSAGES) and len(MESSAGES) == 18 + 5 + 6
 
 
 @pytest.mark.parametrize("preset,feature", sorted(MESSAGES))
@@ -404,12 +428,43 @@ def test_index_key_steps_are_the_live_tiles_up_to_their_last_row(
 def test_the_engine_names_no_cache_kind():
     words = re.compile(r"latent|sparse|conv(?!ert|enien)|lightning|"
                        r"linear_state|hybrid|ut_steps|CKEY|tokensel|"
-                       r"token_select|index_key|indexer", re.IGNORECASE)
+                       r"token_select|index_key|indexer|delta_|gated_delta",
+                       re.IGNORECASE)
     code = _code(ENGINE_FILE)
     assert [(n, t) for n, t in code if words.search(t)] == []
     kernels = ("latent_attention", "block_select_attention",
-               "linear_attention", "token_select_attention")
+               "linear_attention", "token_select_attention",
+               "gated_delta_rule")
     assert not any(k in m for m in _imports(ENGINE_FILE) for k in kernels)
     calls = [n for (n, t), (_, nxt) in zip(code, code[1:])
              if t == "_refuse_features" and nxt == "("]
     assert len(calls) == 2          # its definition and ONE call
+
+
+def test_a_gated_delta_model_is_nine_state_layers_of_two_leaves_each():
+    """``olmo-hybrid-7b-serve``'s twelve layers: nine keep per-slot state
+    (two leaves a layer, each in a module of its own), three keep K/V pages
+    of thirty heads stored as thirty-two."""
+    from deepspeed_tpu.inference.v2 import programs
+    from deepspeed_tpu.models import olmo_hybrid as oh
+
+    cfg = oh.get_config("olmo-hybrid-7b", num_hidden_layers=12,
+                        layer_types=oh._PERIOD * 3, **F32)
+    kind = paged.describe(cfg)
+    assert (kind.state_layers, kind.kv_layers, kind.kv_slots) == (9, 3, 1)
+    assert [type(p).__name__ for p in kind.parts] == ["KVPages",
+                                                      "DeltaState"]
+    tiny = _model("tinyolmohybrid", **F32)
+    model = type(tiny)(programs.serving_config(
+        tiny.config, max_seq_len=64, page_size=16, num_pages=5,
+        tensor_parallel=False, kv_cache_dtype="none"))
+    cache = jax.eval_shape(lambda: programs.init_cache(
+        model, max_seqs=2, width=10))
+    pools, states = paged.split_cache(cache)
+    assert len(states) == 2 * 3 and len(pools) == 1
+    assert sorted({s.shape for s in states}) == [(2, 3, 64), (2, 8, 32)]
+    assert pools[0].shape == (5, 16, 2 * 16, 8)     # fifteen heads as sixteen
+    assert paged.state_bytes_per_seq(cache) == 3 * (8 * 32 + 3 * 64) * 4
+    assert paged.kv_heads_stored(30) == 32 and paged.kv_heads_stored(15) == 16
+    assert [paged.kv_heads_stored(h) for h in (1, 2, 4, 8, 16)] == [
+        1, 2, 4, 8, 16]

@@ -23,8 +23,9 @@ TIERS = {"host_pages": 32}
 
 
 # per-slot state beside the pool, by shape: a few rows a layer (a short
-# convolution's), a matrix a head (linear attention's)
-STATE_SHAPES = {"rows": (2, 8), "matrix": (2, 4, 4)}
+# convolution's), a matrix a head (linear attention's), a matrix whose heads
+# lie along the lanes (the gated delta rule's)
+STATE_SHAPES = {"rows": (2, 8), "matrix": (2, 4, 4), "wide-matrix": (4, 12)}
 
 
 # a layer's pool leaves, by what a cache kind keeps for a page (each leads
@@ -41,12 +42,14 @@ LEAF_SETS = {
 
 
 def make_kv(tiering=None, prefix=False, num_pages=PAGES, state=None,
-            leaves="kv"):
+            leaves="kv", conv_rows=False):
     """A pool whose page ``p`` holds ``100 * p`` plus a small count in
     every leaf of ``LEAF_SETS[leaves]`` (negated in every second leaf), so
     a moved page is told by its content; with ``state`` (a key of
     ``STATE_SHAPES``) a per-slot state leaf beside it whose slot ``s`` holds
-    ``7 + s`` and counts up."""
+    ``7 + s`` and counts up; with ``conv_rows`` a SECOND state leaf of the
+    same layer, in a module of its own (a gated-delta-rule layer's
+    convolution rows, bf16), whose slot ``s`` holds ``50 + s``."""
     layer = {}
     for i, (name, shape) in enumerate(LEAF_SETS[leaves].items()):
         within = np.arange(int(np.prod(shape)), dtype=np.float32).reshape(
@@ -60,6 +63,10 @@ def make_kv(tiering=None, prefix=False, num_pages=PAGES, state=None,
         cache["model"]["layers_1"] = {"slot_state": jnp.asarray(
             (7 + np.arange(SEQS)).reshape((SEQS,) + (1,) * len(shape))
             + np.arange(np.prod(shape), dtype=np.float32).reshape(shape))}
+        if conv_rows:
+            cache["model"]["layers_1"]["conv"] = {"slot_state": jnp.asarray(
+                50.0 + np.arange(SEQS)[:, None, None] + np.zeros((3, 6)),
+                jnp.bfloat16)}
     return KVState(cache, num_pages=num_pages, page_size=PAGE,
                    max_seqs=SEQS, max_seq_len=MAX_LEN,
                    kv_reserve="on_demand",
@@ -231,7 +238,7 @@ def test_export_then_adopt_round_trips_on_a_second_pool(state, leaves):
     kv2.close()
 
 
-@pytest.mark.parametrize("state", ["rows", "matrix"])
+@pytest.mark.parametrize("state", ["rows", "matrix", "wide-matrix"])
 def test_attach_zeroes_spill_carries_and_release_ends_the_state(state):
     """The state's life is the pages': a slot's next tenant reads zeros,
     ``spill`` -> ``restore`` brings back what the sequence left, into
@@ -396,3 +403,48 @@ def test_every_way_out_of_a_slot_leaves_the_same_state(params, site, tiers):
         got = eng.drain()
         np.testing.assert_array_equal(got[r.uid], want[0])
     eng.close()
+
+
+@pytest.mark.parametrize("verb", ["spill-restore", "export-adopt"])
+def test_two_state_leaves_a_layer_travel_together(verb):
+    """A gated-delta-rule layer keeps TWO per-slot leaves (its convolution's
+    rows in a module of their own beside the matrix state): ``attach`` zeroes
+    both, ``spill`` -> ``restore`` and ``export`` -> ``adopt`` carry both into
+    another slot (of another pool), and no other slot's state moves."""
+    two = dict(tiering=TIERS, state="wide-matrix", conv_rows=True)
+    leaves = lambda kv, slot: [
+        np.asarray(kv.cache["model"]["layers_1"]["slot_state"])[slot],
+        np.asarray(kv.cache["model"]["layers_1"]["conv"]["slot_state"]
+                   .astype(jnp.float32))[slot]]
+    kv = make_kv(**two)
+    assert kv.state_bytes_per_seq == 4 * 4 * 12 + 2 * 3 * 6
+    other = [a.copy() for a in leaves(kv, 1)]
+    r = decoding(make_req(1, 10, slot=0))
+    seat(kv, r)
+    assert all((a == 0).all() for a in leaves(kv, 0))
+    layer = kv.cache["model"]["layers_1"]
+    layer["slot_state"] = layer["slot_state"].at[0].set(3.5)
+    layer["conv"]["slot_state"] = layer["conv"]["slot_state"].at[0].set(-2.0)
+    assert kv.spill(r, last_tok=9)
+    kv.release(0)
+    if verb == "spill-restore":
+        into = kv
+    else:
+        blob = kv.export(r)
+        into = make_kv(**two)
+        twin = make_req(7, 10, slot=2)
+        twin.generated, twin.prefill_done = blob["generated"], 10
+        assert into.adopt(blob["spill"], twin)
+        r = twin
+    r.slot = 2
+    seat(into, r)
+    assert all((a == 0).all() for a in leaves(into, 2))
+    assert into.restore(r) == 9
+    matrix, conv = leaves(into, 2)
+    assert (matrix == 3.5).all() and (conv == -2.0).all()
+    for got, want in zip(leaves(kv, 1), other):
+        np.testing.assert_array_equal(got, want)
+    into.release(2)
+    if into is not kv:
+        into.close()
+    kv.close()

@@ -660,6 +660,80 @@ def test_token_select_kernels_at_the_serving_configuration(chip_compile):
     assert f"%{tsa.ROW_INDEX_NAME}" in text
 
 
+# olmo-hybrid-7b-serve: 30 delta-rule heads of 96 / 192 over a 96 x 192
+# float32 state held heads along the lanes; 30 attention heads of 128 stored
+# as 32 in a pool of 2,065 pages, 8 slots of 258 pages, a fused tick of
+# 8 + 1,024 rows
+OLMO = dict(S=8, pp=258, pool=2065, page=64, H=30, dk=96, dv=192, D=128,
+            T=1032)
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_gated_delta_kernels_at_the_serving_configuration(chip_compile,
+                                                          chunk):
+    """``ops/gated_delta_rule.py`` at the published 96 / 192: the chunked
+    scan (the solve, the unaligned lane slice a head of the state block)
+    over the tiles a fused tick of ``olmo-hybrid-7b-serve`` can hold, and
+    the one-token step over its eight slots."""
+    from deepspeed_tpu.ops import gated_delta_rule as gd
+
+    c, i32, bf, f32 = OLMO, jnp.int32, jnp.bfloat16, jnp.float32
+    NT = -(-c["T"] // chunk) + c["S"]
+    rows = lambda d: ((c["H"], NT * chunk, d), bf)
+    state = ((c["S"], c["dk"], c["H"] * c["dv"]), f32)
+    assert (c["H"] * c["dv"]) % 128 == 0
+    assert gd.scan_eligible(c["H"], c["dk"], c["dv"], bf)
+    text = chip_compile(
+        functools.partial(gd._scan.__wrapped__, chunk=chunk,
+                          interpret=False),
+        rows(c["dk"]), rows(c["dk"]), rows(c["dv"]),
+        ((c["H"], NT, 2, chunk), f32), state, ((NT,), i32), ((NT,), i32),
+        ((NT,), i32))
+    assert f"%{gd.SCAN_NAME}" in text
+    if chunk != gd.CHUNK:
+        return
+    row = lambda d: ((c["S"], c["H"], d), bf)
+    gate = ((c["S"], c["H"]), f32)
+    text = chip_compile(
+        functools.partial(gd._step.__wrapped__, interpret=False),
+        row(c["dk"]), row(c["dk"]), row(c["dv"]), gate, gate, state,
+        ((c["S"],), i32), ((c["S"],), jnp.bool_), ((1,), i32))
+    assert f"%{gd.STEP_NAME}" in text
+
+
+def test_thirty_heads_are_served_from_both_kernels_as_thirty_two(
+        chip_compile):
+    """30 KV heads are 60 combined heads a token, no whole number of
+    sublane tiles: both kernels refuse them (``tiling`` answers None).
+    Stored as 32 (``kv_pages.py:kv_heads_stored``) both take the pool."""
+    from deepspeed_tpu.ops import paged_decode, paged_prefill
+
+    c, i32, bf = OLMO, jnp.int32, jnp.bfloat16
+    assert paged.kv_heads_stored(c["H"]) == 32
+    assert paged_prefill.tiling(30, 30, c["page"], c["D"], bf) is None
+    assert paged_decode.tiling(30, c["page"], c["pp"], c["D"], bf) is None
+    tiles = paged_prefill.tiling(32, 32, c["page"], c["D"], bf)
+    decode_tiles = paged_decode.tiling(32, c["page"], c["pp"], c["D"], bf)
+    assert tiles is not None and decode_tiles is not None
+    assert paged_prefill.vmem_bytes(c["T"] + 8, 32, 32, c["page"], c["D"],
+                                    tiles) < paged_prefill._VMEM_CEILING
+    pool = ((c["pool"], c["page"], 64, c["D"]), bf)
+    meta = [((c["S"],), i32), ((c["S"], c["pp"]), i32),
+            ((c["S"] + 1,), i32), ((1,), i32)]
+    text = chip_compile(
+        functools.partial(paged_prefill._chunk.__wrapped__, sm_scale=0.0884,
+                          sliding_window=None, tiles=tiles, interpret=False),
+        ((32, c["T"] + 8, c["D"]), bf), pool, *meta)
+    assert f"%{paged_prefill.NAME}" in text
+    text = chip_compile(
+        functools.partial(paged_decode._decode.__wrapped__, sm_scale=0.0884,
+                          sliding_window=None, tiles=decode_tiles,
+                          interpret=False),
+        ((c["S"], 32, c["D"]), bf), pool, meta[0], meta[1],
+        ((c["S"],), jnp.bool_))
+    assert f"%{paged_decode.NAME}" in text
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4

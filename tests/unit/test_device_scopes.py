@@ -35,12 +35,14 @@ FAMILIES = {
     "glm": {"attn", "mlp", "moe"},
     "sala": {"attn", "linear", "mlp"},
     "keye": {"attn", "moe"},
+    "olmo-hybrid": {"attn", "linear", "mlp"},
 }
 
 
 def _model(family):
     from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
-                                      minicpm_sala, mistral, olmoe, ouro)
+                                      minicpm_sala, mistral, olmo_hybrid,
+                                      olmoe, ouro)
 
     if family == "mistral":
         return mistral.MistralForCausalLM(mistral.get_config(
@@ -58,6 +60,9 @@ def _model(family):
     if family == "sala":
         return minicpm_sala.MiniCPMSALAForCausalLM(
             minicpm_sala.get_config("tinysala", **BF16))
+    if family == "olmo-hybrid":
+        return olmo_hybrid.OlmoHybridForCausalLM(
+            olmo_hybrid.get_config("tinyolmohybrid", **BF16))
     if family == "keye":
         return keye_vl2.KeyeVL2ForCausalLM(keye_vl2.get_config(
             "tinykeye", expert_parallel=False, **BF16))
@@ -251,6 +256,18 @@ def _train_engine(loss_module, dp=2):
      "pallas_call", ("linear", "linear_chunk_scan", "-")),
     ("jit(f)/M/model/layers_1/lightning_attn/jit(_step)/linear_decode_step/"
      "pallas_call", ("linear", "linear_decode_step", "-")),
+    # a gated-delta-rule mixer: its projections, the convolution INSIDE it
+    # (kind ``linear``, not ``conv``), its gates, kernels and block norms
+    ("jit(f)/M/model/layers_0/linear_attn/q_proj/dot_general",
+     ("linear", "q_proj", "-")),
+    ("jit(f)/M/model/layers_0/linear_attn/conv/mul",
+     ("linear", "conv", "-")),
+    ("jit(f)/M/model/layers_0/linear_attn/gates/a_proj/dot_general",
+     ("linear", "gates", "-")),
+    ("jit(f)/M/model/layers_0/linear_attn/jit(_scan)/delta_chunk_scan/"
+     "pallas_call", ("linear", "delta_chunk_scan", "-")),
+    ("jit(f)/M/model/layers_0/linear_attn/jit(_step)/delta_decode_step/"
+     "pallas_call", ("linear", "delta_decode_step", "-")),
     ("jit(f)/M/model/layers_0/self_attn/compress/gather",
      ("attn", "compress", "-")),
     ("jit(f)/M/model/layers_0/self_attn/select/top_k",
