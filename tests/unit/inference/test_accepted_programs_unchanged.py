@@ -15,7 +15,13 @@ and ``keye.*`` and no other: a dropless expert layer's counts gained a
 fourth entry (``moe_visits``, the grouped matmul's row-tile visits), which
 is an output of both programs of every model with expert layers.  PR 50
 added the two programs of ``olmo-hybrid-7b-serve``, taken on its own tree,
-and moved none of the ten.
+and moved none of the ten.  PR 51 re-took ``olmo_hybrid.fused`` and no
+other: it MEANT to move it (``conv_state.py:ragged_short_conv`` gathers its
+carried rows a sequence and no longer a token where a call has more rows
+than sequences; a decode block, a row a sequence, is the parent's to the
+letter).  It also added the two programs of ``lfm2-24b-a2b-serve``, the
+function's other caller: ``lfm2.decode_block`` taken on the parent, which
+this tree reads too, ``lfm2.fused`` on its own tree.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -68,15 +74,25 @@ EXPECTED = {
     # taken by PR 50, which added the model (the tile layout at the delta
     # scan's chunk, the scan and the one-token step over a state held heads
     # along the lanes, a convolution's rows beside it, fifteen attention
-    # heads stored as sixteen); the ten older hashes are as they were
-    "olmo_hybrid.fused": "930b42b334a6e816",
+    # heads stored as sixteen); the ten older hashes are as they were.
+    # ``olmo_hybrid.fused`` re-taken by PR 51, which MEANT to move it
+    # (930b42b334a6e816 before it): the short convolution reads its carried
+    # rows ``[max_seqs, K - 1, C]`` a sequence and adds them to a chunk's
+    # first ``K - 1`` rows where it copied them a token; the decode block,
+    # a row a sequence, is PR 50's to the letter
+    "olmo_hybrid.fused": "d613a233d64e7c0f",
     "olmo_hybrid.decode_block": "45abd5d3a9dd0bed",
+    # added by PR 51 (ROADMAP D14), the same function's other caller: the
+    # decode block taken on PR 51's parent, the tick (da4cded4c6195441
+    # there, moved by the same edit) on its own tree
+    "lfm2.fused": "6965d992f8bd0789",
+    "lfm2.decode_block": "05e1a91e1d19ab60",
 }
 
 
 def _models():
-    from deepspeed_tpu.models import (keye_vl2, minicpm_sala, mistral,
-                                      olmo_hybrid, olmoe, ouro)
+    from deepspeed_tpu.models import (keye_vl2, lfm2_moe, minicpm_sala,
+                                      mistral, olmo_hybrid, olmoe, ouro)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -94,6 +110,8 @@ def _models():
             "tinykeye", expert_parallel=False, **bf16)),
         "olmo_hybrid": olmo_hybrid.OlmoHybridForCausalLM(
             olmo_hybrid.get_config("tinyolmohybrid", **bf16)),
+        "lfm2": lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config(
+            "tinylfm2", expert_parallel=False, **bf16)),
     }
 
 
