@@ -29,8 +29,58 @@ class _StageSpan(Span):
         super().__exit__(exc_type, exc, tb)
         st = self._stats
         st.seconds[self._stage] += self.dur
+        st._book(self.name, self.dur)
         if _metrics.enabled:
             st._stage_hist(self._stage).observe(self.dur)
+        return False
+
+
+class _LeafSpan(Span):
+    """A piece of a stage, bracketed inside it: the tracer's span alone
+    (profiler + ring).  Its duration is the enclosing stage's already, so
+    it reaches neither ``seconds`` nor the histogram; it is booked to the
+    step in progress, for ``serving_stages()["fused_tick_host"]``."""
+
+    __slots__ = ("_stats",)
+
+    def __init__(self, stats: "HostStageStats", name: str,
+                 attrs: Optional[Dict[str, Any]]):
+        super().__init__(trace, "engine." + name, "serving", attrs)
+        self._stats = stats
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        self._stats._book(self.name, self.dur)
+        return False
+
+
+class _StepSpan(Span):
+    """``engine.step`` around one ``step()``.  Which program the step
+    dispatches is known at its end only, so that is where its seconds are
+    booked: wall, and every bracket inside it by span name, under
+    ``HostStageStats.steps_by_program[<program>]``."""
+
+    __slots__ = ("_stats",)
+
+    def __init__(self, stats: "HostStageStats"):
+        super().__init__(trace, "engine.step", "serving", None)
+        self._stats = stats
+
+    def __enter__(self):
+        st = self._stats
+        st._step_program = "none"
+        st._step_spans.clear()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        st = self._stats
+        rec = st.steps_by_program[st._step_program]
+        rec["steps"] += 1
+        rec["wall_s"] += self.dur
+        by_span = rec["by_span_s"]
+        for name, dur in st._step_spans.items():
+            by_span[name] = by_span.get(name, 0.0) + dur
         return False
 
 
@@ -66,6 +116,38 @@ class HostStageStats:
     router ``router.pump`` / ``router.fold`` / ``router.dispatch`` and,
     on the intake path, ``router.submit`` with ``router.prefix_hash``
     inside it.  ``benchmark/idle_report.py`` reads all of them.
+
+    ``leaf(name, **counts)`` brackets a PIECE of a stage, inside the
+    stage's bracket, as ``engine.<name>``: the v2 engine takes the plan of
+    a fused tick apart so (``engine.plan.admit``: the handoff sweep and
+    admission, ``waiting`` / ``admitted``; ``engine.plan.grow``: the page
+    growth that decides between a decode block and a tick, ``rows``;
+    ``engine.plan.rows``: ``_plan_rows``, ``decode_rows`` /
+    ``prefill_seqs`` / ``pages_granted`` / ``page_limited``;
+    ``engine.plan.pack``: ``_pack_tick``'s arrays, ``rows`` / ``seqs`` /
+    ``page_cells``; ``engine.plan.counts``: the cache kind's host-side
+    counts for the dispatch span, ``rows``; ``engine.plan.attrs``: the
+    dispatch span's attributes, in a plan bracket of its own directly
+    before the dispatch), and a decode block brackets the last two.  A
+    leaf is a span and nothing else: its time is its stage's already, so
+    it reaches neither ``seconds`` nor the histogram, and in a profile it
+    inherits its stage's class (``benchmark/layer_metrics/host_spans.py``).
+    No call of the loop outside ``engine.fetch`` was found to wait for the
+    device (PERF.md section 6, PR 52), so there is no ``engine.wait`` span;
+    one found later is bracketed so INSIDE its stage, its seconds booked
+    to ``seconds["device"]`` (``fused_tick_host_ms`` already takes such
+    spans off).
+
+    ``step()`` books a step at its END, by the program it dispatched
+    (``dispatched(program)``: ``fused``, ``decode_block``, ``spec``, else
+    ``none``; a step that dispatched a block and then a tick is the
+    tick's): ``steps_by_program`` holds the steps, their wall seconds
+    and every bracket's seconds by span name (``engine.fetch``'s are the
+    step's wait).  ``serving_stages()["fused_tick_host"]``
+    divides the fused steps' by their number: ``host_ms`` (wall less
+    wait), ``wait_ms`` and ``<span>_ms`` for every stage and leaf, on the
+    tracer's clock, so a run with no profiler session gives the table
+    that ``fused_tick_host_ms`` reads from a traced one.
 
     ``serving_stages()`` reports per-dispatch milliseconds plus
     ``host_bound_fraction`` = host-stage time / (host + device-wait).
@@ -122,6 +204,9 @@ class HostStageStats:
     EXTRA = ("rng_split", "sample", "reap")
     SPAN_NAMES = {s: "engine." + ("fetch" if s == "device" else s)
                   for s in STAGES + EXTRA}
+    # what a ``step()`` can have dispatched; a fused tick outranks the
+    # decode block dispatched earlier in the same step
+    PROGRAMS = ("fused", "decode_block", "spec", "none")
 
     def __init__(self, replica: str = ""):
         # scale-out serving runs several engines in one process; the
@@ -200,11 +285,40 @@ class HostStageStats:
         # a looped model's exit gate, live rows only:
         self.loop_rows = 0          # rows at dispatch, summed
         self.loop_exit_cdf = []     # P(stopped by pass u), summed, u < U
+        # the steps by the program they dispatched: how many, their wall
+        # seconds, and the seconds of every bracket inside them by span
+        # name (``engine.fetch``: blocked on the device)
+        self.steps_by_program: Dict[str, Dict[str, Any]] = {
+            p: {"steps": 0, "wall_s": 0.0, "by_span_s": {}}
+            for p in self.PROGRAMS}
+        self._step_program = "none"
+        self._step_spans: Dict[str, float] = {}    # of the step in progress
 
     def stage(self, name: str, **counts) -> _StageSpan:
         """Bracket one stage of the hot loop; ``counts`` become the
         span's stats."""
         return _StageSpan(self, name, counts or None)
+
+    def leaf(self, name: str, **counts) -> _LeafSpan:
+        """Bracket a piece of a stage, INSIDE that stage's bracket, as
+        ``engine.<name>``; ``counts`` become the span's stats, and
+        ``Span.add`` takes those known afterwards."""
+        return _LeafSpan(self, name, counts or None)
+
+    def step(self) -> _StepSpan:
+        """Bracket one ``step()`` of the v2 engine as ``engine.step``."""
+        return _StepSpan(self)
+
+    def dispatched(self, program: str) -> None:
+        """Count one launch of a compiled program, and name the step in
+        progress after it."""
+        self.dispatches += 1
+        if self._step_program != "fused":
+            self._step_program = program
+
+    def _book(self, span: str, dur: float) -> None:
+        spans = self._step_spans
+        spans[span] = spans.get(span, 0.0) + dur
 
     def _stage_hist(self, name: str):
         """Cached registry child for this stage (lookup once, then a
@@ -251,6 +365,7 @@ class HostStageStats:
                       for s in self.EXTRA})
         if self.drain_reasons:
             out["drain_reasons"] = dict(self.drain_reasons)
+        out["fused_tick_host"] = self.fused_tick_host()
         if self.spec_dispatches:
             sd = self.spec_dispatches
             out["speculation"] = {
@@ -268,6 +383,33 @@ class HostStageStats:
                 "effective_tokens_per_dispatch": round(
                     self.spec_tokens / sd, 2),
             }
+        return out
+
+
+    def fused_tick_host(self) -> Dict[str, Any]:
+        """``serving_stages()["fused_tick_host"]``: what the host spends on
+        a fused tick, over the steps that dispatched one (``ticks``), one
+        level of scalars.  A step's wall seconds less those it was blocked
+        on the device (``wait_ms``), a tick, is ``host_ms``;
+        ``<span>_ms`` splits the tick by stage bracket and by the leaves
+        inside ``engine.plan``, each from its span's own duration, so the
+        table needs no profiler session.  ``<program>_steps`` /
+        ``_wall_s`` / ``_wait_s``: every step of the run by what it
+        dispatched."""
+        fetch = self.SPAN_NAMES["device"]
+        out: Dict[str, Any] = {}
+        for p, r in self.steps_by_program.items():
+            out[f"{p}_steps"] = r["steps"]
+            out[f"{p}_wall_s"] = round(r["wall_s"], 6)
+            out[f"{p}_wait_s"] = round(r["by_span_s"].get(fetch, 0.0), 6)
+        fused = self.steps_by_program["fused"]
+        n = out["ticks"] = fused["steps"]
+        if n:
+            wait = fused["by_span_s"].get(fetch, 0.0)
+            out["host_ms"] = round((fused["wall_s"] - wait) * 1e3 / n, 4)
+            out["wait_ms"] = round(wait * 1e3 / n, 4)
+            for name, s in sorted(fused["by_span_s"].items()):
+                out[f"{name}_ms"] = round(s * 1e3 / n, 4)
         return out
 
 

@@ -1402,7 +1402,7 @@ class RaggedInferenceEngineV2:
                         uids=[r.uid for r in reqs],
                         ticks=self.decode_block_size, spec=True)
         with st.stage("verify"):
-            st.dispatches += 1
+            st.dispatched("spec")
             st.spec_dispatches += 1
             (self.kv.cache, self.kv.draft_cache, _h, new_last, _p, _a, _r,
              toks, mask, prop, accd) = self._run(
@@ -1495,16 +1495,20 @@ class RaggedInferenceEngineV2:
         K = self.decode_block_size
         rows, keys = len(reqs), int(sum(r.length for r in reqs))
         ahead = int(bool(self._queue))
-        st.dispatches += 1
+        st.dispatched("decode_block")
         st.decode_blocks += 1
         st.decode_blocks_ahead += ahead
         st.decode_key_token_steps += K * keys + rows * K * (K - 1) // 2
-        # tick k of the block asks at position length - 1 + k
-        asks = self.cache_kind.counts(st, np.add.outer(
-            [r.length - 1 for r in reqs], np.arange(K)))
-        return st.stage("dispatch", program="decode_block", ahead=ahead,
-                        rows=rows, ticks=K, key_tokens=keys, **asks,
-                        **self._pool_counts())
+        with st.stage("plan"):
+            with st.leaf("plan.counts", rows=rows):
+                # tick k of the block asks at position length - 1 + k
+                asks = self.cache_kind.counts(st, np.add.outer(
+                    [r.length - 1 for r in reqs], np.arange(K)))
+            with st.leaf("plan.attrs"):
+                attrs = dict(program="decode_block", ahead=ahead, rows=rows,
+                             ticks=K, key_tokens=keys, **asks,
+                             **self._pool_counts())
+        return st.stage("dispatch", **attrs)
 
     def _pool_counts(self) -> Dict[str, int]:
         """What every ``engine.dispatch`` span says of the pool as the
@@ -1775,7 +1779,7 @@ class RaggedInferenceEngineV2:
                         spec=bool(spec))
         if spec:
             with st.stage("verify"):
-                st.dispatches += 1
+                st.dispatched("spec")
                 st.spec_dispatches += 1
                 (self.kv.cache, self.kv.draft_cache, dv["hist"],
                  dv["last_tok"], dv["pos"], dv["active"],
@@ -1863,7 +1867,7 @@ class RaggedInferenceEngineV2:
         dispatches when ``pipeline=True``; any prefilling sequence
         falls back to the fused SplitFuse tick."""
         self._sched_seq += 1
-        with trace.bracket("engine.step", cat="serving"):
+        with self.host_stats.step():
             return self._step()
 
     def _step(self) -> int:
@@ -1891,14 +1895,17 @@ class RaggedInferenceEngineV2:
             # handoff request never reaches the decode block / spec /
             # pipeline paths — it leaves its slot the step after its
             # first token lands
-            self._handoff_sweep()
-            self._admit()
-            lc_live = [r for r in self.slots
-                       if r is not None and not r.done and r.lc]
-            live = [r for r in self.slots
-                    if r is not None and not r.done and not r.lc]
-            decoding_ready = (not lc_live and bool(live) and all(
-                r.prefill_done >= r.ctx_len for r in live))
+            with st.leaf("plan.admit", waiting=len(self.waiting)) as leaf:
+                seated = st.admissions
+                self._handoff_sweep()
+                self._admit()
+                lc_live = [r for r in self.slots
+                           if r is not None and not r.done and r.lc]
+                live = [r for r in self.slots
+                        if r is not None and not r.done and not r.lc]
+                decoding_ready = (not lc_live and bool(live) and all(
+                    r.prefill_done >= r.ctx_len for r in live))
+                leaf.add(admitted=st.admissions - seated)
         if self._queue and (lc_live or (decoding_ready and
                                         self.spec_mode != "off")):
             # the long-context driver and the speculative block (its
@@ -1910,7 +1917,7 @@ class RaggedInferenceEngineV2:
             # device: a row whose token only the host has (restored from
             # a spill) goes through one more tick first
             decoding_ready = all(r.pending for r in live)
-        with st.stage("plan"):
+        with st.stage("plan"), st.leaf("plan.grow", rows=len(live)):
             # speculation first: its block writes a k+1-wide span per
             # tick, so it needs more page coverage than a plain block —
             # when the pool can't back it, degrade to the plain decode
@@ -1991,17 +1998,23 @@ class RaggedInferenceEngineV2:
         with st.stage("plan"):
             arrays, sampled, sampling, rows, counts = self._pack_tick(takes)
         args = self.upload_all(arrays)
-        st.prefill_tokens += counts["prefill_tokens"]
-        st.prefill_key_tokens += counts["prefill_key_tokens"]
-        st.decode_key_token_steps += counts["decode_key_tokens"]
-        ahead = int(bool(self._queue))
-        with st.stage("dispatch", program="fused", ahead=ahead, **counts,
-                      head_rows=self.max_seqs, **self._pool_counts()):
-            st.dispatches += 1
+        with st.stage("plan"), st.leaf("plan.attrs"):
+            st.prefill_tokens += counts["prefill_tokens"]
+            st.prefill_key_tokens += counts["prefill_key_tokens"]
+            st.decode_key_token_steps += counts["decode_key_tokens"]
+            ahead = int(bool(self._queue))
+            attrs = dict(program="fused", ahead=ahead, **counts,
+                         head_rows=self.max_seqs, **self._pool_counts())
+        with st.stage("dispatch", **attrs):
+            st.dispatched("fused")
             toks, self._dev_last_tok, self.kv.cache, moe, loop = \
                 self._run(
                     self.fused_sample_step(sampled),
                     self.params, self.kv.cache, self._dev_last_tok, *args)
+            # the tick's uploads are let go HERE: ten device buffers take
+            # 70 us to free (and each free gives the interpreter lock up),
+            # which the frame's end would leave in no bracket
+            del args
         st.ticks += 1
         st.fused_ticks += 1
         st.fused_ticks_ahead += ahead
@@ -2188,44 +2201,49 @@ class RaggedInferenceEngineV2:
         (``_page_limited``: some growth fell short; ``_stalled``: who
         sits the tick out for it).  Returns the prompt tokens each
         prefilling sequence takes, by uid; None when nothing can run."""
-        self._stalled = []
-        self._page_limited = False
-        decode_rs = []
-        for r in self.slots:
-            if r is None or r.done or r.lc or r.prefill_done < r.ctx_len:
-                continue
-            # the tick writes the last generated token at position
-            # length-1, so pages must cover `length` tokens
-            if self.kv.ensure(r.slot, r.length):
-                decode_rs.append(r)
-            else:
-                self._stalled.append(r)    # out of pages: sit this tick out
-        prefill_rs = sorted(
-            (r for r in self.slots
-             if r is not None and not r.lc
-             and r.prefill_done < r.ctx_len),
-            key=lambda r: r.uid)
-        budget = self.T - len(decode_rs)
-        takes: Dict[int, int] = {}
-        for r in prefill_rs:
-            take = min(budget, r.ctx_len - r.prefill_done)
-            if take <= 0:
-                continue                   # batch-budget-limited, not stalled
-            if not self.kv.ensure(r.slot, r.prefill_done + take):
-                # partial growth: cover what the pool allows this tick
-                # (cold prefix-index pages count — KVState.ensure
-                # reclaims them on demand)
-                self._page_limited = True
-                coverable = self.kv.coverable_tokens(r.slot)
-                take = min(take, coverable - r.prefill_done)
-                if take <= 0:
-                    self._stalled.append(r)     # page-limited
+        with self.host_stats.leaf("plan.rows") as leaf:
+            free = self.allocator.free_pages
+            self._stalled = []
+            self._page_limited = False
+            decode_rs = []
+            for r in self.slots:
+                if r is None or r.done or r.lc or r.prefill_done < r.ctx_len:
                     continue
-                self.kv.ensure(r.slot, r.prefill_done + take)
-            takes[r.uid] = take
-            budget -= take
-        self._page_limited |= bool(self._stalled)
-        return takes if decode_rs or takes else None
+                # the tick writes the last generated token at position
+                # length-1, so pages must cover `length` tokens
+                if self.kv.ensure(r.slot, r.length):
+                    decode_rs.append(r)
+                else:
+                    self._stalled.append(r)    # out of pages: sits it out
+            prefill_rs = sorted(
+                (r for r in self.slots
+                 if r is not None and not r.lc
+                 and r.prefill_done < r.ctx_len),
+                key=lambda r: r.uid)
+            budget = self.T - len(decode_rs)
+            takes: Dict[int, int] = {}
+            for r in prefill_rs:
+                take = min(budget, r.ctx_len - r.prefill_done)
+                if take <= 0:
+                    continue        # batch-budget-limited, not stalled
+                if not self.kv.ensure(r.slot, r.prefill_done + take):
+                    # partial growth: cover what the pool allows this tick
+                    # (cold prefix-index pages count — KVState.ensure
+                    # reclaims them on demand)
+                    self._page_limited = True
+                    coverable = self.kv.coverable_tokens(r.slot)
+                    take = min(take, coverable - r.prefill_done)
+                    if take <= 0:
+                        self._stalled.append(r)     # page-limited
+                        continue
+                    self.kv.ensure(r.slot, r.prefill_done + take)
+                takes[r.uid] = take
+                budget -= take
+            self._page_limited |= bool(self._stalled)
+            leaf.add(decode_rows=len(decode_rs), prefill_seqs=len(takes),
+                     pages_granted=free - self.allocator.free_pages,
+                     page_limited=int(self._page_limited))
+            return takes if decode_rs or takes else None
 
     def _pack_tick(self, takes: Dict[int, int]):
         """``_plan_rows``'s tick as the arrays the fused program takes, all
@@ -2234,111 +2252,114 @@ class RaggedInferenceEngineV2:
         device (``tok_src``); ``sampling``: (request, packed sequence
         number) of who samples; ``sampled``: any of them by a draw, so the
         five sampler rows ride along and the sampled program runs."""
-        S = self.max_seqs
-        token_ids = np.zeros((self.T,), np.int32)
-        tok_src = np.full((self.T,), -1, np.int32)
-        positions = np.zeros((self.T,), np.int32)
-        new_kv_dest = np.full((self.T,), 0, np.int32)   # trash page row 0
-        kv_lens = np.zeros((S,), np.int32)
-        # metadata rows are indexed by PACKED sequence number j, not slot:
-        # pack each active slot's page-table row as it is assigned a j
-        page_indices = np.full((S, self.pages_per_seq), -1, np.int32)
-        cu_q_lens = np.zeros((S + 1,), np.int32)
-        sample_rows = np.zeros((S,), np.int32)
-        sample_slot = np.full((S,), -1, np.int32)
-        # each packed sequence's slot: where a model with per-slot state
-        # finds and leaves it (no other model's program takes it)
-        seq_slot = np.zeros((S,), np.int32)
-        sampling: List[Tuple[Request, int]] = []
-        # what the tick asks of the device, for the dispatch span and
-        # the counters: a token at position p attends to p + 1 keys
-        counts = dict(prefill_tokens=0, prefill_key_tokens=0,
-                      decode_key_tokens=0)
+        with self.host_stats.leaf("plan.pack") as leaf:
+            S = self.max_seqs
+            token_ids = np.zeros((self.T,), np.int32)
+            tok_src = np.full((self.T,), -1, np.int32)
+            positions = np.zeros((self.T,), np.int32)
+            new_kv_dest = np.full((self.T,), 0, np.int32)   # trash page row 0
+            kv_lens = np.zeros((S,), np.int32)
+            # metadata rows are indexed by PACKED sequence number j, not slot:
+            # pack each active slot's page-table row as it is assigned a j
+            page_indices = np.full((S, self.pages_per_seq), -1, np.int32)
+            cu_q_lens = np.zeros((S + 1,), np.int32)
+            sample_rows = np.zeros((S,), np.int32)
+            sample_slot = np.full((S,), -1, np.int32)
+            # each packed sequence's slot: where a model with per-slot state
+            # finds and leaves it (no other model's program takes it)
+            seq_slot = np.zeros((S,), np.int32)
+            sampling: List[Tuple[Request, int]] = []
+            # what the tick asks of the device, for the dispatch span and
+            # the counters: a token at position p attends to p + 1 keys
+            counts = dict(prefill_tokens=0, prefill_key_tokens=0,
+                          decode_key_tokens=0)
 
-        # pack sequences in slot order (any fixed order works; the kernel
-        # sees sequences via cu_q_lens row j)
-        stalled_uids = {r.uid for r in self._stalled}
-        t = 0
-        j = 0
-        for r in [s for s in self.slots if s is not None]:
-            if r.done or r.lc or r.uid in stalled_uids:
-                continue
-            self._last_sched[r.slot] = self._sched_seq
-            seq_slot[j] = r.slot
-            if r.prefill_done >= r.ctx_len:                 # decode: 1 tok
-                p = min(r.length - 1, self.max_seq_len - 1)
-                if r.pending:
-                    tok_src[t] = r.slot
-                else:
-                    token_ids[t] = self.last_tokens[r.slot]
-                positions[t] = p
-                new_kv_dest[t] = self._flat_dest(r.slot, p)
-                page_indices[j] = self.page_table[r.slot]
-                kv_lens[j] = p + 1
-                counts["decode_key_tokens"] += p + 1
-                cu_q_lens[j + 1] = cu_q_lens[j] + 1
-                sample_rows[j] = t
-                sample_slot[j] = r.slot
-                sampling.append((r, j))
-                t += 1
-                j += 1
-            else:                                           # prefill chunk
-                take = takes.get(r.uid, 0)
-                if take <= 0:
+            # pack sequences in slot order (any fixed order works; the kernel
+            # sees sequences via cu_q_lens row j)
+            stalled_uids = {r.uid for r in self._stalled}
+            t = 0
+            j = 0
+            for r in [s for s in self.slots if s is not None]:
+                if r.done or r.lc or r.uid in stalled_uids:
                     continue
-                lo = r.prefill_done
-                token_ids[t:t + take] = r.ctx[lo:lo + take]
-                pos = np.arange(lo, lo + take)
-                positions[t:t + take] = pos
-                pg = self.page_table[r.slot, pos // self.page_size]
-                assert (pg > 0).all(), "write into unallocated page"
-                new_kv_dest[t:t + take] = (pg * self.page_size +
-                                           pos % self.page_size)
-                r.prefill_done += take
-                counts["prefill_tokens"] += int(take)
-                counts["prefill_key_tokens"] += int(
-                    take * lo + take * (take + 1) // 2)
-                # every freshly completed full page goes to the prefix
-                # index
-                self.kv.publish(r, r.ctx, r.prefill_done)
-                if trace.enabled:
-                    trace.event("prefill_chunk", cat="request",
-                                uid=r.uid, take=int(take),
-                                prefill_done=int(r.prefill_done),
-                                ctx_len=int(r.ctx_len))
-                page_indices[j] = self.page_table[r.slot]
-                kv_lens[j] = r.prefill_done
-                cu_q_lens[j + 1] = cu_q_lens[j] + take
-                sample_rows[j] = t + take - 1
-                if r.prefill_done >= r.ctx_len:
-                    self.request_latency.on_prefill_done(
-                        r.uid, r.ctx_len - r.pc_cached, r.pc_cached)
+                self._last_sched[r.slot] = self._sched_seq
+                seq_slot[j] = r.slot
+                if r.prefill_done >= r.ctx_len:                 # decode: 1 tok
+                    p = min(r.length - 1, self.max_seq_len - 1)
+                    if r.pending:
+                        tok_src[t] = r.slot
+                    else:
+                        token_ids[t] = self.last_tokens[r.slot]
+                    positions[t] = p
+                    new_kv_dest[t] = self._flat_dest(r.slot, p)
+                    page_indices[j] = self.page_table[r.slot]
+                    kv_lens[j] = p + 1
+                    counts["decode_key_tokens"] += p + 1
+                    cu_q_lens[j + 1] = cu_q_lens[j] + 1
+                    sample_rows[j] = t
                     sample_slot[j] = r.slot
                     sampling.append((r, j))
-                t += take
-                j += 1
-        cu_q_lens[j + 1:] = cu_q_lens[j]
-        arrays = (token_ids[None], tok_src[None], positions[None], kv_lens,
-                  page_indices, cu_q_lens, np.asarray([j], np.int32),
-                  new_kv_dest, sample_rows, sample_slot)
-        if self.state_layers:
-            arrays += (seq_slot,)
-        counts.update(self.cache_kind.counts(
-            self.host_stats, positions[:t], np.diff(cu_q_lens[:j + 1])))
-        sampled = any(r.do_sample for r, _ in sampling)
-        if sampled:
-            do_sample = np.zeros((S,), bool)
-            temperature = np.ones((S,), np.float32)
-            top_k = np.zeros((S,), np.int32)
-            top_p = np.ones((S,), np.float32)
-            seeds = np.zeros((S,), np.int32)    # per-row sampling seed (uid)
-            for r, seq_j in sampling:
-                do_sample[seq_j] = r.do_sample
-                temperature[seq_j] = r.temperature
-                top_k[seq_j] = r.top_k
-                top_p[seq_j] = r.top_p
-                seeds[seq_j] = r.uid
-            arrays += (do_sample, temperature, top_k, top_p, seeds)
+                    t += 1
+                    j += 1
+                else:                                           # prefill chunk
+                    take = takes.get(r.uid, 0)
+                    if take <= 0:
+                        continue
+                    lo = r.prefill_done
+                    token_ids[t:t + take] = r.ctx[lo:lo + take]
+                    pos = np.arange(lo, lo + take)
+                    positions[t:t + take] = pos
+                    pg = self.page_table[r.slot, pos // self.page_size]
+                    assert (pg > 0).all(), "write into unallocated page"
+                    new_kv_dest[t:t + take] = (pg * self.page_size +
+                                               pos % self.page_size)
+                    r.prefill_done += take
+                    counts["prefill_tokens"] += int(take)
+                    counts["prefill_key_tokens"] += int(
+                        take * lo + take * (take + 1) // 2)
+                    # every freshly completed full page goes to the prefix
+                    # index
+                    self.kv.publish(r, r.ctx, r.prefill_done)
+                    if trace.enabled:
+                        trace.event("prefill_chunk", cat="request",
+                                    uid=r.uid, take=int(take),
+                                    prefill_done=int(r.prefill_done),
+                                    ctx_len=int(r.ctx_len))
+                    page_indices[j] = self.page_table[r.slot]
+                    kv_lens[j] = r.prefill_done
+                    cu_q_lens[j + 1] = cu_q_lens[j] + take
+                    sample_rows[j] = t + take - 1
+                    if r.prefill_done >= r.ctx_len:
+                        self.request_latency.on_prefill_done(
+                            r.uid, r.ctx_len - r.pc_cached, r.pc_cached)
+                        sample_slot[j] = r.slot
+                        sampling.append((r, j))
+                    t += take
+                    j += 1
+            cu_q_lens[j + 1:] = cu_q_lens[j]
+            arrays = (token_ids[None], tok_src[None], positions[None], kv_lens,
+                      page_indices, cu_q_lens, np.asarray([j], np.int32),
+                      new_kv_dest, sample_rows, sample_slot)
+            if self.state_layers:
+                arrays += (seq_slot,)
+            sampled = any(r.do_sample for r, _ in sampling)
+            if sampled:
+                do_sample = np.zeros((S,), bool)
+                temperature = np.ones((S,), np.float32)
+                top_k = np.zeros((S,), np.int32)
+                top_p = np.ones((S,), np.float32)
+                seeds = np.zeros((S,), np.int32)    # per-row seed (uid)
+                for r, seq_j in sampling:
+                    do_sample[seq_j] = r.do_sample
+                    temperature[seq_j] = r.temperature
+                    top_k[seq_j] = r.top_k
+                    top_p[seq_j] = r.top_p
+                    seeds[seq_j] = r.uid
+                arrays += (do_sample, temperature, top_k, top_p, seeds)
+            leaf.add(rows=t, seqs=j, page_cells=j * self.pages_per_seq)
+        with self.host_stats.leaf("plan.counts", rows=t):
+            counts.update(self.cache_kind.counts(
+                self.host_stats, positions[:t], np.diff(cu_q_lens[:j + 1])))
         return arrays, sampled, sampling, t, counts
 
     def sample(self, sel_logits, samplers) -> int:

@@ -97,6 +97,13 @@ class Span:
         self.t0 = self._tracer.clock()
         return self
 
+    def add(self, **attrs) -> None:
+        """Stats known only once the bracketed work has run (how many a
+        sweep admitted): call inside the bracket.  They join the
+        annotation's stats and the ring event's ``args``."""
+        self._ann.set_metadata(**attrs)
+        self.attrs = {**self.attrs, **attrs} if self.attrs else attrs
+
     def __exit__(self, exc_type, exc, tb):
         tr = self._tracer
         self.dur = tr.clock() - self.t0

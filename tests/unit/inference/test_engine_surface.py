@@ -49,8 +49,8 @@ PROGRAMS = {"draft_prefill", "ragged_decode_block", "ragged_fused_step",
 STAGES = {"admissions", "admissions_ahead", "blocking_gets",
           "decode_blocks", "decode_blocks_ahead", "decode_key_token_steps",
           "device_ms", "device_wait_s", "dispatch_ms", "dispatches",
-          "draft_ms", "drains", "fused_head", "fused_ticks",
-          "fused_ticks_ahead", "harvest_ms", "harvests",
+          "draft_ms", "drains", "fused_head", "fused_tick_host",
+          "fused_ticks", "fused_ticks_ahead", "harvest_ms", "harvests",
           "host_bound_fraction", "host_s",
           "kernels", "meta_uploads", "pipeline_window", "plan_ms", "pool",
           "prefill_key_tokens", "prefill_tokens", "prefix_ms", "reap_s",

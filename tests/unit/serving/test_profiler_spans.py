@@ -37,6 +37,9 @@ K = 4                           # decode block
 LEAVES = ("engine.plan", "engine.upload", "engine.rng_split",
           "engine.dispatch", "engine.fetch", "engine.harvest",
           "engine.reap")
+# pieces of ``engine.plan``, bracketed inside it (test_plan_leaves.py)
+PLAN_LEAVES = ("engine.plan.admit", "engine.plan.grow", "engine.plan.rows",
+               "engine.plan.pack", "engine.plan.counts", "engine.plan.attrs")
 COUNTERS = ("prefill_tokens", "prefill_key_tokens",
             "decode_key_token_steps", "dispatches", "ticks", "meta_uploads")
 
@@ -117,17 +120,20 @@ def _inside(child, parent):
 
 def test_host_plane_holds_every_span_of_the_table(profiled):
     worker = {e[0] for e in profiled["worker"]}
-    assert worker == {"replica.op", "engine.step", *LEAVES}
+    assert worker == {"replica.op", "engine.step", *LEAVES, *PLAN_LEAVES}
     pump = {e[0] for e in profiled["pump"]}
     assert pump == {"router.pump", "router.fold", "router.dispatch"}
     kinds = {e[3]["kind"] for e in profiled["worker"]
              if e[0] == "replica.op"}
     assert {"put", "step"} <= kinds
     # a count rides on a span only where something reads it: the op's
-    # kind, and the dispatched program's work (the two tests below)
+    # kind, the dispatched program's work (the two tests below), and what
+    # explains a plan leaf's cost (``fused_tick_leaves``, the log line of
+    # ``benchmark/layer_metrics/fused_tick_host_ms.py``)
     assert all(e[3] == {} for e in profiled["worker"] + profiled["pump"]
                if e[0] not in ("replica.op", "engine.dispatch",
-                               "router.dispatch")), "an unread count"
+                               "router.dispatch", *PLAN_LEAVES)
+               ), "an unread count"
 
 
 def test_request_intake_is_a_span_of_the_thread_that_submits(profiled):
