@@ -7,7 +7,14 @@ published widths, 69,120 bytes a layer a sequence) and a ``dk x dv`` float32
 matrix a head, corrected token by token (``ops/gated_delta_rule.py``):
 ``[max_seqs, dk, heads * dv]``, heads along the lanes, 2,211,840 bytes a layer
 a sequence at 30 heads of 96 x 192 and stored as counted (``[.., heads, 96,
-192]`` would be stored as ``[.., 96, 256]``, 2,949,120)."""
+192]`` would be stored as ``[.., 96, 256]``, 2,949,120).
+
+Inside :func:`ragged_gated_delta` a fused tick's rows are 2-D, ``[T, H * d]``
+(rows down, a head a lane block), from the first line to the last, the way
+the projections leave them and ``o_proj`` takes them, as in
+``linear_state.py:ragged_linear_attention`` and for its reason: on the chip
+every reshape to ``[T, H, d]`` or back is two relayout copies, a transpose to
+heads first a third, and a scatter back writes a row at a time."""
 from __future__ import annotations
 
 from typing import Dict
@@ -58,7 +65,10 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     other batch (a fused tick) is separated by ``cu_q_lens``: sequences of
     two rows or more take the chunked scan over tiles of their own
     (``ops/linear_attention.py:tile_layout``), sequences of one row the same
-    one-token step; the two write disjoint rows and slots."""
+    one-token step; the two write disjoint rows and slots.  A tick's rows
+    stay ``[T, H * d]`` all the way (a head a lane block, as the projections
+    leave them and ``o_proj`` takes them): one gather an operand to the
+    tiles' rows, one gather back, no transpose to heads first."""
     T, H, dk = q.shape
     dv = v.shape[-1]
     cu = ragged_meta["cu_q_lens"]
@@ -69,13 +79,14 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     take = lambda a, rows: jnp.take(a, rows, axis=0)
     g, b = g.astype(jnp.float32), b.astype(jnp.float32)
 
-    def one_token(rows, slots, on, state_now):
+    def one_token(rows, slots, on, state_now, pick=take):
         """The one-token step for the sequences where ``on`` holds: their
-        rows of q, k, v and the gates, their slots' states in place."""
+        rows of q, k, v (by ``pick``) and the gates, their slots' states in
+        place."""
         order, n = la.live_first(on)
         rows, slots = take(rows, order), take(slots, order)
         o, new = gd.decode_step(
-            take(q, rows), take(k, rows), take(v, rows), take(g, rows),
+            pick(q, rows), pick(k, rows), pick(v, rows), take(g, rows),
             take(b, rows), state_now, slots, take(pos, rows) == 0, n)
         return rows, jnp.arange(S) < n[0], o, new
 
@@ -92,18 +103,24 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     one, first = _one_row_seqs(ragged_meta, T)
     first_pos = jnp.take(pos, first)
     lay = la.tile_layout(cu, ragged_meta["num_seqs"], first_pos, T, gd.CHUNK)
-    # heads first; a tile row that holds no token is zeros: g = b = 0
-    tiles = lambda a: la.to_tiles(a, lay["rows"]).swapaxes(0, 1)
+    # rows as the projections leave them, ``[T, H * d]``, a head a lane
+    # block, from here to the last line: no operand is re-tiled by head
+    flat = lambda a: a.reshape(a.shape[0], -1)
+    tiles = lambda a: la.rows_to_tiles(flat(a), lay["rows"])
     o_t, st = gd.chunk_scan(
-        tiles(q), tiles(k), tiles(v), tiles(g), tiles(b), state.value,
-        jnp.take(seq_slot, lay["seq"]), lay["init"], lay["len"])
-    o = la.from_tiles(o_t.swapaxes(0, 1), lay["rows"], T)
+        tiles(q), tiles(k), tiles(v),
+        # a tile row that holds no token: g = b = 0
+        la.to_tiles(g, lay["rows"]), la.to_tiles(b, lay["rows"]),
+        state.value, jnp.take(seq_slot, lay["seq"]), lay["init"], lay["len"])
+    o = la.tiles_to_rows(o_t, lay["rows"], T)
     # the one-row sequences, each on its slot's state where it lies
     live = ragged_meta.get("live")
     if live is not None:
         one = jnp.logical_and(one, jnp.take(live, first))
-    rows, on, o_one, state.value = one_token(first, seq_slot, one, st)
-    return o.at[jnp.where(on, rows, T)].set(o_one, mode="drop")
+    by_row = lambda a, rows: take(flat(a), rows).reshape((S,) + a.shape[1:])
+    rows, on, o_one, state.value = one_token(first, seq_slot, one, st, by_row)
+    o = o.at[jnp.where(on, rows, T)].set(flat(o_one), mode="drop")
+    return o.reshape(T, H, dv)
 
 
 class DeltaState(SlotState):

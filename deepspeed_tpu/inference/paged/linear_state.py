@@ -2,7 +2,15 @@
 (``models/minicpm_sala.py``), which keeps a decaying ``d x d`` float32 matrix
 a head instead of keys and values: ``[max_seqs, H, d, d]`` under
 ``tree.py:SLOT_STATE`` with ``conv_state.py``'s contract
-(``ops/linear_attention.py``): 2 MiB a layer a sequence at 32 heads of 128."""
+(``ops/linear_attention.py``): 2 MiB a layer a sequence at 32 heads of 128.
+
+Inside :func:`ragged_linear_attention` a fused tick's rows are 2-D, ``[T, H *
+d]`` (rows down, a head a lane block), from the first line to the last, the
+way the projections leave them and ``o_proj`` takes them: on the chip every
+reshape to ``[T, H, d]`` or back is two relayout copies, a transpose to heads
+first a third, and a scatter back writes a row at a time.  The ``[T, H, d]``
+of the signature is undone at the door and restored on the last line, so XLA
+computes the model's per-head norms where the rows lie."""
 from __future__ import annotations
 
 from typing import Dict
@@ -47,7 +55,10 @@ def ragged_linear_attention(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     two rows or more take the chunked scan over tiles of their own
     (:func:`~deepspeed_tpu.ops.linear_attention.tile_layout`), sequences of
     one row the same one-token step; the two write disjoint rows and
-    slots."""
+    slots.  A tick's rows stay ``[T, H * d]`` all the way (a head a lane
+    block, as the projections leave them and ``o_proj`` takes them): one
+    gather an operand to the tiles' rows, one gather back, no transpose to
+    heads first."""
     T, H, d = q.shape
     cu = ragged_meta["cu_q_lens"]
     S = cu.shape[0] - 1
@@ -56,13 +67,13 @@ def ragged_linear_attention(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     la = linear_attention
     take = lambda a, rows: jnp.take(a, rows, axis=0)
 
-    def one_token(rows, slots, on, state_now):
+    def one_token(rows, slots, on, state_now, pick=take):
         """The one-token step for the sequences where ``on`` holds: their
-        rows of q, k, v, their slots' states in place."""
+        rows of q, k, v (by ``pick``), their slots' states in place."""
         order, n = la.live_first(on)
         rows, slots = take(rows, order), take(slots, order)
         o, new = la.decode_step(
-            take(q, rows), take(k, rows), take(v, rows), log_decay,
+            pick(q, rows), pick(k, rows), pick(v, rows), log_decay,
             state_now, slots, take(pos, rows) == 0, n)
         return rows, jnp.arange(S) < n[0], o, new
 
@@ -80,17 +91,22 @@ def ragged_linear_attention(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     first_pos = jnp.take(pos, first)
     lay = la.tile_layout(cu, ragged_meta["num_seqs"], first_pos, T,
                          la.CHUNK)
-    tiles = lambda a: la.to_tiles(a, lay["rows"]).transpose(1, 0, 2)
+    # rows as the projections leave them, ``[T, H * d]``, a head a lane
+    # block, from here to the last line: no operand is re-tiled by head
+    flat = lambda a: a.reshape(a.shape[0], -1)
+    tiles = lambda a: la.rows_to_tiles(flat(a), lay["rows"])
     o_t, st = la.chunk_scan(
         tiles(q), tiles(k), tiles(v), log_decay, state.value,
         jnp.take(seq_slot, lay["seq"]), lay["init"], lay["len"])
-    o = la.from_tiles(o_t.transpose(1, 0, 2), lay["rows"], T)
+    o = la.tiles_to_rows(o_t, lay["rows"], T)
     # the one-row sequences, each on its slot's state where it lies
     live = ragged_meta.get("live")
     if live is not None:
         one = jnp.logical_and(one, jnp.take(live, first))
-    rows, on, o_one, state.value = one_token(first, seq_slot, one, st)
-    return o.at[jnp.where(on, rows, T)].set(o_one, mode="drop")
+    by_row = lambda a, rows: take(flat(a), rows).reshape((S,) + a.shape[1:])
+    rows, on, o_one, state.value = one_token(first, seq_slot, one, st, by_row)
+    o = o.at[jnp.where(on, rows, T)].set(flat(o_one), mode="drop")
+    return o.reshape(T, H, d)
 
 
 class LinearState(SlotState):

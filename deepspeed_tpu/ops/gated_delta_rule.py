@@ -35,8 +35,14 @@ serve it (``paged/delta_state.py:ragged_gated_delta``):
   number.  The rows of a packed ragged batch are laid out
   in tiles of ``C`` by ``linear_attention.tile_layout`` (a tile is of one
   sequence; a head's state rides a sequence's tiles in the output block;
-  ``LOAD`` / ``ZERO`` / carry as there); a tile row that holds no token has
-  ``g = b = 0`` and zero q, k, v, which leaves state and solve untouched.
+  ``LOAD`` / ``ZERO`` / carry as there), each row AS THE PROJECTIONS LEAVE
+  IT: ``q, k [NT * C, H * dk]``, ``v, o [NT * C, H * dv]``, rows down, a
+  head a lane block.  Nothing is transposed to heads first and nothing
+  comes back: a grid step's block is ``C`` rows of its heads' lanes, and a
+  head is a static lane slice of it, as it is of the state's block.  A
+  tile row at or past the tile's ``len`` holds no token: its ``g`` and ``b``
+  come as zeros and the kernel zeroes its q, k, v whatever the tile holds
+  there, which leaves state and solve untouched.
 - :func:`decode_step` (kernel ``delta_decode_step``): one token a row, the
   state updated where it lies (``linear_attention.live_first``: live rows
   only, an idle slot's state does not move), on the vector unit alone:
@@ -49,7 +55,11 @@ serve it (``paged/delta_state.py:ragged_gated_delta``):
 ``[.., 96, 192]`` is stored as ``[.., 96, 256]`` (a third more to keep, read
 and write).  Heads side by side along the lanes are ``30 x 192 = 45`` whole
 tiles; a grid step takes an even number of heads (``2 x 192 = 3`` tiles) and
-a head's columns are a lane slice of the block.
+a head's columns are a lane slice of the block.  **A tile's rows** are blocked
+the same way: v and o ``(C, 2 x 192)``, whole lane tiles; q and k ``(C, 4 x
+96)``, the lanes of FOUR heads (``2 x 96 = 192`` is no whole number of lane
+tiles), of which the step's parity takes its two; the fifteenth pair's block
+lies half past the row's end, and that half is never read.
 
 Products that touch the state, and the solve, are float32 at
 ``Precision.HIGHEST``; ``K K^T`` and ``Q K^T`` multiply the operands as
@@ -61,6 +71,7 @@ recorded under :data:`KERNEL_SCAN` / :data:`KERNEL_STEP` (``ops/routes.py``).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -84,8 +95,12 @@ _LANES = 128
 # grow with C); on the v5e a tick of 1,024 rows took 1.71 ms a layer at 64
 # and 1.52 at 128, half the grid steps (PERF.md, PR 50)
 CHUNK = 128
-# heads a grid step holds: of the scan (two chains of products to overlap),
-# of the decode step (432 KiB of state in, the same out, double-buffered)
+# heads a grid step holds: of the scan (two chains of products to overlap,
+# and 2 x 192 lanes of v, o and the state are three whole lane tiles; q's and
+# k's 2 x 96 are not, so their block holds two steps' heads: ``_scan_blocks``.
+# Four heads a step, whose 4 x 96 are whole tiles, ran 3 % slower on the
+# v5e: PERF.md, PR 54), of the decode step (432 KiB of state in, the same
+# out, double-buffered)
 _SCAN_HEADS = 2
 _STEP_HEADS = 6
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -123,14 +138,18 @@ def recurrence(q, k, v, g, b, state):
     return o, state
 
 
-def _chunk_math(q, k, v, aux, s_in):
+def _chunk_math(q, k, v, aux, s_in, n):
     """One tile of one head: ``q, k [C, dk]``, ``v [C, dv]``, ``aux [2, C]``
     float32 (row 0: ``G``, the running sum of ``g`` over the tile; row 1:
-    ``b``), ``s_in [dk, dv]`` float32 -> ``(o [C, dv], s_out)``.  Shared by
-    the kernel body and the twin."""
+    ``b``), ``s_in [dk, dv]`` float32, ``n`` live rows -> ``(o [C, dv],
+    s_out)``.  Shared by the kernel body and the twin."""
     C = q.shape[0]
     i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    # a row at or past ``n`` holds no token, whatever the tile holds there
+    # (its g and b come as zeros)
+    live = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0) < n
+    q, k, v = (jnp.where(live, x, jnp.zeros_like(x)) for x in (q, k, v))
     g_row, b_row = aux[0:1, :], aux[1:2, :]                   # [1, C]
     # a row of lanes as a column of sublanes, without a transpose
     column = lambda r: jnp.sum(jnp.where(i == j, r, 0.0), axis=1,
@@ -161,10 +180,11 @@ def _chunk_math(q, k, v, aux, s_in):
 
 
 def _aux(g, b, chunk: int):
-    """``g, b [H, NT * C]`` -> ``[H, NT, 2, C]`` float32: the running sum of
+    """``g, b [NT * C, H]`` -> ``[H, NT, 2, C]`` float32: the running sum of
     ``g`` over each tile, and ``b``."""
-    H, rows = g.shape
-    tiles = lambda a: a.astype(jnp.float32).reshape(H, rows // chunk, chunk)
+    rows, H = g.shape
+    tiles = lambda a: a.astype(jnp.float32).T.reshape(H, rows // chunk,
+                                                      chunk)
     return jnp.stack([jnp.cumsum(tiles(g), axis=-1), tiles(b)], axis=2)
 
 
@@ -183,10 +203,11 @@ def _heads_along_lanes(state):
 def scan_twin(q, k, v, g, b, state, slot, init, length, *,
               chunk: int = CHUNK):
     """The XLA twin of :func:`chunk_scan`, tile by tile."""
-    H, rows, dk = q.shape
+    rows, H = g.shape
     NT = rows // chunk
-    tiles = lambda a: a.reshape(H, NT, chunk, -1).transpose(1, 0, 2, 3)
-    math = jax.vmap(_chunk_math)
+    # [NT * C, H * d] -> [NT, H, C, d]
+    tiles = lambda a: a.reshape(NT, chunk, H, -1).transpose(0, 2, 1, 3)
+    every_head = jax.vmap(_chunk_math, in_axes=(0, 0, 0, 0, 0, None))
     state = _heads_first(state, H)
 
     def step(carry, x):
@@ -194,7 +215,7 @@ def scan_twin(q, k, v, g, b, state, slot, init, length, *,
         q_n, k_n, v_n, aux_n, slot_n, init_n, len_n = x
         s_in = jnp.where(init_n == LOAD, state[slot_n],
                          jnp.where(init_n == ZERO, 0.0, run))
-        o, s_out = math(q_n, k_n, v_n, aux_n, s_in)
+        o, s_out = every_head(q_n, k_n, v_n, aux_n, s_in, len_n)
         live = jnp.logical_or(len_n > 0, init_n == LOAD)
         s_out = jnp.where(len_n > 0, s_out, s_in)
         state = state.at[jnp.where(live, slot_n, state.shape[0])].set(
@@ -205,7 +226,7 @@ def scan_twin(q, k, v, g, b, state, slot, init, length, *,
         step, (state, jnp.zeros_like(state[0])),
         (tiles(q), tiles(k), tiles(v),
          _aux(g, b, chunk).transpose(1, 0, 2, 3), slot, init, length))
-    o = o.transpose(1, 0, 2, 3).reshape(H, rows, -1).astype(v.dtype)
+    o = o.transpose(0, 2, 1, 3).reshape(rows, -1).astype(v.dtype)
     return o, _heads_along_lanes(state)
 
 
@@ -230,14 +251,16 @@ def decode_step_twin(q, k, v, g, b, state, slot, fresh, n):
 # -- the kernels --------------------------------------------------------------
 
 def _scan_kernel(slot_ref, init_ref, len_ref,                # scalar prefetch
-                 q_ref, k_ref, v_ref, aux_ref, s_in_ref, o_ref, s_out_ref):
-    # q, k: [hb, C, dk]; v, o: [hb, C, dv]; aux: [hb, 1, 2, C];
-    # s_in, s_out: [1, dk, hb * dv].  The output state block is the running
-    # state: its index (slot, head block) is the same for every tile of a
-    # sequence
-    n = pl.program_id(1)
+                 q_ref, k_ref, v_ref, aux_ref, s_in_ref, o_ref, s_out_ref,
+                 *, parts: int):
+    # q, k: [C, parts * hb * dk] (or the whole row); v, o: [C, hb * dv];
+    # aux: [hb, 1, 2, C]; s_in, s_out: [1, dk, hb * dv].  A head is a lane
+    # slice of each.  The output state block is the running state: its
+    # index (slot, head block) is the same for every tile of a sequence
+    block, n = pl.program_id(0), pl.program_id(1)
     init, length = init_ref[n], len_ref[n]
-    hb, dv = v_ref.shape[0], v_ref.shape[2]
+    hb, dk = aux_ref.shape[0], s_out_ref.shape[1]
+    dv = v_ref.shape[1] // hb
 
     @pl.when(init == LOAD)
     def _():
@@ -247,13 +270,27 @@ def _scan_kernel(slot_ref, init_ref, len_ref,                # scalar prefetch
     def _():
         s_out_ref[...] = jnp.zeros_like(s_out_ref)
 
+    def of_step(ref):
+        """The lanes of a q/k block that are this step's heads'."""
+        if parts == 1:
+            return ref[...]
+        wide, lanes = ref[...], hb * dk
+        mine = wide[:, :lanes]
+        for part in range(1, parts):
+            mine = jnp.where(block % parts == part,
+                             wide[:, part * lanes:(part + 1) * lanes], mine)
+        return mine
+
     @pl.when(length > 0)
     def _():
+        q, k = of_step(q_ref), of_step(k_ref)
         for h in range(hb):
+            of_qk = slice(h * dk, (h + 1) * dk)
             lanes = slice(h * dv, (h + 1) * dv)
-            o, s_out = _chunk_math(q_ref[h], k_ref[h], v_ref[h],
-                                   aux_ref[h, 0], s_out_ref[0, :, lanes])
-            o_ref[h] = o.astype(o_ref.dtype)
+            o, s_out = _chunk_math(q[:, of_qk], k[:, of_qk], v_ref[:, lanes],
+                                   aux_ref[h, 0], s_out_ref[0, :, lanes],
+                                   length)
+            o_ref[:, lanes] = o.astype(o_ref.dtype)
             s_out_ref[0, :, lanes] = s_out
 
     @pl.when(length == 0)
@@ -261,27 +298,38 @@ def _scan_kernel(slot_ref, init_ref, len_ref,                # scalar prefetch
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def _scan_blocks(heads: int, dk: int):
+    """``(hb, parts)``: the heads a grid step holds, and the steps that
+    share one q/k block: ``hb * dk`` lanes are a block of their own where
+    they are whole lane tiles, else ``parts`` steps' heads side by side are
+    (two of 192 lanes at the published 96), or the whole row."""
+    hb = min(_SCAN_HEADS, heads)
+    parts = _LANES // math.gcd(hb * dk, _LANES)
+    return hb, min(parts, -(-heads // hb))
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"),
                    donate_argnums=(4,))
 def _scan(q, k, v, aux, state, slot, init, length, *, chunk: int,
           interpret: bool):
-    H, rows, dk = q.shape
-    dv = v.shape[2]
-    hb, C = _SCAN_HEADS, chunk
-    NT = rows // C
-    tile = lambda d: pl.BlockSpec((hb, C, d), lambda h, n, *_: (h, n, 0))
+    H, C = aux.shape[0], chunk
+    dk, dv = q.shape[1] // H, v.shape[1] // H
+    NT = q.shape[0] // C
+    hb, parts = _scan_blocks(H, dk)
+    qk = pl.BlockSpec((C, parts * hb * dk), lambda h, n, *_: (n, h // parts))
+    vo = pl.BlockSpec((C, hb * dv), lambda h, n, *_: (n, h))
     block = pl.BlockSpec((1, dk, hb * dv),
                          lambda h, n, slot, *_: (slot[n], 0, h))
     return pl.pallas_call(
-        _scan_kernel,
+        functools.partial(_scan_kernel, parts=parts),
         out_shape=(jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            in_specs=[tile(dk), tile(dk), tile(dv),
+            in_specs=[qk, qk, vo,
                       pl.BlockSpec((hb, 1, 2, C),
                                    lambda h, n, *_: (h, n, 0, 0)), block],
-            out_specs=(tile(dv), block),
+            out_specs=(vo, block),
             grid=(H // hb, NT)),
         # operands are counted with the scalar-prefetch arrays: the state
         # is the eighth, and becomes the second result
@@ -294,25 +342,29 @@ def _scan(q, k, v, aux, state, slot, init, length, *, chunk: int,
 
 def scan_eligible(heads: int, dk: int, dv: int, dtype) -> bool:
     """Mosaic's layout: bf16 operands, whole sublane tiles of keys, and a
-    grid step's heads a whole number of lane tiles side by side."""
+    grid step's heads a whole number of lane tiles side by side (or all the
+    heads there are)."""
+    hb, _ = _scan_blocks(heads, dk)
     return (jnp.dtype(dtype) == jnp.bfloat16 and dk % 8 == 0
-            and heads % _SCAN_HEADS == 0
-            and (_SCAN_HEADS * dv) % _LANES == 0)
+            and heads % hb == 0
+            and ((hb * dv) % _LANES == 0 or hb == heads))
 
 
 def chunk_scan(q, k, v, g, b, state, slot, init, length, *,
                chunk: int = CHUNK, interpret: bool = False
                ) -> Tuple[jax.Array, jax.Array]:
     """The multi-row sequences of a tick in ``linear_attention.tile_layout``'s
-    tiles of ``chunk`` rows: ``q, k [H, NT * C, dk]``, ``v [H, NT * C, dv]``,
-    ``g, b [H, NT * C]`` (the log of a token's decay, at most 0, and the
+    tiles of ``chunk`` rows, each row as the projections leave it (a head a
+    lane block): ``q, k [NT * C, H * dk]``, ``v [NT * C, H * dv]``, ``g, b
+    [NT * C, H]`` (the log of a token's decay, at most 0, and the
     correction's strength; both 0 where a tile row holds no token), ``state
     [slots, dk, H * dv]`` float32, a tile's ``slot``, ``init`` and live rows
-    ``length`` (``[NT]``) -> ``(o [H, NT * C, dv], state)``.  The Pallas
+    ``length`` (``[NT]``) -> ``(o [NT * C, H * dv], state)``.  The Pallas
     kernel on a TPU, else the twin; recorded under :data:`KERNEL_SCAN`."""
-    H, _, dk = q.shape
+    H = g.shape[1]
+    dk, dv = q.shape[1] // H, v.shape[1] // H
     route = routes.take(
-        KERNEL_SCAN, eligible=scan_eligible(H, dk, v.shape[2], q.dtype),
+        KERNEL_SCAN, eligible=scan_eligible(H, dk, dv, q.dtype),
         interpret=interpret, pallas=f"pallas-delta-scan-{chunk}")
     if route == routes.XLA:
         return scan_twin(q, k, v, g, b, state, slot, init, length,

@@ -18,11 +18,16 @@ Two forms serve it (``paged/linear_state.py:ragged_linear_attention``):
   of two powers: the fastest head's ``l^128`` is 1e-47 and its inverse
   overflows.  The rows of a packed ragged batch are laid out in **tiles**
   first (:func:`tile_layout`): each multi-row sequence's rows gathered to
-  whole tiles of ``C``, so a tile belongs to one sequence, the grid is
-  ``(heads, tiles)`` and a head's state rides the tiles of a sequence in
+  whole tiles of ``C``, each row AS THE PROJECTIONS LEAVE IT (``[NT * C, H
+  * d]``: rows down, a head a lane block of 128; nothing is transposed to
+  heads first and nothing comes back), so a tile belongs to one sequence,
+  the grid is ``(head blocks, tiles)``, a step's block is ``C`` rows of
+  its heads' lanes, and a head's state rides the tiles of a sequence in
   the output block itself; a sequence's first tile reads its slot's state
-  (or zeros at position 0), the last leaves it there.  The state array is
-  donated (``input_output_aliases``): slots with no tile keep theirs.
+  (or zeros at position 0), the last leaves it there.  A tile row at or
+  past the tile's ``len`` holds no token and is zeroed in the kernel,
+  whatever the tile holds there (:func:`rows_to_tiles`).  The state array
+  is donated (``input_output_aliases``): slots with no tile keep theirs.
 - :func:`decode_step` (kernel ``linear_decode_step``): one token a row,
   ``2 x d x d x 4`` bytes read and written a head a row and a few hundred
   flops: bandwidth-bound.  The state is updated where it lies (the block
@@ -63,8 +68,11 @@ _LANES = 128
 # tokens a tile of the scan holds: one MXU tile of rows, and the width the
 # decay table ``D`` is built at
 CHUNK = 128
-# heads a grid step of the decode kernel holds (512 KiB of state in, the
-# same out, double-buffered)
+# heads a grid step holds: of the scan (a head's tile is half a microsecond
+# of products, so a step of one head was mostly the step itself; the four
+# heads' lanes of a row are one block of 512), of the decode kernel (512 KiB
+# of state in, the same out, double-buffered)
+_SCAN_HEADS = 4
 _STEP_HEADS = 8
 _HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -128,6 +136,27 @@ def from_tiles(y: jax.Array, rows: jax.Array, T: int) -> jax.Array:
         y, mode="drop")
 
 
+def rows_to_tiles(x: jax.Array, rows: jax.Array) -> jax.Array:
+    """:func:`to_tiles` of ``x [T, W]`` for a consumer that zeroes what lies
+    at or past a tile's ``len`` itself (the chunked scans do): a tile row
+    that holds no token holds the batch's last row, and the gather is one
+    pass where the fill is a second."""
+    return jnp.take(x, rows, axis=0, mode="clip")
+
+
+def tiles_to_rows(y: jax.Array, rows: jax.Array, T: int) -> jax.Array:
+    """:func:`from_tiles` of ``y [NT * tile, W]`` as a GATHER (a scatter
+    writes a row at a time): packed row ``t`` reads the tile row that holds
+    it, and a row that no tile holds reads ``y``'s last row, which is zeros
+    where the producer leaves a tile without a token zero: :func:`tile_layout`
+    bounds the tiles by ``ceil(T / tile) + seqs``, more than can hold a
+    token, so the last is never live."""
+    n = rows.shape[0]
+    held = jnp.full((T,), n - 1, jnp.int32).at[rows].set(
+        jnp.arange(n, dtype=jnp.int32), mode="drop")
+    return jnp.take(y, held, axis=0, mode="clip")
+
+
 # -- the definition and the twins --------------------------------------------
 
 def recurrence(q, k, v, log_decay, state):
@@ -151,6 +180,9 @@ def _chunk_math(q, k, v, lam, s_in, n):
     C = q.shape[0]
     i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    # a row at or past ``n`` holds no token, whatever the tile holds there
+    q, k, v = (jnp.where(row < n, x, jnp.zeros_like(x)) for x in (q, k, v))
     delta = (i - j).astype(jnp.float32)
     table = jnp.where(i >= j, jnp.exp(lam * jnp.maximum(delta, 0.0)), 0.0)
     a = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -158,7 +190,6 @@ def _chunk_math(q, k, v, lam, s_in, n):
     vf = v.astype(jnp.float32)
     o = jnp.dot(a * table, vf, precision=_HIGHEST,
                 preferred_element_type=jnp.float32)
-    row = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
     rowf = row.astype(jnp.float32)
     o = o + jnp.dot(q.astype(jnp.float32) * jnp.exp(lam * (rowf + 1.0)),
                     s_in, precision=_HIGHEST,
@@ -174,10 +205,10 @@ def _chunk_math(q, k, v, lam, s_in, n):
 
 def scan_twin(q, k, v, log_decay, state, slot, init, length):
     """The XLA twin of :func:`chunk_scan`, tile by tile."""
-    H, rows, d = q.shape
-    C = CHUNK
-    NT = rows // C
-    tiles = lambda a: a.reshape(H, NT, C, d).transpose(1, 0, 2, 3)
+    rows, C = q.shape[0], CHUNK
+    H, NT = log_decay.shape[0], rows // C
+    # [NT * C, H * d] -> [NT, H, C, d]
+    tiles = lambda a: a.reshape(NT, C, H, -1).transpose(0, 2, 1, 3)
     lam = log_decay.astype(jnp.float32)
     math = jax.vmap(_chunk_math, in_axes=(0, 0, 0, 0, 0, None))
 
@@ -196,7 +227,7 @@ def scan_twin(q, k, v, log_decay, state, slot, init, length):
     (state, _), o = jax.lax.scan(
         step, (state, jnp.zeros_like(state[0])),
         (tiles(q), tiles(k), tiles(v), slot, init, length))
-    return o.transpose(1, 0, 2, 3).reshape(H, rows, d).astype(q.dtype), state
+    return o.transpose(0, 2, 1, 3).reshape(rows, -1).astype(q.dtype), state
 
 
 def decode_step_twin(q, k, v, log_decay, state, slot, fresh, n):
@@ -217,11 +248,13 @@ def decode_step_twin(q, k, v, log_decay, state, slot, fresh, n):
 
 def _scan_kernel(slot_ref, init_ref, len_ref,                # scalar prefetch
                  q_ref, k_ref, v_ref, lam_ref, s_in_ref, o_ref, s_out_ref):
-    # q, k, v, o: [1, C, d]; lam: [1, 1, 128]; s_in, s_out: [1, 1, d, d].
-    # The output state block is the running state: its index (slot, head)
-    # is the same for every tile of a sequence
+    # q, k, v, o: [C, hb * d], the lanes of the step's heads of a tile's
+    # rows; lam: [hb, 1, 128]; s_in, s_out: [1, hb, d, d].  The output state
+    # block is the running state: its index (slot, head block) is the same
+    # for every tile of a sequence
     n = pl.program_id(1)
     init, length = init_ref[n], len_ref[n]
+    hb, d = s_out_ref.shape[1], s_out_ref.shape[2]
 
     @pl.when(init == LOAD)
     def _():
@@ -233,25 +266,34 @@ def _scan_kernel(slot_ref, init_ref, len_ref,                # scalar prefetch
 
     @pl.when(length > 0)
     def _():
-        lam = lam_ref[0][:, :1]                               # [1, 1]
-        o, s_out = _chunk_math(q_ref[0], k_ref[0], v_ref[0], lam,
-                               s_out_ref[0, 0], length)
-        o_ref[0] = o.astype(o_ref.dtype)
-        s_out_ref[0, 0] = s_out
+        for h in range(hb):
+            lanes = slice(h * d, (h + 1) * d)
+            lam = lam_ref[h][:, :1]                           # [1, 1]
+            o, s_out = _chunk_math(q_ref[:, lanes], k_ref[:, lanes],
+                                   v_ref[:, lanes], lam, s_out_ref[0, h],
+                                   length)
+            o_ref[:, lanes] = o.astype(o_ref.dtype)
+            s_out_ref[0, h] = s_out
 
     @pl.when(length == 0)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
+def scan_heads(heads: int) -> int:
+    """Heads a grid step of the scan holds: as many of :data:`_SCAN_HEADS`
+    as divide the heads."""
+    return next(hb for hb in (_SCAN_HEADS, 2, 1) if heads % hb == 0)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",),
                    donate_argnums=(4,))
 def _scan(q, k, v, lam, state, slot, init, length, *, interpret: bool):
-    H, rows, d = q.shape
-    C = CHUNK
-    NT = rows // C
-    tile = pl.BlockSpec((1, C, d), lambda h, n, *_: (h, n, 0))
-    block = pl.BlockSpec((1, 1, d, d),
+    H, d, C = lam.shape[0], state.shape[-1], CHUNK
+    NT = q.shape[0] // C
+    hb = scan_heads(H)
+    tile = pl.BlockSpec((C, hb * d), lambda h, n, *_: (n, h))
+    block = pl.BlockSpec((1, hb, d, d),
                          lambda h, n, slot, *_: (slot[n], h, 0, 0))
     return pl.pallas_call(
         _scan_kernel,
@@ -260,10 +302,10 @@ def _scan(q, k, v, lam, state, slot, init, length, *, interpret: bool):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[tile, tile, tile,
-                      pl.BlockSpec((1, 1, _LANES),
+                      pl.BlockSpec((hb, 1, _LANES),
                                    lambda h, n, *_: (h, 0, 0)), block],
             out_specs=(tile, block),
-            grid=(H, NT)),
+            grid=(H // hb, NT)),
         # operands are counted with the scalar-prefetch arrays: the state
         # is the eighth, and becomes the second result
         input_output_aliases={7: 1},
@@ -285,12 +327,13 @@ def scan_eligible(d: int, dtype) -> bool:
 
 def chunk_scan(q, k, v, log_decay, state, slot, init, length, *,
                interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """The multi-row sequences of a tick in :func:`tile_layout`'s tiles:
-    ``q, k, v [H, NT * C, d]``, ``state [slots, H, d, d]`` float32, a
-    tile's ``slot``, ``init`` and live rows ``length`` (``[NT]``) ->
-    ``(o [H, NT * C, d], state)``.  The Pallas kernel on a TPU, else the
-    twin; recorded under :data:`KERNEL_SCAN`."""
-    d = q.shape[-1]
+    """The multi-row sequences of a tick in :func:`tile_layout`'s tiles,
+    each row as the projections leave it (a head a lane block): ``q, k, v
+    [NT * C, H * d]``, ``state [slots, H, d, d]`` float32, a tile's
+    ``slot``, ``init`` and live rows ``length`` (``[NT]``) -> ``(o [NT * C,
+    H * d], state)``.  The Pallas kernel on a TPU, else the twin; recorded
+    under :data:`KERNEL_SCAN`."""
+    d = state.shape[-1]
     route = routes.take(KERNEL_SCAN, eligible=scan_eligible(d, q.dtype),
                         interpret=interpret,
                         pallas=f"pallas-linear-scan-{CHUNK}")

@@ -21,7 +21,14 @@ carried rows a sequence and no longer a token where a call has more rows
 than sequences; a decode block, a row a sequence, is the parent's to the
 letter).  It also added the two programs of ``lfm2-24b-a2b-serve``, the
 function's other caller: ``lfm2.decode_block`` taken on the parent, which
-this tree reads too, ``lfm2.fused`` on its own tree.
+this tree reads too, ``lfm2.fused`` on its own tree.  PR 54 re-took
+``olmo_hybrid.fused`` and ``sala.fused`` and no other: it MEANT to move them
+(the chunked scans take a tick's rows ``[rows, H * d]`` as the projections
+leave them, gathered to tiles and back as 2-D rows, where every operand was
+transposed to heads first and ``o`` back); both decode blocks, whose rows
+take the one-token step, are the parent's to the letter (PR 53, the same
+change refused on a run of a cell it cannot reach, had read the same two
+values).
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -60,7 +67,11 @@ EXPECTED = {
     # the chosen blocks a block at a time (``[Hkv, NT, blocks, 128]``) where
     # it took a mask a key (``[Hkv, rows, keys]``); the decode block, whose
     # rows take the chosen-page kernel, is PR 43's to the letter
-    "sala.fused": "2e90302b3dc12e5f",
+    # ``sala.fused`` re-taken by PR 54, which MEANT to move it
+    # (2e90302b3dc12e5f before it): ``ragged_linear_attention`` keeps a
+    # tick's rows ``[T, H * d]`` from the tiles' gather to the gather back
+    # and transposes nothing to heads first
+    "sala.fused": "613d88dcd69cc94d",
     "sala.decode_block": "86bf79c9930ecd7c",
     # taken by PR 47, which added the model; re-taken by PR 48 (above).
     # PR 49 made the chip's ``index_scores`` and ``index_select`` ONE kernel
@@ -79,8 +90,11 @@ EXPECTED = {
     # (930b42b334a6e816 before it): the short convolution reads its carried
     # rows ``[max_seqs, K - 1, C]`` a sequence and adds them to a chunk's
     # first ``K - 1`` rows where it copied them a token; the decode block,
-    # a row a sequence, is PR 50's to the letter
-    "olmo_hybrid.fused": "d613a233d64e7c0f",
+    # a row a sequence, is PR 50's to the letter.  Re-taken again by PR 54,
+    # which MEANT to move it (d613a233d64e7c0f before it):
+    # ``ragged_gated_delta`` keeps a tick's rows ``[T, H * d]`` as
+    # ``ragged_linear_attention`` does (above)
+    "olmo_hybrid.fused": "b0d11e56bd01f5d8",
     "olmo_hybrid.decode_block": "45abd5d3a9dd0bed",
     # added by PR 51 (ROADMAP D14), the same function's other caller: the
     # decode block taken on PR 51's parent, the tick (da4cded4c6195441

@@ -544,7 +544,10 @@ def test_linear_attention_kernels_at_the_serving_configuration(chip_compile):
 
     c, i32, bf, f32 = SALA, jnp.int32, jnp.bfloat16, jnp.float32
     NT = -(-c["T"] // la.CHUNK) + c["S"]
-    rows = ((c["H"], NT * la.CHUNK, c["D"]), bf)
+    # rows as the projections leave them: a head a lane block of 128, four
+    # heads a grid step
+    rows = ((NT * la.CHUNK, c["H"] * c["D"]), bf)
+    assert la.scan_heads(c["H"]) == 4
     state = ((c["S"], c["H"], c["D"], c["D"]), f32)
     lam = ((c["H"], 1, 128), f32)
     text = chip_compile(
@@ -672,17 +675,21 @@ OLMO = dict(S=8, pp=258, pool=2065, page=64, H=30, dk=96, dv=192, D=128,
 def test_gated_delta_kernels_at_the_serving_configuration(chip_compile,
                                                           chunk):
     """``ops/gated_delta_rule.py`` at the published 96 / 192: the chunked
-    scan (the solve, the unaligned lane slice a head of the state block)
-    over the tiles a fused tick of ``olmo-hybrid-7b-serve`` can hold, and
-    the one-token step over its eight slots."""
+    scan (the solve, the unaligned lane slice a head of the state block and
+    of a tile's rows ``[NT * C, H * d]``: two heads a step, q's and k's
+    block the four heads' lanes that are whole lane tiles, the last block
+    half past the row's end) over the tiles a fused tick of
+    ``olmo-hybrid-7b-serve`` can hold, and the one-token step over its
+    eight slots."""
     from deepspeed_tpu.ops import gated_delta_rule as gd
 
     c, i32, bf, f32 = OLMO, jnp.int32, jnp.bfloat16, jnp.float32
     NT = -(-c["T"] // chunk) + c["S"]
-    rows = lambda d: ((c["H"], NT * chunk, d), bf)
+    rows = lambda d: ((NT * chunk, c["H"] * d), bf)
     state = ((c["S"], c["dk"], c["H"] * c["dv"]), f32)
     assert (c["H"] * c["dv"]) % 128 == 0
     assert gd.scan_eligible(c["H"], c["dk"], c["dv"], bf)
+    assert gd._scan_blocks(c["H"], c["dk"]) == (2, 2)
     text = chip_compile(
         functools.partial(gd._scan.__wrapped__, chunk=chunk,
                           interpret=False),
