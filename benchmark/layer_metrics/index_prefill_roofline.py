@@ -4,10 +4,11 @@ the indexer scores for a prompt row (``index_prefill_tokens`` of the fused
 ticks' ``engine.dispatch`` spans: ``t + 1`` a row; ``flops_keye.
 index_scores``) / peak bf16 FLOP/s, over the device self time, in the fused
 tick, of the module ``indexer``, the scopes ``index`` and ``select`` and the
-kernels ``index_scores`` and ``index_select``.  The products contract over
-64 (half of the MXU's width) and the choice is 32 passes of the VPU over
-every score, neither of which the needed work counts: both hold the share
-down, and say what a kernel that scores and chooses in one would gain."""
+kernel ``index_select``, which scores and chooses in one (``index_scores``,
+the scoring kernel its parent had beside it, is still summed where a trace
+holds it).  The products contract over 64 (half of the MXU's width) and the
+choice is 32 passes of the VPU over every score, neither of which the
+needed work counts: both hold the share down."""
 from benchmark import flops_keye
 from benchmark.layer_metrics import keye_trace as kt
 

@@ -4,13 +4,16 @@ device self time of the traced slice's operations by kind and sub-scope
 ``engine.dispatch`` spans.  Not a metric itself.
 
 All of it is kind ``attn`` (module ``self_attn``).  Of PROMPT CHUNKS: the
-scope ``index`` (the tiles' layout) and the kernel ``index_scores``; the
-scope ``select`` (the shared-out ties) and the kernel ``index_select``; the
-kernel ``tokensel_chunk_attention``.  Of ONE-ROW sequences (a decode
-block's rows, a fused tick's decoding sequences): the scope ``index_row``
-and the kernel ``row_index_scores`` (scores), ``select_row``
-(``lax.top_k``) and ``tokensel_row_attention`` (the gather by token and the
-attention).  The module ``indexer`` (the three
+scope ``index`` (the tiles' layout), the scope ``select`` (the shared-out
+ties) and the ONE kernel ``index_select``, which scores and chooses since
+the scores stay in VMEM from the product to the choice (``CHUNK_INDEX``
+still lists ``index_scores``, the scoring kernel of its own that the parent
+of that change had: no program of this tree has such a row, and the name
+reads nothing); the kernel ``tokensel_chunk_attention``.  Of ONE-ROW
+sequences (a decode block's rows, a fused tick's decoding sequences): the
+scope ``index_row`` and the kernel ``row_index_scores`` (scores),
+``select_row`` (``lax.top_k``) and ``tokensel_row_attention`` (the gather
+by token and the attention).  The module ``indexer`` (the three
 projections, the LayerNorm, the rotations) projects every row of a program:
 a fused tick's is counted with its chunks, a decode block's with its rows.
 A program without these scopes (every other model, or the parent of the PR

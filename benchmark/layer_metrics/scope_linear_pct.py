@@ -1,8 +1,14 @@
-"""The linear-attention mixer's share of the device's busy time: self time
-of every operation under a ``lightning_attn`` module (projections, QK-norm,
-RoPE, the chunk-scan kernel, the decode step, the state's gather and
-scatter, the output norm and gate) with the norm that feeds it, over the
-summed self time of the traced slice (``scope_trace``)."""
+"""The matrix-state mixer's share of the device's busy time: self time of
+every operation of kind ``linear`` with the norm that feeds it, over the
+summed self time of the traced slice (``scope_trace``).  The kind is the
+program's (``telemetry/device_scopes.py``), whichever rule updates the state:
+a ``lightning_attn`` module (lightning attention: projections, QK-norm, RoPE,
+``linear_chunk_scan``, ``linear_decode_step``, the rows' gathers to the tiles
+and back, the output norm and gate) or a ``linear_attn`` module (the gated
+delta rule: its projections and gate projections, the short convolution
+INSIDE it with its carried rows, the L2 norms, ``delta_chunk_scan``,
+``delta_decode_step``, the output norm and gate).  A program with neither
+module has no such kind and the reader returns None."""
 from benchmark.layer_metrics import scope_trace
 
 

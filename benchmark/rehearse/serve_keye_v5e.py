@@ -7,8 +7,10 @@ width 768, for a DESCRIBED v5e chip, without a chip:
 and ``programs.build_decode_block`` (``decode_block_size`` ticks of
 ``max_seqs`` rows), over the whole pool, as the engine builds them.  Prints
 what the TPU compiler needs on the device, the kernel calls in each program
-and the routes taken, and refuses a program in which one of the three
-chunk kernels fell to its XLA twin.
+and the routes taken, and refuses a program in which one of the two chunk
+kernels (``index_select``, which scores and chooses in one, and
+``tokensel_chunk_attention``) or the one-row ``row_index_scores`` fell to
+its XLA twin.
 
     JAX_PLATFORMS=cpu python benchmark/rehearse/serve_keye_v5e.py [config]
 
@@ -104,7 +106,7 @@ print({"config": name, "parameters": count_params(params),
        "pages": pages, "page_bytes": page_bytes,
        "bytes_per_token": page_bytes // page, "pool_gib": nbytes(pools) / gib,
        "state_gib": nbytes(states) / gib, "pool_tokens": (pages - 1) * page})
-NAMES = (tsa.INDEX_NAME, tsa.SELECT_NAME, tsa.CHUNK_NAME)
+NAMES = (tsa.SELECT_NAME, tsa.CHUNK_NAME)      # a prompt chunk's, a layer
 ROW = tsa.ROW_INDEX_NAME            # the one-row path's, in both programs
 for what, lowered in (("fused_tick", fused), ("decode_block", block)):
     compiled = lowered.compile()
@@ -121,7 +123,7 @@ for what, lowered in (("fused_tick", fused), ("decode_block", block)):
         **{n + "_calls": len(re.findall(rf"%{n}[.\d]* = ", text))
            for n in NAMES + (ROW,)}}
     print(report)
-    # a decode block's rows take the one-row path: no kernel of the three
+    # a decode block's rows take the one-row path: no chunk kernel
     for n, count in {**{n: layers if what == "fused_tick" else 0
                         for n in NAMES}, ROW: layers}.items():
         assert report[n + "_calls"] == count, (

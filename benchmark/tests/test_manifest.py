@@ -121,6 +121,31 @@ def test_per_layer_is_one_entry_a_reader_and_moves_with_its_cells():
             seen.add(key)
 
 
+# what each cell of the second fold reports, in the order of ``workloads``
+FOLDED = {"chat-open": 18, "pretrain-zero3": 11, "longdoc-closed": 15,
+          "reasoning-closed": 13, "olmoe-reasoning-closed": 15,
+          "ouro-reasoning-closed": 15, "lfm2-reasoning-closed": 21,
+          "glm-longdoc16k-closed": 24, "sala-longdoc16k-closed": 22,
+          "keye-longdoc32k-closed": 24, "olmo-hybrid-longdoc16k-closed": 20}
+
+
+def test_the_second_fold_left_82_entries_and_lost_none():
+    """The eleven cells the benchmark had when ``per_layer`` was folded a
+    second time take 82 entries between them, ONE for each pair of reader
+    and ``moves``, and each reports the count it reported before the fold
+    (198 readings in all).  Entries of a cell a later ``model_config`` PR
+    adds stand beside these and are not counted here; the next fold takes
+    them into the lists and restates this table."""
+    m = H.manifest()
+    assert [w["name"] for w in m["workloads"]][:len(FOLDED)] == list(FOLDED)
+    mine = [e for e in m["per_layer"] if set(e["workloads"]) <= set(FOLDED)]
+    assert len(mine) == 82
+    pairs = [(H.reader_of(e["name"]), e["moves"]) for e in mine]
+    assert len(pairs) == len(set(pairs))
+    for cell, count in FOLDED.items():
+        assert len(H.Cell(cell).metrics("per_layer")) == count, cell
+
+
 def test_no_reader_file_is_left_that_nothing_uses():
     """A file of ``layer_metrics/`` is a reader some entry names, or a
     helper some other file there (or a driver) imports."""
@@ -137,6 +162,16 @@ def test_no_reader_file_is_left_that_nothing_uses():
         assert re.search(r"import .*\b%s\b" % f, text), f
 
 
+def _add_cell(m, name, like, entries, **fields):
+    """A cell ``name`` shaped like the workload ``like``, on the list of
+    ``total_tokens_per_s``, with per-layer ``entries`` of its own."""
+    m["workloads"].append(dict(like, name=name, **fields))
+    for e in m["end_to_end"]:
+        if e["name"] == "total_tokens_per_s":
+            e["workloads"].append(name)
+    m["per_layer"] += entries
+
+
 def test_a_ninth_cell_adds_suffixed_entries_beside_the_folded_ones(
         monkeypatch):
     """How the next ``model_config`` PR lists its cell without editing a
@@ -147,14 +182,9 @@ def test_a_ninth_cell_adds_suffixed_entries_beside_the_folded_ones(
     folded = next(e for e in m["per_layer"] if e["name"] == "device_idle_pct")
     before = {w["name"]: [e["name"] for e in H.Cell(w["name"]).metrics(
         "per_layer")] for w in m["workloads"]}
-    m["workloads"].append(dict(m["workloads"][2], name="ninth",
-                               traffic="reasoning-closed"))
-    for e in m["end_to_end"]:
-        if e["name"] == "total_tokens_per_s":
-            e["workloads"].append("ninth")
     mine = [dict(folded, name=f"{r}.ninth", workloads=["ninth"])
             for r in ("device_idle_pct", "scope_attn_pct")]
-    m["per_layer"] += mine
+    _add_cell(m, "ninth", m["workloads"][2], mine, traffic="reasoning-closed")
     monkeypatch.setattr(H, "manifest", lambda: m)
     assert [e["name"] for e in H.Cell("ninth").metrics("per_layer")] == \
         ["device_idle_pct.ninth", "scope_attn_pct.ninth"]
@@ -166,6 +196,46 @@ def test_a_ninth_cell_adds_suffixed_entries_beside_the_folded_ones(
     assert H.layer_metrics(H.Cell("ninth"), {}) == {
         "device_idle_pct.ninth": {"value": 15.0, "unit": "%"},
         "scope_attn_pct.ninth": {"value": 14.0, "unit": "%"}}
+
+
+def test_a_twelfth_cell_of_twenty_four_entries_fits(monkeypatch):
+    """The room the second fold made, at the size the last three
+    configurations had (21, 23 and 19 entries): a configuration, a cell and
+    TWENTY-FOUR ``<reader>.<suffix>`` entries, sixteen on readers the
+    benchmark shares and eight on readers of the cell's own, fit under 128
+    entries and under the 65,536 bytes the file may have; the cell reports
+    its 24 and every other cell what it reported."""
+    m = H.manifest()
+    before = {w["name"]: [e["name"] for e in H.Cell(w["name"]).metrics(
+        "per_layer")] for w in m["workloads"]}
+    # the sixteen shared readers the last all-expert configuration is on
+    shared = [e for e in H.Cell("keye-longdoc32k-closed").metrics(
+        "per_layer") if "." not in e["name"]][:16]
+    widest = max(m["per_layer"], key=lambda e: len(e["layer"]))
+    mine = [dict(e, name=e["name"] + ".twelfth", workloads=["twelfth"])
+            for e in shared]
+    mine += [dict(widest, name=f"a_scan_of_its_own_{i}_roofline.twelfth",
+                  unit="%", workloads=["twelfth"]) for i in range(8)]
+    assert len(mine) == 24 and len({e["name"] for e in mine}) == 24
+    assert all(NAME.match(e["name"]) for e in mine)
+    longest = max(m["configs"], key=lambda c: len(json.dumps(c)))
+    m["configs"].append(dict(longest, name="twelfth-serve"))
+    _add_cell(m, "twelfth", max(m["workloads"], key=lambda w: len(w["why"])),
+              mine, config="twelfth-serve", traffic="longdoc32k-closed")
+    assert len(m["per_layer"]) <= 128
+    assert len(json.dumps(m, indent=1).encode()) < 65536
+    monkeypatch.setattr(H, "manifest", lambda: m)
+    cell = H.Cell("twelfth")
+    assert [e["name"] for e in cell.metrics("per_layer")] == \
+        [e["name"] for e in mine]
+    for name, had in before.items():
+        assert [e["name"] for e in H.Cell(name).metrics("per_layer")] == had
+    monkeypatch.setattr(H, "plugin", lambda group, name:
+                        types.SimpleNamespace(read=lambda ctx: 1.5))
+    got = H.layer_metrics(cell, {})
+    assert list(got) == [e["name"] for e in mine]
+    assert got["moe_load_max_over_mean.twelfth"] == {"value": 1.5,
+                                                     "unit": "ratio"}
 
 
 def test_nothing_in_the_benchmark_imports_bench_or_private_engine_names():

@@ -90,6 +90,37 @@ def test_e4m3_operands_are_outside_the_tolerance(served):
     assert np.abs(low - want).max() > 100 * TOL
 
 
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_the_mean_gap_separates_e4m3_from_the_program(seed):
+    """The control of ``logit_mean_margin`` at a size a test run can hold:
+    the reference with every matmul operand rounded to e4m3, put in the
+    program's place at every position of one document, puts tokens first
+    whose float32 logits lie under the float32 maximum by 0.003 or more IN
+    THE MEAN; the program (float32 at this size) puts the reference's own
+    token first everywhere.  Two layers of 64 wide move a logit less than
+    six of 2,048 do: at the published widths on the chip the control reads
+    0.029-0.053 over 144 tokens and the bf16 program at most 0.0026
+    (PERF.md section 6, PR 55), with the shipped limit between them."""
+    from deepspeed_tpu.models import keye_vl2 as keye
+
+    model = keye.KeyeVL2ForCausalLM(keye.get_config(
+        "tinykeye", dtype=jnp.float32, param_dtype=jnp.float32, remat=False,
+        use_flash_attention=False, drop_tokens=False))
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed),
+                                 np.zeros((1, 8), np.int32))["params"]
+    exact = np.asarray(ref.logits(params, CFG, TOKENS))
+    at = np.arange(len(TOKENS))
+
+    def mean_gap(first):
+        return float((exact.max(axis=-1) - exact[at, first]).mean())
+
+    low = np.asarray(ref.logits(params, CFG, TOKENS, precision="float8"))
+    got = np.asarray(jax.jit(model.apply)(
+        {"params": params}, np.asarray([TOKENS], np.int32))[0][0])
+    assert mean_gap(low.argmax(axis=-1)) > 3e-3
+    assert mean_gap(got.argmax(axis=-1)) < 1e-4
+
+
 def test_reference_is_independent_of_the_program():
     src = open(ref.__file__).read()
     assert "deepspeed_tpu" not in src.split('"""', 2)[2]

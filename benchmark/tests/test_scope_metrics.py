@@ -214,25 +214,51 @@ def test_the_real_registry_is_empty_without_an_engine():
     assert scope_trace._tables()[0] == {}
 
 
+def _ffn_kinds(config):
+    """Which kinds a configuration's feed-forward halves have, from the
+    source's own keys: ``moe`` where it has routed experts, ``mlp`` where
+    some layer is dense or an expert layer has a shared (dense) expert
+    beside it.  Experts in EVERY layer and no shared one: no ``mlp``."""
+    count = lambda *keys: max(int(config.get(k) or 0) for k in keys)
+    if count("num_experts", "n_routed_experts", "num_local_experts") < 2:
+        return {"mlp"}
+    dense = (count("num_dense_layers", "first_k_dense_replace")
+             or len(config.get("mlp_only_layers") or [])
+             or count("decoder_sparse_step") > 1
+             or count("n_shared_experts", "num_shared_experts"))
+    return {"moe", "mlp"} if dense else {"moe"}
+
+
 def test_which_cells_report_each_scope_reader():
     """Read through ``Cell.metrics``, so a bare entry with a list and a
-    suffixed entry of one cell count alike."""
+    suffixed entry of one cell count alike.  Which cells are expected is
+    read from the configurations' files (``kind``, ``layer_types``, the
+    expert keys), so the next configuration needs no edit here."""
     m = H.manifest()
     by_reader = {}
+    want = {name: set() for name in FAMILIES}
     for w in m["workloads"]:
-        for e in H.Cell(w["name"]).metrics("per_layer"):
+        cell = H.Cell(w["name"])
+        for e in cell.metrics("per_layer"):
             reader = H.reader_of(e["name"])
             if reader in FAMILIES:
                 assert e["unit"] == "%" and e["source"] == "device_trace"
                 by_reader.setdefault(reader, set()).add(w["name"])
-    every = {w["name"] for w in m["workloads"]}
-    expert = {"olmoe-reasoning-closed", "lfm2-reasoning-closed",
-              "glm-longdoc16k-closed"}
-    train = {"pretrain-zero3"}
-    assert by_reader == {
-        "scope_attn_pct": every,
-        "scope_mlp_pct": every - {"olmoe-reasoning-closed"},
-        "scope_moe_pct": expert, "scope_conv_pct": {"lfm2-reasoning-closed"},
-        "scope_head_pct": every, "device_unscoped_pct": every,
-        "scope_remat_pct": train, "scope_bwd_pct": train,
-        "scope_optim_pct": train}
+        kinds = {"attn", "head"} | _ffn_kinds(cell.config)
+        if "conv" in (cell.config.get("layer_types") or []):
+            kinds.add("conv")
+        if cell.config["kind"] == "train":
+            kinds |= {"remat", "bwd", "optim"}
+        for kind in kinds:
+            want[f"scope_{kind}_pct"].add(w["name"])
+        want["device_unscoped_pct"].add(w["name"])
+    assert by_reader == want
+    # the rule, on the four shapes the benchmark has
+    assert _ffn_kinds({"num_experts": 64}) == {"moe"}
+    assert _ffn_kinds({"num_experts": 128, "decoder_sparse_step": 1,
+                       "mlp_only_layers": []}) == {"moe"}
+    assert _ffn_kinds({"num_experts": 64, "num_dense_layers": 2}) == \
+        {"moe", "mlp"}
+    assert _ffn_kinds({"n_routed_experts": 320, "n_shared_experts": 1}) == \
+        {"moe", "mlp"}
+    assert _ffn_kinds({"intermediate_size": 14336}) == {"mlp"}
