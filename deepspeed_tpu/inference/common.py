@@ -266,6 +266,11 @@ class HostStageStats:
         # (a sigmoid router with a selection bias) tokens whose chosen
         # set the bias changed
         self.moe_bias_swaps = 0
+        # (layers that hold a share of their router's experts: the counts
+        # above are then the HELD banks') every copy the router made, and
+        # the experts held, summed as ``moe_experts_hit`` is
+        self.moe_rows_routed = 0
+        self.moe_experts_held = 0
         # a model with per-slot state: sequences a dispatch carried state
         # for, summed; and (block-selecting attention) the queries past
         # ``dense_len``, the keys their chosen blocks hold and the keys a

@@ -25,6 +25,8 @@ K/V pages; a looped model's slots a layer; thirty heads stored as thirty-two),
 ``conv_state.py`` (a short convolution's last inputs), ``linear_state.py`` (a
 decaying matrix a head), ``delta_state.py`` (a matrix a head corrected by the
 delta rule, and the rows of the convolution before it: two leaves a layer),
+``kda_state.py`` (the same two leaves, the matrix's rows decaying each by a
+factor of its own),
 ``block_select.py`` (pages of keys and values chosen by compressed keys),
 ``index_select.py`` (tokens chosen by an indexer whose keys are a pool leaf),
 ``latent.py`` (one latent row a token).  :func:`describe` composes a model's
@@ -53,6 +55,8 @@ from deepspeed_tpu.inference.paged.index_select import (  # noqa: F401
     INDEX_KEY_PAGES, TOKENSEL_KV_PAGES, IndexSelect, TickShape,
     index_key_steps, token_select_counts, token_selection,
     tokensel_update_and_attend)
+from deepspeed_tpu.inference.paged.kda_state import (  # noqa: F401
+    KdaState, kda_layers, ragged_kda)
 from deepspeed_tpu.inference.paged.kv_pages import (  # noqa: F401
     _RPA_QUERY_BLOCK, KVPages, kv_dequant_path, kv_heads_stored, kv_lane_dim,
     kv_slots_per_layer, paged_attention_path, paged_update_and_attend,
@@ -110,13 +114,14 @@ class CacheKind:
 def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
     """The cache of the model whose config is ``cfg``, read off the config's
     own keys (``total_ut_steps``, ``layer_types``, ``mixer_types``,
-    ``selection``, ``sa_config``, ``kv_lora_rank``) by each kind's
+    ``linear_attn_config`` and ``gqa_layers``, ``selection``, ``sa_config``,
+    ``kv_lora_rank``) by each kind's
     predicate; ``tick``: the fused program's shape, for a kind that counts
     a tick's work in the program's tiles."""
     of = int(cfg.num_hidden_layers)
     conv, linear = conv_layers(cfg), linear_layers(cfg)
-    delta = delta_layers(cfg)
-    rows = of - conv - linear - delta
+    delta, vector_delta = delta_layers(cfg), kda_layers(cfg)
+    rows = of - conv - linear - delta - vector_delta
     slots, width = kv_slots_per_layer(cfg), latent_width(cfg)
     selection, indexer = sparse_selection(cfg), token_selection(cfg)
     parts: List[Part] = []
@@ -128,22 +133,25 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
         parts.append(LinearState(linear, of))
     if delta:
         parts.append(DeltaState(delta, of))
+    if vector_delta:
+        parts.append(KdaState(vector_delta, of))
     if selection is not None:
         parts.append(BlockSelect(rows, of, selection))
     elif indexer is not None:
         parts.append(IndexSelect(rows, of, indexer, tick))
     elif width:
         parts.append(Latent(rows, of, width))
-    return CacheKind(tuple(parts), slots, conv + linear + delta, rows, width,
+    return CacheKind(tuple(parts), slots,
+                     conv + linear + delta + vector_delta, rows, width,
                      selection)
 
 
 def state_layers(cfg) -> int:
     """Layers of a model that keep per-slot state beside the pool: the
     ``"conv"`` and the ``"linear_attention"`` entries of its config's
-    ``layer_types`` and the ``"lightning-attn"`` entries of its
-    ``mixer_types``; 0 for a model whose config names none of the three
-    (every layer holds K/V)."""
+    ``layer_types``, the ``"lightning-attn"`` entries of its ``mixer_types``
+    and the layers its ``gqa_layers`` leaves to ``linear_attn_config``; 0 for
+    a model whose config names none of the four (every layer holds K/V)."""
     return describe(cfg).state_layers
 
 

@@ -43,7 +43,8 @@ def delta_layers(cfg) -> int:
 
 def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                        g: jax.Array, b: jax.Array, pos: jax.Array,
-                       ragged_meta: Dict[str, jax.Array]) -> jax.Array:
+                       ragged_meta: Dict[str, jax.Array],
+                       rule=gated_delta_rule) -> jax.Array:
     """The gated delta rule over a packed ragged token batch, inside a flax
     module ``mdl``: ``q, k [T, H, dk]`` (convolved, normed, scaled), ``v [T,
     H, dv]``, ``g, b [T, H]`` float32 (the log of a token's decay; the
@@ -68,14 +69,18 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     one-token step; the two write disjoint rows and slots.  A tick's rows
     stay ``[T, H * d]`` all the way (a head a lane block, as the projections
     leave them and ``o_proj`` takes them): one gather an operand to the
-    tiles' rows, one gather back, no transpose to heads first."""
+    tiles' rows, one gather back, no transpose to heads first.
+
+    ``rule``: the module whose ``chunk_scan``, ``decode_step`` and ``CHUNK``
+    are the rule's two forms (``ops/kda.py`` for a decay that is a vector a
+    key channel: ``g [T, H * dk]``, ``kda_state.py``)."""
     T, H, dk = q.shape
     dv = v.shape[-1]
     cu = ragged_meta["cu_q_lens"]
     S = cu.shape[0] - 1
     state = mdl.variable("cache", SLOT_STATE, jnp.zeros, (S, dk, H * dv),
                          jnp.float32)
-    gd, la = gated_delta_rule, linear_attention
+    gd, la = rule, linear_attention
     take = lambda a, rows: jnp.take(a, rows, axis=0)
     g, b = g.astype(jnp.float32), b.astype(jnp.float32)
 

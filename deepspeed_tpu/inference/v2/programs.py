@@ -66,13 +66,16 @@ def moe_counts(variables) -> Optional[jax.Array]:
     layers; None for a model without expert layers, so its programs gain
     no output.  Where the layers also sow ``bias_swaps`` (the sigmoid
     router with a selection bias: tokens whose chosen set the bias
-    changed) their sum is a fifth entry."""
+    changed) their sum is a fifth entry.  Where the layers hold a share of
+    their router's experts (``moe/layer.py``: ``expert_shards``) they sow
+    ``held`` too, and the LAST TWO entries are its sums: every copy the
+    router made, and the experts held (the first four are then what the
+    held banks saw)."""
     flat = jax.tree_util.tree_flatten_with_path(
         variables.get("moe_stats", {}))[0]
-    is_swaps = lambda path: any(                    # noqa: E731
-        getattr(k, "key", None) == "bias_swaps" for k in path)
-    swaps = [leaf for path, leaf in flat if is_swaps(path)]
-    leaves = [leaf for path, leaf in flat if not is_swaps(path)]
+    named = lambda name: [leaf for path, leaf in flat if any(   # noqa: E731
+        getattr(k, "key", None) == name for k in path)]
+    swaps, held, leaves = named("bias_swaps"), named("held"), named("counts")
     if not leaves:
         return None
     with jax.named_scope("counts"):
@@ -80,6 +83,8 @@ def moe_counts(variables) -> Optional[jax.Array]:
         if swaps:
             counts = jnp.concatenate(
                 [counts, jnp.sum(jnp.stack(swaps))[None]])
+        if held:
+            counts = jnp.concatenate([counts, jnp.sum(jnp.stack(held), 0)])
     return counts
 
 

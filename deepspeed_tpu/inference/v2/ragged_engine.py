@@ -1066,6 +1066,11 @@ class RaggedInferenceEngineV2:
                           # less ``experts_hit``: the visits of the grouped
                           # matmul that needed no new bank
                           "visits": st.moe_visits}
+            if st.moe_experts_held:
+                # a layer that holds a share of its router's experts: the
+                # counts above are the held banks'; all the router's copies
+                out["moe"].update(rows_routed=st.moe_rows_routed,
+                                  experts_held=st.moe_experts_held)
         out.update(self.cache_kind.stages(
             st, self.kv.cache, self.cfg, out["kernels"], self._pages_peak))
         # the head of a fused tick multiplies the rows it samples, gathered
@@ -1152,8 +1157,19 @@ class RaggedInferenceEngineV2:
     def _record_moe(self, counts, attrs: Dict[str, Any]) -> None:
         """One dispatch's expert-layer counts, as a span of their own in
         the profiler's host plane and in ``serving_stages()['moe']``."""
-        rows, hit, load, visits, *swaps = (int(v) for v in counts)
+        rows, hit, load, visits, *more = (int(v) for v in counts)
         st = self.host_stats
+        # four, then ``bias_swaps`` where the router has a selection bias,
+        # then the two of a layer that holds a share of its router's experts
+        # (``programs.moe_counts``)
+        swaps, held = more[:len(more) % 2], more[len(more) % 2:]
+        if held:
+            # the four above are what the HELD banks saw; every copy the
+            # router made, and the experts held, beside them
+            st.moe_rows_routed += held[0]
+            st.moe_experts_held += held[1]
+            attrs = {**attrs, "moe_rows_routed": held[0],
+                     "moe_experts_held": held[1]}
         st.moe_rows += rows
         st.moe_experts_hit += hit
         st.moe_load_max = max(st.moe_load_max, load)

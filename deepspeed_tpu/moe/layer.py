@@ -131,6 +131,15 @@ class MoE(nn.Module):
     # first values of the experts' down-projection ``w2``, as a share of
     # lecun-normal's (a family's own: ``models/lfm2_moe.py`` says why)
     down_init_scale: float = 1.0
+    # one chip's share of a layer that ``expert_shards`` chips share
+    # ("grouped" only): ``num_experts`` is the number HELD here, experts
+    # ``[expert_shard * num_experts, (expert_shard + 1) * num_experts)`` of
+    # a router ``expert_shards * num_experts`` wide.  A copy routed to an
+    # absent expert takes no row and adds nothing: what the other chips'
+    # experts would have added is theirs to compute, and nothing here stands
+    # in for them or for the exchange (``sharded_moe.py:held_moe``)
+    expert_shards: int = 1
+    expert_shard: int = 0
 
     def _can_alltoall(self, topo, n_tokens: int) -> bool:
         ep = int(topo.mesh.shape.get(EXPERT_AXIS, 1))
@@ -373,12 +382,13 @@ class MoE(nn.Module):
 
         # router in fp32 (reference TopKGate keeps the gate fp32,
         # sharded_moe.py:449) — routing decisions are precision-sensitive
-        wg = self.param("gate", nn.initializers.lecun_normal(), (M, E),
+        routed = E * cfg.expert_shards          # the router's width
+        wg = self.param("gate", nn.initializers.lecun_normal(), (M, routed),
                         jnp.float32)
         w = self._expert_params()
         bias = (self.param(
             "expert_bias", nn.initializers.normal(cfg.expert_bias_init_std),
-            (E,), jnp.float32) if cfg.expert_bias else None)
+            (routed,), jnp.float32) if cfg.expert_bias else None)
 
         noise_rng = None
         if (cfg.noisy_gate_policy == "Jitter" and is_training
@@ -394,6 +404,13 @@ class MoE(nn.Module):
                 "serving form only (its training form needs the bias's "
                 "balancing update and an auxiliary loss of its own)")
         assert cfg.scoring in ("softmax", "sigmoid"), cfg.scoring
+        assert 0 <= cfg.expert_shard < cfg.expert_shards, (
+            cfg.expert_shard, cfg.expert_shards)
+        if cfg.expert_shards > 1 and impl != "grouped":
+            raise NotImplementedError(
+                f"expert_shards={cfg.expert_shards} with dispatch_impl="
+                f"{impl!r}: a layer that holds a share of its router's "
+                "experts has its dropless serving form only")
         if impl == "grouped":
             # float32 for real: a TPU's default f32 matmul is one bf16
             # pass, and a served token's experts are not to turn on that
@@ -414,15 +431,25 @@ class MoE(nn.Module):
                 else:
                     experts, weights, l_aux = topk_routing(
                         logits, cfg.k, cfg.normalize_weights)
+            flat_live = None if live is None else live.reshape(-1)
             y, counts = grouped_moe(
                 x.astype(cfg.dtype), experts, weights, E,
-                lambda rows, gs: self._grouped_ffn(rows, gs, w),
-                None if live is None else live.reshape(-1))
+                lambda rows, gs: self._grouped_ffn(rows, gs, w), flat_live,
+                first_held=(cfg.expert_shard * E if cfg.expert_shards > 1
+                            else None))
             # what the tick routed, for whoever asked (the serving
             # engine's spans); a no-op unless the caller made the
             # collection mutable
             if not self.is_initializing():
                 self.sow("moe_stats", "counts", counts)
+                if cfg.expert_shards > 1:
+                    # ``counts`` are what the HELD banks saw; beside them
+                    # every copy the router made, and the experts held
+                    tokens = (x.shape[0] if flat_live is None
+                              else jnp.sum(flat_live.astype(jnp.int32)))
+                    self.sow("moe_stats", "held", jnp.stack(
+                        [jnp.asarray(tokens * cfg.k, jnp.int32),
+                         jnp.asarray(E, jnp.int32)]))
             return y.reshape(orig_shape), l_aux
         if impl == "alltoall":
             with jax.named_scope("moe.alltoall"):

@@ -425,7 +425,8 @@ def sigmoid_bias_routing(logits: jax.Array, bias: Optional[jax.Array],
 
 
 def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
-                num_experts: int, expert_ffn, live: Optional[jax.Array]
+                num_experts: int, expert_ffn, live: Optional[jax.Array],
+                first_held: Optional[int] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """Dropless routed FFN.  ``x [G, M]``; ``experts``/``weights [G, k]``;
     ``expert_ffn(rows [k*G, M], group_sizes [E]) -> [k*G, M]`` runs the
@@ -436,9 +437,16 @@ def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
     Returns ``(y [G, M], counts int32[4])``: token copies routed, experts
     with at least one row, rows of the fullest expert, row-tile visits of
     the grouped matmul (``ops/grouped_matmul.py:visits``).
+
+    ``first_held`` (None: the layer holds every expert the router names):
+    the layer holds the ``num_experts`` banks of experts ``[first_held,
+    first_held + num_experts)`` of a wider router (:func:`held_moe`).
     """
     from deepspeed_tpu.ops import grouped_matmul
 
+    if first_held is not None:
+        return held_moe(x, experts - first_held, weights, num_experts,
+                        expert_ffn, live)
     G, k = experts.shape
     E = num_experts
     # the scopes name the four stages for ``telemetry/device_scopes.py``:
@@ -462,6 +470,83 @@ def grouped_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
             # rows past the last group are whatever the kernel left there
             y = jnp.where(live[:, None], y, 0)
         counts = jnp.stack([jnp.sum(group_sizes),
+                            jnp.sum((group_sizes > 0).astype(jnp.int32)),
+                            jnp.max(group_sizes),
+                            grouped_matmul.visits(group_sizes)])
+    return y, counts
+
+
+# rows of the sorted buffer a held layer's first form has room for, as a
+# share of the routed copies: a chip of eight sees an eighth of them under a
+# uniform router, and seeded routers skew
+HELD_ROOM = 4
+
+
+def held_moe(x: jax.Array, experts: jax.Array, weights: jax.Array,
+             num_experts: int, expert_ffn, live: Optional[jax.Array]
+             ) -> Tuple[jax.Array, jax.Array]:
+    """:func:`grouped_moe` for a layer that HOLDS ``num_experts`` of the
+    experts its router names (one chip's share of a layer that several
+    share): ``experts`` counts from the first one held, so a copy routed to
+    an absent expert lies outside ``[0, num_experts)``, joins no group,
+    takes no row and adds nothing.  What the absent experts would have
+    added is some other chip's to compute and is left out.
+
+    About ``held / routed`` of the copies are live, so the sorted buffer is
+    not built whole: the copies are sorted (absent ones last) and the FIRST
+    ``B`` rows gathered, multiplied and combined, ``B`` a quarter of the
+    copies (:data:`HELD_ROOM`) where the live ones fit, else all of them
+    (a ``cond`` on their count, on the device): dropless whatever the
+    router does, and a tick pays for the copies it has.  The combine is one
+    product on the MXU, ``W [G, B] x out [B, M]`` with ``W`` a row's weight
+    at its token, where the whole layer gathers ``k`` rows a token: most of
+    a token's ``k`` copies are absent here.
+
+    Returns ``(y, counts int32[4])`` as :func:`grouped_moe`: the counts are
+    of what the held banks saw."""
+    from deepspeed_tpu.ops import grouped_matmul
+
+    G, k = experts.shape
+    E = num_experts
+    with jax.named_scope("moe.dispatch"):
+        ec = experts.reshape(-1)                      # copy = token * k + j
+        held = jnp.logical_and(ec >= 0, ec < E)
+        if live is not None:
+            held = jnp.logical_and(held, jnp.repeat(live, k))
+        ec = jnp.where(held, ec, E)
+        order = jnp.argsort(ec, stable=True)          # sorted pos -> copy
+        group_sizes = jnp.sum(jax.nn.one_hot(ec, E, dtype=jnp.int32),
+                              axis=0)
+        rows_live = jnp.sum(group_sizes)
+        w_sorted = jnp.take(jnp.where(held, weights.reshape(-1), 0.0),
+                            order)
+
+    def first(room: int):
+        def run():
+            with jax.named_scope("moe.dispatch"):
+                token = order[:room] // k
+                sorted_rows = jnp.take(x, token, axis=0)
+            with jax.named_scope("moe.experts"):
+                out = expert_ffn(sorted_rows, group_sizes)
+            with jax.named_scope("moe.combine"):
+                # rows past the last group are whatever the kernel left
+                # there: their weight is zero AND they are zeroed
+                at = jnp.arange(room) < rows_live
+                out = jnp.where(at[:, None], out, 0)
+                w = jnp.where(at, w_sorted[:room], 0.0).astype(out.dtype)
+                onto = jnp.where(jnp.arange(G)[:, None] == token[None, :],
+                                 w[None, :], 0)
+                return jnp.dot(
+                    onto, out, preferred_element_type=jnp.float32,
+                    precision=(jax.lax.Precision.HIGHEST
+                               if out.dtype == jnp.float32 else None)
+                ).astype(out.dtype)
+        return run
+
+    room = -(-G * k // HELD_ROOM)
+    y = jax.lax.cond(rows_live <= room, first(room), first(G * k))
+    with jax.named_scope("moe.combine"):
+        counts = jnp.stack([rows_live,
                             jnp.sum((group_sizes > 0).astype(jnp.int32)),
                             jnp.max(group_sizes),
                             grouped_matmul.visits(group_sizes)])

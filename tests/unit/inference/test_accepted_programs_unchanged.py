@@ -28,7 +28,12 @@ leave them, gathered to tiles and back as 2-D rows, where every operand was
 transposed to heads first and ``o`` back); both decode blocks, whose rows
 take the one-token step, are the parent's to the letter (PR 53, the same
 change refused on a run of a cell it cannot reach, had read the same two
-values).
+values).  PR 56 added the two programs of ``solar-open2-250b-serve``, taken
+on its own tree, and moved none of the fourteen: an expert layer told
+``expert_shards=1`` (every older family) reaches ``grouped_moe``'s own body
+and sows no ``held`` leaf, ``ragged_gated_delta`` takes its rule as an
+argument whose default is the module it named, and ``moe_counts`` picks the
+same leaves in the same order.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -101,12 +106,20 @@ EXPECTED = {
     # there, moved by the same edit) on its own tree
     "lfm2.fused": "6965d992f8bd0789",
     "lfm2.decode_block": "05e1a91e1d19ab60",
+    # taken by PR 56, which added the model (the tile layout at the KDA
+    # scan's chunk, the scan and the one-token step with a decay a key
+    # channel, gated GQA without rotary, an expert layer that HOLDS 3 of its
+    # router's 12 experts: ``held_moe`` and its ``cond``); the fourteen older
+    # hashes are as they were
+    "solar.fused": "022473bd94042151",
+    "solar.decode_block": "dd04a8cc32f4b803",
 }
 
 
 def _models():
     from deepspeed_tpu.models import (keye_vl2, lfm2_moe, minicpm_sala,
-                                      mistral, olmo_hybrid, olmoe, ouro)
+                                      mistral, olmo_hybrid, olmoe, ouro,
+                                      solar_open2)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -126,6 +139,8 @@ def _models():
             olmo_hybrid.get_config("tinyolmohybrid", **bf16)),
         "lfm2": lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config(
             "tinylfm2", expert_parallel=False, **bf16)),
+        "solar": solar_open2.SolarOpen2ForCausalLM(solar_open2.get_config(
+            "tinysolar", **bf16)),
     }
 
 

@@ -49,6 +49,7 @@ MODELS = {
     "tinykeye": ("keye_vl2", "KeyeVL2ForCausalLM",
                  dict(expert_parallel=False)),
     "tinyolmohybrid": ("olmo_hybrid", "OlmoHybridForCausalLM", {}),
+    "tinysolar": ("solar_open2", "SolarOpen2ForCausalLM", {}),
 }
 ENGINE = dict(pipeline=False, max_seqs=2, max_seq_len=128, prefill_chunk=32,
               page_size=16, num_pages=20, decode_block_size=2)
@@ -57,7 +58,7 @@ COMMON = {"program", "ahead", "rows", "ticks", "key_tokens", "pages_in_use",
           "pages_total", "prefill_tokens", "prefill_key_tokens",
           "decode_key_tokens", "head_rows"}
 KIND_BLOCKS = ("kernels", "latent", "loop", "linear_state", "sparse",
-               "hybrid", "token_select", "delta_state")
+               "hybrid", "token_select", "delta_state", "kda_state")
 PLAIN = {"paged_attention": "xla-dense",
          "paged_attention_decode": "xla-dense",
          "paged_attention_prefill": "xla-dense"}
@@ -65,6 +66,7 @@ PAD128 = {k: "xla-dense-pad128" for k in PLAIN}
 GROUPED = {"moe_grouped_matmul": "ragged-dot-xla",
            "moe_grouped_matmul_kernel": "ragged-dot"}
 DELTA = {"gated_delta_scan": "xla", "gated_delta_decode": "xla"}
+KDA = {"kda_scan": "xla", "kda_decode": "xla"}
 ANY = type("Any", (), {"__eq__": lambda self, other: True})()
 KEY_STEPS = dict(index_key_steps=3, index_live_key_steps=1)
 
@@ -201,6 +203,21 @@ EXPECTED = {
                                 "state_rows": 6, "kv_heads_stored": 16,
                                 "kv_bytes_per_token": 1024,
                                 "routes": {**PLAIN, **DELTA}}}),
+    # one period: three KDA layers of two leaves each (a 16 x 2*16 float32
+    # matrix and 3 rows of 96 convolution inputs: 3,200 bytes a layer a
+    # sequence), one K/V layer of two heads; 3 of 12 experts held
+    "tinysolar": dict(
+        numbers=(1, 3, 1, 0, None),
+        attrs={"state_layers": 3, "state_bytes": 9600, "kda_layers": 3},
+        asks=[dict(state_rows=1, one_row_seqs=0)] * 3
+        + [dict(state_rows=1)] * 3,
+        stages={"kernels": {**PLAIN, **KDA, **GROUPED},
+                "kda_state": {"state_layers": 3, "kv_layers": 1,
+                              "state_bytes_per_seq": 9600,
+                              "state_bytes_per_seq_counted": 9600,
+                              "state_rows": 6, "kv_heads_stored": 2,
+                              "kv_bytes_per_token": 256,
+                              "routes": {**PLAIN, **KDA, **GROUPED}}}),
 }
 
 
@@ -265,6 +282,7 @@ _SALA = "MiniCPMSALAForCausalLM keeps per-slot state in 2 of its 4 layers"
 _GLM = ("Glm4MoeLiteForCausalLM caches one latent row of 128 values a token "
         "a layer")
 _OLMO = "OlmoHybridForCausalLM keeps per-slot state in 3 of its 4 layers"
+_SOLAR = "SolarOpen2ForCausalLM keeps per-slot state in 3 of its 4 layers"
 _KEYE = ("KeyeVL2ForCausalLM attends the 24 keys an indexer of 2 heads "
          "chooses, token by token, in 2 of its 2 layers")
 _STATE = {
@@ -302,6 +320,10 @@ MESSAGES = {
     ("tinyolmohybrid", "tiering"): f"kv_tiering: {_OLMO}, and a spilled "
     "request's record would carry megabytes of state a sequence beside its "
     "pages (ROADMAP R5(d): a tier for matrix state)",
+    **{("tinysolar", f): m.format(_SOLAR) for f, m in _STATE.items()},
+    ("tinysolar", "tiering"): f"kv_tiering: {_SOLAR}, and a spilled "
+    "request's record would carry megabytes of state a sequence beside its "
+    "pages (ROADMAP R5(d): a tier for matrix state)",
     ("tinyglm", "speculation"): f"speculation.mode='ngram': {_GLM}, and the "
     "speculative verify has no latent attention kernel (nor a draft that "
     "shares the latent pool)",
@@ -330,7 +352,7 @@ MESSAGES = {
 
 def test_the_descriptions_refusals_are_the_ones_listed():
     """Eighteen refusals in five kinds' files (per-slot state's five serve
-    three models, and the matrix state's tiering two), and none for a model
+    four models, and the matrix state's tiering three), and none for a model
     of plain K/V pages."""
     listed = set()
     for preset in MODELS:
@@ -339,7 +361,7 @@ def test_the_descriptions_refusals_are_the_ones_listed():
                 type(model).__name__):
             assert model_is.startswith(type(model).__name__)
             listed |= {(preset, feature) for feature in refuses}
-    assert listed == set(MESSAGES) and len(MESSAGES) == 18 + 5 + 6
+    assert listed == set(MESSAGES) and len(MESSAGES) == 18 + 5 + 6 + 6
 
 
 @pytest.mark.parametrize("preset,feature", sorted(MESSAGES))
@@ -428,13 +450,13 @@ def test_index_key_steps_are_the_live_tiles_up_to_their_last_row(
 def test_the_engine_names_no_cache_kind():
     words = re.compile(r"latent|sparse|conv(?!ert|enien)|lightning|"
                        r"linear_state|hybrid|ut_steps|CKEY|tokensel|"
-                       r"token_select|index_key|indexer|delta_|gated_delta",
+                       r"token_select|index_key|indexer|delta_|gated_delta|kda",
                        re.IGNORECASE)
     code = _code(ENGINE_FILE)
     assert [(n, t) for n, t in code if words.search(t)] == []
     kernels = ("latent_attention", "block_select_attention",
                "linear_attention", "token_select_attention",
-               "gated_delta_rule")
+               "gated_delta_rule", "kda")
     assert not any(k in m for m in _imports(ENGINE_FILE) for k in kernels)
     calls = [n for (n, t), (_, nxt) in zip(code, code[1:])
              if t == "_refuse_features" and nxt == "("]

@@ -73,14 +73,15 @@ def served():
     return MODEL, params
 
 
-def run_schedule(params):
+def run_schedule(params, cfg=CFG, module=oh.OlmoHybridForCausalLM):
     """Every logit row of three teacher-forced sequences through the
-    serving form: ``{sequence: [len, V]}``."""
+    serving form: ``{sequence: [len, V]}`` (``cfg``, ``module``: another
+    family of the same tiny vocabulary, ``test_solar_open2.py``)."""
     pp = MAX_LEN // PAGE
     cfg = programs.serving_config(
-        CFG, max_seq_len=MAX_LEN, page_size=PAGE, num_pages=SLOTS * pp + 1,
+        cfg, max_seq_len=MAX_LEN, page_size=PAGE, num_pages=SLOTS * pp + 1,
         tensor_parallel=False, kv_cache_dtype="none")
-    model = oh.OlmoHybridForCausalLM(cfg)
+    model = module(cfg)
     T = CHUNK + SLOTS
     cache = programs.init_cache(model, max_seqs=SLOTS, width=T)
     seqs = [_tokens(n, 20 + i) for i, n in enumerate(LENGTHS)]

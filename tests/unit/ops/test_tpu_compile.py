@@ -741,6 +741,43 @@ def test_thirty_heads_are_served_from_both_kernels_as_thirty_two(
     assert f"%{paged_decode.NAME}" in text
 
 
+# solar-open2-250b-serve: 64 KDA heads of 128 / 128 over a 128 x 128 float32
+# state held heads along the lanes (both dimensions whole lane tiles), g a
+# vector a key channel; 64 query heads over 8 KV heads of 128 in a pool of
+# 2,065 pages, 8 slots of 258 pages, a fused tick of 8 + 1,024 rows
+SOLAR = dict(S=8, pp=258, pool=2065, page=64, H=64, dk=128, dv=128, Hq=64,
+             Hkv=8, D=128, T=1032)
+
+
+def test_kda_kernels_at_the_serving_configuration(chip_compile):
+    """``ops/kda.py`` at the published 128 / 128: the chunked scan (the
+    running sum, the seven level products with their sublane rolls, the
+    solve) over the tiles a fused tick of ``solar-open2-250b-serve`` can
+    hold, and the one-token step over its eight slots."""
+    from deepspeed_tpu.ops import kda
+
+    c, i32, bf, f32 = SOLAR, jnp.int32, jnp.bfloat16, jnp.float32
+    chunk = kda.CHUNK
+    NT = -(-c["T"] // chunk) + c["S"]
+    rows = lambda d, t=bf: ((NT * chunk, c["H"] * d), t)
+    state = ((c["S"], c["dk"], c["H"] * c["dv"]), f32)
+    assert kda.scan_eligible(c["H"], c["dk"], c["dv"], bf)
+    text = chip_compile(
+        functools.partial(kda._scan.__wrapped__, chunk=chunk,
+                          interpret=False),
+        rows(c["dk"]), rows(c["dk"]), rows(c["dv"]), rows(c["dk"], f32),
+        ((NT * chunk, c["H"]), f32), state, ((NT,), i32), ((NT,), i32),
+        ((NT,), i32))
+    assert f"%{kda.SCAN_NAME}" in text
+    row = lambda d: ((c["S"], c["H"], d), bf)
+    text = chip_compile(
+        functools.partial(kda._step.__wrapped__, interpret=False),
+        row(c["dk"]), row(c["dk"]), row(c["dv"]),
+        ((c["S"], c["H"], c["dk"]), f32), ((c["S"], c["H"]), f32), state,
+        ((c["S"],), i32), ((c["S"],), jnp.bool_), ((1,), i32))
+    assert f"%{kda.STEP_NAME}" in text
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4

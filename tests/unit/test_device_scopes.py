@@ -36,13 +36,14 @@ FAMILIES = {
     "sala": {"attn", "linear", "mlp"},
     "keye": {"attn", "moe"},
     "olmo-hybrid": {"attn", "linear", "mlp"},
+    "solar": {"attn", "linear", "moe"},
 }
 
 
 def _model(family):
     from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
                                       minicpm_sala, mistral, olmo_hybrid,
-                                      olmoe, ouro)
+                                      olmoe, ouro, solar_open2)
 
     if family == "mistral":
         return mistral.MistralForCausalLM(mistral.get_config(
@@ -63,6 +64,9 @@ def _model(family):
     if family == "olmo-hybrid":
         return olmo_hybrid.OlmoHybridForCausalLM(
             olmo_hybrid.get_config("tinyolmohybrid", **BF16))
+    if family == "solar":
+        return solar_open2.SolarOpen2ForCausalLM(
+            solar_open2.get_config("tinysolar", **BF16))
     if family == "keye":
         return keye_vl2.KeyeVL2ForCausalLM(keye_vl2.get_config(
             "tinykeye", expert_parallel=False, **BF16))
@@ -268,6 +272,16 @@ def _train_engine(loss_module, dp=2):
      "pallas_call", ("linear", "delta_chunk_scan", "-")),
     ("jit(f)/M/model/layers_0/linear_attn/jit(_step)/delta_decode_step/"
      "pallas_call", ("linear", "delta_decode_step", "-")),
+    # a KDA mixer under the same module name: its two kernels, the decay's
+    # low-rank pair among its gates, and the held expert layer's ``cond``
+    ("jit(f)/M/model/layers_1/linear_attn/jit(_scan)/kda_chunk_scan/"
+     "pallas_call", ("linear", "kda_chunk_scan", "-")),
+    ("jit(f)/M/model/layers_1/linear_attn/jit(_step)/kda_decode_step/"
+     "pallas_call", ("linear", "kda_decode_step", "-")),
+    ("jit(f)/M/model/layers_1/linear_attn/gates/f_b_proj/dot_general",
+     ("linear", "gates", "-")),
+    ("jit(f)/M/model/layers_1/mlp/cond/branch_0_fun/moe.combine/dot_general",
+     ("moe", "moe.combine", "-")),
     ("jit(f)/M/model/layers_0/self_attn/compress/gather",
      ("attn", "compress", "-")),
     ("jit(f)/M/model/layers_0/self_attn/select/top_k",
