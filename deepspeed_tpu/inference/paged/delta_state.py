@@ -128,6 +128,14 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     return o.reshape(T, H, dv)
 
 
+def _levels_halved(what: str, chunk: int, first: int) -> Dict[str, int]:
+    """``<what>_levels`` of a scan's doubling at its tile and how many of
+    them multiply half the tile, read from the function the kernel unrolls
+    (``gated_delta_rule.levels``)."""
+    halved, of = gated_delta_rule.levels_halved(chunk, first)
+    return {f"{what}_levels_compacted": halved, f"{what}_levels": of}
+
+
 class DeltaState(SlotState):
     """The gated-delta-rule layers of a model whose others keep K/V pages."""
 
@@ -168,4 +176,5 @@ class DeltaState(SlotState):
             "kv_heads_stored": heads,
             "kv_bytes_per_token": (self.of - self.layers) * 2 * heads
             * int(cfg.head_dim) * jnp.dtype(cfg.dtype).itemsize,
+            **_levels_halved("solve", gated_delta_rule.CHUNK, 2),
             "routes": dict(kernels)}}

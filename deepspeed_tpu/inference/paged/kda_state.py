@@ -20,6 +20,7 @@ import functools
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.paged.delta_state import (DeltaState,
+                                                       _levels_halved,
                                                        ragged_gated_delta)
 from deepspeed_tpu.inference.paged.kv_pages import kv_heads_stored
 from deepspeed_tpu.inference.paged.tree import state_bytes_per_seq
@@ -70,4 +71,6 @@ class KdaState(DeltaState):
             "kv_heads_stored": heads,
             "kv_bytes_per_token": (self.of - self.layers) * 2 * heads
             * int(cfg.head_dim) * jnp.dtype(cfg.dtype).itemsize,
+            **_levels_halved("solve", kda.CHUNK, 2),
+            **_levels_halved("pair", kda.CHUNK, 1),
             "routes": dict(kernels)}}

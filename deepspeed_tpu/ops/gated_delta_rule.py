@@ -24,15 +24,22 @@ serve it (``paged/delta_state.py:ragged_gated_delta``):
       O   = diag(exp G) Q S_0 + tril((Q K^T) * exp(G_i - G_j)) U
       S_C = exp(G_C) S_0 + (K * exp(G_C - G_i))^T U
 
-  ``A`` is strictly lower triangular and ``T`` is built by doubling: the
-  inverse of every diagonal block of ``2 s`` rows from those of its halves,
-  ``[[T1, 0], [-T2 A21 T1, T2]]``, ``log2 C - 1`` levels of two products on
-  the MXU.  That is forward substitution by blocks, and as stable: the
-  finite product ``(I - A)(I + A^2)(I + A^4) ..`` costs the same products
-  and passes through powers of ``A`` whose entries, for keys that resemble
-  one another, are thousands of times the inverse's (at keys of 8 it lost
-  three digits of float32 in a tile).  Every exponent is of a non-positive
-  number.  The rows of a packed ragged batch are laid out
+  ``A`` is strictly lower triangular and ``T`` is built by doubling
+  (:func:`solve`, which ``ops/kda.py`` calls too): the inverse of every
+  diagonal block of ``2 s`` rows from those of its halves, ``[[T1, 0], [-T2
+  A21 T1, T2]]``, ``log2 C - 1`` levels of two products on the MXU.  A level
+  couples the ``s`` rows of a block whose index has bit ``s`` with the ``s``
+  that have it not, and changes the former alone: from ``s = 8``, where
+  those rows are whole groups of sublanes, its two products stream those ``C
+  / 2`` rows and no others (``[C/2, C] x [C, C]``; 4 of the 6 levels at ``C``
+  = 128, :func:`levels`), the levels below multiply the whole tile.  What is
+  dropped are products with exact zeros: the results are the whole
+  products' to the bit.  The doubling is forward substitution by blocks,
+  and as stable: the finite product ``(I - A)(I + A^2)(I + A^4) ..`` costs
+  no fewer products and passes through powers of ``A`` whose entries, for
+  keys that resemble one another, are thousands of times the inverse's (at
+  keys of 8 it lost three digits of float32 in a tile).  Every exponent is
+  of a non-positive number.  The rows of a packed ragged batch are laid out
   in tiles of ``C`` by ``linear_attention.tile_layout`` (a tile is of one
   sequence; a head's state rides a sequence's tiles in the output block;
   ``LOAD`` / ``ZERO`` / carry as there), each row AS THE PROJECTIONS LEAVE
@@ -90,10 +97,14 @@ SCAN_NAME = "delta_chunk_scan"
 STEP_NAME = "delta_decode_step"
 
 _LANES = 128
-# tokens a tile of the scan holds.  The MXU's work a token is the same at 64
-# and 128 (the solve is 2 (log2 C - 1) products of C x C, the other products
-# grow with C); on the v5e a tick of 1,024 rows took 1.71 ms a layer at 64
-# and 1.52 at 128, half the grid steps (PERF.md, PR 50)
+# rows a float32 register holds: a level of the doubling whose blocks have at
+# least as many is taken on half the tile (``levels``)
+_SUBLANES = 8
+# tokens a tile of the scan holds.  The MXU's work a token is about the same
+# at 64 and 128 (the solve is two products a level over log2 C - 1 levels,
+# of C x C below s = 8 and of C/2 x C from there: ``levels``; the other
+# products grow with C); on the v5e a tick of 1,024 rows took 1.71 ms a
+# layer at 64 and 1.52 at 128, half the grid steps (PERF.md, PR 50)
 CHUNK = 128
 # heads a grid step holds: of the scan (two chains of products to overlap,
 # and 2 x 192 lanes of v, o and the state are three whole lane tiles; q's and
@@ -118,6 +129,82 @@ def _as_stored(a, b):
     return jax.lax.dot_general(
         a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         precision=_HIGHEST if a.dtype == jnp.float32 else None)
+
+
+# -- a level of the doubling, and the half of a tile it couples ---------------
+
+def levels(C: int, first: int = 2):
+    """``(s, half)`` for every level of a doubling over a tile of ``C`` rows
+    (a power of two), from blocks of ``first`` rows up: the level couples,
+    in every block of ``2 s`` rows, the ``s`` rows whose index has bit ``s``
+    with the ``s`` that have it not.  ``half``: the level multiplies those
+    ``C / 2`` rows alone, which it does where they are whole groups of the
+    eight sublanes of a float32 array, so that taking them is a static
+    choice of registers and no shuffle.  Unrolled by :func:`solve` (and by
+    ``ops/kda.py`` for its level products, from 1), counted by
+    :func:`levels_halved`."""
+    assert C & (C - 1) == 0, f"a tile of {C} rows is no power of two"
+    out, s = [], first
+    while s < C:
+        out.append((s, s >= _SUBLANES))
+        s *= 2
+    return tuple(out)
+
+
+def levels_halved(C: int, first: int = 2):
+    """``(levels that multiply half the tile, levels)`` of :func:`levels`:
+    4 of 6 at a tile of 128 (4 of 7 from 1)."""
+    halves = [half for _, half in levels(C, first)]
+    return sum(halves), len(halves)
+
+
+def rows_with(x, s: int, bit: bool = True):
+    """The rows of ``x [C, ..]`` whose index has bit ``s`` (or has it not),
+    ``C / 2`` of them in their order: blocks of ``s`` rows side by side."""
+    return jnp.concatenate([x[r:r + s] for r in range(s if bit else 0,
+                                                      x.shape[0], 2 * s)])
+
+
+def rows_spread(lower, s: int):
+    """``rows_with(.., s, False)`` undone: ``lower [C / 2, ..]`` at the rows
+    of ``[C, ..]`` whose index has not bit ``s``, zeros at those that have."""
+    zero = jnp.zeros_like(lower[:s])
+    return jnp.concatenate([part for r in range(0, lower.shape[0], s)
+                            for part in (lower[r:r + s], zero)])
+
+
+def rows_plus(x, up, s: int):
+    """``x [C, ..]`` with ``up [C / 2, ..]`` added to the rows whose index
+    has bit ``s``, the others as they are."""
+    parts = []
+    for n, r in enumerate(range(0, x.shape[0], 2 * s)):
+        parts += [x[r:r + s], x[r + s:r + 2 * s] + up[n * s:(n + 1) * s]]
+    return jnp.concatenate(parts)
+
+
+def solve(a):
+    """``(I + A)^-1`` of a strictly lower triangular ``a [C, C]`` float32,
+    by doubling: the inverse of every diagonal block of ``2 s`` rows from
+    those of its two halves, ``[[T1, 0], [-T2 A21 T1, T2]]``, all blocks of
+    a size at once as ``T - T A_off T`` (``A_off``: each block's lower
+    left).  ``A_off`` is zero outside the rows that have bit ``s``, ``T`` is
+    block diagonal in blocks of ``s``, so ``T A_off T`` is zero outside
+    those rows too: a level that :func:`levels` halves streams only them
+    through the two products (``[C/2, C] x [C, C]``; the products it drops
+    are of exact zeros).  Both rules' ``_chunk_math`` call this, kernel and
+    twin alike."""
+    C = a.shape[0]
+    i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    differ = i ^ j                      # a pair's level: its highest set bit
+    t = jnp.where(i == j, 1.0, 0.0) - jnp.where((differ >> 1) == 0, a, 0.0)
+    for s, half in levels(C):
+        off = jnp.where((differ >> (s.bit_length() - 1)) == 1, a, 0.0)
+        if half:
+            t = rows_plus(t, -_dot(_dot(rows_with(t, s), off), t), s)
+        else:
+            t = t - _dot(_dot(t, off), t)
+    return t
 
 
 # -- the definition and the twins --------------------------------------------
@@ -157,17 +244,7 @@ def _chunk_math(q, k, v, aux, s_in, n):
     g_col, b_col = column(g_row), column(b_row)               # [C, 1]
     decay = jnp.exp(jnp.where(i >= j, g_col - g_row, 0.0))    # exp(G_i - G_j)
     a = jnp.where(i > j, b_col * _as_stored(k, k) * decay, 0.0)
-    # (I + A)^-1 by doubling: the inverse of every diagonal block of 2s rows
-    # from those of its two halves, [[T1, 0], [-T2 A21 T1, T2]], all blocks
-    # of a size at once as T - T A_off T (A_off: each block's lower left)
-    same = lambda bits: (i >> bits) == (j >> bits)
-    t = jnp.where(i == j, 1.0, 0.0) - jnp.where(same(1), a, 0.0)
-    bits = 1
-    while (1 << bits) < C:
-        off = jnp.where(jnp.logical_and(same(bits + 1),
-                                        jnp.logical_not(same(bits))), a, 0.0)
-        t = t - _dot(_dot(t, off), t)
-        bits += 1
+    t = solve(a)
     kf, qf, vf = (x.astype(jnp.float32) for x in (k, q, v))
     e_col = jnp.exp(g_col)
     u = _dot(t, b_col * (vf - _dot(kf * e_col, s_in)))        # [C, dv]

@@ -24,8 +24,9 @@ forms serve it (``paged/kda_state.py``):
       O   = (Q * exp G) S_0 + tril(R) U
       S_C = diag(exp G_C) S_0 + (K * exp(G_C - G_i))^T U
 
-  ``T`` by doubling, as ``ops/gated_delta_rule.py`` builds it and for its
-  reason.  **``P`` and ``R`` are no product masked by a scalar**: the
+  ``T`` by doubling, by the one function that builds it for both rules
+  (``ops/gated_delta_rule.py:solve``) and for its reason.  **``P`` and ``R``
+  are no product masked by a scalar**: the
   exponent sits inside the contraction.  They are matrix products again once
   a reference row ``r`` with ``j <= r <= i`` splits it, ``exp(G_i - G_r)
   exp(G_r - G_j)``, both factors at most 1.  The kernel takes one ``r`` a
@@ -34,11 +35,22 @@ forms serve it (``paged/kda_state.py``):
   ``r = (i // s) s``, the first row of ``i``'s block of ``s``, so ``P = sum_s
   mask_s ((K * E_row_s) (K * E_col_s)^T)`` with ``E_row_s = exp(G_i - G_r)``
   on the rows that have the bit (else 0) and ``E_col_s = exp(G_r - G_j)`` on
-  those that have not: ``log2 C`` whole ``[2C, dk] x [dk, C]`` products (K and
-  Q stacked) on the MXU, every pair ``i > j`` in exactly one of them, and
-  ``G_r`` by one sublane roll a level from the level before (``X_s[i] = G[(i
-  // s) s]`` is ``X_(s/2)`` with bit ``s/2`` cleared; the column's reference
-  is ``X_s`` rolled up by ``s``).  Every exponent the kernel takes is of a
+  those that have not: ``log2 C`` products (K and Q stacked) on the MXU,
+  every pair ``i > j`` in exactly one of them, and ``G_r`` by one sublane
+  roll a level from the level before (``X_s[i] = G[(i // s) s]`` is
+  ``X_(s/2)`` with bit ``s/2`` cleared; the column's reference is ``X_s``
+  rolled up by ``s``, which is the reference of the row ``s`` below).  Below
+  ``s = 8`` a level is a whole ``[2C, dk] x [dk, C]`` product whose operands
+  are half zeros.  From there (``gated_delta_rule.levels``: 4 of the 7
+  levels at ``C`` = 128) the rows that have the bit are whole groups of
+  sublanes, and the level takes ``E_row``, ``K * E_row`` and ``Q * E_row``
+  on those ``C / 2`` rows alone, stacked ``[C, dk]``, ``E_col`` and ``K *
+  E_col`` on the ``C / 2`` that have it not (zeros between them: the
+  product's other side stays ``C`` wide, the MXU's time follows the rows
+  streamed and not the columns, PERF.md PR 57), and adds the product to
+  the same rows of ``P`` and ``R``: half the rows streamed, half the
+  ``exp`` and the multiplies, the same numbers to the bit.  Every exponent
+  the kernel takes is of a
   non-positive number (none is ever ``exp(-G)``), so a channel whose decay
   underflows inside a chunk gives zeros, not ``inf * 0``.  ``R``'s diagonal
   is ``q_i . k_i``.  The running sum ``G`` is a product with a triangle of
@@ -75,7 +87,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops import routes
 from deepspeed_tpu.ops.gated_delta_rule import (_dot, _heads_along_lanes,
-                                                _heads_first)
+                                                _heads_first, levels,
+                                                rows_plus, rows_spread,
+                                                rows_with, solve)
 from deepspeed_tpu.ops.linear_attention import LOAD, ZERO
 
 # the route keys, and the operations' names in a program and a device trace
@@ -126,10 +140,12 @@ def _chunk_math(q, k, v, g, b_col, s_in, n, roll=jnp.roll):
     dv]`` float32, ``n`` live rows -> ``(o [C, dv], s_out)``.  Shared by the
     kernel body (``roll``: the sublane rotate) and the twin."""
     C, dk = q.shape
-    assert C & (C - 1) == 0, f"a tile of {C} rows is no power of two"
     i = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    # row and column of the [C / 2, C] block a halved level forms
+    half_i = jax.lax.broadcasted_iota(jnp.int32, (C // 2, C), 0)
+    half_j = jax.lax.broadcasted_iota(jnp.int32, (C // 2, C), 1)
     # a row at or past ``n`` holds no token, whatever the tile holds there
     live = row < n
     qf, kf, vf = (jnp.where(live, x, jnp.zeros_like(x)).astype(jnp.float32)
@@ -141,31 +157,39 @@ def _chunk_math(q, k, v, g, b_col, s_in, n, roll=jnp.roll):
     # is s, by the reference row (i // s) s of i's block of s rows
     p = jnp.zeros((C, C), jnp.float32)
     r = jnp.where(i == j, jnp.sum(qf * kf, axis=1, keepdims=True), 0.0)
-    ref, s, bit = big, 1, 0             # ref[i] = G[(i // s) s]
-    while s < C:
+    ref = big                           # ref[i] = G[(i // s) s]
+    for s, half in levels(C, 1):
+        bit = s.bit_length() - 1
         if s > 1:
             ref = jnp.where((row & (s // 2)) != 0, roll(ref, s // 2, 0), ref)
-        upper = (row & s) != 0
-        e_row = jnp.where(upper, jnp.exp(jnp.minimum(big - ref, 0.0)), 0.0)
         # a column's reference is the first row of the NEXT block of s
-        e_col = jnp.where(upper, 0.0, jnp.exp(jnp.minimum(
-            roll(ref, C - s, 0) - big, 0.0)))
-        m = _dot(jnp.concatenate([kf * e_row, qf * e_row], axis=0),
-                 kf * e_col, _NT)                             # [2C, C]
-        same = (i >> (bit + 1)) == (j >> (bit + 1))
-        p = p + jnp.where(same, m[:C], 0.0)
-        r = r + jnp.where(same, m[C:], 0.0)
-        s, bit = 2 * s, bit + 1
-    a = b_col * p                       # strictly lower triangular
-    # (I + A)^-1 by doubling (ops/gated_delta_rule.py:_chunk_math)
-    same = lambda bits: (i >> bits) == (j >> bits)
-    t = jnp.where(i == j, 1.0, 0.0) - jnp.where(same(1), a, 0.0)
-    bits = 1
-    while (1 << bits) < C:
-        off = jnp.where(jnp.logical_and(same(bits + 1),
-                                        jnp.logical_not(same(bits))), a, 0.0)
-        t = t - _dot(_dot(t, off), t)
-        bits += 1
+        if half:
+            # the rows that have the bit against those that have not, where
+            # that reference is the reference of the row s below
+            ref_up = rows_with(ref, s)
+            e_row = jnp.exp(jnp.minimum(rows_with(big, s) - ref_up, 0.0))
+            e_col = jnp.exp(jnp.minimum(
+                ref_up - rows_with(big, s, False), 0.0))
+            lower = rows_with(kf, s, False) * e_col           # [C/2, dk]
+            m = _dot(jnp.concatenate([rows_with(kf, s) * e_row,
+                                      rows_with(qf, s) * e_row]),
+                     rows_spread(lower, s), _NT)              # [C, C]
+            # the u-th row that has the bit lies in block u // s of 2 s rows
+            same = (half_i >> bit) == (half_j >> (bit + 1))
+            p = rows_plus(p, jnp.where(same, m[:C // 2], 0.0), s)
+            r = rows_plus(r, jnp.where(same, m[C // 2:], 0.0), s)
+        else:
+            upper = (row & s) != 0
+            e_row = jnp.where(upper, jnp.exp(jnp.minimum(big - ref, 0.0)),
+                              0.0)
+            e_col = jnp.where(upper, 0.0, jnp.exp(jnp.minimum(
+                roll(ref, C - s, 0) - big, 0.0)))
+            m = _dot(jnp.concatenate([kf * e_row, qf * e_row]),
+                     kf * e_col, _NT)                         # [2C, C]
+            same = ((i ^ j) >> (bit + 1)) == 0
+            p = p + jnp.where(same, m[:C], 0.0)
+            r = r + jnp.where(same, m[C:], 0.0)
+    t = solve(b_col * p)                # of a strictly lower triangle
     e = jnp.exp(big)
     u = _dot(t, b_col * (vf - _dot(kf * e, s_in)))            # [C, dv]
     o = _dot(qf * e, s_in) + _dot(r, u)

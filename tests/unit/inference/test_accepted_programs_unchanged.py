@@ -33,7 +33,15 @@ on its own tree, and moved none of the fourteen: an expert layer told
 ``expert_shards=1`` (every older family) reaches ``grouped_moe``'s own body
 and sows no ``held`` leaf, ``ragged_gated_delta`` takes its rule as an
 argument whose default is the module it named, and ``moe_counts`` picks the
-same leaves in the same order.
+same leaves in the same order.  PR 57 re-took ``olmo_hybrid.fused`` and
+``solar.fused`` and no other: it MEANT to move these two (the doubling of
+both chunked scans is one function, ``ops/gated_delta_rule.py:solve``, and a
+level with ``s >= 8`` of it and of KDA's ``P`` / ``R`` products multiplies
+the ``C / 2`` rows the level couples where it multiplied the whole tile;
+this file lowers on the CPU, where the tick takes the twins, which unroll
+the same levels); both decode blocks, whose rows take the one-token step,
+and the twelve programs of the six other models are the parent's to the
+letter.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -98,8 +106,11 @@ EXPECTED = {
     # a row a sequence, is PR 50's to the letter.  Re-taken again by PR 54,
     # which MEANT to move it (d613a233d64e7c0f before it):
     # ``ragged_gated_delta`` keeps a tick's rows ``[T, H * d]`` as
-    # ``ragged_linear_attention`` does (above)
-    "olmo_hybrid.fused": "b0d11e56bd01f5d8",
+    # ``ragged_linear_attention`` does (above).  And by PR 57, which MEANT
+    # to move it (b0d11e56bd01f5d8 before it): the scan's solve is
+    # ``gated_delta_rule.solve``, whose levels from ``s = 8`` multiply the
+    # half of the tile they couple
+    "olmo_hybrid.fused": "53a639c575758088",
     "olmo_hybrid.decode_block": "45abd5d3a9dd0bed",
     # added by PR 51 (ROADMAP D14), the same function's other caller: the
     # decode block taken on PR 51's parent, the tick (da4cded4c6195441
@@ -110,8 +121,10 @@ EXPECTED = {
     # scan's chunk, the scan and the one-token step with a decay a key
     # channel, gated GQA without rotary, an expert layer that HOLDS 3 of its
     # router's 12 experts: ``held_moe`` and its ``cond``); the fourteen older
-    # hashes are as they were
-    "solar.fused": "022473bd94042151",
+    # hashes are as they were.  ``solar.fused`` re-taken by PR 57, which
+    # MEANT to move it (022473bd94042151 before it): the same solve, and the
+    # level products of ``P`` and ``R`` on the rows that have the level's bit
+    "solar.fused": "3b7548ccb6e0b14e",
     "solar.decode_block": "dd04a8cc32f4b803",
 }
 

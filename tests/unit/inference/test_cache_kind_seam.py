@@ -202,6 +202,10 @@ EXPECTED = {
                                 "state_bytes_per_seq_counted": 5376,
                                 "state_rows": 6, "kv_heads_stored": 16,
                                 "kv_bytes_per_token": 1024,
+                                # of the doubling's levels at a tile of 128
+                                # (PR 57): those that multiply half of it
+                                "solve_levels_compacted": 4,
+                                "solve_levels": 6,
                                 "routes": {**PLAIN, **DELTA}}}),
     # one period: three KDA layers of two leaves each (a 16 x 2*16 float32
     # matrix and 3 rows of 96 convolution inputs: 3,200 bytes a layer a
@@ -217,6 +221,8 @@ EXPECTED = {
                               "state_bytes_per_seq_counted": 9600,
                               "state_rows": 6, "kv_heads_stored": 2,
                               "kv_bytes_per_token": 256,
+                              "solve_levels_compacted": 4, "solve_levels": 6,
+                              "pair_levels_compacted": 4, "pair_levels": 7,
                               "routes": {**PLAIN, **KDA, **GROUPED}}}),
 }
 
