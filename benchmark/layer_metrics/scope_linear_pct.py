@@ -7,8 +7,10 @@ a ``lightning_attn`` module (lightning attention: projections, QK-norm, RoPE,
 and back, the output norm and gate) or a ``linear_attn`` module (the gated
 delta rule: its projections and gate projections, the short convolution
 INSIDE it with its carried rows, the L2 norms, ``delta_chunk_scan``,
-``delta_decode_step``, the output norm and gate).  A program with neither
-module has no such kind and the reader returns None."""
+``delta_decode_step``, the output norm and gate; the delta rule whose decay
+is a vector a key channel is a ``linear_attn`` module too, with
+``kda_chunk_scan`` and ``kda_decode_step``).  A program with neither module
+has no such kind and the reader returns None."""
 from benchmark.layer_metrics import scope_trace
 
 

@@ -24,7 +24,6 @@ def test_manifest_shape_names_and_units():
     m = H.manifest()
     assert set(m) == KEYS
     assert 1 <= m["run_seconds"] <= 51 and isinstance(m["run_seconds"], int)
-    assert os.path.getsize(os.path.join(H.ROOT, "BENCHMARK.json")) < 65536
     for group in ("configs", "workloads", "end_to_end", "per_layer"):
         names = [x["name"] for x in m[group]]
         assert len(names) == len(set(names)), group
@@ -105,7 +104,6 @@ def test_per_layer_is_one_entry_a_reader_and_moves_with_its_cells():
     exists and reports the end-to-end metric the entry moves, and the
     cells stand in the order of ``workloads``."""
     m = H.manifest()
-    assert len(m["per_layer"]) <= 128
     order = [w["name"] for w in m["workloads"]]
     e2e = {e["name"]: e.get("workloads", order) for e in m["end_to_end"]}
     seen = set()
@@ -121,25 +119,53 @@ def test_per_layer_is_one_entry_a_reader_and_moves_with_its_cells():
             seen.add(key)
 
 
-# what each cell of the second fold reports, in the order of ``workloads``
+def test_the_file_keeps_under_its_two_ceilings():
+    """The HARD ceiling, of the file as it stands: 128 ``per_layer`` entries
+    and 65,536 bytes.  The ROOM a fold must leave is another statement,
+    about the folded cells alone, and the rule that joins the two stands
+    there (``test_a_thirteenth_cell_of_twenty_four_entries_fits``)."""
+    assert len(H.manifest()["per_layer"]) <= 128
+    assert os.path.getsize(os.path.join(H.ROOT, "BENCHMARK.json")) < 65536
+
+
+# what each cell of the third fold reports, in the order of ``workloads``
 FOLDED = {"chat-open": 18, "pretrain-zero3": 11, "longdoc-closed": 15,
           "reasoning-closed": 13, "olmoe-reasoning-closed": 15,
           "ouro-reasoning-closed": 15, "lfm2-reasoning-closed": 21,
-          "glm-longdoc16k-closed": 24, "sala-longdoc16k-closed": 22,
-          "keye-longdoc32k-closed": 24, "olmo-hybrid-longdoc16k-closed": 20}
+          "glm-longdoc16k-closed": 23, "sala-longdoc16k-closed": 21,
+          "keye-longdoc32k-closed": 23, "olmo-hybrid-longdoc16k-closed": 19,
+          "solar-longdoc16k-closed": 24}
 
 
-def test_the_second_fold_left_82_entries_and_lost_none():
-    """The eleven cells the benchmark had when ``per_layer`` was folded a
-    second time take 82 entries between them, ONE for each pair of reader
-    and ``moves``, and each reports the count it reported before the fold
-    (198 readings in all).  Entries of a cell a later ``model_config`` PR
-    adds stand beside these and are not counted here; the next fold takes
-    them into the lists and restates this table."""
+def _folded_alone(m):
+    """``m`` without the cells a later PR added beside the folded ones:
+    their workloads, their configurations, their names on the end-to-end
+    lists and every ``per_layer`` entry that names one of them."""
+    m["per_layer"] = [e for e in m["per_layer"]
+                      if set(e["workloads"]) <= set(FOLDED)]
+    m["workloads"] = [w for w in m["workloads"] if w["name"] in FOLDED]
+    used = {w["config"] for w in m["workloads"]}
+    m["configs"] = [c for c in m["configs"] if c["name"] in used]
+    for e in m["end_to_end"]:
+        if "workloads" in e:
+            e["workloads"] = [c for c in e["workloads"] if c in FOLDED]
+    return m
+
+
+def test_the_third_fold_left_86_entries_and_lost_none():
+    """The twelve cells the benchmark had when ``per_layer`` was folded a
+    third time take 86 entries between them, ONE for each pair of reader
+    and ``moves``, and each reports the count in ``FOLDED`` (218 readings in
+    all: the 220 before the fold, less ``fused_tick_host_ms`` in the four
+    cells where it read ``engine_step_host_ms`` to the digit, plus
+    ``prefill_p50_ms`` and ``hybrid_expert_share_pct`` in the twelfth cell).
+    Entries of a cell a later ``model_config`` PR adds stand beside these
+    and are not counted here; the next fold takes them into the lists and
+    restates this table."""
     m = H.manifest()
     assert [w["name"] for w in m["workloads"]][:len(FOLDED)] == list(FOLDED)
-    mine = [e for e in m["per_layer"] if set(e["workloads"]) <= set(FOLDED)]
-    assert len(mine) == 82
+    mine = _folded_alone(H.manifest())["per_layer"]
+    assert len(mine) == 86
     pairs = [(H.reader_of(e["name"]), e["moves"]) for e in mine]
     assert len(pairs) == len(set(pairs))
     for cell, count in FOLDED.items():
@@ -198,34 +224,42 @@ def test_a_ninth_cell_adds_suffixed_entries_beside_the_folded_ones(
         "scope_attn_pct.ninth": {"value": 14.0, "unit": "%"}}
 
 
-def test_a_twelfth_cell_of_twenty_four_entries_fits(monkeypatch):
-    """The room the second fold made, at the size the last three
-    configurations had (21, 23 and 19 entries): a configuration, a cell and
-    TWENTY-FOUR ``<reader>.<suffix>`` entries, sixteen on readers the
-    benchmark shares and eight on readers of the cell's own, fit under 128
-    entries and under the 65,536 bytes the file may have; the cell reports
-    its 24 and every other cell what it reported."""
-    m = H.manifest()
+def test_a_thirteenth_cell_of_twenty_four_entries_fits(monkeypatch):
+    """The ROOM the third fold left, at the size the last four
+    configurations had (21, 23, 19 and 24 entries): beside THE FOLDED
+    CELLS' ENTRIES ALONE, a configuration, a cell and TWENTY-FOUR
+    ``<reader>.<suffix>`` entries, sixteen on readers the benchmark shares
+    and eight on readers of the cell's own, fit under 128 entries and
+    under the 65,536 bytes the file may have; the cell reports its 24 and
+    every folded cell what it reported.  Every cell outside ``FOLDED`` is
+    dropped from the copy first, so this goes on saying "the last fold
+    left room for 24" after a ``model_config`` PR has used that room: such
+    a PR may use every free entry up to 128, which
+    ``test_the_file_keeps_under_its_two_ceilings`` holds of the file as it
+    stands, and a ``benchmark`` PR folds when fewer than 24 are free."""
+    m = _folded_alone(H.manifest())
+    monkeypatch.setattr(H, "manifest", lambda: m)
     before = {w["name"]: [e["name"] for e in H.Cell(w["name"]).metrics(
         "per_layer")] for w in m["workloads"]}
-    # the sixteen shared readers the last all-expert configuration is on
-    shared = [e for e in H.Cell("keye-longdoc32k-closed").metrics(
+    assert {k: len(v) for k, v in before.items()} == FOLDED
+    # the first sixteen shared readers the last configuration is on
+    shared = [e for e in H.Cell("solar-longdoc16k-closed").metrics(
         "per_layer") if "." not in e["name"]][:16]
     widest = max(m["per_layer"], key=lambda e: len(e["layer"]))
-    mine = [dict(e, name=e["name"] + ".twelfth", workloads=["twelfth"])
+    mine = [dict(e, name=e["name"] + ".thirteenth", workloads=["thirteenth"])
             for e in shared]
-    mine += [dict(widest, name=f"a_scan_of_its_own_{i}_roofline.twelfth",
-                  unit="%", workloads=["twelfth"]) for i in range(8)]
+    mine += [dict(widest, name=f"a_scan_of_its_own_{i}_roofline.thirteenth",
+                  unit="%", workloads=["thirteenth"]) for i in range(8)]
     assert len(mine) == 24 and len({e["name"] for e in mine}) == 24
     assert all(NAME.match(e["name"]) for e in mine)
     longest = max(m["configs"], key=lambda c: len(json.dumps(c)))
-    m["configs"].append(dict(longest, name="twelfth-serve"))
-    _add_cell(m, "twelfth", max(m["workloads"], key=lambda w: len(w["why"])),
-              mine, config="twelfth-serve", traffic="longdoc32k-closed")
+    m["configs"].append(dict(longest, name="thirteenth-serve"))
+    _add_cell(m, "thirteenth",
+              max(m["workloads"], key=lambda w: len(w["why"])),
+              mine, config="thirteenth-serve", traffic="longdoc32k-closed")
     assert len(m["per_layer"]) <= 128
     assert len(json.dumps(m, indent=1).encode()) < 65536
-    monkeypatch.setattr(H, "manifest", lambda: m)
-    cell = H.Cell("twelfth")
+    cell = H.Cell("thirteenth")
     assert [e["name"] for e in cell.metrics("per_layer")] == \
         [e["name"] for e in mine]
     for name, had in before.items():
@@ -234,8 +268,8 @@ def test_a_twelfth_cell_of_twenty_four_entries_fits(monkeypatch):
                         types.SimpleNamespace(read=lambda ctx: 1.5))
     got = H.layer_metrics(cell, {})
     assert list(got) == [e["name"] for e in mine]
-    assert got["moe_load_max_over_mean.twelfth"] == {"value": 1.5,
-                                                     "unit": "ratio"}
+    assert got["hybrid_load_max_over_mean.thirteenth"] == {"value": 1.5,
+                                                           "unit": "ratio"}
 
 
 def test_nothing_in_the_benchmark_imports_bench_or_private_engine_names():
