@@ -287,6 +287,15 @@ class HostStageStats:
         self.sel_rows = 0
         self.sel_key_tokens = 0
         self.sel_visible_tokens = 0
+        # (window layers with a pool of their own) that pool's pages in use,
+        # their peak, the pages sequences gave back while they lived (and
+        # how many of them the last dispatch's span had counted), beside the
+        # first pool's pages in use
+        self.window_pages_held = 0
+        self.window_pages_peak = 0
+        self.window_pages_released = 0
+        self.window_pages_said = 0
+        self.full_pages_held = 0
         # a looped model's exit gate, live rows only:
         self.loop_rows = 0          # rows at dispatch, summed
         self.loop_exit_cdf = []     # P(stopped by pass u), summed, u < U

@@ -29,8 +29,11 @@ delta rule, and the rows of the convolution before it: two leaves a layer),
 factor of its own),
 ``block_select.py`` (pages of keys and values chosen by compressed keys),
 ``index_select.py`` (tokens chosen by an indexer whose keys are a pool leaf),
-``latent.py`` (one latent row a token).  :func:`describe` composes a model's
-parts into the ONE description the engine reads.
+``latent.py`` (one latent row a token), ``window_pages.py`` (the window layers
+of a model that has full layers too: a pool, an allocator and a page table of
+their own, whose pages a sequence gives back as its window leaves them
+behind).  :func:`describe` composes a model's parts into the ONE description
+the engine reads.
 
 The package imports ``deepspeed_tpu.ops`` and ``deepspeed_tpu.utils`` (and, in
 the tensor-parallel branch of ``kv_pages.py`` alone, ``comm`` and
@@ -59,8 +62,8 @@ from deepspeed_tpu.inference.paged.kda_state import (  # noqa: F401
     KdaState, kda_layers, ragged_kda)
 from deepspeed_tpu.inference.paged.kv_pages import (  # noqa: F401
     _RPA_QUERY_BLOCK, KVPages, kv_dequant_path, kv_heads_stored, kv_lane_dim,
-    kv_slots_per_layer, paged_attention_path, paged_update_and_attend,
-    rpa_kv_pages_per_block)
+    kv_slots_per_layer, layer_window, paged_attention_path,
+    paged_update_and_attend, rpa_kv_pages_per_block)
 from deepspeed_tpu.inference.paged.latent import (  # noqa: F401
     LATENT_PAGES, Latent, latent_update_and_attend, latent_width,
     ref_latent_paged_attention)
@@ -69,9 +72,14 @@ from deepspeed_tpu.inference.paged.linear_state import (  # noqa: F401
 from deepspeed_tpu.inference.paged.reference import (  # noqa: F401
     fold_stats, neutral_carry, ref_paged_attention, ref_paged_attention_quant)
 from deepspeed_tpu.inference.paged.tree import (SLOT_STATE,  # noqa: F401
-                                                Part, is_slot_state,
-                                                map_cache, split_cache,
-                                                state_bytes_per_seq)
+                                                WINDOW_POOL, Part,
+                                                is_slot_state, map_cache,
+                                                split_cache,
+                                                state_bytes_per_seq,
+                                                window_pool,
+                                                window_pool_layers)
+from deepspeed_tpu.inference.paged.window_pages import (  # noqa: F401
+    WindowPages, WindowPool, pages_bound)
 
 
 def _merged(answers) -> Dict[str, Any]:
@@ -91,6 +99,9 @@ class CacheKind:
     kv_layers: int          # and those that keep rows in the pool
     latent_width: int       # of a latent layer's row as stored; 0: per-head
     selection: Optional[Any]    # of a block-selecting layer
+    # the part whose layers keep a pool and a page table of their own; None:
+    # every layer that keeps pages keeps them behind the one table
+    window: Optional[WindowPages] = None
 
     def refusals(self, model: str) -> List[Tuple[str, Dict[str, str]]]:
         """Each part's ``(model_is, {feature: why})``, for ``model`` (a
@@ -115,7 +126,7 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
     """The cache of the model whose config is ``cfg``, read off the config's
     own keys (``total_ut_steps``, ``layer_types``, ``mixer_types``,
     ``linear_attn_config`` and ``gqa_layers``, ``selection``, ``sa_config``,
-    ``kv_lora_rank``) by each kind's
+    ``kv_lora_rank``, ``sliding_window``) by each kind's
     predicate; ``tick``: the fused program's shape, for a kind that counts
     a tick's work in the program's tiles."""
     of = int(cfg.num_hidden_layers)
@@ -125,8 +136,16 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
     slots, width = kv_slots_per_layer(cfg), latent_width(cfg)
     selection, indexer = sparse_selection(cfg), token_selection(cfg)
     parts: List[Part] = []
+    own = window_pool_layers(cfg)
     if selection is None and indexer is None and not width:
-        parts.append(KVPages(rows, of, slots))
+        parts.append(KVPages(rows - own, of, slots))
+    window = None
+    if own:
+        window = WindowPages(
+            own, of, int(cfg.sliding_window),
+            0 if tick is None else pages_bound(
+                int(cfg.sliding_window), tick.rows, tick.page_size))
+        parts.append(window)
     if conv:
         parts.append(ConvState(conv, of))
     if linear:
@@ -143,7 +162,7 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
         parts.append(Latent(rows, of, width))
     return CacheKind(tuple(parts), slots,
                      conv + linear + delta + vector_delta, rows, width,
-                     selection)
+                     selection, window)
 
 
 def state_layers(cfg) -> int:

@@ -39,7 +39,7 @@ KV heads are stored as they are.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +48,8 @@ import numpy as np
 from deepspeed_tpu.inference.paged.reference import (
     _MASK_VALUE, _masked_stats, fold_stats, ref_paged_attention,
     ref_paged_attention_quant)
-from deepspeed_tpu.inference.paged.tree import _LANES, Part
+from deepspeed_tpu.inference.paged.tree import (_LANES, WINDOW_POOL, Part,
+                                                window_pool_layers)
 from deepspeed_tpu.ops import paged_decode, paged_prefill, routes
 from deepspeed_tpu.ops.paged_decode import paged_decode_attention
 from deepspeed_tpu.ops.paged_prefill import fused_tick_attention
@@ -95,6 +96,15 @@ def kv_heads_stored(kv_heads: int) -> int:
     return up if (up - kv_heads) * 8 <= kv_heads else kv_heads
 
 
+def layer_window(cfg, layer_kind: Optional[str] = None) -> Optional[int]:
+    """The keys a layer of ``layer_kind`` (its entry of the config's
+    ``layer_types``; None: the model's layers are of one kind) attends
+    behind a row: ``cfg.sliding_window``, but for a ``full_attention``
+    layer, which attends them all."""
+    window = getattr(cfg, "sliding_window", None)
+    return None if layer_kind == "full_attention" else window
+
+
 def _staged_attend_stats(mdl, q: jax.Array, ragged_meta, cfg) -> jax.Array:
     """Chunk-stats dispatch of the partial-residency scan: attend the
     query tokens against a STAGED dense KV block (parked pages uploaded
@@ -135,7 +145,7 @@ def _staged_attend_stats(mdl, q: jax.Array, ragged_meta, cfg) -> jax.Array:
     # anyway (with the window bound) so a partially-covered group near
     # a sliding window stays exact
     mask = kpos[None, :] <= qpos[:, None]                  # [T, R]
-    window = getattr(cfg, "sliding_window", None)
+    window = layer_window(cfg)
     if window is not None:
         mask = mask & (kpos[None, :] > qpos[:, None] - window)
     att = jnp.where(mask[None], att, jnp.float32(_MASK_VALUE))
@@ -149,13 +159,17 @@ def _staged_attend_stats(mdl, q: jax.Array, ragged_meta, cfg) -> jax.Array:
 
 
 def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
-                            ragged_meta: Dict[str, jax.Array], cfg
-                            ) -> jax.Array:
+                            ragged_meta: Dict[str, jax.Array], cfg,
+                            layer_kind: Optional[str] = None) -> jax.Array:
     """Inside an attention module: scatter this tick's K/V rows into the
     layer's page buffer, then ragged-paged attention for all T tokens.
 
     q: [1, H, T, D]; k, v: [1, Hkv, T, D] (rotary already applied).
     Returns [1, H, T, D].  Requires ``mutable=["cache"]`` on apply.
+    ``layer_kind``: the layer's entry of ``cfg.layer_types`` where the model
+    has window and full layers (:func:`layer_window`); a window layer of
+    such a model reads and writes the window layers' OWN pool through their
+    own table (``window_pages.py``: the ``window_`` keys of the metadata).
 
     Partial residency hooks (the chunked multi-dispatch scan): a
     ``staged_kv`` key in ``ragged_meta`` short-circuits to
@@ -189,10 +203,12 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         carry = (ragged_meta["carry_m"], ragged_meta["carry_l"],
                  ragged_meta["carry_acc"])
     Hkv = k.shape[1]
-    P, page = cfg.kv_num_pages, cfg.kv_page_size
+    leaf, P, page, own = "kv_pages", cfg.kv_num_pages, cfg.kv_page_size, ""
+    if layer_kind == "sliding_attention" and window_pool_layers(cfg):
+        leaf, P, own = WINDOW_POOL, cfg.kv_window_num_pages, "window_"
     assert P > 1, "paged_decode requires kv_num_pages (engine sets it)"
-    new_kv_dest = ragged_meta["new_kv_dest"]
-    page_indices = ragged_meta["page_indices"]
+    new_kv_dest = ragged_meta[own + "new_kv_dest"]
+    page_indices = ragged_meta[own + "page_indices"]
     pool_shape = (P, page, 2 * Hkv, D)
     if slots > 1:
         assert carry is None and (
@@ -210,11 +226,10 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                                  page_indices * slots + slot, page_indices)
         P = P * slots
 
-    # KV-cache quantization (reference csrc/fp_quantizer selective_dequant
-    # + inference v2 KV configs): pages persist in fp8 e4m3 or int8 with a
-    # per-(row, head) fp32 scale and are READ quantized — per-tile
-    # register dequant in ops/ragged_paged_quant.py (TPU) or the
-    # gathered-pages XLA reference below; never a full-width pool operand
+    # KV-cache quantization (reference csrc/fp_quantizer selective_dequant):
+    # pages persist in fp8 e4m3 or int8 with a per-(row, head) fp32 scale and
+    # are READ quantized (ops/ragged_paged_quant.py on TPU, the gathered-
+    # pages XLA reference below); never a full-width pool operand
     kv_quant = getattr(cfg, "kv_cache_dtype", "none") or "none"
     if kv_quant in ("fp8", "fp8_e4m3"):
         store_dtype, qmax = jnp.float8_e4m3fn, float(
@@ -225,8 +240,7 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         assert kv_quant == "none", f"unknown kv_cache_dtype {kv_quant!r}"
         store_dtype, qmax = k.dtype, None
 
-    pages_var = mdl.variable(
-        "cache", "kv_pages", jnp.zeros, pool_shape, store_dtype)
+    pages_var = mdl.variable("cache", leaf, jnp.zeros, pool_shape, store_dtype)
 
     # interleave K/V onto combined heads: [T, 2Hkv, D], K even, V odd
     k_rows = k[0].transpose(1, 0, 2)                   # [T, Hkv, D]
@@ -245,11 +259,9 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
             jnp.float32)
         cf = combined.astype(jnp.float32)
         absmax = jnp.max(jnp.abs(cf), axis=-1)         # [T, 2Hkv]
-        # floor the QUOTIENT at the smallest normal f32, not absmax at
-        # an arbitrary 1e-12: fp8's qmax=448 can push absmax/qmax
-        # subnormal, and a subnormal scale's reciprocal overflows
-        # qv = cf/scale to inf before the store-dtype cast.  For any
-        # absmax >= 1e-12 this is bit-identical to the old floor.
+        # floor the QUOTIENT at the smallest normal f32: fp8's qmax=448
+        # can push absmax/qmax subnormal, and a subnormal scale's
+        # reciprocal overflows qv = cf/scale to inf before the cast
         scale = jnp.maximum(absmax / qmax,
                             jnp.float32(np.finfo(np.float32).tiny))
         qv = cf / scale[..., None]
@@ -262,17 +274,15 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         scales_var.value = flat_s.reshape(P, page, 2 * Hkv)
         pages = flat.reshape(P, page, 2 * Hkv, D)
         pages_var.value = pages
-        # NO transient dequant: the quantized pool is read directly by
-        # the dequant-free attention variants below — per-tile register
-        # dequant in the Pallas kernel on TPU, gathered-pages dequant
-        # (O(attended rows), never O(pool)) in the XLA reference
+        # NO transient dequant: the attention variants below read the
+        # quantized pool directly (O(attended rows), never O(pool))
         kv_scales = scales_var.value
 
     qt = q[0].transpose(1, 0, 2)                       # [T, H, D]
     kv_lens = ragged_meta["kv_lens"]
     cu_q_lens = ragged_meta["cu_q_lens"]
     num_seqs = ragged_meta["num_seqs"]
-    window = getattr(cfg, "sliding_window", None)
+    window = layer_window(cfg, layer_kind)
 
     # a decode program says so itself (build_decode_block: one query a
     # slot, ``cu_q_lens`` an arange): its attention is the decode kernel's
@@ -305,13 +315,11 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
 
     def attend_ragged(qt, pages, kv_lens, page_indices, cu_q_lens,
                       num_seqs):
-        # the vLLM-TPU kernel is built for 128 lanes (its lane-width row
-        # stats assert on a smaller D): heads of 128 take it as they are,
-        # heads of 64 zero-padded to 128 (``kv_lane_dim``), every other
-        # width the XLA reference — correct but O(T * total_page_rows).
-        # An incoming chunk-scan carry always routes to the reference:
-        # the upstream kernel has no carry operand (the quantized pool's
-        # own kernel does).
+        # the vLLM-TPU kernel is built for 128 lanes: heads of 128 take it
+        # as they are, heads of 64 zero-padded to 128 (``kv_lane_dim``),
+        # every other width the XLA reference — correct but O(T *
+        # total_page_rows).  An incoming chunk-scan carry always routes to
+        # the reference: the upstream kernel has no carry operand
         if paged_attention_path(
                 head_dim, carry=carry is not None).startswith(
                     "pallas-ragged"):
@@ -319,12 +327,10 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                 import kernel as rpa
 
             # explicit blocks: for a shape outside its tuned table the
-            # upstream lookup answers (128 pages, 32 queries), and from
-            # max_seq_len ~2k up that KV block no longer fits the chip's
-            # scoped VMEM (the v5e compiler: "Ran out of memory in memory
-            # space vmem").  512 KV tokens x 32 queries at up to 8 KV
-            # heads (fewer tokens at more heads) compiles under the
-            # default limit at every page size the engine offers.
+            # upstream lookup answers (128 pages, 32 queries), which from
+            # max_seq_len ~2k up no longer fits the chip's scoped VMEM.
+            # 512 KV tokens x 32 queries at up to 8 KV heads (fewer tokens
+            # at more heads) compiles at every page size the engine offers
             return rpa.ragged_paged_attention(
                 qt, pages, kv_lens, jnp.maximum(page_indices, 0),
                 cu_q_lens, num_seqs, sm_scale=sm_scale,

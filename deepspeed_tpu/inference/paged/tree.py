@@ -6,8 +6,12 @@ pages) but for **per-slot state** that is no page: a ``[max_seqs, ...]`` leaf
 named :data:`SLOT_STATE`, indexed by slot (``conv_state.py``,
 ``linear_state.py``, ``delta_state.py``; a layer may keep several, each in a
 module of its own).  ``KVState`` tells the two apart by that name and knows
-nothing else of a leaf.  :class:`Part` is what the layers of one kind add to
-the description the engine reads (``paged.describe``)."""
+nothing else of a leaf.  A model whose window layers keep a pool OF THEIR OWN
+beside its full layers' (``window_pages.py``) names those leaves
+:data:`WINDOW_POOL`: they lead with THEIR page count and are indexed through
+a page table of their own, so no verb that moves ``leaf[page]`` by the first
+table's pages touches them.  :class:`Part` is what the layers of one kind add
+to the description the engine reads (``paged.describe``)."""
 from __future__ import annotations
 
 import dataclasses
@@ -21,28 +25,58 @@ _LANES = 128            # of the chip's tiles: a pool's minor dim is whole ones
 SLOT_STATE = "slot_state"
 
 
+# and of a window layer's K/V pages, in the pool the window layers share
+WINDOW_POOL = "window_kv_pages"
+
+
+def _named(path, name: str) -> bool:
+    return any(getattr(k, "key", None) == name for k in path)
+
+
 def is_slot_state(path) -> bool:
     """Is the cache leaf at ``path`` (a ``tree_flatten_with_path`` key
     path) per-slot state and not a page pool?"""
-    return any(getattr(k, "key", None) == SLOT_STATE for k in path)
+    return _named(path, SLOT_STATE)
+
+
+def window_pool_layers(cfg) -> int:
+    """The layers of a model that keep their K/V in the window layers' own
+    pool: the ``sliding_attention`` entries of a config whose
+    ``layer_types`` names ``full_attention`` layers beside them; 0 for
+    every other model (one window for the whole model stays on the one
+    pool: ROADMAP, Design debts)."""
+    kinds = list(getattr(cfg, "layer_types", None) or ())
+    kinds = kinds[:int(cfg.num_hidden_layers)]
+    return kinds.count("sliding_attention") if "full_attention" in kinds else 0
 
 
 def split_cache(cache):
-    """``(pools, states)``: the leaves of a cache tree that are page pools
-    (leading dim: pages) and those that are per-slot state (leading dim:
-    slots), each in the tree's own leaf order."""
+    """``(pools, states)``: the leaves of a cache tree that are pages of the
+    first pool (leading dim: its pages) and those that are per-slot state
+    (leading dim: slots), each in the tree's own leaf order.  The window
+    layers' pool is neither (:func:`window_pool`)."""
     flat = jax.tree_util.tree_flatten_with_path(cache)[0]
-    return ([l for p, l in flat if not is_slot_state(p)],
+    return ([l for p, l in flat
+             if not is_slot_state(p) and not _named(p, WINDOW_POOL)],
             [l for p, l in flat if is_slot_state(p)])
 
 
+def window_pool(cache):
+    """The leaves of the window layers' own pool (none: the model has
+    none), in the tree's leaf order."""
+    flat = jax.tree_util.tree_flatten_with_path(cache)[0]
+    return [l for p, l in flat if _named(p, WINDOW_POOL)]
+
+
 def map_cache(cache, pool_fn=None, state_fn=None):
-    """``cache`` with ``pool_fn`` applied to every pool leaf and
-    ``state_fn`` to every per-slot state leaf (None: left as it is)."""
+    """``cache`` with ``pool_fn`` applied to every leaf of the first pool
+    and ``state_fn`` to every per-slot state leaf (None: left as it is);
+    the window layers' pool is left as it is."""
     keep = lambda l: l
     pool_fn, state_fn = pool_fn or keep, state_fn or keep
     return jax.tree_util.tree_map_with_path(
-        lambda p, l: state_fn(l) if is_slot_state(p) else pool_fn(l), cache)
+        lambda p, l: (state_fn(l) if is_slot_state(p) else
+                      l if _named(p, WINDOW_POOL) else pool_fn(l)), cache)
 
 
 def _seq_of_rows(cu: jax.Array, T: int) -> jax.Array:

@@ -37,6 +37,16 @@ record: it is kilobytes, not pages), dead at ``release`` with the slot.  The
 page verbs move pool leaves only.  What cannot carry it (a shared prefix
 needs the state AT the prefix's end) is refused by the engine by name.
 
+A model whose **window layers keep a pool of their own** beside its full
+layers' (``paged/window_pages.py``: leaves named ``paged/tree.py:WINDOW_POOL``)
+has that pool's allocator and page table owned here too, as ``window``:
+``can_admit``, ``attach`` and ``ensure`` hold where BOTH pools have the
+pages, ``ensure`` first gives back the window pages the tick leaves behind,
+``release`` empties both rows and ``audit`` covers both allocators.  The
+verbs that move ``leaf[page]`` (spill, restore, copy-on-write, park) move the
+first pool's leaves only, and every feature that would call them for such a
+model is refused by name.
+
 A request is duck-typed (``uid``, ``slot``, ``ctx``, ``prompt``,
 ``generated``, ``prefill_done``, ``spilled``, ``pc_*``, ``lc``,
 ``lc_parked``); nothing here imports the engine.
@@ -73,7 +83,7 @@ class KVState:
     def __init__(self, cache, *, num_pages: int, page_size: int,
                  max_seqs: int, max_seq_len: int, kv_reserve: str,
                  tier_cfg, pfx_cfg, host_stats, request_latency,
-                 draft_cache: Any = None):
+                 draft_cache: Any = None, window: Any = None):
         assert kv_reserve in ("on_demand", "worst_case"), kv_reserve
         self.cache = cache
         self.draft_cache = {} if draft_cache is None else draft_cache
@@ -87,6 +97,9 @@ class KVState:
         self.allocator = PageAllocator(self.num_pages, self.page_size)
         self.page_table = np.full((max_seqs, self.pages_per_seq), -1,
                                   np.int32)
+        # the window layers' own allocator and table (``paged.WindowPool``);
+        # None: every layer's pages lie behind the one table above
+        self.window = window
         self.spills = 0                # sessions spilled to the tiers
         self.restores = 0              # sessions restored bit-identically
         self._tier_gather = None       # jitted fixed-shape page gather
@@ -173,6 +186,8 @@ class KVState:
         other sequence."""
         total_pages = self.allocator.pages_for(total)
         usable = self.num_pages - 1
+        if self.window is not None:
+            self.window.check_capacity(total)
         if self.tiering is None:
             if total_pages > usable:
                 raise ValueError(
@@ -280,7 +295,8 @@ class KVState:
         avail = self.allocator.free_pages
         if self.pfx is not None:
             avail += self.pfx.reclaimable(exclude={e.key for e in entries})
-        return fresh <= avail
+        return fresh <= avail and (self.window is None
+                                   or self.window.can_admit(need))
 
     def attach(self, req, need: int) -> bool:
         """Build slot ``req.slot``'s page run for an admission covering
@@ -336,6 +352,8 @@ class KVState:
         self._reclaim_for(want_free)
         if want_free > self.allocator.free_pages:
             return False
+        if self.window is not None and not self.window.attach(i, need):
+            return False
         if full:
             # the write frontier (position ctx_len - 1) lands in the
             # last matched page: make it private before the re-prefill
@@ -349,6 +367,7 @@ class KVState:
         if self.state_bytes_per_seq:
             # the slot's previous tenant must not leak into this one
             self._put_state(i, None)
+        self._gauge()
         self.request_latency.on_admit(req.uid)
         if trace.enabled:
             trace.event("request_admit", cat="request", uid=req.uid,
@@ -360,11 +379,18 @@ class KVState:
 
     # -- growth, publication, release --------------------------------------
 
-    def ensure(self, slot: int, upto_tokens: int) -> bool:
+    def ensure(self, slot: int, upto_tokens: int,
+               first_row: Optional[int] = None) -> bool:
         """Grow ``slot``'s page run to cover ``upto_tokens`` cache
         positions; False when the pool can't (scheduler backpressure —
-        the sequence sits this tick out, or gets evicted)."""
+        the sequence sits this tick out, or gets evicted).  ``first_row``:
+        the first position the tick that asks will write; a window pool
+        first takes back what lies a window behind it, and both pools must
+        cover ``upto_tokens``."""
         upto_tokens = min(upto_tokens, self.max_seq_len)
+        if self.window is not None and not self.window.ensure(
+                slot, upto_tokens, first_row):
+            return False
         need = pages_for(upto_tokens, self.page_size)
         have = self.allocator.owned(slot)
         if need <= have:
@@ -374,15 +400,25 @@ class KVState:
             return False
         pages = self.allocator.grow(slot, need - have)
         self.page_table[slot, have:have + len(pages)] = pages
+        self._gauge()
         return True
 
     def coverable_tokens(self, slot: int) -> int:
         """Tokens ``slot`` could cover if it took every page the pool
         can still give (cold prefix-index pages count: :meth:`ensure`
-        reclaims them on demand)."""
-        return (self.allocator.owned(slot) + self.allocator.free_pages +
-                (self.pfx.reclaimable() if self.pfx is not None else 0)
-                ) * self.page_size
+        reclaims them on demand); the lesser of the two pools' answers."""
+        tokens = (self.allocator.owned(slot) + self.allocator.free_pages +
+                  (self.pfx.reclaimable() if self.pfx is not None else 0)
+                  ) * self.page_size
+        return tokens if self.window is None else min(
+            tokens, self.window.coverable_tokens(slot))
+
+    def _gauge(self) -> None:
+        """The first pool's pages in use, for the dispatch spans of a model
+        that has a window pool beside it."""
+        if self.window is not None:
+            self.host_stats.full_pages_held = (
+                self.num_pages - 1 - self.allocator.free_pages)
 
     def _reclaim_for(self, n_pages: int) -> None:
         """Ask the prefix index to give back cold single-reference pages
@@ -420,6 +456,9 @@ class KVState:
         nothing reads it before the next ``attach`` zeroes it."""
         self.allocator.free(slot)
         self.page_table[slot, :] = -1
+        if self.window is not None:
+            self.window.release(slot)
+            self._gauge()
 
     @staticmethod
     def pick_victim(stalled, last_sched):
@@ -852,4 +891,7 @@ class KVState:
             assert row == owned, (
                 f"slot {s}: page-table row {row} != allocator "
                 f"ownership {owned}")
-        return self.allocator.audit(external=external)
+        counts = self.allocator.audit(external=external)
+        if self.window is not None:
+            counts["window"] = self.window.audit(slots)
+        return counts

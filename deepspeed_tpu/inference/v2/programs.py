@@ -39,7 +39,8 @@ from deepspeed_tpu.inference.common import (logits_of, normalize_params,
                                             unroll_scan_params)
 from deepspeed_tpu.inference.paged import (describe, kv_layers,
                                            kv_slots_per_layer, pages_for,
-                                           split_cache, state_layers)
+                                           split_cache, state_layers,
+                                           window_pool, window_pool_layers)
 from deepspeed_tpu.inference.quantization import (dequantize_param_tree,
                                                   quantize_param_tree)
 from deepspeed_tpu.inference.sampling import (filter_logits_batched,
@@ -104,42 +105,62 @@ def loop_counts(variables) -> Optional[jax.Array]:
 
 def serving_config(mcfg, *, max_seq_len: int, page_size: int,
                    num_pages: int, tensor_parallel: bool,
-                   kv_cache_dtype: str):
+                   kv_cache_dtype: str, window_num_pages: int = 0):
     """A zoo model's config in its serving form: paged ragged decode over
     a pool of ``num_pages`` pages, layers unrolled (a scan over layers
-    restacks the mutable cache every step)."""
+    restacks the mutable cache every step).  ``window_num_pages``: the
+    pages of the window layers' own pool, for a model that has one
+    (``paged.window_pool_layers``) and for no other."""
     assert kv_cache_dtype in KV_CACHE_DTYPES, (
         f"kv_cache_dtype must be {'|'.join(KV_CACHE_DTYPES)}, got "
         f"{kv_cache_dtype!r}")
+    own = ({"kv_window_num_pages": int(window_num_pages)}
+           if window_pool_layers(mcfg) else {})
     return dataclasses.replace(
         mcfg, decode=True, ragged_decode=False, paged_decode=True,
         max_cache_len=max_seq_len, scan_layers=False,
         kv_page_size=page_size, kv_num_pages=num_pages,
-        tensor_parallel=tensor_parallel, kv_cache_dtype=kv_cache_dtype)
+        tensor_parallel=tensor_parallel, kv_cache_dtype=kv_cache_dtype,
+        **own)
 
 
-def paged_kv_page_bytes(model, mcfg, page_size: int,
-                        kv_cache_dtype: str) -> int:
+def _with_window_keys(meta, mcfg):
+    """``meta`` with the window layers' table and destinations (copies of
+    the first pool's: shapes are all a probe reads) where ``mcfg`` has a
+    window pool."""
+    if window_pool_layers(mcfg):
+        meta.update(window_page_indices=meta["page_indices"],
+                    window_new_kv_dest=meta["new_kv_dest"])
+    return meta
+
+
+def paged_kv_page_bytes(model, mcfg, page_size: int, kv_cache_dtype: str,
+                        window: bool = False) -> int:
     """Exact device bytes ONE KV page costs across every cache leaf —
     for a quantized pool that is the 1-byte payload page plus its fp32
     scale rows.  Measured by an eval_shape probe of a 2-page pool
     rather than guessed from the layer count, so any model-zoo cache
-    layout (extra leaves, fused layers) is accounted automatically."""
+    layout (extra leaves, fused layers) is accounted automatically.
+    ``window``: a page of the window layers' own pool instead."""
     probe = type(model)(serving_config(
         mcfg, max_seq_len=2 * page_size, page_size=page_size, num_pages=2,
-        tensor_parallel=False, kv_cache_dtype=kv_cache_dtype))
-    meta = {"kv_lens": jnp.zeros((1,), jnp.int32),
-            "page_indices": jnp.full((1, 2), -1, jnp.int32),
-            "cu_q_lens": jnp.zeros((2,), jnp.int32),
-            "num_seqs": jnp.zeros((1,), jnp.int32),
-            "new_kv_dest": jnp.zeros((4,), jnp.int32)}
+        tensor_parallel=False, kv_cache_dtype=kv_cache_dtype,
+        window_num_pages=2))
+    meta = _with_window_keys({
+        "kv_lens": jnp.zeros((1,), jnp.int32),
+        "page_indices": jnp.full((1, 2), -1, jnp.int32),
+        "cu_q_lens": jnp.zeros((2,), jnp.int32),
+        "num_seqs": jnp.zeros((1,), jnp.int32),
+        "new_kv_dest": jnp.zeros((4,), jnp.int32)}, mcfg)
     ids = jnp.zeros((1, 4), jnp.int32)
     pos = jnp.zeros((1, 4), jnp.int32)
     shapes = jax.eval_shape(lambda: probe.init(
         jax.random.PRNGKey(0), ids, positions=pos, ragged_meta=meta))
     # per-slot state beside the pool is no page's cost
+    leaves = (window_pool(shapes["cache"]) if window
+              else split_cache(shapes["cache"])[0])
     total = sum(int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-                for leaf in split_cache(shapes["cache"])[0])
+                for leaf in leaves)
     assert total % 2 == 0, total
     return total // 2
 
@@ -316,12 +337,12 @@ def init_cache(model, *, max_seqs: int, width: int, **placement):
     pages_per_seq = pages_for(model.config.max_cache_len,
                               model.config.kv_page_size)
     # host arrays put on the device: no program compiles to make them
-    dummy_meta = jax.tree_util.tree_map(jnp.asarray, {
+    dummy_meta = _with_window_keys(jax.tree_util.tree_map(jnp.asarray, {
         "kv_lens": np.zeros((max_seqs,), np.int32),
         "page_indices": np.full((max_seqs, pages_per_seq), -1, np.int32),
         "cu_q_lens": np.zeros((max_seqs + 1,), np.int32),
         "num_seqs": np.zeros((1,), np.int32),
-        "new_kv_dest": np.zeros((width,), np.int32)})
+        "new_kv_dest": np.zeros((width,), np.int32)}), model.config)
     ids = jnp.zeros((1, width), jnp.int32)
     pos = jnp.zeros((1, width), jnp.int32)
 
@@ -345,15 +366,25 @@ def _fused_forward(model, *, unroll: bool, wq: Optional[str], native: bool):
     (``inference/common.py:sampled_rows``): no ``[T, V]`` array exists.  A
     model whose head ignores them is refused when the tick is traced.
     Returns ``(sel [max_seqs, V], variables)``."""
+    two_tables = window_pool_layers(model.config) > 0
+
     def forward(params, cache, token_ids, positions, kv_lens, page_indices,
                 cu_q_lens, num_seqs, new_kv_dest, sample_rows, *seq_slot):
         if wq:
             params = dequantize_param_tree(params, native_w8a8=native)
         if unroll:
             params = unroll_scan_params(params)
+        own = {}
+        if two_tables:
+            # a model whose window layers keep a pool of their own: both
+            # tables ride in ``page_indices [2, S, pp]`` and both rows'
+            # destinations in ``new_kv_dest [2, T]``, the first pool's first
+            own = {"window_page_indices": page_indices[1],
+                   "window_new_kv_dest": new_kv_dest[1]}
+            page_indices, new_kv_dest = page_indices[0], new_kv_dest[0]
         meta = {"kv_lens": kv_lens, "page_indices": page_indices,
                 "cu_q_lens": cu_q_lens, "num_seqs": num_seqs,
-                "new_kv_dest": new_kv_dest,
+                "new_kv_dest": new_kv_dest, **own,
                 # static: decode rows and prompt chunks packed by
                 # ``cu_q_lens``, which is what inference/paged/kv_pages.py picks
                 # the chunk kernel (and the decode kernel for the
@@ -481,6 +512,7 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
     1/``block``.  Two variants compile: pure-greedy (no sort) and
     per-seq ``sampled``."""
     S, page, max_len = max_seqs, page_size, max_seq_len
+    two_tables = window_pool_layers(model.config) > 0
 
     def run(params, cache, last_tok, pos, active, remaining,
             page_table, eos_ids, do_sample, temperature, top_k, top_p,
@@ -490,15 +522,26 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
         if unroll:
             params = unroll_scan_params(params)
 
+        def dest_of(table, pos, active):
+            dest_page = jnp.take_along_axis(
+                jnp.maximum(table, 0), (pos // page)[:, None], axis=1)[:, 0]
+            return jnp.where(active, dest_page * page + pos % page, 0)
+
+        own_table = None
+        if two_tables:
+            # ``page_table [2, S, pp]``: the first pool's, then the window
+            # layers' own (``_fused_forward``)
+            page_table, own_table = page_table[0], page_table[1]
+
         def tick(carry, _):
             cache, last_tok, pos, active, remaining, rng = carry
-            dest_page = jnp.take_along_axis(
-                jnp.maximum(page_table, 0),
-                (pos // page)[:, None], axis=1)[:, 0]
-            dest = jnp.where(active, dest_page * page + pos % page, 0)
+            dest = dest_of(page_table, pos, active)
             kv_lens = jnp.where(active, pos + 1, 1)
+            own = ({} if own_table is None else
+                   {"window_page_indices": own_table,
+                    "window_new_kv_dest": dest_of(own_table, pos, active)})
             meta = {"kv_lens": kv_lens,
-                    "page_indices": page_table,
+                    "page_indices": page_table, **own,
                     "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
                     "num_seqs": jnp.asarray([S], jnp.int32),
                     "new_kv_dest": dest, "live": active,

@@ -253,6 +253,7 @@ class RaggedInferenceEngineV2:
                  decode_block_size: int = 8,
                  kv_cache_dtype: Optional[str] = None,
                  kv_pool_bytes: Optional[int] = None,
+                 window_pool_bytes: Optional[int] = None,
                  quantize_weights: Optional[str] = None,
                  kv_reserve: str = "on_demand",
                  pipeline: Optional[bool] = None,
@@ -280,6 +281,10 @@ class RaggedInferenceEngineV2:
         ``kv_pool_bytes``: size the pool by a device byte budget —
         ``num_pages`` becomes the exact number of pages (payload + scale
         rows) that fit; ignored when ``num_pages`` is given.
+        ``window_pool_bytes``: the device bytes of the pool that a model's
+        window layers keep for themselves beside its full layers' (the
+        description's ``window``): required for such a model, refused for
+        any other — two sizes because they are two pools.
         ``quantize_weights``: see
         :func:`~deepspeed_tpu.inference.v2.programs.prepare_params`.
         ``kv_reserve``: "on_demand" (reference blocked-allocator model —
@@ -373,6 +378,19 @@ class RaggedInferenceEngineV2:
             # scales with tokens in flight (admission backpressure).
             num_pages = 1 + max_seqs * self.pages_per_seq
         self.num_pages = int(num_pages)
+        # a second pool, where the description has layers that keep one
+        own = self.cache_kind.window
+        if (own is None) != (window_pool_bytes is None):
+            raise ValueError(
+                f"window_pool_bytes={window_pool_bytes!r}: "
+                f"{type(model).__name__} " + (
+                    "keeps every layer's pages behind one table (size it "
+                    "by num_pages or kv_pool_bytes)" if own is None else
+                    f"keeps {own.layers} of its {own.of} layers' pages in a "
+                    "pool of their own, which has no default size"))
+        self.window_num_pages = 0 if own is None else max(
+            2, int(window_pool_bytes) // programs.paged_kv_page_bytes(
+                model, mcfg, self.page_size, kv_cache_dtype, window=True))
 
         # the model and its weights in serving form; ``param_form`` is
         # what every program builder needs to know of the stored params
@@ -380,7 +398,8 @@ class RaggedInferenceEngineV2:
             model, params, programs.serving_config(
                 mcfg, max_seq_len=max_seq_len, page_size=self.page_size,
                 num_pages=self.num_pages, tensor_parallel=self.tp > 1,
-                kv_cache_dtype=kv_cache_dtype),
+                kv_cache_dtype=kv_cache_dtype,
+                window_num_pages=self.window_num_pages),
             quantize_weights, tp=self.tp)
         self.model = type(model)(self.cfg)
         self.params = programs.place_params(params, **placement)
@@ -483,7 +502,10 @@ class RaggedInferenceEngineV2:
             kv_reserve=kv_reserve, tier_cfg=tier_cfg,
             pfx_cfg=PrefixCacheConfig.coerce(o["prefix_cache"]),
             host_stats=self.host_stats,
-            request_latency=self.request_latency, draft_cache=draft_cache)
+            request_latency=self.request_latency, draft_cache=draft_cache,
+            window=None if own is None else own.host_pool(
+                self.window_num_pages, self.page_size, max_seqs,
+                self.pages_per_seq, self.T, self.host_stats))
         # the most pages any dispatch found in use, and what the cache kind
         # says of its leaves as they are allocated: on every
         # ``engine.dispatch`` span and in ``serving_stages()``
@@ -1454,7 +1476,7 @@ class RaggedInferenceEngineV2:
             sampled = bool(do_sample.any())
         sub = self._split_rng()
         args = self.upload_all(
-            (last_tok, pos, active, remaining, self.page_table,
+            (last_tok, pos, active, remaining, self._tables(),
              eos_ids, do_sample, temperature, top_k, top_p, seeds))
         if trace.enabled:
             trace.event("decode_block", cat="request",
@@ -1668,7 +1690,22 @@ class RaggedInferenceEngineV2:
         admission) under blocks still in flight, and a ``device_put`` may
         alias host memory (the CPU backend does when it is aligned) or
         read it after the call returns."""
-        return self.page_table.copy()
+        tables = self._tables()         # two tables stacked are a copy
+        return tables.copy() if tables is self.page_table else tables
+
+    def _owned(self, slot: int) -> Tuple[int, ...]:
+        """What any change of ``slot``'s page-table rows shows in."""
+        own = self.kv.window
+        return (self.allocator.owned(slot),) + (
+            () if own is None else own.span(slot))
+
+    def _tables(self) -> np.ndarray:
+        """The page table the programs take: ``[max_seqs, pages_per_seq]``,
+        or the first pool's and the second's stacked ``[2, ...]`` for a
+        model whose window layers keep a pool of their own."""
+        own = self.kv.window
+        return self.page_table if own is None else np.stack(
+            [self.page_table, own.page_table])
 
     def _release_carry(self) -> None:
         """Let the decode blocks' device carry go: the next blocks build
@@ -1773,11 +1810,11 @@ class RaggedInferenceEngineV2:
                 else:
                     want = min(r.length + min(K, self._budget(r)),
                                self.max_seq_len)
-                before = self.allocator.owned(s)
-                if not self.kv.ensure(s, want):
+                before = self._owned(s)
+                if not self.kv.ensure(s, want, r.length - 1):
                     grow_ok = False
                     break
-                table_dirty |= self.allocator.owned(s) != before
+                table_dirty |= self._owned(s) != before
         if not grow_ok:
             # out of pages: fold everything and hand control back to the
             # fused path (stall/evict semantics)
@@ -1950,7 +1987,7 @@ class RaggedInferenceEngineV2:
                 all(self.kv.ensure(
                     r.slot,
                     r.length + min(self.decode_block_size,
-                                   self._budget(r)))
+                                   self._budget(r)), r.length - 1)
                     for r in live))
         if spec_block:
             if self.pipeline:
@@ -2204,8 +2241,9 @@ class RaggedInferenceEngineV2:
         return self.kv.audit(
             self.slots, itertools.chain(self.waiting, self._handoff_ready))
 
-    def _flat_dest(self, slot: int, pos: int) -> int:
-        page = self.page_table[slot, pos // self.page_size]
+    def _flat_dest(self, slot: int, pos: int, table=None) -> int:
+        table = self.page_table if table is None else table
+        page = table[slot, pos // self.page_size]
         assert page > 0, "write into unallocated page"
         return int(page) * self.page_size + pos % self.page_size
 
@@ -2227,7 +2265,7 @@ class RaggedInferenceEngineV2:
                     continue
                 # the tick writes the last generated token at position
                 # length-1, so pages must cover `length` tokens
-                if self.kv.ensure(r.slot, r.length):
+                if self.kv.ensure(r.slot, r.length, r.length - 1):
                     decode_rs.append(r)
                 else:
                     self._stalled.append(r)    # out of pages: sits it out
@@ -2242,7 +2280,8 @@ class RaggedInferenceEngineV2:
                 take = min(budget, r.ctx_len - r.prefill_done)
                 if take <= 0:
                     continue        # batch-budget-limited, not stalled
-                if not self.kv.ensure(r.slot, r.prefill_done + take):
+                if not self.kv.ensure(r.slot, r.prefill_done + take,
+                                      r.prefill_done):
                     # partial growth: cover what the pool allows this tick
                     # (cold prefix-index pages count — KVState.ensure
                     # reclaims them on demand)
@@ -2252,7 +2291,8 @@ class RaggedInferenceEngineV2:
                     if take <= 0:
                         self._stalled.append(r)     # page-limited
                         continue
-                    self.kv.ensure(r.slot, r.prefill_done + take)
+                    self.kv.ensure(r.slot, r.prefill_done + take,
+                                   r.prefill_done)
                 takes[r.uid] = take
                 budget -= take
             self._page_limited |= bool(self._stalled)
@@ -2273,11 +2313,16 @@ class RaggedInferenceEngineV2:
             token_ids = np.zeros((self.T,), np.int32)
             tok_src = np.full((self.T,), -1, np.int32)
             positions = np.zeros((self.T,), np.int32)
-            new_kv_dest = np.full((self.T,), 0, np.int32)   # trash page row 0
+            # one table, or (a model whose window layers keep a pool of
+            # their own) the first pool's and the second's, stacked
+            tables = [self.page_table] + (
+                [] if self.kv.window is None else [self.kv.window.page_table])
+            n = len(tables)
+            new_kv_dest = np.full((n, self.T), 0, np.int32)  # trash page row 0
             kv_lens = np.zeros((S,), np.int32)
             # metadata rows are indexed by PACKED sequence number j, not slot:
             # pack each active slot's page-table row as it is assigned a j
-            page_indices = np.full((S, self.pages_per_seq), -1, np.int32)
+            page_indices = np.full((n, S, self.pages_per_seq), -1, np.int32)
             cu_q_lens = np.zeros((S + 1,), np.int32)
             sample_rows = np.zeros((S,), np.int32)
             sample_slot = np.full((S,), -1, np.int32)
@@ -2307,8 +2352,9 @@ class RaggedInferenceEngineV2:
                     else:
                         token_ids[t] = self.last_tokens[r.slot]
                     positions[t] = p
-                    new_kv_dest[t] = self._flat_dest(r.slot, p)
-                    page_indices[j] = self.page_table[r.slot]
+                    for i, table in enumerate(tables):
+                        new_kv_dest[i, t] = self._flat_dest(r.slot, p, table)
+                        page_indices[i, j] = table[r.slot]
                     kv_lens[j] = p + 1
                     counts["decode_key_tokens"] += p + 1
                     cu_q_lens[j + 1] = cu_q_lens[j] + 1
@@ -2325,10 +2371,12 @@ class RaggedInferenceEngineV2:
                     token_ids[t:t + take] = r.ctx[lo:lo + take]
                     pos = np.arange(lo, lo + take)
                     positions[t:t + take] = pos
-                    pg = self.page_table[r.slot, pos // self.page_size]
-                    assert (pg > 0).all(), "write into unallocated page"
-                    new_kv_dest[t:t + take] = (pg * self.page_size +
-                                               pos % self.page_size)
+                    for i, table in enumerate(tables):
+                        pg = table[r.slot, pos // self.page_size]
+                        assert (pg > 0).all(), "write into unallocated page"
+                        new_kv_dest[i, t:t + take] = (pg * self.page_size +
+                                                      pos % self.page_size)
+                        page_indices[i, j] = table[r.slot]
                     r.prefill_done += take
                     counts["prefill_tokens"] += int(take)
                     counts["prefill_key_tokens"] += int(
@@ -2341,7 +2389,6 @@ class RaggedInferenceEngineV2:
                                     uid=r.uid, take=int(take),
                                     prefill_done=int(r.prefill_done),
                                     ctx_len=int(r.ctx_len))
-                    page_indices[j] = self.page_table[r.slot]
                     kv_lens[j] = r.prefill_done
                     cu_q_lens[j + 1] = cu_q_lens[j] + take
                     sample_rows[j] = t + take - 1
@@ -2353,6 +2400,8 @@ class RaggedInferenceEngineV2:
                     t += take
                     j += 1
             cu_q_lens[j + 1:] = cu_q_lens[j]
+            if n == 1:
+                page_indices, new_kv_dest = page_indices[0], new_kv_dest[0]
             arrays = (token_ids[None], tok_src[None], positions[None], kv_lens,
                       page_indices, cu_q_lens, np.asarray([j], np.int32),
                       new_kv_dest, sample_rows, sample_slot)

@@ -55,6 +55,10 @@ MAX_HOPS = 3
 _SCOPE_KIND = {"zero.fetch": "zero", "optim": "optim", "sample": "sample",
                "counts": "rest", "head.rows": "head"}
 _MOE_SCOPE = "moe."
+# scopes that split ONE module's time by the kind of its layer (a window
+# layer's attention and a full layer's, ``models/trinity.py``): where one is
+# in a path it leads the sub-scope, a Pallas kernel's name behind it
+_SPLIT_SCOPES = ("attn_window", "attn_full")
 # the module names of the model files -> kinds
 _MODULE_KIND = {
     "self_attn": "attn", "attention": "attn", "attn": "attn",
@@ -77,7 +81,8 @@ _NORM_ROLE = {"input_layernorm": "mixer", "operator_norm": "mixer",
               "post_attention_norm": "mixer", "post_feedforward_norm": "ffn",
               "input_layernorm_2": "mixer",
               "post_attention_layernorm": "ffn", "ffn_norm": "ffn",
-              "post_attention_layernorm_2": "ffn"}
+              "post_attention_layernorm_2": "ffn",
+              "pre_mlp_layernorm": "ffn", "post_mlp_layernorm": "ffn"}
 _ROLE_DEFAULT = {"mixer": "attn", "ffn": "mlp"}
 _ROLE_KINDS = {"mixer": ("attn", "conv", "linear"), "ffn": ("mlp", "moe")}
 _LAYER = re.compile(r"^(layers(_\d+)?|block|h(_\d+)?)$")
@@ -143,8 +148,11 @@ def _walk(op_name: str):
                     else after(i) or p, layer, None)
     if named is not None:
         i, p = named[0], parts[named[0]]
-        return (named[1], None, after(i) or (p if "." in p else None), layer,
-                module)
+        sub = after(i) or (p if "." in p else None)
+        split = next((q for q in parts if q in _SPLIT_SCOPES), None)
+        if split is not None and sub != split:
+            sub = f"{split}/{sub}"
+        return named[1], None, sub, layer, module
     in_layer = False
     for p in parts:
         base = p.split(".", 1)[0]

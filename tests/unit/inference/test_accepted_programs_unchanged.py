@@ -41,7 +41,14 @@ the ``C / 2`` rows the level couples where it multiplied the whole tile;
 this file lowers on the CPU, where the tick takes the twins, which unroll
 the same levels); both decode blocks, whose rows take the one-token step,
 and the twelve programs of the six other models are the parent's to the
-letter.
+letter.  PR 59 added the two programs of ``trinity-large-serve``, taken on its
+own tree (window layers and full layers in one model, each kind with a pool
+and a page table of its own: ``page_indices [2, S, pp]`` and ``new_kv_dest
+[2, T]`` for this model, and the same ARGUMENTS as every other model's
+programs), and moved none of the sixteen: no other model's program gains an
+argument, ``paged_update_and_attend`` reads the one table and the one window
+where it is told no layer kind, and the decode block's destination rows are
+the same operations under a local name.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -126,13 +133,20 @@ EXPECTED = {
     # level products of ``P`` and ``R`` on the rows that have the level's bit
     "solar.fused": "3b7548ccb6e0b14e",
     "solar.decode_block": "dd04a8cc32f4b803",
+    # taken by PR 59, which added the model (one dense layer and one period
+    # [window, full, window, window] after it: four window layers in a pool
+    # of their own behind the second table, the rotary embedding on them
+    # alone, a norm of q and k a head, the gate, four norms a layer, 3 of 12
+    # experts held); the sixteen older hashes are as they were
+    "trinity.fused": "d10c4ad62da86dbe",
+    "trinity.decode_block": "f5ca964f43bde1af",
 }
 
 
 def _models():
     from deepspeed_tpu.models import (keye_vl2, lfm2_moe, minicpm_sala,
                                       mistral, olmo_hybrid, olmoe, ouro,
-                                      solar_open2)
+                                      solar_open2, trinity)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -154,6 +168,8 @@ def _models():
             "tinylfm2", expert_parallel=False, **bf16)),
         "solar": solar_open2.SolarOpen2ForCausalLM(solar_open2.get_config(
             "tinysolar", **bf16)),
+        "trinity": trinity.TrinityForCausalLM(trinity.get_config(
+            "tinytrinity", **bf16)),
     }
 
 
@@ -164,6 +180,7 @@ def _strip(text: str) -> str:
 
 
 def lowered_hashes():
+    from deepspeed_tpu.inference import paged
     from deepspeed_tpu.inference.v2.ragged_engine import \
         RaggedInferenceEngineV2
 
@@ -171,20 +188,24 @@ def lowered_hashes():
     for name, model in _models().items():
         params = jax.jit(model.init)(
             jax.random.PRNGKey(0), np.zeros((1, 8), np.int32))["params"]
-        eng = RaggedInferenceEngineV2(model, params=params, **ENGINE)
+        # a model whose window layers keep a pool of their own sizes it
+        own = ({"window_pool_bytes": 12 * 16384}
+               if paged.window_pool_layers(model.config) else {})
+        eng = RaggedInferenceEngineV2(model, params=params, **ENGINE, **own)
         S, T, pp = eng.max_seqs, eng.T, eng.pages_per_seq
+        two = (2,) if own else ()       # ... and brings both tables
         i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
         f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
         boo = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)
         fused = eng.fused_sample_step(False).lower(
             eng.params, eng.kv.cache, i32(S), i32(1, T), i32(1, T),
-            i32(1, T), i32(S), i32(S, pp), i32(S + 1), i32(1), i32(T),
-            i32(S), i32(S),
+            i32(1, T), i32(S), i32(*two, S, pp), i32(S + 1), i32(1),
+            i32(*two, T), i32(S), i32(S),
             # a model with per-slot state: each packed sequence's slot
             *([i32(S)] if eng.state_layers else []))
         block = eng.decode_block(False).lower(
             eng.params, eng.kv.cache, i32(S), i32(S), boo(S), i32(S),
-            i32(S, pp), i32(S), boo(S), f32(S), i32(S), f32(S), i32(S),
+            i32(*two, S, pp), i32(S), boo(S), f32(S), i32(S), f32(S), i32(S),
             jax.random.PRNGKey(0))
         for prog, low in (("fused", fused), ("decode_block", block)):
             out[f"{name}.{prog}"] = hashlib.sha256(

@@ -21,7 +21,8 @@ SIGNATURE = dict(
     model=inspect.Parameter.empty, params=None, max_seqs=8, max_seq_len=512,
     prefill_chunk=128, rng=None, page_size=64, num_pages=None,
     topology=None, decode_block_size=8, kv_cache_dtype=None,
-    kv_pool_bytes=None, quantize_weights=None, kv_reserve="on_demand",
+    kv_pool_bytes=None, window_pool_bytes=None, quantize_weights=None,
+    kv_reserve="on_demand",
     pipeline=None, async_depth=None, harvest_interval=None,
     speculation=None, draft_model=None, draft_params=None,
     draft_kv_cache_dtype=None, kv_tiering=None, prefix_cache=None, slo=None,

@@ -100,6 +100,53 @@ def test_the_shares_add_up_to_the_uncut_layer(whole):
     assert np.abs((sum(p + once for p in parts)) - (want + once)).max() > 1
 
 
+def test_the_eight_shares_of_a_trinity_layer_add_up_to_the_uncut_layer():
+    """``trinity-large-serve``'s share, small: eight chips hold four experts
+    each of a router thirty-two wide, top 4 by sigmoid + bias, the chosen
+    scores renormalised (``route_norm``) and scaled by 2.448
+    (``route_scale``).  The eight routed parts, with the shared expert, which
+    every chip computes alike, counted ONCE, are the uncut layer of the
+    family's own plain reference (``benchmark/reference/trinity.py``)."""
+    from benchmark.reference import trinity as tref
+
+    shards, held, k, scale = 8, 4, 4, 2.448
+    layer = lambda n, held, shard=0: MoE(       # noqa: E731
+        hidden_size=M, num_experts=held, intermediate_size=I, k=k,
+        dtype=jnp.float32, param_dtype=jnp.float32, expert_parallel=False,
+        dispatch_impl="grouped", scoring="sigmoid", expert_bias=True,
+        expert_bias_init_std=0.1, normalize_weights=True,
+        routed_scaling_factor=scale, routed_weight_eps=1e-20,
+        expert_shards=n, expert_shard=shard)
+    x = jax.random.normal(jax.random.PRNGKey(2), (G, M), jnp.float32)
+    params = layer(1, shards * held).init(jax.random.PRNGKey(3), x)["params"]
+    parts, rows = [], 0
+    for shard in range(shards):
+        mine = {**params, **{n: params[n][shard * held:(shard + 1) * held]
+                             for n in ("w1", "w2", "w3")}}
+        (y, _), stats = layer(shards, held, shard).apply(
+            {"params": mine}, x, mutable=["moe_stats"])
+        parts.append(np.asarray(y))
+        rows += int(np.asarray(stats["moe_stats"]["counts"][0])[0])
+        assert np.asarray(stats["moe_stats"]["held"][0]).tolist() == [
+            G * k, held]
+    assert rows == G * k                # every copy went to exactly one chip
+    shared = {n: {"kernel": 0.1 * jax.random.normal(jax.random.PRNGKey(i), s)}
+              for i, (n, s) in enumerate((("gate_proj", (M, I)),
+                                          ("up_proj", (M, I)),
+                                          ("down_proj", (I, M))))}
+    with jax.default_matmul_precision("highest"):
+        routed = lambda mutate=None: np.asarray(tref._experts(  # noqa: E731
+            x, params, top_k=k, renormalise=True, scaling=scale,
+            first_held=0, mutate=mutate, precision="float32"))
+        once = np.asarray(tref._swiglu(x, shared, precision="float32"))
+    want = routed() + once
+    assert np.abs((sum(parts) + once) - want).max() < 1e-5
+    assert np.abs(sum(p + once for p in parts) - want).max() > 0.1
+    assert np.abs(parts[0] + once - want).max() > 1e-2
+    # and the scale is in the sum: the reference without it differs
+    assert np.abs(sum(parts) - routed("route_scale_one")).max() > 1e-2
+
+
 def test_a_share_is_the_references_share(whole):
     """One share against the reference given the same share, and against
     the reference's mutation that ignores the held range."""

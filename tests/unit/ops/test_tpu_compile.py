@@ -778,6 +778,57 @@ def test_kda_kernels_at_the_serving_configuration(chip_compile):
     assert f"%{kda.STEP_NAME}" in text
 
 
+# trinity-large-serve: 48 query heads over 8 KV heads of 128, a query group
+# of SIX, which no older configuration has (Mistral 4, Solar 8, Olmo-Hybrid
+# 1); 8 slots of 513 pages, a fused tick of 8 + 1,024 rows; the window
+# layers' pool (657 pages, the published window of 4,096) and the full
+# layers' (4,105 pages, no window) behind a page table each
+TRINITY = dict(S=8, pp=513, page=64, Hq=48, Hkv=8, D=128, T=1032)
+
+
+@pytest.mark.parametrize("pool,window", [
+    pytest.param(657, 4096, id="window-layers-pool"),
+    pytest.param(4105, None, id="full-layers-pool")])
+def test_both_kernels_at_a_query_group_of_six(chip_compile, pool, window):
+    """Both attention kernels at the tiles their rules give a group of six:
+    row tiles of 128 tokens are 768 MXU rows a product against key blocks
+    of 512, under the chunk kernel's own VMEM ceiling at 1,024-row chunks
+    (and over it at 2,048); the decode kernel's groups are Mistral's.  The
+    route names are the ones ``trinity-large-serve`` expects."""
+    from deepspeed_tpu.ops import paged_decode, paged_prefill
+
+    c, i32, bf = TRINITY, jnp.int32, jnp.bfloat16
+    tiles = paged_prefill.tiling(c["Hq"], c["Hkv"], c["page"], c["D"], bf)
+    decode_tiles = paged_decode.tiling(c["Hkv"], c["page"], c["pp"],
+                                       c["D"], bf)
+    assert (tiles, decode_tiles) == ((128, 8), (4, 4))
+    assert paged_prefill.route_name(
+        tiles, c["Hq"], c["Hkv"], c["page"]) == "pallas-chunk-768x512"
+    assert paged_decode.route_name(decode_tiles,
+                                   c["Hkv"]) == "pallas-decode-4x8"
+    assert paged.rpa_kv_pages_per_block(c["Hkv"], c["page"], c["pp"]) == 8
+    width = -(-c["T"] // 16) * 16
+    vmem = lambda rows: paged_prefill.vmem_bytes(   # noqa: E731
+        rows, c["Hq"], c["Hkv"], c["page"], c["D"], tiles)
+    assert vmem(width) < paged_prefill._VMEM_CEILING < vmem(2048 + 16)
+    pages = ((pool, c["page"], 2 * c["Hkv"], c["D"]), bf)
+    meta = [((c["S"],), i32), ((c["S"], c["pp"]), i32),
+            ((c["S"] + 1,), i32), ((1,), i32)]
+    text = chip_compile(
+        functools.partial(paged_prefill._chunk.__wrapped__,
+                          sm_scale=c["D"] ** -0.5, sliding_window=window,
+                          tiles=tiles, interpret=False),
+        ((c["Hq"], width, c["D"]), bf), pages, *meta)
+    assert f"%{paged_prefill.NAME}" in text
+    text = chip_compile(
+        functools.partial(paged_decode._decode.__wrapped__,
+                          sm_scale=c["D"] ** -0.5, sliding_window=window,
+                          tiles=decode_tiles, interpret=False),
+        ((c["S"], c["Hq"], c["D"]), bf), pages, meta[0], meta[1],
+        ((c["S"],), jnp.bool_))
+    assert f"%{paged_decode.NAME}" in text
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4
@@ -863,6 +914,21 @@ GMM_PREFILL = [
 def test_grouped_matmul_kernel_at_the_prefill_ticks_of_the_cells(
         chip_compile, bank, tiling, rows):
     _gmm_compiles(chip_compile, bank, tiling, rows)
+
+
+# trinity-large-serve: 32 of 256 experts of 3,072 x 3,072 held, the widest
+# bank a cell gives the kernel (56.6 MB an expert); the routed copies of a
+# tick of 1,032 rows (top 4) as ``held_moe`` gathers them, a quarter where
+# the live ones fit and all of them where not, and a decode tick's
+GMM_WIDE = [
+    pytest.param(1032, id="prefill-tick-quarter"),
+    pytest.param(4128, id="prefill-tick-all"),
+    pytest.param(32, id="decode-tick")]
+
+
+@pytest.mark.parametrize("rows", GMM_WIDE)
+def test_grouped_matmul_kernel_at_a_bank_3072_wide(chip_compile, rows):
+    _gmm_compiles(chip_compile, (32, 3072, 3072), (128, 3072, 768), rows)
 
 
 @pytest.mark.parametrize("pool_dtype", [jnp.int8, jnp.float8_e4m3fn],

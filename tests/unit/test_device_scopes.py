@@ -315,6 +315,18 @@ def _train_engine(loss_module, dp=2):
      ("attn", "select_row", "-")),
     ("jit(f)/M/model/layers_0/self_attn/tokensel_row_attention/gather",
      ("attn", "tokensel_row_attention", "-")),
+    # a model with window layers and full layers: the scope that says which
+    # leads the sub-scope, a Pallas kernel's name behind it
+    ("jit(f)/M/model/layers_1/self_attn/attn_window/q_proj/dot_general",
+     ("attn", "attn_window", "-")),
+    ("jit(f)/M/model/layers_1/self_attn/attn_window/jit(_chunk)/"
+     "paged_chunk_attention/pallas_call",
+     ("attn", "attn_window/paged_chunk_attention", "-")),
+    ("jit(f)/M/model/layers_2/self_attn/attn_full/jit(_decode)/"
+     "paged_decode_attention/pallas_call",
+     ("attn", "attn_full/paged_decode_attention", "-")),
+    ("jit(f)/M/model/layers_2/pre_mlp_layernorm/rsqrt",
+     ("mlp", "pre_mlp_layernorm", "-")),
     ("jit(f)/while/body/closed_call/sample/sample_logits_batched/reduce",
      ("sample", "sample_logits_batched", "-")),
     ("jit(f)/M/head.rows/gather", ("head", "head.rows", "-")),
