@@ -6,10 +6,14 @@ TPU-native replacement for the reference's fused attention kernels
 ``csrc/transformer/inference/``).  Two implementations behind one API:
 
 - :func:`flash_attention` — a Pallas TPU kernel (online-softmax, blockwise,
-  O(S) memory, causal skip, GQA via head-index mapping).  The grid is
-  ``(B, H, num_q_blocks, num_k_blocks)``; TPU grids execute sequentially per
-  core, so the running max/denominator/accumulator live in VMEM scratch
-  across the innermost (k-block) grid steps.
+  O(S) memory, GQA via head-index mapping).  The grid is ``(B, H, tiles)``
+  over the LIVE tiles of the ``num_q_blocks x num_k_blocks`` rectangle (a
+  causal call has no step for a tile above the diagonal), the ``(i, j)`` of
+  a step read from two scalar-prefetched lists; TPU grids execute
+  sequentially per core, so the running max/denominator/accumulator live
+  in VMEM scratch across the steps of one q block.  A tile that sits
+  square on the causal diagonal is multiplied by row groups, each against
+  the keys it reaches and no others (:func:`_diag_rows`).
 - :func:`blockwise_attention` — a pure-XLA ``lax.scan`` formulation of the
   same math, used as the CPU fallback and as the memory-efficient custom
   backward (recompute-based, matching the flash-attention-2 backward).
@@ -223,72 +227,213 @@ def _block_is_edge(i, j, *, causal: bool, block_q: int, block_k: int,
     return edge
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                      acc_sc, m_sc, l_sc, *,
-                      sm_scale: float, causal: bool,
-                      block_q: int, block_k: int, seq_q: int, seq_k: int):
-    del sm_scale  # folded into q by the caller
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
-    i = pl.program_id(2)
+def _diag_rows(causal: bool, block_q: int, block_k: int, seq_q: int,
+               seq_k: int) -> int:
+    """Rows of a row group of a diagonal tile, 0 where the tiles that cross
+    the diagonal keep the whole-tile masked code.
+
+    A tile crossing the causal diagonal is worked by row groups where it
+    sits square on it: equal blocks, the diagonal's offset a block multiple
+    and no padded tail, so that the diagonal tiles are the only edge tiles
+    of the call.  Row group ``g`` of ``block / sb`` then reaches keys
+    ``[0, (g + 1) * sb)`` of its tile: one rectangular product, the mask on
+    its last ``sb`` columns alone, nothing multiplied above the diagonal.
+    ``sb`` is half the block: three of a tile's four sub-tiles are
+    multiplied and two masked.  Quarters multiply 10 of 16 and were slower
+    in two kernels of three on v5e (PERF.md section 6, PR 60): the MXU
+    streams a group's rows past each 128 x 128 block of keys, and pays
+    the block's load for 256 rows as for 1,024.  The static slices of the
+    scores and of the row statistics need ``sb`` in whole lane tiles; a
+    block whose half is not keeps the whole-tile code too."""
+    if not causal or block_q != block_k or seq_q % block_q or \
+            seq_k % block_k or seq_k < seq_q or (seq_k - seq_q) % block_k:
+        return 0
+    sb = block_q // 2
+    return 0 if sb % _LANE else sb
+
+
+def _live_tiles(nq: int, nk: int, *, causal: bool, block_q: int,
+                block_k: int, seq_q: int, seq_k: int, interpret: bool
+                ) -> np.ndarray:
+    """``[steps, 2]`` int32 ``(i, j)`` of the tiles a call visits, q block
+    major: every tile of a non-causal call, of a causal one those that
+    reach at or under the (shifted) diagonal.  A q block that reaches no
+    key keeps its first tile as a step that multiplies nothing, so its
+    output is still written.  A causal call records how far its work list
+    is the tiles under the diagonal, ``<live tiles>/<grid steps>-diag<sb>``
+    (``flash_attention_causal``, one entry a distinct shape)."""
+    tiles, live = [], 0
+    for i in range(nq):
+        last = nk - 1
+        if causal:
+            last = min(last, ((i + 1) * block_q - 1 + seq_k - seq_q)
+                       // block_k)
+        live += max(last + 1, 0)
+        tiles += [(i, j) for j in range(max(last, 0) + 1)]
+    if causal:
+        sb = _diag_rows(causal, block_q, block_k, seq_q, seq_k)
+        routes.take("flash_attention_causal", every=True,
+                    interpret=interpret,
+                    pallas=f"{live}/{len(tiles)}-diag{sb}")
+    return np.asarray(tiles, np.int32)
+
+
+def _tile_lists(tiles: np.ndarray, major: int):
+    """The scalar-prefetch operands of a sweep over ``tiles`` that
+    accumulates along axis ``1 - major``: the q-block and k-block index of
+    every step, sorted by ``major`` then the other."""
+    order = np.lexsort((tiles[:, 1 - major], tiles[:, major]))
+    return jnp.asarray(tiles[order, 0]), jnp.asarray(tiles[order, 1])
+
+
+def _sweep_ends(major_ref, t, steps: int):
+    """(first, last): whether grid step ``t`` opens / closes the run of
+    steps that share ``major_ref[t]``, the block whose accumulator the
+    kernel holds in scratch."""
+    blk = major_ref[t]
+    first = jnp.logical_or(t == 0, major_ref[jnp.maximum(t - 1, 0)] != blk)
+    last = jnp.logical_or(t == steps - 1,
+                          major_ref[jnp.minimum(t + 1, steps - 1)] != blk)
+    return first, last
+
+
+_ALL = slice(None)  # a whole tile's rows or keys
+
+
+def _lanes(x, n: int):
+    """``[rows, n]`` of a row statistic kept ``[rows, _LANE]`` with the
+    row's value in every lane."""
+    if n <= _LANE:
+        return x[:, :n]
+    if n % _LANE == 0:
+        return jnp.tile(x, (1, n // _LANE))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _whole_tile_mask(i, j, geom):
+    return lambda s: jnp.where(_block_mask(i, j, **geom), s,
+                               DEFAULT_MASK_VALUE)
+
+
+def _diag_mask(sb: int):
+    """Masks the scores of a row group ``[sb, keys]`` or of a key group
+    ``[rows, sb]``: of its sub-tiles only the ``[sb, sb]`` one that sits on
+    the diagonal needs it, the group's last columns or its first rows."""
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (sb, sb), 1) <=
+           jax.lax.broadcasted_iota(jnp.int32, (sb, sb), 0))
+
+    def mask(s):
+        rows, keys = s.shape
+        on = lambda x: jnp.where(tri, x, DEFAULT_MASK_VALUE)
+        if rows == keys:
+            return on(s)
+        if rows == sb:
+            return jnp.concatenate([s[:, :keys - sb], on(s[:, keys - sb:])],
+                                   axis=1)
+        return jnp.concatenate([on(s[:sb]), s[sb:]], axis=0)
+    return mask
+
+
+def _diag_groups(block: int, sb: int, by_keys: bool):
+    """(rows, keys) of a diagonal tile's groups.  Row group ``g`` reaches
+    keys ``[0, (g + 1) * sb)`` of the tile and no others; ``by_keys`` is
+    the transpose, for a kernel that accumulates a key at a time: key
+    group ``c`` is reached by rows ``[c * sb, block)``."""
+    if by_keys:
+        return [(pl.ds(c * sb, block - c * sb), pl.ds(c * sb, sb))
+                for c in range(block // sb)]
+    return [(pl.ds(g * sb, sb), pl.ds(0, (g + 1) * sb))
+            for g in range(block // sb)]
+
+
+def _visit_tile(update, i, j, *, causal: bool, block_q: int, block_k: int,
+                seq_q: int, seq_k: int, by_keys: bool = False):
+    """Work tile ``(i, j)`` of a sweep with ``update(rows, keys, mask)``,
+    which multiplies ``rows`` of the q block by ``keys`` of the k block and
+    accumulates: an interior tile whole and unmasked, a tile on an edge
+    whole under :func:`_block_mask` or, where :func:`_diag_rows` says so,
+    by the groups under the diagonal; a tile that reaches no key (a
+    placeholder step of :func:`_live_tiles`) not at all."""
     # bottom-right-aligned causal diagonal (KV-cache decode convention)
     causal_offset = seq_k - seq_q
-
-    @pl.when(j == 0)
-    def _init():
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-        m_sc[:] = jnp.full_like(m_sc, -jnp.inf)
-        l_sc[:] = jnp.zeros_like(l_sc)
-
     # causal: block row i only reaches key blocks starting at or below its
     # shifted diagonal
     run = jnp.logical_or(
         not causal, j * block_k <= (i + 1) * block_q - 1 + causal_offset)
     geom = dict(causal=causal, block_q=block_q, block_k=block_k,
                 seq_k=seq_k, causal_offset=causal_offset)
-
-    def _tile(masked: bool):
-        # dots stay in the input dtype (bf16 on TPU -> full MXU rate) with
-        # fp32 accumulation; only the softmax statistics run in fp32
-        q = q_ref[0, 0]                                # [bq, d]
-        k = k_ref[0, 0]                                # [bk, d]
-        v = v_ref[0, 0]                                # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bq, bk] f32
-        if masked:
-            s = jnp.where(_block_mask(i, j, **geom), s, DEFAULT_MASK_VALUE)
-        m_prev = m_sc[:, 0]                            # [bq]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_sc[:] = (l_sc[:] * alpha[:, None] +
-                   jnp.broadcast_to(jnp.sum(p, axis=-1)[:, None],
-                                    l_sc.shape))
-        acc_sc[:] = acc_sc[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[:] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
-
     edge = _block_is_edge(i, j, **geom)
+    sb = _diag_rows(causal, block_q, block_k, seq_q, seq_k)
 
     @pl.when(jnp.logical_and(run, edge))
     def _compute_masked():
-        _tile(masked=True)
+        if sb:
+            mask = _diag_mask(sb)
+            for rows, keys in _diag_groups(block_q, sb, by_keys):
+                update(rows, keys, mask)
+        else:
+            update(_ALL, _ALL, _whole_tile_mask(i, j, geom))
 
     @pl.when(jnp.logical_and(run, jnp.logical_not(edge)))
     def _compute_interior():
-        _tile(masked=False)
+        update(_ALL, _ALL)
 
-    @pl.when(j == nk - 1)
+
+def _flash_fwd_kernel(ti_ref, tj_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                      acc_sc, m_sc, l_sc, *,
+                      sm_scale: float, causal: bool,
+                      block_q: int, block_k: int, seq_q: int, seq_k: int):
+    del sm_scale  # folded into q by the caller
+    t = pl.program_id(2)
+    i, j = ti_ref[t], tj_ref[t]
+    first, last = _sweep_ends(ti_ref, t, pl.num_programs(2))
+
+    @pl.when(first)
+    def _init():
+        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[:] = jnp.full_like(m_sc, -jnp.inf)
+        l_sc[:] = jnp.zeros_like(l_sc)
+
+    def _update(rows, keys, mask=None):
+        # dots stay in the input dtype (bf16 on TPU -> full MXU rate) with
+        # fp32 accumulation; only the softmax statistics run in fp32
+        q = q_ref[0, 0, rows, :]                       # [rows, d]
+        k = k_ref[0, 0, keys, :]                       # [keys, d]
+        v = v_ref[0, 0, keys, :]                       # [keys, d]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [rows, keys] f32
+        if mask is not None:
+            s = mask(s)
+        # the statistics keep the scratch's form, a row's value in each of
+        # its lanes: turning them into a [rows] vector and back cost the
+        # forward 6 % at D = 128, S = 4096 on v5e (PERF.md section 6, PR 60)
+        m_prev = m_sc[rows, :]                         # [rows, _LANE]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_new, s.shape[1]))
+        alpha = jnp.exp(m_prev - m_new)
+        l_sc[rows, :] = (l_sc[rows, :] * alpha +
+                         jnp.sum(p, axis=-1, keepdims=True))
+        acc_sc[rows, :] = acc_sc[rows, :] * _lanes(alpha, v.shape[1]) + \
+            jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        m_sc[rows, :] = m_new
+
+    _visit_tile(_update, i, j, causal=causal, block_q=block_q,
+                block_k=block_k, seq_q=seq_q, seq_k=seq_k)
+
+    @pl.when(last)
     def _finalize():
-        l = jnp.maximum(l_sc[:, 0], 1e-30)             # [bq]
-        m = m_sc[:, 0]
+        l = jnp.maximum(l_sc[:], 1e-30)                # [bq, _LANE]
+        m = m_sc[:]
         # rows that never saw a valid key: 0 output, +inf lse (bwd p = 0)
         valid = m > DEFAULT_MASK_VALUE * 0.5
-        o_ref[0, 0] = jnp.where(valid[:, None], acc_sc[:] / l[:, None],
+        d = acc_sc.shape[1]
+        o_ref[0, 0] = jnp.where(_lanes(valid, d), acc_sc[:] / _lanes(l, d),
                                 0.0).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(valid, m + jnp.log(l), jnp.inf)[None, :]
+        lse_ref[0, 0] = jnp.where(valid, m + jnp.log(l),
+                                  jnp.inf)[:, 0][None, :]
 
 
 def _flash_fwd_pallas(q, k, v, *, sm_scale: float, causal: bool,
@@ -314,40 +459,37 @@ def _flash_fwd_pallas(q, k, v, *, sm_scale: float, causal: bool,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, k_pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, k_pad), (0, 0)))
 
-    grid = (B, H, nq, nk)
+    common = dict(causal=causal, block_q=block_q, block_k=block_k,
+                  seq_q=S, seq_k=Sk)
+    tiles = _live_tiles(nq, nk, interpret=interpret, **common)
+    q_spec = pl.BlockSpec((1, 1, block_q, D),
+                          lambda b, h, t, ti, tj: (b, h, ti[t], 0))
+    k_spec = pl.BlockSpec((1, 1, block_k, D),
+                          lambda b, h, t, ti, tj: (b, h // groups, tj[t], 0))
     out, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          seq_q=S, seq_k=Sk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, h // groups, j, 0)),
-            pl.BlockSpec((1, 1, block_k, D),
-                         lambda b, h, i, j: (b, h // groups, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D),
-                         lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, 1, block_q),
-                         lambda b, h, i, j: (b, h, 0, i)),
-        ],
+        functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, **common),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, H, len(tiles)),
+            in_specs=[q_spec, k_spec, k_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((1, 1, 1, block_q),
+                             lambda b, h, t, ti, tj: (b, h, 0, ti[t])),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+                pltpu.VMEM((block_q, _LANE), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, nq * block_q, D), q.dtype),
             jax.ShapeDtypeStruct((B, H, 1, nq * block_q), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-            pltpu.VMEM((block_q, _LANE), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="flash_fwd",
-    )(q, k, v)
+    )(*_tile_lists(tiles, major=0), q, k, v)
     return out[:, :, :S], lse[:, :, 0, :S]
 
 
@@ -362,117 +504,92 @@ def _flash_fwd_pallas(q, k, v, *, sm_scale: float, causal: bool,
 # P is recomputed blockwise from the saved logsumexp — O(S) memory, and every
 # dot hits the MXU in fp32 accumulation.
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_sc, *, sm_scale: float, causal: bool,
+def _flash_bwd_dq_kernel(ti_ref, tj_ref, q_ref, k_ref, v_ref, do_ref,
+                         lse_ref, delta_ref, dq_ref, dq_sc, *,
+                         sm_scale: float, causal: bool,
                          block_q: int, block_k: int, seq_q: int, seq_k: int):
     # q arrives pre-scaled by sm_scale; the caller rescales dq afterwards
     del sm_scale
-    j = pl.program_id(3)
-    nk = pl.num_programs(3)
-    i = pl.program_id(2)
-    causal_offset = seq_k - seq_q
+    t = pl.program_id(2)
+    i, j = ti_ref[t], tj_ref[t]
+    first, last = _sweep_ends(ti_ref, t, pl.num_programs(2))
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
-    run = jnp.logical_or(
-        not causal, j * block_k <= (i + 1) * block_q - 1 + causal_offset)
-    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
-                seq_k=seq_k, causal_offset=causal_offset)
-
-    def _tile(masked: bool):
-        q = q_ref[0, 0]                                # [bq, d]
-        k = k_ref[0, 0]                                # [bk, d]
-        v = v_ref[0, 0]                                # [bk, d]
-        do = do_ref[0, 0]                              # [bq, d]
-        lse = lse_ref[0, 0, 0]                         # [bq] f32
-        delta = delta_ref[0, 0, 0]                     # [bq] f32
+    def _update(rows, keys, mask=None):
+        q = q_ref[0, 0, rows, :]                       # [rows, d]
+        k = k_ref[0, 0, keys, :]                       # [keys, d]
+        v = v_ref[0, 0, keys, :]                       # [keys, d]
+        do = do_ref[0, 0, rows, :]                     # [rows, d]
+        lse = lse_ref[0, 0, 0, rows]                   # [rows] f32
+        delta = delta_ref[0, 0, 0, rows]               # [rows] f32
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        if masked:
-            s = jnp.where(_block_mask(i, j, **geom), s, DEFAULT_MASK_VALUE)
+        if mask is not None:
+            s = mask(s)
         p = jnp.exp(s - lse[:, None])                  # masked/invalid -> 0
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bq, bk]
+            preferred_element_type=jnp.float32)        # [rows, keys]
         ds = (p * (dp - delta[:, None])).astype(k.dtype)
-        dq_sc[:] += jax.lax.dot_general(
+        dq_sc[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    edge = _block_is_edge(i, j, **geom)
+    _visit_tile(_update, i, j, causal=causal, block_q=block_q,
+                block_k=block_k, seq_q=seq_q, seq_k=seq_k)
 
-    @pl.when(jnp.logical_and(run, edge))
-    def _compute_masked():
-        _tile(masked=True)
-
-    @pl.when(jnp.logical_and(run, jnp.logical_not(edge)))
-    def _compute_interior():
-        _tile(masked=False)
-
-    @pl.when(j == nk - 1)
+    @pl.when(last)
     def _finalize():
         dq_ref[0, 0] = dq_sc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_sc, dv_sc, *, sm_scale: float,
-                          causal: bool, block_q: int, block_k: int,
-                          seq_q: int, seq_k: int):
+def _flash_bwd_dkv_kernel(ti_ref, tj_ref, q_ref, k_ref, v_ref, do_ref,
+                          lse_ref, delta_ref, dk_ref, dv_ref, dk_sc, dv_sc,
+                          *, sm_scale: float, causal: bool, block_q: int,
+                          block_k: int, seq_q: int, seq_k: int):
     # q arrives pre-scaled: dk = ds^T @ (q * scale) absorbs the rescale
     del sm_scale
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
-    j = pl.program_id(2)
-    causal_offset = seq_k - seq_q
+    t = pl.program_id(2)
+    qi, j = ti_ref[t], tj_ref[t]
+    first, last = _sweep_ends(tj_ref, t, pl.num_programs(2))
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_sc[:] = jnp.zeros_like(dk_sc)
         dv_sc[:] = jnp.zeros_like(dv_sc)
 
-    run = jnp.logical_or(
-        not causal, j * block_k <= (qi + 1) * block_q - 1 + causal_offset)
-    geom = dict(causal=causal, block_q=block_q, block_k=block_k,
-                seq_k=seq_k, causal_offset=causal_offset)
-
-    def _tile(masked: bool):
-        q = q_ref[0, 0]                                # [bq, d]
-        k = k_ref[0, 0]                                # [bk, d]
-        v = v_ref[0, 0]                                # [bk, d]
-        do = do_ref[0, 0]                              # [bq, d]
-        lse = lse_ref[0, 0, 0]                         # [bq] f32
-        delta = delta_ref[0, 0, 0]                     # [bq] f32
+    def _update(rows, keys, mask=None):
+        q = q_ref[0, 0, rows, :]                       # [rows, d]
+        k = k_ref[0, 0, keys, :]                       # [keys, d]
+        v = v_ref[0, 0, keys, :]                       # [keys, d]
+        do = do_ref[0, 0, rows, :]                     # [rows, d]
+        lse = lse_ref[0, 0, 0, rows]                   # [rows] f32
+        delta = delta_ref[0, 0, 0, rows]               # [rows] f32
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        if masked:
-            s = jnp.where(_block_mask(qi, j, **geom), s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse[:, None])                  # [bq, bk] f32
-        dv_sc[:] += jax.lax.dot_general(
+        if mask is not None:
+            s = mask(s)
+        p = jnp.exp(s - lse[:, None])                  # [rows, keys] f32
+        dv_sc[keys, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bk, d]
+            preferred_element_type=jnp.float32)        # [keys, d]
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bq, bk]
+            preferred_element_type=jnp.float32)        # [rows, keys]
         ds = (p * (dp - delta[:, None])).astype(q.dtype)
-        dk_sc[:] += jax.lax.dot_general(
+        dk_sc[keys, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [bk, d]
+            preferred_element_type=jnp.float32)        # [keys, d]
 
-    edge = _block_is_edge(qi, j, **geom)
+    _visit_tile(_update, qi, j, causal=causal, block_q=block_q,
+                block_k=block_k, seq_q=seq_q, seq_k=seq_k, by_keys=True)
 
-    @pl.when(jnp.logical_and(run, edge))
-    def _compute_masked():
-        _tile(masked=True)
-
-    @pl.when(jnp.logical_and(run, jnp.logical_not(edge)))
-    def _compute_interior():
-        _tile(masked=False)
-
-    @pl.when(qi == nq - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[0, 0] = dk_sc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_sc[:].astype(dv_ref.dtype)
@@ -508,53 +625,47 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, *, sm_scale: float, causal: bool,
     lse4 = lse[:, :, None, :]                          # [B,H,1,Sq_pad]
     delta4 = delta[:, :, None, :]
 
-    common = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
-                  block_k=block_k, seq_q=S, seq_k=Sk)
-    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    common = dict(causal=causal, block_q=block_q, block_k=block_k,
+                  seq_q=S, seq_k=Sk)
+    tiles = _live_tiles(nq, nk, interpret=interpret, **common)
+    common.update(sm_scale=sm_scale)
+    q_spec = pl.BlockSpec((1, 1, block_q, D),
+                          lambda b, h, t, ti, tj: (b, h, ti[t], 0))
     k_spec = pl.BlockSpec((1, 1, block_k, D),
-                          lambda b, h, i, j: (b, h // groups, j, 0))
+                          lambda b, h, t, ti, tj: (b, h // groups, tj[t], 0))
     row_spec = pl.BlockSpec((1, 1, 1, block_q),
-                            lambda b, h, i, j: (b, h, 0, i))
+                            lambda b, h, t, ti, tj: (b, h, 0, ti[t]))
+    in_specs = [q_spec, k_spec, k_spec, q_spec, row_spec, row_spec]
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+    # dq sweep: q block outer, k block inner (accumulate over k)
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **common),
-        grid=(B, H, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=[q_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, H, len(tiles)),
+            in_specs=in_specs, out_specs=[q_spec],
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((B, H, nq * block_q, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret, name="flash_bwd_dq",
-    )(q, k, v, do, lse4, delta4)[0]
+        compiler_params=params, interpret=interpret, name="flash_bwd_dq",
+    )(*_tile_lists(tiles, major=0), q, k, v, do, lse4, delta4)[0]
 
     # dkv sweep: k block outer, q block inner (accumulate over q)
-    kq_q_spec = pl.BlockSpec((1, 1, block_q, D),
-                             lambda b, h, j, i: (b, h, i, 0))
-    kq_k_spec = pl.BlockSpec((1, 1, block_k, D),
-                             lambda b, h, j, i: (b, h // groups, j, 0))
-    kq_row_spec = pl.BlockSpec((1, 1, 1, block_q),
-                               lambda b, h, j, i: (b, h, 0, i))
-    kq_out_spec = pl.BlockSpec((1, 1, block_k, D),
-                               lambda b, h, j, i: (b, h, j, 0))
+    kv_out_spec = pl.BlockSpec((1, 1, block_k, D),
+                               lambda b, h, t, ti, tj: (b, h, tj[t], 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, **common),
-        grid=(B, H, nk, nq),
-        in_specs=[kq_q_spec, kq_k_spec, kq_k_spec, kq_q_spec, kq_row_spec,
-                  kq_row_spec],
-        out_specs=[kq_out_spec, kq_out_spec],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B, H, len(tiles)),
+            in_specs=in_specs, out_specs=[kv_out_spec, kv_out_spec],
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, D), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((B, H, nk * block_k, D), jnp.float32),
             jax.ShapeDtypeStruct((B, H, nk * block_k, D), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret, name="flash_bwd_dkv",
-    )(q, k, v, do, lse4, delta4)
+        compiler_params=params, interpret=interpret, name="flash_bwd_dkv",
+    )(*_tile_lists(tiles, major=1), q, k, v, do, lse4, delta4)
     # dq was computed against the pre-scaled q; undo the fold
     dq = dq * sm_scale
     return dq[:, :, :S], dk[:, :, :Sk], dv[:, :, :Sk]
@@ -632,8 +743,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     backward in both cases.  ``interpret=True`` forces the Pallas kernel in
     interpreter mode (CPU testing).  Blocks clamp to the sequence length;
     the 1024 default measured fastest at the 2k-seq bench shape on v5e
-    (fwd+bwd 1.57 ms vs 1.72 at 512, D=64 GQA) — VMEM comfortably holds
-    [1024, D] tiles for the head dims in use.
+    (fwd+bwd 1.57 ms vs 1.72 at 512, D=64 GQA) and again at D=128 GQA,
+    S=4096 causal, 2 x 32 heads (a chip's share of ``pretrain-zero3``;
+    PR 60: fwd 2.47 + bwd 6.18 ms vs 2.66 + 7.54 at 512) — VMEM
+    comfortably holds [1024, D] tiles for the head dims in use.
     """
     assert q.shape[1] % k.shape[1] == 0, (
         f"q heads {q.shape[1]} not a multiple of kv heads {k.shape[1]}")

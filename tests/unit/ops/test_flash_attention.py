@@ -10,9 +10,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.ops import routes
 from deepspeed_tpu.ops.flash_attention import (flash_attention,
                                                mha_reference,
-                                               _blockwise_fwd)
+                                               _blockwise_fwd,
+                                               _diag_rows,
+                                               _flash_bwd_pallas,
+                                               _flash_fwd_pallas)
+from tests.unit.ops import flash_whole_tile_kernels as whole_tile
 
 
 def _make_qkv(rng, B=2, H=4, Hkv=None, S=128, D=32, dtype=jnp.float32):
@@ -251,3 +256,97 @@ def test_pallas_kernel_partitions_manually_under_a_mesh(devices, mesh_kw):
     np.testing.assert_allclose(got, want, rtol=1e-5)
     for g, w in zip(got_grads, want_grads):
         np.testing.assert_allclose(g, w, atol=5e-4, rtol=5e-4)
+
+
+# (H, Hkv, Sq, Sk, D, causal, block, sb): sb the rows of a row group where a
+# diagonal tile is worked by row groups, 0 where the call keeps the
+# whole-tile code
+ROW_GROUP_CASES = [
+    pytest.param((2, 2, 512, 512, 128, True, 256, 128), id="d128-mha"),
+    pytest.param((4, 1, 512, 512, 128, True, 256, 128), id="d128-gqa4"),
+    pytest.param((2, 2, 512, 512, 64, True, 256, 128), id="d64-mha"),
+    pytest.param((4, 1, 512, 512, 64, True, 256, 128), id="d64-gqa4"),
+    # one tile, all of it diagonal, two row groups of 256: the rows of 512
+    # tokens that the training cell's check of its outputs runs
+    pytest.param((4, 1, 512, 512, 64, True, 1024, 256), id="one-tile"),
+    pytest.param((2, 1, 256, 512, 64, True, 256, 128), id="offset-a-block"),
+    pytest.param((2, 1, 256, 384, 64, True, 256, 0), id="offset-no-multiple"),
+    pytest.param((2, 1, 300, 300, 64, True, 256, 0), id="padded-tail"),
+    pytest.param((2, 1, 512, 512, 64, False, 256, 0), id="not-causal"),
+    pytest.param((2, 1, 96, 96, 32, True, 32, 0), id="blocks-of-32"),
+    pytest.param((2, 1, 256, 256, 64, True, 128, 0), id="one-lane-tile"),
+    # half a block of 192 is no whole lane tiles: Mosaic cannot slice there
+    pytest.param((2, 1, 384, 384, 64, True, 192, 0), id="half-unaligned"),
+]
+
+
+@pytest.mark.parametrize("case", ROW_GROUP_CASES)
+def test_row_groups_engage_by_shape(case):
+    """Where a causal call's tiles sit square on the diagonal the three
+    kernels work them by row groups: forward, dq, dk and dv against the
+    naive reference.  Every other call takes the whole-tile code it took
+    before: the same bits as the kernels kept in
+    ``flash_whole_tile_kernels.py``."""
+    H, Hkv, Sq, Sk, D, causal, block, sb = case
+    assert _diag_rows(causal, min(block, Sq), min(block, Sk), Sq, Sk) == sb
+    rng = np.random.default_rng(60)
+    q, do = (jnp.asarray(rng.normal(size=(1, H, Sq, D)), jnp.float32)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(1, Hkv, Sk, D)), jnp.float32)
+            for _ in range(2))
+    kw = dict(sm_scale=D ** -0.5, causal=causal, block_q=block,
+              block_k=block, interpret=True)
+    out, lse = _flash_fwd_pallas(q, k, v, **kw)
+    dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, do, **kw)
+    if not sb:
+        want_out, want_lse = whole_tile._flash_fwd_pallas(q, k, v, **kw)
+        want = whole_tile._flash_bwd_pallas(q, k, v, want_out, want_lse, do,
+                                            **kw)
+        for got, ref in zip((out, lse, dq, dk, dv),
+                            (want_out, want_lse, *want)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+        return
+    ref, vjp = jax.vjp(functools.partial(mha_reference, causal=causal),
+                       q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+    # the kernels return dk and dv a q head; the caller sums a GQA group
+    dk, dv = (g.reshape(1, Hkv, H // Hkv, Sk, D).sum(axis=2)
+              for g in (dk, dv))
+    for got, ref in zip((dq, dk, dv), vjp(do)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=5e-4, rtol=5e-4)
+
+
+# (Sq, Sk, block) -> what the causal call records: live tiles / grid steps
+# and the rows of a row group
+@pytest.mark.parametrize("shape,want", [
+    ((512, 512, 128), "10/10-diag0"),
+    ((1024, 1024, 256), "10/10-diag128"),
+    ((1024, 1024, 512), "3/3-diag256"),
+    ((512, 512, 1024), "1/1-diag256"),
+    ((256, 768, 256), "3/3-diag128"),
+    ((96, 96, 32), "6/6-diag0"),
+    # rows 0-3 reach no key: their q block keeps one step and no live tile
+    ((8, 4, 4), "1/2-diag0"),
+])
+def test_causal_calls_record_their_live_tiles(monkeypatch, shape, want):
+    """``flash_attention_causal`` names, a distinct call shape, the tiles
+    at and under the diagonal, the grid steps taken for them (no step for
+    a tile above it) and the row group of the diagonal tiles; the forward
+    and the backward of one shape record once, a call that is not causal
+    records nothing."""
+    monkeypatch.setattr(routes, "_taken", {})
+    Sq, Sk, block = shape
+    rng = np.random.default_rng(61)
+    q = jnp.asarray(rng.normal(size=(1, 1, Sq, 8)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, 1, Sk, 8)), jnp.float32)
+            for _ in range(2))
+    attend = functools.partial(flash_attention, block_q=block, block_k=block,
+                               interpret=True)
+    attend(q, k, v, causal=False)
+    assert "flash_attention_causal" not in routes.taken()
+    jax.grad(lambda q: jnp.sum(attend(q, k, v, causal=True)))(q)
+    assert routes.taken()["flash_attention_causal"] == want + "-interpret"
+    assert routes.taken()["flash_attention_fwd"] == "pallas-interpret"
+    assert routes.taken()["flash_attention_bwd"] == "pallas-interpret"

@@ -67,11 +67,15 @@ def chip_compile(one_chip):
     compilation_cache.reset_cache()
 
 
-# (B, H, Hkv, S, D): GPT-2 medium's attention as chip_smoke trains it, and
-# a Llama-3-8B / Mistral-7B GQA layer at 2k
+# (B, H, Hkv, S, D): GPT-2 medium's attention as chip_smoke trains it, a
+# Llama-3-8B / Mistral-7B GQA layer at 2k, and a chip's share of
+# `pretrain-zero3`'s step (two packed rows of 4,096 tokens); and a length
+# that is one block whose half is no whole lane tiles (no row groups there)
 FLASH_SHAPES = [
     pytest.param((2, 16, 16, 1024, 64), id="d64-s1024"),
     pytest.param((1, 32, 8, 2048, 128), id="d128-s2048-gqa"),
+    pytest.param((2, 32, 8, 4096, 128), id="d128-s4096-gqa"),
+    pytest.param((1, 4, 2, 1000, 128), id="d128-s1000-one-block"),
 ]
 
 
