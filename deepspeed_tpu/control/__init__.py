@@ -1,11 +1,10 @@
 """Closed-loop control plane: the signal plane drives the knobs.
 
-The system's perf-critical knobs (speculation mode/k, harvest
-interval, async depth, KV-tiering prefetch and budgets, AIO buffer
-count, decode block, router admission thresholds) were hand-tuned per
-host; PRs 10 and 13 built the signal plane (tracer spans, histogram
-quantiles, SLO burn rates, per-stage counters) that makes tuning them
-*observable*.  This package closes the loop:
+The system's perf-critical knobs (harvest interval, async depth,
+KV-tiering prefetch and budgets, AIO buffer count, decode block, router
+admission thresholds) were hand-tuned per host; PRs 10 and 13 built the
+signal plane (tracer spans, histogram quantiles, SLO burn rates, per-stage
+counters) that makes tuning them *observable*.  This package closes the loop:
 
 - :mod:`~deepspeed_tpu.control.knobs` — the typed knob surface
   (:class:`KnobRegistry`): bounds, step, cooldown, apply callbacks

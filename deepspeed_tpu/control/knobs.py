@@ -11,8 +11,8 @@ knobs through a :class:`KnobRegistry`, which clamps and types every
 write, so a policy bug can propose garbage and the subsystem still
 receives an in-bounds value of the right type.
 
-Knobs whose value is baked into a compiled program (``decode_block``,
-speculation ``k``/mode) carry ``recompiles=True``: they are excluded
+Knobs whose value is baked into a compiled program (``decode_block``)
+carry ``recompiles=True``: they are excluded
 from the online tunable set (``tunable()``) — changing them mid-run
 would trigger fresh XLA compilations on the hot path, breaking the
 engine's zero-recompile steady-state contract — and are reachable only

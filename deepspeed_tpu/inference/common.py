@@ -108,8 +108,8 @@ class HostStageStats:
     clock, ``time.perf_counter`` unless ``telemetry.configure(clock=)``
     replaced it, so ring and stage seconds agree.  Besides the stages
     above the v2 engine brackets ``rng_split`` (the eager key split
-    before a decode block), ``sample`` (the long-context driver's eager
-    ``sample_logits``; a fused tick samples inside its program) and
+    before a decode block), ``sample`` (an eager ``sample_logits`` outside
+    a program; the engine's own ticks sample inside theirs) and
     ``reap`` (``_reap`` and the handoff sweep after a tick's harvest),
     and opens ``engine.step`` around a whole ``step()``;
     the replica worker opens ``replica.op`` around each op and the
@@ -139,9 +139,9 @@ class HostStageStats:
     spans off).
 
     ``step()`` books a step at its END, by the program it dispatched
-    (``dispatched(program)``: ``fused``, ``decode_block``, ``spec``, else
-    ``none``; a step that dispatched a block and then a tick is the
-    tick's): ``steps_by_program`` holds the steps, their wall seconds
+    (``dispatched(program)``: ``fused``, ``decode_block``, else ``none``; a
+    step that dispatched a block and then a tick is the tick's):
+    ``steps_by_program`` holds the steps, their wall seconds
     and every bracket's seconds by span name (``engine.fetch``'s are the
     step's wait).  ``serving_stages()["fused_tick_host"]``
     divides the fused steps' by their number: ``host_ms`` (wall less
@@ -176,14 +176,7 @@ class HostStageStats:
     ``drains`` counts the times the loop waited for the queue's newest
     entry with nothing dispatched behind it, because its next plan
     needed a token's value; ``drain_reasons`` says why, by name
-    (``eos``, ``pages``, ``spec``, ``handoff``, ``lc``, ``api``).
-
-    Speculative decoding adds two host stages — ``draft`` (draft-model
-    KV catch-up prefill + host-side draft planning) and ``verify`` (the
-    fused draft+verify block's dispatch bracket; program handoff time,
-    not device time) — and the ``spec_*`` counters.  When any
-    speculative block ran, ``serving_stages()`` carries a
-    ``speculation`` sub-dict with the acceptance breakdown.
+    (``eos``, ``pages``, ``handoff``, ``api``).
 
     KV tiering adds ``spill`` (page gather + device_get + handoff to
     the tier store) and ``restore`` (tier fetch + verify + page upload
@@ -196,8 +189,8 @@ class HostStageStats:
     emits a ``prefix_cache`` sub-dict merging the index's own stats.
     """
 
-    STAGES = ("plan", "upload", "dispatch", "device", "harvest", "draft",
-              "verify", "spill", "restore", "prefix")
+    STAGES = ("plan", "upload", "dispatch", "device", "harvest", "spill",
+              "restore", "prefix")
     # bracketed and reported in seconds, but no key of the per-dispatch
     # ``<stage>_ms`` table nor part of ``host_s`` (``sample`` and
     # ``reap`` overlap ``harvest`` where they nest in it)
@@ -206,7 +199,7 @@ class HostStageStats:
                   for s in STAGES + EXTRA}
     # what a ``step()`` can have dispatched; a fused tick outranks the
     # decode block dispatched earlier in the same step
-    PROGRAMS = ("fused", "decode_block", "spec", "none")
+    PROGRAMS = ("fused", "decode_block", "none")
 
     def __init__(self, replica: str = ""):
         # scale-out serving runs several engines in one process; the
@@ -234,10 +227,6 @@ class HostStageStats:
         self.meta_uploads = 0     # host->device metadata arrays sent
         self.blocking_gets = 0    # blocking device->host fetches
         self.harvests = 0         # deferred-harvest fold-backs
-        self.spec_dispatches = 0  # speculative draft+verify blocks
-        self.spec_proposed = 0    # draft tokens proposed (device count)
-        self.spec_accepted = 0    # draft tokens accepted (device count)
-        self.spec_tokens = 0      # tokens emitted by speculative blocks
         self.prefix_hits = 0      # admissions that attached >=1 cached page
         self.prefix_misses = 0    # admissions that attached nothing
         self.prefix_hit_pages = 0   # cached pages attached
@@ -354,8 +343,8 @@ class HostStageStats:
             f"{s}_ms": round(self.seconds[s] * 1e3 / d, 4)
             for s in self.STAGES}
         host = sum(self.seconds[s] for s in
-                   ("plan", "upload", "dispatch", "harvest", "draft",
-                    "verify", "spill", "restore", "prefix"))
+                   ("plan", "upload", "dispatch", "harvest", "spill",
+                    "restore", "prefix"))
         dev = self.seconds["device"]
         out["host_s"] = round(host, 4)
         out["device_wait_s"] = round(dev, 4)
@@ -380,23 +369,6 @@ class HostStageStats:
         if self.drain_reasons:
             out["drain_reasons"] = dict(self.drain_reasons)
         out["fused_tick_host"] = self.fused_tick_host()
-        if self.spec_dispatches:
-            sd = self.spec_dispatches
-            out["speculation"] = {
-                "spec_dispatches": sd,
-                "draft_ms": round(self.seconds["draft"] * 1e3 / sd, 4),
-                "verify_ms": round(self.seconds["verify"] * 1e3 / sd, 4),
-                "proposed": self.spec_proposed,
-                "accepted": self.spec_accepted,
-                "acceptance_rate": round(
-                    self.spec_accepted / max(self.spec_proposed, 1), 4),
-                "mean_accepted_len": round(
-                    self.spec_accepted / max(self.spec_tokens -
-                                             self.spec_accepted, 1), 4),
-                "tokens": self.spec_tokens,
-                "effective_tokens_per_dispatch": round(
-                    self.spec_tokens / sd, 2),
-            }
         return out
 
 

@@ -60,40 +60,6 @@ class V2Subtree(ConfigModel):
             f"{cls.__name__}: cannot build from {type(value).__name__}")
 
 
-class SpeculationConfig(V2Subtree):
-    """``v2.speculation`` subtree: speculative decoding on the ragged
-    engine's decode-block path.
-
-    ``mode``: ``off`` | ``ngram`` (prompt-lookup drafting from the
-    sequence's own emitted+prompt tokens — no second model) | ``draft``
-    (a small same-vocab family member proposes; the engine needs the
-    draft module+params passed programmatically, ``draft_model`` here
-    names a model-zoo preset for CLIs/benches to construct).
-    ``k``: drafted tokens per speculative tick — the target scores all
-    ``k+1`` positions in ONE ragged dispatch, so one weight pass
-    amortizes over up to ``k+1`` emitted tokens.
-    ``ngram``: the lookup n-gram length for ``mode=ngram``."""
-
-    _short: ClassVar[Dict[type, str]] = {str: "mode"}
-
-    mode: str = "off"
-    k: int = 4
-    ngram: int = 3
-    draft_model: Optional[str] = None
-
-    @model_validator(mode="after")
-    def _check(self):
-        if self.mode not in ("off", "ngram", "draft"):
-            raise ValueError(
-                f"speculation.mode must be off|ngram|draft, got "
-                f"{self.mode!r}")
-        if self.k < 1:
-            raise ValueError("speculation.k must be >= 1")
-        if self.ngram < 1:
-            raise ValueError("speculation.ngram must be >= 1")
-        return self
-
-
 class KVTieringConfig(V2Subtree):
     """``v2.kv_tiering`` subtree: host-RAM + NVMe spill tiers for the
     paged-KV pool.
@@ -136,22 +102,8 @@ class KVTieringConfig(V2Subtree):
     # a write/read/verify revival probe; a clean probe re-arms the tier
     nvme_fail_threshold: int = 3
     probe_every: int = 8
-    # -- partial residency (long context): a live sequence's page list
-    # may split between HBM-resident pages and parked pages.  The first
-    # ``sink_pages`` (attention sinks) and the most recent
-    # ``window_pages`` stay resident; full middle groups of
-    # ``chunk_pages`` demote through the host/NVMe tiers and stream
-    # back through a fixed staging buffer during the chunked attention
-    # scan.  ``prefetch_lookahead`` bounds how many waiting spilled
-    # sessions the pipeline's restore-prefetch scans ahead (the old
-    # hardcoded islice(waiting, 8)).  ``long_context`` arms the
-    # partial-residency admission path (a request whose full KV exceeds
-    # HBM is admitted as long as its resident window fits HBM and its
-    # total fits the combined tiers).
-    long_context: bool = False
-    sink_pages: int = 1
-    window_pages: int = 8
-    chunk_pages: int = 4
+    # how many waiting spilled sessions the pipeline's restore-prefetch
+    # scans ahead
     prefetch_lookahead: int = 8
 
     @model_validator(mode="after")
@@ -172,18 +124,8 @@ class KVTieringConfig(V2Subtree):
                 "kv_tiering.nvme_fail_threshold must be >= 1")
         if self.probe_every < 1:
             raise ValueError("kv_tiering.probe_every must be >= 1")
-        if self.sink_pages < 1:
-            raise ValueError("kv_tiering.sink_pages must be >= 1")
-        if self.window_pages < 1:
-            raise ValueError("kv_tiering.window_pages must be >= 1")
-        if self.chunk_pages < 1:
-            raise ValueError("kv_tiering.chunk_pages must be >= 1")
         if self.prefetch_lookahead < 1:
             raise ValueError("kv_tiering.prefetch_lookahead must be >= 1")
-        if self.long_context and not self.enabled:
-            raise ValueError(
-                "kv_tiering.long_context requires kv_tiering.enabled — "
-                "partial residency parks middle pages in the spill tiers")
         from deepspeed_tpu.resilience.sdc import CHECKSUM_ALGOS
 
         if self.checksum not in CHECKSUM_ALGOS:
@@ -301,8 +243,6 @@ class InferenceV2Config(ConfigModel):
     # (ops/ragged_paged_quant.py on TPU, the gathered-pages XLA
     # reference elsewhere) — the pool is never materialized full-width.
     kv_cache_dtype: str = "none"
-    speculation: SpeculationConfig = Field(
-        default_factory=SpeculationConfig)
     kv_tiering: KVTieringConfig = Field(default_factory=KVTieringConfig)
     prefix_cache: PrefixCacheConfig = Field(
         default_factory=PrefixCacheConfig)

@@ -167,8 +167,7 @@ class TieredKVStore:
 
         # substrate: timers + digest side pool + bounded IO windows
         # (cat="kv" labels every bracket's trace span — spill/restore
-        # stalls and the partial-residency page-in waits all land on
-        # the kv row of the exported trace)
+        # stalls land on the kv row of the exported trace)
         self.timers = StageTimers(cat="kv")
         self._digests = DigestPool(algo=self.algo, workers=2,
                                    timers=self.timers,
@@ -190,14 +189,9 @@ class TieredKVStore:
             "rereads": 0, "reread_recovered": 0, "quarantined": 0,
             "spill_fallbacks": 0, "bytes_spilled": 0, "bytes_restored": 0,
             "exports": 0, "imports": 0,
-            # partial-residency page-in (peek): parked middles streamed
-            # through staging without dropping the tier entry
-            "pageins": 0, "pagein_pages": 0, "pagein_bytes": 0,
-            "pagein_prefetch_hits": 0, "pagein_prefetch_misses": 0,
             # degraded mode (persistent NVMe failure -> host-only)
             "nvme_failures": 0, "tier_degraded": 0, "tier_rearmed": 0,
             "degraded_folds": 0, "probes": 0, "probe_failures": 0}
-        self._pagein_hist = None
 
         self.spill_dir: Optional[str] = None
         if self.nvme_budget > 0:
@@ -455,60 +449,6 @@ class TieredKVStore:
         self.counters["restores"] += 1
         self.counters["pages_restored"] += ent.n_pages
         self.counters["bytes_restored"] += ent.n_pages * self.page_stride
-        return arrs
-
-    def peek(self, uid: Key) -> List[np.ndarray]:
-        """Read ``uid``'s pages WITHOUT dropping the tier entry — the
-        partial-residency page-in.  A parked middle group streams
-        through the staging ring into the chunked attention scan every
-        tick, while the tier copy (host buffer or NVMe file) stays
-        authoritative, so nothing is re-spilled afterwards.  Pages are
-        digest-verified exactly like :meth:`restore` (transient flips
-        heal by re-read; persistent corruption quarantines and raises
-        :class:`KVRestoreError`).  The blocking wait is observed as the
-        ``pagein_wait`` stage (a ``cat="kv"`` trace span) and the
-        ``dstpu_kv_pagein_stall_ms`` histogram."""
-        self._check_lost(uid)
-        ent = self._entries.get(uid)
-        assert ent is not None, f"uid {uid} not spilled"
-        was = ent.state
-        t0 = time.perf_counter()
-        with self.timers.stage("pagein_wait"):
-            work = self._fetch(ent)
-            digests = self._digests.pop(uid) if self.verify else None
-            if self.verify:
-                with self.timers.stage("pagein_verify"):
-                    self._verify_pages(ent, work, digests)
-                # the entry survives a peek: hand the (already joined)
-                # digests back to the side pool for the next page-in
-                self._digests.submit(uid, lambda d=digests: d)
-            if ent.state == "reading":
-                # prefetch landed this group: the staging slot is done
-                # once the working copy exists; the file remains the
-                # authoritative tier copy
-                self._staging.release(ent.slot)
-                ent.slot = None
-                ent.state = "nvme"
-            arrs = self._unpack(work, ent.n_pages)
-        stall_ms = (time.perf_counter() - t0) * 1e3
-        self.counters["pageins"] += 1
-        self.counters["pagein_pages"] += ent.n_pages
-        self.counters["pagein_bytes"] += ent.n_pages * self.page_stride
-        if was == "reading":
-            self.counters["pagein_prefetch_hits"] += 1
-        elif was == "nvme":
-            self.counters["pagein_prefetch_misses"] += 1
-        from deepspeed_tpu.telemetry.metrics import metrics as _metrics
-        if _metrics.enabled:
-            if self._pagein_hist is None or self._pagein_hist is not \
-                    _metrics.get("dstpu_kv_pagein_stall_ms"):
-                from deepspeed_tpu.telemetry import metrics as _mmod
-                self._pagein_hist = _metrics.histogram(
-                    "dstpu_kv_pagein_stall_ms",
-                    "Partial-residency page-in stall (ms) — wall time a "
-                    "chunked-scan tick blocked on a parked group",
-                    buckets=_mmod.MS_BUCKETS)
-            self._pagein_hist.observe(stall_ms)
         return arrs
 
     def _fetch(self, ent: _Entry) -> np.ndarray:

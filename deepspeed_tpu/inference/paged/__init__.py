@@ -70,7 +70,7 @@ from deepspeed_tpu.inference.paged.latent import (  # noqa: F401
 from deepspeed_tpu.inference.paged.linear_state import (  # noqa: F401
     LINEAR_MIXER, LinearState, linear_layers, ragged_linear_attention)
 from deepspeed_tpu.inference.paged.reference import (  # noqa: F401
-    fold_stats, neutral_carry, ref_paged_attention, ref_paged_attention_quant)
+    ref_paged_attention, ref_paged_attention_quant)
 from deepspeed_tpu.inference.paged.tree import (SLOT_STATE,  # noqa: F401
                                                 WINDOW_POOL, Part,
                                                 is_slot_state, map_cache,

@@ -169,10 +169,10 @@ class PageAllocator:
         i.e. each physical page's refcount equals the number of
         page-table rows referencing it plus held non-slot refs.  Raises
         ``AssertionError`` on a leak or double-grant; returns the
-        counts.  The speculative-decoding rollback path keeps pages it
-        over-allocated for rejected draft positions (they cover the very
-        next block's writes), so accounting exactness — not
-        owned==pages_for(length) minimality — is the invariant."""
+        counts.  A sequence may own pages past its length (a block's
+        growth covers tokens it has yet to write), so accounting
+        exactness — not owned==pages_for(length) minimality — is the
+        invariant."""
         owned = [p for pages in self._owned.values() for p in pages]
         counts: Dict[int, int] = {}
         for p in owned:

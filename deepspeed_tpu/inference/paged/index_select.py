@@ -218,11 +218,6 @@ INDEX_IS = ("{model} attends the {p.indexer.topk} keys an indexer of "
             "{p.indexer.indexer_num_heads} heads chooses, token by token, "
             "in {p.layers} of its {p.of} layers")
 INDEX_REFUSES = {
-    "speculation": ", and the speculative verify has no kernel that scores "
-    "and chooses for its rows (nor a draft that shares the index keys)",
-    "long_context": ", and the partial-residency scan (inference/v2/"
-    "long_context.py) stages and folds keys and values a page at a time, "
-    "where a choice by token needs every index key of the sequence at once",
     "quantized": "; the quantized pool's scale rows and kernel are per head "
     "of a pool laid out heads first, and an index key has no scale",
     "tensor_parallel": ", and the cache's sharding rule splits a pool's "

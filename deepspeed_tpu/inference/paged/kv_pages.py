@@ -46,8 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.paged.reference import (
-    _MASK_VALUE, _masked_stats, fold_stats, ref_paged_attention,
-    ref_paged_attention_quant)
+    ref_paged_attention, ref_paged_attention_quant)
 from deepspeed_tpu.inference.paged.tree import (_LANES, WINDOW_POOL, Part,
                                                 window_pool_layers)
 from deepspeed_tpu.ops import paged_decode, paged_prefill, routes
@@ -105,59 +104,6 @@ def layer_window(cfg, layer_kind: Optional[str] = None) -> Optional[int]:
     return None if layer_kind == "full_attention" else window
 
 
-def _staged_attend_stats(mdl, q: jax.Array, ragged_meta, cfg) -> jax.Array:
-    """Chunk-stats dispatch of the partial-residency scan: attend the
-    query tokens against a STAGED dense KV block (parked pages uploaded
-    through the staging buffer, never entering the pool) and sow the
-    flash-attention stat triple into the ``"carry"`` collection instead
-    of producing attention output.
-
-    ``ragged_meta`` carries ``staged_kv [R, 2*Hkv, D]`` (store dtype —
-    int8/fp8 pages stay 1-byte and dequantize here via
-    ``staged_scales [R, 2*Hkv]``), ``staged_kpos [R]`` absolute key
-    positions, ``staged_qpos [T]`` absolute query positions, and
-    optionally an incoming carry (``carry_m``/``carry_l``/``carry_acc``)
-    folded before sowing.  The pool is untouched: no cache variable is
-    created, so chunk dispatches need no ``cache`` collection at all.
-    Returns zeros shaped like the normal attention output — the driver
-    reads the stats, not the module output.
-    """
-    _, H, T, D = q.shape
-    staged = ragged_meta["staged_kv"]
-    R, combined, _ = staged.shape
-    Hkv = combined // 2
-    sf = staged.astype(jnp.float32)
-    if "staged_scales" in ragged_meta:
-        sf = sf * ragged_meta["staged_scales"][..., None].astype(
-            jnp.float32)
-    k_s = sf[:, 0::2, :]                                   # [R, Hkv, D]
-    v_s = sf[:, 1::2, :]
-    groups = H // Hkv
-    k_r = jnp.repeat(k_s, groups, axis=1)                  # [R, H, D]
-    v_r = jnp.repeat(v_s, groups, axis=1)
-    qt = q[0].transpose(1, 0, 2).astype(jnp.float32)       # [T, H, D]
-    sm_scale = float(1.0 / np.sqrt(D))
-    att = jnp.einsum("thd,rhd->htr", qt, k_r) * sm_scale
-    kpos = ragged_meta["staged_kpos"]
-    qpos = ragged_meta["staged_qpos"]
-    # parked groups are full pages of live tokens strictly below the
-    # query frontier, so the causal bound is usually all-true — kept
-    # anyway (with the window bound) so a partially-covered group near
-    # a sliding window stays exact
-    mask = kpos[None, :] <= qpos[:, None]                  # [T, R]
-    window = layer_window(cfg)
-    if window is not None:
-        mask = mask & (kpos[None, :] > qpos[:, None] - window)
-    att = jnp.where(mask[None], att, jnp.float32(_MASK_VALUE))
-    m_c, l_c, acc_c = _masked_stats(att, mask, v_r)
-    if "carry_m" in ragged_meta:
-        m_c, l_c, acc_c = fold_stats(
-            (ragged_meta["carry_m"], ragged_meta["carry_l"],
-             ragged_meta["carry_acc"]), m_c, l_c, acc_c)
-    mdl.sow("carry", "stats", (m_c, l_c, acc_c))
-    return jnp.zeros((1, H, T, D), q.dtype)
-
-
 def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                             ragged_meta: Dict[str, jax.Array], cfg,
                             layer_kind: Optional[str] = None) -> jax.Array:
@@ -171,14 +117,6 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     such a model reads and writes the window layers' OWN pool through their
     own table (``window_pages.py``: the ``window_`` keys of the metadata).
 
-    Partial residency hooks (the chunked multi-dispatch scan): a
-    ``staged_kv`` key in ``ragged_meta`` short-circuits to
-    :func:`_staged_attend_stats` BEFORE any cache variable exists —
-    chunk dispatches never touch the pool.  ``carry_m``/``carry_l``/
-    ``carry_acc`` keys make the normal (finish) dispatch fold the
-    accumulated chunk stats into its attention via the explicit-carry
-    paths of the reference functions / quantized kernel.
-
     A looped model (``cfg.total_ut_steps = U > 1``) says which pass this
     is in ``ragged_meta["kv_slot"]``: the layer's pool has ``U`` slots a
     page and this call reads and writes slot ``u`` alone, in place.
@@ -189,19 +127,9 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     D = kv_lane_dim(head_dim, getattr(cfg, "kv_cache_dtype", "none"))
     if D != head_dim:
         # heads of 64 live in the pool zero-padded to the kernels' lanes
-        assert "staged_kv" not in ragged_meta and \
-            "carry_m" not in ragged_meta, (
-                "padded K/V heads have no partial-residency scan")
         pad = lambda a: jnp.pad(a, ((0, 0),) * 3 + ((0, D - head_dim),))
         q, k, v = pad(q), pad(k), pad(v)
     slots = kv_slots_per_layer(cfg)
-    if "staged_kv" in ragged_meta:
-        assert slots == 1, "partial residency has one K/V slot a layer"
-        return _staged_attend_stats(mdl, q, ragged_meta, cfg)
-    carry = None
-    if "carry_m" in ragged_meta:
-        carry = (ragged_meta["carry_m"], ragged_meta["carry_l"],
-                 ragged_meta["carry_acc"])
     Hkv = k.shape[1]
     leaf, P, page, own = "kv_pages", cfg.kv_num_pages, cfg.kv_page_size, ""
     if layer_kind == "sliding_attention" and window_pool_layers(cfg):
@@ -211,10 +139,8 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     page_indices = ragged_meta[own + "page_indices"]
     pool_shape = (P, page, 2 * Hkv, D)
     if slots > 1:
-        assert carry is None and (
-            getattr(cfg, "kv_cache_dtype", "none") or "none") == "none", (
-            "a looped model's pool is full-width and wholly resident: no "
-            "quantized pages, no chunk-scan carry")
+        assert (getattr(cfg, "kv_cache_dtype", "none") or "none") == "none", (
+            "a looped model's pool is full-width: no quantized pages")
         # slot u of page p is page p * U + u of the same buffer; a row
         # bound for no page (past the pool) stays past it, and the -1 of
         # an unused page-table entry stays -1
@@ -289,14 +215,12 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     # wherever that kernel's layout holds for this shard of the pool, and
     # otherwise, as for every other program, the call in attend_ragged
     one_query_a_slot = (ragged_meta.get("one_query_a_slot") is True
-                        and carry is None and qmax is None)
+                        and qmax is None)
     # so does a fused tick (programs._fused_forward: decode rows and
     # prompt chunks packed by ``cu_q_lens``): its chunks are the chunk
     # kernel's and its one-query rows the decode kernel's, under the same
-    # rule.  Every other caller (the speculative verify, the chunk scan)
-    # keeps the call in attend_ragged
-    fused_tick = (ragged_meta.get("fused_tick") is True
-                  and carry is None and qmax is None)
+    # rule.  Every other caller keeps the call in attend_ragged
+    fused_tick = ragged_meta.get("fused_tick") is True and qmax is None
 
     def attend(qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
                live=None):
@@ -318,11 +242,8 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         # the vLLM-TPU kernel is built for 128 lanes: heads of 128 take it
         # as they are, heads of 64 zero-padded to 128 (``kv_lane_dim``),
         # every other width the XLA reference — correct but O(T *
-        # total_page_rows).  An incoming chunk-scan carry always routes to
-        # the reference: the upstream kernel has no carry operand
-        if paged_attention_path(
-                head_dim, carry=carry is not None).startswith(
-                    "pallas-ragged"):
+        # total_page_rows)
+        if paged_attention_path(head_dim).startswith("pallas-ragged"):
             from jax.experimental.pallas.ops.tpu.ragged_paged_attention \
                 import kernel as rpa
 
@@ -338,7 +259,7 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                 num_kv_pages_per_block=rpa_kv_pages_per_block(
                     pages.shape[2] // 2, page, page_indices.shape[1]),
                 num_queries_per_block=min(T, _RPA_QUERY_BLOCK))
-        if carry is None and routes.on_tpu():
+        if routes.on_tpu():
             from deepspeed_tpu.utils.logging import logger
 
             logger.warning(
@@ -348,7 +269,7 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
                 "(serving_stages()['kernels'] reports it)")
         return ref_paged_attention(
             qt, pages, kv_lens, page_indices, cu_q_lens, num_seqs,
-            sm_scale=sm_scale, sliding_window=window, carry=carry)
+            sm_scale=sm_scale, sliding_window=window)
 
     def attend_quant(qt, pages, scales, kv_lens, page_indices, cu_q_lens,
                      num_seqs):
@@ -360,11 +281,10 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
 
             return ragged_paged_attention_quant(
                 qt, pages, scales, kv_lens, page_indices, cu_q_lens,
-                num_seqs, sm_scale=sm_scale, sliding_window=window,
-                carry=carry)
+                num_seqs, sm_scale=sm_scale, sliding_window=window)
         return ref_paged_attention_quant(
             qt, pages, scales, kv_lens, page_indices, cu_q_lens, num_seqs,
-            sm_scale=sm_scale, sliding_window=window, carry=carry)
+            sm_scale=sm_scale, sliding_window=window)
 
     # TP serving (reference v2 sharding/attn.py: heads split over the TP
     # group): attention is embarrassingly parallel over heads, so under a
@@ -381,9 +301,6 @@ def paged_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
 
         from deepspeed_tpu.sequence.layer import resolve_mesh
 
-        assert carry is None, (
-            "chunked partial-residency scan requires tensor_parallel=1 "
-            "(the long-context driver gates admission on it)")
         assert H % tp == 0 and Hkv % tp == 0, (
             f"TP serving requires heads divisible by tp={tp} "
             f"(H={H}, Hkv={Hkv})")
@@ -431,18 +348,17 @@ def kv_dequant_path(head_dim: int) -> str:
                        pallas="pallas-quant", xla="xla-gather")
 
 
-def paged_attention_path(head_dim: int, carry: bool = False) -> str:
+def paged_attention_path(head_dim: int) -> str:
     """Which read path a full-width pool takes on this backend: the
-    upstream Pallas ragged kernel (TPU, no incoming chunk-scan carry — it
-    has no carry operand) for heads of 128 (``pallas-ragged``) and for
-    heads of 64 stored zero-padded to 128 (``pallas-ragged-pad128``), or
-    the dense XLA reference (:func:`ref_paged_attention`: ``xla-dense``,
-    over the padded pool ``xla-dense-pad128``).  The engine reports this
-    in ``serving_stages()['kernels']``."""
+    upstream Pallas ragged kernel (TPU) for heads of 128
+    (``pallas-ragged``) and for heads of 64 stored zero-padded to 128
+    (``pallas-ragged-pad128``), or the dense XLA reference
+    (:func:`ref_paged_attention`: ``xla-dense``, over the padded pool
+    ``xla-dense-pad128``).  The engine reports this in
+    ``serving_stages()['kernels']``."""
     pad = "-pad128" if kv_lane_dim(head_dim) != head_dim else ""
     return routes.take("paged_attention",
-                       eligible=kv_lane_dim(head_dim) == _LANES
-                       and not carry,
+                       eligible=kv_lane_dim(head_dim) == _LANES,
                        pallas="pallas-ragged" + pad, xla="xla-dense" + pad)
 
 
@@ -461,10 +377,6 @@ def _serving_tp(cfg) -> int:
 
 LOOP_IS = "{model} runs its layers {p.slots} times a token"
 LOOP_REFUSES = {
-    "speculation": ", and the speculative block's rollback and draft pool "
-    "know one K/V slot a layer",
-    "long_context": ", and the partial-residency scan (inference/v2/"
-    "long_context.py) reads num_hidden_layers as the number of K/V slots",
     "quantized": "; its pool is full-width (the quantized pool's scale rows "
     "have one slot a layer)",
     "tensor_parallel": ", and the pool's sharding rule knows pages of one "

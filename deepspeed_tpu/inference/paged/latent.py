@@ -79,8 +79,6 @@ def latent_update_and_attend(mdl, q: jax.Array, rows: jax.Array,
     module holds it, and the packed sequences' first rows), and gets that
     function's answer in the layout the up-projection reads; a decode
     program takes the decode kernel, every other caller the XLA twin."""
-    assert "staged_kv" not in ragged_meta and "carry_m" not in ragged_meta, (
-        "latent pages have no partial-residency scan")
     W = latent_width(cfg)
     pad = lambda a: a if a.shape[-1] == W else jnp.pad(  # noqa: E731
         a, ((0, 0),) * (a.ndim - 1) + ((0, W - a.shape[-1]),))
@@ -109,10 +107,6 @@ def latent_update_and_attend(mdl, q: jax.Array, rows: jax.Array,
 
 LATENT_IS = "{model} caches one latent row of {p.width} values a token a layer"
 LATENT_REFUSES = {
-    "speculation": ", and the speculative verify has no latent attention "
-    "kernel (nor a draft that shares the latent pool)",
-    "long_context": ", and the partial-residency scan (inference/v2/"
-    "long_context.py) stages and folds per-head keys and values",
     "quantized": "; the quantized pool's scale rows and kernel are per head",
     "tensor_parallel": ", which has no head dim for the pool's sharding rule "
     "to split"}

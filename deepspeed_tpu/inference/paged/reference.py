@@ -1,7 +1,6 @@
 """XLA-compilable attention over a per-head K/V pool, mask-based: the route
-every backend without the Pallas kernels takes (CPU tests), the twin the
-kernels' tests compare against, and the streaming-softmax fold
-``inference/v2/long_context.py`` threads across its dispatches."""
+every backend without the Pallas kernels takes (CPU tests) and the twin the
+kernels' tests compare against."""
 from __future__ import annotations
 
 import jax
@@ -13,63 +12,16 @@ import numpy as np
 _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 
 
-def _masked_stats(att: jax.Array, mask: jax.Array, v_r: jax.Array):
-    """Streaming-softmax statistics of masked logits.
-
-    att: ``[H, T, R]`` scaled logits already filled with ``_MASK_VALUE``
-    where masked; mask: ``[T, R]``; v_r: ``[R, H, D]``.  Returns
-    ``(m [T,H], l [T,H], acc [T,H,D])`` — the flash-attention carry
-    triple; a query row with no valid key keeps the neutral carry
-    ``(m=_MASK_VALUE, l=0, acc=0)``.
-    """
-    m_cur = jnp.max(att, axis=-1).T                        # [T, H]
-    m_safe = jnp.where(m_cur > jnp.float32(_MASK_VALUE * 0.5), m_cur,
-                       jnp.float32(_MASK_VALUE))
-    p = jnp.exp(att - m_safe.T[:, :, None])                # [H, T, R]
-    p = jnp.where(mask[None], p, 0.0)
-    l_cur = jnp.sum(p, axis=-1).T                          # [T, H]
-    acc_cur = jnp.einsum("htr,rhd->thd", p, v_r)           # [T, H, D]
-    return m_safe, l_cur, acc_cur
-
-
-def fold_stats(carry, m_cur, l_cur, acc_cur):
-    """Fold an incoming flash-attention carry ``(m, l, acc)`` with a
-    fresh stat triple — the associative streaming-softmax combine the
-    chunked partial-residency scan threads across dispatches.  The
-    neutral carry ``(m=_MASK_VALUE, l=0, acc=0)`` folds exactly."""
-    m0, l0, acc0 = (x.astype(jnp.float32) for x in carry)
-    m_new = jnp.maximum(m0, m_cur)
-    a0 = jnp.exp(m0 - m_new)
-    a1 = jnp.exp(m_cur - m_new)
-    l_new = a0 * l0 + a1 * l_cur
-    acc_new = a0[..., None] * acc0 + a1[..., None] * acc_cur
-    return m_new, l_new, acc_new
-
-
-def neutral_carry(T: int, H: int, D: int):
-    """The identity element for :func:`fold_stats` (all-masked stats)."""
-    return (jnp.full((T, H), _MASK_VALUE, jnp.float32),
-            jnp.zeros((T, H), jnp.float32),
-            jnp.zeros((T, H, D), jnp.float32))
-
-
 def ref_paged_attention(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
                         page_indices: jax.Array, cu_q_lens: jax.Array,
                         num_seqs: jax.Array, *, sm_scale: float,
-                        sliding_window=None, carry=None) -> jax.Array:
+                        sliding_window=None) -> jax.Array:
     """Same math as the kernel's ``ref_ragged_paged_attention`` but with
     static control flow (where-masks over the flat page buffer), so it
     jits on any backend.  ``page_indices`` may pad unused entries with -1
-    (never matches a real page — including interior holes, which is how
-    a partially-resident sequence's parked columns drop out while the
-    surviving columns keep their true positions).  O(T * P * page_size)
-    — test scale.
-
-    ``carry``: optional incoming flash-attention stats ``(m [T,H],
-    l [T,H], acc [T,H,D])`` from earlier dispatches of a chunked scan;
-    when given the output folds them in via streaming-softmax math
-    (bit-identical shapes, ulp-level numeric difference vs the plain
-    softmax path, which is preserved untouched when ``carry is None``).
+    (never matches a real page — including interior holes: the columns
+    a window pool has given back drop out while the surviving columns
+    keep their true positions).  O(T * P * page_size) — test scale.
     """
     T, H, D = q.shape
     P, page, combined, _ = pages.shape
@@ -113,14 +65,8 @@ def ref_paged_attention(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
     att = jnp.einsum("thd,rhd->htr", q.astype(jnp.float32),
                      k_r.astype(jnp.float32)) * sm_scale
     att = jnp.where(mask[None], att, jnp.float32(_MASK_VALUE))
-    if carry is None:
-        p = jax.nn.softmax(att, axis=-1)
-        y = jnp.einsum("htr,rhd->thd", p, v_r.astype(jnp.float32))
-        return jnp.where(token_valid[:, None, None], y, 0.0).astype(
-            q.dtype)
-    m_c, l_c, acc_c = _masked_stats(att, mask, v_r.astype(jnp.float32))
-    m_n, l_n, acc_n = fold_stats(carry, m_c, l_c, acc_c)
-    y = acc_n / jnp.maximum(l_n, 1e-30)[..., None]
+    p = jax.nn.softmax(att, axis=-1)
+    y = jnp.einsum("htr,rhd->thd", p, v_r.astype(jnp.float32))
     return jnp.where(token_valid[:, None, None], y, 0.0).astype(q.dtype)
 
 
@@ -128,7 +74,7 @@ def ref_paged_attention_quant(q: jax.Array, pages: jax.Array,
                               scales: jax.Array, kv_lens: jax.Array,
                               page_indices: jax.Array, cu_q_lens: jax.Array,
                               num_seqs: jax.Array, *, sm_scale: float,
-                              sliding_window=None, carry=None) -> jax.Array:
+                              sliding_window=None) -> jax.Array:
     """Dequant-free XLA read path for a QUANTIZED page pool: gather each
     sequence's attended pages (still 1-byte) through ``page_indices``,
     dequantize ONLY the gathered operand, then masked attention.  The
@@ -137,10 +83,9 @@ def ref_paged_attention_quant(q: jax.Array, pages: jax.Array,
     (``test_paged_quant.py`` pins that on the traced jaxpr).  Rows
     gathered in page-table order sit at their kv position directly, so
     masking is ``row < kv_len`` + causal bound + per-column validity
-    (a ``-1`` page-table entry — padding or a parked partial-residency
-    hole — gathers the trash page, so its rows must mask out even when
-    they sit below ``kv_len``).  ``carry`` as
-    :func:`ref_paged_attention`.
+    (a ``-1`` page-table entry — padding or an interior hole — gathers
+    the trash page, so its rows must mask out even when they sit below
+    ``kv_len``).
 
     q: ``[T, H, D]``; pages: ``[P, page, 2*Hkv, D]`` int8/fp8_e4m3;
     scales: ``[P, page, 2*Hkv]`` fp32.  O(T * pp * page_size) — the
@@ -172,7 +117,7 @@ def ref_paged_attention_quant(q: jax.Array, pages: jax.Array,
              (t_idx - jnp.take(cu_q_lens[:-1], seq_of_t)))  # [T]
     r_idx = jnp.arange(R, dtype=jnp.int32)
     kv_len_t = jnp.take(kv_lens, seq_of_t)                # [T]
-    # column validity: -1 entries (padding OR interior residency holes)
+    # column validity: -1 entries (padding OR interior holes)
     # gathered the trash page above — their rows never attend
     col_valid = jnp.repeat(page_indices >= 0, page, axis=1)  # [S, R]
     mask = ((r_idx[None, :] <= q_pos[:, None]) &
@@ -188,20 +133,6 @@ def ref_paged_attention_quant(q: jax.Array, pages: jax.Array,
     att = jnp.einsum("thd,trhd->htr", q.astype(jnp.float32),
                      k_t) * sm_scale
     att = jnp.where(mask[None], att, jnp.float32(_MASK_VALUE))
-    if carry is None:
-        p = jax.nn.softmax(att, axis=-1)
-        y = jnp.einsum("htr,trhd->thd", p, v_t)
-        return jnp.where(token_valid[:, None, None], y, 0.0).astype(
-            q.dtype)
-    # carry path reuses the flat-row helper: v as [T, R, H, D] must be
-    # indexed per token, so fold with einsum over the token-gathered v
-    m_cur = jnp.max(att, axis=-1).T                       # [T, H]
-    m_safe = jnp.where(m_cur > jnp.float32(_MASK_VALUE * 0.5), m_cur,
-                       jnp.float32(_MASK_VALUE))
-    p = jnp.exp(att - m_safe.T[:, :, None])
-    p = jnp.where(mask[None], p, 0.0)
-    l_cur = jnp.sum(p, axis=-1).T                         # [T, H]
-    acc_cur = jnp.einsum("htr,trhd->thd", p, v_t)         # [T, H, D]
-    m_n, l_n, acc_n = fold_stats(carry, m_safe, l_cur, acc_cur)
-    y = acc_n / jnp.maximum(l_n, 1e-30)[..., None]
+    p = jax.nn.softmax(att, axis=-1)
+    y = jnp.einsum("htr,trhd->thd", p, v_t)
     return jnp.where(token_valid[:, None, None], y, 0.0).astype(q.dtype)

@@ -138,18 +138,13 @@ class Part:
         return {}
 
 
-# KVState owns the state as it owns pages; what would have to snapshot,
-# share or roll it back and cannot yet is refused by name, not served wrongly
+# KVState owns the state as it owns pages; what would have to snapshot or
+# share it and cannot yet is refused by name, not served wrongly
 SLOT_STATE_IS = "{model} keeps per-slot state in {p.layers} of its {p.of} layers"
 SLOT_STATE_REFUSES = {
     "prefix_cache": ", and a shared prefix (or its copy-on-write page) brings "
     "keys and values only — a sequence that starts behind one needs the "
     "state AT the prefix's end, which the index does not keep",
-    "speculation": ", and the speculative block's rollback rewinds page "
-    "cursors, not state",
-    "long_context": ", and the partial-residency scan (inference/v2/"
-    "long_context.py) runs programs of its own that know keys and values "
-    "only",
     "quantized": "; its pool is full-width (heads of 64 are stored padded to "
     "the kernels' 128 lanes, which the quantized pool's kernel does not "
     "know)",

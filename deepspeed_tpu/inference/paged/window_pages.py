@@ -166,10 +166,6 @@ WINDOW_REFUSES = {
     "prefix_cache": ", and a shared prefix brings the first pool's pages "
     "only — a sequence that starts behind one needs the window layers' last "
     "{p.window} rows AT the prefix's end, which the index does not keep",
-    "speculation": ", and the speculative block's rollback rewinds one page "
-    "cursor a sequence, not two",
-    "long_context": ", and the partial-residency scan (inference/v2/"
-    "long_context.py) parks and stages the columns of one page table",
     "tiering": ", and a spilled request's record carries the rows of one "
     "page table",
     "quantized": "; the window layers' pool is full-width (the quantized "

@@ -69,10 +69,8 @@ def filter_logits_batched(logits: jax.Array, temperature: jax.Array,
                           top_k: jax.Array, top_p: jax.Array) -> jax.Array:
     """Per-row temperature/top-k/top-p filter over [S, V] float32 logits:
     kept entries scaled by temperature, filtered entries at ``-inf``.
-    Softmax of the result is EXACTLY the distribution
-    :func:`sample_logits_batched` draws from — the speculative verify
-    path scores draft tokens against it so acceptance preserves the
-    serving distribution bit-for-bit in expectation."""
+    Softmax of the result is the distribution
+    :func:`sample_logits_batched` draws from."""
     S, V = logits.shape
     lg = logits / jnp.maximum(temperature, 1e-6)[:, None]
     # top-k: threshold at the k-th largest value (k<=0 -> keep all)
@@ -117,90 +115,3 @@ def sample_logits_batched(logits: jax.Array, rng: Optional[jax.Array],
     else:
         sampled = jax.random.categorical(rng, lg, axis=-1).astype(jnp.int32)
     return jnp.where(do_sample, sampled, greedy)
-
-
-def speculative_verify(logits: jax.Array, draft_toks: jax.Array,
-                       draft_probs: Optional[jax.Array],
-                       rng: Optional[jax.Array], do_sample: jax.Array,
-                       temperature: jax.Array, top_k: jax.Array,
-                       top_p: jax.Array):
-    """Accept/rollback core of speculative decoding (jit-pure).
-
-    ``logits`` [S, k+1, V]: the TARGET model's logits over a drafted
-    chunk — row ``i`` conditions on the sequence plus drafted tokens
-    ``< i``.  ``draft_toks`` [S, k]: the proposals.  ``draft_probs``
-    [S, k, V] is the draft's (filtered) proposal distribution, or
-    ``None`` for point-mass drafts (prompt-lookup / n-gram — the draft
-    "distribution" is a delta at the proposed token).
-
-    Returns ``(out_toks [S, k+1], accept_len [S])``: per slot the first
-    ``accept_len`` entries of ``out_toks`` are accepted draft tokens and
-    entry ``accept_len`` is the correction/bonus token, so a slot always
-    emits ``accept_len + 1 in [1, k+1]`` tokens (callers clamp by
-    budget/eos).
-
-    - Greedy rows (``do_sample`` False): longest exact-match prefix
-      against the target argmax; the emitted tokens are the target's
-      greedy continuation REGARDLESS of draft quality, so greedy
-      speculative output is bit-identical to non-speculative decode.
-    - Sampled rows: standard rejection sampling — accept ``d_i`` with
-      probability ``min(1, p_i(d_i) / q_i(d_i))``; on first rejection
-      resample from the residual ``max(p_i - q_i, 0)`` (renormalized);
-      if all accepted, sample the bonus from ``p_{k+1}``.  The output
-      distribution provably equals sampling from ``p`` directly
-      (Leviathan et al.; tested by Monte-Carlo in the suite).
-      ``p`` is the same filtered distribution the non-speculative
-      sampler draws from (:func:`filter_logits_batched`).
-
-    ``rng=None`` compiles the pure-greedy program.
-    """
-    S, K1, V = logits.shape
-    k = K1 - 1
-    lf = logits.astype(jnp.float32)
-    greedy = jnp.argmax(lf, axis=-1).astype(jnp.int32)            # [S, K1]
-    g_match = draft_toks == greedy[:, :k]                         # [S, k]
-    a_greedy = jnp.sum(jnp.cumprod(g_match.astype(jnp.int32),
-                                   axis=1), axis=1)               # [S]
-    if rng is None:
-        return greedy, a_greedy
-
-    rep = lambda x: jnp.repeat(x, K1, axis=0)
-    lg = filter_logits_batched(lf.reshape(S * K1, V), rep(temperature),
-                               rep(top_k), rep(top_p))
-    p = jax.nn.softmax(lg, axis=-1).reshape(S, K1, V)             # [S,K1,V]
-    p_d = jnp.take_along_axis(p[:, :k], draft_toks[..., None],
-                              axis=-1)[..., 0]                    # [S, k]
-    if draft_probs is None:
-        # point-mass draft: q(d)=1 -> accept with prob p(d); residual is
-        # p with the drafted token removed, renormalized
-        ratio = p_d
-        residual = p[:, :k] * (jnp.arange(V)[None, None, :] !=
-                               draft_toks[..., None])
-    else:
-        q = draft_probs.astype(jnp.float32)
-        q_d = jnp.take_along_axis(q, draft_toks[..., None],
-                                  axis=-1)[..., 0]
-        ratio = p_d / jnp.maximum(q_d, 1e-30)
-        residual = jnp.maximum(p[:, :k] - q, 0.0)
-    key_u, key_r, key_b = jax.random.split(rng, 3)
-    u = jax.random.uniform(key_u, (S, k))
-    accept = u < ratio                                            # [S, k]
-    a_samp = jnp.sum(jnp.cumprod(accept.astype(jnp.int32), axis=1),
-                     axis=1)                                      # [S]
-    # residual resample per position (independent keys are fine: the
-    # correction at i is only USED when i is the first rejection);
-    # all-zero residual (p <= q everywhere) falls back to p itself
-    res_sum = jnp.sum(residual, axis=-1, keepdims=True)
-    res = jnp.where(res_sum > 1e-30, residual, p[:, :k])
-    corr = jax.random.categorical(
-        key_r, jnp.log(jnp.maximum(res, 1e-30)), axis=-1)         # [S, k]
-    bonus = jax.random.categorical(
-        key_b, jnp.log(jnp.maximum(p[:, k], 1e-30)), axis=-1)     # [S]
-    fixes = jnp.concatenate([corr, bonus[:, None]],
-                            axis=1).astype(jnp.int32)             # [S, K1]
-    d_pad = jnp.concatenate(
-        [draft_toks, jnp.zeros((S, 1), jnp.int32)], axis=1)
-    j = jnp.arange(K1)[None, :]
-    out_samp = jnp.where(j < a_samp[:, None], d_pad, fixes)
-    out = jnp.where(do_sample[:, None], out_samp, greedy)
-    return out.astype(jnp.int32), jnp.where(do_sample, a_samp, a_greedy)

@@ -3,16 +3,15 @@
 A sequence's keys and values live, at different moments, as pages of the
 device pool that a slot owns, as read-only pages it shares with the prefix
 index, as packed rows parked in the host-RAM / NVMe tiers, as a session blob
-on its way to another replica, or (long context) as a resident window with a
-parked middle.  :class:`KVState` owns all of it: the
+on its way to another replica.  :class:`KVState` owns all of it: the
 :class:`~deepspeed_tpu.inference.paged.PageAllocator`, the host page table,
 the device cache tree, the
 :class:`~deepspeed_tpu.inference.kv_tiering.TieredKVStore`, the
 :class:`~deepspeed_tpu.inference.prefix_cache.PrefixCacheIndex` and the
 fixed-shape page-movement programs.  The scheduler
-(:mod:`~deepspeed_tpu.inference.v2.ragged_engine`) and the long-context
-driver speak to it in verbs on ``(request, slot)`` and never move a page or
-write a page-table cell themselves:
+(:mod:`~deepspeed_tpu.inference.v2.ragged_engine`) speaks to it in verbs on
+``(request, slot)`` and never moves a page or writes a page-table cell
+itself:
 
 ==================  =====================================================
 ``need``            token coverage an admission reserves
@@ -24,7 +23,6 @@ write a page-table cell themselves:
 ``spill/restore``   park a slot's live rows in the tiers / bring them back
 ``export/adopt``    a parked session as a portable blob / install one
 ``forget``          drop what a parked request still holds
-``grow_col/park``   long context: one column in, one middle group out
 ``audit``           refcount conservation over every holder
 ==================  =====================================================
 
@@ -43,13 +41,13 @@ has that pool's allocator and page table owned here too, as ``window``:
 ``can_admit``, ``attach`` and ``ensure`` hold where BOTH pools have the
 pages, ``ensure`` first gives back the window pages the tick leaves behind,
 ``release`` empties both rows and ``audit`` covers both allocators.  The
-verbs that move ``leaf[page]`` (spill, restore, copy-on-write, park) move the
+verbs that move ``leaf[page]`` (spill, restore, copy-on-write) move the
 first pool's leaves only, and every feature that would call them for such a
 model is refused by name.
 
 A request is duck-typed (``uid``, ``slot``, ``ctx``, ``prompt``,
-``generated``, ``prefill_done``, ``spilled``, ``pc_*``, ``lc``,
-``lc_parked``); nothing here imports the engine.
+``generated``, ``prefill_done``, ``spilled``, ``pc_*``); nothing here imports
+the engine.
 """
 from __future__ import annotations
 
@@ -74,8 +72,7 @@ class KVState:
     """The paged pool, its tiers and its prefix index, for ``max_seqs``
     slots.  ``cache`` is the device tree of page pools (every leaf leads
     with the page dim) and of per-slot state, if the model has any (those
-    leaves lead with the slot dim); ``draft_cache`` the draft model's, whose
-    page cursors are the target's.  ``host_stats`` / ``request_latency`` are
+    leaves lead with the slot dim).  ``host_stats`` / ``request_latency`` are
     the engine's: the ``prefix`` / ``spill`` / ``restore`` spans and the
     per-request spill and restore stalls are recorded where the pages
     move."""
@@ -83,10 +80,9 @@ class KVState:
     def __init__(self, cache, *, num_pages: int, page_size: int,
                  max_seqs: int, max_seq_len: int, kv_reserve: str,
                  tier_cfg, pfx_cfg, host_stats, request_latency,
-                 draft_cache: Any = None, window: Any = None):
+                 window: Any = None):
         assert kv_reserve in ("on_demand", "worst_case"), kv_reserve
         self.cache = cache
-        self.draft_cache = {} if draft_cache is None else draft_cache
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.max_seq_len = int(max_seq_len)
@@ -170,7 +166,7 @@ class KVState:
         index's references and the tier store's resources (AIO handle,
         staging buffers, digest pool, spill files).  Idempotent."""
         for r in parked:
-            self._drop_spill(r)
+            self.forget(r)
         if self.pfx is not None:
             self.pfx.clear()
             self.pfx = None
@@ -197,23 +193,10 @@ class KVState:
                     "sequence it could never be scheduled; raise "
                     "num_pages")
             return
-        # two separate bounds, named separately in the rejection:
-        # (1) with long_context, the RESIDENT-WINDOW need (sinks +
-        # recent window + staging slack) must fit HBM — without it
-        # the working-set bound stays an admission-time check, so
-        # tiering keeps accepting requests beyond HBM whose others
-        # spill (max_new_tokens is a budget, not a promise); (2)
-        # the COMBINED-TIER total must fit HBM + host + NVMe.
-        if self.tier_cfg.long_context:
-            resident_need = min(total_pages, self.lc_resident_pages())
-            if resident_need > usable:
-                raise ValueError(
-                    f"request needs {resident_need} HBM-resident KV "
-                    "pages (the partial-residency window: "
-                    "sink_pages + window_pages + chunk_pages + 1) "
-                    f"but the HBM tier owns {usable} usable pages — "
-                    "raise num_pages or shrink the kv_tiering "
-                    "sink_pages/window_pages/chunk_pages knobs")
+        # the working-set bound stays an admission-time check, so tiering
+        # keeps accepting requests beyond HBM whose others spill
+        # (max_new_tokens is a budget, not a promise); the COMBINED-TIER
+        # total must fit HBM + host + NVMe
         cap = usable + self.tiering.budget_pages
         if total_pages > cap:
             raise ValueError(
@@ -224,20 +207,6 @@ class KVState:
                 "— it could never be scheduled; raise num_pages or "
                 "the kv_tiering host_pages/nvme_pages budgets")
 
-    def partially_resident(self, total: int) -> bool:
-        """A sequence of ``total`` tokens cannot be device-resident in
-        full: it decodes under the windowed partial-residency policy."""
-        return (self.tiering is not None and self.tier_cfg.long_context
-                and self.allocator.pages_for(total) > self.num_pages - 1)
-
-    def lc_resident_pages(self) -> int:
-        """HBM pages a partially-resident sequence needs at steady
-        state: sinks + the recent window + one not-yet-parked group in
-        flight + the growth frontier."""
-        t = self.tier_cfg
-        return (int(t.sink_pages) + int(t.window_pages)
-                + int(t.chunk_pages) + 1)
-
     # -- admission --------------------------------------------------------
 
     def need(self, req, block: int) -> int:
@@ -247,11 +216,6 @@ class KVState:
         precisely the steps where ``pipeline=False`` would admit."""
         ctx_len = req.ctx_len
         rem = max(req.max_new_tokens - len(req.generated), 1)
-        if req.lc:
-            # partial residency: reserve the resident WINDOW, not the
-            # context — the parked middle lives in the spill tiers
-            return min(ctx_len + rem,
-                       self.lc_resident_pages() * self.page_size)
         if self.kv_reserve == "worst_case":
             # worst case INCLUDING re-prefilled output for evicted
             # continuations (their ctx carries earlier tokens)
@@ -272,10 +236,6 @@ class KVState:
         ``touch=False`` for probes — LRU order must not move until the
         admission actually happens."""
         total = self.allocator.pages_for(need)
-        if req.lc:
-            # long-context admissions skip the prefix cache (parked
-            # columns would punch holes in a shared prefix run)
-            return total, []
         if req.spilled is not None:
             return total - len(req.spilled.get("shared_pages", ())), []
         if self.pfx is None:
@@ -316,7 +276,7 @@ class KVState:
                 self.allocator.attach(i, shared)
                 self.page_table[i, :len(shared)] = shared
                 attached = len(shared)
-        elif self.pfx is not None and not req.lc:
+        elif self.pfx is not None:
             with st.stage("prefix"):
                 entries = self.pfx.match(req.ctx, touch=True)
                 pages_att: List[int] = []
@@ -436,10 +396,8 @@ class KVState:
         slot holds and has not registered yet to the prefix index
         (chain-hashed onto the request's registered prefix).  Keys off
         how far the rows are written, not dispatch timing, so it is
-        identical in both pipeline modes.  A no-op with the index off
-        and for a long-context request (parked columns would punch
-        holes in a shared prefix run)."""
-        if self.pfx is None or req.lc:
+        identical in both pipeline modes.  A no-op with the index off."""
+        if self.pfx is None:
             return
         page = self.page_size
         while (req.pc_pages + 1) * page <= written:
@@ -747,7 +705,7 @@ class KVState:
 
     # -- parked sessions ----------------------------------------------------
 
-    def _drop_spill(self, r) -> None:
+    def forget(self, r) -> None:
         """Release what a PARKED spilled request still holds: the
         spill-holds pinning its shared prefix pages resident, then the
         tiered payload itself."""
@@ -758,16 +716,6 @@ class KVState:
         r.spilled["shared_pages"] = []
         if self.tiering is not None:
             self.tiering.drop(r.uid)
-
-    def forget(self, r) -> None:
-        """Everything a request that leaves for good still holds off its
-        slot: its spill payload and holds, and a long-context request's
-        parked middle page groups."""
-        self._drop_spill(r)
-        if self.tiering is not None:
-            for g in range(r.lc_parked):
-                self.tiering.drop(f"mid-{r.uid}-{g}")
-        r.lc_parked = 0
 
     def export(self, r) -> Dict[str, Any]:
         """Portable session blob for :meth:`adopt` on another replica.
@@ -798,7 +746,7 @@ class KVState:
             if shared or (n_priv > 0 and not holds):
                 # fold to a re-prefill continuation; release the
                 # spill-holds and the orphaned payload
-                self._drop_spill(r)
+                self.forget(r)
                 blob["ctx"] = np.concatenate(
                     [r.prompt, np.asarray(r.generated, np.int32)])
                 blob["prefill_done"] = 0
@@ -835,33 +783,6 @@ class KVState:
             "shared_pages": []}
         if "state" in spill:
             req.spilled["state"] = [np.asarray(a) for a in spill["state"]]
-        return True
-
-    # -- long context: a resident window with a parked middle ---------------
-
-    def grow_col(self, slot: int, col: int) -> bool:
-        """One page for column ``col`` of ``slot``'s row (always at or
-        past the write frontier, never a parked column).  False = pool
-        dry this tick."""
-        if self.page_table[slot, col] >= 0:
-            return True
-        self._reclaim_for(1)
-        if self.allocator.free_pages < 1:
-            return False
-        self.page_table[slot, col] = self.allocator.grow(slot, 1)[0]
-        return True
-
-    def park(self, slot: int, col0: int, end: int, key: str) -> bool:
-        """Demote columns ``[col0, end)`` of ``slot``'s row into the
-        tiers under ``key``; they become ``-1`` holes that the attention
-        paths mask.  False when the tiers are full: stay resident."""
-        n = end - col0
-        if not self.tiering.can_spill(n):
-            return False
-        pages = [int(p) for p in self.page_table[slot, col0:end]]
-        self.tiering.spill(key, self._gather_rows(pages), n)
-        self.allocator.release_pages(slot, pages)
-        self.page_table[slot, col0:end] = -1
         return True
 
     # -- audit ----------------------------------------------------------------

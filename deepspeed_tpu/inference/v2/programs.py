@@ -14,17 +14,14 @@ the fused tick, a rehearsal can compile one for a described chip.
   result, for whoever checks them.
 - :func:`build_decode_block` — ``block`` decode ticks in a ``lax.scan`` with
   on-device, position-keyed sampling.  ``jit_ragged_decode_block``.
-- :func:`build_spec_block` — the speculative draft + verify + accept block
-  (``jit_spec_verify_block``); :func:`build_draft_prefill` catches the draft
-  model's pool up (``jit_draft_prefill``).
 
 The names are how the profiler's trace and the benchmark's readers find
 device time per program: they do not change.  Beside the programs live what
 they are built from: the serving form of a model and its weights
-(:func:`serving_config`, :func:`prepare_params`, :func:`place_params`,
-:func:`prepare_draft`), the zeroed page pools (:func:`init_cache`,
-:func:`cache_sharding`, :func:`paged_kv_page_bytes`) and the folding of the
-expert layers' and the pass loop's counts.
+(:func:`serving_config`, :func:`prepare_params`, :func:`place_params`), the
+zeroed page pools (:func:`init_cache`, :func:`cache_sharding`,
+:func:`paged_kv_page_bytes`) and the folding of the expert layers' and the
+pass loop's counts.
 """
 from __future__ import annotations
 
@@ -43,10 +40,8 @@ from deepspeed_tpu.inference.paged import (describe, kv_layers,
                                            window_pool, window_pool_layers)
 from deepspeed_tpu.inference.quantization import (dequantize_param_tree,
                                                   quantize_param_tree)
-from deepspeed_tpu.inference.sampling import (filter_logits_batched,
-                                              position_keys,
-                                              sample_logits_batched,
-                                              speculative_verify)
+from deepspeed_tpu.inference.sampling import (position_keys,
+                                              sample_logits_batched)
 from deepspeed_tpu.utils.logging import log_dist
 
 KV_CACHE_DTYPES = ("none", "int8", "fp8", "fp8_e4m3")
@@ -225,51 +220,6 @@ def prepare_params(model, params, cfg, quantize_weights: Optional[str], *,
                  f"({b0 / max(b1, 1):.2f}x)", ranks=[0])
     return cfg, params, dict(unroll=unroll, wq=quantize_weights,
                              native=native)
-
-
-def prepare_draft(draft_model, draft_params, target_cfg, *,
-                  kv_cache_dtype: str, tp: int = 1, device=None):
-    """The draft model of ``speculation.mode='draft'`` in serving form
-    over the TARGET's page geometry (its pool is its own, its page
-    cursors are shared).  Returns ``(draft module, params, dunroll)``."""
-    if draft_model is None:
-        raise ValueError(
-            "speculation.mode='draft' needs a draft model: pass "
-            "draft_model=<small same-vocab llama-family module> "
-            "and draft_params=... (the config's "
-            "speculation.draft_model preset name is for CLIs to "
-            "construct one)")
-    assert tp <= 1, (
-        "draft-model speculation does not compose with "
-        "tensor-parallel serving yet")
-    dmcfg = getattr(draft_model, "config", None)
-    assert (dataclasses.is_dataclass(dmcfg) and
-            hasattr(dmcfg, "rope_theta") and
-            hasattr(dmcfg, "paged_decode")), (
-        "draft model must be a llama-family model-zoo module "
-        "(the ragged paged decode path's requirement)")
-    assert dmcfg.vocab_size == target_cfg.vocab_size, (
-        f"draft vocab {dmcfg.vocab_size} != target vocab "
-        f"{target_cfg.vocab_size} — speculative verify compares token "
-        "ids, the models must share a tokenizer")
-    assert kv_cache_dtype in KV_CACHE_DTYPES, (
-        f"draft_kv_cache_dtype must be {'|'.join(KV_CACHE_DTYPES)}, "
-        f"got {kv_cache_dtype!r}")
-    from deepspeed_tpu.parallel import tensor_parallel as tp_lib
-
-    draft = type(draft_model)(serving_config(
-        dmcfg, max_seq_len=target_cfg.max_cache_len,
-        page_size=target_cfg.kv_page_size,
-        num_pages=target_cfg.kv_num_pages, tensor_parallel=False,
-        kv_cache_dtype=kv_cache_dtype))
-    dparams = normalize_params(
-        draft_model, draft_params,
-        plain_model=type(draft_model)(dataclasses.replace(
-            dmcfg, decode=False)))
-    if tp_lib.has_partitioning(dparams):
-        dparams = tp_lib.unbox_params(dparams)
-    return (draft, jax.device_put(dparams, device),
-            bool(getattr(dmcfg, "scan_layers", False)))
 
 
 def place_params(params, *, mesh=None, tp: int = 1, device=None):
@@ -586,210 +536,3 @@ def build_decode_block(model, *, sampled: bool, max_seqs: int, block: int,
 
     run.__name__ = run.__qualname__ = "ragged_decode_block"
     return jax.jit(run, donate_argnums=(1,))
-
-
-def build_draft_prefill(draft, *, dunroll: bool = False):
-    """ONE compiled chunked prefill program for the draft model's
-    paged KV catch-up (fixed [1, prefill_chunk] shape, one sequence
-    per call — entry-time cost, not steady-state)."""
-    def run(dparams, dcache, ids, positions, kv_lens, page_row, cu,
-            dest):
-        if dunroll:
-            dparams = unroll_scan_params(dparams)
-        meta = {"kv_lens": kv_lens, "page_indices": page_row,
-                "cu_q_lens": cu,
-                "num_seqs": jnp.asarray([1], jnp.int32),
-                "new_kv_dest": dest}
-        _, vars_ = draft.apply(
-            {"params": dparams, "cache": dcache}, ids,
-            positions=positions, mutable=["cache"], ragged_meta=meta)
-        return vars_["cache"]
-
-    run.__name__ = run.__qualname__ = "draft_prefill"
-    return jax.jit(run, donate_argnums=(1,))
-
-
-def build_spec_block(model, draft, *, sampled: bool, mode: str, k: int,
-                     ngram: int, max_seqs: int, block: int, page_size: int,
-                     pages_per_seq: int, max_seq_len: int,
-                     unroll: bool = False, wq: Optional[str] = None,
-                     native: bool = False, dunroll: bool = False):
-    """The fused draft+verify+accept block: ``block`` speculative ticks
-    per dispatch in a ``lax.scan``.  Each tick drafts ``k`` tokens
-    (``mode='ngram'``: lookup over the history buffer; ``'draft'``: a
-    k-step ``draft``-model sub-scan sharing the target's page table),
-    scores all k+1 positions with the TARGET in one ragged chunk per
-    slot, and accepts/rolls back device-side
-    (:func:`~deepspeed_tpu.inference.sampling.speculative_verify`).
-    Rollback is position rollback: rows written for rejected positions
-    sit between the new cursor and the old write frontier, a span the
-    NEXT tick's scatter fully overwrites before attention can reach
-    it."""
-    S, Tt, K1, n = max_seqs, block, k + 1, ngram
-    page, pp, max_len = page_size, pages_per_seq, max_seq_len
-
-    def ngram_propose(hist, pos, last_tok):
-        """Prompt-lookup drafting: most recent earlier occurrence of
-        the trailing n-gram proposes the k tokens that followed it;
-        no match proposes the last token repeated (any proposal is
-        distribution-safe — bad drafts are simply rejected)."""
-        L = max_len
-        ar = jnp.arange(L, dtype=jnp.int32)
-        histp = jnp.pad(hist, ((0, 0), (0, n + k)))
-        tpos = jnp.clip(pos[:, None] - (n - 1) +
-                        jnp.arange(n, dtype=jnp.int32)[None, :],
-                        0, L - 1)
-        tail = jnp.take_along_axis(hist, tpos, axis=1)      # [S, n]
-        match = jnp.ones((S, L), bool)
-        for t in range(n):
-            match = match & (histp[:, t:t + L] == tail[:, t:t + 1])
-        valid = ar[None, :] <= (pos[:, None] - n)
-        score = jnp.where(match & valid, ar[None, :] + 1, 0)
-        best = jnp.argmax(score, axis=1)       # most recent match
-        found = jnp.any(match & valid, axis=1)
-        cidx = best[:, None] + n + jnp.arange(k,
-                                              dtype=jnp.int32)[None, :]
-        cand = jnp.take_along_axis(histp, cidx, axis=1)     # [S, k]
-        fb = jnp.broadcast_to(last_tok[:, None], (S, k))
-        return jnp.where(found[:, None], cand, fb).astype(jnp.int32)
-
-    def run(params, dparams, cache, dcache, hist, last_tok, pos,
-            active, remaining, page_table, eos_ids, do_sample,
-            temperature, top_k, top_p, rng):
-        if wq:
-            params = dequantize_param_tree(params, native_w8a8=native)
-        if unroll:
-            params = unroll_scan_params(params)
-        if mode == "draft" and dunroll:
-            dparams = unroll_scan_params(dparams)
-        rows = jnp.arange(S)
-
-        def draft_propose(dcache, last_tok, pos, active, key):
-            def dstep(carry, key_j):
-                dcache, cur, dpos = carry
-                dvalid = active & (dpos < max_len)
-                dp = jnp.take_along_axis(
-                    jnp.maximum(page_table, 0),
-                    jnp.minimum(dpos // page, pp - 1)[:, None],
-                    axis=1)[:, 0]
-                ddest = jnp.where(dvalid, dp * page + dpos % page, 0)
-                dmeta = {"kv_lens": jnp.where(active, dpos + 1, 1),
-                         "page_indices": page_table,
-                         "cu_q_lens": jnp.arange(S + 1,
-                                                 dtype=jnp.int32),
-                         "num_seqs": jnp.asarray([S], jnp.int32),
-                         "new_kv_dest": ddest}
-                dout, dvars = draft.apply(
-                    {"params": dparams, "cache": dcache}, cur[None],
-                    positions=jnp.where(dvalid, dpos, 0)[None],
-                    mutable=["cache"], ragged_meta=dmeta)
-                dlg = logits_of(dout)[0].astype(jnp.float32)
-                dgreedy = jnp.argmax(dlg, axis=-1).astype(jnp.int32)
-                if sampled:
-                    flg = filter_logits_batched(dlg, temperature,
-                                                top_k, top_p)
-                    qj = jax.nn.softmax(flg, axis=-1)
-                    samp = jax.random.categorical(
-                        key_j, flg, axis=-1).astype(jnp.int32)
-                    nxt = jnp.where(do_sample, samp, dgreedy)
-                    out = (nxt, qj)
-                else:
-                    nxt = dgreedy
-                    out = (nxt,)
-                return (dvars["cache"], nxt, dpos + 1), out
-
-            keys = jax.random.split(key, k)
-            (dcache, _, _), outs = jax.lax.scan(
-                dstep, (dcache, last_tok, pos), keys)
-            d_toks = outs[0].T                              # [S, k]
-            q_probs = (outs[1].transpose(1, 0, 2) if sampled
-                       else None)
-            return dcache, d_toks, q_probs
-
-        def tick(carry, _):
-            (cache, dcache, hist, last_tok, pos, active, remaining,
-             rng, prop, accd) = carry
-            rng, key_d, key_v = jax.random.split(rng, 3)
-            # ---- draft k proposals per slot ----
-            if mode == "ngram":
-                # the cursor token joins the history before matching
-                hist = hist.at[rows, jnp.where(active, pos,
-                                               max_len)].set(
-                    last_tok, mode="drop")
-                d_toks = ngram_propose(hist, pos, last_tok)
-                q_probs = None
-            else:
-                dcache, d_toks, q_probs = draft_propose(
-                    dcache, last_tok, pos, active, key_d)
-            # ---- verify: one ragged chunk of k+1 rows per slot ----
-            chunk = jnp.concatenate([last_tok[:, None], d_toks],
-                                    axis=1)                 # [S, K1]
-            cpos = (pos[:, None] +
-                    jnp.arange(K1, dtype=jnp.int32)[None, :])
-            valid = active[:, None] & (cpos < max_len)
-            dest_page = jnp.take_along_axis(
-                jnp.maximum(page_table, 0),
-                jnp.minimum(cpos // page, pp - 1), axis=1)
-            dest = jnp.where(valid,
-                             dest_page * page + cpos % page, 0)
-            meta = {"kv_lens": jnp.where(active, pos + K1, 1),
-                    "page_indices": page_table,
-                    "cu_q_lens": jnp.arange(
-                        S + 1, dtype=jnp.int32) * K1,
-                    "num_seqs": jnp.asarray([S], jnp.int32),
-                    "new_kv_dest": dest.reshape(-1)}
-            out, vars_ = model.apply(
-                {"params": params, "cache": cache},
-                chunk.reshape(1, -1),
-                positions=jnp.where(valid, cpos, 0).reshape(1, -1),
-                mutable=["cache"], ragged_meta=meta)
-            cache = vars_["cache"]
-            logits = logits_of(out)[0].reshape(S, K1, -1)
-            with jax.named_scope("sample"):
-                out_toks, acc = speculative_verify(
-                    logits, d_toks, q_probs, key_v if sampled else None,
-                    do_sample, temperature, top_k, top_p)
-            # ---- emission clamp: budget, max_len, first eos ----
-            emit = jnp.minimum(acc + 1, remaining)
-            emit = jnp.minimum(emit, max_len - 1 - pos)
-            eos_hit = out_toks == eos_ids[:, None]
-            first_eos = jnp.argmax(eos_hit, axis=1)
-            emit = jnp.where(jnp.any(eos_hit, axis=1),
-                             jnp.minimum(emit, first_eos + 1), emit)
-            emit = jnp.where(active, jnp.clip(emit, 1, K1), 0)
-            j = jnp.arange(K1, dtype=jnp.int32)[None, :]
-            mask_out = active[:, None] & (j < emit[:, None])
-            # ---- advance / roll back the carry ----
-            new_last = jnp.take_along_axis(
-                out_toks, jnp.maximum(emit - 1, 0)[:, None],
-                axis=1)[:, 0]
-            ended_eos = jnp.any(eos_hit & (j < emit[:, None]),
-                                axis=1)
-            if mode == "ngram":
-                hidx = jnp.where(mask_out, pos[:, None] + 1 + j,
-                                 max_len)
-                hist = hist.at[rows[:, None], hidx].set(
-                    out_toks, mode="drop")
-            last_tok = jnp.where(active, new_last, last_tok)
-            pos = jnp.where(active, pos + emit, pos)
-            remaining = remaining - emit
-            prop = prop + jnp.sum(jnp.where(active, k, 0))
-            accd = accd + jnp.sum(jnp.where(active, emit - 1, 0))
-            active = (active & ~ended_eos & (remaining > 0) &
-                      (pos + 1 < max_len))
-            return (cache, dcache, hist, last_tok, pos, active,
-                    remaining, rng, prop, accd), (out_toks, mask_out)
-
-        carry0 = (cache, dcache, hist, last_tok, pos, active,
-                  remaining, rng, jnp.int32(0), jnp.int32(0))
-        carry, (toks, mask) = jax.lax.scan(tick, carry0, length=Tt)
-        (cache, dcache, hist, last_tok, pos, active, remaining, _,
-         prop, accd) = carry
-        # tick-major emission order, [Tt*K1, S] — _fold_block's shape
-        toks = toks.transpose(0, 2, 1).reshape(Tt * K1, S)
-        mask = mask.transpose(0, 2, 1).reshape(Tt * K1, S)
-        return (cache, dcache, hist, last_tok, pos, active,
-                remaining, toks, mask, prop, accd)
-
-    run.__name__ = run.__qualname__ = "spec_verify_block"
-    return jax.jit(run, donate_argnums=(2, 3, 4))

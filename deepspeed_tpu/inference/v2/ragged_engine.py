@@ -19,12 +19,11 @@ host, as the reference's scheduler tier is.  Two neighbours hold the rest:
   ONE fused SplitFuse tick (``engine_v2.py:107`` — a static ``[1, T]``
   batch carries a decode token for EVERY ready sequence AND this tick's
   prefill chunks; raggedness lives in int32 metadata, so exactly one XLA
-  program compiles), the on-device multi-tick decode block (everyone past
-  prefill: ``decode_block_size`` ticks in a ``lax.scan`` with on-device
-  sampling, the host round trip amortized to 1/K), and the speculative
-  draft + verify + accept block.  Attention is the vLLM-TPU ragged paged
-  Pallas kernel on TPU and an XLA-compilable reference on CPU
-  (``inference/paged/``).
+  program compiles) and the on-device multi-tick decode block (everyone
+  past prefill: ``decode_block_size`` ticks in a ``lax.scan`` with
+  on-device sampling, the host round trip amortized to 1/K).  Attention is
+  the vLLM-TPU ragged paged Pallas kernel on TPU and an XLA-compilable
+  reference on CPU (``inference/paged/``).
 
 **Tensor-parallel serving**: pass a ``topology`` with a >1 ``tensor``
 axis — weights shard by AutoTP name rules, the paged KV pool shards over
@@ -70,12 +69,10 @@ queue of dispatched, un-folded work.
   reap gives back, which it reads off its own state, and says why in
   ``host_stats.drain_reasons``: a live row with an EOS id (``eos``: its
   finish is the token's to say); a handoff prefill (``handoff``: parked
-  the step its token lands); the speculative block, whose advance is
-  data-dependent (``spec``: it drains at every harvest and at its entry);
-  a plan short of pages, an eviction, a spill, or a finish in flight while
-  a request waits that what is free before it cannot seat (``pages``); a
-  sequence that ticks outside the batch (``lc``); ``cancel``, ``sync``,
-  ``drain``, ``export_handoff`` / ``export_parked`` (``api``).  Where
+  the step its token lands); a plan short of pages, an eviction, a spill,
+  or a finish in flight while a request waits that what is free before it
+  cannot seat (``pages``); ``cancel``, ``sync``, ``drain``,
+  ``export_handoff`` / ``export_parked`` (``api``).  Where
   nothing is left to run, folding the tail is the end of the work and
   counts as no drain.  ``has_work()`` is true while anything is queued.
 
@@ -93,22 +90,6 @@ host path into plan/upload/dispatch/device/harvest per dispatch, and
 counts ``drains``, ``fused_ticks`` / ``decode_blocks`` / ``admissions``
 and, of each, those that went out with work still in flight (``*_ahead``).
 
-**Speculative decoding** (``speculation.mode != off``) rides the same
-path: each decode-block tick drafts ``k`` tokens per slot (``ngram``:
-prompt lookup over a device-resident token history; ``draft``: a small
-same-vocab family member against the SAME page table, its pool its own)
-and the target scores all ``k+1`` positions in ONE ragged dispatch.
-Greedy slots emit the target's argmax everywhere, so speculative greedy
-output is bit-identical to non-speculative decode; sampled slots use
-rejection sampling with residual resampling
-(:func:`~deepspeed_tpu.inference.sampling.speculative_verify`), so the
-output distribution equals the non-speculative one.  Rollback is pure
-position rollback: KV rows written for rejected positions are overwritten
-by the next block before any query can attend to them, and the pages stay
-owned.  The host projects per-slot advance as ``[1, k+1]``-per-tick BOUNDS
-instead of exact counts, grows pages to the worst case, and drains
-whenever a finish is possible under the fast bound.
-
 Models: anything llama-shaped in the zoo (Llama, Mistral, Qwen2, Mixtral,
 OLMoE, ... — per-token positions thread through attention, which the
 ragged path requires).
@@ -122,7 +103,6 @@ from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.common import HostStageStats
@@ -134,7 +114,6 @@ from deepspeed_tpu.inference.paged import (TickShape, describe,
                                            kv_dequant_path,
                                            paged_attention_path, pages_for)
 from deepspeed_tpu.inference.prefix_cache import ROOT_HASH
-from deepspeed_tpu.inference.sampling import position_keys, sample_logits
 from deepspeed_tpu.inference.v2 import programs
 from deepspeed_tpu.inference.v2.kv_state import KVState
 from deepspeed_tpu.utils.logging import log_dist
@@ -175,13 +154,6 @@ class Request:
     pc_parent: int = ROOT_HASH
     pc_pages: int = 0
     pc_cached: int = 0
-    # partial residency (kv_tiering.long_context): the sequence's KV
-    # exceeds the HBM pool, so only sinks + the recent window stay
-    # resident and LongContextDriver ticks it outside the fused batch.
-    # lc_parked counts middle page GROUPS demoted to the tiers under
-    # "mid-<uid>-<g>" keys (always a contiguous prefix of the middle)
-    lc: bool = False
-    lc_parked: int = 0
     # disaggregated serving: the router marked this request for a
     # prefill->decode handoff — the engine runs its prefill, lets the
     # first token land (it is sampled in the same fused tick the last
@@ -223,8 +195,7 @@ class _Work:
     seq: int                              # its key in the run-ahead window
     block: bool                           # a decode block; else a fused tick
     # a tick: device [max_seqs] tokens by packed sequence; a block: ([K, S]
-    # tokens, [K, S] produced-mask), and (proposed, accepted) behind them
-    # if it speculates
+    # tokens, [K, S] produced-mask)
     toks: Any
     # who gets tokens of it: (request, packed sequence number) of a tick's
     # sampling rows, (request, tokens it added to ``pending``) of a block's
@@ -259,9 +230,6 @@ class RaggedInferenceEngineV2:
                  pipeline: Optional[bool] = None,
                  async_depth: Optional[int] = None,
                  harvest_interval: Optional[int] = None,
-                 speculation: Any = None,
-                 draft_model=None, draft_params: Any = None,
-                 draft_kv_cache_dtype: Optional[str] = None,
                  kv_tiering: Any = None,
                  prefix_cache: Any = None,
                  slo: Any = None,
@@ -269,13 +237,13 @@ class RaggedInferenceEngineV2:
                  replica: Optional[str] = None,
                  control: Any = None,
                  config: Any = None):
-        """The ten options that ``config`` (a ``DeepSpeedInferenceConfig``
+        """The nine options that ``config`` (a ``DeepSpeedInferenceConfig``
         or dict with a ``v2`` subtree) also carries — ``pipeline``,
         ``async_depth``, ``harvest_interval``, ``kv_cache_dtype``,
-        ``speculation``, ``kv_tiering``, ``prefix_cache``, ``control``,
-        ``slo``, ``trace_sample`` — default to None: the config decides
+        ``kv_tiering``, ``prefix_cache``, ``control``, ``slo``,
+        ``trace_sample`` — default to None: the config decides
         (:class:`~deepspeed_tpu.inference.config.InferenceV2Config`
-        documents each) and an explicit keyword wins.  The four subtrees
+        documents each) and an explicit keyword wins.  The three subtrees
         take what their class's ``coerce`` takes.
 
         ``kv_pool_bytes``: size the pool by a device byte budget —
@@ -291,9 +259,6 @@ class RaggedInferenceEngineV2:
         admit on prompt-size pages, grow per decode block, evict +
         requeue as a continuation when the pool runs dry) or
         "worst_case" (reserve prompt + max_new_tokens at admission).
-        ``speculation`` with ``mode="draft"`` needs ``draft_model`` (a
-        small same-vocab llama-family zoo module) and ``draft_params``;
-        ``draft_kv_cache_dtype`` defaults to the target pool's format.
         ``slo``: objective strings (``"ttft_ms_p99 <= 150"``) or an
         :class:`~deepspeed_tpu.telemetry.slo.SLOSet`, fed at reap time.
         ``replica``: metric-label identity for scale-out serving."""
@@ -310,7 +275,6 @@ class RaggedInferenceEngineV2:
         from deepspeed_tpu.inference.config import (ControlConfig,
                                                     KVTieringConfig,
                                                     PrefixCacheConfig,
-                                                    SpeculationConfig,
                                                     load_inference_config)
 
         # a ONE-device topology is a replica slice: this engine's params,
@@ -336,7 +300,7 @@ class RaggedInferenceEngineV2:
         # kv_cache_dtype decides the per-page byte cost a kv_pool_bytes
         # budget divides by (kwarg > config > default)
         o = dict(pipeline=pipeline, async_depth=async_depth,
-                 harvest_interval=harvest_interval, speculation=speculation,
+                 harvest_interval=harvest_interval,
                  kv_tiering=kv_tiering, prefix_cache=prefix_cache,
                  kv_cache_dtype=kv_cache_dtype, slo=slo,
                  trace_sample=trace_sample, control=control)
@@ -458,38 +422,11 @@ class RaggedInferenceEngineV2:
         # block follows block (None: the next block builds them anew)
         self._dev: Optional[Dict[str, Any]] = None
 
-        # -- speculative decoding (programs.build_spec_block) --
-        speculation = SpeculationConfig.coerce(o["speculation"])
-        self.spec_mode = speculation.mode
-        self.spec_k = int(speculation.k)
-        self.spec_ngram = int(speculation.ngram)
-        self._draft = None
-        self._draft_params: Any = {}
-        self._draft_unroll = False
-        # draft KV coverage per slot (positions < value hold draft K/V)
-        self._draft_len = np.zeros((max_seqs,), np.int64)
-        draft_cache = None
-        if self.spec_mode == "draft":
-            # the draft pool defaults to the target pool's storage
-            # format — self-draft speculation gets the same capacity
-            # win unless the caller overrides draft_kv_cache_dtype
-            self.draft_kv_cache_dtype = (
-                kv_cache_dtype if draft_kv_cache_dtype is None
-                else str(draft_kv_cache_dtype))
-            self._draft, self._draft_params, self._draft_unroll = \
-                programs.prepare_draft(
-                    draft_model, draft_params, self.cfg,
-                    kv_cache_dtype=self.draft_kv_cache_dtype, tp=self.tp,
-                    device=self._device)
-            draft_cache = programs.init_cache(
-                self._draft, max_seqs=max_seqs, width=self.T, **placement)
-        # the built programs, cached: one fused tick, one draft prefill,
-        # a greedy and a sampled variant of each block
+        # the built programs, cached: the fused tick with its logits, and a
+        # greedy and a sampled variant of the sampling tick and of the block
         self._step_fn = None
         self._sample_step_cache: Dict[bool, Any] = {}
-        self._draft_prefill = None
         self._decode_block_cache: Dict[bool, Any] = {}
-        self._spec_block_cache: Dict[bool, Any] = {}
         self._scoped: set = set()       # programs device_scopes knows
 
         # -- every form of a sequence's KV state: pool, tiers, index --
@@ -502,7 +439,7 @@ class RaggedInferenceEngineV2:
             kv_reserve=kv_reserve, tier_cfg=tier_cfg,
             pfx_cfg=PrefixCacheConfig.coerce(o["prefix_cache"]),
             host_stats=self.host_stats,
-            request_latency=self.request_latency, draft_cache=draft_cache,
+            request_latency=self.request_latency,
             window=None if own is None else own.host_pool(
                 self.window_num_pages, self.page_size, max_seqs,
                 self.pages_per_seq, self.T, self.host_stats))
@@ -544,7 +481,6 @@ class RaggedInferenceEngineV2:
         self._handoff_ready: List[Request] = []
         self.handoffs = 0              # sessions exported to a decoder
         self.handoff_folds = 0         # handoffs degraded to re-prefill
-        self._lc = None                # lazy LongContextDriver
         # queued spilled sequences prefetched ahead of a possible reap
         # (online-tunable via the kv.prefetch_lookahead knob)
         self.prefetch_lookahead = max(int(tier_cfg.prefetch_lookahead), 1)
@@ -557,8 +493,7 @@ class RaggedInferenceEngineV2:
             f"pages={self.num_pages}x{self.page_size} tp={self.tp} "
             f"decode_block={self.decode_block_size} "
             f"pipeline={self.pipeline} depth={self.async_depth} "
-            f"harvest={self.harvest_interval} spec={self.spec_mode}"
-            f"{f'/k={self.spec_k}' if self.spec_mode != 'off' else ''}"
+            f"harvest={self.harvest_interval}"
             f"{self.kv.describe()} "
             f"(paged KV, fused SplitFuse step)", ranks=[0])
 
@@ -591,7 +526,7 @@ class RaggedInferenceEngineV2:
                 prof = load_profile(control.profile)
                 if prof is not None:
                     # profile seeding runs pre-warmup, so recompiling
-                    # knobs (decode_block, spec k) are still fair game
+                    # knobs (decode_block) are still fair game
                     applied = knobs.apply_profile(prof.knobs)
                     if applied:
                         log_dist(
@@ -650,10 +585,6 @@ class RaggedInferenceEngineV2:
         max_new = int(kw.get("max_new_tokens", 64))
         self.validate_request(prompt, max_new)
         req = Request(uid=next(self._uid), prompt=prompt, **kw)
-        if self.kv.partially_resident(prompt.size + max_new):
-            # the full KV cannot be device-resident: decode under the
-            # windowed partial-residency policy (LongContextDriver)
-            req.lc = True
         self.waiting.append(req)
         self.request_latency.on_submit(req.uid, int(prompt.size))
         if trace.enabled:
@@ -708,12 +639,12 @@ class RaggedInferenceEngineV2:
         resource it holds: slot + pool pages (mid-prefill or
         mid-decode, including inside a pipelined decode carry), tiered
         spill payloads and their shared-prefix spill-holds (parked
-        requests), LC middle-group parkings, and per-slot draft state.
-        ``audit_kv_sharing()`` stays clean across any interleaving —
-        the front door's client-disconnect path depends on it.
+        requests).  ``audit_kv_sharing()`` stays clean across any
+        interleaving — the front door's client-disconnect path depends
+        on it.
 
         Returns the stage the request was cancelled at (``"queued"`` /
-        ``"spilled"`` / ``"prefill"`` / ``"decode"`` / ``"lc"`` /
+        ``"spilled"`` / ``"handoff"`` / ``"prefill"`` / ``"decode"`` /
         ``"finished"``) or ``None`` for an unknown uid (never
         submitted, or already collected)."""
         stage: Optional[str] = None
@@ -737,15 +668,12 @@ class RaggedInferenceEngineV2:
                          else "queued")
                 self.kv.forget(r)
         if stage is None:
-            # resident in a slot (prefill or decode phase; LC sequences
-            # tick outside the fused batch but park in slots the same)
+            # resident in a slot (prefill or decode phase)
             for r in self.slots:
                 if r is None or r.uid != uid:
                     continue
-                stage = ("lc" if r.lc
-                         else "prefill" if r.prefill_done < r.ctx_len
+                stage = ("prefill" if r.prefill_done < r.ctx_len
                          else "decode")
-                self.kv.forget(r)
                 self._unseat(r)
                 break
         if stage is None:
@@ -921,8 +849,8 @@ class RaggedInferenceEngineV2:
         Online-safe knobs: ``harvest_interval`` is read fresh after each
         dispatch; ``async_depth`` re-sizes the run-ahead window in place
         (the substrate back-pressures on the next submit).
-        ``decode_block_size`` and ``spec_k`` are baked into compiled
-        block shapes, so they carry ``recompiles=True`` — reachable only
+        ``decode_block_size`` is baked into the compiled block's shape,
+        so it carries ``recompiles=True`` — reachable only
         by the offline sweep / profile seeding, never the online policy
         (the zero-new-compilations contract).  With tiering on, the
         tier store's prefetch toggle and IO-window depths ride along
@@ -962,17 +890,6 @@ class RaggedInferenceEngineV2:
             lambda: self.decode_block_size, _set_block,
             lo=1, hi=16, step=1, kind="int", recompiles=True,
             doc="device ticks per decode block (compiled shape)"))
-        if self.spec_mode != "off":
-            def _set_spec_k(v):
-                self.spec_k = max(int(v), 1)
-                self._scoped.difference_update(
-                    self._spec_block_cache.values())
-                self._spec_block_cache.clear()
-
-            reg.register(Knob(
-                "engine.spec_k", lambda: self.spec_k, _set_spec_k,
-                lo=1, hi=8, step=1, kind="int", recompiles=True,
-                doc="speculative draft length (compiled shape)"))
         if self.tiering is not None:
             t = self.tiering
 
@@ -1001,20 +918,11 @@ class RaggedInferenceEngineV2:
             def _set_lookahead(v):
                 self.prefetch_lookahead = max(int(v), 1)
 
-            def _set_window(v):
-                self.kv.tier_cfg.window_pages = max(int(v), 1)
-
             reg.register(Knob(
                 "kv.prefetch_lookahead",
                 lambda: self.prefetch_lookahead, _set_lookahead,
                 lo=1, hi=64, step=1, kind="int",
                 doc="queued spilled sequences prefetched ahead of reap"))
-            reg.register(Knob(
-                "kv.window_pages",
-                lambda: int(self.kv.tier_cfg.window_pages), _set_window,
-                lo=1, hi=64, step=1, kind="int",
-                doc="recent HBM-resident pages per partially-resident "
-                    "sequence (long-context residency window)"))
         return reg
 
     def _refuse_features(self, o: Dict[str, Any], kv_cache_dtype: str,
@@ -1025,18 +933,13 @@ class RaggedInferenceEngineV2:
         keeps; why that feature cannot carry it); ``o``: the resolved
         options."""
         from deepspeed_tpu.inference.config import (KVTieringConfig,
-                                                    PrefixCacheConfig,
-                                                    SpeculationConfig)
+                                                    PrefixCacheConfig)
 
-        spec = SpeculationConfig.coerce(o["speculation"])
-        tier = KVTieringConfig.coerce(o["kv_tiering"])
         asked = {       # feature: (asked for, as the message names it)
             "prefix_cache": (PrefixCacheConfig.coerce(
                 o["prefix_cache"]).enabled, "prefix_cache"),
-            "speculation": (spec.mode != "off",
-                            f"speculation.mode={spec.mode!r}"),
-            "long_context": (tier.long_context, "kv_tiering.long_context"),
-            "tiering": (tier.enabled, "kv_tiering"),
+            "tiering": (KVTieringConfig.coerce(o["kv_tiering"]).enabled,
+                        "kv_tiering"),
             "quantized": (kv_cache_dtype != "none",
                           f"kv_cache_dtype={kv_cache_dtype!r}"),
             "tensor_parallel": (self.tp > 1, "tensor-parallel serving")}
@@ -1330,141 +1233,6 @@ class RaggedInferenceEngineV2:
                 self.request_latency.on_tokens(r.uid, len(r.generated))
         return produced
 
-    # -- the speculative decode block (round-6 tentpole) ------------------
-
-    def _spec_grow_want(self, plen: int, rem: int) -> int:
-        """Token coverage one speculative block needs for a slot at
-        cache length ``plen`` with ``rem`` budget left: each of the
-        block's ticks WRITES k+1 KV rows ahead of the cursor regardless
-        of how many tokens are accepted, so pages must cover the
-        worst-case span (writes past ``max_seq_len`` route to the trash
-        page and need no backing)."""
-        K1 = self.spec_k + 1
-        ticks = min(self.decode_block_size, max(int(rem), 1))
-        return int(min(plen + ticks * K1, self.max_seq_len))
-
-    def _hist_array(self, reqs: List[Request]) -> np.ndarray:
-        """Host build of the device token-history buffer [S, max_len]:
-        ``hist[s, i]`` = the sequence's token at cache position ``i``
-        (prompt + generated — exact in the decode phase).  The n-gram
-        drafter matches/continues against it; rebuilt from host state at
-        every pipeline entry/harvest re-anchor."""
-        hist = np.zeros((self.max_seqs, self.max_seq_len), np.int32)
-        if self.spec_mode == "ngram":
-            for r in reqs:
-                seq = np.concatenate(
-                    [r.prompt, np.asarray(r.generated, np.int32)])
-                L = min(r.length, self.max_seq_len)
-                hist[r.slot, :L] = seq[:L]
-        return hist
-
-    def _draft_catchup(self, reqs: List[Request]) -> None:
-        """Bring the draft model's paged KV up to each slot's cursor
-        (positions ``< length - 1``; the drafter itself processes the
-        cursor token).  No-op for slots already covered — inside a
-        decode phase the speculative block keeps draft KV in sync by
-        construction, so this only runs at admission/re-admission."""
-        if self.spec_mode != "draft":
-            return
-        st = self.host_stats
-        C = self.prefill_chunk
-        page = self.page_size
-        with st.stage("draft"):
-            if self._draft_prefill is None:
-                self._draft_prefill = programs.build_draft_prefill(
-                    self._draft, dunroll=self._draft_unroll)
-            fn = self._draft_prefill
-            for r in reqs:
-                target = r.length - 1
-                lo = int(self._draft_len[r.slot])
-                if lo >= target:
-                    continue
-                seq = np.concatenate(
-                    [r.prompt, np.asarray(r.generated, np.int32)])
-                while lo < target:
-                    take = min(C, target - lo)
-                    ids = np.zeros((C,), np.int32)
-                    ids[:take] = seq[lo:lo + take]
-                    pos = np.arange(lo, lo + C, dtype=np.int32)
-                    posc = np.minimum(pos, self.max_seq_len - 1)
-                    pg = self.page_table[
-                        r.slot, np.minimum(pos // page,
-                                           self.pages_per_seq - 1)]
-                    dest = np.where(
-                        np.arange(C) < take,
-                        np.maximum(pg, 0) * page + pos % page,
-                        0).astype(np.int32)
-                    st.meta_uploads += 6
-                    self.kv.draft_cache = self._run(
-                        fn, self._draft_params, self.kv.draft_cache,
-                        jnp.asarray(ids[None]), jnp.asarray(posc[None]),
-                        jnp.asarray([lo + take], jnp.int32),
-                        jnp.asarray(self.page_table[r.slot][None]),
-                        jnp.asarray([0, take], jnp.int32),
-                        jnp.asarray(dest))
-                    lo += take
-                self._draft_len[r.slot] = target
-
-    def spec_block(self, sampled: bool):
-        """The fused draft + verify + accept block
-        (:func:`~deepspeed_tpu.inference.v2.programs.build_spec_block`)."""
-        if sampled not in self._spec_block_cache:
-            self._spec_block_cache[sampled] = programs.build_spec_block(
-                self.model, self._draft, sampled=sampled,
-                mode=self.spec_mode, k=self.spec_k, ngram=self.spec_ngram,
-                max_seqs=self.max_seqs, block=self.decode_block_size,
-                page_size=self.page_size, pages_per_seq=self.pages_per_seq,
-                max_seq_len=self.max_seq_len, dunroll=self._draft_unroll,
-                **self.param_form)
-        return self._spec_block_cache[sampled]
-
-    def _step_spec_block(self, reqs: List[Request]) -> int:
-        """One speculative block, unpipelined (fresh uploads + one
-        blocking harvest per dispatch) — the ``pipeline=False`` spec
-        reference path."""
-        st = self.host_stats
-        with st.stage("plan"):
-            # the spec block keeps the global rng stream (its rejection-
-            # sampling keys are not position-keyed), so seeds is unused
-            (last_tok, pos, active, remaining, eos_ids, do_sample,
-             temperature, top_k, top_p, _seeds) = self._block_arrays(reqs)
-            sampled = bool(do_sample.any())
-            hist = self._hist_array(reqs)
-        self._draft_catchup(reqs)
-        sub = self._split_rng()
-        args = self.upload_all(
-            (hist, last_tok, pos, active, remaining, self.page_table,
-             eos_ids, do_sample, temperature, top_k, top_p))
-        if trace.enabled:
-            trace.event("decode_block", cat="request",
-                        uids=[r.uid for r in reqs],
-                        ticks=self.decode_block_size, spec=True)
-        with st.stage("verify"):
-            st.dispatched("spec")
-            st.spec_dispatches += 1
-            (self.kv.cache, self.kv.draft_cache, _h, new_last, _p, _a, _r,
-             toks, mask, prop, accd) = self._run(
-                self.spec_block(sampled),
-                self.params, self._draft_params, self.kv.cache,
-                self.kv.draft_cache, *args, sub)
-        st.ticks += self.decode_block_size
-        toks, mask, new_last, prop, accd = self.fetch(
-            (toks, mask, new_last, prop, accd))
-        st.harvests += 1
-        with st.stage("harvest"):
-            self.last_tokens = np.array(new_last)
-            produced = self._fold_block(reqs, np.asarray(toks),
-                                        np.asarray(mask))
-            st.spec_proposed += int(prop)
-            st.spec_accepted += int(accd)
-            st.spec_tokens += produced
-            for r in reqs:
-                self._maybe_finish(r)
-                if not r.done:
-                    self._draft_len[r.slot] = r.length - 1
-            self._reap()
-        return produced
-
     def _step_decode_block(self, reqs: List[Request]) -> int:
         """Run one on-device decode block and fold results back into the
         host request state (the ``pipeline=False`` path: fresh metadata
@@ -1614,10 +1382,7 @@ class RaggedInferenceEngineV2:
             st = self.host_stats
             st.drains += 1
             st.drain_reasons[reason] = st.drain_reasons.get(reason, 0) + 1
-        produced = self._fold(len(self._queue))
-        if self._dev is not None and self._dev["spec"]:
-            self._spec_settle()
-        return produced
+        return self._fold(len(self._queue))
 
     def _fold(self, n: int) -> int:
         """Take the ``n`` oldest entries off the queue, bring their tokens
@@ -1655,34 +1420,13 @@ class RaggedInferenceEngineV2:
         return len(w.rows)
 
     def _fold_block_work(self, w: _Work, fetched) -> int:
-        toks, mask, *spec = fetched
+        toks, mask = fetched
         produced = self._fold_block([r for r, _ in w.rows],
                                     np.asarray(toks), np.asarray(mask))
         for r, n in w.rows:
             r.pending -= n
             self._maybe_finish(r)
-        if spec:
-            st = self.host_stats
-            st.spec_proposed += int(spec[0])
-            st.spec_accepted += int(spec[1])
-            st.spec_tokens += produced
         return produced
-
-    def _spec_settle(self) -> None:
-        """After a drain under a speculative carry: a finish tears the
-        carry down (the fused path re-plans); else the device carry stays
-        authoritative and the host's fast/slow bounds collapse to the
-        now-exact lengths."""
-        dv = self._dev
-        if any(r.done for r in dv["reqs"]):
-            self._dev = None
-            return
-        for r in dv["reqs"]:
-            self._draft_len[r.slot] = max(r.length - 1, 0)
-            dv["plen"][r.slot] = r.length
-            dv["rem"][r.slot] = self._budget(r)
-        np.copyto(dv["plen_hi"], dv["plen"])
-        np.copyto(dv["rem_lo"], dv["rem"])
 
     def _table_now(self) -> np.ndarray:
         """The page table as the blocks about to be dispatched must see
@@ -1714,24 +1458,14 @@ class RaggedInferenceEngineV2:
         dv, self._dev = self._dev, None
         self._dev_last_tok = dv["last_tok"]
 
-    def _pipeline_start(self, reqs: List[Request],
-                        spec: bool = False) -> None:
+    def _pipeline_start(self, reqs: List[Request]) -> None:
         """Enter the pipelined decode loop: upload the decode-block
         carry and sampler metadata ONCE, built from counts (a token
         still on the device is in ``length`` and off the budget);
         subsequent blocks chain device-resident state (zero steady-state
         uploads).  With work in flight every row's newest token is on the
         device (the caller saw to it) and ``last_tok`` is what that work
-        hands on, not an upload.  With ``spec`` the speculative block
-        runs instead of the plain one, from exact host state, and the
-        host projects per-slot BOUNDS (advance is 1..k+1 per tick,
-        data-dependent): ``plen``/``rem`` hold the slow bound (1 token
-        per tick, the guaranteed floor), ``plen_hi``/``rem_lo`` the fast
-        bound (k+1 per tick); growth covers the fast bound's write span
-        and a drain is forced as soon as the fast bound says a finish is
-        POSSIBLE."""
-        if spec:
-            self._draft_catchup(reqs)
+        hands on, not an upload."""
         with self.host_stats.stage("plan"):
             (last_tok, pos, active, remaining, eos_ids, do_sample,
              temperature, top_k, top_p, seeds) = self._block_arrays(reqs)
@@ -1745,71 +1479,38 @@ class RaggedInferenceEngineV2:
         self._dev = {
             "reqs": list(reqs),
             "sampled": bool(do_sample.any()),
-            "spec": spec,
             "last_tok": self._dev_last_tok,
             **dict(zip(host, self.upload_all(list(host.values())))),
         }
-        if spec:
-            S = self.max_seqs
-            plen = np.zeros((S,), np.int64)
-            rem = np.zeros((S,), np.int64)
-            has_eos = np.zeros((S,), bool)
-            for r in reqs:
-                plen[r.slot] = r.length
-                rem[r.slot] = remaining[r.slot]
-                has_eos[r.slot] = r.eos_token_id is not None
-            self._dev.update(
-                hist=self.upload(self._hist_array(reqs)), plen=plen,
-                rem=rem, has_eos=has_eos, plen_hi=plen.copy(),
-                rem_lo=rem.copy())
-        else:
-            self._dev["seeds"] = self.upload(seeds)
+        self._dev["seeds"] = self.upload(seeds)
 
     def _pipeline_step(self, admit: bool = True) -> Optional[int]:
         """One more decode block, planned from counts and dispatched
         behind whatever is in flight; then fold what is due
         (:meth:`_settle`), or everything where the next plan needs it
         (:meth:`_drain`: a live row with an EOS id, a pool short of
-        pages, the speculative block).  Returns None where the step is
+        pages).  Returns None where the step is
         not a block's to take (a request became admittable, or no row
         goes on): the carry is released with the blocks left in the
         queue, and ``_step`` goes on along the fused path."""
         dv = self._dev
         st = self.host_stats
         K = self.decode_block_size
-        spec = dv["spec"]
-        K1 = self.spec_k + 1
-        if spec:
-            live = [r for r in dv["reqs"] if dv["rem"][r.slot] > 0 and
-                    dv["plen"][r.slot] < self.max_seq_len]
-        else:
-            # (the device's ``active`` carry retired a finished row by
-            # itself; it writes page 0 in the blocks that follow)
-            live = [r for r in dv["reqs"] if not r.done]
+        # (the device's ``active`` carry retired a finished row by
+        # itself; it writes page 0 in the blocks that follow)
+        live = [r for r in dv["reqs"] if not r.done]
         if not live or (admit and self._admittable()):
-            if spec:
-                # its host state is bounds: exact only once all is folded
-                produced = self._drain("spec")
-                self._dev = None
-                return produced
             self._release_carry()
             return None
         with st.stage("plan"):
-            # grow pages to cover the next block: exact for the plain
-            # block (every sequence that reaches this point un-folded
-            # runs to a count); for a speculative block the projection is
-            # the FAST bound, so growth covers the worst-case k+1-wide
-            # write span
+            # grow pages to cover the next block: exact (every sequence
+            # that reaches this point un-folded runs to a count)
             grow_ok, table_dirty = True, False
             for r in live:
                 s = r.slot
                 self._last_sched[s] = self._sched_seq
-                if spec:
-                    want = self._spec_grow_want(int(dv["plen_hi"][s]),
-                                                int(dv["rem"][s]))
-                else:
-                    want = min(r.length + min(K, self._budget(r)),
-                               self.max_seq_len)
+                want = min(r.length + min(K, self._budget(r)),
+                           self.max_seq_len)
                 before = self._owned(s)
                 if not self.kv.ensure(s, want, r.length - 1):
                     grow_ok = False
@@ -1828,66 +1529,33 @@ class RaggedInferenceEngineV2:
         if trace.enabled:
             trace.event("decode_block", cat="request",
                         uids=[r.uid for r in live],
-                        ticks=self.decode_block_size, pipelined=True,
-                        spec=bool(spec))
-        if spec:
-            with st.stage("verify"):
-                st.dispatched("spec")
-                st.spec_dispatches += 1
-                (self.kv.cache, self.kv.draft_cache, dv["hist"],
-                 dv["last_tok"], dv["pos"], dv["active"],
-                 dv["remaining"], toks, mask, prop,
-                 accd) = self._run(
-                    self.spec_block(dv["sampled"]),
-                    self.params, self._draft_params, self.kv.cache,
-                    self.kv.draft_cache, dv["hist"], dv["last_tok"],
-                    dv["pos"], dv["active"], dv["remaining"],
-                    dv["page_table"], dv["eos_ids"], dv["do_sample"],
-                    dv["temperature"], dv["top_k"], dv["top_p"], sub)
-            work = _Work(next(self._work_seq), True,
-                         (toks, mask, prop, accd),
-                         [(r, 0) for r in live], [])
-        else:
-            with self._decode_dispatch(live):
-                (self.kv.cache, dv["last_tok"], dv["pos"], dv["active"],
-                 dv["remaining"], toks, mask, moe,
-                 loop) = self._run(
-                    self.decode_block(dv["sampled"]),
-                    self.params, self.kv.cache, dv["last_tok"], dv["pos"],
-                    dv["active"], dv["remaining"], dv["page_table"],
-                    dv["eos_ids"], dv["do_sample"], dv["temperature"],
-                    dv["top_k"], dv["top_p"], dv["seeds"], sub)
-            work = _Work(next(self._work_seq), True, (toks, mask), [],
-                         self._count_entries(moe, loop, "decode_block", K,
-                                             len(live)))
+                        ticks=self.decode_block_size, pipelined=True)
+        with self._decode_dispatch(live):
+            (self.kv.cache, dv["last_tok"], dv["pos"], dv["active"],
+             dv["remaining"], toks, mask, moe,
+             loop) = self._run(
+                self.decode_block(dv["sampled"]),
+                self.params, self.kv.cache, dv["last_tok"], dv["pos"],
+                dv["active"], dv["remaining"], dv["page_table"],
+                dv["eos_ids"], dv["do_sample"], dv["temperature"],
+                dv["top_k"], dv["top_p"], dv["seeds"], sub)
+        work = _Work(next(self._work_seq), True, (toks, mask), [],
+                     self._count_entries(moe, loop, "decode_block", K,
+                                         len(live)))
         st.ticks += K
         with st.stage("plan"):
             # count this block's tokens in, and see who ends with it
             wait = False
             for r in live:
-                s = r.slot
-                if spec:
-                    # bounds: per tick a slot advances 1..k+1 tokens
-                    slow = int(max(0, min(K, dv["rem_lo"][s])))
-                    fast = int(min(K * K1, max(dv["rem"][s], 0)))
-                    dv["plen"][s] = min(dv["plen"][s] + slow,
-                                        self.max_seq_len)
-                    dv["plen_hi"][s] = min(dv["plen_hi"][s] + fast,
-                                           self.max_seq_len)
-                    dv["rem"][s] -= slow
-                    dv["rem_lo"][s] -= K * K1
-                    wait |= bool(dv["has_eos"][s] or dv["rem_lo"][s] <= 0
-                                 or dv["plen_hi"][s] >= self.max_seq_len)
-                else:
-                    # exact but for an EOS id, which may end the row on
-                    # any token: the fold takes back what it gave
-                    n = int(min(K, self._budget(r),
-                                self.max_seq_len - r.length))
-                    r.pending += n
-                    self._maybe_finish(r)
-                    work.rows.append((r, n))
-                    work.finishes |= r.done
-                    wait |= r.eos_token_id is not None
+                # exact but for an EOS id, which may end the row on any
+                # token: the fold takes back what it gave
+                n = int(min(K, self._budget(r),
+                            self.max_seq_len - r.length))
+                r.pending += n
+                self._maybe_finish(r)
+                work.rows.append((r, n))
+                work.finishes |= r.done
+                wait |= r.eos_token_id is not None
             if self.tiering is not None and (wait or work.finishes):
                 # a slot may free at the fold of this block: start
                 # NVMe->host reads for the spilled sequences the FIFO
@@ -1899,11 +1567,6 @@ class RaggedInferenceEngineV2:
                                       self.prefetch_lookahead)
                      if q.spilled is not None])
         self._enqueue(work)
-        if spec:
-            # the bounds are re-anchored on exact lengths only
-            if wait or len(self._queue) >= self.harvest_interval:
-                return self._drain("spec")
-            return 0
         if wait:
             return self._drain("eos")
         return self._settle()
@@ -1945,71 +1608,36 @@ class RaggedInferenceEngineV2:
             # an admission behind the blocks in flight, or no row goes on
         with st.stage("plan"):
             # park finished handoff prefills BEFORE admission: a
-            # handoff request never reaches the decode block / spec /
-            # pipeline paths — it leaves its slot the step after its
-            # first token lands
+            # handoff request never reaches the decode block / pipeline
+            # paths — it leaves its slot the step after its first token
+            # lands
             with st.leaf("plan.admit", waiting=len(self.waiting)) as leaf:
                 seated = st.admissions
                 self._handoff_sweep()
                 self._admit()
-                lc_live = [r for r in self.slots
-                           if r is not None and not r.done and r.lc]
                 live = [r for r in self.slots
-                        if r is not None and not r.done and not r.lc]
-                decoding_ready = (not lc_live and bool(live) and all(
-                    r.prefill_done >= r.ctx_len for r in live))
+                        if r is not None and not r.done]
+                decoding_ready = bool(live) and all(
+                    r.prefill_done >= r.ctx_len for r in live)
                 leaf.add(admitted=st.admissions - seated)
-        if self._queue and (lc_live or (decoding_ready and
-                                        self.spec_mode != "off")):
-            # the long-context driver and the speculative block (its
-            # token history, the draft's catch-up) start from the host's
-            # tokens: fold everything, and end the step there
-            return produced + self._drain("lc" if lc_live else "spec")
         if self._queue and decoding_ready:
             # the blocks' carry takes every row's newest token from the
             # device: a row whose token only the host has (restored from
             # a spill) goes through one more tick first
             decoding_ready = all(r.pending for r in live)
         with st.stage("plan"), st.leaf("plan.grow", rows=len(live)):
-            # speculation first: its block writes a k+1-wide span per
-            # tick, so it needs more page coverage than a plain block —
-            # when the pool can't back it, degrade to the plain decode
-            # block (greedy outputs are unchanged either way)
-            spec_block = (decoding_ready and self.spec_mode != "off" and
-                          all(self.kv.ensure(
-                              r.slot,
-                              self._spec_grow_want(r.length,
-                                                   self._budget(r)))
-                              for r in live))
             all_decoding = (
-                not spec_block and decoding_ready and
-                self.decode_block_size > 1 and
+                decoding_ready and self.decode_block_size > 1 and
                 all(self.kv.ensure(
                     r.slot,
                     r.length + min(self.decode_block_size,
                                    self._budget(r)), r.length - 1)
                     for r in live))
-        if spec_block:
-            if self.pipeline:
-                self._pipeline_start(live, spec=True)
-                return produced + self._pipeline_step(admit=False)
-            return produced + self._step_spec_block(live)
         if all_decoding:
             if self.pipeline:
                 self._pipeline_start(live)
                 return produced + self._pipeline_step(admit=False)
             return produced + self._step_decode_block(live)
-        # partially-resident sequences tick through the chunked-scan
-        # driver — never the fused batch, the decode block, or the
-        # pipeline (decoding_ready is gated off while any is live, so
-        # the pipeline cannot start and orphan them)
-        if lc_live:
-            if self._lc is None:
-                from deepspeed_tpu.inference.v2.long_context import \
-                    LongContextDriver
-                self._lc = LongContextDriver(self)
-            for r in lc_live:
-                produced += self._lc.tick(r)
         with st.stage("plan"):
             takes = self._plan_rows()
         if self._queue and (takes is None or self._page_limited):
@@ -2083,10 +1711,10 @@ class RaggedInferenceEngineV2:
         if not self.pipeline:
             return produced + self._drain(None)
         # the next plan is exact without this tick's tokens where nothing
-        # they could say changes it: no sequence short of pages or ticking
-        # outside the batch, no handoff parked the step its token lands,
-        # no end by an EOS id; else they are waited for now, as ever
-        why = ("pages" if self._page_limited else "lc" if lc_live else
+        # they could say changes it: no sequence short of pages, no handoff
+        # parked the step its token lands, no end by an EOS id; else they
+        # are waited for now, as ever
+        why = ("pages" if self._page_limited else
                "handoff" if any(r.handoff for r, _ in sampling) else
                "eos" if any(r.eos_token_id is not None
                             for r, _ in sampling) else None)
@@ -2154,13 +1782,11 @@ class RaggedInferenceEngineV2:
 
     def _unseat(self, r: Request, fold: bool = False) -> None:
         """Give ``r``'s slot back — the ONE place on the scheduler's
-        side: its pages go through ``KVState.release``, the draft
-        model's coverage resets, the slot empties and the request
-        forgets it.  ``fold``: its rows are lost, so it becomes a
-        re-prefill continuation (a spilled, cancelled or finished
-        request keeps its cursors)."""
+        side: its pages go through ``KVState.release``, the slot empties
+        and the request forgets it.  ``fold``: its rows are lost, so it
+        becomes a re-prefill continuation (a spilled, cancelled or
+        finished request keeps its cursors)."""
         self.kv.release(r.slot)
-        self._draft_len[r.slot] = 0
         self.slots[r.slot] = None
         r.slot = -1
         if fold:
@@ -2175,7 +1801,7 @@ class RaggedInferenceEngineV2:
         sequence leaves its slot and waits in ``_handoff_ready`` for
         the router's ``export_handoff`` pull."""
         for r in list(self.slots):
-            if (r is None or not r.handoff or r.done or r.lc
+            if (r is None or not r.handoff or r.done
                     or r.prefill_done < r.ctx_len or not r.generated):
                 continue
             if self._spill(r, reason="handoff"):
@@ -2261,7 +1887,7 @@ class RaggedInferenceEngineV2:
             self._page_limited = False
             decode_rs = []
             for r in self.slots:
-                if r is None or r.done or r.lc or r.prefill_done < r.ctx_len:
+                if r is None or r.done or r.prefill_done < r.ctx_len:
                     continue
                 # the tick writes the last generated token at position
                 # length-1, so pages must cover `length` tokens
@@ -2271,8 +1897,7 @@ class RaggedInferenceEngineV2:
                     self._stalled.append(r)    # out of pages: sits it out
             prefill_rs = sorted(
                 (r for r in self.slots
-                 if r is not None and not r.lc
-                 and r.prefill_done < r.ctx_len),
+                 if r is not None and r.prefill_done < r.ctx_len),
                 key=lambda r: r.uid)
             budget = self.T - len(decode_rs)
             takes: Dict[int, int] = {}
@@ -2341,7 +1966,7 @@ class RaggedInferenceEngineV2:
             t = 0
             j = 0
             for r in [s for s in self.slots if s is not None]:
-                if r.done or r.lc or r.uid in stalled_uids:
+                if r.done or r.uid in stalled_uids:
                     continue
                 self._last_sched[r.slot] = self._sched_seq
                 seq_slot[j] = r.slot
@@ -2427,49 +2052,6 @@ class RaggedInferenceEngineV2:
                 self.host_stats, positions[:t], np.diff(cu_q_lens[:j + 1])))
         return arrays, sampled, sampling, t, counts
 
-    def sample(self, sel_logits, samplers) -> int:
-        """Host-side sampling of selected logits, for a tick that runs
-        outside the fused batch (the long-context driver): one sync, one
-        sampling call per distinct config."""
-        produced = 0
-        groups: Dict[Tuple, List[Tuple[Request, int]]] = {}
-        for r, seq_j, wants in samplers:
-            if not wants:
-                continue
-            key = (r.do_sample, r.temperature, r.top_k, r.top_p)
-            groups.setdefault(key, []).append((r, seq_j))
-        st = self.host_stats
-        for (do_sample, temp, top_k, top_p), pairs in groups.items():
-            # eager device work (a gather and a handful of tiny sampling
-            # programs per distinct row count): its own bracket
-            with st.stage("sample"):
-                rows = np.asarray([j for _, j in pairs])
-                sub = None
-                if do_sample:
-                    # (uid, position)-keyed streams: the draw for token n
-                    # of request u is the same whatever else is
-                    # co-batched, so seeded sampling is reproducible
-                    # under prefix-cache admission reordering (same
-                    # convention as the decode block's per-tick keys)
-                    sub = position_keys(
-                        self._sample_base,
-                        jnp.asarray([r.uid for r, _ in pairs], jnp.int32),
-                        jnp.asarray([r.length - 1 for r, _ in pairs],
-                                    jnp.int32))
-                dev_toks = sample_logits(
-                    sel_logits[rows], sub, do_sample=do_sample,
-                    temperature=temp, top_k=top_k, top_p=top_p)
-            toks = np.asarray(self.fetch(dev_toks))
-            with st.stage("harvest"):
-                for (r, _), tok in zip(pairs, toks):
-                    r.generated.append(int(tok))
-                    self.last_tokens[r.slot] = int(tok)
-                    produced += 1
-                    self.request_latency.on_tokens(r.uid,
-                                                   len(r.generated))
-                    self._maybe_finish(r)
-        return produced
-
     def _maybe_finish(self, req: Request) -> None:
         """``done`` by the count (a token still on the device counts: a
         finish by count or by length is known at dispatch), or by the
@@ -2506,9 +2088,6 @@ class RaggedInferenceEngineV2:
                                 r.ctx_len - r.prompt.size:]]),
                         r.length - 1)
                 self.finished.append(r)
-                # the parked middle groups of a long-context session go
-                # with it
-                self.kv.forget(r)
                 self._unseat(r)
                 rec = self.request_latency.on_finish(r.uid)
                 if trace.enabled:

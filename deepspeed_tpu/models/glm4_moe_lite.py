@@ -129,9 +129,8 @@ class Glm4MoeLiteConfig(MixtralConfig):
             raise NotImplementedError(
                 f"num_nextn_predict_layers={self.num_nextn_predict_layers}: "
                 "the multi-token-prediction block is not built (it drafts "
-                "the token after next and changes no logit; self-drafting "
-                "through the engine's speculative path waits for weights "
-                "under which a draft is likely)")
+                "the token after next and changes no logit, and the "
+                "engine has no loop that would verify a draft)")
         assert not self.scan_layers, "layers of two feed-forwards do not scan"
         assert self.qk_rope_head_dim % 2 == 0, self.qk_rope_head_dim
         # what the shared blocks read, under the names they read it by

@@ -211,12 +211,10 @@ class MonitorMaster(Monitor):
         fleet whose ``Serving/host_bound_fraction`` climbs toward 1.0
         is wasting its accelerators on host scheduling — the signal the
         pipelined host path exists to drive down.  One-level sub-dicts
-        (the ``speculation`` acceptance breakdown, the ``kv_tiering``
-        spill/restore counters) flatten to ``Serving/<group>/<name>``
-        series — a falling ``Serving/speculation/acceptance_rate``
-        means the draft has stopped earning its keep, and a climbing
-        ``Serving/kv_tiering/quarantined`` flags a host whose spill
-        media is corrupting parked KV pages."""
+        (the ``kv_tiering`` spill/restore counters, the ``prefix_cache``
+        hit counters) flatten to ``Serving/<group>/<name>`` series — a
+        climbing ``Serving/kv_tiering/quarantined`` flags a host whose
+        spill media is corrupting parked KV pages."""
         events = []
         for name, value in sorted(serving_stages.items()):
             if isinstance(value, dict):

@@ -59,9 +59,9 @@ from jax.experimental.pallas import tpu as pltpu
 # vanish under softmax, small enough that (mask - mask) stays exact 0
 _MASK_VALUE = -0.7 * float(np.finfo(np.float32).max)
 # the blocks below are f32 and lane-padded (a [rows, 1] stat costs a full
-# 128-lane tile): ~14 MiB at 128 query rows x 32 heads, ~40 MiB with the
-# carry in/out operands — over the 16 MiB default scope, well inside a
-# v5e core's 128 MiB
+# 128-lane tile): ~14 MiB at 128 query rows x 32 heads before the page
+# tiles, so the limit is raised past the 16 MiB default scope; well inside
+# a v5e core's 128 MiB
 _VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
 
@@ -71,29 +71,14 @@ _Q_BLOCK = 128
 
 
 def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
-                  *refs, page: int, groups: int,
-                  sliding_window: Optional[int], has_carry: bool,
-                  out_stats: bool):
-    # positional refs vary with the carry/stats variants: inputs are
-    # (q, pages, scales[, m_in, l_in, acc_in]), outputs are
-    # (o[, m_out, l_out, acc_out]), then the four VMEM scratch buffers.
+                  q_ref, pages_ref, scales_ref, o_ref,
+                  acc_sc, m_sc, l_sc, kv_sc, *, page: int, groups: int,
+                  sliding_window: Optional[int]):
     # Everything per-query is laid out [Hkv, rows, lanes] with
     # rows = q_block * groups (row r is token r // groups, q head
     # h * groups + r % groups), so each KV head's work is plain 2-D
     # [rows, D] x [page, D] tiles — the only layouts Mosaic is asked for
     # keep D (or the page) on the 128-lane dim and never reshape a mask.
-    q_ref, pages_ref, scales_ref = refs[0], refs[1], refs[2]
-    n = 3
-    if has_carry:
-        mi_ref, li_ref, acci_ref = refs[n], refs[n + 1], refs[n + 2]
-        n += 3
-    o_ref = refs[n]
-    n += 1
-    if out_stats:
-        mo_ref, lo_ref, acco_ref = refs[n], refs[n + 1], refs[n + 2]
-        n += 3
-    acc_sc, m_sc, l_sc, kv_sc = refs[n:n + 4]
-
     b = pl.program_id(0)                   # query-row block
     i = pl.program_id(1)                   # sequence slot
     j = pl.program_id(2)                   # page ordinal within the slot
@@ -105,23 +90,12 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
     @pl.when(jnp.logical_and(i == 0, j == 0))
     def _zero_out():
         o_ref[...] = jnp.zeros_like(o_ref)
-        if out_stats:
-            mo_ref[...] = jnp.full_like(mo_ref, _MASK_VALUE)
-            lo_ref[...] = jnp.zeros_like(lo_ref)
-            acco_ref[...] = jnp.zeros_like(acco_ref)
 
     @pl.when(j == 0)
     def _reset_seq():
-        # an incoming chunk-scan carry seeds the accumulators instead of
-        # the neutral element — the explicit carry INPUT
-        if has_carry:
-            acc_sc[...] = acci_ref[...]
-            m_sc[...] = mi_ref[...]
-            l_sc[...] = li_ref[...]
-        else:
-            acc_sc[...] = jnp.zeros_like(acc_sc)
-            m_sc[...] = jnp.full_like(m_sc, _MASK_VALUE)
-            l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, _MASK_VALUE)
+        l_sc[...] = jnp.zeros_like(l_sc)
 
     q0 = cu_ref[i]
     q1 = cu_ref[i + 1]
@@ -131,10 +105,10 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
         jnp.logical_and(i < ns_ref[0], q1 > q0),
         jnp.logical_and(q0 < t0 + N // groups, q1 > t0))
     # page j holds attended rows AND is resident: a -1 entry is padding
-    # or a parked partial-residency hole — its tile (the index map
-    # clamped it to the trash page) is skipped, and because kv
-    # positions derive from the column ordinal j the surviving columns
-    # keep their true absolute positions
+    # or an interior hole — its tile (the index map clamped it to the
+    # trash page) is skipped, and because kv positions derive from the
+    # column ordinal j the surviving columns keep their true absolute
+    # positions
     in_range = jnp.logical_and(j * page < kvl, pi_ref[i, j] >= 0)
 
     @pl.when(jnp.logical_and(live, in_range))
@@ -196,37 +170,23 @@ def _quant_kernel(kv_lens_ref, pi_ref, cu_ref, ns_ref,   # scalar prefetch
             valid = m_sc[h] > _MASK_VALUE * 0.5          # [N, 1]
             val = jnp.where(valid, acc_sc[h] / l, 0.0)
             o_ref[h] = jnp.where(mine, val, o_ref[h])
-            if out_stats:
-                # the explicit carry OUTPUT: raw (un-normalized) stats,
-                # so a later dispatch can keep folding
-                mo_ref[h] = jnp.where(mine, m_sc[h], mo_ref[h])
-                lo_ref[h] = jnp.where(mine, l_sc[h], lo_ref[h])
-                acco_ref[h] = jnp.where(mine, acc_sc[h], acco_ref[h])
 
 
 def ragged_paged_attention_quant(
         q: jax.Array, pages: jax.Array, scales: jax.Array,
         kv_lens: jax.Array, page_indices: jax.Array,
         cu_q_lens: jax.Array, num_seqs: jax.Array, *, sm_scale: float,
-        sliding_window: Optional[int] = None,
-        carry=None, return_stats: bool = False,
-        interpret: bool = False):
+        sliding_window: Optional[int] = None, interpret: bool = False):
     """Ragged paged attention over a QUANTIZED page pool.
 
     q: ``[T, H, D]`` float; pages: ``[P, page, 2*Hkv, D]`` int8 or
     fp8_e4m3; scales: ``[P, page, 2*Hkv]`` fp32; metadata as the
     full-width kernel (``page_indices`` may pad with -1 — trailing
-    padding OR interior partial-residency holes; hole tiles are skipped
-    and the surviving columns keep their true positions).  Returns
-    ``[T, H, D]`` in ``q.dtype``.  D must be 128 — the kernel contract
+    padding OR interior holes; hole tiles are skipped and the surviving
+    columns keep their true positions).  Returns ``[T, H, D]`` in
+    ``q.dtype``.  D must be 128 — the kernel contract
     it shares with the full-width vLLM-TPU kernel; other head dims use
     :func:`~deepspeed_tpu.inference.paged.ref_paged_attention_quant`.
-
-    The flash carry is an explicit input/output for the chunked
-    partial-residency scan: ``carry=(m [T,H], l [T,H], acc [T,H,D])``
-    (fp32) seeds the streaming accumulators instead of the neutral
-    element, and ``return_stats=True`` returns
-    ``(out, (m, l, acc))`` so a later dispatch can keep folding.
     """
     T, H, D = q.shape
     P, page, combined, _ = pages.shape
@@ -245,16 +205,15 @@ def ragged_paged_attention_quant(
     Tp = (Tp + tq - 1) // tq * tq
     N = tq * groups
 
-    def to_rows(x, fill=0.0):
+    def to_rows(x):
         # [T, H, ...] -> [Hkv, Tp * groups, lanes]: the KV head leads so
         # the kernel indexes it on an untiled dim
         x = x.astype(jnp.float32)
         x = x.reshape(T, H, -1)
         if Tp != T:
-            # padded rows belong to no sequence; neutral-pad them so the
-            # seeded accumulators stay finite
+            # padded rows belong to no sequence
             x = jnp.pad(x, ((0, Tp - T), (0, 0), (0, 0)),
-                        constant_values=fill)
+                        constant_values=0.0)
         lanes = x.shape[-1]
         return x.reshape(Tp, Hkv, groups, lanes).transpose(
             1, 0, 2, 3).reshape(Hkv, Tp * groups, lanes)
@@ -276,9 +235,6 @@ def ragged_paged_attention_quant(
     def wide():
         return pl.BlockSpec((Hkv, N, D), lambda b, i, j, *refs: (0, b, 0))
 
-    def stat():
-        return pl.BlockSpec((Hkv, N, 1), lambda b, i, j, *refs: (0, b, 0))
-
     in_specs = [
         wide(),
         pl.BlockSpec((1, page, combined, D),
@@ -288,27 +244,13 @@ def ragged_paged_attention_quant(
                      lambda b, i, j, kvl, pi, cu, ns: (
                          jnp.maximum(pi[i, j], 0), 0, 0)),
     ]
-    operands = [qf, pages, scales]
-    if carry is not None:
-        m0, l0, acc0 = carry
-        in_specs += [stat(), stat(), wide()]
-        operands += [to_rows(m0, _MASK_VALUE), to_rows(l0), to_rows(acc0)]
-
-    rows = Tp * groups
-    out_shape = jax.ShapeDtypeStruct((Hkv, rows, D), jnp.float32)
-    out_spec = wide()
-    if return_stats:
-        out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((Hkv, rows, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((Hkv, rows, 1), jnp.float32),
-                     jax.ShapeDtypeStruct((Hkv, rows, D), jnp.float32))
-        out_spec = (out_spec, stat(), stat(), wide())
+    out_shape = jax.ShapeDtypeStruct((Hkv, Tp * groups, D), jnp.float32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(Tp // tq, S, pp),
         in_specs=in_specs,
-        out_specs=out_spec,
+        out_specs=wide(),
         scratch_shapes=[
             pltpu.VMEM((Hkv, N, D), jnp.float32),
             pltpu.VMEM((Hkv, N, 1), jnp.float32),
@@ -318,9 +260,7 @@ def ragged_paged_attention_quant(
     )
     res = pl.pallas_call(
         functools.partial(_quant_kernel, page=page, groups=groups,
-                          sliding_window=sliding_window,
-                          has_carry=carry is not None,
-                          out_stats=return_stats),
+                          sliding_window=sliding_window),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -328,9 +268,5 @@ def ragged_paged_attention_quant(
         interpret=interpret,
         name="ragged_paged_attention_quant",
     )(kv_lens.astype(jnp.int32), pi, cu_q_lens.astype(jnp.int32),
-      num_seqs.astype(jnp.int32), *operands)
-    if return_stats:
-        out, m, l, acc = res
-        return from_rows(out).astype(q.dtype), (
-            from_rows(m)[..., 0], from_rows(l)[..., 0], from_rows(acc))
+      num_seqs.astype(jnp.int32), qf, pages, scales)
     return from_rows(res).astype(q.dtype)

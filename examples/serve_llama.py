@@ -60,17 +60,6 @@ def main() -> None:
                    help="disable the serving host-path pipeline "
                         "(per-dispatch blocking harvest)")
     p.add_argument("--harvest-interval", type=int, default=4)
-    p.add_argument("--spec-mode", choices=["off", "ngram", "draft"],
-                   default="off",
-                   help="speculative decoding: ngram = prompt-lookup "
-                        "drafting (no second model); draft = a small "
-                        "family member proposes (--draft-model)")
-    p.add_argument("--spec-k", type=int, default=4,
-                   help="drafted tokens per speculative tick")
-    p.add_argument("--draft-model", default="tinyllama",
-                   help="model-zoo preset for --spec-mode draft "
-                        "(random weights unless it matches "
-                        "--checkpoint's family)")
     p.add_argument("--kv-tiering", action="store_true",
                    help="spill cold sequences' KV pages to host RAM "
                         "(and NVMe, with --kv-nvme-pages) instead of "
@@ -134,13 +123,6 @@ def main() -> None:
 
         params = load_hf_checkpoint(model, args.checkpoint)
 
-    spec_kw = {}
-    if args.spec_mode == "draft":
-        dcfg = get_config(args.draft_model, scan_layers=False, remat=False,
-                          use_flash_attention=False,
-                          vocab_size=cfg.vocab_size,
-                          max_position_embeddings=cfg.max_position_embeddings)
-        spec_kw = dict(draft_model=LlamaForCausalLM(dcfg))
     tiering = None
     if args.kv_tiering:
         tiering = {"host_pages": args.kv_host_pages,
@@ -202,9 +184,8 @@ def main() -> None:
             max_seq_len=args.max_seq_len, prefill_chunk=64,
             pipeline=not args.no_pipeline,
             harvest_interval=args.harvest_interval,
-            speculation={"mode": args.spec_mode, "k": args.spec_k},
             kv_cache_dtype=args.kv_cache_dtype, kv_tiering=tiering,
-            prefix_cache=args.prefix_cache, control=control, **spec_kw)
+            prefix_cache=args.prefix_cache, control=control)
 
     # a burst of variable-length "requests"; with --prefix-cache they
     # share a common system prompt so later admissions hit the index
@@ -303,13 +284,6 @@ def main() -> None:
                        ("ticks", "decisions", "accepts", "reverts",
                         "freezes", "guard_violations", "objective")) +
               f" knobs={ctl['knobs']}")
-    spec = stages.get("speculation")
-    if spec:
-        print("speculation: " +
-              " ".join(f"{k}={spec[k]}" for k in
-                       ("spec_dispatches", "draft_ms", "verify_ms",
-                        "acceptance_rate", "mean_accepted_len",
-                        "effective_tokens_per_dispatch")))
     tier = stages.get("kv_tiering")
     if tier:
         print("kv tiering: " +

@@ -1,33 +1,19 @@
 #!/usr/bin/env python
-"""Serving smoke: speculative decoding correctness gate (CI-grade).
+"""Serving smoke: the pipelined host loop's correctness gate (CI-grade).
 
 The serving analogue of ``scripts/chaos_train.py``: runs the ragged
-engine for a few hundred greedy tokens in every speculation mode and
-exits NONZERO if
+engine for a few hundred greedy tokens under the pipelined and the
+unpipelined host loop and exits NONZERO if
 
-- any speculative greedy output diverges from the spec-off reference
-  (speculation must be a pure perf lever — greedy emission is the
-  target model's argmax continuation regardless of draft quality), or
-- the acceptance rate is 0 where the draft provably CAN accept
-  (``ngram`` over a long greedy run — random-init greedy decode falls
-  into repeating cycles the prompt-lookup drafter matches; and
-  ``self_draft`` where the draft IS the target), or
-- a pipelined run's dispatch accounting regresses to per-block syncs.
+- the pipelined greedy output diverges from the ``pipeline=False``
+  reference, or
+- the pipelined run's dispatch accounting regresses to per-block syncs.
 
 With ``--kv-tiering`` it additionally gates the tiered paged-KV store:
 a deliberately tiny HBM pool forces spill/restore traffic, and the run
 exits NONZERO if the tiering-on greedy output diverges from the
 tiering-off reference, if no spill actually happened (the gate would
 be vacuous), or if any restored page skipped digest verification.
-
-With ``--long-context`` it additionally gates partial residency (the
-tiered KV store as virtual memory for attention): a sequence whose KV
-exceeds the HBM pool by >=4x must decode end-to-end on a tiny pool
-(sinks + recent window resident, parked middle streamed back through
-the chunked attention scan), greedy output must be bit-identical to a
-fully-resident control at a size where both fit, the run must actually
-park and page in groups (the gate is vacuous otherwise), and every
-page-in must be digest-verified.
 
 With ``--prefix-cache`` it additionally gates the cross-request prefix
 cache: a shared-system-prompt workload must produce greedy output
@@ -96,7 +82,6 @@ already-tuned config (min of 3 runs each).
 
     JAX_PLATFORMS=cpu python scripts/serve_smoke.py [--tokens 250]
     JAX_PLATFORMS=cpu python scripts/serve_smoke.py --kv-tiering
-    JAX_PLATFORMS=cpu python scripts/serve_smoke.py --long-context
     JAX_PLATFORMS=cpu python scripts/serve_smoke.py --prefix-cache
     JAX_PLATFORMS=cpu python scripts/serve_smoke.py --kv-quant
     JAX_PLATFORMS=cpu python scripts/serve_smoke.py --trace
@@ -124,11 +109,6 @@ def main() -> int:
                    help="also gate the tiered paged-KV store (tiny "
                         "pool, spill/restore parity + verified "
                         "restores)")
-    p.add_argument("--long-context", action="store_true",
-                   help="also gate partial residency (>=4x over-HBM "
-                        "decode end-to-end, greedy parity vs a "
-                        "fully-resident control, non-vacuous park/"
-                        "page-in traffic)")
     p.add_argument("--prefix-cache", action="store_true",
                    help="also gate the cross-request prefix cache "
                         "(shared-prompt parity vs cache-off, nonzero "
@@ -205,68 +185,39 @@ def main() -> int:
                      dtype=jnp.float32, param_dtype=jnp.float32,
                      scan_layers=True, remat=False,
                      use_flash_attention=False)
-    dcfg = get_config("tinyllama", vocab_size=64, hidden_size=16,
-                      intermediate_size=32, num_hidden_layers=1,
-                      num_attention_heads=2, num_key_value_heads=1,
-                      max_position_embeddings=max(max_len, 128),
-                      dtype=jnp.float32, param_dtype=jnp.float32,
-                      scan_layers=False, remat=False,
-                      use_flash_attention=False)
     model = LlamaForCausalLM(cfg)
     params = jax.jit(model.init)(jax.random.PRNGKey(args.seed),
                                  np.zeros((1, 8), np.int32))
-    dparams = jax.jit(LlamaForCausalLM(dcfg).init)(
-        jax.random.PRNGKey(args.seed + 1), np.zeros((1, 8), np.int32))
 
     rng = np.random.default_rng(args.seed)
     prompts = [rng.integers(1, 64, size=(n,), dtype=np.int32)
                for n in (9, 14)]
 
-    def run(spec, **kw):
+    def run(pipeline):
         eng = RaggedInferenceEngineV2(
             LlamaForCausalLM(cfg), params=params, max_seqs=2,
             max_seq_len=max_len, prefill_chunk=16, decode_block_size=8,
-            speculation=spec, rng=jax.random.PRNGKey(args.seed), **kw)
+            pipeline=pipeline, rng=jax.random.PRNGKey(args.seed))
         outs = eng.generate_all(list(prompts),
                                 max_new_tokens=args.tokens)
         return outs, eng
 
-    ref, _ = run("off")
+    ref, _ = run(False)
     failures = 0
-    modes = {
-        "ngram": dict(),
-        "draft": dict(draft_model=LlamaForCausalLM(dcfg),
-                      draft_params=dparams),
-        "self_draft": dict(draft_model=LlamaForCausalLM(cfg),
-                           draft_params=params),
-    }
-    # acceptance CAN be zero for a random unrelated draft (nothing to
-    # agree on) — gate only where acceptance is provably earnable
-    must_accept = {"ngram", "self_draft"}
-    for name, kw in modes.items():
-        spec_mode = "draft" if name == "self_draft" else name
-        outs, eng = run(spec_mode, **kw)
-        spec = eng.serving_stages().get("speculation") or {}
-        ok = sorted(outs) == sorted(ref) and all(
-            np.array_equal(outs[u], ref[u]) for u in ref)
-        if not ok:
-            print(f"FAIL [{name}]: greedy output diverged from spec-off")
-            failures += 1
-        rate = spec.get("acceptance_rate", 0.0)
-        if name in must_accept and not rate > 0:
-            print(f"FAIL [{name}]: acceptance rate is 0 "
-                  f"({spec})")
-            failures += 1
-        st = eng.host_stats
-        if st.blocking_gets >= st.dispatches and st.dispatches > 4:
-            print(f"FAIL [{name}]: pipelined spec run syncs per block "
-                  f"({st.blocking_gets} gets / {st.dispatches} "
-                  "dispatches)")
-            failures += 1
-        print(f"[{name}] ok={ok} acceptance={rate} "
-              f"tokens_per_target_pass="
-              f"{round(1 + spec.get('mean_accepted_len', 0), 3)} "
-              f"spec_dispatches={spec.get('spec_dispatches')}")
+    outs, eng = run(True)
+    ok = sorted(outs) == sorted(ref) and all(
+        np.array_equal(outs[u], ref[u]) for u in ref)
+    if not ok:
+        print("FAIL [pipeline]: greedy output diverged from "
+              "pipeline=False")
+        failures += 1
+    st = eng.host_stats
+    if st.blocking_gets >= st.dispatches and st.dispatches > 4:
+        print("FAIL [pipeline]: the pipelined run syncs per block "
+              f"({st.blocking_gets} gets / {st.dispatches} dispatches)")
+        failures += 1
+    print(f"[pipeline] ok={ok} blocking_gets={st.blocking_gets} "
+          f"dispatches={st.dispatches}")
     if args.kv_tiering:
         # tiny pool: four sequences cannot all stay HBM-resident, so
         # the engine must spill/restore to finish them — and the
@@ -308,64 +259,6 @@ def main() -> int:
               f"pages_verified={st['pages_verified']}/"
               f"{st['pages_restored']}")
         t_eng.close()
-    if args.long_context:
-        # partial residency: the scan programs need unrolled layers
-        # (the chunked dispatches apply per-layer subtrees), so the
-        # gate builds its own non-scan config + params
-        lc_cfg = get_config(
-            "tinyllama", vocab_size=64, hidden_size=32,
-            intermediate_size=64, num_hidden_layers=2,
-            num_attention_heads=4, num_key_value_heads=2,
-            max_position_embeddings=512, dtype=jnp.float32,
-            param_dtype=jnp.float32, scan_layers=False, remat=False,
-            use_flash_attention=False)
-        lc_params = jax.jit(LlamaForCausalLM(lc_cfg).init)(
-            jax.random.PRNGKey(args.seed), np.zeros((1, 8), np.int32))
-        lc_tier = {"host_pages": 256, "long_context": True,
-                   "sink_pages": 1, "window_pages": 2, "chunk_pages": 2}
-
-        def lc_run(num_pages, tiering, prompt, new):
-            eng = RaggedInferenceEngineV2(
-                LlamaForCausalLM(lc_cfg), params=lc_params, max_seqs=2,
-                max_seq_len=512, prefill_chunk=16, page_size=16,
-                num_pages=num_pages, decode_block_size=4,
-                kv_reserve="on_demand", kv_tiering=tiering,
-                rng=jax.random.PRNGKey(args.seed))
-            outs = eng.generate_all([prompt], max_new_tokens=new)
-            return outs, eng
-
-        lc_rng = np.random.default_rng(args.seed + 3)
-        mid = lc_rng.integers(1, 64, size=(200,), dtype=np.int32)
-        l_ref, _ = lc_run(24, None, mid, 48)
-        l_on, l_eng = lc_run(8, dict(lc_tier), mid, 48)
-        st = l_eng.serving_stages()["kv_tiering"]
-        ok = sorted(l_on) == sorted(l_ref) and all(
-            np.array_equal(l_on[u], l_ref[u]) for u in l_ref)
-        if not ok:
-            print("FAIL [long-context]: partially-resident greedy "
-                  "output diverged from the fully-resident control")
-            failures += 1
-        if not (st["spills"] > 0 and st["pageins"] > 0):
-            print("FAIL [long-context]: no park/page-in traffic — the "
-                  f"gate ran vacuously ({st})")
-            failures += 1
-        l_eng.close()
-        big = lc_rng.integers(1, 64, size=(400,), dtype=np.int32)
-        b_outs, b_eng = lc_run(8, dict(lc_tier), big, 56)
-        (b_toks,) = b_outs.values()
-        usable_tokens = (8 - 1) * 16
-        ratio = len(b_toks) / usable_tokens
-        if len(b_toks) != 456 or ratio < 4:
-            print(f"FAIL [long-context]: {len(b_toks)}-token sequence "
-                  f"({ratio:.1f}x the {usable_tokens}-token HBM pool) "
-                  "did not decode end-to-end at >=4x over HBM")
-            failures += 1
-        bst = b_eng.serving_stages()["kv_tiering"]
-        b_eng.close()
-        print(f"[long-context] ok={ok} over_hbm={ratio:.1f}x "
-              f"spills={bst['spills']} pageins={bst['pageins']} "
-              f"pagein_pages={bst['pagein_pages']} "
-              f"pagein_wait_s={bst['pagein_wait_s']}")
     if args.prefix_cache:
         # shared-system-prompt workload: 8 sessions over 4 seats share
         # two full pages of system prompt, one repeats another verbatim
@@ -585,7 +478,7 @@ def main() -> int:
             telemetry.configure(enabled=enabled)
             telemetry.trace.clear()
             t0 = time.perf_counter()
-            _, eng = run("off")
+            _, eng = run(True)
             return time.perf_counter() - t0, eng
 
         # the reference run above already warmed the jit caches; min of
@@ -649,7 +542,7 @@ def main() -> int:
             eng = RaggedInferenceEngineV2(
                 LlamaForCausalLM(cfg), params=params, max_seqs=2,
                 max_seq_len=max_len, prefill_chunk=16,
-                decode_block_size=8, speculation="off",
+                decode_block_size=8,
                 rng=jax.random.PRNGKey(args.seed), **kw)
             outs = eng.generate_all(list(m_prompts), max_new_tokens=60)
             return outs, eng
@@ -1574,12 +1467,10 @@ def main() -> int:
     if failures:
         print(f"serve_smoke: {failures} failure(s)")
         return 1
-    print("serve_smoke: all speculation modes bit-identical to spec-off, "
-          "acceptance healthy" +
+    print("serve_smoke: the pipelined loop bit-identical to "
+          "pipeline=False" +
           (", kv tiering spill/restore exact and verified"
            if args.kv_tiering else "") +
-          (", partial residency exact at >=4x over HBM with verified "
-           "page-ins" if args.long_context else "") +
           (", prefix cache exact with nonzero hit rate and clean "
            "refcount audit" if args.prefix_cache else "") +
           (", quantized pool deterministic, tier-exact, inside the "

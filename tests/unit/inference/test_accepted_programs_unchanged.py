@@ -48,7 +48,11 @@ and a page table of its own: ``page_indices [2, S, pp]`` and ``new_kv_dest
 programs), and moved none of the sixteen: no other model's program gains an
 argument, ``paged_update_and_attend`` reads the one table and the one window
 where it is told no layer kind, and the decode block's destination rows are
-the same operations under a local name.
+the same operations under a local name.  PR 61 added the two programs of
+``glm-4.7-flash-serve``, taken on its PARENT, and then deleted speculative
+decoding and the partial-residency driver from the engine, the program
+builders and the cache state: the twenty read on its tree what they read
+before it.
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -140,13 +144,20 @@ EXPECTED = {
     # experts held); the sixteen older hashes are as they were
     "trinity.fused": "d10c4ad62da86dbe",
     "trinity.decode_block": "f5ca964f43bde1af",
+    # added by PR 61 (ROADMAP D14), taken on its PARENT before anything was
+    # deleted: a PR that edits the engine, the program builders and the cache
+    # state can show that the latent pool's two programs stood (a pool of one
+    # row a token a layer, the absorbed decode, sigmoid routing with a bias
+    # beside a shared expert); the eighteen older hashes are as they were
+    "glm.fused": "2d330374a021b606",
+    "glm.decode_block": "1e96c6f55cbd033a",
 }
 
 
 def _models():
-    from deepspeed_tpu.models import (keye_vl2, lfm2_moe, minicpm_sala,
-                                      mistral, olmo_hybrid, olmoe, ouro,
-                                      solar_open2, trinity)
+    from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
+                                      minicpm_sala, mistral, olmo_hybrid,
+                                      olmoe, ouro, solar_open2, trinity)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -170,6 +181,8 @@ def _models():
             "tinysolar", **bf16)),
         "trinity": trinity.TrinityForCausalLM(trinity.get_config(
             "tinytrinity", **bf16)),
+        "glm": glm4_moe_lite.Glm4MoeLiteForCausalLM(glm4_moe_lite.get_config(
+            "tinyglm", **bf16)),
     }
 
 

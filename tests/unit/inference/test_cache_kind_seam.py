@@ -275,10 +275,10 @@ def test_the_engine_says_of_the_cache_what_it_said_at_the_parent(
 
 # -- the refusals: the description's data, the parent's messages -------------
 
+# the four features a cache kind can refuse: what the engine has that reads
+# a leaf as per-head K/V pages of one pool
+FEATURES = {"prefix_cache", "tiering", "quantized", "tensor_parallel"}
 ASK = {"prefix_cache": dict(prefix_cache={"enabled": True}),
-       "speculation": dict(speculation={"mode": "ngram", "k": 2}),
-       "long_context": dict(kv_tiering={"host_pages": 32,
-                                        "long_context": True}),
        "tiering": dict(kv_tiering={"host_pages": 32}),
        "quantized": dict(kv_cache_dtype="int8"),
        "tensor_parallel": dict(tensor_parallel=2)}
@@ -295,22 +295,12 @@ _STATE = {
     "prefix_cache": "prefix_cache: {}, and a shared prefix (or its copy-on-"
     "write page) brings keys and values only — a sequence that starts behind "
     "one needs the state AT the prefix's end, which the index does not keep",
-    "speculation": "speculation.mode='ngram': {}, and the speculative block's "
-    "rollback rewinds page cursors, not state",
-    "long_context": "kv_tiering.long_context: {}, and the partial-residency "
-    "scan (inference/v2/long_context.py) runs programs of its own that know "
-    "keys and values only",
     "quantized": "kv_cache_dtype='int8': {}; its pool is full-width (heads "
     "of 64 are stored padded to the kernels' 128 lanes, which the quantized "
     "pool's kernel does not know)",
     "tensor_parallel": "tensor-parallel serving: {}, and the cache's sharding "
     "rule knows page pools only"}
 MESSAGES = {
-    ("tinyouro", "speculation"): f"speculation.mode='ngram': {_OURO}, and the "
-    "speculative block's rollback and draft pool know one K/V slot a layer",
-    ("tinyouro", "long_context"): f"kv_tiering.long_context: {_OURO}, and the "
-    "partial-residency scan (inference/v2/long_context.py) reads "
-    "num_hidden_layers as the number of K/V slots",
     ("tinyouro", "quantized"): f"kv_cache_dtype='int8': {_OURO}; its pool is "
     "full-width (the quantized pool's scale rows have one slot a layer)",
     ("tinyouro", "tensor_parallel"): f"tensor-parallel serving: {_OURO}, and "
@@ -330,23 +320,10 @@ MESSAGES = {
     ("tinysolar", "tiering"): f"kv_tiering: {_SOLAR}, and a spilled "
     "request's record would carry megabytes of state a sequence beside its "
     "pages (ROADMAP R5(d): a tier for matrix state)",
-    ("tinyglm", "speculation"): f"speculation.mode='ngram': {_GLM}, and the "
-    "speculative verify has no latent attention kernel (nor a draft that "
-    "shares the latent pool)",
-    ("tinyglm", "long_context"): f"kv_tiering.long_context: {_GLM}, and the "
-    "partial-residency scan (inference/v2/long_context.py) stages and folds "
-    "per-head keys and values",
     ("tinyglm", "quantized"): f"kv_cache_dtype='int8': {_GLM}; the quantized "
     "pool's scale rows and kernel are per head",
     ("tinyglm", "tensor_parallel"): f"tensor-parallel serving: {_GLM}, which "
     "has no head dim for the pool's sharding rule to split",
-    ("tinykeye", "speculation"): f"speculation.mode='ngram': {_KEYE}, and the "
-    "speculative verify has no kernel that scores and chooses for its rows "
-    "(nor a draft that shares the index keys)",
-    ("tinykeye", "long_context"): f"kv_tiering.long_context: {_KEYE}, and "
-    "the partial-residency scan (inference/v2/long_context.py) stages and "
-    "folds keys and values a page at a time, where a choice by token needs "
-    "every index key of the sequence at once",
     ("tinykeye", "quantized"): f"kv_cache_dtype='int8': {_KEYE}; the "
     "quantized pool's scale rows and kernel are per head of a pool laid out "
     "heads first, and an index key has no scale",
@@ -357,17 +334,47 @@ MESSAGES = {
 
 
 def test_the_descriptions_refusals_are_the_ones_listed():
-    """Eighteen refusals in five kinds' files (per-slot state's five serve
-    four models, and the matrix state's tiering three), and none for a model
-    of plain K/V pages."""
+    """Twenty-one refusals in five kinds' files (two of a looped pool, a
+    latent pool and an indexer's keys each; per-slot state's three serve four
+    models, and the matrix state's tiering three), none for a model of plain
+    K/V pages, and every one of a feature the engine HAS: a dictionary that
+    grows a key for another fails here."""
     listed = set()
     for preset in MODELS:
         model = _model(preset)
         for model_is, refuses in paged.describe(model.config).refusals(
                 type(model).__name__):
             assert model_is.startswith(type(model).__name__)
+            assert set(refuses) <= FEATURES, set(refuses) - FEATURES
             listed |= {(preset, feature) for feature in refuses}
-    assert listed == set(MESSAGES) and len(MESSAGES) == 18 + 5 + 6 + 6
+    assert listed == set(MESSAGES) and len(MESSAGES) == 2 * 3 + 3 * 4 + 3
+    assert set(ASK) == FEATURES
+
+
+def test_what_the_engine_no_longer_has_is_an_unknown_name(caplog):
+    """Speculative decoding and the partial-residency driver are gone with no
+    shim: a config that names either warns of the key by name, as of any
+    unknown key, and the constructor refuses their keywords."""
+    import logging
+
+    from deepspeed_tpu.inference.config import load_inference_config
+    from deepspeed_tpu.utils.logging import logger
+
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.WARNING, logger=logger.name):
+            v2 = load_inference_config({"v2": {
+                "speculation": {"mode": "ngram"},
+                "kv_tiering": {"host_pages": 32, "long_context": True}}}).v2
+    finally:
+        logger.removeHandler(caplog.handler)
+    said = caplog.text
+    assert "speculation" in said and "long_context" in said
+    assert "speculation" not in type(v2).model_fields
+    assert "long_context" not in type(v2.kv_tiering).model_fields
+    for gone in ("speculation", "draft_model"):
+        with pytest.raises(TypeError, match=gone):
+            RaggedInferenceEngineV2(_model("tinymistral"), **{gone: None})
 
 
 @pytest.mark.parametrize("preset,feature", sorted(MESSAGES))

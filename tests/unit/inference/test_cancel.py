@@ -2,8 +2,8 @@
 
 ``cancel(uid)`` must release EVERY resource a request holds at ANY
 lifecycle stage — queued, spilled to the tiers, mid-prefill,
-mid-decode (inside a pipelined carry), LC-parked, or finished-but-
-uncollected — and the conservation audits must stay clean after each:
+mid-decode (inside a pipelined carry), or finished-but-uncollected —
+and the conservation audits must stay clean after each:
 ``PageAllocator.audit()`` via ``audit_kv_sharing()`` (slot rows +
 prefix entries + spill-holds cover every refcount) and
 ``TieredKVStore.audit()`` (no orphaned spill payloads).  Survivors of
@@ -27,18 +27,6 @@ CFG = get_config("tinyllama", vocab_size=64, hidden_size=32,
                  param_dtype=jnp.float32, scan_layers=True, remat=False,
                  use_flash_attention=False)
 
-# resident geometry for the LC stage: sink 1 + window 2 + chunk 2 + 1
-# staging = 6 pages must fit the usable pool.  The LC driver needs
-# unrolled layers_<i> params, so its engine gets a no-scan config.
-LC_TIER = {"host_pages": 256, "long_context": True,
-           "sink_pages": 1, "window_pages": 2, "chunk_pages": 2}
-CFG_LC = get_config("tinyllama", vocab_size=64, hidden_size=32,
-                    intermediate_size=64, num_hidden_layers=2,
-                    num_attention_heads=4, num_key_value_heads=2,
-                    max_position_embeddings=256, dtype=jnp.float32,
-                    param_dtype=jnp.float32, scan_layers=False,
-                    remat=False, use_flash_attention=False)
-
 
 @pytest.fixture(scope="module")
 def params():
@@ -47,15 +35,7 @@ def params():
                                np.zeros((1, 8), np.int32))
 
 
-@pytest.fixture(scope="module")
-def params_lc():
-    model = LlamaForCausalLM(CFG_LC)
-    return jax.jit(model.init)(jax.random.PRNGKey(7),
-                               np.zeros((1, 8), np.int32))
-
-
-def make(params, tiering=None, prefix=None, pipeline=False, cfg=CFG,
-         **kw):
+def make(params, tiering=None, prefix=None, pipeline=False, **kw):
     kw.setdefault("max_seqs", 4)
     kw.setdefault("max_seq_len", 256)
     kw.setdefault("prefill_chunk", 16)
@@ -63,7 +43,7 @@ def make(params, tiering=None, prefix=None, pipeline=False, cfg=CFG,
     kw.setdefault("num_pages", 9)
     kw.setdefault("decode_block_size", 4)
     kw.setdefault("kv_reserve", "on_demand")
-    return RaggedInferenceEngineV2(LlamaForCausalLM(cfg), params=params,
+    return RaggedInferenceEngineV2(LlamaForCausalLM(CFG), params=params,
                                    pipeline=pipeline, kv_tiering=tiering,
                                    prefix_cache=prefix,
                                    rng=jax.random.PRNGKey(11), **kw)
@@ -168,32 +148,6 @@ class TestCancelStages:
         outs = _finish(eng)
         assert sorted(outs) == sorted(u for u in uids if u != victim.uid)
         eng.tiering.audit()
-        eng.close()
-
-    def test_cancel_lc_parked_drops_middle_groups(self, params_lc):
-        # a long-context request parks middle page groups in the tiers
-        # (mid-{uid}-{g} keys); cancelling mid-flight must drop them all
-        eng = make(params_lc, cfg=CFG_LC, tiering=LC_TIER, num_pages=8,
-                   max_seqs=1)
-        (p,) = _prompts((150,))
-        uid = eng.put_request(p, max_new_tokens=16)
-        parked = False
-        for _ in range(300):
-            eng.step()
-            r = next((s for s in eng.slots
-                      if s is not None and s.uid == uid), None)
-            if r is not None and r.lc and r.lc_parked > 0:
-                parked = True
-                break
-            if not eng.has_work():
-                break
-        assert parked, "LC request never parked a middle group"
-        assert eng.tiering.holds(f"mid-{uid}-0")
-        assert eng.cancel(uid) == "lc"
-        assert not eng.tiering.holds(f"mid-{uid}-0")
-        eng.audit_kv_sharing()
-        eng.tiering.audit()
-        assert not eng.has_work()
         eng.close()
 
     def test_cancel_finished_uncollected(self, params):

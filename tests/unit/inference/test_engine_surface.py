@@ -12,8 +12,7 @@ import pytest
 
 from deepspeed_tpu.inference.common import HostStageStats
 from deepspeed_tpu.inference.config import InferenceV2Config
-from deepspeed_tpu.inference.v2 import (kv_state, long_context, programs,
-                                        ragged_engine)
+from deepspeed_tpu.inference.v2 import kv_state, programs, ragged_engine
 from deepspeed_tpu.inference.v2.ragged_engine import RaggedInferenceEngineV2
 from deepspeed_tpu.models.llama import LlamaForCausalLM, get_config
 
@@ -23,45 +22,40 @@ SIGNATURE = dict(
     topology=None, decode_block_size=8, kv_cache_dtype=None,
     kv_pool_bytes=None, window_pool_bytes=None, quantize_weights=None,
     kv_reserve="on_demand",
-    pipeline=None, async_depth=None, harvest_interval=None,
-    speculation=None, draft_model=None, draft_params=None,
-    draft_kv_cache_dtype=None, kv_tiering=None, prefix_cache=None, slo=None,
-    trace_sample=None, replica=None, control=None, config=None)
+    pipeline=None, async_depth=None, harvest_interval=None, kv_tiering=None,
+    prefix_cache=None, slo=None, trace_sample=None, replica=None,
+    control=None, config=None)
 V2_FIELDS = ["pipeline", "async_depth", "harvest_interval", "kv_cache_dtype",
-             "speculation", "kv_tiering", "prefix_cache", "control", "slo",
-             "trace_sample"]
+             "kv_tiering", "prefix_cache", "control", "slo", "trace_sample"]
 SPANS = {"plan": "engine.plan", "upload": "engine.upload",
          "dispatch": "engine.dispatch", "device": "engine.fetch",
-         "harvest": "engine.harvest", "draft": "engine.draft",
-         "verify": "engine.verify", "spill": "engine.spill",
+         "harvest": "engine.harvest", "spill": "engine.spill",
          "restore": "engine.restore", "prefix": "engine.prefix",
          "rng_split": "engine.rng_split", "sample": "engine.sample",
          "reap": "engine.reap"}
-EVENTS = {"decode_block", "lc_park", "lc_tick", "prefill_chunk",
-          "prefix_cow", "prefix_demote", "prefix_revive", "request_admit",
-          "request_cancel", "request_evict", "request_export",
-          "request_handoff", "request_handoff_ready", "request_import",
-          "request_reap", "request_restore", "request_restore_failed",
-          "request_spill", "request_submit"}
-PROGRAMS = {"draft_prefill", "ragged_decode_block", "ragged_fused_step",
-            "spec_verify_block"}
+EVENTS = {"decode_block", "prefill_chunk", "prefix_cow", "prefix_demote",
+          "prefix_revive", "request_admit", "request_cancel", "request_evict",
+          "request_export", "request_handoff", "request_handoff_ready",
+          "request_import", "request_reap", "request_restore",
+          "request_restore_failed", "request_spill", "request_submit"}
+PROGRAMS = {"ragged_decode_block", "ragged_fused_step"}
 # keys of a plain engine that served one request; "metrics" and
 # "trace_sampling" come and go with the process-wide registry
 STAGES = {"admissions", "admissions_ahead", "blocking_gets",
           "decode_blocks", "decode_blocks_ahead", "decode_key_token_steps",
           "device_ms", "device_wait_s", "dispatch_ms", "dispatches",
-          "draft_ms", "drains", "fused_head", "fused_tick_host",
+          "drains", "fused_head", "fused_tick_host",
           "fused_ticks", "fused_ticks_ahead", "harvest_ms", "harvests",
           "host_bound_fraction", "host_s",
           "kernels", "meta_uploads", "pipeline_window", "plan_ms", "pool",
           "prefill_key_tokens", "prefill_tokens", "prefix_ms", "reap_s",
           "requests", "restore_ms", "rng_split_s", "sample_s", "spill_ms",
-          "ticks", "upload_ms", "verify_ms"}
+          "ticks", "upload_ms"}
 COMPLETED = {"errors", "finish_t", "handoff_stall_ms", "handoffs",
              "prefill_ms", "prompt_tokens", "queue_wait_ms",
              "router_queue_wait_ms", "spill_stall_ms", "spills", "submit_t",
              "tokens", "tpot_ms", "ttft_ms", "uid"}
-MODULES = (ragged_engine, kv_state, programs, long_context)
+MODULES = (ragged_engine, kv_state, programs)
 
 
 def test_the_constructor_and_the_config_fields_are_the_published_ones():
@@ -115,7 +109,7 @@ def test_the_engine_reads_its_kv_state_and_nothing_else_writes_it(served):
     # the rule a reviewer can grep: pages move in kv_state.py alone
     moves = re.compile(r"allocator\.(free|grow|attach|cow|incref|decref)\("
                        r"|page_table\[[^]]*\] *=")
-    for m in (ragged_engine, programs, long_context):
+    for m in (ragged_engine, programs):
         assert not moves.search(inspect.getsource(m)), m.__name__
     assert "ragged_engine" not in inspect.getsource(programs)
     for line in inspect.getsource(kv_state).split("\n"):

@@ -4,17 +4,16 @@ byte-accounted sizing, and composition with every serving subsystem.
 The kv_quant tentpole's contracts above the kernel:
 
 - **Plumbing**: ``kv_cache_dtype`` resolves kwarg > config
-  (``inference.v2.kv_cache_dtype``) > "none"; the draft model's pool
-  follows the target's format unless overridden.
+  (``inference.v2.kv_cache_dtype``) > "none".
 - **Byte accounting**: ``kv_pool_bytes`` sizes the pool by exact device
   bytes (payload + scale rows) — the same budget holds ~2x the pages
   quantized.
 - **Composition**: spill/restore carries the quantized payload + scales
   digest-verified and byte-identical (a transient bitflip on the
   quantized bytes heals via re-read); the prefix cache shares and COWs
-  quantized pages with clean refcount audits; speculation (ngram) and
-  the pipelined host path stay output-identical on a quantized pool —
-  each with the zero-new-compilations guard where it applies.
+  quantized pages with clean refcount audits; the pipelined host path
+  stays output-identical on a quantized pool — each with the
+  zero-new-compilations guard where it applies.
 - **"none" is untouched**: no scale leaves, no kv_quant stats block —
   the full-width path is structurally the pre-quantization engine.
 """
@@ -142,18 +141,6 @@ class TestPlumbing:
         per_page = sized_q.cache_bytes() // sized_q.num_pages
         assert sized_q.cache_bytes() + per_page > budget
 
-    def test_draft_pool_follows_target_format(self, params):
-        draft = LlamaForCausalLM(CFG)
-        eng = make(params, fmt="int8", speculation="draft",
-                   draft_model=draft, draft_params=params)
-        assert eng.draft_kv_cache_dtype == "int8"
-        assert _scale_leaves(eng.kv.draft_cache)
-        over = make(params, fmt="int8", speculation="draft",
-                    draft_model=draft, draft_params=params,
-                    draft_kv_cache_dtype="none")
-        assert over.draft_kv_cache_dtype == "none"
-        assert not _scale_leaves(over.kv.draft_cache)
-
     def test_serving_stages_kv_quant_block(self, params):
         eng = make(params, fmt="int8")
         _serve(eng, sizes=[12, 9], max_new_tokens=10)
@@ -244,16 +231,6 @@ class TestComposition:
         assert fin["referenced"] == eng.kv.pfx.stats()["resident_entries"]
         eng.close()
         assert eng.allocator.audit(external={})["referenced"] == 0
-
-    def test_speculation_ngram_parity_on_quant_pool(self, params):
-        """Greedy speculative decode over a quantized pool is
-        bit-identical to non-speculative decode over the SAME pool —
-        the accept/rollback contract is format-independent."""
-        plain = _serve(make(params, fmt="int8"))
-        eng = make(params, fmt="int8", speculation="ngram")
-        spec = _serve(eng)
-        assert eng.host_stats.spec_dispatches > 0
-        _assert_same_outputs(plain, spec)
 
     def test_pipeline_parity_on_quant_pool(self, params):
         on = _serve(make(params, fmt="fp8", pipeline=True))

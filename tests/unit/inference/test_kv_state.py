@@ -383,7 +383,6 @@ def test_every_way_out_of_a_slot_leaves_the_same_state(params, site, tiers):
     r, left_as = site(eng)
     assert eng.slots == [None, None] and r.slot == -1
     assert (eng.page_table == -1).all()
-    assert not eng._draft_len.any()
     assert all(eng.allocator.owned(s) == 0 for s in range(2))
     counts = eng.audit_kv_sharing()
     assert counts["referenced"] == 0

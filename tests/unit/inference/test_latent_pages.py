@@ -200,11 +200,7 @@ def test_copy_on_write_copies_a_latent_page(params):
 
 
 @pytest.mark.parametrize("kw,named", [
-    (dict(speculation={"mode": "ngram", "k": 2}), "speculation"),
-    (dict(kv_tiering={"host_pages": 32, "long_context": True}),
-     "long_context"),
-    (dict(kv_cache_dtype="int8"), "kv_cache_dtype")],
-    ids=["speculation", "long-context", "quantized-pool"])
+    (dict(kv_cache_dtype="int8"), "kv_cache_dtype")], ids=["quantized-pool"])
 def test_what_reads_a_pool_as_heads_is_refused_by_name(params, kw, named):
     with pytest.raises(NotImplementedError, match=named):
         make_engine(params, **kw)

@@ -149,11 +149,7 @@ def test_the_decode_program_holds_the_block_stack_once(params):
 
 
 @pytest.mark.parametrize("kw,named", [
-    (dict(speculation={"mode": "ngram", "k": 2}), "speculation"),
-    (dict(kv_tiering={"host_pages": 32, "long_context": True}),
-     "long_context"),
-    (dict(kv_cache_dtype="int8"), "kv_cache_dtype")],
-    ids=["speculation", "long-context", "quantized-pool"])
+    (dict(kv_cache_dtype="int8"), "kv_cache_dtype")], ids=["quantized-pool"])
 def test_what_knows_one_slot_a_layer_is_refused_by_name(params, kw, named):
     with pytest.raises(NotImplementedError, match=named):
         make_engine(params, **kw)

@@ -2,7 +2,6 @@
 ragged/kv_cache.py semantics): memory scales with allocated pages, the
 allocator recycles pages, and the fused SplitFuse step admits multiple
 prefilling requests into one tick."""
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -213,47 +212,3 @@ class TestDecodeKernelSelection:
         eng.generate_all(self._prompts(), max_new_tokens=3)
         assert interpreter() is None
         assert "paged_attention_decode" not in eng.serving_stages()["kernels"]
-
-    def test_a_speculative_verify_tick_never_asks(self, wide_params,
-                                                  interpreter):
-        eng = _wide_engine(wide_params, decode_block_size=4,
-                           speculation="ngram")
-        eng.generate_all(self._prompts(), max_new_tokens=9)
-        assert eng.host_stats.spec_dispatches > 0
-        assert interpreter() is None
-
-    def test_a_tick_with_a_carry_keeps_the_reference(self, interpreter):
-        """The chunk scan's finish dispatch folds earlier chunks' stats:
-        no kernel but the quantized pool's takes a carry."""
-        import flax.linen as nn
-
-        from deepspeed_tpu.inference import paged
-
-        S, Hq, Dh, P, page = 2, 8, 128, 4, 16
-        cfg = dataclasses.replace(WIDE, kv_num_pages=P, kv_page_size=page)
-
-        class Attend(nn.Module):
-            @nn.compact
-            def __call__(self, q, k, v, meta):
-                return paged.paged_update_and_attend(self, q, k, v, meta, cfg)
-
-        r = np.random.default_rng(0)
-        q, k, v = (jnp.asarray(r.standard_normal((1, Hq, S, Dh)),
-                               jnp.bfloat16) for _ in range(3))
-        meta = {"kv_lens": jnp.asarray([3, 1], jnp.int32),
-                "page_indices": jnp.asarray([[1, -1], [2, -1]], jnp.int32),
-                "cu_q_lens": jnp.arange(S + 1, dtype=jnp.int32),
-                "num_seqs": jnp.asarray([S], jnp.int32),
-                "new_kv_dest": jnp.asarray([page + 2, 2 * page], jnp.int32),
-                "live": jnp.asarray([True, True]),
-                "one_query_a_slot": True}
-        plain, _ = Attend().apply({}, q, k, v, meta, mutable=["cache"])
-        assert interpreter() == "pallas-decode-16x8-interpret"
-        m, l, acc = paged.neutral_carry(S, Hq, Dh)
-        carried, _ = Attend().apply(
-            {}, q, k, v, dict(meta, carry_m=m, carry_l=l, carry_acc=acc),
-            mutable=["cache"])
-        assert interpreter() == "pallas-decode-16x8-interpret"   # not asked
-        np.testing.assert_allclose(np.asarray(plain, np.float32),
-                                   np.asarray(carried, np.float32),
-                                   atol=1.6e-2)

@@ -234,40 +234,6 @@ def test_tiny_magnitude_rows_survive_roundtrip(fmt, tol):
     assert np.abs(deq).max() > 0, "rows collapsed to zero (old floor)"
 
 
-@pytest.mark.parametrize("fmt", ["int8", "fp8"])
-def test_pallas_kernel_carry_in_stats_out_matches_reference(fmt):
-    """The chunk-scan variant: an incoming (m, l, acc) carry folds exactly
-    as in the XLA reference, the returned raw stats normalize to the
-    output, and a neutral carry changes nothing."""
-    from deepspeed_tpu.inference.paged import neutral_carry
-
-    pages, scales = _pool(fmt)
-    q, kv_lens, pi, cu, ns = _meta()
-    T, H, D = q.shape
-    r = np.random.default_rng(1)
-    carry = (jnp.asarray(r.standard_normal((T, H)), jnp.float32),
-             jnp.asarray(r.random((T, H)) + 0.5, jnp.float32),
-             jnp.asarray(r.standard_normal((T, H, D)), jnp.float32))
-    ref = ref_paged_attention_quant(q, pages, scales, kv_lens, pi, cu, ns,
-                                    sm_scale=SM, sliding_window=7,
-                                    carry=carry)
-    ker, (m, l, acc) = ragged_paged_attention_quant(
-        q, pages, scales, kv_lens, pi, cu, ns, sm_scale=SM,
-        sliding_window=7, carry=carry, return_stats=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=5e-6)
-    assert m.shape == l.shape == (T, H) and acc.shape == (T, H, D)
-    np.testing.assert_allclose(
-        np.asarray(acc / jnp.maximum(l, 1e-30)[..., None]),
-        np.asarray(ker), atol=5e-6)
-
-    plain = ragged_paged_attention_quant(q, pages, scales, kv_lens, pi, cu,
-                                         ns, sm_scale=SM, interpret=True)
-    seeded = ragged_paged_attention_quant(
-        q, pages, scales, kv_lens, pi, cu, ns, sm_scale=SM,
-        carry=neutral_carry(T, H, D), interpret=True)
-    np.testing.assert_array_equal(np.asarray(seeded), np.asarray(plain))
-
-
 def test_pallas_kernel_blocks_over_query_rows():
     """More query rows than one VMEM block (128): sequences that span, end
     in and start in different blocks, and padded tail rows."""

@@ -180,7 +180,6 @@ def test_a_step_is_booked_under_the_program_it_dispatched(served):
     steps = [s for s in spans if s[0] == "engine.step"]
     assert got["ticks"] == got["fused_steps"] == len(ticks) == st.fused_ticks
     assert got["decode_block_steps"] == len(blocks) == st.decode_blocks
-    assert got["spec_steps"] == 0
     assert got["none_steps"] == len(steps) - len(ticks) - len(blocks)
     for program, inners in (("fused", ticks), ("decode_block", blocks)):
         mine = [s for s in steps

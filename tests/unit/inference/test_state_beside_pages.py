@@ -244,11 +244,8 @@ def test_one_ahead_dispatch_reads_the_state_its_predecessor_wrote(fam):
 
 @pytest.mark.parametrize("kw,named", [
     (dict(prefix_cache={"enabled": True}), "prefix_cache"),
-    (dict(speculation={"mode": "ngram", "k": 2}), "speculation"),
-    (dict(kv_tiering={"host_pages": 32, "long_context": True}),
-     "long_context"),
     (dict(kv_cache_dtype="int8"), "kv_cache_dtype")],
-    ids=["prefix-cache", "speculation", "long-context", "quantized-pool"])
+    ids=["prefix-cache", "quantized-pool"])
 def test_what_cannot_carry_the_state_is_refused_by_name(fam, kw, named):
     with pytest.raises(NotImplementedError, match=named):
         fam.make(**kw)

@@ -285,9 +285,6 @@ def test_the_window_pool_has_no_default_size(params):
 
 
 ASK = {"prefix_cache": dict(prefix_cache={"enabled": True}),
-       "speculation": dict(speculation={"mode": "ngram", "k": 2}),
-       "long_context": dict(kv_tiering={"host_pages": 32,
-                                        "long_context": True}),
        "tiering": dict(kv_tiering={"host_pages": 32}),
        "quantized": dict(kv_cache_dtype="int8"),
        "tensor_parallel": dict(tensor_parallel=2)}
@@ -295,8 +292,6 @@ _IS = ("TrinityForCausalLM keeps the keys and values of 4 of its 5 layers in "
        "a pool of their own, whose pages a sequence gives back as its window "
        "of 12 leaves them behind")
 NAMED = {"prefix_cache": "prefix_cache: ", "tiering": "kv_tiering: ",
-         "speculation": "speculation.mode='ngram': ",
-         "long_context": "kv_tiering.long_context: ",
          "quantized": "kv_cache_dtype='int8': ",
          "tensor_parallel": "tensor-parallel serving: "}
 

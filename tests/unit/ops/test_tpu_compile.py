@@ -943,18 +943,3 @@ def test_quantized_pages_kernel(chip_compile, pool_dtype):
                           sliding_window=WINDOW),
         ((T, H, D), jnp.bfloat16), ((POOL, PAGE, 2 * HKV, D), pool_dtype),
         ((POOL, PAGE, 2 * HKV), jnp.float32), *META)
-
-
-def test_quantized_pages_kernel_chunk_scan_carry(chip_compile):
-    """The long-context scan's variant: carry in, stats out."""
-    def attend(q, pages, scales, kv_lens, pi, cu, ns, m, l, acc):
-        return ragged_paged_attention_quant(
-            q, pages, scales, kv_lens, pi, cu, ns, sm_scale=D ** -0.5,
-            sliding_window=WINDOW, carry=(m, l, acc), return_stats=True)
-
-    chip_compile(
-        attend, ((T, H, D), jnp.bfloat16),
-        ((POOL, PAGE, 2 * HKV, D), jnp.int8),
-        ((POOL, PAGE, 2 * HKV), jnp.float32), *META,
-        ((T, H), jnp.float32), ((T, H), jnp.float32),
-        ((T, H, D), jnp.float32))

@@ -202,3 +202,39 @@ def test_sp_loss_matches_dp_loss(devices):
         results[name] = [float(jax.device_get(
             engine.train_batch(batch=batch))) for _ in range(3)]
     np.testing.assert_allclose(results["dp"], results["sp"], rtol=2e-4)
+
+
+class TestUlyssesCommBytes:
+    """Uneven-head Ulysses a2a: the byte accounting (the mesh parity tests
+    are above)."""
+
+    def test_uneven_kv_bytes_at_kv_head_rate(self):
+        from deepspeed_tpu.sequence import ulysses_comm_bytes
+
+        plan = ulysses_comm_bytes((2, 8, 64, 16), (2, 2, 64, 16), sp=4)
+        # replicate ships H/sp=2 kv-head-pairs/device; once ships the
+        # single kv head each query block consumes
+        assert plan["kv_bytes_once"] < plan["kv_bytes_replicate"]
+        assert plan["kv_once_ratio"] == 0.5
+        assert plan["total_once"] < plan["total_replicate"]
+
+    def test_even_heads_unchanged(self):
+        from deepspeed_tpu.sequence import ulysses_comm_bytes
+
+        plan = ulysses_comm_bytes((2, 8, 64, 16), (2, 4, 64, 16), sp=4)
+        assert "kv_bytes_even" in plan
+
+    def test_uneven_plan_covers_every_query_head(self):
+        from deepspeed_tpu.sequence.layer import _uneven_kv_plan
+
+        for H, Hkv, sp in [(8, 2, 4), (16, 2, 8), (12, 3, 4),
+                           (8, 2, 8)]:
+            idx, lmap, m = _uneven_kv_plan(H, Hkv, sp)
+            g, Hl = H // Hkv, H // sp
+            assert idx.shape == (sp * m,)
+            for r in range(sp):
+                dev_heads = idx[r * m:(r + 1) * m]
+                for j in range(Hl):
+                    want = (r * Hl + j) // g
+                    assert dev_heads[lmap[r, j]] == want, (H, Hkv, sp, r,
+                                                          j)

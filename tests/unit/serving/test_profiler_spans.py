@@ -32,8 +32,8 @@ CFG = get_config("tinyllama", vocab_size=64, hidden_size=32,
 SIZES = (21, 5, 13)             # prompts cross the 8-token prefill chunk
 MAX_NEW = 9                     # one fused token, then two decode blocks
 K = 4                           # decode block
-# (``engine.sample`` is the long-context driver's host sampler alone: the
-# fused tick samples inside its program)
+# (no loop of the engine opens ``engine.sample``: its ticks sample inside
+# their programs)
 LEAVES = ("engine.plan", "engine.upload", "engine.rng_split",
           "engine.dispatch", "engine.fetch", "engine.harvest",
           "engine.reap")

@@ -4,7 +4,7 @@ The load-bearing contracts:
 
 - **Restore is bit-identical to never having spilled**: a spilled
   sequence's pages come back exactly (greedy AND seeded sampling,
-  pipeline on/off, speculation on) — restore is a page upload, not a
+  pipeline on/off) — restore is a page upload, not a
   re-prefill, and tiering-on greedy output equals tiering-off output
   while ``evictions`` drops to zero.
 - **Tiering off is byte-for-byte today's engine**: ``tiering is None``,
@@ -241,14 +241,6 @@ class TestEngineTiering:
         _assert_same_outputs(a, b)
         ea.close()
         eb.close()
-
-    def test_speculation_composes_with_tiering(self, params):
-        eng_kw = dict(speculation="ngram")
-        off, _ = _serve(params, None, eng_kw=eng_kw)
-        on, eon = _serve(params, {"host_pages": 64}, eng_kw=eng_kw)
-        assert eon.spills > 0
-        _assert_same_outputs(off, on)
-        eon.close()
 
     def test_nvme_tier_parity(self, params, tmp_path):
         off, _ = _serve(params, None, sizes=[12, 20, 9, 16, 14, 18])

@@ -17,9 +17,9 @@ The load-bearing contracts:
   engine's external-holders map via ``audit_kv_sharing``) holds at
   every step under COW + spill pressure.
 - **Composition**: tiering (shared pages are spill-exempt via
-  spill-holds; demoted index pages revive once for all waiters) and
-  speculation compose without output changes; steady state adds zero
-  new compilations.
+  spill-holds; demoted index pages revive once for all waiters)
+  composes without output changes; steady state adds zero new
+  compilations.
 """
 import jax
 import jax.numpy as jnp
@@ -363,17 +363,6 @@ class TestPrefixComposition:
                         [on[k] for k in sorted(on)]):
             np.testing.assert_array_equal(a, b)
         eng.audit_kv_sharing()
-        eng.close()
-
-    def test_composes_with_speculation_greedy(self, params):
-        prompts = _shared_prompts(8)
-        off = _serve(make(params, prefix=False, speculation="ngram"),
-                     prompts)
-        eng = make(params, prefix=True, speculation="ngram")
-        on = _serve(eng, prompts)
-        assert eng.host_stats.spec_dispatches > 0
-        assert eng.serving_stages()["prefix_cache"]["hit_requests"] > 0
-        _assert_same_outputs(off, on)
         eng.close()
 
     def test_zero_new_compiles_steady_state(self, params):

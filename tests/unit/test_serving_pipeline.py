@@ -595,23 +595,6 @@ class TestFusedTicksAhead:
                                        rtol=1e-5)
 
 
-def _family(family):
-    """``(model, weights)`` of a tiny member of ``family`` in float32: the
-    dense llama of this file, OLMoE's dropless experts, Ouro's looped
-    layers, LFM2's convolution state beside the pages."""
-    if family in ("olmoe", "ouro"):
-        return _tiny(family)
-    if family == "llama":
-        model = LlamaForCausalLM(CFG)
-    else:
-        from deepspeed_tpu.models import lfm2_moe
-        model = lfm2_moe.Lfm2MoeForCausalLM(lfm2_moe.get_config(
-            "tinylfm2", dtype=jnp.float32, param_dtype=jnp.float32,
-            remat=False, use_flash_attention=False))
-    return model, jax.jit(model.init)(
-        jax.random.PRNGKey(7), np.zeros((1, 8), np.int32))["params"]
-
-
 CALLERS = 4
 
 
@@ -657,12 +640,13 @@ class TestOneQueueOfDispatchedWork:
     nothing in flight; what needs a token's value drains, and says why."""
 
     @pytest.mark.parametrize("kw", [{}, SEEDED], ids=["greedy", "seeded"])
-    @pytest.mark.parametrize("family", ["llama", "olmoe", "ouro", "lfm2"])
     def test_each_requests_tokens_are_the_unpipelined_runs_in_a_closed_loop(
-            self, family, kw):
-        model, weights = _family(family)
-        on, eng, _ = _closed_loop(model, weights, True, **kw)
-        off, _, _ = _closed_loop(model, weights, False, **kw)
+            self, params, kw):
+        """(By every other tiny serving model:
+        ``tests/unit/inference/test_loop_contracts*.py``.)"""
+        model = LlamaForCausalLM(CFG)
+        on, eng, _ = _closed_loop(model, params, True, **kw)
+        off, _, _ = _closed_loop(model, params, False, **kw)
         assert len(on) == 12
         _assert_same_outputs(on, off)
         st = eng.host_stats
@@ -679,7 +663,7 @@ class TestOneQueueOfDispatchedWork:
         trace.clear()
         trace.configure(enabled=True)
         try:
-            _, eng, _ = _closed_loop(*_family("llama"), True)
+            _, eng, _ = _closed_loop(LlamaForCausalLM(CFG), params, True)
             spans = [e["args"] for e in trace.snapshot()
                      if e["name"] == "engine.dispatch"]
         finally:
@@ -707,7 +691,7 @@ class TestOneQueueOfDispatchedWork:
         assert len(after_a_block) >= 3
         assert all(b["ahead"] == 1 for b in after_a_block)
 
-    @pytest.mark.parametrize("case", ["eos", "pages", "handoff", "spec"])
+    @pytest.mark.parametrize("case", ["eos", "pages", "handoff"])
     def test_what_needs_a_tokens_value_drains_and_says_why(self, params,
                                                            case):
         """The same traffic that never drains, with the one thing added
@@ -719,10 +703,8 @@ class TestOneQueueOfDispatchedWork:
             eng_kw = dict(max_seqs=3, page_size=8, num_pages=9,
                           kv_reserve="on_demand")
             kw["max_new_tokens"] = 24
-        elif case == "handoff":
-            extra = dict(handoff=True)
         else:
-            eng_kw = dict(speculation={"mode": "ngram", "k": 2})
+            extra = dict(handoff=True)
         eng = make(params, True, **eng_kw)
         for i, p in enumerate(_prompts([12, 20, 9], seed=3)):
             eng.put_request(p, **kw, **(extra if i == 1 else {}))
