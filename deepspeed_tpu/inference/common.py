@@ -123,7 +123,9 @@ class HostStageStats:
     admission, ``waiting`` / ``admitted``; ``engine.plan.grow``: the page
     growth that decides between a decode block and a tick, ``rows``;
     ``engine.plan.rows``: ``_plan_rows``, ``decode_rows`` /
-    ``prefill_seqs`` / ``pages_granted`` / ``page_limited``;
+    ``prefill_seqs`` / ``pages_granted`` / ``page_limited`` /
+    ``prompt_rows`` / ``prompt_tiles`` (the prompt rows handed out and the
+    row tiles of 128 their chunks span as they lie in the tick);
     ``engine.plan.pack``: ``_pack_tick``'s arrays, ``rows`` / ``seqs`` /
     ``page_cells``; ``engine.plan.counts``: the cache kind's host-side
     counts for the dispatch span, ``rows``; ``engine.plan.attrs``: the
@@ -147,7 +149,10 @@ class HostStageStats:
     divides the fused steps' by their number: ``host_ms`` (wall less
     wait), ``wait_ms`` and ``<span>_ms`` for every stage and leaf, on the
     tracer's clock, so a run with no profiler session gives the table
-    that ``fused_tick_host_ms`` reads from a traced one.
+    that ``fused_tick_host_ms`` reads from a traced one; and the sums of
+    the plans' ``prompt_rows`` and ``prompt_tiles`` with their quotient,
+    ``prompt_rows_per_tile``: 128 where every chunk is whole tiles from
+    row 0, 128 x 8/9 where every chunk buys a ninth tile for a few rows.
 
     ``serving_stages()`` reports per-dispatch milliseconds plus
     ``host_bound_fraction`` = host-stage time / (host + device-wait).
@@ -236,6 +241,11 @@ class HostStageStats:
         self.prefill_key_tokens = 0   # keys those tokens attend to
         self.decode_key_token_steps = 0   # context tokens x token steps
         self.fused_ticks = 0        # SplitFuse ticks dispatched
+        # the prompt rows the plans of ticks handed out, and the row tiles
+        # of 128 their chunks span as they lie in the tick (a chunk of
+        # 1,028 rows from row 4 spans nine, one of 1,024 from row 0 eight)
+        self.prompt_rows = 0
+        self.prompt_tiles = 0
         # ... of them with earlier work un-folded (dispatched ahead)
         self.fused_ticks_ahead = 0
         self.decode_blocks = 0      # plain decode blocks dispatched
@@ -381,7 +391,9 @@ class HostStageStats:
         inside ``engine.plan``, each from its span's own duration, so the
         table needs no profiler session.  ``<program>_steps`` /
         ``_wall_s`` / ``_wait_s``: every step of the run by what it
-        dispatched."""
+        dispatched.  ``prompt_rows`` / ``prompt_tiles``: what
+        ``engine.plan.rows`` said, summed, and ``prompt_rows_per_tile``
+        their quotient, the rows a tile the chunk kernels span holds."""
         fetch = self.SPAN_NAMES["device"]
         out: Dict[str, Any] = {}
         for p, r in self.steps_by_program.items():
@@ -396,6 +408,11 @@ class HostStageStats:
             out["wait_ms"] = round(wait * 1e3 / n, 4)
             for name, s in sorted(fused["by_span_s"].items()):
                 out[f"{name}_ms"] = round(s * 1e3 / n, 4)
+        out["prompt_rows"] = self.prompt_rows
+        out["prompt_tiles"] = self.prompt_tiles
+        if self.prompt_tiles:
+            out["prompt_rows_per_tile"] = round(
+                self.prompt_rows / self.prompt_tiles, 4)
         return out
 
 

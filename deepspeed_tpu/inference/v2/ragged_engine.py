@@ -118,6 +118,36 @@ from deepspeed_tpu.inference.v2 import programs
 from deepspeed_tpu.inference.v2.kv_state import KVState
 from deepspeed_tpu.utils.logging import log_dist
 
+# rows of one tile of every chunk kernel a fused tick runs (the scans'
+# ``CHUNK``, the attentions' ``TILE`` / ``_TILE_TOKENS`` under ``ops/``;
+# ``tests/unit/inference/test_plan_tiles.py`` holds the seven to it).  The
+# plan of a tick hands prompt rows out in whole tiles of it and packs them
+# from the tick's row 0, so no kernel is asked for a tile of a few rows.
+# The engine's to know: no option, no config key
+ROW_TILE = 128
+# ... where the rows a chunk gives up for it are this few.  A tile part
+# full costs the chunk kernels a whole tile and the rows in it cost every
+# other kernel ``rows / ROW_TILE`` of one, so holding them back pays where
+# the chunk kernels are a larger share of a tick than that: at 8 rows, 6 %,
+# which the least of them is (``paged_chunk_attention`` in Mistral's ticks,
+# 6.8 % of busy time; 26-43 % in the long-prompt models: PERF.md section 6,
+# PR 63, where 32 rows held back of 544 read -3.5 %)
+ROWS_GIVEN_UP = ROW_TILE // 16
+
+
+def whole_tiles(rest: int, room: int, first: bool) -> int:
+    """The prompt rows a sequence with ``rest`` left takes of ``room``: all
+    it has left; else the whole row tiles the room holds, where the part
+    tile given up is :data:`ROWS_GIVEN_UP` rows or fewer; else the room.
+    ``first``: no chunk leads the tick yet, and the first is never nothing
+    (a room under a tile is all a tiny tick or a short pool has)."""
+    if rest <= room:
+        return rest
+    part = room % ROW_TILE
+    if part > ROWS_GIVEN_UP or (first and part == room):
+        return room
+    return room - part
+
 
 @dataclasses.dataclass
 class Request:
@@ -211,9 +241,10 @@ class RaggedInferenceEngineV2:
 
     One ``step()`` = (admit waiting requests into free slots, reserving
     KV pages) + EITHER one fused SplitFuse tick (any sequence still
-    prefilling: a decode token for every ready sequence plus prompt
-    chunks, in one ``T = max_seqs + prefill_chunk`` batch) OR one
-    ``decode_block_size``-tick on-device decode block (everyone
+    prefilling: prompt chunks of whole row tiles from row 0 of the batch,
+    tails and part tiles behind them, then a decode token for every ready
+    sequence, in one batch of ``T = max_seqs + prefill_chunk`` rows) OR
+    one ``decode_block_size``-tick on-device decode block (everyone
     decoding).
     """
 
@@ -1876,11 +1907,18 @@ class RaggedInferenceEngineV2:
     def _plan_rows(self):
         """Who runs in the next SplitFuse tick: one decode token per
         ready sequence plus prompt chunks for prefilling sequences, as
-        far as the pool backs them.  Grows pages and changes nothing
-        else, so a plan can be thrown away and made again
-        (``_page_limited``: some growth fell short; ``_stalled``: who
-        sits the tick out for it).  Returns the prompt tokens each
-        prefilling sequence takes, by uid; None when nothing can run."""
+        far as the pool backs them.  A prefilling sequence takes the rest
+        of its prompt where the tick's rows (and the pool) hold it, else
+        the whole row tiles (:data:`ROW_TILE`) they hold where that gives
+        up a few rows only (:func:`whole_tiles`: 1,024 of a tick of 1,032
+        whatever the decode rows beside it, and the rows left go to no
+        one), so a prompt advances by whole tiles up to its tail.  Grows
+        pages and changes nothing else, so a plan can be thrown away and
+        made again (``_page_limited``: some growth fell short;
+        ``_stalled``: who sits the tick out for it).  Returns the prompt
+        tokens each prefilling sequence takes, by uid, IN THE ORDER THE
+        TICK PACKS THEM (whole-tile chunks, then the others; by uid
+        within each); None when nothing can run."""
         with self.host_stats.leaf("plan.rows") as leaf:
             free = self.allocator.free_pages
             self._stalled = []
@@ -1902,17 +1940,20 @@ class RaggedInferenceEngineV2:
             budget = self.T - len(decode_rs)
             takes: Dict[int, int] = {}
             for r in prefill_rs:
-                take = min(budget, r.ctx_len - r.prefill_done)
+                rest = r.ctx_len - r.prefill_done
+                take = whole_tiles(rest, budget, not takes)
                 if take <= 0:
                     continue        # batch-budget-limited, not stalled
                 if not self.kv.ensure(r.slot, r.prefill_done + take,
                                       r.prefill_done):
                     # partial growth: cover what the pool allows this tick
                     # (cold prefix-index pages count — KVState.ensure
-                    # reclaims them on demand)
+                    # reclaims them on demand), in whole tiles too
                     self._page_limited = True
                     coverable = self.kv.coverable_tokens(r.slot)
-                    take = min(take, coverable - r.prefill_done)
+                    take = whole_tiles(
+                        rest, min(take, coverable - r.prefill_done),
+                        not takes)
                     if take <= 0:
                         self._stalled.append(r)     # page-limited
                         continue
@@ -1921,14 +1962,30 @@ class RaggedInferenceEngineV2:
                 takes[r.uid] = take
                 budget -= take
             self._page_limited |= bool(self._stalled)
+            # whole-tile chunks lie first, so each starts on a tile of the
+            # tick's rows; at most one sequence a tick crosses a boundary
+            takes = dict(sorted(
+                takes.items(), key=lambda kv: kv[1] % ROW_TILE != 0))
+            # the kernels' tiles that the chunks span as they lie
+            rows = tiles = 0
+            for n in takes.values():
+                tiles += (rows + n - 1) // ROW_TILE - rows // ROW_TILE + 1
+                rows += n
             leaf.add(decode_rows=len(decode_rs), prefill_seqs=len(takes),
                      pages_granted=free - self.allocator.free_pages,
-                     page_limited=int(self._page_limited))
+                     page_limited=int(self._page_limited),
+                     prompt_rows=rows, prompt_tiles=tiles)
+            self.host_stats.prompt_rows += rows
+            self.host_stats.prompt_tiles += tiles
             return takes if decode_rs or takes else None
 
     def _pack_tick(self, takes: Dict[int, int]):
         """``_plan_rows``'s tick as the arrays the fused program takes, all
-        in ONE batch: ``(arrays, sampled, sampling, rows, counts)``.
+        in ONE batch: ``(arrays, sampled, sampling, rows, counts)``.  The
+        prompt chunks of ``takes`` lie first, in its order (the plan's:
+        whole-tile chunks from row 0, so each starts on a row tile of the
+        tick, then tails and part tiles), the one-row sequences behind
+        them.
         A decode row whose token a tick in flight sampled reads it on the
         device (``tok_src``); ``sampling``: (request, packed sequence
         number) of who samples; ``sampled``: any of them by a draw, so the
@@ -1960,14 +2017,19 @@ class RaggedInferenceEngineV2:
             counts = dict(prefill_tokens=0, prefill_key_tokens=0,
                           decode_key_tokens=0)
 
-            # pack sequences in slot order (any fixed order works; the kernel
-            # sees sequences via cu_q_lens row j)
+            # the prompt chunks in the plan's order (whole tiles from row 0,
+            # then the others), the one-row sequences behind them in slot
+            # order: the kernels see sequences via cu_q_lens row j, and every
+            # array indexed by j or by row t is built by this one loop
             stalled_uids = {r.uid for r in self._stalled}
+            live = {r.uid: r for r in self.slots
+                    if r is not None and not r.done
+                    and r.uid not in stalled_uids}
             t = 0
             j = 0
-            for r in [s for s in self.slots if s is not None]:
-                if r.done or r.uid in stalled_uids:
-                    continue
+            for r in ([live[u] for u in takes if u in live] +
+                      [r for r in live.values()
+                       if r.prefill_done >= r.ctx_len]):
                 self._last_sched[r.slot] = self._sched_seq
                 seq_slot[j] = r.slot
                 if r.prefill_done >= r.ctx_len:                 # decode: 1 tok
@@ -1989,9 +2051,7 @@ class RaggedInferenceEngineV2:
                     t += 1
                     j += 1
                 else:                                           # prefill chunk
-                    take = takes.get(r.uid, 0)
-                    if take <= 0:
-                        continue
+                    take = takes[r.uid]
                     lo = r.prefill_done
                     token_ids[t:t + take] = r.ctx[lo:lo + take]
                     pos = np.arange(lo, lo + take)

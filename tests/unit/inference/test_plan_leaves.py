@@ -28,7 +28,8 @@ MAX_NEW = 9                     # one fused token, then two decode blocks
 FUSED_LEAVES = {"engine.plan.admit": {"waiting", "admitted"},
                 "engine.plan.grow": {"rows"},
                 "engine.plan.rows": {"decode_rows", "prefill_seqs",
-                                     "pages_granted", "page_limited"},
+                                     "pages_granted", "page_limited",
+                                     "prompt_rows", "prompt_tiles"},
                 "engine.plan.pack": {"rows", "seqs", "page_cells"},
                 "engine.plan.counts": {"rows"},
                 "engine.plan.attrs": set()}
