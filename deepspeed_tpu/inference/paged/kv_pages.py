@@ -34,7 +34,16 @@ sublanes of a page's tiles, both Pallas kernels need whole tiles of them
 with zero heads before the call and drops the padded heads' (zero-query)
 outputs after it, so the result is exact; a token costs the pool 32 heads'
 bytes, a fifteenth more.  Only where the padding is that cheap: two or four
-KV heads are stored as they are.
+KV heads are stored as they are.  **Four** (``models/smallthinker.py``: plain
+GQA, 28 query heads over four) take both kernels all the same: a token's eight
+combined heads are half a sublane tile, so the kernels fetch and buffer the
+pool a PAIR of tokens a tile (``ops/paged_decode.py:tokens_a_tile``: ``[P,
+page / 2, 16, Dh]``, the same bytes where they lie) and a token costs the pool
+and a decode tick its own four heads' bytes, not eight's.  **Two** would be
+four tokens a tile, which no configuration reads through these kernels (the
+one model with two KV heads attends chosen blocks of a pool of its own
+layout): that tile has never been handed to the compiler, and such a pool
+keeps the XLA twin.
 """
 from __future__ import annotations
 

@@ -82,7 +82,10 @@ class MoE(nn.Module):
     eval_capacity_factor: float = 2.0
     min_capacity: int = 4
     drop_tokens: bool = True
-    activation: str = "swiglu"             # "swiglu" (Mixtral) | "gelu"
+    # the expert body: "swiglu" (Mixtral: w2(silu(w1 x) * w3 x)), "reglu"
+    # (SmallThinker: w2(relu(w1 x) * w3 x), the same three banks) or "gelu"
+    # (the biased pair w2 gelu(w1 x + b1) + b2); any other value raises
+    activation: str = "swiglu"
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     expert_parallel: bool = True           # annotate the expert mesh axis
@@ -194,26 +197,34 @@ class MoE(nn.Module):
                 init = nn.with_partitioning(init, spec)
             return self.param(name, init, shape, cfg.param_dtype)
 
-        if cfg.activation == "swiglu":                           # Mixtral
+        if cfg._glu_gate() is not None:          # the gated bodies' banks
             return {"w1": expert_param("w1", (E, M, I), (ep, None, tp)),
                     "w3": expert_param("w3", (E, M, I), (ep, None, tp)),
                     "w2": expert_param("w2", (E, I, M), (ep, tp, None))}
-        elif cfg.activation == "gelu":
-            return {"w1": expert_param("w1", (E, M, I), (ep, None, tp)),
-                    "b1": expert_param("b1", (E, I), (ep, tp), bias=True),
-                    "w2": expert_param("w2", (E, I, M), (ep, tp, None)),
-                    "b2": expert_param("b2", (E, M), (ep, None), bias=True)}
-        raise ValueError(f"unknown MoE activation {cfg.activation!r}")
+        return {"w1": expert_param("w1", (E, M, I), (ep, None, tp)),
+                "b1": expert_param("b1", (E, I), (ep, tp), bias=True),
+                "w2": expert_param("w2", (E, I, M), (ep, tp, None)),
+                "b2": expert_param("b2", (E, M), (ep, None), bias=True)}
+
+    def _glu_gate(self):
+        """What a gated body puts on ``w1 x`` (``swiglu``: silu, ``reglu``:
+        relu); None for the biased GELU pair; any other name raises."""
+        gates = {"swiglu": nn.silu, "reglu": nn.relu, "gelu": None}
+        if self.activation not in gates:
+            raise ValueError(
+                f"unknown MoE activation {self.activation!r}: one of "
+                f"{sorted(gates)}")
+        return gates[self.activation]
 
     def _expert_ffn(self, disp: jax.Array, w) -> jax.Array:
         """[E?, C, M] dispatched tokens -> [E?, C, M] expert outputs (the
         leading dim is global E on the einsum path, local E/ep under the
         alltoall shard_map)."""
-        dt = self.dtype
-        if self.activation == "swiglu":
+        dt, gate = self.dtype, self._glu_gate()
+        if gate is not None:
             h = jnp.einsum("ecm,emi->eci", disp, w["w1"].astype(dt))
             u = jnp.einsum("ecm,emi->eci", disp, w["w3"].astype(dt))
-            return jnp.einsum("eci,eim->ecm", nn.silu(h) * u,
+            return jnp.einsum("eci,eim->ecm", gate(h) * u,
                               w["w2"].astype(dt))
         h = jnp.einsum("ecm,emi->eci", disp, w["w1"].astype(dt))
         h = jax.nn.gelu(h + w["b1"].astype(dt)[:, None])
@@ -225,11 +236,11 @@ class MoE(nn.Module):
         """[R, M] expert-sorted rows -> [R, M]: the same FFN as
         :meth:`_expert_ffn`, each expert's run of rows against its own
         bank."""
-        dt = self.dtype
+        dt, gate = self.dtype, self._glu_gate()
         grouped_matmul_route()
         dot = lambda a, b: grouped_matmul(a, b.astype(dt), group_sizes)
-        if self.activation == "swiglu":
-            return dot(nn.silu(dot(rows, w["w1"])) * dot(rows, w["w3"]),
+        if gate is not None:
+            return dot(gate(dot(rows, w["w1"])) * dot(rows, w["w3"]),
                        w["w2"])
         # a row's bias is its expert's: expand by the same group sizes
         total = rows.shape[0]
@@ -370,11 +381,19 @@ class MoE(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, is_training: bool = True,
-                 live: Optional[jax.Array] = None
-                 ) -> Tuple[jax.Array, jax.Array]:
+                 live: Optional[jax.Array] = None, *,
+                 route_only: bool = False,
+                 routing: Optional[Tuple[jax.Array, jax.Array]] = None):
         """``live`` (flat bool, one per row of ``x``; None: all) is read
         by the dropless path only: rows that are no token take no expert
-        row and count in no counter."""
+        row and count in no counter.
+
+        A router that reads another tensor than the rows it dispatches
+        (SmallThinker: the layer's input, before its attention) takes two
+        calls of the SAME module, dropless path only: ``routing = moe(h,
+        route_only=True)`` where the router's input is at hand, its matmul
+        and top-k issued there, then ``moe(x, live=live, routing=routing)``
+        with the rows.  A caller that passes neither routes from ``x``."""
         cfg = self
         orig_shape = x.shape
         M, E = cfg.hidden_size, cfg.num_experts
@@ -385,7 +404,8 @@ class MoE(nn.Module):
         routed = E * cfg.expert_shards          # the router's width
         wg = self.param("gate", nn.initializers.lecun_normal(), (M, routed),
                         jnp.float32)
-        w = self._expert_params()
+        # the call that only routes makes no bank
+        w = None if route_only else self._expert_params()
         bias = (self.param(
             "expert_bias", nn.initializers.normal(cfg.expert_bias_init_std),
             (routed,), jnp.float32) if cfg.expert_bias else None)
@@ -411,7 +431,16 @@ class MoE(nn.Module):
                 f"expert_shards={cfg.expert_shards} with dispatch_impl="
                 f"{impl!r}: a layer that holds a share of its router's "
                 "experts has its dropless serving form only")
-        if impl == "grouped":
+        if (route_only or routing is not None) and (
+                impl != "grouped" or sigmoid):
+            raise NotImplementedError(
+                f"a router input apart from the dispatched rows with "
+                f"dispatch_impl={impl!r}, scoring={cfg.scoring!r}: the "
+                "dropless softmax form only")
+        if impl == "grouped" and routing is not None:
+            experts, weights = routing
+            l_aux = jnp.zeros((), jnp.float32)
+        elif impl == "grouped":
             # float32 for real: a TPU's default f32 matmul is one bf16
             # pass, and a served token's experts are not to turn on that
             with jax.named_scope("moe.route"):
@@ -431,6 +460,9 @@ class MoE(nn.Module):
                 else:
                     experts, weights, l_aux = topk_routing(
                         logits, cfg.k, cfg.normalize_weights)
+        if route_only:
+            return experts, weights
+        if impl == "grouped":
             flat_live = None if live is None else live.reshape(-1)
             y, counts = grouped_moe(
                 x.astype(cfg.dtype), experts, weights, E,

@@ -44,12 +44,15 @@ p·V product are rounded to the pool's dtype.  The pool layout
 ``[P, page, 2 * Hkv, D]`` is unchanged.
 
 Mosaic needs ``D == 128``, whole sublane tiles of combined heads a token
-and whole lane tiles of rows a page (:func:`tiling` answers None
-otherwise, and the caller keeps the path it had).  Under
+(at FOUR KV heads: a PAIR of tokens, :func:`tokens_a_tile`; the pool is then
+fetched and buffered as ``[P, page / 2, 16, D]``, a bitcast of the array
+where it lies, and the body is the same to the letter) and whole lane tiles
+of rows a page (:func:`tiling` answers None otherwise, and the caller keeps
+the path it had).  Under
 ``interpret=True`` the same kernel runs through the Pallas interpreter at
 any head count (``tests/unit/ops/test_paged_decode.py``);
 ``tests/unit/ops/test_tpu_compile.py`` hands it to the chip's compiler at
-both serving configurations' widths.
+the serving configurations' widths.
 """
 from __future__ import annotations
 
@@ -83,18 +86,35 @@ _GROUP_BYTES = 1024 * 1024
 _STEP_ROWS = 2048
 
 
+def tokens_a_tile(kv_heads: int) -> Optional[int]:
+    """Tokens whose ``2 * kv_heads`` combined heads fill whole bf16 sublane
+    tiles of 16 rows: 1 at a multiple of eight KV heads; 2 at FOUR, where a
+    tile holds the eight combined heads of a PAIR of tokens (both kernels
+    then fetch and buffer the pool as ``[P, page / 2, 16, D]``, the same
+    bytes, and row ``t * Hkv + h`` of the packed words is token t's head h
+    as at any other count); None otherwise.  Two KV heads would be four
+    tokens a tile: no configuration reads two through these kernels (the one
+    with two attends chosen blocks of a pool of its own), so that layout has
+    never been handed to the compiler and keeps the path it had."""
+    if (2 * kv_heads) % 16 == 0:
+        return 1
+    return 2 if kv_heads == 4 else None
+
+
 def tiling(kv_heads: int, page_size: int, pages_per_seq: int,
            head_dim: int, dtype) -> Optional[Tuple[int, int]]:
     """``(pages a DMA group, pages a step of the body)`` from the shapes
     alone, or None where the kernel's layout does not hold: head_dim 128,
     a bf16 pool (K and V of a head are the halves of one 32-bit word, and
     a bf16 is the upper half of its float32), whole sublane tiles of
-    combined heads a token, and whole lane tiles of (token, KV head) pairs
+    combined heads a token or, at four KV heads, a pair of tokens
+    (:func:`tokens_a_tile`), and whole lane tiles of (token, KV head) pairs
     a page."""
     dtype = jnp.dtype(dtype)
     if head_dim != _LANES or dtype != jnp.bfloat16:
         return None
-    if (2 * kv_heads) % 16 or (page_size * kv_heads) % _LANES:
+    pair = tokens_a_tile(kv_heads)
+    if pair is None or page_size % pair or (page_size * kv_heads) % _LANES:
         return None
     page_bytes = page_size * 2 * kv_heads * head_dim * dtype.itemsize
     step = max(1, _STEP_ROWS // (page_size * kv_heads))
@@ -153,14 +173,17 @@ def _kernel(slot_ref, page0_ref, count_ref, flags_ref, nwork_ref,
             q_ref, pages_hbm, o_ref,
             buf, sems, m_sc, l_sc, acc_sc, *,
             sm_scale: float, sliding_window: Optional[int],
-            pages_per_seq: int):
-    # q_ref, o_ref: [S, H, D]; pages_hbm: [P, page, 2Hkv, D];
-    # buf: [2, G // step, step * page, 2Hkv, D], ``step`` pages multiplied
-    # at a time; m_sc, l_sc: [H, 1]; acc_sc: [H, D]
-    page = pages_hbm.shape[1]
-    _, steps, tokens, combined, D = buf.shape
+            pages_per_seq: int, page: int, Hkv: int):
+    # q_ref, o_ref: [S, H, D]; pages_hbm: [P, page / pair, pair * 2Hkv, D]
+    # (``pair`` tokens a sublane tile, :func:`tokens_a_tile`);
+    # buf: [2, G // step, step * page / pair, pair * 2Hkv, D], ``step``
+    # pages multiplied at a time; m_sc, l_sc: [H, 1]; acc_sc: [H, D]
+    combined = 2 * Hkv
+    _, steps, tiles_a_step, tile_rows, D = buf.shape
+    pair = tile_rows // combined
+    tokens = tiles_a_step * pair
     step = tokens // page
-    Hkv, group = combined // 2, steps * step
+    group = steps * step
     H = q_ref.shape[1]
     rows = tokens * Hkv              # (token, KV head) pairs of a step
     n_work = nwork_ref[0]
@@ -176,7 +199,8 @@ def _kernel(slot_ref, page0_ref, count_ref, flags_ref, nwork_ref,
                           + jnp.minimum(page0 + i, pages_per_seq - 1)], 0)
             yield i, pltpu.make_async_copy(
                 pages_hbm.at[page_id],
-                buf.at[slot, i // step, pl.ds(i % step * page, page)],
+                buf.at[slot, i // step,
+                       pl.ds(i % step * (page // pair), page // pair)],
                 sems.at[slot])
 
     def start(w, slot):
@@ -276,6 +300,10 @@ def _decode(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
     pages_per_seq = page_indices.shape[1]
     group, step = tiles
     assert group % step == 0, tiles
+    pair = tokens_a_tile(combined // 2) or 1   # the interpreter: any count
+    if pair > 1:
+        # a sublane tile of a token pair: the same bytes where they lie
+        pages = pages.reshape(-1, page // pair, pair * combined, D)
     work, n_work = work_list(
         kv_lens, live, page_size=page, pages_per_seq=pages_per_seq,
         group=group, sliding_window=sliding_window)
@@ -283,7 +311,8 @@ def _decode(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
     return pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale,
                           sliding_window=sliding_window,
-                          pages_per_seq=pages_per_seq),
+                          pages_per_seq=pages_per_seq, page=page,
+                          Hkv=combined // 2),
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
@@ -291,8 +320,8 @@ def _decode(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
             out_specs=whole,
             grid=(1,),
             scratch_shapes=[
-                pltpu.VMEM((2, group // step, step * page, combined, D),
-                           pages.dtype),
+                pltpu.VMEM((2, group // step, step * page // pair,
+                            pair * combined, D), pages.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),

@@ -56,8 +56,11 @@ working room.  At ``mistral-7b-serve``'s shapes (T 544, five row tiles of
 v5e's 128.
 
 Mosaic needs ``D == 128``, a bf16 pool, whole sublane tiles of combined
-heads a token and row tiles of whole sublane tiles (:func:`tiling`
-answers None otherwise, and the caller keeps the path it had).  Under
+heads a token (at FOUR KV heads a PAIR of tokens a tile,
+``paged_decode.tokens_a_tile``: a block is fetched and buffered as ``[keys /
+2, 16, D]`` and a head's rows are picked out of it at a stride of four) and
+row tiles of whole sublane tiles (:func:`tiling` answers None otherwise, and
+the caller keeps the path it had).  Under
 ``interpret=True`` the same kernel runs through the Pallas interpreter at
 any head count (``tests/unit/ops/test_paged_prefill.py``);
 ``tests/unit/ops/test_tpu_compile.py`` hands it to the chip's compiler at
@@ -109,12 +112,16 @@ def tiling(heads: int, kv_heads: int, page_size: int, head_dim: int,
     """``(tokens a row tile, pages a key block)`` from the shapes alone, or
     None where the kernel's layout does not hold: head_dim 128, a bf16 pool
     (K and V of a head are the halves of one 32-bit word), whole sublane
-    tiles of combined heads a token, whole query-head groups, and key
-    blocks of whole lane tiles."""
+    tiles of combined heads a token or, at four KV heads, a pair of tokens
+    (``paged_decode.tokens_a_tile``; two KV heads keep the path they had),
+    whole query-head groups, and key blocks of whole lane tiles."""
     dtype = jnp.dtype(dtype)
     if head_dim != _LANES or dtype != jnp.bfloat16:
         return None
-    if kv_heads < 1 or heads % kv_heads or (2 * kv_heads) % 16:
+    if kv_heads < 1 or heads % kv_heads:
+        return None
+    pair = paged_decode.tokens_a_tile(kv_heads)
+    if pair is None or page_size % pair:
         return None
     least, most = _TILE_TOKENS
     rt = min(most, max(least, _TILE_ROWS // (heads // kv_heads)))
@@ -177,19 +184,22 @@ def _kernel(seq_ref, block_ref, flags_ref, nwork_ref, kv_lens_ref, cu_ref,
             q_ref, pages_hbm, o_ref,
             buf, sems, k_sc, vt_sc, delta_sc, m_sc, l_sc, acc_sc, *,
             sm_scale: float, sliding_window: Optional[int],
-            pages_per_seq: int):
-    # q_ref, o_ref: [Hkv, G, T, D]; pages_hbm: [P, page, 2Hkv, D];
-    # buf: [2, keys, 2Hkv, D]; k_sc: [Hkv, keys, D]; vt_sc: [Hkv, D, keys];
+            pages_per_seq: int, page: int):
+    # q_ref, o_ref: [Hkv, G, T, D]; pages_hbm: [P, page / pair, pair * 2Hkv,
+    # D] and buf: [2, keys / pair, pair * 2Hkv, D] (``pair`` tokens a sublane
+    # tile, ``paged_decode.tokens_a_tile``); k_sc: [Hkv, keys, D];
+    # vt_sc: [Hkv, D, keys];
     # delta_sc: [keys, R] int32; m_sc, l_sc: [Hkv, tiles, 1, R];
     # acc_sc: [Hkv, tiles, D, R].  Scores, weights and the accumulator are
     # held TRANSPOSED (keys down, query rows across): a row's maximum and
     # sum are then reductions down the sublanes, which the vector unit
     # does, and not across the lanes, which the v5e does by seven rotations
     # a vreg (PERF.md, PR 36)
-    page = pages_hbm.shape[1]
-    _, keys, combined, D = buf.shape
-    Hkv, kb = combined // 2, keys // page
-    _, G, T, _ = q_ref.shape
+    Hkv, G, T, D = q_ref.shape
+    combined = 2 * Hkv
+    pair = buf.shape[2] // combined
+    keys = buf.shape[1] * pair
+    kb = keys // page
     R = delta_sc.shape[1]
     rt = R // G
     cols = max(1, keys // _LANES)
@@ -207,7 +217,8 @@ def _kernel(seq_ref, block_ref, flags_ref, nwork_ref, kv_lens_ref, cu_ref,
                 table_ref[s * pages_per_seq
                           + jnp.minimum(page0 + i, pages_per_seq - 1)], 0)
             yield page0 + i < held, pltpu.make_async_copy(
-                pages_hbm.at[page_id], buf.at[slot, pl.ds(i * page, page)],
+                pages_hbm.at[page_id],
+                buf.at[slot, pl.ds(i * (page // pair), page // pair)],
                 sems.at[slot])
 
     def start(w, slot):
@@ -380,6 +391,10 @@ def _chunk(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
     Hkv = combined // 2
     G = H // Hkv
     rt, kb = tiles
+    pair = paged_decode.tokens_a_tile(Hkv) or 1   # the interpreter: any count
+    if pair > 1:
+        # a sublane tile of a token pair: the same bytes where they lie
+        pages = pages.reshape(-1, page // pair, pair * combined, D)
     assert T % 16 == 0 and T >= rt, (T, rt)
     n_tiles = -(-T // rt)
     R, keys = rt * G, kb * page
@@ -391,7 +406,7 @@ def _chunk(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
     return pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale,
                           sliding_window=sliding_window,
-                          pages_per_seq=pages_per_seq),
+                          pages_per_seq=pages_per_seq, page=page),
         out_shape=jax.ShapeDtypeStruct((Hkv, G, T, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
@@ -399,7 +414,8 @@ def _chunk(q: jax.Array, pages: jax.Array, kv_lens: jax.Array,
             out_specs=whole,
             grid=(1,),
             scratch_shapes=[
-                pltpu.VMEM((2, keys, combined, D), pages.dtype),
+                pltpu.VMEM((2, keys // pair, pair * combined, D),
+                           pages.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.VMEM((Hkv, keys, D), pages.dtype),
                 pltpu.VMEM((Hkv, D, keys), pages.dtype),

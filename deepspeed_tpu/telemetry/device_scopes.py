@@ -56,8 +56,9 @@ _SCOPE_KIND = {"zero.fetch": "zero", "optim": "optim", "sample": "sample",
                "counts": "rest", "head.rows": "head"}
 _MOE_SCOPE = "moe."
 # scopes that split ONE module's time by the kind of its layer (a window
-# layer's attention and a full layer's, ``models/trinity.py``): where one is
-# in a path it leads the sub-scope, a Pallas kernel's name behind it
+# layer's attention and a full layer's, ``models/trinity.py`` and
+# ``models/smallthinker.py``): where one is in a path it leads the sub-scope,
+# a Pallas kernel's name behind it
 _SPLIT_SCOPES = ("attn_window", "attn_full")
 # the module names of the model files -> kinds
 _MODULE_KIND = {

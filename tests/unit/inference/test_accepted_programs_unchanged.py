@@ -52,7 +52,14 @@ the same operations under a local name.  PR 61 added the two programs of
 ``glm-4.7-flash-serve``, taken on its PARENT, and then deleted speculative
 decoding and the partial-residency driver from the engine, the program
 builders and the cache state: the twenty read on its tree what they read
-before it.
+before it.  PR 64 added the two programs of
+``smallthinker-21b-a3b-serve``, taken on its own tree (the router's matmul
+and top-k traced BEFORE the layer's attention from the layer's unnormed
+input, ReGLU experts, four KV heads under both tables), and moved none of
+the twenty: ``moe/layer.py`` routes from the rows it dispatches where a
+caller passes neither ``route_only`` nor ``routing``, ``swiglu`` is the same
+``silu``, and the paged kernels' paired tile is the chip's alone (this file
+lowers on the CPU, where both programs take the twins).
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -151,13 +158,20 @@ EXPECTED = {
     # beside a shared expert); the eighteen older hashes are as they were
     "glm.fused": "2d330374a021b606",
     "glm.decode_block": "1e96c6f55cbd033a",
+    # taken by PR 64, which added the model (two periods [full, window,
+    # window, window]: six window layers in a pool of their own behind the
+    # second table, the rotary embedding on them alone, the router issued
+    # before attention, ReGLU banks); the twenty older hashes are as they were
+    "smallthinker.fused": "9243c30541c804a3",
+    "smallthinker.decode_block": "20f5f50bd6b890a2",
 }
 
 
 def _models():
     from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
                                       minicpm_sala, mistral, olmo_hybrid,
-                                      olmoe, ouro, solar_open2, trinity)
+                                      olmoe, ouro, smallthinker,
+                                      solar_open2, trinity)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
                 scan_layers=False, remat=False, use_flash_attention=False)
@@ -183,6 +197,8 @@ def _models():
             "tinytrinity", **bf16)),
         "glm": glm4_moe_lite.Glm4MoeLiteForCausalLM(glm4_moe_lite.get_config(
             "tinyglm", **bf16)),
+        "smallthinker": smallthinker.SmallThinkerForCausalLM(
+            smallthinker.get_config("tinysmallthinker", **bf16)),
     }
 
 

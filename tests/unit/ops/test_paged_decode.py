@@ -80,7 +80,9 @@ def _check(case, window, tiles):
 
 HEADS = [pytest.param((32, 8), id="mistral-32q-8kv"),
          pytest.param((16, 16), id="olmoe-16q-16kv"),
-         pytest.param((8, 2), id="narrow-8q-2kv")]
+         pytest.param((8, 2), id="narrow-8q-2kv"),
+         # a token pair a sublane tile, a query group of seven
+         pytest.param((28, 4), id="smallthinker-28q-4kv")]
 
 
 @pytest.mark.parametrize("context", [1, 12, 63, 64, 65, PAGE * PPS])
@@ -99,6 +101,25 @@ def test_a_binding_sliding_window(heads, window):
     lens = [PAGE * PPS, 12, 300, 65, 129]
     live = [True, True, False, True, True]
     _check(_case(heads, lens, live, seed=window), window, tiles=(4, 2))
+
+
+@pytest.mark.parametrize("window", [None, 100, 200])
+def test_four_kv_heads_through_a_table_with_released_columns(window):
+    """``smallthinker-21b-a3b-serve``'s head counts through a window layer's
+    table as ``window_pages.py`` leaves it: the columns wholly behind a live
+    slot's window read -1 and their pages hold NaN (another sequence's by
+    now), and the step's pages are multiplied a token PAIR a tile."""
+    lens = [PAGE * PPS, 12, 300, 65, 450]
+    live = [True, True, False, True, True]
+    q, pages, kv_lens, table, live_ = _case((28, 4), lens, live, seed=3)
+    pool, table = np.array(pages.astype(jnp.float32)), np.array(table)
+    for s, n in enumerate(lens):
+        gone = max(n - window, 0) // PAGE if window else 0
+        pool[table[s, :gone]] = np.nan
+        table[s, :gone] = -1
+    case = (q, jnp.asarray(pool, jnp.bfloat16), kv_lens, jnp.asarray(table),
+            live_)
+    _check(case, window, tiles=(4, 2))
 
 
 def test_work_list_holds_only_pages_that_are_attended():
@@ -138,8 +159,10 @@ def test_no_live_slot_is_no_work():
     (8, 16, 64, 128, jnp.bfloat16, (16, 16)),  # 2,048 rows a step
     (8, 256, 16, 128, jnp.bfloat16, (1, 1)),
     (32, 64, 64, 128, jnp.bfloat16, (1, 1)),   # never under a page
-    (4, 64, 64, 128, jnp.bfloat16, None),   # half a sublane tile a token
-    (2, 64, 64, 128, jnp.bfloat16, None),
+    (4, 64, 64, 128, jnp.bfloat16, (8, 8)),    # a token PAIR a sublane tile
+    (4, 64, 129, 128, jnp.bfloat16, (8, 8)),   # smallthinker-21b-a3b-serve
+    (4, 16, 64, 128, jnp.bfloat16, None),   # half a lane tile of rows
+    (2, 64, 64, 128, jnp.bfloat16, None),   # four tokens a tile: never built
     (8, 8, 64, 128, jnp.bfloat16, None),    # half a lane tile of rows
     (8, 64, 64, 64, jnp.bfloat16, None),    # head_dim
     (8, 64, 64, 128, jnp.float32, None),    # K and V are not one word

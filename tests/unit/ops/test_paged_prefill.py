@@ -98,6 +98,8 @@ def _close(got, want, case, least=2):
 
 
 MISTRAL, OLMOE, NARROW = (32, 8), (16, 16), (8, 2)
+# four KV heads: a token PAIR a sublane tile; a query group of seven
+SMALLTHINKER = (28, 4)
 
 # (heads, [(query rows, kv length)], window, (tokens a row tile, pages a
 # key block), loop)
@@ -125,6 +127,13 @@ TICKS = {
         (NARROW, [(30, PAGE * PPS)], None, (16, 16), None),
     "key-blocks-of-four-lane-tiles-a-diagonal-tile-reaches-some-of":
         (NARROW, [(1, 9), (150, 700)], None, (32, 32), None),
+    "4kv-7q-a-chunk-off-page-and-off-tile":
+        (SMALLTHINKER, [(37, 100)], None, (16, 2), None),
+    "4kv-7q-one-query-sequences-between-chunks-of-several-key-blocks":
+        (SMALLTHINKER, [(1, 40), (75, 230), (1, 7), (21, 21)], None,
+         (16, 4), None),
+    "4kv-7q-a-window-that-binds-inside-a-chunk":
+        (SMALLTHINKER, [(60, 200), (1, 90)], 24, (16, 2), None),
     "a-tick-of-one-row-tile":
         (NARROW, [(9, 20), (1, 3)], None, (64, 2), None),
 }
@@ -156,6 +165,33 @@ def test_heads_of_64_padded_to_128():
                                sm_scale=64 ** -0.5)
     assert (np.asarray(got[..., 64:], np.float32) == 0).all()
     _close(got[..., :64], want, case)
+
+
+def test_four_kv_heads_through_a_table_with_released_columns():
+    """A window layer's table as ``window_pages.py`` leaves it behind a
+    long prompt: the columns wholly behind the chunk's first row's window
+    read -1, their pages hold NaN (another sequence's by now), and the chunk
+    kernel (a token PAIR a tile, a group of seven) never fetches them: a
+    released column of a key block it does fetch clamps to the trash page,
+    whose stale rows are finite and masked."""
+    window, seqs, page = 40, [(48, 400), (1, 90), (20, 20)], 64
+    q, pages, kv_lens, table, cu, n = _case(SMALLTHINKER, seqs, seed=11,
+                                            page=page)
+    pool, table = np.array(pages.astype(jnp.float32)), np.array(table)
+    for j, (q_len, kv) in enumerate(seqs):
+        gone = max(kv - q_len - window + 1, 0) // page
+        pool[table[j, :gone]] = np.nan
+        table[j, :gone] = -1
+    pool[0] = np.random.default_rng(5).standard_normal(pool[0].shape)
+    case = (q, jnp.asarray(pool, jnp.bfloat16), kv_lens, jnp.asarray(table),
+            cu, n)
+    got = _chunk(case, window=window, tiles=(16, 2))
+    _close(got, _reference(case, window), case)
+    # and both kernels through the one call a fused tick makes
+    got = pp.fused_tick_attention(
+        *case, sm_scale=SM, sliding_window=window, fallback=None,
+        fallback_route="xla-dense", interpret=True)
+    _close(got, _reference(case, window), case, least=1)
 
 
 def test_no_sequence_is_no_work():
@@ -192,8 +228,10 @@ def test_work_list_holds_only_blocks_that_are_attended():
     (64, 8, 256, 128, jnp.bfloat16, (128, 2)),   # never under 128 tokens
     (32, 16, 64, 128, jnp.bfloat16, (256, 8)),
     (32, 8, 1024, 128, jnp.bfloat16, (128, 1)),  # never under a page
-    (16, 4, 64, 128, jnp.bfloat16, None),    # half a sublane tile a token
-    (8, 2, 64, 128, jnp.bfloat16, None),
+    (16, 4, 64, 128, jnp.bfloat16, (128, 8)),   # a token PAIR a tile
+    (28, 4, 64, 128, jnp.bfloat16, (128, 8)),   # smallthinker: a group of 7
+    (28, 4, 1, 128, jnp.bfloat16, None),     # half a pair a page
+    (8, 2, 64, 128, jnp.bfloat16, None),     # four tokens a tile: never built
     (12, 8, 64, 128, jnp.bfloat16, None),    # no whole group of heads
     (32, 8, 32, 128, jnp.bfloat16, (128, 16)),
     (32, 8, 64, 64, jnp.bfloat16, None),     # head_dim

@@ -833,6 +833,74 @@ def test_both_kernels_at_a_query_group_of_six(chip_compile, pool, window):
     assert f"%{paged_decode.NAME}" in text
 
 
+# smallthinker-21b-a3b-serve: 28 query heads over FOUR KV heads of 128, a
+# query group of SEVEN; a token's eight combined heads are HALF a bf16
+# sublane tile, so both kernels fetch and buffer the pool a PAIR of tokens a
+# tile (``paged_decode.tokens_a_tile``); 32 slots of 129 pages, a fused tick
+# of 32 + 1,024 rows (ISSUE 64's Step 0; the sweep on the chip then chose
+# chunks of 512, the same kernel at fewer rows); the window layers' pool at
+# that tick (2,625 pages, the published window of 4,096) and the full layers'
+# (4,129 pages, no window)
+SMALLTHINKER = dict(S=32, pp=129, page=64, Hq=28, Hkv=4, D=128, T=1056)
+
+
+@pytest.mark.parametrize("pool,window", [
+    pytest.param(2625, 4096, id="window-layers-pool"),
+    pytest.param(4129, None, id="full-layers-pool")])
+def test_both_kernels_at_four_kv_heads_and_a_query_group_of_seven(
+        chip_compile, pool, window):
+    """Step 0 of ISSUE 64: Mosaic takes both attention kernels with a token
+    PAIR a sublane tile, at a tick of 1,056 rows and at 32 one-row
+    sequences, with the pool stored as FOUR heads.  Row tiles of 128 tokens
+    are 896 MXU rows a product against key blocks of 512; the decode
+    kernel's groups are 8 pages of a step.  The route names are the ones
+    ``smallthinker-21b-a3b-serve`` expects, and the pool is read where it
+    lies: no copy of it is in either program."""
+    from deepspeed_tpu.ops import paged_decode, paged_prefill
+
+    c, i32, bf = SMALLTHINKER, jnp.int32, jnp.bfloat16
+    assert paged_decode.tokens_a_tile(c["Hkv"]) == 2
+    assert paged.kv_heads_stored(c["Hkv"]) == 4
+    tiles = paged_prefill.tiling(c["Hq"], c["Hkv"], c["page"], c["D"], bf)
+    decode_tiles = paged_decode.tiling(c["Hkv"], c["page"], c["pp"],
+                                       c["D"], bf)
+    assert (tiles, decode_tiles) == ((128, 8), (8, 8))
+    assert paged_prefill.route_name(
+        tiles, c["Hq"], c["Hkv"], c["page"]) == "pallas-chunk-896x512"
+    assert paged_decode.route_name(decode_tiles,
+                                   c["Hkv"]) == "pallas-decode-8x4"
+    vmem = lambda rows: paged_prefill.vmem_bytes(   # noqa: E731
+        rows, c["Hq"], c["Hkv"], c["page"], c["D"], tiles)
+    assert vmem(c["T"]) == 49_479_680 < paged_prefill._VMEM_CEILING
+    pool_shape = (pool, c["page"], 2 * c["Hkv"], c["D"])
+    pages = (pool_shape, bf)
+    meta = [((c["S"],), i32), ((c["S"], c["pp"]), i32),
+            ((c["S"] + 1,), i32), ((1,), i32)]
+
+    def reads_the_pool_where_it_lies(text):
+        # the paired view is a bitcast of the parameter, never a copy
+        assert f"bf16[{pool},32,16,128]" in text
+        copies = [line for line in text.splitlines()
+                  if f"bf16[{pool}," in line and " copy(" in line]
+        assert not copies, copies
+
+    text = chip_compile(
+        functools.partial(paged_prefill._chunk.__wrapped__,
+                          sm_scale=c["D"] ** -0.5, sliding_window=window,
+                          tiles=tiles, interpret=False),
+        ((c["Hq"], c["T"], c["D"]), bf), pages, *meta)
+    assert f"%{paged_prefill.NAME}" in text
+    reads_the_pool_where_it_lies(text)
+    text = chip_compile(
+        functools.partial(paged_decode._decode.__wrapped__,
+                          sm_scale=c["D"] ** -0.5, sliding_window=window,
+                          tiles=decode_tiles, interpret=False),
+        ((c["S"], c["Hq"], c["D"]), bf), pages, meta[0], meta[1],
+        ((c["S"],), jnp.bool_))
+    assert f"%{paged_decode.NAME}" in text
+    reads_the_pool_where_it_lies(text)
+
+
 def test_kv_block_never_under_a_page_nor_over_512_tokens():
     assert paged.rpa_kv_pages_per_block(8, 64, 64) == 8
     assert paged.rpa_kv_pages_per_block(16, 64, 64) == 4

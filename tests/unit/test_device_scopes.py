@@ -327,6 +327,12 @@ def _train_engine(loss_module, dp=2):
      ("attn", "attn_full/paged_decode_attention", "-")),
     ("jit(f)/M/model/layers_2/pre_mlp_layernorm/rsqrt",
      ("mlp", "pre_mlp_layernorm", "-")),
+    # a router issued BEFORE its layer's attention (``models/smallthinker.py``:
+    # the expert module called twice) is the expert layer's all the same
+    ("jit(f)/M/model/layers_3/block_sparse_moe/moe.route/dot_general",
+     ("moe", "moe.route", "-")),
+    ("jit(f)/M/model/layers_3/block_sparse_moe/moe.route/top_k",
+     ("moe", "moe.route", "-")),
     ("jit(f)/while/body/closed_call/sample/sample_logits_batched/reduce",
      ("sample", "sample_logits_batched", "-")),
     ("jit(f)/M/head.rows/gather", ("head", "head.rows", "-")),
