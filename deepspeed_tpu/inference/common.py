@@ -125,7 +125,8 @@ class HostStageStats:
     ``engine.plan.rows``: ``_plan_rows``, ``decode_rows`` /
     ``prefill_seqs`` / ``pages_granted`` / ``page_limited`` /
     ``prompt_rows`` / ``prompt_tiles`` (the prompt rows handed out and the
-    row tiles of 128 their chunks span as they lie in the tick);
+    row tiles of 128 their chunks span as they lie in the tick) /
+    ``tiles_aligned`` (1 where they lie in the tick's own tiles);
     ``engine.plan.pack``: ``_pack_tick``'s arrays, ``rows`` / ``seqs`` /
     ``page_cells``; ``engine.plan.counts``: the cache kind's host-side
     counts for the dispatch span, ``rows``; ``engine.plan.attrs``: the
@@ -152,7 +153,11 @@ class HostStageStats:
     that ``fused_tick_host_ms`` reads from a traced one; and the sums of
     the plans' ``prompt_rows`` and ``prompt_tiles`` with their quotient,
     ``prompt_rows_per_tile``: 128 where every chunk is whole tiles from
-    row 0, 128 x 8/9 where every chunk buys a ninth tile for a few rows.
+    row 0, 128 x 8/9 where every chunk buys a ninth tile for a few rows;
+    and ``aligned_ticks`` with ``aligned_tick_share``, the ticks whose
+    chunks lay in the tick's own row tiles, so that a matrix-state layer
+    scanned them where they lay (``engine.plan.rows`` says ``tiles_aligned``
+    tick by tick).
 
     ``serving_stages()`` reports per-dispatch milliseconds plus
     ``host_bound_fraction`` = host-stage time / (host + device-wait).
@@ -246,6 +251,10 @@ class HostStageStats:
         # 1,028 rows from row 4 spans nine, one of 1,024 from row 0 eight)
         self.prompt_rows = 0
         self.prompt_tiles = 0
+        # the ticks dispatched whose chunks lie in the tick's own row tiles
+        # (``ragged_engine.py:tiles_aligned``): a matrix-state layer reads
+        # such a tick's rows as its scan's tiles and gathers nothing
+        self.aligned_ticks = 0
         # ... of them with earlier work un-folded (dispatched ahead)
         self.fused_ticks_ahead = 0
         self.decode_blocks = 0      # plain decode blocks dispatched
@@ -393,7 +402,14 @@ class HostStageStats:
         ``_wall_s`` / ``_wait_s``: every step of the run by what it
         dispatched.  ``prompt_rows`` / ``prompt_tiles``: what
         ``engine.plan.rows`` said, summed, and ``prompt_rows_per_tile``
-        their quotient, the rows a tile the chunk kernels span holds."""
+        their quotient, the rows a tile the chunk kernels span holds.
+        ``aligned_ticks``: the ticks dispatched whose chunks lay in the
+        tick's own row tiles (``ragged_engine.py:tiles_aligned``), which a
+        matrix-state layer scans where they lie with no gather to tiles or
+        back (``ops/linear_attention.py:scan_tick``); ``aligned_tick_share``
+        of all that were dispatched (11 of 12 where prompts of 12 chunks
+        follow one another: the tick that holds a tail beside the next
+        head is the other)."""
         fetch = self.SPAN_NAMES["device"]
         out: Dict[str, Any] = {}
         for p, r in self.steps_by_program.items():
@@ -413,6 +429,10 @@ class HostStageStats:
         if self.prompt_tiles:
             out["prompt_rows_per_tile"] = round(
                 self.prompt_rows / self.prompt_tiles, 4)
+        out["aligned_ticks"] = self.aligned_ticks
+        if self.fused_ticks:
+            out["aligned_tick_share"] = round(
+                self.aligned_ticks / self.fused_ticks, 4)
         return out
 
 

@@ -14,7 +14,10 @@ Inside :func:`ragged_gated_delta` a fused tick's rows are 2-D, ``[T, H * d]``
 the projections leave them and ``o_proj`` takes them, as in
 ``linear_state.py:ragged_linear_attention`` and for its reason: on the chip
 every reshape to ``[T, H, d]`` or back is two relayout copies, a transpose to
-heads first a third, and a scatter back writes a row at a time."""
+heads first a third, and a scatter back writes a row at a time.  And a tick
+whose chunks start on row tiles is scanned WHERE ITS ROWS LIE
+(``ops/linear_attention.py:scan_tick``): the gathers to tiles and back are
+the boundary tick's alone."""
 from __future__ import annotations
 
 from typing import Dict
@@ -68,8 +71,10 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     (``ops/linear_attention.py:tile_layout``), sequences of one row the same
     one-token step; the two write disjoint rows and slots.  A tick's rows
     stay ``[T, H * d]`` all the way (a head a lane block, as the projections
-    leave them and ``o_proj`` takes them): one gather an operand to the
-    tiles' rows, one gather back, no transpose to heads first.
+    leave them and ``o_proj`` takes them), and where the tick's chunks start
+    on row tiles they ARE the scan's tiles and nothing moves
+    (``linear_attention.scan_tick``); else one gather an operand to the
+    tiles' rows, one gather back.  No transpose to heads first either way.
 
     ``rule``: the module whose ``chunk_scan``, ``decode_step`` and ``CHUNK``
     are the rule's two forms (``ops/kda.py`` for a decay that is a vector a
@@ -111,13 +116,19 @@ def ragged_gated_delta(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     # rows as the projections leave them, ``[T, H * d]``, a head a lane
     # block, from here to the last line: no operand is re-tiled by head
     flat = lambda a: a.reshape(a.shape[0], -1)
-    tiles = lambda a: la.rows_to_tiles(flat(a), lay["rows"])
-    o_t, st = gd.chunk_scan(
-        tiles(q), tiles(k), tiles(v),
-        # a tile row that holds no token: g = b = 0
-        la.to_tiles(g, lay["rows"]), la.to_tiles(b, lay["rows"]),
-        state.value, jnp.take(seq_slot, lay["seq"]), lay["init"], lay["len"])
-    o = la.tiles_to_rows(o_t, lay["rows"], T)
+    # a tile row that holds no token: b = 0, and g = 0 where it is a number
+    # a head; a ``g`` a key channel wide is a row like q's, which the rule's
+    # scan zeroes past a tile's ``len`` itself (``ops/kda.py``)
+    wide = g.shape[1] != H
+
+    def scan(rows, gates, st, *tiles):
+        q_t, k_t, v_t, g_t = rows if wide else rows + gates[:1]
+        return gd.chunk_scan(q_t, k_t, v_t, g_t, gates[-1], st, *tiles)
+
+    o, st = la.scan_tick(
+        scan, lay, seq_slot, T, gd.CHUNK,
+        (flat(q), flat(k), flat(v)) + ((g,) if wide else ()),
+        (b,) if wide else (g, b), state.value)
     # the one-row sequences, each on its slot's state where it lies
     live = ragged_meta.get("live")
     if live is not None:

@@ -11,8 +11,10 @@ of 128 x 128, both dimensions whole lane tiles.
 
 A tick's rows take ``delta_state.py:ragged_gated_delta``'s path to the
 letter (tiles of their own for the sequences of two rows or more, the
-one-token step for the others, rows ``[T, H * d]`` all the way); what differs
-is the rule, and that ``g`` is ``[T, H * d]``, a key channel wide."""
+one-token step for the others, rows ``[T, H * d]`` all the way, scanned
+where they lie in a tick whose chunks start on row tiles); what differs is
+the rule, and that ``g`` is ``[T, H * d]``, a key channel wide: a row like
+q's to the layout, which the scan zeroes past a tile's ``len`` itself."""
 from __future__ import annotations
 
 import functools

@@ -10,7 +10,10 @@ way the projections leave them and ``o_proj`` takes them: on the chip every
 reshape to ``[T, H, d]`` or back is two relayout copies, a transpose to heads
 first a third, and a scatter back writes a row at a time.  The ``[T, H, d]``
 of the signature is undone at the door and restored on the last line, so XLA
-computes the model's per-head norms where the rows lie."""
+computes the model's per-head norms where the rows lie.  And a tick whose
+chunks start on row tiles is scanned WHERE ITS ROWS LIE
+(``ops/linear_attention.py:scan_tick``): the gathers to tiles and back are
+the boundary tick's alone."""
 from __future__ import annotations
 
 from typing import Dict
@@ -56,9 +59,12 @@ def ragged_linear_attention(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     (:func:`~deepspeed_tpu.ops.linear_attention.tile_layout`), sequences of
     one row the same one-token step; the two write disjoint rows and
     slots.  A tick's rows stay ``[T, H * d]`` all the way (a head a lane
-    block, as the projections leave them and ``o_proj`` takes them): one
-    gather an operand to the tiles' rows, one gather back, no transpose to
-    heads first."""
+    block, as the projections leave them and ``o_proj`` takes them), and
+    where the tick's chunks start on row tiles they ARE the scan's tiles
+    and nothing moves (``linear_attention.scan_tick``: the plan of a tick
+    lays whole-tile chunks from row 0, ``ragged_engine.py:_pack_tick``);
+    else one gather an operand to the tiles' rows, one gather back.  No
+    transpose to heads first either way."""
     T, H, d = q.shape
     cu = ragged_meta["cu_q_lens"]
     S = cu.shape[0] - 1
@@ -94,11 +100,11 @@ def ragged_linear_attention(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
     # rows as the projections leave them, ``[T, H * d]``, a head a lane
     # block, from here to the last line: no operand is re-tiled by head
     flat = lambda a: a.reshape(a.shape[0], -1)
-    tiles = lambda a: la.rows_to_tiles(flat(a), lay["rows"])
-    o_t, st = la.chunk_scan(
-        tiles(q), tiles(k), tiles(v), log_decay, state.value,
-        jnp.take(seq_slot, lay["seq"]), lay["init"], lay["len"])
-    o = la.tiles_to_rows(o_t, lay["rows"], T)
+    o, st = la.scan_tick(
+        lambda rows, _, st, *tiles: la.chunk_scan(*rows, log_decay, st,
+                                                  *tiles),
+        lay, seq_slot, T, la.CHUNK, (flat(q), flat(k), flat(v)), (),
+        state.value)
     # the one-row sequences, each on its slot's state where it lies
     live = ragged_meta.get("live")
     if live is not None:

@@ -149,6 +149,27 @@ def whole_tiles(rest: int, room: int, first: bool) -> int:
     return room - part
 
 
+def tiles_aligned(takes, T: int) -> bool:
+    """Whether a tick of ``T`` rows that packs the prompt chunks ``takes``
+    (rows a chunk, in the tick's order from row 0, the one-row sequences
+    behind them) lies in its own row tiles: every chunk of two rows or more
+    starts where the whole tiles of those before it end, and together they
+    fit the ``T // ROW_TILE`` whole tiles the tick holds.  The rule the
+    device evaluates from ``cu_q_lens``
+    (``ops/linear_attention.py:tile_layout``'s ``aligned``), where a
+    matrix-state layer then reads the tick's rows as its scan's tiles and
+    gathers nothing; here it only counts
+    (``HostStageStats.aligned_ticks``)."""
+    rows = tiles = 0
+    for n in takes:
+        if n >= 2:
+            if rows != tiles * ROW_TILE:
+                return False
+            tiles += -(-n // ROW_TILE)
+        rows += n
+    return tiles <= T // ROW_TILE
+
+
 @dataclasses.dataclass
 class Request:
     uid: int
@@ -500,9 +521,11 @@ class RaggedInferenceEngineV2:
         self._dev_last_tok = jax.device_put(
             np.zeros((max_seqs,), np.int32), self._device)
         # what the last plan of a tick found of the pool: sequences that
-        # sit the tick out, and whether any growth fell short
+        # sit the tick out, and whether any growth fell short; and whether
+        # its chunks lie in the tick's own row tiles (``tiles_aligned``)
         self._stalled: List[Request] = []
         self._page_limited = False
+        self._plan_aligned = False
         # generated-token count already reported per uid (stream_deltas)
         self._stream_cursor: Dict[int, int] = {}
         self.cancels = 0
@@ -1729,6 +1752,7 @@ class RaggedInferenceEngineV2:
             del args
         st.ticks += 1
         st.fused_ticks += 1
+        st.aligned_ticks += self._plan_aligned
         st.fused_ticks_ahead += ahead
         for r, _ in sampling:
             # the token exists from here on, wherever it is: lengths and
@@ -1971,10 +1995,14 @@ class RaggedInferenceEngineV2:
             for n in takes.values():
                 tiles += (rows + n - 1) // ROW_TILE - rows // ROW_TILE + 1
                 rows += n
+            # ... and whether they lie in the tick's own tiles: booked when
+            # the tick is dispatched, a plan made again counts once
+            self._plan_aligned = tiles_aligned(takes.values(), self.T)
             leaf.add(decode_rows=len(decode_rs), prefill_seqs=len(takes),
                      pages_granted=free - self.allocator.free_pages,
                      page_limited=int(self._page_limited),
-                     prompt_rows=rows, prompt_tiles=tiles)
+                     prompt_rows=rows, prompt_tiles=tiles,
+                     tiles_aligned=int(self._plan_aligned))
             self.host_stats.prompt_rows += rows
             self.host_stats.prompt_tiles += tiles
             return takes if decode_rs or takes else None

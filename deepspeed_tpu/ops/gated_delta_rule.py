@@ -87,7 +87,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops import routes
-from deepspeed_tpu.ops.linear_attention import LOAD, ZERO
+from deepspeed_tpu.ops.linear_attention import LOAD, ZERO, zeros_past
 
 # the route keys, and the operations' names in a program and a device trace
 KERNEL = "gated_delta"
@@ -391,7 +391,7 @@ def _scan(q, k, v, aux, state, slot, init, length, *, chunk: int,
           interpret: bool):
     H, C = aux.shape[0], chunk
     dk, dv = q.shape[1] // H, v.shape[1] // H
-    NT = q.shape[0] // C
+    NT = slot.shape[0]
     hb, parts = _scan_blocks(H, dk)
     qk = pl.BlockSpec((C, parts * hb * dk), lambda h, n, *_: (n, h // parts))
     vo = pl.BlockSpec((C, hb * dv), lambda h, n, *_: (n, h))
@@ -432,22 +432,28 @@ def chunk_scan(q, k, v, g, b, state, slot, init, length, *,
                ) -> Tuple[jax.Array, jax.Array]:
     """The multi-row sequences of a tick in ``linear_attention.tile_layout``'s
     tiles of ``chunk`` rows, each row as the projections leave it (a head a
-    lane block): ``q, k [NT * C, H * dk]``, ``v [NT * C, H * dv]``, ``g, b
-    [NT * C, H]`` (the log of a token's decay, at most 0, and the
-    correction's strength; both 0 where a tile row holds no token), ``state
-    [slots, dk, H * dv]`` float32, a tile's ``slot``, ``init`` and live rows
-    ``length`` (``[NT]``) -> ``(o [NT * C, H * dv], state)``.  The Pallas
-    kernel on a TPU, else the twin; recorded under :data:`KERNEL_SCAN`."""
+    lane block): ``q, k [R, H * dk]``, ``v [R, H * dv]`` with ``R >= NT * C``
+    (the rows past the last tile's are read by no one and come back zeros:
+    ``linear_attention.scan_tick``), ``g, b [NT * C or more, H]`` (the log of
+    a token's decay, at most 0, and the correction's strength; both 0 where
+    a tile row holds no token), ``state [slots, dk, H * dv]`` float32, a
+    tile's ``slot``, ``init`` and live rows ``length`` (``[NT]``) -> ``(o [R,
+    H * dv], state)``.  The Pallas kernel on a TPU, else the twin; recorded
+    under :data:`KERNEL_SCAN`."""
     H = g.shape[1]
     dk, dv = q.shape[1] // H, v.shape[1] // H
+    R, whole = q.shape[0], slot.shape[0] * chunk
+    g, b = g[:whole], b[:whole]
     route = routes.take(
         KERNEL_SCAN, eligible=scan_eligible(H, dk, dv, q.dtype),
         interpret=interpret, pallas=f"pallas-delta-scan-{chunk}")
     if route == routes.XLA:
-        return scan_twin(q, k, v, g, b, state, slot, init, length,
-                         chunk=chunk)
-    return _scan(q, k, v, _aux(g, b, chunk), state, slot, init, length,
-                 chunk=chunk, interpret=interpret)
+        o, state = scan_twin(q[:whole], k[:whole], v[:whole], g, b, state,
+                             slot, init, length, chunk=chunk)
+    else:
+        o, state = _scan(q, k, v, _aux(g, b, chunk), state, slot, init,
+                         length, chunk=chunk, interpret=interpret)
+    return zeros_past(o, whole, R), state
 
 
 def _step_kernel(slot_ref, fresh_ref, n_ref,                 # scalar prefetch

@@ -90,7 +90,7 @@ from deepspeed_tpu.ops.gated_delta_rule import (_dot, _heads_along_lanes,
                                                 _heads_first, levels,
                                                 rows_plus, rows_spread,
                                                 rows_with, solve)
-from deepspeed_tpu.ops.linear_attention import LOAD, ZERO
+from deepspeed_tpu.ops.linear_attention import LOAD, ZERO, zeros_past
 
 # the route keys, and the operations' names in a program and a device trace
 KERNEL = "kda"
@@ -325,7 +325,7 @@ def _scan(q, k, v, g, b, state, slot, init, length, *, chunk: int,
                       pl.BlockSpec((1, C, hb), lambda h, n, *_: (h, n, 0)),
                       block],
             out_specs=(vo, block),
-            grid=(H // hb, rows // C)),
+            grid=(H // hb, slot.shape[0])),
         # operands are counted with the scalar-prefetch arrays: the state
         # is the ninth, and becomes the second result
         input_output_aliases={8: 1},
@@ -348,22 +348,28 @@ def chunk_scan(q, k, v, g, b, state, slot, init, length, *,
                ) -> Tuple[jax.Array, jax.Array]:
     """The multi-row sequences of a tick in ``linear_attention.tile_layout``'s
     tiles of ``chunk`` rows, each row as the projections leave it (a head a
-    lane block): ``q, k [NT * C, H * dk]``, ``v [NT * C, H * dv]``, ``g [NT *
-    C, H * dk]`` (the log of a token's decay a key channel, at most 0), ``b
-    [NT * C, H]``, ``state [slots, dk, H * dv]`` float32, a tile's ``slot``,
-    ``init`` and live rows ``length`` (``[NT]``) -> ``(o [NT * C, H * dv],
-    state)``.  The Pallas kernel on a TPU, else the twin; recorded under
+    lane block): ``q, k [R, H * dk]``, ``v [R, H * dv]``, ``g [R, H * dk]``
+    (the log of a token's decay a key channel, at most 0) with ``R >= NT *
+    C`` (the rows past the last tile's are read by no one and come back
+    zeros: ``linear_attention.scan_tick``), ``b [NT * C or more, H]``,
+    ``state [slots, dk, H * dv]`` float32, a tile's ``slot``, ``init`` and
+    live rows ``length`` (``[NT]``) -> ``(o [R, H * dv], state)``.  The
+    Pallas kernel on a TPU, else the twin; recorded under
     :data:`KERNEL_SCAN`."""
     H = b.shape[1]
     dk, dv = q.shape[1] // H, v.shape[1] // H
+    R, whole = q.shape[0], slot.shape[0] * chunk
     route = routes.take(
         KERNEL_SCAN, eligible=scan_eligible(H, dk, dv, q.dtype),
         interpret=interpret, pallas=f"pallas-kda-scan-{chunk}")
     if route == routes.XLA:
-        return scan_twin(q, k, v, g, b, state, slot, init, length,
-                         chunk=chunk)
-    return _scan(q, k, v, g, b, state, slot, init, length, chunk=chunk,
-                 interpret=interpret)
+        o, state = scan_twin(q[:whole], k[:whole], v[:whole], g[:whole],
+                             b[:whole], state, slot, init, length,
+                             chunk=chunk)
+    else:
+        o, state = _scan(q, k, v, g, b, state, slot, init, length,
+                         chunk=chunk, interpret=interpret)
+    return zeros_past(o, whole, R), state
 
 
 def _step_kernel(slot_ref, fresh_ref, n_ref,                 # scalar prefetch

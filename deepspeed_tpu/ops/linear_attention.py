@@ -17,17 +17,23 @@ Two forms serve it (``paged/linear_state.py:ragged_linear_attention``):
   ``D`` is the exponential of a difference of indices, never a quotient
   of two powers: the fastest head's ``l^128`` is 1e-47 and its inverse
   overflows.  The rows of a packed ragged batch are laid out in **tiles**
-  first (:func:`tile_layout`): each multi-row sequence's rows gathered to
-  whole tiles of ``C``, each row AS THE PROJECTIONS LEAVE IT (``[NT * C, H
-  * d]``: rows down, a head a lane block of 128; nothing is transposed to
-  heads first and nothing comes back), so a tile belongs to one sequence,
-  the grid is ``(head blocks, tiles)``, a step's block is ``C`` rows of
-  its heads' lanes, and a head's state rides the tiles of a sequence in
-  the output block itself; a sequence's first tile reads its slot's state
-  (or zeros at position 0), the last leaves it there.  A tile row at or
-  past the tile's ``len`` holds no token and is zeroed in the kernel,
-  whatever the tile holds there (:func:`rows_to_tiles`).  The state array
-  is donated (``input_output_aliases``): slots with no tile keep theirs.
+  first (:func:`tile_layout`): each multi-row sequence's rows in whole
+  tiles of ``C``, each row AS THE PROJECTIONS LEAVE IT (``[.., H * d]``:
+  rows down, a head a lane block of 128; nothing is transposed to heads
+  first and nothing comes back), so a tile belongs to one sequence, the
+  grid is ``(head blocks, tiles)``, a step's block is ``C`` rows of its
+  heads' lanes, and a head's state rides the tiles of a sequence in the
+  output block itself; a sequence's first tile reads its slot's state (or
+  zeros at position 0), the last leaves it there.  A tile row at or past
+  the tile's ``len`` holds no token and is zeroed in the kernel, whatever
+  the tile holds there.  Where every chunk of a tick starts on a multiple
+  of ``C`` rows (``ragged_engine.py`` plans and packs it so: whole-tile
+  chunks from row 0) the tiles ARE the tick's rows, ``tile_layout`` says
+  so (``aligned``), and the scan reads the ``T // C`` tiles where they lie;
+  else each operand is gathered to ``ceil(T / C) + seqs`` tiles
+  (:func:`rows_to_tiles`) and ``o`` back (:func:`tiles_to_rows`).
+  :func:`scan_tick` chooses, on the device.  The state array is donated
+  (``input_output_aliases``): slots with no tile keep theirs.
 - :func:`decode_step` (kernel ``linear_decode_step``): one token a row,
   ``2 x d x d x 4`` bytes read and written a head a row and a few hundred
   flops: bandwidth-bound.  The state is updated where it lies (the block
@@ -100,7 +106,15 @@ def tile_layout(cu_q_lens: jax.Array, num_seqs: jax.Array, first_pos:
     sequence starts at position 0 (``first_pos [seqs]``), else
     :data:`CONTINUE`; with no tile at all, tile 0 is ``LOAD``: it copies a
     state onto itself), ``len [NT]`` (live rows, 0 past the last tile),
-    ``pos0 [NT]`` (the position of a tile's first row) and ``n [1]``."""
+    ``pos0 [NT]`` (the position of a tile's first row), ``n [1]`` and
+    ``aligned`` (a scalar: the tiles ARE the tick's own rows, tile ``m`` rows
+    ``m * tile .. m * tile + tile - 1``, for every tile that holds a token,
+    and those are among the ``T // tile`` whole tiles the rows hold.  It
+    holds where every multi-row sequence starts on a multiple of ``tile``
+    with nothing but the tail of the one before it in between, which is how
+    ``ragged_engine.py:_pack_tick`` lays a tick whose chunks are whole
+    tiles; :func:`scan_tick` then scans the first ``T // tile`` tiles where
+    the rows lie)."""
     S = cu_q_lens.shape[0] - 1
     NT = -(-T // tile) + S
     q_lens = cu_q_lens[1:] - cu_q_lens[:-1]
@@ -118,9 +132,16 @@ def tile_layout(cu_q_lens: jax.Array, num_seqs: jax.Array, first_pos:
     i = jnp.arange(tile, dtype=jnp.int32)
     rows = cu_q_lens[seq][:, None] + idx[:, None] * tile + i[None]
     rows = jnp.where(i[None] < length[:, None], rows, T).reshape(-1)
-    return {"rows": rows.astype(jnp.int32), "seq": seq.astype(jnp.int32),
-            "init": init.astype(jnp.int32), "len": length.astype(jnp.int32),
-            "pos0": (first_pos[seq] + idx * tile).astype(jnp.int32), "n": n}
+    lay = {"rows": rows.astype(jnp.int32), "seq": seq.astype(jnp.int32),
+           "init": init.astype(jnp.int32), "len": length.astype(jnp.int32),
+           "pos0": (first_pos[seq] + idx * tile).astype(jnp.int32), "n": n}
+    # read by the matrix-state kinds alone (``scan_tick``): a program that
+    # does not ask for it does not hold it
+    at_home = cu_q_lens[seq] + idx * tile == jnp.arange(NT) * tile
+    lay["aligned"] = jnp.logical_and(
+        jnp.all(jnp.logical_or(jnp.logical_not(valid), at_home)),
+        n[0] <= T // tile)
+    return lay
 
 
 def to_tiles(x: jax.Array, rows: jax.Array) -> jax.Array:
@@ -140,7 +161,8 @@ def rows_to_tiles(x: jax.Array, rows: jax.Array) -> jax.Array:
     """:func:`to_tiles` of ``x [T, W]`` for a consumer that zeroes what lies
     at or past a tile's ``len`` itself (the chunked scans do): a tile row
     that holds no token holds the batch's last row, and the gather is one
-    pass where the fill is a second."""
+    pass where the fill is a second.  Taken by a tick whose chunks do not
+    lie on its own tiles, and by no other (:func:`scan_tick`)."""
     return jnp.take(x, rows, axis=0, mode="clip")
 
 
@@ -150,11 +172,66 @@ def tiles_to_rows(y: jax.Array, rows: jax.Array, T: int) -> jax.Array:
     it, and a row that no tile holds reads ``y``'s last row, which is zeros
     where the producer leaves a tile without a token zero: :func:`tile_layout`
     bounds the tiles by ``ceil(T / tile) + seqs``, more than can hold a
-    token, so the last is never live."""
+    token, so the last is never live.  The way back of a tick that went to
+    tiles by :func:`rows_to_tiles` (:func:`scan_tick`)."""
     n = rows.shape[0]
     held = jnp.full((T,), n - 1, jnp.int32).at[rows].set(
         jnp.arange(n, dtype=jnp.int32), mode="drop")
     return jnp.take(y, held, axis=0, mode="clip")
+
+
+def zeros_past(o: jax.Array, whole: int, rows: int) -> jax.Array:
+    """A scan's ``o`` over ``rows`` rows of which the tiles hold the first
+    ``whole``: a kernel's ``[rows, W]``, whose rows past them no grid step
+    wrote, or a twin's ``[whole, W]``, with zeros from there."""
+    if o.shape[0] == rows:
+        return o if whole == rows else o.at[whole:].set(0)
+    return jnp.pad(o, ((0, rows - whole), (0, 0)))
+
+
+def scan_tick(scan, lay: Dict[str, jax.Array], seq_slot: jax.Array, T: int,
+              tile: int, rows, gates, state):
+    """A chunked scan over the multi-row sequences of a tick of ``T`` rows,
+    by :func:`tile_layout`'s ``lay``: ``scan(rows, gates, state, slot, init,
+    len) -> (o, state)`` with ``rows`` the operands ``[.., W]`` whose rows
+    past a tile's ``len`` the scan zeroes itself, ``gates`` those ``[.., h]``
+    that must come as zeros there -> ``(o [T, W], state)``; a row no tile
+    holds reads zeros.
+
+    ONE algorithm, two layouts of its operands, chosen on the device by
+    ``lay["aligned"]`` (``lax.cond``: one program, nothing a caller sets).
+    Where the tiles ARE the tick's rows nothing moves: the scan reads the
+    operands as the projections left them through its own blocks, ``T //
+    tile`` tiles and no more, the gates are masked where they lie (a head
+    wide: an elementwise pass), and its ``o`` is the answer.  Else (a chunk
+    that starts off a tile: the tail of one prompt beside the head of the
+    next) each operand is gathered to the tiles (:func:`rows_to_tiles`,
+    :func:`to_tiles`) and ``o`` back (:func:`tiles_to_rows`).  The same
+    rows multiplied by the same kernels either way: the results are equal
+    to the bit."""
+    slot = jnp.take(seq_slot, lay["seq"])
+
+    def gathered(rows, gates, state):
+        o_t, state = scan(
+            tuple(rows_to_tiles(a, lay["rows"]) for a in rows),
+            tuple(to_tiles(a, lay["rows"]) for a in gates),
+            state, slot, lay["init"], lay["len"])
+        return tiles_to_rows(o_t, lay["rows"], T), state
+
+    own = T // tile                 # the whole tiles a tick's rows hold
+    if own == 0:
+        return gathered(rows, gates, state)
+
+    def as_they_lie(rows, gates, state):
+        length = lay["len"][:own]
+        live = (jnp.arange(own * tile, dtype=jnp.int32) % tile
+                < jnp.repeat(length, tile))[:, None]
+        return scan(rows, tuple(jnp.where(live, a[:own * tile], 0)
+                                for a in gates),
+                    state, slot[:own], lay["init"][:own], length)
+
+    return jax.lax.cond(lay["aligned"], as_they_lie, gathered, rows, gates,
+                        state)
 
 
 # -- the definition and the twins --------------------------------------------
@@ -290,7 +367,7 @@ def scan_heads(heads: int) -> int:
                    donate_argnums=(4,))
 def _scan(q, k, v, lam, state, slot, init, length, *, interpret: bool):
     H, d, C = lam.shape[0], state.shape[-1], CHUNK
-    NT = q.shape[0] // C
+    NT = slot.shape[0]
     hb = scan_heads(H)
     tile = pl.BlockSpec((C, hb * d), lambda h, n, *_: (n, h))
     block = pl.BlockSpec((1, hb, d, d),
@@ -329,18 +406,24 @@ def chunk_scan(q, k, v, log_decay, state, slot, init, length, *,
                interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """The multi-row sequences of a tick in :func:`tile_layout`'s tiles,
     each row as the projections leave it (a head a lane block): ``q, k, v
-    [NT * C, H * d]``, ``state [slots, H, d, d]`` float32, a tile's
-    ``slot``, ``init`` and live rows ``length`` (``[NT]``) -> ``(o [NT * C,
-    H * d], state)``.  The Pallas kernel on a TPU, else the twin; recorded
-    under :data:`KERNEL_SCAN`."""
+    [R, H * d]`` with ``R >= NT * C`` (tile ``n`` is rows ``n C .. n C + C -
+    1``; the rows past the last tile's are read by no one and come back
+    zeros: :func:`scan_tick`), ``state [slots, H, d, d]`` float32, a tile's
+    ``slot``, ``init`` and live rows ``length`` (``[NT]``) -> ``(o [R, H *
+    d], state)``.  The Pallas kernel on a TPU, else the twin; recorded under
+    :data:`KERNEL_SCAN`."""
     d = state.shape[-1]
+    R, whole = q.shape[0], slot.shape[0] * CHUNK
     route = routes.take(KERNEL_SCAN, eligible=scan_eligible(d, q.dtype),
                         interpret=interpret,
                         pallas=f"pallas-linear-scan-{CHUNK}")
     if route == routes.XLA:
-        return scan_twin(q, k, v, log_decay, state, slot, init, length)
-    return _scan(q, k, v, _lam_lanes(log_decay), state, slot, init, length,
-                 interpret=interpret)
+        o, state = scan_twin(q[:whole], k[:whole], v[:whole], log_decay,
+                             state, slot, init, length)
+    else:
+        o, state = _scan(q, k, v, _lam_lanes(log_decay), state, slot, init,
+                         length, interpret=interpret)
+    return zeros_past(o, whole, R), state
 
 
 def _step_kernel(slot_ref, fresh_ref, n_ref,                 # scalar prefetch

@@ -59,7 +59,19 @@ input, ReGLU experts, four KV heads under both tables), and moved none of
 the twenty: ``moe/layer.py`` routes from the rows it dispatches where a
 caller passes neither ``route_only`` nor ``routing``, ``swiglu`` is the same
 ``silu``, and the paged kernels' paired tile is the chip's alone (this file
-lowers on the CPU, where both programs take the twins).
+lowers on the CPU, where both programs take the twins).  PR 66 re-took
+``sala.fused``, ``olmo_hybrid.fused`` and ``solar.fused`` and no other: it
+MEANT to move them (the three matrix-state kinds scan a tick through
+``ops/linear_attention.py:scan_tick``, which reads the tick's own rows as the
+scan's tiles where ``tile_layout`` says they are; at this file's tick of 20
+rows, under one tile, the three keep the gathers and nothing else, in
+another order, and KDA's ``g`` is gathered by ``rows_to_tiles`` as q is), and
+added ``<model>.fused_tiles`` for the three: the same tick at 128 + 4 rows,
+which holds the conditional and both layouts.  The three decode blocks and
+the sixteen programs of the eight other models are the parent's to the
+letter: ``tile_layout`` gained a key, ``aligned``, and a program that does
+not read it does not hold it (``keye.fused``, whose token selection reads
+the same layout, is as it was).
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/
@@ -77,6 +89,10 @@ import pytest
 
 ENGINE = dict(max_seqs=4, max_seq_len=64, page_size=16, prefill_chunk=16,
               decode_block_size=4, num_pages=12)
+# a tick of one row tile and four rows: where a matrix-state layer's scan
+# holds its conditional (``<model>.fused_tiles``)
+TILES = dict(ENGINE, max_seq_len=256, prefill_chunk=128, num_pages=24)
+MATRIX_STATE = ("sala", "olmo_hybrid", "solar")
 
 # at the parent of PR 35 (commit 92a6aee), JAX 0.9.0.  The three ``fused``
 # hashes were re-taken by PR 41, which MEANT to move them: the tick's final
@@ -102,7 +118,9 @@ EXPECTED = {
     # (2e90302b3dc12e5f before it): ``ragged_linear_attention`` keeps a
     # tick's rows ``[T, H * d]`` from the tiles' gather to the gather back
     # and transposes nothing to heads first
-    "sala.fused": "613d88dcd69cc94d",
+    # Re-taken by PR 66, which MEANT to move it (613d88dcd69cc94d before it):
+    # the scan goes through ``linear_attention.scan_tick`` (header)
+    "sala.fused": "75e3b78e6247a7db",
     "sala.decode_block": "86bf79c9930ecd7c",
     # taken by PR 47, which added the model; re-taken by PR 48 (above).
     # PR 49 made the chip's ``index_scores`` and ``index_select`` ONE kernel
@@ -127,8 +145,9 @@ EXPECTED = {
     # ``ragged_linear_attention`` does (above).  And by PR 57, which MEANT
     # to move it (b0d11e56bd01f5d8 before it): the scan's solve is
     # ``gated_delta_rule.solve``, whose levels from ``s = 8`` multiply the
-    # half of the tile they couple
-    "olmo_hybrid.fused": "53a639c575758088",
+    # half of the tile they couple.  And by PR 66, which MEANT to move it
+    # (53a639c575758088 before it): ``scan_tick`` (header)
+    "olmo_hybrid.fused": "ac425874177e8ba6",
     "olmo_hybrid.decode_block": "45abd5d3a9dd0bed",
     # added by PR 51 (ROADMAP D14), the same function's other caller: the
     # decode block taken on PR 51's parent, the tick (da4cded4c6195441
@@ -142,7 +161,10 @@ EXPECTED = {
     # hashes are as they were.  ``solar.fused`` re-taken by PR 57, which
     # MEANT to move it (022473bd94042151 before it): the same solve, and the
     # level products of ``P`` and ``R`` on the rows that have the level's bit
-    "solar.fused": "3b7548ccb6e0b14e",
+    # Re-taken by PR 66, which MEANT to move it (3b7548ccb6e0b14e before it):
+    # ``scan_tick`` (header), and ``g``, a key channel wide, gathered to the
+    # tiles as q is (the scan zeroes it past a tile's ``len`` itself)
+    "solar.fused": "ca0485438f5ebfe5",
     "solar.decode_block": "dd04a8cc32f4b803",
     # taken by PR 59, which added the model (one dense layer and one period
     # [window, full, window, window] after it: four window layers in a pool
@@ -164,6 +186,13 @@ EXPECTED = {
     # before attention, ReGLU banks); the twenty older hashes are as they were
     "smallthinker.fused": "9243c30541c804a3",
     "smallthinker.decode_block": "20f5f50bd6b890a2",
+    # added by PR 66, taken on its own tree: the fused tick of the three
+    # matrix-state models at ``TILES``, 132 rows, where ``scan_tick`` holds
+    # ``lax.cond`` on ``tile_layout``'s ``aligned`` over the scan on the
+    # tick's own rows and the scan on gathered tiles
+    "sala.fused_tiles": "705f67376f7e206d",
+    "olmo_hybrid.fused_tiles": "b5a3ea3915d44f72",
+    "solar.fused_tiles": "e607b2685e40c2a0",
 }
 
 
@@ -213,6 +242,21 @@ def lowered_hashes():
     from deepspeed_tpu.inference.v2.ragged_engine import \
         RaggedInferenceEngineV2
 
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    boo = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)
+    digest = lambda low: hashlib.sha256(
+        _strip(low.as_text()).encode()).hexdigest()[:16]
+
+    def fused_tick(eng, two):
+        S, T, pp = eng.max_seqs, eng.T, eng.pages_per_seq
+        return eng.fused_sample_step(False).lower(
+            eng.params, eng.kv.cache, i32(S), i32(1, T), i32(1, T),
+            i32(1, T), i32(S), i32(*two, S, pp), i32(S + 1), i32(1),
+            i32(*two, T), i32(S), i32(S),
+            # a model with per-slot state: each packed sequence's slot
+            *([i32(S)] if eng.state_layers else []))
+
     out = {}
     for name, model in _models().items():
         params = jax.jit(model.init)(
@@ -221,25 +265,18 @@ def lowered_hashes():
         own = ({"window_pool_bytes": 12 * 16384}
                if paged.window_pool_layers(model.config) else {})
         eng = RaggedInferenceEngineV2(model, params=params, **ENGINE, **own)
-        S, T, pp = eng.max_seqs, eng.T, eng.pages_per_seq
+        S, pp = eng.max_seqs, eng.pages_per_seq
         two = (2,) if own else ()       # ... and brings both tables
-        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-        f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
-        boo = lambda *s: jax.ShapeDtypeStruct(s, jnp.bool_)
-        fused = eng.fused_sample_step(False).lower(
-            eng.params, eng.kv.cache, i32(S), i32(1, T), i32(1, T),
-            i32(1, T), i32(S), i32(*two, S, pp), i32(S + 1), i32(1),
-            i32(*two, T), i32(S), i32(S),
-            # a model with per-slot state: each packed sequence's slot
-            *([i32(S)] if eng.state_layers else []))
-        block = eng.decode_block(False).lower(
+        out[f"{name}.fused"] = digest(fused_tick(eng, two))
+        out[f"{name}.decode_block"] = digest(eng.decode_block(False).lower(
             eng.params, eng.kv.cache, i32(S), i32(S), boo(S), i32(S),
             i32(*two, S, pp), i32(S), boo(S), f32(S), i32(S), f32(S), i32(S),
-            jax.random.PRNGKey(0))
-        for prog, low in (("fused", fused), ("decode_block", block)):
-            out[f"{name}.{prog}"] = hashlib.sha256(
-                _strip(low.as_text()).encode()).hexdigest()[:16]
+            jax.random.PRNGKey(0)))
         eng.close()
+        if name in MATRIX_STATE:
+            eng = RaggedInferenceEngineV2(model, params=params, **TILES)
+            out[f"{name}.fused_tiles"] = digest(fused_tick(eng, ()))
+            eng.close()
     return out
 
 

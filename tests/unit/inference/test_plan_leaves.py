@@ -29,7 +29,8 @@ FUSED_LEAVES = {"engine.plan.admit": {"waiting", "admitted"},
                 "engine.plan.grow": {"rows"},
                 "engine.plan.rows": {"decode_rows", "prefill_seqs",
                                      "pages_granted", "page_limited",
-                                     "prompt_rows", "prompt_tiles"},
+                                     "prompt_rows", "prompt_tiles",
+                                     "tiles_aligned"},
                 "engine.plan.pack": {"rows", "seqs", "page_cells"},
                 "engine.plan.counts": {"rows"},
                 "engine.plan.attrs": set()}

@@ -9,6 +9,11 @@ packs them from row 0 of the tick (``ragged_engine.py``: ``ROW_TILE``,
   whole-tile chunks from row 0, the others, one-row sequences, and every
   array indexed by packed sequence or by row agrees with a plain
   re-derivation from the requests (both page tables of a window model);
+- whether a tick's chunks lie in its own row tiles is one rule on the host
+  (``tiles_aligned``, counted into ``aligned_tick_share``) and on the device
+  (``tile_layout``'s ``aligned``, where a matrix-state layer then gathers
+  nothing), and a tick of Solar's tiny model serves the same tokens either
+  way;
 - a prompt that crosses three ticks beside a decode row serves the reference's
   tokens, and ``serving_stages()["fused_tick_host"]`` says how many rows a
   spanned tile held.
@@ -21,7 +26,8 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.inference.v2 import programs
 from deepspeed_tpu.inference.v2.ragged_engine import (
-    ROW_TILE, ROWS_GIVEN_UP, RaggedInferenceEngineV2, whole_tiles)
+    ROW_TILE, ROWS_GIVEN_UP, RaggedInferenceEngineV2, tiles_aligned,
+    whole_tiles)
 from deepspeed_tpu.models import lfm2_moe
 from deepspeed_tpu.models import trinity as tr
 from deepspeed_tpu.models.llama import LlamaForCausalLM, get_config
@@ -137,6 +143,12 @@ def _want_takes(eng, decode_rows):
     return takes
 
 
+def _device_says_aligned(cu_q_lens, num_seqs, T):
+    return bool(linear_attention.tile_layout(
+        jnp.asarray(cu_q_lens, jnp.int32), jnp.asarray(num_seqs, jnp.int32),
+        jnp.zeros((len(cu_q_lens) - 1,), jnp.int32), T, ROW_TILE)["aligned"])
+
+
 def _check_tick(eng, takes, decode_rows):
     """Pack ``takes`` and hold every array to the requests' own state."""
     by_uid = {r.uid: r for r in eng.slots if r is not None}
@@ -200,6 +212,10 @@ def _check_tick(eng, takes, decode_rows):
     # the plan counted rows and tiles, not the pack; the tiles a chunk's rows
     # fall in, row by row
     assert (stats.prompt_rows, stats.prompt_tiles) == (rows0, tiles0)
+    # ... and said whether the chunks lie in the tick's own tiles, by the
+    # rule the device evaluates from the packed ``cu_q_lens``
+    assert _device_says_aligned(cu_q_lens, num, eng.T) == tiles_aligned(
+        takes.values(), eng.T) == eng._plan_aligned
     return sum(len(set(np.arange(cu_q_lens[j], cu_q_lens[j + 1]) // ROW_TILE))
                for j in range(len(takes)))
 
@@ -314,6 +330,107 @@ def test_a_per_slot_state_model_finds_its_slots_in_the_packed_order():
     assert list(takes.items()) == [(whole, ROW_TILE), (tail, 124)]
     assert _check_tick(eng, takes, 2) == 2
     eng.close()
+
+
+# -- the tick's own tiles ------------------------------------------------------
+
+@pytest.mark.parametrize("takes,T,want", [
+    ([1024], 1032, True),               # one chunk from row 0
+    ([512, 512], 1032, True),           # two, each on a tile
+    ([384, 300], 1032, True),           # a tail behind whole tiles
+    ([300], 1032, True),                # a prompt's last chunk alone
+    ([], 1032, True),                   # decode rows only
+    ([1, 1], 1032, True),               # one-row chunks are no tiles' matter
+    ([300, 724], 1032, False),          # the head of the next behind a tail
+    ([128, 1, 128], 1032, False),       # a row between two chunks
+    ([1, 128], 1032, False),
+    ([1028], 1032, False),              # the ninth tile is not whole
+    ([1024], 1024, True),
+    ([16], 20, False),                  # a tick under a tile has none
+    ([], 20, True),
+])
+def test_the_hosts_rule_of_a_tick_in_its_own_tiles(takes, T, want):
+    assert tiles_aligned(takes, T) is want
+    cu = np.concatenate([[0], np.cumsum(takes + [1] * (8 - len(takes)))])
+    assert _device_says_aligned(cu, [8], T) is want
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_hosts_rule_is_the_devices_scalar_over_random_ticks(seed):
+    """Chunks as ``_pack_tick`` lays them (the multi-row ones in ANY order
+    here, one-row chunks among them, the decode rows behind): the count on
+    the host and the scalar on the device are one rule."""
+    r = np.random.default_rng(seed)
+    said = []
+    for _ in range(40):
+        T = int(r.choice([520, 1032, 1024, 264]))
+        takes, room = [], T - 8
+        while room > 0 and len(takes) < 6 and r.random() < 0.8:
+            n = int(r.choice([1, ROW_TILE, 2 * ROW_TILE, 3 * ROW_TILE,
+                              int(r.integers(2, 300))]))
+            n = min(n, room)
+            takes.append(n)
+            room -= n
+        seqs = len(takes) + int(r.integers(0, 9 - len(takes)))
+        lens = takes + [1] * (seqs - len(takes))
+        cu = np.concatenate([[0], np.cumsum(lens + [0] * (8 - seqs))])
+        said.append(tiles_aligned(takes, T))
+        assert _device_says_aligned(cu, [seqs], T) is said[-1], (takes, T)
+    assert 5 < sum(said) < 35
+
+
+def test_a_tick_of_solars_tiny_model_counts_into_the_aligned_share(
+        monkeypatch):
+    """Two prompts through a tick of 128 + 4 rows: the chunks of the first
+    lie on the tick's tiles, the tick that holds its tail beside the
+    second's head does not, and the engine serves the same tokens whichever
+    layout the KDA layers' scan reads (``scan_tick``'s two branches)."""
+    from deepspeed_tpu.models import solar_open2
+
+    cfg = solar_open2.get_config("tinysolar", dtype=jnp.float32,
+                                 param_dtype=jnp.float32, remat=False,
+                                 use_flash_attention=False)
+    model = solar_open2.SolarOpen2ForCausalLM(cfg)
+    params = jax.jit(model.init)(jax.random.PRNGKey(3),
+                                 np.zeros((1, 8), np.int32))["params"]
+    prompts = [_tokens(300, 11), _tokens(200, 12)]
+
+    def serve():
+        eng = RaggedInferenceEngineV2(
+            model, params=params, max_seqs=4, max_seq_len=384,
+            prefill_chunk=ROW_TILE, page_size=16, num_pages=60,
+            decode_block_size=4)
+        assert eng.T == ROW_TILE + 4
+        flags = []
+        plan = eng._plan_rows
+
+        def watching():
+            takes = plan()
+            flags.append((list((takes or {}).values()), eng._plan_aligned))
+            return takes
+
+        eng._plan_rows = watching
+        try:
+            out = eng.generate_all(prompts, max_new_tokens=5)
+            return ({u: t.tolist() for u, t in out.items()}, flags,
+                    eng.serving_stages()["fused_tick_host"])
+        finally:
+            eng.close()
+
+    tokens, flags, got = serve()
+    chunks = [f for f in flags if f[0]]
+    assert chunks == [([128], True), ([128], True), ([44, 88], False),
+                      ([112], True)]
+    assert got["aligned_ticks"] == got["ticks"] - 1
+    assert got["aligned_tick_share"] == round(
+        got["aligned_ticks"] / got["ticks"], 4)
+    # the same run with the device told that no tick is aligned
+    layout = linear_attention.tile_layout
+    monkeypatch.setattr(
+        linear_attention, "tile_layout",
+        lambda *a: {**layout(*a), "aligned": jnp.asarray(False)})
+    gathered, flags_2, _ = serve()
+    assert flags_2 == flags and gathered == tokens
 
 
 # -- a served run -------------------------------------------------------------

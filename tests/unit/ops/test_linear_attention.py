@@ -370,3 +370,92 @@ def test_the_fastest_heads_decay_underflows_and_nothing_overflows():
                           jnp.ones((D, D), jnp.float32),
                           jnp.asarray(la.CHUNK))
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+
+
+# -- a tick whose chunks start on row tiles IS its tile layout ---------------
+
+# the three matrix-state rules, each at a tile of its own: (tile, heads, dk,
+# dv, the width of g: 0 none, 1 a number a head, dk a key channel)
+RULES = {"linear": (128, 2, 128, 128, 0), "delta": (64, 2, 64, 128, 1),
+         "kda": (32, 2, 64, 64, 64)}
+# rows a sequence of a tick of ``3 tiles + 8`` rows, in tiles; and whether
+# the tiles are the tick's own rows
+ALIGNED = {
+    "whole-tiles-beside-decode-rows": ([2.0, 1, 1, 1], True),
+    "two-chunks-of-whole-tiles": ([1.0, 2.0, 1], True),
+    # the decode rows lie in the tile the tail leaves part empty
+    "a-tail-behind-whole-tiles": ([2.35, 1, 1], True),
+    "no-multi-row-sequence": ([1, 1, 1, 1], True),
+    # its last rows lie in the eight past the whole tiles
+    "a-chunk-that-ends-past-the-whole-tiles": ([3.04], False),
+    "two-tails": ([1.6, 0.8, 1], False),
+    "a-row-between-two-chunks": ([1.0, 1, 1.0], False),
+}
+
+
+def _tick(rule, case, first_pos):
+    from deepspeed_tpu.ops import gated_delta_rule as gd, kda
+
+    tile, heads, dk, dv, gw = RULES[rule]
+    T, S = 3 * tile + 8, 5
+    lens = [n if isinstance(n, int) else int(n * tile)
+            for n in ALIGNED[case][0]]
+    cu = jnp.asarray(np.concatenate(
+        [[0], np.cumsum(lens + [0] * (S - len(lens)))]), jnp.int32)
+    r = np.random.default_rng(len(case))
+    bf = lambda *s: jnp.asarray(r.normal(size=s) * 0.3, jnp.float32).astype(
+        jnp.bfloat16)
+    rows = (bf(T, heads * dk), bf(T, heads * dk), bf(T, heads * dv))
+    gates = ()
+    if gw:
+        g = jnp.asarray(r.uniform(-2.0, -1e-3, size=(T, heads * gw)),
+                        jnp.float32)
+        b = jnp.asarray(r.uniform(0.1, 1.9, size=(T, heads)), jnp.float32)
+        rows, gates = (rows + (g,), (b,)) if gw > 1 else (rows, (g, b))
+    shape = (S, heads, dk, dv) if rule == "linear" else (S, dk, heads * dv)
+    state = jnp.asarray(r.normal(size=shape), jnp.float32)
+    lay = la.tile_layout(cu, jnp.asarray([len(lens)]),
+                         jnp.full((S,), first_pos, jnp.int32), T, tile)
+    ld = la.head_log_decay(heads)
+
+    def scan(kind):
+        def run(rows, gates, st, *tiles):
+            if rule == "linear":
+                return la.chunk_scan(*rows, ld, st, *tiles, interpret=kind)
+            q, k, v, g = rows if gw > 1 else rows + gates[:1]
+            return (kda if gw > 1 else gd).chunk_scan(
+                q, k, v, g, gates[-1], st, *tiles, chunk=tile,
+                interpret=kind)
+        return run
+
+    held = np.zeros((T,), bool)
+    for lo, n in zip(np.asarray(cu), lens):
+        held[lo:lo + n] = n >= 2
+    return T, tile, lay, scan, rows, gates, state, held
+
+
+@pytest.mark.parametrize("first_pos", [0, 640])
+@pytest.mark.parametrize("case", sorted(ALIGNED))
+@pytest.mark.parametrize("rule,kind", [
+    ("linear", "twin"), ("delta", "twin"), ("kda", "twin"),
+    ("linear", "kernel"), ("delta", "kernel"), ("kda", "kernel")])
+def test_a_tick_read_as_its_own_tiles_is_the_gathered_tick_to_the_bit(
+        rule, kind, case, first_pos):
+    """``scan_tick`` where ``tile_layout`` says the tiles ARE the tick's rows
+    (no row moved, ``T // tile`` tiles) against the same tick gathered to
+    ``ceil(T / tile) + seqs`` tiles and back: ``o``, every slot's state and
+    the zeros in the rows no tile holds, equal to the bit."""
+    T, tile, lay, scan, rows, gates, state, held = _tick(rule, case,
+                                                         first_pos)
+    assert bool(lay["aligned"]) == ALIGNED[case][1]
+    slots = jnp.asarray([3, 0, 4, 1, 2], jnp.int32)
+    run = jax.jit(lambda lay, st: la.scan_tick(
+        scan(kind == "kernel"), lay, slots, T, tile, rows, gates, st))
+    o, new = run(lay, state + 0)
+    o_g, new_g = run({**lay, "aligned": jnp.asarray(False)}, state + 0)
+    assert o.shape == o_g.shape == (T, rows[2].shape[1])
+    assert (o == o_g).all() and (new == new_g).all()
+    assert (np.asarray(o, np.float32)[~held] == 0).all()
+    if held.any():
+        assert (np.asarray(o, np.float32)[held] != 0).any()
+        assert (new != state).any()
