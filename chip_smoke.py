@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Does the system still start on the chip?  The quickest end-to-end proof.
 
-    python chip_smoke.py            # one TPU chip: train phase, serve phase
+    python chip_smoke.py            # one TPU chip: train, serve, delta rule
     python chip_smoke.py --chips 4  # one 4-chip host: ZeRO-3 and 4 replicas
 
 One process, the entry points a user calls, published widths, random
@@ -20,7 +20,12 @@ printed say how long the smoke took, not how fast the system is.
   tokens and compiles nothing, the attention ran through the Pallas
   kernels (bf16 pool and int8 pool), and the engine's logits agree with
   the model's plain ``apply``.
-- ``--chips 4`` runs neither, only what exists across chips: ZeRO-3 over a
+- *delta rule*: the two delta-rule families (``models/olmo_hybrid.py``,
+  ``models/solar_open2.py``; two heads at their published head sizes, one
+  period of layers) through ``RaggedInferenceEngineV2``: every request
+  completes, and a fused tick's short convolution took its one-pass kernel
+  (``ops/short_conv.py``), not the twin.
+- ``--chips 4`` runs none of them, only what exists across chips: ZeRO-3 over a
   4-device ``data`` mesh against the one-device run of the same job, and
   four replicas behind the router, one per chip, against one replica.
 
@@ -67,6 +72,7 @@ class Plan:
     quant_prompt_lens: Tuple[int, ...]
     quant_new_tokens: int
     parity_prompt_len: int
+    delta_models: Dict[str, Any]    # family -> its model, bf16
     # True: a TPU is attached, so every kernel must take its Pallas route
     # and show up in the compiled programs.  False (tests): the XLA twins.
     on_chip: bool = True
@@ -79,6 +85,7 @@ def full_plan() -> Plan:
 
     published = mistral.get_config("mistral-7b")
     return Plan(
+        delta_models=delta_models(),
         gpt2=gpt2.get_config("gpt2-350m", n_positions=1024,
                              use_flash_attention=True),
         train_seq=1024, train_micro=32, train_steps=4,
@@ -97,6 +104,28 @@ def full_plan() -> Plan:
         new_tokens=64,
         quant_prompt_lens=(512, 768), quant_new_tokens=16,
         parity_prompt_len=48)
+
+
+def delta_models() -> Dict[str, Any]:
+    """One period of each delta-rule family, bf16, two heads of the
+    published sizes: 96 / 192 (a head no whole lane tiles) and 128 (a head a
+    lane tile)."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import olmo_hybrid, solar_open2
+
+    bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                scan_layers=False, remat=False, use_flash_attention=False)
+    return {
+        "olmo_hybrid": olmo_hybrid.OlmoHybridForCausalLM(
+            olmo_hybrid.get_config(
+                "tinyolmohybrid", linear_key_head_dim=96,
+                linear_value_head_dim=192, **bf16)),
+        "solar_open2": solar_open2.SolarOpen2ForCausalLM(
+            solar_open2.get_config(
+                "tinysolar", linear_attn_config=dict(
+                    short_conv_kernel_size=4, head_dim=128, num_heads=2,
+                    num_kv_heads=None), **bf16))}
 
 
 # -- train -----------------------------------------------------------------
@@ -432,6 +461,44 @@ def serve_phase(plan: Plan) -> Dict[str, Any]:
     return out
 
 
+# -- delta rule ------------------------------------------------------------
+
+def delta_phase(plan: Plan) -> Dict[str, Any]:
+    import jax
+
+    from deepspeed_tpu.inference.v2.ragged_engine import \
+        RaggedInferenceEngineV2
+    from deepspeed_tpu.ops import routes, short_conv
+
+    want = (f"pallas-rows-{short_conv.ROWS}x{short_conv.LANE_BLOCK}"
+            if plan.on_chip else routes.XLA)
+    out: Dict[str, Any] = {"phase": "delta_rule", "routes": {},
+                           "new_tokens": plan.quant_new_tokens,
+                           "prompt_tokens": list(plan.quant_prompt_lens)}
+    for name, model in plan.delta_models.items():
+        params = jax.jit(model.init)(jax.random.PRNGKey(SEED),
+                                     np.zeros((1, 8), np.int32))["params"]
+        rng = np.random.default_rng(SEED + 2)
+        prompts = [rng.integers(1, model.config.vocab_size, size=(n,))
+                   .astype(np.int32) for n in plan.quant_prompt_lens]
+        eng = RaggedInferenceEngineV2(model, params=params, **plan.serve)
+        try:
+            outs = eng.generate_all(prompts,
+                                    max_new_tokens=plan.quant_new_tokens)
+            check(len(outs) == len(prompts) and all(
+                len(t) == len(p) + plan.quant_new_tokens
+                for t, p in zip((outs[u] for u in sorted(outs)), prompts)),
+                f"{name}: a request did not complete")
+            out["routes"][name] = eng.serving_stages()["kernels"].get(
+                short_conv.KERNEL)
+        finally:
+            eng.close()
+    check(set(out["routes"].values()) == {want},
+          f"short convolution routes {out['routes']}, expected {want}: a "
+          "delta-rule model's fused tick took the twin")
+    return out
+
+
 # -- four chips --------------------------------------------------------------
 
 def _bytes_by_device(tree) -> Dict[Any, int]:
@@ -566,7 +633,7 @@ def run(plan: Plan, chips: int = 1) -> int:
     check(len(devices) == chips,
           f"--chips {chips} but JAX reports {len(devices)} device(s)")
     cache_dir = enable_compile_cache()
-    phases = (train_phase, serve_phase) if chips == 1 else \
+    phases = (train_phase, serve_phase, delta_phase) if chips == 1 else \
         (zero3_phase, replicas_phase)
     with count_compiles() as compiles:
         for phase in phases:

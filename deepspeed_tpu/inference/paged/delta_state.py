@@ -32,7 +32,8 @@ from deepspeed_tpu.inference.paged.tree import (MATRIX_STATE_REFUSES,
                                                 SLOT_STATE_REFUSES, SlotState,
                                                 _one_row_seqs,
                                                 state_bytes_per_seq)
-from deepspeed_tpu.ops import gated_delta_rule, linear_attention, routes
+from deepspeed_tpu.ops import (gated_delta_rule, linear_attention, routes,
+                               short_conv)
 
 # the layers of a model whose config names them (models/olmo_hybrid.py)
 DELTA_LAYER = "linear_attention"
@@ -150,6 +151,9 @@ def _levels_halved(what: str, chunk: int, first: int) -> Dict[str, int]:
 class DeltaState(SlotState):
     """The gated-delta-rule layers of a model whose others keep K/V pages."""
 
+    # the module of the rule's two kernels (``kda_state.py``: ``ops/kda.py``)
+    rule = gated_delta_rule
+
     def refusals(self, model):
         model_is, _ = super().refusals(model)
         return model_is, {**SLOT_STATE_REFUSES, **MATRIX_STATE_REFUSES}
@@ -165,7 +169,9 @@ class DeltaState(SlotState):
         return counts
 
     def kernels(self, route):
-        return routes.taken(prefix=gated_delta_rule.KERNEL)
+        # the rule's two kernels, and the convolution before them
+        return {**routes.taken(prefix=self.rule.KERNEL),
+                **routes.taken(prefix=short_conv.KERNEL)}
 
     def stages(self, st, cache, cfg, kernels, pages_peak):
         # what a sequence's state costs as stored and as counted (a matrix

@@ -26,7 +26,7 @@ from deepspeed_tpu.inference.paged.delta_state import (DeltaState,
                                                        ragged_gated_delta)
 from deepspeed_tpu.inference.paged.kv_pages import kv_heads_stored
 from deepspeed_tpu.inference.paged.tree import state_bytes_per_seq
-from deepspeed_tpu.ops import kda, routes
+from deepspeed_tpu.ops import kda
 
 
 def kda_layers(cfg) -> int:
@@ -47,12 +47,11 @@ class KdaState(DeltaState):
     """The KDA layers of a model whose others keep K/V pages: a
     gated-delta-rule part's refusals and counts, its own names."""
 
+    rule = kda
+
     def span_attrs(self, cache):
         return {**super(DeltaState, self).span_attrs(cache),
                 "kda_layers": self.layers}
-
-    def kernels(self, route):
-        return routes.taken(prefix=kda.KERNEL)
 
     def stages(self, st, cache, cfg, kernels, pages_peak):
         # what a sequence's state costs as stored and as counted (a matrix

@@ -184,19 +184,45 @@ def _decay_proj_init(key, shape, dtype=jnp.float32):
 
 class ShortConv(nn.Module):
     """A causal depthwise convolution over q~, k~ and v~ side by side, then
-    SiLU; served, its last inputs are per-slot state of this module."""
+    SiLU, then q and k at unit length a head (q scaled by ``dk ** -0.5``);
+    served, its last inputs are per-slot state of this module.
+
+    ``parts``: ``(q~, k~, v~)`` as their projections leave them, ``[B, S, H *
+    dk]`` twice and ``[B, S, H * dv]`` -> ``q, k [B, S, H, dk]``, ``v [B, S,
+    H, dv]``.  A served fused tick on the chip is ONE pass a part
+    (``ops/short_conv.py``: the kernel takes the parts where they lie, the
+    norms inside it); every other call runs the lines below on the parts
+    side by side."""
 
     config: OlmoHybridConfig
 
     @nn.compact
-    def __call__(self, u, positions, ragged_meta=None):
-        cfg = self.config
+    def __call__(self, parts, positions, ragged_meta=None, *, heads: int):
+        cfg, H = self.config, heads
+        u = jnp.concatenate(parts, axis=-1)
         B, S, C = u.shape
+        dk, dv = parts[0].shape[-1] // H, parts[2].shape[-1] // H
         K = cfg.linear_conv_kernel_dim
         taps = self.param("weight", _taps_init, (C, K), cfg.param_dtype)
+        scales = (float(1.0 / np.sqrt(dk)), 1.0, None)
+
+        def unit(a, scale):
+            a = a.reshape(B, S, H, dk).astype(jnp.float32)
+            a = a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _L2_EPS)
+            return (a * scale).astype(cfg.dtype)
+
         if cfg.paged_decode:
             from deepspeed_tpu.inference.paged import ragged_short_conv
+            from deepspeed_tpu.ops import routes, short_conv
 
+            if short_conv.route(S, ragged_meta["cu_q_lens"].shape[0] - 1, K,
+                                u.dtype, dk) != routes.XLA:
+                q, k, v = ragged_short_conv(
+                    self, tuple(p[0] for p in parts), taps,
+                    positions.reshape(-1), ragged_meta, silu=True,
+                    unit=(dk, _L2_EPS, scales))
+                return (q.reshape(B, S, H, dk), k.reshape(B, S, H, dk),
+                        v.reshape(B, S, H, dv))
             c = ragged_short_conv(self, u[0], taps, positions.reshape(-1),
                                   ragged_meta)[None]
         else:
@@ -205,7 +231,9 @@ class ShortConv(nn.Module):
             c = sum(jnp.pad(u, ((0, 0), (d, 0), (0, 0)))[:, :S].astype(
                 jnp.float32) * t[:, K - 1 - d] for d in range(K)
             ).astype(u.dtype)
-        return jax.nn.silu(c.astype(jnp.float32)).astype(u.dtype)
+        y = jax.nn.silu(c.astype(jnp.float32)).astype(u.dtype)
+        q, k, v = jnp.split(y, [H * dk, 2 * H * dk], axis=-1)
+        return unit(q, scales[0]), unit(k, scales[1]), v.reshape(B, S, H, dv)
 
 
 class GatedDeltaNet(nn.Module):
@@ -227,18 +255,9 @@ class GatedDeltaNet(nn.Module):
             assert ragged_meta is not None and B == 1, (
                 "paged token batches are [1, T] with the engine's "
                 "ragged_meta")
-        qkv = jnp.concatenate([proj("q_proj", H * dk), proj("k_proj", H * dk),
-                               proj("v_proj", H * dv)], axis=-1)
-        qkv = ShortConv(cfg, name="conv")(qkv, positions, ragged_meta)
-        q, k, v = jnp.split(qkv, [H * dk, 2 * H * dk], axis=-1)
-
-        def unit(a, scale):
-            a = a.reshape(B, S, H, dk).astype(jnp.float32)
-            a = a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _L2_EPS)
-            return (a * scale).astype(cfg.dtype)
-
-        q, k = unit(q, float(1.0 / np.sqrt(dk))), unit(k, 1.0)
-        v = v.reshape(B, S, H, dv)
+        q, k, v = ShortConv(cfg, name="conv")(
+            (proj("q_proj", H * dk), proj("k_proj", H * dk),
+             proj("v_proj", H * dv)), positions, ragged_meta, heads=H)
         with jax.named_scope("gates"):
             f32 = dict(dense, dtype=jnp.float32)
             beta = jax.nn.sigmoid(nn.Dense(H, name="b_proj", **f32)(u))

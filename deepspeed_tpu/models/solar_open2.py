@@ -87,7 +87,6 @@ EMBED_INIT_STD = 1.0
 EXPERT_DOWN_INIT_SCALE = 0.3
 # the DeepSeek-V3 family's code adds this to the sum of the chosen scores
 ROUTED_WEIGHT_EPS = 1e-20
-_L2_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,18 +211,9 @@ class KimiDeltaAttention(nn.Module):
             assert ragged_meta is not None and B == 1, (
                 "paged token batches are [1, T] with the engine's "
                 "ragged_meta")
-        qkv = jnp.concatenate([proj("q_proj", H * d), proj("k_proj", H * d),
-                               proj("v_proj", H * d)], axis=-1)
-        qkv = ShortConv(cfg, name="conv")(qkv, positions, ragged_meta)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def unit(a, scale):
-            a = a.reshape(B, S, H, d).astype(jnp.float32)
-            a = a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + _L2_EPS)
-            return (a * scale).astype(cfg.dtype)
-
-        q, k = unit(q, float(1.0 / np.sqrt(d))), unit(k, 1.0)
-        v = v.reshape(B, S, H, d)
+        q, k, v = ShortConv(cfg, name="conv")(
+            (proj("q_proj", H * d), proj("k_proj", H * d),
+             proj("v_proj", H * d)), positions, ragged_meta, heads=H)
         with jax.named_scope("gates"):
             f32 = dict(dtype=jnp.float32)
             beta = jax.nn.sigmoid(proj("b_proj", H, **f32))

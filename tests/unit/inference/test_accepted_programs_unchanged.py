@@ -71,7 +71,19 @@ which holds the conditional and both layouts.  The three decode blocks and
 the sixteen programs of the eight other models are the parent's to the
 letter: ``tile_layout`` gained a key, ``aligned``, and a program that does
 not read it does not hold it (``keye.fused``, whose token selection reads
-the same layout, is as it was).
+the same layout, is as it was).  PR 67 re-took NONE: a delta-rule mixer's
+short convolution, SiLU and unit norms are one pass of a kernel in a fused tick
+ON THE CHIP (``ops/short_conv.py``, chosen by ``short_conv.route``), and this
+file lowers on the CPU, where ``models/olmo_hybrid.py:ShortConv`` keeps the
+lines it had (now its own for both families: the concatenation and the norms
+moved into it from the two model files, in the order they were traced) and
+``ragged_short_conv`` of an array is the parent's body with its state update
+in a function: ``olmo_hybrid.*``, ``solar.*`` and ``lfm2.*``, ticks and decode
+blocks, read what they read (the kernel is held to those lines in
+``tests/unit/inference/test_conv_state.py`` and compiled for the chip in
+``tests/unit/ops/test_tpu_compile.py``).  ``lfm2``'s tick keeps the twin on
+the chip too (Step 0 of PR 67: its ``[544, 2048]`` rows read slower through
+the kernel).
 
 The hashes were taken on the parent commit by this file's own
 :func:`lowered_hashes` (``python tests/unit/inference/

@@ -65,8 +65,11 @@ PLAIN = {"paged_attention": "xla-dense",
 PAD128 = {k: "xla-dense-pad128" for k in PLAIN}
 GROUPED = {"moe_grouped_matmul": "ragged-dot-xla",
            "moe_grouped_matmul_kernel": "ragged-dot"}
-DELTA = {"gated_delta_scan": "xla", "gated_delta_decode": "xla"}
-KDA = {"kda_scan": "xla", "kda_decode": "xla"}
+# the rule's two kernels, and since PR 67 the route of the short convolution
+# before them (``ops/short_conv.py``: the twin on the CPU)
+DELTA = {"gated_delta_scan": "xla", "gated_delta_decode": "xla",
+         "short_conv": "xla"}
+KDA = {"kda_scan": "xla", "kda_decode": "xla", "short_conv": "xla"}
 ANY = type("Any", (), {"__eq__": lambda self, other: True})()
 KEY_STEPS = dict(index_key_steps=3, index_live_key_steps=1)
 

@@ -782,6 +782,70 @@ def test_kda_kernels_at_the_serving_configuration(chip_compile):
     assert f"%{kda.STEP_NAME}" in text
 
 
+# (width, the head size the pass norms, or None): Solar's q~ (64 KDA heads of
+# 128, a head a lane tile) and v~; Olmo-Hybrid's q~ (30 heads of 96: no whole
+# lane tiles, so the block is the part's width, a tile of the pass four heads
+# in three lane tiles and the last one two heads in one and a half) and v~
+# (45 lane tiles: blocks of five)
+SHORT_CONV = [
+    pytest.param(8192, 128, id="solar-q-normed"),
+    pytest.param(8192, None, id="solar-v"),
+    pytest.param(2880, 96, id="olmo-hybrid-q-normed"),
+    pytest.param(5760, None, id="olmo-hybrid-v")]
+
+
+@pytest.mark.parametrize("width,head_dim", SHORT_CONV)
+def test_short_conv_rows_at_the_serving_configurations(chip_compile, width,
+                                                       head_dim):
+    """``ops/short_conv.py`` over a part of a fused tick of 8 + 1,024 rows
+    (the last block of rows holds 8 of them) with the 24 rows that start a
+    chunk to set, under the default scoped VMEM by its own reckoning."""
+    from deepspeed_tpu.ops import short_conv
+
+    assert short_conv.eligible(SOLAR["T"], 4, jnp.bfloat16, head_dim)
+    assert short_conv.vmem_bytes(width, 4, head_dim) < 16 * 1024 * 1024
+    text = chip_compile(
+        functools.partial(short_conv._rows.__wrapped__, silu=True,
+                          head_dim=head_dim, eps=1e-6, interpret=False),
+        ((SOLAR["T"], width), jnp.bfloat16), ((4, width), jnp.float32),
+        ((24, width), jnp.float32), ((24,), jnp.int32), ((1,), jnp.float32))
+    assert f"%{short_conv.NAME}" in text
+
+
+def test_a_kda_layers_pre_scan_function_writes_no_float32_rows(chip_compile):
+    """q~, k~ and v~ of a Solar KDA layer through the one-pass body of
+    ``ragged_short_conv`` (the rows that start a chunk computed whole, three
+    calls of the kernel, the state's update): no value of the
+    compiled program is the tick's rows in float32, which the twin's
+    scatter-add wrote and SiLU read back (PR 67)."""
+    import flax.linen as nn
+
+    from deepspeed_tpu.inference.paged.tree import SLOT_STATE
+    from deepspeed_tpu.ops import short_conv
+
+    c, i32, bf = SOLAR, jnp.int32, jnp.bfloat16
+    T, S, W, K = c["T"], c["S"], c["H"] * c["dk"], 4
+
+    class PreScan(nn.Module):
+        @nn.compact
+        def __call__(self, parts, taps, pos, meta):
+            return paged.ragged_short_conv(
+                self, parts, taps, pos, meta, silu=True,
+                unit=(c["dk"], 1e-6, (c["dk"] ** -0.5, 1.0, None)))
+
+    def run(state, q, k, v, taps, pos, cu, num_seqs, seq_slot):
+        meta = {"cu_q_lens": cu, "num_seqs": num_seqs, "seq_slot": seq_slot}
+        return PreScan().apply({"cache": {SLOT_STATE: state}}, (q, k, v),
+                               taps, pos, meta, mutable=["cache"])
+
+    text = chip_compile(
+        run, ((S, K - 1, 3 * W), bf), ((T, W), bf), ((T, W), bf),
+        ((T, W), bf), ((3 * W, K), bf), ((T,), i32), ((S + 1,), i32),
+        ((1,), i32), ((S,), i32))
+    assert text.count(f"%{short_conv.NAME}") >= 3
+    assert f"f32[{T},{3 * W}]" not in text and f"f32[{T},{W}]" not in text
+
+
 # trinity-large-serve: 48 query heads over 8 KV heads of 128, a query group
 # of SIX, which no older configuration has (Mistral 4, Solar 8, Olmo-Hybrid
 # 1); 8 slots of 513 pages, a fused tick of 8 + 1,024 rows; the window

@@ -54,7 +54,8 @@ def _tiny_plan(chip_smoke):
                    page_size=16, decode_block_size=4),
         prompt_lens=(20, 33, 41, 50), new_tokens=8,
         quant_prompt_lens=(20, 24), quant_new_tokens=6,
-        parity_prompt_len=12, on_chip=False)
+        parity_prompt_len=12, delta_models=chip_smoke.delta_models(),
+        on_chip=False)
 
 
 def _json_lines(text):
@@ -79,7 +80,7 @@ def test_one_chip_phases_and_last_line(chip_smoke, capsys, monkeypatch,
     assert chip_smoke.run(plan, chips=1) == 0
     assert jax.config.jax_compilation_cache_dir == before
     lines = _json_lines(capsys.readouterr().out)
-    train, serve, cache, last = lines[-4:]
+    train, serve, delta, cache, last = lines[-5:]
     assert train["phase"] == "train" and train["losses"][-1] < \
         train["losses"][0]
     assert serve["phase"] == "serve" and serve["compiles_after_warmup"] == 0
@@ -87,6 +88,8 @@ def test_one_chip_phases_and_last_line(chip_smoke, capsys, monkeypatch,
                                "bf16_decode": "xla-dense",
                                "bf16_prefill": "xla-dense"}
     assert serve["streamed"] == 2
+    assert delta["phase"] == "delta_rule" and delta["routes"] == {
+        "olmo_hybrid": "xla", "solar_open2": "xla"}
     assert cache["phase"] == "compile_cache" and cache["dir"] == \
         str(tmp_path)
     assert last == {"ok": True, "device": {
@@ -154,6 +157,14 @@ def test_a_decode_block_on_another_route_fails_the_serve_phase(
     with pytest.raises(chip_smoke.SmokeFailure,
                        match="'bf16_decode': 'pallas-ragged'"):
         chip_smoke.serve_phase(_tiny_plan(chip_smoke))
+
+
+def test_a_delta_rule_tick_on_the_twin_fails_the_delta_phase(chip_smoke):
+    """On the chip a fused tick's short convolution must take its one-pass
+    kernel; here it takes the twin, which a plan for the chip refuses."""
+    plan = dataclasses.replace(_tiny_plan(chip_smoke), on_chip=True)
+    with pytest.raises(chip_smoke.SmokeFailure, match="took the twin"):
+        chip_smoke.delta_phase(plan)
 
 
 def test_compile_cache_dir(monkeypatch, tmp_path, cache_config_restored):
