@@ -6,7 +6,8 @@ share a layer reads 12.5 under a uniform router; a seeded router skews.  It
 tells the absent experts' share from imbalance among the held ones
 (``hybrid_load_max_over_mean`` reads the second).  Spans without the count (a
 layer that holds every expert its router names, or the parent of the PR that
-added it) give nothing to read."""
+added it) give nothing to read.  Read in ``solar-longdoc16k-closed`` and
+``trinity-longdoc32k-closed``, one bare entry since PR 68."""
 from benchmark.layer_metrics import moe_trace
 
 

@@ -156,6 +156,45 @@ def test_the_readers_on_a_hybrid_models_spans():
     assert kv_pool_peak_pct.read(ctx) == pytest.approx(100 * 800 / 1279)
 
 
+def _shared_of(ctx, shards):
+    """``ctx`` with its ``engine.moe`` spans as a chip that holds one of
+    ``shards`` shares of each router's experts writes them: ``moe_rows`` is
+    what the held banks saw, ``moe_rows_routed`` every copy the router made,
+    ``bias_swaps`` (live tokens over the whole router) as it was."""
+    events = [(n, a, d, dict(st, moe_rows=st["moe_rows"] // shards,
+                             moe_rows_routed=st["moe_rows"])
+               if n == "engine.moe" else st) for n, a, d, st in _worker()]
+    return dict(ctx, _host_trace=host_spans.from_planes(_device(),
+                                                        {0: events}))
+
+
+def _bias_swap_pct_until_pr_68(ctx):
+    from benchmark.layer_metrics import moe_trace
+
+    spans = [r for r in moe_trace.spans(ctx) if "bias_swaps" in r]
+    return 100.0 * sum(int(r["bias_swaps"]) for r in spans) / (
+        sum(int(r["moe_rows"]) for r in spans) / 4)
+
+
+@pytest.mark.parametrize("shards,old_over_new", [(None, 1), (1, 1), (8, 8)])
+def test_bias_swap_pct_divides_by_the_routings_the_router_made(
+        shards, old_over_new):
+    """A span without ``moe_rows_routed`` (LFM2, GLM: every expert held)
+    reads what it read to the last digit; one of a chip that holds a share
+    of its router's experts reads the same share of the same routings,
+    where the form until PR 68 divided by the held rows and read ``shards``
+    times it."""
+    ctx = _ctx() if shards is None else _shared_of(_ctx(), shards)
+    share = 100 * 1184 / 6144
+    if shards is None:
+        assert bias_swap_pct.read(ctx) == _bias_swap_pct_until_pr_68(ctx)
+    assert bias_swap_pct.read(ctx) == pytest.approx(share)
+    # the old form on one chip of eight: a share over 100
+    assert _bias_swap_pct_until_pr_68(ctx) == pytest.approx(
+        old_over_new * share)
+    assert 0 < share < 100 < 8 * share
+
+
 def test_a_parents_spans_give_nothing_to_read():
     ctx = _ctx(state=False)
     for reader in (hybrid_decode_roofline, bias_swap_pct,

@@ -220,7 +220,8 @@ def _ffn_kinds(config):
     some layer is dense or an expert layer has a shared (dense) expert
     beside it.  Experts in EVERY layer and no shared one: no ``mlp``."""
     count = lambda *keys: max(int(config.get(k) or 0) for k in keys)
-    if count("num_experts", "n_routed_experts", "num_local_experts") < 2:
+    if count("num_experts", "n_routed_experts", "num_local_experts",
+             "moe_num_primary_experts") < 2:
         return {"mlp"}
     dense = (count("num_dense_layers", "first_k_dense_replace")
              or len(config.get("mlp_only_layers") or [])
@@ -253,7 +254,7 @@ def test_which_cells_report_each_scope_reader():
             want[f"scope_{kind}_pct"].add(w["name"])
         want["device_unscoped_pct"].add(w["name"])
     assert by_reader == want
-    # the rule, on the four shapes the benchmark has
+    # the rule, on the shapes the benchmark has
     assert _ffn_kinds({"num_experts": 64}) == {"moe"}
     assert _ffn_kinds({"num_experts": 128, "decoder_sparse_step": 1,
                        "mlp_only_layers": []}) == {"moe"}
@@ -262,3 +263,6 @@ def test_which_cells_report_each_scope_reader():
     assert _ffn_kinds({"n_routed_experts": 320, "n_shared_experts": 1}) == \
         {"moe", "mlp"}
     assert _ffn_kinds({"intermediate_size": 14336}) == {"mlp"}
+    # SmallThinker's key for its routed experts (PR 64's cell; the rule read
+    # it as a dense model until PR 68)
+    assert _ffn_kinds({"moe_num_primary_experts": 64}) == {"moe"}
