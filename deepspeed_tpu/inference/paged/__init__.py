@@ -29,7 +29,8 @@ delta rule, and the rows of the convolution before it: two leaves a layer),
 factor of its own),
 ``block_select.py`` (pages of keys and values chosen by compressed keys),
 ``index_select.py`` (tokens chosen by an indexer whose keys are a pool leaf),
-``latent.py`` (one latent row a token), ``window_pages.py`` (the window layers
+``latent.py`` (one latent row a token), ``latent_select.py`` (latent rows
+chosen by an indexer whose keys are a second leaf), ``window_pages.py`` (the window layers
 of a model that has full layers too: a pool, an allocator and a page table of
 their own, whose pages a sequence gives back as its window leaves them
 behind).  :func:`describe` composes a model's parts into the ONE description
@@ -67,6 +68,8 @@ from deepspeed_tpu.inference.paged.kv_pages import (  # noqa: F401
 from deepspeed_tpu.inference.paged.latent import (  # noqa: F401
     LATENT_PAGES, Latent, latent_update_and_attend, latent_width,
     ref_latent_paged_attention)
+from deepspeed_tpu.inference.paged.latent_select import (  # noqa: F401
+    LatentSelect, chosen_latent_update_and_attend)
 from deepspeed_tpu.inference.paged.linear_state import (  # noqa: F401
     LINEAR_MIXER, LinearState, linear_layers, ragged_linear_attention)
 from deepspeed_tpu.inference.paged.reference import (  # noqa: F401
@@ -156,6 +159,8 @@ def describe(cfg, tick: Optional[TickShape] = None) -> CacheKind:
         parts.append(KdaState(vector_delta, of))
     if selection is not None:
         parts.append(BlockSelect(rows, of, selection))
+    elif indexer is not None and width:
+        parts.append(LatentSelect(rows, of, indexer, tick, width))
     elif indexer is not None:
         parts.append(IndexSelect(rows, of, indexer, tick))
     elif width:

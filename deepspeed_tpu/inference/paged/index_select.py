@@ -17,13 +17,15 @@ Both leaves lead with the page dim."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.inference.paged.tree import Part, _one_row_seqs
+from deepspeed_tpu.inference.paged.tree import (Part, _one_row_seqs,
+                                                scoped_step)
 from deepspeed_tpu.ops import linear_attention, routes
 from deepspeed_tpu.ops import token_select_attention as tsa
 
@@ -157,13 +159,7 @@ def tokensel_update_and_attend(mdl, q: jax.Array, k: jax.Array, v: jax.Array,
         """One query a sequence (``[S, ...]``) over its chosen tokens; a
         fused tick with no such sequence (``any_on`` false) pays for no
         step of it."""
-        def step(scope, fn, like):
-            with jax.named_scope(scope):
-                if any_on is None:
-                    return fn()
-                return jax.lax.cond(any_on, fn, lambda: jax.tree_util.tree_map(
-                    lambda a: jnp.zeros(a.shape, a.dtype), like))
-
+        step = functools.partial(scoped_step, any_on=any_on)
         K = tsa.padded_keys(page_indices.shape[1], page)
         k = min(sa.topk, K)
         spec = jax.ShapeDtypeStruct

@@ -98,6 +98,19 @@ def _one_row_seqs(ragged_meta, T: int):
     return one, first
 
 
+def scoped_step(scope: str, fn, like, any_on=None):
+    """``fn()`` under the named scope ``scope``: one step of a one-row path
+    (its scores, its choice, its attention).  Where ``any_on`` (a traced
+    scalar: has the batch a sequence of one row at all) is given, a batch
+    that has none pays for no step of it and gets zeros shaped as ``like``
+    (a tree of ``jax.ShapeDtypeStruct``)."""
+    with jax.named_scope(scope):
+        if any_on is None:
+            return fn()
+        return jax.lax.cond(any_on, fn, lambda: jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, a.dtype), like))
+
+
 def state_bytes_per_seq(cache) -> int:
     """Bytes of per-slot state one sequence owns (0: the model has none)."""
     return sum(math.prod(leaf.shape[1:]) * leaf.dtype.itemsize

@@ -62,7 +62,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -107,6 +107,14 @@ class Glm4MoeLiteConfig(MixtralConfig):
     qk_nope_head_dim: int = 192
     qk_rope_head_dim: int = 64
     v_head_dim: int = 256
+    # the chips that share each expert layer, and which share this is: the
+    # router is ``n_routed_experts * expert_shards`` wide and
+    # ``n_routed_experts`` the experts HELD here (no keys of the source; the
+    # published models are ``expert_shards=1``)
+    expert_shards: int = 1
+    expert_shard: int = 0
+    # a first value (the module docstring)
+    expert_down_init_scale: float = EXPERT_DOWN_INIT_SCALE
     # layers of two feed-forwards do not scan; one chip holds the stage
     scan_layers: bool = False
     expert_parallel: bool = False
@@ -174,6 +182,84 @@ def get_config(preset: str, **overrides) -> Glm4MoeLiteConfig:
     return Glm4MoeLiteConfig(**kw)
 
 
+class LatentParts(NamedTuple):
+    """What a latent-attention module of this lineage projects from its
+    input (:func:`latent_parts`): the query latent ``c_q [B, S,
+    q_lora_rank]``; ``queries(c_q, positions)``, every head's ``(q_nope [B,
+    H, n, dn], q_rope [B, H, n, dr])`` of ``n`` of its rows, heads first and
+    rotated; ``q``, that pair of ALL its rows (None where the caller asks
+    for none); the normalised latent ``c [B, S, rank]`` and the one rotated key
+    ``k_r [B, S, dr]`` the heads share; the up-projection ``w_kvb [rank, H,
+    dn + dv]``; and ``out``, the output projection of ``[..., H, dv]``."""
+    c_q: jax.Array
+    queries: Callable
+    q: Any
+    c: jax.Array
+    k_r: jax.Array
+    w_kvb: jax.Array
+    out: Callable
+
+
+def latent_parts(mdl: nn.Module, x, positions,
+                 all_queries: bool = True) -> LatentParts:
+    """The projections of the module docstring's attention, made inside
+    ``mdl`` (a compact module whose ``config`` is of this lineage) under the
+    names a checkpoint gives them; ``all_queries``: every row's queries
+    among them, made where this lineage's first family traced them (a
+    caller that gathers its rows first asks for none)."""
+    cfg = mdl.config
+    B, S, E = x.shape
+    H = cfg.num_attention_heads
+    dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                  cfg.v_head_dim)
+    rank = cfg.kv_lora_rank
+    dense = dict(use_bias=False, dtype=cfg.dtype,
+                 param_dtype=cfg.param_dtype, **_init_kwargs(cfg))
+    norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
+    c_q = norm("q_a_layernorm")(
+        nn.Dense(cfg.q_lora_rank, name="q_a_proj", **dense)(x))
+    q_b_proj = nn.Dense(H * (dn + dr), name="q_b_proj", **dense)
+
+    def queries(c_q, positions):
+        q = q_b_proj(c_q).reshape(B, -1, H, dn + dr).transpose(0, 2, 1, 3)
+        return q[..., :dn], rotary_embedding(q[..., dn:], positions,
+                                             cfg.rope_theta)
+
+    q = queries(c_q, positions) if all_queries else None
+    kv_a = nn.Dense(rank + dr, name="kv_a_proj_with_mqa", **dense)(x)
+    c = norm("kv_a_layernorm")(kv_a[..., :rank])        # [B, S, rank]
+    k_r = rotary_embedding(kv_a[..., rank:][:, None], positions,
+                           cfg.rope_theta)[:, 0]        # [B, S, dr]
+    w_kvb = mdl.param("kv_b_proj", cfg.kernel_init,
+                      (rank, H * (dn + dv)), cfg.param_dtype)
+    w_kvb = w_kvb.astype(cfg.dtype).reshape(rank, H, dn + dv)
+    o_proj = nn.Dense(E, name="o_proj", **dense)
+    out = lambda y: o_proj(y.reshape(y.shape[:-2] + (H * dv,)))
+    return LatentParts(c_q, queries, q, c, k_r, w_kvb, out)
+
+
+def expanded_attention(cfg, q_nope, q_rope, c, k_r, w_kvb, ok):
+    """Whole sequences from their first token, expanded and nothing cached:
+    ``q_nope [B, H, S, dn]``, ``q_rope [B, H, S, dr]`` over the keys and
+    values ``W_kvb c`` gives every head, under the mask ``ok [..., S, S]``
+    (a query down, a key across) -> ``[B, S, H, dv]``."""
+    dn = cfg.qk_nope_head_dim
+    with jax.named_scope("kv_b"):
+        kv = jnp.einsum("bsc,chd->bhsd", c, w_kvb)
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    scores = (jnp.einsum("bhqn,bhkn->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhqr,bkr->bhqk", q_rope, k_r,
+                           preferred_element_type=jnp.float32))
+    p = jax.nn.softmax(
+        jnp.where(ok, scores * softmax_scale(cfg), -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkv->bqhv", p.astype(cfg.dtype), v)
+
+
+def softmax_scale(cfg) -> float:
+    return float(1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+
+
 class Glm4MoeLiteAttention(nn.Module):
     """Multi-head latent attention (the module docstring's equations):
     expanded where whole sequences are run, absorbed over the paged latent
@@ -185,36 +271,13 @@ class Glm4MoeLiteAttention(nn.Module):
     def __call__(self, x, positions, deterministic: bool = True,
                  ragged_meta=None):
         cfg = self.config
-        B, S, E = x.shape
+        B, S, _ = x.shape
         H = cfg.num_attention_heads
-        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                      cfg.v_head_dim)
+        dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         rank = cfg.kv_lora_rank
-        dense = dict(use_bias=False, dtype=cfg.dtype,
-                     param_dtype=cfg.param_dtype, **_init_kwargs(cfg))
-        norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-        c_q = norm("q_a_layernorm")(
-            nn.Dense(cfg.q_lora_rank, name="q_a_proj", **dense)(x))
-        q_b_proj = nn.Dense(H * (dn + dr), name="q_b_proj", **dense)
-
-        def queries(c_q, positions):
-            """``[B, n, q_lora_rank]`` -> every head's ``(q_nope [B, H, n,
-            dn], q_rope [B, H, n, dr])``, heads first."""
-            q = q_b_proj(c_q).reshape(B, -1, H, dn + dr).transpose(0, 2, 1, 3)
-            return q[..., :dn], rotary_embedding(q[..., dn:], positions,
-                                                 cfg.rope_theta)
-
-        q_nope, q_rope = queries(c_q, positions)
-        kv_a = nn.Dense(rank + dr, name="kv_a_proj_with_mqa", **dense)(x)
-        c = norm("kv_a_layernorm")(kv_a[..., :rank])        # [B, S, rank]
-        k_r = rotary_embedding(kv_a[..., rank:][:, None], positions,
-                               cfg.rope_theta)[:, 0]        # [B, S, dr]
-        w_kvb = self.param("kv_b_proj", cfg.kernel_init,
-                           (rank, H * (dn + dv)), cfg.param_dtype)
-        w_kvb = w_kvb.astype(cfg.dtype).reshape(rank, H, dn + dv)
-        sm_scale = float(1.0 / np.sqrt(dn + dr))
-        o_proj = nn.Dense(E, name="o_proj", **dense)
-        out = lambda y: o_proj(y.reshape(y.shape[:-2] + (H * dv,)))
+        c_q, queries, (q_nope, q_rope), c, k_r, w_kvb, out = latent_parts(
+            self, x, positions)
+        sm_scale = softmax_scale(cfg)
 
         if cfg.paged_decode:
             from deepspeed_tpu.inference.paged import (
@@ -275,44 +338,45 @@ class Glm4MoeLiteAttention(nn.Module):
             return out(y[None])
 
         # whole sequences from their first token: expanded, nothing cached
-        with jax.named_scope("kv_b"):
-            kv = jnp.einsum("bsc,chd->bhsd", c, w_kvb)
-        k_nope, v = kv[..., :dn], kv[..., dn:]
-        scores = (jnp.einsum("bhqn,bhkn->bhqk", q_nope, k_nope,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bhqr,bkr->bhqk", q_rope, k_r,
-                               preferred_element_type=jnp.float32))
         causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-        p = jax.nn.softmax(
-            jnp.where(causal, scores * sm_scale, -jnp.inf), axis=-1)
-        y = jnp.einsum("bhqk,bhkv->bqhv", p.astype(cfg.dtype), v)
-        return out(y)
+        return out(expanded_attention(cfg, q_nope, q_rope, c, k_r, w_kvb,
+                                      causal))
+
+
+def routed_experts(cfg: Glm4MoeLiteConfig, name: str = "mlp"):
+    """The routed half of an expert layer as the module docstring has it
+    (``moe/layer.py``'s dropless form under the ``noaux_tc`` router), of the
+    share of the router's experts ``cfg`` holds."""
+    return moe_layer(
+        cfg, name, intermediate_size=cfg.moe_intermediate_size,
+        normalize_weights=cfg.norm_topk_prob, scoring="sigmoid",
+        expert_bias=True, expert_bias_init_std=EXPERT_BIAS_INIT_STD,
+        down_init_scale=cfg.expert_down_init_scale,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        routed_weight_eps=ROUTED_WEIGHT_EPS,
+        expert_shards=cfg.expert_shards, expert_shard=cfg.expert_shard,
+        dispatch_impl="grouped")
 
 
 class Glm4MoeLiteBlock(nn.Module):
     config: Glm4MoeLiteConfig
     layer_idx: int
+    # the lineage's other family brings its own (``models/glm_moe_dsa.py``)
+    attention: Callable = Glm4MoeLiteAttention
 
     @nn.compact
     def __call__(self, x, positions, deterministic: bool = True,
                  ragged_meta=None):
         cfg = self.config
         norm = lambda name: RMSNorm(cfg.rms_norm_eps, cfg.dtype, name=name)
-        x = x + Glm4MoeLiteAttention(cfg, name="self_attn")(
+        x = x + self.attention(cfg, name="self_attn")(
             norm("input_layernorm")(x), positions, deterministic,
             ragged_meta)
         h = norm("post_attention_layernorm")(x)
         if self.layer_idx < cfg.first_k_dense_replace:
             return x + LlamaMLP(cfg, name="mlp")(h)
         live = live_rows(ragged_meta)
-        y, _ = moe_layer(
-            cfg, "mlp", intermediate_size=cfg.moe_intermediate_size,
-            normalize_weights=cfg.norm_topk_prob, scoring="sigmoid",
-            expert_bias=True, expert_bias_init_std=EXPERT_BIAS_INIT_STD,
-            down_init_scale=EXPERT_DOWN_INIT_SCALE,
-            routed_scaling_factor=cfg.routed_scaling_factor,
-            routed_weight_eps=ROUTED_WEIGHT_EPS,
-            dispatch_impl="grouped")(h, live=live)
+        y, _ = routed_experts(cfg)(h, live=live)
         if cfg.n_shared_experts:
             # ungated, unscaled, on every live row; padding rows and
             # inactive slots stay zeros as the routed path leaves them
@@ -332,6 +396,9 @@ class Glm4MoeLiteModel(nn.Module):
     """Hidden states after the final norm."""
 
     config: Glm4MoeLiteConfig
+    attention: Callable = Glm4MoeLiteAttention
+    embedding_init: Callable = in_float32(nn.initializers.variance_scaling(
+        1.0, "fan_in", "normal", out_axis=0))
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
@@ -343,11 +410,9 @@ class Glm4MoeLiteModel(nn.Module):
             positions = jnp.arange(input_ids.shape[1])
         x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                      param_dtype=cfg.param_dtype, name="embed_tokens",
-                     embedding_init=in_float32(
-                         nn.initializers.variance_scaling(
-                             1.0, "fan_in", "normal", out_axis=0)))(input_ids)
+                     embedding_init=self.embedding_init)(input_ids)
         for i in range(cfg.num_hidden_layers):
-            x = Glm4MoeLiteBlock(cfg, i, name=f"layers_{i}")(
+            x = Glm4MoeLiteBlock(cfg, i, self.attention, name=f"layers_{i}")(
                 x, positions, deterministic, ragged_meta)
         # a fused tick's head rows, before the row-wise final norm
         x = sampled_rows(x, ragged_meta)
@@ -356,12 +421,15 @@ class Glm4MoeLiteModel(nn.Module):
 
 class Glm4MoeLiteForCausalLM(nn.Module):
     config: Glm4MoeLiteConfig
+    # the body, which the lineage's other family gives its own attention
+    # and first values
+    body: Callable = Glm4MoeLiteModel
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,
                  ragged_meta=None):
         cfg = self.config
-        x = Glm4MoeLiteModel(cfg, name="model")(
+        x = self.body(cfg, name="model")(
             input_ids, positions, deterministic, ragged_meta)
         return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="lm_head",

@@ -59,7 +59,14 @@ input, ReGLU experts, four KV heads under both tables), and moved none of
 the twenty: ``moe/layer.py`` routes from the rows it dispatches where a
 caller passes neither ``route_only`` nor ``routing``, ``swiglu`` is the same
 ``silu``, and the paged kernels' paired tile is the chip's alone (this file
-lowers on the CPU, where both programs take the twins).  PR 66 re-took
+lowers on the CPU, where both programs take the twins).  PR 69 added the two
+programs of ``glm-5-serve``, taken on its own tree, and moved none of the
+twenty-five: ``models/glm4_moe_lite.py``'s attention makes its projections
+in a function the new family shares (``latent_parts``, every row's queries
+where they were traced: between ``q_a``'s norm and ``kv_a``), its block takes
+its routed half from ``routed_experts`` with ``expert_shards=1``, and
+``paged.describe`` takes its new branch for a config with BOTH
+``kv_lora_rank`` and an indexer, which no older family has.  PR 66 re-took
 ``sala.fused``, ``olmo_hybrid.fused`` and ``solar.fused`` and no other: it
 MEANT to move them (the three matrix-state kinds scan a tick through
 ``ops/linear_attention.py:scan_tick``, which reads the tick's own rows as the
@@ -205,13 +212,19 @@ EXPECTED = {
     "sala.fused_tiles": "705f67376f7e206d",
     "olmo_hybrid.fused_tiles": "b5a3ea3915d44f72",
     "solar.fused_tiles": "e607b2685e40c2a0",
+    # taken by PR 69, which added the model (a latent row and an index key a
+    # token, two pool leaves; the indexer fed by the query latent, the choice
+    # by token, the absorbed queries made from gathered rows of ``c_q``; 4 of
+    # 8 experts held); the twenty-five older hashes are as they were
+    "glm5.fused": "871c959472f8a6ba",
+    "glm5.decode_block": "2343f9c53f22d4b7",
 }
 
 
 def _models():
-    from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
-                                      minicpm_sala, mistral, olmo_hybrid,
-                                      olmoe, ouro, smallthinker,
+    from deepspeed_tpu.models import (glm4_moe_lite, glm_moe_dsa, keye_vl2,
+                                      lfm2_moe, minicpm_sala, mistral,
+                                      olmo_hybrid, olmoe, ouro, smallthinker,
                                       solar_open2, trinity)
 
     bf16 = dict(dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
@@ -240,6 +253,8 @@ def _models():
             "tinyglm", **bf16)),
         "smallthinker": smallthinker.SmallThinkerForCausalLM(
             smallthinker.get_config("tinysmallthinker", **bf16)),
+        "glm5": glm_moe_dsa.GlmMoeDsaForCausalLM(glm_moe_dsa.get_config(
+            "tinyglm5", **bf16)),
     }
 
 

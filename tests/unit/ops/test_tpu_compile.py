@@ -667,6 +667,71 @@ def test_token_select_kernels_at_the_serving_configuration(chip_compile):
     assert f"%{tsa.ROW_INDEX_NAME}" in text
 
 
+# glm-5-serve: 64 heads over rows of 512 + 64 stored as 640, 32 index heads
+# of 128 over ONE index key a row of lanes, pages of 64, 8 slots of 258
+# pages, a pool of 2,080 pages, a fused tick of 8 + 1,024 rows
+GLM5 = dict(S=8, pp=258, pool=2080, page=64, H=64, W=640, rank=512, HI=32,
+            dI=128, T=1032, topk=2048)
+
+
+def test_chosen_latent_kernels_at_the_serving_configuration(chip_compile):
+    """ISSUE 69's step 0, asked of the chip's compiler before any chip call:
+    ``index_select`` and ``row_index_scores`` at 32 index heads of 128 (a key
+    a row: ``index_pack`` 1), and ``ops/latent_select_attention.py``'s chunk
+    kernel at 64 heads, sixteen a grid step.  The DENSE latent chunk kernel
+    cannot stand at 64 heads: its tile is every head of 128 tokens and it
+    keeps the tick's whole output, 158 MiB against its 100 MiB ceiling, so
+    ``fused_tick_attention`` would take its XLA twin there."""
+    from deepspeed_tpu.ops import latent_attention as dense
+    from deepspeed_tpu.ops import latent_select_attention as lsa
+    from deepspeed_tpu.ops import linear_attention as la
+    from deepspeed_tpu.ops import token_select_attention as tsa
+
+    c, i32, bf = GLM5, jnp.int32, jnp.bfloat16
+    _, rt, kb = dense.tiling(c["page"], c["pp"], c["W"], c["rank"], bf)
+    need = dense.chunk_vmem_bytes(dense.tick_rows(c["T"]), c["H"], c["W"],
+                                  c["rank"], rt, kb * c["page"])
+    assert need > dense._VMEM_CEILING and need >> 20 == 158
+    NT = -(-c["T"] // tsa.TILE) + c["S"]
+    assert tsa.TILE == la.CHUNK == lsa.TILE and NT == 17
+    K = tsa.padded_keys(c["pp"], c["page"])
+    assert K == 33 * 512 and tsa.key_step(c["page"]) == 512
+    tiles = (((NT,), i32),) * 3
+    table = ((c["S"], c["pp"]), i32)
+    assert tsa.index_pack(c["page"], c["dI"]) == 1
+    index_pool = tsa.index_leaf_shape(c["pool"], c["page"], c["dI"])
+    assert index_pool == (c["pool"], 64, 128)       # a key a row of lanes
+    assert tsa._index_eligible(
+        jax.ShapeDtypeStruct((1, 1, c["dI"]), bf),
+        jax.ShapeDtypeStruct(index_pool, bf))
+    asked = tsa.select_vmem_bytes(K, c["HI"] * tsa.TILE, 512, 1)
+    assert asked >> 20 == 44                # a third of the v5e's VMEM
+    text = chip_compile(
+        functools.partial(tsa._select.__wrapped__, topk=c["topk"],
+                          interpret=False),
+        ((NT, c["HI"] * tsa.TILE, c["dI"]), bf),
+        ((NT, 1, c["HI"] * tsa.TILE), jnp.float32),
+        (index_pool, bf), table, *tiles)
+    assert f"%{tsa.SELECT_NAME}" in text and f'"size":"{asked}"' in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    text = chip_compile(
+        functools.partial(tsa._row_index.__wrapped__, interpret=False),
+        ((c["S"], c["HI"], c["dI"]), bf), ((c["S"], c["HI"]), jnp.float32),
+        (index_pool, bf), table, ((c["S"],), i32), ((c["S"],), i32))
+    assert f"%{tsa.ROW_INDEX_NAME}" in text
+    hg = lsa.heads_a_step(c["H"])
+    assert hg == 16 and lsa.eligible(c["W"], c["rank"], c["page"], bf)
+    asked = lsa.chunk_vmem_bytes(hg, c["W"], c["rank"], c["page"])
+    assert asked >> 20 == 32
+    text = chip_compile(
+        functools.partial(lsa._chunk.__wrapped__, rank=c["rank"],
+                          sm_scale=1 / 16.0, hg=hg, interpret=False),
+        ((c["H"], NT * lsa.TILE, c["W"]), bf),
+        ((c["pool"], c["page"], c["W"]), bf), ((NT, K, lsa.TILE), bf),
+        *tiles, table)
+    assert f"%{lsa.CHUNK_NAME}" in text and f'"size":"{asked}"' in text
+
+
 # olmo-hybrid-7b-serve: 30 delta-rule heads of 96 / 192 over a 96 x 192
 # float32 state held heads along the lanes; 30 attention heads of 128 stored
 # as 32 in a pool of 2,065 pages, 8 slots of 258 pages, a fused tick of

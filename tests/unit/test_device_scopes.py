@@ -37,14 +37,18 @@ FAMILIES = {
     "keye": {"attn", "moe"},
     "olmo-hybrid": {"attn", "linear", "mlp"},
     "solar": {"attn", "linear", "moe"},
+    "glm5": {"attn", "mlp", "moe"},
 }
 
 
 def _model(family):
-    from deepspeed_tpu.models import (glm4_moe_lite, keye_vl2, lfm2_moe,
-                                      minicpm_sala, mistral, olmo_hybrid,
-                                      olmoe, ouro, solar_open2)
+    from deepspeed_tpu.models import (glm4_moe_lite, glm_moe_dsa, keye_vl2,
+                                      lfm2_moe, minicpm_sala, mistral,
+                                      olmo_hybrid, olmoe, ouro, solar_open2)
 
+    if family == "glm5":
+        return glm_moe_dsa.GlmMoeDsaForCausalLM(
+            glm_moe_dsa.get_config("tinyglm5", **BF16))
     if family == "mistral":
         return mistral.MistralForCausalLM(mistral.get_config(
             "tinymistral", hidden_size=256, num_attention_heads=2,
@@ -315,6 +319,19 @@ def _train_engine(loss_module, dp=2):
      ("attn", "select_row", "-")),
     ("jit(f)/M/model/layers_0/self_attn/tokensel_row_attention/gather",
      ("attn", "tokensel_row_attention", "-")),
+    # latent rows chosen by token: the chunk pass's kernel, the one-row
+    # path's gather and attention (the row's absorbed queries inside it), the
+    # indexer's module fed by the query latent
+    ("jit(f)/M/model/layers_0/self_attn/jit(_chunk)/"
+     "latent_select_chunk_attention/pallas_call",
+     ("attn", "latent_select_chunk_attention", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/latent_select_row_attention/cond/"
+     "branch_1_fun/q_b_proj/dot_general",
+     ("attn", "latent_select_row_attention", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/latent_select_row_attention/gather",
+     ("attn", "latent_select_row_attention", "-")),
+    ("jit(f)/M/model/layers_0/self_attn/indexer/wq_b/dot_general",
+     ("attn", "indexer", "-")),
     # a model with window layers and full layers: the scope that says which
     # leads the sub-scope, a Pallas kernel's name behind it
     ("jit(f)/M/model/layers_1/self_attn/attn_window/q_proj/dot_general",
